@@ -236,6 +236,26 @@ def test_placement_does_not_alias_user_arrays():
     np.testing.assert_allclose(np.asarray(w1), np.asarray(wp - 0.1 * jg), rtol=1e-4, atol=1e-5)
 
 
+def test_init_sharded_is_born_placed_with_the_eager_values():
+    # a model larger than one chip cannot be built eagerly and moved
+    # afterwards; under jit with out_shardings no leaf is ever whole on one
+    # device — and the values are the eager call's, key for key (same PRNG
+    # bits whatever the sharding; the fused program may round the float math
+    # one bfloat16 ulp apart on a stray element)
+    cfg = llama.Config.from_name("tiny-llama-debug")
+    init = lambda: llama.init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.bfloat16)
+    mesh = dist.make_mesh({"fsdp": 8})
+    eager = init()
+    placed = dist.init_sharded(init, lambda shapes: dist.fsdp_shardings(shapes, mesh, min_size=64))
+    want = dist.fsdp_shardings(eager, mesh, min_size=64)
+    for x, y, sh in zip(*map(jax.tree_util.tree_leaves, (eager, placed, want))):
+        assert y.sharding == sh
+        np.testing.assert_allclose(np.asarray(x, np.float32), np.asarray(y, np.float32),
+                                   rtol=2 ** -7, atol=0)
+    assert any(len(y.sharding.spec) and y.sharding.spec[0] == "fsdp"
+               for y in jax.tree_util.tree_leaves(placed))
+
+
 def test_train_step_uses_sharded_flash_kernels(monkeypatch):
     # VERDICT round-1 weak #3: distributed TrainSteps must keep the Pallas
     # flash kernels (shard_map over batch/head axes), not fall back to the

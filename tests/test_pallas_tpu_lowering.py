@@ -1,0 +1,154 @@
+"""Every ``pallas_call`` site lowered for the TPU, on the CPU.
+
+The CPU suite runs the kernels under the Pallas interpreter
+(``pallasex._interpret()`` is true off-TPU), and the interpreter accepts
+what the TPU compiler refuses: a block whose last two dims neither tile by
+(8, 128) nor span the array, a ``scatter``, a matmul without a float32
+accumulator.  Nine of the twelve serving variants were refused at lowering
+for exactly those reasons while every differential test passed.  Here each
+kernel is lowered with ``lowering_platforms=("tpu",)`` and ``interpret``
+forced off — and, where the installed ``libtpu`` hands out a device-less
+``v5e`` topology, compiled by the real Mosaic too, which is where a
+``dot_general`` Mosaic cannot lay out shows up.  Seconds, no chip.
+
+One GQA shape (4 query heads per KV group, as Mistral-7B) and one MHA shape;
+head size 128 and block size 16 as served.  A variant left unrepaired would
+be ``xfail(strict=True)`` with the compiler's message; none is.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from thunder_tpu.executors import pallasex as px
+from thunder_tpu.serving.kernel_check import run_checks
+
+BF, F32, I32, I8, F8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8, jnp.float8_e4m3fn
+HS, BS, L, B, NBB, NB, T = 128, 16, 2, 2, 4, 9, 32
+SHAPES = {"gqa": (8, 2), "mha": (4, 4)}          # (n_head, n_query_groups)
+
+
+@pytest.fixture(scope="module")
+def tpu_sharding():
+    """A sharding on a TPU v5e device that exists only as a compile target,
+    or None where this installation cannot describe one (lowering alone is
+    checked then)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or one without AOT topologies
+        print(f"no TPU topology here ({type(e).__name__}: {e}); lowering only")
+        return None
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def compiled_not_interpreted(monkeypatch):
+    monkeypatch.setattr(px, "_interpret", lambda: False)
+
+
+def _cases(nh: int, ng: int) -> dict:
+    """name -> (fn, [(shape, dtype), ...]) for every pallas_call variant."""
+    q, fk = ((B, nh, HS), BF), ((B, ng, HS), BF)
+    qT, fT = ((B, nh, T, HS), BF), ((B, ng, T, HS), BF)
+    q5, f5 = ((B, nh, 5, HS), BF), ((B, ng, 5, HS), BF)
+    tab, pos, ne = ((B, NBB), I32), ((B,), I32), ((B,), I32)
+    vals, svals = ((B, L, ng, HS), BF), ((B, L, ng), F32)
+    scales = ((NB, L, ng, BS), F32)
+    chunk, dest, p1 = ((T // BS, L, ng, BS, HS), BF), ((NBB,), I32), ((1,), I32)
+
+    def arena(dt):
+        return ((NB, L, ng, BS, HS), dt)
+
+    decode = functools.partial(px.paged_attn_decode, layer=1)
+    verify = functools.partial(px.paged_attn_verify, layer=1)
+    write = functools.partial(px.paged_token_write, block_size=BS)
+    fused = functools.partial(px.paged_token_write_fused, block_size=BS)
+    cases = {
+        "paged_attn_decode": (decode, [q, arena(BF), arena(BF), fk, fk, tab, pos]),
+        "paged_attn_decode/window": (
+            functools.partial(decode, window=24), [q, arena(BF), arena(BF), fk, fk, tab, pos]),
+        "paged_attn_verify": (verify, [qT, arena(BF), arena(BF), fT, fT, tab, pos]),
+        "paged_attn_verify/T5": (verify, [q5, arena(BF), arena(BF), f5, f5, tab, pos]),
+        "paged_token_write": (write, [arena(BF), vals, tab, pos]),
+        "paged_token_write/scales": (write, [scales, svals, tab, pos]),
+        "paged_token_write/masked": (
+            lambda a, v, t, p, n: write(a, v, t, p, n_emit=n, offset=1),
+            [arena(BF), vals, tab, pos, ne]),
+        "paged_token_write/masked_scales": (
+            lambda a, v, t, p, n: write(a, v, t, p, n_emit=n, offset=0),
+            [scales, svals, tab, pos, ne]),
+        "paged_chunk_write": (
+            functools.partial(px.paged_chunk_write, block_size=BS),
+            [arena(BF), chunk, dest, p1]),
+        "lora_delta_fused/T1": (
+            functools.partial(px.lora_delta_fused, scaling=2.0),
+            [((B, 1, nh * HS), BF), ((B, 8, nh * HS), BF), ((B, ng * HS, 8), BF)]),
+        "lora_delta_fused/chunk": (
+            functools.partial(px.lora_delta_fused, scaling=2.0),
+            [((1, T, nh * HS), BF), ((1, 8, nh * HS), BF), ((1, ng * HS, 8), BF)]),
+    }
+    for name, dt in (("int8", I8), ("fp8", F8)):
+        quant = lambda f: (lambda q_, k, v, ks, vs, fk_, fv, t, p:
+                           f(q_, k, v, fk_, fv, t, p, k_scale=ks, v_scale=vs))
+        cases[f"paged_attn_decode/{name}"] = (
+            quant(decode), [q, arena(dt), arena(dt), scales, scales, fk, fk, tab, pos])
+        cases[f"paged_attn_verify/{name}"] = (
+            quant(verify), [qT, arena(dt), arena(dt), scales, scales, fT, fT, tab, pos])
+        cases[f"paged_token_write_fused/{name}"] = (
+            fused, [arena(dt), scales, vals, tab, pos])
+        cases[f"paged_token_write_fused/{name}/masked"] = (
+            lambda a, s, v, t, p, n: fused(a, s, v, t, p, n_emit=n, offset=1),
+            [arena(dt), scales, vals, tab, pos, ne])
+        cases[f"paged_chunk_write_fused/{name}"] = (
+            functools.partial(px.paged_chunk_write_fused, block_size=BS),
+            [arena(dt), scales, chunk, dest, p1])
+
+    # the training kernels: the jitted inner functions are called unwrapped,
+    # so no cached interpreted trace can stand in for the compiled one
+    Tq, scale = 256, 1.0 / np.sqrt(HS)
+    qf, kf = ((B * nh, Tq, HS), BF), ((B * ng, Tq, HS), BF)
+    lse = ((B * nh, Tq, 1), F32)
+    for name, window in (("", None), ("/window", 128)):
+        cases[f"flash_sdpa_fwd{name}"] = (
+            lambda q_, k, v, w=window: px._flash_fwd.__wrapped__(
+                q_, k, v, None, True, scale, nh, ng, None, 1, w), [qf, kf, kf])
+        cases[f"flash_sdpa_bwd{name}"] = (
+            lambda g, q_, k, v, o, l, w=window: px._flash_bwd.__wrapped__(
+                g, q_, k, v, o, l, None, True, scale, nh, ng, None, 1, w),
+            [qf, qf, kf, kf, qf, lse])
+    cases["flash_cross_entropy"] = (
+        px._flash_ce.__wrapped__, [((256, 2048), BF), ((256,), I32)])
+    return cases
+
+
+CASES = {shape: _cases(*heads) for shape, heads in SHAPES.items()}
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+@pytest.mark.parametrize("kernel", CASES["gqa"])
+def test_kernel_lowers_and_compiles_for_tpu(shape, kernel, tpu_sharding):
+    fn, specs = CASES[shape][kernel]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()   # Mosaic, not the interpreter
+    if tpu_sharding is not None:
+        lowered.compile()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_kernels_agree_with_their_jnp_references_interpreted(dtype):
+    """The check ``chip_smoke.py`` runs compiled on the chip, interpreted
+    here at tiny widths: every serving kernel against the gather path's own
+    building blocks, tolerances as documented in ``serving.kernel_check``."""
+    rows = run_checks(n_head=4, n_query_groups=2, head_size=16, block_size=4,
+                      dtype=dtype, window=7)
+    assert len(rows) >= 20
+    assert all(r["ok"] for r in rows), [r for r in rows if not r["ok"]]

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 import thunder_tpu as tt
+from thunder_tpu.distributed import make_mesh
 from thunder_tpu.models import llama
 from thunder_tpu.observability.metrics import registry
 from thunder_tpu.serving import (
@@ -471,7 +472,9 @@ class TestRecoveryParity:
 
     def test_mesh_engine_recovers_bit_identical(self, micro):
         cfg, params = micro
-        mesh = jax.make_mesh((2,), ("tp",))
+        # not jax.make_mesh: since jax 0.9 its axes default to Explicit
+        # (sharding in types), which the engine's programs are not written for
+        mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])
         eng = _engine(cfg, params, mesh=mesh)
         ref = eng.submit(P0, max_new_tokens=6).result().new_tokens
         eng = _engine(

@@ -42,6 +42,7 @@ from thunder_tpu.distributed.sharding import (
     batch_spec,
     ddp_shardings,
     fsdp_shardings,
+    init_sharded,
     kv_cache_spec,
     llama_shardings,
     make_mesh,
@@ -60,6 +61,7 @@ __all__ = [
     "batch_spec",
     "ddp_shardings",
     "fsdp_shardings",
+    "init_sharded",
     "kv_cache_spec",
     "llama_shardings",
     "make_mesh",

@@ -43,21 +43,13 @@ __all__ = [
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: newer jax exposes
-    ``jax.shard_map`` (replication checking spelled ``check_vma``), older
-    releases only ``jax.experimental.shard_map`` (spelled ``check_rep``).
-    Every shard_map in the repo goes through here so the version split
-    lives in one place; checking is disabled either way — the per-shard
-    bodies close over collectives the checker cannot see through."""
+    """``jax.shard_map`` with replication checking off.  Every shard_map in
+    the repo goes through here, so that choice lives in one place: the
+    per-shard bodies close over collectives the checker cannot see through."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @unique

@@ -35,6 +35,7 @@ __all__ = [
     "ddp_shardings",
     "llama_shardings",
     "apply_shardings",
+    "init_sharded",
     "ShardingRules",
 ]
 
@@ -237,3 +238,16 @@ def _place_no_alias(x, s):
 def apply_shardings(tree, shardings):
     """Places a pytree onto devices per a matching pytree of shardings."""
     return jax.tree_util.tree_map(_place_no_alias, tree, shardings)
+
+
+def init_sharded(init_fn: Callable[[], Any], shardings_fn: Callable[[Any], Any]):
+    """Builds a pytree that is born placed: ``init_fn()`` runs under
+    ``jax.jit`` with ``out_shardings = shardings_fn(shapes)``, where
+    ``shapes`` is its ``jax.eval_shape`` result (any ``*_shardings`` rule
+    here accepts it).  Each device materializes only its own shards, so a
+    model larger than one chip can be initialised; building every leaf
+    eagerly and moving it with :func:`apply_shardings` puts the whole tree
+    on the default device first.  The PRNG is partitionable — same bits per
+    key whatever the sharding — so the values are the eager call's, up to
+    the last-ulp rounding of a fused program."""
+    return jax.jit(init_fn, out_shardings=shardings_fn(jax.eval_shape(init_fn)))()

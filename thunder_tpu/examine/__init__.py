@@ -177,20 +177,38 @@ def train_memory_report(train_step) -> dict:
     return dict(train_step.profile_stats())
 
 
-# hardware peaks (bf16 FLOP/s, HBM bytes/s) keyed by jax backend — the ONE
-# source of truth for roofline/MFU math (bench.py imports this).  TPU row is
-# the v5e chip; the cpu row is nominal so smoke MFU stays well-defined.
-HW_PEAKS: dict[str, tuple[float, float]] = {
-    "tpu": (197e12, 819e9),
-    "cpu": (1e12, 100e9),
+# Published peaks of one chip, keyed by jax's ``device_kind`` — the ONE table
+# behind every roofline, MFU and utilization figure (bench.py reads it).  A
+# device that is not here is an error, never a default.
+DEVICE_PEAKS: dict[str, dict] = {
+    "TPU v5 lite": {
+        "bf16_flops_per_sec": 197e12,
+        "int8_ops_per_sec": 393e12,
+        "hbm_bytes": 16e9,
+        "hbm_bytes_per_sec": 819e9,
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
 }
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The :data:`DEVICE_PEAKS` row of ``device_kind``; raises for a device
+    whose peaks nobody has written down with their source."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); add a row with its source to "
+            f"thunder_tpu.examine.DEVICE_PEAKS — a peak is never assumed"
+        ) from None
 
 
 def cost_analysis(fn: Callable, *args, flops_per_sec: float | None = None,
                   bytes_per_sec: float | None = None) -> dict:
     """XLA's OWN cost model for ``fn`` at ``args``: FLOPs, HBM bytes
-    accessed, arithmetic intensity, and a roofline step-time estimate at the
-    hardware peaks (defaulted per backend; v5e for TPU).
+    accessed, arithmetic intensity, and — given peaks — a roofline step-time
+    estimate.
 
     ``fn`` must be jax-traceable at ``args`` — a plain jax/numpy callable,
     or a thunder execution trace's ``python_callable()``
@@ -199,15 +217,13 @@ def cost_analysis(fn: Callable, *args, flops_per_sec: float | None = None,
     the exact compiled program, not an analytic FLOPs formula.
 
     Roofline keys (``roofline_seconds``/``compute_seconds``/
-    ``memory_seconds``/``bound``) are present whenever both peaks resolve —
-    explicitly passed, or defaulted from ``HW_PEAKS`` for the backend.
+    ``memory_seconds``/``bound``) are present when both peaks are passed
+    (``device_peaks(kind)`` has a chip's).  The counts describe the program
+    as THIS backend compiled it, whatever peaks they are divided by.
     """
     import jax
 
-    compiled = jax.jit(fn).lower(*args).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns one entry per device program
-        ca = ca[0] if ca else {}
+    ca = jax.jit(fn).lower(*args).compile().cost_analysis()
     flops = float(ca.get("flops", 0.0))
     bytes_accessed = float(ca.get("bytes accessed", 0.0))
     out = {
@@ -215,11 +231,6 @@ def cost_analysis(fn: Callable, *args, flops_per_sec: float | None = None,
         "bytes_accessed": bytes_accessed,
         "arithmetic_intensity": (flops / bytes_accessed) if bytes_accessed else None,
     }
-    peak = HW_PEAKS.get(jax.default_backend())
-    if flops_per_sec is None and peak is not None:
-        flops_per_sec = peak[0]
-    if bytes_per_sec is None and peak is not None:
-        bytes_per_sec = peak[1]
     if flops_per_sec is not None and bytes_per_sec is not None:
         t_compute = flops / flops_per_sec
         t_memory = bytes_accessed / bytes_per_sec

@@ -18,7 +18,12 @@ operator symbols with checkers and a grad transform), re-designed for TPU:
 
 On non-TPU backends the kernels can run via the Pallas interpreter
 (``THUNDER_TPU_PALLAS_INTERPRET=1``) for testing; otherwise dispatch falls
-back to the jnp reference implementation.
+back to the jnp reference implementation.  That is also what happens when
+jax could not reach a TPU and settled for the CPU, so a program that means to
+run on the chip checks ``jax.devices()[0].platform`` itself
+(``chip_smoke.py``, ``bench.require_tpu``).  The interpreter accepts block
+shapes and ops Mosaic refuses: ``tests/test_pallas_tpu_lowering.py`` lowers
+every kernel here for the TPU.
 """
 from __future__ import annotations
 
@@ -32,11 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pltpu compiles only where the TPU plugin exists; interpret mode doesn't need it
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from thunder_tpu.core.prims import PrimIDs, prim_lookup
 from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_executor
@@ -306,7 +307,7 @@ def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: 
         _fwd_kernel, BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window
     )
     params = {}
-    if pltpu is not None and not _interpret():
+    if not _interpret():
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
@@ -332,9 +333,9 @@ def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: 
             jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((BQ, 1), jnp.float32) if pltpu is not None else None,
-            pltpu.VMEM((BQ, 1), jnp.float32) if pltpu is not None else None,
-            pltpu.VMEM((BQ, hs), jnp.float32) if pltpu is not None else None,
+            pltpu.VMEM((BQ, 1), jnp.float32),
+            pltpu.VMEM((BQ, 1), jnp.float32),
+            pltpu.VMEM((BQ, hs), jnp.float32),
         ],
         interpret=_interpret(),
         **params,
@@ -474,7 +475,7 @@ def _flash_bwd(g, q, k, v, out, lse, mask, causal: bool, scale: float, H: int, G
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True)
 
     params = {}
-    if pltpu is not None and not _interpret():
+    if not _interpret():
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
@@ -500,7 +501,7 @@ def _flash_bwd(g, q, k, v, out, lse, mask, causal: bool, scale: float, H: int, G
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, BQ, hs), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Tq, hs), q.dtype),
-        scratch_shapes=[pltpu.VMEM((BQ, hs), jnp.float32) if pltpu is not None else None],
+        scratch_shapes=[pltpu.VMEM((BQ, hs), jnp.float32)],
         interpret=_interpret(),
         **params,
     )(*dq_operands)
@@ -538,8 +539,8 @@ def _flash_bwd(g, q, k, v, out, lse, mask, causal: bool, scale: float, H: int, G
             jax.ShapeDtypeStruct((BH, Tk, hs), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((BK, hs), jnp.float32) if pltpu is not None else None,
-            pltpu.VMEM((BK, hs), jnp.float32) if pltpu is not None else None,
+            pltpu.VMEM((BK, hs), jnp.float32),
+            pltpu.VMEM((BK, hs), jnp.float32),
         ],
         interpret=_interpret(),
         **params,
@@ -861,23 +862,27 @@ def _ce_kernel(logits_ref, tgt_ref, loss_ref, lse_ref, m_s, s_s, p_s, *, BN, BV)
         loss_ref[...] = lse - p_s[...]
 
 
-@functools.lru_cache(maxsize=1)
-def _tuning() -> dict:
-    """Measured kernel tuning, committed by tools/kernel_tune.py from a real
-    TPU run (VERDICT r3 #2: a kernel that loses to XLA must win or yield).
-    Keys: ``ce.bn`` / ``ce.bv_cap`` (block geometry), ``ce.claim`` (default
-    **False** — the checker defers to the XLA lowering until a measurement
-    says otherwise)."""
-    import json
-
-    path = os.environ.get(
+def _tuning_path() -> str:
+    return os.environ.get(
         "THUNDER_TPU_PALLAS_TUNING",
         os.path.join(os.path.dirname(os.path.abspath(__file__)), "pallas_tuning.json"),
     )
+
+
+@functools.lru_cache(maxsize=1)
+def _tuning() -> dict:
+    """Measured kernel tuning, committed by tools/kernel_tune.py from a real
+    TPU run (a kernel that loses to XLA must win or yield).
+    Keys: ``ce.bn`` / ``ce.bv_cap`` (block geometry), ``ce.claim`` (default
+    **False** — the checker defers to the XLA lowering until a measurement
+    says otherwise).  No file means no tuning; a file that does not parse
+    raises, because it decides which kernel runs."""
+    import json
+
     try:
-        with open(path) as f:
+        with open(_tuning_path()) as f:
             return json.load(f)
-    except Exception:
+    except FileNotFoundError:
         return {}
 
 
@@ -914,7 +919,7 @@ def _flash_ce(logits, target):
     BN, BV = _ce_blocks(N, V)
     kernel = functools.partial(_ce_kernel, BN=BN, BV=BV)
     params = {}
-    if pltpu is not None and not _interpret():
+    if not _interpret():
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         )
@@ -934,9 +939,9 @@ def _flash_ce(logits, target):
             jax.ShapeDtypeStruct((N, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((BN, 1), jnp.float32) if pltpu is not None else None,
-            pltpu.VMEM((BN, 1), jnp.float32) if pltpu is not None else None,
-            pltpu.VMEM((BN, 1), jnp.float32) if pltpu is not None else None,
+            pltpu.VMEM((BN, 1), jnp.float32),
+            pltpu.VMEM((BN, 1), jnp.float32),
+            pltpu.VMEM((BN, 1), jnp.float32),
         ],
         interpret=_interpret(),
         **params,
@@ -1067,10 +1072,23 @@ ex.register_implementation(PrimIDs.CROSS_ENTROPY_FWD, _ce_op, checker=_ce_checke
 
 def paged_available() -> bool:
     """Whether the paged decode kernels can run here: Pallas enabled (TPU, or
-    interpret mode opted in) and the TPU lowering package imports (scalar
-    prefetch and VMEM scratch come from ``pallas.tpu`` even when
-    interpreted)."""
-    return _pallas_available() and pltpu is not None
+    interpret mode opted in)."""
+    return _pallas_available()
+
+
+def _scale_column(s_ref, g, bs):
+    """KV group ``g``'s dequant scales as a ``(bs, 1)`` column.
+
+    The scale arena is ``(num_blocks, L, ng, bs)``, so the smallest block
+    Mosaic accepts is the whole ``(ng, bs)`` slab of one arena block (the
+    last two block dims must equal the array's); the group's row is picked
+    here.  The row has ``bs`` on lanes and the K/V tile wants it on sublanes:
+    the masked lane-sum below is that transpose in ops Mosaic lowers at any
+    ``bs``, and it is exact (one non-zero term per output)."""
+    row = s_ref[0, 0, pl.ds(g, 1), :]                      # (1, bs)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
 
 def _paged_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest, bs,
@@ -1081,7 +1099,7 @@ def _paged_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest, bs,
     else:
         ks_ref = vs_ref = None
         fk_ref, fv_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    i, j = pl.program_id(0), pl.program_id(2)
+    i, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nb = pl.num_programs(2)
     p_i = pos_ref[i]
 
@@ -1094,7 +1112,7 @@ def _paged_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest, bs,
     def _dequant(x_ref, s_ref, dt):
         x = x_ref[0, 0, 0]                                 # (bs, hs) storage dtype
         if s_ref is not None:
-            x = (x.astype(jnp.float32) * s_ref[0, 0, 0][:, None]).astype(cdtype)
+            x = (x.astype(jnp.float32) * _scale_column(s_ref, g, bs)).astype(cdtype)
         return x.astype(dt)
 
     def _online(s, v, dt):
@@ -1134,15 +1152,22 @@ def _paged_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest, bs,
 
     @pl.when(j == nb - 1)
     def _finalize():
-        q = q_ref[0, 0]
-        fk = fk_ref[0, 0].astype(q.dtype)                  # (hs,) at cdtype
-        fv = fv_ref[0, 0].astype(q.dtype)
-        s_f = jax.lax.dot_general(
-            q, fk[None, :], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) / sm                                             # (rep, 1), never masked
-        _online(s_f, fv[None, :], q.dtype)
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        # the fresh token is one key: its score and value terms are written
+        # as float32 multiply-and-sum, because Mosaic refuses the
+        # (rep, hs)·(1, hs) dot_general for rep > 1.  The operands are rounded
+        # to q.dtype first, as a matmul's would be, and a product of two such
+        # values is exact in float32.
+        q = q_ref[0, 0].astype(jnp.float32)                # (rep, hs)
+        fk = fk_ref[0, 0].astype(q_ref.dtype).astype(jnp.float32)  # (1, hs)
+        fv = fv_ref[0, 0].astype(q_ref.dtype).astype(jnp.float32)
+        s_f = jnp.sum(q * fk, axis=1, keepdims=True) / sm  # (rep, 1), never masked
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_new = jnp.maximum(m_prev, s_f)
+        p = jnp.exp(s_f - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + p
+        acc = acc_ref[...] * corr + p.astype(q_ref.dtype).astype(jnp.float32) * fv
+        o_ref[0, 0] = (acc / l_new).astype(o_ref.dtype)
 
 
 def _ragged_step(i, j, p, nb, *, bs, window):
@@ -1197,9 +1222,11 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
         (1, 1, 1, bs, hs),
         lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, g, 0, 0))
     scale_spec = pl.BlockSpec(
-        (1, 1, 1, bs),
-        lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, g, 0))
-    fresh_spec = pl.BlockSpec((1, 1, hs), lambda i, g, j, tab, p, nb: (i, g, 0))
+        (1, 1, ng, bs),                                # see _scale_column
+        lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, 0, 0))
+    # (B, ng, 1, hs): a (1, 1, hs) block of the rank-3 array would tile
+    # (ng, hs) by (1, hs), which Mosaic refuses
+    fresh_spec = pl.BlockSpec((1, 1, 1, hs), lambda i, g, j, tab, p, nb: (i, g, 0, 0))
     q_spec = pl.BlockSpec((1, 1, rep, hs), lambda i, g, j, tab, p, nb: (i, g, 0, 0))
 
     in_specs = [q_spec, arena_spec, arena_spec]
@@ -1208,7 +1235,7 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
         in_specs += [scale_spec, scale_spec]
         args += [k_scale, v_scale]
     in_specs += [fresh_spec, fresh_spec]
-    args += [fresh_k, fresh_v]
+    args += [fresh_k[:, :, None, :], fresh_v[:, :, None, :]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -1238,59 +1265,111 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     return out.reshape(B, nh, hs)
 
 
-def _paged_write_kernel(tab_ref, pos_ref, a_ref, v_ref, o_ref, *, rank5):
-    del tab_ref, pos_ref, a_ref  # routing happens in the BlockSpec index maps
-    if rank5:
-        o_ref[0, :, :, 0, :] = v_ref[0]
-    else:
-        o_ref[0, :, :, 0] = v_ref[0]
+def _token_dest(tab, p, ne, i, *, bs, offset):
+    """Request ``i``'s destination ``(block, slot)`` for the token at chunk
+    offset ``offset``, from the scalar-prefetch refs: ``tab[i, (pos +
+    offset) // bs]`` / ``(pos + offset) % bs``, or sink block 0 slot 0 when
+    the keep-mask ``offset < n_emit[i]`` rejects it.  Shared by the BlockSpec
+    index maps (block) and the kernel bodies (slot)."""
+    at = p[i] + offset
+    blk, slot = tab[i, at // bs], at % bs
+    if ne is not None:
+        keep = offset < ne[i]
+        blk, slot = jnp.where(keep, blk, 0), jnp.where(keep, slot, 0)
+    return blk, slot
 
 
-def paged_token_write(arena, vals, tables, pos, *, block_size):
+def _merge_slot(old, new, slot):
+    """``old`` with row ``slot`` of its block_size dim (axis 1) replaced by
+    ``new`` (size 1 there).  Mosaic takes neither a slot-granular block (the
+    last two block dims must tile by (8, 128) or span the array's) nor a
+    one-row dynamic store or DMA into a tiled dim, so a token lands as a
+    whole-block select: read the block, replace one row, write it back."""
+    row = jax.lax.broadcasted_iota(jnp.int32, old.shape, 1)
+    return jnp.where(row == slot, new, old)
+
+
+def _token_write_kernel(*refs, bs, offset, masked):
+    # refs = (tab, pos, n_emit?, arena, vals, out)
+    tab_ref, pos_ref = refs[:2]
+    ne_ref = refs[2] if masked else None
+    a_ref, v_ref, o_ref = refs[-3:]
+    _, slot = _token_dest(tab_ref, pos_ref, ne_ref, pl.program_id(0),
+                          bs=bs, offset=offset)
+    o_ref[0, 0] = _merge_slot(a_ref[0, 0], v_ref[0, 0], slot)
+
+
+def _token_specs(arena, *, bs, offset):
+    """Arena and token-value BlockSpecs over ``grid=(B, L)``: one (ng, bs[,
+    hs]) block of one layer per step, so VMEM use does not grow with depth.
+    Values ride with a size-1 block_size dim, (B, L, ng, 1[, hs]), which
+    broadcasts against the block in :func:`_merge_slot`."""
+    tail = (0,) * (arena.ndim - 3)
+
+    def a_index(i, l, tab, p, *ne):
+        blk, _ = _token_dest(tab, p, ne[0] if ne else None, i, bs=bs, offset=offset)
+        return (blk, l, 0) + tail
+
+    a_spec = pl.BlockSpec((1, 1) + arena.shape[2:], a_index)
+    v_spec = pl.BlockSpec((1, 1, arena.shape[2], 1) + arena.shape[4:],
+                          lambda i, l, *_: (i, l, 0) + tail)
+    return a_spec, v_spec
+
+
+def _token_call(kernel, prefetch, arenas, vals, in_specs, out_specs):
+    B, L = vals[0].shape[:2]
+    n = len(prefetch)
+    kwargs = {}
+    if not _interpret():
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n, grid=(B, L),
+            in_specs=in_specs, out_specs=out_specs),
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arenas],
+        # arenas in == arenas out (in-place): unvisited blocks keep their bytes
+        input_output_aliases={n + k: k for k in range(len(arenas))},
+        interpret=_interpret(),
+        **kwargs,
+    )(*prefetch, *arenas, *vals)
+
+
+def paged_token_write(arena, vals, tables, pos, *, block_size, n_emit=None,
+                      offset=0):
     """In-place single-token arena write (the scatter_token replacement).
 
     ``arena``: (num_blocks, L, ng, bs, hs) K/V arena — or (num_blocks, L, ng,
     bs) scale arena; ``vals``: (B, L, ng, hs) (or (B, L, ng)) at the arena
     dtype — quantize *before* calling (``quant.quantize_kv``), so the stored
     values match scatter_token_q exactly.  Each request's destination block
-    and slot (``tables[i, pos[i] // bs]``, ``pos[i] % bs``) are computed in
-    the BlockSpec index map; the arena aliases the output, so untouched
-    blocks keep their bytes and no scatter primitive appears in the program.
-    Padding rows (all-sink tables, pos 0) land in sink block 0, whose
-    contents are never attended.
+    (``tables[i, pos[i] // bs]``) is computed in the BlockSpec index map and
+    its slot (``pos[i] % bs``) in the kernel, which rewrites that one block
+    with the token's row replaced (:func:`_merge_slot`); the arena aliases
+    the output, so untouched blocks keep their bytes and no scatter
+    primitive appears in the program.  Padding rows (all-sink tables, pos 0)
+    land in sink block 0, whose contents are never attended.
+
+    Keep-masked form, for the speculative verify commit and multi-step
+    decode: with ``n_emit`` (B,) int32 and a static chunk ``offset``,
+    request ``i`` lands ``vals[i]`` at slot ``pos[i] + offset`` iff ``offset
+    < n_emit[i]``; rejected rows route to sink block 0 slot 0, so a rejected
+    draft's KV stays invisible.  With ``offset=0`` and ``n_emit = live ∈ {0,
+    1}`` the predicate *is* the per-row liveness mask of multi-step decode
+    (``write_fresh_kv_live``): a live row stores the same bytes as the
+    unmasked write, a finished row sinks every remaining iteration's write,
+    and the N-step program stays static-shape with zero scatters.
     """
-    bs = block_size
-    B = vals.shape[0]
-    if arena.ndim == 5:
-        _, L, ng, _, hs = arena.shape
-        a_spec = pl.BlockSpec(
-            (1, L, ng, 1, hs),
-            lambda i, tab, p: (tab[i, p[i] // bs], 0, 0, p[i] % bs, 0))
-        v_spec = pl.BlockSpec((1, L, ng, hs), lambda i, tab, p: (i, 0, 0, 0))
-    else:
-        _, L, ng, _ = arena.shape
-        a_spec = pl.BlockSpec(
-            (1, L, ng, 1),
-            lambda i, tab, p: (tab[i, p[i] // bs], 0, 0, p[i] % bs))
-        v_spec = pl.BlockSpec((1, L, ng), lambda i, tab, p: (i, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B,),
-        in_specs=[a_spec, v_spec],
-        out_specs=a_spec,
-    )
-    kwargs = {}
-    if not _interpret():
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",))
-    return pl.pallas_call(
-        functools.partial(_paged_write_kernel, rank5=arena.ndim == 5),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
-        input_output_aliases={2: 0},   # arena in == arena out (in-place)
-        interpret=_interpret(),
-        **kwargs,
-    )(tables, pos, arena, vals)
+    prefetch = (tables, pos) if n_emit is None else (
+        tables, pos, n_emit.astype(jnp.int32))
+    a_spec, v_spec = _token_specs(arena, bs=block_size, offset=offset)
+    (out,) = _token_call(
+        functools.partial(_token_write_kernel, bs=block_size, offset=offset,
+                          masked=n_emit is not None),
+        prefetch, (arena,), (jnp.expand_dims(vals, 3),),
+        [a_spec, v_spec], [a_spec])
+    return out
 
 
 def _paged_verify_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest,
@@ -1308,7 +1387,7 @@ def _paged_verify_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest,
     else:
         ks_ref = vs_ref = None
         fk_ref, fv_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    i, j = pl.program_id(0), pl.program_id(2)
+    i, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nb = pl.num_programs(2)
     p_i = pos_ref[i]
 
@@ -1321,7 +1400,7 @@ def _paged_verify_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest,
     def _dequant(x_ref, s_ref, dt):
         x = x_ref[0, 0, 0]                                 # (bs, hs) storage dtype
         if s_ref is not None:
-            x = (x.astype(jnp.float32) * s_ref[0, 0, 0][:, None]).astype(cdtype)
+            x = (x.astype(jnp.float32) * _scale_column(s_ref, g, bs)).astype(cdtype)
         return x.astype(dt)
 
     def _online(s, v, dt):
@@ -1385,7 +1464,7 @@ def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     ``q``: (B, nh, T, hs) chunk queries at global positions
     ``[pos, pos+T)``; ``fresh_k``/``fresh_v``: (B, ng, T, hs) the chunk's own
     projected K/V at the cache compute dtype (not yet in the arena — the
-    caller commits the accepted prefix with :func:`paged_token_write_masked`,
+    caller commits the accepted prefix with the keep-masked :func:`paged_token_write`,
     or the whole chunk with :func:`paged_chunk_write`, afterwards).
     Arena/scale/table/pos/``n_blocks`` arguments as
     :func:`paged_attn_decode`.  Sliding-window models are rejected upstream
@@ -1410,8 +1489,8 @@ def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
         (1, 1, 1, bs, hs),
         lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, g, 0, 0))
     scale_spec = pl.BlockSpec(
-        (1, 1, 1, bs),
-        lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, g, 0))
+        (1, 1, ng, bs),                                # see _scale_column
+        lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, 0, 0))
     fresh_spec = pl.BlockSpec((1, 1, T, hs), lambda i, g, j, tab, p, nb: (i, g, 0, 0))
     q_spec = pl.BlockSpec((1, 1, rep * T, hs), lambda i, g, j, tab, p, nb: (i, g, 0, 0))
 
@@ -1449,72 +1528,6 @@ def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
         **kwargs,
     )(tables, pos, n_blocks, *args)
     return out.reshape(B, nh, T, hs)
-
-
-def _paged_write_masked_kernel(tab_ref, pos_ref, ne_ref, a_ref, v_ref, o_ref, *, rank5):
-    del tab_ref, pos_ref, ne_ref, a_ref  # routing happens in the index maps
-    if rank5:
-        o_ref[0, :, :, 0, :] = v_ref[0]
-    else:
-        o_ref[0, :, :, 0] = v_ref[0]
-
-
-def paged_token_write_masked(arena, vals, tables, pos, n_emit, offset, *, block_size):
-    """Keep-masked arena write for the speculative verify commit — and,
-    at ``offset=0``, the per-row liveness write of multi-step decode.
-
-    Request ``i`` lands ``vals[i]`` — the K/V (or scale) of chunk offset
-    ``offset`` — at arena slot ``pos[i] + offset`` iff ``offset <
-    n_emit[i]``; rejected offsets route to sink block 0 slot 0 (whose bytes
-    are never attended), so rejected-draft KV stays invisible without a
-    scatter primitive in the program.  ``offset`` is static (one call per
-    chunk position); ``n_emit`` rides as a scalar-prefetch operand so the
-    routing happens in the BlockSpec index map.
-
-    Multi-step decode liveness contract (``write_fresh_kv_live``): with
-    ``offset=0`` and ``n_emit = live ∈ {0, 1}`` the predicate *is* the
-    per-row liveness mask — a live row commits exactly like the unmasked
-    single-step ``paged_token_write`` (bit-identical stored bytes), a row
-    that finished earlier in the scan sinks every remaining iteration's
-    write, so the N-step program stays static-shape with zero scatters.
-    """
-    bs = block_size
-    B = vals.shape[0]
-    k = offset
-    if arena.ndim == 5:
-        _, L, ng, _, hs = arena.shape
-        a_spec = pl.BlockSpec(
-            (1, L, ng, 1, hs),
-            lambda i, tab, p, ne: (
-                jnp.where(k < ne[i], tab[i, (p[i] + k) // bs], 0), 0, 0,
-                jnp.where(k < ne[i], (p[i] + k) % bs, 0), 0))
-        v_spec = pl.BlockSpec((1, L, ng, hs), lambda i, tab, p, ne: (i, 0, 0, 0))
-    else:
-        _, L, ng, _ = arena.shape
-        a_spec = pl.BlockSpec(
-            (1, L, ng, 1),
-            lambda i, tab, p, ne: (
-                jnp.where(k < ne[i], tab[i, (p[i] + k) // bs], 0), 0, 0,
-                jnp.where(k < ne[i], (p[i] + k) % bs, 0)))
-        v_spec = pl.BlockSpec((1, L, ng), lambda i, tab, p, ne: (i, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B,),
-        in_specs=[a_spec, v_spec],
-        out_specs=a_spec,
-    )
-    kwargs = {}
-    if not _interpret():
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",))
-    return pl.pallas_call(
-        functools.partial(_paged_write_masked_kernel, rank5=arena.ndim == 5),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
-        input_output_aliases={3: 0},   # arena in == arena out (in-place)
-        interpret=_interpret(),
-        **kwargs,
-    )(tables, pos, n_emit.astype(jnp.int32), arena, vals)
 
 
 def _chunk_dest(c, dest_ref, pos_ref, *, bs):
@@ -1576,15 +1589,20 @@ def paged_chunk_write(arena, vals, dest, pos, *, block_size):
     )(dest, pos, arena, vals)
 
 
+def _qmax(storage) -> float:
+    return 127.0 if storage == jnp.dtype(jnp.int8) else float(jnp.finfo(storage).max)
+
+
 def _absmax_quant(x, qmax, storage):
     """The exact :func:`serving.quant.quantize_kv` math, in-kernel: float32
     absmax over the last (hs) dim, scale 1.0 for all-zero rows, int8
     round-and-clip / fp8 cast.  Same ops in the same order, so the stored
-    bytes are bit-identical to the unfused quantize-then-write path."""
+    bytes are bit-identical to the unfused quantize-then-write path.
+    Returns ``(q, scale, xf)``; ``scale`` keeps the reduced dim at size 1."""
     xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=-1)
+    amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
     scale = jnp.where(amax == 0.0, 1.0, amax / qmax)
-    y = xf / scale[..., None]
+    y = xf / scale
     if jnp.dtype(storage) == jnp.dtype(jnp.int8):
         q = jnp.clip(jnp.round(y), -qmax, qmax).astype(storage)
     else:
@@ -1598,7 +1616,7 @@ def _paged_chunk_write_fused_kernel(dest_ref, pos_ref, a_ref, s_ref, v_ref,
     c = pl.program_id(0)
     q, scale, xf = _absmax_quant(v_ref[0], qmax, oa_ref.dtype)
     oa_ref[0] = q
-    os_ref[0] = scale
+    os_ref[0] = scale[..., 0]
     # masked quantization-error sums behind the serving.kv_quant.rel_err
     # gauge: only blocks actually written (non-sink dest) count, matching
     # scatter_blocks_q's mask
@@ -1606,11 +1624,15 @@ def _paged_chunk_write_fused_kernel(dest_ref, pos_ref, a_ref, s_ref, v_ref,
     idx = pos_ref[0] // bs + c
     live = jnp.logical_and(idx < nbb, dest_ref[jnp.minimum(idx, nbb - 1)] != 0)
     m = live.astype(jnp.float32)
-    dq = q.astype(jnp.float32) * scale[..., None]
-    err = jnp.zeros((8, 128), jnp.float32)
-    err = err.at[0, 0].set(jnp.sum(jnp.abs(dq - xf)) * m)
-    err = err.at[0, 1].set(jnp.sum(jnp.abs(xf)) * m)
-    oe_ref[0] = err
+    dq = q.astype(jnp.float32) * scale
+    # the two sums land at [0, 0] and [0, 1] of a zero (8, 128) tile; a
+    # select on iotas, because Mosaic has no scatter for ``.at[].set``
+    row = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    oe_ref[0] = jnp.where(
+        row != 0, 0.0,
+        jnp.where(col == 0, jnp.sum(jnp.abs(dq - xf)) * m,
+                  jnp.where(col == 1, jnp.sum(jnp.abs(xf)) * m, 0.0)))
 
 
 def paged_chunk_write_fused(arena, scale_arena, vals, dest, pos, *, block_size):
@@ -1626,7 +1648,7 @@ def paged_chunk_write_fused(arena, scale_arena, vals, dest, pos, *, block_size):
     rel_err figure ``scatter_blocks_q`` reports."""
     bs = block_size
     nc, L, ng, _bs, hs = vals.shape
-    qmax = 127.0 if arena.dtype == jnp.dtype(jnp.int8) else float(jnp.finfo(arena.dtype).max)
+    qmax = _qmax(arena.dtype)
     route = functools.partial(_chunk_dest, bs=bs)
     a_spec = pl.BlockSpec(
         (1, L, ng, bs, hs), lambda c, dest, p: (route(c, dest, p), 0, 0, 0, 0))
@@ -1658,83 +1680,56 @@ def paged_chunk_write_fused(arena, scale_arena, vals, dest, pos, *, block_size):
     )(dest, pos, arena, scale_arena, vals)
 
 
-def _paged_token_write_fused_kernel(tab_ref, pos_ref, *rest, qmax):
-    # rest = (ne_ref?, a_ref, s_ref, v_ref, oa_ref, os_ref) — the masked
-    # variant prepends its n_emit prefetch ref; all routing (including the
-    # emit predicate) happens in the BlockSpec index maps
-    del tab_ref, pos_ref
-    v_ref, oa_ref, os_ref = rest[-3:]
-    q, scale, _ = _absmax_quant(v_ref[0], qmax, oa_ref.dtype)
-    oa_ref[0, :, :, 0, :] = q
-    os_ref[0, :, :, 0] = scale
+def _token_write_fused_kernel(*refs, bs, offset, masked, qmax):
+    # refs = (tab, pos, n_emit?, arena, scales, vals, vals_rows, out_a, out_s)
+    tab_ref, pos_ref = refs[:2]
+    ne_ref = refs[2] if masked else None
+    a_ref, s_ref, v_ref, vr_ref, oa_ref, os_ref = refs[-6:]
+    _, slot = _token_dest(tab_ref, pos_ref, ne_ref, pl.program_id(0),
+                          bs=bs, offset=offset)
+    # the value block keeps ng as a major dim, (ng, bs, hs), the scale block
+    # has it on sublanes, (ng, bs): the same absmax runs once per layout —
+    # identical scales — so no relayout is needed in between
+    q, _, _ = _absmax_quant(v_ref[0, 0], qmax, oa_ref.dtype)      # (ng, 1, hs)
+    _, scale, _ = _absmax_quant(vr_ref[0, 0], qmax, oa_ref.dtype)  # (ng, 1)
+    oa_ref[0, 0] = _merge_slot(a_ref[0, 0], q, slot)
+    os_ref[0, 0] = _merge_slot(s_ref[0, 0], scale, slot)
 
 
 def paged_token_write_fused(arena, scale_arena, vals, tables, pos, *,
                             block_size, n_emit=None, offset=0):
-    """Quantizing twin of :func:`paged_token_write` (and, with ``n_emit``,
-    of :func:`paged_token_write_masked`): ``vals`` (B, L, ng, hs) arrive at
-    the compute dtype; the kernel runs the exact ``quantize_kv`` absmax math
-    and lands value + scale through two aliased outputs in one pallas_call —
-    the decode program's quantize-on-write with no standalone quantize op.
+    """Quantizing twin of :func:`paged_token_write` (keep-masked form
+    included): ``vals`` (B, L, ng, hs) arrive at the compute dtype; the
+    kernel runs the exact ``quantize_kv`` absmax math and lands value + scale
+    through two aliased outputs in one pallas_call — the decode program's
+    quantize-on-write with no standalone quantize op.
     Returns ``(arena, scale_arena)``."""
-    bs = block_size
-    B = vals.shape[0]
-    _, L, ng, _, hs = arena.shape
-    qmax = 127.0 if arena.dtype == jnp.dtype(jnp.int8) else float(jnp.finfo(arena.dtype).max)
-    k = offset
-    if n_emit is None:
-        a_spec = pl.BlockSpec(
-            (1, L, ng, 1, hs),
-            lambda i, tab, p: (tab[i, p[i] // bs], 0, 0, p[i] % bs, 0))
-        s_spec = pl.BlockSpec(
-            (1, L, ng, 1),
-            lambda i, tab, p: (tab[i, p[i] // bs], 0, 0, p[i] % bs))
-        v_spec = pl.BlockSpec((1, L, ng, hs), lambda i, tab, p: (i, 0, 0, 0))
-        num_prefetch, prefetch = 2, (tables, pos)
-    else:
-        a_spec = pl.BlockSpec(
-            (1, L, ng, 1, hs),
-            lambda i, tab, p, ne: (
-                jnp.where(k < ne[i], tab[i, (p[i] + k) // bs], 0), 0, 0,
-                jnp.where(k < ne[i], (p[i] + k) % bs, 0), 0))
-        s_spec = pl.BlockSpec(
-            (1, L, ng, 1),
-            lambda i, tab, p, ne: (
-                jnp.where(k < ne[i], tab[i, (p[i] + k) // bs], 0), 0, 0,
-                jnp.where(k < ne[i], (p[i] + k) % bs, 0)))
-        v_spec = pl.BlockSpec((1, L, ng, hs), lambda i, tab, p, ne: (i, 0, 0, 0))
-        num_prefetch, prefetch = 3, (tables, pos, n_emit.astype(jnp.int32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_prefetch,
-        grid=(B,),
-        in_specs=[a_spec, s_spec, v_spec],
-        out_specs=[a_spec, s_spec],
-    )
-    kwargs = {}
-    if not _interpret():
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",))
-    na = num_prefetch  # arena arg index right after the prefetch operands
-    return pl.pallas_call(
-        functools.partial(_paged_token_write_fused_kernel, qmax=qmax),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(arena.shape, arena.dtype),
-            jax.ShapeDtypeStruct(scale_arena.shape, scale_arena.dtype),
-        ],
-        input_output_aliases={na: 0, na + 1: 1},
-        interpret=_interpret(),
-        **kwargs,
-    )(*prefetch, arena, scale_arena, vals)
+    prefetch = (tables, pos) if n_emit is None else (
+        tables, pos, n_emit.astype(jnp.int32))
+    a_spec, v_spec = _token_specs(arena, bs=block_size, offset=offset)
+    s_spec, _ = _token_specs(scale_arena, bs=block_size, offset=offset)
+    vr_spec = pl.BlockSpec((1, 1) + vals.shape[2:], lambda i, l, *_: (i, l, 0, 0))
+    return _token_call(
+        functools.partial(_token_write_fused_kernel, bs=block_size,
+                          offset=offset, masked=n_emit is not None,
+                          qmax=_qmax(arena.dtype)),
+        prefetch, (arena, scale_arena), (jnp.expand_dims(vals, 3), vals),
+        [a_spec, s_spec, v_spec, vr_spec], [a_spec, s_spec])
 
 
 def _lora_delta_kernel(x_ref, a_ref, b_ref, o_ref, *, scaling):
     x = x_ref[0]                                       # (T, C)
     a = a_ref[0].astype(x.dtype)                       # (r, C)
     b = b_ref[0].astype(x.dtype)                       # (fout, r)
-    d = jax.lax.dot_general(x, a, (((1,), (1,)), ((), ())))
-    o_ref[0] = (jax.lax.dot_general(d, b, (((1,), (1,)), ((), ()))) * scaling
-                ).astype(o_ref.dtype)
+    # the MXU accumulates in float32 (Mosaic refuses any other accumulator);
+    # rounding each product back to x.dtype is what the unfused einsums'
+    # default accumulation does
+    def mm(u, w):
+        return jax.lax.dot_general(
+            u, w, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(x.dtype)
+
+    o_ref[0] = (mm(mm(x, a), b) * scaling).astype(o_ref.dtype)
 
 
 def lora_delta_fused(x, a, b, scaling):
@@ -1742,8 +1737,8 @@ def lora_delta_fused(x, a, b, scaling):
     per target instead of two standalone HLO einsums (the Liger fused-
     epilogue pattern applied to the adapter path).  ``x``: (B, T, fin);
     ``a``: (B, r, fin); ``b``: (B, fout, r) → (B, T, fout), same dtype flow
-    as ``models.generate._lora_delta`` (factors cast to ``x.dtype``, default
-    accumulation), so the delta is bit-identical to the unfused twin.  Used
+    as ``models.generate._lora_delta`` (factors cast to ``x.dtype``, each
+    product rounded to ``x.dtype``), so the delta matches the unfused twin.  Used
     by the meshless kernel path only — under a mesh the unfused einsums stay
     (a bare pallas_call has no SPMD rule)."""
     B, T, C = x.shape

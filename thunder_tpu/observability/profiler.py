@@ -276,8 +276,6 @@ def _cost_thunk_for(bsym: BoundSymbol, fn: Callable) -> Callable | None:
             return fn(*a, **kw)
 
         ca = jax.jit(call).lower(*structs).compile().cost_analysis()
-        if isinstance(ca, list):  # older jax: one entry per device program
-            ca = ca[0] if ca else {}
         flops = ca.get("flops")
         bytes_accessed = ca.get("bytes accessed")
         return (

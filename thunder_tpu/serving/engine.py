@@ -298,6 +298,9 @@ class ServingEngine:
     ):
         if shardings is not None and mesh is None:
             raise ValueError("shardings= requires mesh= (param placement needs a mesh)")
+        from thunder_tpu.core import compile_cache
+
+        compile_cache.ensure_enabled()  # an engine warms tens of bucket programs
         self.async_step = bool(async_step)
         if prefill_chunk is not None and not self.async_step:
             raise ValueError(

@@ -44,8 +44,6 @@ from thunder_tpu.executors.pallasex import (
     paged_chunk_write_fused,
     paged_token_write,
     paged_token_write_fused,
-    paged_token_write_masked,
-    pltpu as _pltpu,
 )
 from thunder_tpu.models.generate import (
     _linear,
@@ -61,7 +59,7 @@ __all__ = ["forward_paged", "write_fresh_kv", "write_fresh_kv_live",
 
 
 def _smap(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions — the shared compat shim."""
+    """shard_map through the repo's one entry (replication checking off)."""
     from thunder_tpu.distributed.prims import shard_map_compat
 
     return shard_map_compat(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
@@ -71,15 +69,12 @@ def paged_supported(cfg, model_fn_is_default: bool, mesh=None) -> tuple[bool, st
     """Structural support check for the paged decode path: ``(ok, why)``.
 
     The kernel mirrors ``forward_with_cache``'s math, so a custom
-    ``model_fn`` can't ride it; the TPU lowering package must import (scalar
-    prefetch / VMEM scratch live in ``pallas.tpu`` even when interpreted);
-    and under a mesh the heads must actually shard over ``tp`` the way
-    ``kv_cache_spec`` lays the arena out (a degraded/replicated spec would
-    silently disagree with the shard_map specs here)."""
+    ``model_fn`` can't ride it; and under a mesh the heads must actually
+    shard over ``tp`` the way ``kv_cache_spec`` lays the arena out (a
+    degraded/replicated spec would silently disagree with the shard_map
+    specs here)."""
     if not model_fn_is_default:
         return False, "custom model_fn (kernel mirrors forward_with_cache)"
-    if _pltpu is None:
-        return False, "pallas TPU lowering package unavailable"
     if mesh is not None:
         if "tp" not in mesh.axis_names:
             return False, "mesh has no tp axis"
@@ -236,16 +231,23 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     return logits, fresh
 
 
-def _write(arena, vals, tables, pos, *, block_size, mesh):
+def _write(arena, vals, tables, pos, *, block_size, mesh, n_emit=None, offset=0):
     if mesh is None:
-        return paged_token_write(arena, vals, tables, pos, block_size=block_size)
+        return paged_token_write(arena, vals, tables, pos, block_size=block_size,
+                                 n_emit=n_emit, offset=offset)
     rank5 = arena.ndim == 5
     aspec = P(None, None, "tp", None, None) if rank5 else P(None, None, "tp", None)
     vspec = P(None, None, "tp", None) if rank5 else P(None, None, "tp")
+    if n_emit is None:
+        return _smap(
+            lambda a, v, t, p: paged_token_write(a, v, t, p, block_size=block_size),
+            mesh, (aspec, vspec, P(None, None), P(None)), aspec,
+        )(arena, vals, tables, pos)
     return _smap(
-        lambda a, v, t, p: paged_token_write(a, v, t, p, block_size=block_size),
-        mesh, (aspec, vspec, P(None, None), P(None)), aspec,
-    )(arena, vals, tables, pos)
+        lambda a, v, t, p, n: paged_token_write(
+            a, v, t, p, block_size=block_size, n_emit=n, offset=offset),
+        mesh, (aspec, vspec, P(None, None), P(None), P(None)), aspec,
+    )(arena, vals, tables, pos, n_emit)
 
 
 def _write_fused(arena, scale, vals, tables, pos, *, block_size, mesh,
@@ -312,7 +314,7 @@ def write_fresh_kv_live(arenas, fresh, tables, pos, live, *, block_size,
     earlier in the scan, or batch padding) is sink-routed (block 0, never
     attended) so the remaining iterations of a finished request leave no
     trace in its real blocks.  Implemented as an offset-0 masked write —
-    ``n_emit = live`` makes :func:`paged_token_write_masked`'s
+    ``n_emit = live`` makes :func:`paged_token_write`'s
     ``offset < n_emit`` predicate the liveness mask itself — so the stored
     bytes for live rows are bit-identical to the single-step kernel's and
     the program still contains zero scatter primitives.  Quantized pools
@@ -320,7 +322,7 @@ def write_fresh_kv_live(arenas, fresh, tables, pos, live, *, block_size,
     :func:`write_fresh_kv`."""
     n_emit = live.astype(jnp.int32)
     if kv_dtype is None:
-        w = partial(_write_masked, tables=tables, pos=pos, n_emit=n_emit,
+        w = partial(_write, tables=tables, pos=pos, n_emit=n_emit,
                     offset=0, block_size=block_size, mesh=mesh)
         return {"k": w(arenas["k"], fresh["k"]), "v": w(arenas["v"], fresh["v"])}
     ka, ks = _write_fused(arenas["k"], arenas["k_scale"], fresh["k"], tables,
@@ -330,20 +332,6 @@ def write_fresh_kv_live(arenas, fresh, tables, pos, live, *, block_size,
                           pos, block_size=block_size, mesh=mesh,
                           n_emit=n_emit, offset=0)
     return {"k": ka, "v": va, "k_scale": ks, "v_scale": vs}
-
-
-def _write_masked(arena, vals, tables, pos, n_emit, offset, *, block_size, mesh):
-    if mesh is None:
-        return paged_token_write_masked(arena, vals, tables, pos, n_emit,
-                                        offset, block_size=block_size)
-    rank5 = arena.ndim == 5
-    aspec = P(None, None, "tp", None, None) if rank5 else P(None, None, "tp", None)
-    vspec = P(None, None, "tp", None) if rank5 else P(None, None, "tp")
-    return _smap(
-        lambda a, v, t, p, n: paged_token_write_masked(
-            a, v, t, p, n, offset, block_size=block_size),
-        mesh, (aspec, vspec, P(None, None), P(None), P(None)), aspec,
-    )(arena, vals, tables, pos, n_emit)
 
 
 def write_fresh_kv_masked(arenas, fresh, tables, pos, n_emit, *, block_size,
@@ -364,8 +352,9 @@ def write_fresh_kv_masked(arenas, fresh, tables, pos, n_emit, *, block_size,
         for name in ("k", "v"):
             a = out[name]
             for k in range(T):
-                a = _write_masked(a, fresh[name][:, :, :, k], tables, pos,
-                                  n_emit, k, block_size=block_size, mesh=mesh)
+                a = _write(a, fresh[name][:, :, :, k], tables, pos,
+                           n_emit=n_emit, offset=k, block_size=block_size,
+                           mesh=mesh)
             out[name] = a
         return out
     for name in ("k", "v"):

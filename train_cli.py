@@ -118,9 +118,11 @@ def main(argv=None):
     )
     T = args.seq or min(cfg.block_size, 128)
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
-    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=dtype)
+    def init():
+        return llama.init_params(cfg, jax.random.PRNGKey(0), dtype=dtype)
+
     log(f"config={cfg.name} n_layer={cfg.n_layer} n_embd={cfg.n_embd} "
-        f"params={llama.param_count(params)/1e6:.1f}M B={args.batch} T={T} "
+        f"params={llama.param_count(jax.eval_shape(init))/1e6:.1f}M B={args.batch} T={T} "
         f"mode={args.mode} devices={args.devices} dtype={args.dtype}")
 
     idx = jax.random.randint(jax.random.PRNGKey(1), (args.batch, T), 0, cfg.vocab_size)
@@ -137,6 +139,7 @@ def main(argv=None):
         # training losses directly: jax.value_and_grad through the shard_map
         # (grad sync comes out of the broadcast transpose), optax update jitted
         # alongside — one compiled program per step, like TrainStep
+        params = init()
         if args.mode == "sp":
             assert T % args.devices == 0, f"--seq {T} must divide over sp={args.devices}"
             mesh = dist.make_mesh({"sp": args.devices}, devices=devices)
@@ -189,17 +192,20 @@ def main(argv=None):
     else:
         if args.mode == "none":
             mesh = dist.make_mesh({"dp": 1}, devices=devices[:1])
-            params = dist.ddp(params, mesh)
+            rule = dist.ddp_shardings
         elif args.mode == "ddp":
             mesh = dist.make_mesh({"dp": args.devices}, devices=devices)
-            params = dist.ddp(params, mesh)
+            rule = dist.ddp_shardings
         elif args.mode in ("fsdp", "zero3"):
             mesh = dist.make_mesh({"fsdp": args.devices}, devices=devices)
-            params = dist.fsdp(params, mesh)
+            rule = dist.fsdp_shardings
         else:  # tp_fsdp
             tp = 2 if args.devices % 2 == 0 else 1
             mesh = dist.make_mesh({"fsdp": args.devices // tp, "tp": tp}, devices=devices)
-            params = dist.tp_fsdp(params, mesh)
+            rule = dist.llama_shardings
+        # born placed: no leaf is ever whole on one device, so a model larger
+        # than one chip can be initialised
+        params = dist.init_sharded(init, lambda shapes: rule(shapes, mesh))
 
         def loss_fn(p, i, t, c, s):
             return llama.gpt_loss(p, i, t, c, s, cfg)
@@ -370,7 +376,11 @@ def main(argv=None):
         save_checkpoint(args.checkpoint_dir, {"params": params, "opt_state": opt_state}, step=args.steps)
         log(f"checkpoint saved to {args.checkpoint_dir}")
 
+    from thunder_tpu._platform import device_info
+
     print(json.dumps({
+        # the rate below is this device's: a CPU run measures the CPU
+        "device": device_info(),
         "config": cfg.name, "mode": args.mode, "devices": args.devices,
         "quant": args.quant,
         "fused_ce": bool(args.fused_ce),

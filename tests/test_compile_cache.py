@@ -132,4 +132,5 @@ class TestPersistentCompilationCache:
         jfn = tt.jit(lambda x: x + 1)
         jfn(np.ones(4, dtype=np.float32))
         pc = tt.compile_stats(jfn).persistent_cache
-        assert set(pc) == {"persistent_cache_hits", "persistent_cache_misses", "dir"}
+        assert set(pc) == {"persistent_cache_hits", "persistent_cache_misses", "dir",
+                           "jaxpr_trace_s", "lower_s", "backend_compile_s"}

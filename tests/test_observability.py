@@ -289,31 +289,39 @@ class TestHooks:
             obs.unregister_hook("on_cache_miss", broken)
 
 
-class TestDynamicEnvGate:
-    """Satellite 1: the annotate gate must read the env var dynamically —
-    the old core/profile.py froze it at import time."""
+class TestNoAnnotationGate:
+    """The profiler session is the only switch of an annotation: no
+    environment variable, no module flag, no shim."""
 
-    def test_annotate_env_read_after_import(self, monkeypatch):
-        from thunder_tpu.core import profile as prof
+    def test_span_annotates_whatever_the_old_env_var_says(self, monkeypatch):
+        import sys
 
-        monkeypatch.delenv("THUNDER_TPU_ANNOTATE_TRACES", raising=False)
-        assert not prof.profiling_enabled()
-        assert not obs.profiling_enabled()
-        monkeypatch.setenv("THUNDER_TPU_ANNOTATE_TRACES", "1")
-        # set AFTER import: now visible, both through the shim and the package
-        assert prof.profiling_enabled()
-        assert obs.profiling_enabled()
-        with prof.add_markers("region"):
-            pass
-        with obs.add_markers("region-2"):
-            pass
+        from jax.profiler import TraceAnnotation
 
-    def test_legacy_enabled_attr_still_overrides(self, monkeypatch):
-        from thunder_tpu.core import profile as prof
+        ev = sys.modules["thunder_tpu.observability.events"]   # the module, not the accessor
+        opened = []
+        monkeypatch.setattr(ev, "TraceAnnotation",
+                            lambda name, **kw: opened.append((name, kw)) or TraceAnnotation(name, **kw))
+        for value in (None, "0", "1"):
+            if value is None:
+                monkeypatch.delenv("THUNDER_TPU_ANNOTATE_TRACES", raising=False)
+            else:
+                monkeypatch.setenv("THUNDER_TPU_ANNOTATE_TRACES", value)
+            with obs.span("region", k=value or "unset"):
+                pass
+        assert opened == [("thunder_tpu.region", {"k": k}) for k in ("unset", "0", "1")]
 
-        monkeypatch.delenv("THUNDER_TPU_ANNOTATE_TRACES", raising=False)
-        monkeypatch.setattr(prof, "_ENABLED", True)
-        assert prof.profiling_enabled()
+    def test_the_gate_and_the_shim_are_gone(self):
+        import importlib
+
+        for gone in ("add_markers", "profiling_enabled", "annotations_enabled"):
+            assert not hasattr(obs, gone), gone
+        with pytest.raises(ModuleNotFoundError):
+            importlib.import_module("thunder_tpu.core.profile")
+        # the per-symbol annotation stays, under profile=True alone
+        cfn = tt.jit(_mlp, profile=True)
+        cfn(*_xw())
+        assert len(tt.profile_stats(cfn)) > 0
 
 
 class TestUnguardableKeySharpEdge:

@@ -18,6 +18,7 @@ be ``xfail(strict=True)`` with the compiler's message; none is.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -131,15 +132,50 @@ def _cases(nh: int, ng: int) -> dict:
 CASES = {shape: _cases(*heads) for shape, heads in SHAPES.items()}
 
 
+def kernel_names(case: str) -> list[str]:
+    """The ``name=`` of every ``pallas_call`` a case makes: the function that
+    builds the kernel, and its variant (quantised arenas, keep-masked write)."""
+    base, _, variant = case.partition("/")
+    if base == "flash_sdpa_fwd":
+        return ["_flash_fwd"]
+    if base == "flash_sdpa_bwd":
+        return ["_flash_bwd_dq", "_flash_bwd_dkv"]
+    if base in ("paged_attn_decode", "paged_attn_verify") and variant in ("int8", "fp8"):
+        base += "_quant"
+    if variant.startswith("masked") or variant.endswith("/masked"):
+        base += "_masked"
+    return [base]
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("kernel", CASES["gqa"])
 def test_kernel_lowers_and_compiles_for_tpu(shape, kernel, tpu_sharding):
     fn, specs = CASES[shape][kernel]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
     lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
-    assert "tpu_custom_call" in lowered.as_text()   # Mosaic, not the interpreter
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text   # Mosaic, not the interpreter
+    for name in kernel_names(kernel):
+        assert f'kernel_name = "{name}"' in text, (kernel, name)
     if tpu_sharding is not None:
-        lowered.compile()
+        # the TPU compiler names the custom call after its innermost scope,
+        # and that is the name an operation has in a device trace
+        compiled = lowered.compile().as_text()
+        for name in kernel_names(kernel):
+            assert re.search(rf"%{name}(\.\d+)? = ", compiled), (kernel, name)
+
+
+def test_every_pallas_call_site_is_named():
+    import inspect
+
+    src = inspect.getsource(px)
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 10
+    assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
+        "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
+        "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",
+        "paged_attn_verify_quant", "paged_token_write", "paged_token_write_masked",
+        "paged_token_write_fused", "paged_token_write_fused_masked",
+        "paged_chunk_write", "paged_chunk_write_fused", "lora_delta_fused"}
 
 
 @pytest.mark.slow

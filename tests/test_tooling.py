@@ -2,7 +2,7 @@
 
 Reference parity: ``thunder/examine/__init__.py:49``, sharp-edges policy
 (``core/options.py:146`` + ``jit_ext.py:472``), ``core/patterns.py:99``,
-``core/profile.py:7``.
+``core/profile.py:7`` (here ``observability.span``).
 """
 import numpy as np
 import pytest
@@ -274,20 +274,38 @@ class TestPatterns:
 
 
 class TestProfileMarkers:
-    def test_disabled_by_default(self):
-        from thunder_tpu.core.profile import add_markers, profiling_enabled
+    """Reference ``add_markers`` is ``observability.span`` here: a jax
+    profiler annotation with nothing to enable."""
 
-        assert not profiling_enabled()
-        with add_markers("test-region"):
-            pass  # no-op without the env var
+    def test_span_marks_a_region_with_no_session_open(self):
+        from thunder_tpu.observability import events, span
 
-    def test_enabled_wraps_jax_annotation(self, monkeypatch):
-        import thunder_tpu.core.profile as prof
+        with span("test-region", ring=False):
+            x = np.ones(3).sum()  # an inactive annotation: the work runs as it is
+        assert x == 3.0 and events() == []
 
-        monkeypatch.setattr(prof, "_ENABLED", True)
-        with prof.add_markers("region-x"):
-            x = np.ones(3).sum()
-        assert x == 3.0
+    def test_span_wraps_a_jax_annotation_named_with_the_prefix(self, monkeypatch):
+        import sys
+
+        import thunder_tpu.observability.events  # noqa: F401
+
+        ev = sys.modules["thunder_tpu.observability.events"]
+        seen = []
+
+        class Annotation:
+            def __init__(self, name, **kw):
+                seen.append(("init", name, kw))
+
+            def __enter__(self):
+                seen.append("enter")
+
+            def __exit__(self, *exc):
+                seen.append("exit")
+
+        monkeypatch.setattr(ev, "TraceAnnotation", Annotation)
+        with ev.span("region-x", ring=False, size=3):
+            seen.append("body")
+        assert seen == [("init", "thunder_tpu.region-x", {"size": 3}), "enter", "body", "exit"]
 
 
 def test_execution_callback_file(tmp_path):

@@ -187,7 +187,7 @@ class TestRequestTracing:
         )
 
     def test_engine_step_spans_on_engine_track(self, traced):
-        steps = [e for e in traced["serving"] if e["name"] == "engine.step"]
+        steps = [e for e in traced["serving"] if e["name"] == "serve.step"]
         assert sum(1 for e in steps if e["ph"] == "B") == \
                sum(1 for e in steps if e["ph"] == "E") > 0
         assert all(e["cat"] == "serving.engine" for e in steps)

@@ -18,7 +18,11 @@ Switched on lazily by the entry points that compile large programs
 (``thunder_tpu.jit``, ``TrainStep``, ``ServingEngine``), so a plain ``import
 thunder_tpu`` never mutates jax config.  Cross-process hit/miss counters come
 from jax's monitoring events (``/jax/compilation_cache/cache_hits`` /
-``cache_misses``) and surface via ``stats()`` / ``thunder_tpu.compile_stats``.
+``cache_misses``), and the seconds every ``jax.jit`` of the process spent
+tracing to a jaxpr, lowering it to MLIR, and in the backend's compile (a
+compilation on a cold cache, a load on a warm one) from its duration events
+(``/jax/core/compile/*_duration``); both surface via ``stats()`` /
+``thunder_tpu.compile_stats``.
 """
 from __future__ import annotations
 
@@ -30,7 +34,11 @@ __all__ = ["enable", "ensure_enabled", "stats", "cache_dir"]
 _lock = threading.Lock()
 _enabled_dir: str | None = None
 _listener_registered = False
-_counts = {"persistent_cache_hits": 0, "persistent_cache_misses": 0}
+_counts = {"persistent_cache_hits": 0, "persistent_cache_misses": 0,
+           "jaxpr_trace_s": 0.0, "lower_s": 0.0, "backend_compile_s": 0.0}
+_DURATIONS = {"/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace_s",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+              "/jax/core/compile/backend_compile_duration": "backend_compile_s"}
 
 
 def _default_dir() -> str:
@@ -45,11 +53,27 @@ def _on_event(name: str, **kwargs) -> None:
         _counts["persistent_cache_misses"] += 1
 
 
+def _on_duration(name: str, secs: float, **kwargs) -> None:
+    key = _DURATIONS.get(name)
+    if key is not None:
+        _counts[key] += secs
+
+
+def _register_listeners() -> None:
+    global _listener_registered
+    if not _listener_registered:
+        import jax
+
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listener_registered = True
+
+
 def enable() -> str:
     """Switches the persistent cache on and registers the hit/miss counter.
     Returns the active directory: the one ``JAX_COMPILATION_CACHE_DIR``
     names, else ``<checkout>/.jax_cache``.  Idempotent."""
-    global _enabled_dir, _listener_registered
+    global _enabled_dir
     with _lock:
         if _enabled_dir is not None:
             return _enabled_dir
@@ -71,9 +95,7 @@ def enable() -> str:
         # small eager ops around them are cheap to store
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        if not _listener_registered:
-            jax.monitoring.register_event_listener(_on_event)
-            _listener_registered = True
+        _register_listeners()
         _enabled_dir = directory
         return _enabled_dir
 
@@ -100,5 +122,8 @@ def cache_dir() -> str | None:
 def stats() -> dict:
     """Process-wide persistent-cache counters: ``persistent_cache_hits`` is
     programs loaded from disk instead of compiled (cross-process reuse),
-    ``persistent_cache_misses`` is fresh compilations written to the cache."""
+    ``persistent_cache_misses`` is fresh compilations written to the cache;
+    ``jaxpr_trace_s``, ``lower_s`` and ``backend_compile_s`` are the seconds
+    spent in Python tracing, in lowering and in the backend (compile or cache
+    load) since the cache was switched on."""
     return dict(_counts, dir=_enabled_dir)

@@ -37,6 +37,7 @@ from thunder_tpu.distributed.sharding import (
     llama_shardings,
     _prune_spec,
 )
+from thunder_tpu.observability.events import span
 
 __all__ = ["ddp", "fsdp", "tp_fsdp", "TrainStep", "make_train_step", "combine_threshold_options"]
 
@@ -262,6 +263,7 @@ class TrainStep:
         # a fresh build
         self._cache: dict = {}
         self._jitted = None
+        self._calls = 0                     # the ``step`` of the next train.step span
 
     def _auto_remat(self, fw_trace, params, opt_state, batch) -> bool:
         """remat="auto": skip trace-level rematerialization when the
@@ -682,7 +684,10 @@ class TrainStep:
     def _get_entry(self, params, opt_state, batch):
         key = self._batch_key(batch)
         if key not in self._cache:
-            self._cache[key] = self._build(params, opt_state, batch)
+            # the same span tt.jit draws around its pipeline: interpretation,
+            # the fw/bw split, transforms and lowering all happen in _build
+            with span("compile", fn="train_step"):
+                self._cache[key] = self._build(params, opt_state, batch)
         self._jitted = self._cache[key]["step"]
         return self._cache[key]
 
@@ -719,9 +724,13 @@ class TrainStep:
         return contextlib.nullcontext()
 
     def __call__(self, params, opt_state, *batch):
-        batch = self._prepare(batch)
-        with self._mesh_context(), self._donation_ctx():
-            return self._get_jitted(params, opt_state, batch)(params, opt_state, *batch)
+        # one span a call, in a jax.profiler trace only (a ring pair a step
+        # would evict the compile pipeline's)
+        with span("train.step", ring=False, step=self._calls):
+            self._calls += 1
+            batch = self._prepare(batch)
+            with self._mesh_context(), self._donation_ctx():
+                return self._get_jitted(params, opt_state, batch)(params, opt_state, *batch)
 
     def grads(self, params, opt_state, *batch):
         """One micro step: ``(loss, grads)`` with no optimizer update — the

@@ -322,6 +322,7 @@ def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: 
         operands.append(mask)
     return pl.pallas_call(
         kernel,
+        name="_flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -497,6 +498,7 @@ def _flash_bwd(g, q, k, v, out, lse, mask, causal: bool, scale: float, H: int, G
         functools.partial(
             _bwd_dq_kernel, BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window
         ),
+        name="_flash_bwd_dq",
         grid=(BH, Tq // BQ, Tk // BK),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, BQ, hs), lambda b, i, j: (b, i, 0)),
@@ -528,6 +530,7 @@ def _flash_bwd(g, q, k, v, out, lse, mask, causal: bool, scale: float, H: int, G
         functools.partial(
             _bwd_dkv_kernel, BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window
         ),
+        name="_flash_bwd_dkv",
         grid=(BH, Tk // BK, Tq // BQ),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -925,6 +928,7 @@ def _flash_ce(logits, target):
         )
     losses, lse = pl.pallas_call(
         kernel,
+        name="flash_cross_entropy",
         grid=(N // BN, V // BV),
         in_specs=[
             pl.BlockSpec((BN, BV), lambda i, j: (i, j)),
@@ -1257,6 +1261,7 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
             _paged_kernel, bs=bs, window=window, quantized=quantized,
             cdtype=fresh_k.dtype, sm=float(np.sqrt(hs)),
         ),
+        name="paged_attn_decode" + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, ng, rep, hs), q.dtype),
         interpret=_interpret(),
@@ -1316,7 +1321,7 @@ def _token_specs(arena, *, bs, offset):
     return a_spec, v_spec
 
 
-def _token_call(kernel, prefetch, arenas, vals, in_specs, out_specs):
+def _token_call(name, kernel, prefetch, arenas, vals, in_specs, out_specs):
     B, L = vals[0].shape[:2]
     n = len(prefetch)
     kwargs = {}
@@ -1325,6 +1330,7 @@ def _token_call(kernel, prefetch, arenas, vals, in_specs, out_specs):
             dimension_semantics=("arbitrary", "arbitrary"))
     return pl.pallas_call(
         kernel,
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n, grid=(B, L),
             in_specs=in_specs, out_specs=out_specs),
@@ -1365,6 +1371,7 @@ def paged_token_write(arena, vals, tables, pos, *, block_size, n_emit=None,
         tables, pos, n_emit.astype(jnp.int32))
     a_spec, v_spec = _token_specs(arena, bs=block_size, offset=offset)
     (out,) = _token_call(
+        "paged_token_write" + ("_masked" if n_emit is not None else ""),
         functools.partial(_token_write_kernel, bs=block_size, offset=offset,
                           masked=n_emit is not None),
         prefetch, (arena,), (jnp.expand_dims(vals, 3),),
@@ -1522,6 +1529,7 @@ def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
             _paged_verify_kernel, bs=bs, T=T, quantized=quantized,
             cdtype=fresh_k.dtype, sm=float(np.sqrt(hs)),
         ),
+        name="paged_attn_verify" + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, ng, rep * T, hs), q.dtype),
         interpret=_interpret(),
@@ -1581,6 +1589,7 @@ def paged_chunk_write(arena, vals, dest, pos, *, block_size):
             dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         _paged_chunk_write_kernel,
+        name="paged_chunk_write",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
         input_output_aliases={2: 0},   # arena in == arena out (in-place)
@@ -1668,6 +1677,7 @@ def paged_chunk_write_fused(arena, scale_arena, vals, dest, pos, *, block_size):
             dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         functools.partial(_paged_chunk_write_fused_kernel, bs=bs, qmax=qmax),
+        name="paged_chunk_write_fused",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(arena.shape, arena.dtype),
@@ -1710,6 +1720,7 @@ def paged_token_write_fused(arena, scale_arena, vals, tables, pos, *,
     s_spec, _ = _token_specs(scale_arena, bs=block_size, offset=offset)
     vr_spec = pl.BlockSpec((1, 1) + vals.shape[2:], lambda i, l, *_: (i, l, 0, 0))
     return _token_call(
+        "paged_token_write_fused" + ("_masked" if n_emit is not None else ""),
         functools.partial(_token_write_fused_kernel, bs=block_size,
                           offset=offset, masked=n_emit is not None,
                           qmax=_qmax(arena.dtype)),
@@ -1750,6 +1761,7 @@ def lora_delta_fused(x, a, b, scaling):
             dimension_semantics=("parallel",))
     return pl.pallas_call(
         functools.partial(_lora_delta_kernel, scaling=scaling),
+        name="lora_delta_fused",
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, T, C), lambda i: (i, 0, 0)),

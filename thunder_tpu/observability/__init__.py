@@ -1,25 +1,44 @@
-"""Observability: runtime profiling, compile-pipeline tracing, and metrics.
+"""Observability: spans, runtime profiling, and metrics.
 
-Three pillars:
+**Spans** (``events.py``) are the one way the program marks host work.
+``span(name, **meta)`` opens a ``jax.profiler.TraceAnnotation`` named
+``thunder_tpu.<name>``: start a ``jax.profiler`` trace and the spans are
+there, on the profiler's clock, beside the device operations.  No option,
+keyword or environment variable switches them: with no profiler session an
+annotation is inactive and costs about two microseconds.  The names:
+
+- compile pipeline (always also in the ring buffer below): ``compile``,
+  ``transform:*``, ``lower``, ``lower:*``, ``codegen``, ``xla_compile``;
+- one serving engine step (``serving/engine.py``): ``serve.step`` [``step``,
+  ``queued``, ``running``, ``t_ns``] with the children ``serve.harvest``
+  (``serve.harvest.wait`` [``kind``, ``rows`` or ``rid``]: the host waits for
+  the device; ``serve.harvest.emit``: the rest), ``serve.expire``,
+  ``serve.decode_dispatch`` [``rows``, ``bucket``, ``steady``] (its
+  ``serve.decode_dispatch.call`` is the jitted program's call alone),
+  ``serve.admit`` [``admitted``], ``serve.prefill_dispatch`` [``rid``,
+  ``tokens``, ``bucket``, ``piece``], ``serve.gauges``, and
+  ``serve.compile`` [``kind``, ``bucket``] around the first call of a
+  freshly built bucket program; ``serve.recover`` around a recovery;
+- training: ``train.step`` [``step``] around ``TrainStep.__call__`` and
+  ``train.snapshot`` [``bytes``] around ``train_loop``'s host snapshot.
+
+The pillars on top:
 
 1. **Runtime profiling transform** (``profiler.py``) — a post-lowering pass
    wrapping each executed BoundSymbol / XLA fusion region in monotonic-clock
-   timing (optional ``jax.block_until_ready`` fences, ``TraceAnnotation``
-   ranges folded in from the old ``core/profile.py``).  Enable with
+   timing (optional ``jax.block_until_ready`` fences) and a
+   ``TraceAnnotation`` range named after the symbol.  Enable with
    ``tt.jit(fn, profile=True)`` or ``THUNDER_TPU_PROFILE=1``; query with
    ``thunder_tpu.profile_stats(cfn)``.
 
-2. **Compile-pipeline event tracing** (``events.py``) — structured
-   begin/end events for interpretation, transforms, lowering, codegen, and
-   XLA compilation in a bounded ring buffer; export with
+2. **The event ring** (``events.py``) — the compile pipeline's spans as
+   begin/end events in a bounded ring buffer; export with
    ``thunder_tpu.export_chrome_trace(path)`` (Perfetto-loadable).
 
 3. **Unified metrics registry** (``metrics.py``) — counters / gauges /
    histograms that the dispatcher, the compiler, and the profiler publish
    into, plus user hook callbacks (``on_compile_start/end``,
    ``on_cache_hit/miss``, ``on_dispatch``).
-
-Numerics-and-memory layer on top (ISSUE 3):
 
 4. **Debug hooks + anomaly detection** (``debug.py``) — pre/post callbacks
    on every executed symbol (``tt.jit(fn, debug_hooks=...)``) and a NaN/Inf
@@ -33,11 +52,10 @@ Numerics-and-memory layer on top (ISSUE 3):
 6. **Training-step telemetry** (``telemetry.py``) — ``StepLogger`` JSONL +
    registry mirror, driven by ``train_cli.py --telemetry``.
 
-Serving-plane layer on top (ISSUE 6):
-
 7. **Request-lifecycle tracing** (``tracing.py``) — async Chrome-trace
    spans per served request (queued / prefill compile-vs-cached / decode
-   steps / finish) merged with the compile-pipeline ring into one
+   steps / finish) in the ring, where the engine's step spans then land
+   too, merged with the compile pipeline's into one
    ``tt.export_chrome_trace`` Perfetto timeline; ``tt.serve(...,
    trace=True)`` / ``THUNDER_TPU_TRACE_SERVING=1``.
 
@@ -49,16 +67,12 @@ Serving-plane layer on top (ISSUE 6):
    scheduler/pool state, auto-dumped to JSON when ``step()`` raises;
    ``tt.flight_record(path)``.
 
-``core/profile.py`` is now a shim over this package; its old import-frozen
-env gate is fixed here (``config.py`` reads the environment dynamically —
-including the event-ring capacity, re-applied on every append).
+``config.py`` reads the environment dynamically, the event-ring capacity
+included (re-applied on every append).
 """
 from __future__ import annotations
 
-import contextlib
-
 from thunder_tpu.observability.config import (  # noqa: F401
-    annotations_enabled,
     anomaly_env_enabled,
     event_buffer_capacity,
     flight_recorder_env_enabled,
@@ -103,11 +117,8 @@ from thunder_tpu.observability.goodput import (  # noqa: F401
 )
 
 __all__ = [
-    "annotations_enabled",
     "profiling_env_enabled",
     "anomaly_env_enabled",
-    "profiling_enabled",
-    "add_markers",
     "snapshot",
     "reset_observability",
     # events
@@ -149,27 +160,6 @@ __all__ = [
     "compile_begin",
     "compile_end",
 ]
-
-
-def profiling_enabled() -> bool:
-    """Legacy gate name (old ``core/profile.py``): True when trace
-    annotations are enabled.  Reads ``THUNDER_TPU_ANNOTATE_TRACES``
-    dynamically — setting it after import now works."""
-    return annotations_enabled()
-
-
-@contextlib.contextmanager
-def add_markers(msg: str):
-    """Annotates the enclosed device work with ``msg`` in jax profiles
-    (``jax.profiler.TraceAnnotation``), gated dynamically."""
-    if not annotations_enabled():
-        yield
-        return
-    assert "\n" not in msg, msg
-    import jax
-
-    with jax.profiler.TraceAnnotation(msg):
-        yield
 
 
 def snapshot() -> dict:

@@ -1,8 +1,7 @@
 """Observability configuration: dynamic environment gates.
 
-The old ``core/profile.py`` computed its enable flag ONCE at module import,
-so ``THUNDER_TPU_ANNOTATE_TRACES`` set in a test or notebook after import
-was silently ignored.  Every gate here reads the environment at call time;
+Every gate here reads the environment at call time, so a variable set in a
+test or a notebook after import takes effect;
 the per-call cost is one ``os.environ`` lookup, paid only on paths that are
 already instrumentation (never on the uninstrumented hot path).
 """
@@ -11,7 +10,6 @@ from __future__ import annotations
 import os
 
 __all__ = [
-    "annotations_enabled",
     "profiling_env_enabled",
     "anomaly_env_enabled",
     "event_buffer_capacity",
@@ -25,13 +23,6 @@ _TRUTHY = ("1", "y", "Y", "true", "on")
 
 def _env_flag(name: str) -> bool:
     return os.getenv(name, "") in _TRUTHY
-
-
-def annotations_enabled() -> bool:
-    """``jax.profiler.TraceAnnotation`` ranges around instrumented symbols
-    (visible in XLA/TensorBoard profiles).  Gated by
-    ``THUNDER_TPU_ANNOTATE_TRACES``, read dynamically."""
-    return _env_flag("THUNDER_TPU_ANNOTATE_TRACES")
 
 
 def profiling_env_enabled() -> bool:

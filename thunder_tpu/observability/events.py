@@ -1,26 +1,35 @@
-"""Event tracing: a ring buffer of begin/end events, exportable as
-Chrome-trace / Perfetto JSON.
+"""Spans: one primitive, two sinks.
+
+:func:`span` is the program's one way to mark a stretch of host work.  It
+opens a ``jax.profiler.TraceAnnotation("thunder_tpu." + name, **meta)``, so
+while a ``jax.profiler`` trace is being recorded the span lies in the
+``.xplane.pb`` on the profiler's clock, beside the device operations it
+dispatched or waited for; with no profiler session the annotation is
+inactive (under a microsecond) and there is nothing to switch on or off.  It
+also records a ``B``/``E`` event pair, under the same name without the
+prefix, into a bounded ring buffer that :func:`export_chrome_trace` writes
+as Chrome-trace / Perfetto JSON.
 
 Every stage of the compile pipeline (interpretation, each transform,
-lowering/claiming, codegen, XLA compile) records a ``B``/``E`` event pair
-via :func:`span`, and the serving plane (``observability/tracing.py``)
-records *async* per-request lifecycle spans (``ph: "b"/"e"`` keyed by
-request id) into the same buffer — one :func:`export_chrome_trace` call
-yields one merged Perfetto timeline.  Events live in a bounded ring buffer
-(the oldest events drop first — an orphaned ``B`` from eviction is
-tolerated by Perfetto), so long-running processes never grow unbounded.
-Nothing on the *dispatch* hot path records events; recording happens only
-on compile-time and explicitly-traced serving paths, where one
-``perf_counter_ns`` + deque append is noise against tracing and XLA
-compilation.
+lowering/claiming, codegen, XLA compile) is a span that always writes the
+ring.  The serving engine's step phases (``serve.step`` and its children)
+and the training step (``train.step``) pass ``ring=False`` unless request
+tracing is on (``tt.serve(trace=True)``): a ring pair every few
+milliseconds for ever would only evict the compile spans.  The serving
+plane (``observability/tracing.py``) also records *async* per-request
+lifecycle spans (``ph: "b"/"e"`` keyed by request id) into the same ring;
+each ``serve.step`` carries ``t_ns``, the ring's clock at its entry, so an
+operator can lay those on the profiler's timeline.  The oldest events drop
+first (an orphaned ``B`` from eviction is tolerated by Perfetto), so
+long-running processes never grow unbounded.  Nothing on ``tt.jit``'s
+per-call *dispatch* path opens a span.
 
 The ring capacity (``THUNDER_TPU_EVENT_BUFFER``) is re-read on every
 append, so changing it after import takes effect on the next recorded
-event (the old import-frozen ``deque(maxlen=...)`` silently ignored late
-changes).
+event.
 
-``span`` is built on ``contextlib.contextmanager`` and therefore also works
-as a decorator (each call re-creates the context).
+``span`` is a ``contextlib.ContextDecorator``: it also works as a decorator
+(each call re-creates the context).
 """
 from __future__ import annotations
 
@@ -29,7 +38,9 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import ContextDecorator
+
+from jax.profiler import TraceAnnotation
 
 from thunder_tpu.observability.config import event_buffer_capacity
 
@@ -92,15 +103,46 @@ def record_event(
     _events.append(ev)
 
 
-@contextmanager
-def span(name: str, **meta):
-    """Records a ``B``/``E`` pair around the enclosed work (exception-safe).
-    Usable as a context manager or as a decorator."""
-    record_event("B", name, meta or None)
-    try:
-        yield
-    finally:
-        record_event("E", name)
+PROFILE_PREFIX = "thunder_tpu."
+
+
+class span(ContextDecorator):
+    """Marks the enclosed host work as ``name`` (exception-safe): a profiler
+    annotation ``thunder_tpu.<name>`` carrying ``meta`` as its arguments,
+    always, and a ``B``/``E`` pair in the ring unless ``ring`` is false.
+    ``track`` names the ring events' display track (``cat``/``pid``/``tid``
+    of :func:`record_event`).  :meth:`set` adds arguments that are known
+    only inside the span.  Usable as a context manager or as a decorator."""
+
+    __slots__ = ("name", "ring", "track", "meta", "_annotation", "_late")
+
+    def __init__(self, name: str, *, ring: bool = True, track: dict | None = None, **meta):
+        self.name, self.ring, self.track, self.meta = name, ring, track or {}, meta
+        self._late = None
+
+    def set(self, **meta) -> None:
+        """More arguments for the open span: on the annotation at once, in
+        the ring on its ``E`` event."""
+        self._annotation.set_metadata(**meta)
+        if self.ring:
+            self._late = {**(self._late or {}), **meta}
+
+    def _recreate_cm(self):
+        # as a decorator: a fresh context a call, so a decorated function may recurse
+        return span(self.name, ring=self.ring, track=self.track, **self.meta)
+
+    def __enter__(self):
+        self._annotation = TraceAnnotation(PROFILE_PREFIX + self.name, **self.meta)
+        self._annotation.__enter__()
+        if self.ring:
+            record_event("B", self.name, self.meta or None, **self.track)
+        return self
+
+    def __exit__(self, *exc):
+        if self.ring:
+            record_event("E", self.name, self._late, **self.track)
+        self._annotation.__exit__(*exc)
+        return False
 
 
 def events() -> list[dict]:

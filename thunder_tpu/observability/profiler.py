@@ -4,9 +4,10 @@ A POST-lowering pass (`instrument_for_profiling`) rewrites the execution
 trace so every claimed BoundSymbol — executor op or XLA fusion region — is
 swapped for a wrapper symbol whose ``python_impl`` times the original
 callable with the monotonic clock, optionally fences with
-``jax.block_until_ready`` for device-accurate numbers, and folds in the old
-``core/profile.py`` behavior by opening a ``jax.profiler.TraceAnnotation``
-range when ``THUNDER_TPU_ANNOTATE_TRACES`` is on (read dynamically).
+``jax.block_until_ready`` for device-accurate numbers, and opens a
+``jax.profiler.TraceAnnotation`` range named after the symbol, so a
+``jax.profiler`` trace of a ``profile=True`` function shows which symbol
+launched which device work.
 
 Per-symbol call counts and wall time accumulate into a
 :class:`ProfileReport` (query via ``thunder_tpu.profile_stats(cfn)``;
@@ -27,12 +28,14 @@ from collections.abc import Mapping
 from dataclasses import dataclass
 from typing import Any, Callable
 
+import jax
+from jax.profiler import TraceAnnotation
+
 from thunder_tpu.core.prims import OpTags, PrimIDs
 from thunder_tpu.core.proxies import NumberProxy, TensorProxy
 from thunder_tpu.core.pytree import tree_flatten, tree_unflatten
 from thunder_tpu.core.symbol import BoundSymbol, Symbol, default_python_printer
 from thunder_tpu.core.trace import TraceCtx, TraceProvenance, from_trace
-from thunder_tpu.observability.config import annotations_enabled
 from thunder_tpu.observability.metrics import registry
 
 __all__ = [
@@ -241,8 +244,6 @@ def _cost_thunk_for(bsym: BoundSymbol, fn: Callable) -> Callable | None:
     structs, slots, baked = [], [], []
     for i, x in enumerate(flat):
         if isinstance(x, TensorProxy):
-            import jax
-
             structs.append(
                 jax.ShapeDtypeStruct(
                     tuple(int(s) for s in x.shape), dtypes.to_jax_dtype(x.dtype)
@@ -252,7 +253,6 @@ def _cost_thunk_for(bsym: BoundSymbol, fn: Callable) -> Callable | None:
             baked.append(None)
         elif isinstance(x, NumberProxy):
             if x.value is None:
-                import jax
                 import numpy as np
 
                 structs.append(
@@ -266,8 +266,6 @@ def _cost_thunk_for(bsym: BoundSymbol, fn: Callable) -> Callable | None:
             baked.append(x)
 
     def thunk():
-        import jax
-
         def call(*tensors):
             vals = list(baked)
             for slot, t in zip(slots, tensors):
@@ -292,18 +290,10 @@ def _make_timed(label: str, fn: Callable, rec: SymbolProfile, barriers: bool) ->
     reg_ns = registry().histogram("profile.symbol_ns")
 
     def _profiled(*args, **kwargs):
-        annotate = annotations_enabled()
         t0 = perf()
-        if annotate:
-            import jax
-
-            with jax.profiler.TraceAnnotation(label):
-                out = fn(*args, **kwargs)
-        else:
+        with TraceAnnotation(label):
             out = fn(*args, **kwargs)
         if barriers:
-            import jax
-
             try:
                 jax.block_until_ready(out)
             except Exception:

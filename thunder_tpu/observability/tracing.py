@@ -27,8 +27,10 @@ lane; under ``async_step=True`` a ``decode``/``prefill`` span covers
 dispatch → harvest (the true token latency including the deliberately
 deferred materialization), not just the host call.
 
-Engine drive-loop work lands as synchronous ``engine.step`` spans on a
-dedicated ``engine`` track.  Everything goes into the shared event ring, so
+Engine drive-loop work is the engine's own ``serve.step`` spans and their
+children (``observability.events.span``: always in a ``jax.profiler``
+trace; with a tracer also in the ring, on a dedicated ``engine`` track,
+:attr:`RequestTracer.engine_track`).  Everything goes into the shared event ring, so
 ``tt.export_chrome_trace(path)`` yields ONE Perfetto timeline where the
 TTFT gap of any request decomposes visibly into queue wait vs cold compile
 vs execute, next to the compile-pipeline rows.
@@ -85,6 +87,9 @@ class RequestTracer:
         self._pid = serving_pid()
         register_process_name(self._pid, "thunder_tpu serving")
         register_thread_name(self._pid, ENGINE_TID, engine_label)
+        # the ``track`` of the engine's synchronous step spans
+        # (``events.span(..., track=...)``): one shared row
+        self.engine_track = {"cat": self.CAT_ENGINE, "pid": self._pid, "tid": ENGINE_TID}
 
     def _tid(self, rid: int) -> int:
         return REQUEST_TID_BASE + rid
@@ -110,14 +115,3 @@ class RequestTracer:
         record_event("n", name, args or None, cat=self.CAT_REQUEST,
                      pid=self._pid, tid=self._tid(rid), id=rid)
 
-    #
-    # engine drive-loop spans (synchronous, one shared track)
-    #
-
-    def engine_begin(self, name: str, **args) -> None:
-        record_event("B", name, args or None, cat=self.CAT_ENGINE,
-                     pid=self._pid, tid=ENGINE_TID)
-
-    def engine_end(self, name: str, **args) -> None:
-        record_event("E", name, args or None, cat=self.CAT_ENGINE,
-                     pid=self._pid, tid=ENGINE_TID)

@@ -72,6 +72,7 @@ Serving-plane observability (all off by default; the off path is an
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass
 from functools import partial
@@ -90,6 +91,7 @@ from thunder_tpu.observability.config import (
     flight_recorder_env_enabled,
     serving_trace_env_enabled,
 )
+from thunder_tpu.observability.events import span
 from thunder_tpu.observability.flight import FlightRecorder
 from thunder_tpu.observability.goodput import resolve_goodput
 from thunder_tpu.observability.metrics import registry
@@ -757,64 +759,85 @@ class ServingEngine:
         Sync (``async_step=False``): the original expire → admit+prefill →
         one blocking decode.  Returns whether any work happened.  When a
         flight recorder is armed, any exception out of the step auto-dumps
-        the flight record before propagating; when tracing is on, the step
-        lands as an ``engine.step`` span."""
+        the flight record before propagating.  The step and its phases are
+        spans (:meth:`_span`): ``serve.step`` and its children."""
         if self._closed:
             raise RuntimeError("engine is shut down")
         self.step_calls += 1
-        tr = self._tracer
-        if tr is not None:
-            tr.engine_begin("engine.step",
-                            queued=len(self.scheduler.queue),
-                            running=len(self.scheduler.running))
-        try:
-            worked = self._step_async() if self.async_step else self._step_inner()
-            self._retry_streak = 0                         # a clean step resets the budget
-        except Exception as e:
-            # blast-radius containment: classified faults are absorbed —
-            # quarantine / retry / recover — and the loop keeps serving;
-            # anything unclassified keeps the crash-dump-and-raise contract
+        # t_ns: the ring's clock at entry, on the profiler's timeline
+        with self._span("serve.step", step=self.step_calls,
+                        queued=len(self.scheduler.queue),
+                        running=len(self.scheduler.running),
+                        t_ns=time.perf_counter_ns()):
             try:
-                handled = self._absorb_fault(e)
-            except Exception as e2:
-                if self._flight is not None:
-                    self._flight.crash_dump(e2)
-                if tr is not None:
-                    tr.engine_end("engine.step", error=type(e2).__name__)
-                raise
-            if not handled:
-                if self._flight is not None:
-                    self._flight.crash_dump(e)
-                if tr is not None:
-                    tr.engine_end("engine.step", error=type(e).__name__)
-                raise
-            worked = True
-        if tr is not None:
-            tr.engine_end("engine.step", worked=worked)
+                worked = self._step_async() if self.async_step else self._step_inner()
+                self._retry_streak = 0                     # a clean step resets the budget
+            except Exception as e:
+                # blast-radius containment: classified faults are absorbed —
+                # quarantine / retry / recover — and the loop keeps serving;
+                # anything unclassified keeps the crash-dump-and-raise contract
+                try:
+                    handled = self._absorb_fault(e)
+                except Exception as e2:
+                    if self._flight is not None:
+                        self._flight.crash_dump(e2)
+                    raise
+                if not handled:
+                    if self._flight is not None:
+                        self._flight.crash_dump(e)
+                    raise
+                worked = True
         return worked
+
+    def _span(self, name: str, **meta) -> span:
+        """A span of the step loop (``observability.events.span``): always an
+        annotation in a ``jax.profiler`` trace; in the event ring, on the
+        tracer's engine track, only under ``trace=True``."""
+        tr = self._tracer
+        if tr is None:
+            return span(name, ring=False, **meta)
+        return span(name, track=tr.engine_track, **meta)
+
+    def _compile_span(self, compiled: bool, kind: str, a: int, b: int):
+        """``serve.compile`` around the first call of a program
+        :meth:`_program` built fresh: the call that traces it and compiles
+        or loads it.  Nothing around any later call."""
+        if not compiled:
+            return contextlib.nullcontext()
+        return self._span("serve.compile", kind=kind, bucket=f"{a}x{b}")
+
+    def _expire_deadlines(self) -> bool:
+        with self._span("serve.expire"):
+            expired = self.scheduler.deadline_expired()
+            for req in expired:
+                self._finish(req, FINISH_DEADLINE)
+        return bool(expired)
+
+    def _admit(self) -> bool:
+        with self._span("serve.admit") as sp:
+            admitted = 0
+            while self._try_admit():
+                admitted += 1
+            sp.set(admitted=admitted)
+        return admitted > 0
 
     def _step_inner(self) -> bool:
         """The synchronous scheduler iteration (``async_step=False``):
         byte-identical to the pre-async engine."""
-        worked = False
-        for req in self.scheduler.deadline_expired():
-            self._finish(req, FINISH_DEADLINE)
-            worked = True
-        while self._try_admit():
-            worked = True
+        worked = self._expire_deadlines()
+        worked = self._admit() or worked
         for r in list(self.scheduler.running):
             if not r.generated and r.state == "running":
                 # a request stranded without token 0 (its admission prefill
                 # was absorbed as a fault, or recovery reset it): re-prefill
                 # before the decode batch consumes generated[-1]
-                self._prefill_harvest(self._prefill_dispatch(r))
-                self._release_retired()
-                self._sample_occupancy()
+                self._harvest_inline(self._prefill_dispatch(r))
                 worked = True
         if self.scheduler.running:
             self._decode_once()
             worked = True
-        self._update_gauges()
+        with self._span("serve.gauges"):
+            self._update_gauges()
         return worked
 
     def _step_async(self) -> bool:
@@ -831,17 +854,15 @@ class ServingEngine:
            work that overlaps the device's decode.
         """
         worked = self._harvest()
-        for req in self.scheduler.deadline_expired():
-            self._finish(req, FINISH_DEADLINE)
-            worked = True
+        worked = self._expire_deadlines() or worked
         if self.scheduler.decode_ready():
             self._decode_once()
             worked = True
-        while self._try_admit():
-            worked = True
+        worked = self._admit() or worked
         if self._advance_prefills():
             worked = True
-        self._update_gauges()
+        with self._span("serve.gauges"):
+            self._update_gauges()
         return worked
 
     def _harvest(self) -> bool:
@@ -849,6 +870,10 @@ class ServingEngine:
         dispatched before the prefill pieces, so the device finishes it
         first).  This is where the host blocks — drive loops calling
         ``step()`` back off *inside* this wait instead of busy-polling."""
+        with self._span("serve.harvest"):
+            return self._harvest_inflight()
+
+    def _harvest_inflight(self) -> bool:
         wd = self.watchdog_timeout_s
         if wd is not None:
             # the watchdog: an in-flight record that aged past the timeout
@@ -878,9 +903,21 @@ class ServingEngine:
             # program, so all of last step's donated-arena consumers have
             # completed — dropping the parked handles is free now (doing it
             # at dispatch would block the host for the whole device step)
-            self._release_retired()
-            self._sample_occupancy()
+            with self._span("serve.harvest.emit"):
+                self._release_retired()
+                self._sample_occupancy()
         return worked
+
+    def _harvest_inline(self, rec: dict) -> None:
+        """The synchronous loop's harvest of the record just dispatched."""
+        with self._span("serve.harvest"):
+            if rec["kind"] == "decode":
+                self._decode_harvest(rec)
+            else:
+                self._prefill_harvest(rec)
+            with self._span("serve.harvest.emit"):
+                self._release_retired()     # outputs materialized: consumer done
+                self._sample_occupancy()
 
     def _release_retired(self) -> None:
         """Drops the parked donated-arena handles of every pool the engine
@@ -1382,9 +1419,7 @@ class ServingEngine:
         if self.async_step:
             self._inflight_prefill.append(rec)
         else:
-            self._prefill_harvest(rec)
-            self._release_retired()         # token materialized: consumer done
-            self._sample_occupancy()
+            self._harvest_inline(rec)
 
     def _chunk_kind(self) -> str:
         """The non-speculative chunk program kind this engine dispatches —
@@ -1413,6 +1448,10 @@ class ServingEngine:
         or an intermediate ``prefill_chunk`` (writes KV only — no sampling,
         no key split, so the final piece's draw stays bit-identical to the
         unchunked prefill)."""
+        with self._span("serve.prefill_dispatch", rid=req.rid) as sp:
+            return self._prefill_piece(req, sp)
+
+    def _prefill_piece(self, req: Request, sp: span) -> dict:
         self._fault_point(FP_PREFILL, (req.rid,))
         sch, pool = self.scheduler, self.pool
         bs = pool.block_size
@@ -1435,6 +1474,9 @@ class ServingEngine:
         else:
             kind = "prefill" if final else self._chunk_kind()
         prog, compiled = self._program(kind, Tb, nbb)
+        # tokens: this piece's prompt tokens, not the padded bucket
+        sp.set(tokens=n_real, bucket=f"{Tb}x{nbb}", piece=kind)
+        first_call = self._compile_span(compiled, kind, Tb, nbb)
         req.prefill_compiled = req.prefill_compiled or compiled
         # the dispatch phase is named by its dominant cost: a fresh program
         # pays the XLA compile here, a cached one only dispatches
@@ -1449,13 +1491,14 @@ class ServingEngine:
             tr.begin(req.rid, name, lane="prefill")
         darenas = None
         if final and self.spec is not None:
-            tok, arenas, darenas, key, qerr = prog(
-                self.params, self.spec.draft_params,
-                jnp.asarray(toks)[None], jnp.int32(pos), jnp.int32(n_real),
-                pool.arenas, self.draft_pool.arenas,
-                jnp.asarray(table), jnp.asarray(dest), jnp.asarray(req.key),
-                self._lora_arenas(), jnp.asarray([req.adapter_slot], dtype=jnp.int32),
-            )
+            with first_call:
+                tok, arenas, darenas, key, qerr = prog(
+                    self.params, self.spec.draft_params,
+                    jnp.asarray(toks)[None], jnp.int32(pos), jnp.int32(n_real),
+                    pool.arenas, self.draft_pool.arenas,
+                    jnp.asarray(table), jnp.asarray(dest), jnp.asarray(req.key),
+                    self._lora_arenas(), jnp.asarray([req.adapter_slot], dtype=jnp.int32),
+                )
             rec = {"kind": "prefill", "req": req, "tok": tok, "key": key,
                    "qerr": qerr, "compiled": compiled, "span": name,
                    "epoch": req.preemptions, "t_clock": sch.clock()}
@@ -1472,27 +1515,30 @@ class ServingEngine:
                 args += (jnp.asarray(req.constraint.mask()[None])
                          if req.constraint is not None
                          else self._ones_mask((1, self._vocab)),)
-            tok, arenas, key, qerr = prog(*args)
+            with first_call:
+                tok, arenas, key, qerr = prog(*args)
             rec = {"kind": "prefill", "req": req, "tok": tok, "key": key,
                    "qerr": qerr, "compiled": compiled, "span": name,
                    "epoch": req.preemptions, "t_clock": sch.clock()}
         elif self.spec is not None:
-            arenas, darenas, qerr = prog(
-                self.params, self.spec.draft_params,
-                jnp.asarray(toks)[None], jnp.int32(pos),
-                pool.arenas, self.draft_pool.arenas,
-                jnp.asarray(table), jnp.asarray(dest),
-                self._lora_arenas(), jnp.asarray([req.adapter_slot], dtype=jnp.int32),
-            )
+            with first_call:
+                arenas, darenas, qerr = prog(
+                    self.params, self.spec.draft_params,
+                    jnp.asarray(toks)[None], jnp.int32(pos),
+                    pool.arenas, self.draft_pool.arenas,
+                    jnp.asarray(table), jnp.asarray(dest),
+                    self._lora_arenas(), jnp.asarray([req.adapter_slot], dtype=jnp.int32),
+                )
             rec = {"kind": "chunk", "req": req, "qerr": qerr,
                    "compiled": compiled, "span": name,
                    "t_clock": sch.clock()}
         else:
-            arenas, qerr = prog(
-                self.params, jnp.asarray(toks)[None], jnp.int32(pos),
-                pool.arenas, jnp.asarray(table), jnp.asarray(dest),
-                self._lora_arenas(), jnp.asarray([req.adapter_slot], dtype=jnp.int32),
-            )
+            with first_call:
+                arenas, qerr = prog(
+                    self.params, jnp.asarray(toks)[None], jnp.int32(pos),
+                    pool.arenas, jnp.asarray(table), jnp.asarray(dest),
+                    self._lora_arenas(), jnp.asarray([req.adapter_slot], dtype=jnp.int32),
+                )
             rec = {"kind": "chunk", "req": req, "qerr": qerr,
                    "compiled": compiled, "span": name,
                    "t_clock": sch.clock()}
@@ -1554,7 +1600,8 @@ class ServingEngine:
             # the scalar fetch doubles as the fence on the chunk execution
             # (release_retired relies on every harvested record having
             # materialized an output of its program)
-            qerr = float(np.asarray(rec["qerr"]))
+            with self._span("serve.harvest.wait", kind="prefill_chunk", rid=req.rid):
+                qerr = float(np.asarray(rec["qerr"]))
             if pool.quantized_kv:
                 registry().gauge("serving.kv_quant.rel_err").set(qerr)
             if tr is not None:
@@ -1573,22 +1620,24 @@ class ServingEngine:
                 tr.end(req.rid, "prefill.host")
                 tr.end(req.rid, "prefill", aborted=True)
             return
-        req.key = np.asarray(rec["key"])
-        tok0 = int(np.asarray(rec["tok"])[0])              # blocks until the device delivers
+        with self._span("serve.harvest.wait", kind="prefill", rid=req.rid):
+            req.key = np.asarray(rec["key"])
+            tok0 = int(np.asarray(rec["tok"])[0])          # blocks until the device delivers
         req.first_token_t = self.scheduler.clock()         # TTFT = token availability, not dispatch
-        if tr is not None:
-            tr.end(req.rid, "prefill.host")
-            tr.end(req.rid, "prefill", compile=req.prefill_compiled)
-        self.tokens_generated += 1                         # prefill samples token 0
-        if gp is not None:
-            gp.commit_tokens(1)                            # token 0 streams below
-        reg = registry()
-        reg.counter("serving.tokens").inc()
-        if pool.quantized_kv:
-            # measured quantization error of THIS prefill's written blocks
-            # (sum|dq-x|/sum|x| over non-sink destinations)
-            reg.gauge("serving.kv_quant.rel_err").set(float(np.asarray(rec["qerr"])))
-        self._emit_token(req, tok0)
+        with self._span("serve.harvest.emit"):
+            if tr is not None:
+                tr.end(req.rid, "prefill.host")
+                tr.end(req.rid, "prefill", compile=req.prefill_compiled)
+            self.tokens_generated += 1                     # prefill samples token 0
+            if gp is not None:
+                gp.commit_tokens(1)                        # token 0 streams below
+            reg = registry()
+            reg.counter("serving.tokens").inc()
+            if pool.quantized_kv:
+                # measured quantization error of THIS prefill's written blocks
+                # (sum|dq-x|/sum|x| over non-sink destinations)
+                reg.gauge("serving.kv_quant.rel_err").set(float(np.asarray(rec["qerr"])))
+            self._emit_token(req, tok0)
 
     #
     # goodput / occupancy accounting helpers
@@ -1646,18 +1695,20 @@ class ServingEngine:
         """One decode-lane turn: dispatch the bucketed decode program for
         the decode-ready batch; sync harvests inline, async parks the
         record in the in-flight table for the next step's harvest."""
-        if self.spec is not None:
-            from thunder_tpu.serving.speculative import spec_decode_dispatch
+        with self._span("serve.decode_dispatch") as sp:
+            if self.spec is not None:
+                from thunder_tpu.serving.speculative import spec_decode_dispatch
 
-            rec = spec_decode_dispatch(self)
-        else:
-            rec = self._decode_dispatch()
-        if self.async_step:
-            self._inflight_decode = rec
-        else:
-            self._decode_harvest(rec)
-            self._release_retired()         # tokens materialized: consumer done
-            self._sample_occupancy()
+                rec = spec_decode_dispatch(self)
+            else:
+                rec = self._decode_dispatch()
+            # steady: last step's device outputs were this step's inputs
+            sp.set(rows=len(rec["running"]), bucket="{}x{}".format(*rec["bucket"]),
+                   steady=rec["steady"])
+            if self.async_step:
+                self._inflight_decode = rec
+        if not self.async_step:
+            self._harvest_inline(rec)
 
     def _decode_dispatch(self) -> dict:
         sch, pool = self.scheduler, self.pool
@@ -1670,7 +1721,8 @@ class ServingEngine:
         sig = (tuple(r.rid for r in running), Bb, nbb)
         N = self.n_decode_steps
         st = self._decode_state
-        if st is not None and st["sig"] == sig:
+        steady = st is not None and st["sig"] == sig
+        if steady:
             # steady state: the batch composition and tables are unchanged
             # since the last step, so this step's inputs ARE the previous
             # step's device outputs (toks=nxt, keys=new_keys, pos=pos+N)
@@ -1768,11 +1820,14 @@ class ServingEngine:
             call_args = call_args + (stop_d,)
         if cmask_d is not None:
             call_args = call_args + (cmask_d,)
+        with self._span("serve.decode_dispatch.call"), \
+                self._compile_span(compiled, kind, Bb, nbb):
+            outs = prog(*call_args)
         if N > 1:
-            ys_tok, ys_emit, toks_f, keys_f, pos_f, arenas = prog(*call_args)
+            ys_tok, ys_emit, toks_f, keys_f, pos_f, arenas = outs
             nxt, new_keys, new_pos = toks_f, keys_f, pos_f
         else:
-            nxt, new_keys, new_pos, arenas = prog(*call_args)
+            nxt, new_keys, new_pos, arenas = outs
         # past the point of no return: the call consumed the donated arenas
         self._fault_point(FP_SCATTER, tuple(r.rid for r in running))
         pool.set_arenas(arenas)
@@ -1784,6 +1839,7 @@ class ServingEngine:
         rec = {"kind": "decode", "running": running, "nxt": nxt,
                "new_keys": new_keys, "pos": host_pos, "bucket": [Bb, nbb],
                "pkind": kind, "compiled": compiled, "step": self.decode_steps,
+               "steady": steady,
                "epochs": [r.preemptions for r in running],
                "t_disp": time.perf_counter(), "t_clock": sch.clock()}
         if N > 1:
@@ -1799,18 +1855,25 @@ class ServingEngine:
             from thunder_tpu.serving.speculative import spec_decode_harvest
 
             return spec_decode_harvest(self, rec)
-        if rec.get("multi"):
-            return self._decode_harvest_multi(rec)
-        sch = self.scheduler
+        multi = rec.get("multi")
         running = rec["running"]
         self._fault_point(FP_HARVEST, tuple(r.rid for r in running))
         t0 = time.perf_counter()
-        nxt = np.asarray(rec["nxt"])                       # the host block
-        new_keys = np.asarray(rec["new_keys"])
+        with self._span("serve.harvest.wait", kind="decode", rows=len(running)):
+            # the host block: (Bb,) tokens, or the multi-step visit's (N, Bb)
+            # token matrix and liveness mask
+            fetched = [np.asarray(rec[k]) for k in
+                       (("nxt", "emit", "new_keys") if multi else ("nxt", "new_keys"))]
+        stall = time.perf_counter() - t0
+        with self._span("serve.harvest.emit"):
+            (self._decode_emit_multi if multi else self._decode_emit)(rec, t0, stall, *fetched)
+
+    def _decode_emit(self, rec: dict, t0: float, stall: float, nxt, new_keys) -> None:
+        sch = self.scheduler
+        running = rec["running"]
         if self.async_step:
             # overlap accounting: host work since dispatch vs the residual
             # device wait the materialization just paid
-            stall = time.perf_counter() - t0
             overlapped = t0 - rec["t_disp"]
             frac = overlapped / (overlapped + stall) if (overlapped + stall) > 0 else 0.0
             self._stall_s_sum += stall
@@ -1895,7 +1958,8 @@ class ServingEngine:
             # the next dispatch rebuilds from host state
             self._decode_state = None
 
-    def _decode_harvest_multi(self, rec: dict) -> None:
+    def _decode_emit_multi(self, rec: dict, t0: float, stall: float, nxt, emit,
+                           new_keys) -> None:
         """Harvest one multi-step visit: up to N tokens per row.
 
         ``rec["nxt"]`` is the (N, Bb) token matrix and ``rec["emit"]`` the
@@ -1909,13 +1973,7 @@ class ServingEngine:
         sch = self.scheduler
         running = rec["running"]
         N = rec["multi"]
-        self._fault_point(FP_HARVEST, tuple(r.rid for r in running))
-        t0 = time.perf_counter()
-        nxt = np.asarray(rec["nxt"])                       # (N, Bb) host block
-        emit = np.asarray(rec["emit"])                     # (N, Bb) bool
-        new_keys = np.asarray(rec["new_keys"])
         if self.async_step:
-            stall = time.perf_counter() - t0
             overlapped = t0 - rec["t_disp"]
             frac = overlapped / (overlapped + stall) if (overlapped + stall) > 0 else 0.0
             self._stall_s_sum += stall
@@ -2280,22 +2338,29 @@ class ServingEngine:
     def _recover(self, cause: dict) -> None:
         reg = registry()
         t0 = time.perf_counter()
-        tr = self._tracer
-        if tr is not None:
-            tr.engine_begin("engine.recover", cause=cause.get("type"))
         if self._flight is not None:
             self._flight.record("recover", cause=cause,
                                 rids=[r.rid for r in self.scheduler.running])
+        with self._span("serve.recover", cause=cause.get("type")):
+            self._recover_until_sound()
+        self.recoveries += 1
+        self._retry_streak = 0
+        dt = time.perf_counter() - t0
+        reg.counter("serving.faults.recoveries").inc()
+        reg.histogram("serving.recovery.duration_s").observe(dt)
+        if self._flight is not None:
+            self._flight.record("recovered", duration_s=dt,
+                                rids=[r.rid for r in self.scheduler.running])
+
+    def _recover_until_sound(self) -> None:
         attempts = 0
         while True:
             try:
                 self._recover_once()
-                break
+                return
             except Exception as e:
                 ecls = classify_fault(e)
                 if ecls is None:
-                    if tr is not None:
-                        tr.engine_end("engine.recover", error=type(e).__name__)
                     raise
                 if ecls == CLASS_REQUEST:
                     # a poison request resurfaced during its own replay:
@@ -2307,23 +2372,11 @@ class ServingEngine:
                     continue
                 attempts += 1
                 if attempts > self._retry.max_retries:
-                    if tr is not None:
-                        tr.engine_end("engine.recover", error="RecoveryError")
                     raise RecoveryError(
                         f"re-prefill recovery failed {attempts} times "
                         f"(last: {type(e).__name__}: {e})"
                     ) from e
                 self._retry.sleep(self._retry.backoff(attempts))
-        self.recoveries += 1
-        self._retry_streak = 0
-        dt = time.perf_counter() - t0
-        reg.counter("serving.faults.recoveries").inc()
-        reg.histogram("serving.recovery.duration_s").observe(dt)
-        if self._flight is not None:
-            self._flight.record("recovered", duration_s=dt,
-                                rids=[r.rid for r in self.scheduler.running])
-        if tr is not None:
-            tr.engine_end("engine.recover", duration_s=dt)
 
     def _recover_once(self) -> None:
         """One recovery attempt: drop in-flight work, rebuild fresh zeroed

@@ -490,7 +490,8 @@ def spec_decode_dispatch(eng) -> dict:
     nbb = eng._nbb(_nbb_raw)
     sig = (tuple(r.rid for r in running), Bb, nbb)
     st = eng._spec_state
-    if st is not None and st["sig"] == sig:
+    steady = st is not None and st["sig"] == sig
+    if steady:
         toks_d, pos_d = st["toks"], st["pos"]
         tables_d, keys_d, slots_d = st["tables"], st["keys"], st["slots"]
         host_pos = st["host_pos"]
@@ -512,8 +513,10 @@ def spec_decode_dispatch(eng) -> dict:
         tables_d, keys_d = jnp.asarray(tables), jnp.asarray(keys)
         slots_d = jnp.asarray(slots)
     dprog, dcompiled = eng._program("draft_decode", Bb, nbb)
-    drafts, q_rows, keys_mid, darenas = dprog(
-        eng.spec.draft_params, toks_d, pos_d, tables_d, dpool.arenas, keys_d)
+    with eng._span("serve.decode_dispatch.call"), \
+            eng._compile_span(dcompiled, "draft_decode", Bb, nbb):
+        drafts, q_rows, keys_mid, darenas = dprog(
+            eng.spec.draft_params, toks_d, pos_d, tables_d, dpool.arenas, keys_d)
     dpool.set_arenas(darenas)
     # a fault HERE retries safely even though the draft arenas were donated:
     # the rerun recommits the same deterministic slots (this round's writes
@@ -542,10 +545,12 @@ def spec_decode_dispatch(eng) -> dict:
             tr.begin(r.rid, "decode", step=eng.decode_steps,
                      compile=dcompiled or vcompiled, bucket=[Bb, nbb],
                      lane="decode", attn=eng.attn, spec=True, K=K)
-    emitted, n_emit, y, new_keys, new_pos, arenas = vprog(
-        eng.params, toks_d, pos_d, tables_d, pool.arenas,
-        drafts, q_rows, keys_mid, lora_arenas, slots_d,
-    )
+    with eng._span("serve.decode_dispatch.call"), \
+            eng._compile_span(vcompiled, vkind, Bb, nbb):
+        emitted, n_emit, y, new_keys, new_pos, arenas = vprog(
+            eng.params, toks_d, pos_d, tables_d, pool.arenas,
+            drafts, q_rows, keys_mid, lora_arenas, slots_d,
+        )
     # past the point of no return: the call consumed the donated arenas
     eng._fault_point(FP_SCATTER, tuple(r.rid for r in running))
     pool.set_arenas(arenas)
@@ -557,6 +562,7 @@ def spec_decode_dispatch(eng) -> dict:
            "emitted": emitted, "n_emit": n_emit, "new_keys": new_keys,
            "pos": host_pos, "bucket": [Bb, nbb], "vkind": vkind,
            "compiled": dcompiled or vcompiled, "step": eng.decode_steps,
+           "steady": steady,
            "t_disp": time.perf_counter(), "t_clock": sch.clock()}
     eng.decode_steps += 1
     eng.spec_rounds += 1
@@ -576,15 +582,21 @@ def spec_decode_harvest(eng, rec: dict) -> None:
     (``serving.spec.accept_len``) and the accepted/drafted counters."""
     from thunder_tpu.serving.faults import FP_HARVEST
 
-    sch = eng.scheduler
     running = rec["running"]
     eng._fault_point(FP_HARVEST, tuple(r.rid for r in running))
     t0 = time.perf_counter()
-    emitted = np.asarray(rec["emitted"])               # the host block
-    n_emit = np.asarray(rec["n_emit"])
-    new_keys = np.asarray(rec["new_keys"])
+    with eng._span("serve.harvest.wait", kind="decode", rows=len(running)):
+        emitted = np.asarray(rec["emitted"])           # the host block
+        n_emit = np.asarray(rec["n_emit"])
+        new_keys = np.asarray(rec["new_keys"])
+    stall = time.perf_counter() - t0
+    with eng._span("serve.harvest.emit"):
+        _spec_emit(eng, rec, t0, stall, emitted, n_emit, new_keys)
+
+
+def _spec_emit(eng, rec: dict, t0: float, stall: float, emitted, n_emit, new_keys) -> None:
+    running = rec["running"]
     if eng.async_step:
-        stall = time.perf_counter() - t0
         overlapped = t0 - rec["t_disp"]
         frac = overlapped / (overlapped + stall) if (overlapped + stall) > 0 else 0.0
         eng._stall_s_sum += stall

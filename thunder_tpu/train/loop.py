@@ -33,6 +33,7 @@ from typing import Callable, Sequence
 import jax
 import numpy as np
 
+from thunder_tpu.observability.events import span
 from thunder_tpu.observability.metrics import registry
 from thunder_tpu.serving.faults import (
     CLASS_ENGINE,
@@ -64,9 +65,11 @@ class TrainLoopResult:
 
 
 def _snapshot(state):
-    return jax.tree_util.tree_map(
-        lambda x: np.asarray(jax.device_get(x)) if isinstance(x, jax.Array) else x, state
-    )
+    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(state) if isinstance(x, jax.Array))
+    with span("train.snapshot", bytes=nbytes):
+        return jax.tree_util.tree_map(
+            lambda x: np.asarray(jax.device_get(x)) if isinstance(x, jax.Array) else x, state
+        )
 
 
 def _replace(template, host_state):

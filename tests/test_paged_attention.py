@@ -27,12 +27,16 @@ import numpy as np
 import pytest
 
 import thunder_tpu as tt
+from thunder_tpu.executors import pallasex as px
 from thunder_tpu.models import generate as gen
 from thunder_tpu.models import llama
 from thunder_tpu.serving import AdapterRegistry, FaultPlan, FaultSpec, make_lora_factors
 from thunder_tpu.serving.faults import FP_DECODE
+from thunder_tpu.serving.kernel_check import _ref_attend
+from thunder_tpu.serving.kv_pool import gather_dense
 from thunder_tpu.serving.lora import valid_targets
 from thunder_tpu.serving.paged_attention import paged_supported
+from thunder_tpu.serving.quant import gather_dense_q, quantize_kv
 
 # 2 layers (layer-indexed arena reads), GQA 4:2 (in-kernel q-group
 # replication), tiny widths so interpret-mode kernels stay cheap
@@ -189,6 +193,136 @@ class TestPagedParity:
 
 
 #
+# the decode kernel's walk: chunks of C table blocks, from the row's first
+# live block to its last, against the jnp reference on the same bytes
+#
+
+W_BS, W_HS, W_REP, W_L, W_NBB, W_C = 4, 16, 2, 2, 8, 3
+W_LAYER = 1
+# contexts at every edge of a block and of a chunk (C * bs = 12 keys), the
+# empty one (the fresh token alone) and a full table
+W_CONTEXTS = (0, 1, W_BS - 1, W_BS, W_C * W_BS - 1, W_C * W_BS, W_C * W_BS + 1,
+              W_NBB * W_BS)
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    """C = 3 at the tiny shapes below, so a table of 8 blocks is three chunks."""
+    monkeypatch.setattr(px, "_PAGED_CHUNK_KEYS", W_C * W_BS)
+    assert px.paged_kv_chunk_blocks(2, W_BS, W_HS, 4) == W_C
+
+
+def _walk_inputs(ng, B=1, nbb=W_NBB, seed=0):
+    """Random float32 arenas in which row ``i`` owns blocks ``1 + i*nbb ...``
+    (block 0 is the sink), queries and fresh K/V."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    rnd = lambda *shape: jax.random.normal(next(keys), shape, jnp.float32)
+    nb = 1 + B * nbb
+    arenas = rnd(nb, W_L, ng, W_BS, W_HS), rnd(nb, W_L, ng, W_BS, W_HS)
+    tables = (1 + jnp.arange(B * nbb, dtype=jnp.int32)).reshape(B, nbb)
+    q, fk, fv = rnd(B, ng * W_REP, W_HS), rnd(B, ng, W_HS), rnd(B, ng, W_HS)
+    return arenas, tables, q, fk, fv
+
+
+def _decode(q, k, v, fk, fv, tables, pos, window=None, ks=None, vs=None):
+    return px.paged_attn_decode(q, k, v, fk, fv, tables, jnp.asarray(pos, jnp.int32),
+                                layer=W_LAYER, window=window, k_scale=ks, v_scale=vs)
+
+
+def _reference(q, kd, vd, fk, fv, pos, window=None):
+    ref = _ref_attend(q[:, :, None], kd[W_LAYER], vd[W_LAYER], fk[:, :, None],
+                      fv[:, :, None], jnp.asarray(pos, jnp.int32), window)
+    return ref[:, :, 0]
+
+
+_TOL = 8 * float(jnp.finfo(jnp.float32).eps)     # serving.kernel_check's bound
+
+
+class TestDecodeWalk:
+    @pytest.mark.parametrize("context", W_CONTEXTS)
+    def test_block_and_chunk_edges(self, small_chunks, context):
+        (k, v), tables, q, fk, fv = _walk_inputs(ng=2)
+        got = _decode(q, k, v, fk, fv, tables, [context])
+        ref = _reference(q, *gather_dense(k, v, tables), fk, fv, [context])
+        assert float(jnp.max(jnp.abs(got - ref))) <= _TOL
+
+    # (window, context): the window's first slot falls inside a block of the
+    # first chunk; two chunks remain; the window holds the fresh token alone;
+    # the window is wider than the context
+    @pytest.mark.parametrize("window,context", [(10, 23), (18, 30), (6, 32),
+                                                (1, 9), (64, 17)])
+    def test_window_cuts_inside_a_chunk(self, small_chunks, window, context):
+        (k, v), tables, q, fk, fv = _walk_inputs(ng=2, seed=1)
+        got = _decode(q, k, v, fk, fv, tables, [context], window=window)
+        ref = _reference(q, *gather_dense(k, v, tables), fk, fv, [context], window)
+        assert float(jnp.max(jnp.abs(got - ref))) <= _TOL
+
+    @pytest.mark.parametrize("context", (W_BS - 1, W_C * W_BS + 1, W_NBB * W_BS))
+    @pytest.mark.parametrize("storage", ["int8", pytest.param(
+        "fp8", marks=pytest.mark.skipif(_FP8 is None, reason="no float8_e4m3fn"))])
+    def test_quantized_arenas(self, small_chunks, storage, context):
+        (k, v), tables, q, fk, fv = _walk_inputs(ng=2, seed=2)
+        dt = jnp.int8 if storage == "int8" else _FP8
+        (kq, ks), (vq, vs) = quantize_kv(k, dt), quantize_kv(v, dt)
+        got = _decode(q, kq, vq, fk, fv, tables, [context], ks=ks, vs=vs)
+        kd, vd = gather_dense_q(kq, vq, ks, vs, tables, jnp.float32)
+        ref = _reference(q, kd, vd, fk, fv, [context])
+        assert float(jnp.max(jnp.abs(got - ref))) <= _TOL
+
+    @pytest.mark.parametrize("ng", [1, 2, 8])
+    def test_kv_groups(self, small_chunks, ng):
+        (k, v), tables, q, fk, fv = _walk_inputs(ng=ng, B=2, seed=3)
+        pos = [W_C * W_BS + 2, 5]
+        got = _decode(q, k, v, fk, fv, tables, pos)
+        ref = _reference(q, *gather_dense(k, v, tables), fk, fv, pos)
+        assert float(jnp.max(jnp.abs(got - ref))) <= _TOL
+
+    def test_row_is_bit_identical_alone_batched_and_under_a_wider_table(
+            self, small_chunks):
+        """A row's output depends on its own table, position and queries
+        only: not on the rows beside it, nor on the bucket's table width."""
+        (k, v), tables, q, fk, fv = _walk_inputs(ng=2, B=4, seed=4)
+        pos = np.array([W_C * W_BS + 5, 2, W_NBB * W_BS, 0], np.int32)
+        batched = np.asarray(_decode(q, k, v, fk, fv, tables, pos, window=20))
+        wide = jnp.concatenate([tables, jnp.zeros_like(tables)], axis=1)  # sink-padded
+        widened = np.asarray(_decode(q, k, v, fk, fv, wide, pos, window=20))
+        for i in range(4):
+            alone = np.asarray(_decode(q[i:i + 1], k, v, fk[i:i + 1], fv[i:i + 1],
+                                       tables[i:i + 1], pos[i:i + 1], window=20))
+            assert np.array_equal(alone[0], batched[i]), i
+            assert np.array_equal(alone[0], widened[i]), i
+
+    @pytest.mark.parametrize("ng", [1, 2])
+    @pytest.mark.parametrize("storage", ["plain", "int8", pytest.param(
+        "fp8", marks=pytest.mark.skipif(_FP8 is None, reason="no float8_e4m3fn"))])
+    def test_narrow_heads_go_block_by_block(self, monkeypatch, storage, ng):
+        """Where the walk cannot be compiled (on the TPU: a head size that is
+        not whole 128-lane tiles) the token rides as query 0 of a verify
+        chunk; the same attention, here interpreted."""
+        monkeypatch.setattr(px, "paged_head_size_ok", lambda hs: False)
+        assert px.paged_kv_chunk_blocks(ng, W_BS, W_HS, 4) == 1
+        (k, v), tables, q, fk, fv = _walk_inputs(ng=ng, B=3, seed=5)
+        pos, ks, vs = [W_C * W_BS + 2, 0, W_NBB * W_BS], None, None
+        kd, vd = gather_dense(k, v, tables)
+        if storage != "plain":
+            dt = jnp.int8 if storage == "int8" else _FP8
+            (k, ks), (v, vs) = quantize_kv(k, dt), quantize_kv(v, dt)
+            kd, vd = gather_dense_q(k, v, ks, vs, tables, jnp.float32)
+        got = _decode(q, k, v, fk, fv, tables, pos, ks=ks, vs=vs)
+        assert float(jnp.max(jnp.abs(got - _reference(q, kd, vd, fk, fv, pos)))) <= _TOL
+        with pytest.raises(NotImplementedError, match="sliding window"):
+            _decode(q, k, v, fk, fv, tables, pos, window=8, ks=ks, vs=vs)
+
+    def test_chunk_follows_the_shapes_not_the_table(self):
+        # offline-batch's shapes: 8 groups of 16 x 128 bfloat16 -> 16 blocks,
+        # 256 keys, 1 MiB of K+V a chunk; int8 and a tp=4 shard reach the key cap
+        assert px.paged_kv_chunk_blocks(8, 16, 128, 2) == 16
+        assert px.paged_kv_chunk_blocks(8, 16, 128, 1) == 32
+        assert px.paged_kv_chunk_blocks(2, 16, 128, 2) == 32
+        assert px.paged_kv_chunk_blocks(8, 256, 128, 4) == 1
+
+
+#
 # sink-block hygiene (satellite): physical block 0 is dead weight
 #
 
@@ -299,6 +433,9 @@ class TestAttnKnob:
         assert st["mode"] == "paged" and st["requested"] == "paged"
         assert st["fallback_reason"] is None
         assert st["kernel_steps"] > 0 and st["fallback_steps"] == 0
+        # what walk the run measured: at these tiny widths the cap of 512
+        # keys a chunk binds, not the byte budget
+        assert st["kv_chunk_tokens"] == 512
         # the module program cache may satisfy this engine's decode_paged
         # program from an earlier engine; the census key exists either way
         assert "decode_paged" in eng.compile_counts
@@ -313,7 +450,7 @@ class TestAttnKnob:
         st = eng.stats()["attn"]
         assert st["mode"] == "gather" and st["requested"] == "gather"
         assert st["kernel_steps"] == 0 and st["fallback_steps"] == 0
-        assert st["fallback_reason"] is None
+        assert st["fallback_reason"] is None and st["kv_chunk_tokens"] is None
 
     def test_auto_falls_back_on_cpu_and_counts(self, micro, monkeypatch):
         """Without THUNDER_TPU_PALLAS_INTERPRET=1, auto on CPU keeps the
@@ -339,6 +476,39 @@ class TestAttnKnob:
         cfg, params = micro
         with pytest.raises(ValueError, match="attn="):
             _engine(cfg, params, attn="fancy")
+
+    @pytest.mark.parametrize("attn", ["auto", "paged"])
+    @pytest.mark.parametrize("hs", [64, 96])
+    def test_narrow_windowed_heads_take_the_gather_path_on_tpu(self, hs, attn, monkeypatch):
+        """Compiled for the TPU the decode walk cannot copy arena slabs of a
+        head size that is not whole 128-lane tiles (test_pallas_tpu_lowering
+        holds the compiler to that), and the per-block kernel that serves
+        such heads has no sliding window: a model with both resolves to the
+        gather path when the engine is built, with a counted reason, and an
+        explicit attn="paged" is refused there, not at the first decode step."""
+        def cfg_of(**kw):
+            return llama.Config.from_name("tiny-llama-debug", **{
+                **MICRO, "n_head": 2, "n_query_groups": 2, "n_embd": 2 * hs, **kw})
+
+        cfg = cfg_of(sliding_window=8)
+        assert cfg.head_size == hs
+        monkeypatch.setattr(px, "_interpret", lambda: False)   # as on the chip
+        ok, why = paged_supported(cfg, True)
+        assert not ok and f"head_size={hs}" in why and "window" in why
+        assert paged_supported(cfg_of(), True) == (True, "")           # no window
+        assert paged_supported(cfg_of(n_embd=256, sliding_window=8), True) == (True, "")
+        params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+        if attn == "paged":
+            with pytest.raises(ValueError, match=f"head_size={hs}"):
+                _engine(cfg, params, attn=attn)
+            return
+        monkeypatch.setattr(px, "_pallas_available", lambda: True)
+        st = _engine(cfg, params, attn=attn).stats()["attn"]
+        assert st["mode"] == "gather" and f"head_size={hs}" in st["fallback_reason"]
+        assert st["kv_chunk_tokens"] is None
+        # without the window the same heads stay on the kernels, a block a step
+        st = _engine(cfg_of(), params, attn=attn).stats()["attn"]
+        assert st["mode"] == "paged" and st["kv_chunk_tokens"] == 4
 
     def test_paged_supported_reasons(self, micro):
         cfg, _ = micro

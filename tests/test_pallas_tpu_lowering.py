@@ -13,7 +13,10 @@ forced off — and, where the installed ``libtpu`` hands out a device-less
 
 One GQA shape (4 query heads per KV group, as Mistral-7B) and one MHA shape;
 head size 128 and block size 16 as served.  A variant left unrepaired would
-be ``xfail(strict=True)`` with the compiler's message; none is.
+be ``xfail(strict=True)`` with the compiler's message; none is.  The decode
+walk is also compiled at the benchmark cell's shapes (plain, windowed, int8,
+fp8), at head size 256 and block size 8, and at head sizes 64 and 96, where
+Mosaic refuses the walk's copies and the token goes block by block.
 """
 from __future__ import annotations
 
@@ -163,6 +166,93 @@ def test_kernel_lowers_and_compiles_for_tpu(shape, kernel, tpu_sharding):
         compiled = lowered.compile().as_text()
         for name in kernel_names(kernel):
             assert re.search(rf"%{name}(\.\d+)? = ", compiled), (kernel, name)
+
+
+def _decode_args(nh, ng, hs, bs, rows, width, pool, layers, store, sharding):
+    """``paged_attn_decode``'s operands as shapes: ``(fn kwargs -> call, args)``
+    with the scale arenas where ``store`` is a quantised dtype."""
+    arena = ((pool, layers, ng, bs, hs), store)
+    specs = [((rows, nh, hs), BF), arena, arena, ((rows, ng, hs), BF),
+             ((rows, ng, hs), BF), ((rows, width), I32), ((rows,), I32)]
+    if store != BF:
+        specs += [((pool, layers, ng, bs), F32)] * 2
+    return [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in specs]
+
+
+def _decode(layer, window=None):
+    def fn(q, k, v, fk, fv, tables, pos, ks=None, vs=None):
+        return px.paged_attn_decode(q, k, v, fk, fv, tables, pos, layer=layer,
+                                    window=window, k_scale=ks, v_scale=vs)
+    return fn
+
+
+@pytest.mark.parametrize("variant,store,window", [
+    ("plain", BF, None), ("window", BF, 4096), ("int8", I8, None), ("fp8", F8, None)])
+def test_decode_walk_compiles_at_offline_batch_shapes(variant, store, window, tpu_sharding):
+    """``paged_attn_decode`` at the benchmark cell's own shapes (Mistral-7B
+    heads 32/8, 32 rows, a table 224 blocks wide, the cell's pool): the chunk
+    the kernel derives there, and the VMEM its buffers and its score tile
+    take, are checked by Mosaic without a chip.  The quantised stores walk
+    twice the blocks a chunk (C = 32) and add the two dequantised buffers."""
+    nh, ng, rows, width, pool, layers = 32, 8, 32, 224, 6144, 16
+    quantized = store != BF
+    assert px.paged_kv_chunk_blocks(ng, BS, HS, jnp.dtype(store).itemsize) * BS == (
+        512 if quantized else 256)
+    args = _decode_args(nh, ng, HS, BS, rows, width, pool, layers, store, tpu_sharding)
+    lowered = jax.jit(_decode(layers - 1, window)).trace(*args).lower(
+        lowering_platforms=("tpu",))
+    name = "paged_attn_decode" + ("_quant" if quantized else "")
+    assert f'kernel_name = "{name}"' in lowered.as_text()
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        # the call as a device trace shows it: the block table first, one
+        # four-dimensional result (what the benchmark's roofline reader matches)
+        call = next(l for l in compiled.as_text().splitlines()
+                    if re.search(rf"%{name}(\.\d+)? = ", l))
+        assert re.search(r"= bf16\[32,8,4,128\]\S* custom-call\(%tables", call), call
+        if not quantized:                                   # no arena copy
+            assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("store", [BF, I8], ids=["plain", "int8"])
+@pytest.mark.parametrize("bs", [8, 16])
+@pytest.mark.parametrize("hs", [128, 256])
+@pytest.mark.parametrize("shape", SHAPES)
+def test_decode_walk_compiles_at_other_widths(shape, hs, bs, store, tpu_sharding):
+    """Whole lane tiles of head size, and a block of half a bfloat16 sublane
+    tile: what :func:`paged_attn_decode`'s docstring says the TPU takes."""
+    if tpu_sharding is None:
+        pytest.skip("no device-less TPU topology to compile for")
+    nh, ng = SHAPES[shape]
+    args = _decode_args(nh, ng, hs, bs, B, NBB, NB, L, store, tpu_sharding)
+    jax.jit(_decode(1, window=24)).trace(*args).lower(lowering_platforms=("tpu",)).compile()
+
+
+@pytest.mark.parametrize("store", [BF, I8, F8], ids=["plain", "int8", "fp8"])
+@pytest.mark.parametrize("hs", [64, 96])
+@pytest.mark.parametrize("shape", [*SHAPES, "gpt2"])
+def test_narrow_heads_decode_block_by_block(shape, hs, store, tpu_sharding, monkeypatch):
+    """A head size that is not whole 128-lane tiles.  The walk's own arena
+    copies cannot be compiled (Mosaic holds the arena padded to 128 lanes and
+    refuses the narrower slice), so ``paged_attn_decode`` sends the token
+    through ``paged_attn_verify``'s per-block grid, which compiles; with a
+    sliding window it raises before any lowering (``paged_supported`` keeps
+    such a model off the kernels).  If the last check fails because Mosaic
+    now compiles the walk, drop ``paged_head_size_ok``."""
+    nh, ng = {**SHAPES, "gpt2": (12, 12)}[shape]
+    args = _decode_args(nh, ng, hs, BS, B, NBB, NB, L, store, tpu_sharding)
+    assert not px.paged_head_size_ok(hs) and px.paged_head_size_ok(128)
+    with pytest.raises(NotImplementedError, match="sliding window"):
+        jax.jit(_decode(1, window=24)).trace(*args)
+    lowered = jax.jit(_decode(1)).trace(*args).lower(lowering_platforms=("tpu",))
+    name = "paged_attn_verify" + ("_quant" if store != BF else "")
+    assert f'kernel_name = "{name}"' in lowered.as_text()
+    if tpu_sharding is None:
+        return
+    assert re.search(rf"%{name}(\.\d+)? = ", lowered.compile().as_text())
+    monkeypatch.setattr(px, "paged_head_size_ok", lambda hs: True)
+    with pytest.raises(Exception, match=r"must be aligned to tiling \(128\)"):
+        jax.jit(_decode(1)).trace(*args).lower(lowering_platforms=("tpu",)).compile()
 
 
 def test_every_pallas_call_site_is_named():

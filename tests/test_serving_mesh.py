@@ -292,6 +292,21 @@ class TestMeshPagedAttention:
         st = eng.stats()["attn"]
         assert st["mode"] == "paged" and st["kernel_steps"] > 0
 
+    def test_kv_chunk_tokens_follow_the_local_arena(self, micro, tp2, monkeypatch):
+        """stats()["attn"]["kv_chunk_tokens"] is derived from the arena shard
+        the kernel is handed under shard_map: half the KV groups a device,
+        twice the table entries a chunk where the byte budget binds."""
+        from thunder_tpu.executors import pallasex as px
+
+        cfg, params = micro
+        mesh, _ = tp2
+        one = _engine(cfg, params, None, max_batch=2, attn="paged")
+        _, _, ng, bs, hs = one.pool.k_arena.shape
+        monkeypatch.setattr(px, "_PAGED_CHUNK_BYTES", 4 * (2 * 2 * ng * bs * hs * 4))
+        for m, blocks in ((None, 4), (mesh, 8)):
+            eng = _engine(cfg, params, m, max_batch=2, attn="paged")
+            assert eng.stats()["attn"]["kv_chunk_tokens"] == blocks * bs
+
     def test_paged_int8_parity_on_mesh(self, micro, tp2):
         cfg, params = micro
         mesh, _ = tp2

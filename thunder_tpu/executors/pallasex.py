@@ -44,7 +44,7 @@ from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_
 
 __all__ = [
     "ex", "pallas_ex", "flash_sdpa", "flash_sdpa_backward",
-    "paged_attn_decode", "paged_token_write", "paged_available",
+    "paged_attn_decode", "paged_token_write", "paged_available", "paged_head_size_ok",
 ]
 
 # exp(MASK_VALUE - lse) underflows to 0 without the inf-inf NaN hazard of -inf
@@ -1050,19 +1050,31 @@ ex.register_implementation(PrimIDs.CROSS_ENTROPY_FWD, _ce_op, checker=_ce_checke
 # into the dense layout forward_with_cache wants.  These two kernels read and
 # write the arena *in place*:
 #
-# - ``paged_attn_decode``: grid (request, kv-group, kv-block); the block
-#   table and positions ride in as **scalar-prefetch** operands so the
-#   BlockSpec index maps fetch each request's physical arena blocks directly
-#   (no gather primitive anywhere in the program).  Online softmax
-#   accumulates across blocks in VMEM scratch; the positional keep-mask
-#   (strictly-older slots, optional sliding window) and the int8/fp8 dequant
-#   from the scale arenas are fused in-kernel; GQA is native (q reshaped to
-#   (B, ng, rep, hs), one grid step per KV group).  The *fresh* token's K/V
-#   (this step's projection, at the cache compute dtype — exactly what the
-#   dense path would have written before attending) joins as the final
-#   online-softmax term, so every row has at least one kept key and the
-#   quantized path attends the diagonal at full precision, matching
-#   quantize-on-scatter semantics bit-for-bit.
+# - ``paged_attn_decode``: grid (request,).  The arenas stay in HBM
+#   (``pl.ANY``) and the kernel fetches them itself: the block table and
+#   positions ride in as **scalar-prefetch** operands, and one grid step walks
+#   its request's table from the first live block (0, or where the sliding
+#   window begins) to the last in *chunks* of ``C`` consecutive entries.  A
+#   block's KV groups of one layer are contiguous in the arena, so one DMA a
+#   table entry lands all ``ng`` groups, ``(ng, bs, hs)``, in a
+#   double-buffered VMEM scratch; a chunk (``C * bs`` keys a group) is
+#   attended with one dot batched over the groups while the next chunk's
+#   copies are in flight (:func:`_arena_walk`; no gather primitive anywhere
+#   in the program).  ``C`` follows from the shapes and a fixed VMEM budget
+#   (:func:`paged_kv_chunk_blocks`).  Online softmax runs across chunks in
+#   loop-carried values; the positional keep-mask (strictly-older slots,
+#   optional sliding window) and the int8/fp8 dequant from the scale arenas
+#   are fused in-kernel; GQA is native (q reshaped to (B, ng, rep, hs)).  A
+#   request pays for its own context: neither the other rows nor the width of
+#   the table bucket enter its walk, so its output is the same bits alone, in
+#   any batch and under any bucket.  The *fresh* token's K/V (this step's
+#   projection, at the cache compute dtype — exactly what the dense path
+#   would have written before attending) joins as the final online-softmax
+#   term, so every row has at least one kept key and the quantized path
+#   attends the diagonal at full precision, matching quantize-on-scatter
+#   semantics bit-for-bit.  Compiled for the TPU the slab copies need a head
+#   size of whole 128-lane tiles (:func:`paged_head_size_ok`); other head
+#   sizes take ``paged_attn_verify``'s per-block grid (:func:`_decode_by_blocks`).
 # - ``paged_token_write``: the scatter_token replacement — one grid step per
 #   request lands the fresh K/V (or its quantization scale) in its
 #   ``table[pos // bs]``/``pos % bs`` arena slot via an aliased output
@@ -1080,194 +1092,280 @@ def paged_available() -> bool:
     return _pallas_available()
 
 
-def _scale_column(s_ref, g, bs):
-    """KV group ``g``'s dequant scales as a ``(bs, 1)`` column.
-
-    The scale arena is ``(num_blocks, L, ng, bs)``, so the smallest block
-    Mosaic accepts is the whole ``(ng, bs)`` slab of one arena block (the
-    last two block dims must equal the array's); the group's row is picked
-    here.  The row has ``bs`` on lanes and the K/V tile wants it on sublanes:
-    the masked lane-sum below is that transpose in ops Mosaic lowers at any
-    ``bs``, and it is exact (one non-zero term per output)."""
-    row = s_ref[0, 0, pl.ds(g, 1), :]                      # (1, bs)
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1))
-    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+def paged_head_size_ok(hs: int) -> bool:
+    """Whether :func:`paged_attn_decode`'s chunked walk can fetch arenas of
+    head size ``hs`` here.  Compiled for the TPU it copies ``(ng, bs, hs)``
+    slabs out of the HBM arena itself, and Mosaic takes such a copy only where
+    ``hs`` is whole 128-lane tiles: it holds a narrower arena padded to 128
+    lanes and refuses the 64- or 96-lane slice of it ("Slice shape along
+    dimension 4 must be aligned to tiling (128)"), whatever the form of the
+    index.  Narrower heads are fetched a block a grid step through BlockSpecs
+    (:func:`_decode_by_blocks`), which Mosaic takes at any ``hs``.  The
+    interpreter has no tiles and walks any ``hs``."""
+    return _interpret() or hs % 128 == 0
 
 
-def _paged_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest, bs,
-                  window, quantized, cdtype, sm):
-    del nb_ref  # raggedness lives in the BlockSpec index maps
-    if quantized:
-        ks_ref, vs_ref, fk_ref, fv_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        fk_ref, fv_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    i, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nb = pl.num_programs(2)
-    p_i = pos_ref[i]
+# VMEM that one chunk of the decode walk may hold: K and V of its table
+# entries, in both slots of the double buffer.  And the most keys a chunk
+# attends at once, which bounds the score tile whatever the byte budget says.
+_PAGED_CHUNK_BYTES = 2 * 1024 * 1024
+_PAGED_CHUNK_KEYS = 512
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _MASK_VALUE)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _dequant(x_ref, s_ref, dt):
-        x = x_ref[0, 0, 0]                                 # (bs, hs) storage dtype
-        if s_ref is not None:
-            x = (x.astype(jnp.float32) * _scale_column(s_ref, g, bs)).astype(cdtype)
-        return x.astype(dt)
+def paged_kv_chunk_blocks(ng: int, bs: int, hs: int, itemsize: int) -> int:
+    """``C``: how many consecutive table entries one step of
+    :func:`paged_attn_decode`'s walk fetches and attends, from what the call
+    sees — the arena's (local) KV groups, block size, head size and storage
+    item size.  Not from the table's width nor the batch: a row's chunk
+    boundaries, and with them the order of its sums, depend on the row alone."""
+    if not paged_head_size_ok(hs):
+        return 1                                        # _decode_by_blocks
+    per_block = 2 * 2 * ng * bs * hs * itemsize        # K and V, two slots
+    return max(1, min(_PAGED_CHUNK_BYTES // per_block, _PAGED_CHUNK_KEYS // bs))
 
-    def _online(s, v, dt):
-        # one online-softmax step: fold scores ``s`` (rep, n) / values ``v``
-        # (n, hs) into the running (m, l, acc) scratch
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(dt), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
 
-    # skip blocks with no kept slot: entirely future (sink-padded table
-    # entries included), or entirely beyond the sliding window.  Every block
-    # that *does* run keeps >= 1 slot, so exp() never sees an all-masked row.
-    run = (j * bs) < p_i
-    if window is not None:
-        run = jnp.logical_and(run, (j * bs + bs - 1) > (p_i - window))
+def _scale_column(row, bs, at=0):
+    """Lanes ``[at, at + bs)`` of the dequant-scale row ``row`` (1, n) as a
+    ``(bs, 1)`` column.  The row has a block's slots on lanes and the K/V tile
+    wants them on sublanes: the masked lane-sum below is that transpose in
+    ops Mosaic lowers at any ``bs``, and it is exact (one non-zero term per
+    output)."""
+    n = row.shape[1]
+    pick = (jax.lax.broadcasted_iota(jnp.int32, (bs, n), 1)
+            == jax.lax.broadcasted_iota(jnp.int32, (bs, n), 0) + at)
+    return jnp.sum(jnp.where(pick, row, 0.0), axis=1, keepdims=True)
 
-    @pl.when(run)
-    def _block():
-        q = q_ref[0, 0]                                    # (rep, hs)
-        k = _dequant(k_ref, ks_ref, q.dtype)
+
+def _scale_rows(scale, layer):
+    """One layer of a ``(num_blocks, L, ng, bs)`` scale arena as lane-dense
+    rows ``(num_blocks, 1, n)``, ``n`` = ``ng * bs`` rounded up to 128 lanes:
+    a block's scale for group ``g``, slot ``j`` sits at lane ``g * bs + j``.
+    Mosaic cannot slice an HBM array whose last dim is not whole lane tiles,
+    so the walk could not copy ``(ng, bs)`` slabs out of the arena itself; a
+    slice, a reshape and a pad of one layer's scales (no gather) is what the
+    kernel's row copies read instead."""
+    nb, _, ng, bs = scale.shape
+    rows = scale[:, layer].reshape(nb, 1, ng * bs)
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, -(ng * bs) % 128)))
+
+
+def _arena_walk(tab_ref, i, p_i, q, arenas, bufs, sem, *, layer, bs, C, window,
+                cdtype, sm):
+    """Request ``i``'s online softmax over its strictly-older arena slots:
+    ``(m, l, acc)`` for queries ``q`` (ng, rows, hs), before the fresh term.
+
+    ``arenas``: the HBM refs ``(k, v)``, or ``(k, v, k_rows, v_rows)`` with
+    the layer's :func:`_scale_rows`; ``bufs``: their VMEM chunk buffers,
+    ``(2, ng, C, bs, hs)`` for K/V and ``(2, C, 1, n)`` for scale rows, then
+    (quantized) the two ``(ng, C, bs, hs)`` buffers the dequantized chunk is
+    built in; ``sem``: DMA semaphores ``(2, len(arenas))``.
+
+    The walk covers table entries ``[lo, hi)``: ``hi`` is the first block with
+    no slot ``< pos``, ``lo`` the block of the oldest slot the window keeps.
+    Chunk ``c`` is entries ``lo + c*C ...``, counted from the row's own first
+    live block.  The last chunk's tail (entries ``>= hi``) re-fetches block
+    ``hi - 1``: real arena bytes, so the value product never meets what an
+    unwritten buffer holds, and their slots are masked as future ones.  Every
+    chunk that runs starts at a live block and so keeps at least one slot:
+    ``exp`` never sees an all-masked row."""
+    quantized = len(arenas) == 4
+    ng, rows, hs = q.shape
+    first = 0 if window is None else jnp.maximum(p_i - (window - 1), 0)
+    lo = first // bs
+    hi = jnp.where(first < p_i, (p_i + bs - 1) // bs, lo)
+    n_chunks = (hi - lo + C - 1) // C
+
+    def copies(c, slot, act):
+        # start, or wait for, the copies of chunk ``c``'s table entries
+        def one(t, _):
+            blk = tab_ref[i, jnp.minimum(lo + c * C + t, hi - 1)]
+            for n, (src, dst) in enumerate(zip(arenas, bufs)):
+                src, dst = ((src.at[blk, layer], dst.at[slot, :, t]) if n < 2
+                            else (src.at[blk], dst.at[slot, t]))
+                getattr(pltpu.make_async_copy(src, dst, sem.at[slot, n]), act)()
+        jax.lax.fori_loop(0, C, one, None)
+
+    def dequant(slot):
+        # per table entry and group: (bs, hs) stored values times their
+        # (bs, 1) scale column, rounded to the cache compute dtype exactly as
+        # the gather path's dequantize does
+        def one(t, _):
+            for x_buf, s_buf, d_buf in zip(bufs[:2], bufs[2:4], bufs[4:]):
+                for g in range(ng):
+                    col = _scale_column(s_buf[slot, t], bs, at=g * bs)
+                    d_buf[g, t] = (x_buf[slot, g, t].astype(jnp.float32) * col
+                                   ).astype(cdtype)
+        jax.lax.fori_loop(0, C, one, None)
+
+    def tile(x):                                           # (ng, C, bs, hs)
+        return x.reshape(ng, C * bs, hs).astype(q.dtype)
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        copies(0, 0, "start")
+
+    def chunk(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            copies(c + 1, 1 - slot, "start")
+
+        copies(c, slot, "wait")
+        if quantized:
+            dequant(slot)
+            k, v = tile(bufs[4][...]), tile(bufs[5][...])
+        else:
+            k, v = tile(bufs[0][slot]), tile(bufs[1][slot])
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) / sm                                             # (rep, bs)
-        posn = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        ) / sm                                             # (ng, rows, C*bs)
+        posn = (lo + c * C) * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, C * bs), 2)
         keep = posn < p_i                                  # strictly older: the
         if window is not None:                             # fresh token is the
-            keep = jnp.logical_and(keep, posn > p_i - window)  # final term below
+            keep = jnp.logical_and(keep, posn > p_i - window)  # caller's final term
         s = jnp.where(keep, s, _MASK_VALUE)
-        _online(s, _dequant(v_ref, vs_ref, q.dtype), q.dtype)
-
-    @pl.when(j == nb - 1)
-    def _finalize():
-        # the fresh token is one key: its score and value terms are written
-        # as float32 multiply-and-sum, because Mosaic refuses the
-        # (rep, hs)·(1, hs) dot_general for rep > 1.  The operands are rounded
-        # to q.dtype first, as a matmul's would be, and a product of two such
-        # values is exact in float32.
-        q = q_ref[0, 0].astype(jnp.float32)                # (rep, hs)
-        fk = fk_ref[0, 0].astype(q_ref.dtype).astype(jnp.float32)  # (1, hs)
-        fv = fv_ref[0, 0].astype(q_ref.dtype).astype(jnp.float32)
-        s_f = jnp.sum(q * fk, axis=1, keepdims=True) / sm  # (rep, 1), never masked
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, s_f)
-        p = jnp.exp(s_f - m_new)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + p
-        acc = acc_ref[...] * corr + p.astype(q_ref.dtype).astype(jnp.float32) * fv
-        o_ref[0, 0] = (acc / l_new).astype(o_ref.dtype)
+        l_new = l_prev * corr + jnp.sum(p, axis=2, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            p.astype(q.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    return jax.lax.fori_loop(0, n_chunks, chunk, (
+        jnp.full((ng, rows, 1), _MASK_VALUE, jnp.float32),
+        jnp.zeros((ng, rows, 1), jnp.float32),
+        jnp.zeros((ng, rows, hs), jnp.float32)))
 
 
-def _ragged_step(i, j, p, nb, *, bs, window):
-    """Ragged block walk: clamp grid step ``j`` into request ``i``'s live
-    block range.  Out-of-range steps (bucket padding past the request's last
-    real block, or — under a sliding window — blocks that slid out) re-map to
-    the nearest live block, so consecutive grid steps hand the pipeline the
-    *same* arena indices and it skips re-issuing the DMA: a short request
-    stops paying its bucket without the grid (program identity) changing.
-    The compute for those steps was already ``pl.when``-skipped; this clamps
-    the *fetch*."""
-    hi = jnp.maximum(nb[i], 1) - 1
-    jj = jnp.minimum(j, hi)
-    if window is not None:
-        lo = jnp.minimum(jnp.maximum(p[i] - (window - 1), 0) // bs, hi)
-        jj = jnp.maximum(jj, lo)
-    return jj
+def _paged_kernel(tab_ref, pos_ref, q_ref, *rest, n_arenas, sm, **walk):
+    arenas, (fk_ref, fv_ref, o_ref), scratch = (
+        rest[:n_arenas], rest[n_arenas:n_arenas + 3], rest[n_arenas + 3:])
+    i = pl.program_id(0)
+    q = q_ref[0]                                           # (ng, rep, hs)
+    m_prev, l_prev, acc = _arena_walk(
+        tab_ref, i, pos_ref[i], q, arenas, scratch[:-1], scratch[-1], sm=sm, **walk)
+    # the fresh token is one key: its score and value terms are written as
+    # float32 multiply-and-sum, because Mosaic refuses the (rep, hs)·(1, hs)
+    # dot_general for rep > 1.  The operands are rounded to q.dtype first, as
+    # a matmul's would be, and a product of two such values is exact in
+    # float32.
+    qf = q.astype(jnp.float32)
+    fk = fk_ref[0].astype(q.dtype).astype(jnp.float32)     # (ng, 1, hs)
+    fv = fv_ref[0].astype(q.dtype).astype(jnp.float32)
+    s_f = jnp.sum(qf * fk, axis=2, keepdims=True) / sm    # never masked
+    m_new = jnp.maximum(m_prev, s_f)
+    p = jnp.exp(s_f - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + p
+    acc = acc * corr + p.astype(q.dtype).astype(jnp.float32) * fv
+    o_ref[0] = (acc / l_new).astype(o_ref.dtype)
+
+
+def _ragged_step(i, j, nb):
+    """``paged_attn_verify``'s block walk: clamp grid step ``j`` into request
+    ``i``'s live block range.  Out-of-range steps (bucket padding past the
+    request's last real block) re-map to the last live block, so consecutive
+    grid steps hand the pipeline the *same* arena indices and it skips
+    re-issuing the DMA.  The compute for those steps is ``pl.when``-skipped;
+    this clamps the *fetch*."""
+    return jnp.minimum(j, jnp.maximum(nb[i], 1) - 1)
 
 
 def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
-                      layer, k_scale=None, v_scale=None, window=None,
-                      n_blocks=None):
+                      layer, k_scale=None, v_scale=None, window=None):
     """Single-token attention straight off the KV block arena, one layer.
 
     ``q``: (B, nh, hs) queries at the compute dtype; ``k_arena``/``v_arena``:
     the FULL (num_blocks, L, ng, bs, hs) serving-pool arenas (storage dtype;
-    int8/fp8 when quantized) — ``layer`` picks the layer *inside the BlockSpec
-    index map*, so no per-layer arena slice (a full-arena copy) ever
-    materializes; ``fresh_k``/``fresh_v``: (B, ng, hs) this step's projected
-    K/V at the cache compute dtype (NOT yet in the arena — the caller lands
-    them with :func:`paged_token_write` afterwards); ``tables``: (B, nbb)
-    int32 sink-padded block tables; ``pos``: (B,) int32 global positions;
-    ``k_scale``/``v_scale``: (num_blocks, L, ng, bs) float32 dequant scales
-    (both or neither); ``window``: ``cfg.sliding_window``; ``n_blocks``:
-    (B,) int32 per-request live block counts (derived from ``pos`` when
-    omitted) — the ragged-walk prefetch vector (see :func:`_ragged_step`).
+    int8/fp8 when quantized) — they stay in HBM and the kernel copies
+    ``[block, layer]`` slabs out of them, so no per-layer arena slice (a
+    full-arena copy) ever materializes; ``fresh_k``/``fresh_v``: (B, ng, hs)
+    this step's projected K/V at the cache compute dtype (NOT yet in the
+    arena — the caller lands them with :func:`paged_token_write`
+    afterwards); ``tables``: (B, nbb) int32 sink-padded block tables;
+    ``pos``: (B,) int32 global positions; ``k_scale``/``v_scale``:
+    (num_blocks, L, ng, bs) float32 dequant scales (both or neither);
+    ``window``: ``cfg.sliding_window``.  On the TPU the chunked walk needs
+    ``hs`` to be a multiple of 128 (:func:`paged_head_size_ok`); other head
+    sizes go a block a grid step (:func:`_decode_by_blocks`), and with a
+    sliding window they are refused (``paged_supported`` sends such a model to
+    the gather path when the engine is built).  ``bs`` = 8 and 16 both
+    compile, at int8 and bfloat16.
     Returns (B, nh, hs) attention outputs at ``q.dtype``.
     """
     B, nh, hs = q.shape
-    num_blocks, _L, ng, bs, _ = k_arena.shape
-    nbb = int(tables.shape[1])
+    _, _L, ng, bs, _ = k_arena.shape
     rep = nh // ng
     assert rep * ng == nh, (nh, ng)
+    if not paged_head_size_ok(hs):
+        if window is not None:
+            raise NotImplementedError(
+                f"paged_attn_decode on the TPU: head_size {hs} is not a multiple "
+                "of 128 and the per-block kernel has no sliding window")
+        return _decode_by_blocks(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos,
+                                 layer=layer, k_scale=k_scale, v_scale=v_scale)
     quantized = k_scale is not None
-    q4 = q.reshape(B, ng, rep, hs)
-    if n_blocks is None:
-        n_blocks = (pos + (bs - 1)) // bs
-    n_blocks = n_blocks.astype(jnp.int32)
-    step = functools.partial(_ragged_step, bs=bs, window=window)
+    cdtype = fresh_k.dtype
+    C = paged_kv_chunk_blocks(ng, bs, hs, k_arena.dtype.itemsize)
 
-    arena_spec = pl.BlockSpec(
-        (1, 1, 1, bs, hs),
-        lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, g, 0, 0))
-    scale_spec = pl.BlockSpec(
-        (1, 1, ng, bs),                                # see _scale_column
-        lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     # (B, ng, 1, hs): a (1, 1, hs) block of the rank-3 array would tile
     # (ng, hs) by (1, hs), which Mosaic refuses
-    fresh_spec = pl.BlockSpec((1, 1, 1, hs), lambda i, g, j, tab, p, nb: (i, g, 0, 0))
-    q_spec = pl.BlockSpec((1, 1, rep, hs), lambda i, g, j, tab, p, nb: (i, g, 0, 0))
-
-    in_specs = [q_spec, arena_spec, arena_spec]
-    args = [q4, k_arena, v_arena]
+    fresh_spec = pl.BlockSpec((1, ng, 1, hs), lambda i, tab, p: (i, 0, 0, 0))
+    q_spec = pl.BlockSpec((1, ng, rep, hs), lambda i, tab, p: (i, 0, 0, 0))
+    arenas = [k_arena, v_arena]
+    scratch = [pltpu.VMEM((2, ng, C, bs, hs), k_arena.dtype)] * 2
     if quantized:
-        in_specs += [scale_spec, scale_spec]
-        args += [k_scale, v_scale]
-    in_specs += [fresh_spec, fresh_spec]
-    args += [fresh_k[:, :, None, :], fresh_v[:, :, None, :]]
+        arenas += [_scale_rows(k_scale, layer), _scale_rows(v_scale, layer)]
+        scratch += [pltpu.VMEM((2, C) + arenas[2].shape[1:], jnp.float32)] * 2
+        scratch += [pltpu.VMEM((ng, C, bs, hs), cdtype)] * 2
+    scratch.append(pltpu.SemaphoreType.DMA((2, len(arenas))))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, ng, nbb),
-        in_specs=in_specs,
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[q_spec] + [hbm] * len(arenas) + [fresh_spec, fresh_spec],
         out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, hs), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     kwargs = {}
     if not _interpret():
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel",))
     out = pl.pallas_call(
         functools.partial(
-            _paged_kernel, bs=bs, window=window, quantized=quantized,
-            cdtype=fresh_k.dtype, sm=float(np.sqrt(hs)),
+            _paged_kernel, n_arenas=len(arenas), layer=layer, bs=bs, C=C,
+            window=window, cdtype=cdtype, sm=float(np.sqrt(hs)),
         ),
         name="paged_attn_decode" + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, ng, rep, hs), q.dtype),
         interpret=_interpret(),
         **kwargs,
-    )(tables, pos, n_blocks, *args)
+    )(tables, pos, q.reshape(B, ng, rep, hs), *arenas,
+      fresh_k[:, :, None, :], fresh_v[:, :, None, :])
     return out.reshape(B, nh, hs)
+
+
+def _decode_by_blocks(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, **kw):
+    """:func:`paged_attn_decode` for a head size the chunked walk cannot be
+    compiled for (:func:`paged_head_size_ok`): the token is the first query
+    of an 8-wide :func:`paged_attn_verify` chunk, whose ``(bs, hs)`` tiles
+    arrive through BlockSpecs, a block and a KV group a grid step.  Query 0
+    of a chunk sees the arena's strictly-older slots and fresh key 0 alone,
+    which is the decode step's attention; the other seven rows are copies
+    that fill a sublane tile (Mosaic refuses the one-key fresh dot for GQA)
+    and are dropped."""
+    def wide(x):
+        return jnp.broadcast_to(x[:, :, None, :], (*x.shape[:2], 8, x.shape[2]))
+
+    return paged_attn_verify(wide(q), k_arena, v_arena, wide(fresh_k), wide(fresh_v),
+                             tables, pos, **kw)[:, :, 0]
 
 
 def _token_dest(tab, p, ne, i, *, bs, offset):
@@ -1386,8 +1484,8 @@ def _paged_verify_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest,
     kernel (:func:`paged_attn_verify` docstring): T chunk queries per request
     share one pass over the arena blocks, with the causal intra-chunk mask
     folded into the final online-softmax term.  Queries ride flattened as
-    (rep*T, hs) rows so the arena phase is the single-token kernel's math at
-    a wider row count."""
+    (rep*T, hs) rows so the arena phase is the single-token kernel's math, a
+    block and a KV group at a time, at a wider row count."""
     del nb_ref  # raggedness lives in the BlockSpec index maps
     if quantized:
         ks_ref, vs_ref, fk_ref, fv_ref, o_ref, m_ref, l_ref, acc_ref = rest
@@ -1407,7 +1505,10 @@ def _paged_verify_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest,
     def _dequant(x_ref, s_ref, dt):
         x = x_ref[0, 0, 0]                                 # (bs, hs) storage dtype
         if s_ref is not None:
-            x = (x.astype(jnp.float32) * _scale_column(s_ref, g, bs)).astype(cdtype)
+            # the scale block is the whole (ng, bs) slab of one arena block
+            # (the last two block dims must equal the array's): pick the row
+            col = _scale_column(s_ref[0, 0, pl.ds(g, 1), :], bs)
+            x = (x.astype(jnp.float32) * col).astype(cdtype)
         return x.astype(dt)
 
     def _online(s, v, dt):
@@ -1460,7 +1561,7 @@ def _paged_verify_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
-                      layer, k_scale=None, v_scale=None, n_blocks=None):
+                      layer, k_scale=None, v_scale=None):
     """Multi-token-query attention off the KV block arena, one layer — the
     speculative verify step's kernel (T = K+1) and, generalized to T = the
     chunk width, the chunked-prefill attention kernel (the arena keep-mask
@@ -1473,8 +1574,10 @@ def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     projected K/V at the cache compute dtype (not yet in the arena — the
     caller commits the accepted prefix with the keep-masked :func:`paged_token_write`,
     or the whole chunk with :func:`paged_chunk_write`, afterwards).
-    Arena/scale/table/pos/``n_blocks`` arguments as
-    :func:`paged_attn_decode`.  Sliding-window models are rejected upstream
+    Arena/scale/table/pos arguments as :func:`paged_attn_decode`; the grid
+    is (request, kv-group, kv-block) with one ``(bs, hs)`` arena tile a step,
+    fetched through BlockSpec index maps that :func:`_ragged_step` clamps to
+    the request's live blocks.  Sliding-window models are rejected upstream
     (speculation needs full caches; the chunked-prefill resolution falls
     back to gather).  Returns (B, nh, T, hs) at ``q.dtype``.
     """
@@ -1487,17 +1590,14 @@ def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     # (B, nh, T, hs) -> (B, ng, rep*T, hs): nh splits as (ng, rep), then the
     # adjacent (rep, T) dims fold — row r = rep_idx*T + t
     qf = q.reshape(B, ng, rep * T, hs)
-    if n_blocks is None:
-        n_blocks = (pos + (bs - 1)) // bs
-    n_blocks = n_blocks.astype(jnp.int32)
-    step = functools.partial(_ragged_step, bs=bs, window=None)
+    n_blocks = ((pos + (bs - 1)) // bs).astype(jnp.int32)
 
     arena_spec = pl.BlockSpec(
         (1, 1, 1, bs, hs),
-        lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, g, 0, 0))
+        lambda i, g, j, tab, p, nb: (tab[i, _ragged_step(i, j, nb)], layer, g, 0, 0))
     scale_spec = pl.BlockSpec(
-        (1, 1, ng, bs),                                # see _scale_column
-        lambda i, g, j, tab, p, nb: (tab[i, step(i, j, p, nb)], layer, 0, 0))
+        (1, 1, ng, bs),                                # see _dequant
+        lambda i, g, j, tab, p, nb: (tab[i, _ragged_step(i, j, nb)], layer, 0, 0))
     fresh_spec = pl.BlockSpec((1, 1, T, hs), lambda i, g, j, tab, p, nb: (i, g, 0, 0))
     q_spec = pl.BlockSpec((1, 1, rep * T, hs), lambda i, g, j, tab, p, nb: (i, g, 0, 0))
 

@@ -214,8 +214,8 @@ class GoodputLedger:
     def note_blocks(self, kind: str, walked: int, real: int) -> None:
         """Record one paged-attention dispatch's block-walk widths.
 
-        ``walked`` is the bucketed count the compiled grid iterates
-        (``rows x nbb x steps``); ``real`` is the count the ragged clamp
+        ``walked`` is the bucketed count the program's tables span
+        (``rows x nbb x steps``); ``real`` is the count the ragged walk
         actually streams from the arena (per-row ``ceil(pos / block_size)``,
         clamped to ``[1, nbb]``).  ``walked - real`` block-loads is exactly
         what ragged decode saves over bucketed walking — a visibility

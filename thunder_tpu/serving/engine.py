@@ -367,6 +367,16 @@ class ServingEngine:
             self.attn, self._attn_fallback_reason = "gather", None
         self.attn_kernel_steps = 0
         self.attn_fallback_steps = 0
+        # keys a step of paged_attn_decode's walk attends (a group), from the
+        # arena shard the decode program's kernel is handed; None on gather
+        self._kv_chunk_tokens = None
+        if self.attn == "paged":
+            from thunder_tpu.executors.pallasex import paged_kv_chunk_blocks
+
+            arena = self.pool.k_arena
+            _, _, ng, bs, hs = arena.sharding.shard_shape(arena.shape)
+            self._kv_chunk_tokens = bs * paged_kv_chunk_blocks(
+                ng, bs, hs, arena.dtype.itemsize)
         # multi-tenant LoRA: a bounded AdapterRegistry shared across engines;
         # its stacked factor arenas are program *arguments* (register/evict
         # are data writes), only its geometry enters the program identity
@@ -1077,6 +1087,7 @@ class ServingEngine:
                 "fallback_reason": self._attn_fallback_reason,
                 "kernel_steps": self.attn_kernel_steps,
                 "fallback_steps": self.attn_fallback_steps,
+                "kv_chunk_tokens": self._kv_chunk_tokens,
                 # per-kind resolution: decode and chunk-prefill resolve
                 # independently (the chunk kernel needs block-aligned
                 # widths and no sliding window), so a single top-level
@@ -1799,8 +1810,8 @@ class ServingEngine:
             self._attn_steps["decode"][1] += 1
             self._m_attn_fallback.inc()
         if self._goodput is not None and self.attn == "paged":
-            # ragged-decode visibility: the compiled grid spans Bb x nbb
-            # blocks per step but the ragged clamp streams only each row's
+            # ragged-decode visibility: the bucket's tables span Bb x nbb
+            # blocks per step but the kernel's walk streams only each row's
             # live range — per-row ceil(pos / bs) clamped to [1, nbb]
             # (padding rows collapse to one block, the sink); host ints
             # only, the dispatch itself is untouched

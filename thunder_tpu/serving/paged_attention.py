@@ -42,6 +42,7 @@ from thunder_tpu.executors.pallasex import (
     paged_attn_verify,
     paged_chunk_write,
     paged_chunk_write_fused,
+    paged_head_size_ok,
     paged_token_write,
     paged_token_write_fused,
 )
@@ -69,12 +70,18 @@ def paged_supported(cfg, model_fn_is_default: bool, mesh=None) -> tuple[bool, st
     """Structural support check for the paged decode path: ``(ok, why)``.
 
     The kernel mirrors ``forward_with_cache``'s math, so a custom
-    ``model_fn`` can't ride it; and under a mesh the heads must actually
-    shard over ``tp`` the way ``kv_cache_spec`` lays the arena out (a
-    degraded/replicated spec would silently disagree with the shard_map
-    specs here)."""
+    ``model_fn`` can't ride it; compiled for the TPU, the decode kernel's
+    windowed walk needs a head size of whole 128-lane tiles (narrower heads
+    take the per-block kernel, which has no window); and under a mesh the
+    heads must actually shard over ``tp`` the way ``kv_cache_spec`` lays the
+    arena out (a degraded/replicated spec would silently disagree with the
+    shard_map specs here)."""
     if not model_fn_is_default:
         return False, "custom model_fn (kernel mirrors forward_with_cache)"
+    if cfg.sliding_window is not None and not paged_head_size_ok(cfg.head_size):
+        return False, (
+            f"head_size={cfg.head_size} is not a multiple of 128 and the model "
+            "has a sliding window: the per-block decode kernel has none")
     if mesh is not None:
         if "tp" not in mesh.axis_names:
             return False, "mesh has no tp axis"

@@ -1,0 +1,456 @@
+"""The hybrid sparse decoder (Gated-DeltaNet and gated-attention layers, an
+expert share behind a router over all experts) against the benchmark's plain
+float32 reference (``chipbench/models/hybrid_moe_decoder.py``: the recurrence
+token by token, the experts by a mask), at tiny sizes on the CPU.
+
+Program and reference are given the same float32 weights, so they agree to
+rounding; the tolerance is that of float32 sums in another order.
+"""
+from __future__ import annotations
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import thunder_tpu as tt  # noqa: E402
+from chipbench import common  # noqa: E402
+from thunder_tpu.executors import jaxex  # noqa: E402
+from thunder_tpu.executors import pallasex as px  # noqa: E402
+from thunder_tpu.models import llama  # noqa: E402
+
+arch = common.load_module("models", "hybrid_moe_decoder")
+
+T = 128
+TINY = {
+    "model_name": "tiny-hybrid", "hidden_size": 64, "head_dim": 32, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "partial_rotary_factor": 0.25, "rope_theta": 10000000,
+    "linear_num_key_heads": 2, "linear_num_value_heads": 4, "linear_key_head_dim": 16,
+    "linear_value_head_dim": 16, "linear_conv_kernel_dim": 4, "moe_intermediate_size": 48,
+    "shared_expert_intermediate_size": 48, "num_experts": 16, "published_num_experts": 16,
+    "num_experts_per_tok": 4, "vocab_size": 256, "num_hidden_layers": 4, "full_attention_interval": 4,
+    "max_position_embeddings": 256, "rms_norm_eps": 1e-6, "initializer_range": 0.05,
+}
+
+
+def tiny(held: int = 16, first: int = 0, **over) -> dict:
+    return {**TINY, "num_experts": held, "first_expert": first, **over}
+
+
+def rel(a, b) -> float:
+    a, b = jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
+    return float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30))
+
+
+def _batch(hf, B=2, seed=1):
+    toks = jax.random.randint(jax.random.PRNGKey(seed), (B, T + 1), 0, hf["vocab_size"])
+    return toks[:, :-1], toks[:, 1:]
+
+
+def _noisy(params, seed=7):
+    """Norm weights, ``A_log`` and ``dt_bias`` off their initial values, so
+    that a wrong ``1 + w`` or a dropped bias shows."""
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return jax.tree_util.tree_unflatten(tree, [
+        x + 0.1 * jax.random.normal(k, x.shape, x.dtype) if x.ndim == 1 else x for x, k in zip(leaves, keys)])
+
+
+@functools.lru_cache(maxsize=None)
+def _compared(held: int):
+    """Program and reference on one batch: logits of a sequence, loss, and the
+    gradient of every weight, as ``{path: (program, reference)}``."""
+    hf = tiny(held)
+    params = _noisy(arch.make_params(hf, common.seed_words(5), dtype=jnp.float32))
+    cfg = llama.Config(**arch.program_config(hf))
+    cos, sin = arch.rope_tables(hf, T)
+    idx, tgt = _batch(hf)
+    logits = tt.jit(lambda p, i, c, s: llama.gpt_forward(p, i, c, s, cfg))(params, idx, cos, sin)[0]
+    loss, grads = tt.value_and_grad(lambda p, i, t, c, s: llama.gpt_loss(p, i, t, c, s, cfg))(
+        params, idx, tgt, cos, sin)
+    grads = grads[0] if isinstance(grads, (tuple, list)) else grads
+    ref_loss, parts = arch.ref_loss_and_grads(hf, params, idx, tgt, every_leaf=True)
+    pairs = {}
+    for where, part in parts:
+        got = grads
+        for w in where:
+            got = got[w]
+        for path, leaf in jax.tree_util.tree_flatten_with_path(part)[0]:
+            g = got
+            for k in path:
+                g = g[k.key]
+            pairs["/".join(map(str, where)) + jax.tree_util.keystr(path)] = (g, leaf)
+    return {"logits": (logits, arch.ref_logits(hf, params, idx[0], jnp.arange(T))),
+            "loss": (float(loss), ref_loss), "grads": pairs}
+
+
+HELD = (16, 8, 4)
+GROUPS = {"gdn": "['gdn']", "attn": "['attn']", "experts": "['mlp']['", "shared": "['shared']",
+          "norms": "['norm_", "embedding_head": None}
+
+
+@pytest.mark.parametrize("held", HELD)
+def test_logits_match_reference(held):
+    got, ref = _compared(held)["logits"]
+    assert rel(got, ref) < 2e-5
+
+
+@pytest.mark.parametrize("held", HELD)
+def test_loss_matches_reference(held):
+    got, ref = _compared(held)["loss"]
+    assert abs(got - ref) < 1e-5 * abs(ref)
+
+
+@pytest.mark.parametrize("group", sorted(GROUPS))
+@pytest.mark.parametrize("held", HELD)
+def test_every_weights_gradient_matches_reference(held, group):
+    pairs = {k: v for k, v in _compared(held)["grads"].items()
+             if (not k.startswith("blocks") if group == "embedding_head" else GROUPS[group] in k)}
+    if group == "experts":
+        pairs = {k: v for k, v in pairs.items() if "shared" not in k}
+    assert pairs, group
+    worst = {k: rel(g, r) for k, (g, r) in pairs.items()}
+    # A_log and dt_bias are sums over every token of terms that cancel: float32 in another order
+    assert max(worst.values()) < (2e-3 if group == "gdn" else 2e-4), worst
+
+
+def test_every_leaf_of_the_model_is_compared():
+    hf = tiny(8)
+    n = len(jax.tree_util.tree_leaves(arch.make_params(hf, common.seed_words(1))))
+    assert len(_compared(8)["grads"]) == n == 3 * 17 + 16 + 3
+
+
+# --------------------------------------------------------------------------
+# the chunked gated delta rule against the recurrence, token by token
+# --------------------------------------------------------------------------
+
+def _recurrence(q, k, v, g, beta):
+    rep = v.shape[1] // q.shape[1]
+    q, k = jnp.repeat(q, rep, 1), jnp.repeat(k, rep, 1)
+
+    def head(q, k, v, g, b):
+        def step(S, x):
+            qt, kt, vt, gt, bt = x
+            S = S * jnp.exp(gt)
+            d = (vt - S.T @ kt) * bt
+            S = S + jnp.outer(kt, d)
+            return S, S.T @ qt
+        return jax.lax.scan(step, jnp.zeros((k.shape[-1], v.shape[-1])), (q, k, v, g, b))[1]
+
+    return jax.vmap(jax.vmap(head))(q, k, v, g, beta)
+
+
+def _scan_inputs(Tn, decay, B=2, Hk=2, Hv=4, dk=16, dv=24):
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    q = jax.random.normal(ks[0], (B, Hk, Tn, dk))
+    k = jax.random.normal(ks[1], (B, Hk, Tn, dk))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, Hv, Tn, dv))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (B, Hv, Tn)))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[4], (B, Hv, Tn)))
+    return q, k, v, g, beta
+
+
+DECAYS = {"g_near_0": 1e-3, "g_moderate": 1.0, "g_strongly_negative": 12.0}
+
+
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+@pytest.mark.parametrize("Tn", [150, 192], ids=["T_not_whole_chunks", "T_whole_chunks"])
+def test_chunked_xla_matches_recurrence_forward_and_backward(Tn, decay):
+    args = _scan_inputs(Tn, DECAYS[decay])
+    assert rel(jaxex._gdn_chunked(*args, 64), _recurrence(*args)) < 1e-5
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    got = jaxex._gdn_chunk_backward_impl(w, *args, 64)
+    ref = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
+    assert max(rel(a, b) for a, b in zip(got, ref)) < 1e-4
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+
+
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+@pytest.mark.parametrize("Tn", [192, 1024], ids=["one_block", "two_blocks_state_carried"])
+def test_pallas_gdn_chunk_fwd_matches_recurrence(interpreted, Tn, decay):
+    args = _scan_inputs(Tn, DECAYS[decay], B=1)
+    got = px.gdn_chunk(*args, 64)
+    assert got is not None
+    # the inverse and its products run in three bfloat16 passes: about 16 bits
+    assert rel(got, _recurrence(*args)) < 5e-5
+
+
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+@pytest.mark.parametrize("Tn", [192, 1024], ids=["one_block", "two_blocks_state_carried_back"])
+def test_pallas_gdn_chunk_bwd_matches_recurrence(interpreted, Tn, decay):
+    args = _scan_inputs(Tn, DECAYS[decay], B=1)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    got = px.gdn_chunk_backward(w, *args, 64)
+    ref = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
+    assert got is not None and max(rel(a, b) for a, b in zip(got, ref)) < 1e-4
+
+
+@pytest.mark.parametrize("decay", ["g_near_0", "g_strongly_negative"])
+def test_gated_delta_rule_prim_through_jit_and_its_backward_rule(interpreted, decay):
+    """The prim traced by ``tt.jit`` and differentiated by its own backward
+    rule, both passes claimed by the Pallas executor."""
+    import thunder_tpu.torch as ltorch
+
+    args = _scan_inputs(192, DECAYS[decay], B=1)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    before = px.stats.get("gdn", 0)
+    loss, grads = tt.value_and_grad(lambda q, k, v, g, b, w_: ltorch.sum(ltorch.gated_delta_rule(q, k, v, g, b) * w_),
+                                    argnums=(0, 1, 2, 3, 4))(*args, w)
+    assert px.stats.get("gdn", 0) > before, "the claim is counted in pallasex.stats"
+    ref_loss, ref = jax.value_and_grad(lambda *a: jnp.sum(_recurrence(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
+    assert abs(float(loss) - float(ref_loss)) < 1e-4 * abs(float(ref_loss)) + 1e-5
+    assert max(rel(a, b) for a, b in zip(grads, ref)) < 1e-4
+
+
+def test_pallas_declines_what_it_cannot_tile_and_xla_pads(interpreted):
+    args = _scan_inputs(150, 1.0)
+    assert px.gdn_chunk(*args, 64) is None
+    assert rel(jaxex._gdn_chunk_impl(*args, 64), _recurrence(*args)) < 1e-5
+
+
+# --------------------------------------------------------------------------
+# the expert share
+# --------------------------------------------------------------------------
+
+WAVE_TILES = 8
+
+
+def _plan(idx, first, held, tile):
+    """The whole sorted buffer: every wave's rows, one after another."""
+    plan = jaxex.moe_plan(jnp.asarray(idx), first, held, tile, WAVE_TILES)
+    waves = plan["tile_group"].shape[0] // WAVE_TILES
+    row_src = np.concatenate([np.asarray(jaxex.moe_wave_rows(plan, w, tile, WAVE_TILES)[0]) for w in range(waves)])
+    return row_src, np.asarray(plan["tile_group"]), int(plan["tiles_used"])
+
+
+def _first_wave(idx, first, held, tile, wave_tiles=64):
+    plan = jaxex.moe_plan(jnp.asarray(idx), first, held, tile, wave_tiles)
+    row_src, tg, used = jaxex.moe_wave_rows(plan, 0, tile, wave_tiles)
+    return row_src, tg, used.reshape(1)
+
+
+def _gathered(x, row_src, k):
+    return jnp.where((row_src >= 0)[:, None], jnp.take(x, jnp.maximum(row_src, 0) // k, axis=0), 0)
+
+
+@pytest.mark.parametrize("first,held,tile", [(0, 16, 8), (4, 8, 8), (12, 4, 16), (0, 2, 128)])
+def test_plan_places_every_held_assignment_once_and_drops_none(first, held, tile):
+    rng = np.random.default_rng(first + held)
+    N, k, E = 96, 4, 16
+    idx = np.stack([rng.permutation(E)[:k] for _ in range(N)]).astype(np.int32)
+    row_src, tile_group, used = _plan(idx, first, held, tile)
+    flat = idx.reshape(-1)
+    is_held = (flat >= first) & (flat < first + held)
+    wave = WAVE_TILES * tile
+    assert row_src.shape[0] % wave == 0 and row_src.shape[0] >= N * k + held * (tile - 1), "the worst case fits"
+    rows = np.flatnonzero(row_src >= 0)
+    assert sorted(row_src[rows]) == list(np.flatnonzero(is_held)), \
+        "an assignment on a held expert has one row, no other has any"
+    assert (flat[row_src[rows]] - first == tile_group[rows // tile]).all(), "a row lies in a tile of its expert"
+    assert rows.max(initial=-1) < used * tile
+    assert (np.diff(tile_group[:used]) >= 0).all(), "groups are contiguous and ascending"
+
+
+def _expert_operands(seed=0, N=64, k=4, held=8, first=4, tile=8, C=128, I=128, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    idx = np.stack([rng.permutation(16)[:k] for _ in range(N)]).astype(np.int32)
+    row_src, tg, tu = _first_wave(idx, first, held, tile)
+    x = jnp.asarray(rng.standard_normal((N, C)), dtype)
+    w = jnp.asarray(rng.standard_normal((held, C, I)), dtype)
+    return _gathered(x, row_src, k), w, tg, tu
+
+
+@pytest.mark.parametrize("which", ["rows_times_group", "rows_times_group_transposed", "group_x_t_dy"])
+def test_pallas_grouped_products_match_ragged_dot(interpreted, monkeypatch, which):
+    xb, w, tg, tu = _expert_operands()
+    dy = jnp.asarray(np.random.default_rng(1).standard_normal((xb.shape[0], w.shape[2])), jnp.float32)
+    if which == "group_x_t_dy":
+        got = px.grouped_mm_dw(xb, dy, tg, tu, w.shape[0])
+    else:
+        t = which.endswith("transposed")
+        got = px.grouped_mm(xb, jnp.swapaxes(w, 1, 2) if t else w, tg, tu, t)
+    monkeypatch.setattr(jaxex, "_grouped_mm_fast_path", None)
+    monkeypatch.setattr(jaxex, "_grouped_mm_dw_fast_path", None)
+    ref = (jaxex._grouped_mm_dw_impl(xb, dy, tg, tu, w.shape[0]) if which == "group_x_t_dy"
+           else jaxex._grouped_mm_impl(xb, w, tg, tu))
+    assert got is not None and rel(got, ref) < 1e-5
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["xla", "pallas_interpreted"])
+def test_the_grouped_product_and_its_two_backward_products(monkeypatch, pallas):
+    """``jaxex._gmm``, the one differentiable grouped product (the expert
+    share is made of it): forward and the two products of its backward rule
+    against plain per-group products."""
+    if pallas:
+        monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+    xb, w, tg, tu = _expert_operands()
+    tile = xb.shape[0] // tg.shape[0]
+
+    def plain(x_, w_):
+        rows = jnp.repeat(tg, tile)
+        out = jnp.einsum("rk,rkn->rn", x_, w_[rows])
+        return jnp.where((jnp.arange(x_.shape[0]) < tu[0] * tile)[:, None], out, 0.0)
+
+    c = jnp.asarray(np.random.default_rng(2).standard_normal((xb.shape[0], w.shape[2])), jnp.float32)
+    loss, (dx, dw) = jax.value_and_grad(lambda x_, w_: jnp.sum(jaxex._gmm(x_, w_, tg, tu) * c), argnums=(0, 1))(xb, w)
+    ref_loss, (rx, rw) = jax.value_and_grad(lambda x_, w_: jnp.sum(plain(x_, w_) * c), argnums=(0, 1))(xb, w)
+    assert abs(float(loss) - float(ref_loss)) < 1e-4 * abs(float(ref_loss)) + 1e-4
+    assert rel(dx, rx) < 1e-5 and rel(dw, rw) < 1e-5
+
+
+def test_a_group_without_rows_gets_a_zero_gradient(interpreted):
+    idx = jnp.tile(jnp.asarray([[4, 5, 0, 1]], jnp.int32), (32, 1))     # of experts 4..11 only 4 and 5 are picked
+    row_src, tg, tu = _first_wave(idx, 4, 8, 8)
+    xb = _gathered(jnp.ones((32, 128)), row_src, 4)
+    dw = px.grouped_mm_dw(xb, jnp.ones((xb.shape[0], 128)), tg, tu, 8)
+    assert float(jnp.abs(dw[2:]).max()) == 0.0 and float(dw[0].min()) == 32.0
+
+
+def _expert_layer(hf, params_mlp, x):
+    cfg = llama.Config(**arch.program_config(hf))
+    return tt.jit(lambda mp, x_: llama.sparse_moe_mlp(mp, x_, cfg))(params_mlp, x)
+
+
+def _without_shared(mp):
+    zero = jax.tree_util.tree_map(jnp.zeros_like, mp["shared"])
+    return {**mp, "shared": zero}
+
+
+def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+    whole = tiny(16)
+    mp = arch.make_params(whole, common.seed_words(3), dtype=jnp.float32)["blocks"][0]["mlp"]
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, T, whole["hidden_size"]))
+    uncut = _expert_layer(whole, mp, x)
+    C, I = whole["hidden_size"], whole["moe_intermediate_size"]
+    total = None
+    for share in range(8):
+        first = 2 * share
+        part = {**_without_shared(mp), "fc_1": mp["fc_1"][first * C:(first + 2) * C],
+                "fc_2": mp["fc_2"][first * C:(first + 2) * C], "proj": mp["proj"][first * I:(first + 2) * I]}
+        y = _expert_layer(tiny(2, first), part, x)
+        total = y if total is None else total + y
+    shared_once = _expert_layer(tiny(2, 0), {**mp, "fc_1": jnp.zeros_like(mp["fc_1"][:2 * C]),
+                                             "fc_2": mp["fc_2"][:2 * C], "proj": mp["proj"][:2 * I]}, x)
+    assert rel(total + shared_once, uncut) < 1e-5
+    s = arch.sizes(whole)
+    assert rel(uncut[0], arch._experts(x[0], None, mp, s, False)) < 1e-5, "and the reference agrees on the uncut layer"
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["xla", "pallas_interpreted"])
+def test_no_token_is_dropped_when_one_held_expert_takes_over_half_of_the_rows(monkeypatch, pallas):
+    if pallas:
+        monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+    hf = tiny(4, 2, hidden_size=128, moe_intermediate_size=128, shared_expert_intermediate_size=128)
+    s = arch.sizes(hf)
+    mp = arch.make_params(hf, common.seed_words(11), dtype=jnp.float32)["blocks"][0]["mlp"]
+    d = jnp.ones((s["C"],)) / s["C"] ** 0.5
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, T, s["C"])) + 4.0 * d
+    mp["gate"] = mp["gate"].at[3].set(2.0 * d)       # expert 3, held here, wins every token
+    probs = jax.nn.softmax(x[0] @ mp["gate"].T, -1)
+    _, top = jax.lax.top_k(probs, s["k"])
+    on_held = (top >= 2) & (top < 6)
+    assert int((top == 3).sum()) == T and int((top == 3).sum()) > 0.5 * int(on_held.sum())
+    before = px.stats.get("grouped_mm", 0)
+    got = _expert_layer(hf, mp, x)
+    assert (px.stats.get("grouped_mm", 0) > before) == pallas
+    assert rel(got[0], arch._experts(x[0], None, mp, s, False)) < 1e-5
+
+
+@pytest.mark.parametrize("skew", [False, True], ids=["spread", "every_token_on_one_held_expert"])
+def test_rows_past_the_first_wave_are_computed_not_dropped(skew):
+    """Told that its 8 experts are 8 of 256, the share sizes its waves for a
+    thirty-second of the rows (8 tiles); the routing below, over 16 experts,
+    needs 17 or 18: the later waves run only because the routing filled the
+    earlier ones, forward and backward."""
+    total = 256
+    rng = np.random.default_rng(0)
+    N, k, first, held, tile, C, I = 50, 4, 4, 8, 8, 6, 5
+    idx = np.stack([rng.permutation(16)[:k] for _ in range(N)]).astype(np.int32)
+    if skew:
+        idx[:, 0] = 5
+    tw, x = jnp.asarray(rng.random((N, k)), jnp.float32), jnp.asarray(rng.standard_normal((N, C)), jnp.float32)
+    w1, w3 = (jnp.asarray(rng.standard_normal((held, C, I)), jnp.float32) for _ in range(2))
+    w2 = jnp.asarray(rng.standard_normal((held, I, C)), jnp.float32)
+    wave_tiles = jaxex.moe_wave_tiles(N * k, held, total, tile)
+    assert wave_tiles == 8 and int(jaxex.moe_plan(jnp.asarray(idx), first, held, tile, wave_tiles)["tiles_used"]) > 16
+
+    def dense(x, tw, w1, w3, w2):
+        return sum(jnp.sum(tw * (idx == e + first), axis=1)[:, None]
+                   * ((jax.nn.silu(x @ w1[e]) * (x @ w3[e])) @ w2[e]) for e in range(held))
+
+    assert rel(jaxex._moe_share(x, jnp.asarray(idx), tw, w1, w3, w2, first, total, tile), dense(x, tw, w1, w3, w2)) < 1e-5
+    dy = jnp.asarray(rng.standard_normal((N, C)), jnp.float32)
+    got = jaxex._moe_expert_share_backward_impl(dy, x, jnp.asarray(idx), tw, w1, w3, w2, first, total, tile)
+    ref = jax.vjp(dense, x, tw, w1, w3, w2)[1](dy)
+    assert max(rel(a, b) for a, b in zip(got, ref)) < 1e-5
+
+
+# --------------------------------------------------------------------------
+# what does not run this model, and what is untouched
+# --------------------------------------------------------------------------
+
+def _tiny_cfg_and_params():
+    hf = tiny(8)
+    return llama.Config(**arch.program_config(hf)), arch.make_params(hf, common.seed_words(1))
+
+
+def test_generate_refuses_the_config_with_one_clear_error():
+    from thunder_tpu.models import generate
+
+    cfg, params = _tiny_cfg_and_params()
+    with pytest.raises(NotImplementedError, match="cannot be served.*linear_attention"):
+        generate.generate(params, jnp.zeros((1, 4), jnp.int32), cfg, 2)
+
+
+def test_serve_refuses_the_config_with_one_clear_error():
+    cfg, params = _tiny_cfg_and_params()
+    with pytest.raises(NotImplementedError, match="cannot be served.*linear_attention"):
+        tt.serve(None, params, cfg, num_blocks=8, max_batch=1)
+
+
+def test_an_expert_share_without_linear_layers_is_refused_too():
+    from thunder_tpu.models.generate import require_servable
+
+    cfg = llama.Config(name="moe-only", n_layer=2, n_head=4, n_embd=64, mlp_class="SparseMoE", n_expert=8,
+                       n_expert_per_token=2, intermediate_size=32)
+    with pytest.raises(NotImplementedError, match="SparseMoE"):
+        require_servable(cfg)
+    require_servable(llama.Config.from_name("tiny-mistral-debug"))
+
+
+def test_init_params_builds_the_layout_the_reference_builds():
+    cfg, params = _tiny_cfg_and_params()
+    own = llama.init_params(cfg, jax.random.PRNGKey(0))
+    shapes = lambda t: jax.tree_util.tree_map(lambda a: a.shape, t)  # noqa: E731
+    assert shapes(own) == shapes(params)
+    assert cfg.layer_types == ("linear_attention",) * 3 + ("full_attention",)
+
+
+@pytest.mark.parametrize("name", ["tiny-llama-debug", "tiny-mistral-debug", "tiny-moe-debug", "tiny-pythia-debug"])
+def test_presets_that_were_there_draw_the_weights_they_drew(name):
+    """The new kinds take no random key from the presets' stream."""
+    cfg = llama.Config.from_name(name)
+    assert cfg.layer_types is None and cfg.training_only is None
+    n = 3 + cfg.n_layer * (5 + 3 * max(1, cfg.n_expert))
+    first = jax.random.split(jax.random.PRNGKey(0), n)[0]
+    want = (jax.random.normal(first, (cfg.padded_vocab_size, cfg.n_embd), jnp.float32) * 0.02).astype(jnp.bfloat16)
+    assert jnp.array_equal(llama.init_params(cfg, jax.random.PRNGKey(0))["wte"], want)
+
+
+def test_layer_types_are_checked():
+    with pytest.raises(AssertionError):
+        llama.Config(n_layer=2, layer_types=("full_attention",))
+    with pytest.raises(AssertionError):
+        llama.Config(n_layer=1, layer_types=("linear_attention",))     # no head counts given

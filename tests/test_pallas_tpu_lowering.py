@@ -129,6 +129,29 @@ def _cases(nh: int, ng: int) -> dict:
             [qf, qf, kf, kf, qf, lse])
     cases["flash_cross_entropy"] = (
         px._flash_ce.__wrapped__, [((256, 2048), BF), ((256,), I32)])
+    # the chunked gated delta rule (2 key heads, 4 value heads, two blocks of
+    # 8 chunks) and the grouped products over sorted rows (8 tiles, 4 groups)
+    Tg, Cg = 1024, 64
+    cases["gdn_chunk_fwd"] = (
+        lambda q_, k, v, gc, gr, b: px._gdn_fwd.__wrapped__(q_, k, v, gc, gr, b, 2, 4, Cg, 512),
+        [((2, Tg, HS), BF), ((2, Tg, HS), BF), ((4, Tg, HS), BF), ((4, Tg, 1), F32),
+         ((4, Tg // Cg, Cg), F32), ((4, Tg, 1), F32)])
+    gdn_ops = cases["gdn_chunk_fwd"][1]
+    cases["gdn_chunk_fwd/states"] = (
+        lambda q_, k, v, gc, gr, b: px._gdn_fwd.__wrapped__(q_, k, v, gc, gr, b, 2, 4, Cg, 512, emit_states=True),
+        gdn_ops)
+    cases["gdn_chunk_bwd"] = (
+        lambda do, q_, k, v, gc, gr, b, st: px._gdn_bwd.__wrapped__(do, q_, k, v, gc, gr, b, st, 2, 4, Cg, 512),
+        [((4, Tg, HS), BF), *gdn_ops, ((4, Tg // Cg, HS, HS), F32)])
+    rows, tiles, plan = ((1024, 256), BF), ((8,), I32), ((1,), I32)
+    cases["moe_grouped_mm"] = (
+        px._moe_grouped_mm.__wrapped__, [rows, ((4, 256, 512), BF), tiles, plan])
+    cases["moe_grouped_mm/transposed"] = (
+        lambda x, w, tg, tu: px._moe_grouped_mm.__wrapped__(x, w, tg, tu, transpose_w=True),
+        [rows, ((4, 512, 256), BF), tiles, plan])
+    cases["moe_grouped_mm_dw"] = (
+        lambda x, dy, tg, tu: px._moe_grouped_mm_dw.__wrapped__(x, dy, tg, tu, groups=4),
+        [rows, ((1024, 512), BF), tiles, plan])
     return cases
 
 
@@ -259,13 +282,14 @@ def test_every_pallas_call_site_is_named():
     import inspect
 
     src = inspect.getsource(px)
-    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 10
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 14
     assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
         "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
         "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",
         "paged_attn_verify_quant", "paged_token_write", "paged_token_write_masked",
         "paged_token_write_fused", "paged_token_write_fused_masked",
-        "paged_chunk_write", "paged_chunk_write_fused", "lora_delta_fused"}
+        "paged_chunk_write", "paged_chunk_write_fused", "lora_delta_fused",
+        "gdn_chunk_fwd", "gdn_chunk_bwd", "moe_grouped_mm", "moe_grouped_mm_dw"}
 
 
 @pytest.mark.slow

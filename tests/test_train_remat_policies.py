@@ -88,7 +88,46 @@ class TestTrainStepPolicies:
                 "remat changed the loss — recompute must be a memory "
                 "transform, not a math transform")
 
+    def test_recomputing_at_first_use_never_raises_the_peak(self, sweep):
+        """Each recomputed op sits just before its first reader, so dropping
+        residuals cannot lengthen what is alive at the peak."""
+        peaks = [sweep[p][1]["peak_bytes_estimate"] for p in ("none", "attention", "full_block")]
+        assert peaks[0] >= peaks[1] >= peaks[2], peaks
+
     def test_reduction_frac_surfaced(self, sweep):
         st = sweep["full_block"][1]
         assert 0.0 < st["remat_residual_reduction_frac"] <= 1.0
         assert st["residual_bytes_no_remat"] >= st["residual_bytes"]
+
+
+def test_place_late_puts_each_recomputed_op_before_its_first_reader():
+    """Needs first, then the op, then the reader; an op nobody reads is left out."""
+    from types import SimpleNamespace as NS
+
+    from thunder_tpu.core.rematerialization import _place_late
+
+    def op(name, args, outs):
+        return NS(name=name, flat_proxy_args=[NS(name=a) for a in args], flat_proxy_outs=[NS(name=o) for o in outs])
+
+    recompute = [op("r1", ["x"], ["a"]), op("r2", ["a"], ["b"]), op("r3", ["y"], ["c"]), op("r4", ["x"], ["unused"])]
+    body = [op("b1", ["g"], ["h"]), op("b2", ["h", "c"], ["i"]), op("b3", ["i", "b"], ["j"])]
+    assert [o.name for o in _place_late(recompute, body)] == ["b1", "r3", "b2", "r1", "r2", "b3"]
+
+
+@pytest.mark.parametrize("pol", ["none", "attention"])
+def test_recomputation_is_held_behind_barriers(pol):
+    """The backward trace, and the program lowered from it, carry an
+    ``optimization_barrier`` for every group of recomputed ops that waits
+    for a gradient; with nothing recomputed there is none.  (``full_block``
+    chains every layer's cone to the one before, so its first reader pulls
+    them all and nothing is left to hold.)"""
+    idx = jax.random.randint(jax.random.PRNGKey(1), (B, T), 0, CFG.vocab_size)
+    cos, sin = llama.build_rope_cache(CFG, T)
+    mesh = dist.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    params = dist.ddp(llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32), mesh)
+    ts = dist.make_train_step(lambda p, i, t, c, s: llama.gpt_loss(p, i, t, c, s, CFG),
+                              optax.adamw(1e-3), mesh, remat=pol)
+    opt = ts.init_optimizer_state(params)
+    counts = (ts.lower_hlo(params, opt, idx, idx, cos, sin).count("optimization_barrier"),
+              str(ts.bw_trace).count("optimization_barrier"))
+    assert counts == (0, 0) if pol == "none" else min(counts) > 0, counts

@@ -210,6 +210,22 @@ class PrimIDs(Enum):
     CROSS_ENTROPY_FWD = auto()
     FUSED_LINEAR_CE = auto()
     FUSED_LINEAR_CE_BACKWARD = auto()
+    # chunked gated delta rule (linear attention with a decayed rank-1 state
+    # update): one fused prim with its own backward, claimed by the Pallas
+    # executor's ``gdn_chunk_fwd`` kernel
+    GDN_CHUNK = auto()
+    GDN_CHUNK_BACKWARD = auto()
+    # the causal depthwise conv over time that feeds it (a few shifted
+    # multiply-adds a channel: cheap enough to make again in the backward pass)
+    CAUSAL_CONV1D = auto()
+    CAUSAL_CONV1D_BACKWARD = auto()
+    # an expert layer's share of a mixture of experts, one fused prim with its
+    # own backward (sorted rows, no capacity, nothing dropped)
+    MOE_EXPERT_SHARE = auto()
+    MOE_EXPERT_SHARE_BACKWARD = auto()
+    # identity on a tuple of tensors that the compiler may not reorder across
+    # (ties the start of a recomputation to the gradient that needs it)
+    OPTIMIZATION_BARRIER = auto()
     # einsum stays one prim so XLA lowers it straight to dot_general
     # (the reference decomposes via opt_einsum, torch/__init__.py einsum)
     EINSUM = auto()
@@ -1223,6 +1239,153 @@ fused_linear_ce_backward = make_prim(
     PrimIDs.FUSED_LINEAR_CE_BACKWARD, "fused_linear_ce_backward",
     meta=_fused_linear_ce_backward_meta, tags=(OpTags.MATMUL_OP,),
 )
+
+
+#: tokens of a chunk of the chunked gated delta rule (every executor's)
+GDN_CHUNK = 64
+
+
+def _gdn_check(q, k, v, g, beta) -> None:
+    for t in (q, k, v, g, beta):
+        _check_tensor(t)
+    check(q.ndim == 4 and k.shape == q.shape, lambda: f"gdn_chunk: q/k must be (B, Hk, T, dk), got {q.shape}/{k.shape}")
+    check(v.ndim == 4 and v.shape[0] == q.shape[0] and v.shape[2] == q.shape[2],
+          lambda: f"gdn_chunk: v must be (B, Hv, T, dv), got {v.shape} against q {q.shape}")
+    check(v.shape[1] % q.shape[1] == 0,
+          lambda: f"gdn_chunk: {v.shape[1]} value heads are no multiple of {q.shape[1]} key heads")
+    check(tuple(g.shape) == tuple(v.shape[:3]) and tuple(beta.shape) == tuple(v.shape[:3]),
+          lambda: f"gdn_chunk: g/beta must be (B, Hv, T), got {g.shape}/{beta.shape}")
+
+
+def _gdn_chunk_meta(q: TensorProxy, k: TensorProxy, v: TensorProxy, g: TensorProxy, beta: TensorProxy) -> TensorProxy:
+    """The gated delta rule over a whole sequence, by chunks.  Per value head
+    (key head ``h // (Hv // Hk)``), with ``S (dk, dv)`` float32, zero at the
+    sequence start: ``S <- S * exp(g_t)``; ``d_t = (v_t - S^T k_t) * beta_t``;
+    ``S <- S + k_t d_t^T``; ``o_t = S^T q_t``.  ``g`` is the float32 log of
+    the decay (<= 0).  Executors run the chunked form (a triangular solve
+    inside each chunk of ``GDN_CHUNK`` tokens, the state carried between chunks),
+    never a T-step loop.  Returns ``o (B, Hv, T, dv)`` in ``v``'s dtype."""
+    _gdn_check(q, k, v, g, beta)
+    rg = any(t.requires_grad for t in (q, k, v, g, beta)) and dtypes.is_inexact_dtype(v.dtype)
+    return _out_like(v, requires_grad=rg)
+
+
+gdn_chunk = make_prim(PrimIDs.GDN_CHUNK, "gdn_chunk", meta=_gdn_chunk_meta, tags=(OpTags.MATMUL_OP,))
+
+
+def _gdn_chunk_backward_meta(do: TensorProxy, q: TensorProxy, k: TensorProxy, v: TensorProxy, g: TensorProxy,
+                             beta: TensorProxy):
+    """Gradients of :func:`gdn_chunk` in all five operands from the output's
+    cotangent; the forward pass is computed again chunk by chunk, so nothing
+    but the operands is saved."""
+    _check_tensor(do)
+    _gdn_check(q, k, v, g, beta)
+    return tuple(_out_like(t, requires_grad=False) for t in (q, k, v, g, beta))
+
+
+gdn_chunk_backward = make_prim(
+    PrimIDs.GDN_CHUNK_BACKWARD, "gdn_chunk_backward", meta=_gdn_chunk_backward_meta, tags=(OpTags.MATMUL_OP,)
+)
+
+
+def _causal_conv1d_check(x, w) -> None:
+    _check_tensor(x)
+    _check_tensor(w)
+    check(x.ndim == 3 and w.ndim == 2 and w.shape[0] == x.shape[2],
+          lambda: f"causal_conv1d: x (B, T, C), w (C, K); got {x.shape}, {w.shape}")
+
+
+def _causal_conv1d_meta(x: TensorProxy, w: TensorProxy) -> TensorProxy:
+    """Causal depthwise convolution over time: ``out[b, t, c] = sum_j w[c, j] *
+    x[b, t - (K - 1 - j), c]`` with zeros before the sequence (torch
+    ``conv1d(groups=C, padding=K - 1)`` cut to ``T``, no bias).  Tagged
+    elementwise: the rematerialization pass makes it again from its input
+    rather than save its output."""
+    _causal_conv1d_check(x, w)
+    return _out_like(x, requires_grad=(x.requires_grad or w.requires_grad) and dtypes.is_inexact_dtype(x.dtype))
+
+
+causal_conv1d = make_prim(PrimIDs.CAUSAL_CONV1D, "causal_conv1d", meta=_causal_conv1d_meta,
+                          tags=(OpTags.ELEMENTWISE_BINARY_OP,))
+
+
+def _causal_conv1d_backward_meta(g: TensorProxy, x: TensorProxy, w: TensorProxy):
+    _check_tensor(g)
+    _causal_conv1d_check(x, w)
+    return _out_like(x, requires_grad=False), _out_like(w, requires_grad=False)
+
+
+causal_conv1d_backward = make_prim(PrimIDs.CAUSAL_CONV1D_BACKWARD, "causal_conv1d_backward",
+                                   meta=_causal_conv1d_backward_meta, tags=(OpTags.REDUCTION_OP,))
+
+
+#: rows of a grouped product's tile: each expert's group is padded to whole tiles
+MOE_ROW_TILE = 128
+
+
+def _moe_share_check(x, top_idx, top_w, fc_1, fc_2, proj, first, total) -> None:
+    for t in (x, top_idx, top_w, fc_1, fc_2, proj):
+        _check_tensor(t)
+    check(x.ndim == 2, lambda: f"moe_expert_share: x must be (N, C), got {x.shape}")
+    check(top_idx.ndim == 2 and top_idx.shape[0] == x.shape[0] and dtypes.is_exact_dtype(top_idx.dtype),
+          lambda: f"moe_expert_share: top_idx must be (N, k) integer, got {top_idx.shape} {top_idx.dtype}")
+    check(tuple(top_w.shape) == tuple(top_idx.shape), lambda: f"moe_expert_share: top_w {top_w.shape} vs top_idx {top_idx.shape}")
+    E, C, I = fc_1.shape if fc_1.ndim == 3 else (0, 0, 0)
+    check(fc_1.ndim == 3 and C == x.shape[1] and tuple(fc_2.shape) == (E, C, I) and tuple(proj.shape) == (E, I, C),
+          lambda: f"moe_expert_share: fc_1/fc_2 (held, C, I), proj (held, I, C); got {fc_1.shape}, {fc_2.shape}, {proj.shape}")
+    check(0 <= int(first) and int(first) + E <= int(total),
+          lambda: f"moe_expert_share: experts [{first}, {first} + {E}) of {total}")
+
+
+def _moe_expert_share_meta(x: TensorProxy, top_idx: TensorProxy, top_w: TensorProxy, fc_1: TensorProxy,
+                           fc_2: TensorProxy, proj: TensorProxy, first: int, total: int) -> TensorProxy:
+    """What the experts ``[first, first + held)`` of ``total`` add to a
+    mixture-of-experts layer's result.  ``x (N, C)``; ``top_idx``, ``top_w (N, k)``: each token's
+    experts among *all* of them and their weights; ``fc_1``, ``fc_2 (held, C,
+    I)`` and ``proj (held, I, C)``: SwiGLU weights for ``x @ W``.  Returns
+    ``sum over the slots s with top_idx[n, s] held of top_w[n, s] * expert(x[n])``,
+    ``(N, C)``.  Executors sort the assignments that fall on the held experts
+    by expert into whole ``MOE_ROW_TILE``-row tiles and multiply them as groups; no
+    capacity is assumed and no token is dropped, whatever the routing
+    (``total`` only sizes the buffers for the rows an even routing sends)."""
+    _moe_share_check(x, top_idx, top_w, fc_1, fc_2, proj, first, total)
+    rg = any(t.requires_grad for t in (x, top_w, fc_1, fc_2, proj)) and dtypes.is_inexact_dtype(x.dtype)
+    return _out_like(x, requires_grad=rg)
+
+
+moe_expert_share = make_prim(PrimIDs.MOE_EXPERT_SHARE, "moe_expert_share", meta=_moe_expert_share_meta,
+                             tags=(OpTags.MATMUL_OP,))
+
+
+def _moe_expert_share_backward_meta(dy: TensorProxy, x: TensorProxy, top_idx: TensorProxy, top_w: TensorProxy,
+                                    fc_1: TensorProxy, fc_2: TensorProxy, proj: TensorProxy, first: int, total: int):
+    """Gradients of :func:`moe_expert_share` in ``x``, ``top_w`` and the three
+    weights; the sorted rows and the experts' hidden activations are made
+    again, so the forward saves its operands alone."""
+    _check_tensor(dy)
+    _moe_share_check(x, top_idx, top_w, fc_1, fc_2, proj, first, total)
+    return tuple(_out_like(t, requires_grad=False) for t in (x, top_w, fc_1, fc_2, proj))
+
+
+moe_expert_share_backward = make_prim(
+    PrimIDs.MOE_EXPERT_SHARE_BACKWARD, "moe_expert_share_backward", meta=_moe_expert_share_backward_meta,
+    tags=(OpTags.MATMUL_OP,))
+
+
+def _optimization_barrier_meta(*tensors: TensorProxy) -> tuple:
+    """``jax.lax.optimization_barrier``: returns its operands unchanged, all
+    at once; nothing that reads a result can be scheduled before every
+    operand exists.  The rematerialization pass puts one between a saved
+    residual and the ops that recompute from it, with the gradient that
+    first needs the result as the other operand."""
+    check(len(tensors) > 0, lambda: "optimization_barrier needs at least one tensor")
+    for t in tensors:
+        _check_tensor(t)
+    return tuple(_out_like(t) for t in tensors)
+
+
+optimization_barrier = make_prim(PrimIDs.OPTIMIZATION_BARRIER, "optimization_barrier",
+                                 meta=_optimization_barrier_meta)
 
 
 def _einsum_meta(spec: str, *operands: TensorProxy) -> TensorProxy:

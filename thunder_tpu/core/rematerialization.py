@@ -87,6 +87,52 @@ def _bytes(p: Proxy) -> int:
     return n * width
 
 
+def _place_late(recompute: list, body: list, trace: TraceCtx | None = None) -> list:
+    """``body`` with every op of ``recompute`` (in forward order) inserted
+    just before the first op that needs one of its outputs, its own needs
+    first.  With ``trace`` (where new proxies are named), what such a group
+    of ops reads from outside (saved residuals, weights) first passes an
+    ``optimization_barrier`` together with a value the backward pass has
+    just made and the reader takes too (the gradient arriving at that
+    layer): the compiler's scheduler is free to hoist a recomputation that
+    depends on residuals alone to the top of the backward pass, and does."""
+    import thunder_tpu.core.prims as prims
+    from thunder_tpu.core.proxies import variableify
+
+    producer = {o.name: (i, b) for i, b in enumerate(recompute) for o in b.flat_proxy_outs}
+    placed: set[int] = set()
+    made_late: set[str] = set()
+    out: list = []
+
+    def need(name: str, group: list) -> None:
+        i, b = producer.get(name, (None, None))
+        if b is None or i in placed:
+            return
+        placed.add(i)
+        for a in b.flat_proxy_args:
+            need(a.name, group)
+        group.append(b)
+
+    for b in body:
+        group: list = []
+        for a in b.flat_proxy_args:
+            need(a.name, group)
+        gate = next((a for a in b.flat_proxy_args if isinstance(a, TensorProxy) and a.name in made_late), None)
+        if group and gate is not None and trace is not None:
+            leaves = {a.name: a for g in group for a in g.flat_proxy_args
+                      if isinstance(a, TensorProxy) and a.name not in producer}
+            if leaves:
+                with tracectx(trace):
+                    held = prims.optimization_barrier.meta(*leaves.values(), gate)
+                    out.append(prims.optimization_barrier.bind(*leaves.values(), gate, output=held))
+                swap = {variableify(old): new for old, new in zip(leaves.values(), held)}
+                group = [g.from_bsym_swap_proxies(swap, skip_output=True) for g in group]
+        out.extend(group)
+        out.append(b)
+        made_late.update(o.name for o in b.flat_proxy_outs)
+    return out
+
+
 def saved_bytes(fw_trace: TraceCtx) -> int:
     """Total bytes of the forward trace's saved-for-backward residuals
     (the second element of its RETURN) — the quantity remat shrinks."""
@@ -110,6 +156,12 @@ def rematerialize_forward_and_backward(
     GSPMD attaches to them), bottoming out only at trace inputs and other
     saved values, so residual memory shrinks toward the inputs at the cost
     of backward recompute.  RANDOM-tagged ops are never recomputed.
+
+    Each recomputed op is placed just before the backward op that first
+    reads it, not at the top of the backward trace, and held there by an
+    ``optimization_barrier`` (:func:`_place_late`), so the recomputed
+    activations of different layers are never alive together (a model whose
+    cones are hundreds of megabytes a layer does not fit otherwise).
     """
     # locate the fw return bsym: (output, saved)
     ret = None
@@ -246,7 +298,7 @@ def rematerialize_forward_and_backward(
     new_bw = from_trace(bw_trace)
     prepend = [b for _, b in sorted(recompute_bsyms.items())]
     body = [b for b in bw_trace.bound_symbols]
-    new_bw.bound_symbols = prepend + body
+    new_bw.bound_symbols = _place_late(prepend, body, new_bw)
     bw_args = new_saved + cotangents
     new_bw.args = tuple(bw_args)
     new_bw.set_siginfo(SigInfo(name="backward", args=[(p.name, None) for p in bw_args]))

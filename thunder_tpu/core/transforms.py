@@ -65,6 +65,7 @@ nondifferentiable_ids = {
     PrimIDs.FULL, PrimIDs.IOTA, PrimIDs.UNIFORM, PrimIDs.RANDN, PrimIDs.RANDINT,
     PrimIDs.MULTINOMIAL, PrimIDs.EMBEDDING_BACKWARD, PrimIDs.ITEM,
     PrimIDs.SDPA_BACKWARD,
+    PrimIDs.OPTIMIZATION_BARRIER, PrimIDs.GDN_CHUNK_BACKWARD, PrimIDs.CAUSAL_CONV1D_BACKWARD, PrimIDs.MOE_EXPERT_SHARE_BACKWARD,
 }
 
 
@@ -735,6 +736,28 @@ def _fused_linear_ce_bw(bsym, g_losses, g_lse):
 
 
 _fused_linear_ce_bw._accepts_none_cotangents = True
+
+
+@register_backward_rule(PrimIDs.GDN_CHUNK)
+def _gdn_chunk_bw(bsym, g_out):
+    """Saved: the five operands.  The backward prim runs the chunked forward
+    again and differentiates it chunk by chunk."""
+    q, k, v, g, beta = bsym.args
+    return list(zip((q, k, v, g, beta), prims.gdn_chunk_backward(g_out, q, k, v, g, beta)))
+
+
+@register_backward_rule(PrimIDs.CAUSAL_CONV1D)
+def _causal_conv1d_bw(bsym, g):
+    x, w = bsym.args
+    return list(zip((x, w), prims.causal_conv1d_backward(g, x, w)))
+
+
+@register_backward_rule(PrimIDs.MOE_EXPERT_SHARE)
+def _moe_expert_share_bw(bsym, g):
+    """Saved: the operands.  ``top_idx`` takes no gradient."""
+    x, top_idx, top_w, fc_1, fc_2, proj, first, total = bsym.args
+    grads = prims.moe_expert_share_backward(g, x, top_idx, top_w, fc_1, fc_2, proj, first, total)
+    return list(zip((x, top_w, fc_1, fc_2, proj), grads))
 
 
 @register_backward_rule(PrimIDs.EMBEDDING)

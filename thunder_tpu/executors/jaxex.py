@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from thunder_tpu.core import dtypes
 from thunder_tpu.core.devices import Device, to_jax_device
-from thunder_tpu.core.prims import PrimIDs, prim_lookup
+from thunder_tpu.core.prims import GDN_CHUNK, MOE_ROW_TILE, PrimIDs, prim_lookup
 from thunder_tpu.extend import OperatorExecutor, add_always_executor, add_default_executor, register_executor
 
 __all__ = ["ex", "jax_ex", "get_prim_impl", "prim_impls"]
@@ -690,6 +690,327 @@ def _fused_linear_ce_backward_impl(g, h, w, target, lse, ignore_index=-100):
     dh, dwcs = jax.lax.scan(body, jnp.zeros((N, C), dtype=jnp.float32), jnp.arange(n_chunks))
     dw = dwcs.reshape(V, C)
     return dh.astype(h.dtype), dw
+
+
+# Chunked gated delta rule.  ``_gdn_chunked`` is the plain XLA decomposition
+# (everything that does not depend on the state is batched over all chunks;
+# a ``lax.scan`` over chunks carries the state through three small batched
+# products a step).  It is the executor of last resort and, with its
+# derivative (``_gdn_chunked_backward``), the oracle of the Pallas
+# ``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` kernels, which pallasex.py installs
+# into the two hooks below.
+_gdn_fast_path: Callable | None = None  # (q, k, v, g, beta, chunk) -> o or None
+_gdn_bwd_fast_path: Callable | None = None  # (do, q, k, v, g, beta, chunk) -> five gradients or None
+
+
+def _unit_lower_inverse(A):
+    """``(I + A)^-1`` for strictly lower-triangular ``A (..., C, C)``: with
+    ``B = -A`` nilpotent, ``(I + B)(I + B^2)(I + B^4)...`` is the whole
+    Neumann series after ``log2 C`` squarings -- products only, no
+    row-by-row substitution, so it runs on the MXU and differentiates."""
+    C = A.shape[-1]
+    hi = jax.lax.Precision.HIGHEST
+    P = -A
+    T = jnp.eye(C, dtype=A.dtype) + P
+    n = 1
+    while 2 * n < C:
+        P = jnp.matmul(P, P, precision=hi)
+        T = T + jnp.matmul(T, P, precision=hi)
+        n *= 2
+    return T
+
+
+def _gdn_chunked(q, k, v, g, beta, chunk):
+    """q, k ``(B, Hk, T, dk)``, v ``(B, Hv, T, dv)``, g, beta ``(B, Hv, T)``
+    -> o ``(B, Hv, T, dv)``.  T is padded to whole chunks with tokens that
+    leave the state alone (g = 0, beta = 0)."""
+    B, Hk, T, dk = q.shape
+    Hv, dv = v.shape[1], v.shape[3]
+    rep = Hv // Hk
+    C = int(chunk)
+    pad = (-T) % C
+    f32 = jnp.float32
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) for t in (q, k, v))
+        g, beta = (jnp.pad(t, ((0, 0), (0, 0), (0, pad))) for t in (g, beta))
+    n = (T + pad) // C
+    qc = jnp.repeat(q, rep, axis=1).reshape(B, Hv, n, C, dk)
+    kc = jnp.repeat(k, rep, axis=1).reshape(B, Hv, n, C, dk)
+    vc = v.reshape(B, Hv, n, C, dv)
+    bc = beta.astype(f32).reshape(B, Hv, n, C, 1)
+    G = jnp.cumsum(g.astype(f32).reshape(B, Hv, n, C), axis=-1)       # log decay since the chunk's start
+    low = jnp.tril(jnp.ones((C, C), bool))
+    # decay from token j to token i of a chunk, j <= i; masked before the exp
+    M = jnp.exp(jnp.where(low, G[..., :, None] - G[..., None, :], -jnp.inf))
+    kk = jnp.einsum("...ik,...jk->...ij", kc, kc, preferred_element_type=f32)
+    Tm = _unit_lower_inverse(jnp.where(jnp.tril(low, -1), bc * M * kk, 0.0))
+    eG = jnp.exp(G)[..., None]
+    U = jnp.einsum("...ij,...jd->...id", Tm, bc * vc.astype(f32))     # d_t with an empty state
+    W = jnp.einsum("...ij,...jk->...ik", Tm, bc * eG * kc.astype(f32))  # what the carried state takes off it
+    QK = M * jnp.einsum("...ik,...jk->...ij", qc, kc, preferred_element_type=f32)
+    Qd = eG * qc.astype(f32)
+    Glast = G[..., -1]
+    Kd = jnp.exp(Glast[..., None] - G)[..., None] * kc.astype(f32)
+
+    def step(S, xs):
+        U_, W_, QK_, Qd_, Kd_, gl = xs
+        D = U_ - jnp.einsum("bhik,bhkd->bhid", W_, S)
+        o = jnp.einsum("bhik,bhkd->bhid", Qd_, S) + jnp.einsum("bhij,bhjd->bhid", QK_, D)
+        S = jnp.exp(gl)[..., None, None] * S + jnp.einsum("bhik,bhid->bhkd", Kd_, D)
+        return S, o
+
+    lead = lambda a: jnp.moveaxis(a, 2, 0)  # noqa: E731 -- chunks first
+    _, o = jax.lax.scan(jax.checkpoint(step), jnp.zeros((B, Hv, dk, dv), f32),
+                        tuple(lead(a) for a in (U, W, QK, Qd, Kd, Glast)))
+    o = jnp.moveaxis(o, 0, 2).reshape(B, Hv, T + pad, dv)
+    return o[:, :, :T].astype(v.dtype)
+
+
+@impl(PrimIDs.GDN_CHUNK)
+def _gdn_chunk_impl(q, k, v, g, beta, chunk=GDN_CHUNK):
+    if _gdn_fast_path is not None:
+        res = _gdn_fast_path(q, k, v, g, beta, chunk)
+        if res is not None:
+            return res
+    return _gdn_chunked(q, k, v, g, beta, chunk)
+
+
+def _gdn_chunked_backward(do, q, k, v, g, beta, chunk):
+    """The XLA chunked form differentiated: the forward scan again (each
+    chunk's step checkpointed, so only the carried states are kept), then
+    the backward scan."""
+    _, vjp = jax.vjp(lambda *a: _gdn_chunked(*a, chunk), q, k, v, g, beta)
+    return vjp(do)
+
+
+@impl(PrimIDs.GDN_CHUNK_BACKWARD)
+def _gdn_chunk_backward_impl(do, q, k, v, g, beta, chunk=GDN_CHUNK):
+    if _gdn_bwd_fast_path is not None:
+        res = _gdn_bwd_fast_path(do, q, k, v, g, beta, chunk)
+        if res is not None:
+            return res
+    return _gdn_chunked_backward(do, q, k, v, g, beta, chunk)
+
+
+@impl(PrimIDs.OPTIMIZATION_BARRIER)
+def _optimization_barrier_impl(*tensors):
+    return tuple(jax.lax.optimization_barrier(tensors))
+
+
+def _shifted(xp, T, K):
+    """The K windows of T steps of a sequence padded by K - 1 steps."""
+    return [xp[:, j:j + T] for j in range(K)]
+
+
+@impl(PrimIDs.CAUSAL_CONV1D)
+def _causal_conv1d_impl(x, w):
+    T, K = x.shape[1], w.shape[1]
+    wf = w.astype(jnp.float32)
+    taps = _shifted(jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32), T, K)
+    return sum(tap * wf[:, j] for j, tap in enumerate(taps)).astype(x.dtype)
+
+
+@impl(PrimIDs.CAUSAL_CONV1D_BACKWARD)
+def _causal_conv1d_backward_impl(g, x, w):
+    T, K = x.shape[1], w.shape[1]
+    gf, wf = g.astype(jnp.float32), w.astype(jnp.float32)
+    # tap j weighs the token K - 1 - j steps back, so its gradient comes from that many steps on
+    ahead = _shifted(jnp.pad(gf, ((0, 0), (0, K - 1), (0, 0))), T, K)
+    dx = sum(ahead[K - 1 - j] * wf[:, j] for j in range(K))
+    taps = _shifted(jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32), T, K)
+    dw = jnp.stack([jnp.sum(gf * tap, axis=(0, 1)) for tap in taps], axis=1)
+    return dx.astype(x.dtype), dw.astype(w.dtype)
+
+
+# Grouped matrix products over rows sorted by group, each group padded to
+# whole row tiles, the group sizes known only at run time inside static shapes
+# (``lax.ragged_dot`` here; pallasex installs ``moe_grouped_mm*``), and an
+# expert layer's share made of them.  ``tile_group (R / tile,)`` names each
+# tile's group, ``tiles_used (1,)`` how many tiles hold rows; the time follows
+# the tiles used.
+_grouped_mm_fast_path: Callable | None = None      # (x, w, tile_group, tiles_used, transpose_w) -> out or None
+_grouped_mm_dw_fast_path: Callable | None = None   # (x, dy, tile_group, tiles_used, groups) -> dw or None
+
+
+def _group_sizes(tile_group, tiles_used, rows, groups):
+    tile = rows // tile_group.shape[0]
+    used = jnp.arange(tile_group.shape[0]) < tiles_used[0]
+    return tile * jnp.sum((tile_group[:, None] == jnp.arange(groups)[None, :]) & used[:, None], axis=0,
+                          dtype=jnp.int32)
+
+
+def _grouped_mm_impl(x, w, tile_group, tiles_used, transpose_w=False):
+    """``out[tile t] = x[tile t] @ w[tile_group[t]]`` for the used tiles, zero
+    after; ``x (R, K)``, ``w (G, K, N)`` (``(G, N, K)`` with ``transpose_w``)."""
+    if _grouped_mm_fast_path is not None:
+        res = _grouped_mm_fast_path(x, w, tile_group, tiles_used, transpose_w)
+        if res is not None:
+            return res
+    gs = _group_sizes(tile_group, tiles_used, x.shape[0], w.shape[0])
+    out = jax.lax.ragged_dot(x, jnp.swapaxes(w, 1, 2) if transpose_w else w, gs)
+    return jnp.where((jnp.arange(x.shape[0]) < jnp.sum(gs))[:, None], out, 0).astype(x.dtype)
+
+
+def _grouped_mm_dw_impl(x, dy, tile_group, tiles_used, groups):
+    """``dw[g] = sum over the used tiles t of group g of x[tile t]^T @ dy[tile
+    t]``, ``(groups, K, N)``; a group without rows gets zeros."""
+    if _grouped_mm_dw_fast_path is not None:
+        res = _grouped_mm_dw_fast_path(x, dy, tile_group, tiles_used, groups)
+        if res is not None:
+            return res
+    gs = _group_sizes(tile_group, tiles_used, x.shape[0], groups)
+    keep = (jnp.arange(x.shape[0]) < jnp.sum(gs))[:, None]
+    w0 = jnp.zeros((groups, x.shape[1], dy.shape[1]), x.dtype)
+    _, vjp = jax.vjp(lambda w_: jax.lax.ragged_dot(jnp.where(keep, x, 0), w_, gs), w0)
+    return vjp(jnp.where(keep, dy, 0).astype(x.dtype))[0]
+
+
+# The expert share.  Every (token, slot) assignment may fall on a held expert,
+# so the sorted buffer's worst case is N * k rows; what a step really routes
+# here is about held / all of that.  The rows are therefore worked through in
+# *waves* sized for an even routing (``moe_wave_tiles``): the first always, the
+# others only where the routing filled the one before (``lax.cond``), so memory
+# and time follow the rows routed and nothing is ever dropped.  A wave gathers
+# its rows from the tokens and adds its results back to them; differentiated,
+# the gather becomes the scatter-add and the scatter-add the gather.
+def moe_wave_tiles(assignments: int, held: int, total: int, tile: int) -> int:
+    """Tiles of one wave: the rows an even routing sends to ``held`` of
+    ``total`` experts and an eighth more, a tile of padding a group, in eights."""
+    even = assignments * held / total / tile
+    return max(8, -(-int(1.125 * even + held) // 8) * 8)
+
+
+def moe_plan(top_idx, first: int, held: int, tile: int, wave_tiles: int) -> dict:
+    """Sorts the ``(N, k)`` choices that fall on experts ``[first, first +
+    held)`` by expert, each group padded to whole ``tile``-row tiles, into a
+    buffer of ``R`` rows: the worst case the shapes allow, in whole waves.
+    Returns int32 ``order (N * k,)`` (the flat assignments ``n * k + s`` sorted
+    by expert, those on other experts last), ``off (held + 1,)`` (where each
+    group starts in ``order``), ``cnt``, ``poff (held,)`` (its rows, and where
+    it starts in the buffer), ``tile_group (R / tile,)`` and ``tiles_used ()``."""
+    N, k = top_idx.shape
+    A = N * k
+    wave = wave_tiles * tile
+    R = -(-(A + held * (tile - 1)) // wave) * wave
+    i32 = jnp.int32
+    e = top_idx.reshape(A).astype(i32) - first
+    key = jnp.where((e >= 0) & (e < held), e, held)
+    skey, order = jax.lax.sort((key, jnp.arange(A, dtype=i32)), num_keys=1, is_stable=True)
+    off = jnp.searchsorted(skey, jnp.arange(held + 1, dtype=i32)).astype(i32)
+    cnt = off[1:] - off[:-1]
+    padded = -(-cnt // tile) * tile                            # each group in whole tiles
+    pend = jnp.cumsum(padded)
+    t = jnp.arange(R // tile, dtype=i32)
+    tile_group = jnp.minimum(jnp.searchsorted(pend, t * tile, side="right"), held - 1).astype(i32)
+    return {"order": order, "off": off, "cnt": cnt, "poff": pend - padded,
+            "tile_group": tile_group, "tiles_used": (pend[-1] // tile).astype(i32)}
+
+
+def moe_wave_rows(plan: dict, w: int, tile: int, wave_tiles: int):
+    """Wave ``w`` of the sorted buffer: ``row_src (wave rows,)`` (the flat
+    assignment a row holds, -1 for padding and past the rows routed), its
+    tiles' groups and how many of them are used."""
+    T = wave_tiles
+    tg = plan["tile_group"][w * T:(w + 1) * T]
+    used = jnp.clip(plan["tiles_used"] - w * T, 0, T)
+    t = jnp.arange(T, dtype=jnp.int32)
+    # a tile's first row within its group; its rows follow one another in ``order``
+    within = ((w * T + t) * tile - plan["poff"][tg])[:, None] + jnp.arange(tile, dtype=jnp.int32)[None, :]
+    valid = (t < used)[:, None] & (within < plan["cnt"][tg][:, None])
+    rank = plan["off"][tg][:, None] + within
+    A = plan["order"].shape[0]
+    row_src = jnp.where(valid, plan["order"][jnp.clip(rank, 0, A - 1)], -1)
+    return row_src.reshape(T * tile), tg, used
+
+
+@jax.custom_vjp
+def _gmm(x, w, tile_group, tiles_used):
+    return _grouped_mm_impl(x, w, tile_group, tiles_used, False)
+
+
+def _gmm_bwd(res, g):
+    x, w, tile_group, tiles_used = res
+    return (_grouped_mm_impl(g, w, tile_group, tiles_used, True),
+            _grouped_mm_dw_impl(x, g, tile_group, tiles_used, w.shape[0]), None, None)
+
+
+_gmm.defvjp(lambda x, w, tg, tu: (_gmm(x, w, tg, tu), (x, w, tg, tu)), _gmm_bwd)
+
+
+def _moe_wave(x, top_w, fc_1, fc_2, proj, row_src, tile_group, tiles_used):
+    """One wave of the sorted buffer: its rows gathered from their tokens,
+    through the experts' SwiGLU as grouped products, weighted, added back to
+    their tokens in float32.  ``row_src`` and ``tile_group`` are the wave's."""
+    k = top_w.shape[1]
+    valid = row_src >= 0
+    a = jnp.maximum(row_src, 0)
+    xb = jnp.where(valid[:, None], jnp.take(x, a // k, axis=0), 0)
+    wb = jnp.where(valid, jnp.take(top_w.reshape(-1), a), 0)
+    used = tiles_used.reshape(1)
+    h = jax.nn.silu(_gmm(xb, fc_1, tile_group, used)) * _gmm(xb, fc_2, tile_group, used)
+    yb = _gmm(h * wb[:, None].astype(h.dtype), proj, tile_group, used)
+    # padding rows hold zeros: they may land on token 0
+    return jnp.zeros(x.shape, jnp.float32).at[a // k].add(yb.astype(jnp.float32))
+
+
+def _run_wave(static, plan, w, *operands):
+    tile, wave_tiles, _ = static
+    return _moe_wave(*operands, *moe_wave_rows(plan, w, tile, wave_tiles))
+
+
+def _waves_after_the_first(static, plan, *operands):
+    """Waves 1.. of the buffer, each only where the routing reached it."""
+    _, wave_tiles, n_waves = static
+    y = jnp.zeros(operands[0].shape, jnp.float32)
+    for w in range(1, n_waves):
+        y = jax.lax.cond(plan["tiles_used"] > w * wave_tiles,
+                         lambda y_, *a, w=w: y_ + _run_wave(static, plan, w, *a), lambda y_, *a: y_, y, *operands)
+    return y
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _overflow(static, plan, *operands):
+    """What the rows past the first wave add: nothing, and at no cost, unless
+    the routing filled it.  Differentiated by hand so that the branch not
+    taken makes zeros for the five gradients only (left to ``jax.vjp``, a
+    ``cond`` hands every residual of every later wave out of both branches:
+    gigabytes of zeros a layer in the step that never needs them)."""
+    return jax.lax.cond(plan["tiles_used"] > static[1], lambda *a: _waves_after_the_first(static, plan, *a),
+                        lambda *a: jnp.zeros(a[0].shape, jnp.float32), *operands)
+
+
+def _overflow_bwd(static, res, g):
+    plan, operands = res
+
+    def taken(g_, *a):
+        return jax.vjp(lambda *a_: _waves_after_the_first(static, plan, *a_), *a)[1](g_)
+
+    grads = jax.lax.cond(plan["tiles_used"] > static[1], taken,
+                         lambda g_, *a: tuple(jnp.zeros_like(o) for o in a), g, *operands)
+    return (None, *grads)
+
+
+_overflow.defvjp(lambda static, plan, *operands: (_overflow(static, plan, *operands), (plan, operands)),
+                 _overflow_bwd)
+
+
+@impl(PrimIDs.MOE_EXPERT_SHARE)
+def _moe_share(x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile=MOE_ROW_TILE):
+    held = fc_1.shape[0]
+    wave_tiles = moe_wave_tiles(top_idx.size, held, total, tile)
+    plan = moe_plan(top_idx, first, held, tile, wave_tiles)
+    static = (tile, wave_tiles, plan["tile_group"].shape[0] // wave_tiles)
+    operands = (x, top_w, fc_1, fc_2, proj)
+    y = _run_wave(static, plan, 0, *operands)
+    if static[2] > 1:
+        y = y + _overflow(static, plan, *operands)
+    return y.astype(x.dtype)
+
+
+@impl(PrimIDs.MOE_EXPERT_SHARE_BACKWARD)
+def _moe_expert_share_backward_impl(dy, x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile=MOE_ROW_TILE):
+    _, vjp = jax.vjp(lambda *a: _moe_share(a[0], top_idx, *a[1:], first, total, tile), x, top_w, fc_1, fc_2, proj)
+    return vjp(dy)
 
 
 def get_prim_impl(pid: PrimIDs) -> Callable | None:

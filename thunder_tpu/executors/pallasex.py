@@ -39,12 +39,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from thunder_tpu.core.prims import PrimIDs, prim_lookup
+from thunder_tpu.core.prims import GDN_CHUNK, PrimIDs, prim_lookup
 from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_executor
 
 __all__ = [
     "ex", "pallas_ex", "flash_sdpa", "flash_sdpa_backward",
     "paged_attn_decode", "paged_token_write", "paged_available", "paged_head_size_ok",
+    "gdn_chunk", "grouped_mm", "grouped_mm_dw",
 ]
 
 # exp(MASK_VALUE - lse) underflows to 0 without the inf-inf NaN hazard of -inf
@@ -1875,6 +1876,498 @@ def lora_delta_fused(x, a, b, scaling):
     )(x, a, b)
 
 
+# ---------------------------------------------------------------------------
+# Chunked gated delta rule: ``gdn_chunk_fwd``.
+#
+# Grid (value head, block of NC chunks); the chunk axis is sequential and a
+# VMEM scratch carries the head's (dk, dv) float32 state from block to block.
+# A chunk of C tokens: the decay matrix M_ij = exp(G_i - G_j) (j <= i) from the
+# within-chunk cumulative log-decay G (made outside, handed in once as a column
+# and once as rows, so the kernel never transposes), the strictly lower
+# A = beta * M * K K^T, its unit-triangular inverse by squarings (products
+# only), then five products against the carried state.  q and k come from
+# their key head by index map (no repeat in HBM).  The backward pass,
+# ``gdn_chunk_bwd``, runs the forward kernel again for the state before every
+# chunk, then walks the blocks from the last to the first with the gradient in
+# the state carried the other way; each chunk's terms are made again from the
+# operands (nothing but the operands is saved between the passes).  Its oracle
+# is the XLA chunked form differentiated (jaxex ``_gdn_chunked_backward``).
+# ---------------------------------------------------------------------------
+
+_GDN_BLOCK_TOKENS = 512
+
+
+def _dot3(a, b, dims):
+    """A float32 product in three bfloat16 passes on the MXU (``hi hi + hi lo
+    + lo hi``: about 16 bits of each operand, between a GPU's TF32 and
+    float32).  Mosaic has one pass and six (``HIGHEST``) and nothing between;
+    the triangular inverse and what is multiplied by it need more than one
+    and are a third of the kernel's time at six."""
+    f32, bf = jnp.float32, jnp.bfloat16
+    ah, bh = a.astype(bf), b.astype(bf)
+    al, bl = (a - ah.astype(f32)).astype(bf), (b - bh.astype(f32)).astype(bf)
+    d = functools.partial(jax.lax.dot_general, dimension_numbers=dims, preferred_element_type=f32)
+    return d(ah, bh) + d(ah, bl) + d(al, bh)
+
+
+_AB = (((1,), (0,)), ((), ()))       # a @ b
+_AB_T = (((1,), (1,)), ((), ()))     # a @ b^T
+_AT_B = (((0,), (0,)), ((), ()))     # a^T @ b
+
+
+def _gdn_chunk_terms(q, k, v, Gc, Gr, beta, row, col, eye):
+    """What a chunk's forward and backward share and the state does not enter:
+    the decay matrix ``M``, ``K K^T``, the unit-triangular inverse ``Tm`` (by
+    squarings, float32 in three passes), ``beta V``, ``beta e^G K`` and the
+    inverse applied to both."""
+    f32 = jnp.float32
+    mm = functools.partial(_dot3, dims=_AB)
+    C = row.shape[0]
+    M = jnp.exp(jnp.where(row >= col, Gc - Gr, _MASK_VALUE))          # (C, C), 0 above the diagonal
+    kk = jax.lax.dot_general(k, k, _AB_T, preferred_element_type=f32)
+    P = -jnp.where(row > col, beta * M * kk, 0.0)
+    Tm = eye + P
+    n = 1
+    while 2 * n < C:                                                    # (I + P)(I + P^2)(I + P^4)...
+        P = mm(P, P)
+        Tm = Tm + mm(Tm, P)
+        n *= 2
+    eG = jnp.exp(Gc)
+    Ub = beta * v.astype(f32)
+    Wb = beta * eG * k.astype(f32)
+    return M, kk, Tm, eG, Ub, Wb, mm(Tm, Ub), mm(Tm, Wb)
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, b_ref, o_ref, *rest, C, NC, emit_states):
+    f32 = jnp.float32
+    st_ref, s_ref = rest if emit_states else (None, rest[0])
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    eye = (row == col).astype(f32)
+    ab_t, at_b = _AB_T, _AT_B
+    mm = functools.partial(jax.lax.dot_general, dimension_numbers=_AB, preferred_element_type=f32)
+    for c in range(NC):
+        sl = slice(c * C, (c + 1) * C)
+        q, k, v = q_ref[0, sl, :], k_ref[0, sl, :], v_ref[0, sl, :]
+        dt = v.dtype
+        Gc, Gr, beta = gc_ref[0, sl, :], gr_ref[0, c:c + 1, :], b_ref[0, sl, :]
+        M, _, _, eG, _, _, U, W = _gdn_chunk_terms(q, k, v, Gc, Gr, beta, row, col, eye)
+        S = s_ref[...]
+        if emit_states:
+            st_ref[0, c] = S
+        Sd = S.astype(dt)
+        D = U - mm(W.astype(dt), Sd)
+        QK = jnp.where(row >= col, M * jax.lax.dot_general(q, k, ab_t, preferred_element_type=f32), 0.0)
+        o = mm((eG * q.astype(f32)).astype(dt), Sd) + mm(QK.astype(dt), D.astype(dt))
+        o_ref[0, sl, :] = o.astype(o_ref.dtype)
+        glast = Gc[C - 1:C, :]
+        Kd = (jnp.exp(glast - Gc) * k.astype(f32)).astype(dt)
+        # (1, 1) -> (dk, 1) -> (dk, dv): Mosaic broadcasts one way at a time
+        s_ref[...] = jnp.exp(jnp.broadcast_to(glast, (S.shape[0], 1))) * S + jax.lax.dot_general(
+            Kd, D.astype(dt), at_b, preferred_element_type=f32)
+
+
+def _gdn_specs(Hk: int, Hv: int, TB: int, C: int, dk: int, dv: int, block):
+    """BlockSpecs of the kernels' shared operands (q, k from their key head;
+    v, the log-decay as a column and as rows, beta), with the block of a grid
+    step chosen by ``block(i)`` (forward: i; backward: last first)."""
+    rep, NC = Hv // Hk, TB // C
+    kv_head = lambda b, i: ((b // Hv) * Hk + (b % Hv) // rep, block(i), 0)  # noqa: E731
+    own = lambda b, i: (b, block(i), 0)  # noqa: E731
+    return own, [pl.BlockSpec((1, TB, dk), kv_head), pl.BlockSpec((1, TB, dk), kv_head),
+                 pl.BlockSpec((1, TB, dv), own), pl.BlockSpec((1, TB, 1), own),
+                 pl.BlockSpec((1, NC, C), own), pl.BlockSpec((1, TB, 1), own)]
+
+
+def _seq_params():
+    if _interpret():
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))}
+
+
+@functools.partial(jax.jit, static_argnames=("Hk", "Hv", "C", "TB", "emit_states"))
+def _gdn_fwd(q, k, v, Gc, Gr, beta, Hk: int, Hv: int, C: int, TB: int, emit_states: bool = False):
+    """q, k (B*Hk, T, dk), v (B*Hv, T, dv), Gc, beta (B*Hv, T, 1) f32, Gr
+    (B*Hv, T/C, C) f32 -> o (B*Hv, T, dv); with ``emit_states`` also the
+    float32 state before each chunk, (B*Hv, T/C, dk, dv)."""
+    BH, T, dv = v.shape
+    dk = q.shape[-1]
+    own, in_specs = _gdn_specs(Hk, Hv, TB, C, dk, dv, lambda i: i)
+    out_specs = [pl.BlockSpec((1, TB, dv), own)]
+    out_shape = [jax.ShapeDtypeStruct((BH, T, dv), v.dtype)]
+    if emit_states:
+        out_specs.append(pl.BlockSpec((1, TB // C, dk, dv), lambda b, i: (b, i, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((BH, T // C, dk, dv), jnp.float32))
+    res = pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, C=C, NC=TB // C, emit_states=emit_states),
+        name="gdn_chunk_fwd",
+        grid=(BH, T // TB),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        interpret=_interpret(),
+        **_seq_params(),
+    )(q, k, v, Gc, Gr, beta)
+    return res if emit_states else res[0]
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, b_ref, do_ref, st_ref,
+                    dq_ref, dk_ref, dv_ref, db_ref, dgc_ref, dgr_ref, ds_ref, *, C, NC):
+    """One block of NC chunks, last chunk first; ``ds_ref`` carries the
+    gradient in the state back from the block after it.  Every forward term
+    is made again from the operands and the chunk's saved starting state."""
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    eye = (row == col).astype(f32)
+    low, strict = row >= col, row > col
+    last_col = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1) == C - 1
+    dot = functools.partial(jax.lax.dot_general, preferred_element_type=f32)
+    ab, ab_t, at_b = _AB, _AB_T, _AT_B
+    rows = lambda a: jnp.sum(a, axis=1, keepdims=True)  # noqa: E731 -- (C, n) -> (C, 1)
+    total = lambda a: jnp.sum(rows(a), axis=0, keepdims=True)  # noqa: E731 -- -> (1, 1)
+    for c in reversed(range(NC)):
+        sl = slice(c * C, (c + 1) * C)
+        q, k, v, do = q_ref[0, sl, :], k_ref[0, sl, :], v_ref[0, sl, :], do_ref[0, sl, :]
+        dt = v.dtype
+        Gc, Gr, beta = gc_ref[0, sl, :], gr_ref[0, c:c + 1, :], b_ref[0, sl, :]
+        M, kk, Tm, eG, Ub, Wb, U, W = _gdn_chunk_terms(q, k, v, Gc, Gr, beta, row, col, eye)
+        S0 = st_ref[0, c]
+        S0d, dS = S0.astype(dt), ds_ref[...]
+        dSd = dS.astype(dt)
+        kf, qf = k.astype(f32), q.astype(f32)
+        glast = Gc[C - 1:C, :]
+        e_last = jnp.exp(glast - Gc)                                     # (C, 1)
+        Kd, Qd = e_last * kf, eG * qf
+        D = U - dot(W.astype(dt), S0d, ab)
+        QKraw = dot(q, k, ab_t)
+        QKm = jnp.where(low, M * QKraw, 0.0)
+        Dd = D.astype(dt)
+        # through the read-out, the state's update and D = U - W S0
+        dD = dot(QKm.astype(dt), do, at_b) + dot(Kd.astype(dt), dSd, ab)
+        dDd = dD.astype(dt)
+        dQKm = jnp.where(low, dot(do, Dd, ab_t), 0.0)
+        dQd = dot(do, S0d, ab_t)
+        dKd = dot(Dd, dSd, ab_t)
+        dW = -dot(dDd, S0d, ab_t)
+        e_glast = jnp.exp(jnp.broadcast_to(glast, (S0.shape[0], 1)))     # (dk, 1)
+        ds_ref[...] = (dot(Qd.astype(dt), do, at_b) + e_glast * dS - dot(W.astype(dt), dDd, at_b))
+        # through U = Tm Ub, W = Tm Wb and Tm = (I + A)^-1
+        dT = _dot3(dD, Ub, ab_t) + _dot3(dW, Wb, ab_t)
+        dUb = _dot3(Tm, dD, at_b)
+        dWb = _dot3(Tm, dW, at_b)
+        dA = -jnp.where(strict, _dot3(_dot3(Tm, dT, at_b), Tm, ab_t), 0.0)
+        dKK = dA * beta * M
+        dM = dA * beta * kk + dQKm * QKraw
+        dQKraw = (dQKm * M).astype(dt)
+        dKKd = dKK.astype(dt)
+        dk_ref[0, sl, :] = (dot(dKKd, k, ab) + dot(dKKd, k, at_b) + dot(dQKraw, q, at_b)
+                            + e_last * dKd + beta * eG * dWb).astype(dk_ref.dtype)
+        dq_ref[0, sl, :] = (dot(dQKraw, k, ab) + eG * dQd).astype(dq_ref.dtype)
+        dv_ref[0, sl, :] = (beta * dUb).astype(dv_ref.dtype)
+        db_ref[0, sl, :] = rows(dA * M * kk) + rows(dWb * eG * kf) + rows(dUb * v.astype(f32))
+        dMM = dM * M
+        dgc_ref[0, sl, :] = rows(dMM) + rows(dQd * Qd) - rows(dKd * Kd) + rows(dWb * Wb)
+        # the chunk's last log-decay also scales the carried state and every key of the update
+        d_last = total(dKd * Kd) + total(dS * (e_glast * S0))
+        dgr_ref[0, c:c + 1, :] = jnp.where(last_col, jnp.broadcast_to(d_last, (1, C)), 0.0) - jnp.sum(
+            dMM, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("Hk", "Hv", "C", "TB"))
+def _gdn_bwd(do, q, k, v, Gc, Gr, beta, states, Hk: int, Hv: int, C: int, TB: int):
+    """Gradients a value head: dq, dk (B*Hv, T, dk) (to be summed over the
+    heads that share a key head), dv, dbeta (B*Hv, T, 1), and the gradient in
+    the within-chunk cumulative log-decay as a column (B*Hv, T, 1) and as
+    rows (B*Hv, T/C, C), to be added."""
+    BH, T, dv = v.shape
+    dk = q.shape[-1]
+    nb = T // TB
+    own, in_specs = _gdn_specs(Hk, Hv, TB, C, dk, dv, lambda i: nb - 1 - i)
+    col_spec = pl.BlockSpec((1, TB, 1), own)
+    f32 = jnp.float32
+    return pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, C=C, NC=TB // C),
+        name="gdn_chunk_bwd",
+        grid=(BH, nb),
+        in_specs=in_specs + [pl.BlockSpec((1, TB, dv), own),
+                             pl.BlockSpec((1, TB // C, dk, dv), lambda b, i: (b, nb - 1 - i, 0, 0))],
+        out_specs=[pl.BlockSpec((1, TB, dk), own), pl.BlockSpec((1, TB, dk), own), pl.BlockSpec((1, TB, dv), own),
+                   col_spec, col_spec, pl.BlockSpec((1, TB // C, C), own)],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, dk), q.dtype), jax.ShapeDtypeStruct((BH, T, dk), k.dtype),
+                   jax.ShapeDtypeStruct((BH, T, dv), v.dtype), jax.ShapeDtypeStruct((BH, T, 1), f32),
+                   jax.ShapeDtypeStruct((BH, T, 1), f32), jax.ShapeDtypeStruct((BH, T // C, C), f32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), f32)],
+        interpret=_interpret(),
+        **_seq_params(),
+    )(q, k, v, Gc, Gr, beta, do, states)
+
+
+def _gdn_block_tokens(T: int, C: int) -> int:
+    """Tokens a grid step: whole chunks, and the chunk rows of ``Gr`` a whole
+    sublane tile (8) or the whole array."""
+    for tb in (_GDN_BLOCK_TOKENS, T):
+        if T % tb == 0 and tb % C == 0 and ((tb // C) % 8 == 0 or tb == T):
+            return tb
+    return 0
+
+
+def _gdn_supported(q_shape, v_shape, dtype, chunk) -> bool:
+    T, dk = q_shape[-2:]
+    dv = v_shape[-1]
+    if str(dtype) not in ("bfloat16", "float32") or T % chunk or _gdn_block_tokens(T, chunk) == 0:
+        return False
+    if not _interpret() and (dk % 128 or dv % 128 or chunk % 8):
+        return False   # Mosaic wants lane-dense heads
+    return True
+
+
+def _gdn_dispatchable(q, k, v, chunk) -> bool:
+    if not _enabled() or not _gdn_supported(q.shape, v.shape, v.dtype, int(chunk)):
+        return False
+    mesh = _mesh_var.get()
+    # no sharded dispatch yet: the XLA decomposition partitions as plain products
+    return not ((mesh is not None and mesh.devices.size > 1) or any(_concrete_multi_device(x) for x in (q, k, v)))
+
+
+def _gdn_operands(q, k, v, g, beta, C: int):
+    """The kernels' flat-batch operands: heads folded into the batch, the
+    log-decay summed within each chunk (as a column and as rows)."""
+    B, Hk, T, dk = q.shape
+    Hv, dv = v.shape[1], v.shape[3]
+    G = jnp.cumsum(g.astype(jnp.float32).reshape(B * Hv, T // C, C), axis=-1)
+    return (q.reshape(B * Hk, T, dk), k.reshape(B * Hk, T, dk), v.reshape(B * Hv, T, dv),
+            G.reshape(B * Hv, T, 1), G, beta.astype(jnp.float32).reshape(B * Hv, T, 1))
+
+
+def gdn_chunk(q, k, v, g, beta, chunk=GDN_CHUNK):
+    """The chunked gated delta rule through ``gdn_chunk_fwd``, or None where
+    the shapes do not qualify (the XLA decomposition runs then)."""
+    if not _gdn_dispatchable(q, k, v, chunk):
+        return None
+    stats["gdn"] = stats.get("gdn", 0) + 1
+    C, T = int(chunk), q.shape[2]
+    o = _gdn_fwd(*_gdn_operands(q, k, v, g, beta, C), q.shape[1], v.shape[1], C, _gdn_block_tokens(T, C))
+    return o.reshape(v.shape)
+
+
+def gdn_chunk_backward(do, q, k, v, g, beta, chunk=GDN_CHUNK):
+    """Its backward pass: ``gdn_chunk_fwd`` again for the state before every
+    chunk, then ``gdn_chunk_bwd`` from the last block to the first; or None."""
+    if not _gdn_dispatchable(q, k, v, chunk):
+        return None
+    stats["gdn"] = stats.get("gdn", 0) + 1
+    B, Hk, T, dk = q.shape
+    Hv, dv = v.shape[1], v.shape[3]
+    C, rep = int(chunk), Hv // Hk
+    TB = _gdn_block_tokens(T, C)
+    ops = _gdn_operands(q, k, v, g, beta, C)
+    _, states = _gdn_fwd(*ops, Hk, Hv, C, TB, emit_states=True)
+    dq, dk_, dv_, db, dgc, dgr = _gdn_bwd(do.reshape(B * Hv, T, dv).astype(v.dtype), *ops, states, Hk, Hv, C, TB)
+    # q and k a key head: the heads that read it summed; the log-decay's gradient: back through the cumsum
+    per_key = lambda d: d.astype(jnp.float32).reshape(B, Hk, rep, T, dk).sum(axis=2)  # noqa: E731
+    dG = dgc.reshape(B * Hv, T // C, C) + dgr
+    dg = jnp.flip(jnp.cumsum(jnp.flip(dG, -1), axis=-1), -1).reshape(B, Hv, T)
+    return (per_key(dq).astype(q.dtype), per_key(dk_).astype(k.dtype), dv_.reshape(v.shape),
+            dg.astype(g.dtype), db.reshape(B, Hv, T).astype(beta.dtype))
+
+
+def _gdn_full(q, k, v, g, beta):
+    res = gdn_chunk(q, k, v, g, beta)
+    if res is None:
+        from thunder_tpu.executors.jaxex import _gdn_chunked
+
+        return _gdn_chunked(q, k, v, g, beta, GDN_CHUNK)
+    return res
+
+
+_gdn_op = ex.register_operator("pallas_gdn_chunk", like=prim_lookup[PrimIDs.GDN_CHUNK], fn=_gdn_full)
+
+
+def _gdn_checker(q, k, v, g, beta):
+    from thunder_tpu.core import dtypes as _dt
+
+    return _enabled() and _gdn_supported(tuple(q.shape), tuple(v.shape), _dt.to_jax_dtype(v.dtype), GDN_CHUNK)
+
+
+ex.register_implementation(PrimIDs.GDN_CHUNK, _gdn_op, checker=_gdn_checker)
+
+
+def _gdn_backward_full(do, q, k, v, g, beta):
+    res = gdn_chunk_backward(do, q, k, v, g, beta)
+    if res is None:
+        from thunder_tpu.executors.jaxex import _gdn_chunked_backward
+
+        return _gdn_chunked_backward(do, q, k, v, g, beta, GDN_CHUNK)
+    return res
+
+
+_gdn_bwd_op = ex.register_operator(
+    "pallas_gdn_chunk_backward", like=prim_lookup[PrimIDs.GDN_CHUNK_BACKWARD], fn=_gdn_backward_full)
+ex.register_implementation(PrimIDs.GDN_CHUNK_BACKWARD, _gdn_bwd_op,
+                           checker=lambda do, *a: _gdn_checker(*a))
+
+
+# ---------------------------------------------------------------------------
+# Grouped matrix products over rows sorted by group: ``moe_grouped_mm`` and
+# ``moe_grouped_mm_dw``.  The plan (``jaxex.moe_plan``) pads every group to
+# whole row tiles, so a grid step is one tile against one group's weights,
+# chosen by the scalar-prefetched ``tile_group``.  Tiles past ``tiles_used``
+# point at the last used tile's rows and the last group's weights, so nothing
+# is fetched for them; they write zeros.  The time follows the tiles used.
+# ---------------------------------------------------------------------------
+
+
+def _gmm_kernel(tg_ref, used_ref, x_ref, w_ref, o_ref, *, transpose_w):
+    t = pl.program_id(0)
+
+    @pl.when(t < used_ref[0])
+    def _compute():
+        dims = (((1,), (1,)), ((), ())) if transpose_w else (((1,), (0,)), ((), ()))
+        o_ref[...] = jax.lax.dot_general(x_ref[...], w_ref[0], dims,
+                                         preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    @pl.when(t >= used_ref[0])
+    def _pad():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _gmm_col_block(K: int, N: int, itemsize: int) -> int:
+    """Columns of the weight block: all of them where a group's whole matrix
+    is at most 2 MiB (one grid step a tile, the weights fetched once a group),
+    else the widest 128-multiple under that."""
+    if K * N * itemsize <= 2 << 20:
+        return N
+    for b in (1024, 512, 256, 128):
+        if N % b == 0 and K * b * itemsize <= 2 << 20:
+            return b
+    return 128 if N % 128 == 0 else N
+
+
+@functools.partial(jax.jit, static_argnames=("transpose_w",))
+def _moe_grouped_mm(x, w, tile_group, tiles_used, transpose_w: bool = False):
+    R, K = x.shape
+    nt = tile_group.shape[0]
+    TM = R // nt
+    N = w.shape[1] if transpose_w else w.shape[2]
+    TN = _gmm_col_block(K, N, x.dtype.itemsize)
+    last = lambda used: jnp.maximum(used[0] - 1, 0)  # noqa: E731
+    if transpose_w:
+        w_spec = pl.BlockSpec((1, TN, K), lambda t, j, tg, used: (tg[t], j, 0))
+    else:
+        w_spec = pl.BlockSpec((1, K, TN), lambda t, j, tg, used: (tg[t], 0, j))
+    params = {}
+    if not _interpret():
+        params["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_w=transpose_w),
+        name="moe_grouped_mm",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nt, N // TN),
+            in_specs=[pl.BlockSpec((TM, K), lambda t, j, tg, used: (jnp.minimum(t, last(used)), 0)), w_spec],
+            out_specs=pl.BlockSpec((TM, TN), lambda t, j, tg, used: (t, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
+        interpret=_interpret(),
+        **params,
+    )(tile_group, tiles_used, x, w)
+
+
+def _gmm_dw_kernel(tg_ref, used_ref, x_ref, dy_ref, zero_ref, o_ref, acc_ref):
+    del zero_ref   # aliased to the output: groups without rows keep its zeros
+    t = pl.program_id(0)
+    nt = pl.num_programs(0)
+    used = used_ref[0]
+    g = tg_ref[t]
+    first = jnp.logical_or(t == 0, tg_ref[jnp.maximum(t - 1, 0)] != g)
+    last = jnp.logical_or(t == used - 1, tg_ref[jnp.minimum(t + 1, nt - 1)] != g)
+
+    @pl.when(t < used)
+    def _compute():
+        @pl.when(first)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += jax.lax.dot_general(x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+                                            preferred_element_type=jnp.float32)
+
+        @pl.when(last)
+        def _():
+            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("groups",))
+def _moe_grouped_mm_dw(x, dy, tile_group, tiles_used, groups: int):
+    R, K = x.shape
+    N = dy.shape[1]
+    nt = tile_group.shape[0]
+    TM = R // nt
+    tile = lambda t, tg, used: (jnp.minimum(t, jnp.maximum(used[0] - 1, 0)), 0)  # noqa: E731
+    params = {}
+    if not _interpret():
+        params["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    return pl.pallas_call(
+        _gmm_dw_kernel,
+        name="moe_grouped_mm_dw",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nt,),
+            in_specs=[pl.BlockSpec((TM, K), tile), pl.BlockSpec((TM, N), tile),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, K, N), lambda t, tg, used: (tg[t], 0, 0)),
+            scratch_shapes=[pltpu.VMEM((K, N), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((groups, K, N), x.dtype),
+        input_output_aliases={4: 0},
+        interpret=_interpret(),
+        **params,
+    )(tile_group, tiles_used, x, dy, jnp.zeros((groups, K, N), x.dtype))
+
+
+def _gmm_supported(R: int, nt: int, K: int, N: int, dtype) -> bool:
+    if str(dtype) not in ("bfloat16", "float32") or nt <= 0 or R % nt:
+        return False
+    if not _interpret() and ((R // nt) % 16 or K % 128 or N % 128):
+        return False
+    return True
+
+
+def _gmm_dispatchable(*operands) -> bool:
+    mesh = _mesh_var.get()
+    return not ((mesh is not None and mesh.devices.size > 1) or any(_concrete_multi_device(x) for x in operands))
+
+
+def grouped_mm(x, w, tile_group, tiles_used, transpose_w=False):
+    """``moe_grouped_mm``, or None where the shapes do not qualify."""
+    N = w.shape[1] if transpose_w else w.shape[2]
+    if not (_enabled() and _gmm_supported(x.shape[0], tile_group.shape[0], x.shape[1], N, x.dtype)
+            and _gmm_dispatchable(x, w)):
+        return None
+    stats["grouped_mm"] = stats.get("grouped_mm", 0) + 1
+    return _moe_grouped_mm(x, w, tile_group, tiles_used, transpose_w=bool(transpose_w))
+
+
+def grouped_mm_dw(x, dy, tile_group, tiles_used, groups):
+    """``moe_grouped_mm_dw``, or None where the shapes do not qualify."""
+    if not (_enabled() and _gmm_supported(x.shape[0], tile_group.shape[0], x.shape[1], dy.shape[1], x.dtype)
+            and _gmm_dispatchable(x, dy)):
+        return None
+    stats["grouped_mm"] = stats.get("grouped_mm", 0) + 1
+    return _moe_grouped_mm_dw(x, dy.astype(x.dtype), tile_group, tiles_used, groups=int(groups))
+
+
 # install the fast paths so XLA fusion regions and TrainStep trace evaluation
 # reach the same kernels
 from thunder_tpu.executors import jaxex as _jaxex
@@ -1882,3 +2375,7 @@ from thunder_tpu.executors import jaxex as _jaxex
 _jaxex._sdpa_fast_path = flash_sdpa
 _jaxex._sdpa_bwd_fast_path = flash_sdpa_backward
 _jaxex._ce_fast_path = flash_cross_entropy
+_jaxex._gdn_fast_path = gdn_chunk
+_jaxex._gdn_bwd_fast_path = gdn_chunk_backward
+_jaxex._grouped_mm_fast_path = grouped_mm
+_jaxex._grouped_mm_dw_fast_path = grouped_mm_dw

@@ -331,6 +331,17 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
     return out, ck, cv
 
 
+def require_servable(cfg: Config) -> None:
+    """The one refusal of a config this module's forward cannot run (a
+    ``linear_attention`` layer or an expert share): such a model trains
+    through ``tt.jit`` / ``make_train_step``; serving it is not built yet."""
+    why = getattr(cfg, "training_only", None)
+    if why:
+        raise NotImplementedError(
+            f"config {cfg.name!r} cannot be served by models.generate / tt.serve: {why}. "
+            "It trains through tt.jit / distributed.make_train_step (llama.gpt_loss).")
+
+
 def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *,
                        quantized=False, lora=None, lora_scaling=1.0):
     """Forward of new tokens ``idx`` (B, T) at global positions [pos, pos+T)
@@ -421,6 +432,7 @@ def generate(
     shards its KV-group dim, and XLA partitions the decode program from the
     input placements (per-head attention local, one reduce at the output
     projection)."""
+    require_servable(cfg)
     prompt = jnp.asarray(prompt)
     B, T_prompt = prompt.shape
     assert max_new_tokens >= 0, max_new_tokens

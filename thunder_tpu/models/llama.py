@@ -93,6 +93,32 @@ class Config:
     # GPT-2 uses the tanh gelu approximation ("gelu_new"); torch/our default
     # is the exact erf form
     gelu_approximate: str = "none"
+    # A kind per layer (hf ``layer_types``): "full_attention" (softmax
+    # attention, the default for every layer) or "linear_attention" (a gated
+    # delta-rule mixer, below).  None = all full attention
+    layer_types: tuple | None = None
+    # Gated softmax attention: RMSNorm of q and k a head, and an output gate
+    # (``wq`` projects to q and gate; o <- o * sigmoid(gate) before ``wo``)
+    qk_norm: bool = False
+    attn_output_gate: bool = False
+    # RMSNorm weights stored zero-centred: the scale is ``1 + w``
+    norm_zero_centered: bool = False
+    # The gated delta-rule mixer of "linear_attention" layers: key/value head
+    # counts and sizes, and the causal depthwise conv's width
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    # mlp_class "SparseMoE": softmax router over all ``n_expert``, top
+    # ``n_expert_per_token`` renormalised, SwiGLU experts of width
+    # ``intermediate_size``.  The layer holds experts ``[expert_first,
+    # expert_first + expert_held)`` (None = all of them) and computes their
+    # part of the result; a shared expert of width ``shared_expert_size``
+    # sits behind a sigmoid gate (0 = none)
+    expert_first: int = 0
+    expert_held: int | None = None
+    shared_expert_size: int = 0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling_llama3, dict):
@@ -112,6 +138,21 @@ class Config:
             assert self.n_expert > 0, "LLaMAMoE requires n_expert > 0"
             assert 0 < self.n_expert_per_token <= self.n_expert
             assert not self.bias, "bias is not supported for the MoE MLP"
+        if self.mlp_class == "SparseMoE":
+            assert 0 < self.n_expert_per_token <= self.n_expert, "SparseMoE requires n_expert > 0"
+            if self.expert_held is None:
+                self.expert_held = self.n_expert - self.expert_first
+            assert 0 <= self.expert_first and 0 < self.expert_held <= self.n_expert - self.expert_first
+            assert not self.bias, "bias is not supported for the MoE MLP"
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            assert len(self.layer_types) == self.n_layer, "layer_types needs one kind a layer"
+            assert set(self.layer_types) <= {"full_attention", "linear_attention"}, self.layer_types
+            if "linear_attention" in self.layer_types:
+                nk, nv = self.linear_num_key_heads, self.linear_num_value_heads
+                assert nk > 0 and nv % nk == 0 and self.linear_key_head_dim > 0 and self.linear_value_head_dim > 0, (
+                    "linear_attention layers need linear_num_key_heads/_value_heads and their head dims")
+                assert not self.bias and not self.parallel_residual, "linear_attention: sequential, bias-free blocks only"
         if self.bias:
             assert self.norm_class == "LayerNorm", "bias implies LayerNorm (GPT-2/NeoX style)"
         assert not (self.lm_head_bias and self.fused_head_ce), (
@@ -123,6 +164,19 @@ class Config:
     @property
     def rope_n_elem(self) -> int:
         return int(self.rotary_percentage * self.head_size)
+
+    def layer_kind(self, i: int) -> str:
+        return "full_attention" if self.layer_types is None else self.layer_types[i]
+
+    @property
+    def training_only(self) -> str | None:
+        """Why ``models.generate`` and ``tt.serve`` cannot run this config, or
+        None: the server holds no recurrent state and no expert share yet."""
+        if self.layer_types is not None and "linear_attention" in self.layer_types:
+            return "it has linear_attention layers (recurrent state; the KV cache cannot hold it)"
+        if self.mlp_class == "SparseMoE":
+            return "its mlp_class is SparseMoE (an expert share; the serving forward has no such layer)"
+        return None
 
     @classmethod
     def from_name(cls, name: str, **overrides) -> "Config":
@@ -241,7 +295,7 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
     def dense(key, fan_in, fan_out):
         return (jax.random.normal(key, (fan_out, fan_in), dtype=jnp.float32) * std).astype(dtype)
 
-    n_keys = 3 + config.n_layer * (5 + 3 * max(1, config.n_expert))
+    n_keys = 3 + config.n_layer * (5 + 3 * max(1, config.n_expert) + (8 if config.mlp_class == "SparseMoE" else 0))
     keys = iter(jax.random.split(key, n_keys))
 
     def zeros(n):
@@ -263,23 +317,41 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
         params["wpe"] = (jax.random.normal(next(keys), (config.block_size, config.n_embd),
                                            dtype=jnp.float32) * std).astype(dtype)
 
-    for _ in range(config.n_layer):
-        block = {
-            "norm_1": jnp.ones((config.n_embd,), dtype=dtype),
-            "attn": {
-                "wq": dense(next(keys), config.n_embd, nh * hs),
+    # a zero-centred norm weight starts at 0 (scale 1 + w = 1)
+    norm_init = jnp.zeros if config.norm_zero_centered else jnp.ones
+    if config.norm_zero_centered:
+        params["ln_f"] = norm_init((config.n_embd,), dtype=dtype)
+
+    for i in range(config.n_layer):
+        block = {"norm_1": norm_init((config.n_embd,), dtype=dtype)}
+        if config.layer_kind(i) == "linear_attention":
+            nk, nv = config.linear_num_key_heads, config.linear_num_value_heads
+            dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
+            block["gdn"] = {
+                "in_proj_qkvz": dense(next(keys), config.n_embd, 2 * nk * dk + 2 * nv * dv),
+                "in_proj_ba": dense(next(keys), config.n_embd, 2 * nv),
+                "conv_w": dense(next(keys), config.linear_conv_kernel, 2 * nk * dk + nv * dv),
+                "A_log": jnp.log(jax.random.uniform(next(keys), (nv,), jnp.float32, 1e-3, 16.0)).astype(dtype),
+                "dt_bias": jnp.ones((nv,), dtype=dtype),
+                "norm": jnp.ones((dv,), dtype=dtype),
+                "out_proj": dense(next(keys), nv * dv, config.n_embd),
+            }
+        else:
+            block["attn"] = {
+                "wq": dense(next(keys), config.n_embd, nh * hs * (2 if config.attn_output_gate else 1)),
                 "wk": dense(next(keys), config.n_embd, ng * hs),
                 "wv": dense(next(keys), config.n_embd, ng * hs),
                 "wo": dense(next(keys), nh * hs, config.n_embd),
-            },
-        }
+            }
+            if config.qk_norm:
+                block["attn"].update(q_norm=norm_init((hs,), dtype=dtype), k_norm=norm_init((hs,), dtype=dtype))
         if config.bias:
             block["norm_1_b"] = zeros(config.n_embd)
             block["attn"].update(
                 bq=zeros(nh * hs), bk=zeros(ng * hs), bv=zeros(ng * hs), bo=zeros(config.n_embd)
             )
         if not config.shared_attention_norm:
-            block["norm_2"] = jnp.ones((config.n_embd,), dtype=dtype)
+            block["norm_2"] = norm_init((config.n_embd,), dtype=dtype)
             if config.bias:
                 block["norm_2_b"] = zeros(config.n_embd)
         if config.mlp_class == "LLaMAMoE":
@@ -298,6 +370,23 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 "fc_2": stacked(config.n_embd, config.intermediate_size),
                 "proj": stacked(config.intermediate_size, config.n_embd),
             }
+        elif config.mlp_class == "SparseMoE":
+            # held experts stacked and flattened to two dims, "x @ W" layout:
+            # fc_1/fc_2 (held * C, I), proj (held * I, C); the grouped
+            # products view them as (held, C, I) and (held, I, C)
+            Eh, I, C = config.expert_held, config.intermediate_size, config.n_embd
+            block["mlp"] = {
+                "gate": dense(next(keys), C, config.n_expert),
+                "fc_1": dense(next(keys), I, Eh * C),
+                "fc_2": dense(next(keys), I, Eh * C),
+                "proj": dense(next(keys), C, Eh * I),
+            }
+            if config.shared_expert_size:
+                Is = config.shared_expert_size
+                block["mlp"]["shared"] = {
+                    "fc_1": dense(next(keys), C, Is), "fc_2": dense(next(keys), C, Is),
+                    "proj": dense(next(keys), Is, C), "gate": dense(next(keys), C, 1),
+                }
         elif config.mlp_class in ("LLaMAMLP", "GemmaMLP"):
             block["mlp"] = {
                 "fc_1": dense(next(keys), config.n_embd, config.intermediate_size),
@@ -384,9 +473,15 @@ def apply_rope(x, cos, sin):
     return roped.to(x.dtype)
 
 
+def _rms_weight(weight, config: Config):
+    """The scale a stored RMSNorm weight stands for: ``1 + w`` in float32
+    where the config stores them zero-centred."""
+    return ltorch.to(weight, ltorch.float32) + 1.0 if config.norm_zero_centered else weight
+
+
 def _norm(x, weight, config: Config, bias=None):
     if config.norm_class == "RMSNorm":
-        return ltorch.rms_norm(x, (config.n_embd,), weight, eps=config.norm_eps)
+        return ltorch.rms_norm(x, (config.n_embd,), _rms_weight(weight, config), eps=config.norm_eps)
     return ltorch.layer_norm(x, (config.n_embd,), weight, bias, eps=config.norm_eps)
 
 
@@ -411,8 +506,18 @@ def attention(ap, x, cos, sin, config: Config):
     k = proj("wk", x, ap["wk"], ap.get("bk"))  # (B, T, ng*hs)
     v = proj("wv", x, ap["wv"], ap.get("bv"))
 
-    q = q.reshape(B, T, nh, hs).permute(0, 2, 1, 3)  # (B, nh, T, hs)
-    k = k.reshape(B, T, ng, hs).permute(0, 2, 1, 3)  # (B, ng, T, hs)
+    gate = None
+    if config.attn_output_gate:
+        # wq projects to (q, gate) a head
+        qg = q.reshape(B, T, nh, 2 * hs)
+        q, gate = qg[..., :hs], qg[..., hs:].reshape(B, T, nh * hs)
+    q = q.reshape(B, T, nh, hs)
+    k = k.reshape(B, T, ng, hs)
+    if config.qk_norm:
+        q = ltorch.rms_norm(q, (hs,), _rms_weight(ap["q_norm"], config), eps=config.norm_eps)
+        k = ltorch.rms_norm(k, (hs,), _rms_weight(ap["k_norm"], config), eps=config.norm_eps)
+    q = q.permute(0, 2, 1, 3)  # (B, nh, T, hs)
+    k = k.permute(0, 2, 1, 3)  # (B, ng, T, hs)
     v = v.reshape(B, T, ng, hs).permute(0, 2, 1, 3)
 
     n_elem = config.rope_n_elem
@@ -432,7 +537,78 @@ def attention(ap, x, cos, sin, config: Config):
         q, k, v, is_causal=True, sliding_window=config.sliding_window
     )  # (B, nh, T, hs)
     y = y.permute(0, 2, 1, 3).reshape(B, T, nh * hs)
+    if gate is not None:
+        y = y * ltorch.sigmoid(gate)
     return proj("wo", y, ap["wo"], ap.get("bo"))
+
+
+def _l2norm(x, eps: float = 1e-6):
+    """x / sqrt(sum(x^2) + eps) over the head, in float32, back in x's dtype."""
+    xf = ltorch.to(x, ltorch.float32)
+    return ltorch.to(xf * ltorch.rsqrt(ltorch.sum(xf * xf, -1, True) + eps), x.dtype)
+
+
+def gated_delta_net(gp, x, config: Config):
+    """The "linear_attention" mixer (Gated DeltaNet, hf Qwen3NextGatedDeltaNet):
+    q, k, v pass a causal depthwise conv and SiLU; each value head keeps a
+    ``(dk, dv)`` float32 state that decays by ``exp(g_t)`` and takes the
+    rank-1 delta-rule update ``k_t ((v_t - S^T k_t) beta_t)^T``; the read-out
+    ``S^T q_t`` is RMS-normed a head, gated by ``silu(z)`` and projected.
+    The recurrence is ``ltorch.gated_delta_rule``: the chunked algorithm."""
+    B, T, _ = x.shape
+    nk, nv = config.linear_num_key_heads, config.linear_num_value_heads
+    dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
+    qkvz = ltorch.linear(x, gp["in_proj_qkvz"])
+    ba = ltorch.linear(x, gp["in_proj_ba"])
+    n_qkv = 2 * nk * dk + nv * dv
+    qkv, z = qkvz[..., :n_qkv], qkvz[..., n_qkv:]
+    # causal depthwise conv over time (torch conv1d, groups = channels, K - 1
+    # zeros on the left, no bias): tap j of a channel weighs the token K - 1 - j back
+    qkv = ltorch.silu(ltorch.causal_conv1d(qkv, gp["conv_w"]))
+    q = qkv[..., : nk * dk].reshape(B, T, nk, dk)
+    k = qkv[..., nk * dk: 2 * nk * dk].reshape(B, T, nk, dk)
+    v = qkv[..., 2 * nk * dk:].reshape(B, T, nv, dv)
+    q = _l2norm(q) * (dk ** -0.5)
+    k = _l2norm(k)
+    beta = ltorch.sigmoid(ltorch.to(ba[..., :nv], ltorch.float32))
+    a = ltorch.to(ba[..., nv:], ltorch.float32)
+    g = -ltorch.exp(ltorch.to(gp["A_log"], ltorch.float32)) * ltorch.softplus(
+        a + ltorch.to(gp["dt_bias"], ltorch.float32))
+    o = ltorch.gated_delta_rule(
+        q.permute(0, 2, 1, 3), k.permute(0, 2, 1, 3), v.permute(0, 2, 1, 3),
+        g.permute(0, 2, 1), beta.permute(0, 2, 1))  # (B, nv, T, dv)
+    o = ltorch.rms_norm(o.permute(0, 2, 1, 3), (dv,), gp["norm"], eps=config.norm_eps)
+    o = o * ltorch.silu(z.reshape(B, T, nv, dv))
+    return ltorch.linear(o.reshape(B, T, nv * dv), gp["out_proj"])
+
+
+def sparse_moe_mlp(mp, x, config: Config):
+    """An expert layer told which experts it holds (``expert_first``,
+    ``expert_held`` of ``n_expert``): the router is a float32 softmax over
+    *all* experts, the top ``n_expert_per_token`` renormalised; the held
+    experts' part of the result comes from ``ltorch.moe_expert_share``
+    (one fused prim: sorted rows, grouped products, no capacity and no
+    dropped token), what
+    the other experts would add is left out; the shared expert, behind its
+    sigmoid gate, is added once.  Under an ``ep`` axis the shares' results
+    would be summed across chips; on one chip there is no exchange."""
+    B, T, C = x.shape
+    I, Eh = config.intermediate_size, config.expert_held
+    x2 = x.reshape(B * T, C)
+    # router logits leave the product in float32 (no rounding to bfloat16 before
+    # the top-k: a rounded logit flips choices that a float32 router keeps)
+    probs = ltorch.softmax(ltorch.linear(ltorch.to(x2, ltorch.float32), ltorch.to(mp["gate"], ltorch.float32)), -1)
+    top_w, top_idx = ltorch.topk(probs, config.n_expert_per_token, -1)
+    top_w = top_w / ltorch.sum(top_w, -1, True)
+    y = ltorch.moe_expert_share(
+        x2, top_idx, top_w, mp["fc_1"].reshape(Eh, C, I), mp["fc_2"].reshape(Eh, C, I),
+        mp["proj"].reshape(Eh, I, C), config.expert_first, config.n_expert)
+    if config.shared_expert_size:
+        sp = mp["shared"]
+        shared = ltorch.linear(ltorch.silu(ltorch.linear(x2, sp["fc_1"])) * ltorch.linear(x2, sp["fc_2"]),
+                               sp["proj"])
+        y = y + ltorch.sigmoid(ltorch.linear(x2, sp["gate"])) * shared
+    return y.reshape(B, T, C)
 
 
 def moe_mlp(mp, x, config: Config):
@@ -465,6 +641,8 @@ def moe_mlp(mp, x, config: Config):
 def mlp(mp, x, config: Config):
     if config.mlp_class == "LLaMAMoE":
         return moe_mlp(mp, x, config)
+    if config.mlp_class == "SparseMoE":
+        return sparse_moe_mlp(mp, x, config)
     if config.mlp_class == "LLaMAMLP":
         return ltorch.linear(
             ltorch.silu(ltorch.linear(x, mp["fc_1"], mp.get("fc_1_b")))
@@ -485,9 +663,12 @@ def mlp(mp, x, config: Config):
     )
 
 
-def block_forward(bp, x, cos, sin, config: Config):
+def block_forward(bp, x, cos, sin, config: Config, kind: str = "full_attention"):
     n1 = _norm(x, bp["norm_1"], config, bp.get("norm_1_b"))
-    h = attention(bp["attn"], n1, cos, sin, config)
+    if kind == "linear_attention":
+        h = gated_delta_net(bp["gdn"], n1, config)
+    else:
+        h = attention(bp["attn"], n1, cos, sin, config)
     if config.parallel_residual:
         n2 = n1 if config.shared_attention_norm else _norm(x, bp["norm_2"], config, bp.get("norm_2_b"))
         return x + h + mlp(bp["mlp"], n2, config)
@@ -503,8 +684,8 @@ def gpt_hidden(params, idx, cos, sin, config: Config):
     if config.learned_pos_embedding:
         T = idx.shape[1]
         x = x + params["wpe"][:T]
-    for bp in params["blocks"]:
-        x = block_forward(bp, x, cos, sin, config)
+    for i, bp in enumerate(params["blocks"]):
+        x = block_forward(bp, x, cos, sin, config, config.layer_kind(i))
     return _norm(x, params["ln_f"], config, params.get("ln_f_b"))
 
 

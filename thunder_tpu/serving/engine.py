@@ -298,6 +298,9 @@ class ServingEngine:
         constraints=None,
         goodput=None,
     ):
+        from thunder_tpu.models.generate import require_servable
+
+        require_servable(cfg)
         if shardings is not None and mesh is None:
             raise ValueError("shardings= requires mesh= (param placement needs a mesh)")
         from thunder_tpu.core import compile_cache

@@ -1248,6 +1248,42 @@ def fused_linear_cross_entropy(h, weight, target, ignore_index=-100, reduction="
     return clang.true_divide(total, clang.maximum(n_valid, 1.0))
 
 
+@torchsymbol()
+def gated_delta_rule(q, k, v, g, beta):
+    """Gated delta rule over a sequence (thunder extension; the linear
+    attention of hybrid decoders): q, k ``(B, Hk, T, dk)``, v ``(B, Hv, T,
+    dv)``, float32 log-decay ``g`` and write strength ``beta`` ``(B, Hv,
+    T)`` -> ``(B, Hv, T, dv)``.  One fused prim: executors run the chunked
+    algorithm (Pallas ``gdn_chunk_fwd``, or its XLA decomposition)."""
+    g = clang.maybe_convert_to_dtype(g, dtypes.float32)
+    beta = clang.maybe_convert_to_dtype(beta, dtypes.float32)
+    return prims.gdn_chunk(q, k, v, g, beta)
+
+
+@torchsymbol()
+def causal_conv1d(x, weight):
+    """Causal depthwise conv over time (thunder extension): ``x (B, T, C)``,
+    ``weight (C, K)`` -> ``(B, T, C)``; torch ``conv1d(x^T, weight[:, None],
+    groups=C, padding=K - 1)[..., :T]`` without the transposes.  One cheap
+    prim, made again in the backward pass rather than saved."""
+    return prims.causal_conv1d(x, weight)
+
+
+@torchsymbol()
+def moe_expert_share(x, top_idx, top_w, fc_1, fc_2, proj, first, total):
+    """The part of a mixture-of-experts layer that the experts held here
+    give (thunder extension).  ``x (N, C)``; ``top_idx``, ``top_w (N, k)``:
+    each token's experts among *all* of them and their weights; ``fc_1``,
+    ``fc_2 (held, C, I)`` and ``proj (held, I, C)``: the SwiGLU weights of
+    experts ``[first, first + held)`` of ``total``.  One fused prim: the assignments that
+    fall on those are sorted by expert into whole row tiles (no capacity,
+    nothing dropped), multiplied as groups (``moe_grouped_mm*``), weighted,
+    and summed back a token; what the other experts would add is left out."""
+    return prims.moe_expert_share(x, clang.maybe_convert_to_dtype(top_idx, dtypes.int32),
+                                  clang.maybe_convert_to_dtype(top_w, dtypes.float32),
+                                  fc_1, fc_2, proj, int(first), int(total))
+
+
 @torchsymbol(_tfn("nn", "functional", "mse_loss"))
 def mse_loss(a, b, reduction="mean"):
     d = clang.sub(a, b)

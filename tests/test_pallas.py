@@ -182,6 +182,32 @@ def test_flash_causal_cross_lengths(interpret_kernels, Tq, Tk):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4, err_msg=n)
 
 
+@pytest.mark.parametrize("rep", [1, 2])
+def test_flash_windowed_rows_past_the_keys_band(monkeypatch, interpret_kernels, rep):
+    """Tq 512 over Tk 128 under a window of 128, blocks of 128: rows from 255
+    on keep no pair (the reference reads NaN there, forward and backward).
+    The kernels give such a row one fully masked block: a finite output, no
+    gradient from it, and every other row as the reference on those alone."""
+    monkeypatch.setenv("THUNDER_TPU_FLASH_BQ", "128")
+    monkeypatch.setenv("THUNDER_TPU_FLASH_BK", "128")
+    Tq, Tk, window, hs, live = 512, 128, 128, 128, 255
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, g = (jax.random.normal(k, (1, 2 * rep, Tq, hs)) for k in ks[:2])
+    k, v = (jax.random.normal(k, (1, 2, Tk, hs)) for k in ks[2:])
+    scale = 1.0 / np.sqrt(hs)
+    out, lse = pallasex.flash_sdpa(q, k, v, None, True, scale, window)
+    dq, dk, dv = pallasex.flash_sdpa_backward(g, q, k, v, out, lse, None, True, scale, window)
+    assert pallasex.flash_schedule == {"grid_steps": 4, "running_blocks": 2, "edge_blocks_a_full_row": 1}
+    assert np.isnan(np.asarray(_sdpa_reference(q, k, v, None, True, scale, window)[0][..., live:, :])).all()
+    assert np.isfinite(np.asarray(out)).all() and not np.asarray(dq[..., live:, :]).any()
+    top = lambda x: x[..., :live, :]   # noqa: E731
+    oref, lref = _sdpa_reference(top(q), k, v, None, True, scale, window)
+    np.testing.assert_allclose(np.asarray(top(out)), np.asarray(oref), atol=2e-5, rtol=2e-5)
+    want = _sdpa_backward_reference(top(g), top(q), k, v, oref, lref, None, True, scale, window)
+    for a, b, n in zip((top(dq), dk, dv), want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4, err_msg=n)
+
+
 def test_sharded_flash_matches_reference(interpret_kernels):
     # shard_map dispatch over batch/head axes: numerics identical to the
     # single-device kernel and the jnp reference
@@ -454,3 +480,167 @@ def test_sharded_flash_with_padding_mask(interpret_kernels):
     dqr, dkr, dvr = _sdpa_backward_reference(g, q, k, v, out, lse, mask, False, scale)
     for a, b, n in ((dq, dqr, "dq"), (dk, dkr, "dk"), (dv, dvr, "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4, err_msg=n)
+
+
+#
+# The schedule of running blocks (what a grid step of the flash kernels
+# visits, fetches and masks), alone and through the kernels
+#
+
+SCHEDULES = [
+    # Tq, Tk, BQ, BK, causal, window
+    (1024, 1024, 128, 128, True, 384),      # interior and edge blocks on both sides
+    (1024, 1024, 128, 128, True, None),     # the triangle
+    (512, 512, 128, 128, False, None),      # the rectangle
+    (256, 512, 128, 128, True, None),       # Tq < Tk: columns nothing attends
+    (512, 256, 128, 128, True, None),       # Tq > Tk, top-left alignment
+    (512, 256, 128, 128, True, 64),         # ... and rows that attend nothing
+    (1024, 1024, 256, 128, True, 100),      # window < BK, BQ != BK
+    (1024, 1024, 128, 256, True, 5000),     # window >= T
+    (1024, 1024, 512, 512, True, 1),        # the diagonal alone
+    (384, 384, 128, 128, True, 128),        # window == block
+    (8192, 8192, 512, 512, True, 4096),     # the Mistral train cell
+    (8192, 8192, 512, 512, True, None),     # the hybrid cell's attention layer
+]
+
+
+def _kept_by_brute_force(Tq, Tk, BQ, BK, causal, window):
+    r, c = np.arange(Tq)[:, None], np.arange(Tk)[None, :]
+    keep = np.ones((Tq, Tk), bool)
+    if causal:
+        keep &= r >= c
+    if window is not None:
+        keep &= c > r - window
+    blocks = keep.reshape(Tq // BQ, BQ, Tk // BK, BK)
+    return blocks.any(axis=(1, 3)), blocks.all(axis=(1, 3))
+
+
+@pytest.mark.parametrize("by_column", [False, True], ids=["rows", "columns"])
+@pytest.mark.parametrize("case", SCHEDULES, ids=lambda c: "-".join(map(str, c)))
+def test_flash_schedule_lists_the_blocks_with_a_kept_pair(case, by_column):
+    some, whole = _kept_by_brute_force(*case)
+    qi, kj, head, flag = pallasex._flash_schedule(*case, by_column=by_column)
+    line, cross = (kj, qi) if by_column else (qi, kj)
+    listed = np.zeros_like(some)
+    listed[qi, kj] = True
+    assert len(set(zip(qi, kj))) == len(qi)                      # nothing twice
+    assert (listed >= some).all()                                # every block with a kept pair
+    # beyond them: one fully masked block for each line that attends nothing
+    empty = ~some.any(axis=0 if by_column else 1)
+    extra = listed & ~some
+    assert (extra.sum(axis=0 if by_column else 1) == empty).all()
+    # edge: the block holds a masked pair (and, but for those extras, a kept one)
+    assert (((flag & pallasex._EDGE) != 0) == ~whole[qi, kj]).all()
+    # walked line by line, first/last bracketing each line's entries
+    assert (np.diff(line) >= 0).all() and (np.diff(cross)[np.diff(line) == 0] > 0).all()
+    starts = np.r_[True, np.diff(line) != 0]
+    ends = np.r_[np.diff(line) != 0, True]
+    assert (((flag & pallasex._FIRST) != 0) == starts).all()
+    assert (((flag & pallasex._LAST) != 0) == ends).all()
+    assert set(line) == set(range(some.shape[1 if by_column else 0])) and not head.any()
+
+
+@pytest.mark.parametrize("rep", [4, 8])
+@pytest.mark.parametrize("case", SCHEDULES[:5], ids=lambda c: "-".join(map(str, c)))
+def test_flash_schedule_walks_a_column_once_a_head_of_the_group(case, rep):
+    qi1, kj1, _, flag1 = pallasex._flash_schedule(*case, by_column=True)
+    qi, kj, head, flag = pallasex._flash_schedule(*case, by_column=True, rep=rep)
+    assert len(qi) == rep * len(qi1)
+    at = 0
+    for col in range(case[1] // case[3]):
+        rows, f1 = qi1[kj1 == col], flag1[kj1 == col]
+        n = len(rows)
+        for r in range(rep):
+            sl = slice(at, at + n)
+            assert (qi[sl] == rows).all() and (kj[sl] == col).all() and (head[sl] == r).all()
+            assert ((flag[sl] & pallasex._EDGE) == (f1 & pallasex._EDGE)).all()
+            at += n
+        column = flag[at - rep * n:at]
+        # one accumulator a column: opened by the first head's first block,
+        # closed by the last head's last
+        assert [i for i, f in enumerate(column) if f & pallasex._FIRST] == [0]
+        assert [i for i, f in enumerate(column) if f & pallasex._LAST] == [rep * n - 1]
+
+
+def _trace_flash(BH, BG, T, hs, H, G, window):
+    q = jax.ShapeDtypeStruct((BH, T, hs), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((BG, T, hs), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)
+    scale = 1.0 / np.sqrt(hs)
+    jax.eval_shape(lambda q, k, v: pallasex._flash_fwd.__wrapped__(
+        q, k, v, None, True, scale, H, G, None, 1, window), q, k, k)
+    fwd = dict(pallasex.flash_schedule)
+    pallasex.flash_schedule.clear()
+    jax.eval_shape(lambda g, q, k, v, o, l: pallasex._flash_bwd.__wrapped__(
+        g, q, k, v, o, l, None, True, scale, H, G, None, 1, window), q, q, k, k, q, lse)
+    return fwd, dict(pallasex.flash_schedule)
+
+
+@pytest.mark.parametrize("cell,blocks,steps,edges_a_full_row", [
+    ("mistral", 512, 108, 2), ("hybrid", 512, 136, 1), ("mistral", None, 30, 2), ("hybrid", None, 36, 1)])
+def test_flash_schedule_counter_at_the_train_cells_shapes(monkeypatch, cell, blocks, steps, edges_a_full_row):
+    """``pallasex.flash_schedule``, filled at trace time.  In blocks of 512 the
+    Mistral cell's call has 108 grid steps a head (the rectangle has 256),
+    every one a running block, 2 edge blocks in a full row (the diagonal and
+    the window's far edge); in the blocks ``_flash_blocks`` derives (1024) 30
+    of 64, again 2 edges a full row."""
+    for which in "QK":
+        if blocks:
+            monkeypatch.setenv(f"THUNDER_TPU_FLASH_B{which}", str(blocks))
+        else:
+            monkeypatch.delenv(f"THUNDER_TPU_FLASH_B{which}", raising=False)
+    shape = {"mistral": (32, 8, 8192, 128, 32, 8, 4096), "hybrid": (32, 4, 8192, 256, 16, 2, None)}[cell]
+    fwd, bwd = _trace_flash(*shape)
+    assert fwd == bwd == {"grid_steps": steps, "running_blocks": steps, "edge_blocks_a_full_row": edges_a_full_row}
+    # ``stats`` stays flat counters: its readers sum and subtract them
+    assert all(type(v) is int for v in pallasex.stats.values())
+
+
+@pytest.mark.parametrize("hs,dtype,mq,window,want", [
+    (128, jnp.bfloat16, 1, 4096, 1024), (256, jnp.bfloat16, 1, None, 1024), (128, jnp.float32, 1, None, 1024),
+    (384, jnp.bfloat16, 1, None, 512), (256, jnp.float32, 1, None, 512),   # a block of 1024 rows outgrows VMEM
+    (128, jnp.bfloat16, 8192, None, 512),                                   # so does a (1024, 1024) mask block
+    (128, jnp.bfloat16, 1, 1024, 512), (128, jnp.bfloat16, 1, 2048, 1024)])  # a band under two wide blocks
+def test_flash_blocks_follow_head_dtype_mask_and_window(monkeypatch, hs, dtype, mq, window, want):
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
+    q = jax.ShapeDtypeStruct((4, 8192, hs), dtype)
+    assert pallasex._flash_blocks(q, q, mq, window) == (want, want)
+    short = jax.ShapeDtypeStruct((4, 1536, hs), dtype)     # 1536 = 3 * 512
+    assert pallasex._flash_blocks(q, short, mq, window) == (want, 512)
+
+
+def _band_case(rep, hs, mask_kind, T=640, window=384):
+    ks = jax.random.split(jax.random.PRNGKey(rep * 1000 + hs), 6)
+    B, G = 1, 1
+    q, g = (jax.random.normal(k, (B, G * rep, T, hs), jnp.float32) for k in ks[:2])
+    k, v = (jax.random.normal(k, (B, G, T, hs), jnp.float32) for k in ks[2:4])
+    mask = None
+    if mask_kind == "padding":      # (B, 1, 1, Tk): a row, the same for every query
+        mask = jnp.where(jax.random.uniform(ks[4], (B, 1, 1, T)) < 0.1, -1e9, 0.0).at[..., :8].set(0.0)
+    elif mask_kind == "bias":       # (1, H, Tq, Tk): a block of its own every grid step
+        mask = 0.5 * jax.random.normal(ks[5], (1, G * rep, T, T), jnp.float32)
+    return q, k, v, g, mask
+
+
+@pytest.mark.parametrize("mask_kind", [None, "padding", "bias"])
+@pytest.mark.parametrize("hs", [128, 256])
+@pytest.mark.parametrize("rep", [1, 4, 8])
+def test_flash_band_with_interior_and_edge_blocks_matches_reference(monkeypatch, interpret_kernels,
+                                                                    rep, hs, mask_kind):
+    """T 640 in blocks of 128 under a window of 384: a full row (the last two)
+    is an edge block, two interior blocks and the diagonal; forward and all
+    three gradients against the float32 reference."""
+    monkeypatch.setenv("THUNDER_TPU_FLASH_BQ", "128")
+    monkeypatch.setenv("THUNDER_TPU_FLASH_BK", "128")
+    q, k, v, g, mask = _band_case(rep, hs, mask_kind)
+    scale, window = 1.0 / np.sqrt(hs), 384
+    out, lse = pallasex.flash_sdpa(q, k, v, mask, True, scale, window)
+    assert pallasex.flash_schedule == {"grid_steps": 5 * 4 - 6, "running_blocks": 14, "edge_blocks_a_full_row": 2}
+    oref, lref = _sdpa_reference(q, k, v, mask, True, scale, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(oref), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lref), atol=2e-5, rtol=2e-5)
+    got = pallasex.flash_sdpa_backward(g, q, k, v, out, lse, mask, True, scale, window)
+    want = _sdpa_backward_reference(g, q, k, v, out, lse, mask, True, scale, window)
+    for a, b, n in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4, err_msg=n)

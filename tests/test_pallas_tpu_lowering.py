@@ -16,7 +16,13 @@ head size 128 and block size 16 as served.  A variant left unrepaired would
 be ``xfail(strict=True)`` with the compiler's message; none is.  The decode
 walk is also compiled at the benchmark cell's shapes (plain, windowed, int8,
 fp8), at head size 256 and block size 8, and at head sizes 64 and 96, where
-Mosaic refuses the walk's copies and the token goes block by block.
+Mosaic refuses the walk's copies and the token goes block by block.  The flash
+kernels are compiled with ``lse`` as lane-dense rows ``(BH, 1, T)``, with a
+padding mask and a mask block a grid step (the transposes of
+``_flash_bwd_dkv``), and at the train cells' own kinds: a window that spans
+several blocks of 1024, head 256 with 8 query heads a group, and the widest
+tiles blocks of 1024 are derived for (float32 at head 128; a padding mask's
+row over the whole rectangle).
 """
 from __future__ import annotations
 
@@ -118,15 +124,37 @@ def _cases(nh: int, ng: int) -> dict:
     # so no cached interpreted trace can stand in for the compiled one
     Tq, scale = 256, 1.0 / np.sqrt(HS)
     qf, kf = ((B * nh, Tq, HS), BF), ((B * ng, Tq, HS), BF)
-    lse = ((B * nh, Tq, 1), F32)
-    for name, window in (("", None), ("/window", 128)):
+    lse = ((B * nh, 1, Tq), F32)
+    shared, full = ((1, 1, Tq), F32), ((B * nh, Tq, Tq), F32)
+
+    def flash(name, window=None, mask=None, mode=None, mq=1, causal=True, heads=(nh, ng), ops=(qf, kf, lse)):
+        qs, ks, ls = ops
+        ms = [] if mask is None else [mask]
         cases[f"flash_sdpa_fwd{name}"] = (
-            lambda q_, k, v, w=window: px._flash_fwd.__wrapped__(
-                q_, k, v, None, True, scale, nh, ng, None, 1, w), [qf, kf, kf])
+            lambda q_, k, v, *m: px._flash_fwd.__wrapped__(
+                q_, k, v, *(m or (None,)), causal, scale, *heads, mode, mq, window), [qs, ks, ks, *ms])
         cases[f"flash_sdpa_bwd{name}"] = (
-            lambda g, q_, k, v, o, l, w=window: px._flash_bwd.__wrapped__(
-                g, q_, k, v, o, l, None, True, scale, nh, ng, None, 1, w),
-            [qf, qf, kf, kf, qf, lse])
+            lambda g, q_, k, v, o, l, *m: px._flash_bwd.__wrapped__(
+                g, q_, k, v, o, l, *(m or (None,)), causal, scale, *heads, mode, mq, window),
+            [qs, qs, ks, ks, qs, ls, *ms])
+
+    flash("")
+    flash("/window", window=128)
+    flash("/padding_mask", mask=shared, mode="shared", mq=1, causal=False)
+    flash("/full_mask", mask=full, mode="full", mq=Tq)
+    # the benchmark cells' own kinds: a window that spans several blocks, and
+    # head 256 with 8 query heads a group and no window (T cut, blocks as there)
+    Tc = 2048
+    flash("/band_of_blocks", window=1024,
+          ops=(((nh, Tc, HS), BF), ((ng, Tc, HS), BF), ((nh, 1, Tc), F32)))
+    flash("/head256_rep8", heads=(8, 1),
+          ops=(((8, Tc, 256), BF), ((1, Tc, 256), BF), ((8, 1, Tc), F32)))
+    # the widest tiles ``_flash_blocks`` derives blocks of 1024 for: float32 at
+    # head 128, and every block of the rectangle with a padding mask's row
+    flash("/float32_blocks_of_1024",
+          ops=(((nh, Tc, HS), F32), ((ng, Tc, HS), F32), ((nh, 1, Tc), F32)))
+    flash("/padding_mask_blocks_of_1024", mask=((1, 1, Tc), F32), mode="shared", mq=1, causal=False,
+          ops=(((nh, Tc, HS), BF), ((ng, Tc, HS), BF), ((nh, 1, Tc), F32)))
     cases["flash_cross_entropy"] = (
         px._flash_ce.__wrapped__, [((256, 2048), BF), ((256,), I32)])
     # the chunked gated delta rule (2 key heads, 4 value heads, two blocks of

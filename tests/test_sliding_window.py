@@ -1,10 +1,13 @@
 """Sliding-window (Mistral-style) attention through the whole stack.
 
 The band is a *structural* parameter of the fused SDPA prim — not an O(T²)
-additive mask — so the flash kernels skip blocks outside [i-window, i] and
-long-T attention cost scales O(T·window).  (Beyond-ref: the reference's
-sdpaex checker matrix, sdpaex.py:240-474, has no sliding-window case; HF
-Mistral there pays for a materialized banded mask.)
+additive mask — so the flash kernels never visit a block outside
+[i-window, i]: their grid is the list of blocks with a kept pair
+(``pallasex._flash_schedule``, by scalar prefetch), a block outside the band
+is neither a grid step nor a copy, only the blocks on the band's two edges
+build a mask, and long-T attention cost scales O(T·window).  (Beyond-ref: the
+reference's sdpaex checker matrix, sdpaex.py:240-474, has no sliding-window
+case; HF Mistral there pays for a materialized banded mask.)
 """
 from __future__ import annotations
 
@@ -113,6 +116,21 @@ class TestSlidingWindowSDPA:
             del os.environ["THUNDER_TPU_PALLAS_INTERPRET"]
         ref = _ref_banded_sdpa(q, k, v, 100)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window,steps,edges_full_row", [
+    (None, 136, 1), (8192, 136, 1), (4096, 108, 2), (1024, 45, 2), (512, 31, 2), (100, 31, 2), (1, 16, 1)])
+def test_grid_steps_follow_the_band(window, steps, edges_full_row):
+    """T 8192 in blocks of 512: the flash grid has as many steps a head as the
+    band has blocks, not the rectangle's 256."""
+    from thunder_tpu.executors import pallasex
+
+    qi, kj, _, flag = pallasex._flash_schedule(8192, 8192, 512, 512, True, window)
+    assert len(qi) == steps
+    full_row = (qi == 15)
+    assert int(((flag[full_row] & pallasex._EDGE) != 0).sum()) == edges_full_row
+    # every listed block lies in the band
+    assert ((qi - kj) >= 0).all() and (window is None or ((qi - kj - 1) * 512 + 1 < window).all())
 
 
 class TestMistralModel:

@@ -93,8 +93,9 @@ def _enabled() -> bool:
     return _pallas_available()
 
 
-def _block(T: int, which: str = "") -> int:
-    """Largest supported block size dividing ``T``.
+def _block(T: int, which: str = "", wide: bool = False) -> int:
+    """Largest supported block size dividing ``T``: from 1024 down where the
+    call is ``wide`` (``_flash_blocks``), else from 512.
 
     ``THUNDER_TPU_FLASH_BQ`` / ``THUNDER_TPU_FLASH_BK`` override the choice
     for the q/kv axis (tuning knob; ignored when it does not divide T).
@@ -110,10 +111,25 @@ def _block(T: int, which: str = "") -> int:
                 b = 0
             if b > 0 and T % b == 0:
                 return b
-    for b in (512, 256, 128):
+    for b in (1024, 512, 256, 128)[0 if wide else 1:]:
         if T % b == 0:
             return b
     return 0
+
+
+def _flash_blocks(q, k, mq: int, window: int | None) -> tuple[int, int]:
+    """``(BQ, BK)`` of a flash call on q ``(BH, Tq, hs)`` and k ``(BG, Tk, hs)``.
+
+    Blocks of 1024 where they fit VMEM (a row of ``hs`` elements within 512
+    bytes, no mask block a query row: what Mosaic compiles for a v5e) and the
+    causal band is at least two of them wide; else 512 and down.  Measured on
+    one v5e at T 8192 (PERF.md, PR 29): a grid step costs its 0.35 us whatever
+    it holds and the forward kernel rescales its accumulator once a step, so
+    the wider block wins (16.9 -> 14.0 ms at head 128 under a window of 4096,
+    32.7 -> 30.8 ms at head 256 without) until the band's edges waste more
+    than that (a tie under a window of 1024)."""
+    wide = q.shape[2] * q.dtype.itemsize <= 512 and mq == 1 and (window is None or window >= 2048)
+    return _block(q.shape[1], "Q", wide), _block(k.shape[1], "K", wide)
 
 
 def _pad128(hs: int) -> int:
@@ -206,49 +222,156 @@ def _supported(q_shape, k_shape, v_shape, dtype, causal, mask_shape=None, window
 
 
 #
-# Forward kernel
+# The flash kernels.
+#
+# One mechanism for all three: a grid step is a block of the score matrix
+# that holds at least one kept pair.  ``_flash_schedule`` lists those blocks
+# from ``(Tq, Tk, BQ, BK, causal, window)`` at trace time; the kernels take
+# the list by scalar prefetch, their grid is ``(heads, blocks listed)`` and
+# every index map reads its block's ``(i, j)`` from the list.  A block outside
+# the causal band is no step and no copy.  A non-causal call, ``Tq != Tk``
+# (top-left alignment) and a band narrower than a block are the same
+# function: the list is the full rectangle, the shifted triangle, the
+# diagonal.
+#
+# Each entry carries three flags.  ``first``/``last`` bracket the blocks that
+# share an accumulator (a row of blocks for ``_flash_fwd``/``_flash_bwd_dq``;
+# for ``_flash_bwd_dkv`` a column, walked once for each of the group's ``rep``
+# query heads, so dk and dv leave the kernel summed over the group in
+# float32).  ``edge`` marks a block that also holds a masked pair: the block
+# on the diagonal and the one on the window's far edge.  Only those build the
+# iotas, compares and select; every other block holds kept pairs alone.  A
+# user's additive mask is added in every block.
+#
+# ``lse`` and the backward pass's ``delta`` are lane-dense rows, ``(BH, 1,
+# Tq)`` float32 in blocks of ``(1, 1, BQ)``: a ``(BQ, 1)`` block of a
+# ``(BH, Tq, 1)`` array is a column of 128-lane tiles, 128 times its bytes in
+# HBM and in every copy.  ``_flash_bwd_dkv`` computes the scores transposed
+# (``k q^T``, a ``(BK, BQ)`` tile) so the rows broadcast as they arrive and
+# ``dv += p^T g``, ``dk += ds^T q`` are plain products; ``_flash_fwd`` and
+# ``_flash_bwd_dq`` transpose a column to a row, a row to a column, once a row
+# of blocks.
+#
+# Blocks are ``_flash_blocks``' (1024 where they fit VMEM and the band).  On
+# one v5e (PERF.md, PR 29) the two backward kernels then run at 88-94% of the
+# MXU's peak over the pairs their blocks hold, the forward kernel at 67%.
 #
 
+_FIRST, _LAST, _EDGE = 1, 2, 4
 
-def _fwd_kernel(*refs, BQ, BK, causal, scale, has_mask, window):
+
+def _band_blocks(Tq: int, Tk: int, BQ: int, BK: int, causal: bool, window: int | None):
+    """Boolean grids over the blocks of the score matrix: ``run`` where block
+    ``(i, j)`` (rows ``i*BQ ..``, columns ``j*BK ..``) holds a kept pair,
+    ``whole`` where it holds nothing else.  A pair is kept where ``0 <= row -
+    col`` (causal) ``< window``."""
+    i = np.arange(Tq // BQ)[:, None]
+    j = np.arange(Tk // BK)[None, :]
+    if not causal:
+        run = np.ones((Tq // BQ, Tk // BK), bool)
+        return run, run
+    # row - col over a block spans [lo, hi], every value taken
+    lo, hi = i * BQ - (j * BK + BK - 1), i * BQ + BQ - 1 - j * BK
+    top = np.inf if window is None else window - 1
+    return (hi >= 0) & (lo <= top), (lo >= 0) & (hi <= top)
+
+
+@functools.lru_cache(maxsize=None)
+def _flash_schedule(Tq: int, Tk: int, BQ: int, BK: int, causal: bool, window: int | None,
+                    by_column: bool = False, rep: int = 1):
+    """The blocks a flash kernel visits, in order: int32 arrays ``(qi, kj,
+    head, flag)``, one entry a grid step.
+
+    Listed are the blocks with a kept pair, row by row (``by_column``: column
+    by column, each column ``rep`` times with ``head`` counting up).  ``flag``
+    has ``_FIRST``/``_LAST`` on a row's (column's) first and last entry and
+    ``_EDGE`` where the block also holds a masked pair.  A row (column)
+    without any kept pair still gets its nearest block, fully masked, so its
+    output is written."""
+    run, whole = _band_blocks(Tq, Tk, BQ, BK, causal, window)
+    if by_column:
+        run, whole = run.T, whole.T
+    along, across = (BK, BQ) if by_column else (BQ, BK)
+    entries = []
+    for line in range(run.shape[0]):
+        cross = np.flatnonzero(run[line])
+        if not len(cross):
+            cross = [min(line * along // across, run.shape[1] - 1)]
+        walk = [(c, r) for r in range(rep) for c in cross]
+        for n, (c, r) in enumerate(walk):
+            flag = (n == 0) * _FIRST | (n == len(walk) - 1) * _LAST | (not whole[line, c]) * _EDGE
+            entries.append((c, line, r, flag) if by_column else (line, c, r, flag))
+    out = tuple(np.array(a, dtype=np.int32) for a in zip(*entries))
+    for a in out:   # cached: every caller gets these very arrays
+        a.setflags(write=False)
+    return out
+
+
+# what the last flash call built visits a head (trace time): grid steps, the
+# blocks among them with a kept pair, the edge blocks of a full row.  Not
+# among ``stats``: readers sum and subtract those counters.
+flash_schedule: dict[str, int] = {}
+
+
+def _note_schedule(Tq, Tk, BQ, BK, causal, window):
+    qi, _, _, flag = _flash_schedule(Tq, Tk, BQ, BK, causal, window)
+    run, _ = _band_blocks(Tq, Tk, BQ, BK, causal, window)
+    edges = np.bincount(qi[(flag & _EDGE) != 0], minlength=Tq // BQ)
+    flash_schedule.update(
+        grid_steps=len(qi),
+        running_blocks=int(run.sum()),
+        edge_blocks_a_full_row=int(edges[np.argmax(np.bincount(qi))]),
+    )
+
+
+def _keep(row0, col0, shape, rows_axis: int, window):
+    """The kept pairs of an edge block whose first row and column are
+    ``row0``/``col0``; ``rows_axis`` is the axis the rows run along."""
+    row = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, rows_axis)
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rows_axis)
+    keep = row >= col
+    if window is not None:
+        keep = jnp.logical_and(keep, col > row - window)
+    return keep
+
+
+def _on_edge(flag, causal: bool, body):
+    """Run ``body(masked)``: with the band's mask on an edge block, without
+    it on every other."""
+    if not causal:
+        body(False)
+        return
+    edge = (flag & _EDGE) != 0
+    pl.when(edge)(lambda: body(True))
+    pl.when(jnp.logical_not(edge))(lambda: body(False))
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+
+
+def _fwd_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window):
     if has_mask:
         q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_s, l_s, acc_s = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s = refs
         mask_ref = None
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+    t = pl.program_id(1)
+    i, j, flag = qi_ref[t], kj_ref[t], flag_ref[t]
 
-    @pl.when(j == 0)
+    @pl.when((flag & _FIRST) != 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, _MASK_VALUE)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    # causal: skip KV blocks strictly above the diagonal; sliding window
-    # additionally skips blocks entirely below the band (col <= row - window)
-    run = (j * BK <= i * BQ + BQ - 1) if causal else True
-    if window is not None:
-        run = jnp.logical_and(run, j * BK + BK - 1 > i * BQ - window)
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
+    def block(masked: bool):
         v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (BQ, BK)
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT, preferred_element_type=jnp.float32) * scale  # (BQ, BK)
         if has_mask:
             s = s + mask_ref[0].astype(jnp.float32)  # (1|BQ, BK) broadcasts
-        if causal:
-            row = i * BQ + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 0)
-            col = j * BK + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
-            keep = row >= col
-            if window is not None:
-                keep = jnp.logical_and(keep, col > row - window)
-            s = jnp.where(keep, s, _MASK_VALUE)
+        if masked:
+            s = jnp.where(_keep(i * BQ, j * BK, (BQ, BK), 0, window), s, _MASK_VALUE)
         m_prev = m_s[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -256,92 +379,90 @@ def _fwd_kernel(*refs, BQ, BK, causal, scale, has_mask, window):
         l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         m_s[...] = m_new
         acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(j == nk - 1)
+    _on_edge(flag, causal, block)
+
+    @pl.when((flag & _LAST) != 0)
     def _finalize():
         o_ref[0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
-        lse_ref[0] = m_s[...] + jnp.log(l_s[...])
+        lse_ref[0] = (m_s[...] + jnp.log(l_s[...])).T
 
 
-def _kv_index(H: int, G: int):
-    """K/V BlockSpec head gather: flat q index ``b*H + h`` reads KV group
-    ``h // (H//G)`` — GQA without expanding K/V in HBM (rep=1 ⇒ identity)."""
+def _flash_specs(H: int, G: int, mode: str | None, mq: int, BQ: int, BK: int, hs: int, by_group: bool = False):
+    """BlockSpecs of the flash kernels' operands: a q-shaped block, a
+    k-shaped one, a row of ``lse``/``delta``, the additive mask's.  Index maps
+    take ``(b, t, qi, kj, ...)``: the flat query head and the schedule's entry
+    (``by_group``, for ``_flash_bwd_dkv``: the flat KV group, with the entry's
+    ``head`` prefetched third)."""
     rep = H // G
+    if by_group:
+        qh = lambda b, t, p: b * rep + p[2][t]   # noqa: E731
+        kh = lambda b, t, p: b                   # noqa: E731
+    else:
+        qh = lambda b, t, p: b                   # noqa: E731
+        # flat q index b*H + h reads KV group h // rep: GQA without
+        # expanding K/V in HBM (rep = 1: the identity)
+        kh = lambda b, t, p: (b // H) * G + (b % H) // rep   # noqa: E731
 
-    def index(b, i, j):
-        return ((b // H) * G + (b % H) // rep, j, 0)
+    def mask_index(b, t, *p):
+        f = qh(b, t, p)
+        m = {"shared": 0, "batch": f // H, "head": f % H, "full": f}[mode]
+        return (m, p[0][t] if mq > 1 else 0, p[1][t])
 
-    return index
-
-
-def _mask_index(mode: str, H: int, mq_blocked: bool):
-    """Mask BlockSpec index map for the canonical (M, mq, Tk) layout."""
-
-    def index(b, i, j):
-        m = {"shared": 0, "batch": b // H, "head": b % H, "full": b}[mode]
-        return (m, i if mq_blocked else 0, j)
-
-    return index
+    return (
+        pl.BlockSpec((1, BQ, hs), lambda b, t, *p: (qh(b, t, p), p[0][t], 0)),
+        pl.BlockSpec((1, BK, hs), lambda b, t, *p: (kh(b, t, p), p[1][t], 0)),
+        pl.BlockSpec((1, 1, BQ), lambda b, t, *p: (qh(b, t, p), 0, p[0][t])),
+        pl.BlockSpec((1, BQ if mq > 1 else 1, BK), mask_index),
+    )
 
 
-def _mask_spec(mode: str, mq: int, H: int, BQ: int, BK: int):
-    blk = (1, BQ if mq > 1 else 1, BK)
-    return pl.BlockSpec(blk, _mask_index(mode, H, mq > 1))
+def _flash_params():
+    if _interpret():
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))}
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "H", "G", "mode", "mq", "window"))
 def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: str | None, mq: int,
                window: int | None = None):
     """q (BH, Tq, hs), k/v (BG, Tk, hs), mask (M, mq, Tk) f32 or None
-    -> out (BH, Tq, hs), lse (BH, Tq, 1) f32.  ``H``/``G`` are the per-shard
+    -> out (BH, Tq, hs), lse (BH, 1, Tq) f32.  ``H``/``G`` are the per-shard
     q/KV head counts (the flat-batch gather key for GQA); ``mode``/``mq``
     classify the mask layout (see _canon_mask)."""
     BH, Tq, hs = q.shape
     Tk = k.shape[1]
-    BQ, BK = _block(Tq, "Q"), _block(Tk, "K")
-    grid = (BH, Tq // BQ, Tk // BK)
+    BQ, BK = _flash_blocks(q, k, mq, window)
     has_mask = mask is not None
-
-    kernel = functools.partial(
-        _fwd_kernel, BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window
-    )
-    params = {}
-    if not _interpret():
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    in_specs = [
-        pl.BlockSpec((1, BQ, hs), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, BK, hs), _kv_index(H, G)),
-        pl.BlockSpec((1, BK, hs), _kv_index(H, G)),
-    ]
-    operands = [q, k, v]
+    qi, kj, _, flag = _flash_schedule(Tq, Tk, BQ, BK, causal, window)
+    _note_schedule(Tq, Tk, BQ, BK, causal, window)
+    q_spec, kv_spec, row_spec, mask_spec = _flash_specs(H, G, mode, mq, BQ, BK, hs)
+    in_specs, operands = [q_spec, kv_spec, kv_spec], [q, k, v]
     if has_mask:
-        in_specs.append(_mask_spec(mode, mq, H, BQ, BK))
+        in_specs.append(mask_spec)
         operands.append(mask)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window),
         name="_flash_fwd",
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, BQ, hs), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, BQ, 1), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(BH, len(qi)),
+            in_specs=in_specs,
+            out_specs=[q_spec, row_spec],
+            scratch_shapes=[
+                pltpu.VMEM((BQ, 1), jnp.float32),
+                pltpu.VMEM((BQ, 1), jnp.float32),
+                pltpu.VMEM((BQ, hs), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, hs), q.dtype),
-            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((BQ, 1), jnp.float32),
-            pltpu.VMEM((BQ, 1), jnp.float32),
-            pltpu.VMEM((BQ, hs), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Tq), jnp.float32),
         ],
         interpret=_interpret(),
-        **params,
-    )(*operands)
+        **_flash_params(),
+    )(qi, kj, flag, *operands)
 
 
 #
@@ -349,111 +470,74 @@ def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: 
 #
 
 
-def _bwd_dq_kernel(*refs, BQ, BK, causal, scale, has_mask, window):
+def _bwd_dq_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window):
     if has_mask:
-        g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, mask_ref, dq_ref, dq_s = refs
+        g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, mask_ref, dq_ref, dq_s, lse_s, delta_s = refs
     else:
-        g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, dq_ref, dq_s = refs
+        g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, dq_ref, dq_s, lse_s, delta_s = refs
         mask_ref = None
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+    t = pl.program_id(1)
+    i, j, flag = qi_ref[t], kj_ref[t], flag_ref[t]
 
-    @pl.when(j == 0)
+    @pl.when((flag & _FIRST) != 0)
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
+        lse_s[...] = lse_ref[0].T
+        delta_s[...] = delta_ref[0].T
 
-    run = (j * BK <= i * BQ + BQ - 1) if causal else True
-    if window is not None:
-        run = jnp.logical_and(run, j * BK + BK - 1 > i * BQ - window)
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]
+    def block(masked: bool):
         k = k_ref[0]
-        v = v_ref[0]
         g = g_ref[0]
-        lse = lse_ref[0]  # (BQ, 1) f32
-        delta = delta_ref[0]  # (BQ, 1) f32
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
+        s = jax.lax.dot_general(q_ref[0], k, _NT, preferred_element_type=jnp.float32) * scale
         if has_mask:
             s = s + mask_ref[0].astype(jnp.float32)
-        p = jnp.exp(s - lse)  # (BQ, BK)
-        if causal:
-            row = i * BQ + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 0)
-            col = j * BK + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
-            keep = row >= col
-            if window is not None:
-                keep = jnp.logical_and(keep, col > row - window)
-            p = jnp.where(keep, p, 0.0)
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (BQ, BK)
-        ds = p * (dp - delta)
-        dq_s[...] += scale * jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        p = jnp.exp(s - lse_s[...])  # (BQ, BK)
+        if masked:
+            p = jnp.where(_keep(i * BQ, j * BK, (BQ, BK), 0, window), p, 0.0)
+        dp = jax.lax.dot_general(g, v_ref[0], _NT, preferred_element_type=jnp.float32)  # (BQ, BK)
+        ds = p * (dp - delta_s[...])
+        dq_s[...] += scale * jax.lax.dot_general(ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(j == nk - 1)
+    _on_edge(flag, causal, block)
+
+    @pl.when((flag & _LAST) != 0)
     def _finalize():
         dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, BQ, BK, causal, scale, has_mask, window):
+def _bwd_dkv_kernel(qi_ref, kj_ref, head_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window):
+    del head_ref   # the index maps' alone
     if has_mask:
         g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, mask_ref, dk_ref, dv_ref, dk_s, dv_s = refs
     else:
         g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s = refs
         mask_ref = None
-    jk = pl.program_id(1)
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
+    t = pl.program_id(1)
+    i, j, flag = qi_ref[t], kj_ref[t], flag_ref[t]
 
-    @pl.when(iq == 0)
+    @pl.when((flag & _FIRST) != 0)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    run = (iq * BQ + BQ - 1 >= jk * BK) if causal else True
-    if window is not None:
-        run = jnp.logical_and(run, jk * BK + BK - 1 > iq * BQ - window)
-
-    @pl.when(run)
-    def _compute():
+    def block(masked: bool):
+        # everything transposed: tiles are (BK, BQ), lse and delta rows
         q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
         g = g_ref[0]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (BQ, BK)
+        s = jax.lax.dot_general(k_ref[0], q, _NT, preferred_element_type=jnp.float32) * scale
         if has_mask:
-            s = s + mask_ref[0].astype(jnp.float32)
-        p = jnp.exp(s - lse)
-        if causal:
-            row = iq * BQ + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 0)
-            col = jk * BK + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
-            keep = row >= col
-            if window is not None:
-                keep = jnp.logical_and(keep, col > row - window)
-            p = jnp.where(keep, p, 0.0)
-        # dv += p^T @ g   (contract over q rows)
-        dv_s[...] += jax.lax.dot_general(
-            p.astype(g.dtype), g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (BQ, BK)
-        ds = p * (dp - delta)  # (BQ, BK)
-        dk_s[...] += scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            s = s + mask_ref[0].astype(jnp.float32).T  # (BK, 1|BQ) broadcasts
+        p = jnp.exp(s - lse_ref[0])
+        if masked:
+            p = jnp.where(_keep(i * BQ, j * BK, (BK, BQ), 1, window), p, 0.0)
+        dv_s[...] += jax.lax.dot_general(p.astype(g.dtype), g, _NN, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_ref[0], g, _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0])
+        dk_s[...] += scale * jax.lax.dot_general(ds.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(iq == nq - 1)
+    _on_edge(flag, causal, block)
+
+    @pl.when((flag & _LAST) != 0)
     def _finalize():
         dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
@@ -462,97 +546,70 @@ def _bwd_dkv_kernel(*refs, BQ, BK, causal, scale, has_mask, window):
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "H", "G", "mode", "mq", "window"))
 def _flash_bwd(g, q, k, v, out, lse, mask, causal: bool, scale: float, H: int, G: int, mode: str | None, mq: int,
                window: int | None = None):
-    """g/q/out (BH, Tq, hs), k/v (BG, Tk, hs), lse (BH, Tq, 1);
+    """g/q/out (BH, Tq, hs), k/v (BG, Tk, hs), lse (BH, 1, Tq);
     returns (dq (BH,...), dk, dv (BG,...)).
 
-    GQA: the kernels run over the expanded (BH) grid with K/V gathered by
-    index map; dk/dv come out per-q-head and are reduced over each group's
-    ``rep`` heads by XLA afterwards (one cheap (BG, rep) sum — the scores
-    recompute itself stays group-shared-K/V, which is the bandwidth win)."""
+    GQA: ``_flash_bwd_dq`` runs over the flat query heads with K/V gathered by
+    index map; ``_flash_bwd_dkv`` runs over the KV groups and walks each
+    column of blocks once a query head of the group, so K/V are fetched once
+    a column and dk/dv are summed in its float32 accumulators."""
     BH, Tq, hs = q.shape
     BG, Tk, _ = k.shape
-    BQ, BK = _block(Tq, "Q"), _block(Tk, "K")
-    rep = H // G
+    BQ, BK = _flash_blocks(q, k, mq, window)
     has_mask = mask is not None
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1).reshape(BH, 1, Tq)
+    _note_schedule(Tq, Tk, BQ, BK, causal, window)
+    static = dict(BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window)
+    operands = [g, q, k, v, lse, delta] + ([mask] if has_mask else [])
 
-    params = {}
-    if not _interpret():
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
+    def specs(by_group):
+        q_spec, kv_spec, row_spec, mask_spec = _flash_specs(H, G, mode, mq, BQ, BK, hs, by_group)
+        ins = [q_spec, q_spec, kv_spec, kv_spec, row_spec, row_spec] + ([mask_spec] if has_mask else [])
+        return ins, q_spec, kv_spec
 
-    dq_in_specs = [
-        pl.BlockSpec((1, BQ, hs), lambda b, i, j: (b, i, 0)),  # g
-        pl.BlockSpec((1, BQ, hs), lambda b, i, j: (b, i, 0)),  # q
-        pl.BlockSpec((1, BK, hs), _kv_index(H, G)),  # k
-        pl.BlockSpec((1, BK, hs), _kv_index(H, G)),  # v
-        pl.BlockSpec((1, BQ, 1), lambda b, i, j: (b, i, 0)),  # lse
-        pl.BlockSpec((1, BQ, 1), lambda b, i, j: (b, i, 0)),  # delta
-    ]
-    dq_operands = [g, q, k, v, lse, delta]
-    if has_mask:
-        dq_in_specs.append(_mask_spec(mode, mq, H, BQ, BK))
-        dq_operands.append(mask)
-
+    qi, kj, _, flag = _flash_schedule(Tq, Tk, BQ, BK, causal, window)
+    dq_in, dq_out, _ = specs(False)
     dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window
-        ),
+        functools.partial(_bwd_dq_kernel, **static),
         name="_flash_bwd_dq",
-        grid=(BH, Tq // BQ, Tk // BK),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, BQ, hs), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Tq, hs), q.dtype),
-        scratch_shapes=[pltpu.VMEM((BQ, hs), jnp.float32)],
-        interpret=_interpret(),
-        **params,
-    )(*dq_operands)
-
-    # the dkv grid swaps (i, j): index-map arg order is (b, j, i)
-    kv_idx = _kv_index(H, G)
-    dkv_in_specs = [
-        pl.BlockSpec((1, BQ, hs), lambda b, j, i: (b, i, 0)),  # g
-        pl.BlockSpec((1, BQ, hs), lambda b, j, i: (b, i, 0)),  # q
-        pl.BlockSpec((1, BK, hs), lambda b, j, i: kv_idx(b, i, j)),  # k
-        pl.BlockSpec((1, BK, hs), lambda b, j, i: kv_idx(b, i, j)),  # v
-        pl.BlockSpec((1, BQ, 1), lambda b, j, i: (b, i, 0)),  # lse
-        pl.BlockSpec((1, BQ, 1), lambda b, j, i: (b, i, 0)),  # delta
-    ]
-    dkv_operands = [g, q, k, v, lse, delta]
-    if has_mask:
-        midx = _mask_index(mode, H, mq > 1)
-        dkv_in_specs.append(
-            pl.BlockSpec((1, BQ if mq > 1 else 1, BK), lambda b, j, i: midx(b, i, j))
-        )
-        dkv_operands.append(mask)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(BH, len(qi)),
+            in_specs=dq_in,
+            out_specs=dq_out,
+            scratch_shapes=[
+                pltpu.VMEM((BQ, hs), jnp.float32),
+                pltpu.VMEM((BQ, 1), jnp.float32),
+                pltpu.VMEM((BQ, 1), jnp.float32),
+            ],
         ),
+        out_shape=jax.ShapeDtypeStruct((BH, Tq, hs), q.dtype),
+        interpret=_interpret(),
+        **_flash_params(),
+    )(qi, kj, flag, *operands)
+
+    sched = _flash_schedule(Tq, Tk, BQ, BK, causal, window, by_column=True, rep=H // G)
+    dkv_in, _, kv_out = specs(True)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, **static),
         name="_flash_bwd_dkv",
-        grid=(BH, Tk // BK, Tq // BQ),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, BK, hs), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, BK, hs), lambda b, j, i: (b, j, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(BG, len(sched[0])),
+            in_specs=dkv_in,
+            out_specs=[kv_out, kv_out],
+            scratch_shapes=[
+                pltpu.VMEM((BK, hs), jnp.float32),
+                pltpu.VMEM((BK, hs), jnp.float32),
+            ],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Tk, hs), k.dtype),
-            jax.ShapeDtypeStruct((BH, Tk, hs), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((BK, hs), jnp.float32),
-            pltpu.VMEM((BK, hs), jnp.float32),
+            jax.ShapeDtypeStruct((BG, Tk, hs), k.dtype),
+            jax.ShapeDtypeStruct((BG, Tk, hs), v.dtype),
         ],
         interpret=_interpret(),
-        **params,
-    )(*dkv_operands)
-    if rep > 1:
-        # flat q-head order is (b, g, r): fold rep into the group dim and sum
-        dk = dk.reshape(BG, rep, Tk, hs).astype(jnp.float32).sum(axis=1).astype(k.dtype)
-        dv = dv.reshape(BG, rep, Tk, hs).astype(jnp.float32).sum(axis=1).astype(v.dtype)
+        **_flash_params(),
+    )(*sched, *operands)
     return dq, dk, dv
 
 
@@ -623,7 +680,7 @@ def _bwd_local(g, q, k, v, out, lse, mask, causal: bool, scale: float, window: i
     r3 = lambda x, T, n: _pad_hs(x.reshape(n, T, hs), hs, hp)
     dq, dk, dv = _flash_bwd(
         r3(g, Tq, BH), r3(q, Tq, BH), r3(k, Tk, BG), r3(v, Tk, BG), r3(out, Tq, BH),
-        lse.reshape(BH, Tq, 1).astype(jnp.float32),
+        lse.reshape(BH, 1, Tq).astype(jnp.float32),
         mask3,
         bool(causal), float(scale), H, G, mode, mq,
         window=None if window is None else int(window),

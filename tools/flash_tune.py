@@ -1,42 +1,116 @@
-"""Grid-search the flash-attention kernel block sizes on the chip.
+"""Time the three flash kernels alone on the chip, at the benchmark cells' shapes.
 
-Writes one line per (BQ, BK) config: fwd ms and fwd+bwd ms at the sweep's
-headline attention shape.  Needs a TPU; exits non-zero if any geometry
-failed."""
-import sys, os
+    python3 tools/flash_tune.py [--shapes mistral,hybrid] [--blocks 512x512,512x256] [--check]
+
+For each shape and each (BQ, BK) (none given: what ``pallasex._block``
+derives), one line: ms a call of ``_flash_fwd``, ``_flash_bwd_dq`` and
+``_flash_bwd_dkv`` by name from a device trace of five forward and five
+backward calls, and of whatever else XLA runs beside them in the backward
+program (``delta``, a sum over the group's heads).  ``--check`` first compares
+out, dq, dk, dv with the float32 reference at T 2048 (compiled kernels, not
+the interpreter).  Needs a TPU; exits non-zero if a geometry failed."""
+import argparse
+import os
+import sys
+import tempfile
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import jax, jax.numpy as jnp
-import thunder_tpu as tt
-import thunder_tpu.torch as ltorch
-from bench import _best_ms, require_tpu
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-print(require_tpu("flash_tune"), flush=True)
-B, H, T, hs = 8, 32, 2048, 128
-key = jax.random.PRNGKey(0)
-k2 = lambda i: jax.random.fold_in(key, i)
-q = jax.random.normal(k2(0), (B, H, T, hs), dtype=jnp.bfloat16)
-k = jax.random.normal(k2(1), (B, H, T, hs), dtype=jnp.bfloat16)
-v = jax.random.normal(k2(2), (B, H, T, hs), dtype=jnp.bfloat16)
+from bench import require_tpu
+from chipbench import trace
+from thunder_tpu.executors import pallasex as px
+from thunder_tpu.executors.jaxex import _sdpa_backward_reference, _sdpa_reference
 
-GRID = [(512, 512), (256, 512), (512, 256), (256, 256), (1024, 512),
-        (512, 1024), (1024, 1024), (128, 512), (256, 1024), (2048, 512)]
+# B, H, G, T, hs, window: one sequence of the Mistral train cell; the two of
+# the hybrid cell's gated attention layer
+SHAPES = {"mistral": (1, 32, 8, 8192, 128, 4096), "hybrid": (2, 16, 2, 8192, 256, None)}
+REPS = 5
 
-def sdpa(q, k, v):
-    return ltorch.scaled_dot_product_attention(q, k, v, is_causal=True)
 
-failed = []
-for BQ, BK in GRID:
-    os.environ["THUNDER_TPU_FLASH_BQ"] = str(BQ)
-    os.environ["THUNDER_TPU_FLASH_BK"] = str(BK)
-    jax.clear_caches()
-    try:
-        ffn = tt.jit(sdpa)
-        gfn = tt.grad(lambda q, k, v: sdpa(q, k, v).sum(), argnums=(0, 1, 2))
-        fwd = _best_ms(ffn, q, k, v, reps=2)
-        fb = _best_ms(gfn, q, k, v, reps=2)
-        print(f"BQ={BQ:4d} BK={BK:4d}: fwd {fwd:7.3f} ms  fwd+bwd {fb:7.3f} ms", flush=True)
-    except Exception as e:  # a geometry Mosaic refuses is a result of the search
-        failed.append((BQ, BK))
-        print(f"BQ={BQ:4d} BK={BK:4d}: FAILED {type(e).__name__}: {str(e)[:120]}", flush=True)
-if failed:
-    sys.exit(f"flash_tune: {len(failed)} of {len(GRID)} geometries failed: {failed}")
+def operands(B, H, G, T, hs, dtype=jnp.bfloat16):
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    shape = lambda n: (B * n, T, hs)   # noqa: E731
+    q, g = (jax.random.normal(k, shape(H), dtype) for k in ks[:2])
+    k, v = (jax.random.normal(k, shape(G), dtype) for k in ks[2:])
+    return q, k, v, g
+
+
+def kernel_ms(run, reps):
+    """ms a call of every device operation ``run()`` starts, by name."""
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        for _ in range(reps):
+            run()
+        jax.profiler.stop_trace()
+        ops = trace.load(trace.find_xplane(d)).top_ops(n=100)
+    return {name: seconds * 1e3 / reps for name, seconds in ops}
+
+
+def time_shape(name, blocks):
+    B, H, G, T, hs, window = SHAPES[name]
+    q, k, v, g = operands(B, H, G, T, hs)
+    scale = 1.0 / np.sqrt(hs)
+    fwd = lambda: px._flash_fwd(q, k, v, None, True, scale, H, G, None, 1, window)   # noqa: E731
+    out, lse = jax.block_until_ready(fwd())
+    bwd = lambda: px._flash_bwd(g, q, k, v, out, lse, None, True, scale, H, G, None, 1, window)   # noqa: E731
+    jax.block_until_ready(bwd())
+    ms = kernel_ms(lambda: jax.block_until_ready((fwd(), bwd())), REPS)
+    three = [ms.pop(n, float("nan")) for n in ("_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv")]
+    rest = ", ".join(f"{n} {t:.3f}" for n, t in sorted(ms.items(), key=lambda kv: -kv[1])[:4])
+    print(f"{name:8s} {blocks or 'derived':>9s}: fwd {three[0]:7.3f}  dq {three[1]:7.3f}  dkv {three[2]:7.3f}"
+          f"  sum {sum(three):7.3f} ms   beside them: {rest}   schedule {px.flash_schedule}", flush=True)
+
+
+def check():
+    """Compiled kernels against the float32 reference, T 2048, both cells' kinds."""
+    worst = 0.0
+    for name, (B, H, G, _, hs, window) in SHAPES.items():
+        T, window = 2048, None if window is None else 1024
+        q, k, v, g = (x.reshape(B, -1, T, hs) for x in operands(B, H, G, T, hs))
+        scale = 1.0 / np.sqrt(hs)
+        out, lse = px.flash_sdpa(q, k, v, None, True, scale, window)
+        got = (out, *px.flash_sdpa_backward(g, q, k, v, out, lse, None, True, scale, window))
+        f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+        oref, lref = _sdpa_reference(*f32[:3], None, True, scale, window)
+        want = (oref, *_sdpa_backward_reference(f32[3], *f32[:3], oref, lref, None, True, scale, window))
+        for what, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            err = float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
+            worst = max(worst, err)
+            print(f"check {name:8s} {what:3s} relative error {err:.5f}", flush=True)
+    return worst
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shapes", default="mistral,hybrid")
+    ap.add_argument("--blocks", default="")
+    ap.add_argument("--check", action="store_true")
+    ap.add_argument("--window", type=int, help="another window for the mistral shape (to place _block's rule)")
+    args = ap.parse_args()
+    print(require_tpu("flash_tune"), flush=True)
+    if args.window:
+        SHAPES["mistral"] = (*SHAPES["mistral"][:5], args.window)
+    if args.check and check() > 0.02:   # bfloat16 operands: 0.003-0.006
+        sys.exit("flash_tune: the compiled kernels disagree with the reference")
+    failed = []
+    for blocks in args.blocks.split(",") if args.blocks else [""]:
+        for which in "QK":
+            os.environ.pop(f"THUNDER_TPU_FLASH_B{which}", None)
+        if blocks:
+            os.environ["THUNDER_TPU_FLASH_BQ"], os.environ["THUNDER_TPU_FLASH_BK"] = blocks.split("x")
+        jax.clear_caches()
+        for name in args.shapes.split(","):
+            try:
+                time_shape(name, blocks)
+            except Exception as e:  # a geometry Mosaic refuses is a result of the search
+                failed.append((name, blocks))
+                print(f"{name:8s} {blocks:>9s}: FAILED {type(e).__name__}: {str(e)[:300]}", flush=True)
+    if failed:
+        sys.exit(f"flash_tune: {len(failed)} geometries failed: {failed}")
+
+
+if __name__ == "__main__":
+    main()

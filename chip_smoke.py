@@ -222,12 +222,10 @@ def main() -> None:
               "count": len(devices)}
 
     from thunder_tpu.core import compile_cache
-    from thunder_tpu.executors import pallasex
     from thunder_tpu.models import llama
 
     t0 = time.perf_counter()
-    emit(device, "start", t0, jax=jax.__version__, cache_dir=compile_cache.enable(),
-         tuning={"path": pallasex._tuning_path(), "loaded": pallasex._tuning()})
+    emit(device, "start", t0, jax=jax.__version__, cache_dir=compile_cache.enable())
     if "kernels" in phases:
         kernels_phase(device, llama.Config.from_name(CONFIG))
     if "train" in phases:

@@ -55,3 +55,23 @@ def _reset_observability_state():
 def pytest_configure(config):
     # tier-1 runs with -m 'not slow'; soak/long-horizon tests opt out with it
     config.addinivalue_line("markers", "slow: long-running test, excluded from tier-1")
+    # xdist hands files out by their number of tests, largest first, unless told
+    # otherwise: the order below is to stand (no xdist, no such option)
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+# The tier-1 command runs `--dist loadfile`: a file is one worker's from start
+# to end, so the run lasts as long as the last long file to start.  xdist's own
+# order (by number of tests) starts a file of five long tests last; collection
+# order starts it late in the alphabet.  Start the long files first (seconds a
+# file: ROADMAP.md D11); everything else keeps its order.
+_LONGEST_FIRST = (
+    "test_sequence_parallel.py", "test_ring_attention.py", "test_hybrid_moe.py",
+    "test_train_cli.py", "test_paged_attention.py", "test_pallas.py",
+)
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(_LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))

@@ -76,20 +76,29 @@ def test_generate_quantized_int8_runs_close():
 
 
 def test_generate_zero_tokens_and_compile_cache():
+    import dataclasses
+
     from thunder_tpu.models.generate import _generate_cache
 
-    cfg = llama.Config.from_name("tiny-llama-debug")
+    # a norm_eps no other test uses: the cache is one 16-entry dict shared by
+    # the whole worker, so this test looks for its own key, never at len()
+    cfg = llama.Config.from_name("tiny-llama-debug", norm_eps=1.5e-5)
+    cfg_key = tuple(sorted(dataclasses.asdict(cfg).items()))
     params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 3), 0, cfg.vocab_size)
 
     out0 = gen.generate(params, prompt, cfg, 0)
     np.testing.assert_array_equal(np.asarray(out0), np.asarray(prompt))
 
-    n_before = len(_generate_cache)
+    def mine():
+        return [k for k in _generate_cache if k[0] == cfg_key]
+
+    assert mine() == []
     gen.generate(params, prompt, cfg, 3, cache_dtype=jnp.float32)
-    n_mid = len(_generate_cache)
+    (key,) = mine()
+    pair = _generate_cache[key]
     gen.generate(params, prompt, cfg, 3, cache_dtype=jnp.float32)
-    assert len(_generate_cache) == n_mid > n_before  # second call reuses
+    assert mine() == [key] and _generate_cache[key] is pair   # second call reuses
 
 
 def test_tp_sharded_decode_matches_single_device():

@@ -498,7 +498,7 @@ class TestGeneralJit:
         """A .get() MISS on a dict that is NOT value-guardable (holds
         non-primitives) must emit a dedicated absence guard (check_absent):
         inserting the key later retraces instead of replaying the baked
-        default branch (ADVICE r4: the whole-dict guard silently no-opped
+        default branch (the whole-dict guard once silently no-opped
         here)."""
         def f(x):
             return x * MODULE_BIG_CFG.get("warmup", 1)

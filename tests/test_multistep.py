@@ -422,7 +422,7 @@ class TestHostVisitAccounting:
 
     def test_multi_step_amortizes_visits(self, micro):
         """Same workload at N=4: >= 4x fewer host visits per decode
-        token (the measured contract behind BENCH_MULTISTEP.json)."""
+        token."""
         cfg, params = micro
         e1 = _engine(cfg, params)
         _drive(e1, _prompts(cfg), n=9)

@@ -325,7 +325,7 @@ class TestNoAnnotationGate:
 
 
 class TestUnguardableKeySharpEdge:
-    """Satellite 2 (ADVICE r5 low, interpreter.py _read_keys): iterating a
+    """interpreter.py _read_keys: iterating a
     tracked dict with unguardable keys under-guards (LEN only while keys and
     values bake) — it must surface through the sharp-edges policy."""
 

@@ -257,48 +257,28 @@ def test_flash_cross_entropy_unsupported_declines(interpret_kernels):
     assert flash_cross_entropy(jnp.ones((7, 999)), jnp.zeros(7, dtype=jnp.int32)) is None
 
 
-@pytest.fixture
-def claim_ce(tmp_path, monkeypatch):
-    """Explicit ``ce.claim: true`` tuning override: the claim path stays
-    tested even though the *default* is now yield (the kernel was last
-    measured losing to XLA on the default geometry)."""
-    import json
+def test_ce_runs_the_kernel_in_jit_pipeline(interpret_kernels, monkeypatch):
+    """The one route to the fused CE kernel: the XLA executor's
+    CROSS_ENTROPY_FWD calls ``jaxex._ce_fast_path`` (installed by pallasex
+    as ``flash_cross_entropy``).  Value against torch, and the kernel was
+    entered and did not decline: an edit that drops the route fails here."""
+    from thunder_tpu.executors import jaxex
 
-    tuning = tmp_path / "tuning.json"
-    tuning.write_text(json.dumps({"ce": {"claim": True}}))
-    monkeypatch.setenv("THUNDER_TPU_PALLAS_TUNING", str(tuning))
-    pallasex._tuning.cache_clear()
-    yield
-    pallasex._tuning.cache_clear()
+    assert jaxex._ce_fast_path is pallasex.flash_cross_entropy
+    taken = []
 
+    def counting(logits, target):
+        res = pallasex.flash_cross_entropy(logits, target)
+        taken.append(res is not None)
+        return res
 
-def test_ce_claimed_in_jit_pipeline(interpret_kernels, claim_ce):
+    monkeypatch.setattr(jaxex, "_ce_fast_path", counting)
     rng = np.random.default_rng(4)
     logits = rng.standard_normal((64, 1024)).astype(np.float32)
     tgt = rng.integers(0, 1024, (64,)).astype(np.int32)
     jfn = tt.jit(lambda l, t: ltorch.cross_entropy(l, t))
     got = float(jfn(logits, tgt))
-    src = tt.last_traces(jfn)[-1].python()
-    assert "pallas_cross_entropy" in src, src
-    import torch
-
-    ref = float(torch.nn.functional.cross_entropy(torch.from_numpy(logits), torch.from_numpy(tgt).long()))
-    np.testing.assert_allclose(got, ref, rtol=1e-5)
-
-
-def test_ce_yields_by_default(interpret_kernels):
-    """Without a measured ``ce.claim: true`` in the tuning file the checker
-    defers to the XLA lowering (win-or-yield: the last on-TPU measurement
-    had the kernel losing at the default geometry) — and the result is the
-    same either way."""
-    pallasex._tuning.cache_clear()
-    rng = np.random.default_rng(4)
-    logits = rng.standard_normal((64, 1024)).astype(np.float32)
-    tgt = rng.integers(0, 1024, (64,)).astype(np.int32)
-    jfn = tt.jit(lambda l, t: ltorch.cross_entropy(l, t))
-    got = float(jfn(logits, tgt))
-    src = tt.last_traces(jfn)[-1].python()
-    assert "pallas_cross_entropy" not in src, src
+    assert taken and all(taken), taken
     import torch
 
     ref = float(torch.nn.functional.cross_entropy(torch.from_numpy(logits), torch.from_numpy(tgt).long()))

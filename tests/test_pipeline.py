@@ -72,8 +72,8 @@ def test_pp_loss_matches_single_device(n_micro):
 def test_pp_sliding_window_matches_single_device():
     """Sliding-window (Mistral-family) configs through pp: the stage fn
     traces models.llama.block_forward, which threads config.sliding_window
-    into the fused SDPA — assert the numerics actually match (ADVICE r3
-    flagged the sp/ulysses analogs of this path)."""
+    into the fused SDPA — assert the numerics actually match (the
+    sp/ulysses analogs of this path once dropped the window)."""
     cfg, params, idx, tgt, cos, sin = _setup(T=32)
     cfg = llama.Config.from_name("tiny-llama-debug", n_layer=4, sliding_window=8)
     ref, _ = _ref_loss_and_grads(cfg, params, idx, tgt, cos, sin)

@@ -6,8 +6,7 @@ run with the same seed — greedy AND temperature sampling (each request
 carries its own PRNG key chain, split exactly like the solo path).  Policy
 behavior (admission, FIFO, deadlines, eviction, prefix sharing, window
 expiry) is tested host-side on a micro model so the whole file stays
-CPU-fast; multi-request soak coverage lives in ``bench.py serving``
-(``slow``-marked here).
+CPU-fast; the multi-request soak at the end is ``slow``-marked.
 """
 from __future__ import annotations
 

@@ -1,0 +1,703 @@
+"""Counts and parity bits that used to be asserted only on recorded runs.
+
+Each case of ``test_count_invariant[<target>-<key>]`` is one count, parity
+bit or conservation identity (compiled programs against the bucket bound, zero
+programs for a warm engine, blocks, bytes from shapes, rows a decode step
+held, tokens a session did not prefill again) read off a live engine or a
+compiled program at a tiny debug config.  No case reads a clock.  A case is
+here only where no other tier-1 test holds the same line; where one does, it
+stays there (CHANGES.md, PR 30, lists which).
+"""
+from __future__ import annotations
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import thunder_tpu as tt
+from thunder_tpu.models import llama
+from thunder_tpu.serving import (
+    AdapterRegistry,
+    FaultPlan,
+    FaultSpec,
+    RetryPolicy,
+    SpecConfig,
+    arena_block_bytes,
+    blocks_for_arena_bytes,
+    make_lora_factors,
+)
+
+MICRO = dict(
+    n_layer=2, n_head=4, n_query_groups=2, n_embd=32,
+    intermediate_size=64, vocab_size=64, block_size=64,
+)
+BUCKETS = dict(batch_buckets=(4,), block_buckets=(8,), prefill_buckets=(16,))
+
+
+@functools.cache
+def _model(**over):
+    """``over`` empty: the model the cases share.  Otherwise a model no other
+    test builds, so that this file's first engine on it finds no program in
+    the module cache (the cache is keyed by the config)."""
+    cfg = llama.Config.from_name("tiny-llama-debug", **{**MICRO, **over})
+    return cfg, llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def micro():
+    return _model()
+
+
+def _engine(cfg, params, **kw):
+    kw.setdefault("block_size", 4)
+    kw.setdefault("num_blocks", 32)
+    kw.setdefault("max_batch", 4)
+    kw.setdefault("cache_dtype", jnp.float32)
+    for k, v in BUCKETS.items():
+        kw.setdefault(k, v)
+    return tt.serve(None, params, cfg, **kw)
+
+
+def _prompt(seed, n, cfg):
+    return np.asarray(
+        jax.random.randint(jax.random.PRNGKey(seed), (n,), 0, cfg.vocab_size)
+    ).astype(np.int32)
+
+
+def _reqs(cfg, lens, n, seed=0):
+    return [{"prompt": _prompt(seed + i, m, cfg), "max_new_tokens": n}
+            for i, m in enumerate(lens)]
+
+
+def _tokens(results):
+    return [tuple(int(t) for t in r.tokens) for r in results]
+
+
+def _drive_peak(eng, reqs):
+    """Submit everything, step to the end; the most requests that ran at once
+    and the most distinct adapters among them."""
+    handles = [eng.submit(**r) for r in reqs]
+    peak = distinct = 0
+    while eng.scheduler.queue or eng.scheduler.running:
+        running = eng.scheduler.running
+        peak = max(peak, len(running))
+        distinct = max(distinct, len({r.adapter_slot for r in running if r.adapter_slot}))
+        if not eng.step():
+            break
+    return handles, peak, distinct
+
+
+CASES = {}
+
+
+def case(name):
+    def register(fn):
+        CASES[name] = fn
+        return fn
+    return register
+
+
+#
+# serving: the plain engine
+#
+
+
+@case("serving-compiles_within_bucket_bound")
+def _(micro):
+    """Mixed lengths through the default (power-of-two) bucket ladders: what
+    the engine compiled is inside what its bucket sets allow, kind by kind."""
+    cfg, params = micro
+    eng = tt.serve(None, params, cfg, block_size=4, num_blocks=48, max_batch=4,
+                   cache_dtype=jnp.float32)
+    eng.run(_reqs(cfg, (3, 5, 9, 14, 2, 7), 6))
+    st = eng.stats()
+    counts = st["compile_counts"]
+    assert counts["prefill"] >= 1 and counts["decode"] >= 1
+    assert sum(counts.values()) <= st["bucket_bound"]
+    widths = len(eng._table_widths)
+    assert counts["decode"] <= len(eng.scheduler.batch_buckets) * widths
+    assert counts["prefill"] <= len(eng.scheduler.prefill_buckets) * widths
+
+
+@case("serving-cold_compile_prefills_measured")
+def _(micro):
+    """The per-request compile tag: on a cold program cache as many prefills
+    are tagged as prefill programs were compiled; a second engine of the same
+    configuration tags none and compiles nothing."""
+    cfg, params = _model(vocab_size=48)
+    reqs = _reqs(cfg, (3, 6, 11, 5), 4)
+    cold = _engine(cfg, params, prefill_buckets=(8, 16))
+    res = cold.run([dict(r) for r in reqs])
+    tagged = sum(1 for r in res if r.prefill_compiled)
+    assert tagged == cold.compile_counts["prefill"] == 2      # one a bucket
+    warm = _engine(cfg, params, prefill_buckets=(8, 16))
+    res_w = warm.run([dict(r) for r in reqs])
+    assert not any(r.prefill_compiled for r in res_w)
+    assert sum(warm.compile_counts.values()) == 0
+    assert _tokens(res_w) == _tokens(res)
+
+
+@case("serving-occupancy_accounts_for_every_decode_token")
+def _(micro):
+    """Rows summed over decode steps are the tokens that decode produced:
+    everything generated less the one token a request that prefill samples."""
+    cfg, params = micro
+    eng = _engine(cfg, params)
+    res = eng.run(_reqs(cfg, (3, 5, 9, 4, 6), 5, seed=10))
+    st = eng.stats()
+    generated = sum(len(r.new_tokens) for r in res)
+    assert st["tokens_generated"] == generated == 25
+    rows = st["mean_batch_occupancy"] * st["decode_steps"]
+    assert rows == pytest.approx(generated - len(res))
+    assert st["mean_batch_occupancy"] > 1.0
+
+
+#
+# serving_async: chunked prefill
+#
+
+
+@case("serving_async-cold_compile_prefills_measured")
+def _(micro):
+    """A second chunking engine finds every program, the chunk kind among
+    them, and serves the same tokens."""
+    cfg, params = micro
+    reqs = _reqs(cfg, (37, 5, 7), 4, seed=20)
+    kw = dict(prefill_chunk=8, prefill_buckets=(8, 16), num_blocks=48, block_buckets=(16,))
+    first = _engine(cfg, params, **kw)
+    res = first.run([dict(r) for r in reqs])
+    assert first.chunk_runs > 0
+    second = _engine(cfg, params, **kw)
+    res2 = second.run([dict(r) for r in reqs])
+    assert second.chunk_runs == first.chunk_runs
+    assert "prefill_chunk" in second.compile_counts
+    assert sum(second.compile_counts.values()) == 0
+    assert not any(r.prefill_compiled for r in res2)
+    assert _tokens(res2) == _tokens(res)
+
+
+@case("serving_async-mean_batch_occupancy")
+def _(micro):
+    """Short requests queued behind a long chunked prompt still share decode
+    steps with each other: the lane does not serialise the batch."""
+    cfg, params = micro
+    eng = _engine(cfg, params, prefill_chunk=8, prefill_buckets=(8, 16), num_blocks=48,
+                  block_buckets=(16,))
+    res = eng.run(_reqs(cfg, (37, 5, 7, 6), 6, seed=30))
+    st = eng.stats()
+    assert st["chunk_runs"] >= 4                  # 37 tokens in pieces of 8
+    assert st["mean_batch_occupancy"] > 1.0
+    rows = st["mean_batch_occupancy"] * st["decode_steps"]
+    assert rows == pytest.approx(sum(len(r.new_tokens) for r in res) - len(res))
+
+
+#
+# capacity: quantized block storage and adapters
+#
+
+
+@functools.cache
+def _capacity():
+    """A flood of sixteen requests into a float32 and an int8 pool of one
+    arena-byte budget, at head size 16 (the note in ``serving/quant.py``)."""
+    cfg, params = _model(n_embd=64)
+    assert cfg.head_size == 16
+    bs, prompt_len, max_new, n_flood, usable = 4, 8, 8, 16, 16
+    budget = (usable + 1) * arena_block_bytes(cfg, bs, jnp.float32)   # + the sink block
+    out = {"budget": budget}
+    for name, kv in (("f32", None), ("int8", "int8")):
+        nb = blocks_for_arena_bytes(cfg, bs, budget, jnp.float32, kv_dtype=kv)
+        eng = _engine(cfg, params, block_size=bs, num_blocks=nb, max_batch=n_flood,
+                      max_queue=2 * n_flood, batch_buckets=(n_flood,),
+                      **({"kv_dtype": kv} if kv else {}))
+        _, peak, _ = _drive_peak(eng, _reqs(cfg, (prompt_len,) * n_flood, max_new, seed=40))
+        out[name] = {"blocks": nb, "peak": peak, "stats": eng.stats(),
+                     "block_bytes": eng.pool.block_bytes()}
+    return out
+
+
+@case("capacity-admitted_ratio")
+def _(micro):
+    """At one arena-byte budget the int8 pool keeps at least three times as
+    many requests resident as the float32 pool (head size 16: 64 bytes a
+    slot-head against 16 + 4)."""
+    run = _capacity()
+    assert run["int8"]["peak"] > run["f32"]["peak"] >= 2
+    assert run["int8"]["peak"] >= 3 * run["f32"]["peak"]
+
+
+@case("capacity-arena_bytes_within_budget")
+def _(micro):
+    """Both pools of the comparison were built inside the same budget, and one
+    block more would not have fitted: the live arenas' bytes, from shapes."""
+    run = _capacity()
+    for name in ("f32", "int8"):
+        side = run[name]
+        assert side["stats"]["arena_bytes"] == side["blocks"] * side["block_bytes"]
+        assert side["stats"]["arena_bytes"] <= run["budget"] < (
+            side["stats"]["arena_bytes"] + side["block_bytes"])
+    assert run["int8"]["stats"]["kv_dtype"] == "int8"
+
+
+@case("capacity-compiles_within_bucket_bound")
+def _(micro):
+    """The storage dtype is program identity, not a new ladder: the flooded
+    int8 engine stays inside its bucket bound."""
+    st = _capacity()["int8"]["stats"]
+    assert 0 < sum(st["compile_counts"].values()) <= st["bucket_bound"]
+
+
+@case("capacity-adapter_mix_max_distinct")
+def _(micro):
+    """Three tenants' rows are in one decode batch at the same time (not one
+    after another), beside a base-model row."""
+    cfg, params = micro
+    reg = AdapterRegistry(cfg, rank=2, max_adapters=4)
+    for i, name in enumerate(("a", "b", "c")):
+        reg.register(name, make_lora_factors(cfg, 2, jax.random.PRNGKey(10 + i), std=0.5))
+    eng = _engine(cfg, params, lora=reg)
+    reqs = [dict(r, adapter_id=a) for r, a in
+            zip(_reqs(cfg, (4, 6, 9, 5), 5, seed=50), ("a", "b", "c", None))]
+    handles, peak, distinct = _drive_peak(eng, reqs)
+    assert peak == 4 and distinct == 3
+    assert all(len(h.result(drive=False).new_tokens) == 5 for h in handles)
+
+
+#
+# tracing: an armed engine records, an explicitly disarmed one is the default
+#
+
+
+@functools.cache
+def _armed():
+    """Three requests through an engine with spans, SLO monitor and flight
+    recorder all on."""
+    cfg, params = _model()
+    eng = _engine(cfg, params, trace=True, flight_recorder=True,
+                  slo={"ttft_s": 30.0, "tpot_s": 30.0, "queue_s": 30.0})
+    eng.run(_reqs(cfg, (3, 7, 11), 4, seed=60))
+    return eng
+
+
+@case("tracing-slo_dimensions")
+def _(micro):
+    """Three latency targets arm four dimensions (the deadline rides along),
+    and every finished request is counted once in each."""
+    dims = _armed().slo_report()["dimensions"]
+    assert sorted(dims) == ["deadline", "queue_s", "tpot_s", "ttft_s"]
+    for name, d in dims.items():
+        assert d["good"] + d["bad"] == 3, (name, d)
+        assert d["bad"] == 0, (name, d)
+
+
+@case("tracing-flight_events")
+def _(micro):
+    """The armed engine's flight ring saw every decode dispatch."""
+    eng = _armed()
+    kinds = [e.get("kind") for e in eng._flight.events()]
+    assert eng._flight.events_recorded >= len(kinds) > 0
+    assert kinds.count("decode") == eng.stats()["decode_steps"]
+
+
+@case("tracing-explicit_off_is_the_default_engine")
+def _(micro):
+    """``trace=False, slo=None, flight_recorder=False`` is the default engine:
+    no tracer, monitor or recorder object, the same static program key, no
+    program compiled beyond the default engine's."""
+    # the module, not the events() that the package re-exports under its name
+    ev = importlib.import_module("thunder_tpu.observability.events")
+
+    cfg, params = micro
+    reqs = _reqs(cfg, (3, 7), 4, seed=70)
+    plain = _engine(cfg, params)
+    res = plain.run([dict(r) for r in reqs])
+    ev.clear_events()
+    off = _engine(cfg, params, trace=False, slo=None, flight_recorder=False)
+    res_off = off.run([dict(r) for r in reqs])
+    assert off._tracer is None and off._slo is None and off._flight is None
+    assert off._static_key() == plain._static_key()
+    assert sum(off.compile_counts.values()) == 0
+    assert off.slo_report() == {"enabled": False}
+    assert not [e for e in ev.events() if e.get("cat", "").startswith("serving")]
+    assert _tokens(res_off) == _tokens(res)
+
+
+#
+# paged attention, ragged decode, paged chunk prefill
+#
+
+
+PAGED = dict(attn="paged", prefill_chunk=8, prefill_buckets=(8, 16), block_buckets=(12,),
+             num_blocks=64)
+
+
+@functools.cache
+def _ragged():
+    """One long row among three short ones through the paged engine."""
+    cfg, params = _model()
+    reqs = _reqs(cfg, (3, 3, 3, 40), 5, seed=80)
+    eng = _engine(cfg, params, goodput=True, **PAGED)
+    return reqs, eng, eng.run([dict(r) for r in reqs])
+
+
+@case("ragged-blocks_walked")
+def _(micro):
+    """One long row among three short ones: the tables span Bb x nbb blocks a
+    decode dispatch, the rows' live ranges less than half of that."""
+    _, eng, _ = _ragged()
+    blk = eng.stats()["goodput"]["blocks"]
+    per = eng.goodput_report()["blocks_per_kind"]["decode_paged"]
+    assert per["dispatches"] == eng.stats()["decode_steps"]
+    assert blk["walked"] == per["dispatches"] * 4 * 12
+    assert blk["walked"] >= 2 * blk["real"] > 0
+
+
+@case("ragged-warm_engine_new_programs")
+def _(micro):
+    """A second paged engine with paged chunk prefill compiles nothing and
+    serves the same tokens."""
+    cfg, params = micro
+    reqs, eng, res = _ragged()
+    warm = _engine(cfg, params, goodput=True, **PAGED)
+    res_w = warm.run([dict(r) for r in reqs])
+    kinds = warm.stats()["attn"]["kinds"]
+    assert kinds["decode"]["mode"] == kinds["prefill_chunk"]["mode"] == "paged"
+    assert kinds["prefill_chunk"]["kernel_steps"] > 0
+    assert sum(warm.compile_counts.values()) == 0
+    assert _tokens(res_w) == _tokens(res)
+
+
+@case("ragged-compiles_within_bucket_bound")
+def _(micro):
+    """The paged kinds replace their gather twins, they do not add to them."""
+    cfg, params = _model(vocab_size=56)
+    eng = _engine(cfg, params, **PAGED)
+    eng.run(_reqs(cfg, (3, 5, 21, 40), 4, seed=90))
+    st = eng.stats()
+    counts = {k: v for k, v in st["compile_counts"].items() if v}
+    assert set(counts) <= {"prefill", "prefill_chunk_paged", "decode_paged"}, counts
+    assert 0 < sum(counts.values()) <= st["bucket_bound"]
+
+
+@case("paged_attn-kernel_steps")
+def _(micro):
+    """Every decode dispatch of the paged engine went through the kernel."""
+    _, eng, _ = _ragged()
+    st = eng.stats()
+    assert st["attn"]["kinds"]["decode"]["kernel_steps"] == st["decode_steps"] > 0
+    assert st["attn"]["fallback_steps"] == 0
+
+
+#
+# serving_dp: two replicas behind the router
+#
+
+
+def _fleet(cfg, params, **kw):
+    kw.setdefault("replicas", 2)
+    kw.setdefault("block_buckets", (4, 16))
+    kw.setdefault("prefill_buckets", (8, 16, 64))
+    kw.setdefault("num_blocks", 48)
+    return _engine(cfg, params, **kw)
+
+
+def _family(cfg, n, length):
+    base = _prompt(77, length, cfg)
+    out = []
+    for i in range(n):
+        p = base.copy()
+        p[-1] = (i + 1) % cfg.vocab_size
+        out.append(p)
+    return out
+
+
+@functools.cache
+def _segregated():
+    """A long shared-prefix family, then three short strangers, through two
+    replicas."""
+    cfg, params = _model()
+    longs = _family(cfg, 3, 40)
+    shorts = [_prompt(100 + i, 5, cfg) for i in range(3)]
+    reqs = [{"prompt": p, "max_new_tokens": 4} for p in longs + shorts]
+    fleet = _fleet(cfg, params)
+    handles = [fleet.submit(**r) for r in reqs]
+    fleet.drain()
+    return reqs, fleet, handles, fleet.stats()
+
+
+@case("serving_dp-routed_is_everything_submitted")
+def _(micro):
+    """Nothing stays in the router: every request was routed, the lanes' counts
+    add up, and a drained fleet is level."""
+    reqs, _, handles, st = _segregated()
+    r = st["router"]
+    assert r["submitted"] == r["routed"] == len(reqs)
+    assert sum(r["routed_by_replica"]) == r["routed"] and all(r["routed_by_replica"])
+    assert r["queue_depth"] == 0 and r["expired"] == 0 and r["imbalance"] == 0
+    assert all(len(h.result(drive=False).new_tokens) == 4 for h in handles)
+
+
+@case("serving_dp-shape_segregation")
+def _(micro):
+    """What the router is for: the long family stays on one lane, and the
+    other lane never builds a decode program at the wide table."""
+    _, fleet, handles, st = _segregated()
+    long_lanes = {h.replica for h in handles[:3]}
+    assert len(long_lanes) == 1 and st["router"]["affinity_hits"] >= 2
+    (long_lane,) = long_lanes
+    short_eng = fleet.engines[1 - long_lane]
+    widths = {k[2] for k in short_eng._programs if k[0] == "decode"}
+    assert widths == {4}, widths
+    wide = {k[2] for k in fleet.engines[long_lane]._programs if k[0] == "decode"}
+    assert 16 in wide
+
+
+@case("serving_dp-decode_compiles_within_bucket_bound")
+def _(micro):
+    """Each lane's programs are inside its own bucket bound."""
+    _, fleet, _, st = _segregated()
+    for eng, per in zip(fleet.engines, st["per_replica"]):
+        assert sum(per["compile_counts"].values()) <= per["bucket_bound"]
+        assert per["decode_steps"] > 0
+
+
+#
+# serving_spec: the speculative lane
+#
+
+
+def _spec_engine(**kw):
+    """The shared model as target, a one-layer model of the family as draft."""
+    cfg, params = _model()
+    dcfg = llama.Config.from_name("tiny-llama-debug", **{**MICRO, "n_layer": 1})
+    draft = llama.init_params(dcfg, jax.random.PRNGKey(9), dtype=jnp.float32)
+    return _engine(cfg, params, num_blocks=64, speculative=SpecConfig(draft, dcfg, K=2),
+                   retry=RetryPolicy(sleep=lambda s: None), **kw)
+
+
+@functools.cache
+def _speculated():
+    cfg, _ = _model()
+    reqs = _reqs(cfg, (5, 6, 7), 8, seed=110)
+    eng = _spec_engine()
+    return reqs, eng, eng.run([dict(r) for r in reqs])
+
+
+@case("serving_spec-accept_len_hist_counts_every_live_row")
+def _(micro):
+    """With several rows a round, the histogram has one entry a live row a
+    round: at least the rounds, and as many as the draft tokens over K."""
+    _, eng, _ = _speculated()
+    sp = eng.stats()["spec"]
+    entries = sum(sp["accept_len_hist"].values())
+    assert entries > sp["rounds"] > 0                      # rows shared rounds
+    assert entries == eng.spec_draft_tokens // 2
+    assert eng.spec_accepted_tokens == sum((n - 1) * c for n, c in sp["accept_len_hist"].items())
+
+
+@case("serving_spec-warm_engine_new_programs")
+def _(micro):
+    """A second speculative engine compiles none of the lane's kinds."""
+    reqs, _, res = _speculated()
+    second = _spec_engine()
+    res2 = second.run([dict(r) for r in reqs])
+    assert {"draft_decode", "verify", "spec_prefill"} <= set(second.compile_counts)
+    assert sum(second.compile_counts.values()) == 0
+    assert not any(r.prefill_compiled for r in res2)
+    assert _tokens(res2) == _tokens(res)
+
+
+#
+# goodput, sessions, recovery, multi-step decode
+#
+
+
+@case("goodput-new_programs_with_goodput_speculative")
+def _(micro):
+    """The ledger is no part of the speculative lane's program keys either."""
+    from thunder_tpu.serving.engine import _program_cache
+
+    cfg, _ = micro
+    reqs = _reqs(cfg, (5, 6, 7), 6, seed=120)
+    off = _spec_engine()
+    res = off.run([dict(r) for r in reqs])
+    keys = set(_program_cache)
+    on = _spec_engine(goodput=True)
+    res_on = on.run([dict(r) for r in reqs])
+    assert set(_program_cache) == keys
+    assert sum(on.compile_counts.values()) == 0
+    snap = on.stats()["goodput"]
+    assert snap["committed"] + sum(snap["waste"].values()) == snap["positions"]
+    assert _tokens(res_on) == _tokens(res)
+
+
+@case("sessions-prefill_tokens_saved")
+def _(micro):
+    """Turn 2 of a resident session prefills its tail only: fewer prefill
+    positions than a cold engine given the same history, by exactly the
+    tokens of the blocks it re-attached."""
+    cfg, params = micro
+    kw = dict(goodput=True, num_blocks=48, prefill_buckets=(8, 16, 32), block_buckets=(8,))
+
+    def prefill_positions(eng):
+        per = eng.goodput_report()["per_kind"]
+        return sum(v["positions"] - v["waste"].get("pad_prefill", 0)
+                   for k, v in per.items() if k.startswith("prefill"))
+
+    p1 = _prompt(130, 13, cfg)
+    eng = _engine(cfg, params, sessions=True, **kw)
+    r1 = eng.submit(p1, max_new_tokens=5, session_id="chat").result()
+    before = prefill_positions(eng)
+    p2 = np.concatenate([p1, np.asarray(r1.new_tokens, np.int32), _prompt(131, 3, cfg)])
+    r2 = eng.submit(p2, max_new_tokens=4, session_id="chat").result()
+    resident = prefill_positions(eng) - before
+    cold_eng = _engine(cfg, params, **kw)
+    rc = cold_eng.submit(p2, max_new_tokens=4).result()
+    cold = prefill_positions(cold_eng)
+    assert r2.new_tokens == rc.new_tokens
+    assert r2.shared_prefix_blocks == (len(p1) + 5 - 1) // 4 and rc.shared_prefix_blocks == 0
+    assert cold == len(p2)
+    assert cold - resident == r2.shared_prefix_blocks * 4
+
+
+@case("recovery-retry_and_rebuild_in_one_drive")
+def _(micro):
+    """A transient dispatch failure (retried in place) and an out-of-memory
+    at harvest (arenas rebuilt, requests replayed) in the same drive: the
+    tokens of the fault-free run, both paths taken, no block leaked."""
+    cfg, params = micro
+    reqs = _reqs(cfg, (5, 9, 6), 8, seed=140)
+    retry = RetryPolicy(sleep=lambda s: None)
+    ref = _engine(cfg, params, retry=retry).run([dict(r) for r in reqs])
+    plan = FaultPlan(specs=[FaultSpec(point="decode.dispatch", kind="fail", at=2),
+                            FaultSpec(point="harvest", kind="oom", at=5)])
+    eng = _engine(cfg, params, retry=retry, fault_plan=plan)
+    res = eng.run([dict(r) for r in reqs])
+    assert _tokens(res) == _tokens(ref)
+    assert plan.injected == 2 and eng.recoveries == 1
+    assert eng.pool.num_free == eng.pool.num_usable
+
+
+@case("recovery-tokens_replayed")
+def _(micro):
+    """A recovery mid-decode replays each running request's known tokens and
+    no more: its prompt and what it has generated, less the newest token
+    (whose KV the next decode step writes).  The requests' own bills say so,
+    and the ledger's ``replay_recovery`` is those positions plus the slots
+    of the one decode dispatch that was in flight and thrown away; the
+    streams go on as if nothing had happened."""
+    cfg, params = micro
+    reqs = _reqs(cfg, (6, 9), 10, seed=170)
+    ref = _engine(cfg, params).run([dict(r) for r in reqs])
+    eng = _engine(cfg, params, goodput=True)
+    handles = [eng.submit(**r) for r in reqs]
+    while any(len(h._req.generated) < 4 for h in handles):
+        eng.step()
+    in_flight = eng._inflight_decode
+    assert in_flight is not None and in_flight["bucket"][0] == 4
+    eng.recover()
+    expected = [len(r["prompt"]) + len(h._req.generated) - 1 for r, h in zip(reqs, handles)]
+    eng.drain()
+    res = [h.result(drive=False) for h in handles]
+    assert eng.recoveries == 1
+    assert [r.tokens_recomputed for r in res] == expected
+    assert eng.stats()["goodput"]["waste"]["replay_recovery"] == sum(expected) + 4
+    assert _tokens(res) == _tokens(ref)
+
+
+@case("multistep-host_visits_per_token_at_every_horizon")
+def _(micro):
+    """At horizon N the host visits the device at most 1/N as often a decode
+    token (a tenth of slack for the last, partial visit), for the horizons on
+    either side of the one test_multistep.py holds."""
+    cfg, params = micro
+
+    def visits_per_token(n):
+        eng = _engine(cfg, params, num_blocks=64, **({"decode_steps": n} if n > 1 else {}))
+        res = eng.run(_reqs(cfg, (5, 6, 7, 8), 17, seed=150))
+        assert eng.stats()["mean_batch_occupancy"] == 4.0
+        st = eng.stats()
+        decode_tokens = st["tokens_generated"] - len(res)
+        assert decode_tokens == 4 * 16
+        return st["host_visits"] / decode_tokens, _tokens(res)
+
+    base, ref = visits_per_token(1)
+    for n in (2, 8):
+        per, toks = visits_per_token(n)
+        assert per <= base / n * 1.1, (n, per, base)
+        assert toks == ref
+
+
+#
+# donation: a parameter tree's update
+#
+
+
+@case("donation-param_tree_update_aliases_every_leaf")
+def _(micro):
+    """``p - lr * g`` over a model's whole parameter tree under
+    ``donate=True``: both trees are donated leaf by leaf, every new leaf
+    lands in a donated buffer, and the peak falls by the tree's bytes."""
+    from thunder_tpu.examine import memory_timeline
+    from thunder_tpu.observability.metrics import registry
+
+    _, params = micro
+    grads = jax.tree_util.tree_map(lambda a: jnp.full_like(a, 0.5), params)
+    leaves = jax.tree_util.tree_leaves(params)
+    nbytes = sum(a.size * a.dtype.itemsize for a in leaves)
+
+    def sgd(p, g):
+        return jax.tree_util.tree_map(lambda a, b: a - 0.01 * b, p, g)
+
+    off = tt.jit(sgd, donate=False)
+    off(params, grads)
+    donated = registry().counter("donation.buffers_donated").value
+    on = tt.jit(sgd, donate=True)
+
+    def copy(tree):
+        return jax.tree_util.tree_map(lambda x: x.copy(), tree)
+
+    new = on(copy(params), copy(grads))
+    assert registry().counter("donation.buffers_donated").value - donated == 2 * len(leaves)
+    regions = tt.donation_stats(on)["forward"]["regions"]
+    assert sum(len(r["aliases"]) for r in regions) == len(leaves)
+    t_on = memory_timeline(tt.last_traces(on)[-1])
+    t_off = memory_timeline(tt.last_traces(off)[-1])
+    assert t_on["donated_bytes"] == 2 * nbytes and t_off["donated_bytes"] == 0
+    assert t_off["peak_bytes_estimate"] - t_on["peak_bytes_estimate"] == nbytes
+    for a, b in zip(jax.tree_util.tree_leaves(new), jax.tree_util.tree_leaves(off(params, grads))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+#
+# serving_mesh: a tensor-parallel engine
+#
+
+
+@case("serving_mesh-mean_batch_occupancy")
+def _(micro):
+    """The SPMD engine batches as the single-device engine does: the same
+    rows a decode step, the same steps, for the same requests."""
+    from thunder_tpu import distributed as dist
+
+    cfg, params = micro
+    reqs = _reqs(cfg, (3, 5, 9, 4), 5, seed=160)
+    single = _engine(cfg, params)
+    res = single.run([dict(r) for r in reqs])
+    mesh = dist.make_mesh({"tp": 2}, devices=jax.devices()[:2])
+    eng = _engine(cfg, params, mesh=mesh)
+    res_m = eng.run([dict(r) for r in reqs])
+    st, st1 = eng.stats(), single.stats()
+    assert st["mesh"]["devices"] == 2
+    assert st["decode_steps"] == st1["decode_steps"]
+    assert st["mean_batch_occupancy"] == st1["mean_batch_occupancy"] > 1.0
+    assert [len(r.new_tokens) for r in res_m] == [len(r.new_tokens) for r in res]
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_count_invariant(name, micro):
+    CASES[name](micro)

@@ -8,8 +8,7 @@ the second pillar: one compile per (mesh, bucket), shared across engines
 via the module program cache, never shared across distinct device sets.
 
 Everything runs on the conftest 8-virtual-device CPU mesh with the micro
-model (1 layer, 16-wide) so the whole file stays inside the tier-1 budget;
-throughput soak lives in ``bench.py serving_mesh``.
+model (1 layer, 16-wide) so the whole file stays inside the tier-1 budget.
 """
 from __future__ import annotations
 

@@ -158,7 +158,7 @@ class TestQuantizedPool:
     def test_block_bytes_capacity_multiple(self, micro):
         """hs=8 micro: int8+scale costs (8+4) bytes per slot-head vs 32 for
         f32 — and the pool's own accounting agrees with the analytic
-        helper used by the capacity bench."""
+        helper."""
         cfg, _ = micro
         f32 = PagedKVPool(cfg, num_blocks=8, block_size=4, dtype=jnp.float32)
         i8 = PagedKVPool(cfg, num_blocks=8, block_size=4, dtype=jnp.float32,
@@ -336,7 +336,8 @@ class TestQuantizedEngine:
     def test_equal_bytes_pool_admits_more_requests(self, micro):
         """The capacity acceptance at unit scale: at one arena-byte budget
         the int8 engine keeps strictly more requests resident than the f32
-        engine (the full 3x gate lives in bench.py capacity)."""
+        engine (the 3x multiple at hs=16:
+        tests/test_serving_invariants.py)."""
         cfg, params = micro
         budget = 13 * arena_block_bytes(cfg, 4, jnp.float32)
         nb_f32 = blocks_for_arena_bytes(cfg, 4, budget, jnp.float32)

@@ -4,6 +4,11 @@ Reference parity: ``thunder/examine/__init__.py:49``, sharp-edges policy
 (``core/options.py:146`` + ``jit_ext.py:472``), ``core/patterns.py:99``,
 ``core/profile.py:7`` (here ``observability.span``).
 """
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 import torch
@@ -107,6 +112,42 @@ class TestExamine:
         assert mem["input_bytes"] == 16 * 16 * 4
         assert mem["output_bytes"] == 16 * 16 * 4
         assert mem["peak_bytes_estimate"] >= mem["input_bytes"]
+
+    def test_device_peaks_is_one_sourced_table_and_refuses_an_unknown_device(self):
+        """Peaks live in examine.DEVICE_PEAKS keyed by device_kind, each with
+        its source; a device that is not there is an error, not a default."""
+        from thunder_tpu.examine import DEVICE_PEAKS, device_peaks
+
+        assert DEVICE_PEAKS and all(row["source"] for row in DEVICE_PEAKS.values())
+        assert device_peaks("TPU v5 lite") is DEVICE_PEAKS["TPU v5 lite"]
+        for kind in ("cpu", "TPU v9 imaginary"):
+            with pytest.raises(ValueError, match="no published peaks"):
+                device_peaks(kind)
+
+
+class TestTools:
+    """``tools/`` needs the chip and cannot run here, but what can be held
+    without one is: every tool byte-compiles, and a tool that times a device
+    fails without a TPU instead of printing a CPU number."""
+
+    TOOLS = Path(__file__).resolve().parent.parent / "tools"
+
+    def test_all_tools_compile(self):
+        import py_compile
+
+        tools = sorted(self.TOOLS.glob("*.py"))
+        assert tools, self.TOOLS
+        for t in tools:
+            py_compile.compile(str(t), doraise=True)
+
+    def test_flash_tune_without_a_tpu_exits_nonzero_and_prints_no_result(self):
+        proc = subprocess.run(
+            [sys.executable, str(self.TOOLS / "flash_tune.py")],
+            capture_output=True, text=True, timeout=600,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+        assert proc.returncode != 0
+        assert proc.stdout.strip() == "" and "needs a TPU" in proc.stderr
 
 
 class TestSharpEdges:

@@ -114,7 +114,7 @@ class TestAccumMemoryAccounting:
         assert st["peak_bytes_estimate"] >= st["accum_buffer_bytes"]
         # microbatch traces: the activation portion of the peak shrinks with
         # B/k (at toy shapes the param-sized accumulator can still dominate
-        # the total — bench.py's accum sweep shows the net win at real sizes)
+        # the total)
         _, _, ts1 = _run(1)
         assert ts1.profile_stats()["accum_buffer_bytes"] == 0
         act_k2 = st["peak_bytes_estimate"] - st["accum_buffer_bytes"]

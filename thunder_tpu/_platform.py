@@ -1,10 +1,10 @@
-"""Pin JAX to the CPU backend with N virtual devices — for the test suite,
-the CPU-count bench modes and ``train_cli.py --virtual-cpu``.
+"""Pin JAX to the CPU backend with N virtual devices — for the test suite
+and ``train_cli.py --virtual-cpu``.
 
 ``JAX_PLATFORMS=cpu`` alone selects the CPU (the tier-1 command sets it), but
 a multi-device mesh also needs ``--xla_force_host_platform_device_count`` in
 ``XLA_FLAGS``, which XLA reads once at backend init.  One helper, so its
-users (tests/conftest.py, bench.py, __graft_entry__.py) cannot diverge.
+users (tests/conftest.py, train_cli.py, __graft_entry__.py) cannot diverge.
 
 Importing :mod:`thunder_tpu` does not initialize the JAX backend, so calling
 :func:`force_cpu` right after the package import is safe.
@@ -55,8 +55,8 @@ def force_cpu(n_devices: int = 1) -> None:
 
 
 def device_info() -> dict:
-    """The device as jax reports it — part of every result a bench, tool or
-    CLI prints, so that a number always says what it ran on."""
+    """The device as jax reports it — part of every result a tool or CLI
+    prints, so that a number always says what it ran on."""
     import jax
 
     devices = jax.devices()

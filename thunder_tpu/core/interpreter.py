@@ -434,7 +434,7 @@ def _read_keys(ctx: "InterpreterCompileCtx", d: dict) -> list | None:
     key order.  When keys are not guardable only a LEN guard is possible,
     and the observed keys/values still bake into the trace — an UNDER-guard
     (same-length key replacement replays stale results), so it is surfaced
-    through the sharp-edges policy (warn/error; ADVICE r5 low).  Returns the
+    through the sharp-edges policy (warn/error).  Returns the
     key list, or None when d is untracked."""
     base_rec = ctx.prov_of(d)
     if base_rec is None:
@@ -1097,7 +1097,7 @@ def _unwind(frame: Frame, ins, exc_table, e: BaseException) -> int:
 # per-code-object handler resolution: one list indexed by instruction, built
 # once — removes the opname attribute access + dict hash from the hot loop.
 # Weak keys: code objects of dynamically generated functions must not be
-# pinned forever in long-lived processes (ADVICE r3)
+# pinned forever in long-lived processes
 _resolved_handlers: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
 
 

@@ -60,8 +60,8 @@ def report_unguardable_keys(policy: SHARP_EDGES_OPTIONS, where: str) -> None:
     key objects) unrolls the loop over the OBSERVED keys/values, but the
     prologue can only re-check the dict's LENGTH — replacing a key at the
     same length would silently replay the stale program.  Surface that
-    under-guarding per policy instead of staying silent (ADVICE r5:
-    interpreter.py _read_keys)."""
+    under-guarding per policy instead of staying silent
+    (interpreter.py _read_keys)."""
     _dispatch(policy, (
         f"sharp edge: iteration over a tracked dict with unguardable keys "
         f"({where}) during tracing — the observed keys and values are baked "

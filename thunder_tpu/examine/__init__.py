@@ -178,7 +178,7 @@ def train_memory_report(train_step) -> dict:
 
 
 # Published peaks of one chip, keyed by jax's ``device_kind`` — the ONE table
-# behind every roofline, MFU and utilization figure (bench.py reads it).  A
+# behind every roofline, MFU and utilization figure this package gives.  A
 # device that is not here is an error, never a default.
 DEVICE_PEAKS: dict[str, dict] = {
     "TPU v5 lite": {

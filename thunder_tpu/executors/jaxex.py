@@ -415,7 +415,7 @@ def _embedding_backward_impl(grad, indices, num_weights, padding_idx):
     num_weights = int(num_weights)
     flat_idx = indices.reshape(-1)
     flat_grad = grad.reshape(-1, grad.shape[-1])
-    from thunder_tpu.executors.pallasex import _mesh_var, _tuning
+    from thunder_tpu.executors.pallasex import _mesh_var
 
     def onehot_matmul():
         oh = (flat_idx[:, None] == jnp.arange(num_weights)[None, :])
@@ -434,11 +434,6 @@ def _embedding_backward_impl(grad, indices, num_weights, padding_idx):
         # rematerialization" when the vocab dim is sharded) or produces a
         # numerically WRONG sum (measured 5e-2 vs an f64 reference when the
         # embd dim is sharded).
-        out = onehot_matmul()
-    elif _tuning().get("embedding_bwd", {}).get("single_device_winner") == "onehot":
-        # single device is a measured choice (tools/kernel_tune.py): the
-        # matmul costs 2·N·V·C real FLOPs but rides the MXU, the scatter is
-        # bandwidth+serialization — whichever won on hardware is recorded
         out = onehot_matmul()
     else:
         out = jnp.zeros((num_weights, grad.shape[-1]), dtype=grad.dtype)

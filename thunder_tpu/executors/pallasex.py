@@ -21,7 +21,7 @@ On non-TPU backends the kernels can run via the Pallas interpreter
 back to the jnp reference implementation.  That is also what happens when
 jax could not reach a TPU and settled for the CPU, so a program that means to
 run on the chip checks ``jax.devices()[0].platform`` itself
-(``chip_smoke.py``, ``bench.require_tpu``).  The interpreter accepts block
+(``chip_smoke.py``, ``chipbench/run.py``, ``tools/flash_tune.py``).  The interpreter accepts block
 shapes and ops Mosaic refuses: ``tests/test_pallas_tpu_lowering.py`` lowers
 every kernel here for the TPU.
 """
@@ -718,7 +718,7 @@ def _qkv_spec(mesh, q_shape, k_shape):
 def _concrete_multi_device(x) -> bool:
     """True iff ``x`` is a concrete array sharded across >1 device: a bare
     pallas_call on it would be GSPMD-replicated (all-gather + redundant
-    compute; round-1 ADVICE), so dispatch declines outside a mesh context."""
+    compute), so dispatch declines outside a mesh context."""
     try:
         sh = getattr(x, "sharding", None)
         return sh is not None and len(sh.device_set) > 1
@@ -923,44 +923,16 @@ def _ce_kernel(logits_ref, tgt_ref, loss_ref, lse_ref, m_s, s_s, p_s, *, BN, BV)
         loss_ref[...] = lse - p_s[...]
 
 
-def _tuning_path() -> str:
-    return os.environ.get(
-        "THUNDER_TPU_PALLAS_TUNING",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)), "pallas_tuning.json"),
-    )
-
-
-@functools.lru_cache(maxsize=1)
-def _tuning() -> dict:
-    """Measured kernel tuning, committed by tools/kernel_tune.py from a real
-    TPU run (a kernel that loses to XLA must win or yield).
-    Keys: ``ce.bn`` / ``ce.bv_cap`` (block geometry), ``ce.claim`` (default
-    **False** — the checker defers to the XLA lowering until a measurement
-    says otherwise).  No file means no tuning; a file that does not parse
-    raises, because it decides which kernel runs."""
-    import json
-
-    try:
-        with open(_tuning_path()) as f:
-            return json.load(f)
-    except FileNotFoundError:
-        return {}
-
-
 def _ce_blocks(n: int, v: int) -> tuple[int, int] | None:
-    tuned = _tuning().get("ce", {})
-    bn = next((b for b in (tuned.get("bn", 256), 256, 128, 64, 32, 16, 8)
-               if isinstance(b, int) and b > 0 and n % b == 0), None)
+    bn = next((b for b in (256, 128, 64, 32, 16, 8) if n % b == 0), None)
     if bn is None:
         return None
     # Widest lane-aligned (×128) divisor of v under a VMEM budget: wider
-    # vocab tiles mean fewer grid steps and longer DMA bursts.  Round 3 lost
-    # 3% to XLA at V=32000 because the old power-of-two divisor list picked
-    # BV=256; 32000 = 128·250 admits BV=3200 under the same budget.
-    bv_cap = int(tuned.get("bv_cap", 4096))
+    # vocab tiles mean fewer grid steps and longer DMA bursts (32000 =
+    # 128·250 admits BV=3200 where a power-of-two list stops at 256).
     budget = 4 * 1024 * 1024  # f32 block bytes; pallas double-buffers on top
     bv = None
-    for k in range(min(v, bv_cap) // 128, 0, -1):
+    for k in range(min(v, 4096) // 128, 0, -1):
         b = k * 128
         if v % b == 0 and bn * b * 4 <= budget:
             bv = b
@@ -1067,38 +1039,6 @@ def flash_cross_entropy(logits, target):
         ((P(row, None), P(row)), (P(row), P(row))),
     )
 
-
-def _ce_full(logits, target):
-    res = flash_cross_entropy(logits, target)
-    if res is None:
-        from thunder_tpu.executors.jaxex import _cross_entropy_fwd_reference
-
-        return _cross_entropy_fwd_reference(logits, target)
-    return res
-
-
-_ce_op = ex.register_operator(
-    "pallas_cross_entropy", like=prim_lookup[PrimIDs.CROSS_ENTROPY_FWD], fn=_ce_full
-)
-
-
-def _ce_checker(logits, target):
-    # Default is YIELD: the kernel was last *measured* losing to XLA on the
-    # default geometry, and win-or-yield says an unmeasured claim is a
-    # regression risk.  A fresh TPU measurement (tools/kernel_tune.py)
-    # writes ``ce.claim: true`` into pallas_tuning.json to re-arm it.
-    if not _tuning().get("ce", {}).get("claim", False):
-        return False
-    try:
-        from thunder_tpu.core import dtypes as _dt
-
-        jdt = _dt.to_jax_dtype(logits.dtype)
-    except Exception:
-        return False
-    return _enabled() and _ce_supported(tuple(logits.shape), tuple(target.shape), jdt)
-
-
-ex.register_implementation(PrimIDs.CROSS_ENTROPY_FWD, _ce_op, checker=_ce_checker)
 
 # ---------------------------------------------------------------------------
 # Paged-attention decode: flash-decoding over the serving KV block arena.

@@ -470,7 +470,7 @@ _generate_cache: dict = {}
 
 def _compiled_generate(cfg, B, T_prompt, max_new_tokens, T_max, temperature, quantized, dtype_str):
     """Jitted prefill/decode pair, cached per static configuration so
-    repeated generate() calls (and benchmarks) hit steady-state compiled
+    repeated generate() calls hit steady-state compiled
     programs instead of re-tracing."""
     import dataclasses
 

@@ -24,7 +24,7 @@ instrumented symbol's outputs are scanned for NaN/Inf and the first hit
 raises a structured :class:`AnomalyError` naming the symbol, the user
 file:line(s) that produced it, the offending output, and a one-command repro
 hint.  The scan synchronizes on each symbol's outputs — this is a debugging
-mode, not a production one; ``bench.py anomaly`` measures the cost.
+mode, not a production one.
 
 Both features are off by default, and off means OFF: the pass never runs and
 the generated execution program is byte-identical to the uninstrumented one

@@ -636,7 +636,7 @@ class ServingEngine:
             self._m_overlap = reg0.gauge("serving.step.overlap_frac")
         self._compile_log: list[dict] = []               # per-bucket compile causes
         # serving-plane observability (all off by default; the off path is
-        # one `is None` check per touch point — measured by bench.py tracing)
+        # one `is None` check per touch point)
         if trace is None:
             trace = serving_trace_env_enabled()
         self._tracer = RequestTracer() if trace else None

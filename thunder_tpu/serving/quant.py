@@ -24,7 +24,8 @@ coordinate, i.e. an absmax over the ``head_size`` values of one token's K
 Capacity math: a stored slot-head costs ``hs`` bytes (int8 or fp8) plus 4
 bytes of scale instead of ``hs * itemsize`` — ``hs*4 / (hs+4)`` more blocks
 per arena byte vs a float32 pool (3.2x at ``hs=16``, 3.76x at ``hs=64``;
-``bench.py capacity`` gates the measured admitted-concurrency win).  int8
+``tests/test_serving_invariants.py`` holds the admitted-concurrency multiple
+on a live engine).  int8
 and fp8 cost identical bytes; they differ only in error shape.
 
 Error model: absmax int8 keeps ~2 decimal digits; expect ~1e-2 relative
@@ -215,8 +216,7 @@ def scatter_blocks_q(arena, scale_arena, dense, dest_table):
 
 def arena_block_bytes(cfg, block_size: int, dtype, kv_dtype=None) -> int:
     """Bytes ONE pool block costs across both (K+V) arenas, including the
-    scale arenas on the int8 path — the unit of byte-based capacity math
-    (``bench.py capacity`` sizes equal-byte pools with this)."""
+    scale arenas on the int8 path — the unit of byte-based capacity math."""
     L, ng, bs, hs = kv_block_shape(cfg, block_size)
     storage = resolve_kv_dtype(kv_dtype, dtype)
     per_side = L * ng * bs * hs * storage.itemsize
@@ -228,6 +228,6 @@ def arena_block_bytes(cfg, block_size: int, dtype, kv_dtype=None) -> int:
 def blocks_for_arena_bytes(cfg, block_size: int, budget_bytes: int, dtype,
                            kv_dtype=None) -> int:
     """Total blocks (sink included) an arena-byte budget affords — the
-    equal-bytes pool sizing behind the capacity bench."""
+    equal-bytes pool sizing."""
     bb = arena_block_bytes(cfg, block_size, dtype, kv_dtype)
     return max(int(budget_bytes) // bb, 2)

@@ -22,8 +22,8 @@ Bit-identity: batches come from ``batch_for_step(step)`` — a pure function
 of the step index — and checkpoints capture (params, opt_state) *after*
 step ``s`` under the name ``s+1`` (steps completed).  A replay therefore
 re-executes the exact program on the exact inputs, and the final loss
-curve is bit-identical to the undisturbed run's (the acceptance gate
-``bench.py scaling`` measures).
+curve is bit-identical to the undisturbed run's (``tests/test_train_loop.py``
+holds it).
 """
 from __future__ import annotations
 

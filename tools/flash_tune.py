@@ -19,8 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import require_tpu
 from chipbench import trace
+from thunder_tpu._platform import device_info
 from thunder_tpu.executors import pallasex as px
 from thunder_tpu.executors.jaxex import _sdpa_backward_reference, _sdpa_reference
 
@@ -90,7 +90,11 @@ def main():
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--window", type=int, help="another window for the mistral shape (to place _block's rule)")
     args = ap.parse_args()
-    print(require_tpu("flash_tune"), flush=True)
+    device = device_info()
+    if device["platform"] != "tpu":
+        sys.exit(f"flash_tune: times the kernels on a device and needs a TPU; jax found "
+                 f"{device['platform']!r} ({device['kind']}).  Nothing was measured.")
+    print(device, flush=True)
     if args.window:
         SHAPES["mistral"] = (*SHAPES["mistral"][:5], args.window)
     if args.check and check() > 0.02:   # bfloat16 operands: 0.003-0.006

@@ -130,20 +130,25 @@ def test_every_leaf_of_the_model_is_compared():
 # the chunked gated delta rule against the recurrence, token by token
 # --------------------------------------------------------------------------
 
-def _recurrence(q, k, v, g, beta):
+def _recurrence_and_states(q, k, v, g, beta):
+    """o, and the float32 state before every token, (B, Hv, T, dk, dv)."""
     rep = v.shape[1] // q.shape[1]
     q, k = jnp.repeat(q, rep, 1), jnp.repeat(k, rep, 1)
 
     def head(q, k, v, g, b):
-        def step(S, x):
+        def step(S0, x):
             qt, kt, vt, gt, bt = x
-            S = S * jnp.exp(gt)
+            S = S0 * jnp.exp(gt)
             d = (vt - S.T @ kt) * bt
             S = S + jnp.outer(kt, d)
-            return S, S.T @ qt
+            return S, (S.T @ qt, S0)
         return jax.lax.scan(step, jnp.zeros((k.shape[-1], v.shape[-1])), (q, k, v, g, b))[1]
 
     return jax.vmap(jax.vmap(head))(q, k, v, g, beta)
+
+
+def _recurrence(q, k, v, g, beta):
+    return _recurrence_and_states(q, k, v, g, beta)[0]
 
 
 def _scan_inputs(Tn, decay, B=2, Hk=2, Hv=4, dk=16, dv=24):
@@ -165,9 +170,10 @@ DECAYS = {"g_near_0": 1e-3, "g_moderate": 1.0, "g_strongly_negative": 12.0}
 @pytest.mark.parametrize("Tn", [150, 192], ids=["T_not_whole_chunks", "T_whole_chunks"])
 def test_chunked_xla_matches_recurrence_forward_and_backward(Tn, decay):
     args = _scan_inputs(Tn, DECAYS[decay])
-    assert rel(jaxex._gdn_chunked(*args, 64), _recurrence(*args)) < 1e-5
+    o, states = jaxex._gdn_chunked(*args, 64)
+    assert rel(o, _recurrence(*args)) < 1e-5
     w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
-    got = jaxex._gdn_chunk_backward_impl(w, *args, 64)
+    got = jaxex._gdn_chunk_backward_impl(w, *args, states, 64)
     ref = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
     assert max(rel(a, b) for a, b in zip(got, ref)) < 1e-4
 
@@ -184,7 +190,7 @@ def test_pallas_gdn_chunk_fwd_matches_recurrence(interpreted, Tn, decay):
     got = px.gdn_chunk(*args, 64)
     assert got is not None
     # the inverse and its products run in three bfloat16 passes: about 16 bits
-    assert rel(got, _recurrence(*args)) < 5e-5
+    assert rel(got[0], _recurrence(*args)) < 5e-5
 
 
 @pytest.mark.parametrize("decay", sorted(DECAYS))
@@ -192,9 +198,66 @@ def test_pallas_gdn_chunk_fwd_matches_recurrence(interpreted, Tn, decay):
 def test_pallas_gdn_chunk_bwd_matches_recurrence(interpreted, Tn, decay):
     args = _scan_inputs(Tn, DECAYS[decay], B=1)
     w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
-    got = px.gdn_chunk_backward(w, *args, 64)
+    got = px.gdn_chunk_backward(w, *args, px.gdn_chunk(*args, 64)[1], 64)
     ref = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
     assert got is not None and max(rel(a, b) for a, b in zip(got, ref)) < 1e-4
+
+
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+@pytest.mark.parametrize("Tn", [192, 1024, 1536], ids=["one_block", "two_blocks", "three_blocks"])
+@pytest.mark.parametrize("executor", ["pallas", "xla"])
+def test_saved_states_are_the_recurrences_at_every_stride_boundary(interpreted, executor, Tn, decay):
+    """What the forward pass keeps for the backward: the state a value head
+    before each block of ``gdn_state_stride`` tokens, from both executors."""
+    from thunder_tpu.core.prims import gdn_state_stride
+
+    args = _scan_inputs(Tn, DECAYS[decay], B=1)
+    stride = gdn_state_stride(Tn)
+    assert stride == (Tn if Tn % 512 else 512)
+    _, states = px.gdn_chunk(*args, 64) if executor == "pallas" else jaxex._gdn_chunked(*args, 64)
+    assert states.shape == (1, 4, Tn // stride, 16, 24) and states.dtype == jnp.float32
+    assert not states[:, :, 0].any(), "a sequence starts from an empty state"
+    if Tn > stride:
+        assert rel(states[:, :, 1:], _recurrence_and_states(*args)[1][:, :, stride::stride]) < 5e-5
+
+
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+def test_backward_from_saved_states_over_three_blocks(interpreted, decay):
+    """``gdn_chunk_backward`` takes the states of either executor's forward
+    pass (the XLA scan's here) and holds the recurrence's gradients."""
+    args = _scan_inputs(1536, DECAYS[decay], B=1)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    got = px.gdn_chunk_backward(w, *args, jaxex._gdn_chunked(*args, 64)[1], 64)
+    ref = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
+    assert got is not None and max(rel(a, b) for a, b in zip(got, ref)) < 1e-4
+
+
+def test_backward_trace_holds_no_second_forward_and_the_states_are_saved(interpreted):
+    """Through ``tt.jit`` with the default remat policy: the backward trace
+    calls ``gdn_chunk_backward`` and no ``gdn_chunk``; ``states`` (an anchor's
+    output: the prim is a ``MATMUL_OP``) is among what the forward pass saves;
+    ``pallasex.gdn_schedule`` says what was built."""
+    import thunder_tpu.torch as ltorch
+    from thunder_tpu.core.transforms import flatten_to_prims
+
+    args = _scan_inputs(1024, DECAYS["g_moderate"], B=1)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    vg = tt.value_and_grad(lambda q, k, v, g, b, w_: ltorch.sum(ltorch.gated_delta_rule(
+        ltorch.tanh(q), k, v, g, b) * w_), argnums=(0, 1, 2, 3, 4))
+    loss, grads = vg(*args, w)
+    fw, bw = tt.last_traces(vg)[-1], tt.last_backward_traces(vg)[-1]
+    names = lambda trace: [b.sym.name for b in flatten_to_prims(trace.bound_symbols)]   # noqa: E731
+    called = lambda trace, what: sum(n.endswith(what) for n in names(trace))   # noqa: E731
+    assert called(fw, "gdn_chunk") == 1 and called(bw, "gdn_chunk_backward") == 1
+    assert called(bw, "gdn_chunk") == 0, names(bw)
+    saved = [p for p in bw.args if getattr(p, "shape", None) == (1, 4, 2, 16, 24)]
+    assert len(saved) == 1 and "float32" in str(saved[0].dtype), [(p.name, p.shape) for p in bw.args]
+    assert px.gdn_schedule == {"state_stride_tokens": 512, "states_saved_bytes": 4 * 4 * 2 * 16 * 24,
+                               "forward_calls_in_backward": 0, "chunks_a_product": 1}
+    qt = jnp.tanh(args[0])
+    ref = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * w), argnums=(0, 1, 2, 3, 4))(qt, *args[1:])
+    assert max(rel(a, b) for a, b in zip(grads[1:], ref[1:])) < 1e-4
+    assert rel(grads[0], ref[0] * (1 - qt ** 2)) < 1e-4
 
 
 @pytest.mark.parametrize("decay", ["g_near_0", "g_strongly_negative"])
@@ -214,10 +277,20 @@ def test_gated_delta_rule_prim_through_jit_and_its_backward_rule(interpreted, de
     assert max(rel(a, b) for a, b in zip(grads, ref)) < 1e-4
 
 
+@pytest.mark.parametrize("head,dtype,fits", [(128, "bfloat16", True), (128, "float32", True), (256, "bfloat16", True),
+                                             (256, "float32", False), (384, "bfloat16", False)])
+def test_pallas_declines_a_block_whose_chunks_would_not_fit_vmem(interpreted, head, dtype, fits):
+    """``gdn_chunk_bwd`` keeps a block's chunks in VMEM between its walks; the
+    line is where Mosaic's own refusal sits (``tests/test_pallas_tpu_lowering.py``
+    compiles the widest that pass)."""
+    assert px._gdn_supported((1, 2, 1024, head), (1, 4, 1024, head), jnp.dtype(dtype), 64) is fits
+
+
 def test_pallas_declines_what_it_cannot_tile_and_xla_pads(interpreted):
     args = _scan_inputs(150, 1.0)
     assert px.gdn_chunk(*args, 64) is None
-    assert rel(jaxex._gdn_chunk_impl(*args, 64), _recurrence(*args)) < 1e-5
+    o, states = jaxex._gdn_chunk_impl(*args, 64)
+    assert rel(o, _recurrence(*args)) < 1e-5 and states.shape == (2, 4, 1, 16, 24)
 
 
 # --------------------------------------------------------------------------

@@ -22,7 +22,10 @@ padding mask and a mask block a grid step (the transposes of
 ``_flash_bwd_dkv``), and at the train cells' own kinds: a window that spans
 several blocks of 1024, head 256 with 8 query heads a group, and the widest
 tiles blocks of 1024 are derived for (float32 at head 128; a padding mask's
-row over the whole rectangle).
+row over the whole rectangle).  The scan kernels (``gdn_chunk_fwd``,
+``gdn_chunk_bwd``) are compiled with the log-decay and beta a row a chunk and
+the state a block, at the widest heads ``_gdn_supported`` lets through, and at
+the hybrid cell's own shape, where no float32 column may reach them.
 """
 from __future__ import annotations
 
@@ -158,19 +161,22 @@ def _cases(nh: int, ng: int) -> dict:
     cases["flash_cross_entropy"] = (
         px._flash_ce.__wrapped__, [((256, 2048), BF), ((256,), I32)])
     # the chunked gated delta rule (2 key heads, 4 value heads, two blocks of
-    # 8 chunks) and the grouped products over sorted rows (8 tiles, 4 groups)
+    # 8 chunks; the log-decay and beta a row a chunk, the state a block) and
+    # the grouped products over sorted rows (8 tiles, 4 groups)
     Tg, Cg = 1024, 64
+
+    def gdn_bwd_specs(hs, dt):        # do, then the forward's q, k, v, G, beta, then the states
+        qk, v, row = ((2, Tg, hs), dt), ((4, Tg, hs), dt), ((4, Tg // Cg, Cg), F32)
+        return [v, qk, qk, v, row, row, ((4, Tg // 512, hs, hs), F32)]
+
+    gdn_bwd = lambda do, q_, k, v, g, b, st: px._gdn_bwd.__wrapped__(do, q_, k, v, g, b, st, 2, 4, Cg, 512)  # noqa: E731
     cases["gdn_chunk_fwd"] = (
-        lambda q_, k, v, gc, gr, b: px._gdn_fwd.__wrapped__(q_, k, v, gc, gr, b, 2, 4, Cg, 512),
-        [((2, Tg, HS), BF), ((2, Tg, HS), BF), ((4, Tg, HS), BF), ((4, Tg, 1), F32),
-         ((4, Tg // Cg, Cg), F32), ((4, Tg, 1), F32)])
-    gdn_ops = cases["gdn_chunk_fwd"][1]
-    cases["gdn_chunk_fwd/states"] = (
-        lambda q_, k, v, gc, gr, b: px._gdn_fwd.__wrapped__(q_, k, v, gc, gr, b, 2, 4, Cg, 512, emit_states=True),
-        gdn_ops)
-    cases["gdn_chunk_bwd"] = (
-        lambda do, q_, k, v, gc, gr, b, st: px._gdn_bwd.__wrapped__(do, q_, k, v, gc, gr, b, st, 2, 4, Cg, 512),
-        [((4, Tg, HS), BF), *gdn_ops, ((4, Tg // Cg, HS, HS), F32)])
+        lambda q_, k, v, g, b: px._gdn_fwd.__wrapped__(q_, k, v, g, b, 2, 4, Cg, 512), gdn_bwd_specs(HS, BF)[1:6])
+    cases["gdn_chunk_bwd"] = (gdn_bwd, gdn_bwd_specs(HS, BF))
+    # the widest blocks ``_gdn_supported`` lets through: what a block's chunks
+    # keep live between the kernel's walks has to fit beside the operands
+    cases["gdn_chunk_bwd/float32"] = (gdn_bwd, gdn_bwd_specs(HS, F32))
+    cases["gdn_chunk_bwd/head256"] = (gdn_bwd, gdn_bwd_specs(2 * HS, BF))
     rows, tiles, plan = ((1024, 256), BF), ((8,), I32), ((1,), I32)
     cases["moe_grouped_mm"] = (
         px._moe_grouped_mm.__wrapped__, [rows, ((4, 256, 512), BF), tiles, plan])
@@ -217,6 +223,36 @@ def test_kernel_lowers_and_compiles_for_tpu(shape, kernel, tpu_sharding):
         compiled = lowered.compile().as_text()
         for name in kernel_names(kernel):
             assert re.search(rf"%{name}(\.\d+)? = ", compiled), (kernel, name)
+
+
+@pytest.mark.parametrize("kernel", ["gdn_chunk_fwd", "gdn_chunk_bwd"])
+def test_scan_kernels_at_the_hybrid_cell_take_rows_not_columns(kernel, tpu_sharding, monkeypatch):
+    """The chunked gated delta rule as the hybrid cell calls it (two sequences
+    of 8192 tokens, 16 key and 32 value heads of 128): the log-decay, beta
+    and their gradients reach the kernels a row a chunk.  A ``(..., T, 1)``
+    float32 operand or result is a column of 128-lane tiles, 128 times its
+    bytes in HBM and in the copies XLA puts before the call (268 MB each
+    here); the backward call takes the state a block of 512 tokens and no
+    forward call comes before it."""
+    monkeypatch.setattr(px, "_interpret", lambda: False)
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    B_, Hk, Hv, Ts = 2, 16, 32, 8192
+    qk, v, g = ((B_, Hk, Ts, HS), BF), ((B_, Hv, Ts, HS), BF), ((B_, Hv, Ts), F32)
+    if kernel == "gdn_chunk_fwd":
+        fn, specs = px.gdn_chunk, [qk, qk, v, g, g]
+    else:
+        fn, specs = px.gdn_chunk_backward, [v, qk, qk, v, g, g, ((B_, Hv, Ts // 512, HS, HS), F32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and f'kernel_name = "{kernel}"' in text
+    assert not re.search(r"tensor<(\d+x)+1xf32>", text), "a float32 column reaches the kernel"
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        call = next(l for l in compiled.as_text().splitlines() if re.search(rf"%{kernel}(\.\d+)? = ", l))
+        assert not re.search(r"f32\[(\d+,)+1\]", call), call
+        if kernel == "gdn_chunk_fwd":                        # no padded copy, no temporary at all
+            assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def _decode_args(nh, ng, hs, bs, rows, width, pool, layers, store, sharding):

@@ -140,9 +140,10 @@ class TestTools:
         for t in tools:
             py_compile.compile(str(t), doraise=True)
 
-    def test_flash_tune_without_a_tpu_exits_nonzero_and_prints_no_result(self):
+    @pytest.mark.parametrize("tool", ["flash_tune.py", "gdn_tune.py"])
+    def test_kernel_timers_without_a_tpu_exit_nonzero_and_print_no_result(self, tool):
         proc = subprocess.run(
-            [sys.executable, str(self.TOOLS / "flash_tune.py")],
+            [sys.executable, str(self.TOOLS / tool)],
             capture_output=True, text=True, timeout=600,
             env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )

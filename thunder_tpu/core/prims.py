@@ -1245,6 +1245,13 @@ fused_linear_ce_backward = make_prim(
 GDN_CHUNK = 64
 
 
+def gdn_state_stride(T: int) -> int:
+    """Tokens between two states that :func:`gdn_chunk` saves for its backward
+    pass: the Pallas kernels' block, or the whole sequence where that does not
+    divide it (one block, one state)."""
+    return 512 if T % 512 == 0 else T
+
+
 def _gdn_check(q, k, v, g, beta) -> None:
     for t in (q, k, v, g, beta):
         _check_tensor(t)
@@ -1257,29 +1264,43 @@ def _gdn_check(q, k, v, g, beta) -> None:
           lambda: f"gdn_chunk: g/beta must be (B, Hv, T), got {g.shape}/{beta.shape}")
 
 
-def _gdn_chunk_meta(q: TensorProxy, k: TensorProxy, v: TensorProxy, g: TensorProxy, beta: TensorProxy) -> TensorProxy:
+def _gdn_states_shape(q, v) -> tuple:
+    B, Hv, T, dv = v.shape
+    return (B, Hv, T // gdn_state_stride(T), q.shape[3], dv)
+
+
+def _gdn_chunk_meta(q: TensorProxy, k: TensorProxy, v: TensorProxy, g: TensorProxy,
+                    beta: TensorProxy) -> tuple[TensorProxy, TensorProxy]:
     """The gated delta rule over a whole sequence, by chunks.  Per value head
     (key head ``h // (Hv // Hk)``), with ``S (dk, dv)`` float32, zero at the
     sequence start: ``S <- S * exp(g_t)``; ``d_t = (v_t - S^T k_t) * beta_t``;
     ``S <- S + k_t d_t^T``; ``o_t = S^T q_t``.  ``g`` is the float32 log of
     the decay (<= 0).  Executors run the chunked form (a triangular solve
     inside each chunk of ``GDN_CHUNK`` tokens, the state carried between chunks),
-    never a T-step loop.  Returns ``o (B, Hv, T, dv)`` in ``v``'s dtype."""
+    never a T-step loop.  Returns ``(o, states)``: ``o (B, Hv, T, dv)`` in
+    ``v``'s dtype, and the float32 ``S`` before tokens 0, ``stride``, ``2
+    stride``, ... (:func:`gdn_state_stride`) as ``(B, Hv, T / stride, dk,
+    dv)``: the residual the backward pass starts each block from, as ``sdpa``
+    returns its ``lse``."""
     _gdn_check(q, k, v, g, beta)
     rg = any(t.requires_grad for t in (q, k, v, g, beta)) and dtypes.is_inexact_dtype(v.dtype)
-    return _out_like(v, requires_grad=rg)
+    states = TensorProxy(shape=_gdn_states_shape(q, v), device=v.device, dtype=dtypes.float32, requires_grad=False)
+    return _out_like(v, requires_grad=rg), states
 
 
 gdn_chunk = make_prim(PrimIDs.GDN_CHUNK, "gdn_chunk", meta=_gdn_chunk_meta, tags=(OpTags.MATMUL_OP,))
 
 
 def _gdn_chunk_backward_meta(do: TensorProxy, q: TensorProxy, k: TensorProxy, v: TensorProxy, g: TensorProxy,
-                             beta: TensorProxy):
+                             beta: TensorProxy, states: TensorProxy):
     """Gradients of :func:`gdn_chunk` in all five operands from the output's
-    cotangent; the forward pass is computed again chunk by chunk, so nothing
-    but the operands is saved."""
+    cotangent and the saved ``states``: each block's chunks are walked again
+    from the block's starting state, so nothing else is saved."""
     _check_tensor(do)
+    _check_tensor(states)
     _gdn_check(q, k, v, g, beta)
+    check(tuple(states.shape) == _gdn_states_shape(q, v),
+          lambda: f"gdn_chunk_backward: states must be {_gdn_states_shape(q, v)}, got {tuple(states.shape)}")
     return tuple(_out_like(t, requires_grad=False) for t in (q, k, v, g, beta))
 
 

@@ -739,11 +739,20 @@ _fused_linear_ce_bw._accepts_none_cotangents = True
 
 
 @register_backward_rule(PrimIDs.GDN_CHUNK)
-def _gdn_chunk_bw(bsym, g_out):
-    """Saved: the five operands.  The backward prim runs the chunked forward
-    again and differentiates it chunk by chunk."""
+def _gdn_chunk_bw(bsym, g_out, g_states):
+    """Saved: the five operands and ``states``, the float32 state at each
+    block's start (``prims.gdn_state_stride`` tokens apart).  The backward
+    prim rebuilds a block's chunks from its state and runs no forward pass."""
     q, k, v, g, beta = bsym.args
-    return list(zip((q, k, v, g, beta), prims.gdn_chunk_backward(g_out, q, k, v, g, beta)))
+    out, states = bsym.output
+    if g_states is not None:
+        raise NotImplementedError("differentiating through gdn_chunk's saved states is not supported")
+    if g_out is None:
+        g_out = clang.full_like(out, 0.0)
+    return list(zip((q, k, v, g, beta), prims.gdn_chunk_backward(g_out, q, k, v, g, beta, states)))
+
+
+_gdn_chunk_bw._accepts_none_cotangents = True
 
 
 @register_backward_rule(PrimIDs.CAUSAL_CONV1D)

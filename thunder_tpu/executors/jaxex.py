@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from thunder_tpu.core import dtypes
 from thunder_tpu.core.devices import Device, to_jax_device
-from thunder_tpu.core.prims import GDN_CHUNK, MOE_ROW_TILE, PrimIDs, prim_lookup
+from thunder_tpu.core.prims import GDN_CHUNK, MOE_ROW_TILE, PrimIDs, gdn_state_stride, prim_lookup
 from thunder_tpu.extend import OperatorExecutor, add_always_executor, add_default_executor, register_executor
 
 __all__ = ["ex", "jax_ex", "get_prim_impl", "prim_impls"]
@@ -690,12 +690,13 @@ def _fused_linear_ce_backward_impl(g, h, w, target, lse, ignore_index=-100):
 # Chunked gated delta rule.  ``_gdn_chunked`` is the plain XLA decomposition
 # (everything that does not depend on the state is batched over all chunks;
 # a ``lax.scan`` over chunks carries the state through three small batched
-# products a step).  It is the executor of last resort and, with its
+# products a step, inside a scan over the blocks whose starting states the
+# prim returns).  It is the executor of last resort and, with its
 # derivative (``_gdn_chunked_backward``), the oracle of the Pallas
 # ``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` kernels, which pallasex.py installs
 # into the two hooks below.
-_gdn_fast_path: Callable | None = None  # (q, k, v, g, beta, chunk) -> o or None
-_gdn_bwd_fast_path: Callable | None = None  # (do, q, k, v, g, beta, chunk) -> five gradients or None
+_gdn_fast_path: Callable | None = None  # (q, k, v, g, beta, chunk) -> (o, states) or None
+_gdn_bwd_fast_path: Callable | None = None  # (do, q, k, v, g, beta, states, chunk) -> five gradients or None
 
 
 def _unit_lower_inverse(A):
@@ -717,8 +718,10 @@ def _unit_lower_inverse(A):
 
 def _gdn_chunked(q, k, v, g, beta, chunk):
     """q, k ``(B, Hk, T, dk)``, v ``(B, Hv, T, dv)``, g, beta ``(B, Hv, T)``
-    -> o ``(B, Hv, T, dv)``.  T is padded to whole chunks with tokens that
-    leave the state alone (g = 0, beta = 0)."""
+    -> o ``(B, Hv, T, dv)`` and the float32 state at the start of every block
+    of ``gdn_state_stride(T)`` tokens, ``(B, Hv, blocks, dk, dv)``.  T is
+    padded to whole chunks with tokens that leave the state alone (g = 0,
+    beta = 0)."""
     B, Hk, T, dk = q.shape
     Hv, dv = v.shape[1], v.shape[3]
     rep = Hv // Hk
@@ -754,11 +757,16 @@ def _gdn_chunked(q, k, v, g, beta, chunk):
         S = jnp.exp(gl)[..., None, None] * S + jnp.einsum("bhik,bhid->bhkd", Kd_, D)
         return S, o
 
-    lead = lambda a: jnp.moveaxis(a, 2, 0)  # noqa: E731 -- chunks first
-    _, o = jax.lax.scan(jax.checkpoint(step), jnp.zeros((B, Hv, dk, dv), f32),
-                        tuple(lead(a) for a in (U, W, QK, Qd, Kd, Glast)))
-    o = jnp.moveaxis(o, 0, 2).reshape(B, Hv, T + pad, dv)
-    return o[:, :, :T].astype(v.dtype)
+    def block(S, xs):
+        S_next, o = jax.lax.scan(jax.checkpoint(step), S, xs)
+        return S_next, (o, S)
+
+    nb = T // gdn_state_stride(T)                                      # 1 where the stride is the sequence
+    lead = lambda a: jnp.moveaxis(a, 2, 0).reshape(nb, n // nb, *a.shape[:2], *a.shape[3:])  # noqa: E731 -- blocks, chunks first
+    _, (o, states) = jax.lax.scan(block, jnp.zeros((B, Hv, dk, dv), f32),
+                                  tuple(lead(a) for a in (U, W, QK, Qd, Kd, Glast)))
+    o = jnp.moveaxis(o.reshape(n, B, Hv, C, dv), 0, 2).reshape(B, Hv, T + pad, dv)
+    return o[:, :, :T].astype(v.dtype), jnp.moveaxis(states, 0, 2)
 
 
 @impl(PrimIDs.GDN_CHUNK)
@@ -773,15 +781,15 @@ def _gdn_chunk_impl(q, k, v, g, beta, chunk=GDN_CHUNK):
 def _gdn_chunked_backward(do, q, k, v, g, beta, chunk):
     """The XLA chunked form differentiated: the forward scan again (each
     chunk's step checkpointed, so only the carried states are kept), then
-    the backward scan."""
-    _, vjp = jax.vjp(lambda *a: _gdn_chunked(*a, chunk), q, k, v, g, beta)
+    the backward scan.  The oracle: it takes no saved states."""
+    _, vjp = jax.vjp(lambda *a: _gdn_chunked(*a, chunk)[0], q, k, v, g, beta)
     return vjp(do)
 
 
 @impl(PrimIDs.GDN_CHUNK_BACKWARD)
-def _gdn_chunk_backward_impl(do, q, k, v, g, beta, chunk=GDN_CHUNK):
+def _gdn_chunk_backward_impl(do, q, k, v, g, beta, states, chunk=GDN_CHUNK):
     if _gdn_bwd_fast_path is not None:
-        res = _gdn_bwd_fast_path(do, q, k, v, g, beta, chunk)
+        res = _gdn_bwd_fast_path(do, q, k, v, g, beta, states, chunk)
         if res is not None:
             return res
     return _gdn_chunked_backward(do, q, k, v, g, beta, chunk)

@@ -31,6 +31,7 @@ import contextlib
 import contextvars
 import functools
 import os
+import types
 from typing import Callable
 
 import jax
@@ -39,7 +40,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from thunder_tpu.core.prims import GDN_CHUNK, PrimIDs, prim_lookup
+from thunder_tpu.core.prims import GDN_CHUNK, PrimIDs, gdn_state_stride, prim_lookup
 from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_executor
 
 __all__ = [
@@ -1874,25 +1875,42 @@ def lora_delta_fused(x, a, b, scaling):
 
 
 # ---------------------------------------------------------------------------
-# Chunked gated delta rule: ``gdn_chunk_fwd``.
+# Chunked gated delta rule: ``gdn_chunk_fwd`` and ``gdn_chunk_bwd``.
 #
-# Grid (value head, block of NC chunks); the chunk axis is sequential and a
+# Grid (value head, block of NC chunks); the block axis is sequential and a
 # VMEM scratch carries the head's (dk, dv) float32 state from block to block.
 # A chunk of C tokens: the decay matrix M_ij = exp(G_i - G_j) (j <= i) from the
-# within-chunk cumulative log-decay G (made outside, handed in once as a column
-# and once as rows, so the kernel never transposes), the strictly lower
-# A = beta * M * K K^T, its unit-triangular inverse by squarings (products
-# only), then five products against the carried state.  q and k come from
-# their key head by index map (no repeat in HBM).  The backward pass,
-# ``gdn_chunk_bwd``, runs the forward kernel again for the state before every
-# chunk, then walks the blocks from the last to the first with the gradient in
-# the state carried the other way; each chunk's terms are made again from the
-# operands (nothing but the operands is saved between the passes).  Its oracle
-# is the XLA chunked form differentiated (jaxex ``_gdn_chunked_backward``).
+# within-chunk cumulative log-decay G, the strictly lower A = beta * M * K K^T,
+# its unit-triangular inverse by squarings (products only), then five products
+# against the carried state.  q and k come from their key head by index map
+# (no repeat in HBM).
+#
+# A chunk is a chain of small dependent products (ten in the inverse alone,
+# 64 rows each), and Mosaic runs the kernel body nearly as written: chunk after
+# chunk, a call was one chain's latencies end to end.  So what the state does
+# not enter is written for all of a block's chunks together, a product at a
+# time (``_gdn_block_terms``), and the walks that do need the state (two
+# dependent products a chunk, ``_gdn_state_walk`` and its mirror for the
+# gradient) carry the work that hangs off them between their steps.  On one
+# v5e (PERF.md, PR 31) that took a forward call from 11.0 to 6.2 ms and a
+# backward call from 17.2 to 9.9; two chunks a product (a block-diagonal
+# system of 128 rows) was slower than one (6.6, 13.2): once the chains overlap
+# the array's passes bind, and the zero blocks cost theirs.
+#
+# ``G`` and ``beta`` reach the kernels a row a chunk, ``(BH, T/C, C)`` float32,
+# and their gradients leave so: a ``(BH, T, 1)`` column is 128-lane tiles, 128
+# times its bytes in HBM and in the copies XLA puts around the call.  A chunk's
+# row becomes the column the algebra wants in the kernel (``_column``).
+#
+# Between the passes the forward kernel keeps the state each *block* starts
+# from, ``(BH, T/TB, dk, dv)`` float32 (``prims.gdn_state_stride``: 67 MB a
+# layer at B 2, 32 heads of 128, T 8192).  ``gdn_chunk_bwd`` walks the blocks
+# from the last to the first with the gradient in the state carried the other
+# way; in a block it first walks forward from the saved state, keeping each
+# chunk's terms, ``D`` and starting state in VMEM, then backward.  No forward
+# call runs in the backward pass.  Its oracle is the XLA
+# chunked form differentiated (jaxex ``_gdn_chunked_backward``).
 # ---------------------------------------------------------------------------
-
-_GDN_BLOCK_TOKENS = 512
-
 
 def _dot3(a, b, dims):
     """A float32 product in three bfloat16 passes on the MXU (``hi hi + hi lo
@@ -1912,73 +1930,110 @@ _AB_T = (((1,), (1,)), ((), ()))     # a @ b^T
 _AT_B = (((0,), (0,)), ((), ()))     # a^T @ b
 
 
-def _gdn_chunk_terms(q, k, v, Gc, Gr, beta, row, col, eye):
-    """What a chunk's forward and backward share and the state does not enter:
-    the decay matrix ``M``, ``K K^T``, the unit-triangular inverse ``Tm`` (by
-    squarings, float32 in three passes), ``beta V``, ``beta e^G K`` and the
-    inverse applied to both."""
-    f32 = jnp.float32
+def _column(r, eye):
+    """A chunk's row ``(1, C)`` as a column ``(C, 1)``: eight vregs and a lane
+    reduction, once a chunk."""
+    return jnp.sum(eye * r, axis=1, keepdims=True)
+
+
+def _as_row(c, eye):
+    """A column ``(C, 1)`` as a row ``(1, C)``."""
+    return jnp.sum(eye * c, axis=0, keepdims=True)
+
+
+def _gdn_masks(C: int):
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    return (row == col).astype(jnp.float32), row >= col, row > col
+
+
+def _gdn_block_terms(k_ref, v_ref, g_ref, b_ref, C: int, NC: int, eye, low, strict):
+    """What the state does not enter, for every chunk of a block, as lists over
+    the chunks: the decay matrix ``M``, ``K K^T``, the unit-triangular inverse
+    ``Tm = (I + beta M K K^T)^-1`` (by squarings, float32 in three passes:
+    thirty of a chunk's MXU passes), ``beta V``, ``beta e^G K`` and the inverse
+    applied to both (``U``, ``W``), with the factors of the state's update.
+    Every product is written for all chunks before the next one: the chunks'
+    chains (ten dependent products in the inverse alone) do not depend on each
+    other, and Mosaic overlaps what is written side by side."""
+    f32, dt = jnp.float32, v_ref.dtype
     mm = functools.partial(_dot3, dims=_AB)
-    C = row.shape[0]
-    M = jnp.exp(jnp.where(row >= col, Gc - Gr, _MASK_VALUE))          # (C, C), 0 above the diagonal
-    kk = jax.lax.dot_general(k, k, _AB_T, preferred_element_type=f32)
-    P = -jnp.where(row > col, beta * M * kk, 0.0)
-    Tm = eye + P
+    ch = range(NC)
+    t = types.SimpleNamespace()
+    t.k = [k_ref[0, c * C:(c + 1) * C, :] for c in ch]
+    t.v = [v_ref[0, c * C:(c + 1) * C, :] for c in ch]
+    t.Gr = [g_ref[0, c:c + 1, :] for c in ch]
+    t.Gc = [_column(r, eye) for r in t.Gr]
+    t.beta = [_column(b_ref[0, c:c + 1, :], eye) for c in ch]
+    t.M = [jnp.exp(jnp.where(low, t.Gc[c] - t.Gr[c], _MASK_VALUE)) for c in ch]     # (C, C), 0 above the diagonal
+    t.kk = [jax.lax.dot_general(t.k[c], t.k[c], _AB_T, preferred_element_type=f32) for c in ch]
+    P = [-jnp.where(strict, t.beta[c] * t.M[c] * t.kk[c], 0.0) for c in ch]
+    t.Tm = [eye + P[c] for c in ch]
     n = 1
     while 2 * n < C:                                                    # (I + P)(I + P^2)(I + P^4)...
-        P = mm(P, P)
-        Tm = Tm + mm(Tm, P)
+        P = [mm(P[c], P[c]) for c in ch]
+        t.Tm = [t.Tm[c] + mm(t.Tm[c], P[c]) for c in ch]
         n *= 2
-    eG = jnp.exp(Gc)
-    Ub = beta * v.astype(f32)
-    Wb = beta * eG * k.astype(f32)
-    return M, kk, Tm, eG, Ub, Wb, mm(Tm, Ub), mm(Tm, Wb)
+    t.eG = [jnp.exp(t.Gc[c]) for c in ch]
+    t.Ub = [t.beta[c] * t.v[c].astype(f32) for c in ch]
+    t.Wb = [t.beta[c] * t.eG[c] * t.k[c].astype(f32) for c in ch]
+    t.U = [mm(t.Tm[c], t.Ub[c]) for c in ch]
+    t.W = [mm(t.Tm[c], t.Wb[c]).astype(dt) for c in ch]
+    # the chunk's last log-decay scales the carried state and every key of the update
+    t.glast = [t.Gc[c][C - 1:C, :] for c in ch]
+    t.e_last = [jnp.exp(t.glast[c] - t.Gc[c]) for c in ch]                           # (C, 1)
+    t.Kd = [t.e_last[c] * t.k[c].astype(f32) for c in ch]
+    # (1, 1) -> (dk, 1) -> (dk, dv): Mosaic broadcasts one way at a time
+    t.e_glast = [jnp.exp(jnp.broadcast_to(t.glast[c], (k_ref.shape[2], 1))) for c in ch]
+    return t
 
 
-def _gdn_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, b_ref, o_ref, *rest, C, NC, emit_states):
-    f32 = jnp.float32
-    st_ref, s_ref = rest if emit_states else (None, rest[0])
+def _gdn_state_walk(S, t, dt, beside):
+    """The walk that needs the state, two dependent products a chunk: ``D = U
+    - W S`` and ``S <- e^g S + Kd^T D``.  Returns each chunk's starting state,
+    each chunk's ``D`` and the state after the block.  ``beside(c, S, D)`` is
+    written between two steps: work that hangs off the chain and fills the
+    time its products are in flight."""
+    dot = functools.partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
+    starts, Ds = [], []
+    for c, (W, U, Kd, e_glast) in enumerate(zip(t.W, t.U, t.Kd, t.e_glast)):
+        starts.append(S)
+        Ds.append((U - dot(W, S.astype(dt), _AB)).astype(dt))
+        S = e_glast * S + dot(Kd.astype(dt), Ds[-1], _AT_B)
+        beside(c, starts[-1], Ds[-1])
+    return starts, Ds, S
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, s_ref, *, C, NC):
+    f32, dt = jnp.float32, v_ref.dtype
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    eye = (row == col).astype(f32)
-    ab_t, at_b = _AB_T, _AT_B
-    mm = functools.partial(jax.lax.dot_general, dimension_numbers=_AB, preferred_element_type=f32)
-    for c in range(NC):
-        sl = slice(c * C, (c + 1) * C)
-        q, k, v = q_ref[0, sl, :], k_ref[0, sl, :], v_ref[0, sl, :]
-        dt = v.dtype
-        Gc, Gr, beta = gc_ref[0, sl, :], gr_ref[0, c:c + 1, :], b_ref[0, sl, :]
-        M, _, _, eG, _, _, U, W = _gdn_chunk_terms(q, k, v, Gc, Gr, beta, row, col, eye)
-        S = s_ref[...]
-        if emit_states:
-            st_ref[0, c] = S
-        Sd = S.astype(dt)
-        D = U - mm(W.astype(dt), Sd)
-        QK = jnp.where(row >= col, M * jax.lax.dot_general(q, k, ab_t, preferred_element_type=f32), 0.0)
-        o = mm((eG * q.astype(f32)).astype(dt), Sd) + mm(QK.astype(dt), D.astype(dt))
-        o_ref[0, sl, :] = o.astype(o_ref.dtype)
-        glast = Gc[C - 1:C, :]
-        Kd = (jnp.exp(glast - Gc) * k.astype(f32)).astype(dt)
-        # (1, 1) -> (dk, 1) -> (dk, dv): Mosaic broadcasts one way at a time
-        s_ref[...] = jnp.exp(jnp.broadcast_to(glast, (S.shape[0], 1))) * S + jax.lax.dot_general(
-            Kd, D.astype(dt), at_b, preferred_element_type=f32)
+    st_ref[0, 0] = s_ref[...]          # the state this block starts from: what the backward pass keeps
+    eye, low, strict = _gdn_masks(C)
+    dot = functools.partial(jax.lax.dot_general, preferred_element_type=f32)
+    t = _gdn_block_terms(k_ref, v_ref, g_ref, b_ref, C, NC, eye, low, strict)
+
+    def read_out(c, S, D):
+        q = q_ref[0, c * C:(c + 1) * C, :]
+        QK = jnp.where(low, t.M[c] * dot(q, t.k[c], _AB_T), 0.0)
+        o = dot((t.eG[c] * q.astype(f32)).astype(dt), S.astype(dt), _AB) + dot(QK.astype(dt), D, _AB)
+        o_ref[0, c * C:(c + 1) * C, :] = o.astype(o_ref.dtype)
+
+    _, _, s_ref[...] = _gdn_state_walk(s_ref[...], t, dt, beside=read_out)
 
 
 def _gdn_specs(Hk: int, Hv: int, TB: int, C: int, dk: int, dv: int, block):
     """BlockSpecs of the kernels' shared operands (q, k from their key head;
-    v, the log-decay as a column and as rows, beta), with the block of a grid
-    step chosen by ``block(i)`` (forward: i; backward: last first)."""
+    v; the log-decay and beta, a row a chunk), with the block of a grid step
+    chosen by ``block(i)`` (forward: i; backward: last first)."""
     rep, NC = Hv // Hk, TB // C
     kv_head = lambda b, i: ((b // Hv) * Hk + (b % Hv) // rep, block(i), 0)  # noqa: E731
     own = lambda b, i: (b, block(i), 0)  # noqa: E731
     return own, [pl.BlockSpec((1, TB, dk), kv_head), pl.BlockSpec((1, TB, dk), kv_head),
-                 pl.BlockSpec((1, TB, dv), own), pl.BlockSpec((1, TB, 1), own),
-                 pl.BlockSpec((1, NC, C), own), pl.BlockSpec((1, TB, 1), own)]
+                 pl.BlockSpec((1, TB, dv), own), pl.BlockSpec((1, NC, C), own), pl.BlockSpec((1, NC, C), own)]
 
 
 def _seq_params():
@@ -1987,144 +2042,168 @@ def _seq_params():
     return {"compiler_params": pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))}
 
 
-@functools.partial(jax.jit, static_argnames=("Hk", "Hv", "C", "TB", "emit_states"))
-def _gdn_fwd(q, k, v, Gc, Gr, beta, Hk: int, Hv: int, C: int, TB: int, emit_states: bool = False):
-    """q, k (B*Hk, T, dk), v (B*Hv, T, dv), Gc, beta (B*Hv, T, 1) f32, Gr
-    (B*Hv, T/C, C) f32 -> o (B*Hv, T, dv); with ``emit_states`` also the
-    float32 state before each chunk, (B*Hv, T/C, dk, dv)."""
+@functools.partial(jax.jit, static_argnames=("Hk", "Hv", "C", "TB"))
+def _gdn_fwd(q, k, v, G, beta, Hk: int, Hv: int, C: int, TB: int):
+    """q, k (B*Hk, T, dk), v (B*Hv, T, dv), G, beta (B*Hv, T/C, C) f32 -> o
+    (B*Hv, T, dv) and the float32 state before each block of ``TB`` tokens,
+    (B*Hv, T/TB, dk, dv)."""
     BH, T, dv = v.shape
     dk = q.shape[-1]
     own, in_specs = _gdn_specs(Hk, Hv, TB, C, dk, dv, lambda i: i)
-    out_specs = [pl.BlockSpec((1, TB, dv), own)]
-    out_shape = [jax.ShapeDtypeStruct((BH, T, dv), v.dtype)]
-    if emit_states:
-        out_specs.append(pl.BlockSpec((1, TB // C, dk, dv), lambda b, i: (b, i, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((BH, T // C, dk, dv), jnp.float32))
-    res = pl.pallas_call(
-        functools.partial(_gdn_fwd_kernel, C=C, NC=TB // C, emit_states=emit_states),
+    return pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, C=C, NC=TB // C),
         name="gdn_chunk_fwd",
         grid=(BH, T // TB),
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=[pl.BlockSpec((1, TB, dv), own), pl.BlockSpec((1, 1, dk, dv), lambda b, i: (b, i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, dv), v.dtype),
+                   jax.ShapeDtypeStruct((BH, T // TB, dk, dv), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=_interpret(),
         **_seq_params(),
-    )(q, k, v, Gc, Gr, beta)
-    return res if emit_states else res[0]
+    )(q, k, v, G, beta)
 
 
-def _gdn_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, b_ref, do_ref, st_ref,
-                    dq_ref, dk_ref, dv_ref, db_ref, dgc_ref, dgr_ref, ds_ref, *, C, NC):
-    """One block of NC chunks, last chunk first; ``ds_ref`` carries the
-    gradient in the state back from the block after it.  Every forward term
-    is made again from the operands and the chunk's saved starting state."""
-    f32 = jnp.float32
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref,
+                    dq_ref, dk_ref, dv_ref, db_ref, dg_ref, ds_ref, *, C, NC):
+    """One block of NC chunks.  Forward as ``gdn_chunk_fwd`` from the block's
+    saved starting state (the terms, then the walk: each chunk's starting state
+    and ``D`` stay in VMEM; the state's update is the one product this adds to
+    what the pass needs anyway), with the products of the output's gradient
+    that need no gradient in the state beside the walk.  Then the gradient in
+    the state walks back, last chunk first, two dependent products a chunk,
+    ``ds_ref`` carrying it in from the block after, with what hangs off it up
+    to ``dT`` between the steps; the rest (through the inverse, and every
+    gradient that leaves) is made for all chunks together.  Where each line
+    stands was measured (PERF.md, PR 31): all of a chunk between two steps
+    costs 12.8 ms a call, none of it 11.1, this 9.9."""
+    f32, dt = jnp.float32, v_ref.dtype
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    eye = (row == col).astype(f32)
-    low, strict = row >= col, row > col
+    eye, low, strict = _gdn_masks(C)
     last_col = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1) == C - 1
     dot = functools.partial(jax.lax.dot_general, preferred_element_type=f32)
     ab, ab_t, at_b = _AB, _AB_T, _AT_B
     rows = lambda a: jnp.sum(a, axis=1, keepdims=True)  # noqa: E731 -- (C, n) -> (C, 1)
     total = lambda a: jnp.sum(rows(a), axis=0, keepdims=True)  # noqa: E731 -- -> (1, 1)
-    for c in reversed(range(NC)):
+    ch = range(NC)
+    each = lambda f, *lists: [f(*x) for x in zip(*lists)]  # noqa: E731 -- one line of the algebra, every chunk
+
+    t = _gdn_block_terms(k_ref, v_ref, g_ref, b_ref, C, NC, eye, low, strict)
+    q = [q_ref[0, c * C:(c + 1) * C, :] for c in ch]
+    do = [do_ref[0, c * C:(c + 1) * C, :] for c in ch]
+    Qd, QKraw, QKm, dD_out, dS_out, dQKm, dQd = ([None] * NC for _ in range(7))
+
+    def beside(c, S, D):     # what the output's gradient gives without the gradient in the state
+        Qd[c] = t.eG[c] * q[c].astype(f32)
+        QKraw[c] = dot(q[c], t.k[c], ab_t)
+        QKm[c] = jnp.where(low, t.M[c] * QKraw[c], 0.0)
+        dD_out[c] = dot(QKm[c].astype(dt), do[c], at_b)
+        dS_out[c] = dot(Qd[c].astype(dt), do[c], at_b)
+        dQKm[c] = jnp.where(low, dot(do[c], D, ab_t), 0.0)
+        dQd[c] = dot(do[c], S.astype(dt), ab_t)
+
+    S0, Dd, _ = _gdn_state_walk(st_ref[0, 0], t, dt, beside=beside)
+    k, v, beta, M, kk, Tm, eG, Ub, Wb, Wd, Kd = t.k, t.v, t.beta, t.M, t.kk, t.Tm, t.eG, t.Ub, t.Wb, t.W, t.Kd
+    # the chain: through the read-out, the state's update and D = U - W S0
+    dS, dD, dKd, dW, dUb, dWb, dT = ([None] * NC for _ in range(7))
+    carried = ds_ref[...]
+    for c in reversed(ch):
+        dS[c] = carried                                                 # the gradient in the state after chunk c
+        dD[c] = dD_out[c] + dot(Kd[c].astype(dt), carried.astype(dt), ab)
+        carried = dS_out[c] + t.e_glast[c] * carried - dot(Wd[c], dD[c].astype(dt), at_b)
+        dKd[c] = dot(Dd[c], dS[c].astype(dt), ab_t)
+        dW[c] = -dot(dD[c].astype(dt), S0[c].astype(dt), ab_t)
+        # through U = Tm Ub, W = Tm Wb
+        dUb[c] = _dot3(Tm[c], dD[c], at_b)
+        dWb[c] = _dot3(Tm[c], dW[c], at_b)
+        dT[c] = _dot3(dD[c], Ub[c], ab_t) + _dot3(dW[c], Wb[c], ab_t)
+    ds_ref[...] = carried
+    # off the chain: through Tm = (I + A)^-1
+    TdT = each(lambda Tm, dT: _dot3(Tm, dT, at_b), Tm, dT)
+    dA = each(lambda TdT, Tm: -jnp.where(strict, _dot3(TdT, Tm, ab_t), 0.0), TdT, Tm)
+    dKK = each(lambda dA, beta, M: (dA * beta * M).astype(dt), dA, beta, M)
+    dMM = each(lambda dA, beta, kk, dQKm, QKraw, M: (dA * beta * kk + dQKm * QKraw) * M, dA, beta, kk, dQKm, QKraw, M)
+    dQKraw = each(lambda dQKm, M: (dQKm * M).astype(dt), dQKm, M)
+    for c in ch:
         sl = slice(c * C, (c + 1) * C)
-        q, k, v, do = q_ref[0, sl, :], k_ref[0, sl, :], v_ref[0, sl, :], do_ref[0, sl, :]
-        dt = v.dtype
-        Gc, Gr, beta = gc_ref[0, sl, :], gr_ref[0, c:c + 1, :], b_ref[0, sl, :]
-        M, kk, Tm, eG, Ub, Wb, U, W = _gdn_chunk_terms(q, k, v, Gc, Gr, beta, row, col, eye)
-        S0 = st_ref[0, c]
-        S0d, dS = S0.astype(dt), ds_ref[...]
-        dSd = dS.astype(dt)
-        kf, qf = k.astype(f32), q.astype(f32)
-        glast = Gc[C - 1:C, :]
-        e_last = jnp.exp(glast - Gc)                                     # (C, 1)
-        Kd, Qd = e_last * kf, eG * qf
-        D = U - dot(W.astype(dt), S0d, ab)
-        QKraw = dot(q, k, ab_t)
-        QKm = jnp.where(low, M * QKraw, 0.0)
-        Dd = D.astype(dt)
-        # through the read-out, the state's update and D = U - W S0
-        dD = dot(QKm.astype(dt), do, at_b) + dot(Kd.astype(dt), dSd, ab)
-        dDd = dD.astype(dt)
-        dQKm = jnp.where(low, dot(do, Dd, ab_t), 0.0)
-        dQd = dot(do, S0d, ab_t)
-        dKd = dot(Dd, dSd, ab_t)
-        dW = -dot(dDd, S0d, ab_t)
-        e_glast = jnp.exp(jnp.broadcast_to(glast, (S0.shape[0], 1)))     # (dk, 1)
-        ds_ref[...] = (dot(Qd.astype(dt), do, at_b) + e_glast * dS - dot(W.astype(dt), dDd, at_b))
-        # through U = Tm Ub, W = Tm Wb and Tm = (I + A)^-1
-        dT = _dot3(dD, Ub, ab_t) + _dot3(dW, Wb, ab_t)
-        dUb = _dot3(Tm, dD, at_b)
-        dWb = _dot3(Tm, dW, at_b)
-        dA = -jnp.where(strict, _dot3(_dot3(Tm, dT, at_b), Tm, ab_t), 0.0)
-        dKK = dA * beta * M
-        dM = dA * beta * kk + dQKm * QKraw
-        dQKraw = (dQKm * M).astype(dt)
-        dKKd = dKK.astype(dt)
-        dk_ref[0, sl, :] = (dot(dKKd, k, ab) + dot(dKKd, k, at_b) + dot(dQKraw, q, at_b)
-                            + e_last * dKd + beta * eG * dWb).astype(dk_ref.dtype)
-        dq_ref[0, sl, :] = (dot(dQKraw, k, ab) + eG * dQd).astype(dq_ref.dtype)
-        dv_ref[0, sl, :] = (beta * dUb).astype(dv_ref.dtype)
-        db_ref[0, sl, :] = rows(dA * M * kk) + rows(dWb * eG * kf) + rows(dUb * v.astype(f32))
-        dMM = dM * M
-        dgc_ref[0, sl, :] = rows(dMM) + rows(dQd * Qd) - rows(dKd * Kd) + rows(dWb * Wb)
-        # the chunk's last log-decay also scales the carried state and every key of the update
-        d_last = total(dKd * Kd) + total(dS * (e_glast * S0))
-        dgr_ref[0, c:c + 1, :] = jnp.where(last_col, jnp.broadcast_to(d_last, (1, C)), 0.0) - jnp.sum(
-            dMM, axis=0, keepdims=True)
+        kf = k[c].astype(f32)
+        dk_ref[0, sl, :] = (dot(dKK[c], k[c], ab) + dot(dKK[c], k[c], at_b) + dot(dQKraw[c], q[c], at_b)
+                            + t.e_last[c] * dKd[c] + beta[c] * eG[c] * dWb[c]).astype(dk_ref.dtype)
+        dq_ref[0, sl, :] = (dot(dQKraw[c], k[c], ab) + eG[c] * dQd[c]).astype(dq_ref.dtype)
+        dv_ref[0, sl, :] = (beta[c] * dUb[c]).astype(dv_ref.dtype)
+        db_ref[0, c:c + 1, :] = _as_row(
+            rows(dA[c] * M[c] * kk[c]) + rows(dWb[c] * eG[c] * kf) + rows(dUb[c] * v[c].astype(f32)), eye)
+        # G enters M as a column and as a row; the chunk's last log-decay also
+        # scales the carried state and every key of the update
+        d_col = rows(dMM[c]) + rows(dQd[c] * Qd[c]) - rows(dKd[c] * Kd[c]) + rows(dWb[c] * Wb[c])
+        d_last = total(dKd[c] * Kd[c]) + total(dS[c] * (t.e_glast[c] * S0[c]))
+        dg_ref[0, c:c + 1, :] = (_as_row(d_col, eye) - jnp.sum(dMM[c], axis=0, keepdims=True)
+                                 + jnp.where(last_col, jnp.broadcast_to(d_last, (1, C)), 0.0))
 
 
 @functools.partial(jax.jit, static_argnames=("Hk", "Hv", "C", "TB"))
-def _gdn_bwd(do, q, k, v, Gc, Gr, beta, states, Hk: int, Hv: int, C: int, TB: int):
-    """Gradients a value head: dq, dk (B*Hv, T, dk) (to be summed over the
-    heads that share a key head), dv, dbeta (B*Hv, T, 1), and the gradient in
-    the within-chunk cumulative log-decay as a column (B*Hv, T, 1) and as
-    rows (B*Hv, T/C, C), to be added."""
+def _gdn_bwd(do, q, k, v, G, beta, states, Hk: int, Hv: int, C: int, TB: int):
+    """Gradients a value head from the state before each block, (B*Hv, T/TB,
+    dk, dv) f32: dq, dk (B*Hv, T, dk) (to be summed over the heads that share
+    a key head), dv, and dbeta and the gradient in the within-chunk cumulative
+    log-decay, a row a chunk as they came: (B*Hv, T/C, C)."""
     BH, T, dv = v.shape
     dk = q.shape[-1]
     nb = T // TB
     own, in_specs = _gdn_specs(Hk, Hv, TB, C, dk, dv, lambda i: nb - 1 - i)
-    col_spec = pl.BlockSpec((1, TB, 1), own)
-    f32 = jnp.float32
+    row_spec, rows = pl.BlockSpec((1, TB // C, C), own), jax.ShapeDtypeStruct((BH, T // C, C), jnp.float32)
     return pl.pallas_call(
         functools.partial(_gdn_bwd_kernel, C=C, NC=TB // C),
         name="gdn_chunk_bwd",
         grid=(BH, nb),
         in_specs=in_specs + [pl.BlockSpec((1, TB, dv), own),
-                             pl.BlockSpec((1, TB // C, dk, dv), lambda b, i: (b, nb - 1 - i, 0, 0))],
+                             pl.BlockSpec((1, 1, dk, dv), lambda b, i: (b, nb - 1 - i, 0, 0))],
         out_specs=[pl.BlockSpec((1, TB, dk), own), pl.BlockSpec((1, TB, dk), own), pl.BlockSpec((1, TB, dv), own),
-                   col_spec, col_spec, pl.BlockSpec((1, TB // C, C), own)],
+                   row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((BH, T, dk), q.dtype), jax.ShapeDtypeStruct((BH, T, dk), k.dtype),
-                   jax.ShapeDtypeStruct((BH, T, dv), v.dtype), jax.ShapeDtypeStruct((BH, T, 1), f32),
-                   jax.ShapeDtypeStruct((BH, T, 1), f32), jax.ShapeDtypeStruct((BH, T // C, C), f32)],
-        scratch_shapes=[pltpu.VMEM((dk, dv), f32)],
+                   jax.ShapeDtypeStruct((BH, T, dv), v.dtype), rows, rows],
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=_interpret(),
         **_seq_params(),
-    )(q, k, v, Gc, Gr, beta, do, states)
+    )(q, k, v, G, beta, do, states)
 
 
 def _gdn_block_tokens(T: int, C: int) -> int:
-    """Tokens a grid step: whole chunks, and the chunk rows of ``Gr`` a whole
-    sublane tile (8) or the whole array."""
-    for tb in (_GDN_BLOCK_TOKENS, T):
-        if T % tb == 0 and tb % C == 0 and ((tb // C) % 8 == 0 or tb == T):
-            return tb
-    return 0
+    """Tokens a grid step, which is the stride of the saved states
+    (``prims.gdn_state_stride``): whole chunks, and the chunk rows of ``Gr`` a
+    whole sublane tile (8) or the whole array."""
+    tb = gdn_state_stride(T)
+    return tb if tb % C == 0 and ((tb // C) % 8 == 0 or tb == T) else 0
+
+
+def _gdn_bwd_vmem_bytes(TB: int, C: int, dk: int, dv: int, itemsize: int) -> int:
+    """About what ``gdn_chunk_bwd`` holds in VMEM for a block: between its walks
+    every chunk's starting state, the gradient in it and the read-out's part of
+    that gradient (float32 ``(dk, dv)``), six ``(C, C)`` terms and some eight
+    float32 rows of ``C`` by a head; and the operands' double-buffered blocks.
+    Against the 16 MiB of scoped VMEM Mosaic gives a v5e kernel this sits on
+    the right side of what compiles: heads of 128 in float32 (7.9 MiB) and of
+    256 in bfloat16 (14.8) do, 256 in float32 (18.3) and 384 (26.7) do not."""
+    live = (TB // C) * 4 * (3 * dk * dv + 6 * C * C + 4 * C * (dk + dv))
+    blocks = 2 * itemsize * TB * (4 * dk + 3 * dv) + 2 * 4 * dk * dv      # q, k, dq, dk; v, do, dv; the state
+    return live + blocks
+
+
+_GDN_VMEM_BUDGET = 16 << 20
 
 
 def _gdn_supported(q_shape, v_shape, dtype, chunk) -> bool:
     T, dk = q_shape[-2:]
     dv = v_shape[-1]
-    if str(dtype) not in ("bfloat16", "float32") or T % chunk or _gdn_block_tokens(T, chunk) == 0:
+    TB = _gdn_block_tokens(T, chunk)
+    if str(dtype) not in ("bfloat16", "float32") or TB == 0:
         return False
+    if _gdn_bwd_vmem_bytes(TB, chunk, dk, dv, jnp.dtype(dtype).itemsize) > _GDN_VMEM_BUDGET:
+        return False   # a block's chunks would not fit beside its operands
     if not _interpret() and (dk % 128 or dv % 128 or chunk % 8):
         return False   # Mosaic wants lane-dense heads
     return True
@@ -2140,28 +2219,40 @@ def _gdn_dispatchable(q, k, v, chunk) -> bool:
 
 def _gdn_operands(q, k, v, g, beta, C: int):
     """The kernels' flat-batch operands: heads folded into the batch, the
-    log-decay summed within each chunk (as a column and as rows)."""
+    log-decay summed within each chunk, it and beta a row a chunk."""
     B, Hk, T, dk = q.shape
     Hv, dv = v.shape[1], v.shape[3]
     G = jnp.cumsum(g.astype(jnp.float32).reshape(B * Hv, T // C, C), axis=-1)
     return (q.reshape(B * Hk, T, dk), k.reshape(B * Hk, T, dk), v.reshape(B * Hv, T, dv),
-            G.reshape(B * Hv, T, 1), G, beta.astype(jnp.float32).reshape(B * Hv, T, 1))
+            G, beta.astype(jnp.float32).reshape(B * Hv, T // C, C))
+
+
+#: the last scan built, at trace time (a dict of its own, as ``flash_schedule``:
+#: the readers of ``stats`` sum its values)
+gdn_schedule: dict[str, int] = {}
 
 
 def gdn_chunk(q, k, v, g, beta, chunk=GDN_CHUNK):
-    """The chunked gated delta rule through ``gdn_chunk_fwd``, or None where
-    the shapes do not qualify (the XLA decomposition runs then)."""
+    """The chunked gated delta rule through ``gdn_chunk_fwd``: ``(o, states)``,
+    or None where the shapes do not qualify (the XLA decomposition runs
+    then)."""
     if not _gdn_dispatchable(q, k, v, chunk):
         return None
     stats["gdn"] = stats.get("gdn", 0) + 1
-    C, T = int(chunk), q.shape[2]
-    o = _gdn_fwd(*_gdn_operands(q, k, v, g, beta, C), q.shape[1], v.shape[1], C, _gdn_block_tokens(T, C))
-    return o.reshape(v.shape)
+    B, Hk, T, dk = q.shape
+    Hv, dv = v.shape[1], v.shape[3]
+    C = int(chunk)
+    TB = _gdn_block_tokens(T, C)
+    gdn_schedule.update(state_stride_tokens=TB, states_saved_bytes=4 * B * Hv * (T // TB) * dk * dv,
+                        forward_calls_in_backward=0, chunks_a_product=1)
+    o, states = _gdn_fwd(*_gdn_operands(q, k, v, g, beta, C), Hk, Hv, C, TB)
+    return o.reshape(v.shape), states.reshape(B, Hv, T // TB, dk, dv)
 
 
-def gdn_chunk_backward(do, q, k, v, g, beta, chunk=GDN_CHUNK):
-    """Its backward pass: ``gdn_chunk_fwd`` again for the state before every
-    chunk, then ``gdn_chunk_bwd`` from the last block to the first; or None."""
+def gdn_chunk_backward(do, q, k, v, g, beta, states, chunk=GDN_CHUNK):
+    """Its backward pass: ``gdn_chunk_bwd`` from the last block to the first,
+    each block's chunks rebuilt in VMEM from the block's saved state; or
+    None."""
     if not _gdn_dispatchable(q, k, v, chunk):
         return None
     stats["gdn"] = stats.get("gdn", 0) + 1
@@ -2169,12 +2260,11 @@ def gdn_chunk_backward(do, q, k, v, g, beta, chunk=GDN_CHUNK):
     Hv, dv = v.shape[1], v.shape[3]
     C, rep = int(chunk), Hv // Hk
     TB = _gdn_block_tokens(T, C)
-    ops = _gdn_operands(q, k, v, g, beta, C)
-    _, states = _gdn_fwd(*ops, Hk, Hv, C, TB, emit_states=True)
-    dq, dk_, dv_, db, dgc, dgr = _gdn_bwd(do.reshape(B * Hv, T, dv).astype(v.dtype), *ops, states, Hk, Hv, C, TB)
+    dq, dk_, dv_, db, dG = _gdn_bwd(
+        do.reshape(B * Hv, T, dv).astype(v.dtype), *_gdn_operands(q, k, v, g, beta, C),
+        states.reshape(B * Hv, T // TB, dk, dv), Hk, Hv, C, TB)
     # q and k a key head: the heads that read it summed; the log-decay's gradient: back through the cumsum
     per_key = lambda d: d.astype(jnp.float32).reshape(B, Hk, rep, T, dk).sum(axis=2)  # noqa: E731
-    dG = dgc.reshape(B * Hv, T // C, C) + dgr
     dg = jnp.flip(jnp.cumsum(jnp.flip(dG, -1), axis=-1), -1).reshape(B, Hv, T)
     return (per_key(dq).astype(q.dtype), per_key(dk_).astype(k.dtype), dv_.reshape(v.shape),
             dg.astype(g.dtype), db.reshape(B, Hv, T).astype(beta.dtype))
@@ -2201,8 +2291,8 @@ def _gdn_checker(q, k, v, g, beta):
 ex.register_implementation(PrimIDs.GDN_CHUNK, _gdn_op, checker=_gdn_checker)
 
 
-def _gdn_backward_full(do, q, k, v, g, beta):
-    res = gdn_chunk_backward(do, q, k, v, g, beta)
+def _gdn_backward_full(do, q, k, v, g, beta, states):
+    res = gdn_chunk_backward(do, q, k, v, g, beta, states)
     if res is None:
         from thunder_tpu.executors.jaxex import _gdn_chunked_backward
 
@@ -2213,7 +2303,7 @@ def _gdn_backward_full(do, q, k, v, g, beta):
 _gdn_bwd_op = ex.register_operator(
     "pallas_gdn_chunk_backward", like=prim_lookup[PrimIDs.GDN_CHUNK_BACKWARD], fn=_gdn_backward_full)
 ex.register_implementation(PrimIDs.GDN_CHUNK_BACKWARD, _gdn_bwd_op,
-                           checker=lambda do, *a: _gdn_checker(*a))
+                           checker=lambda do, *a: _gdn_checker(*a[:5]))
 
 
 # ---------------------------------------------------------------------------

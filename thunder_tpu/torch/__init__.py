@@ -1257,7 +1257,7 @@ def gated_delta_rule(q, k, v, g, beta):
     algorithm (Pallas ``gdn_chunk_fwd``, or its XLA decomposition)."""
     g = clang.maybe_convert_to_dtype(g, dtypes.float32)
     beta = clang.maybe_convert_to_dtype(beta, dtypes.float32)
-    return prims.gdn_chunk(q, k, v, g, beta)
+    return prims.gdn_chunk(q, k, v, g, beta)[0]
 
 
 @torchsymbol()

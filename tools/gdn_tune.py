@@ -1,0 +1,103 @@
+"""Time the two kernels of the chunked gated delta rule alone on the chip, at the hybrid cell's shape.
+
+    python3 tools/gdn_tune.py [--check]
+
+One line: ms a call of ``gdn_chunk_fwd`` and ``gdn_chunk_bwd`` by name from a
+device trace of five forward and five backward calls (B 2, 16 key and 32 value
+heads of 128, T 8192, bfloat16), of whatever else XLA runs beside them (the
+cumulative log-decay, the sum over the heads of a key head), and
+``pallasex.gdn_schedule``.  ``--check`` first compares o, the saved states and
+the five gradients with the float32 recurrence, token by token, at T 1024
+(compiled kernels, not the interpreter; bfloat16 and float32 operands).  Needs
+a TPU; exits non-zero without one, or if the check fails."""
+import argparse
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import jax
+import jax.numpy as jnp
+
+from thunder_tpu._platform import device_info
+from thunder_tpu.core.prims import GDN_CHUNK, gdn_state_stride
+from thunder_tpu.executors import pallasex as px
+from tools.flash_tune import REPS, kernel_ms
+
+# B, Hk, Hv, T, dk, dv: the two sequences of the hybrid cell's DeltaNet layers
+SHAPE = (2, 16, 32, 8192, 128, 128)
+
+
+def operands(B, Hk, Hv, T, dk, dv, dtype=jnp.bfloat16):
+    """Unit keys, queries scaled as the model's, a decay of about 0.6 a token."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)   # noqa: E731
+    q = unit(jax.random.normal(ks[0], (B, Hk, T, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, Hk, T, dk)))
+    v, do = (jax.random.normal(key, (B, Hv, T, dv)) for key in ks[2:4])
+    g = -jax.nn.softplus(jax.random.normal(ks[4], (B, Hv, T)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, Hv, T)))
+    return do.astype(dtype), q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+def recurrence(q, k, v, g, beta):
+    """The rule token by token in float32: o and the state before every token."""
+    rep = v.shape[1] // q.shape[1]
+    q, k = jnp.repeat(q, rep, 1), jnp.repeat(k, rep, 1)
+
+    def head(q, k, v, g, b):
+        def step(S, x):
+            qt, kt, vt, gt, bt = x
+            S0 = S
+            S = S * jnp.exp(gt)
+            S = S + jnp.outer(kt, (vt - S.T @ kt) * bt)
+            return S, (S.T @ qt, S0)
+        return jax.lax.scan(step, jnp.zeros((k.shape[-1], v.shape[-1])), (q, k, v, g, b))[1]
+
+    return jax.vmap(jax.vmap(head))(q, k, v, g, beta)
+
+
+def check():
+    worst = 0.0
+    T = 1024
+    rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))   # noqa: E731
+    for dtype in (jnp.bfloat16, jnp.float32):
+        do, *ops = operands(1, 4, 8, T, *SHAPE[4:], dtype=dtype)
+        o, states = px.gdn_chunk(*ops)
+        got = (o, states, *px.gdn_chunk_backward(do, *ops, states))
+        with jax.default_matmul_precision("highest"):
+            f32 = [x.astype(jnp.float32) for x in ops]
+            oref, every = recurrence(*f32)
+            grads = jax.grad(lambda *a: jnp.sum(recurrence(*a)[0] * do.astype(jnp.float32)), argnums=(0, 1, 2, 3, 4))(*f32)
+        want = (oref, every[:, :, ::gdn_state_stride(T)], *grads)
+        for what, a, b in zip(("o", "states", "dq", "dk", "dv", "dg", "dbeta"), got, want):
+            worst = max(worst, rel(a, b))
+            print(f"check {jnp.dtype(dtype).name:8s} {what:6s} relative error {rel(a, b):.6f}", flush=True)
+    return worst
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--check", action="store_true")
+    args = ap.parse_args()
+    device = device_info()
+    if device["platform"] != "tpu":
+        sys.exit(f"gdn_tune: times the kernels on a device and needs a TPU; jax found "
+                 f"{device['platform']!r} ({device['kind']}).  Nothing was measured.")
+    print(device, flush=True)
+    if args.check and check() > 0.02:   # bfloat16 operands: a few thousandths
+        sys.exit("gdn_tune: the compiled kernels disagree with the recurrence")
+    do, *ops = operands(*SHAPE)
+    fwd = jax.jit(lambda *a: px.gdn_chunk(*a, GDN_CHUNK))
+    bwd = jax.jit(lambda *a: px.gdn_chunk_backward(*a, GDN_CHUNK))
+    _, states = jax.block_until_ready(fwd(*ops))
+    jax.block_until_ready(bwd(do, *ops, states))
+    ms = kernel_ms(lambda: jax.block_until_ready((fwd(*ops), bwd(do, *ops, states))), REPS)
+    two = [sum(t for n, t in ms.items() if n.startswith(name)) for name in ("gdn_chunk_fwd", "gdn_chunk_bwd")]
+    rest = {n: t for n, t in ms.items() if not n.startswith("gdn_chunk")}
+    beside = ", ".join(f"{n} {t:.3f}" for n, t in sorted(rest.items(), key=lambda kv: -kv[1])[:5])
+    print(f"T {SHAPE[3]}: gdn_chunk_fwd {two[0]:7.3f}  gdn_chunk_bwd {two[1]:7.3f}  sum {sum(two):7.3f} ms a call;"
+          f"  beside them {sum(rest.values()):.3f}: {beside}   schedule {px.gdn_schedule}", flush=True)
+
+
+if __name__ == "__main__":
+    main()

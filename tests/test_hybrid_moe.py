@@ -480,20 +480,22 @@ def _tiny_cfg_and_params():
 
 
 def test_generate_refuses_the_config_with_one_clear_error():
+    """Since PR 32 the server runs linear_attention layers; this model's expert
+    share is what it still lacks, and the refusal names it."""
     from thunder_tpu.models import generate
 
     cfg, params = _tiny_cfg_and_params()
-    with pytest.raises(NotImplementedError, match="cannot be served.*linear_attention"):
+    with pytest.raises(NotImplementedError, match="cannot be served.*SparseMoE"):
         generate.generate(params, jnp.zeros((1, 4), jnp.int32), cfg, 2)
 
 
 def test_serve_refuses_the_config_with_one_clear_error():
     cfg, params = _tiny_cfg_and_params()
-    with pytest.raises(NotImplementedError, match="cannot be served.*linear_attention"):
+    with pytest.raises(NotImplementedError, match="cannot be served.*SparseMoE"):
         tt.serve(None, params, cfg, num_blocks=8, max_batch=1)
 
 
-def test_an_expert_share_without_linear_layers_is_refused_too():
+def test_an_expert_share_is_refused_and_linear_layers_alone_are_not():
     from thunder_tpu.models.generate import require_servable
 
     cfg = llama.Config(name="moe-only", n_layer=2, n_head=4, n_embd=64, mlp_class="SparseMoE", n_expert=8,
@@ -501,6 +503,14 @@ def test_an_expert_share_without_linear_layers_is_refused_too():
     with pytest.raises(NotImplementedError, match="SparseMoE"):
         require_servable(cfg)
     require_servable(llama.Config.from_name("tiny-mistral-debug"))
+    linear = llama.Config(name="linear-dense", n_layer=2, n_head=4, n_embd=64, intermediate_size=96,
+                          layer_types=("linear_attention", "full_attention"), linear_num_key_heads=2,
+                          linear_num_value_heads=2, linear_key_head_dim=8, linear_value_head_dim=16)
+    assert linear.training_only is None
+    require_servable(linear)
+    # this model's gated attention and zero-centred norms have no serving form either
+    gated = _tiny_cfg_and_params()[0]
+    assert gated.attn_output_gate and gated.qk_norm and gated.norm_zero_centered
 
 
 def test_init_params_builds_the_layout_the_reference_builds():

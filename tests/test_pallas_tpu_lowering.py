@@ -177,6 +177,17 @@ def _cases(nh: int, ng: int) -> dict:
     # keep live between the kernel's walks has to fit beside the operands
     cases["gdn_chunk_bwd/float32"] = (gdn_bwd, gdn_bwd_specs(HS, F32))
     cases["gdn_chunk_bwd/head256"] = (gdn_bwd, gdn_bwd_specs(2 * HS, BF))
+    # the server's two: the scan from a state to a state, and one step a row on the
+    # state arena in place, at heads of 96 and 192 (three fourths of a lane tile
+    # and one and a half), and at a bfloat16 arena
+    cases["gdn_chunk_fwd/state"] = (
+        lambda q_, k, v, g, b, h: px._gdn_fwd.__wrapped__(q_, k, v, g, b, 2, 4, Cg, 512, h0=h),
+        gdn_bwd_specs(HS, BF)[1:6] + [((4, HS, HS), F32)])
+    step = lambda a, s, q_, k, v, g, b: px.gdn_decode_step(a, s, q_, k, v, g, b, layer=1)  # noqa: E731
+    step_specs = lambda dt: [((5, 2, 4, 96, 192), dt), ((3,), I32), ((3, 2, 96), BF), ((3, 2, 96), BF),  # noqa: E731
+                             ((3, 4, 192), BF), ((3, 4), F32), ((3, 4), F32)]
+    cases["gdn_decode_step"] = (step, step_specs(F32))
+    cases["gdn_decode_step/bfloat16"] = (step, step_specs(BF))
     rows, tiles, plan = ((1024, 256), BF), ((8,), I32), ((1,), I32)
     cases["moe_grouped_mm"] = (
         px._moe_grouped_mm.__wrapped__, [rows, ((4, 256, 512), BF), tiles, plan])
@@ -205,6 +216,44 @@ def kernel_names(case: str) -> list[str]:
     if variant.startswith("masked") or variant.endswith("/masked"):
         base += "_masked"
     return [base]
+
+
+@pytest.mark.parametrize("kernel", ["gdn_chunk_state", "gdn_decode_step"])
+def test_the_servers_scan_and_step_compile_at_the_published_heads(kernel, tpu_sharding, monkeypatch):
+    """Olmo-Hybrid's delta-rule heads are 96 and 192 wide, neither a whole
+    lane tile.  The scan reaches ``gdn_chunk_fwd`` padded to 128 and 256
+    (zero columns: exact) instead of falling to the XLA form, at the longest
+    prefill bucket; the step takes the arena's ``(96, 192)`` tiles as they
+    are, a row's thirty heads a grid step, 32 rows of 12 layers' arena."""
+    monkeypatch.setattr(px, "_interpret", lambda: False)
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    H, dk, dv = 30, 96, 192
+    if kernel == "gdn_chunk_state":
+        Ts = 2560
+        fn = px.gdn_chunk_state
+        specs = [((1, H, Ts, dk), BF), ((1, H, Ts, dk), BF), ((1, H, Ts, dv), BF), ((1, H, Ts), F32), ((1, H, Ts), F32),
+                 ((1, H, dk, dv), F32)]
+        name = "gdn_chunk_fwd"
+    else:
+        fn = functools.partial(px.gdn_decode_step, layer=11)
+        specs = [((33, 12, H, dk, dv), F32), ((32,), I32), ((32, H, dk), BF), ((32, H, dk), BF), ((32, H, dv), BF),
+                 ((32, H), F32), ((32, H), F32)]
+        name = "gdn_decode_step"
+    before = dict(px.stats)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and f'kernel_name = "{name}"' in text
+    claim = "gdn" if kernel == "gdn_chunk_state" else "gdn_decode"
+    assert px.stats.get(claim, 0) == before.get(claim, 0) + 1            # claimed, not the XLA form
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        call = next(l for l in compiled.as_text().splitlines() if re.search(rf"%{name}(\.\d+)? = ", l))
+        if kernel == "gdn_decode_step":
+            # in place: the arena aliases its result; and nothing of the call's
+            # shape fits the reader that finds paged_attn_decode by its operands
+            assert "output_to_operand_aliasing" in call
+            assert not re.match(r"^\s*%\S+ = \w+\[\d+(,\d+){3}\]\S* custom-call\(s32\[\d+,\d+\]", call)
 
 
 @pytest.mark.parametrize("shape", SHAPES)
@@ -346,14 +395,14 @@ def test_every_pallas_call_site_is_named():
     import inspect
 
     src = inspect.getsource(px)
-    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 14
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 15
     assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
         "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
         "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",
         "paged_attn_verify_quant", "paged_token_write", "paged_token_write_masked",
         "paged_token_write_fused", "paged_token_write_fused_masked",
         "paged_chunk_write", "paged_chunk_write_fused", "lora_delta_fused",
-        "gdn_chunk_fwd", "gdn_chunk_bwd", "moe_grouped_mm", "moe_grouped_mm_dw"}
+        "gdn_chunk_fwd", "gdn_chunk_bwd", "gdn_decode_step", "moe_grouped_mm", "moe_grouped_mm_dw"}
 
 
 @pytest.mark.slow

@@ -697,6 +697,7 @@ def _fused_linear_ce_backward_impl(g, h, w, target, lse, ignore_index=-100):
 # into the two hooks below.
 _gdn_fast_path: Callable | None = None  # (q, k, v, g, beta, chunk) -> (o, states) or None
 _gdn_bwd_fast_path: Callable | None = None  # (do, q, k, v, g, beta, states, chunk) -> five gradients or None
+_gdn_state_fast_path: Callable | None = None  # (q, k, v, g, beta, h0, chunk) -> (o, last state) or None
 
 
 def _unit_lower_inverse(A):
@@ -722,6 +723,24 @@ def _gdn_chunked(q, k, v, g, beta, chunk):
     of ``gdn_state_stride(T)`` tokens, ``(B, Hv, blocks, dk, dv)``.  T is
     padded to whole chunks with tokens that leave the state alone (g = 0,
     beta = 0)."""
+    return _gdn_chunked_from(q, k, v, g, beta, chunk)[:2]
+
+
+def gdn_chunk_state(q, k, v, g, beta, h0, chunk=GDN_CHUNK):
+    """The scan as the server runs it: from the float32 state ``h0 (B, Hv, dk,
+    dv)`` to ``(o, the state after the last token)``, through the Pallas
+    kernel where it takes the shapes, else the XLA decomposition."""
+    if _gdn_state_fast_path is not None:
+        res = _gdn_state_fast_path(q, k, v, g, beta, h0, chunk)
+        if res is not None:
+            return res
+    o, _, last = _gdn_chunked_from(q, k, v, g, beta, chunk, h0)
+    return o, last.astype(h0.dtype)
+
+
+def _gdn_chunked_from(q, k, v, g, beta, chunk, h0=None):
+    """:func:`_gdn_chunked` from the state ``h0`` (zeros where None), with the
+    state after the last token as a third result."""
     B, Hk, T, dk = q.shape
     Hv, dv = v.shape[1], v.shape[3]
     rep = Hv // Hk
@@ -763,10 +782,10 @@ def _gdn_chunked(q, k, v, g, beta, chunk):
 
     nb = T // gdn_state_stride(T)                                      # 1 where the stride is the sequence
     lead = lambda a: jnp.moveaxis(a, 2, 0).reshape(nb, n // nb, *a.shape[:2], *a.shape[3:])  # noqa: E731 -- blocks, chunks first
-    _, (o, states) = jax.lax.scan(block, jnp.zeros((B, Hv, dk, dv), f32),
-                                  tuple(lead(a) for a in (U, W, QK, Qd, Kd, Glast)))
+    first = jnp.zeros((B, Hv, dk, dv), f32) if h0 is None else h0.astype(f32)
+    last, (o, states) = jax.lax.scan(block, first, tuple(lead(a) for a in (U, W, QK, Qd, Kd, Glast)))
     o = jnp.moveaxis(o.reshape(n, B, Hv, C, dv), 0, 2).reshape(B, Hv, T + pad, dv)
-    return o[:, :, :T].astype(v.dtype), jnp.moveaxis(states, 0, 2)
+    return o[:, :, :T].astype(v.dtype), jnp.moveaxis(states, 0, 2), last
 
 
 @impl(PrimIDs.GDN_CHUNK)

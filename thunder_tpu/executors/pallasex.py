@@ -46,7 +46,7 @@ from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_
 __all__ = [
     "ex", "pallas_ex", "flash_sdpa", "flash_sdpa_backward",
     "paged_attn_decode", "paged_token_write", "paged_available", "paged_head_size_ok",
-    "gdn_chunk", "grouped_mm", "grouped_mm_dw",
+    "gdn_chunk", "gdn_chunk_state", "gdn_decode_step", "grouped_mm", "grouped_mm_dw",
 ]
 
 # exp(MASK_VALUE - lse) underflows to 0 without the inf-inf NaN hazard of -inf
@@ -2004,12 +2004,19 @@ def _gdn_state_walk(S, t, dt, beside):
     return starts, Ds, S
 
 
-def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, s_ref, *, C, NC):
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, C, NC, carry):
+    """``carry`` (the server's call): one operand more, the state the sequence
+    starts from, and one result more, the state it ends in; the trainer's call
+    has neither and starts from zero."""
     f32, dt = jnp.float32, v_ref.dtype
+    if carry:
+        h0_ref, o_ref, st_ref, fin_ref, s_ref = rest
+    else:
+        o_ref, st_ref, s_ref = rest
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        s_ref[...] = jnp.zeros_like(s_ref)
+        s_ref[...] = h0_ref[0].astype(f32) if carry else jnp.zeros_like(s_ref)
 
     st_ref[0, 0] = s_ref[...]          # the state this block starts from: what the backward pass keeps
     eye, low, strict = _gdn_masks(C)
@@ -2023,6 +2030,9 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, s_ref, *, 
         o_ref[0, c * C:(c + 1) * C, :] = o.astype(o_ref.dtype)
 
     _, _, s_ref[...] = _gdn_state_walk(s_ref[...], t, dt, beside=read_out)
+    if carry:
+        # the block stays in VMEM while its index stands still: what leaves is the last block's
+        fin_ref[0] = s_ref[...].astype(fin_ref.dtype)
 
 
 def _gdn_specs(Hk: int, Hv: int, TB: int, C: int, dk: int, dv: int, block):
@@ -2043,25 +2053,33 @@ def _seq_params():
 
 
 @functools.partial(jax.jit, static_argnames=("Hk", "Hv", "C", "TB"))
-def _gdn_fwd(q, k, v, G, beta, Hk: int, Hv: int, C: int, TB: int):
+def _gdn_fwd(q, k, v, G, beta, Hk: int, Hv: int, C: int, TB: int, h0=None):
     """q, k (B*Hk, T, dk), v (B*Hv, T, dv), G, beta (B*Hv, T/C, C) f32 -> o
     (B*Hv, T, dv) and the float32 state before each block of ``TB`` tokens,
-    (B*Hv, T/TB, dk, dv)."""
+    (B*Hv, T/TB, dk, dv).  With ``h0 (B*Hv, dk, dv)`` the scan starts from it
+    and a third result is the state after the last token, in ``h0``'s dtype."""
     BH, T, dv = v.shape
     dk = q.shape[-1]
+    carry = h0 is not None
     own, in_specs = _gdn_specs(Hk, Hv, TB, C, dk, dv, lambda i: i)
+    state = pl.BlockSpec((1, dk, dv), lambda b, i: (b, 0, 0))
+    out_specs = [pl.BlockSpec((1, TB, dv), own), pl.BlockSpec((1, 1, dk, dv), lambda b, i: (b, i, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((BH, T, dv), v.dtype),
+                 jax.ShapeDtypeStruct((BH, T // TB, dk, dv), jnp.float32)]
+    if carry:
+        in_specs, out_specs = in_specs + [state], out_specs + [state]
+        out_shape = out_shape + [jax.ShapeDtypeStruct((BH, dk, dv), h0.dtype)]
     return pl.pallas_call(
-        functools.partial(_gdn_fwd_kernel, C=C, NC=TB // C),
+        functools.partial(_gdn_fwd_kernel, C=C, NC=TB // C, carry=carry),
         name="gdn_chunk_fwd",
         grid=(BH, T // TB),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, TB, dv), own), pl.BlockSpec((1, 1, dk, dv), lambda b, i: (b, i, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((BH, T, dv), v.dtype),
-                   jax.ShapeDtypeStruct((BH, T // TB, dk, dv), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=_interpret(),
         **_seq_params(),
-    )(q, k, v, G, beta)
+    )(q, k, v, G, beta, *((h0,) if carry else ()))
 
 
 def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref,
@@ -2196,17 +2214,23 @@ def _gdn_bwd_vmem_bytes(TB: int, C: int, dk: int, dv: int, itemsize: int) -> int
 _GDN_VMEM_BUDGET = 16 << 20
 
 
+def _gdn_lanes(d: int) -> int:
+    """The width a head is handed to the compiled kernels at: whole 128-lane
+    tiles.  Zero columns of q and k add nothing to a product over the head, and
+    zero columns of v give zero columns of the state and of o, so the padding
+    is exact; the interpreter takes any width."""
+    return d if _interpret() else -(-d // 128) * 128
+
+
 def _gdn_supported(q_shape, v_shape, dtype, chunk) -> bool:
-    T, dk = q_shape[-2:]
-    dv = v_shape[-1]
+    T = q_shape[-2]
+    dk, dv = _gdn_lanes(q_shape[-1]), _gdn_lanes(v_shape[-1])
     TB = _gdn_block_tokens(T, chunk)
     if str(dtype) not in ("bfloat16", "float32") or TB == 0:
         return False
     if _gdn_bwd_vmem_bytes(TB, chunk, dk, dv, jnp.dtype(dtype).itemsize) > _GDN_VMEM_BUDGET:
         return False   # a block's chunks would not fit beside its operands
-    if not _interpret() and (dk % 128 or dv % 128 or chunk % 8):
-        return False   # Mosaic wants lane-dense heads
-    return True
+    return _interpret() or chunk % 8 == 0
 
 
 def _gdn_dispatchable(q, k, v, chunk) -> bool:
@@ -2217,13 +2241,19 @@ def _gdn_dispatchable(q, k, v, chunk) -> bool:
     return not ((mesh is not None and mesh.devices.size > 1) or any(_concrete_multi_device(x) for x in (q, k, v)))
 
 
+def _lane_pad(x, width: int):
+    return x if x.shape[-1] == width else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
 def _gdn_operands(q, k, v, g, beta, C: int):
-    """The kernels' flat-batch operands: heads folded into the batch, the
-    log-decay summed within each chunk, it and beta a row a chunk."""
+    """The kernels' flat-batch operands: heads folded into the batch and
+    padded to whole lane tiles (``_gdn_lanes``), the log-decay summed within
+    each chunk, it and beta a row a chunk."""
     B, Hk, T, dk = q.shape
     Hv, dv = v.shape[1], v.shape[3]
     G = jnp.cumsum(g.astype(jnp.float32).reshape(B * Hv, T // C, C), axis=-1)
-    return (q.reshape(B * Hk, T, dk), k.reshape(B * Hk, T, dk), v.reshape(B * Hv, T, dv),
+    return (_lane_pad(q.reshape(B * Hk, T, dk), _gdn_lanes(dk)), _lane_pad(k.reshape(B * Hk, T, dk), _gdn_lanes(dk)),
+            _lane_pad(v.reshape(B * Hv, T, dv), _gdn_lanes(dv)),
             G, beta.astype(jnp.float32).reshape(B * Hv, T // C, C))
 
 
@@ -2246,7 +2276,25 @@ def gdn_chunk(q, k, v, g, beta, chunk=GDN_CHUNK):
     gdn_schedule.update(state_stride_tokens=TB, states_saved_bytes=4 * B * Hv * (T // TB) * dk * dv,
                         forward_calls_in_backward=0, chunks_a_product=1)
     o, states = _gdn_fwd(*_gdn_operands(q, k, v, g, beta, C), Hk, Hv, C, TB)
-    return o.reshape(v.shape), states.reshape(B, Hv, T // TB, dk, dv)
+    return o[..., :dv].reshape(v.shape), states[..., :dk, :dv].reshape(B, Hv, T // TB, dk, dv)
+
+
+def gdn_chunk_state(q, k, v, g, beta, h0, chunk=GDN_CHUNK):
+    """The same scan for the server: from the state ``h0 (B, Hv, dk, dv)`` (a
+    prompt's first piece hands zeros) to ``(o, state after the last token)``,
+    the state in ``h0``'s dtype; or None where the shapes do not qualify.  A
+    token with ``g = 0`` and ``beta = 0`` leaves the state as it was: that is
+    how a padded prompt's tail is told to do nothing."""
+    if not _gdn_dispatchable(q, k, v, chunk):
+        return None
+    stats["gdn"] = stats.get("gdn", 0) + 1
+    B, Hk, T, dk = q.shape
+    Hv, dv = v.shape[1], v.shape[3]
+    C = int(chunk)
+    pk, pv = _gdn_lanes(dk), _gdn_lanes(dv)
+    h0 = jnp.pad(h0.reshape(B * Hv, dk, dv), ((0, 0), (0, pk - dk), (0, pv - dv)))
+    o, _, fin = _gdn_fwd(*_gdn_operands(q, k, v, g, beta, C), Hk, Hv, C, _gdn_block_tokens(T, C), h0=h0)
+    return o[..., :dv].reshape(v.shape), fin[:, :dk, :dv].reshape(B, Hv, dk, dv)
 
 
 def gdn_chunk_backward(do, q, k, v, g, beta, states, chunk=GDN_CHUNK):
@@ -2260,14 +2308,93 @@ def gdn_chunk_backward(do, q, k, v, g, beta, states, chunk=GDN_CHUNK):
     Hv, dv = v.shape[1], v.shape[3]
     C, rep = int(chunk), Hv // Hk
     TB = _gdn_block_tokens(T, C)
+    pk, pv = _gdn_lanes(dk), _gdn_lanes(dv)
+    states = jnp.pad(states.reshape(B * Hv, T // TB, dk, dv), ((0, 0), (0, 0), (0, pk - dk), (0, pv - dv)))
     dq, dk_, dv_, db, dG = _gdn_bwd(
-        do.reshape(B * Hv, T, dv).astype(v.dtype), *_gdn_operands(q, k, v, g, beta, C),
-        states.reshape(B * Hv, T // TB, dk, dv), Hk, Hv, C, TB)
+        _lane_pad(do.reshape(B * Hv, T, dv).astype(v.dtype), pv), *_gdn_operands(q, k, v, g, beta, C),
+        states, Hk, Hv, C, TB)
     # q and k a key head: the heads that read it summed; the log-decay's gradient: back through the cumsum
-    per_key = lambda d: d.astype(jnp.float32).reshape(B, Hk, rep, T, dk).sum(axis=2)  # noqa: E731
+    per_key = lambda d: d[..., :dk].astype(jnp.float32).reshape(B, Hk, rep, T, dk).sum(axis=2)  # noqa: E731
     dg = jnp.flip(jnp.cumsum(jnp.flip(dG, -1), axis=-1), -1).reshape(B, Hv, T)
-    return (per_key(dq).astype(q.dtype), per_key(dk_).astype(k.dtype), dv_.reshape(v.shape),
+    return (per_key(dq).astype(q.dtype), per_key(dk_).astype(k.dtype), dv_[..., :dv].reshape(v.shape),
             dg.astype(g.dtype), db.reshape(B, Hv, T).astype(beta.dtype))
+
+
+# ---------------------------------------------------------------------------
+# One delta-rule step a row, on the server's state arena: ``gdn_decode_step``.
+#
+# Grid (row); a row's state, ``(Hv, dk, dv)`` of the arena's slot the
+# scalar-prefetched table names, comes into VMEM once, takes the decay, the
+# rank-one update and the read-out on the vector unit in float32 (products of
+# one row: the MXU has nothing to win), and goes back to the same place: the
+# arena aliases its result, so the other slots keep their bytes and the
+# program holds no gather, update and scatter of 2 MB a row.  Padding rows
+# name slot 0, the sink, which nobody reads.  q and k arrive a column a head,
+# ``(rows, dk, Hk)``: a head's key is then a lane of a small block and
+# broadcasts along the state's lanes without a transpose in the kernel.
+#   a = exp(g);  kS = k^T S;  qS = q^T S;  d = beta (v - a kS)
+#   S <- a S + k d^T;   o = a qS + (q . k) d      (= q^T of the new S)
+# ---------------------------------------------------------------------------
+
+def gdn_step_math(S, kc, qc, v, a, beta):
+    """One head's step in float32: ``S (dk, dv)``, the key and the query as
+    columns ``(dk, 1)``, ``v``, the decay ``a = exp(g)`` and ``beta`` as rows
+    ``(1, dv)`` -> ``(o (1, dv), S)``.  The kernel's body and the dense cache's
+    step (``models.generate``) are this one function: a served token and a
+    solo ``generate()`` token take the same formulas in the same order."""
+    kS = jnp.sum(S * kc, axis=0, keepdims=True)
+    qS = jnp.sum(S * qc, axis=0, keepdims=True)
+    d = beta * (v - a * kS)
+    return a * qS + jnp.sum(qc * kc, axis=0, keepdims=True) * d, a * S + kc * d
+
+
+def _gdn_decode_kernel(slot_ref, qT_ref, kT_ref, v_ref, a_ref, b_ref, s_ref, o_ref, so_ref, *, Hv, rep):
+    del slot_ref   # the row's slot lives in the BlockSpec index maps
+    f32 = jnp.float32
+    qT, kT = qT_ref[0], kT_ref[0]                                     # (dk, Hk) float32
+    for h in range(Hv):
+        o, S = gdn_step_math(s_ref[0, 0, h].astype(f32), kT[:, h // rep:h // rep + 1], qT[:, h // rep:h // rep + 1],
+                             v_ref[0, h:h + 1, :].astype(f32), a_ref[0, h:h + 1, :], b_ref[0, h:h + 1, :])
+        so_ref[0, 0, h] = S.astype(so_ref.dtype)
+        o_ref[0, h:h + 1, :] = o.astype(o_ref.dtype)
+
+
+def gdn_decode_step(arena, slots, q, k, v, g, beta, *, layer: int):
+    """One token a row through the gated delta rule, the state read and
+    written once, in place.  ``arena (slots + 1, L_lin, Hv, dk, dv)`` (float32,
+    or what the pool was told to store); ``slots (rows,)`` int32, 0 the sink;
+    q, k ``(rows, Hk, dk)`` as the mixer hands them (unit keys, scaled
+    queries), v ``(rows, Hv, dv)``, g (log-decay) and beta ``(rows, Hv)``
+    float32.  Returns ``(o (rows, Hv, dv) in v's dtype, arena)``."""
+    stats["gdn_decode"] = stats.get("gdn_decode", 0) + 1
+    rows, Hk, dk = q.shape
+    Hv, dv = v.shape[1], v.shape[2]
+    f32 = jnp.float32
+    lanes = lambda x: jnp.broadcast_to(x.astype(f32)[:, :, None], (rows, Hv, dv))  # noqa: E731
+    row = lambda i, s: (i, 0, 0)  # noqa: E731
+    mine = lambda i, s: (s[i], layer, 0, 0, 0)  # noqa: E731
+    kwargs = {}
+    if not _interpret():
+        # a row's state in and out, double-buffered: four blocks of Hv (dk, dv) tiles
+        tile = 4 * Hv * (-(-dk // 8) * 8) * (-(-dv // 128) * 128) * 4
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=max(32 << 20, tile + (16 << 20)))
+    o, arena = pl.pallas_call(
+        functools.partial(_gdn_decode_kernel, Hv=Hv, rep=Hv // Hk),
+        name="gdn_decode_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows,),
+            in_specs=[pl.BlockSpec((1, dk, Hk), row), pl.BlockSpec((1, dk, Hk), row),
+                      pl.BlockSpec((1, Hv, dv), row), pl.BlockSpec((1, Hv, dv), row), pl.BlockSpec((1, Hv, dv), row),
+                      pl.BlockSpec((1, 1, Hv, dk, dv), mine)],
+            out_specs=[pl.BlockSpec((1, Hv, dv), row), pl.BlockSpec((1, 1, Hv, dk, dv), mine)]),
+        out_shape=[jax.ShapeDtypeStruct((rows, Hv, dv), v.dtype), jax.ShapeDtypeStruct(arena.shape, arena.dtype)],
+        input_output_aliases={6: 1},     # operands: the slot table, five small ones, the arena
+        interpret=_interpret(),
+        **kwargs,
+    )(slots.astype(jnp.int32), jnp.swapaxes(q, 1, 2).astype(f32), jnp.swapaxes(k, 1, 2).astype(f32),
+      v, lanes(jnp.exp(g)), lanes(beta), arena)
+    return o, arena
 
 
 def _gdn_full(q, k, v, g, beta):
@@ -2464,5 +2591,6 @@ _jaxex._sdpa_bwd_fast_path = flash_sdpa_backward
 _jaxex._ce_fast_path = flash_cross_entropy
 _jaxex._gdn_fast_path = gdn_chunk
 _jaxex._gdn_bwd_fast_path = gdn_chunk_backward
+_jaxex._gdn_state_fast_path = gdn_chunk_state
 _jaxex._grouped_mm_fast_path = grouped_mm
 _jaxex._grouped_mm_dw_fast_path = grouped_mm_dw

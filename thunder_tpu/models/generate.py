@@ -40,6 +40,8 @@ __all__ = [
     "generate",
     "cache_len",
     "cache_shape",
+    "state_shapes",
+    "gdn_mixer",
     "kv_block_shape",
     "ring_slot",
     "ring_gather_positions",
@@ -70,6 +72,13 @@ def _norm(x, w, cfg: Config, b=None):
     if b is not None:
         out = out + b.astype(jnp.float32)
     return out.astype(x.dtype)
+
+
+def _rms(x, w, eps):
+    """RMSNorm over the last axis in float32, back in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * w.astype(jnp.float32)).astype(x.dtype)
 
 
 def _rope(x, cos, sin):
@@ -152,8 +161,11 @@ def _project_qkv(ap, x, cos_t, sin_t, cfg: Config, *, lin=None, lora=None,
             o = o + delta_fn(x, *lora[name], lora_scaling)
         return o
 
-    q = proj("wq", "bq").reshape(B, T, nh, hs).transpose(0, 2, 1, 3)
-    k = proj("wk", "bk").reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
+    q, k = proj("wq", "bq"), proj("wk", "bk")
+    if cfg.qk_norm_whole:   # over the whole projection, before the split into heads
+        q, k = _rms(q, ap["q_norm"], cfg.norm_eps), _rms(k, ap["k_norm"], cfg.norm_eps)
+    q = q.reshape(B, T, nh, hs).transpose(0, 2, 1, 3)
+    k = k.reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
     v = proj("wv", "bv").reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
     n_elem = cfg.rope_n_elem
     if n_elem > 0:
@@ -181,8 +193,21 @@ _cache_len = cache_len  # back-compat alias
 def cache_shape(cfg: Config, B: int, T_max: int) -> tuple[int, int, int, int, int]:
     """Dense KV-cache geometry ``(L, B, n_query_groups, Tc, hs)`` — the one
     layout every cache consumer (``init_cache``, the serving KV pool's
-    gathered views) agrees on."""
-    return (cfg.n_layer, B, cfg.n_query_groups, cache_len(cfg, T_max), cfg.head_size)
+    gathered views) agrees on.  ``L`` counts the layers that keep K and V
+    (``cfg.kv_layers``): a linear_attention layer has none."""
+    return (len(cfg.kv_layers), B, cfg.n_query_groups, cache_len(cfg, T_max), cfg.head_size)
+
+
+def state_shapes(cfg: Config, B: int) -> dict:
+    """What the linear_attention layers keep a sequence, beside the KV:
+    ``conv (L_lin, B, K - 1, channels)``, the conv's last inputs, and ``state
+    (L_lin, B, nv, dk, dv)``, the delta rule's.  Empty for a model without
+    such layers."""
+    n = len(cfg.linear_layers)
+    if not n:
+        return {}
+    return {"conv": (n, B, cfg.linear_conv_kernel - 1, cfg.linear_qkv_width),
+            "state": (n, B, cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim)}
 
 
 def kv_block_shape(cfg: Config, block_size: int) -> tuple[int, int, int, int]:
@@ -190,7 +215,7 @@ def kv_block_shape(cfg: Config, block_size: int) -> tuple[int, int, int, int]:
     paged serving pool's arena — one block holds ``block_size`` consecutive
     token slots of every layer's K (or V), so a gather over a request's
     block table reassembles exactly the :func:`cache_shape` layout."""
-    return (cfg.n_layer, cfg.n_query_groups, block_size, cfg.head_size)
+    return (len(cfg.kv_layers), cfg.n_query_groups, block_size, cfg.head_size)
 
 
 def ring_slot(pos, window: int):
@@ -233,7 +258,12 @@ def init_cache(cfg: Config, B: int, T_max: int, dtype=jnp.bfloat16, *, mesh=None
         z = jnp.zeros(shape, dtype=dtype)
         return jax.device_put(z, sh) if sh is not None else z
 
-    return {"k": zeros(), "v": zeros()}
+    cache = {"k": zeros(), "v": zeros()}
+    shapes = state_shapes(cfg, B)
+    if shapes:
+        assert mesh is None, "a recurrent state has no sharded cache yet"
+        cache.update(conv=jnp.zeros(shapes["conv"], dtype), state=jnp.zeros(shapes["state"], jnp.float32))
+    return cache
 
 
 def _is_vec_pos(pos) -> bool:
@@ -331,9 +361,78 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
     return out, ck, cv
 
 
+def _l2norm(x, eps: float = 1e-6):
+    xf = x.astype(jnp.float32)
+    return (xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)).astype(x.dtype)
+
+
+def gdn_mixer(gp, x, tail, cfg: Config, recur, *, n_real=None, lin=_linear):
+    """A linear_attention layer's mixer on new tokens ``x (B, T, C)``, for the
+    dense cache and the paged server alike (``llama.gated_delta_net`` with its
+    state carried).  ``tail (B, K - 1, channels)`` holds the conv's inputs of
+    the K - 1 tokens before ``x`` (zeros before a sequence's first); the
+    delta rule itself is ``recur(q, k, v, g, beta) -> o``: q, k ``(B, nk, T,
+    dk)`` (unit keys, scaled queries), v ``(B, nv, T, dv)``, g (log-decay) and
+    beta ``(B, nv, T)`` float32, o ``(B, nv, T, dv)``; the caller's closure
+    reads and writes the state wherever it keeps it.  Of the T tokens the
+    first ``n_real`` are real (all, where None): the others get ``g = 0`` and
+    ``beta = 0``, which leaves the state exactly as it was, and the new tail
+    ends at the last real token.  Returns ``(y (B, T, C), new tail)``."""
+    B, T, _ = x.shape
+    nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv, K = cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.linear_conv_kernel
+    n_qkv = cfg.linear_qkv_width
+    qkvz, ba = lin(x, gp["in_proj_qkvz"]), lin(x, gp["in_proj_ba"])
+    z = qkvz[..., n_qkv:]
+    seen = jnp.concatenate([tail.astype(qkvz.dtype), qkvz[..., :n_qkv]], axis=1)        # (B, K - 1 + T, channels)
+    new_tail = (seen[:, T:] if n_real is None
+                else jax.lax.dynamic_slice_in_dim(seen, n_real, K - 1, axis=1)).astype(tail.dtype)
+    # causal depthwise conv, no bias: tap j of a channel weighs the token K - 1 - j back
+    w = gp["conv_w"].astype(jnp.float32)
+    qkv = jax.nn.silu(sum(seen[:, j:j + T].astype(jnp.float32) * w[:, j] for j in range(K))).astype(x.dtype)
+    heads = lambda a, n, d: a.reshape(B, T, n, d).transpose(0, 2, 1, 3)  # noqa: E731
+    q = _l2norm(heads(qkv[..., :nk * dk], nk, dk)) * (dk ** -0.5)
+    k = _l2norm(heads(qkv[..., nk * dk:2 * nk * dk], nk, dk))
+    v = heads(qkv[..., 2 * nk * dk:], nv, dv)
+    beta = jax.nn.sigmoid(ba[..., :nv].astype(jnp.float32)) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
+    g = -jnp.exp(gp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+        ba[..., nv:].astype(jnp.float32) + gp["dt_bias"].astype(jnp.float32))
+    if n_real is not None:
+        real = (jnp.arange(T) < n_real)[None, :, None]
+        g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+    o = recur(q, k, v, g.transpose(0, 2, 1), beta.transpose(0, 2, 1))                   # (B, nv, T, dv)
+    o = _rms(o.transpose(0, 2, 1, 3), gp["norm"], cfg.norm_eps) * jax.nn.silu(z.reshape(B, T, nv, dv))
+    return lin(o.reshape(B, T, nv * dv), gp["out_proj"]), new_tail
+
+
+def gdn_recur_dense(state):
+    """``recur`` for :func:`gdn_mixer` over a dense state ``(B, nv, dk, dv)``:
+    the chunked scan for a piece of a prompt, one step of the rule for a
+    token.  Returns ``(recur, box)``; after the call ``box[0]`` is the state
+    after the last token, in ``state``'s dtype."""
+    box = [state]
+
+    def recur(q, k, v, g, beta):
+        from thunder_tpu.executors import jaxex, pallasex
+
+        T, rep = v.shape[2], v.shape[1] // q.shape[1]
+        if T > 1:
+            o, box[0] = jaxex.gdn_chunk_state(q, k, v, g, beta, state)
+            return o
+        f32 = jnp.float32
+        col = lambda a: jnp.repeat(a[:, :, 0], rep, axis=1).astype(f32)[..., None]  # noqa: E731 -- (B, nv, dk, 1)
+        row = lambda a: jnp.broadcast_to(a.astype(f32), v.shape)  # noqa: E731 -- (B, nv, 1, dv)
+        o, S = jax.vmap(jax.vmap(pallasex.gdn_step_math))(
+            state.astype(f32), col(k), col(q), v.astype(f32), row(jnp.exp(g)[..., None]), row(beta[..., None]))
+        box[0] = S.astype(state.dtype)
+        return o.astype(v.dtype)
+
+    return recur, box
+
+
 def require_servable(cfg: Config) -> None:
-    """The one refusal of a config this module's forward cannot run (a
-    ``linear_attention`` layer or an expert share): such a model trains
+    """The one refusal of a config this module's forward cannot run (an
+    expert share, gated or per-head-normed attention): such a model trains
     through ``tt.jit`` / ``make_train_step``; serving it is not built yet."""
     why = getattr(cfg, "training_only", None)
     if why:
@@ -342,10 +441,32 @@ def require_servable(cfg: Config) -> None:
             "It trains through tt.jit / distributed.make_train_step (llama.gpt_loss).")
 
 
+def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0):
+    """A block from its mixer's output ``h`` on: the residual sums, the norms
+    and the MLP, for every block layout (``n1``: the mixer's input, which a
+    shared attention norm hands to the MLP too).  The dense cache's forward
+    and the paged server's end their blocks here."""
+    mlp = partial(_mlp, bp["mlp"], cfg=cfg, quantized=quantized, lora=lora, lora_scaling=lora_scaling)
+    if cfg.post_sublayer_norm:          # OLMo: the norms sit on what the sublayers give
+        x = x + _norm(h, bp["norm_1"], cfg)
+        return x + _norm(mlp(x), bp["norm_2"], cfg)
+    if cfg.parallel_residual:
+        n2 = n1 if cfg.shared_attention_norm else _norm(x, bp["norm_2"], cfg, bp.get("norm_2_b"))
+        return x + h + mlp(n2)
+    x = x + h
+    return x + mlp(_norm(x, bp["norm_2"], cfg, bp.get("norm_2_b")))
+
+
 def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *,
-                       quantized=False, lora=None, lora_scaling=1.0):
+                       quantized=False, lora=None, lora_scaling=1.0, n_real=None):
     """Forward of new tokens ``idx`` (B, T) at global positions [pos, pos+T)
     against/into ``cache``.  Returns (logits (B, T, V), updated cache).
+
+    A model with linear_attention layers keeps ``cache["conv"]`` and
+    ``cache["state"]`` beside ``k``/``v`` (:func:`state_shapes`), which hold
+    the full-attention layers only; they are the conv's last inputs and the
+    delta rule's state *before* position ``pos``.  ``n_real``: how many of the
+    T tokens are real (a padded prompt: the tail must leave the state alone).
 
     ``lora``: optional per-request LoRA factors —
     ``{target: {"a": (B, L, r, fin), "b": (B, L, fout, r)}}`` with one
@@ -371,28 +492,33 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
         cos_t = jax.lax.dynamic_slice_in_dim(cos_all, pos, T, axis=0)
         sin_t = jax.lax.dynamic_slice_in_dim(sin_all, pos, T, axis=0)
 
-    new_k, new_v = [], []
+    new_k, new_v, new_conv, new_state = [], [], [], []
+    lin = partial(_linear, quantized=quantized)
     for l, bp in enumerate(params["blocks"]):
-        n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
+        # OLMo's blocks norm what a sublayer gives, not what it takes
+        n1 = x if cfg.post_sublayer_norm else _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
         lora_l = None
         if lora:
             lora_l = {t: (ab["a"][:, l], ab["b"][:, l]) for t, ab in lora.items()}
-        h, ck, cv = _attn_with_cache(
-            bp["attn"], n1, cos_t, sin_t, cache["k"][l], cache["v"][l], pos, cfg,
-            quantized=quantized, lora=lora_l, lora_scaling=lora_scaling,
-        )
-        new_k.append(ck)
-        new_v.append(cv)
-        if cfg.parallel_residual:
-            n2 = n1 if cfg.shared_attention_norm else _norm(x, bp["norm_2"], cfg, bp.get("norm_2_b"))
-            x = x + h + _mlp(bp["mlp"], n2, cfg, quantized=quantized,
-                             lora=lora_l, lora_scaling=lora_scaling)
+        if cfg.layer_kind(l) == "linear_attention":
+            j = len(new_state)
+            recur, box = gdn_recur_dense(cache["state"][j])
+            h, tail = gdn_mixer(bp["gdn"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
+            new_conv.append(tail)
+            new_state.append(box[0])
         else:
-            x = x + h
-            x = x + _mlp(bp["mlp"], _norm(x, bp["norm_2"], cfg, bp.get("norm_2_b")), cfg,
-                         quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
+            j = len(new_k)
+            h, ck, cv = _attn_with_cache(
+                bp["attn"], n1, cos_t, sin_t, cache["k"][j], cache["v"][j], pos, cfg,
+                quantized=quantized, lora=lora_l, lora_scaling=lora_scaling,
+            )
+            new_k.append(ck)
+            new_v.append(cv)
+        x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
 
     cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    if new_state:
+        cache.update(conv=jnp.stack(new_conv), state=jnp.stack(new_state))
     x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
     head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
     logits = (_linear(x, head, params.get("lm_head_b"), quantized=quantized)).astype(jnp.float32)

@@ -110,6 +110,16 @@ class Config:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 4
+    # hf ``linear_allow_neg_eigval``: beta = 2 sigmoid(b), in (0, 2), so the
+    # transition exp(g) (I - beta k k^T) has the eigenvalue exp(g) (1 - beta) in
+    # (-1, 1) along k
+    linear_allow_neg_eigval: bool = False
+    # OLMo 2/3 blocks: the norms sit on the sublayers' *outputs*
+    # (``x + norm_1(mixer(x))``, then ``+ norm_2(mlp(.))``), not their inputs
+    post_sublayer_norm: bool = False
+    # q/k RMSNorm over the whole projected width (``q_norm (nh * hs)``, ``k_norm
+    # (ng * hs)``) before the split into heads (OLMo 2/3); ``qk_norm`` norms a head
+    qk_norm_whole: bool = False
     # mlp_class "SparseMoE": softmax router over all ``n_expert``, top
     # ``n_expert_per_token`` renormalised, SwiGLU experts of width
     # ``intermediate_size``.  The layer holds experts ``[expert_first,
@@ -153,6 +163,10 @@ class Config:
                 assert nk > 0 and nv % nk == 0 and self.linear_key_head_dim > 0 and self.linear_value_head_dim > 0, (
                     "linear_attention layers need linear_num_key_heads/_value_heads and their head dims")
                 assert not self.bias and not self.parallel_residual, "linear_attention: sequential, bias-free blocks only"
+        assert not (self.qk_norm and self.qk_norm_whole), "qk_norm norms a head, qk_norm_whole the projection: one of them"
+        if self.post_sublayer_norm:
+            assert not self.parallel_residual and not self.shared_attention_norm, (
+                "post_sublayer_norm: sequential blocks with a norm after each sublayer")
         if self.bias:
             assert self.norm_class == "LayerNorm", "bias implies LayerNorm (GPT-2/NeoX style)"
         assert not (self.lm_head_bias and self.fused_head_ce), (
@@ -169,13 +183,33 @@ class Config:
         return "full_attention" if self.layer_types is None else self.layer_types[i]
 
     @property
+    def kv_layers(self) -> tuple:
+        """The model layers that keep K and V, in order: the one map from a
+        model layer to its layer of the server's K/V cache and arenas
+        (``kv_layers.index(i)``).  Every layer of a model without ``layer_types``."""
+        return tuple(i for i in range(self.n_layer) if self.layer_kind(i) == "full_attention")
+
+    @property
+    def linear_layers(self) -> tuple:
+        """The model layers that keep a recurrent state and a conv tail, in
+        order (``linear_layers.index(i)`` is the layer of the state arena)."""
+        return tuple(i for i in range(self.n_layer) if self.layer_kind(i) == "linear_attention")
+
+    @property
+    def linear_qkv_width(self) -> int:
+        """Channels of a linear_attention layer's conv: q and k a key head, v a value head."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
+
+    @property
     def training_only(self) -> str | None:
         """Why ``models.generate`` and ``tt.serve`` cannot run this config, or
-        None: the server holds no recurrent state and no expert share yet."""
-        if self.layer_types is not None and "linear_attention" in self.layer_types:
-            return "it has linear_attention layers (recurrent state; the KV cache cannot hold it)"
+        None: the server holds no expert share and no gated attention yet."""
         if self.mlp_class == "SparseMoE":
             return "its mlp_class is SparseMoE (an expert share; the serving forward has no such layer)"
+        for knob in ("attn_output_gate", "qk_norm", "norm_zero_centered"):
+            if getattr(self, knob):
+                return f"it sets {knob} (the serving forward's attention and norms have no such form)"
         return None
 
     @classmethod
@@ -345,6 +379,8 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
             }
             if config.qk_norm:
                 block["attn"].update(q_norm=norm_init((hs,), dtype=dtype), k_norm=norm_init((hs,), dtype=dtype))
+            if config.qk_norm_whole:
+                block["attn"].update(q_norm=norm_init((nh * hs,), dtype=dtype), k_norm=norm_init((ng * hs,), dtype=dtype))
         if config.bias:
             block["norm_1_b"] = zeros(config.n_embd)
             block["attn"].update(
@@ -506,6 +542,9 @@ def attention(ap, x, cos, sin, config: Config):
     k = proj("wk", x, ap["wk"], ap.get("bk"))  # (B, T, ng*hs)
     v = proj("wv", x, ap["wv"], ap.get("bv"))
 
+    if config.qk_norm_whole:
+        q = ltorch.rms_norm(q, (nh * hs,), _rms_weight(ap["q_norm"], config), eps=config.norm_eps)
+        k = ltorch.rms_norm(k, (ng * hs,), _rms_weight(ap["k_norm"], config), eps=config.norm_eps)
     gate = None
     if config.attn_output_gate:
         # wq projects to (q, gate) a head
@@ -571,6 +610,8 @@ def gated_delta_net(gp, x, config: Config):
     q = _l2norm(q) * (dk ** -0.5)
     k = _l2norm(k)
     beta = ltorch.sigmoid(ltorch.to(ba[..., :nv], ltorch.float32))
+    if config.linear_allow_neg_eigval:
+        beta = beta * 2.0
     a = ltorch.to(ba[..., nv:], ltorch.float32)
     g = -ltorch.exp(ltorch.to(gp["A_log"], ltorch.float32)) * ltorch.softplus(
         a + ltorch.to(gp["dt_bias"], ltorch.float32))
@@ -664,6 +705,11 @@ def mlp(mp, x, config: Config):
 
 
 def block_forward(bp, x, cos, sin, config: Config, kind: str = "full_attention"):
+    if config.post_sublayer_norm:
+        h = (gated_delta_net(bp["gdn"], x, config) if kind == "linear_attention"
+             else attention(bp["attn"], x, cos, sin, config))
+        x = x + _norm(h, bp["norm_1"], config)
+        return x + _norm(mlp(bp["mlp"], x, config), bp["norm_2"], config)
     n1 = _norm(x, bp["norm_1"], config, bp.get("norm_1_b"))
     if kind == "linear_attention":
         h = gated_delta_net(bp["gdn"], n1, config)

@@ -112,6 +112,8 @@ from thunder_tpu.serving.faults import (
     resolve_fault_plan,
 )
 from thunder_tpu.serving.kv_pool import (
+    gather_state,
+    scatter_state,
     SINK_BLOCK,
     PagedKVPool,
     PrefixIndex,
@@ -249,6 +251,35 @@ class RequestHandle:
 # suite full of small engines — reuses steady-state compiled programs
 _program_cache: dict = {}
 
+
+def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=None, lora=None,
+                       mesh=None, decode_steps: int = 1, model_fn=None) -> str | None:
+    """Why an engine with these options cannot serve a config that keeps a
+    recurrent state a request, or None.  Each is a mechanism that is not
+    built, not a shortcut that was skipped (ROADMAP Queue 2)."""
+    if prefix_sharing:
+        return ("prefix_sharing=True is unsupported: a prefix's KV blocks can be shared, its "
+                "recurrent state cannot (no snapshot of the state at a block edge is kept)")
+    if sessions is not None and sessions is not False:
+        return ("sessions= is unsupported: a parked session re-attaches through the shared-prefix "
+                "path, which has no state snapshot to resume from")
+    if speculative is not None:
+        return ("speculative= is unsupported: a rejected draft's tokens have already advanced the "
+                "state, and there is no rollback")
+    if lora is not None:
+        return ("lora= is unsupported: the adapter arenas are a model layer's attention and MLP "
+                "targets; the mixer's projections (in_proj_qkvz, out_proj) have none")
+    if mesh is not None:
+        return "mesh= is unsupported: the state arena has no layout under a tp axis"
+    if int(decode_steps) > 1:
+        return ("decode_steps > 1 is unsupported: a row that finishes inside a multi-step visit "
+                "would go on advancing its state")
+    if model_fn is not None:
+        return "a custom model_fn is unsupported: the state programs mirror forward_with_cache"
+    if cfg.sliding_window is not None:
+        return "a sliding window is unsupported beside a recurrent state (block expiry is untested with it)"
+    return None
+
 # one decode program's collective census per (mesh, static config, bucket):
 # the census pays an extra AOT compile, so it is module-cached like programs
 _collectives_cache: dict = {}
@@ -273,7 +304,7 @@ class ServingEngine:
         cache_dtype=None,
         kv_dtype=None,
         lora=None,
-        prefix_sharing: bool = True,
+        prefix_sharing: bool | None = None,
         clock: Callable[[], float] | None = None,
         telemetry=None,
         batch_buckets: Sequence[int] | None = None,
@@ -301,6 +332,21 @@ class ServingEngine:
         from thunder_tpu.models.generate import require_servable
 
         require_servable(cfg)
+        # a model with linear_attention layers keeps a recurrent state a
+        # request beside its KV; what such a state cannot serve yet refuses
+        # here, with its reason (as paged_supported does for the kernels)
+        self._hybrid = bool(getattr(cfg, "linear_layers", ()))
+        if self._hybrid:
+            why = hybrid_unsupported(
+                cfg, prefix_sharing=prefix_sharing, sessions=sessions, speculative=speculative,
+                lora=lora, mesh=mesh, decode_steps=decode_steps, model_fn=model_fn)
+            if why:
+                raise NotImplementedError(
+                    f"config {getattr(cfg, 'name', '?')!r} has linear_attention layers "
+                    f"(a recurrent state a request): {why}")
+            prefix_sharing = False
+        if prefix_sharing is None:
+            prefix_sharing = True
         if shardings is not None and mesh is None:
             raise ValueError("shardings= requires mesh= (param placement needs a mesh)")
         from thunder_tpu.core import compile_cache
@@ -340,6 +386,8 @@ class ServingEngine:
         self.pool = PagedKVPool(
             cfg, num_blocks=num_blocks, block_size=block_size, dtype=dtype,
             kv_dtype=kv_dtype, mesh=mesh,
+            # a state slot a batch slot: a request that has one never waits for the other
+            **({"state_slots": max_batch} if self._hybrid else {}),
         )
         # decode attention path, resolved ONCE at construction (each engine
         # builds exactly one decode program kind, so the program-set bound
@@ -627,6 +675,12 @@ class ServingEngine:
         self._m_attn_fallback = reg0.counter("serving.attn.fallback_steps")
         self._m_host_visits = reg0.counter("serving.decode.host_visits")
         self._m_pool_occ = reg0.gauge("serving.pool.occupancy_frac")
+        if self._hybrid:
+            st = self.pool.state
+            reg0.gauge("serving.state.slots").set(st.num_slots)
+            reg0.gauge("serving.state.arena_bytes").set(st.arena_bytes())
+            self._m_state_leased = reg0.gauge("serving.state.leased")
+            self._m_state_low_water = reg0.gauge("serving.state.free_slots_low_water")
         if speculative is not None:
             self._m_spec_rounds = reg0.counter("serving.spec.rounds")
             self._m_spec_accepted = reg0.counter("serving.spec.accepted_tokens")
@@ -998,6 +1052,32 @@ class ServingEngine:
         if not handle.done():
             self._finish(handle._req, FINISH_EVICTED)
 
+    def held(self, handle: RequestHandle) -> dict:
+        """What the caches hold of a running request, read once nothing is
+        in flight (an async engine harvests first): ``tokens``, how many of
+        its prompt and generated tokens went in; ``k`` and ``v`` ``(L_kv, ng,
+        tokens, hs)`` as attention reads them (a quantised arena comes
+        dequantised, at the compute dtype); and, for a model with
+        linear_attention layers, its slot's ``state (L_lin, nv, dk, dv)`` and
+        ``conv (L_lin, K - 1, channels)`` as stored.  For the tests and for a
+        comparison with a reference; changes nothing."""
+        if self.async_step:
+            self._harvest()
+        req = handle._req
+        if req.state != "running":
+            raise RuntimeError(f"request {req.rid} is {req.state}: the caches hold a running request only")
+        arenas = self.pool.arenas
+        table = jnp.asarray([req.block_table[:self.pool.blocks_for_tokens(req.pos)]], dtype=jnp.int32)
+        if self.pool.quantized_kv:
+            k, v = gather_dense_q(arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
+                                  table, self.pool.dtype)
+        else:
+            k, v = gather_dense(arenas["k"], arenas["v"], table)
+        out = {"tokens": req.pos, "k": k[:, 0, :, :req.pos], "v": v[:, 0, :, :req.pos]}
+        if self._hybrid:
+            out.update({name: arena[req.state_slot] for name, arena in self.pool.state.arenas.items()})
+        return out
+
     def shutdown(self, *, drain: bool = True) -> None:
         """Graceful stop: optionally drains, discards whatever is still in
         flight, evicts whatever remains, closes owned telemetry, and
@@ -1067,6 +1147,7 @@ class ServingEngine:
             "pool_utilization": self.pool.utilization(),
             "kv_dtype": str(self.pool.kv_dtype),
             "arena_bytes": self.pool.arena_bytes(),
+            **({"state": self.pool.state.snapshot()} if self._hybrid else {}),
             "async_step": self.async_step,
             "prefill_chunk": sch.prefill_chunk,
             "decode_steps": self.decode_steps,
@@ -1435,6 +1516,14 @@ class ServingEngine:
         else:
             self._harvest_inline(rec)
 
+    def _state_args(self, state_slot: int, n_real: int) -> tuple:
+        """What a chunk program of a model with a recurrent state takes
+        beside the rest: the request's state slot and how many of the piece's
+        tokens are real (the padded tail must leave the state alone)."""
+        if not self._hybrid:
+            return ()
+        return jnp.asarray([state_slot], dtype=jnp.int32), jnp.int32(n_real)
+
     def _chunk_kind(self) -> str:
         """The non-speculative chunk program kind this engine dispatches —
         resolved once at construction (``self.attn_chunk``), so raggedness
@@ -1523,6 +1612,8 @@ class ServingEngine:
                 jnp.asarray(req.key),
                 self._lora_arenas(), jnp.asarray([req.adapter_slot], dtype=jnp.int32),
             )
+            if self._hybrid:
+                args += (jnp.asarray([req.state_slot], dtype=jnp.int32),)
             if self._constraints:
                 # the final piece samples token 0: it must respect the
                 # request's automaton exactly like every decode draw
@@ -1552,6 +1643,7 @@ class ServingEngine:
                     self.params, jnp.asarray(toks)[None], jnp.int32(pos),
                     pool.arenas, jnp.asarray(table), jnp.asarray(dest),
                     self._lora_arenas(), jnp.asarray([req.adapter_slot], dtype=jnp.int32),
+                    *self._state_args(req.state_slot, n_real),
                 )
             rec = {"kind": "chunk", "req": req, "qerr": qerr,
                    "compiled": compiled, "span": name,
@@ -1743,6 +1835,7 @@ class ServingEngine:
             # plus the cached tables/slots — zero host->device transfers
             toks_d, pos_d = st["toks"], st["pos"]
             tables_d, keys_d, slots_d = st["tables"], st["keys"], st["slots"]
+            sslots_d = st.get("sslots")
             host_pos = st["host_pos"] + N
             stop_d = st.get("stop")
         else:
@@ -1752,6 +1845,7 @@ class ServingEngine:
             keys = np.zeros((Bb, *np.shape(running[0].key)),
                             dtype=np.asarray(running[0].key).dtype)
             slots = np.zeros(Bb, dtype=np.int32)           # padding rows: base slot
+            sslots = np.zeros(Bb, dtype=np.int32)          # padding rows: the sink state slot
             # multi-step stopping: the last position a row may write before
             # FINISH_LENGTH (see _build_decode_multi); -1 parks padding rows
             # dead from step 0
@@ -1763,11 +1857,13 @@ class ServingEngine:
                 tables[i, : len(r.block_table)] = r.block_table
                 keys[i] = r.key
                 slots[i] = r.adapter_slot
+                sslots[i] = r.state_slot
                 stop[i] = r.prompt_len + r.max_new_tokens - 2
             # commit once; the chained steps reuse these device buffers
             toks_d, pos_d = jnp.asarray(toks), jnp.asarray(host_pos)
             tables_d, keys_d = jnp.asarray(tables), jnp.asarray(keys)
             slots_d = jnp.asarray(slots)
+            sslots_d = jnp.asarray(sslots) if self._hybrid else None
             stop_d = jnp.asarray(stop) if N > 1 else None
         # constrained decoding: the per-row token masks are fresh host data
         # every dispatch (the automata advanced at the last harvest) — an
@@ -1832,6 +1928,8 @@ class ServingEngine:
                      keys_d, lora_arenas, slots_d)
         if N > 1:
             call_args = call_args + (stop_d,)
+        if sslots_d is not None:
+            call_args = call_args + (sslots_d,)
         if cmask_d is not None:
             call_args = call_args + (cmask_d,)
         with self._span("serve.decode_dispatch.call"), \
@@ -1849,6 +1947,7 @@ class ServingEngine:
             "sig": sig, "toks": nxt, "pos": new_pos, "tables": tables_d,
             "keys": new_keys, "slots": slots_d, "host_pos": host_pos,
             **({"stop": stop_d} if N > 1 else {}),
+            **({"sslots": sslots_d} if sslots_d is not None else {}),
         }
         rec = {"kind": "decode", "running": running, "nxt": nxt,
                "new_keys": new_keys, "pos": host_pos, "bucket": [Bb, nbb],
@@ -2262,6 +2361,9 @@ class ServingEngine:
         # the post-mortem capacity floor: how close the pool ever came to
         # exhaustion (also in the flight-recorder pool snapshot)
         self._m_pool_low_water.set(self.pool.free_blocks_low_water)
+        if self._hybrid:
+            self._m_state_leased.set(self.pool.state.leased)
+            self._m_state_low_water.set(self.pool.state.free_low_water)
 
     #
     # fault containment + re-prefill recovery
@@ -2501,6 +2603,7 @@ class ServingEngine:
                     pool.arenas, jnp.asarray(table), jnp.asarray(dest),
                     self._lora_arenas(),
                     jnp.asarray([adapter_slot], dtype=jnp.int32),
+                    *self._state_args(req.state_slot if req is not None else 0, n_real),
                 )
                 self._note_chunk_attn_step()
             pool.set_arenas(arenas)
@@ -2728,6 +2831,7 @@ class ServingEngine:
 
     def _build_prefill(self, Tb: int, nbb: int) -> Callable:
         cfg, fwd, temp = self.cfg, self._forward, self.temperature
+        hybrid = self._hybrid
         qkv = self.pool.quantized_kv
         cdtype = jnp.dtype(self.pool.dtype)
         cap = self.pool.capacity_tokens(nbb)
@@ -2746,26 +2850,35 @@ class ServingEngine:
                 )
             else:
                 kd, vd = gather_dense(arenas["k"], arenas["v"], table[None, :])
+            held, more = {}, {}
+            if hybrid:
+                # the request's state slot rides before the constraint mask;
+                # a prompt's first piece starts from zeros, whatever the slot's
+                # last owner left, and the padded tail leaves the state alone
+                sslot, cmask = cmask[0], cmask[1:]
+                held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
+                more = {"n_real": n_real}
             logits, cache = fwd(
-                params, toks, pos, {"k": kd, "v": vd}, cos_all, sin_all, cfg,
-                **self._fwd_kwargs(lora, slot),
+                params, toks, pos, {"k": kd, "v": vd, **held}, cos_all, sin_all, cfg,
+                **self._fwd_kwargs(lora, slot), **more,
             )
             last = jax.lax.dynamic_index_in_dim(logits, n_real - 1, axis=1, keepdims=False)
             if cmask:
                 last = jnp.where(cmask[0], last, -jnp.inf)
             key, sub = jax.random.split(key)
             tok = sample_token(last, temp, sub)            # (1,) — solo-prefill parity
+            kept = scatter_state(arenas, cache, sslot) if hybrid else {}
             if qkv:
                 k_arena, k_scale, k_err = scatter_blocks_q(
                     arenas["k"], arenas["k_scale"], cache["k"], dest)
                 v_arena, v_scale, v_err = scatter_blocks_q(
                     arenas["v"], arenas["v_scale"], cache["v"], dest)
                 arenas = {"k": k_arena, "v": v_arena,
-                          "k_scale": k_scale, "v_scale": v_scale}
+                          "k_scale": k_scale, "v_scale": v_scale, **kept}
                 qerr = 0.5 * (k_err + v_err)
             else:
                 arenas = {"k": scatter_blocks(arenas["k"], cache["k"], dest),
-                          "v": scatter_blocks(arenas["v"], cache["v"], dest)}
+                          "v": scatter_blocks(arenas["v"], cache["v"], dest), **kept}
                 qerr = jnp.float32(0.0)
             return tok, arenas, key, qerr
 
@@ -2779,13 +2892,16 @@ class ServingEngine:
         but unused, so XLA dead-code-eliminates the lm_head matmul — a
         chunk is strictly cheaper than a same-width prefill."""
         cfg, fwd = self.cfg, self._forward
+        hybrid = self._hybrid
         qkv = self.pool.quantized_kv
         cdtype = jnp.dtype(self.pool.dtype)
         cap = self.pool.capacity_tokens(nbb)
         cos_all, sin_all = build_rope_cache(cfg, cap)
 
+        # a model that keeps a recurrent state hands two things more: the
+        # request's state slot and how many of the piece's tokens are real
         @partial(jax.jit, donate_argnums=(3,), **self._jit_kwargs("prefill_chunk"))
-        def prefill_chunk(params, toks, pos, arenas, table, dest, lora, slot):
+        def prefill_chunk(params, toks, pos, arenas, table, dest, lora, slot, *state_args):
             if qkv:
                 kd, vd = gather_dense_q(
                     arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
@@ -2793,21 +2909,27 @@ class ServingEngine:
                 )
             else:
                 kd, vd = gather_dense(arenas["k"], arenas["v"], table[None, :])
+            held, more = {}, {}
+            if hybrid:
+                sslot, n_real = state_args
+                held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
+                more = {"n_real": n_real}
             _logits, cache = fwd(
-                params, toks, pos, {"k": kd, "v": vd}, cos_all, sin_all, cfg,
-                **self._fwd_kwargs(lora, slot),
+                params, toks, pos, {"k": kd, "v": vd, **held}, cos_all, sin_all, cfg,
+                **self._fwd_kwargs(lora, slot), **more,
             )
+            kept = scatter_state(arenas, cache, sslot) if hybrid else {}
             if qkv:
                 k_arena, k_scale, k_err = scatter_blocks_q(
                     arenas["k"], arenas["k_scale"], cache["k"], dest)
                 v_arena, v_scale, v_err = scatter_blocks_q(
                     arenas["v"], arenas["v_scale"], cache["v"], dest)
                 arenas = {"k": k_arena, "v": v_arena,
-                          "k_scale": k_scale, "v_scale": v_scale}
+                          "k_scale": k_scale, "v_scale": v_scale, **kept}
                 qerr = 0.5 * (k_err + v_err)
             else:
                 arenas = {"k": scatter_blocks(arenas["k"], cache["k"], dest),
-                          "v": scatter_blocks(arenas["v"], cache["v"], dest)}
+                          "v": scatter_blocks(arenas["v"], cache["v"], dest), **kept}
                 qerr = jnp.float32(0.0)
             return arenas, qerr
 
@@ -2827,10 +2949,12 @@ class ServingEngine:
         (block-aligned chunk widths, no sliding window)."""
         from thunder_tpu.serving.paged_attention import (
             forward_paged,
+            with_state,
             write_fresh_kv_chunk,
         )
 
         cfg = self.cfg
+        hybrid = self._hybrid
         qkv = self.pool.quantized_kv
         cdtype = jnp.dtype(self.pool.dtype)
         kv_dtype = jnp.dtype(self.pool.kv_dtype) if qkv else None
@@ -2842,21 +2966,24 @@ class ServingEngine:
         @partial(jax.jit, donate_argnums=(3,),
                  **self._jit_kwargs("prefill_chunk_paged"))
         def prefill_chunk_paged(params, toks, pos, arenas, table, dest, lora,
-                                slot):
+                                slot, *state_args):
             pv = jnp.reshape(pos, (1,)).astype(jnp.int32)   # (B=1,) vec pos
+            more = dict(zip(("sslots", "n_real"), state_args)) if hybrid else {}
             _logits, fresh = forward_paged(
                 params, toks, pv, arenas, table[None, :], cos_all, sin_all,
                 cfg, cdtype=cdtype, mesh=mesh, lora_fused=True,
-                **self._fwd_kwargs(lora, slot),
+                **self._fwd_kwargs(lora, slot), **more,
             )
-            return write_fresh_kv_chunk(
+            out, qerr = write_fresh_kv_chunk(
                 arenas, fresh, dest, pv, block_size=bs,
                 kv_dtype=kv_dtype, mesh=mesh)
+            return with_state(out, fresh), qerr
 
         return prefill_chunk_paged
 
     def _build_decode(self, Bb: int, nbb: int) -> Callable:
         cfg, fwd, temp = self.cfg, self._forward, self.temperature
+        hybrid = self._hybrid
         qkv = self.pool.quantized_kv
         cdtype = jnp.dtype(self.pool.dtype)
         bs = self.pool.block_size
@@ -2885,8 +3012,13 @@ class ServingEngine:
                 )
             else:
                 kd, vd = gather_dense(arenas["k"], arenas["v"], tables)
+            held = {}
+            if hybrid:
+                # the rows' state slots ride before the constraint mask
+                sslots, cmask = cmask[0], cmask[1:]
+                held = gather_state(arenas, sslots, jnp.zeros((Bb,), bool))
             logits, cache = fwd(
-                params, toks[:, None], pos, {"k": kd, "v": vd}, cos_all, sin_all, cfg,
+                params, toks[:, None], pos, {"k": kd, "v": vd, **held}, cos_all, sin_all, cfg,
                 **self._fwd_kwargs(lora, slots),
             )
             sp = jax.vmap(jax.random.split)(keys)          # per-request key chains
@@ -2911,12 +3043,14 @@ class ServingEngine:
                     arenas["k"], arenas["k_scale"], pick(kc, pos), dest_block, dest_slot)
                 v_arena, v_scale = scatter_token_q(
                     arenas["v"], arenas["v_scale"], pick(vc, pos), dest_block, dest_slot)
-                arenas = {"k": k_arena, "v": v_arena,
-                          "k_scale": k_scale, "v_scale": v_scale}
+                new = {"k": k_arena, "v": v_arena,
+                       "k_scale": k_scale, "v_scale": v_scale}
             else:
-                arenas = {"k": scatter_token(arenas["k"], pick(kc, pos), dest_block, dest_slot),
-                          "v": scatter_token(arenas["v"], pick(vc, pos), dest_block, dest_slot)}
-            return nxt, new_keys, pos + 1, arenas
+                new = {"k": scatter_token(arenas["k"], pick(kc, pos), dest_block, dest_slot),
+                       "v": scatter_token(arenas["v"], pick(vc, pos), dest_block, dest_slot)}
+            if hybrid:
+                new.update(scatter_state(arenas, cache, sslots))
+            return nxt, new_keys, pos + 1, new
 
         return decode
 
@@ -2928,9 +3062,10 @@ class ServingEngine:
         the aliased write kernel, so the compiled program contains zero
         gather/scatter primitives (tests assert this on the jaxpr) and no
         dense cache ever materializes."""
-        from thunder_tpu.serving.paged_attention import forward_paged, write_fresh_kv
+        from thunder_tpu.serving.paged_attention import forward_paged, with_state, write_fresh_kv
 
         cfg, temp = self.cfg, self.temperature
+        hybrid = self._hybrid
         qkv = self.pool.quantized_kv
         cdtype = jnp.dtype(self.pool.dtype)
         kv_dtype = jnp.dtype(self.pool.kv_dtype) if qkv else None
@@ -2942,10 +3077,14 @@ class ServingEngine:
         @partial(jax.jit, donate_argnums=(4,), **self._jit_kwargs("decode_paged"))
         def decode_paged(params, toks, pos, tables, arenas, keys, lora, slots,
                          *cmask):
+            more = {}
+            if hybrid:
+                # the rows' state slots ride before the constraint mask
+                more, cmask = {"sslots": cmask[0]}, cmask[1:]
             logits, fresh = forward_paged(
                 params, toks[:, None], pos, arenas, tables, cos_all, sin_all,
                 cfg, cdtype=cdtype, mesh=mesh, lora_fused=True,
-                **self._fwd_kwargs(lora, slots),
+                **self._fwd_kwargs(lora, slots), **more,
             )
             sp = jax.vmap(jax.random.split)(keys)          # per-request key chains
             new_keys, subs = sp[:, 0], sp[:, 1]
@@ -2955,8 +3094,9 @@ class ServingEngine:
             nxt = jax.vmap(lambda l, k: sample_token(l[None], temp, k)[0])(
                 lg, subs
             )
-            arenas = write_fresh_kv(arenas, fresh, tables, pos, block_size=bs,
-                                    kv_dtype=kv_dtype, mesh=mesh)
+            arenas = with_state(
+                write_fresh_kv(arenas, fresh, tables, pos, block_size=bs,
+                               kv_dtype=kv_dtype, mesh=mesh), fresh)
             return nxt, new_keys, pos + 1, arenas
 
         return decode_paged

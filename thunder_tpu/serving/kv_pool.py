@@ -53,8 +53,8 @@ import numpy as np
 from thunder_tpu.models.generate import kv_block_shape
 from thunder_tpu.serving.quant import is_quantized_kv, resolve_kv_dtype
 
-__all__ = ["PoolExhaustedError", "ArenaMismatchError", "PagedKVPool",
-           "PrefixIndex", "chunk_tables", "dest_for_pos",
+__all__ = ["PoolExhaustedError", "ArenaMismatchError", "PagedKVPool", "StatePool",
+           "PrefixIndex", "chunk_tables", "dest_for_pos", "gather_state", "scatter_state",
            "OCCUPANCY_WINDOW"]
 
 SINK_BLOCK = 0  # reserved physical block for padding/expired table entries
@@ -90,6 +90,89 @@ class ArenaMismatchError(ValueError):
         )
 
 
+SINK_SLOT = 0   # reserved state slot for padding rows, as block 0 is for table entries
+
+
+class StatePool:
+    """What a model's linear_attention layers keep a request, beside its KV
+    blocks: a slot-indexed arena of the delta rule's states, ``state (slots +
+    1, L_lin, nv, dk, dv)`` in float32 (``STATE_DTYPE``: no option of the
+    engine's, a deployment holds what the configuration states),
+    and of the conv's last inputs, ``conv (slots + 1, L_lin, K - 1,
+    channels)`` at the compute dtype.  A request leases one slot at admission
+    and gives it back when it finishes; slot 0 is the garbage sink padding
+    rows of a static-shape program point at.  The arrays travel in the KV
+    pool's ``arenas`` pytree (donated beside K and V, rebuilt with them); a
+    leased slot's contents are whatever its last owner left: the prompt's
+    first piece starts from zeros in the program, not here."""
+
+    STATE_DTYPE = jnp.float32
+
+    def __init__(self, cfg, slots: int, dtype):
+        from thunder_tpu.models.generate import state_shapes
+
+        if slots < 1:
+            raise ValueError(f"state_slots must be >= 1, got {slots}")
+        shapes = state_shapes(cfg, slots + 1)
+        # slot-major: a row's state is one contiguous slab a kernel can name by its slot
+        self.shapes = {k: (v[1], v[0], *v[2:]) for k, v in shapes.items()}
+        self.dtypes = {"state": jnp.dtype(self.STATE_DTYPE), "conv": jnp.dtype(dtype)}
+        self.num_slots = int(slots)
+        self.layers = len(cfg.linear_layers)
+        self._free: list[int] = list(range(self.num_slots, SINK_SLOT, -1))   # pop() -> lowest id
+        self._free_low_water = len(self._free)
+        self.rebuild()
+
+    def rebuild(self) -> None:
+        self.state = jnp.zeros(self.shapes["state"], self.dtypes["state"])
+        self.conv = jnp.zeros(self.shapes["conv"], self.dtypes["conv"])
+
+    @property
+    def arenas(self) -> dict:
+        return {"state": self.state, "conv": self.conv}
+
+    @property
+    def free_low_water(self) -> int:
+        """Fewest free slots ever observed."""
+        return self._free_low_water
+
+    @property
+    def leased(self) -> int:
+        return self.num_slots - len(self._free)
+
+    def can_lease(self) -> bool:
+        return bool(self._free)
+
+    def lease(self) -> int:
+        if not self._free:
+            raise PoolExhaustedError(f"no free state slot of {self.num_slots}")
+        slot = self._free.pop()
+        self._free_low_water = min(self._free_low_water, len(self._free))
+        return slot
+
+    def free(self, slot: int) -> None:
+        if slot == SINK_SLOT:
+            return
+        if slot in self._free or not 0 < slot <= self.num_slots:
+            raise ValueError(f"double free of state slot {slot}")
+        self._free.append(slot)
+
+    def slot_bytes(self) -> int:
+        """Bytes one slot costs across both arenas: the unit beside
+        ``block_bytes`` in byte-based admission."""
+        return (int(self.state.nbytes) + int(self.conv.nbytes)) // (self.num_slots + 1)
+
+    def arena_bytes(self) -> int:
+        return int(self.state.nbytes) + int(self.conv.nbytes)
+
+    def snapshot(self) -> dict:
+        return {"slots": self.num_slots, "leased": self.leased,
+                "free_low_water": self._free_low_water, "arena_bytes": self.arena_bytes(),
+                "slot_bytes": self.slot_bytes(), "layers": self.layers,
+                "dtype": str(self.dtypes["state"]), "conv_dtype": str(self.dtypes["conv"]),
+                "fill_frac": self.leased / self.num_slots}
+
+
 class PagedKVPool:
     """Block arena + free-list allocator + per-block reference counts.
 
@@ -107,7 +190,8 @@ class PagedKVPool:
     stays host-side and identical to the single-device pool."""
 
     def __init__(self, cfg, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
-                 *, kv_dtype=None, mesh=None, axis: str = "tp"):
+                 *, kv_dtype=None, mesh=None, axis: str = "tp",
+                 state_slots: int | None = None):
         if num_blocks < 2:
             raise ValueError(f"num_blocks must be >= 2 (block 0 is the sink), got {num_blocks}")
         if block_size < 1:
@@ -133,6 +217,14 @@ class PagedKVPool:
         else:
             self.arena_sharding = None
 
+        # two kinds of cache in one manager: a model with linear_attention
+        # layers keeps a state slot a request beside its blocks (the K/V
+        # arenas' layer axis then holds the full-attention layers only)
+        self.state = None
+        if getattr(cfg, "linear_layers", ()):
+            if state_slots is None:
+                raise ValueError("a config with linear_attention layers needs state_slots=")
+            self.state = StatePool(cfg, state_slots, dtype)
         # independent buffers (no copy traffic between K and V updates)
         self.k_arena = self._zeros(shape, self.kv_dtype)
         self.v_arena = self._zeros(shape, self.kv_dtype)
@@ -253,6 +345,7 @@ class PagedKVPool:
             "last": tl[-1] if tl else None,
             "peak_leased": max((s[2] for s in tl), default=0),
             "occupancy_frac": self.utilization(),
+            **({"state": self.state.snapshot()} if self.state is not None else {}),
         }
 
     def state_snapshot(self) -> dict:
@@ -272,6 +365,7 @@ class PagedKVPool:
             "kv_dtype": str(self.kv_dtype),
             "arena_bytes": self.arena_bytes(),
             "occupancy_timeline": [list(s) for s in self._occ_ring],
+            **({"state": self.state.snapshot()} if self.state is not None else {}),
         }
         if self.arena_sharding is not None:
             snap["arena_spec"] = str(self.arena_sharding.spec)
@@ -318,12 +412,16 @@ class PagedKVPool:
         if self.quantized_kv:
             out["k_scale"] = self.k_scale
             out["v_scale"] = self.v_scale
+        if self.state is not None:
+            out.update(self.state.arenas)
         return out
 
     def _check_arena(self, name: str, new: jax.Array) -> None:
         scale = name.endswith("_scale")
         want_shape = self._scale_shape if scale else self._arena_shape
         want_dtype = jnp.dtype(jnp.float32) if scale else jnp.dtype(self.kv_dtype)
+        if name in ("state", "conv"):
+            want_shape, want_dtype = self.state.shapes[name], self.state.dtypes[name]
         if tuple(new.shape) != want_shape:
             raise ArenaMismatchError(name, "shape", want_shape, tuple(new.shape))
         if new.dtype != want_dtype:
@@ -360,7 +458,10 @@ class PagedKVPool:
         # release_retired() at harvest, when the consumer has finished and
         # the deref costs microseconds.
         self._retired.append((self.k_arena, self.v_arena,
-                              self.k_scale, self.v_scale))
+                              self.k_scale, self.v_scale,
+                              *(self.state.arenas.values() if self.state is not None else ())))
+        if self.state is not None:
+            self.state.state, self.state.conv = arenas["state"], arenas["conv"]
         self.k_arena = arenas["k"]
         self.v_arena = arenas["v"]
         if self.quantized_kv:
@@ -395,6 +496,8 @@ class PagedKVPool:
         if self.quantized_kv:
             self.k_scale = self._zeros(self._scale_shape, jnp.float32)
             self.v_scale = self._zeros(self._scale_shape, jnp.float32)
+        if self.state is not None:
+            self.state.rebuild()      # slots stay leased; the replay rebuilds what they held
 
     def update_arenas(self, k_arena: jax.Array, v_arena: jax.Array,
                       k_scale: jax.Array | None = None,
@@ -537,6 +640,26 @@ def chunk_tables(block_table, pos: int, n_tokens: int, nbb: int,
     lo, hi = pos // bs, min(len(block_table), -(-(pos + n_tokens) // bs))
     dest[lo:hi] = block_table[lo:hi]
     return table, dest
+
+
+def gather_state(arenas, slots, fresh):
+    """The rows' recurrent state in the dense cache's layout
+    (``generate.state_shapes``): ``{"conv": (L_lin, B, K - 1, channels),
+    "state": (L_lin, B, nv, dk, dv)}`` from the slot-major arenas, zeros for
+    a row that is ``fresh`` (``(B,)`` bool: its sequence starts here, and the
+    slot still holds its last owner's).  Pure jnp; call inside jit."""
+    def one(arena):
+        rows = jnp.take(arena, slots, axis=0)                        # (B, L_lin, ...)
+        rows = jnp.where(fresh.reshape((-1,) + (1,) * (rows.ndim - 1)), jnp.zeros_like(rows), rows)
+        return jnp.swapaxes(rows, 0, 1)
+    return {"conv": one(arenas["conv"]), "state": one(arenas["state"])}
+
+
+def scatter_state(arenas, cache, slots):
+    """The inverse of :func:`gather_state`: the rows' new state back to their
+    slots (padding rows all write the sink, slot 0).  Returns the two arenas."""
+    return {name: arenas[name].at[slots].set(jnp.swapaxes(cache[name], 0, 1).astype(arenas[name].dtype))
+            for name in ("conv", "state")}
 
 
 def gather_dense(k_arena, v_arena, tables):

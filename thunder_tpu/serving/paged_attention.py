@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from thunder_tpu.executors.pallasex import (
+    gdn_decode_step,
     lora_delta_fused,
     paged_attn_decode,
     paged_attn_verify,
@@ -47,15 +48,16 @@ from thunder_tpu.executors.pallasex import (
     paged_token_write_fused,
 )
 from thunder_tpu.models.generate import (
+    gdn_mixer,
+    _close_block,
     _linear,
     _lora_delta,
-    _mlp,
     _norm,
     _project_qkv,
 )
 from thunder_tpu.serving.quant import quantize_kv
 
-__all__ = ["forward_paged", "write_fresh_kv", "write_fresh_kv_live",
+__all__ = ["forward_paged", "with_state", "write_fresh_kv", "write_fresh_kv_live",
            "write_fresh_kv_masked", "write_fresh_kv_chunk", "paged_supported"]
 
 
@@ -157,9 +159,46 @@ def _attn_paged_multi(q, arenas, fresh_k, fresh_v, tables, pos, *, layer, mesh):
     return _smap(local, mesh, in_specs, hspec)(*args)
 
 
+def _gdn_paged(gp, x, arenas, sslots, pos, cfg, *, layer, n_real, lin):
+    """A linear_attention layer of :func:`forward_paged`: ``generate.gdn_mixer``
+    (the one mixer; the dense cache calls it too) with the state where the
+    server keeps it.  A decode step (T = 1) runs ``gdn_decode_step`` on the
+    state arena in place; a piece of a prompt (one row) runs the chunked scan
+    from the slot's state, from zeros where the piece starts at position 0,
+    and writes the last state back.  Returns ``(y, state arena, conv arena)``."""
+    from thunder_tpu.executors import jaxex
+
+    T = x.shape[1]
+    held = {"state": arenas["state"]}
+    tail = arenas["conv"][sslots, layer]                             # (B, K - 1, channels)
+    fresh = (pos == 0) if T > 1 else None
+    if fresh is not None:
+        tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail), tail)
+
+    def recur(q, k, v, g, beta):
+        if T == 1:
+            o, held["state"] = gdn_decode_step(held["state"], sslots, q[:, :, 0], k[:, :, 0], v[:, :, 0],
+                                               g[:, :, 0], beta[:, :, 0], layer=layer)
+            return o[:, :, None]
+        h0 = held["state"][sslots, layer]
+        h0 = jnp.where(fresh[:, None, None, None], jnp.zeros_like(h0), h0)
+        o, last = jaxex.gdn_chunk_state(q, k, v, g, beta, h0)
+        held["state"] = held["state"].at[sslots, layer].set(last)
+        return o
+
+    y, new_tail = gdn_mixer(gp, x, tail, cfg, recur, n_real=n_real, lin=lin)
+    return y, held["state"], arenas["conv"].at[sslots, layer].set(new_tail)
+
+
+def with_state(arenas, fresh):
+    """The arenas a paged program returns: K and V as the writers left them,
+    the state and conv arenas as :func:`forward_paged` left them."""
+    return {**arenas, **{k: fresh[k] for k in ("state", "conv") if k in fresh}}
+
+
 def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                   cdtype, quantized=False, lora=None, lora_scaling=1.0,
-                  mesh=None, lora_fused=False):
+                  mesh=None, lora_fused=False, sslots=None, n_real=None):
     """Decode/verify forward straight off the KV block arenas.
 
     Mirrors ``forward_with_cache`` (vec-pos) except attention: instead of
@@ -178,10 +217,18 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     ``lora_fused`` routes the per-target adapter deltas through the fused
     ``lora_delta_fused`` kernel instead of standalone HLO einsums —
     bit-identical math, meshless only (a bare pallas_call has no SPMD
-    rule)."""
+    rule).
+
+    A model with linear_attention layers: ``arenas`` also holds the state
+    pool's ``state`` and ``conv``, ``sslots`` (B,) names each row's slot (0
+    the sink) and ``n_real`` how many of a prompt piece's T tokens are real;
+    the K/V arenas' layer axis counts the full-attention layers only
+    (``cfg.kv_layers``), and ``fresh`` carries the two updated state arenas
+    beside the fresh K/V (:func:`with_state`)."""
     B, T = idx.shape
     hs, nh = cfg.head_size, cfg.n_head
     window = cfg.sliding_window
+    state_arena, conv_arena, n_lin = arenas.get("state"), arenas.get("conv"), 0
     x = params["wte"][idx]
     if cfg.scale_embedding:
         x = x * (cfg.n_embd ** 0.5)
@@ -195,46 +242,48 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     delta_fn = lora_delta_fused if (lora_fused and mesh is None) else _lora_delta
     fresh_k, fresh_v = [], []
     for l, bp in enumerate(params["blocks"]):
-        n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
+        n1 = x if cfg.post_sublayer_norm else _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
         lora_l = None
         if lora:
             lora_l = {t: (ab["a"][:, l], ab["b"][:, l]) for t, ab in lora.items()}
-        q, k, v = _project_qkv(bp["attn"], n1, cos_t, sin_t, cfg, lin=lin,
-                               lora=lora_l, lora_scaling=lora_scaling,
-                               delta_fn=delta_fn)
-        # fresh K/V at the cache compute dtype — the exact values the dense
-        # path writes before attending
-        if T == 1:
-            # q: (B, nh, 1, hs) → (B, nh, hs)
-            fk = k[:, :, 0].astype(cdtype)
-            fv = v[:, :, 0].astype(cdtype)
-            y = _attn_paged(q[:, :, 0], arenas, fk, fv, tables, pos,
-                            layer=l, window=window, mesh=mesh)
-            y = y.reshape(B, 1, nh * hs)
+        if cfg.layer_kind(l) == "linear_attention":
+            h, state_arena, conv_arena = _gdn_paged(
+                bp["gdn"], n1, {"state": state_arena, "conv": conv_arena}, sslots, pos, cfg,
+                layer=n_lin, n_real=n_real, lin=lin)
+            n_lin += 1
         else:
-            fk = k.astype(cdtype)                  # (B, ng, T, hs)
-            fv = v.astype(cdtype)
-            y = _attn_paged_multi(q, arenas, fk, fv, tables, pos,
-                                  layer=l, mesh=mesh)
-            y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
-        h = lin(y, bp["attn"]["wo"], bp["attn"].get("bo"))
-        if lora_l is not None and "wo" in lora_l:
-            h = h + delta_fn(y, *lora_l["wo"], lora_scaling)
-        fresh_k.append(fk)
-        fresh_v.append(fv)
-        if cfg.parallel_residual:
-            n2 = n1 if cfg.shared_attention_norm else _norm(x, bp["norm_2"], cfg, bp.get("norm_2_b"))
-            x = x + h + _mlp(bp["mlp"], n2, cfg, quantized=quantized,
-                             lora=lora_l, lora_scaling=lora_scaling)
-        else:
-            x = x + h
-            x = x + _mlp(bp["mlp"], _norm(x, bp["norm_2"], cfg, bp.get("norm_2_b")), cfg,
-                         quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
+            q, k, v = _project_qkv(bp["attn"], n1, cos_t, sin_t, cfg, lin=lin,
+                                   lora=lora_l, lora_scaling=lora_scaling,
+                                   delta_fn=delta_fn)
+            kvl = len(fresh_k)                         # this layer's place in the K/V arenas
+            # fresh K/V at the cache compute dtype — the exact values the dense
+            # path writes before attending
+            if T == 1:
+                # q: (B, nh, 1, hs) → (B, nh, hs)
+                fk = k[:, :, 0].astype(cdtype)
+                fv = v[:, :, 0].astype(cdtype)
+                y = _attn_paged(q[:, :, 0], arenas, fk, fv, tables, pos,
+                                layer=kvl, window=window, mesh=mesh)
+                y = y.reshape(B, 1, nh * hs)
+            else:
+                fk = k.astype(cdtype)                  # (B, ng, T, hs)
+                fv = v.astype(cdtype)
+                y = _attn_paged_multi(q, arenas, fk, fv, tables, pos,
+                                      layer=kvl, mesh=mesh)
+                y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
+            h = lin(y, bp["attn"]["wo"], bp["attn"].get("bo"))
+            if lora_l is not None and "wo" in lora_l:
+                h = h + delta_fn(y, *lora_l["wo"], lora_scaling)
+            fresh_k.append(fk)
+            fresh_v.append(fv)
+        x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
 
     x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
     head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
     logits = (_linear(x, head, params.get("lm_head_b"), quantized=quantized)).astype(jnp.float32)
     fresh = {"k": jnp.stack(fresh_k, axis=1), "v": jnp.stack(fresh_v, axis=1)}
+    if state_arena is not None:
+        fresh.update(state=state_arena, conv=conv_arena)
     return logits, fresh
 
 

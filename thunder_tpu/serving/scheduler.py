@@ -119,6 +119,7 @@ class Request:
     block_table: list[int] = field(default_factory=list)
     n_shared_blocks: int = 0                # leading table entries leased via share()
     pos: int = 0                            # cache slots written (prompt + generated)
+    state_slot: int = 0                     # the recurrent state's slot (0: none; models with linear_attention layers)
     # lifecycle
     state: str = "queued"                   # queued | running | finished
     generated: list[int] = field(default_factory=list)
@@ -228,8 +229,12 @@ class Scheduler:
         """The reservation in **stored arena bytes** — block count × the
         pool's per-block cost at its storage dtype (int8 blocks plus their
         scale arenas cost ~4x less than f32, which is where quantized
-        capacity shows up in admission accounting)."""
-        return self.blocks_needed(req) * self.pool.block_bytes()
+        capacity shows up in admission accounting), plus one slot of the
+        state arena where the model keeps a recurrent state: a request costs
+        both kinds."""
+        state = self.pool.state
+        return (self.blocks_needed(req) * self.pool.block_bytes()
+                + (state.slot_bytes() if state is not None else 0))
 
     def check_feasible(self, prompt_len: int, max_new_tokens: int) -> int:
         """The never-fits validation, callable without constructing a
@@ -336,6 +341,9 @@ class Scheduler:
         if not self.queue or len(self.running) >= self.max_batch:
             return None
         head = self.queue[0]
+        state = self.pool.state
+        if state is not None and not state.can_lease():
+            return None
         if self.pool.can_alloc(max(self.blocks_needed(head) - shared_blocks, 0)):
             return head
         return None
@@ -346,6 +354,8 @@ class Scheduler:
         self.queue.popleft()
         req.block_table = block_table
         req.n_shared_blocks = n_shared
+        if self.pool.state is not None:
+            req.state_slot = self.pool.state.lease()
         # the block-aligned prefill resume point: tokens below it are
         # resident via the shared prefix; prefill pieces advance pos from
         # here (chunked prefill dispatches one piece per engine step)
@@ -369,9 +379,16 @@ class Scheduler:
         req.state = "finished"
         req.finish_reason = reason
         req.finish_t = self.clock()
+        self._release(req)
+
+    def _release(self, req: Request) -> None:
+        """Gives back what the request holds of both kinds: blocks and state slot."""
         if req.block_table:
             self.pool.free([b for b in req.block_table if b != SINK_BLOCK])
             req.block_table = []
+        if req.state_slot:
+            self.pool.state.free(req.state_slot)
+            req.state_slot = 0
 
     def preempt(self, req: Request) -> None:
         """Evict-and-resume checkpoint: running → queued, blocks released.
@@ -386,9 +403,7 @@ class Scheduler:
         time), behind every strictly more urgent entry."""
         assert req.state == "running", f"cannot preempt {req.state} request"
         self.running.remove(req)
-        if req.block_table:
-            self.pool.free([b for b in req.block_table if b != SINK_BLOCK])
-            req.block_table = []
+        self._release(req)
         req.n_shared_blocks = 0
         req.pos = 0
         req.state = "queued"
@@ -445,6 +460,7 @@ class Scheduler:
                 "pos": r.pos,
                 "prefilled": r.pos >= r.prompt_len,
                 "blocks": len(r.block_table),
+                "state_slot": r.state_slot,
                 "reserved_bytes": self.bytes_needed(r),
                 "shared_blocks": r.n_shared_blocks,
                 "adapter_id": r.adapter_id,

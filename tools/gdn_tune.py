@@ -1,8 +1,12 @@
 """Time the two kernels of the chunked gated delta rule alone on the chip, at the hybrid cell's shape.
 
-    python3 tools/gdn_tune.py [--check]
+    python3 tools/gdn_tune.py [--check] [--serve]
 
-One line: ms a call of ``gdn_chunk_fwd`` and ``gdn_chunk_bwd`` by name from a
+``--serve`` times what a server runs instead, at Olmo-Hybrid's heads (30 of 96
+and 192): ``gdn_chunk_fwd`` from a state to a state at every prefill bucket
+(T 1024, 2048, 2560, one sequence) and ``gdn_decode_step`` on a 33-slot,
+12-layer arena at 32 rows; with ``--check`` both against the float32
+recurrence first.  One line: ms a call of ``gdn_chunk_fwd`` and ``gdn_chunk_bwd`` by name from a
 device trace of five forward and five backward calls (B 2, 16 key and 32 value
 heads of 128, T 8192, bfloat16), of whatever else XLA runs beside them (the
 cumulative log-decay, the sum over the heads of a key head), and
@@ -75,15 +79,82 @@ def check():
     return worst
 
 
+# Hk, Hv, dk, dv, rows, arena slots, arena layers, prefill buckets: the serving cell's linear-attention layers
+SERVE = (30, 30, 96, 192, 32, 33, 12, (1024, 2048, 2560))
+
+
+def recurrence_from(q, k, v, g, beta, h0):
+    """o and the last state from ``h0``, token by token in float32."""
+    rep = v.shape[1] // q.shape[1]
+    q, k = jnp.repeat(q, rep, 1), jnp.repeat(k, rep, 1)
+
+    def head(q, k, v, g, b, S0):
+        def step(S, x):
+            qt, kt, vt, gt, bt = x
+            S = S * jnp.exp(gt)
+            S = S + jnp.outer(kt, (vt - S.T @ kt) * bt)
+            return S, S.T @ qt
+        return jax.lax.scan(step, S0, (q, k, v, g, b))
+
+    last, o = jax.vmap(jax.vmap(head))(q, k, v, g, beta, h0)
+    return o, last
+
+
+def serve(check: bool):
+    Hk, Hv, dk, dv, rows, slots, layers, buckets = SERVE
+    rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))   # noqa: E731
+    key = jax.random.PRNGKey(1)
+    arena = jax.random.normal(key, (slots, layers, Hv, dk, dv)) * 0.1
+    table = jnp.arange(1, rows + 1, dtype=jnp.int32)
+    if check:
+        _, q, k, v, g, beta = operands(1, Hk, Hv, 1024, dk, dv)
+        beta, h0 = 2.0 * beta, arena[1:2, 0]
+        o, last = px.gdn_chunk_state(q, k, v, g, beta, h0)
+        with jax.default_matmul_precision("highest"):
+            want = recurrence_from(*(x.astype(jnp.float32) for x in (q, k, v, g, beta)), h0)
+            tok = [x[:, :, 0] for x in operands(rows, Hk, Hv, 1, dk, dv)[1:]]
+            tok[4] = 2.0 * tok[4]
+            so, sa = px.gdn_decode_step(arena, table, *tok, layer=3)
+            swant = recurrence_from(*(x.astype(jnp.float32)[:, :, None] for x in tok), arena[table, 3])
+        worst = max(rel(o, want[0]), rel(last, want[1]), rel(so, swant[0][:, :, 0]), rel(sa[table, 3], swant[1]))
+        print(f"check serve: scan o {rel(o, want[0]):.6f} last state {rel(last, want[1]):.6f}; "
+              f"step o {rel(so, swant[0][:, :, 0]):.6f} state {rel(sa[table, 3], swant[1]):.6f}", flush=True)
+        if worst > 0.02:
+            sys.exit("gdn_tune: the compiled serving kernels disagree with the recurrence")
+    for T in buckets:
+        _, *ops = operands(1, Hk, Hv, T, dk, dv)
+        fwd = jax.jit(px.gdn_chunk_state)
+        jax.block_until_ready(fwd(*ops, arena[1:2, 0]))
+        ms = kernel_ms(lambda: jax.block_until_ready(fwd(*ops, arena[1:2, 0])), REPS)
+        own = sum(t for n, t in ms.items() if n.startswith("gdn_chunk_fwd"))
+        print(f"serve T {T}: gdn_chunk_fwd {own:7.3f} ms a call; beside it {sum(ms.values()) - own:.3f}", flush=True)
+    tok = [x[:, :, 0] for x in operands(rows, Hk, Hv, 1, dk, dv)[1:]]
+    step = jax.jit(lambda a, *t: px.gdn_decode_step(a, table, *t, layer=3), donate_argnums=(0,))
+    box = [arena]
+
+    def once():
+        _, box[0] = jax.block_until_ready(step(box[0], *tok))
+
+    once()
+    ms = kernel_ms(once, REPS)
+    own = sum(t for n, t in ms.items() if n.startswith("gdn_decode_step"))
+    counted = rows * 2 * Hv * dk * dv * 4
+    print(f"serve step, {rows} rows: gdn_decode_step {own:7.3f} ms a call ({counted / own / 1e6:.1f} GB/s of state as "
+          f"counted); beside it {sum(ms.values()) - own:.3f}", flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--check", action="store_true")
+    ap.add_argument("--serve", action="store_true")
     args = ap.parse_args()
     device = device_info()
     if device["platform"] != "tpu":
         sys.exit(f"gdn_tune: times the kernels on a device and needs a TPU; jax found "
                  f"{device['platform']!r} ({device['kind']}).  Nothing was measured.")
     print(device, flush=True)
+    if args.serve:
+        return serve(args.check)
     if args.check and check() > 0.02:   # bfloat16 operands: a few thousandths
         sys.exit("gdn_tune: the compiled kernels disagree with the recurrence")
     do, *ops = operands(*SHAPE)

@@ -68,13 +68,14 @@ def _served(eng, which=range(len(REQUESTS))):
 def served(model):
     """Six requests through three slots, so slots and state slots turn over, on
     both decode paths; the engine's counts afterwards."""
-    os.environ["THUNDER_TPU_PALLAS_INTERPRET"] = "1"
     out = {}
-    for attn in ("gather", "paged"):
-        claims = px.stats.get("gdn_decode", 0)
-        eng = _engine(model, attn)
-        out[attn] = (_served(eng), eng.stats(), eng._flight_state(), px.stats.get("gdn_decode", 0) - claims)
-        eng.shutdown()
+    with pytest.MonkeyPatch.context() as env:       # the worker's next test file gets its environment back
+        env.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+        for attn in ("gather", "paged"):
+            claims = px.stats.get("gdn_decode", 0)
+            eng = _engine(model, attn)
+            out[attn] = (_served(eng), eng.stats(), eng._flight_state(), px.stats.get("gdn_decode", 0) - claims)
+            eng.shutdown()
     return out
 
 

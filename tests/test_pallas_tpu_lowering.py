@@ -391,6 +391,95 @@ def test_narrow_heads_decode_block_by_block(shape, hs, store, tpu_sharding, monk
         jax.jit(_decode(1)).trace(*args).lower(lowering_platforms=("tpu",)).compile()
 
 
+# --------------------------------------------------------------------------
+# the serving engine's whole-prompt prefill, as the two serve cells build it
+# --------------------------------------------------------------------------
+
+SERVE_CELLS = {   # cell -> (layers lowered, full-attention layers among them, a prefill bucket, its flash blocks)
+    "mistral7b-serve-1chip.offline-batch": (2, 2, 3072, 1024),
+    "olmo-hybrid-serve-1chip.offline-longgen": (4, 1, 2560, 512),
+}
+
+
+@functools.cache
+def _cell_engine(cell: str):
+    """The cell's engine at its published widths with the depth cut further,
+    over weights that are shapes alone (nothing of 7B is allocated here)."""
+    import thunder_tpu as tt
+    from chipbench import common
+    from thunder_tpu.models import llama
+
+    _, config, mix = common.open_cell(cell)
+    arch = common.load_module("models", config["arch"])
+    layers = SERVE_CELLS[cell][0]
+    hf = {**config, "num_hidden_layers": layers}
+    if "layer_types" in hf:
+        hf["layer_types"] = hf["layer_types"][:layers]
+    cfg = llama.Config(**arch.program_config(hf))
+    params = jax.eval_shape(functools.partial(arch.make_params, hf), common.seed_words(1))
+    kw = {**config["engine"], **mix["engine"], "num_blocks": 240, "max_batch": 2, "batch_buckets": [2]}
+    return cfg, params, tt.serve(None, params, cfg, **kw)
+
+
+def _lower_prefill(cell: str, kind: str, sharding):
+    cfg, params, eng = _cell_engine(cell)
+    Tb, bs = SERVE_CELLS[cell][2], eng.pool.block_size
+    nbb = Tb // bs if kind == "prefill_fresh" else eng._nbb(Tb // bs)
+    prog = eng._build_prefill(Tb, nbb, fresh=kind == "prefill_fresh")
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)  # noqa: E731
+    one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)  # noqa: E731
+    where = (one((nbb,)),) if kind == "prefill_fresh" else (one((nbb,)), one((nbb,)))
+    args = (jax.tree_util.tree_map(sds, params), one((1, Tb)), *([] if kind == "prefill_fresh" else [one(())]), one(()),
+            jax.tree_util.tree_map(sds, eng.pool.arenas), *where, one((2,), jnp.uint32), {}, one((1,)),
+            *([one((1,))] if eng._hybrid else []))
+    before = px.stats["direct"]
+    lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
+    return cfg, eng, nbb, lowered, px.stats["direct"] - before
+
+
+@pytest.mark.parametrize("kind", ["prefill_fresh", "prefill"])
+@pytest.mark.parametrize("cell", SERVE_CELLS)
+def test_a_whole_prompts_prefill_lowers_to_flash_and_a_head_of_one_row(cell, kind, tpu_sharding, monkeypatch):
+    """``prefill_fresh`` at a bucket of each serve cell, lowered for a v5e: a
+    ``_flash_fwd`` custom call a full-attention layer on grouped K/V, no
+    gather from the K/V arenas, no float32 tensor of (heads, bucket, table
+    width), and a head product of one row.  The ``prefill`` kind beside it
+    still gathers the table and scores all of it, with the head on one row
+    too (both take ``logits_at``)."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    layers, full, Tb, block = SERVE_CELLS[cell]
+    cfg, eng, nbb, lowered, claims = _lower_prefill(cell, kind, tpu_sharding)
+    text = lowered.as_text()
+    table = nbb * eng.pool.block_size
+    V, nh = cfg.padded_vocab_size, cfg.n_head
+    scored = {int(m) for m in re.findall(rf"tensor<1x{nh}x{Tb}x(\d+)xf32>", text)}      # widths of float32 (heads, bucket, .)
+    head_rows = {int(m) for m in re.findall(rf"tensor<1x(\d+)x{V}xf32>", text)}
+    gathers = len(re.findall(r"stablehlo\.gather", text)) + len(re.findall(r"stablehlo\.dynamic_gather", text))
+    assert head_rows == {1}, head_rows                                  # the head on one row, both kinds
+    if kind == "prefill_fresh":
+        assert claims == full and 'kernel_name = "_flash_fwd"' in text      # one function, called a layer
+        assert not {w for w in scored if w >= Tb}, scored
+        # what is gathered: the token embeddings (and nothing of an arena, which has five dims)
+        assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)
+        sched = px.flash_schedule
+        n = Tb // block
+        assert sched["grid_steps"] == sched["running_blocks"] == n * (n + 1) // 2      # the causal triangle's blocks
+    else:
+        assert claims == 0 and "_flash_fwd" not in text
+        assert f"tensor<1x{nh}x{Tb}x{table}xf32>" in text               # scores against every slot of the table
+        assert gathers >= 2
+    if tpu_sharding is not None and kind == "prefill_fresh":
+        compiled = lowered.compile()
+        hlo = compiled.as_text()
+        assert len(re.findall(r"%_flash_fwd(\.\d+)? = ", hlo)) == full
+        # the reader of paged_attn_decode finds its call by operands (a table first, one
+        # four-dimensional result): the flash call's tuple result must not fit it
+        for line in (l for l in hlo.splitlines() if re.search(r"%_flash_fwd(\.\d+)? = ", l)):
+            assert not re.match(r"^\s*%\S+ = \w+\[\d+(,\d+){3}\]\S* custom-call\(s32\[\d+,\d+\]", line)
+        temps = compiled.memory_analysis().temp_size_in_bytes
+        assert temps < 4 * nh * Tb * table                            # less than one layer's float32 scores
+
+
 def test_every_pallas_call_site_is_named():
     import inspect
 

@@ -486,6 +486,7 @@ class TestEngine:
         assert stats["bucket_bound"] == (
             (len(eng.scheduler.batch_buckets) + len(eng.scheduler.prefill_buckets))
             * len(eng._table_widths)
+            + len(eng.scheduler.prefill_buckets)   # prefill_fresh reads no table: one a bucket
         )
         # window dodge: a width whose gathered capacity equals the window
         # (which forward_with_cache would read as the ring layout) is shifted

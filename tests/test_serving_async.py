@@ -207,6 +207,7 @@ class TestPrefillLane:
         assert stats["bucket_bound"] == (
             (len(sch.batch_buckets) + 2 * len(sch.prefill_buckets))
             * len(eng._table_widths)
+            + len(sch.prefill_buckets)           # prefill_fresh reads no table: one a bucket
         )
 
 

@@ -116,11 +116,33 @@ def _(micro):
     eng.run(_reqs(cfg, (3, 5, 9, 14, 2, 7), 6))
     st = eng.stats()
     counts = st["compile_counts"]
-    assert counts["prefill"] >= 1 and counts["decode"] >= 1
+    # every prompt here is whole and at position 0: the fresh kind, which reads
+    # no table and so is built once a prefill bucket, whatever the table's width
+    assert counts["prefill_fresh"] >= 1 and counts["prefill"] == 0 and counts["decode"] >= 1
+    assert st["prefill_fresh_runs"] == st["prefill_runs"] == 6
     assert sum(counts.values()) <= st["bucket_bound"]
     widths = len(eng._table_widths)
     assert counts["decode"] <= len(eng.scheduler.batch_buckets) * widths
-    assert counts["prefill"] <= len(eng.scheduler.prefill_buckets) * widths
+    assert counts["prefill_fresh"] <= len(eng.scheduler.prefill_buckets)
+
+
+@case("serving-prefill_fresh_is_one_program_a_bucket")
+def _(micro):
+    """The fresh kind reads no table: requests whose tables differ in width
+    share one program a prefill bucket (the general kinds go by width too); its
+    runs are the whole prompts among the prefill runs."""
+    cfg, params = _model(vocab_size=40)
+    eng = _engine(cfg, params, block_buckets=(4, 8, 16), prefill_buckets=(8, 16), prefill_chunk=16,
+                  prefix_sharing=False)
+    lens_new = ((5, 2), (6, 20), (7, 40), (12, 2), (13, 30), (24, 3))     # the last in two pieces
+    eng.run([{"prompt": _prompt(70 + i, m, cfg), "max_new_tokens": n} for i, (m, n) in enumerate(lens_new)])
+    st = eng.stats()
+    counts = st["compile_counts"]
+    assert counts["prefill_fresh"] == 2 and counts["prefill"] == 1          # buckets 8 and 16; one last piece
+    assert {b for k, _, b in eng._programs if k == "prefill_fresh"} == {2, 4}      # the blocks a bucket fills
+    assert len(eng._table_widths) >= 3          # tables of 2 to 12 blocks: a width each for the general kinds
+    assert st["prefill_runs"] == 6 and st["prefill_fresh_runs"] == 5 and st["chunk_runs"] == 1
+    assert sum(counts.values()) <= st["bucket_bound"]
 
 
 @case("serving-cold_compile_prefills_measured")
@@ -133,7 +155,7 @@ def _(micro):
     cold = _engine(cfg, params, prefill_buckets=(8, 16))
     res = cold.run([dict(r) for r in reqs])
     tagged = sum(1 for r in res if r.prefill_compiled)
-    assert tagged == cold.compile_counts["prefill"] == 2      # one a bucket
+    assert tagged == cold.compile_counts["prefill_fresh"] == 2      # one a bucket
     warm = _engine(cfg, params, prefill_buckets=(8, 16))
     res_w = warm.run([dict(r) for r in reqs])
     assert not any(r.prefill_compiled for r in res_w)
@@ -379,7 +401,7 @@ def _(micro):
     eng.run(_reqs(cfg, (3, 5, 21, 40), 4, seed=90))
     st = eng.stats()
     counts = {k: v for k, v in st["compile_counts"].items() if v}
-    assert set(counts) <= {"prefill", "prefill_chunk_paged", "decode_paged"}, counts
+    assert set(counts) <= {"prefill", "prefill_fresh", "prefill_chunk_paged", "decode_paged"}, counts
     assert 0 < sum(counts.values()) <= st["bucket_bound"]
 
 

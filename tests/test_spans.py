@@ -207,7 +207,7 @@ class TestEngineSpans:
         assert admitted == len(handles)
         pieces = prof.named("serve.prefill_dispatch")
         assert sorted(s["args"]["tokens"] for s in pieces) == [2, 5, 8]   # not the padded bucket
-        assert {s["args"]["piece"] for s in pieces} == {"prefill"}
+        assert {s["args"]["piece"] for s in pieces} == {"prefill_fresh"}   # whole prompts at position 0
         compiled = {(s["args"]["kind"], s["args"]["bucket"]) for s in prof.named("serve.compile")}
         built = {(c["kind"], "{}x{}".format(*c["bucket"])) for c in eng._compile_log}
         assert compiled == built and len(prof.named("serve.compile")) == len(built)

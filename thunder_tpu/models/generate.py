@@ -280,8 +280,17 @@ def _expand_groups(kk, vv, nh):
     return kk, vv
 
 
+def _band_keep(T: int, W):
+    """``(1, 1, T, T)`` keep-mask of a prompt attending itself: causal, and
+    within the window ``W`` where there is one."""
+    row = jnp.arange(T)[None, None, :, None]
+    col = jnp.arange(T)[None, None, None, :]
+    keep = col <= row
+    return keep if W is None else jnp.logical_and(keep, col > row - W)
+
+
 def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized=False,
-                     lora=None, lora_scaling=1.0):
+                     lora=None, lora_scaling=1.0, sharded=False):
     """x: (B, T, C) new tokens at global positions [pos, pos+T).  Writes their
     K/V into the per-layer cache (ck/cv: (B, ng, Tc, hs)) and attends against
     every slot the model may see.
@@ -291,6 +300,13 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
     layout (slot = position % window) when ``sliding_window`` bounds it.
     Each branch decides (kk, vv, keep-mask, cache writes); the scoring tail
     is shared.
+
+    A prompt at a *static* position 0 (``pos`` the Python integer 0, ``T >
+    1``) can see nothing but its own causal triangle, in either layout: it
+    attends the fresh ``k``/``v`` and reads no slot of the cache, through the
+    flash kernel where that takes the shapes (grouped K/V as they are), else
+    through the shared tail over ``(T, T)``.  ``sharded`` (the operands live on
+    a mesh, where a bare ``pallas_call`` would be replicated) keeps the tail.
     """
     B, T, C = x.shape
     hs, nh, ng = cfg.head_size, cfg.n_head, cfg.n_query_groups
@@ -302,6 +318,7 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
     ring = W is not None and Tc == W
     vec = _is_vec_pos(pos)
     assert not (ring and vec), "per-row positions are not supported with a ring cache"
+    fresh = isinstance(pos, int) and pos == 0 and T > 1
 
     if not ring:
         if vec:
@@ -311,25 +328,25 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
         else:
             ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), pos, axis=2)
             cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), pos, axis=2)
-        kk, vv = ck, cv
-        # query at global position pos+t sees cache slots (pos+t-W, pos+t]
-        j = jnp.arange(Tc)[None, None, None, :]
-        if vec:
-            qpos = (pos[:, None] + jnp.arange(T)[None, :])[:, None, :, None]  # (B,1,T,1)
+        if fresh:
+            kk, vv, keep = k, v, _band_keep(T, W)
         else:
-            qpos = (pos + jnp.arange(T))[None, None, :, None]
-        keep = j <= qpos
-        if W is not None:
-            keep = jnp.logical_and(keep, j > qpos - W)
+            kk, vv = ck, cv
+            # query at global position pos+t sees cache slots (pos+t-W, pos+t]
+            j = jnp.arange(Tc)[None, None, None, :]
+            if vec:
+                qpos = (pos[:, None] + jnp.arange(T)[None, :])[:, None, :, None]  # (B,1,T,1)
+            else:
+                qpos = (pos + jnp.arange(T))[None, None, :, None]
+            keep = j <= qpos
+            if W is not None:
+                keep = jnp.logical_and(keep, j > qpos - W)
     elif T > 1:
         # ring prefill: the chunk attends within itself (banded); the cache
         # keeps each ring slot's latest prompt position.  pos==0 because a
         # later chunk would need K/V already evicted from the ring.
-        assert isinstance(pos, int) and pos == 0, "ring-cache prefill must start at position 0"
-        kk, vv = k, v
-        row = jnp.arange(T)[None, None, :, None]
-        col = jnp.arange(T)[None, None, None, :]
-        keep = jnp.logical_and(col <= row, col > row - W)
+        assert fresh, "ring-cache prefill must start at position 0"
+        kk, vv, keep = k, v, _band_keep(T, W)
         # slot j <- the latest prompt position p ≡ j (mod W); slots with no
         # such position stay garbage (masked positionally at decode)
         gather = ring_gather_positions(T, W)
@@ -347,13 +364,22 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
         gp = pos - jax.lax.rem(jax.lax.rem(pos - j, W) + W, W)
         keep = (gp >= 0)[None, None, None, :]
 
-    kk, vv = _expand_groups(kk, vv, nh)
-    scores = jnp.einsum(
-        "bhqd,bhkd->bhqk", q, kk.astype(q.dtype), preferred_element_type=jnp.float32
-    ) / math.sqrt(hs)
-    scores = jnp.where(keep, scores, -jnp.inf)
-    w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    y = jnp.einsum("bhqk,bhkd->bhqd", w, vv.astype(q.dtype))
+    flash = None
+    if fresh and not sharded:
+        from thunder_tpu.executors import pallasex
+
+        flash = pallasex.flash_sdpa(q, k, v, None, True, 1.0 / math.sqrt(hs),
+                                    W if W is not None and T > W else None)
+    if flash is not None:
+        y = flash[0]
+    else:
+        kk, vv = _expand_groups(kk, vv, nh)
+        scores = jnp.einsum(
+            "bhqd,bhkd->bhqk", q, kk.astype(q.dtype), preferred_element_type=jnp.float32
+        ) / math.sqrt(hs)
+        scores = jnp.where(keep, scores, -jnp.inf)
+        w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        y = jnp.einsum("bhqk,bhkd->bhqd", w, vv.astype(q.dtype))
     y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
     out = lin(y, ap["wo"], ap.get("bo"))
     if lora is not None and "wo" in lora:
@@ -458,9 +484,14 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
 
 
 def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *,
-                       quantized=False, lora=None, lora_scaling=1.0, n_real=None):
+                       quantized=False, lora=None, lora_scaling=1.0, n_real=None, logits_at=None,
+                       sharded=False):
     """Forward of new tokens ``idx`` (B, T) at global positions [pos, pos+T)
-    against/into ``cache``.  Returns (logits (B, T, V), updated cache).
+    against/into ``cache``.  Returns (logits (B, T, V), updated cache); with
+    ``logits_at`` (an index into the T tokens, traced or not) the head runs on
+    that one row alone and the logits are ``(B, 1, V)``: a prefill samples
+    from its last real token and from nothing else.  ``sharded``: the caller
+    placed params and cache on a mesh (:func:`_attn_with_cache`).
 
     A model with linear_attention layers keeps ``cache["conv"]`` and
     ``cache["state"]`` beside ``k``/``v`` (:func:`state_shapes`), which hold
@@ -510,7 +541,7 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
             j = len(new_k)
             h, ck, cv = _attn_with_cache(
                 bp["attn"], n1, cos_t, sin_t, cache["k"][j], cache["v"][j], pos, cfg,
-                quantized=quantized, lora=lora_l, lora_scaling=lora_scaling,
+                quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, sharded=sharded,
             )
             new_k.append(ck)
             new_v.append(cv)
@@ -520,6 +551,8 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
     if new_state:
         cache.update(conv=jnp.stack(new_conv), state=jnp.stack(new_state))
     x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
+    if logits_at is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
     head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
     logits = (_linear(x, head, params.get("lm_head_b"), quantized=quantized)).astype(jnp.float32)
     return logits, cache
@@ -577,7 +610,8 @@ def generate(
     dtype = cache_dtype if cache_dtype is not None else params["wte"].dtype
 
     prefill, decode_all = _compiled_generate(
-        cfg, B, T_prompt, max_new_tokens, T_max, float(temperature), quantized, str(dtype)
+        cfg, B, T_prompt, max_new_tokens, T_max, float(temperature), quantized, str(dtype),
+        mesh is not None,
     )
     cache = init_cache(cfg, B, T_max, dtype=dtype, mesh=mesh)
     first, cache, key = prefill(params, prompt, cache, key)
@@ -594,17 +628,20 @@ def generate(
 _generate_cache: dict = {}
 
 
-def _compiled_generate(cfg, B, T_prompt, max_new_tokens, T_max, temperature, quantized, dtype_str):
+def _compiled_generate(cfg, B, T_prompt, max_new_tokens, T_max, temperature, quantized, dtype_str,
+                       sharded=False):
     """Jitted prefill/decode pair, cached per static configuration so
     repeated generate() calls hit steady-state compiled
     programs instead of re-tracing."""
     import dataclasses
 
-    # mesh deliberately absent from the key: jax.jit re-specializes on input
-    # shardings, so one cached pair serves every placement
+    # of the mesh the key holds only that there is one (the prefill's flash
+    # call has no partitioning rule, so a placed prompt keeps the einsum form):
+    # jax.jit re-specializes on input shardings, so one cached pair serves
+    # every placement
     key = (
         tuple(sorted(dataclasses.asdict(cfg).items())),
-        B, T_prompt, max_new_tokens, T_max, temperature, quantized, dtype_str,
+        B, T_prompt, max_new_tokens, T_max, temperature, quantized, dtype_str, sharded,
     )
     cached = _generate_cache.get(key)
     if cached is not None:
@@ -617,7 +654,8 @@ def _compiled_generate(cfg, B, T_prompt, max_new_tokens, T_max, temperature, qua
     @partial(jax.jit, donate_argnums=(2,))
     def prefill(params, prompt, cache, key):
         logits, cache = forward_with_cache(
-            params, prompt, 0, cache, cos_all, sin_all, cfg, quantized=quantized
+            params, prompt, 0, cache, cos_all, sin_all, cfg, quantized=quantized,
+            logits_at=T_prompt - 1, sharded=sharded,
         )
         key, sub = jax.random.split(key)
         nxt = _sample(logits[:, -1], temperature, sub)

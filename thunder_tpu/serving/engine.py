@@ -86,6 +86,7 @@ from thunder_tpu.models.generate import (
     build_rope_cache,
     forward_with_cache,
     sample_token,
+    state_shapes,
 )
 from thunder_tpu.observability.config import (
     flight_recorder_env_enabled,
@@ -624,11 +625,12 @@ class ServingEngine:
         # drive-loop accounting (mirrored into the registry as it changes)
         self.decode_steps = 0
         self.prefill_runs = 0
+        self.prefill_fresh_runs = 0     # of them, whole prompts at position 0
         self.chunk_runs = 0
         self.step_calls = 0
         self.tokens_generated = 0
         self._occupancy_sum = 0
-        self.compile_counts = {"prefill": 0, "prefill_chunk": 0,
+        self.compile_counts = {"prefill": 0, "prefill_fresh": 0, "prefill_chunk": 0,
                                "prefill_chunk_paged": 0, "decode": 0,
                                "decode_paged": 0, "decode_multi": 0,
                                "decode_multi_paged": 0, "spec_prefill": 0,
@@ -1129,7 +1131,8 @@ class ServingEngine:
         # (doubled under speculative serving: each round runs draft_decode
         # AND verify at the same bucket), prefill per prefill bucket, plus
         # the chunk kind when chunking is on — or once recovery has
-        # replayed through the chunk programs
+        # replayed through the chunk programs; a prefill_fresh program reads
+        # no table, so it is one a prefill bucket whatever the width
         kinds = len(sch.batch_buckets) * (
             2 if self.spec is not None else 1
         ) + len(sch.prefill_buckets) * (
@@ -1158,6 +1161,7 @@ class ServingEngine:
                 if self.host_visits else None
             ),
             "prefill_runs": self.prefill_runs,
+            "prefill_fresh_runs": self.prefill_fresh_runs,
             "chunk_runs": self.chunk_runs,
             "step_calls": self.step_calls,
             "tokens_generated": self.tokens_generated,
@@ -1191,7 +1195,8 @@ class ServingEngine:
                     },
                 },
             },
-            "bucket_bound": kinds * len(self._table_widths),
+            "bucket_bound": kinds * len(self._table_widths) + (
+                len(sch.prefill_buckets) if self.spec is None else 0),
             "prefix_lookups": self._prefix_lookups,
             "prefix_hits": self._prefix_hits,
             "recoveries": self.recoveries,
@@ -1572,8 +1577,16 @@ class ServingEngine:
         # block range — everything else (shared prefix, earlier chunks,
         # bucket padding) sinks (chunk granularity, see kv_pool.chunk_tables)
         table, dest = chunk_tables(req.block_table, pos, Tb, nbb, bs)
+        fresh = final and pos == 0 and self.spec is None
         if self.spec is not None:
             kind = "spec_prefill" if final else "spec_prefill_chunk"
+        elif fresh:
+            # a whole prompt at position 0 (no shared block, no earlier piece)
+            # has nothing before it in the arenas: its program reads none of
+            # them, and its table is the blocks the bucket fills
+            kind = "prefill_fresh"
+            nbb = -(-Tb // bs)
+            dest = dest[:nbb]
         else:
             kind = "prefill" if final else self._chunk_kind()
         prog, compiled = self._program(kind, Tb, nbb)
@@ -1607,9 +1620,9 @@ class ServingEngine:
                    "epoch": req.preemptions, "t_clock": sch.clock()}
         elif final:
             args = (
-                self.params, jnp.asarray(toks)[None], jnp.int32(pos), jnp.int32(n_real),
-                pool.arenas, jnp.asarray(table), jnp.asarray(dest),
-                jnp.asarray(req.key),
+                self.params, jnp.asarray(toks)[None],
+                *(() if fresh else (jnp.int32(pos),)), jnp.int32(n_real), pool.arenas,
+                *(() if fresh else (jnp.asarray(table),)), jnp.asarray(dest), jnp.asarray(req.key),
                 self._lora_arenas(), jnp.asarray([req.adapter_slot], dtype=jnp.int32),
             )
             if self._hybrid:
@@ -1672,6 +1685,9 @@ class ServingEngine:
         if final:
             self.prefill_runs += 1
             reg.counter("serving.steps.prefill").inc()
+            if fresh:
+                self.prefill_fresh_runs += 1
+                reg.counter("serving.steps.prefill_fresh").inc()
         else:
             self.chunk_runs += 1
             reg.counter("serving.steps.prefill_chunk").inc()
@@ -2748,6 +2764,7 @@ class ServingEngine:
                 }[kind], self)
             else:
                 build = {"prefill": self._build_prefill,
+                         "prefill_fresh": partial(self._build_prefill, fresh=True),
                          "prefill_chunk": self._build_prefill_chunk,
                          "prefill_chunk_paged": self._build_prefill_chunk_paged,
                          "decode": self._build_decode,
@@ -2790,7 +2807,7 @@ class ServingEngine:
             )
         kw = program_shardings(kind, self.params, self.mesh, self.pool.arena_sharding)
         if self._constraints and kind in (
-                "prefill", "decode", "decode_paged",
+                "prefill", "prefill_fresh", "decode", "decode_paged",
                 "decode_multi", "decode_multi_paged"):
             # the trailing constraint-mask argument is replicated like every
             # other small host-built per-step array
@@ -2829,21 +2846,39 @@ class ServingEngine:
             kw["lora_scaling"] = self._registry.scaling
         return kw
 
-    def _build_prefill(self, Tb: int, nbb: int) -> Callable:
+    def _build_prefill(self, Tb: int, nbb: int, *, fresh: bool = False) -> Callable:
+        """The program of a prompt's last piece: forward, sample token 0
+        (splitting the key as solo ``generate()`` does), write the K/V out.
+
+        ``fresh``: the ``prefill_fresh`` kind, a whole prompt at position 0.
+        There is nothing before it, so the program takes neither ``pos`` nor a
+        table and reads no arena: the forward gets the Python integer 0 (the
+        prompt attends its own keys, ``generate._attn_with_cache``), the state
+        starts from zeros, and ``dest`` names the ``nbb`` blocks the bucket's
+        ``Tb`` positions fill (a cache as wide as the window reads as a ring
+        there: for a prompt at 0 that fills it, the same slots).  Everything
+        else is the ``prefill`` kind's.
+
+        The in-tree forward projects row ``n_real - 1`` alone onto the
+        vocabulary (``logits_at``) and, under a mesh, keeps the prompt's
+        attention off the flash kernel (``sharded``); a custom ``model_fn``
+        is called as before and its full logits indexed."""
         cfg, fwd, temp = self.cfg, self._forward, self.temperature
         hybrid = self._hybrid
         qkv = self.pool.quantized_kv
         cdtype = jnp.dtype(self.pool.dtype)
         cap = self.pool.capacity_tokens(nbb)
-        cos_all, sin_all = build_rope_cache(cfg, cap)
+        # a fresh program's table is as wide as its bucket; its rope table is
+        # built at the table width that holds it all the same, a shape the
+        # other kinds' are built at: a bucket adds no eager program of its own
+        cos_all, sin_all = build_rope_cache(
+            cfg, self.pool.capacity_tokens(self._nbb(nbb)) if fresh else cap)
+        in_tree, sharded = fwd is forward_with_cache, self.mesh is not None
 
-        # Constrained engines pass one trailing ``(1, V)`` bool mask; plain
-        # engines pass nothing, so the traced program (and its module-cache
-        # entry) is byte-identical to a pre-constraints engine.
-        @partial(jax.jit, donate_argnums=(4,), **self._jit_kwargs("prefill"))
-        def prefill(params, toks, pos, n_real, arenas, table, dest, key, lora, slot,
-                    *cmask):
-            if qkv:
+        def run(params, toks, pos, n_real, arenas, table, dest, key, lora, slot, cmask):
+            if fresh:
+                kd = vd = jnp.zeros(self.pool.dense_shape(1, nbb), cdtype)
+            elif qkv:
                 kd, vd = gather_dense_q(
                     arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
                     table[None, :], cdtype,
@@ -2856,13 +2891,19 @@ class ServingEngine:
                 # a prompt's first piece starts from zeros, whatever the slot's
                 # last owner left, and the padded tail leaves the state alone
                 sslot, cmask = cmask[0], cmask[1:]
-                held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
+                if fresh:       # zeros without a read (a hybrid engine serves the in-tree forward only)
+                    held = {name: jnp.zeros(shape, arenas[name].dtype)
+                            for name, shape in state_shapes(cfg, 1).items()}
+                else:
+                    held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
                 more = {"n_real": n_real}
+            own = {"logits_at": n_real - 1, "sharded": sharded} if in_tree else {}
             logits, cache = fwd(
                 params, toks, pos, {"k": kd, "v": vd, **held}, cos_all, sin_all, cfg,
-                **self._fwd_kwargs(lora, slot), **more,
+                **self._fwd_kwargs(lora, slot), **more, **own,
             )
-            last = jax.lax.dynamic_index_in_dim(logits, n_real - 1, axis=1, keepdims=False)
+            last = logits[:, 0] if in_tree else jax.lax.dynamic_index_in_dim(
+                logits, n_real - 1, axis=1, keepdims=False)
             if cmask:
                 last = jnp.where(cmask[0], last, -jnp.inf)
             key, sub = jax.random.split(key)
@@ -2881,6 +2922,21 @@ class ServingEngine:
                           "v": scatter_blocks(arenas["v"], cache["v"], dest), **kept}
                 qerr = jnp.float32(0.0)
             return tok, arenas, key, qerr
+
+        if fresh:
+            @partial(jax.jit, donate_argnums=(3,), **self._jit_kwargs("prefill_fresh"))
+            def prefill_fresh(params, toks, n_real, arenas, dest, key, lora, slot, *cmask):
+                return run(params, toks, 0, n_real, arenas, None, dest, key, lora, slot, cmask)
+
+            return prefill_fresh
+
+        # Constrained engines pass one trailing ``(1, V)`` bool mask; plain
+        # engines pass nothing, so the traced program (and its module-cache
+        # entry) is byte-identical to a pre-constraints engine.
+        @partial(jax.jit, donate_argnums=(4,), **self._jit_kwargs("prefill"))
+        def prefill(params, toks, pos, n_real, arenas, table, dest, key, lora, slot,
+                    *cmask):
+            return run(params, toks, pos, n_real, arenas, table, dest, key, lora, slot, cmask)
 
         return prefill
 

@@ -151,6 +151,7 @@ def program_shardings(kind: str, params, mesh: Mesh, arena_sh: NamedSharding,
 
     - prefill: ``(params, toks, pos, n_real, arenas, table, dest, key,
       lora, slot)`` → ``(tok, arenas, key, qerr)``
+    - prefill_fresh: prefill's row without ``pos`` and ``table``
     - prefill_chunk: ``(params, toks, pos, arenas, table, dest, lora,
       slot)`` → ``(arenas, qerr)``
     - decode:  ``(params, toks, pos, tables, arenas, keys, lora, slots)``
@@ -173,6 +174,11 @@ def program_shardings(kind: str, params, mesh: Mesh, arena_sh: NamedSharding,
     if kind == "prefill":
         return dict(
             in_shardings=(param_sh, repl, repl, repl, arena_sh, repl, repl, repl, repl, repl),
+            out_shardings=(repl, arena_sh, repl, repl),
+        )
+    if kind == "prefill_fresh":
+        return dict(
+            in_shardings=(param_sh, repl, repl, arena_sh, repl, repl, repl, repl),
             out_shardings=(repl, arena_sh, repl, repl),
         )
     if kind in ("prefill_chunk", "prefill_chunk_paged"):

@@ -1,6 +1,7 @@
 """Time the three flash kernels alone on the chip, at the benchmark cells' shapes.
 
     python3 tools/flash_tune.py [--shapes mistral,hybrid] [--blocks 512x512,512x256] [--check]
+    python3 tools/flash_tune.py --serve
 
 For each shape and each (BQ, BK) (none given: what ``pallasex._block``
 derives), one line: ms a call of ``_flash_fwd``, ``_flash_bwd_dq`` and
@@ -8,7 +9,11 @@ derives), one line: ms a call of ``_flash_fwd``, ``_flash_bwd_dq`` and
 backward calls, and of whatever else XLA runs beside them in the backward
 program (``delta``, a sum over the group's heads).  ``--check`` first compares
 out, dq, dk, dv with the float32 reference at T 2048 (compiled kernels, not
-the interpreter).  Needs a TPU; exits non-zero if a geometry failed."""
+the interpreter).  ``--serve``: the forward kernel alone as a whole prompt's
+prefill calls it in the two serve cells (``generate._attn_with_cache`` at a
+static position 0), a line a prefill bucket, beside the form it replaced: the
+same prompt scored in float32 against every slot of the request's table.
+Needs a TPU; exits non-zero if a geometry failed."""
 import argparse
 import os
 import sys
@@ -27,6 +32,10 @@ from thunder_tpu.executors.jaxex import _sdpa_backward_reference, _sdpa_referenc
 # B, H, G, T, hs, window: one sequence of the Mistral train cell; the two of
 # the hybrid cell's gated attention layer
 SHAPES = {"mistral": (1, 32, 8, 8192, 128, 4096), "hybrid": (2, 16, 2, 8192, 256, None)}
+# H, G, hs, window, slots of the request's table, prefill buckets: a full-attention
+# layer of ``offline-batch`` (Mistral-7B) and of ``offline-longgen`` (Olmo-Hybrid-7B)
+SERVE = {"mistral": (32, 8, 128, 4096, 3584, (1024, 2048, 3072)),
+         "olmo-hybrid": (30, 30, 128, None, 3328, (1024, 2048, 2560))}
 REPS = 5
 
 
@@ -64,6 +73,40 @@ def time_shape(name, blocks):
           f"  sum {sum(three):7.3f} ms   beside them: {rest}   schedule {px.flash_schedule}", flush=True)
 
 
+def table_form(q, kt, vt, window):
+    """What a prompt at a traced position 0 costs ``_attn_with_cache``: K/V of
+    the whole table broadcast to the query heads, float32 scores against every
+    slot, mask, softmax, the second product."""
+    from thunder_tpu.models.generate import _expand_groups
+
+    T, Tc = q.shape[2], kt.shape[2]
+    kk, vv = _expand_groups(kt, vt, q.shape[1])
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, kk, preferred_element_type=jnp.float32) / np.sqrt(q.shape[-1])
+    row, col = jnp.arange(T)[None, None, :, None], jnp.arange(Tc)[None, None, None, :]
+    keep = col <= row if window is None else jnp.logical_and(col <= row, col > row - window)
+    w = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", w, vv)
+
+
+def time_serve(name):
+    H, G, hs, window, table, buckets = SERVE[name]
+    for T in buckets:
+        q, k, v, _ = (x.reshape(1, -1, T, hs) for x in operands(1, H, G, T, hs))
+        kt, vt = (jnp.pad(x, ((0, 0), (0, 0), (0, table - T), (0, 0))) for x in (k, v))
+        bind = window if window is not None and T > window else None      # as _attn_with_cache passes it
+        flash = jax.jit(lambda q, k, v: px.flash_sdpa(q, k, v, None, True, 1.0 / np.sqrt(hs), bind)[0])
+        dense = jax.jit(lambda q, kt, vt: table_form(q, kt, vt, window))
+        a, b = jax.block_until_ready((flash(q, k, v), dense(q, kt, vt)))
+        err = float(jnp.linalg.norm((a - b).astype(jnp.float32)) / jnp.linalg.norm(b.astype(jnp.float32)))
+        schedule = dict(px.flash_schedule)
+        kernel = kernel_ms(lambda: jax.block_until_ready(flash(q, k, v)), REPS)
+        form = kernel_ms(lambda: jax.block_until_ready(dense(q, kt, vt)), REPS)
+        ops = ", ".join(f"{n} {t:.3f}" for n, t in sorted(form.items(), key=lambda kv: -kv[1])[:4])
+        print(f"serve {name:11s} T {T:5d}: _flash_fwd {kernel.get('_flash_fwd', float('nan')):7.3f} ms a layer"
+              f" (all ops {sum(kernel.values()):7.3f})   against a table of {table}: {sum(form.values()):7.3f} ms ({ops})"
+              f"   they differ by {err:.5f}   schedule {schedule}", flush=True)
+
+
 def check():
     """Compiled kernels against the float32 reference, T 2048, both cells' kinds."""
     worst = 0.0
@@ -88,6 +131,7 @@ def main():
     ap.add_argument("--shapes", default="mistral,hybrid")
     ap.add_argument("--blocks", default="")
     ap.add_argument("--check", action="store_true")
+    ap.add_argument("--serve", action="store_true", help="the forward kernel at the serve cells' prefill buckets")
     ap.add_argument("--window", type=int, help="another window for the mistral shape (to place _block's rule)")
     args = ap.parse_args()
     device = device_info()
@@ -99,6 +143,10 @@ def main():
         SHAPES["mistral"] = (*SHAPES["mistral"][:5], args.window)
     if args.check and check() > 0.02:   # bfloat16 operands: 0.003-0.006
         sys.exit("flash_tune: the compiled kernels disagree with the reference")
+    if args.serve:
+        for name in SERVE:
+            time_serve(name)
+        return
     failed = []
     for blocks in args.blocks.split(",") if args.blocks else [""]:
         for which in "QK":

@@ -480,11 +480,118 @@ def test_a_whole_prompts_prefill_lowers_to_flash_and_a_head_of_one_row(cell, kin
         assert temps < 4 * nh * Tb * table                            # less than one layer's float32 scores
 
 
+# --------------------------------------------------------------------------
+# latent attention as the A.X-K1 cell serves it
+# --------------------------------------------------------------------------
+
+MLA_CELL = "axk1-serve-1chip.offline-longctx"
+
+
+@pytest.mark.parametrize("kernel", ["mla_paged_decode", "mla_latent_write", "mla_latent_write_masked"])
+def test_the_latent_kernels_compile_at_the_cells_shapes(kernel, tpu_sharding, monkeypatch):
+    """64 rows, a table 640 blocks wide, the cell's arena of 32768 blocks of 16
+    rows of 640 (576 padded to whole lane tiles), 64 heads: the walk's copies
+    are whole-tile slabs, the table fits the scalar memory, nothing of the arena
+    is copied."""
+    monkeypatch.setattr(px, "_pallas_available", lambda: True)
+    rows, width, pool, layers, nh, W, dc = 64, 640, 32768, 6, 64, 640, 512
+    arena, tab, pos = ((pool, layers, 1, BS, W), BF), ((rows, width), I32), ((rows,), I32)
+    if kernel == "mla_paged_decode":
+        fn = functools.partial(px.mla_paged_decode, layer=layers - 1, dc=dc, scale=0.13)
+        specs = [((rows, nh, W), BF), arena, ((rows, W), BF), tab, pos]
+    elif kernel == "mla_latent_write":
+        fn = functools.partial(px.paged_token_write, block_size=BS, name="mla_latent_write")
+        specs = [arena, ((rows, layers, 1, W), BF), tab, pos]
+    else:
+        fn = lambda a, v, t, p, n: px.paged_token_write(a, v, t, p, block_size=BS, n_emit=n, offset=0,  # noqa: E731
+                                                        name="mla_latent_write")
+        specs = [arena, ((rows, layers, 1, W), BF), tab, pos, ((rows,), I32)]
+    before = px.stats.get("mla_decode", 0)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and f'kernel_name = "{kernel}"' in text
+    if kernel == "mla_paged_decode":
+        assert px.stats["mla_decode"] == before + 1                     # claimed, not the XLA form
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        assert re.search(rf"%{kernel}(\.\d+)? = ", compiled.as_text())
+        if kernel == "mla_paged_decode":
+            assert compiled.memory_analysis().temp_size_in_bytes == 0       # no arena copy
+            # nothing of the call fits the reader that finds paged_attn_decode by its operands
+            call = next(l for l in compiled.as_text().splitlines() if re.search(r"%mla_paged_decode(\.\d+)? = ", l))
+            assert not re.match(r"^\s*%\S+ = \w+\[\d+(,\d+){3}\]\S* custom-call\(s32\[\d+,\d+\]", call)
+
+
+def test_a_row_of_576_is_refused_by_the_walks_copies(tpu_sharding, monkeypatch):
+    """Why the arena's rows are padded: the kernel asks for whole lane tiles."""
+    monkeypatch.setattr(px, "_pallas_available", lambda: True)
+    with pytest.raises(AssertionError):
+        px.mla_paged_decode(jnp.zeros((2, 4, 576), BF), jnp.zeros((9, 1, 1, BS, 576), BF), jnp.zeros((2, 576), BF),
+                            jnp.zeros((2, 4), I32), jnp.zeros((2,), I32), layer=0, dc=512, scale=1.0)
+
+
+@functools.cache
+def _mla_engine():
+    """The cell's engine at its published widths, the dense layer and one
+    expert layer, over weights that are shapes alone."""
+    import thunder_tpu as tt
+    from chipbench import common
+    from thunder_tpu.models import llama
+
+    _, config, mix = common.open_cell(MLA_CELL)
+    arch = common.load_module("models", config["arch"])
+    hf = {**config, "num_hidden_layers": 2}
+    cfg = llama.Config(**arch.program_config(hf))
+    params = jax.eval_shape(functools.partial(arch.make_params, hf), common.seed_words(1))
+    return cfg, params, tt.serve(None, params, cfg, **{**config["engine"], **mix["engine"], "num_blocks": 700})
+
+
+@pytest.mark.parametrize("kind", ["prefill_fresh", "decode_paged"])
+def test_the_latent_cells_programs_lower_to_their_kernels(kind, tpu_sharding, monkeypatch):
+    """A whole prompt's prefill attends its expanded keys through ``_flash_fwd``
+    (heads of 192 beside values of 128, padded with zeros to one size) and
+    sorts its rows through ``moe_grouped_mm``; a decode step calls
+    ``mla_paged_decode`` once a layer, ``moe_grouped_mm`` for the expert layer
+    and lands its rows through one ``mla_latent_write``; no arena is gathered."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    monkeypatch.setattr(px, "_pallas_available", lambda: True)
+    cfg, params, eng = _mla_engine()
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=tpu_sharding)  # noqa: E731
+    one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
+    before = dict(px.stats)
+    if kind == "prefill_fresh":
+        Tb = 4096
+        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
+        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)))
+    else:
+        prog = eng._build_decode_paged(64, 640)
+        args = (weights, one((64,)), one((64,)), one((64, 640)), arenas, one((64, 2), jnp.uint32), {}, one((64,)))
+    lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
+    assert claimed("grouped_mm") >= 3 and 'kernel_name = "moe_grouped_mm"' in text
+    assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)      # nothing of an arena's five dims
+    if kind == "prefill_fresh":
+        assert claimed("direct") == cfg.n_layer and 'kernel_name = "_flash_fwd"' in text
+        assert px.flash_schedule["grid_steps"] > 0
+        assert {int(m) for m in re.findall(rf"tensor<1x(\d+)x{cfg.padded_vocab_size}xf32>", text)} == {1}
+    else:
+        assert claimed("mla_decode") == cfg.n_layer
+        assert text.count('kernel_name = "mla_latent_write"') == 1
+    if tpu_sharding is not None:
+        hlo = lowered.compile().as_text()
+        names = ("_flash_fwd",) if kind == "prefill_fresh" else ("mla_paged_decode", "mla_latent_write")
+        for name in (*names, "moe_grouped_mm"):
+            assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
+
+
 def test_every_pallas_call_site_is_named():
     import inspect
 
     src = inspect.getsource(px)
-    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 15
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 16      # PR 34: mla_paged_decode
     assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
         "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
         "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",

@@ -10,6 +10,8 @@ Tiny float32 models; the prompts are shorter than their buckets.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import os
 import sys
 
@@ -202,7 +204,9 @@ def test_the_static_and_the_traced_position_zero_agree():
 
 @pytest.fixture(scope="module")
 def counted():
-    cfg, params = _model(vocab_size=40)       # a configuration of its own: nobody's programs in the module's cache
+    # a configuration of its own, by name: nobody's programs in the module's cache, in any order of files
+    cfg, params = _model(vocab_size=40)
+    cfg = dataclasses.replace(cfg, name="prefill-fresh-counted")
     reg = registry()
     before = {n: reg.counter(n).value for n in ("serving.steps.prefill_fresh", "serving.steps.prefill",
                                                 "serving.compiles.prefill_fresh")}

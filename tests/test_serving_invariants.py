@@ -10,6 +10,7 @@ stays there (CHANGES.md, PR 30, lists which).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import importlib
 
@@ -42,8 +43,14 @@ BUCKETS = dict(batch_buckets=(4,), block_buckets=(8,), prefill_buckets=(16,))
 def _model(**over):
     """``over`` empty: the model the cases share.  Otherwise a model no other
     test builds, so that this file's first engine on it finds no program in
-    the module cache (the cache is keyed by the config)."""
+    the module cache: the cache is keyed by the config, and the config carries
+    this file's name (widths alone can meet another file's: ``vocab_size`` 40
+    and 48 are ``test_prefill_fresh.py``'s too, and whichever file a worker ran
+    first had compiled the other's programs)."""
     cfg = llama.Config.from_name("tiny-llama-debug", **{**MICRO, **over})
+    if over:
+        cfg = dataclasses.replace(
+            cfg, name="serving-invariants-" + "-".join(f"{k}{v}" for k, v in sorted(over.items())))
     return cfg, llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
 
 

@@ -88,6 +88,10 @@ def kv_cache_spec(cfg, mesh: Mesh | None, *, axis: str = "tp") -> P:
     """
     if mesh is None or axis not in mesh.axis_names or mesh.shape[axis] <= 1:
         return P()
+    if getattr(cfg, "latent", False):
+        # a latent cache's axis 2 is one row for all heads: nothing to split
+        # (the serving engine refuses a mesh for such a model, with the reason)
+        return P()
     if cfg.n_query_groups % mesh.shape[axis] != 0:
         return P()
     return P(None, None, axis)

@@ -45,7 +45,7 @@ from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_
 
 __all__ = [
     "ex", "pallas_ex", "flash_sdpa", "flash_sdpa_backward",
-    "paged_attn_decode", "paged_token_write", "paged_available", "paged_head_size_ok",
+    "paged_attn_decode", "paged_token_write", "paged_available", "paged_head_size_ok", "mla_paged_decode",
     "gdn_chunk", "gdn_chunk_state", "gdn_decode_step", "grouped_mm", "grouped_mm_dw",
 ]
 
@@ -1440,7 +1440,7 @@ def _token_call(name, kernel, prefetch, arenas, vals, in_specs, out_specs):
 
 
 def paged_token_write(arena, vals, tables, pos, *, block_size, n_emit=None,
-                      offset=0):
+                      offset=0, name="paged_token_write"):
     """In-place single-token arena write (the scatter_token replacement).
 
     ``arena``: (num_blocks, L, ng, bs, hs) K/V arena — or (num_blocks, L, ng,
@@ -1463,17 +1463,142 @@ def paged_token_write(arena, vals, tables, pos, *, block_size, n_emit=None,
     (``write_fresh_kv_live``): a live row stores the same bytes as the
     unmasked write, a finished row sinks every remaining iteration's write,
     and the N-step program stays static-shape with zero scatters.
+
+    ``name``: the kernel's name in a device trace (a latent arena's write is
+    ``mla_latent_write``).
     """
     prefetch = (tables, pos) if n_emit is None else (
         tables, pos, n_emit.astype(jnp.int32))
     a_spec, v_spec = _token_specs(arena, bs=block_size, offset=offset)
     (out,) = _token_call(
-        "paged_token_write" + ("_masked" if n_emit is not None else ""),
+        name + ("_masked" if n_emit is not None else ""),
         functools.partial(_token_write_kernel, bs=block_size, offset=offset,
                           masked=n_emit is not None),
         prefetch, (arena,), (jnp.expand_dims(vals, 3),),
         [a_spec, v_spec], [a_spec])
     return out
+
+
+# ---------------------------------------------------------------------------
+# ``mla_paged_decode``: one token's latent attention straight off the latent
+# arena, in the absorbed form.  A grid step is a request: its table's blocks
+# are copied out of the HBM arena once, a chunk of ``_MLA_CHUNK_KEYS`` rows at
+# a time into a double buffer, and every head scores the same rows (one
+# ``(nh, W) x (W, keys)`` product) and sums the same rows (``(nh, keys) x
+# (keys, dc)``): the arena's bytes are read once for all heads, where expanded
+# keys and values would be ``nh (dn + dr + dv) / (dc + dr)`` times as many.
+# Online softmax across chunks in float32; the fresh token's row (this step's,
+# not yet in the arena) is the last term, as in ``paged_attn_decode``.
+# ---------------------------------------------------------------------------
+
+_MLA_CHUNK_KEYS = 512
+
+
+def _mla_decode_kernel(tab_ref, pos_ref, q_ref, arena, f_ref, o_ref, buf, sem, *, layer, bs, C, dc, scale):
+    i = pl.program_id(0)
+    p_i = pos_ref[i]
+    q = q_ref[0]                                           # (nh, W)
+    nh, W = q.shape
+    hi = (p_i + bs - 1) // bs                              # table entries with a slot before pos
+    n_chunks = (hi + C - 1) // C
+
+    def copies(c, slot, act):
+        # the tail of the last chunk fetches block hi - 1 again: real rows, masked as future ones
+        def one(t, _):
+            blk = tab_ref[i, jnp.minimum(c * C + t, hi - 1)]
+            getattr(pltpu.make_async_copy(arena.at[blk, layer, 0], buf.at[slot, t], sem.at[slot]), act)()
+        jax.lax.fori_loop(0, C, one, None)
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        copies(0, 0, "start")
+
+    def chunk(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            copies(c + 1, 1 - slot, "start")
+
+        copies(c, slot, "wait")
+        rows = buf[slot].reshape(C * bs, W).astype(q.dtype)
+        s = jax.lax.dot_general(q, rows, _NT, preferred_element_type=jnp.float32) * scale   # (nh, C * bs)
+        posn = c * C * bs + jax.lax.broadcasted_iota(jnp.int32, (1, C * bs), 1)
+        s = jnp.where(posn < p_i, s, _MASK_VALUE)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(p.astype(q.dtype), rows[:, :dc], _NN,
+                                               preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    m_prev, l_prev, acc = jax.lax.fori_loop(0, n_chunks, chunk, (
+        jnp.full((nh, 1), _MASK_VALUE, jnp.float32), jnp.zeros((nh, 1), jnp.float32),
+        jnp.zeros((nh, dc), jnp.float32)))
+    # the fresh row is one key: multiply-and-sum in float32 of operands rounded
+    # to q's dtype, as a matmul's would be
+    f = f_ref[0].astype(q.dtype).astype(jnp.float32)       # (1, W)
+    s_f = jnp.sum(q.astype(jnp.float32) * f, axis=1, keepdims=True) * scale
+    m_new = jnp.maximum(m_prev, s_f)
+    p = jnp.exp(s_f - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + p
+    acc = acc * corr + p.astype(q.dtype).astype(jnp.float32) * f[:, :dc]
+    o_ref[0] = (acc / l_new).astype(o_ref.dtype)
+
+
+def _mla_decode_xla(q, arena, fresh, tables, pos, *, layer, dc, scale):
+    """:func:`mla_paged_decode` in XLA, for a backend without Pallas: the
+    rows' blocks gathered, the fresh row put at ``pos``, one softmax."""
+    B, nbb = tables.shape
+    bs, W = arena.shape[3], arena.shape[4]
+    rows = jnp.take(arena[:, layer, 0], tables, axis=0).reshape(B, nbb * bs, W).astype(q.dtype)
+    rows = jax.vmap(lambda r, f, p: jax.lax.dynamic_update_slice_in_dim(r, f[None], p, axis=0))(
+        rows, fresh.astype(q.dtype), pos)
+    s = jnp.einsum("bhw,bsw->bhs", q, rows, preferred_element_type=jnp.float32) * scale
+    keep = jnp.arange(nbb * bs)[None, None, :] <= pos[:, None, None]
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhs,bsc->bhc", p, rows[..., :dc], preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def mla_paged_decode(q, arena, fresh, tables, pos, *, layer: int, dc: int, scale: float):
+    """One token a row of absorbed latent attention off the latent arena, one
+    layer.  ``q (B, nh, W)``: the absorbed queries ``[q_nope W_k | q_rope | 0]``
+    at the compute dtype; ``arena (num_blocks, L, 1, bs, W)``: the pool's
+    latent arena, rows ``[c_kv (dc) | k_r | 0]``, ``W`` whole 128-lane tiles; it
+    stays in HBM and a request's ``[block, layer]`` slabs are copied out once
+    for all ``nh`` heads; ``fresh (B, W)``: this step's rows (not yet in the
+    arena: the caller lands them with ``paged_token_write`` afterwards);
+    ``tables (B, nbb)`` int32 sink-padded, ``pos (B,)`` int32.  Returns the
+    heads' weighted latents ``(B, nh, dc)`` at ``q``'s dtype (the caller maps
+    them to values with ``W_v``).  Without Pallas (a CPU that did not opt into
+    the interpreter) the XLA form runs."""
+    B, nh, W = q.shape
+    bs = arena.shape[3]
+    assert arena.shape[2] == 1 and arena.shape[4] == W and W % 128 == 0 and dc % 128 == 0, (arena.shape, W, dc)
+    if not _pallas_available():
+        return _mla_decode_xla(q, arena, fresh, tables, pos, layer=layer, dc=dc, scale=scale)
+    stats["mla_decode"] = stats.get("mla_decode", 0) + 1
+    C = max(1, _MLA_CHUNK_KEYS // bs)
+    kwargs = {}
+    if not _interpret():
+        kwargs["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("parallel",))
+    return pl.pallas_call(
+        functools.partial(_mla_decode_kernel, layer=layer, bs=bs, C=C, dc=dc, scale=float(scale)),
+        name="mla_paged_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[pl.BlockSpec((1, nh, W), lambda i, tab, p: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((1, 1, W), lambda i, tab, p: (i, 0, 0))],
+            out_specs=pl.BlockSpec((1, nh, dc), lambda i, tab, p: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, C, bs, W), arena.dtype), pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((B, nh, dc), q.dtype),
+        interpret=_interpret(),
+        **kwargs,
+    )(tables, pos, q, arena, fresh[:, None, :])
 
 
 def _paged_verify_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest,
@@ -2477,10 +2602,12 @@ def _moe_grouped_mm(x, w, tile_group, tiles_used, transpose_w: bool = False):
     N = w.shape[1] if transpose_w else w.shape[2]
     TN = _gmm_col_block(K, N, x.dtype.itemsize)
     last = lambda used: jnp.maximum(used[0] - 1, 0)  # noqa: E731
+    # a tile past the used ones stays on the weight block the step before it held: no copy for it
+    col = lambda t, j, used: jnp.where(t < used[0], j, N // TN - 1)  # noqa: E731
     if transpose_w:
-        w_spec = pl.BlockSpec((1, TN, K), lambda t, j, tg, used: (tg[t], j, 0))
+        w_spec = pl.BlockSpec((1, TN, K), lambda t, j, tg, used: (tg[t], col(t, j, used), 0))
     else:
-        w_spec = pl.BlockSpec((1, K, TN), lambda t, j, tg, used: (tg[t], 0, j))
+        w_spec = pl.BlockSpec((1, K, TN), lambda t, j, tg, used: (tg[t], 0, col(t, j, used)))
     params = {}
     if not _interpret():
         params["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"))

@@ -42,6 +42,10 @@ __all__ = [
     "cache_shape",
     "state_shapes",
     "gdn_mixer",
+    "mla_mixer",
+    "mla_latent",
+    "moe_share_mlp",
+    "route_sigmoid_group",
     "kv_block_shape",
     "ring_slot",
     "ring_gather_positions",
@@ -105,6 +109,12 @@ def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0):
             y = contrib if y is None else y + contrib
         return y
 
+    kind = cfg.mlp_class
+    if kind == "SparseMoE":
+        if "gate" in mp:
+            return moe_share_mlp(mp, x, cfg, lin=lin)
+        kind = "LLaMAMLP"       # one of the model's leading dense layers
+
     def ll(name, inp, bias=None):
         # one targeted matmul: the per-request LoRA delta rides on the
         # matmul INPUT (same placement rule as _project_qkv / wo)
@@ -113,9 +123,9 @@ def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0):
             o = o + _lora_delta(inp, *lora[name], lora_scaling)
         return o
 
-    if cfg.mlp_class == "LLaMAMLP":
+    if kind == "LLaMAMLP":
         return ll("proj", jax.nn.silu(ll("fc_1", x, "fc_1_b")) * ll("fc_2", x, "fc_2_b"), "proj_b")
-    if cfg.mlp_class == "GemmaMLP":
+    if kind == "GemmaMLP":
         return ll(
             "proj",
             jax.nn.gelu(ll("fc_1", x, "fc_1_b"), approximate=cfg.gelu_approximate == "tanh")
@@ -127,6 +137,191 @@ def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0):
         jax.nn.gelu(ll("fc", x, "fc_b"), approximate=cfg.gelu_approximate == "tanh"),
         "proj_b",
     )
+
+
+def route_sigmoid_group(scores, cfg: Config):
+    """The group-limited choice (DeepSeek-V3 without its score-correction
+    bias) from float32 ``scores (N, E)`` in (0, 1): the experts in ``n_group``
+    groups, a group's score the sum of its two best, the best ``topk_group``
+    groups kept, the top ``n_expert_per_token`` of their experts; the chosen
+    scores renormalised to sum one and scaled by ``routed_scaling_factor``.
+    Returns ``(top_w, top_idx)``, both ``(N, k)``."""
+    N, E = scores.shape
+    G = cfg.n_group
+    grouped = scores.reshape(N, G, E // G)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)                       # (N, G)
+    _, best = jax.lax.top_k(group_score, cfg.topk_group)
+    kept = jnp.any(best[:, :, None] == jnp.arange(G)[None, None, :], axis=1)           # (N, G)
+    masked = jnp.where(kept[:, :, None], grouped, 0.0).reshape(N, E)
+    _, top_idx = jax.lax.top_k(masked, cfg.n_expert_per_token)
+    top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+    top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    return top_w, top_idx
+
+
+MOE_DECODE_ROW_TILE = 16   # bfloat16's sublane tile: the fewest rows moe_grouped_mm compiles for
+
+
+def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear):
+    """A SparseMoE layer in the server, on ``x (B, T, C)``: the router scores
+    *all* ``n_expert`` in float32 (:func:`route_sigmoid_group`), the layer
+    holds experts ``[expert_first, expert_first + expert_held)`` and computes
+    their part (``jaxex._moe_share``, the trainer's forward: the step's rows
+    sorted by held expert into whole row tiles, grouped products through
+    ``moe_grouped_mm``, nothing dropped); what the other experts would add is
+    left out, and the shared expert is added once.  A decode step routes a few
+    rows an expert, so its tiles are the narrowest the kernel takes."""
+    from thunder_tpu.core.prims import MOE_ROW_TILE
+    from thunder_tpu.executors import jaxex
+
+    B, T, C = x.shape
+    I, Eh = cfg.intermediate_size, cfg.expert_held
+    x2 = x.reshape(B * T, C)
+    scores = jax.nn.sigmoid(x2.astype(jnp.float32) @ mp["gate"].T.astype(jnp.float32))
+    top_w, top_idx = route_sigmoid_group(scores, cfg)
+    # rows an even routing sends a held expert: under a tile of them, a tile is mostly padding
+    even = B * T * cfg.n_expert_per_token / cfg.n_expert
+    y = jaxex._moe_share(
+        x2, top_idx, top_w, mp["fc_1"].reshape(Eh, C, I), mp["fc_2"].reshape(Eh, C, I),
+        mp["proj"].reshape(Eh, I, C), cfg.expert_first, cfg.n_expert,
+        tile=MOE_ROW_TILE if even >= MOE_ROW_TILE // 2 else MOE_DECODE_ROW_TILE)
+    if cfg.shared_expert_size:
+        sp = mp["shared"]
+        shared = lin(jax.nn.silu(lin(x2, sp["fc_1"])) * lin(x2, sp["fc_2"]), sp["proj"])
+        if cfg.shared_expert_gate:
+            shared = jax.nn.sigmoid(lin(x2, sp["gate"])) * shared
+        y = y + shared
+    return y.reshape(B, T, C)
+
+
+def pad_lanes(x, width: int):
+    """``x`` with zeros after its last axis up to ``width`` (a latent row as wide as its cache's)."""
+    pad = width - x.shape[-1]
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),)) if pad else x
+
+
+def mla_latent(ap, x, cos_t, sin_t, cfg: Config, lin=_linear):
+    """What a latent-attention layer's cache holds of new tokens ``x (B, T,
+    C)``: ``[c_kv | k_r] (B, T, kv_lora_rank + qk_rope_head_dim)``, the latent
+    after its RMSNorm and the one rotated key all heads share."""
+    dc = cfg.kv_lora_rank
+    kv = lin(x, ap["wkv_a"])
+    c_kv = _rms(kv[..., :dc], ap["kv_norm"], cfg.norm_eps)
+    k_r = _rope(kv[..., dc:][:, None], cos_t, sin_t)[:, 0]
+    return jnp.concatenate([c_kv, k_r], axis=-1)
+
+
+def mla_heads(ap, cfg: Config):
+    """``wkv_b`` a head: ``(W_k (nh, dn, dc), W_v (nh, dv, dc))``: the maps
+    from the latent to a head's unrotated key and to its value."""
+    w = ap["wkv_b"].reshape(cfg.n_head, cfg.qk_nope_head_dim + cfg.v_head_dim, cfg.kv_lora_rank)
+    return w[:, :cfg.qk_nope_head_dim], w[:, cfg.qk_nope_head_dim:]
+
+
+def mla_expand(ap, latent, cfg: Config):
+    """The expanded keys and values of cached tokens: ``latent (B, S, >= dc +
+    dr)`` to ``k (B, nh, S, dn + dr)`` (a head's unrotated part, then the
+    shared rotated key) and ``v (B, nh, S, dv)``."""
+    dc, dr, nh = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.n_head
+    w_k, w_v = mla_heads(ap, cfg)
+    c_kv, k_r = latent[..., :dc], latent[..., dc:dc + dr]
+    k_nope = jnp.einsum("bsc,hdc->bhsd", c_kv, w_k.astype(c_kv.dtype))
+    v = jnp.einsum("bsc,hdc->bhsd", c_kv, w_v.astype(c_kv.dtype))
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_r[:, None], (*k_nope.shape[:3], dr))], axis=-1)
+    return k, v
+
+
+def mla_absorb(ap, q_nope, q_rope, cfg: Config, width: int | None = None):
+    """The absorbed query: ``[q_nope W_k | q_rope] (B, nh, T, dc + dr)``, which
+    scores a cached ``[c_kv | k_r]`` row as the expanded head would; padded
+    with zeros to ``width`` where the cache's rows are wider."""
+    w_k, _ = mla_heads(ap, cfg)
+    qt = jnp.einsum("bhtd,hdc->bhtc", q_nope, w_k.astype(q_nope.dtype))
+    q = jnp.concatenate([qt, q_rope], axis=-1)
+    return pad_lanes(q, width or q.shape[-1])
+
+
+def mla_unabsorb(ap, ot, cfg: Config):
+    """A head's output from its weighted sum of latents: ``ot (B, nh, T, dc)``
+    to ``(B, nh, T, dv)``."""
+    _, w_v = mla_heads(ap, cfg)
+    return jnp.einsum("bhtc,hdc->bhtd", ot, w_v.astype(ot.dtype))
+
+
+def mla_attend_latents(q, latents, keep, cfg: Config):
+    """Absorbed attention in XLA: ``q (B, nh, T, W)`` (:func:`mla_absorb`) over
+    cached rows ``latents (B, S, W)``, ``keep`` broadcastable to ``(B, nh, T,
+    S)``; float32 scores and sums.  Returns the weighted latents ``(B, nh, T,
+    dc)`` at ``q``'s dtype."""
+    lat = latents.astype(q.dtype)
+    s = jnp.einsum("bhtw,bsw->bhts", q, lat, preferred_element_type=jnp.float32) * cfg.attn_scale
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhts,bsc->bhtc", p, lat[..., :cfg.kv_lora_rank])
+
+
+def mla_mixer(ap, x, cos_t, sin_t, cfg: Config, attend, *, lin=_linear):
+    """A latent-attention layer's mixer on new tokens ``x (B, T, C)``, for the
+    dense cache and the paged server alike.  Projects the queries (through the
+    rank-``q_lora_rank`` bottleneck and its norm) and the tokens' cache rows
+    (:func:`mla_latent`); attention itself is
+    ``attend(q_nope (B, nh, T, dn), q_rope (B, nh, T, dr), latent (B, T, dc +
+    dr)) -> o (B, nh, T, dv)``: the caller's closure keeps the rows wherever
+    its cache is and attends in the expanded form (:func:`mla_expand`) or the
+    absorbed one (:func:`mla_absorb`, :func:`mla_unabsorb`).  Returns ``y (B,
+    T, C)``."""
+    B, T, _ = x.shape
+    nh, dn, dv = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim
+    q = lin(_rms(lin(x, ap["wq_a"]), ap["q_norm"], cfg.norm_eps), ap["wq_b"])
+    q = q.reshape(B, T, nh, cfg.head_size).transpose(0, 2, 1, 3)
+    q_nope, q_rope = q[..., :dn], _rope(q[..., dn:], cos_t, sin_t)
+    o = attend(q_nope, q_rope, mla_latent(ap, x, cos_t, sin_t, cfg, lin))
+    return lin(o.transpose(0, 2, 1, 3).reshape(B, T, nh * dv), ap["wo"])
+
+
+def _mla_with_cache(ap, x, cos_t, sin_t, cl, pos, cfg: Config, *, quantized=False, sharded=False):
+    """A latent-attention layer against the dense cache ``cl (B, 1, Tc, W)``, W
+    at least ``cfg.latent_width`` (the paged pool's rows are padded to whole
+    lane tiles): writes the new tokens' rows at ``[pos, pos + T)`` and attends.
+    A whole prompt at the static position 0 attends its own expanded keys,
+    through the flash kernel where that takes the shapes (q, k and v padded
+    with zeros to one head size: exact); a later piece of a prompt the expanded
+    keys of the whole cache; one token (T = 1) the cached rows themselves, in
+    the absorbed form.  Returns ``(y, cl)``."""
+    B, T, _ = x.shape
+    Tc, W = cl.shape[2], cl.shape[3]
+    vec = _is_vec_pos(pos)
+    fresh = isinstance(pos, int) and pos == 0 and T > 1
+    box = [cl]
+
+    def attend(q_nope, q_rope, latent):
+        row = pad_lanes(latent, W).astype(cl.dtype)[:, None]
+        if vec:
+            box[0] = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(c, u, p, axis=1))(cl, row, pos)
+        else:
+            box[0] = jax.lax.dynamic_update_slice_in_dim(cl, row, pos, axis=2)
+        if fresh:
+            keys, keep = latent, _band_keep(T, None)
+        else:
+            keys = box[0][:, 0]
+            qpos = ((pos[:, None] + jnp.arange(T)[None, :])[:, None, :, None] if vec
+                    else (pos + jnp.arange(T))[None, None, :, None])
+            keep = jnp.arange(Tc)[None, None, None, :] <= qpos
+        if T == 1:
+            return mla_unabsorb(ap, mla_attend_latents(mla_absorb(ap, q_nope, q_rope, cfg, W), keys, keep, cfg), cfg)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k, v = mla_expand(ap, keys.astype(q.dtype), cfg)
+        if fresh and not sharded:
+            from thunder_tpu.executors import pallasex
+
+            flash = pallasex.flash_sdpa(q, k, pad_lanes(v, q.shape[-1]), None, True, cfg.attn_scale, None)
+            if flash is not None:
+                return flash[0][..., :cfg.v_head_dim]
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * cfg.attn_scale
+        w = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bhkd->bhqd", w, v)
+
+    y = mla_mixer(ap, x, cos_t, sin_t, cfg, attend, lin=partial(_linear, quantized=quantized))
+    return y, box[0]
 
 
 def _lora_delta(x, a, b, scaling):
@@ -195,6 +390,8 @@ def cache_shape(cfg: Config, B: int, T_max: int) -> tuple[int, int, int, int, in
     layout every cache consumer (``init_cache``, the serving KV pool's
     gathered views) agrees on.  ``L`` counts the layers that keep K and V
     (``cfg.kv_layers``): a linear_attention layer has none."""
+    if cfg.latent:   # one row a token a layer, read by every head: ``latent`` in the cache's dict
+        return (cfg.n_layer, B, 1, T_max, cfg.latent_width)
     return (len(cfg.kv_layers), B, cfg.n_query_groups, cache_len(cfg, T_max), cfg.head_size)
 
 
@@ -214,7 +411,13 @@ def kv_block_shape(cfg: Config, block_size: int) -> tuple[int, int, int, int]:
     """Per-block geometry ``(L, n_query_groups, block_size, hs)`` of the
     paged serving pool's arena — one block holds ``block_size`` consecutive
     token slots of every layer's K (or V), so a gather over a request's
-    block table reassembles exactly the :func:`cache_shape` layout."""
+    block table reassembles exactly the :func:`cache_shape` layout.  A
+    latent-attention model's one arena holds ``(L, 1, block_size, W)``: a row
+    is a token's latent and its rotated key, ``W`` their ``latent_width`` padded
+    with zeros to whole 128-lane tiles (576 to 640), which is how the chip
+    lays a narrower row out anyway and what the decode kernel's copies need."""
+    if cfg.latent:
+        return (cfg.n_layer, 1, block_size, -(-cfg.latent_width // 128) * 128)
     return (len(cfg.kv_layers), cfg.n_query_groups, block_size, cfg.head_size)
 
 
@@ -258,6 +461,9 @@ def init_cache(cfg: Config, B: int, T_max: int, dtype=jnp.bfloat16, *, mesh=None
         z = jnp.zeros(shape, dtype=dtype)
         return jax.device_put(z, sh) if sh is not None else z
 
+    if cfg.latent:
+        assert mesh is None, "a latent cache has no sharded layout yet"
+        return {"latent": zeros()}
     cache = {"k": zeros(), "v": zeros()}
     shapes = state_shapes(cfg, B)
     if shapes:
@@ -458,7 +664,7 @@ def gdn_recur_dense(state):
 
 def require_servable(cfg: Config) -> None:
     """The one refusal of a config this module's forward cannot run (an
-    expert share, gated or per-head-normed attention): such a model trains
+    expert share routed by softmax, gated or per-head-normed attention): such a model trains
     through ``tt.jit`` / ``make_train_step``; serving it is not built yet."""
     why = getattr(cfg, "training_only", None)
     if why:
@@ -523,7 +729,7 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
         cos_t = jax.lax.dynamic_slice_in_dim(cos_all, pos, T, axis=0)
         sin_t = jax.lax.dynamic_slice_in_dim(sin_all, pos, T, axis=0)
 
-    new_k, new_v, new_conv, new_state = [], [], [], []
+    new_k, new_v, new_conv, new_state, new_latent = [], [], [], [], []
     lin = partial(_linear, quantized=quantized)
     for l, bp in enumerate(params["blocks"]):
         # OLMo's blocks norm what a sublayer gives, not what it takes
@@ -537,6 +743,10 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
             h, tail = gdn_mixer(bp["gdn"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
             new_conv.append(tail)
             new_state.append(box[0])
+        elif cfg.latent:
+            h, cl = _mla_with_cache(bp["attn"], n1, cos_t, sin_t, cache["latent"][l], pos, cfg,
+                                    quantized=quantized, sharded=sharded)
+            new_latent.append(cl)
         else:
             j = len(new_k)
             h, ck, cv = _attn_with_cache(
@@ -547,7 +757,7 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
             new_v.append(cv)
         x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
 
-    cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    cache = {"latent": jnp.stack(new_latent)} if cfg.latent else {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
     if new_state:
         cache.update(conv=jnp.stack(new_conv), state=jnp.stack(new_state))
     x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))

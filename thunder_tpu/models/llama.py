@@ -129,10 +129,53 @@ class Config:
     expert_first: int = 0
     expert_held: int | None = None
     shared_expert_size: int = 0
+    # The router of a SparseMoE layer.  "softmax" as above; "sigmoid_group"
+    # (DeepSeek-V3): float32 sigmoid scores over all experts in ``n_group``
+    # groups, a group's score the sum of its two best, the best ``topk_group``
+    # groups kept, the top ``n_expert_per_token`` of what is left renormalised
+    # and scaled by ``routed_scaling_factor``.  ``shared_expert_gate`` False:
+    # the shared expert is added ungated
+    moe_router: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    shared_expert_gate: bool = True
+    # The first ``first_k_dense`` layers of a SparseMoE model keep a dense
+    # SwiGLU of width ``dense_intermediate_size`` in place of the experts
+    first_k_dense: int = 0
+    dense_intermediate_size: int | None = None
+    # Multi-head latent attention (DeepSeek-V2/V3), on where ``kv_lora_rank``
+    # > 0: q through a rank-``q_lora_rank`` bottleneck with an RMSNorm
+    # (required), heads of ``qk_nope_head_dim`` unrotated and
+    # ``qk_rope_head_dim`` rotated query dims; keys and values expanded a head
+    # from one normed latent of ``kv_lora_rank`` a token, beside one rotated key
+    # of ``qk_rope_head_dim`` shared by all heads; values of ``v_head_dim``.
+    # The cache keeps the latent and the rotated key alone
+    # (``latent_width`` numbers a token a layer)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope rescaling (hf rope_scaling type "yarn"): ``factor``,
+    # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    # ``mscale``, ``mscale_all_dim``; stored like ``rope_scaling_llama3``
+    rope_scaling_yarn: tuple | dict | None = None
 
     def __post_init__(self):
         if isinstance(self.rope_scaling_llama3, dict):
             self.rope_scaling_llama3 = tuple(sorted(self.rope_scaling_llama3.items()))
+        if isinstance(self.rope_scaling_yarn, dict):
+            self.rope_scaling_yarn = tuple(sorted(self.rope_scaling_yarn.items()))
+        if self.kv_lora_rank:
+            assert self.qk_nope_head_dim > 0 and self.qk_rope_head_dim > 0 and self.v_head_dim > 0, (
+                "latent attention needs qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+            assert self.q_lora_rank > 0, "latent attention needs q_lora_rank (a single query projection is not built)"
+            assert self.qk_rope_head_dim % 2 == 0 and self.sliding_window is None and not self.bias
+            assert self.layer_types is None and not (self.qk_norm or self.qk_norm_whole or self.attn_output_gate)
+            if self.head_size is None:
+                self.head_size = self.qk_nope_head_dim + self.qk_rope_head_dim
+            assert self.head_size == self.qk_nope_head_dim + self.qk_rope_head_dim
         if self.padded_vocab_size is None:
             # pad to a multiple of 64 for TPU-friendly gather/matmul tiling
             self.padded_vocab_size = ((self.vocab_size + 63) // 64) * 64
@@ -154,6 +197,15 @@ class Config:
                 self.expert_held = self.n_expert - self.expert_first
             assert 0 <= self.expert_first and 0 < self.expert_held <= self.n_expert - self.expert_first
             assert not self.bias, "bias is not supported for the MoE MLP"
+            assert self.moe_router in ("softmax", "sigmoid_group"), self.moe_router
+            if self.moe_router == "sigmoid_group":
+                assert self.n_expert % self.n_group == 0 and 0 < self.topk_group <= self.n_group
+                assert self.n_expert // self.n_group >= 2, "a group's score is the sum of its two best"
+                assert self.n_expert_per_token <= self.topk_group * (self.n_expert // self.n_group)
+            if self.first_k_dense and self.dense_intermediate_size is None:
+                self.dense_intermediate_size = self.intermediate_size
+        else:
+            assert not self.first_k_dense, "first_k_dense: the leading dense layers of a SparseMoE model"
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             assert len(self.layer_types) == self.n_layer, "layer_types needs one kind a layer"
@@ -177,7 +229,33 @@ class Config:
 
     @property
     def rope_n_elem(self) -> int:
+        if self.latent:
+            return self.qk_rope_head_dim
         return int(self.rotary_percentage * self.head_size)
+
+    @property
+    def latent(self) -> bool:
+        """Multi-head latent attention: the cache is one latent a token a layer."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a token a layer of a latent cache: the normed latent and the one rotated key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def attn_scale(self) -> float:
+        """What attention multiplies its scores by: ``head_size^-1/2``, times
+        YaRN's ``mscale^2`` (``0.1 mscale_all_dim ln(factor) + 1``) where set."""
+        scale = self.head_size ** -0.5
+        yarn = dict(self.rope_scaling_yarn or ())
+        if yarn.get("mscale_all_dim"):
+            scale *= _yarn_mscale(float(yarn["factor"]), float(yarn["mscale_all_dim"])) ** 2
+        return scale
+
+    def mlp_dense(self, i: int) -> bool:
+        """Whether layer ``i`` of a SparseMoE model is one of its leading dense layers."""
+        return self.mlp_class == "SparseMoE" and i < self.first_k_dense
 
     def layer_kind(self, i: int) -> str:
         return "full_attention" if self.layer_types is None else self.layer_types[i]
@@ -204,9 +282,10 @@ class Config:
     @property
     def training_only(self) -> str | None:
         """Why ``models.generate`` and ``tt.serve`` cannot run this config, or
-        None: the server holds no expert share and no gated attention yet."""
-        if self.mlp_class == "SparseMoE":
-            return "its mlp_class is SparseMoE (an expert share; the serving forward has no such layer)"
+        None: the server's expert share has one router, and no gated attention yet."""
+        if self.mlp_class == "SparseMoE" and self.moe_router == "softmax":
+            return ("its mlp_class is SparseMoE with the softmax router (the serving forward's expert "
+                    "share routes by moe_router='sigmoid_group' alone)")
         for knob in ("attn_output_gate", "qk_norm", "norm_zero_centered"):
             if getattr(self, knob):
                 return f"it sets {knob} (the serving forward's attention and norms have no such form)"
@@ -329,7 +408,8 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
     def dense(key, fan_in, fan_out):
         return (jax.random.normal(key, (fan_out, fan_in), dtype=jnp.float32) * std).astype(dtype)
 
-    n_keys = 3 + config.n_layer * (5 + 3 * max(1, config.n_expert) + (8 if config.mlp_class == "SparseMoE" else 0))
+    n_keys = 3 + config.n_layer * (5 + 3 * max(1, config.n_expert) + (8 if config.mlp_class == "SparseMoE" else 0)
+                                   + (2 if config.latent else 0))
     keys = iter(jax.random.split(key, n_keys))
 
     def zeros(n):
@@ -370,6 +450,17 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 "norm": jnp.ones((dv,), dtype=dtype),
                 "out_proj": dense(next(keys), nv * dv, config.n_embd),
             }
+        elif config.latent:
+            dc, dr, rq = config.kv_lora_rank, config.qk_rope_head_dim, config.q_lora_rank
+            block["attn"] = {
+                "wkv_a": dense(next(keys), config.n_embd, dc + dr),
+                "kv_norm": jnp.ones((dc,), dtype=dtype),
+                "wkv_b": dense(next(keys), dc, nh * (config.qk_nope_head_dim + config.v_head_dim)),
+                "wo": dense(next(keys), nh * config.v_head_dim, config.n_embd),
+                "wq_a": dense(next(keys), config.n_embd, rq),
+                "q_norm": jnp.ones((rq,), dtype=dtype),
+                "wq_b": dense(next(keys), rq, nh * hs),
+            }
         else:
             block["attn"] = {
                 "wq": dense(next(keys), config.n_embd, nh * hs * (2 if config.attn_output_gate else 1)),
@@ -406,6 +497,10 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 "fc_2": stacked(config.n_embd, config.intermediate_size),
                 "proj": stacked(config.intermediate_size, config.n_embd),
             }
+        elif config.mlp_dense(i):
+            Id = config.dense_intermediate_size
+            block["mlp"] = {"fc_1": dense(next(keys), config.n_embd, Id), "fc_2": dense(next(keys), config.n_embd, Id),
+                            "proj": dense(next(keys), Id, config.n_embd)}
         elif config.mlp_class == "SparseMoE":
             # held experts stacked and flattened to two dims, "x @ W" layout:
             # fc_1/fc_2 (held * C, I), proj (held * I, C); the grouped
@@ -421,8 +516,10 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 Is = config.shared_expert_size
                 block["mlp"]["shared"] = {
                     "fc_1": dense(next(keys), C, Is), "fc_2": dense(next(keys), C, Is),
-                    "proj": dense(next(keys), Is, C), "gate": dense(next(keys), C, 1),
+                    "proj": dense(next(keys), Is, C),
                 }
+                if config.shared_expert_gate:
+                    block["mlp"]["shared"]["gate"] = dense(next(keys), C, 1)
         elif config.mlp_class in ("LLaMAMLP", "GemmaMLP"):
             block["mlp"] = {
                 "fc_1": dense(next(keys), config.n_embd, config.intermediate_size),
@@ -477,16 +574,48 @@ def _llama3_rescale_freqs(theta: jax.Array, params: dict) -> jax.Array:
     return scaled
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_rescale_freqs(theta: jax.Array, n_elem: int, base: float, params: dict) -> jax.Array:
+    """YaRN (hf ``_compute_yarn_parameters``): the dims that turn more than
+    ``beta_fast`` times over the original context keep their frequency, those
+    that turn fewer than ``beta_slow`` times divide it by ``factor``, a linear
+    ramp over the dims between.  ``dim(b) = n ln(orig / (2 pi b)) / (2 ln base)``."""
+    factor = float(params["factor"])
+    orig = float(params["original_max_position_embeddings"])
+
+    def dim(turns):
+        return n_elem * math.log(orig / (turns * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(dim(float(params.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(dim(float(params.get("beta_slow", 1)))), n_elem - 1)
+    ramp = jnp.clip((jnp.arange(n_elem // 2, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+    return theta * (1.0 - ramp) + theta / factor * ramp
+
+
 def build_rope_cache(config: Config, seq_len: int, dtype=jnp.float32) -> tuple[jax.Array, jax.Array]:
     """Precomputed (cos, sin) of shape (seq_len, rope_n_elem), host-side."""
     n_elem = config.rope_n_elem
     theta = 1.0 / (config.rope_base ** (jnp.arange(0, n_elem, 2, dtype=jnp.float32) / n_elem))
     if config.rope_scaling_llama3 is not None:
         theta = _llama3_rescale_freqs(theta, dict(config.rope_scaling_llama3))
+    mag = 1.0
+    if config.rope_scaling_yarn is not None:
+        yarn = dict(config.rope_scaling_yarn)
+        theta = _yarn_rescale_freqs(theta, n_elem, float(config.rope_base), yarn)
+        # cos and sin carry mscale / mscale_all_dim (1 where both are set alike)
+        factor = float(yarn["factor"])
+        mag = (_yarn_mscale(factor, float(yarn.get("mscale") or 1.0))
+               / _yarn_mscale(factor, float(yarn.get("mscale_all_dim") or 0.0)))
     seq = jnp.arange(seq_len, dtype=jnp.float32) / config.rope_condense_ratio
     idx_theta = jnp.outer(seq, theta)  # (T, n_elem/2)
     idx_theta = jnp.concatenate([idx_theta, idx_theta], axis=-1)  # (T, n_elem)
-    return jnp.cos(idx_theta).astype(dtype), jnp.sin(idx_theta).astype(dtype)
+    cos, sin = jnp.cos(idx_theta), jnp.sin(idx_theta)
+    if mag != 1.0:          # no eager product (a program of its own) where there is nothing to scale
+        cos, sin = cos * mag, sin * mag
+    return cos.astype(dtype), sin.astype(dtype)
 
 
 #
@@ -704,7 +833,23 @@ def mlp(mp, x, config: Config):
     )
 
 
+def serving_only(config: Config) -> str | None:
+    """Why ``block_forward`` (``tt.jit`` / ``make_train_step``) cannot run this
+    config, or None: latent attention, the group-limited router and leading
+    dense layers are built in ``models.generate`` for the server alone."""
+    if config.latent:
+        return "kv_lora_rank > 0 (latent attention is built in models.generate, for tt.serve, and has no traced form)"
+    if config.mlp_class == "SparseMoE" and (config.moe_router != "softmax" or config.first_k_dense
+                                            or (config.shared_expert_size and not config.shared_expert_gate)):
+        return ("a SparseMoE layer with moe_router='sigmoid_group', first_k_dense or an ungated shared expert "
+                "(built in models.generate, for tt.serve; the traced expert layer routes by softmax)")
+    return None
+
+
 def block_forward(bp, x, cos, sin, config: Config, kind: str = "full_attention"):
+    why = serving_only(config)
+    if why:
+        raise NotImplementedError(f"config {config.name!r} cannot be trained through tt.jit: it sets {why}")
     if config.post_sublayer_norm:
         h = (gated_delta_net(bp["gdn"], x, config) if kind == "linear_attention"
              else attention(bp["attn"], x, cos, sin, config))

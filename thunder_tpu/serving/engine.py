@@ -121,6 +121,7 @@ from thunder_tpu.serving.kv_pool import (
     chunk_tables,
     dest_for_pos,
     gather_dense,
+    gather_rows,
     scatter_blocks,
     scatter_token,
 )
@@ -281,6 +282,34 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
         return "a sliding window is unsupported beside a recurrent state (block expiry is untested with it)"
     return None
 
+def latent_unsupported(cfg, *, kv_dtype=None, cache_dtype=None, speculative=None, lora=None, mesh=None,
+                       model_fn=None, attn: str = "auto") -> str | None:
+    """Why an engine with these options cannot serve a latent-attention config
+    (one latent a token a layer in place of K and V), or None.  Each is a
+    mechanism that is not built (ROADMAP Queue 2)."""
+    from thunder_tpu.serving.quant import is_quantized_kv, resolve_kv_dtype
+
+    dtype = cache_dtype if cache_dtype is not None else jnp.bfloat16
+    if kv_dtype is not None and is_quantized_kv(resolve_kv_dtype(kv_dtype, dtype), dtype):
+        return ("kv_dtype= (an int8 or fp8 arena) is unsupported: the latent decode kernel has no dequant, "
+                "and a latent is read by every head, so its scale is not a head's")
+    if mesh is not None:
+        return ("mesh= is unsupported: the latent arena has one row for all heads, so no heads axis shards "
+                "over tp, and the decode kernel has no partitioning rule")
+    if speculative is not None:
+        return ("speculative= is unsupported: the verify step attends several draft tokens a row, and the "
+                "latent decode kernel takes one")
+    if lora is not None:
+        return ("lora= is unsupported: the adapter arenas target wq/wk/wv/wo; the latent projections "
+                "(wq_a, wq_b, wkv_a, wkv_b) have none")
+    if model_fn is not None:
+        return "a custom model_fn is unsupported: the latent programs mirror forward_with_cache"
+    if attn == "gather":
+        return ("attn='gather' is unsupported: the gather decode program has no latent form; the paged "
+                "decode program runs mla_paged_decode's XLA form where Pallas is off")
+    return None
+
+
 # one decode program's collective census per (mesh, static config, bucket):
 # the census pays an extra AOT compile, so it is module-cached like programs
 _collectives_cache: dict = {}
@@ -346,6 +375,14 @@ class ServingEngine:
                     f"config {getattr(cfg, 'name', '?')!r} has linear_attention layers "
                     f"(a recurrent state a request): {why}")
             prefix_sharing = False
+        self._latent = bool(getattr(cfg, "latent", False))
+        if self._latent:
+            why = latent_unsupported(cfg, kv_dtype=kv_dtype, cache_dtype=cache_dtype, speculative=speculative,
+                                     lora=lora, mesh=mesh, model_fn=model_fn, attn=attn)
+            if why:
+                raise NotImplementedError(
+                    f"config {getattr(cfg, 'name', '?')!r} has latent attention (one latent a token a "
+                    f"layer in place of K and V): {why}")
         if prefix_sharing is None:
             prefix_sharing = True
         if shardings is not None and mesh is None:
@@ -410,7 +447,9 @@ class ServingEngine:
             if not ok:
                 raise ValueError(f"attn='paged' is unsupported here: {why}")
             self.attn, self._attn_fallback_reason = "paged", None
-        elif attn == "auto" and ok and paged_available():
+        elif attn == "auto" and ok and (paged_available() or self._latent):
+            # a latent arena has the paged decode program alone: without
+            # Pallas its kernel call is the XLA form
             self.attn, self._attn_fallback_reason = "paged", None
         elif attn == "auto":
             self.attn = "gather"
@@ -422,7 +461,11 @@ class ServingEngine:
         # keys a step of paged_attn_decode's walk attends (a group), from the
         # arena shard the decode program's kernel is handed; None on gather
         self._kv_chunk_tokens = None
-        if self.attn == "paged":
+        if self._latent:
+            from thunder_tpu.executors.pallasex import _MLA_CHUNK_KEYS
+
+            self._kv_chunk_tokens = _MLA_CHUNK_KEYS
+        elif self.attn == "paged":
             from thunder_tpu.executors.pallasex import paged_kv_chunk_blocks
 
             arena = self.pool.k_arena
@@ -555,6 +598,8 @@ class ServingEngine:
             chunk_why = "speculative prefill writes the draft arena (gather chunk)"
         elif cfg.sliding_window is not None:
             chunk_why = "sliding-window keep-mask is decode-only"
+        elif self._latent:
+            chunk_why = "a latent cache's piece attends its expanded keys (the dense form)"
         elif sch.prefill_chunk is not None and sch.prefill_chunk % block_size:
             chunk_why = (f"prefill_chunk={sch.prefill_chunk} not a multiple "
                          f"of block_size={block_size}")
@@ -1059,7 +1104,8 @@ class ServingEngine:
         in flight (an async engine harvests first): ``tokens``, how many of
         its prompt and generated tokens went in; ``k`` and ``v`` ``(L_kv, ng,
         tokens, hs)`` as attention reads them (a quantised arena comes
-        dequantised, at the compute dtype); and, for a model with
+        dequantised, at the compute dtype), or, for a latent-attention model,
+        ``latent (L, tokens, latent_width)`` in their place; and, for a model with
         linear_attention layers, its slot's ``state (L_lin, nv, dk, dv)`` and
         ``conv (L_lin, K - 1, channels)`` as stored.  For the tests and for a
         comparison with a reference; changes nothing."""
@@ -1070,6 +1116,9 @@ class ServingEngine:
             raise RuntimeError(f"request {req.rid} is {req.state}: the caches hold a running request only")
         arenas = self.pool.arenas
         table = jnp.asarray([req.block_table[:self.pool.blocks_for_tokens(req.pos)]], dtype=jnp.int32)
+        if self._latent:     # (L, tokens, latent_width): the rows without their lane padding
+            rows = gather_rows(arenas["latent"], table)
+            return {"tokens": req.pos, "latent": rows[:, 0, 0, :req.pos, :self.cfg.latent_width]}
         if self.pool.quantized_kv:
             k, v = gather_dense_q(arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
                                   table, self.pool.dtype)
@@ -1118,7 +1167,7 @@ class ServingEngine:
             "axes": {a: int(self.mesh.shape[a]) for a in self.mesh.axis_names},
             "arena_spec": str(self.pool.arena_sharding.spec),
             "arena_shard_bytes": self.pool.per_shard_bytes(),
-            "arena_total_bytes": int(self.pool.k_arena.nbytes) * 2,
+            "arena_total_bytes": int(self.pool.k_arena.nbytes) * 2,   # a mesh serves K and V only
             "collectives_decode": self._mesh_collectives,  # None until censused
         }
 
@@ -1151,6 +1200,9 @@ class ServingEngine:
             "kv_dtype": str(self.pool.kv_dtype),
             "arena_bytes": self.pool.arena_bytes(),
             **({"state": self.pool.state.snapshot()} if self._hybrid else {}),
+            **({"moe": {"experts_held": self.cfg.expert_held, "expert_first": self.cfg.expert_first,
+                        "experts_published": self.cfg.n_expert, "router": self.cfg.moe_router}}
+               if self.cfg.mlp_class == "SparseMoE" else {}),
             "async_step": self.async_step,
             "prefill_chunk": sch.prefill_chunk,
             "decode_steps": self.decode_steps,
@@ -2714,6 +2766,8 @@ class ServingEngine:
             return None
         import dataclasses
 
+        from thunder_tpu.executors.pallasex import paged_available
+
         return (
             tuple(sorted(dataclasses.asdict(self.cfg).items())),
             self.pool.block_size, str(self.pool.dtype), str(self.pool.kv_dtype),
@@ -2735,6 +2789,9 @@ class ServingEngine:
             # mask ARGUMENTS (the LoRA idiom), so program identity never
             # sees a grammar; off collapses to None for cache sharing
             "constrained" if self._constraints else None,
+            # a latent engine builds the paged decode program with or without
+            # Pallas (the kernel, or its XLA form): which one is the program's
+            ("latent", paged_available()) if self._latent else None,
         )
 
     def _program(self, kind: str, a: int, b: int) -> tuple[Callable, bool]:
@@ -2846,6 +2903,35 @@ class ServingEngine:
             kw["lora_scaling"] = self._registry.scaling
         return kw
 
+    def _dense_cache(self, arenas, tables, cdtype) -> dict:
+        """The rows' blocks as the dense cache ``forward_with_cache`` takes
+        (inside a program): K and V, dequantised from a quantised pool, or a
+        latent-attention model's one ``latent``."""
+        if self._latent:
+            return {"latent": gather_rows(arenas["latent"], tables)}
+        if self.pool.quantized_kv:
+            kd, vd = gather_dense_q(
+                arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"], tables, cdtype)
+        else:
+            kd, vd = gather_dense(arenas["k"], arenas["v"], tables)
+        return {"k": kd, "v": vd}
+
+    def _blocks_back(self, arenas, cache, dest) -> tuple[dict, Any]:
+        """A one-row dense cache's blocks back into their arenas at ``dest``
+        (inside a program): the arenas written, and the quantisation error
+        measured (0 for a pool stored at the compute dtype)."""
+        if self._latent:
+            return {"latent": scatter_blocks(arenas["latent"], cache["latent"], dest)}, jnp.float32(0.0)
+        if self.pool.quantized_kv:
+            k_arena, k_scale, k_err = scatter_blocks_q(
+                arenas["k"], arenas["k_scale"], cache["k"], dest)
+            v_arena, v_scale, v_err = scatter_blocks_q(
+                arenas["v"], arenas["v_scale"], cache["v"], dest)
+            return ({"k": k_arena, "v": v_arena, "k_scale": k_scale, "v_scale": v_scale},
+                    0.5 * (k_err + v_err))
+        return ({"k": scatter_blocks(arenas["k"], cache["k"], dest),
+                 "v": scatter_blocks(arenas["v"], cache["v"], dest)}, jnp.float32(0.0))
+
     def _build_prefill(self, Tb: int, nbb: int, *, fresh: bool = False) -> Callable:
         """The program of a prompt's last piece: forward, sample token 0
         (splitting the key as solo ``generate()`` does), write the K/V out.
@@ -2865,7 +2951,6 @@ class ServingEngine:
         is called as before and its full logits indexed."""
         cfg, fwd, temp = self.cfg, self._forward, self.temperature
         hybrid = self._hybrid
-        qkv = self.pool.quantized_kv
         cdtype = jnp.dtype(self.pool.dtype)
         cap = self.pool.capacity_tokens(nbb)
         # a fresh program's table is as wide as its bucket; its rope table is
@@ -2877,14 +2962,10 @@ class ServingEngine:
 
         def run(params, toks, pos, n_real, arenas, table, dest, key, lora, slot, cmask):
             if fresh:
-                kd = vd = jnp.zeros(self.pool.dense_shape(1, nbb), cdtype)
-            elif qkv:
-                kd, vd = gather_dense_q(
-                    arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
-                    table[None, :], cdtype,
-                )
+                zeros = jnp.zeros(self.pool.dense_shape(1, nbb), cdtype)
+                dense = {name: zeros for name in (("latent",) if self._latent else ("k", "v"))}
             else:
-                kd, vd = gather_dense(arenas["k"], arenas["v"], table[None, :])
+                dense = self._dense_cache(arenas, table[None, :], cdtype)
             held, more = {}, {}
             if hybrid:
                 # the request's state slot rides before the constraint mask;
@@ -2899,7 +2980,7 @@ class ServingEngine:
                 more = {"n_real": n_real}
             own = {"logits_at": n_real - 1, "sharded": sharded} if in_tree else {}
             logits, cache = fwd(
-                params, toks, pos, {"k": kd, "v": vd, **held}, cos_all, sin_all, cfg,
+                params, toks, pos, {**dense, **held}, cos_all, sin_all, cfg,
                 **self._fwd_kwargs(lora, slot), **more, **own,
             )
             last = logits[:, 0] if in_tree else jax.lax.dynamic_index_in_dim(
@@ -2909,19 +2990,8 @@ class ServingEngine:
             key, sub = jax.random.split(key)
             tok = sample_token(last, temp, sub)            # (1,) — solo-prefill parity
             kept = scatter_state(arenas, cache, sslot) if hybrid else {}
-            if qkv:
-                k_arena, k_scale, k_err = scatter_blocks_q(
-                    arenas["k"], arenas["k_scale"], cache["k"], dest)
-                v_arena, v_scale, v_err = scatter_blocks_q(
-                    arenas["v"], arenas["v_scale"], cache["v"], dest)
-                arenas = {"k": k_arena, "v": v_arena,
-                          "k_scale": k_scale, "v_scale": v_scale, **kept}
-                qerr = 0.5 * (k_err + v_err)
-            else:
-                arenas = {"k": scatter_blocks(arenas["k"], cache["k"], dest),
-                          "v": scatter_blocks(arenas["v"], cache["v"], dest), **kept}
-                qerr = jnp.float32(0.0)
-            return tok, arenas, key, qerr
+            written, qerr = self._blocks_back(arenas, cache, dest)
+            return tok, {**written, **kept}, key, qerr
 
         if fresh:
             @partial(jax.jit, donate_argnums=(3,), **self._jit_kwargs("prefill_fresh"))
@@ -2949,7 +3019,6 @@ class ServingEngine:
         chunk is strictly cheaper than a same-width prefill."""
         cfg, fwd = self.cfg, self._forward
         hybrid = self._hybrid
-        qkv = self.pool.quantized_kv
         cdtype = jnp.dtype(self.pool.dtype)
         cap = self.pool.capacity_tokens(nbb)
         cos_all, sin_all = build_rope_cache(cfg, cap)
@@ -2958,36 +3027,19 @@ class ServingEngine:
         # request's state slot and how many of the piece's tokens are real
         @partial(jax.jit, donate_argnums=(3,), **self._jit_kwargs("prefill_chunk"))
         def prefill_chunk(params, toks, pos, arenas, table, dest, lora, slot, *state_args):
-            if qkv:
-                kd, vd = gather_dense_q(
-                    arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
-                    table[None, :], cdtype,
-                )
-            else:
-                kd, vd = gather_dense(arenas["k"], arenas["v"], table[None, :])
+            dense = self._dense_cache(arenas, table[None, :], cdtype)
             held, more = {}, {}
             if hybrid:
                 sslot, n_real = state_args
                 held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
                 more = {"n_real": n_real}
             _logits, cache = fwd(
-                params, toks, pos, {"k": kd, "v": vd, **held}, cos_all, sin_all, cfg,
+                params, toks, pos, {**dense, **held}, cos_all, sin_all, cfg,
                 **self._fwd_kwargs(lora, slot), **more,
             )
             kept = scatter_state(arenas, cache, sslot) if hybrid else {}
-            if qkv:
-                k_arena, k_scale, k_err = scatter_blocks_q(
-                    arenas["k"], arenas["k_scale"], cache["k"], dest)
-                v_arena, v_scale, v_err = scatter_blocks_q(
-                    arenas["v"], arenas["v_scale"], cache["v"], dest)
-                arenas = {"k": k_arena, "v": v_arena,
-                          "k_scale": k_scale, "v_scale": v_scale, **kept}
-                qerr = 0.5 * (k_err + v_err)
-            else:
-                arenas = {"k": scatter_blocks(arenas["k"], cache["k"], dest),
-                          "v": scatter_blocks(arenas["v"], cache["v"], dest), **kept}
-                qerr = jnp.float32(0.0)
-            return arenas, qerr
+            written, qerr = self._blocks_back(arenas, cache, dest)
+            return {**written, **kept}, qerr
 
         return prefill_chunk
 

@@ -54,7 +54,7 @@ from thunder_tpu.models.generate import kv_block_shape
 from thunder_tpu.serving.quant import is_quantized_kv, resolve_kv_dtype
 
 __all__ = ["PoolExhaustedError", "ArenaMismatchError", "PagedKVPool", "StatePool",
-           "PrefixIndex", "chunk_tables", "dest_for_pos", "gather_state", "scatter_state",
+           "PrefixIndex", "chunk_tables", "dest_for_pos", "gather_state", "scatter_state", "gather_rows",
            "OCCUPANCY_WINDOW"]
 
 SINK_BLOCK = 0  # reserved physical block for padding/expired table entries
@@ -203,6 +203,13 @@ class PagedKVPool:
         self.kv_dtype = resolve_kv_dtype(kv_dtype, dtype)  # storage dtype
         self.quantized_kv = is_quantized_kv(self.kv_dtype, dtype)
         self.mesh = mesh
+        # a latent-attention model keeps ONE arena, ``arenas["latent"]``: a
+        # row is a token's latent and rotated key, read by every head; it
+        # lives in ``k_arena`` (``v_arena`` is None), so the allocator, the
+        # tables and the chunk writers above see blocks as they always did
+        self.latent = bool(getattr(cfg, "latent", False))
+        if self.latent and (self.quantized_kv or mesh is not None):
+            raise ValueError("a latent arena has no quantised storage and no layout under a mesh")
         shape = (self.num_blocks, *kv_block_shape(cfg, self.block_size))
         self._arena_shape = shape
         self._scale_shape = shape[:-1]                  # absmax over hs
@@ -227,7 +234,7 @@ class PagedKVPool:
             self.state = StatePool(cfg, state_slots, dtype)
         # independent buffers (no copy traffic between K and V updates)
         self.k_arena = self._zeros(shape, self.kv_dtype)
-        self.v_arena = self._zeros(shape, self.kv_dtype)
+        self.v_arena = None if self.latent else self._zeros(shape, self.kv_dtype)
         if self.quantized_kv:
             self.k_scale = self._zeros(self._scale_shape, jnp.float32)
             self.v_scale = self._zeros(self._scale_shape, jnp.float32)
@@ -345,8 +352,23 @@ class PagedKVPool:
             "last": tl[-1] if tl else None,
             "peak_leased": max((s[2] for s in tl), default=0),
             "occupancy_frac": self.utilization(),
+            **self.kind_snapshot(),
             **({"state": self.state.snapshot()} if self.state is not None else {}),
         }
+
+    def kind_snapshot(self) -> dict:
+        """What a block's row is (``kv``: keys and values a head; ``latent``:
+        one latent a token, all heads) and a token's bytes over all layers:
+        as counted from the model's widths, and as the arena's rows hold them
+        (a latent row is padded to whole lane tiles)."""
+        cfg = self.cfg
+        item = jnp.dtype(self.kv_dtype).itemsize
+        if self.latent:
+            counted = cfg.n_layer * cfg.latent_width * item
+        else:
+            counted = 2 * len(cfg.kv_layers) * cfg.n_query_groups * cfg.head_size * item
+        return {"kind": "latent" if self.latent else "kv", "token_bytes_counted": counted,
+                "token_bytes_laid_out": self.block_bytes() // self.block_size}
 
     def state_snapshot(self) -> dict:
         """Allocator state for the flight recorder: occupancy plus the
@@ -364,6 +386,7 @@ class PagedKVPool:
             "lease_refs": int(counts.sum()),
             "kv_dtype": str(self.kv_dtype),
             "arena_bytes": self.arena_bytes(),
+            **self.kind_snapshot(),
             "occupancy_timeline": [list(s) for s in self._occ_ring],
             **({"state": self.state.snapshot()} if self.state is not None else {}),
         }
@@ -388,7 +411,7 @@ class PagedKVPool:
         """Bytes one block costs across all arenas (K+V data, plus the
         scale arenas on the quantized path) — the unit of byte-based
         admission/capacity accounting."""
-        total = int(self.k_arena.nbytes) + int(self.v_arena.nbytes)
+        total = int(self.k_arena.nbytes) + (0 if self.latent else int(self.v_arena.nbytes))
         if self.quantized_kv:
             total += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
         return total // self.num_blocks
@@ -407,7 +430,10 @@ class PagedKVPool:
     @property
     def arenas(self) -> dict:
         """The arena pytree a bucket program takes (and returns donated):
-        ``{"k", "v"}`` plus ``{"k_scale", "v_scale"}`` on the int8 path."""
+        ``{"k", "v"}`` plus ``{"k_scale", "v_scale"}`` on the int8 path;
+        ``{"latent"}`` alone for a latent-attention model."""
+        if self.latent:
+            return {"latent": self.k_arena}
         out = {"k": self.k_arena, "v": self.v_arena}
         if self.quantized_kv:
             out["k_scale"] = self.k_scale
@@ -462,6 +488,9 @@ class PagedKVPool:
                               *(self.state.arenas.values() if self.state is not None else ())))
         if self.state is not None:
             self.state.state, self.state.conv = arenas["state"], arenas["conv"]
+        if self.latent:
+            self.k_arena = arenas["latent"]
+            return
         self.k_arena = arenas["k"]
         self.v_arena = arenas["v"]
         if self.quantized_kv:
@@ -492,7 +521,7 @@ class PagedKVPool:
         new buffers come up with the same shard-local placement."""
         self._retired.clear()
         self.k_arena = self._zeros(self._arena_shape, self.kv_dtype)
-        self.v_arena = self._zeros(self._arena_shape, self.kv_dtype)
+        self.v_arena = None if self.latent else self._zeros(self._arena_shape, self.kv_dtype)
         if self.quantized_kv:
             self.k_scale = self._zeros(self._scale_shape, jnp.float32)
             self.v_scale = self._zeros(self._scale_shape, jnp.float32)
@@ -662,19 +691,21 @@ def scatter_state(arenas, cache, slots):
             for name in ("conv", "state")}
 
 
+def gather_rows(arena, tables):
+    """One arena's blocks as a dense cache: ``tables`` (B, nb) int32
+    physical-block ids (sink-padded) to (L, B, ng, nb*bs, hs), the
+    :func:`cache_shape` layout ``forward_with_cache`` consumes (a latent
+    arena: ng 1, hs the row's width).  Pure jnp; call inside jit."""
+    g = jnp.take(arena, tables, axis=0)        # (B, nb, L, ng, bs, hs)
+    g = g.transpose(2, 0, 3, 1, 4, 5)          # (L, B, ng, nb, bs, hs)
+    L, B, ng, nb, bs, hs = g.shape
+    return g.reshape(L, B, ng, nb * bs, hs)
+
+
 def gather_dense(k_arena, v_arena, tables):
-    """Reassembles dense caches from block tables.
-
-    ``tables``: (B, nb) int32 physical-block ids (sink-padded).  Returns
-    ``k, v`` of shape (L, B, ng, nb*bs, hs) — the :func:`cache_shape` layout
-    ``forward_with_cache`` consumes.  Pure jnp; call inside jit."""
-    def one(arena):
-        g = jnp.take(arena, tables, axis=0)        # (B, nb, L, ng, bs, hs)
-        g = g.transpose(2, 0, 3, 1, 4, 5)          # (L, B, ng, nb, bs, hs)
-        L, B, ng, nb, bs, hs = g.shape
-        return g.reshape(L, B, ng, nb * bs, hs)
-
-    return one(k_arena), one(v_arena)
+    """Reassembles the dense K and V caches from block tables
+    (:func:`gather_rows` of each)."""
+    return gather_rows(k_arena, tables), gather_rows(v_arena, tables)
 
 
 def dest_for_pos(tables, pos, live, *, block_size):

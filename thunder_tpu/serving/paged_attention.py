@@ -39,6 +39,7 @@ from jax.sharding import PartitionSpec as P
 from thunder_tpu.executors.pallasex import (
     gdn_decode_step,
     lora_delta_fused,
+    mla_paged_decode,
     paged_attn_decode,
     paged_attn_verify,
     paged_chunk_write,
@@ -49,6 +50,10 @@ from thunder_tpu.executors.pallasex import (
 )
 from thunder_tpu.models.generate import (
     gdn_mixer,
+    mla_absorb,
+    mla_mixer,
+    mla_unabsorb,
+    pad_lanes,
     _close_block,
     _linear,
     _lora_delta,
@@ -190,6 +195,31 @@ def _gdn_paged(gp, x, arenas, sslots, pos, cfg, *, layer, n_real, lin):
     return y, held["state"], arenas["conv"].at[sslots, layer].set(new_tail)
 
 
+def _mla_paged(ap, x, arena, tables, pos, cos_t, sin_t, cfg, *, layer, cdtype, lin):
+    """A latent-attention layer of :func:`forward_paged`, one token a row:
+    ``generate.mla_mixer`` (the one mixer; the dense cache calls it too) with
+    the rows where the server keeps them.  The absorbed queries attend the
+    row's blocks of the latent arena through ``mla_paged_decode`` (every head
+    reads the same rows, fetched once) and the heads' weighted latents go
+    through ``W_v``.  Returns ``(y, this step's rows (B, W))`` at the cache
+    compute dtype, padded as the arena's rows are."""
+    if x.shape[1] != 1:
+        raise NotImplementedError(
+            "a latent cache is attended one token a row (mla_paged_decode); a piece of a prompt or a "
+            "draft's verify has no multi-query latent kernel and goes through the dense form")
+    W = arena.shape[-1]
+    box = []
+
+    def attend(q_nope, q_rope, latent):
+        row = pad_lanes(latent[:, 0], W).astype(cdtype)
+        box.append(row)
+        ot = mla_paged_decode(mla_absorb(ap, q_nope, q_rope, cfg, W)[:, :, 0], arena, row, tables, pos,
+                              layer=layer, dc=cfg.kv_lora_rank, scale=cfg.attn_scale)
+        return mla_unabsorb(ap, ot[:, :, None], cfg)
+
+    return mla_mixer(ap, x, cos_t, sin_t, cfg, attend, lin=lin), box[0]
+
+
 def with_state(arenas, fresh):
     """The arenas a paged program returns: K and V as the writers left them,
     the state and conv arenas as :func:`forward_paged` left them."""
@@ -240,7 +270,7 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
 
     lin = partial(_linear, quantized=quantized)
     delta_fn = lora_delta_fused if (lora_fused and mesh is None) else _lora_delta
-    fresh_k, fresh_v = [], []
+    fresh_k, fresh_v, fresh_rows = [], [], []
     for l, bp in enumerate(params["blocks"]):
         n1 = x if cfg.post_sublayer_norm else _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
         lora_l = None
@@ -251,6 +281,10 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                 bp["gdn"], n1, {"state": state_arena, "conv": conv_arena}, sslots, pos, cfg,
                 layer=n_lin, n_real=n_real, lin=lin)
             n_lin += 1
+        elif cfg.latent:
+            h, row = _mla_paged(bp["attn"], n1, arenas["latent"], tables, pos, cos_t, sin_t, cfg,
+                                layer=l, cdtype=cdtype, lin=lin)
+            fresh_rows.append(row)
         else:
             q, k, v = _project_qkv(bp["attn"], n1, cos_t, sin_t, cfg, lin=lin,
                                    lora=lora_l, lora_scaling=lora_scaling,
@@ -281,16 +315,19 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
     head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
     logits = (_linear(x, head, params.get("lm_head_b"), quantized=quantized)).astype(jnp.float32)
+    if cfg.latent:          # (B, L, 1, W): the token writer's layout, one group
+        return logits, {"latent": jnp.stack(fresh_rows, axis=1)[:, :, None]}
     fresh = {"k": jnp.stack(fresh_k, axis=1), "v": jnp.stack(fresh_v, axis=1)}
     if state_arena is not None:
         fresh.update(state=state_arena, conv=conv_arena)
     return logits, fresh
 
 
-def _write(arena, vals, tables, pos, *, block_size, mesh, n_emit=None, offset=0):
+def _write(arena, vals, tables, pos, *, block_size, mesh, n_emit=None, offset=0,
+           name="paged_token_write"):
     if mesh is None:
         return paged_token_write(arena, vals, tables, pos, block_size=block_size,
-                                 n_emit=n_emit, offset=offset)
+                                 n_emit=n_emit, offset=offset, name=name)
     rank5 = arena.ndim == 5
     aspec = P(None, None, "tp", None, None) if rank5 else P(None, None, "tp", None)
     vspec = P(None, None, "tp", None) if rank5 else P(None, None, "tp")
@@ -347,7 +384,12 @@ def write_fresh_kv(arenas, fresh, tables, pos, *, block_size, kv_dtype=None,
     so the stored bytes stay bit-identical to the gather path's while no
     standalone quantize op appears in the program.  Returns the updated
     arenas dict (aliased buffers: no scatter primitive, untouched blocks
-    keep their bytes; padding rows land in sink block 0, never attended)."""
+    keep their bytes; padding rows land in sink block 0, never attended).
+    A latent arena takes its one row a layer the same way, under the kernel
+    name ``mla_latent_write``."""
+    if "latent" in arenas:
+        return {"latent": _write(arenas["latent"], fresh["latent"], tables, pos, block_size=block_size,
+                                 mesh=mesh, name="mla_latent_write")}
     if kv_dtype is None:
         w = partial(_write, tables=tables, pos=pos, block_size=block_size,
                     mesh=mesh)
@@ -377,6 +419,9 @@ def write_fresh_kv_live(arenas, fresh, tables, pos, live, *, block_size,
     take the same fused quantize-on-write epilogue as
     :func:`write_fresh_kv`."""
     n_emit = live.astype(jnp.int32)
+    if "latent" in arenas:
+        return {"latent": _write(arenas["latent"], fresh["latent"], tables, pos, n_emit=n_emit, offset=0,
+                                 block_size=block_size, mesh=mesh, name="mla_latent_write")}
     if kv_dtype is None:
         w = partial(_write, tables=tables, pos=pos, n_emit=n_emit,
                     offset=0, block_size=block_size, mesh=mesh)

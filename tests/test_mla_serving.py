@@ -7,7 +7,7 @@ and the engine (whole-prompt prefill, then decode through the paged latents,
 requests of different lengths together) against the reference's logits;
 absorbed against expanded attention; the YaRN tables and the group-limited
 router against closed forms; the decode kernel interpreted against its XLA
-form; the expert shares adding up to the uncut layer, the shared expert counted
+form (the walk's own cases are ``tests/test_mla_walk.py``); the expert shares adding up to the uncut layer, the shared expert counted
 once; what the engine holds of a request; what is refused, with its reason.
 """
 from __future__ import annotations

@@ -487,14 +487,33 @@ def test_a_whole_prompts_prefill_lowers_to_flash_and_a_head_of_one_row(cell, kin
 MLA_CELL = "axk1-serve-1chip.offline-longctx"
 
 
-@pytest.mark.parametrize("kernel", ["mla_paged_decode", "mla_latent_write", "mla_latent_write_masked"])
+def _mosaic_module(lowered_text: str) -> str:
+    """The one Mosaic kernel of a lowered program, as MLIR text (the custom
+    call carries it as bytecode)."""
+    import base64
+
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    (body,) = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text)
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    tpu.register_dialect(ctx)
+    with ctx:
+        return str(ir.Module.parse(base64.b64decode(body)))
+
+
+@pytest.mark.parametrize("kernel", ["mla_paged_decode", "mla_paged_decode/128heads", "mla_latent_write", "mla_latent_write_masked"])
 def test_the_latent_kernels_compile_at_the_cells_shapes(kernel, tpu_sharding, monkeypatch):
     """64 rows, a table 640 blocks wide, the cell's arena of 32768 blocks of 16
-    rows of 640 (576 padded to whole lane tiles), 64 heads: the walk's copies
-    are whole-tile slabs, the table fits the scalar memory, nothing of the arena
-    is copied."""
+    rows of 640 (576 padded to whole lane tiles), 64 heads (and 128, a whole pass
+    of the matrix unit): the walk's copies are whole-tile slabs, the table fits
+    the scalar memory, nothing of the arena is copied; the two chunk buffers are
+    what ``mla_chunk_keys`` derives and fit the budget it states; the rows are
+    held and the queries streamed, so the module transposes nothing."""
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
-    rows, width, pool, layers, nh, W, dc = 64, 640, 32768, 6, 64, 640, 512
+    kernel, _, heads = kernel.partition("/")
+    rows, width, pool, layers, nh, W, dc = 64, 640, 32768, 6, 128 if heads else 64, 640, 512
     arena, tab, pos = ((pool, layers, 1, BS, W), BF), ((rows, width), I32), ((rows,), I32)
     if kernel == "mla_paged_decode":
         fn = functools.partial(px.mla_paged_decode, layer=layers - 1, dc=dc, scale=0.13)
@@ -513,6 +532,12 @@ def test_the_latent_kernels_compile_at_the_cells_shapes(kernel, tpu_sharding, mo
     assert text.count("tpu_custom_call") == 1 and f'kernel_name = "{kernel}"' in text
     if kernel == "mla_paged_decode":
         assert px.stats["mla_decode"] == before + 1                     # claimed, not the XLA form
+        keys = px.mla_chunk_keys(BS, W, 2)
+        assert keys == 1024 and 2 * keys * W * 2 <= px._MLA_BUFFER_BYTES
+        module = _mosaic_module(text)
+        assert module.count(f"memref<{keys // BS}x{BS}x{W}xbf16, #tpu.memory_space<vmem>>") >= 2    # the two buffers
+        assert f"vector<{nh}x{W}xbf16>, vector<{keys}x{W}xbf16>, vector<{nh}x{keys}xf32>" in module   # q streamed, rows held
+        assert not re.search(r'[a-z_.]+\.transpose"?\(', module)
     if tpu_sharding is not None:
         compiled = lowered.compile()
         assert re.search(rf"%{kernel}(\.\d+)? = ", compiled.as_text())

@@ -140,7 +140,7 @@ class TestTools:
         for t in tools:
             py_compile.compile(str(t), doraise=True)
 
-    @pytest.mark.parametrize("tool", ["flash_tune.py", "gdn_tune.py"])
+    @pytest.mark.parametrize("tool", ["flash_tune.py", "gdn_tune.py", "mla_tune.py"])
     def test_kernel_timers_without_a_tpu_exit_nonzero_and_print_no_result(self, tool):
         proc = subprocess.run(
             [sys.executable, str(self.TOOLS / tool)],
@@ -149,6 +149,35 @@ class TestTools:
         )
         assert proc.returncode != 0
         assert proc.stdout.strip() == "" and "needs a TPU" in proc.stderr
+
+
+    def test_mla_tune_builds_the_cells_operands_and_checks_under_the_interpreter(self, monkeypatch):
+        """The tool's contexts are the mix's, its tables scatter a row's blocks
+        and sink-pad, and its ``--check`` (the interpreted kernel against
+        ``_mla_decode_xla`` here) passes at a small shape; taking a part out
+        leaves a kernel that still traces and restores the kernel afterwards."""
+        import numpy as np
+
+        from thunder_tpu.executors import pallasex as px
+        sys.path.insert(0, str(self.TOOLS.parent))
+        from tools import mla_tune
+
+        contexts = mla_tune.cell_contexts(64)
+        assert contexts.min() == 2624 and contexts.max() == 9480 and round(float(contexts.mean())) == 6064
+        shape = dict(rows=3, nh=4, W=256, dc=128, bs=16, layers=2, table=8, pool=24)
+        small = np.asarray([0, 37, 100], np.int32)
+        q, arena, fresh, tables, pos = mla_tune.operands(small, **shape)
+        assert arena.shape == (24, 2, 1, 16, 256) and q.shape == (3, 4, 256) and fresh.shape == (3, 256)
+        used = np.asarray(tables)[np.asarray(tables) > 0]
+        assert len(used) == len(set(used)) == 0 + 3 + 7 and not np.asarray(tables)[0].any() and list(pos) == [0, 37, 100]
+        monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr(px, "_MLA_CHUNK_KEYS", 32)
+        assert mla_tune.check(small, shape) < 0.02
+        hooks = (px._mla_dot, px._mla_start_chunk, px._mla_wait_chunk)
+        for part in ("products", "copies"):
+            with mla_tune.taken_out(part):
+                out = mla_tune.all_layers(2, 128)(q, arena, fresh, tables, pos)
+            assert out.shape == (3, 4, 128) and (px._mla_dot, px._mla_start_chunk, px._mla_wait_chunk) == hooks
 
 
 class TestSharpEdges:

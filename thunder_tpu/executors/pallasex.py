@@ -1482,61 +1482,163 @@ def paged_token_write(arena, vals, tables, pos, *, block_size, n_emit=None,
 # ---------------------------------------------------------------------------
 # ``mla_paged_decode``: one token's latent attention straight off the latent
 # arena, in the absorbed form.  A grid step is a request: its table's blocks
-# are copied out of the HBM arena once, a chunk of ``_MLA_CHUNK_KEYS`` rows at
-# a time into a double buffer, and every head scores the same rows (one
-# ``(nh, W) x (W, keys)`` product) and sums the same rows (``(nh, keys) x
-# (keys, dc)``): the arena's bytes are read once for all heads, where expanded
-# keys and values would be ``nh (dn + dr + dv) / (dc + dr)`` times as many.
-# Online softmax across chunks in float32; the fresh token's row (this step's,
-# not yet in the arena) is the last term, as in ``paged_attn_decode``.
+# are copied out of the HBM arena once, a chunk of ``mla_chunk_keys`` rows at a
+# time, and every head scores the same rows (one ``(nh, W) x (W, keys)``
+# product) and sums the same rows (``(nh, keys) x (keys, dc)``): the arena's
+# bytes are read once for all heads, where expanded keys and values would be
+# ``nh (dn + dr + dv) / (dc + dr)`` times as many.  Online softmax across chunks
+# in float32; the fresh token's row (this step's, not yet in the arena) is the
+# last term, as in ``paged_attn_decode``.
+#
+# What the chunk loop is built around (PR 35, measured with tools/mla_tune.py):
+# starting a block's copy costs the core about 20 ns whatever its size, a
+# chunk's copies cost as long as its products, and the core overlaps the two
+# only inside one basic block and only where it can see that the copies' target
+# is not what the products read.  So
+# - the two chunk buffers are two scratch arrays with static roles: the loop
+#   takes two chunks a turn, attending one buffer while the other's copies are
+#   started in the same straight-line code (no ``pl.when`` around them; a
+#   ``fori_loop`` that the lowering unrolls: ``C`` is static), and a slot's
+#   ``C`` copies are awaited once;
+# - the matrix unit holds the rows and streams the queries in both products.
+#   Holding the queries (64 fill half a 128-row pass) and streaming the rows
+#   measured slower at 64 heads, so there is one form for every head count;
+# - nothing is conditional in a turn: the copy started beside a row's last
+#   chunk is the first chunk of the next row that has one (``chain``, from
+#   ``pos`` in the wrapper), so a request finds its first chunk in flight,
+#   started by its predecessor; grid step 0 starts the first such row's.  The
+#   buffer a row begins in (the parity of all chunks before it) rides in SMEM
+#   scratch and picks one of two instances of the walk; the grid is
+#   ``"arbitrary"``: it is walked in order.  The last row with a cached token
+#   has no successor: it fetches its own last chunk again and waits for it.
+# A row's sums depend on the row alone: which rows its neighbours are changes
+# what is prefetched and when, never a chunk's boundaries nor their order.
+# Straight-line code is long (448 copy starts, twelve products): the layer is
+# an operand and a program's layers share one traced body (``_mla_decode_call``).
 # ---------------------------------------------------------------------------
 
-_MLA_CHUNK_KEYS = 512
+_MLA_CHUNK_KEYS = 1024
+_MLA_BUFFER_BYTES = 4 * 1024 * 1024   # both chunk buffers
 
 
-def _mla_decode_kernel(tab_ref, pos_ref, q_ref, arena, f_ref, o_ref, buf, sem, *, layer, bs, C, dc, scale):
+def mla_chunk_keys(bs: int, W: int, itemsize: int) -> int:
+    """Keys a step of :func:`mla_paged_decode`'s walk attends, from what the
+    call sees: ``_MLA_CHUNK_KEYS`` (512 and 2,048 measured slower at 16, 64 and
+    128 heads), fewer where two buffers of as many rows of ``W`` would pass
+    ``_MLA_BUFFER_BYTES``, whole blocks of ``bs`` and one at least.  Not from the
+    table's width nor the batch: a row's chunk boundaries depend on the row alone."""
+    keys = min(_MLA_CHUNK_KEYS, _MLA_BUFFER_BYTES // (2 * W * itemsize))
+    return max(1, keys // bs) * bs
+
+
+def _mla_dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _mla_start_chunk(tab_ref, arena, buf, sem, r, c, hi, *, layer, C):
+    """Start the ``C`` block copies of row ``r``'s chunk ``c`` into ``buf``, as
+    straight-line code (a loop unrolled where the kernel is lowered: traced
+    once).  The tail of a row's last chunk fetches block ``hi - 1`` again: real
+    rows, masked as future ones."""
+    def one(t, _):
+        blk = tab_ref[r, jnp.minimum(c * C + t, hi - 1)]
+        pltpu.make_async_copy(arena.at[blk, layer, 0], buf.at[t], sem).start()
+    jax.lax.fori_loop(0, C, one, None, unroll=True)
+
+
+def _mla_wait_chunk(buf, sem, *, C):
+    """Wait once for the bytes of the ``C`` copies started into ``buf``."""
+    pltpu.make_async_copy(buf.at[pl.ds(0, C)], buf.at[pl.ds(0, C)], sem).wait()
+
+
+def _mla_decode_kernel(tab_ref, pos_ref, chain_ref, layer_ref, q_ref, arena, f_ref, o_ref, buf0, buf1, sem, par, *,
+                       bs, C, dc, scale, parts):
+    dot, start_chunk, wait_chunk = parts
+    layer = layer_ref[0]
     i = pl.program_id(0)
+    B = pl.num_programs(0)
     p_i = pos_ref[i]
     q = q_ref[0]                                           # (nh, W)
     nh, W = q.shape
-    hi = (p_i + bs - 1) // bs                              # table entries with a slot before pos
-    n_chunks = (hi + C - 1) // C
+    bufs = (buf0, buf1)
 
-    def copies(c, slot, act):
-        # the tail of the last chunk fetches block hi - 1 again: real rows, masked as future ones
-        def one(t, _):
-            blk = tab_ref[i, jnp.minimum(c * C + t, hi - 1)]
-            getattr(pltpu.make_async_copy(arena.at[blk, layer, 0], buf.at[slot, t], sem.at[slot]), act)()
-        jax.lax.fori_loop(0, C, one, None)
+    def blocks(r):
+        return (pos_ref[r] + bs - 1) // bs                 # table entries with a slot before pos
 
-    @pl.when(n_chunks > 0)
+    def start(r, c, hi_r, k):
+        start_chunk(tab_ref, arena, bufs[k], sem.at[k], r, c, hi_r, layer=layer, C=C)
+
+    def wait(k):
+        wait_chunk(buf0, sem.at[k], C=C)
+
+    hi = blocks(i)
+    n = (hi + C - 1) // C
+    nxt = chain_ref[i]
+    last = nxt == B
+    r_n = jnp.minimum(nxt, B - 1)
+    hi_n = blocks(r_n)
+
+    @pl.when(i == 0)
     def _first():
-        copies(0, 0, "start")
+        par[0] = 0
+        first = chain_ref[B]
 
-    def chunk(c, carry):
+        @pl.when(first < B)
+        def _():
+            start(first, 0, blocks(first), 0)
+
+    def ahead(c, k):
+        # into buffer k: this row's chunk c if it has one, else the first chunk of
+        # the next row that has any; the last such row of the grid fetches its own
+        # last chunk again (waited for below, never attended)
+        own = jnp.logical_or(c < n, last)
+        chunk = jnp.where(c < n, c, jnp.where(last, n - 1, 0))
+        start(jnp.where(own, i, r_n), chunk, jnp.where(own, hi, hi_n), k)
+
+    def attend(c, k, carry):
         m_prev, l_prev, acc = carry
-        slot = c % 2
-
-        @pl.when(c + 1 < n_chunks)
-        def _next():
-            copies(c + 1, 1 - slot, "start")
-
-        copies(c, slot, "wait")
-        rows = buf[slot].reshape(C * bs, W).astype(q.dtype)
-        s = jax.lax.dot_general(q, rows, _NT, preferred_element_type=jnp.float32) * scale   # (nh, C * bs)
+        rows = bufs[k][...].reshape(C * bs, W).astype(q.dtype)
+        s = dot(q, rows, _NT) * scale                      # (nh, C * bs)
         posn = c * C * bs + jax.lax.broadcasted_iota(jnp.int32, (1, C * bs), 1)
         s = jnp.where(posn < p_i, s, _MASK_VALUE)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * corr + jax.lax.dot_general(p.astype(q.dtype), rows[:, :dc], _NN,
-                                               preferred_element_type=jnp.float32)
+        acc = acc * corr + dot(p.astype(q.dtype), rows[:, :dc], _NN)
         return m_new, l_new, acc
 
-    m_prev, l_prev, acc = jax.lax.fori_loop(0, n_chunks, chunk, (
-        jnp.full((nh, 1), _MASK_VALUE, jnp.float32), jnp.zeros((nh, 1), jnp.float32),
-        jnp.zeros((nh, dc), jnp.float32)))
+    def walk(a, b):
+        def pair(j, carry):
+            c = 2 * j
+            ahead(c + 1, b)
+            wait(a)
+            carry = attend(c, a, carry)
+            ahead(c + 2, a)
+            wait(b)
+            return attend(c + 1, b, carry)
+
+        def odd(carry):
+            ahead(n, b)
+            wait(a)
+            return attend(n - 1, a, carry)
+
+        carry = jax.lax.fori_loop(0, n // 2, pair, (
+            jnp.full((nh, 1), _MASK_VALUE, jnp.float32), jnp.zeros((nh, 1), jnp.float32),
+            jnp.zeros((nh, dc), jnp.float32)))
+        return jax.lax.cond(n % 2 == 1, odd, lambda carry: carry, carry)
+
+    base = par[0]
+    m_prev, l_prev, acc = jax.lax.cond(base == 0, lambda: walk(0, 1), lambda: walk(1, 0))
+
+    @pl.when(n > 0)
+    def _handed_over():
+        spare = (base + n) % 2
+        par[0] = spare
+
+        @pl.when(last)
+        def _():
+            wait(spare)
     # the fresh row is one key: multiply-and-sum in float32 of operands rounded
     # to q's dtype, as a matmul's would be
     f = f_ref[0].astype(q.dtype).astype(jnp.float32)       # (1, W)
@@ -1574,31 +1676,56 @@ def mla_paged_decode(q, arena, fresh, tables, pos, *, layer: int, dc: int, scale
     ``tables (B, nbb)`` int32 sink-padded, ``pos (B,)`` int32.  Returns the
     heads' weighted latents ``(B, nh, dc)`` at ``q``'s dtype (the caller maps
     them to values with ``W_v``).  Without Pallas (a CPU that did not opt into
-    the interpreter) the XLA form runs."""
+    the interpreter) the XLA form runs.
+
+    The walk (the comment block above ``_MLA_CHUNK_KEYS``): the rows are held in
+    the matrix unit and the queries streamed, at any head count; a chunk is
+    ``C = mla_chunk_keys(bs, W, itemsize) // bs`` table entries, from the
+    call's own shapes (1,024 keys at A.X-K1's: two buffers of 1.3 MB); a row's
+    first chunk is its predecessor's to start, through ``chain``: ``chain[i]`` is
+    the next row after ``i`` with a cached token (``B``: none) and ``chain[B]``
+    the first such row."""
     B, nh, W = q.shape
     bs = arena.shape[3]
     assert arena.shape[2] == 1 and arena.shape[4] == W and W % 128 == 0 and dc % 128 == 0, (arena.shape, W, dc)
     if not _pallas_available():
         return _mla_decode_xla(q, arena, fresh, tables, pos, layer=layer, dc=dc, scale=scale)
     stats["mla_decode"] = stats.get("mla_decode", 0) + 1
-    C = max(1, _MLA_CHUNK_KEYS // bs)
+    return _mla_decode_call(
+        tables, pos, jnp.full((1,), layer, jnp.int32), q, arena, fresh[:, None, :],
+        C=mla_chunk_keys(bs, W, arena.dtype.itemsize) // bs, dc=dc, scale=float(scale), interpret=_interpret(),
+        parts=(_mla_dot, _mla_start_chunk, _mla_wait_chunk))
+
+
+@functools.partial(jax.jit, static_argnames=("C", "dc", "scale", "interpret", "parts"))
+def _mla_decode_call(tables, pos, layer, q, arena, fresh, *, C, dc, scale, interpret, parts):
+    """The kernel's call, one traced and lowered body for every layer of a
+    program (the layer is an operand): its straight-line copies make it some
+    thousand operations long.  Everything the trace reads besides the operands
+    is a static argument: the chunk, the interpreter, the loop's three parts
+    (``tools/mla_tune.py`` takes one out)."""
+    B, nh, W = q.shape
+    bs = arena.shape[3]
+    after = jax.lax.cummin(jnp.where(pos > 0, jnp.arange(B, dtype=jnp.int32), B), reverse=True)
+    chain = jnp.concatenate([after[1:], jnp.full((1,), B, jnp.int32), after[:1]])
     kwargs = {}
-    if not _interpret():
-        kwargs["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("parallel",))
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     return pl.pallas_call(
-        functools.partial(_mla_decode_kernel, layer=layer, bs=bs, C=C, dc=dc, scale=float(scale)),
+        functools.partial(_mla_decode_kernel, bs=bs, C=C, dc=dc, scale=scale, parts=parts),
         name="mla_paged_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B,),
-            in_specs=[pl.BlockSpec((1, nh, W), lambda i, tab, p: (i, 0, 0)),
+            num_scalar_prefetch=4, grid=(B,),
+            in_specs=[pl.BlockSpec((1, nh, W), lambda i, *_: (i, 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec((1, 1, W), lambda i, tab, p: (i, 0, 0))],
-            out_specs=pl.BlockSpec((1, nh, dc), lambda i, tab, p: (i, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((2, C, bs, W), arena.dtype), pltpu.SemaphoreType.DMA((2,))]),
+                      pl.BlockSpec((1, 1, W), lambda i, *_: (i, 0, 0))],
+            out_specs=pl.BlockSpec((1, nh, dc), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((C, bs, W), arena.dtype), pltpu.VMEM((C, bs, W), arena.dtype),
+                            pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((B, nh, dc), q.dtype),
-        interpret=_interpret(),
+        interpret=interpret,
         **kwargs,
-    )(tables, pos, q, arena, fresh[:, None, :])
+    )(tables, pos, chain, layer, q, arena, fresh)
 
 
 def _paged_verify_kernel(tab_ref, pos_ref, nb_ref, q_ref, k_ref, v_ref, *rest,

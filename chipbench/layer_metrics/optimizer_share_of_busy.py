@@ -1,0 +1,10 @@
+"""Layer: training step.  Source: device_trace: the share of the operations' seconds
+spent under the scope group `optimizer` (`optimizer.update`, the parameters' update,
+a gradient accumulator), read from each instruction's `op_name`
+(`chipbench/op_scopes.py`; denominator as `mixer_share_of_busy`).  One reader for every
+`optimizer_share_of_busy.<split>`.  `None` where the program writes no scopes."""
+
+
+def read(ctx):
+    from chipbench import op_scopes
+    return op_scopes.share(ctx, 'optimizer')

@@ -64,7 +64,7 @@ from thunder_tpu import observability  # noqa: F401  (metrics/events/profiler)
 from thunder_tpu.observability import reset_observability
 from thunder_tpu.observability.debug import AnomalyError
 from thunder_tpu.executors.donation import DonationError
-from thunder_tpu.observability.events import span as _phase_span
+from thunder_tpu.observability.events import scope, span as _phase_span
 
 __version__ = "0.1.0"
 
@@ -96,6 +96,7 @@ __all__ = [
     "serve",
     "export_chrome_trace",
     "flight_record",
+    "scope",
     "observability",
     "reset_observability",
     "AnomalyError",

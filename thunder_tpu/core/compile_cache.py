@@ -27,6 +27,7 @@ compilation on a cold cache, a load on a warm one) from its duration events
 from __future__ import annotations
 
 import os
+import re
 import threading
 
 __all__ = ["enable", "ensure_enabled", "stats", "cache_dir"]
@@ -41,9 +42,12 @@ _DURATIONS = {"/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace_s",
               "/jax/core/compile/backend_compile_duration": "backend_compile_s"}
 
 
+def _checkout_root() -> str:
+    return os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
 def _default_dir() -> str:
-    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    return os.path.join(root, ".jax_cache")
+    return os.path.join(_checkout_root(), ".jax_cache")
 
 
 def _on_event(name: str, **kwargs) -> None:
@@ -91,6 +95,17 @@ def enable() -> str:
             # the cache is in use; by now params have usually been built, so
             # make it decide again
             jax_cc.reset_cache()
+        # A profile reads an operation's scope (``observability.scope``) and
+        # source line from the executable's own metadata, and JAX leaves
+        # metadata out of the cache key by default: a program that differs from
+        # a cached one in its scopes alone would load the older executable and
+        # show the older names.  So metadata is part of the key, with source
+        # paths cut to the checkout's root so that a checkout elsewhere still
+        # finds the entries (a line that moves in a traced frame does miss).
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+        if not jax.config.jax_hlo_source_file_canonicalization_regex:
+            jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                              "^" + re.escape(_checkout_root() + os.sep))
         # persist everything: device programs all cross any threshold, and the
         # small eager ops around them are cheap to store
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

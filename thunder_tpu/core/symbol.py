@@ -30,6 +30,7 @@ __all__ = [
     "gather_tags",
     "gather_provenance",
     "provenance_inherited",
+    "scope_path",
 ]
 
 
@@ -71,22 +72,32 @@ def _is_machinery_file(fname: str) -> bool:
     return hit
 
 
-def _capture_provenance() -> tuple[str | None, int | None]:
-    """(filename, lineno) of the nearest user frame, or (None, None)."""
+def _capture_provenance() -> tuple[str | None, int | None, str | None]:
+    """(filename, lineno, function name) of the nearest user frame within 64
+    frames, or (None, None, None)."""
     f = sys._getframe(2)
     depth = 0
     while f is not None and depth < 64:
-        if not _is_machinery_file(f.f_code.co_filename):
-            return f.f_code.co_filename, f.f_lineno
+        code = f.f_code
+        if not _is_machinery_file(code.co_filename):
+            return code.co_filename, f.f_lineno, code.co_name
         f = f.f_back
         depth += 1
-    return None, None
+    return None, None, None
 
+
+# The device scope a bound symbol is recorded under: the "/"-joined names of
+# the ``observability.scope`` contexts open at trace time ("blk0/mixer/qkv").
+# ``Symbol.__call__`` stamps it on the bound symbol beside the provenance; it
+# travels by the same route (from_bsym, provenance_inherited) to the lowering
+# (executors/utils.py ``lower_bsyms``), which runs the symbol's JAX operations
+# under ``jax.named_scope`` of it, so the device's profile names its work.
+scope_path: ContextVar[str] = ContextVar("scope_path", default="")
 
 # rewriting passes that re-trace on behalf of an existing bsym (executor
 # execution_transforms, backward-rule expansion) set this so the freshly
-# recorded bsyms inherit the original's provenance instead of walking a
-# stack made entirely of framework frames
+# recorded bsyms inherit the original's provenance and scope instead of
+# walking a stack made entirely of framework frames
 _provenance_override: ContextVar[tuple | None] = ContextVar(
     "provenance_override", default=None
 )
@@ -94,8 +105,9 @@ _provenance_override: ContextVar[tuple | None] = ContextVar(
 
 @contextmanager
 def provenance_inherited(bsym: "BoundSymbol"):
-    """Bound symbols recorded inside inherit ``bsym``'s source provenance."""
-    token = _provenance_override.set((bsym.source_filename, bsym.source_positions))
+    """Bound symbols recorded inside inherit ``bsym``'s source provenance
+    and its scope."""
+    token = _provenance_override.set((bsym.source_filename, bsym.source_positions, bsym.scope))
     try:
         yield
     finally:
@@ -282,9 +294,11 @@ class Symbol(SymbolInterface):
         bsym = self.bind(*args, output=result, subsymbols=subsymbols, **kwargs)
         override = _provenance_override.get()
         if override is not None:
-            bsym.source_filename, bsym.source_positions = override
+            bsym.source_filename, bsym.source_positions, bsym.scope = override
         else:
-            bsym.source_filename, bsym.source_positions = _capture_provenance()
+            bsym.source_filename, bsym.source_positions, fn_name = _capture_provenance()
+            # under no scope, the user function's name says more than nothing
+            bsym.scope = scope_path.get() or fn_name
         trace.record(bsym)
         return result
 
@@ -304,6 +318,7 @@ class BoundSymbol(BoundSymbolInterface):
         header: str | None = None,
         source_filename: str | None = None,
         source_positions: Any = None,
+        scope: str | None = None,
     ):
         self.sym = sym
         self.args = args
@@ -314,6 +329,8 @@ class BoundSymbol(BoundSymbolInterface):
         self.header = header
         self.source_filename = source_filename
         self.source_positions = source_positions
+        #: device scope ("blk0/mixer/qkv"; see ``scope_path``), None if unknown
+        self.scope = scope
         self._out_printables = None
 
     #
@@ -371,6 +388,7 @@ class BoundSymbol(BoundSymbolInterface):
             header=kwargs.get("header", self.header),
             source_filename=kwargs.get("source_filename", self.source_filename),
             source_positions=kwargs.get("source_positions", self.source_positions),
+            scope=kwargs.get("scope", self.scope),
         )
         return new
 

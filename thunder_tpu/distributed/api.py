@@ -37,7 +37,7 @@ from thunder_tpu.distributed.sharding import (
     llama_shardings,
     _prune_spec,
 )
-from thunder_tpu.observability.events import span
+from thunder_tpu.observability.events import scope, span
 
 __all__ = ["ddp", "fsdp", "tp_fsdp", "TrainStep", "make_train_step", "combine_threshold_options"]
 
@@ -160,9 +160,14 @@ def default_batch_shardings(mesh: Mesh, batch: Sequence) -> tuple[NamedSharding,
 
 
 def _trace_to_jax_fn(trace) -> Callable:
-    """A pure-JAX callable evaluating ``trace`` (inputs = trace.args order)."""
+    """A pure-JAX callable evaluating ``trace`` (inputs = trace.args order),
+    for use under ``jax.jit``: every symbol lowers under its scope, a
+    backward trace's after ``bwd``."""
     from thunder_tpu.core.prims import PrimIDs
-    from thunder_tpu.executors.utils import eval_bsyms, resolve_args
+    from thunder_tpu.core.trace import TraceTag
+    from thunder_tpu.executors.utils import lower_bsyms, resolve_args
+
+    backward = TraceTag.BACKWARD in trace.tags
 
     input_names = [p.name for p in trace.args]
     ret_bsym = None
@@ -174,7 +179,7 @@ def _trace_to_jax_fn(trace) -> Callable:
     def fn(*vals):
         assert len(vals) == len(input_names), f"expected {len(input_names)} inputs, got {len(vals)}"
         env = dict(zip(input_names, vals))
-        eval_bsyms(trace.bound_symbols, env)
+        lower_bsyms(trace.bound_symbols, env, backward=backward)
         args, _ = resolve_args(env, ret_bsym.args, {})
         return args[0] if len(args) == 1 else args
 
@@ -501,8 +506,9 @@ class TrainStep:
         import optax
 
         def apply_gradients(params, opt_state, grads):
-            updates, new_opt_state = self.optimizer.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), new_opt_state
+            with scope("optimizer"):
+                updates, new_opt_state = self.optimizer.update(grads, opt_state, params)
+                return optax.apply_updates(params, updates), new_opt_state
 
         # shardings: params/opt from their current placement; batch from specs
         param_sh = jax.tree_util.tree_map(lambda x: x.sharding, params)
@@ -565,17 +571,19 @@ class TrainStep:
                     it = iter(mbs)
                     args = tuple(next(it) if m else b for b, m in zip(split, accum_mask))
                     loss, grads = grad_fn(params, *args)
-                    acc = jax.tree_util.tree_map(
-                        lambda a, g: a + g.astype(jnp.float32), acc, grads
-                    )
+                    with scope("optimizer/accum"):
+                        acc = jax.tree_util.tree_map(
+                            lambda a, g: a + g.astype(jnp.float32), acc, grads
+                        )
                     return (acc, loss_sum + loss.astype(jnp.float32)), None
 
                 (acc, loss_sum), _ = jax.lax.scan(
                     body, (acc0, jnp.zeros((), jnp.float32)), scanned
                 )
-                grads = jax.tree_util.tree_map(
-                    lambda a, p: (a / k).astype(jnp.asarray(p).dtype), acc, params
-                )
+                with scope("optimizer/accum"):
+                    grads = jax.tree_util.tree_map(
+                        lambda a, p: (a / k).astype(jnp.asarray(p).dtype), acc, params
+                    )
                 loss = loss_sum / k  # mean of microbatch means == batch mean
                 grads = jax.lax.with_sharding_constraint(grads, param_sh)
                 new_params, new_opt_state = apply_gradients(params, opt_state, grads)

@@ -10,12 +10,14 @@ from __future__ import annotations
 from numbers import Number
 from typing import Any, Callable, Sequence
 
+import jax
+
 from thunder_tpu.core.proxies import AnyProxy, NumberProxy, Proxy, StringProxy, TensorProxy, variableify
 from thunder_tpu.core.pytree import tree_flatten, tree_unflatten
 from thunder_tpu.core.symbol import BoundSymbol
 from thunder_tpu.core.utils import OrderedSet, consumers, producers
 
-__all__ = ["Region", "eval_bsyms", "resolve_impl", "resolve_args", "trace_return_names"]
+__all__ = ["Region", "eval_bsyms", "lower_bsyms", "resolve_impl", "resolve_args", "trace_return_names"]
 
 
 def trace_return_names(trace) -> set[str]:
@@ -125,7 +127,7 @@ def bind_outputs(env: dict[str, Any], output, result) -> None:
         ri += 1
 
 
-def eval_bsyms(bsyms: Sequence[BoundSymbol], env: dict[str, Any]) -> None:
+def eval_bsyms(bsyms: Sequence[BoundSymbol], env: dict[str, Any], _prefix: str | None = None) -> None:
     """Executes bound symbols over concrete values, updating ``env`` in place.
 
     Composites without a concrete implementation are evaluated through their
@@ -139,9 +141,27 @@ def eval_bsyms(bsyms: Sequence[BoundSymbol], env: dict[str, Any]) -> None:
         fn = resolve_impl(bsym)
         if fn is None:
             if bsym.subsymbols:
-                eval_bsyms(bsym.subsymbols, env)
+                eval_bsyms(bsym.subsymbols, env, _prefix)
                 continue
             raise RuntimeError(f"No implementation found for {bsym.sym.name} ({bsym.sym.id})")
         args, kwargs = resolve_args(env, bsym.args, bsym.kwargs)
-        result = fn(*args, **kwargs)
+        if _prefix is None:
+            result = fn(*args, **kwargs)
+        else:
+            # a symbol with neither scope nor provenance shows by name in the
+            # profile's unscoped list instead of vanishing into ``jit(...)``
+            with jax.named_scope(_prefix + (bsym.scope or f"unscoped/{bsym.sym.name}")):
+                result = fn(*args, **kwargs)
         bind_outputs(env, bsym.output, result)
+
+
+def lower_bsyms(bsyms: Sequence[BoundSymbol], env: dict[str, Any], *, backward: bool = False) -> None:
+    """``eval_bsyms`` for the callers that run under ``jax.jit`` (an XLA
+    region, a train step's forward and backward trace): each symbol's JAX
+    operations are traced under ``jax.named_scope`` of the scope it was
+    recorded in (``BoundSymbol.scope``), after ``bwd`` for a backward trace,
+    recomputation the remat pass placed there included.  The path becomes the
+    ``op_name`` of the HLO instructions, so the device's profile says which
+    part of the model an operation belongs to.  Metadata only: the compiled
+    code does not change."""
+    eval_bsyms(bsyms, env, "bwd/" if backward else "")

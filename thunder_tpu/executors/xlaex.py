@@ -21,10 +21,10 @@ from thunder_tpu.core.compile_data import get_compile_option
 from thunder_tpu.core.prims import OpTags, PrimIDs
 from thunder_tpu.core.proxies import Proxy, TensorProxy, unvariableify
 from thunder_tpu.core.symbol import BoundSymbol, Symbol
-from thunder_tpu.core.trace import TraceCtx, TraceProvenance, from_trace
+from thunder_tpu.core.trace import TraceCtx, TraceProvenance, TraceTag, from_trace
 from thunder_tpu.core.utils import consumers, producers
 from thunder_tpu.extend import FusionExecutor, add_default_executor, register_executor
-from thunder_tpu.executors.utils import Region, eval_bsyms
+from thunder_tpu.executors.utils import Region, lower_bsyms
 from thunder_tpu.observability.events import span as _phase_span
 
 __all__ = ["XLAFusionExecutor", "ex", "xla_ex"]
@@ -44,9 +44,12 @@ _NONFUSIBLE_IDS = {
 class FusionCallable:
     """A compiled region; keeps the sub-trace for inspection and re-lowering."""
 
-    def __init__(self, name: str, bsyms: Sequence[BoundSymbol], inputs: Sequence[Proxy], outputs: Sequence[Proxy]):
+    def __init__(self, name: str, bsyms: Sequence[BoundSymbol], inputs: Sequence[Proxy], outputs: Sequence[Proxy],
+                 backward: bool = False):
         self.name = name
         self.bsyms = list(bsyms)
+        #: a region of a backward trace: its operations' scopes start with ``bwd``
+        self.backward = backward
         self.input_names = [p.name for p in inputs]
         self.output_names = [p.name for p in outputs]
         #: positions donated to XLA (set post-lowering by the donation pass —
@@ -72,7 +75,7 @@ class FusionCallable:
 
     def _raw(self, *vals):
         env = dict(zip(self.input_names, vals))
-        eval_bsyms(self.bsyms, env)
+        lower_bsyms(self.bsyms, env, backward=self.backward)
         return tuple(env[n] for n in self.output_names)
 
     def __call__(self, *vals):
@@ -151,7 +154,8 @@ class XLAFusionExecutor(FusionExecutor):
     def can_fuse(self, bsym: BoundSymbol) -> bool:
         return self._is_fusible(bsym)
 
-    def fuse(self, region_bsyms: list[BoundSymbol], fusion_counter: int, producers_map, consumers_map, return_proxies) -> BoundSymbol:
+    def fuse(self, region_bsyms: list[BoundSymbol], fusion_counter: int, producers_map, consumers_map, return_proxies,
+             backward: bool = False) -> BoundSymbol:
         region = Region(producers_map, consumers_map, region_bsyms)
         # tensors have runtime identity; numbers resolve statically UNLESS
         # their value is unknown at trace time (item() results) — those are
@@ -173,7 +177,7 @@ class XLAFusionExecutor(FusionExecutor):
                 out_names.add(p.name)
 
         name = f"XLA{fusion_counter}"
-        fusion = FusionCallable(name, region_bsyms, inputs, outputs)
+        fusion = FusionCallable(name, region_bsyms, inputs, outputs, backward=backward)
         sym = Symbol(name=name, meta=None, is_fusion=True, executor=self)
         bsym = sym.bind(
             *inputs,
@@ -249,7 +253,8 @@ class XLAFusionExecutor(FusionExecutor):
             ):
                 new_bsyms.extend(g.bsyms)
             else:
-                new_bsyms.append(self.fuse(g.bsyms, fusion_counter, producers_map, consumers_map, return_proxies))
+                new_bsyms.append(self.fuse(g.bsyms, fusion_counter, producers_map, consumers_map, return_proxies,
+                                           backward=TraceTag.BACKWARD in trace.tags))
                 fusion_counter += 1
 
         ntrace = from_trace(trace)

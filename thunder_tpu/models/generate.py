@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from thunder_tpu.models.llama import Config, build_rope_cache
+from thunder_tpu.observability.events import scope
 
 __all__ = [
     "init_cache",
@@ -98,15 +99,17 @@ def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0):
         # stacked per-expert weights: per-request LoRA deltas are not
         # supported here (AdapterRegistry rejects MoE MLP targets)
         E, k = cfg.n_expert, cfg.n_expert_per_token
-        router = x.astype(jnp.float32) @ mp["gate"].T.astype(jnp.float32)
-        top_logits, top_idx = jax.lax.top_k(router, k)
-        probs = jax.nn.softmax(top_logits, axis=-1)
+        with scope("router"):
+            router = x.astype(jnp.float32) @ mp["gate"].T.astype(jnp.float32)
+            top_logits, top_idx = jax.lax.top_k(router, k)
+            probs = jax.nn.softmax(top_logits, axis=-1)
         y = None
-        for e in range(E):
-            w_e = jnp.sum(probs * (top_idx == e).astype(jnp.float32), axis=-1)
-            xe = lin(jax.nn.silu(lin(x, mp["fc_1"][e])) * lin(x, mp["fc_2"][e]), mp["proj"][e])
-            contrib = xe * w_e[..., None].astype(x.dtype)
-            y = contrib if y is None else y + contrib
+        with scope("experts"):
+            for e in range(E):
+                w_e = jnp.sum(probs * (top_idx == e).astype(jnp.float32), axis=-1)
+                xe = lin(jax.nn.silu(lin(x, mp["fc_1"][e])) * lin(x, mp["fc_2"][e]), mp["proj"][e])
+                contrib = xe * w_e[..., None].astype(x.dtype)
+                y = contrib if y is None else y + contrib
         return y
 
     kind = cfg.mlp_class
@@ -123,20 +126,16 @@ def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0):
             o = o + _lora_delta(inp, *lora[name], lora_scaling)
         return o
 
-    if kind == "LLaMAMLP":
-        return ll("proj", jax.nn.silu(ll("fc_1", x, "fc_1_b")) * ll("fc_2", x, "fc_2_b"), "proj_b")
-    if kind == "GemmaMLP":
-        return ll(
-            "proj",
-            jax.nn.gelu(ll("fc_1", x, "fc_1_b"), approximate=cfg.gelu_approximate == "tanh")
-            * ll("fc_2", x, "fc_2_b"),
-            "proj_b",
-        )
-    return ll(
-        "proj",
-        jax.nn.gelu(ll("fc", x, "fc_b"), approximate=cfg.gelu_approximate == "tanh"),
-        "proj_b",
-    )
+    with scope("up"):
+        if kind == "LLaMAMLP":
+            h = jax.nn.silu(ll("fc_1", x, "fc_1_b")) * ll("fc_2", x, "fc_2_b")
+        elif kind == "GemmaMLP":
+            h = (jax.nn.gelu(ll("fc_1", x, "fc_1_b"), approximate=cfg.gelu_approximate == "tanh")
+                 * ll("fc_2", x, "fc_2_b"))
+        else:
+            h = jax.nn.gelu(ll("fc", x, "fc_b"), approximate=cfg.gelu_approximate == "tanh")
+    with scope("down"):
+        return ll("proj", h, "proj_b")
 
 
 def route_sigmoid_group(scores, cfg: Config):
@@ -177,20 +176,23 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear):
     B, T, C = x.shape
     I, Eh = cfg.intermediate_size, cfg.expert_held
     x2 = x.reshape(B * T, C)
-    scores = jax.nn.sigmoid(x2.astype(jnp.float32) @ mp["gate"].T.astype(jnp.float32))
-    top_w, top_idx = route_sigmoid_group(scores, cfg)
+    with scope("router"):
+        scores = jax.nn.sigmoid(x2.astype(jnp.float32) @ mp["gate"].T.astype(jnp.float32))
+        top_w, top_idx = route_sigmoid_group(scores, cfg)
     # rows an even routing sends a held expert: under a tile of them, a tile is mostly padding
     even = B * T * cfg.n_expert_per_token / cfg.n_expert
-    y = jaxex._moe_share(
-        x2, top_idx, top_w, mp["fc_1"].reshape(Eh, C, I), mp["fc_2"].reshape(Eh, C, I),
-        mp["proj"].reshape(Eh, I, C), cfg.expert_first, cfg.n_expert,
-        tile=MOE_ROW_TILE if even >= MOE_ROW_TILE // 2 else MOE_DECODE_ROW_TILE)
+    with scope("experts"):
+        y = jaxex._moe_share(
+            x2, top_idx, top_w, mp["fc_1"].reshape(Eh, C, I), mp["fc_2"].reshape(Eh, C, I),
+            mp["proj"].reshape(Eh, I, C), cfg.expert_first, cfg.n_expert,
+            tile=MOE_ROW_TILE if even >= MOE_ROW_TILE // 2 else MOE_DECODE_ROW_TILE)
     if cfg.shared_expert_size:
-        sp = mp["shared"]
-        shared = lin(jax.nn.silu(lin(x2, sp["fc_1"])) * lin(x2, sp["fc_2"]), sp["proj"])
-        if cfg.shared_expert_gate:
-            shared = jax.nn.sigmoid(lin(x2, sp["gate"])) * shared
-        y = y + shared
+        with scope("shared"):
+            sp = mp["shared"]
+            shared = lin(jax.nn.silu(lin(x2, sp["fc_1"])) * lin(x2, sp["fc_2"]), sp["proj"])
+            if cfg.shared_expert_gate:
+                shared = jax.nn.sigmoid(lin(x2, sp["gate"])) * shared
+            y = y + shared
     return y.reshape(B, T, C)
 
 
@@ -200,6 +202,7 @@ def pad_lanes(x, width: int):
     return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),)) if pad else x
 
 
+@scope("mla/latent")
 def mla_latent(ap, x, cos_t, sin_t, cfg: Config, lin=_linear):
     """What a latent-attention layer's cache holds of new tokens ``x (B, T,
     C)``: ``[c_kv | k_r] (B, T, kv_lora_rank + qk_rope_head_dim)``, the latent
@@ -218,6 +221,7 @@ def mla_heads(ap, cfg: Config):
     return w[:, :cfg.qk_nope_head_dim], w[:, cfg.qk_nope_head_dim:]
 
 
+@scope("mla/expand")
 def mla_expand(ap, latent, cfg: Config):
     """The expanded keys and values of cached tokens: ``latent (B, S, >= dc +
     dr)`` to ``k (B, nh, S, dn + dr)`` (a head's unrotated part, then the
@@ -231,6 +235,7 @@ def mla_expand(ap, latent, cfg: Config):
     return k, v
 
 
+@scope("mla/absorb")
 def mla_absorb(ap, q_nope, q_rope, cfg: Config, width: int | None = None):
     """The absorbed query: ``[q_nope W_k | q_rope] (B, nh, T, dc + dr)``, which
     scores a cached ``[c_kv | k_r]`` row as the expanded head would; padded
@@ -241,6 +246,7 @@ def mla_absorb(ap, q_nope, q_rope, cfg: Config, width: int | None = None):
     return pad_lanes(q, width or q.shape[-1])
 
 
+@scope("mla/unabsorb")
 def mla_unabsorb(ap, ot, cfg: Config):
     """A head's output from its weighted sum of latents: ``ot (B, nh, T, dc)``
     to ``(B, nh, T, dv)``."""
@@ -248,6 +254,7 @@ def mla_unabsorb(ap, ot, cfg: Config):
     return jnp.einsum("bhtc,hdc->bhtd", ot, w_v.astype(ot.dtype))
 
 
+@scope("attn")
 def mla_attend_latents(q, latents, keep, cfg: Config):
     """Absorbed attention in XLA: ``q (B, nh, T, W)`` (:func:`mla_absorb`) over
     cached rows ``latents (B, S, W)``, ``keep`` broadcastable to ``(B, nh, T,
@@ -271,11 +278,13 @@ def mla_mixer(ap, x, cos_t, sin_t, cfg: Config, attend, *, lin=_linear):
     T, C)``."""
     B, T, _ = x.shape
     nh, dn, dv = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim
-    q = lin(_rms(lin(x, ap["wq_a"]), ap["q_norm"], cfg.norm_eps), ap["wq_b"])
-    q = q.reshape(B, T, nh, cfg.head_size).transpose(0, 2, 1, 3)
-    q_nope, q_rope = q[..., :dn], _rope(q[..., dn:], cos_t, sin_t)
+    with scope("mla/q"):
+        q = lin(_rms(lin(x, ap["wq_a"]), ap["q_norm"], cfg.norm_eps), ap["wq_b"])
+        q = q.reshape(B, T, nh, cfg.head_size).transpose(0, 2, 1, 3)
+        q_nope, q_rope = q[..., :dn], _rope(q[..., dn:], cos_t, sin_t)
     o = attend(q_nope, q_rope, mla_latent(ap, x, cos_t, sin_t, cfg, lin))
-    return lin(o.transpose(0, 2, 1, 3).reshape(B, T, nh * dv), ap["wo"])
+    with scope("out"):
+        return lin(o.transpose(0, 2, 1, 3).reshape(B, T, nh * dv), ap["wo"])
 
 
 def _mla_with_cache(ap, x, cos_t, sin_t, cl, pos, cfg: Config, *, quantized=False, sharded=False):
@@ -294,31 +303,33 @@ def _mla_with_cache(ap, x, cos_t, sin_t, cl, pos, cfg: Config, *, quantized=Fals
     box = [cl]
 
     def attend(q_nope, q_rope, latent):
-        row = pad_lanes(latent, W).astype(cl.dtype)[:, None]
-        if vec:
-            box[0] = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(c, u, p, axis=1))(cl, row, pos)
-        else:
-            box[0] = jax.lax.dynamic_update_slice_in_dim(cl, row, pos, axis=2)
-        if fresh:
-            keys, keep = latent, _band_keep(T, None)
-        else:
-            keys = box[0][:, 0]
-            qpos = ((pos[:, None] + jnp.arange(T)[None, :])[:, None, :, None] if vec
-                    else (pos + jnp.arange(T))[None, None, :, None])
-            keep = jnp.arange(Tc)[None, None, None, :] <= qpos
+        with scope("cache"):
+            row = pad_lanes(latent, W).astype(cl.dtype)[:, None]
+            if vec:
+                box[0] = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(c, u, p, axis=1))(cl, row, pos)
+            else:
+                box[0] = jax.lax.dynamic_update_slice_in_dim(cl, row, pos, axis=2)
+            if fresh:
+                keys, keep = latent, _band_keep(T, None)
+            else:
+                keys = box[0][:, 0]
+                qpos = ((pos[:, None] + jnp.arange(T)[None, :])[:, None, :, None] if vec
+                        else (pos + jnp.arange(T))[None, None, :, None])
+                keep = jnp.arange(Tc)[None, None, None, :] <= qpos
         if T == 1:
             return mla_unabsorb(ap, mla_attend_latents(mla_absorb(ap, q_nope, q_rope, cfg, W), keys, keep, cfg), cfg)
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k, v = mla_expand(ap, keys.astype(q.dtype), cfg)
-        if fresh and not sharded:
-            from thunder_tpu.executors import pallasex
+        with scope("attn"):
+            if fresh and not sharded:
+                from thunder_tpu.executors import pallasex
 
-            flash = pallasex.flash_sdpa(q, k, pad_lanes(v, q.shape[-1]), None, True, cfg.attn_scale, None)
-            if flash is not None:
-                return flash[0][..., :cfg.v_head_dim]
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * cfg.attn_scale
-        w = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(q.dtype)
-        return jnp.einsum("bhqk,bhkd->bhqd", w, v)
+                flash = pallasex.flash_sdpa(q, k, pad_lanes(v, q.shape[-1]), None, True, cfg.attn_scale, None)
+                if flash is not None:
+                    return flash[0][..., :cfg.v_head_dim]
+            s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * cfg.attn_scale
+            w = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(q.dtype)
+            return jnp.einsum("bhqk,bhkd->bhqd", w, v)
 
     y = mla_mixer(ap, x, cos_t, sin_t, cfg, attend, lin=partial(_linear, quantized=quantized))
     return y, box[0]
@@ -356,18 +367,20 @@ def _project_qkv(ap, x, cos_t, sin_t, cfg: Config, *, lin=None, lora=None,
             o = o + delta_fn(x, *lora[name], lora_scaling)
         return o
 
-    q, k = proj("wq", "bq"), proj("wk", "bk")
-    if cfg.qk_norm_whole:   # over the whole projection, before the split into heads
-        q, k = _rms(q, ap["q_norm"], cfg.norm_eps), _rms(k, ap["k_norm"], cfg.norm_eps)
-    q = q.reshape(B, T, nh, hs).transpose(0, 2, 1, 3)
-    k = k.reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
-    v = proj("wv", "bv").reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
+    with scope("qkv"):
+        q, k = proj("wq", "bq"), proj("wk", "bk")
+        if cfg.qk_norm_whole:   # over the whole projection, before the split into heads
+            q, k = _rms(q, ap["q_norm"], cfg.norm_eps), _rms(k, ap["k_norm"], cfg.norm_eps)
+        q = q.reshape(B, T, nh, hs).transpose(0, 2, 1, 3)
+        k = k.reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
+        v = proj("wv", "bv").reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
     n_elem = cfg.rope_n_elem
     if n_elem > 0:
-        q_r = _rope(q[..., :n_elem], cos_t, sin_t)
-        k_r = _rope(k[..., :n_elem], cos_t, sin_t)
-        q = jnp.concatenate([q_r, q[..., n_elem:]], axis=-1) if n_elem < hs else q_r
-        k = jnp.concatenate([k_r, k[..., n_elem:]], axis=-1) if n_elem < hs else k_r
+        with scope("rope"):
+            q_r = _rope(q[..., :n_elem], cos_t, sin_t)
+            k_r = _rope(k[..., :n_elem], cos_t, sin_t)
+            q = jnp.concatenate([q_r, q[..., n_elem:]], axis=-1) if n_elem < hs else q_r
+            k = jnp.concatenate([k_r, k[..., n_elem:]], axis=-1) if n_elem < hs else k_r
     return q, k, v
 
 
@@ -526,70 +539,74 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
     assert not (ring and vec), "per-row positions are not supported with a ring cache"
     fresh = isinstance(pos, int) and pos == 0 and T > 1
 
-    if not ring:
-        if vec:
-            upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(c, u, p, axis=1))
-            ck = upd(ck, k.astype(ck.dtype), pos)
-            cv = upd(cv, v.astype(cv.dtype), pos)
-        else:
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), pos, axis=2)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), pos, axis=2)
-        if fresh:
-            kk, vv, keep = k, v, _band_keep(T, W)
-        else:
-            kk, vv = ck, cv
-            # query at global position pos+t sees cache slots (pos+t-W, pos+t]
-            j = jnp.arange(Tc)[None, None, None, :]
+    # the cache's writes, the slots attended and their mask
+    with scope("cache"):
+        if not ring:
             if vec:
-                qpos = (pos[:, None] + jnp.arange(T)[None, :])[:, None, :, None]  # (B,1,T,1)
+                upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(c, u, p, axis=1))
+                ck = upd(ck, k.astype(ck.dtype), pos)
+                cv = upd(cv, v.astype(cv.dtype), pos)
             else:
-                qpos = (pos + jnp.arange(T))[None, None, :, None]
-            keep = j <= qpos
-            if W is not None:
-                keep = jnp.logical_and(keep, j > qpos - W)
-    elif T > 1:
-        # ring prefill: the chunk attends within itself (banded); the cache
-        # keeps each ring slot's latest prompt position.  pos==0 because a
-        # later chunk would need K/V already evicted from the ring.
-        assert fresh, "ring-cache prefill must start at position 0"
-        kk, vv, keep = k, v, _band_keep(T, W)
-        # slot j <- the latest prompt position p ≡ j (mod W); slots with no
-        # such position stay garbage (masked positionally at decode)
-        gather = ring_gather_positions(T, W)
-        ck = jnp.take(k, gather, axis=2).astype(ck.dtype)
-        cv = jnp.take(v, gather, axis=2).astype(cv.dtype)
-    else:
-        # ring decode: one token at global position pos -> slot pos % W
-        slot = ring_slot(pos, W)
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), slot, axis=2)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), slot, axis=2)
-        kk, vv = ck, cv
-        # slot j holds global position pos - ((pos - j) mod W) — always in
-        # (pos-W, pos]; mask only slots never written (negative position)
-        j = jnp.arange(W)
-        gp = pos - jax.lax.rem(jax.lax.rem(pos - j, W) + W, W)
-        keep = (gp >= 0)[None, None, None, :]
+                ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), pos, axis=2)
+                cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), pos, axis=2)
+            if fresh:
+                kk, vv, keep = k, v, _band_keep(T, W)
+            else:
+                kk, vv = ck, cv
+                # query at global position pos+t sees cache slots (pos+t-W, pos+t]
+                j = jnp.arange(Tc)[None, None, None, :]
+                if vec:
+                    qpos = (pos[:, None] + jnp.arange(T)[None, :])[:, None, :, None]  # (B,1,T,1)
+                else:
+                    qpos = (pos + jnp.arange(T))[None, None, :, None]
+                keep = j <= qpos
+                if W is not None:
+                    keep = jnp.logical_and(keep, j > qpos - W)
+        elif T > 1:
+            # ring prefill: the chunk attends within itself (banded); the cache
+            # keeps each ring slot's latest prompt position.  pos==0 because a
+            # later chunk would need K/V already evicted from the ring.
+            assert fresh, "ring-cache prefill must start at position 0"
+            kk, vv, keep = k, v, _band_keep(T, W)
+            # slot j <- the latest prompt position p ≡ j (mod W); slots with no
+            # such position stay garbage (masked positionally at decode)
+            gather = ring_gather_positions(T, W)
+            ck = jnp.take(k, gather, axis=2).astype(ck.dtype)
+            cv = jnp.take(v, gather, axis=2).astype(cv.dtype)
+        else:
+            # ring decode: one token at global position pos -> slot pos % W
+            slot = ring_slot(pos, W)
+            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), slot, axis=2)
+            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), slot, axis=2)
+            kk, vv = ck, cv
+            # slot j holds global position pos - ((pos - j) mod W) — always in
+            # (pos-W, pos]; mask only slots never written (negative position)
+            j = jnp.arange(W)
+            gp = pos - jax.lax.rem(jax.lax.rem(pos - j, W) + W, W)
+            keep = (gp >= 0)[None, None, None, :]
 
     flash = None
-    if fresh and not sharded:
-        from thunder_tpu.executors import pallasex
+    with scope("attn"):
+        if fresh and not sharded:
+            from thunder_tpu.executors import pallasex
 
-        flash = pallasex.flash_sdpa(q, k, v, None, True, 1.0 / math.sqrt(hs),
-                                    W if W is not None and T > W else None)
-    if flash is not None:
-        y = flash[0]
-    else:
-        kk, vv = _expand_groups(kk, vv, nh)
-        scores = jnp.einsum(
-            "bhqd,bhkd->bhqk", q, kk.astype(q.dtype), preferred_element_type=jnp.float32
-        ) / math.sqrt(hs)
-        scores = jnp.where(keep, scores, -jnp.inf)
-        w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        y = jnp.einsum("bhqk,bhkd->bhqd", w, vv.astype(q.dtype))
-    y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
-    out = lin(y, ap["wo"], ap.get("bo"))
-    if lora is not None and "wo" in lora:
-        out = out + _lora_delta(y, *lora["wo"], lora_scaling)
+            flash = pallasex.flash_sdpa(q, k, v, None, True, 1.0 / math.sqrt(hs),
+                                        W if W is not None and T > W else None)
+        if flash is not None:
+            y = flash[0]
+        else:
+            kk, vv = _expand_groups(kk, vv, nh)
+            scores = jnp.einsum(
+                "bhqd,bhkd->bhqk", q, kk.astype(q.dtype), preferred_element_type=jnp.float32
+            ) / math.sqrt(hs)
+            scores = jnp.where(keep, scores, -jnp.inf)
+            w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            y = jnp.einsum("bhqk,bhkd->bhqd", w, vv.astype(q.dtype))
+    with scope("out"):
+        y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
+        out = lin(y, ap["wo"], ap.get("bo"))
+        if lora is not None and "wo" in lora:
+            out = out + _lora_delta(y, *lora["wo"], lora_scaling)
     return out, ck, cv
 
 
@@ -614,27 +631,32 @@ def gdn_mixer(gp, x, tail, cfg: Config, recur, *, n_real=None, lin=_linear):
     nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv, K = cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.linear_conv_kernel
     n_qkv = cfg.linear_qkv_width
-    qkvz, ba = lin(x, gp["in_proj_qkvz"]), lin(x, gp["in_proj_ba"])
-    z = qkvz[..., n_qkv:]
-    seen = jnp.concatenate([tail.astype(qkvz.dtype), qkvz[..., :n_qkv]], axis=1)        # (B, K - 1 + T, channels)
-    new_tail = (seen[:, T:] if n_real is None
-                else jax.lax.dynamic_slice_in_dim(seen, n_real, K - 1, axis=1)).astype(tail.dtype)
-    # causal depthwise conv, no bias: tap j of a channel weighs the token K - 1 - j back
-    w = gp["conv_w"].astype(jnp.float32)
-    qkv = jax.nn.silu(sum(seen[:, j:j + T].astype(jnp.float32) * w[:, j] for j in range(K))).astype(x.dtype)
-    heads = lambda a, n, d: a.reshape(B, T, n, d).transpose(0, 2, 1, 3)  # noqa: E731
-    q = _l2norm(heads(qkv[..., :nk * dk], nk, dk)) * (dk ** -0.5)
-    k = _l2norm(heads(qkv[..., nk * dk:2 * nk * dk], nk, dk))
-    v = heads(qkv[..., 2 * nk * dk:], nv, dv)
-    beta = jax.nn.sigmoid(ba[..., :nv].astype(jnp.float32)) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
-    g = -jnp.exp(gp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
-        ba[..., nv:].astype(jnp.float32) + gp["dt_bias"].astype(jnp.float32))
-    if n_real is not None:
-        real = (jnp.arange(T) < n_real)[None, :, None]
-        g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
-    o = recur(q, k, v, g.transpose(0, 2, 1), beta.transpose(0, 2, 1))                   # (B, nv, T, dv)
-    o = _rms(o.transpose(0, 2, 1, 3), gp["norm"], cfg.norm_eps) * jax.nn.silu(z.reshape(B, T, nv, dv))
-    return lin(o.reshape(B, T, nv * dv), gp["out_proj"]), new_tail
+    with scope("gdn/in_proj"):
+        qkvz, ba = lin(x, gp["in_proj_qkvz"]), lin(x, gp["in_proj_ba"])
+        z = qkvz[..., n_qkv:]
+    with scope("gdn/conv"):
+        seen = jnp.concatenate([tail.astype(qkvz.dtype), qkvz[..., :n_qkv]], axis=1)    # (B, K - 1 + T, channels)
+        new_tail = (seen[:, T:] if n_real is None
+                    else jax.lax.dynamic_slice_in_dim(seen, n_real, K - 1, axis=1)).astype(tail.dtype)
+        # causal depthwise conv, no bias: tap j of a channel weighs the token K - 1 - j back
+        w = gp["conv_w"].astype(jnp.float32)
+        qkv = jax.nn.silu(sum(seen[:, j:j + T].astype(jnp.float32) * w[:, j] for j in range(K))).astype(x.dtype)
+    with scope("gdn/gates"):
+        heads = lambda a, n, d: a.reshape(B, T, n, d).transpose(0, 2, 1, 3)  # noqa: E731
+        q = _l2norm(heads(qkv[..., :nk * dk], nk, dk)) * (dk ** -0.5)
+        k = _l2norm(heads(qkv[..., nk * dk:2 * nk * dk], nk, dk))
+        v = heads(qkv[..., 2 * nk * dk:], nv, dv)
+        beta = jax.nn.sigmoid(ba[..., :nv].astype(jnp.float32)) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
+        g = -jnp.exp(gp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            ba[..., nv:].astype(jnp.float32) + gp["dt_bias"].astype(jnp.float32))
+        if n_real is not None:
+            real = (jnp.arange(T) < n_real)[None, :, None]
+            g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+    with scope("gdn/scan"):
+        o = recur(q, k, v, g.transpose(0, 2, 1), beta.transpose(0, 2, 1))               # (B, nv, T, dv)
+    with scope("gdn/out"):
+        o = _rms(o.transpose(0, 2, 1, 3), gp["norm"], cfg.norm_eps) * jax.nn.silu(z.reshape(B, T, nv, dv))
+        return lin(o.reshape(B, T, nv * dv), gp["out_proj"]), new_tail
 
 
 def gdn_recur_dense(state):
@@ -679,14 +701,33 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
     shared attention norm hands to the MLP too).  The dense cache's forward
     and the paged server's end their blocks here."""
     mlp = partial(_mlp, bp["mlp"], cfg=cfg, quantized=quantized, lora=lora, lora_scaling=lora_scaling)
+    # each sublayer's norm and residual sum count with the sublayer
     if cfg.post_sublayer_norm:          # OLMo: the norms sit on what the sublayers give
-        x = x + _norm(h, bp["norm_1"], cfg)
-        return x + _norm(mlp(x), bp["norm_2"], cfg)
-    if cfg.parallel_residual:
-        n2 = n1 if cfg.shared_attention_norm else _norm(x, bp["norm_2"], cfg, bp.get("norm_2_b"))
-        return x + h + mlp(n2)
-    x = x + h
-    return x + mlp(_norm(x, bp["norm_2"], cfg, bp.get("norm_2_b")))
+        with scope("mixer/norm"):
+            x = x + _norm(h, bp["norm_1"], cfg)
+        with scope("mlp"):
+            m = mlp(x)
+            with scope("norm"):
+                return x + _norm(m, bp["norm_2"], cfg)
+    with scope("mlp"):
+        if cfg.parallel_residual:
+            if cfg.shared_attention_norm:
+                n2 = n1
+            else:
+                with scope("norm"):
+                    n2 = _norm(x, bp["norm_2"], cfg, bp.get("norm_2_b"))
+            with scope("residual"):
+                xh = x + h
+            m = mlp(n2)
+            with scope("residual"):
+                return xh + m
+        with scope("residual"):     # the mixer's, summed where the MLP's norm reads it
+            x = x + h
+        with scope("norm"):
+            n2 = _norm(x, bp["norm_2"], cfg, bp.get("norm_2_b"))
+        m = mlp(n2)
+        with scope("residual"):
+            return x + m
 
 
 def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *,
@@ -711,63 +752,78 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
     :func:`serving.lora.gather_adapter_slots` produces); the delta
     ``lora_scaling * B(A(x))`` lands next to each target's matmul."""
     B, T = idx.shape
-    x = params["wte"][idx]
-    if cfg.scale_embedding:
-        x = x * (cfg.n_embd ** 0.5)  # weak-typed scalar: multiply stays in x.dtype
     vec = _is_vec_pos(pos)
-    if cfg.learned_pos_embedding:
+    with scope("embed"):        # the tokens' rows, and their positions' rows of the rope tables
+        x = params["wte"][idx]
+        if cfg.scale_embedding:
+            x = x * (cfg.n_embd ** 0.5)  # weak-typed scalar: multiply stays in x.dtype
+        if cfg.learned_pos_embedding:
+            if vec:
+                x = x + jax.vmap(
+                    lambda p: jax.lax.dynamic_slice_in_dim(params["wpe"], p, T, axis=0))(pos)
+            else:
+                x = x + jax.lax.dynamic_slice_in_dim(params["wpe"], pos, T, axis=0)
         if vec:
-            x = x + jax.vmap(
-                lambda p: jax.lax.dynamic_slice_in_dim(params["wpe"], p, T, axis=0))(pos)
+            # (B, 1, T, n_elem): broadcasts against (B, nh, T, hs) inside _rope
+            cos_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(cos_all, p, T, axis=0))(pos)[:, None]
+            sin_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(sin_all, p, T, axis=0))(pos)[:, None]
         else:
-            x = x + jax.lax.dynamic_slice_in_dim(params["wpe"], pos, T, axis=0)
-    if vec:
-        # (B, 1, T, n_elem): broadcasts against (B, nh, T, hs) inside _rope
-        cos_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(cos_all, p, T, axis=0))(pos)[:, None]
-        sin_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(sin_all, p, T, axis=0))(pos)[:, None]
-    else:
-        cos_t = jax.lax.dynamic_slice_in_dim(cos_all, pos, T, axis=0)
-        sin_t = jax.lax.dynamic_slice_in_dim(sin_all, pos, T, axis=0)
+            cos_t = jax.lax.dynamic_slice_in_dim(cos_all, pos, T, axis=0)
+            sin_t = jax.lax.dynamic_slice_in_dim(sin_all, pos, T, axis=0)
 
     new_k, new_v, new_conv, new_state, new_latent = [], [], [], [], []
     lin = partial(_linear, quantized=quantized)
     for l, bp in enumerate(params["blocks"]):
-        # OLMo's blocks norm what a sublayer gives, not what it takes
-        n1 = x if cfg.post_sublayer_norm else _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
         lora_l = None
         if lora:
             lora_l = {t: (ab["a"][:, l], ab["b"][:, l]) for t, ab in lora.items()}
-        if cfg.layer_kind(l) == "linear_attention":
-            j = len(new_state)
-            recur, box = gdn_recur_dense(cache["state"][j])
-            h, tail = gdn_mixer(bp["gdn"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
-            new_conv.append(tail)
-            new_state.append(box[0])
-        elif cfg.latent:
-            h, cl = _mla_with_cache(bp["attn"], n1, cos_t, sin_t, cache["latent"][l], pos, cfg,
-                                    quantized=quantized, sharded=sharded)
-            new_latent.append(cl)
-        else:
-            j = len(new_k)
-            h, ck, cv = _attn_with_cache(
-                bp["attn"], n1, cos_t, sin_t, cache["k"][j], cache["v"][j], pos, cfg,
-                quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, sharded=sharded,
-            )
-            new_k.append(ck)
-            new_v.append(cv)
-        x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
+        with scope(f"blk{l}"):
+            with scope("mixer"):
+                # OLMo's blocks norm what a sublayer gives, not what it takes
+                if cfg.post_sublayer_norm:
+                    n1 = x
+                else:
+                    with scope("norm"):
+                        n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
+                if cfg.layer_kind(l) == "linear_attention":
+                    j = len(new_state)
+                    recur, box = gdn_recur_dense(cache["state"][j])
+                    h, tail = gdn_mixer(bp["gdn"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
+                    new_conv.append(tail)
+                    new_state.append(box[0])
+                elif cfg.latent:
+                    h, cl = _mla_with_cache(bp["attn"], n1, cos_t, sin_t, cache["latent"][l], pos, cfg,
+                                            quantized=quantized, sharded=sharded)
+                    new_latent.append(cl)
+                else:
+                    j = len(new_k)
+                    h, ck, cv = _attn_with_cache(
+                        bp["attn"], n1, cos_t, sin_t, cache["k"][j], cache["v"][j], pos, cfg,
+                        quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, sharded=sharded,
+                    )
+                    new_k.append(ck)
+                    new_v.append(cv)
+            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
 
-    cache = {"latent": jnp.stack(new_latent)} if cfg.latent else {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    if new_state:
-        cache.update(conv=jnp.stack(new_conv), state=jnp.stack(new_state))
-    x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
-    if logits_at is not None:
-        x = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
-    head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
-    logits = (_linear(x, head, params.get("lm_head_b"), quantized=quantized)).astype(jnp.float32)
-    return logits, cache
+    with scope("mixer/cache"):
+        cache = {"latent": jnp.stack(new_latent)} if cfg.latent else {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+        if new_state:
+            cache.update(conv=jnp.stack(new_conv), state=jnp.stack(new_state))
+    return _head_logits(params, x, cfg, logits_at, quantized), cache
 
 
+def _head_logits(params, x, cfg: Config, logits_at, quantized):
+    """The last norm and the logits in float32, of row ``logits_at`` alone where given."""
+    with scope("head/norm"):
+        x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
+        if logits_at is not None:
+            x = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
+    with scope("head/logits"):
+        head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
+        return (_linear(x, head, params.get("lm_head_b"), quantized=quantized)).astype(jnp.float32)
+
+
+@scope("head/sample")
 def sample_token(logits, temperature, key):
     """Greedy (``temperature == 0``) or temperature sampling over the last
     axis; ``temperature`` is static (baked into the compiled program)."""

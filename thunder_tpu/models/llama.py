@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 import thunder_tpu.torch as ltorch
+from thunder_tpu.observability.events import scope
 
 __all__ = [
     "Config",
@@ -667,47 +668,51 @@ def attention(ap, x, cos, sin, config: Config):
             o = o + ltorch.linear(ltorch.linear(x_in, a), b)
         return o
 
-    q = proj("wq", x, ap["wq"], ap.get("bq"))  # (B, T, nh*hs)
-    k = proj("wk", x, ap["wk"], ap.get("bk"))  # (B, T, ng*hs)
-    v = proj("wv", x, ap["wv"], ap.get("bv"))
+    with scope("qkv"):
+        q = proj("wq", x, ap["wq"], ap.get("bq"))  # (B, T, nh*hs)
+        k = proj("wk", x, ap["wk"], ap.get("bk"))  # (B, T, ng*hs)
+        v = proj("wv", x, ap["wv"], ap.get("bv"))
 
-    if config.qk_norm_whole:
-        q = ltorch.rms_norm(q, (nh * hs,), _rms_weight(ap["q_norm"], config), eps=config.norm_eps)
-        k = ltorch.rms_norm(k, (ng * hs,), _rms_weight(ap["k_norm"], config), eps=config.norm_eps)
-    gate = None
-    if config.attn_output_gate:
-        # wq projects to (q, gate) a head
-        qg = q.reshape(B, T, nh, 2 * hs)
-        q, gate = qg[..., :hs], qg[..., hs:].reshape(B, T, nh * hs)
-    q = q.reshape(B, T, nh, hs)
-    k = k.reshape(B, T, ng, hs)
-    if config.qk_norm:
-        q = ltorch.rms_norm(q, (hs,), _rms_weight(ap["q_norm"], config), eps=config.norm_eps)
-        k = ltorch.rms_norm(k, (hs,), _rms_weight(ap["k_norm"], config), eps=config.norm_eps)
-    q = q.permute(0, 2, 1, 3)  # (B, nh, T, hs)
-    k = k.permute(0, 2, 1, 3)  # (B, ng, T, hs)
-    v = v.reshape(B, T, ng, hs).permute(0, 2, 1, 3)
+        if config.qk_norm_whole:
+            q = ltorch.rms_norm(q, (nh * hs,), _rms_weight(ap["q_norm"], config), eps=config.norm_eps)
+            k = ltorch.rms_norm(k, (ng * hs,), _rms_weight(ap["k_norm"], config), eps=config.norm_eps)
+        gate = None
+        if config.attn_output_gate:
+            # wq projects to (q, gate) a head
+            qg = q.reshape(B, T, nh, 2 * hs)
+            q, gate = qg[..., :hs], qg[..., hs:].reshape(B, T, nh * hs)
+        q = q.reshape(B, T, nh, hs)
+        k = k.reshape(B, T, ng, hs)
+        if config.qk_norm:
+            q = ltorch.rms_norm(q, (hs,), _rms_weight(ap["q_norm"], config), eps=config.norm_eps)
+            k = ltorch.rms_norm(k, (hs,), _rms_weight(ap["k_norm"], config), eps=config.norm_eps)
+        q = q.permute(0, 2, 1, 3)  # (B, nh, T, hs)
+        k = k.permute(0, 2, 1, 3)  # (B, ng, T, hs)
+        v = v.reshape(B, T, ng, hs).permute(0, 2, 1, 3)
 
     n_elem = config.rope_n_elem
     if n_elem > 0:
-        q_roped = apply_rope(q[..., :n_elem], cos, sin)
-        k_roped = apply_rope(k[..., :n_elem], cos, sin)
-        if n_elem < hs:
-            q = ltorch.cat([q_roped, q[..., n_elem:]], dim=-1)
-            k = ltorch.cat([k_roped, k[..., n_elem:]], dim=-1)
-        else:
-            q, k = q_roped, k_roped
+        with scope("rope"):
+            q_roped = apply_rope(q[..., :n_elem], cos, sin)
+            k_roped = apply_rope(k[..., :n_elem], cos, sin)
+            if n_elem < hs:
+                q = ltorch.cat([q_roped, q[..., n_elem:]], dim=-1)
+                k = ltorch.cat([k_roped, k[..., n_elem:]], dim=-1)
+            else:
+                q, k = q_roped, k_roped
 
     # GQA (ng != nh) is passed natively: the fused SDPA prim gathers KV
     # groups by index inside the flash kernels, so K/V are never expanded
     # to nh heads in HBM (nh/ng× KV-bandwidth saving at Llama-70B/Mixtral)
-    y = ltorch.scaled_dot_product_attention(
-        q, k, v, is_causal=True, sliding_window=config.sliding_window
-    )  # (B, nh, T, hs)
-    y = y.permute(0, 2, 1, 3).reshape(B, T, nh * hs)
-    if gate is not None:
-        y = y * ltorch.sigmoid(gate)
-    return proj("wo", y, ap["wo"], ap.get("bo"))
+    with scope("attn"):
+        y = ltorch.scaled_dot_product_attention(
+            q, k, v, is_causal=True, sliding_window=config.sliding_window
+        )  # (B, nh, T, hs)
+    with scope("out"):
+        y = y.permute(0, 2, 1, 3).reshape(B, T, nh * hs)
+        if gate is not None:
+            y = y * ltorch.sigmoid(gate)
+        return proj("wo", y, ap["wo"], ap.get("bo"))
 
 
 def _l2norm(x, eps: float = 1e-6):
@@ -726,30 +731,35 @@ def gated_delta_net(gp, x, config: Config):
     B, T, _ = x.shape
     nk, nv = config.linear_num_key_heads, config.linear_num_value_heads
     dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
-    qkvz = ltorch.linear(x, gp["in_proj_qkvz"])
-    ba = ltorch.linear(x, gp["in_proj_ba"])
-    n_qkv = 2 * nk * dk + nv * dv
-    qkv, z = qkvz[..., :n_qkv], qkvz[..., n_qkv:]
+    with scope("gdn/in_proj"):
+        qkvz = ltorch.linear(x, gp["in_proj_qkvz"])
+        ba = ltorch.linear(x, gp["in_proj_ba"])
+        n_qkv = 2 * nk * dk + nv * dv
+        qkv, z = qkvz[..., :n_qkv], qkvz[..., n_qkv:]
     # causal depthwise conv over time (torch conv1d, groups = channels, K - 1
     # zeros on the left, no bias): tap j of a channel weighs the token K - 1 - j back
-    qkv = ltorch.silu(ltorch.causal_conv1d(qkv, gp["conv_w"]))
-    q = qkv[..., : nk * dk].reshape(B, T, nk, dk)
-    k = qkv[..., nk * dk: 2 * nk * dk].reshape(B, T, nk, dk)
-    v = qkv[..., 2 * nk * dk:].reshape(B, T, nv, dv)
-    q = _l2norm(q) * (dk ** -0.5)
-    k = _l2norm(k)
-    beta = ltorch.sigmoid(ltorch.to(ba[..., :nv], ltorch.float32))
-    if config.linear_allow_neg_eigval:
-        beta = beta * 2.0
-    a = ltorch.to(ba[..., nv:], ltorch.float32)
-    g = -ltorch.exp(ltorch.to(gp["A_log"], ltorch.float32)) * ltorch.softplus(
-        a + ltorch.to(gp["dt_bias"], ltorch.float32))
-    o = ltorch.gated_delta_rule(
-        q.permute(0, 2, 1, 3), k.permute(0, 2, 1, 3), v.permute(0, 2, 1, 3),
-        g.permute(0, 2, 1), beta.permute(0, 2, 1))  # (B, nv, T, dv)
-    o = ltorch.rms_norm(o.permute(0, 2, 1, 3), (dv,), gp["norm"], eps=config.norm_eps)
-    o = o * ltorch.silu(z.reshape(B, T, nv, dv))
-    return ltorch.linear(o.reshape(B, T, nv * dv), gp["out_proj"])
+    with scope("gdn/conv"):
+        qkv = ltorch.silu(ltorch.causal_conv1d(qkv, gp["conv_w"]))
+    with scope("gdn/gates"):
+        q = qkv[..., : nk * dk].reshape(B, T, nk, dk)
+        k = qkv[..., nk * dk: 2 * nk * dk].reshape(B, T, nk, dk)
+        v = qkv[..., 2 * nk * dk:].reshape(B, T, nv, dv)
+        q = _l2norm(q) * (dk ** -0.5)
+        k = _l2norm(k)
+        beta = ltorch.sigmoid(ltorch.to(ba[..., :nv], ltorch.float32))
+        if config.linear_allow_neg_eigval:
+            beta = beta * 2.0
+        a = ltorch.to(ba[..., nv:], ltorch.float32)
+        g = -ltorch.exp(ltorch.to(gp["A_log"], ltorch.float32)) * ltorch.softplus(
+            a + ltorch.to(gp["dt_bias"], ltorch.float32))
+    with scope("gdn/scan"):
+        o = ltorch.gated_delta_rule(
+            q.permute(0, 2, 1, 3), k.permute(0, 2, 1, 3), v.permute(0, 2, 1, 3),
+            g.permute(0, 2, 1), beta.permute(0, 2, 1))  # (B, nv, T, dv)
+    with scope("gdn/out"):
+        o = ltorch.rms_norm(o.permute(0, 2, 1, 3), (dv,), gp["norm"], eps=config.norm_eps)
+        o = o * ltorch.silu(z.reshape(B, T, nv, dv))
+        return ltorch.linear(o.reshape(B, T, nv * dv), gp["out_proj"])
 
 
 def sparse_moe_mlp(mp, x, config: Config):
@@ -767,17 +777,20 @@ def sparse_moe_mlp(mp, x, config: Config):
     x2 = x.reshape(B * T, C)
     # router logits leave the product in float32 (no rounding to bfloat16 before
     # the top-k: a rounded logit flips choices that a float32 router keeps)
-    probs = ltorch.softmax(ltorch.linear(ltorch.to(x2, ltorch.float32), ltorch.to(mp["gate"], ltorch.float32)), -1)
-    top_w, top_idx = ltorch.topk(probs, config.n_expert_per_token, -1)
-    top_w = top_w / ltorch.sum(top_w, -1, True)
-    y = ltorch.moe_expert_share(
-        x2, top_idx, top_w, mp["fc_1"].reshape(Eh, C, I), mp["fc_2"].reshape(Eh, C, I),
-        mp["proj"].reshape(Eh, I, C), config.expert_first, config.n_expert)
+    with scope("router"):
+        probs = ltorch.softmax(ltorch.linear(ltorch.to(x2, ltorch.float32), ltorch.to(mp["gate"], ltorch.float32)), -1)
+        top_w, top_idx = ltorch.topk(probs, config.n_expert_per_token, -1)
+        top_w = top_w / ltorch.sum(top_w, -1, True)
+    with scope("experts"):
+        y = ltorch.moe_expert_share(
+            x2, top_idx, top_w, mp["fc_1"].reshape(Eh, C, I), mp["fc_2"].reshape(Eh, C, I),
+            mp["proj"].reshape(Eh, I, C), config.expert_first, config.n_expert)
     if config.shared_expert_size:
-        sp = mp["shared"]
-        shared = ltorch.linear(ltorch.silu(ltorch.linear(x2, sp["fc_1"])) * ltorch.linear(x2, sp["fc_2"]),
-                               sp["proj"])
-        y = y + ltorch.sigmoid(ltorch.linear(x2, sp["gate"])) * shared
+        with scope("shared"):
+            sp = mp["shared"]
+            shared = ltorch.linear(ltorch.silu(ltorch.linear(x2, sp["fc_1"])) * ltorch.linear(x2, sp["fc_2"]),
+                                   sp["proj"])
+            y = y + ltorch.sigmoid(ltorch.linear(x2, sp["gate"])) * shared
     return y.reshape(B, T, C)
 
 
@@ -792,19 +805,21 @@ def moe_mlp(mp, x, config: Config):
     plain MXU matmuls; for expert-parallel execution over an ``ep`` mesh
     axis see ``thunder_tpu.distributed.moe``."""
     E, k = config.n_expert, config.n_expert_per_token
-    router = ltorch.linear(x, mp["gate"])  # (B, T, E)
-    top_logits, top_idx = ltorch.topk(router, k, -1)  # (B, T, k)
-    probs = ltorch.softmax(ltorch.to(top_logits, ltorch.float32), -1)
+    with scope("router"):
+        router = ltorch.linear(x, mp["gate"])  # (B, T, E)
+        top_logits, top_idx = ltorch.topk(router, k, -1)  # (B, T, k)
+        probs = ltorch.softmax(ltorch.to(top_logits, ltorch.float32), -1)
     y = None
-    for e in range(E):
-        # summed routing weight for expert e over the k slots: (B, T)
-        w_e = ltorch.sum(probs * ltorch.to(ltorch.eq(top_idx, e), ltorch.float32), -1)
-        xe = ltorch.linear(
-            ltorch.silu(ltorch.linear(x, mp["fc_1"][e])) * ltorch.linear(x, mp["fc_2"][e]),
-            mp["proj"][e],
-        )
-        contrib = xe * ltorch.to(ltorch.unsqueeze(w_e, -1), x.dtype)
-        y = contrib if y is None else y + contrib
+    with scope("experts"):
+        for e in range(E):
+            # summed routing weight for expert e over the k slots: (B, T)
+            w_e = ltorch.sum(probs * ltorch.to(ltorch.eq(top_idx, e), ltorch.float32), -1)
+            xe = ltorch.linear(
+                ltorch.silu(ltorch.linear(x, mp["fc_1"][e])) * ltorch.linear(x, mp["fc_2"][e]),
+                mp["proj"][e],
+            )
+            contrib = xe * ltorch.to(ltorch.unsqueeze(w_e, -1), x.dtype)
+            y = contrib if y is None else y + contrib
     return y
 
 
@@ -813,24 +828,19 @@ def mlp(mp, x, config: Config):
         return moe_mlp(mp, x, config)
     if config.mlp_class == "SparseMoE":
         return sparse_moe_mlp(mp, x, config)
-    if config.mlp_class == "LLaMAMLP":
-        return ltorch.linear(
-            ltorch.silu(ltorch.linear(x, mp["fc_1"], mp.get("fc_1_b")))
-            * ltorch.linear(x, mp["fc_2"], mp.get("fc_2_b")),
-            mp["proj"], mp.get("proj_b"),
-        )
-    if config.mlp_class == "GemmaMLP":
-        # gated MLP with a gelu gate (litgpt GemmaMLP: LLaMAMLP with gelu)
-        return ltorch.linear(
-            ltorch.gelu(ltorch.linear(x, mp["fc_1"], mp.get("fc_1_b")),
-                        approximate=config.gelu_approximate)
-            * ltorch.linear(x, mp["fc_2"], mp.get("fc_2_b")),
-            mp["proj"], mp.get("proj_b"),
-        )
-    return ltorch.linear(
-        ltorch.gelu(ltorch.linear(x, mp["fc"], mp.get("fc_b")), approximate=config.gelu_approximate),
-        mp["proj"], mp.get("proj_b"),
-    )
+    with scope("up"):
+        if config.mlp_class == "LLaMAMLP":
+            h = (ltorch.silu(ltorch.linear(x, mp["fc_1"], mp.get("fc_1_b")))
+                 * ltorch.linear(x, mp["fc_2"], mp.get("fc_2_b")))
+        elif config.mlp_class == "GemmaMLP":
+            # gated MLP with a gelu gate (litgpt GemmaMLP: LLaMAMLP with gelu)
+            h = (ltorch.gelu(ltorch.linear(x, mp["fc_1"], mp.get("fc_1_b")),
+                             approximate=config.gelu_approximate)
+                 * ltorch.linear(x, mp["fc_2"], mp.get("fc_2_b")))
+        else:
+            h = ltorch.gelu(ltorch.linear(x, mp["fc"], mp.get("fc_b")), approximate=config.gelu_approximate)
+    with scope("down"):
+        return ltorch.linear(h, mp["proj"], mp.get("proj_b"))
 
 
 def serving_only(config: Config) -> str | None:
@@ -850,41 +860,67 @@ def block_forward(bp, x, cos, sin, config: Config, kind: str = "full_attention")
     why = serving_only(config)
     if why:
         raise NotImplementedError(f"config {config.name!r} cannot be trained through tt.jit: it sets {why}")
+    # each sublayer's norm and residual add count with the sublayer
+    def mixer(x_in):
+        if kind == "linear_attention":
+            return gated_delta_net(bp["gdn"], x_in, config)
+        return attention(bp["attn"], x_in, cos, sin, config)
+
     if config.post_sublayer_norm:
-        h = (gated_delta_net(bp["gdn"], x, config) if kind == "linear_attention"
-             else attention(bp["attn"], x, cos, sin, config))
-        x = x + _norm(h, bp["norm_1"], config)
-        return x + _norm(mlp(bp["mlp"], x, config), bp["norm_2"], config)
-    n1 = _norm(x, bp["norm_1"], config, bp.get("norm_1_b"))
-    if kind == "linear_attention":
-        h = gated_delta_net(bp["gdn"], n1, config)
-    else:
-        h = attention(bp["attn"], n1, cos, sin, config)
-    if config.parallel_residual:
-        n2 = n1 if config.shared_attention_norm else _norm(x, bp["norm_2"], config, bp.get("norm_2_b"))
-        return x + h + mlp(bp["mlp"], n2, config)
-    x = x + h
-    return x + mlp(bp["mlp"], _norm(x, bp["norm_2"], config, bp.get("norm_2_b")), config)
+        with scope("mixer"):
+            h = mixer(x)
+            with scope("norm"):
+                x = x + _norm(h, bp["norm_1"], config)
+        with scope("mlp"):
+            h = mlp(bp["mlp"], x, config)
+            with scope("norm"):
+                return x + _norm(h, bp["norm_2"], config)
+    with scope("mixer"):
+        with scope("norm"):
+            n1 = _norm(x, bp["norm_1"], config, bp.get("norm_1_b"))
+        h = mixer(n1)
+        if not config.parallel_residual:
+            with scope("residual"):
+                x = x + h
+    with scope("mlp"):
+        if config.parallel_residual:
+            if config.shared_attention_norm:
+                n2 = n1
+            else:
+                with scope("norm"):
+                    n2 = _norm(x, bp["norm_2"], config, bp.get("norm_2_b"))
+            m = mlp(bp["mlp"], n2, config)
+            with scope("residual"):
+                return x + h + m
+        with scope("norm"):
+            n2 = _norm(x, bp["norm_2"], config, bp.get("norm_2_b"))
+        m = mlp(bp["mlp"], n2, config)
+        with scope("residual"):
+            return x + m
 
 
 def gpt_hidden(params, idx, cos, sin, config: Config):
     """Token ids (B, T) int32 → final hidden states (B, T, C) (pre-head)."""
-    x = ltorch.embedding(idx, params["wte"])
-    if config.scale_embedding:
-        x = x * (config.n_embd ** 0.5)
-    if config.learned_pos_embedding:
-        T = idx.shape[1]
-        x = x + params["wpe"][:T]
+    with scope("embed"):
+        x = ltorch.embedding(idx, params["wte"])
+        if config.scale_embedding:
+            x = x * (config.n_embd ** 0.5)
+        if config.learned_pos_embedding:
+            T = idx.shape[1]
+            x = x + params["wpe"][:T]
     for i, bp in enumerate(params["blocks"]):
-        x = block_forward(bp, x, cos, sin, config, config.layer_kind(i))
-    return _norm(x, params["ln_f"], config, params.get("ln_f_b"))
+        with scope(f"blk{i}"):
+            x = block_forward(bp, x, cos, sin, config, config.layer_kind(i))
+    with scope("head/norm"):
+        return _norm(x, params["ln_f"], config, params.get("ln_f_b"))
 
 
 def gpt_forward(params, idx, cos, sin, config: Config):
     """Token ids (B, T) int32 → logits (B, T, padded_vocab_size)."""
     x = gpt_hidden(params, idx, cos, sin, config)
     head = params["wte"] if config.tie_embeddings else params["lm_head"]
-    return ltorch.linear(x, head, params.get("lm_head_b"))
+    with scope("head/logits"):
+        return ltorch.linear(x, head, params.get("lm_head_b"))
 
 
 def gpt_loss(params, idx, targets, cos, sin, config: Config):
@@ -897,12 +933,15 @@ def gpt_loss(params, idx, targets, cos, sin, config: Config):
         x = gpt_hidden(params, idx, cos, sin, config)
         head = params["wte"] if config.tie_embeddings else params["lm_head"]
         C = x.shape[-1]
-        return ltorch.fused_linear_cross_entropy(
-            x.reshape(-1, C), head, targets.reshape(-1)
-        )
+        # the logits' product is inside the fused prim: it counts as loss
+        with scope("head/loss"):
+            return ltorch.fused_linear_cross_entropy(
+                x.reshape(-1, C), head, targets.reshape(-1)
+            )
     logits = gpt_forward(params, idx, cos, sin, config)
     V = logits.shape[-1]
-    return ltorch.cross_entropy(logits.reshape(-1, V).to(ltorch.float32), targets.reshape(-1))
+    with scope("head/loss"):
+        return ltorch.cross_entropy(logits.reshape(-1, V).to(ltorch.float32), targets.reshape(-1))
 
 
 def _bucket_up(n: int, minimum: int) -> int:

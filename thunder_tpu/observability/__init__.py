@@ -22,6 +22,18 @@ annotation is inactive and costs about two microseconds.  The names:
 - training: ``train.step`` [``step``] around ``TrainStep.__call__`` and
   ``train.snapshot`` [``bytes``] around ``train_loop``'s host snapshot.
 
+**Scopes** (``events.py``) are the same for the device: ``scope(name)``
+(``tt.scope``) names the operations traced inside it.  Paths are
+"/"-separated (``bwd/blk3/mixer/qkv``) and end up as the ``op_name`` of
+each HLO instruction, which the device's profile reports for every
+operation it ran.  One component is a group from the closed set
+``events.GROUPS`` (``embed``, ``mixer``, ``mlp``, ``head``, ``optimizer``);
+``blk<i>`` stands outside it, and the lowering of a backward trace puts
+``bwd`` first.  The models under ``models/``, the paged cache's writes and
+reads and the optimizer step write them; a user's model gets them by calling
+``tt.scope`` and otherwise the names of its functions.  Always on: a scope
+is metadata, and the compiled code is the same with or without it.
+
 The pillars on top:
 
 1. **Runtime profiling transform** (``profiler.py``) — a post-lowering pass
@@ -86,6 +98,7 @@ from thunder_tpu.observability.events import (  # noqa: F401
     record_event,
     register_process_name,
     register_thread_name,
+    scope,
     span,
 )
 from thunder_tpu.observability.flight import (  # noqa: F401
@@ -123,6 +136,7 @@ __all__ = [
     "reset_observability",
     # events
     "span",
+    "scope",
     "record_event",
     "events",
     "clear_events",

@@ -30,6 +30,14 @@ event.
 
 ``span`` is a ``contextlib.ContextDecorator``: it also works as a decorator
 (each call re-creates the context).
+
+:func:`scope` is ``span``'s twin for the device: it names the *operations*
+traced inside it, not the host time spent tracing them.  A path of scopes
+("blk0/mixer/qkv") ends up as the ``op_name`` of every HLO instruction the
+enclosed code lowers to, which the device's profile reports for each
+operation it ran (XProf's op name column; ``chipbench/op_scopes.py``).  It
+is metadata on the instruction: the compiled code is the same with or
+without it, so it too is always on.
 """
 from __future__ import annotations
 
@@ -40,13 +48,18 @@ import time
 from collections import deque
 from contextlib import ContextDecorator
 
+from jax import named_scope
 from jax.profiler import TraceAnnotation
 
+from thunder_tpu.core.symbol import scope_path
+from thunder_tpu.core.trace import get_tracectx
 from thunder_tpu.observability.config import event_buffer_capacity
 
 __all__ = [
     "record_event",
     "span",
+    "scope",
+    "GROUPS",
     "events",
     "clear_events",
     "export_chrome_trace",
@@ -142,6 +155,53 @@ class span(ContextDecorator):
         if self.ring:
             record_event("E", self.name, self._late, **self.track)
         self._annotation.__exit__(*exc)
+        return False
+
+
+#: The closed set of top-level kinds of device work.  One component of a
+#: scope path should be one of these (``blk3/mixer/qkv``, ``head/loss``,
+#: ``optimizer``): readers split a step's device time by them, and whatever
+#: carries none counts as unscoped.  The lowering of a backward trace puts
+#: ``bwd`` before the path.
+GROUPS = ("embed", "mixer", "mlp", "head", "optimizer")
+
+
+class scope(ContextDecorator):
+    """Names the device operations traced inside as ``name``, one component
+    of a "/"-separated path that nests with the scopes around it.
+
+    Inside a ``tt.jit`` / ``make_train_step`` trace the path is stamped on
+    every bound symbol recorded (``BoundSymbol.scope``), follows it through
+    the passes as its source provenance does, and becomes a
+    ``jax.named_scope`` when the symbol is lowered under ``jax.jit``.
+    Anywhere else (plain JAX being traced, as the serving forward is) it is
+    ``jax.named_scope(name)`` at once.  Names must depend on the model's
+    structure alone (no ids, counters or addresses): they are part of the
+    lowered text.  Usable as a context manager or as a decorator."""
+
+    __slots__ = ("name", "_token", "_named")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def _recreate_cm(self):
+        return scope(self.name)
+
+    def __enter__(self):
+        if get_tracectx() is not None:
+            outer = scope_path.get()
+            self._named = None
+            self._token = scope_path.set(f"{outer}/{self.name}" if outer else self.name)
+        else:
+            self._named = named_scope(self.name)
+            self._named.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._named is None:
+            scope_path.reset(self._token)
+        else:
+            self._named.__exit__(*exc)
         return False
 
 

@@ -92,7 +92,7 @@ from thunder_tpu.observability.config import (
     flight_recorder_env_enabled,
     serving_trace_env_enabled,
 )
-from thunder_tpu.observability.events import span
+from thunder_tpu.observability.events import scope, span
 from thunder_tpu.observability.flight import FlightRecorder
 from thunder_tpu.observability.goodput import resolve_goodput
 from thunder_tpu.observability.metrics import registry
@@ -2903,6 +2903,7 @@ class ServingEngine:
             kw["lora_scaling"] = self._registry.scaling
         return kw
 
+    @scope("mixer/cache")
     def _dense_cache(self, arenas, tables, cdtype) -> dict:
         """The rows' blocks as the dense cache ``forward_with_cache`` takes
         (inside a program): K and V, dequantised from a quantised pool, or a
@@ -2916,6 +2917,7 @@ class ServingEngine:
             kd, vd = gather_dense(arenas["k"], arenas["v"], tables)
         return {"k": kd, "v": vd}
 
+    @scope("mixer/cache")
     def _blocks_back(self, arenas, cache, dest) -> tuple[dict, Any]:
         """A one-row dense cache's blocks back into their arenas at ``dest``
         (inside a program): the arenas written, and the quantisation error
@@ -2961,35 +2963,38 @@ class ServingEngine:
         in_tree, sharded = fwd is forward_with_cache, self.mesh is not None
 
         def run(params, toks, pos, n_real, arenas, table, dest, key, lora, slot, cmask):
-            if fresh:
-                zeros = jnp.zeros(self.pool.dense_shape(1, nbb), cdtype)
-                dense = {name: zeros for name in (("latent",) if self._latent else ("k", "v"))}
-            else:
-                dense = self._dense_cache(arenas, table[None, :], cdtype)
             held, more = {}, {}
-            if hybrid:
-                # the request's state slot rides before the constraint mask;
-                # a prompt's first piece starts from zeros, whatever the slot's
-                # last owner left, and the padded tail leaves the state alone
-                sslot, cmask = cmask[0], cmask[1:]
-                if fresh:       # zeros without a read (a hybrid engine serves the in-tree forward only)
-                    held = {name: jnp.zeros(shape, arenas[name].dtype)
-                            for name, shape in state_shapes(cfg, 1).items()}
+            with scope("mixer/cache"):
+                if fresh:
+                    zeros = jnp.zeros(self.pool.dense_shape(1, nbb), cdtype)
+                    dense = {name: zeros for name in (("latent",) if self._latent else ("k", "v"))}
                 else:
-                    held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
-                more = {"n_real": n_real}
+                    dense = self._dense_cache(arenas, table[None, :], cdtype)
+                if hybrid:
+                    # the request's state slot rides before the constraint mask;
+                    # a prompt's first piece starts from zeros, whatever the slot's
+                    # last owner left, and the padded tail leaves the state alone
+                    sslot, cmask = cmask[0], cmask[1:]
+                    if fresh:       # zeros without a read (a hybrid engine serves the in-tree forward only)
+                        held = {name: jnp.zeros(shape, arenas[name].dtype)
+                                for name, shape in state_shapes(cfg, 1).items()}
+                    else:
+                        held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
+                    more = {"n_real": n_real}
             own = {"logits_at": n_real - 1, "sharded": sharded} if in_tree else {}
             logits, cache = fwd(
                 params, toks, pos, {**dense, **held}, cos_all, sin_all, cfg,
                 **self._fwd_kwargs(lora, slot), **more, **own,
             )
-            last = logits[:, 0] if in_tree else jax.lax.dynamic_index_in_dim(
-                logits, n_real - 1, axis=1, keepdims=False)
-            if cmask:
-                last = jnp.where(cmask[0], last, -jnp.inf)
-            key, sub = jax.random.split(key)
+            with scope("head/sample"):
+                last = logits[:, 0] if in_tree else jax.lax.dynamic_index_in_dim(
+                    logits, n_real - 1, axis=1, keepdims=False)
+                if cmask:
+                    last = jnp.where(cmask[0], last, -jnp.inf)
+                key, sub = jax.random.split(key)
             tok = sample_token(last, temp, sub)            # (1,) — solo-prefill parity
-            kept = scatter_state(arenas, cache, sslot) if hybrid else {}
+            with scope("mixer/cache"):
+                kept = scatter_state(arenas, cache, sslot) if hybrid else {}
             written, qerr = self._blocks_back(arenas, cache, dest)
             return tok, {**written, **kept}, key, qerr
 
@@ -3110,54 +3115,57 @@ class ServingEngine:
         # (all-True rows are a bit-exact no-op); plain engines pass nothing.
         @partial(jax.jit, donate_argnums=(4,), **self._jit_kwargs("decode"))
         def decode(params, toks, pos, tables, arenas, keys, lora, slots, *cmask):
-            dest_block = jnp.take_along_axis(
-                tables, (pos // bs)[:, None], axis=1)[:, 0]
-            dest_slot = pos % bs
-            if qkv:
-                kd, vd = gather_dense_q(
-                    arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
-                    tables, cdtype,
-                )
-            else:
-                kd, vd = gather_dense(arenas["k"], arenas["v"], tables)
             held = {}
-            if hybrid:
-                # the rows' state slots ride before the constraint mask
-                sslots, cmask = cmask[0], cmask[1:]
-                held = gather_state(arenas, sslots, jnp.zeros((Bb,), bool))
+            with scope("mixer/cache"):
+                dest_block = jnp.take_along_axis(
+                    tables, (pos // bs)[:, None], axis=1)[:, 0]
+                dest_slot = pos % bs
+                if qkv:
+                    kd, vd = gather_dense_q(
+                        arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
+                        tables, cdtype,
+                    )
+                else:
+                    kd, vd = gather_dense(arenas["k"], arenas["v"], tables)
+                if hybrid:
+                    # the rows' state slots ride before the constraint mask
+                    sslots, cmask = cmask[0], cmask[1:]
+                    held = gather_state(arenas, sslots, jnp.zeros((Bb,), bool))
             logits, cache = fwd(
                 params, toks[:, None], pos, {"k": kd, "v": vd, **held}, cos_all, sin_all, cfg,
                 **self._fwd_kwargs(lora, slots),
             )
-            sp = jax.vmap(jax.random.split)(keys)          # per-request key chains
-            new_keys, subs = sp[:, 0], sp[:, 1]
-            lg = logits[:, 0]
-            if cmask:
-                lg = jnp.where(cmask[0], lg, -jnp.inf)
+            with scope("head/sample"):
+                sp = jax.vmap(jax.random.split)(keys)      # per-request key chains
+                new_keys, subs = sp[:, 0], sp[:, 1]
+                lg = logits[:, 0]
+                if cmask:
+                    lg = jnp.where(cmask[0], lg, -jnp.inf)
             # (1, V) per row under vmap == the unbatched B=1 generate() draw
             nxt = jax.vmap(lambda l, k: sample_token(l[None], temp, k)[0])(
                 lg, subs
             )
-            kc = cache["k"].transpose(1, 0, 2, 3, 4)       # (B, L, ng, cap, hs)
-            vc = cache["v"].transpose(1, 0, 2, 3, 4)
-            pick = jax.vmap(
-                lambda c, p: jax.lax.dynamic_index_in_dim(c, p, axis=2, keepdims=False)
-            )
-            if qkv:
-                # the picked values are THIS step's freshly computed K/V (the
-                # dense cache write at pos), so quantize-on-scatter sees exact
-                # inputs — no requantization drift across steps
-                k_arena, k_scale = scatter_token_q(
-                    arenas["k"], arenas["k_scale"], pick(kc, pos), dest_block, dest_slot)
-                v_arena, v_scale = scatter_token_q(
-                    arenas["v"], arenas["v_scale"], pick(vc, pos), dest_block, dest_slot)
-                new = {"k": k_arena, "v": v_arena,
-                       "k_scale": k_scale, "v_scale": v_scale}
-            else:
-                new = {"k": scatter_token(arenas["k"], pick(kc, pos), dest_block, dest_slot),
-                       "v": scatter_token(arenas["v"], pick(vc, pos), dest_block, dest_slot)}
-            if hybrid:
-                new.update(scatter_state(arenas, cache, sslots))
+            with scope("mixer/cache"):
+                kc = cache["k"].transpose(1, 0, 2, 3, 4)   # (B, L, ng, cap, hs)
+                vc = cache["v"].transpose(1, 0, 2, 3, 4)
+                pick = jax.vmap(
+                    lambda c, p: jax.lax.dynamic_index_in_dim(c, p, axis=2, keepdims=False)
+                )
+                if qkv:
+                    # the picked values are THIS step's freshly computed K/V (the
+                    # dense cache write at pos), so quantize-on-scatter sees exact
+                    # inputs — no requantization drift across steps
+                    k_arena, k_scale = scatter_token_q(
+                        arenas["k"], arenas["k_scale"], pick(kc, pos), dest_block, dest_slot)
+                    v_arena, v_scale = scatter_token_q(
+                        arenas["v"], arenas["v_scale"], pick(vc, pos), dest_block, dest_slot)
+                    new = {"k": k_arena, "v": v_arena,
+                           "k_scale": k_scale, "v_scale": v_scale}
+                else:
+                    new = {"k": scatter_token(arenas["k"], pick(kc, pos), dest_block, dest_slot),
+                           "v": scatter_token(arenas["v"], pick(vc, pos), dest_block, dest_slot)}
+                if hybrid:
+                    new.update(scatter_state(arenas, cache, sslots))
             return nxt, new_keys, pos + 1, new
 
         return decode
@@ -3194,11 +3202,12 @@ class ServingEngine:
                 cfg, cdtype=cdtype, mesh=mesh, lora_fused=True,
                 **self._fwd_kwargs(lora, slots), **more,
             )
-            sp = jax.vmap(jax.random.split)(keys)          # per-request key chains
-            new_keys, subs = sp[:, 0], sp[:, 1]
-            lg = logits[:, 0]
-            if cmask:
-                lg = jnp.where(cmask[0], lg, -jnp.inf)
+            with scope("head/sample"):
+                sp = jax.vmap(jax.random.split)(keys)      # per-request key chains
+                new_keys, subs = sp[:, 0], sp[:, 1]
+                lg = logits[:, 0]
+                if cmask:
+                    lg = jnp.where(cmask[0], lg, -jnp.inf)
             nxt = jax.vmap(lambda l, k: sample_token(l[None], temp, k)[0])(
                 lg, subs
             )

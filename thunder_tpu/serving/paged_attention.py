@@ -55,11 +55,13 @@ from thunder_tpu.models.generate import (
     mla_unabsorb,
     pad_lanes,
     _close_block,
+    _head_logits,
     _linear,
     _lora_delta,
     _norm,
     _project_qkv,
 )
+from thunder_tpu.observability.events import scope
 from thunder_tpu.serving.quant import quantize_kv
 
 __all__ = ["forward_paged", "with_state", "write_fresh_kv", "write_fresh_kv_live",
@@ -175,24 +177,28 @@ def _gdn_paged(gp, x, arenas, sslots, pos, cfg, *, layer, n_real, lin):
 
     T = x.shape[1]
     held = {"state": arenas["state"]}
-    tail = arenas["conv"][sslots, layer]                             # (B, K - 1, channels)
-    fresh = (pos == 0) if T > 1 else None
-    if fresh is not None:
-        tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail), tail)
+    with scope("cache"):
+        tail = arenas["conv"][sslots, layer]                         # (B, K - 1, channels)
+        fresh = (pos == 0) if T > 1 else None
+        if fresh is not None:
+            tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail), tail)
 
     def recur(q, k, v, g, beta):
         if T == 1:
             o, held["state"] = gdn_decode_step(held["state"], sslots, q[:, :, 0], k[:, :, 0], v[:, :, 0],
                                                g[:, :, 0], beta[:, :, 0], layer=layer)
             return o[:, :, None]
-        h0 = held["state"][sslots, layer]
-        h0 = jnp.where(fresh[:, None, None, None], jnp.zeros_like(h0), h0)
+        with scope("cache"):
+            h0 = held["state"][sslots, layer]
+            h0 = jnp.where(fresh[:, None, None, None], jnp.zeros_like(h0), h0)
         o, last = jaxex.gdn_chunk_state(q, k, v, g, beta, h0)
-        held["state"] = held["state"].at[sslots, layer].set(last)
+        with scope("cache"):
+            held["state"] = held["state"].at[sslots, layer].set(last)
         return o
 
     y, new_tail = gdn_mixer(gp, x, tail, cfg, recur, n_real=n_real, lin=lin)
-    return y, held["state"], arenas["conv"].at[sslots, layer].set(new_tail)
+    with scope("cache"):
+        return y, held["state"], arenas["conv"].at[sslots, layer].set(new_tail)
 
 
 def _mla_paged(ap, x, arena, tables, pos, cos_t, sin_t, cfg, *, layer, cdtype, lin):
@@ -211,10 +217,13 @@ def _mla_paged(ap, x, arena, tables, pos, cos_t, sin_t, cfg, *, layer, cdtype, l
     box = []
 
     def attend(q_nope, q_rope, latent):
-        row = pad_lanes(latent[:, 0], W).astype(cdtype)
+        with scope("cache"):
+            row = pad_lanes(latent[:, 0], W).astype(cdtype)
         box.append(row)
-        ot = mla_paged_decode(mla_absorb(ap, q_nope, q_rope, cfg, W)[:, :, 0], arena, row, tables, pos,
-                              layer=layer, dc=cfg.kv_lora_rank, scale=cfg.attn_scale)
+        q = mla_absorb(ap, q_nope, q_rope, cfg, W)[:, :, 0]
+        with scope("attn"):
+            ot = mla_paged_decode(q, arena, row, tables, pos,
+                                  layer=layer, dc=cfg.kv_lora_rank, scale=cfg.attn_scale)
         return mla_unabsorb(ap, ot[:, :, None], cfg)
 
     return mla_mixer(ap, x, cos_t, sin_t, cfg, attend, lin=lin), box[0]
@@ -259,65 +268,73 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     hs, nh = cfg.head_size, cfg.n_head
     window = cfg.sliding_window
     state_arena, conv_arena, n_lin = arenas.get("state"), arenas.get("conv"), 0
-    x = params["wte"][idx]
-    if cfg.scale_embedding:
-        x = x * (cfg.n_embd ** 0.5)
-    if cfg.learned_pos_embedding:
-        x = x + jax.vmap(
-            lambda p: jax.lax.dynamic_slice_in_dim(params["wpe"], p, T, axis=0))(pos)
-    cos_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(cos_all, p, T, axis=0))(pos)[:, None]
-    sin_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(sin_all, p, T, axis=0))(pos)[:, None]
+    with scope("embed"):        # the tokens' rows, and their positions' rows of the rope tables
+        x = params["wte"][idx]
+        if cfg.scale_embedding:
+            x = x * (cfg.n_embd ** 0.5)
+        if cfg.learned_pos_embedding:
+            x = x + jax.vmap(
+                lambda p: jax.lax.dynamic_slice_in_dim(params["wpe"], p, T, axis=0))(pos)
+        cos_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(cos_all, p, T, axis=0))(pos)[:, None]
+        sin_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(sin_all, p, T, axis=0))(pos)[:, None]
 
     lin = partial(_linear, quantized=quantized)
     delta_fn = lora_delta_fused if (lora_fused and mesh is None) else _lora_delta
     fresh_k, fresh_v, fresh_rows = [], [], []
     for l, bp in enumerate(params["blocks"]):
-        n1 = x if cfg.post_sublayer_norm else _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
         lora_l = None
         if lora:
             lora_l = {t: (ab["a"][:, l], ab["b"][:, l]) for t, ab in lora.items()}
-        if cfg.layer_kind(l) == "linear_attention":
-            h, state_arena, conv_arena = _gdn_paged(
-                bp["gdn"], n1, {"state": state_arena, "conv": conv_arena}, sslots, pos, cfg,
-                layer=n_lin, n_real=n_real, lin=lin)
-            n_lin += 1
-        elif cfg.latent:
-            h, row = _mla_paged(bp["attn"], n1, arenas["latent"], tables, pos, cos_t, sin_t, cfg,
-                                layer=l, cdtype=cdtype, lin=lin)
-            fresh_rows.append(row)
-        else:
-            q, k, v = _project_qkv(bp["attn"], n1, cos_t, sin_t, cfg, lin=lin,
-                                   lora=lora_l, lora_scaling=lora_scaling,
-                                   delta_fn=delta_fn)
-            kvl = len(fresh_k)                         # this layer's place in the K/V arenas
-            # fresh K/V at the cache compute dtype — the exact values the dense
-            # path writes before attending
-            if T == 1:
-                # q: (B, nh, 1, hs) → (B, nh, hs)
-                fk = k[:, :, 0].astype(cdtype)
-                fv = v[:, :, 0].astype(cdtype)
-                y = _attn_paged(q[:, :, 0], arenas, fk, fv, tables, pos,
-                                layer=kvl, window=window, mesh=mesh)
-                y = y.reshape(B, 1, nh * hs)
-            else:
-                fk = k.astype(cdtype)                  # (B, ng, T, hs)
-                fv = v.astype(cdtype)
-                y = _attn_paged_multi(q, arenas, fk, fv, tables, pos,
-                                      layer=kvl, mesh=mesh)
-                y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
-            h = lin(y, bp["attn"]["wo"], bp["attn"].get("bo"))
-            if lora_l is not None and "wo" in lora_l:
-                h = h + delta_fn(y, *lora_l["wo"], lora_scaling)
-            fresh_k.append(fk)
-            fresh_v.append(fv)
-        x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
+        with scope(f"blk{l}"):
+            with scope("mixer"):
+                if cfg.post_sublayer_norm:
+                    n1 = x
+                else:
+                    with scope("norm"):
+                        n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
+                if cfg.layer_kind(l) == "linear_attention":
+                    h, state_arena, conv_arena = _gdn_paged(
+                        bp["gdn"], n1, {"state": state_arena, "conv": conv_arena}, sslots, pos, cfg,
+                        layer=n_lin, n_real=n_real, lin=lin)
+                    n_lin += 1
+                elif cfg.latent:
+                    h, row = _mla_paged(bp["attn"], n1, arenas["latent"], tables, pos, cos_t, sin_t, cfg,
+                                        layer=l, cdtype=cdtype, lin=lin)
+                    fresh_rows.append(row)
+                else:
+                    q, k, v = _project_qkv(bp["attn"], n1, cos_t, sin_t, cfg, lin=lin,
+                                           lora=lora_l, lora_scaling=lora_scaling,
+                                           delta_fn=delta_fn)
+                    kvl = len(fresh_k)                         # this layer's place in the K/V arenas
+                    # fresh K/V at the cache compute dtype — the exact values the dense
+                    # path writes before attending
+                    with scope("attn"):
+                        if T == 1:
+                            # q: (B, nh, 1, hs) → (B, nh, hs)
+                            fk = k[:, :, 0].astype(cdtype)
+                            fv = v[:, :, 0].astype(cdtype)
+                            y = _attn_paged(q[:, :, 0], arenas, fk, fv, tables, pos,
+                                            layer=kvl, window=window, mesh=mesh)
+                            y = y.reshape(B, 1, nh * hs)
+                        else:
+                            fk = k.astype(cdtype)                  # (B, ng, T, hs)
+                            fv = v.astype(cdtype)
+                            y = _attn_paged_multi(q, arenas, fk, fv, tables, pos,
+                                                  layer=kvl, mesh=mesh)
+                            y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
+                    with scope("out"):
+                        h = lin(y, bp["attn"]["wo"], bp["attn"].get("bo"))
+                        if lora_l is not None and "wo" in lora_l:
+                            h = h + delta_fn(y, *lora_l["wo"], lora_scaling)
+                    fresh_k.append(fk)
+                    fresh_v.append(fv)
+            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
 
-    x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
-    head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
-    logits = (_linear(x, head, params.get("lm_head_b"), quantized=quantized)).astype(jnp.float32)
-    if cfg.latent:          # (B, L, 1, W): the token writer's layout, one group
-        return logits, {"latent": jnp.stack(fresh_rows, axis=1)[:, :, None]}
-    fresh = {"k": jnp.stack(fresh_k, axis=1), "v": jnp.stack(fresh_v, axis=1)}
+    logits = _head_logits(params, x, cfg, None, quantized)
+    with scope("mixer/cache"):
+        if cfg.latent:          # (B, L, 1, W): the token writer's layout, one group
+            return logits, {"latent": jnp.stack(fresh_rows, axis=1)[:, :, None]}
+        fresh = {"k": jnp.stack(fresh_k, axis=1), "v": jnp.stack(fresh_v, axis=1)}
     if state_arena is not None:
         fresh.update(state=state_arena, conv=conv_arena)
     return logits, fresh
@@ -372,6 +389,7 @@ def _write_fused(arena, scale, vals, tables, pos, *, block_size, mesh,
     )(arena, scale, vals, tables, pos, n_emit)
 
 
+@scope("mixer/cache")
 def write_fresh_kv(arenas, fresh, tables, pos, *, block_size, kv_dtype=None,
                    mesh=None):
     """Lands one decode step's fresh K/V in the arenas, in place.
@@ -401,6 +419,7 @@ def write_fresh_kv(arenas, fresh, tables, pos, *, block_size, kv_dtype=None,
     return {"k": ka, "v": va, "k_scale": ks, "v_scale": vs}
 
 
+@scope("mixer/cache")
 def write_fresh_kv_live(arenas, fresh, tables, pos, live, *, block_size,
                         kv_dtype=None, mesh=None):
     """Lands one multi-step scan iteration's fresh K/V, keep-masked by
@@ -435,6 +454,7 @@ def write_fresh_kv_live(arenas, fresh, tables, pos, live, *, block_size,
     return {"k": ka, "v": va, "k_scale": ks, "v_scale": vs}
 
 
+@scope("mixer/cache")
 def write_fresh_kv_masked(arenas, fresh, tables, pos, n_emit, *, block_size,
                           kv_dtype=None, mesh=None):
     """Lands a verify step's accepted-prefix K/V in the arenas, in place.
@@ -475,6 +495,7 @@ def _chunk_blocks(x, bs):
     return x[0].reshape(L, ng, T // bs, bs, hs).transpose(2, 0, 1, 3, 4)
 
 
+@scope("mixer/cache")
 def write_fresh_kv_chunk(arenas, fresh, dest, pos, *, block_size,
                          kv_dtype=None, mesh=None):
     """Lands one chunked-prefill piece's K/V in the arenas, block-granule,

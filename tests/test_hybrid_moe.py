@@ -260,6 +260,52 @@ def test_backward_trace_holds_no_second_forward_and_the_states_are_saved(interpr
     assert rel(grads[0], ref[0] * (1 - qt ** 2)) < 1e-4
 
 
+def test_the_train_step_claims_the_conv_kernels_a_layer_and_keeps_its_gradients(interpreted):
+    """``make_train_step`` on the tiny model (conv width 2 x 2 x 16 + 4 x 16 =
+    128, one lane tile): each of the three DeltaNet layers claims
+    ``causal_conv1d_fwd`` in the forward pass, again where the remat pass makes
+    the cone of q, k, v before the scan's backward, and ``causal_conv1d_bwd``
+    once: the conv with its SiLU is one symbol of that cone where it was two,
+    and the sum before the activation is nowhere saved.  The gradients, read
+    from AdamW's first moment as the benchmark's check reads them, are the
+    reference's."""
+    import optax
+
+    from thunder_tpu import distributed as dist
+
+    hf = tiny(16)
+    params = _noisy(arch.make_params(hf, common.seed_words(5), dtype=jnp.float32))
+    cfg = llama.Config(**arch.program_config(hf))
+    layers = sum(kind == "linear_attention" for kind in cfg.layer_types)
+    assert layers == 3
+    idx, tgt = _batch(hf)
+    batch = (idx, tgt, *arch.rope_tables(hf, T))
+    mesh = dist.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = dist.make_train_step(lambda p, i, t, c, s: llama.gpt_loss(p, i, t, c, s, cfg),
+                                optax.adamw(1e-5, b1=0.9), mesh, donate=False)
+    opt_state = step.init_optimizer_state(params)
+    before = px.stats.get("causal_conv", 0)
+    _, opt_state, loss = step(params, opt_state, *batch)
+    assert px.stats["causal_conv"] - before == layers * 3, "forward, made again, backward: a layer"
+    assert px.conv_schedule["tile_c"] == 128 and px.conv_schedule["bytes_a_backward_call"] == 3 * 2 * T * 128 * 4
+    ref_loss, parts = arch.ref_loss_and_grads(hf, params, idx, tgt, every_leaf=True)
+    assert abs(float(loss) - ref_loss) < 1e-5 * abs(ref_loss)
+    worst = {}
+    for where, part in parts:
+        got = opt_state[0].mu
+        for k in where:
+            got = got[k]
+        for path, leaf in jax.tree_util.tree_flatten_with_path(part)[0]:
+            g = got
+            for k in path:
+                g = g[k.key]
+            worst["/".join(map(str, where)) + jax.tree_util.keystr(path)] = rel(10.0 * g, leaf)
+    conv = {k: e for k, e in worst.items() if "conv_w" in k}
+    assert len(conv) == layers and max(conv.values()) < 2e-4, conv
+    # the limits of test_every_weights_gradient_matches_reference (A_log and dt_bias: sums that cancel)
+    assert max(worst.values()) < 2e-3 and max(e for k, e in worst.items() if "['gdn']" not in k) < 2e-4, worst
+
+
 @pytest.mark.parametrize("decay", ["g_near_0", "g_strongly_negative"])
 def test_gated_delta_rule_prim_through_jit_and_its_backward_rule(interpreted, decay):
     """The prim traced by ``tt.jit`` and differentiated by its own backward

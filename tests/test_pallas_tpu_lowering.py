@@ -25,7 +25,9 @@ tiles blocks of 1024 are derived for (float32 at head 128; a padding mask's
 row over the whole rectangle).  The scan kernels (``gdn_chunk_fwd``,
 ``gdn_chunk_bwd``) are compiled with the log-decay and beta a row a chunk and
 the state a block, at the widest heads ``_gdn_supported`` lets through, and at
-the hybrid cell's own shape, where no float32 column may reach them.
+the hybrid cell's own shape, where no float32 column may reach them.  The
+causal conv's two (``causal_conv1d_fwd``, ``causal_conv1d_bwd``) are compiled
+with a ragged last tile and at both hybrid models' published widths.
 """
 from __future__ import annotations
 
@@ -197,6 +199,18 @@ def _cases(nh: int, ng: int) -> dict:
     cases["moe_grouped_mm_dw"] = (
         lambda x, dy, tg, tu: px._moe_grouped_mm_dw.__wrapped__(x, dy, tg, tu, groups=4),
         [rows, ((1024, 512), BF), tiles, plan])
+    # the DeltaNet layers' causal conv with its SiLU: a ragged last tile of a float32
+    # sequence (the masks of the backward pass), and three taps without an activation
+    conv = lambda act, tiles: (   # noqa: E731
+        lambda x, w: px._conv_fwd.__wrapped__(x, w, act, tiles),
+        lambda g, x, w: px._conv_bwd.__wrapped__(g, x, w, act, tiles))
+    x_f32, x_bf = ((2, 1040, 384), F32), ((1, 2048, 256), BF)
+    fwd, bwd = conv("silu", px._conv_tiles(1040, 384, 4))
+    cases["causal_conv1d_fwd"] = (fwd, [x_f32, ((384, 4), F32)])
+    cases["causal_conv1d_bwd"] = (bwd, [x_f32, x_f32, ((384, 4), F32)])
+    fwd, bwd = conv(None, px._conv_tiles(2048, 256, 2))
+    cases["causal_conv1d_fwd/three_taps"] = (fwd, [x_bf, ((256, 3), BF)])
+    cases["causal_conv1d_bwd/three_taps"] = (bwd, [x_bf, x_bf, ((256, 3), BF)])
     return cases
 
 
@@ -254,6 +268,36 @@ def test_the_servers_scan_and_step_compile_at_the_published_heads(kernel, tpu_sh
             # shape fits the reader that finds paged_attn_decode by its operands
             assert "output_to_operand_aliasing" in call
             assert not re.match(r"^\s*%\S+ = \w+\[\d+(,\d+){3}\]\S* custom-call\(s32\[\d+,\d+\]", call)
+
+
+@pytest.mark.parametrize("kernel", ["causal_conv1d_fwd", "causal_conv1d_bwd"])
+@pytest.mark.parametrize("cell", ["qwen3next_train", "olmo_hybrid"])
+def test_conv_kernels_compile_at_the_published_widths(cell, kernel, tpu_sharding, monkeypatch):
+    """The causal conv as a DeltaNet layer calls it: q | k | v of Qwen3-Next
+    side by side over the hybrid cell's two sequences, ``(2, 8192, 8192)``, and
+    of Olmo-Hybrid over a prompt, ``(1, 2560, 11520)`` (90 lane tiles: tiles of
+    768 channels).  One kernel a call, no padded or float32 copy beside it: the
+    call's only temporaries are the backward pass's partial rows of ``dw``."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    B_, Ts, C = {"qwen3next_train": (2, 8192, 8192), "olmo_hybrid": (1, 2560, 11520)}[cell]
+    x, w = ((B_, Ts, C), BF), ((C, 4), BF)
+    if kernel == "causal_conv1d_fwd":
+        fn, specs = functools.partial(px.causal_conv1d, activation="silu"), [x, w]
+    else:
+        fn, specs = functools.partial(px.causal_conv1d_backward, activation="silu"), [x, x, w]
+    before = px.stats.get("causal_conv", 0)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    assert px.stats["causal_conv"] == before + 1
+    assert px.conv_schedule["bytes_a_forward_call"] == 2 * B_ * Ts * C * 2
+    assert C % px.conv_schedule["tile_c"] == 0 and px.conv_schedule["tile_t"] == {8192: 512, 11520: 640}[C]
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and f'kernel_name = "{kernel}"' in text
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        assert re.search(rf"%{kernel}(\.\d+)? = ", compiled.as_text())
+        # at most dw's eight float32 rows a tap a sequence and its small sums; never a copy of x
+        assert compiled.memory_analysis().temp_size_in_bytes <= 4 * B_ * 8 * 4 * C * 4
 
 
 @pytest.mark.parametrize("shape", SHAPES)
@@ -616,14 +660,15 @@ def test_every_pallas_call_site_is_named():
     import inspect
 
     src = inspect.getsource(px)
-    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 16      # PR 34: mla_paged_decode
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 18      # PR 37: the causal conv's two
     assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
         "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
         "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",
         "paged_attn_verify_quant", "paged_token_write", "paged_token_write_masked",
         "paged_token_write_fused", "paged_token_write_fused_masked",
         "paged_chunk_write", "paged_chunk_write_fused", "lora_delta_fused",
-        "gdn_chunk_fwd", "gdn_chunk_bwd", "gdn_decode_step", "moe_grouped_mm", "moe_grouped_mm_dw"}
+        "gdn_chunk_fwd", "gdn_chunk_bwd", "gdn_decode_step", "moe_grouped_mm", "moe_grouped_mm_dw",
+        "causal_conv1d_fwd", "causal_conv1d_bwd"}
 
 
 @pytest.mark.slow

@@ -140,7 +140,7 @@ class TestTools:
         for t in tools:
             py_compile.compile(str(t), doraise=True)
 
-    @pytest.mark.parametrize("tool", ["flash_tune.py", "gdn_tune.py", "mla_tune.py"])
+    @pytest.mark.parametrize("tool", ["flash_tune.py", "gdn_tune.py", "mla_tune.py", "conv_tune.py"])
     def test_kernel_timers_without_a_tpu_exit_nonzero_and_print_no_result(self, tool):
         proc = subprocess.run(
             [sys.executable, str(self.TOOLS / tool)],

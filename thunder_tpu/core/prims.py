@@ -1309,20 +1309,28 @@ gdn_chunk_backward = make_prim(
 )
 
 
-def _causal_conv1d_check(x, w) -> None:
+#: what ``causal_conv1d`` can apply to its sum before it rounds it
+CAUSAL_CONV_ACTIVATIONS = (None, "silu")
+
+
+def _causal_conv1d_check(x, w, activation) -> None:
     _check_tensor(x)
     _check_tensor(w)
     check(x.ndim == 3 and w.ndim == 2 and w.shape[0] == x.shape[2],
           lambda: f"causal_conv1d: x (B, T, C), w (C, K); got {x.shape}, {w.shape}")
+    check(activation in CAUSAL_CONV_ACTIVATIONS,
+          lambda: f"causal_conv1d: activation is one of {CAUSAL_CONV_ACTIVATIONS}, got {activation!r}")
 
 
-def _causal_conv1d_meta(x: TensorProxy, w: TensorProxy) -> TensorProxy:
-    """Causal depthwise convolution over time: ``out[b, t, c] = sum_j w[c, j] *
-    x[b, t - (K - 1 - j), c]`` with zeros before the sequence (torch
-    ``conv1d(groups=C, padding=K - 1)`` cut to ``T``, no bias).  Tagged
-    elementwise: the rematerialization pass makes it again from its input
-    rather than save its output."""
-    _causal_conv1d_check(x, w)
+def _causal_conv1d_meta(x: TensorProxy, w: TensorProxy, activation: str | None = None) -> TensorProxy:
+    """Causal depthwise convolution over time: ``out[b, t, c] = act(sum_j w[c,
+    j] * x[b, t - (K - 1 - j), c])`` with zeros before the sequence (torch
+    ``conv1d(groups=C, padding=K - 1)`` cut to ``T``, no bias) and ``act`` the
+    identity or SiLU, applied to the float32 sum before it is rounded
+    (upstream's ``causal_conv1d_fn``).  Tagged elementwise: the
+    rematerialization pass makes it again from its input rather than save its
+    output."""
+    _causal_conv1d_check(x, w, activation)
     return _out_like(x, requires_grad=(x.requires_grad or w.requires_grad) and dtypes.is_inexact_dtype(x.dtype))
 
 
@@ -1330,9 +1338,11 @@ causal_conv1d = make_prim(PrimIDs.CAUSAL_CONV1D, "causal_conv1d", meta=_causal_c
                           tags=(OpTags.ELEMENTWISE_BINARY_OP,))
 
 
-def _causal_conv1d_backward_meta(g: TensorProxy, x: TensorProxy, w: TensorProxy):
+def _causal_conv1d_backward_meta(g: TensorProxy, x: TensorProxy, w: TensorProxy, activation: str | None = None):
+    """``(dx, dw)`` from the output's cotangent and the operands alone: the
+    sum before the activation is made again from ``x``, not saved."""
     _check_tensor(g)
-    _causal_conv1d_check(x, w)
+    _causal_conv1d_check(x, w, activation)
     return _out_like(x, requires_grad=False), _out_like(w, requires_grad=False)
 
 

@@ -757,8 +757,11 @@ _gdn_chunk_bw._accepts_none_cotangents = True
 
 @register_backward_rule(PrimIDs.CAUSAL_CONV1D)
 def _causal_conv1d_bw(bsym, g):
-    x, w = bsym.args
-    return list(zip((x, w), prims.causal_conv1d_backward(g, x, w)))
+    """Saved: ``x`` and ``w``; the backward prim makes the sum before the
+    activation again."""
+    x, w, *rest = bsym.args
+    activation = rest[0] if rest else bsym.kwargs.get("activation")
+    return list(zip((x, w), prims.causal_conv1d_backward(g, x, w, activation)))
 
 
 @register_backward_rule(PrimIDs.MOE_EXPERT_SHARE)

@@ -819,29 +819,62 @@ def _optimization_barrier_impl(*tensors):
     return tuple(jax.lax.optimization_barrier(tensors))
 
 
+# The causal depthwise conv of the DeltaNet layers with its activation
+# (pallasex installs ``causal_conv1d_fwd`` / ``causal_conv1d_bwd``: one pass over
+# HBM each way).  The XLA forms below are the fallback and the tests' oracle:
+# XLA reads each shifted window of the padded float32 copy again.
+_causal_conv_fast_path: Callable | None = None       # (x, w, activation) -> out or None
+_causal_conv_bwd_fast_path: Callable | None = None   # (g, x, w, activation) -> (dx, dw) or None
+
+
 def _shifted(xp, T, K):
     """The K windows of T steps of a sequence padded by K - 1 steps."""
     return [xp[:, j:j + T] for j in range(K)]
 
 
-@impl(PrimIDs.CAUSAL_CONV1D)
-def _causal_conv1d_impl(x, w):
-    T, K = x.shape[1], w.shape[1]
+def _causal_conv_taps(x, K):
+    """``x`` in float32 as each tap sees it: tap j the token K - 1 - j back."""
+    return _shifted(jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32), x.shape[1], K)
+
+
+def _causal_conv1d_xla(x, w, activation=None):
     wf = w.astype(jnp.float32)
-    taps = _shifted(jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32), T, K)
-    return sum(tap * wf[:, j] for j, tap in enumerate(taps)).astype(x.dtype)
+    y = sum(tap * wf[:, j] for j, tap in enumerate(_causal_conv_taps(x, w.shape[1])))
+    return (jax.nn.silu(y) if activation == "silu" else y).astype(x.dtype)
 
 
-@impl(PrimIDs.CAUSAL_CONV1D_BACKWARD)
-def _causal_conv1d_backward_impl(g, x, w):
+@impl(PrimIDs.CAUSAL_CONV1D)
+def _causal_conv1d_impl(x, w, activation=None):
+    if _causal_conv_fast_path is not None:
+        res = _causal_conv_fast_path(x, w, activation)
+        if res is not None:
+            return res
+    return _causal_conv1d_xla(x, w, activation)
+
+
+def _causal_conv1d_backward_xla(g, x, w, activation=None):
     T, K = x.shape[1], w.shape[1]
     gf, wf = g.astype(jnp.float32), w.astype(jnp.float32)
+    taps = _causal_conv_taps(x, K)
+    if activation == "silu":
+        # the float32 sum again, never rounded: silu'(y) = s (1 + y (1 - s)), s = sigmoid(y)
+        y = sum(tap * wf[:, j] for j, tap in enumerate(taps))
+        s = jax.nn.sigmoid(y)
+        gf = gf * s * (1.0 + y * (1.0 - s))
     # tap j weighs the token K - 1 - j steps back, so its gradient comes from that many steps on
     ahead = _shifted(jnp.pad(gf, ((0, 0), (0, K - 1), (0, 0))), T, K)
     dx = sum(ahead[K - 1 - j] * wf[:, j] for j in range(K))
-    taps = _shifted(jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32), T, K)
     dw = jnp.stack([jnp.sum(gf * tap, axis=(0, 1)) for tap in taps], axis=1)
     return dx.astype(x.dtype), dw.astype(w.dtype)
+
+
+@impl(PrimIDs.CAUSAL_CONV1D_BACKWARD)
+def _causal_conv1d_backward_impl(g, x, w, activation=None):
+    if _causal_conv_bwd_fast_path is not None:
+        res = _causal_conv_bwd_fast_path(g, x, w, activation)
+        if res is not None:
+            return res
+    return _causal_conv1d_backward_xla(g, x, w, activation)
 
 
 # Grouped matrix products over rows sorted by group, each group padded to

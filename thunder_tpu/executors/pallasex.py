@@ -40,13 +40,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from thunder_tpu.core.prims import GDN_CHUNK, PrimIDs, gdn_state_stride, prim_lookup
+from thunder_tpu.core.prims import CAUSAL_CONV_ACTIVATIONS, GDN_CHUNK, PrimIDs, gdn_state_stride, prim_lookup
 from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_executor
 
 __all__ = [
     "ex", "pallas_ex", "flash_sdpa", "flash_sdpa_backward",
     "paged_attn_decode", "paged_token_write", "paged_available", "paged_head_size_ok", "mla_paged_decode",
-    "gdn_chunk", "gdn_chunk_state", "gdn_decode_step", "grouped_mm", "grouped_mm_dw",
+    "gdn_chunk", "gdn_chunk_state", "gdn_decode_step", "grouped_mm", "grouped_mm_dw", "causal_conv1d", "causal_conv1d_backward",
 ]
 
 # exp(MASK_VALUE - lse) underflows to 0 without the inf-inf NaN hazard of -inf
@@ -2836,6 +2836,247 @@ def grouped_mm_dw(x, dy, tile_group, tiles_used, groups):
     return _moe_grouped_mm_dw(x, dy.astype(x.dtype), tile_group, tiles_used, groups=int(groups))
 
 
+# ---------------------------------------------------------------------------
+# The DeltaNet layers' causal depthwise conv with its activation, one pass over
+# HBM each way: ``causal_conv1d_fwd`` and ``causal_conv1d_bwd``.
+#
+#   y[t] = sum_s w_s x[t - s], s = 0 .. K - 1 (tap j of ``w (C, K)`` is s = K - 1 - j);  out = act(y)
+#   gy = g act'(y);  dx[t] = sum_s w_s gy[t + s];  dw_s = sum_{b, t} gy[t] x[t - s]
+#
+# Channels lie on lanes and time on sublanes.  Grid (B, C / tC, T / tT); a grid
+# step holds a ``(tT, tC)`` tile and the rows around it that its taps reach as
+# further blocks of the same array, one sublane tile of the dtype each (x
+# behind; in the backward pass x and g ahead too), zeros past either end of the
+# sequence.  Inside a tile a loop walks ``rows`` rows at a time, so the float32
+# terms of a step stay near the registers; a shift in time is a sublane roll of
+# the rows with the eight before (or after) them.  The forward loop hands the
+# next step its last eight rows of x; the backward loop runs from the tile's
+# end and hands on the first eight rows of gy, so ``y`` of the rows ahead is
+# made once a tile.  ``dw`` gathers in a float32 block revisited along T, eight
+# partial rows a tap (whole vregs: no reduce across sublanes in the kernel),
+# summed over them and the batch outside.  Products and sums are float32 and
+# ``y`` is never rounded.
+# ---------------------------------------------------------------------------
+
+_CONV_TILE_BYTES = 1 << 20     # of x's dtype: the double-buffered blocks of the backward pass are six of them
+_CONV_STEP_VREGS = 16          # float32 vregs a term of a loop step fills
+
+
+def _conv_tiles(T: int, C: int, itemsize: int) -> tuple[int, int, int]:
+    """``(tT, tC, rows)``: the widest whole-lane-tile divisor of ``C`` up to
+    1024, as many rows as keep the tile within ``_CONV_TILE_BYTES`` (all of a
+    shorter sequence; the last tile of a longer one may be ragged), and the
+    rows a loop step takes: they divide the tile and a float32 term of theirs
+    is ``_CONV_STEP_VREGS`` vregs where that is a whole sublane tile of the
+    dtype.  Measured on one v5e at ``(2, 8192, 8192)`` bfloat16 (PERF.md, PR
+    37; forward / backward ms a call): terms of 128, 64, 32, 16 vregs 1.38 /
+    2.81, 1.19 / 2.39, 1.00 / 1.86, 0.95 / 1.58 (what does not fit the
+    registers spills), of 8 slower again (1.06 / 1.63); tiles of 256, 512 and
+    1024 rows 1.21, 1.19, 1.18 forward; 2048 channels wide Mosaic refuses."""
+    tC = 128 * max(d for d in range(1, 9) if (C // 128) % d == 0)
+    tT = min(T, _CONV_TILE_BYTES // (tC * itemsize) // 64 * 64)
+    fit = max(32 // itemsize, _CONV_STEP_VREGS * 1024 // tC)   # at least a sublane tile of the dtype
+    return tT, tC, next(r for r in (128, 64, 32, 16, 8) if r <= fit and tT % r == 0)
+
+
+def _sublane_rows(dtype) -> int:
+    """Rows of a sublane tile of ``dtype``: 8 of four bytes, 16 of two."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _conv_supported(x_shape, w_shape, dtype, activation) -> bool:
+    """Whole lane tiles of channels, whole sublane tiles of time in ``dtype``,
+    taps that an eight-row halo holds."""
+    if str(dtype) not in ("bfloat16", "float32") or activation not in CAUSAL_CONV_ACTIVATIONS:
+        return False
+    T, C = x_shape[1:]
+    return C % 128 == 0 and T % _sublane_rows(dtype) == 0 and 1 <= w_shape[1] <= 8
+
+
+def _rows_back(rows, s: int, lo: int, n: int):
+    """``rows[lo - s : lo - s + n]`` of a float32 value whose rows are whole
+    sublane tiles: a roll and an aligned slice (negative ``s`` looks ahead)."""
+    return rows[lo:lo + n] if s == 0 else pltpu.roll(rows, s % rows.shape[0], 0)[lo:lo + n]
+
+
+def _conv_sum(ext, w, n: int):
+    """``y`` of the ``n`` rows after the first eight of ``ext``; and x as each tap sees it."""
+    taps = [_rows_back(ext, s, 8, n) for s in range(w.shape[0])]
+    y = taps[0] * w[0:1]
+    for s in range(1, len(taps)):
+        y = y + taps[s] * w[s:s + 1]
+    return y, taps
+
+
+def _sigmoid(y, exact: bool):
+    """The quotient for float32 operands; for 16-bit ones the tanh unit's form,
+    a third of the quotient's vector operations and about 1e-5 off it, a
+    200th of the rounding their result takes (PERF.md, PR 37)."""
+    return 1.0 / (1.0 + jnp.exp(-y)) if exact else 0.5 * jnp.tanh(0.5 * y) + 0.5
+
+
+def _conv_fwd_kernel(x_ref, xb_ref, w_ref, o_ref, *, rows, silu):
+    f32 = jnp.float32
+    tT = x_ref.shape[1]
+    w = w_ref[...]
+    behind = jnp.where(pl.program_id(2) == 0, 0.0, xb_ref[0].astype(f32)[-8:])
+
+    def step(r, behind):
+        at = pl.multiple_of(r * rows, rows)
+        x = x_ref[0, pl.ds(at, rows), :].astype(f32)
+        y, _ = _conv_sum(jnp.concatenate([behind, x], 0), w, rows)
+        o_ref[0, pl.ds(at, rows), :] = (y * _sigmoid(y, x_ref.dtype == f32) if silu else y).astype(o_ref.dtype)
+        return x[-8:]
+
+    jax.lax.fori_loop(0, tT // rows, step, behind)
+
+
+def _conv_bwd_kernel(g_ref, ga_ref, x_ref, xb_ref, xa_ref, w_ref, dx_ref, dw_ref, *, rows, silu, T):
+    f32 = jnp.float32
+    tT = x_ref.shape[1]
+    t, nt = pl.program_id(2), pl.num_programs(2)
+    w = w_ref[...]
+    K = w.shape[0]
+    ragged = T % tT != 0
+    h = xb_ref.shape[1]
+
+    def gy_of(g, y):
+        if not silu:
+            return g
+        s = _sigmoid(y, x_ref.dtype == f32)
+        return g * (s * (1.0 + y * (1.0 - s)))
+
+    def load(ref, at, n):
+        v = ref[0, pl.ds(at, n), :].astype(f32)
+        if ragged:   # what a ragged last tile holds past the sequence is not zeros
+            row = t * tT + at + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(row < T, v, 0.0)
+        return v
+
+    def eight_before(at):
+        """x's eight rows before row ``at`` of the tile: read a whole sublane tile of the dtype."""
+        return load(x_ref, pl.multiple_of(at - h, h), h)[-8:]
+
+    @pl.when(t == 0)
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    # gy of the eight rows after the tile: from x's last rows here and the rows ahead
+    last = t == nt - 1
+    x_ahead = jnp.where(last, 0.0, xa_ref[0].astype(f32)[:8])
+    y_ahead, _ = _conv_sum(jnp.concatenate([eight_before(tT), x_ahead], 0), w, 8)
+    gy_ahead = gy_of(jnp.where(last, 0.0, ga_ref[0].astype(f32)[:8]), y_ahead)
+    x_behind = jnp.where(t == 0, 0.0, xb_ref[0].astype(f32)[-8:])
+    n = tT // rows
+
+    def step(i, gy_ahead):
+        r = n - 1 - i
+        at = pl.multiple_of(r * rows, rows)
+        behind = jnp.where(r == 0, x_behind, eight_before(jnp.maximum(at, h)))
+        y, taps = _conv_sum(jnp.concatenate([behind, load(x_ref, at, rows)], 0), w, rows)
+        gy = gy_of(load(g_ref, at, rows), y)
+        gext = jnp.concatenate([gy, gy_ahead], 0)
+        dx = gy * w[0:1]
+        for s in range(1, K):
+            dx = dx + _rows_back(gext, -s, 0, rows) * w[s:s + 1]
+        dx_ref[0, pl.ds(at, rows), :] = dx.astype(dx_ref.dtype)
+        for s in range(K):
+            dw_ref[0, 8 * s:8 * s + 8, :] += jnp.sum((gy * taps[s]).reshape(rows // 8, 8, -1), axis=0)
+        return gy[:8]
+
+    jax.lax.fori_loop(0, n, step, gy_ahead)
+
+
+def _conv_params(last: str):
+    if _interpret():
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", last))}
+
+
+def _conv_specs(T: int, tT: int, tC: int, h: int):
+    """BlockSpecs of a tile, of the sublane tile behind it and of the one
+    ahead (each clamped into the array: the kernels put zeros there)."""
+    tile = pl.BlockSpec((1, tT, tC), lambda b, c, t: (b, t, c))
+    behind = pl.BlockSpec((1, h, tC), lambda b, c, t: (b, jnp.maximum(t * (tT // h) - 1, 0), c))
+    ahead = pl.BlockSpec((1, h, tC), lambda b, c, t: (b, jnp.minimum((t + 1) * (tT // h), T // h - 1), c))
+    return tile, behind, ahead
+
+
+def _conv_taps_first(w):
+    """``w (C, K)`` as the kernels take it: float32 ``(K, C)``, row s the tap s tokens back."""
+    return jnp.flip(w.astype(jnp.float32), 1).T
+
+
+@functools.partial(jax.jit, static_argnames=("activation", "tiles"))
+def _conv_fwd(x, w, activation, tiles):
+    B, T, C = x.shape
+    K = w.shape[1]
+    tT, tC, rows = tiles
+    tile, behind, _ = _conv_specs(T, tT, tC, _sublane_rows(x.dtype))
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, rows=rows, silu=activation == "silu"),
+        name="causal_conv1d_fwd",
+        grid=(B, C // tC, pl.cdiv(T, tT)),
+        in_specs=[tile, behind, pl.BlockSpec((K, tC), lambda b, c, t: (0, c))],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=_interpret(),
+        **_conv_params("parallel"),
+    )(x, x, _conv_taps_first(w))
+
+
+@functools.partial(jax.jit, static_argnames=("activation", "tiles"))
+def _conv_bwd(g, x, w, activation, tiles):
+    B, T, C = x.shape
+    K = w.shape[1]
+    tT, tC, rows = tiles
+    tile, behind, ahead = _conv_specs(T, tT, tC, _sublane_rows(x.dtype))
+    dx, dw = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, rows=rows, silu=activation == "silu", T=T),
+        name="causal_conv1d_bwd",
+        grid=(B, C // tC, pl.cdiv(T, tT)),
+        in_specs=[tile, ahead, tile, behind, ahead, pl.BlockSpec((K, tC), lambda b, c, t: (0, c))],
+        out_specs=[tile, pl.BlockSpec((1, 8 * K, tC), lambda b, c, t: (b, 0, c))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype), jax.ShapeDtypeStruct((B, 8 * K, C), jnp.float32)],
+        interpret=_interpret(),
+        **_conv_params("arbitrary"),
+    )(g, g, x, x, x, _conv_taps_first(w))
+    # eight partial rows a tap a sequence: summed here, and back to (C, K) in w's order of taps
+    return dx, jnp.flip(dw.reshape(B, K, 8, C).sum(axis=(0, 2)), 0).T.astype(w.dtype)
+
+
+#: the last conv built, at trace time (a dict of its own, as ``flash_schedule``)
+conv_schedule: dict[str, int] = {}
+
+
+def _conv_claim(x, w, activation, *others):
+    """The tiles of a call the kernels take, counted; or None."""
+    if not (_enabled() and _conv_supported(x.shape, w.shape, x.dtype, activation) and _gmm_dispatchable(x, *others)):
+        return None
+    stats["causal_conv"] = stats.get("causal_conv", 0) + 1
+    B, T, C = x.shape
+    tiles = _conv_tiles(T, C, x.dtype.itemsize)
+    conv_schedule.update(tile_t=tiles[0], tile_c=tiles[1], halo_rows=_sublane_rows(x.dtype),
+                         bytes_a_forward_call=2 * B * T * C * x.dtype.itemsize,
+                         bytes_a_backward_call=3 * B * T * C * x.dtype.itemsize)
+    return tiles
+
+
+def causal_conv1d(x, w, activation=None):
+    """``causal_conv1d_fwd``: x ``(B, T, C)``, w ``(C, K)`` -> ``act(conv(x))``
+    in x's dtype; or None where the shapes do not qualify (the XLA form runs
+    then)."""
+    tiles = _conv_claim(x, w, activation)
+    return None if tiles is None else _conv_fwd(x, w, activation=activation, tiles=tiles)
+
+
+def causal_conv1d_backward(g, x, w, activation=None):
+    """``causal_conv1d_bwd``: ``(dx, dw)`` from the output's cotangent and the
+    operands; or None."""
+    tiles = _conv_claim(x, w, activation, g)
+    return None if tiles is None else _conv_bwd(g.astype(x.dtype), x, w, activation=activation, tiles=tiles)
+
+
 # install the fast paths so XLA fusion regions and TrainStep trace evaluation
 # reach the same kernels
 from thunder_tpu.executors import jaxex as _jaxex
@@ -2848,3 +3089,5 @@ _jaxex._gdn_bwd_fast_path = gdn_chunk_backward
 _jaxex._gdn_state_fast_path = gdn_chunk_state
 _jaxex._grouped_mm_fast_path = grouped_mm
 _jaxex._grouped_mm_dw_fast_path = grouped_mm_dw
+_jaxex._causal_conv_fast_path = causal_conv1d
+_jaxex._causal_conv_bwd_fast_path = causal_conv1d_backward

@@ -739,7 +739,7 @@ def gated_delta_net(gp, x, config: Config):
     # causal depthwise conv over time (torch conv1d, groups = channels, K - 1
     # zeros on the left, no bias): tap j of a channel weighs the token K - 1 - j back
     with scope("gdn/conv"):
-        qkv = ltorch.silu(ltorch.causal_conv1d(qkv, gp["conv_w"]))
+        qkv = ltorch.causal_conv1d(qkv, gp["conv_w"], activation="silu")
     with scope("gdn/gates"):
         q = qkv[..., : nk * dk].reshape(B, T, nk, dk)
         k = qkv[..., nk * dk: 2 * nk * dk].reshape(B, T, nk, dk)

@@ -1261,12 +1261,14 @@ def gated_delta_rule(q, k, v, g, beta):
 
 
 @torchsymbol()
-def causal_conv1d(x, weight):
+def causal_conv1d(x, weight, activation=None):
     """Causal depthwise conv over time (thunder extension): ``x (B, T, C)``,
     ``weight (C, K)`` -> ``(B, T, C)``; torch ``conv1d(x^T, weight[:, None],
-    groups=C, padding=K - 1)[..., :T]`` without the transposes.  One cheap
-    prim, made again in the backward pass rather than saved."""
-    return prims.causal_conv1d(x, weight)
+    groups=C, padding=K - 1)[..., :T]`` without the transposes, then
+    ``activation`` (None or ``"silu"``, as upstream's ``causal_conv1d_fn``) on
+    the float32 sum.  One cheap prim, made again in the backward pass rather
+    than saved."""
+    return prims.causal_conv1d(x, weight, activation)
 
 
 @torchsymbol()

@@ -299,7 +299,7 @@ class TestDecodeWalk:
         """Where the walk cannot be compiled (on the TPU: a head size that is
         not whole 128-lane tiles) the token rides as query 0 of a verify
         chunk; the same attention, here interpreted."""
-        monkeypatch.setattr(px, "paged_head_size_ok", lambda hs: False)
+        monkeypatch.setattr(px, "paged_walk_lanes_ok", lambda lanes: False)
         assert px.paged_kv_chunk_blocks(ng, W_BS, W_HS, 4) == 1
         (k, v), tables, q, fk, fv = _walk_inputs(ng=ng, B=3, seed=5)
         pos, ks, vs = [W_C * W_BS + 2, 0, W_NBB * W_BS], None, None
@@ -480,35 +480,43 @@ class TestAttnKnob:
     @pytest.mark.parametrize("attn", ["auto", "paged"])
     @pytest.mark.parametrize("hs", [64, 96])
     def test_narrow_windowed_heads_take_the_gather_path_on_tpu(self, hs, attn, monkeypatch):
-        """Compiled for the TPU the decode walk cannot copy arena slabs of a
-        head size that is not whole 128-lane tiles (test_pallas_tpu_lowering
-        holds the compiler to that), and the per-block kernel that serves
-        such heads has no sliding window: a model with both resolves to the
-        gather path when the engine is built, with a counted reason, and an
-        explicit attn="paged" is refused there, not at the first decode step."""
+        """Compiled for the TPU the decode walk cannot copy arena slabs whose
+        rows are not whole 128-lane tiles (test_pallas_tpu_lowering holds the
+        compiler to that), and the per-block kernel that serves such arenas has
+        no sliding window: a model with both resolves to the gather path when
+        the engine is built, with a counted reason, and an explicit
+        attn="paged" is refused there, not at the first decode step.  A head
+        of 96 is such an arena always; a head of 64 where its KV heads cannot
+        lie two to a row: a quantised arena here (at the compute dtype the pool
+        packs them, and the walk takes the window)."""
         def cfg_of(**kw):
             return llama.Config.from_name("tiny-llama-debug", **{
                 **MICRO, "n_head": 2, "n_query_groups": 2, "n_embd": 2 * hs, **kw})
 
         cfg = cfg_of(sliding_window=8)
         assert cfg.head_size == hs
+        store = {"kv_dtype": "int8"} if hs == 64 else {}
         monkeypatch.setattr(px, "_interpret", lambda: False)   # as on the chip
-        ok, why = paged_supported(cfg, True)
+        ok, why = paged_supported(cfg, True, arena_lanes=hs)
         assert not ok and f"head_size={hs}" in why and "window" in why
+        assert paged_supported(cfg, True)[0] == (hs == 64)            # two heads of 64 a row: walked, window and all
         assert paged_supported(cfg_of(), True) == (True, "")           # no window
         assert paged_supported(cfg_of(n_embd=256, sliding_window=8), True) == (True, "")
         params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
         if attn == "paged":
             with pytest.raises(ValueError, match=f"head_size={hs}"):
-                _engine(cfg, params, attn=attn)
+                _engine(cfg, params, attn=attn, **store)
             return
         monkeypatch.setattr(px, "_pallas_available", lambda: True)
-        st = _engine(cfg, params, attn=attn).stats()["attn"]
+        st = _engine(cfg, params, attn=attn, **store).stats()["attn"]
         assert st["mode"] == "gather" and f"head_size={hs}" in st["fallback_reason"]
-        assert st["kv_chunk_tokens"] is None
+        assert st["kv_chunk_tokens"] is None and st["path"] is None
         # without the window the same heads stay on the kernels, a block a step
-        st = _engine(cfg_of(), params, attn=attn).stats()["attn"]
-        assert st["mode"] == "paged" and st["kv_chunk_tokens"] == 4
+        st = _engine(cfg_of(), params, attn=attn, **store).stats()["attn"]
+        assert st["mode"] == "paged" and st["kv_chunk_tokens"] == 4 and st["path"] == "by_blocks"
+        if hs == 64:    # and at the compute dtype on the walk, in packed rows, with the window
+            st = _engine(cfg, params, attn=attn).stats()["attn"]
+            assert (st["mode"], st["path"], st["lane_pack"]) == ("paged", "walk", 2)
 
     def test_paged_supported_reasons(self, micro):
         cfg, _ = micro

@@ -16,7 +16,9 @@ head size 128 and block size 16 as served.  A variant left unrepaired would
 be ``xfail(strict=True)`` with the compiler's message; none is.  The decode
 walk is also compiled at the benchmark cell's shapes (plain, windowed, int8,
 fp8), at head size 256 and block size 8, and at head sizes 64 and 96, where
-Mosaic refuses the walk's copies and the token goes block by block.  The flash
+Mosaic refuses the walk's copies and the token goes block by block; a head of
+64 in a lane-packed arena (two KV heads a 128-lane row) is walked, at the LFM2
+cell's shapes and in its two programs.  The flash
 kernels are compiled with ``lse`` as lane-dense rows ``(BH, 1, T)``, with a
 padding mask and a mask block a grid step (the transposes of
 ``_flash_bwd_dkv``), and at the train cells' own kinds: a window that spans
@@ -418,10 +420,13 @@ def test_narrow_heads_decode_block_by_block(shape, hs, store, tpu_sharding, monk
     through ``paged_attn_verify``'s per-block grid, which compiles; with a
     sliding window it raises before any lowering (``paged_supported`` keeps
     such a model off the kernels).  If the last check fails because Mosaic
-    now compiles the walk, drop ``paged_head_size_ok``."""
+    now compiles the walk, drop ``paged_walk_lanes_ok``.  (A head of 64 in a
+    lane-packed arena is another arena: rows of 128 lanes, which the walk takes:
+    ``test_the_narrow_head_cells_kernels_compile_at_its_shapes``.)"""
     nh, ng = {**SHAPES, "gpt2": (12, 12)}[shape]
     args = _decode_args(nh, ng, hs, BS, B, NBB, NB, L, store, tpu_sharding)
-    assert not px.paged_head_size_ok(hs) and px.paged_head_size_ok(128)
+    assert not px.paged_walk_lanes_ok(hs) and px.paged_walk_lanes_ok(128)
+    assert px.paged_head_size_ok(64) and not px.paged_head_size_ok(96)      # 64 divides a lane tile: packable
     with pytest.raises(NotImplementedError, match="sliding window"):
         jax.jit(_decode(1, window=24)).trace(*args)
     lowered = jax.jit(_decode(1)).trace(*args).lower(lowering_platforms=("tpu",))
@@ -430,7 +435,7 @@ def test_narrow_heads_decode_block_by_block(shape, hs, store, tpu_sharding, monk
     if tpu_sharding is None:
         return
     assert re.search(rf"%{name}(\.\d+)? = ", lowered.compile().as_text())
-    monkeypatch.setattr(px, "paged_head_size_ok", lambda hs: True)
+    monkeypatch.setattr(px, "paged_walk_lanes_ok", lambda lanes: True)
     with pytest.raises(Exception, match=r"must be aligned to tiling \(128\)"):
         jax.jit(_decode(1)).trace(*args).lower(lowering_platforms=("tpu",)).compile()
 
@@ -652,6 +657,111 @@ def test_the_latent_cells_programs_lower_to_their_kernels(kind, tpu_sharding, mo
     if tpu_sharding is not None:
         hlo = lowered.compile().as_text()
         names = ("_flash_fwd",) if kind == "prefill_fresh" else ("mla_paged_decode", "mla_latent_write")
+        for name in (*names, "moe_grouped_mm"):
+            assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
+
+
+# --------------------------------------------------------------------------
+# a head of 64 in a lane-packed arena, as the LFM2 cell serves it
+# --------------------------------------------------------------------------
+
+LFM2_CELL = "lfm2moe-serve-1chip.offline-wide"
+
+
+@pytest.mark.parametrize("kernel", ["paged_attn_decode", "paged_attn_decode/window", "paged_token_write",
+                                    "paged_token_write_masked"])
+def test_the_narrow_head_cells_kernels_compile_at_its_shapes(kernel, tpu_sharding):
+    """256 rows, a table 256 blocks wide (65,536 entries of scalar memory), the
+    cell's arena of 47,104 blocks of 16 tokens, 8 KV heads of 64 two to a
+    128-lane row (``(blocks, 3, 4, 16, 128)``), 32 query heads: the walk's copies
+    are whole-tile slabs, nothing of the arena is copied, and no per-block
+    kernel stands in."""
+    kernel, _, windowed = kernel.partition("/")
+    rows, width, pool, layers, nh, ng, hs = 256, 256, 47104, 3, 32, 8, 64
+    arena, tab, pos = ((pool, layers, ng // 2, BS, 128), BF), ((rows, width), I32), ((rows,), I32)
+    if kernel == "paged_attn_decode":
+        fn = functools.partial(px.paged_attn_decode, layer=layers - 1, window=1024 if windowed else None)
+        specs = [((rows, nh, hs), BF), arena, arena, ((rows, ng, hs), BF), ((rows, ng, hs), BF), tab, pos]
+    elif kernel == "paged_token_write":
+        fn = functools.partial(px.paged_token_write, block_size=BS)
+        specs = [arena, ((rows, layers, ng, hs), BF), tab, pos]
+    else:
+        fn = lambda a, v, t, p, n: px.paged_token_write(a, v, t, p, block_size=BS, n_emit=n, offset=0)  # noqa: E731
+        specs = [arena, ((rows, layers, ng, hs), BF), tab, pos, ((rows,), I32)]
+    assert px.paged_walk_lanes_ok(128) and not px.paged_walk_lanes_ok(hs)
+    assert px.paged_kv_chunk_blocks(ng // 2, BS, 128, 2) == 32              # 512 keys a chunk, 1 MiB of K and V in flight
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and f'kernel_name = "{kernel}"' in text and "paged_attn_verify" not in text
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        assert re.search(rf"%{kernel}(\.\d+)? = ", compiled.as_text())
+        if kernel == "paged_attn_decode":
+            assert compiled.memory_analysis().temp_size_in_bytes == 0       # no arena copy, no padded copy of a row
+
+
+@functools.cache
+def _lfm2_engine():
+    """The cell's engine at its published widths, the first four layers (conv,
+    conv, attention, conv: both dense layers and two expert layers of 32), over
+    weights that are shapes alone."""
+    import thunder_tpu as tt
+    from chipbench import common
+    from thunder_tpu.models import llama
+
+    _, config, mix = common.open_cell(LFM2_CELL)
+    arch = common.load_module("models", config["arch"])
+    hf = {**config, "num_hidden_layers": 4}
+    cfg = llama.Config(**arch.program_config(hf))
+    params = jax.eval_shape(functools.partial(arch.make_params, hf), common.seed_words(1))
+    return cfg, params, tt.serve(None, params, cfg, **{**config["engine"], **mix["engine"], "num_blocks": 700})
+
+
+@pytest.mark.parametrize("kind", ["prefill_fresh", "decode_paged"])
+def test_the_narrow_head_cells_programs_lower_to_their_kernels(kind, tpu_sharding, monkeypatch):
+    """A whole prompt's prefill attends through ``_flash_fwd`` (heads of 64
+    padded with zeros to 128) and sorts its rows through ``moe_grouped_mm``; a
+    decode step of 256 rows walks the lane-packed arena through
+    ``paged_attn_decode`` once an attention layer, routes through
+    ``moe_grouped_mm`` and lands its K and V through one ``paged_token_write``
+    each; the conv layers are XLA's, their tails a slot's rows; no arena is
+    gathered and no per-block kernel is called."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    monkeypatch.setattr(px, "_pallas_available", lambda: True)
+    cfg, params, eng = _lfm2_engine()
+    st = eng.stats()
+    assert st["attn"]["mode"] == "paged" and st["attn"]["path"] == "walk" and st["attn"]["lane_pack"] == 2
+    assert eng.pool.k_arena.shape == (700, 1, 4, 16, 128) and eng.pool.state.conv.shape == (257, 3, 2, 2048)
+    occ = st["pool_occupancy"]
+    assert occ["token_bytes_counted"] == occ["token_bytes_laid_out"] == 2 * 8 * 64 * 2       # one attention layer here
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=tpu_sharding)  # noqa: E731
+    one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
+    before = dict(px.stats)
+    if kind == "prefill_fresh":
+        Tb = 2560
+        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
+        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)), one((1,)))
+    else:
+        prog = eng._build_decode_paged(256, 256)
+        args = (weights, one((256,)), one((256,)), one((256, 256)), arenas, one((256, 2), jnp.uint32), {}, one((256,)),
+                one((256,)))
+    lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
+    assert claimed("grouped_mm") >= 3 and 'kernel_name = "moe_grouped_mm"' in text
+    assert "paged_attn_verify" not in text
+    assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)      # nothing of an arena's five dims
+    if kind == "prefill_fresh":
+        assert claimed("direct") == len(cfg.kv_layers) and 'kernel_name = "_flash_fwd"' in text
+        assert {int(m) for m in re.findall(rf"tensor<1x(\d+)x{cfg.padded_vocab_size}xf32>", text)} == {1}
+    else:
+        assert text.count('kernel_name = "paged_attn_decode"') == len(cfg.kv_layers)
+        assert text.count('kernel_name = "paged_token_write"') == 2
+    if tpu_sharding is not None:
+        hlo = lowered.compile().as_text()
+        names = ("_flash_fwd",) if kind == "prefill_fresh" else ("paged_attn_decode", "paged_token_write")
         for name in (*names, "moe_grouped_mm"):
             assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
 

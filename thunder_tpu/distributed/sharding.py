@@ -77,7 +77,11 @@ def kv_cache_spec(cfg, mesh: Mesh | None, *, axis: str = "tp") -> P:
     The int8 pool's float32 scale arenas
     ``(num_blocks, L, n_query_groups, block_size)`` keep the heads dim at
     axis 2 as well, so this spec is a valid prefix for them too: all four
-    serving arrays place with the ONE rule.
+    serving arrays place with the ONE rule.  The arena under a mesh is always
+    a head a row: the pool's lane-packed layout for heads under 128
+    (``(num_blocks, L, n_query_groups / P, block_size, 128)``,
+    ``PagedKVPool.lane_pack``) folds ``P`` heads into axis 4, which this rule
+    would split across, so ``PagedKVPool`` packs on one device only.
 
     Heads split over ``axis`` (tensor-parallel: each device holds its
     query groups' cache, attention stays device-local, only the output

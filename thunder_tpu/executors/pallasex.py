@@ -1071,9 +1071,13 @@ def flash_cross_entropy(logits, target):
 #   would have written before attending) joins as the final online-softmax
 #   term, so every row has at least one kept key and the quantized path
 #   attends the diagonal at full precision, matching quantize-on-scatter
-#   semantics bit-for-bit.  Compiled for the TPU the slab copies need a head
-#   size of whole 128-lane tiles (:func:`paged_head_size_ok`); other head
-#   sizes take ``paged_attn_verify``'s per-block grid (:func:`_decode_by_blocks`).
+#   semantics bit-for-bit.  Compiled for the TPU the slab copies need arena
+#   rows of whole 128-lane tiles: a head size of that, or a head size that
+#   divides 128 in a lane-packed arena (``P = 128 // hs`` KV heads side by side
+#   in a row, ``(num_blocks, L, ng / P, bs, 128)``: the walk then sees ``ng / P``
+#   groups of ``P * rep`` query rows, a head's queries in its own lanes and
+#   zeros in the others', :func:`_lane_packed_queries`).  Arena rows that are
+#   neither take ``paged_attn_verify``'s per-block grid (:func:`_decode_by_blocks`).
 # - ``paged_token_write``: the scatter_token replacement — one grid step per
 #   request lands the fresh K/V (or its quantization scale) in its
 #   ``table[pos // bs]``/``pos % bs`` arena slot via an aliased output
@@ -1091,17 +1095,28 @@ def paged_available() -> bool:
     return _pallas_available()
 
 
+def paged_walk_lanes_ok(lanes: int) -> bool:
+    """Whether :func:`paged_attn_decode`'s chunked walk can fetch arenas whose
+    rows are ``lanes`` wide here.  Compiled for the TPU it copies ``(ng, bs,
+    lanes)`` slabs out of the HBM arena itself, and Mosaic takes such a copy
+    only where ``lanes`` is whole 128-lane tiles: it holds a narrower arena
+    padded to 128 lanes and refuses the 64- or 96-lane slice of it ("Slice
+    shape along dimension 4 must be aligned to tiling (128)"), whatever the
+    form of the index.  Narrower rows are fetched a block a grid step through
+    BlockSpecs (:func:`_decode_by_blocks`), which Mosaic takes at any width.
+    The interpreter has no tiles and walks any width."""
+    return _interpret() or lanes % 128 == 0
+
+
 def paged_head_size_ok(hs: int) -> bool:
-    """Whether :func:`paged_attn_decode`'s chunked walk can fetch arenas of
-    head size ``hs`` here.  Compiled for the TPU it copies ``(ng, bs, hs)``
-    slabs out of the HBM arena itself, and Mosaic takes such a copy only where
-    ``hs`` is whole 128-lane tiles: it holds a narrower arena padded to 128
-    lanes and refuses the 64- or 96-lane slice of it ("Slice shape along
-    dimension 4 must be aligned to tiling (128)"), whatever the form of the
-    index.  Narrower heads are fetched a block a grid step through BlockSpecs
-    (:func:`_decode_by_blocks`), which Mosaic takes at any ``hs``.  The
-    interpreter has no tiles and walks any ``hs``."""
-    return _interpret() or hs % 128 == 0
+    """Whether a head of size ``hs`` can ride :func:`paged_attn_decode`'s
+    chunked walk here: whole 128-lane tiles, or a size that divides 128, which
+    the pool stores lane-packed (``kv_pool.PagedKVPool.lane_pack``: 128 // hs KV
+    heads side by side in a row; 64, 32, 16 ...).  What the walk is handed
+    decides (:func:`paged_walk_lanes_ok` of the arena's rows): a head of 96, a
+    quantised arena of a narrow head (a head a row, its scale a head's) or KV
+    heads that do not come in whole rows go a block a grid step."""
+    return _interpret() or hs % 128 == 0 or (hs < 128 and 128 % hs == 0)
 
 
 # VMEM that one chunk of the decode walk may hold: K and V of its table
@@ -1117,7 +1132,7 @@ def paged_kv_chunk_blocks(ng: int, bs: int, hs: int, itemsize: int) -> int:
     sees — the arena's (local) KV groups, block size, head size and storage
     item size.  Not from the table's width nor the batch: a row's chunk
     boundaries, and with them the order of its sums, depend on the row alone."""
-    if not paged_head_size_ok(hs):
+    if not paged_walk_lanes_ok(hs):
         return 1                                        # _decode_by_blocks
     per_block = 2 * 2 * ng * bs * hs * itemsize        # K and V, two slots
     return max(1, min(_PAGED_CHUNK_BYTES // per_block, _PAGED_CHUNK_KEYS // bs))
@@ -1275,6 +1290,29 @@ def _ragged_step(i, j, nb):
     return jnp.minimum(j, jnp.maximum(nb[i], 1) - 1)
 
 
+def _lane_packed_queries(q, P: int):
+    """Queries for a lane-packed arena: ``q (B, ng, rep, hs)`` to ``(B, ng / P,
+    P * rep, P * hs)``.  Row ``j * rep + r`` of packed group ``g`` is query ``r``
+    of KV head ``g * P + j`` in lanes ``[j hs, (j + 1) hs)`` and zeros in the
+    other heads' lanes: the zeros cancel those heads' keys in ``q k^T``, so a
+    row's scores are its own head's, exactly."""
+    B, ng, rep, hs = q.shape
+    own = jnp.eye(P, dtype=q.dtype)                                   # (head of the row, head of the lanes)
+    packed = q.reshape(B, ng // P, P, rep, 1, hs) * own[None, None, :, None, :, None]
+    return packed.reshape(B, ng // P, P * rep, P * hs)
+
+
+def _lane_packed_outputs(o, P: int):
+    """The inverse for the walk's output ``(B, ng / P, P * rep, P * hs)``: a
+    row's own head's lanes of ``p v`` (the others hold what its weights make of
+    the neighbouring heads' values, and are dropped) to ``(B, ng, rep, hs)``."""
+    B, gp, rows, lanes = o.shape
+    rep, hs = rows // P, lanes // P
+    o = o.reshape(B, gp, P, rep, P, hs)
+    own = jnp.stack([o[:, :, j, :, j] for j in range(P)], axis=2)     # (B, ng / P, P, rep, hs)
+    return own.reshape(B, gp * P, rep, hs)
+
+
 def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
                       layer, k_scale=None, v_scale=None, window=None):
     """Single-token attention straight off the KV block arena, one layer.
@@ -1289,28 +1327,41 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     afterwards); ``tables``: (B, nbb) int32 sink-padded block tables;
     ``pos``: (B,) int32 global positions; ``k_scale``/``v_scale``:
     (num_blocks, L, ng, bs) float32 dequant scales (both or neither);
-    ``window``: ``cfg.sliding_window``.  On the TPU the chunked walk needs
-    ``hs`` to be a multiple of 128 (:func:`paged_head_size_ok`); other head
-    sizes go a block a grid step (:func:`_decode_by_blocks`), and with a
+    ``window``: ``cfg.sliding_window``.  A **lane-packed** arena
+    (``(num_blocks, L, ng / P, bs, P * hs)``, ``P`` KV heads of a token side by
+    side in a row; told by its last axis against ``q``'s) is walked as ``ng /
+    P`` groups of ``P * rep`` query rows (:func:`_lane_packed_queries`): the
+    same kernel body, twice the matrix unit's passes at ``P`` = 2, half of them
+    on zeros, the arena's bytes read once.  On the TPU the chunked walk needs
+    the arena's rows to be whole 128-lane tiles (:func:`paged_walk_lanes_ok`);
+    other arenas go a block a grid step (:func:`_decode_by_blocks`), and with a
     sliding window they are refused (``paged_supported`` sends such a model to
     the gather path when the engine is built).  ``bs`` = 8 and 16 both
     compile, at int8 and bfloat16.
     Returns (B, nh, hs) attention outputs at ``q.dtype``.
     """
     B, nh, hs = q.shape
-    _, _L, ng, bs, _ = k_arena.shape
-    rep = nh // ng
-    assert rep * ng == nh, (nh, ng)
-    if not paged_head_size_ok(hs):
+    _, _L, ng, bs, lanes = k_arena.shape
+    P = lanes // hs                                      # KV heads a row of the arena
+    assert P * hs == lanes and (P == 1 or k_scale is None), (hs, lanes)
+    rep = nh // (ng * P)
+    assert rep * ng * P == nh, (nh, ng, P)
+    if not paged_walk_lanes_ok(lanes):
         if window is not None:
             raise NotImplementedError(
-                f"paged_attn_decode on the TPU: head_size {hs} is not a multiple "
-                "of 128 and the per-block kernel has no sliding window")
+                f"paged_attn_decode on the TPU: arena rows of {lanes} lanes (head_size {hs}) are not "
+                "whole 128-lane tiles and the per-block kernel has no sliding window")
         return _decode_by_blocks(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos,
                                  layer=layer, k_scale=k_scale, v_scale=v_scale)
     quantized = k_scale is not None
     cdtype = fresh_k.dtype
-    C = paged_kv_chunk_blocks(ng, bs, hs, k_arena.dtype.itemsize)
+    C = paged_kv_chunk_blocks(ng, bs, lanes, k_arena.dtype.itemsize)
+    sm = float(np.sqrt(hs))
+    q = q.reshape(B, ng * P, rep, hs)
+    if P > 1:       # the kernel below sees heads of ``lanes``, ``P * rep`` query rows a group
+        q = _lane_packed_queries(q, P)
+        fresh_k, fresh_v = (x.reshape(B, ng, lanes) for x in (fresh_k, fresh_v))
+        rep, hs = P * rep, lanes
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     # (B, ng, 1, hs): a (1, 1, hs) block of the rank-3 array would tile
@@ -1339,16 +1390,17 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     out = pl.pallas_call(
         functools.partial(
             _paged_kernel, n_arenas=len(arenas), layer=layer, bs=bs, C=C,
-            window=window, cdtype=cdtype, sm=float(np.sqrt(hs)),
+            window=window, cdtype=cdtype, sm=sm,
         ),
         name="paged_attn_decode" + ("_quant" if quantized else ""),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, ng, rep, hs), q.dtype),
         interpret=_interpret(),
         **kwargs,
-    )(tables, pos, q.reshape(B, ng, rep, hs), *arenas,
-      fresh_k[:, :, None, :], fresh_v[:, :, None, :])
-    return out.reshape(B, nh, hs)
+    )(tables, pos, q, *arenas, fresh_k[:, :, None, :], fresh_v[:, :, None, :])
+    if P > 1:
+        out = _lane_packed_outputs(out, P)
+    return out.reshape(B, nh, -1)
 
 
 def _decode_by_blocks(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, **kw):
@@ -1446,7 +1498,9 @@ def paged_token_write(arena, vals, tables, pos, *, block_size, n_emit=None,
     ``arena``: (num_blocks, L, ng, bs, hs) K/V arena — or (num_blocks, L, ng,
     bs) scale arena; ``vals``: (B, L, ng, hs) (or (B, L, ng)) at the arena
     dtype — quantize *before* calling (``quant.quantize_kv``), so the stored
-    values match scatter_token_q exactly.  Each request's destination block
+    values match scatter_token_q exactly.  A lane-packed arena (``(num_blocks,
+    L, ng / P, bs, P * hs)``) takes the same ``vals``: a token's ``P``
+    consecutive heads are one contiguous row, a reshape.  Each request's destination block
     (``tables[i, pos[i] // bs]``) is computed in the BlockSpec index map and
     its slot (``pos[i] % bs``) in the kernel, which rewrites that one block
     with the token's row replaced (:func:`_merge_slot`); the arena aliases
@@ -1469,6 +1523,8 @@ def paged_token_write(arena, vals, tables, pos, *, block_size, n_emit=None,
     """
     prefetch = (tables, pos) if n_emit is None else (
         tables, pos, n_emit.astype(jnp.int32))
+    if arena.ndim == 5 and vals.shape[-1] != arena.shape[-1]:
+        vals = vals.reshape(*vals.shape[:2], arena.shape[2], arena.shape[4])
     a_spec, v_spec = _token_specs(arena, bs=block_size, offset=offset)
     (out,) = _token_call(
         name + ("_masked" if n_emit is not None else ""),
@@ -1833,7 +1889,11 @@ def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     back to gather).  Returns (B, nh, T, hs) at ``q.dtype``.
     """
     B, nh, T, hs = q.shape
-    num_blocks, _L, ng, bs, _ = k_arena.shape
+    num_blocks, _L, ng, bs, lanes = k_arena.shape
+    if lanes != hs:
+        raise NotImplementedError(
+            f"paged_attn_verify: a lane-packed arena (rows of {lanes} lanes for heads of {hs}) has no "
+            "multi-query kernel; build the pool with lane_pack=1")
     nbb = int(tables.shape[1])
     rep = nh // ng
     assert rep * ng == nh, (nh, ng)

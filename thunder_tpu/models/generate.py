@@ -43,11 +43,15 @@ __all__ = [
     "cache_shape",
     "state_shapes",
     "gdn_mixer",
+    "shortconv_mixer",
     "mla_mixer",
     "mla_latent",
     "moe_share_mlp",
+    "moe_row_tile",
     "route_sigmoid_group",
+    "route_sigmoid_bias",
     "kv_block_shape",
+    "kv_lane_pack",
     "ring_slot",
     "ring_gather_positions",
     "sample_token",
@@ -158,19 +162,50 @@ def route_sigmoid_group(scores, cfg: Config):
     return top_w, top_idx
 
 
+def route_sigmoid_bias(scores, bias, cfg: Config):
+    """The biased choice (LFM2; DeepSeek-V3's score correction without its
+    groups) from float32 ``scores (N, E)`` in (0, 1) and ``bias (E,)``: the top
+    ``n_expert_per_token`` of ``scores + bias``; the weights are the chosen
+    *scores*, without the bias, over their sum plus 1e-6 (hf's
+    ``norm_topk_prob``), scaled by ``routed_scaling_factor``.  The bias moves the
+    choice and never a weight.  Returns ``(top_w, top_idx)``, both ``(N, k)``."""
+    _, top_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), cfg.n_expert_per_token)
+    top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+    top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-6) * cfg.routed_scaling_factor
+    return top_w, top_idx
+
+
 MOE_DECODE_ROW_TILE = 16   # bfloat16's sublane tile: the fewest rows moe_grouped_mm compiles for
+
+
+def moe_row_tile(rows_an_expert: float) -> int:
+    """Rows of a tile of the served expert share's sorted rows, from the rows an
+    even routing sends a held expert.  ``moe_grouped_mm`` walks the row tiles
+    and, inside a tile, the column blocks of its expert's weights, so an expert
+    whose rows span two tiles has its weights read twice: a tile holds about
+    twice an expert's even share (a power of two, so that an expert that drew a
+    few rows more still fits one), no fewer than ``MOE_DECODE_ROW_TILE`` and no
+    more than the trainer's ``MOE_ROW_TILE``, which a prompt's rows fill
+    several times over.  (The cell that showed it, 32 rows an expert: tiles of
+    16 read every expert's weights 2.5 times a step, 0.37 of the product's
+    roofline; ``PERF.md`` section 6, PR 39.)"""
+    from thunder_tpu.core.prims import MOE_ROW_TILE
+
+    if rows_an_expert >= MOE_ROW_TILE // 2:
+        return MOE_ROW_TILE
+    return min(MOE_ROW_TILE, max(MOE_DECODE_ROW_TILE, 1 << math.ceil(math.log2(max(2 * rows_an_expert, 1)))))
 
 
 def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear):
     """A SparseMoE layer in the server, on ``x (B, T, C)``: the router scores
-    *all* ``n_expert`` in float32 (:func:`route_sigmoid_group`), the layer
+    *all* ``n_expert`` in float32 (:func:`route_sigmoid_group`, or
+    :func:`route_sigmoid_bias` on ``mp["expert_bias"]``), the layer
     holds experts ``[expert_first, expert_first + expert_held)`` and computes
     their part (``jaxex._moe_share``, the trainer's forward: the step's rows
     sorted by held expert into whole row tiles, grouped products through
     ``moe_grouped_mm``, nothing dropped); what the other experts would add is
     left out, and the shared expert is added once.  A decode step routes a few
-    rows an expert, so its tiles are the narrowest the kernel takes."""
-    from thunder_tpu.core.prims import MOE_ROW_TILE
+    rows an expert, so its tiles are narrow (:func:`moe_row_tile`)."""
     from thunder_tpu.executors import jaxex
 
     B, T, C = x.shape
@@ -178,14 +213,16 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear):
     x2 = x.reshape(B * T, C)
     with scope("router"):
         scores = jax.nn.sigmoid(x2.astype(jnp.float32) @ mp["gate"].T.astype(jnp.float32))
-        top_w, top_idx = route_sigmoid_group(scores, cfg)
-    # rows an even routing sends a held expert: under a tile of them, a tile is mostly padding
-    even = B * T * cfg.n_expert_per_token / cfg.n_expert
+        if cfg.moe_router == "sigmoid_bias":
+            top_w, top_idx = route_sigmoid_bias(scores, mp["expert_bias"], cfg)
+        else:
+            top_w, top_idx = route_sigmoid_group(scores, cfg)
+    even = B * T * cfg.n_expert_per_token / cfg.n_expert      # rows an even routing sends a held expert
     with scope("experts"):
         y = jaxex._moe_share(
             x2, top_idx, top_w, mp["fc_1"].reshape(Eh, C, I), mp["fc_2"].reshape(Eh, C, I),
             mp["proj"].reshape(Eh, I, C), cfg.expert_first, cfg.n_expert,
-            tile=MOE_ROW_TILE if even >= MOE_ROW_TILE // 2 else MOE_DECODE_ROW_TILE)
+            tile=moe_row_tile(even))
     if cfg.shared_expert_size:
         with scope("shared"):
             sp = mp["shared"]
@@ -371,8 +408,10 @@ def _project_qkv(ap, x, cos_t, sin_t, cfg: Config, *, lin=None, lora=None,
         q, k = proj("wq", "bq"), proj("wk", "bk")
         if cfg.qk_norm_whole:   # over the whole projection, before the split into heads
             q, k = _rms(q, ap["q_norm"], cfg.norm_eps), _rms(k, ap["k_norm"], cfg.norm_eps)
-        q = q.reshape(B, T, nh, hs).transpose(0, 2, 1, 3)
-        k = k.reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
+        q, k = q.reshape(B, T, nh, hs), k.reshape(B, T, ng, hs)
+        if cfg.qk_norm:         # a head at a time, one weight of ``hs`` a layer each, before the rotation
+            q, k = _rms(q, ap["q_norm"], cfg.norm_eps), _rms(k, ap["k_norm"], cfg.norm_eps)
+        q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
         v = proj("wv", "bv").reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
     n_elem = cfg.rope_n_elem
     if n_elem > 0:
@@ -409,10 +448,14 @@ def cache_shape(cfg: Config, B: int, T_max: int) -> tuple[int, int, int, int, in
 
 
 def state_shapes(cfg: Config, B: int) -> dict:
-    """What the linear_attention layers keep a sequence, beside the KV:
-    ``conv (L_lin, B, K - 1, channels)``, the conv's last inputs, and ``state
-    (L_lin, B, nv, dk, dv)``, the delta rule's.  Empty for a model without
-    such layers."""
+    """What a sequence keeps beside its KV, by the kind of the model's layers.
+    linear_attention: ``conv (L_lin, B, K - 1, channels)``, the conv's last
+    inputs, and ``state (L_lin, B, nv, dk, dv)``, the delta rule's.  conv (a
+    gated short convolution): ``conv (L_conv, B, conv_kernel - 1, n_embd)``, the
+    conv's last inputs, and nothing else.  Empty for a model of attention
+    layers alone."""
+    if cfg.conv_layers:
+        return {"conv": (len(cfg.conv_layers), B, cfg.conv_kernel - 1, cfg.n_embd)}
     n = len(cfg.linear_layers)
     if not n:
         return {}
@@ -420,18 +463,37 @@ def state_shapes(cfg: Config, B: int) -> dict:
             "state": (n, B, cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim)}
 
 
-def kv_block_shape(cfg: Config, block_size: int) -> tuple[int, int, int, int]:
+def kv_lane_pack(cfg: Config) -> int:
+    """How many KV heads the paged pool may lay side by side in one 128-lane
+    row: ``128 // head_size`` where the head size divides 128 and the KV heads
+    come in whole such sets, else 1.  The chip holds a narrower last axis
+    padded to 128 lanes (twice the bytes at a head of 64) and Mosaic refuses
+    to copy a slice of such a row, so a packed arena is what lets a narrow head
+    ride ``paged_attn_decode``'s walk."""
+    hs, ng = cfg.head_size, cfg.n_query_groups
+    if cfg.latent or hs >= 128 or 128 % hs:
+        return 1
+    pack = 128 // hs
+    return pack if ng % pack == 0 else 1
+
+
+def kv_block_shape(cfg: Config, block_size: int, lane_pack: int = 1) -> tuple[int, int, int, int]:
     """Per-block geometry ``(L, n_query_groups, block_size, hs)`` of the
     paged serving pool's arena — one block holds ``block_size`` consecutive
     token slots of every layer's K (or V), so a gather over a request's
-    block table reassembles exactly the :func:`cache_shape` layout.  A
+    block table reassembles exactly the :func:`cache_shape` layout.  With
+    ``lane_pack`` P > 1 (:func:`kv_lane_pack`; the pool's choice) a row holds
+    P consecutive KV heads of one token side by side: ``(L, n_query_groups / P,
+    block_size, P * hs)``, head ``g`` in lanes ``[(g % P) hs, (g % P + 1) hs)``
+    of group ``g // P``, the same bytes in the same order as ``(token, head,
+    hs)`` would give.  A
     latent-attention model's one arena holds ``(L, 1, block_size, W)``: a row
     is a token's latent and its rotated key, ``W`` their ``latent_width`` padded
     with zeros to whole 128-lane tiles (576 to 640), which is how the chip
     lays a narrower row out anyway and what the decode kernel's copies need."""
     if cfg.latent:
         return (cfg.n_layer, 1, block_size, -(-cfg.latent_width // 128) * 128)
-    return (len(cfg.kv_layers), cfg.n_query_groups, block_size, cfg.head_size)
+    return (len(cfg.kv_layers), cfg.n_query_groups // lane_pack, block_size, cfg.head_size * lane_pack)
 
 
 def ring_slot(pos, window: int):
@@ -481,7 +543,8 @@ def init_cache(cfg: Config, B: int, T_max: int, dtype=jnp.bfloat16, *, mesh=None
     shapes = state_shapes(cfg, B)
     if shapes:
         assert mesh is None, "a recurrent state has no sharded cache yet"
-        cache.update(conv=jnp.zeros(shapes["conv"], dtype), state=jnp.zeros(shapes["state"], jnp.float32))
+        cache.update({name: jnp.zeros(shape, jnp.float32 if name == "state" else dtype)
+                      for name, shape in shapes.items()})
     return cache
 
 
@@ -615,6 +678,21 @@ def _l2norm(x, eps: float = 1e-6):
     return (xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)).astype(x.dtype)
 
 
+def _causal_taps(tail, x, w, n_real=None):
+    """A causal depthwise conv over time in XLA, its state carried: ``x (B, T,
+    channels)`` after ``tail (B, K - 1, channels)``, the inputs of the K - 1
+    tokens before it; ``w (channels, K)``, tap j of a channel weighing the token
+    K - 1 - j back; no bias.  Returns the sums in float32 ``(B, T, channels)``
+    and the new tail, which ends at token ``n_real`` (the last, where None) and
+    keeps ``tail``'s dtype."""
+    T, K = x.shape[1], w.shape[1]
+    seen = jnp.concatenate([tail.astype(x.dtype), x], axis=1)                          # (B, K - 1 + T, channels)
+    new_tail = (seen[:, T:] if n_real is None
+                else jax.lax.dynamic_slice_in_dim(seen, n_real, K - 1, axis=1)).astype(tail.dtype)
+    w = w.astype(jnp.float32)
+    return sum(seen[:, j:j + T].astype(jnp.float32) * w[:, j] for j in range(K)), new_tail
+
+
 def gdn_mixer(gp, x, tail, cfg: Config, recur, *, n_real=None, lin=_linear):
     """A linear_attention layer's mixer on new tokens ``x (B, T, C)``, for the
     dense cache and the paged server alike (``llama.gated_delta_net`` with its
@@ -629,18 +707,14 @@ def gdn_mixer(gp, x, tail, cfg: Config, recur, *, n_real=None, lin=_linear):
     ends at the last real token.  Returns ``(y (B, T, C), new tail)``."""
     B, T, _ = x.shape
     nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-    dk, dv, K = cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.linear_conv_kernel
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     n_qkv = cfg.linear_qkv_width
     with scope("gdn/in_proj"):
         qkvz, ba = lin(x, gp["in_proj_qkvz"]), lin(x, gp["in_proj_ba"])
         z = qkvz[..., n_qkv:]
     with scope("gdn/conv"):
-        seen = jnp.concatenate([tail.astype(qkvz.dtype), qkvz[..., :n_qkv]], axis=1)    # (B, K - 1 + T, channels)
-        new_tail = (seen[:, T:] if n_real is None
-                    else jax.lax.dynamic_slice_in_dim(seen, n_real, K - 1, axis=1)).astype(tail.dtype)
-        # causal depthwise conv, no bias: tap j of a channel weighs the token K - 1 - j back
-        w = gp["conv_w"].astype(jnp.float32)
-        qkv = jax.nn.silu(sum(seen[:, j:j + T].astype(jnp.float32) * w[:, j] for j in range(K))).astype(x.dtype)
+        conv, new_tail = _causal_taps(tail, qkvz[..., :n_qkv], gp["conv_w"], n_real)
+        qkv = jax.nn.silu(conv).astype(x.dtype)
     with scope("gdn/gates"):
         heads = lambda a, n, d: a.reshape(B, T, n, d).transpose(0, 2, 1, 3)  # noqa: E731
         q = _l2norm(heads(qkv[..., :nk * dk], nk, dk)) * (dk ** -0.5)
@@ -657,6 +731,28 @@ def gdn_mixer(gp, x, tail, cfg: Config, recur, *, n_real=None, lin=_linear):
     with scope("gdn/out"):
         o = _rms(o.transpose(0, 2, 1, 3), gp["norm"], cfg.norm_eps) * jax.nn.silu(z.reshape(B, T, nv, dv))
         return lin(o.reshape(B, T, nv * dv), gp["out_proj"]), new_tail
+
+
+def shortconv_mixer(cp, x, tail, cfg: Config, *, n_real=None, lin=_linear):
+    """A conv layer's mixer (a gated short convolution, hf ``Lfm2ShortConv``) on
+    new tokens ``x (B, T, C)``, for the dense cache and the paged server alike:
+    ``[B | C | u] = x W_in``; ``v = B * u``; a causal depthwise conv of
+    ``conv_kernel`` taps over ``v`` (tap j of a channel weighs the token K - 1 -
+    j back; no bias, no activation), in float32 over ``[tail | v]``; ``y = C *
+    conv``; ``W_out``.  ``tail (B, K - 1, C)`` holds ``v`` of the K - 1 tokens
+    before ``x`` (zeros before a sequence's first), and is all a sequence
+    keeps.  Of the T tokens the first ``n_real`` are real (all, where None): the
+    new tail ends at the last real one.  Returns ``(y (B, T, C), new tail)``."""
+    with scope("conv/in_proj"):
+        b, c, u = jnp.split(lin(x, cp["in_proj"]), 3, axis=-1)
+    with scope("conv/gate"):
+        v = b * u
+    with scope("conv/conv"):
+        conv, new_tail = _causal_taps(tail, v, cp["conv_w"], n_real)
+    with scope("conv/gate"):
+        y = (c.astype(jnp.float32) * conv).astype(x.dtype)
+    with scope("conv/out"):
+        return lin(y, cp["out_proj"]), new_tail
 
 
 def gdn_recur_dense(state):
@@ -686,7 +782,8 @@ def gdn_recur_dense(state):
 
 def require_servable(cfg: Config) -> None:
     """The one refusal of a config this module's forward cannot run (an
-    expert share routed by softmax, gated or per-head-normed attention): such a model trains
+    expert share routed by softmax, attention with an output gate, norms with
+    zero-centred weights: ``Config.training_only``): such a model trains
     through ``tt.jit`` / ``make_train_step``; serving it is not built yet."""
     why = getattr(cfg, "training_only", None)
     if why:
@@ -743,7 +840,8 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
     A model with linear_attention layers keeps ``cache["conv"]`` and
     ``cache["state"]`` beside ``k``/``v`` (:func:`state_shapes`), which hold
     the full-attention layers only; they are the conv's last inputs and the
-    delta rule's state *before* position ``pos``.  ``n_real``: how many of the
+    delta rule's state *before* position ``pos``.  A model with conv layers
+    keeps ``cache["conv"]`` alone (:func:`shortconv_mixer`'s tails).  ``n_real``: how many of the
     T tokens are real (a padded prompt: the tail must leave the state alone).
 
     ``lora``: optional per-request LoRA factors —
@@ -791,6 +889,10 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
                     h, tail = gdn_mixer(bp["gdn"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
                     new_conv.append(tail)
                     new_state.append(box[0])
+                elif cfg.layer_kind(l) == "conv":
+                    h, tail = shortconv_mixer(bp["conv"], n1, cache["conv"][len(new_conv)], cfg,
+                                              n_real=n_real, lin=lin)
+                    new_conv.append(tail)
                 elif cfg.latent:
                     h, cl = _mla_with_cache(bp["attn"], n1, cos_t, sin_t, cache["latent"][l], pos, cfg,
                                             quantized=quantized, sharded=sharded)
@@ -807,8 +909,10 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
 
     with scope("mixer/cache"):
         cache = {"latent": jnp.stack(new_latent)} if cfg.latent else {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+        if new_conv:
+            cache.update(conv=jnp.stack(new_conv))
         if new_state:
-            cache.update(conv=jnp.stack(new_conv), state=jnp.stack(new_state))
+            cache.update(state=jnp.stack(new_state))
     return _head_logits(params, x, cfg, logits_at, quantized), cache
 
 

@@ -95,9 +95,15 @@ class Config:
     # is the exact erf form
     gelu_approximate: str = "none"
     # A kind per layer (hf ``layer_types``): "full_attention" (softmax
-    # attention, the default for every layer) or "linear_attention" (a gated
-    # delta-rule mixer, below).  None = all full attention
+    # attention, the default for every layer), "linear_attention" (a gated
+    # delta-rule mixer, below) or "conv" (a gated short convolution, below; in
+    # the server alone).  None = all full attention
     layer_types: tuple | None = None
+    # The gated short convolution of "conv" layers (hf Lfm2ShortConv): ``[B | C |
+    # u] = x W_in``, a causal depthwise conv of ``conv_kernel`` taps (hf
+    # ``conv_L_cache``) without bias or activation over ``B * u``, gated by ``C``,
+    # then ``W_out``.  A sequence keeps the conv's last ``conv_kernel - 1`` inputs
+    conv_kernel: int = 3
     # Gated softmax attention: RMSNorm of q and k a head, and an output gate
     # (``wq`` projects to q and gate; o <- o * sigmoid(gate) before ``wo``)
     qk_norm: bool = False
@@ -134,8 +140,10 @@ class Config:
     # (DeepSeek-V3): float32 sigmoid scores over all experts in ``n_group``
     # groups, a group's score the sum of its two best, the best ``topk_group``
     # groups kept, the top ``n_expert_per_token`` of what is left renormalised
-    # and scaled by ``routed_scaling_factor``.  ``shared_expert_gate`` False:
-    # the shared expert is added ungated
+    # and scaled by ``routed_scaling_factor``; "sigmoid_bias" (LFM2): the same
+    # scores, the top ``n_expert_per_token`` chosen on ``score + expert_bias`` (a
+    # float32 vector a layer), the weights the chosen *scores* renormalised and
+    # scaled.  ``shared_expert_gate`` False: the shared expert is added ungated
     moe_router: str = "softmax"
     n_group: int = 1
     topk_group: int = 1
@@ -198,7 +206,7 @@ class Config:
                 self.expert_held = self.n_expert - self.expert_first
             assert 0 <= self.expert_first and 0 < self.expert_held <= self.n_expert - self.expert_first
             assert not self.bias, "bias is not supported for the MoE MLP"
-            assert self.moe_router in ("softmax", "sigmoid_group"), self.moe_router
+            assert self.moe_router in ("softmax", "sigmoid_group", "sigmoid_bias"), self.moe_router
             if self.moe_router == "sigmoid_group":
                 assert self.n_expert % self.n_group == 0 and 0 < self.topk_group <= self.n_group
                 assert self.n_expert // self.n_group >= 2, "a group's score is the sum of its two best"
@@ -210,7 +218,12 @@ class Config:
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             assert len(self.layer_types) == self.n_layer, "layer_types needs one kind a layer"
-            assert set(self.layer_types) <= {"full_attention", "linear_attention"}, self.layer_types
+            assert set(self.layer_types) <= {"full_attention", "linear_attention", "conv"}, self.layer_types
+            assert not {"linear_attention", "conv"} <= set(self.layer_types), (
+                "linear_attention and conv layers in one model: a request's state slot holds one kind's arenas")
+            if "conv" in self.layer_types:
+                assert self.conv_kernel >= 2, "conv layers need conv_kernel >= 2 taps"
+                assert not self.bias and not self.parallel_residual, "conv: sequential, bias-free blocks only"
             if "linear_attention" in self.layer_types:
                 nk, nv = self.linear_num_key_heads, self.linear_num_value_heads
                 assert nk > 0 and nv % nk == 0 and self.linear_key_head_dim > 0 and self.linear_value_head_dim > 0, (
@@ -275,6 +288,18 @@ class Config:
         return tuple(i for i in range(self.n_layer) if self.layer_kind(i) == "linear_attention")
 
     @property
+    def conv_layers(self) -> tuple:
+        """The model layers that keep a short convolution's tail and nothing
+        else, in order (``conv_layers.index(i)`` is the layer of the conv arena)."""
+        return tuple(i for i in range(self.n_layer) if self.layer_kind(i) == "conv")
+
+    @property
+    def state_layers(self) -> tuple:
+        """The layers that keep something a request beside K and V (a slot of
+        the server's state pool): the linear_attention or the conv layers."""
+        return self.linear_layers or self.conv_layers
+
+    @property
     def linear_qkv_width(self) -> int:
         """Channels of a linear_attention layer's conv: q and k a key head, v a value head."""
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim
@@ -283,11 +308,12 @@ class Config:
     @property
     def training_only(self) -> str | None:
         """Why ``models.generate`` and ``tt.serve`` cannot run this config, or
-        None: the server's expert share has one router, and no gated attention yet."""
+        None: the server's expert share routes by sigmoid scores alone, its
+        attention has no output gate and its norms no zero-centred weights."""
         if self.mlp_class == "SparseMoE" and self.moe_router == "softmax":
             return ("its mlp_class is SparseMoE with the softmax router (the serving forward's expert "
-                    "share routes by moe_router='sigmoid_group' alone)")
-        for knob in ("attn_output_gate", "qk_norm", "norm_zero_centered"):
+                    "share routes by moe_router='sigmoid_group' or 'sigmoid_bias')")
+        for knob in ("attn_output_gate", "norm_zero_centered"):
             if getattr(self, knob):
                 return f"it sets {knob} (the serving forward's attention and norms have no such form)"
         return None
@@ -451,6 +477,13 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 "norm": jnp.ones((dv,), dtype=dtype),
                 "out_proj": dense(next(keys), nv * dv, config.n_embd),
             }
+        elif config.layer_kind(i) == "conv":
+            C = config.n_embd
+            block["conv"] = {
+                "in_proj": dense(next(keys), C, 3 * C),           # [B | C | u]
+                "conv_w": dense(next(keys), config.conv_kernel, C),
+                "out_proj": dense(next(keys), C, C),
+            }
         elif config.latent:
             dc, dr, rq = config.kv_lora_rank, config.qk_rope_head_dim, config.q_lora_rank
             block["attn"] = {
@@ -473,7 +506,7 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 block["attn"].update(q_norm=norm_init((hs,), dtype=dtype), k_norm=norm_init((hs,), dtype=dtype))
             if config.qk_norm_whole:
                 block["attn"].update(q_norm=norm_init((nh * hs,), dtype=dtype), k_norm=norm_init((ng * hs,), dtype=dtype))
-        if config.bias:
+        if config.bias:     # never beside a linear_attention or conv layer (Config refuses)
             block["norm_1_b"] = zeros(config.n_embd)
             block["attn"].update(
                 bq=zeros(nh * hs), bk=zeros(ng * hs), bv=zeros(ng * hs), bo=zeros(config.n_embd)
@@ -513,6 +546,8 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 "fc_2": dense(next(keys), I, Eh * C),
                 "proj": dense(next(keys), C, Eh * I),
             }
+            if config.moe_router == "sigmoid_bias":     # hf starts it at zero; it takes no gradient
+                block["mlp"]["expert_bias"] = jnp.zeros((config.n_expert,), jnp.float32)
             if config.shared_expert_size:
                 Is = config.shared_expert_size
                 block["mlp"]["shared"] = {
@@ -845,14 +880,18 @@ def mlp(mp, x, config: Config):
 
 def serving_only(config: Config) -> str | None:
     """Why ``block_forward`` (``tt.jit`` / ``make_train_step``) cannot run this
-    config, or None: latent attention, the group-limited router and leading
-    dense layers are built in ``models.generate`` for the server alone."""
+    config, or None: latent attention, the gated short convolution, the sigmoid
+    routers and leading dense layers are built in ``models.generate`` for the
+    server alone."""
+    if config.conv_layers:
+        return ("layer_types with 'conv' (the gated short convolution is built in models.generate, for tt.serve, "
+                "and has no traced form)")
     if config.latent:
         return "kv_lora_rank > 0 (latent attention is built in models.generate, for tt.serve, and has no traced form)"
     if config.mlp_class == "SparseMoE" and (config.moe_router != "softmax" or config.first_k_dense
                                             or (config.shared_expert_size and not config.shared_expert_gate)):
-        return ("a SparseMoE layer with moe_router='sigmoid_group', first_k_dense or an ungated shared expert "
-                "(built in models.generate, for tt.serve; the traced expert layer routes by softmax)")
+        return ("a SparseMoE layer with moe_router='sigmoid_group' or 'sigmoid_bias', first_k_dense or an ungated "
+                "shared expert (built in models.generate, for tt.serve; the traced expert layer routes by softmax)")
     return None
 
 

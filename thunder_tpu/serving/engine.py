@@ -257,11 +257,12 @@ _program_cache: dict = {}
 def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=None, lora=None,
                        mesh=None, decode_steps: int = 1, model_fn=None) -> str | None:
     """Why an engine with these options cannot serve a config that keeps a
-    recurrent state a request, or None.  Each is a mechanism that is not
-    built, not a shortcut that was skipped (ROADMAP Queue 2)."""
+    state a request beside its KV (a delta rule's recurrent state and conv
+    tail, or a short convolution's tail alone), or None.  Each is a mechanism
+    that is not built, not a shortcut that was skipped (ROADMAP Queue 2)."""
     if prefix_sharing:
         return ("prefix_sharing=True is unsupported: a prefix's KV blocks can be shared, its "
-                "recurrent state cannot (no snapshot of the state at a block edge is kept)")
+                "recurrent state or conv tail cannot (no snapshot of the state at a block edge is kept)")
     if sessions is not None and sessions is not False:
         return ("sessions= is unsupported: a parked session re-attaches through the shared-prefix "
                 "path, which has no state snapshot to resume from")
@@ -270,7 +271,7 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
                 "state, and there is no rollback")
     if lora is not None:
         return ("lora= is unsupported: the adapter arenas are a model layer's attention and MLP "
-                "targets; the mixer's projections (in_proj_qkvz, out_proj) have none")
+                "targets; the mixer's projections (in_proj_qkvz, in_proj, out_proj) have none")
     if mesh is not None:
         return "mesh= is unsupported: the state arena has no layout under a tp axis"
     if int(decode_steps) > 1:
@@ -279,7 +280,8 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
     if model_fn is not None:
         return "a custom model_fn is unsupported: the state programs mirror forward_with_cache"
     if cfg.sliding_window is not None:
-        return "a sliding window is unsupported beside a recurrent state (block expiry is untested with it)"
+        return ("a sliding window is unsupported beside a recurrent state or conv tail "
+                "(block expiry is untested with it)")
     return None
 
 def latent_unsupported(cfg, *, kv_dtype=None, cache_dtype=None, speculative=None, lora=None, mesh=None,
@@ -363,17 +365,18 @@ class ServingEngine:
 
         require_servable(cfg)
         # a model with linear_attention layers keeps a recurrent state a
-        # request beside its KV; what such a state cannot serve yet refuses
-        # here, with its reason (as paged_supported does for the kernels)
-        self._hybrid = bool(getattr(cfg, "linear_layers", ()))
+        # request beside its KV, one with conv layers a conv tail; what such a
+        # state cannot serve yet refuses here, with its reason (as
+        # paged_supported does for the kernels)
+        self._hybrid = bool(getattr(cfg, "state_layers", ()))
         if self._hybrid:
             why = hybrid_unsupported(
                 cfg, prefix_sharing=prefix_sharing, sessions=sessions, speculative=speculative,
                 lora=lora, mesh=mesh, decode_steps=decode_steps, model_fn=model_fn)
             if why:
-                raise NotImplementedError(
-                    f"config {getattr(cfg, 'name', '?')!r} has linear_attention layers "
-                    f"(a recurrent state a request): {why}")
+                kind = ("linear_attention layers (a recurrent state a request)" if cfg.linear_layers
+                        else "conv layers (a conv tail a request)")
+                raise NotImplementedError(f"config {getattr(cfg, 'name', '?')!r} has {kind}: {why}")
             prefix_sharing = False
         self._latent = bool(getattr(cfg, "latent", False))
         if self._latent:
@@ -426,6 +429,9 @@ class ServingEngine:
             kv_dtype=kv_dtype, mesh=mesh,
             # a state slot a batch slot: a request that has one never waits for the other
             **({"state_slots": max_batch} if self._hybrid else {}),
+            # a draft's verify attends several tokens a row through the per-block
+            # kernel, which reads a head a row: speculation keeps that layout
+            **({"lane_pack": 1} if speculative is not None else {}),
         )
         # decode attention path, resolved ONCE at construction (each engine
         # builds exactly one decode program kind, so the program-set bound
@@ -441,7 +447,8 @@ class ServingEngine:
         from thunder_tpu.executors.pallasex import paged_available
         from thunder_tpu.serving.paged_attention import paged_supported
 
-        ok, why = paged_supported(cfg, self._forward is forward_with_cache, mesh)
+        ok, why = paged_supported(cfg, self._forward is forward_with_cache, mesh,
+                                  arena_lanes=None if self._latent else self.pool.k_arena.shape[-1])
         self._attn_requested = attn
         if attn == "paged":
             if not ok:
@@ -461,6 +468,7 @@ class ServingEngine:
         # keys a step of paged_attn_decode's walk attends (a group), from the
         # arena shard the decode program's kernel is handed; None on gather
         self._kv_chunk_tokens = None
+        self._attn_path = None
         if self._latent:
             from thunder_tpu.executors.pallasex import _MLA_CHUNK_KEYS
 
@@ -468,10 +476,13 @@ class ServingEngine:
         elif self.attn == "paged":
             from thunder_tpu.executors.pallasex import paged_kv_chunk_blocks
 
+            from thunder_tpu.executors.pallasex import paged_walk_lanes_ok
+
             arena = self.pool.k_arena
             _, _, ng, bs, hs = arena.sharding.shard_shape(arena.shape)
             self._kv_chunk_tokens = bs * paged_kv_chunk_blocks(
                 ng, bs, hs, arena.dtype.itemsize)
+            self._attn_path = "walk" if paged_walk_lanes_ok(hs) else "by_blocks"
         # multi-tenant LoRA: a bounded AdapterRegistry shared across engines;
         # its stacked factor arenas are program *arguments* (register/evict
         # are data writes), only its geometry enters the program identity
@@ -523,7 +534,7 @@ class ServingEngine:
                 speculative.draft_params = _pp(speculative.draft_params, mesh, None)
             self.draft_pool = PagedKVPool(
                 speculative.draft_cfg, num_blocks=num_blocks,
-                block_size=block_size, dtype=dtype,
+                block_size=block_size, dtype=dtype, lane_pack=1,
                 # the draft arena may quantize independently of the target
                 # (SpecConfig.draft_kv_dtype; None inherits kv_dtype)
                 kv_dtype=(speculative.draft_kv_dtype
@@ -600,6 +611,9 @@ class ServingEngine:
             chunk_why = "sliding-window keep-mask is decode-only"
         elif self._latent:
             chunk_why = "a latent cache's piece attends its expanded keys (the dense form)"
+        elif self.pool.lane_pack > 1:
+            chunk_why = (f"a lane-packed arena ({self.pool.lane_pack} KV heads a row) has no multi-query "
+                         "kernel: a chunk attends its gathered keys")
         elif sch.prefill_chunk is not None and sch.prefill_chunk % block_size:
             chunk_why = (f"prefill_chunk={sch.prefill_chunk} not a multiple "
                          f"of block_size={block_size}")
@@ -1107,7 +1121,9 @@ class ServingEngine:
         dequantised, at the compute dtype), or, for a latent-attention model,
         ``latent (L, tokens, latent_width)`` in their place; and, for a model with
         linear_attention layers, its slot's ``state (L_lin, nv, dk, dv)`` and
-        ``conv (L_lin, K - 1, channels)`` as stored.  For the tests and for a
+        ``conv (L_lin, K - 1, channels)`` as stored (conv layers: ``conv
+        (L_conv, conv_kernel - 1, n_embd)`` alone).  A lane-packed arena's
+        heads come apart again.  For the tests and for a
         comparison with a reference; changes nothing."""
         if self.async_step:
             self._harvest()
@@ -1123,7 +1139,7 @@ class ServingEngine:
             k, v = gather_dense_q(arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
                                   table, self.pool.dtype)
         else:
-            k, v = gather_dense(arenas["k"], arenas["v"], table)
+            k, v = gather_dense(arenas["k"], arenas["v"], table, self.pool.lane_pack)
         out = {"tokens": req.pos, "k": k[:, 0, :, :req.pos], "v": v[:, 0, :, :req.pos]}
         if self._hybrid:
             out.update({name: arena[req.state_slot] for name, arena in self.pool.state.arenas.items()})
@@ -1228,6 +1244,11 @@ class ServingEngine:
                 "kernel_steps": self.attn_kernel_steps,
                 "fallback_steps": self.attn_fallback_steps,
                 "kv_chunk_tokens": self._kv_chunk_tokens,
+                # which form the decode kernel takes for this arena (None on
+                # gather or a latent arena): the chunked walk, or a block a grid
+                # step; and the KV heads a row of the arena holds
+                "path": self._attn_path,
+                "lane_pack": self.pool.lane_pack,
                 # per-kind resolution: decode and chunk-prefill resolve
                 # independently (the chunk kernel needs block-aligned
                 # widths and no sliding window), so a single top-level
@@ -2914,7 +2935,7 @@ class ServingEngine:
             kd, vd = gather_dense_q(
                 arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"], tables, cdtype)
         else:
-            kd, vd = gather_dense(arenas["k"], arenas["v"], tables)
+            kd, vd = gather_dense(arenas["k"], arenas["v"], tables, self.pool.lane_pack)
         return {"k": kd, "v": vd}
 
     @scope("mixer/cache")
@@ -3126,7 +3147,7 @@ class ServingEngine:
                         tables, cdtype,
                     )
                 else:
-                    kd, vd = gather_dense(arenas["k"], arenas["v"], tables)
+                    kd, vd = gather_dense(arenas["k"], arenas["v"], tables, self.pool.lane_pack)
                 if hybrid:
                     # the rows' state slots ride before the constraint mask
                     sslots, cmask = cmask[0], cmask[1:]
@@ -3263,7 +3284,7 @@ class ServingEngine:
                         arenas["k_scale"], arenas["v_scale"], tables, cdtype,
                     )
                 else:
-                    kd, vd = gather_dense(arenas["k"], arenas["v"], tables)
+                    kd, vd = gather_dense(arenas["k"], arenas["v"], tables, self.pool.lane_pack)
                 logits, cache = fwd(
                     params, toks[:, None], pos, {"k": kd, "v": vd},
                     cos_all, sin_all, cfg, **kw,

@@ -6,7 +6,12 @@ fixed-size **blocks** — ``(num_blocks, L, n_query_groups, block_size, hs)``
 for K and V each (per-block geometry from
 :func:`models.generate.kv_block_shape`, so a gather over a request's block
 table reassembles exactly the dense :func:`models.generate.cache_shape`
-layout that ``forward_with_cache`` already consumes).  Fragmentation is
+layout that ``forward_with_cache`` already consumes).  A head size that
+divides 128 is stored **lane-dense**: ``P = 128 // hs`` consecutive KV heads of
+a token side by side in one 128-lane row, ``(num_blocks, L, n_query_groups / P,
+block_size, 128)`` (``PagedKVPool.lane_pack``), because the chip would hold a
+narrower last axis padded to 128 lanes (twice the bytes at a head of 64) and
+the decode kernel's walk cannot copy a slice of such a row.  Fragmentation is
 bounded to one partial block per request, admission control becomes a free-
 block count, and finished/expired requests return their blocks in O(blocks).
 
@@ -50,11 +55,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from thunder_tpu.models.generate import kv_block_shape
+from thunder_tpu.models.generate import kv_block_shape, kv_lane_pack
 from thunder_tpu.serving.quant import is_quantized_kv, resolve_kv_dtype
 
 __all__ = ["PoolExhaustedError", "ArenaMismatchError", "PagedKVPool", "StatePool",
-           "PrefixIndex", "chunk_tables", "dest_for_pos", "gather_state", "scatter_state", "gather_rows",
+           "PrefixIndex", "chunk_tables", "dest_for_pos", "gather_state", "scatter_state", "gather_rows", "pack_lanes",
            "OCCUPANCY_WINDOW"]
 
 SINK_BLOCK = 0  # reserved physical block for padding/expired table entries
@@ -94,12 +99,15 @@ SINK_SLOT = 0   # reserved state slot for padding rows, as block 0 is for table 
 
 
 class StatePool:
-    """What a model's linear_attention layers keep a request, beside its KV
-    blocks: a slot-indexed arena of the delta rule's states, ``state (slots +
+    """What a model's linear_attention or conv layers keep a request, beside
+    its KV blocks: slot-indexed arenas of what ``generate.state_shapes`` names.
+    linear_attention: the delta rule's states, ``state (slots +
     1, L_lin, nv, dk, dv)`` in float32 (``STATE_DTYPE``: no option of the
     engine's, a deployment holds what the configuration states),
-    and of the conv's last inputs, ``conv (slots + 1, L_lin, K - 1,
-    channels)`` at the compute dtype.  A request leases one slot at admission
+    and the conv's last inputs, ``conv (slots + 1, L_lin, K - 1,
+    channels)`` at the compute dtype.  conv (a gated short convolution): the
+    tails ``conv (slots + 1, L_conv, conv_kernel - 1, n_embd)`` at the compute
+    dtype, and no ``state``.  A request leases one slot at admission
     and gives it back when it finishes; slot 0 is the garbage sink padding
     rows of a static-shape program point at.  The arrays travel in the KV
     pool's ``arenas`` pytree (donated beside K and V, rebuilt with them); a
@@ -116,20 +124,33 @@ class StatePool:
         shapes = state_shapes(cfg, slots + 1)
         # slot-major: a row's state is one contiguous slab a kernel can name by its slot
         self.shapes = {k: (v[1], v[0], *v[2:]) for k, v in shapes.items()}
-        self.dtypes = {"state": jnp.dtype(self.STATE_DTYPE), "conv": jnp.dtype(dtype)}
+        self.dtypes = {k: jnp.dtype(self.STATE_DTYPE if k == "state" else dtype) for k in self.shapes}
         self.num_slots = int(slots)
-        self.layers = len(cfg.linear_layers)
+        self.layers = len(cfg.state_layers)
         self._free: list[int] = list(range(self.num_slots, SINK_SLOT, -1))   # pop() -> lowest id
         self._free_low_water = len(self._free)
         self.rebuild()
 
     def rebuild(self) -> None:
-        self.state = jnp.zeros(self.shapes["state"], self.dtypes["state"])
-        self.conv = jnp.zeros(self.shapes["conv"], self.dtypes["conv"])
+        self._arenas = {k: jnp.zeros(shape, self.dtypes[k]) for k, shape in self.shapes.items()}
 
     @property
     def arenas(self) -> dict:
-        return {"state": self.state, "conv": self.conv}
+        """``{"state", "conv"}`` for linear_attention layers, ``{"conv"}`` for conv layers."""
+        return dict(self._arenas)
+
+    @property
+    def state(self):
+        """The delta rule's state arena, or None for a model of conv layers."""
+        return self._arenas.get("state")
+
+    @property
+    def conv(self):
+        return self._arenas["conv"]
+
+    def install(self, arenas: dict) -> None:
+        """The arenas a donated program returned, in place of the ones it took."""
+        self._arenas = {k: arenas[k] for k in self.shapes}
 
     @property
     def free_low_water(self) -> int:
@@ -158,18 +179,19 @@ class StatePool:
         self._free.append(slot)
 
     def slot_bytes(self) -> int:
-        """Bytes one slot costs across both arenas: the unit beside
+        """Bytes one slot costs across its arenas: the unit beside
         ``block_bytes`` in byte-based admission."""
-        return (int(self.state.nbytes) + int(self.conv.nbytes)) // (self.num_slots + 1)
+        return self.arena_bytes() // (self.num_slots + 1)
 
     def arena_bytes(self) -> int:
-        return int(self.state.nbytes) + int(self.conv.nbytes)
+        return sum(int(a.nbytes) for a in self._arenas.values())
 
     def snapshot(self) -> dict:
+        """``dtype`` is the recurrent state's storage where there is one, else the tails'."""
         return {"slots": self.num_slots, "leased": self.leased,
                 "free_low_water": self._free_low_water, "arena_bytes": self.arena_bytes(),
-                "slot_bytes": self.slot_bytes(), "layers": self.layers,
-                "dtype": str(self.dtypes["state"]), "conv_dtype": str(self.dtypes["conv"]),
+                "slot_bytes": self.slot_bytes(), "layers": self.layers, "arenas": sorted(self.shapes),
+                "dtype": str(self.dtypes.get("state", self.dtypes["conv"])), "conv_dtype": str(self.dtypes["conv"]),
                 "fill_frac": self.leased / self.num_slots}
 
 
@@ -191,7 +213,7 @@ class PagedKVPool:
 
     def __init__(self, cfg, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
                  *, kv_dtype=None, mesh=None, axis: str = "tp",
-                 state_slots: int | None = None):
+                 state_slots: int | None = None, lane_pack: int | None = None):
         if num_blocks < 2:
             raise ValueError(f"num_blocks must be >= 2 (block 0 is the sink), got {num_blocks}")
         if block_size < 1:
@@ -210,7 +232,16 @@ class PagedKVPool:
         self.latent = bool(getattr(cfg, "latent", False))
         if self.latent and (self.quantized_kv or mesh is not None):
             raise ValueError("a latent arena has no quantised storage and no layout under a mesh")
-        shape = (self.num_blocks, *kv_block_shape(cfg, self.block_size))
+        # KV heads side by side in a 128-lane row (``generate.kv_lane_pack``):
+        # where the head size divides 128, for an arena stored at the compute
+        # dtype on one device.  A quantised arena keeps a head a row (its scale
+        # is a head's) and so does a sharded one (``kv_cache_spec`` splits the
+        # heads axis); ``lane_pack=1`` asks for that layout outright
+        auto = 1 if (self.quantized_kv or mesh is not None) else kv_lane_pack(cfg)
+        self.lane_pack = auto if lane_pack is None else int(lane_pack)
+        if self.lane_pack not in (1, auto):
+            raise ValueError(f"lane_pack={lane_pack}: this config and storage pack {auto} KV heads a row, or 1")
+        shape = (self.num_blocks, *kv_block_shape(cfg, self.block_size, self.lane_pack))
         self._arena_shape = shape
         self._scale_shape = shape[:-1]                  # absmax over hs
         if mesh is not None:
@@ -228,9 +259,9 @@ class PagedKVPool:
         # layers keeps a state slot a request beside its blocks (the K/V
         # arenas' layer axis then holds the full-attention layers only)
         self.state = None
-        if getattr(cfg, "linear_layers", ()):
+        if getattr(cfg, "state_layers", ()):
             if state_slots is None:
-                raise ValueError("a config with linear_attention layers needs state_slots=")
+                raise ValueError("a config with linear_attention or conv layers needs state_slots=")
             self.state = StatePool(cfg, state_slots, dtype)
         # independent buffers (no copy traffic between K and V updates)
         self.k_arena = self._zeros(shape, self.kv_dtype)
@@ -359,16 +390,22 @@ class PagedKVPool:
     def kind_snapshot(self) -> dict:
         """What a block's row is (``kv``: keys and values a head; ``latent``:
         one latent a token, all heads) and a token's bytes over all layers:
-        as counted from the model's widths, and as the arena's rows hold them
-        (a latent row is padded to whole lane tiles)."""
+        as counted from the model's widths, and as the chip lays the arena's
+        rows out: whole 128-lane tiles (a latent row is padded to them in the
+        arena's own shape; a K/V row of a head under 128 that is not
+        lane-packed, by the chip).  ``lane_pack``: KV heads a row."""
         cfg = self.cfg
         item = jnp.dtype(self.kv_dtype).itemsize
         if self.latent:
             counted = cfg.n_layer * cfg.latent_width * item
         else:
             counted = 2 * len(cfg.kv_layers) * cfg.n_query_groups * cfg.head_size * item
+        lanes = self._arena_shape[-1]
+        laid_out = (1 if self.latent else 2) * int(np.prod(self._arena_shape[1:-1])) * (-(-lanes // 128) * 128) * item
+        if self.quantized_kv:       # the two scale arenas, as counted
+            laid_out += 2 * int(np.prod(self._scale_shape[1:])) * 4
         return {"kind": "latent" if self.latent else "kv", "token_bytes_counted": counted,
-                "token_bytes_laid_out": self.block_bytes() // self.block_size}
+                "token_bytes_laid_out": laid_out // self.block_size, "lane_pack": self.lane_pack}
 
     def state_snapshot(self) -> dict:
         """Allocator state for the flight recorder: occupancy plus the
@@ -404,7 +441,7 @@ class PagedKVPool:
         return n_blocks * self.block_size
 
     def dense_shape(self, B: int, n_blocks: int) -> tuple[int, ...]:
-        L, ng, bs, hs = kv_block_shape(self.cfg, self.block_size)
+        L, ng, bs, hs = kv_block_shape(self.cfg, self.block_size)   # the dense cache packs nothing
         return (L, B, ng, n_blocks * bs, hs)
 
     def block_bytes(self) -> int:
@@ -446,7 +483,7 @@ class PagedKVPool:
         scale = name.endswith("_scale")
         want_shape = self._scale_shape if scale else self._arena_shape
         want_dtype = jnp.dtype(jnp.float32) if scale else jnp.dtype(self.kv_dtype)
-        if name in ("state", "conv"):
+        if self.state is not None and name in self.state.shapes:
             want_shape, want_dtype = self.state.shapes[name], self.state.dtypes[name]
         if tuple(new.shape) != want_shape:
             raise ArenaMismatchError(name, "shape", want_shape, tuple(new.shape))
@@ -487,7 +524,7 @@ class PagedKVPool:
                               self.k_scale, self.v_scale,
                               *(self.state.arenas.values() if self.state is not None else ())))
         if self.state is not None:
-            self.state.state, self.state.conv = arenas["state"], arenas["conv"]
+            self.state.install(arenas)
         if self.latent:
             self.k_arena = arenas["latent"]
             return
@@ -676,36 +713,52 @@ def gather_state(arenas, slots, fresh):
     (``generate.state_shapes``): ``{"conv": (L_lin, B, K - 1, channels),
     "state": (L_lin, B, nv, dk, dv)}`` from the slot-major arenas, zeros for
     a row that is ``fresh`` (``(B,)`` bool: its sequence starts here, and the
-    slot still holds its last owner's).  Pure jnp; call inside jit."""
+    slot still holds its last owner's); ``conv`` alone for a model of conv
+    layers.  Pure jnp; call inside jit."""
     def one(arena):
         rows = jnp.take(arena, slots, axis=0)                        # (B, L_lin, ...)
         rows = jnp.where(fresh.reshape((-1,) + (1,) * (rows.ndim - 1)), jnp.zeros_like(rows), rows)
         return jnp.swapaxes(rows, 0, 1)
-    return {"conv": one(arenas["conv"]), "state": one(arenas["state"])}
+    return {name: one(arenas[name]) for name in ("conv", "state") if name in arenas}
 
 
 def scatter_state(arenas, cache, slots):
     """The inverse of :func:`gather_state`: the rows' new state back to their
-    slots (padding rows all write the sink, slot 0).  Returns the two arenas."""
+    slots (padding rows all write the sink, slot 0).  Returns the arenas written."""
     return {name: arenas[name].at[slots].set(jnp.swapaxes(cache[name], 0, 1).astype(arenas[name].dtype))
-            for name in ("conv", "state")}
+            for name in ("conv", "state") if name in arenas}
 
 
-def gather_rows(arena, tables):
+def gather_rows(arena, tables, lane_pack: int = 1):
     """One arena's blocks as a dense cache: ``tables`` (B, nb) int32
     physical-block ids (sink-padded) to (L, B, ng, nb*bs, hs), the
     :func:`cache_shape` layout ``forward_with_cache`` consumes (a latent
-    arena: ng 1, hs the row's width).  Pure jnp; call inside jit."""
+    arena: ng 1, hs the row's width).  ``lane_pack`` P: the arena's rows hold P
+    KV heads side by side (``PagedKVPool.lane_pack``), and come apart here.
+    Pure jnp; call inside jit."""
     g = jnp.take(arena, tables, axis=0)        # (B, nb, L, ng, bs, hs)
+    B, nb, L, ng, bs, hs = g.shape
+    if lane_pack > 1:                          # (.., ng / P, bs, P hs) -> (.., ng / P, P, bs, hs)
+        g = g.reshape(B, nb, L, ng, bs, lane_pack, hs // lane_pack).transpose(0, 1, 2, 3, 5, 4, 6)
+        ng, hs = ng * lane_pack, hs // lane_pack
+        g = g.reshape(B, nb, L, ng, bs, hs)
     g = g.transpose(2, 0, 3, 1, 4, 5)          # (L, B, ng, nb, bs, hs)
-    L, B, ng, nb, bs, hs = g.shape
     return g.reshape(L, B, ng, nb * bs, hs)
 
 
-def gather_dense(k_arena, v_arena, tables):
+def gather_dense(k_arena, v_arena, tables, lane_pack: int = 1):
     """Reassembles the dense K and V caches from block tables
     (:func:`gather_rows` of each)."""
-    return gather_rows(k_arena, tables), gather_rows(v_arena, tables)
+    return gather_rows(k_arena, tables, lane_pack), gather_rows(v_arena, tables, lane_pack)
+
+
+def pack_lanes(x, lanes: int):
+    """``x (..., ng, hs)`` as a lane-packed arena's rows ``(..., ng hs / lanes,
+    lanes)``: consecutive KV heads side by side, a reshape.  ``x`` as it is
+    where ``hs == lanes``."""
+    if x.shape[-1] == lanes:
+        return x
+    return x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1] // lanes, lanes)
 
 
 def dest_for_pos(tables, pos, live, *, block_size):
@@ -730,7 +783,8 @@ def scatter_token(arena, new_kv, dest_block, dest_slot):
     """Writes one token's K (or V) per batch row back into the arena.
 
     ``new_kv``: (B, L, ng, hs); ``dest_block``/``dest_slot``: (B,) int32
-    (sink-routed for padding rows).  Pure jnp; call inside jit on a donated
+    (sink-routed for padding rows); a lane-packed arena takes them as its
+    rows (:func:`pack_lanes`).  Pure jnp; call inside jit on a donated
     arena.  The source dtype must already match the arena (int8 arenas go
     through :func:`quant.scatter_token_q` instead)."""
     if jnp.dtype(new_kv.dtype) != jnp.dtype(arena.dtype):
@@ -740,7 +794,7 @@ def scatter_token(arena, new_kv, dest_block, dest_slot):
                 f"dtype {jnp.dtype(arena.dtype)} — route int8 arenas through "
                 f"quant.scatter_token_q; anything else is a silent truncation",
         )
-    return arena.at[dest_block, :, :, dest_slot, :].set(new_kv)
+    return arena.at[dest_block, :, :, dest_slot, :].set(pack_lanes(new_kv, arena.shape[-1]))
 
 
 def scatter_blocks(arena, dense, dest_table):
@@ -765,4 +819,6 @@ def scatter_blocks(arena, dense, dest_table):
     L, B, ng, cap, hs = dense.shape
     bs = arena.shape[3]
     blocks = dense[:, 0].reshape(L, ng, cap // bs, bs, hs).transpose(2, 0, 1, 3, 4)
+    if arena.shape[-1] != hs:       # a lane-packed arena: (nb, L, ng / P, bs, P hs)
+        blocks = pack_lanes(blocks.transpose(0, 1, 3, 2, 4), arena.shape[-1]).transpose(0, 1, 3, 2, 4)
     return arena.at[dest_table].set(blocks)

@@ -44,12 +44,14 @@ from thunder_tpu.executors.pallasex import (
     paged_attn_verify,
     paged_chunk_write,
     paged_chunk_write_fused,
-    paged_head_size_ok,
     paged_token_write,
+    paged_walk_lanes_ok,
     paged_token_write_fused,
 )
 from thunder_tpu.models.generate import (
     gdn_mixer,
+    kv_lane_pack,
+    shortconv_mixer,
     mla_absorb,
     mla_mixer,
     mla_unabsorb,
@@ -75,22 +77,29 @@ def _smap(fn, mesh, in_specs, out_specs):
     return shard_map_compat(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
-def paged_supported(cfg, model_fn_is_default: bool, mesh=None) -> tuple[bool, str]:
+def paged_supported(cfg, model_fn_is_default: bool, mesh=None, arena_lanes: int | None = None) -> tuple[bool, str]:
     """Structural support check for the paged decode path: ``(ok, why)``.
 
     The kernel mirrors ``forward_with_cache``'s math, so a custom
     ``model_fn`` can't ride it; compiled for the TPU, the decode kernel's
-    windowed walk needs a head size of whole 128-lane tiles (narrower heads
-    take the per-block kernel, which has no window); and under a mesh the
+    windowed walk needs arena rows of whole 128-lane tiles (``arena_lanes``: the
+    pool's, where the caller has built one; else what the pool would lay out
+    for this config on one device: a head of whole tiles, or a head that
+    divides 128 with its KV heads in whole rows, lane-packed).  The heads that
+    still take the per-block kernel, which has no window: 96 and the like, a
+    narrow head in a quantised or sharded arena, or one whose KV heads do not
+    fill rows.  And under a mesh the
     heads must actually shard over ``tp`` the way ``kv_cache_spec`` lays the
     arena out (a degraded/replicated spec would silently disagree with the
     shard_map specs here)."""
     if not model_fn_is_default:
         return False, "custom model_fn (kernel mirrors forward_with_cache)"
-    if cfg.sliding_window is not None and not paged_head_size_ok(cfg.head_size):
+    if arena_lanes is None:
+        arena_lanes = cfg.head_size * (1 if mesh is not None else kv_lane_pack(cfg))
+    if cfg.sliding_window is not None and not paged_walk_lanes_ok(arena_lanes):
         return False, (
-            f"head_size={cfg.head_size} is not a multiple of 128 and the model "
-            "has a sliding window: the per-block decode kernel has none")
+            f"head_size={cfg.head_size} in arena rows of {arena_lanes} lanes (not whole 128-lane tiles) and "
+            "the model has a sliding window: the per-block decode kernel has none")
     if mesh is not None:
         if "tp" not in mesh.axis_names:
             return False, "mesh has no tp axis"
@@ -201,6 +210,20 @@ def _gdn_paged(gp, x, arenas, sslots, pos, cfg, *, layer, n_real, lin):
         return y, held["state"], arenas["conv"].at[sslots, layer].set(new_tail)
 
 
+def _conv_paged(cp, x, conv_arena, sslots, pos, cfg, *, layer, n_real, lin):
+    """A conv layer of :func:`forward_paged`: ``generate.shortconv_mixer`` (the
+    one mixer; the dense cache calls it too) with the tails where the server
+    keeps them: a row's slot of the conv arena, zeros where a piece of a prompt
+    starts at position 0.  Returns ``(y, conv arena)``."""
+    with scope("cache"):
+        tail = conv_arena[sslots, layer]                             # (B, K - 1, C)
+        if x.shape[1] > 1:
+            tail = jnp.where((pos == 0)[:, None, None], jnp.zeros_like(tail), tail)
+    y, new_tail = shortconv_mixer(cp, x, tail, cfg, n_real=n_real, lin=lin)
+    with scope("cache"):
+        return y, conv_arena.at[sslots, layer].set(new_tail)
+
+
 def _mla_paged(ap, x, arena, tables, pos, cos_t, sin_t, cfg, *, layer, cdtype, lin):
     """A latent-attention layer of :func:`forward_paged`, one token a row:
     ``generate.mla_mixer`` (the one mixer; the dense cache calls it too) with
@@ -263,11 +286,12 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     the sink) and ``n_real`` how many of a prompt piece's T tokens are real;
     the K/V arenas' layer axis counts the full-attention layers only
     (``cfg.kv_layers``), and ``fresh`` carries the two updated state arenas
-    beside the fresh K/V (:func:`with_state`)."""
+    beside the fresh K/V (:func:`with_state`).  A model with conv layers: the
+    same with ``conv`` alone (the tails, :func:`_conv_paged`)."""
     B, T = idx.shape
     hs, nh = cfg.head_size, cfg.n_head
     window = cfg.sliding_window
-    state_arena, conv_arena, n_lin = arenas.get("state"), arenas.get("conv"), 0
+    state_arena, conv_arena, n_lin, n_conv = arenas.get("state"), arenas.get("conv"), 0, 0
     with scope("embed"):        # the tokens' rows, and their positions' rows of the rope tables
         x = params["wte"][idx]
         if cfg.scale_embedding:
@@ -297,6 +321,10 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                         bp["gdn"], n1, {"state": state_arena, "conv": conv_arena}, sslots, pos, cfg,
                         layer=n_lin, n_real=n_real, lin=lin)
                     n_lin += 1
+                elif cfg.layer_kind(l) == "conv":
+                    h, conv_arena = _conv_paged(bp["conv"], n1, conv_arena, sslots, pos, cfg,
+                                                layer=n_conv, n_real=n_real, lin=lin)
+                    n_conv += 1
                 elif cfg.latent:
                     h, row = _mla_paged(bp["attn"], n1, arenas["latent"], tables, pos, cos_t, sin_t, cfg,
                                         layer=l, cdtype=cdtype, lin=lin)
@@ -335,8 +363,10 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
         if cfg.latent:          # (B, L, 1, W): the token writer's layout, one group
             return logits, {"latent": jnp.stack(fresh_rows, axis=1)[:, :, None]}
         fresh = {"k": jnp.stack(fresh_k, axis=1), "v": jnp.stack(fresh_v, axis=1)}
+    if conv_arena is not None:
+        fresh.update(conv=conv_arena)
     if state_arena is not None:
-        fresh.update(state=state_arena, conv=conv_arena)
+        fresh.update(state=state_arena)
     return logits, fresh
 
 

@@ -437,6 +437,137 @@ def test_a_group_without_rows_gets_a_zero_gradient(interpreted):
     assert float(jnp.abs(dw[2:]).max()) == 0.0 and float(dw[0].min()) == 32.0
 
 
+# ``moe_grouped_mm``'s two layouts (``pallasex._gmm_blocks``): the whole matrix as the block, and
+# column blocks inside a tile where VMEM does not hold the matrix twice.  Forced here at a small
+# width by the VMEM the kernel may ask for.
+GMM_K, GMM_N, GMM_TM, GMM_GROUPS = 128, 384, 8, 5
+GMM_TILES = (0, 1, 2, 5)                 # tiles of the first four groups; four more tiles hold nothing
+V5E_VMEM_CAP = 96 << 20                  # three quarters of a v5e core's 128 MiB
+
+
+def _gmm_layout(monkeypatch, layout: str, itemsize: int = 4):
+    """Makes the rule choose ``layout`` at ``(GMM_K, GMM_N)``."""
+    monkeypatch.setattr(px, "_GMM_VMEM_MARGIN", 0)
+    width = GMM_N if layout == "whole_matrix" else 128
+    monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: px._gmm_vmem(GMM_TM, GMM_K, width, itemsize))
+
+
+def _gmm_tiles():
+    """The plan names its last group for the tiles past the used ones; here that group drew no row."""
+    tg = np.repeat(np.arange(len(GMM_TILES)), GMM_TILES).tolist()
+    return jnp.asarray(tg + [GMM_GROUPS - 1] * 4, jnp.int32), jnp.asarray([len(tg)], jnp.int32)
+
+
+@pytest.mark.parametrize("transpose_w", [False, True], ids=["w", "w_transposed"])
+@pytest.mark.parametrize("layout", ["whole_matrix", "column_blocks"])
+def test_grouped_product_matches_ragged_dot_in_every_layout(interpreted, monkeypatch, layout, transpose_w):
+    """Groups of 0, 1, 2 and 5 tiles, four tiles past ``tiles_used``: both
+    layouts give ``lax.ragged_dot``'s rows and zeros after them."""
+    _gmm_layout(monkeypatch, layout)
+    tg, used = _gmm_tiles()
+    nt = tg.shape[0]
+    assert px._gmm_blocks(GMM_K, GMM_N, 4, GMM_TM, nt)["col_blocks"] == {"whole_matrix": 1, "column_blocks": 3}[layout]
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((nt * GMM_TM, GMM_K)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((GMM_GROUPS, GMM_K, GMM_N)), jnp.float32)
+    got = px._moe_grouped_mm.__wrapped__(x, jnp.swapaxes(w, 1, 2) if transpose_w else w, tg, used,
+                                         transpose_w=transpose_w)
+    monkeypatch.setattr(jaxex, "_grouped_mm_fast_path", None)
+    ref = jaxex._grouped_mm_impl(x, w, tg, used)
+    assert rel(got, ref) < 1e-5
+    assert float(jnp.abs(got[int(used[0]) * GMM_TM:]).max()) == 0.0 and float(jnp.abs(got[:GMM_TM]).min()) > 0.0
+
+
+def _weight_fetches(plan, tg, used, transpose_w):
+    """Walks the grid in order through the weight block's index map: how often
+    the block index changes (each change is a copy; the first block is one)."""
+    grid, (_, w_spec), _ = px._gmm_specs(GMM_TM, GMM_K, tg.shape[0], plan, transpose_w)
+    seen, changes = None, 0
+    for t in range(grid[0]):
+        for j in range(grid[1]):
+            block = tuple(int(i) for i in w_spec.index_map(t, j, tg, used))
+            changes += block != seen
+            seen = block
+    return grid, changes
+
+
+@pytest.mark.parametrize("transpose_w", [False, True], ids=["w", "w_transposed"])
+@pytest.mark.parametrize("layout", ["whole_matrix", "column_blocks"])
+def test_a_groups_weight_block_is_fetched_once_however_many_tiles_it_has(monkeypatch, layout, transpose_w):
+    """Where the schedule says ``weight_fetches_a_group == 1`` the walk copies
+    one block a used group, never one a tile; where the matrix went through
+    in column blocks it says every tile may fetch, and every used tile does.
+    The tiles past the used ones copy nothing in either, though the plan
+    names a group for them that drew no row."""
+    _gmm_layout(monkeypatch, layout)
+    tg, used = (np.asarray(a) for a in _gmm_tiles())
+    nt = tg.shape[0]
+    plan = px._gmm_blocks(GMM_K, GMM_N, 4, GMM_TM, nt)
+    grid, changes = _weight_fetches(plan, tg, used, transpose_w)
+    used_groups, used_tiles, nj = sum(n > 0 for n in GMM_TILES), sum(GMM_TILES), plan["col_blocks"]
+    assert grid == (nt, nj)
+    if layout == "whole_matrix":
+        assert plan["weight_fetches_a_group"] == 1 and changes == used_groups < used_tiles
+    else:
+        assert plan["weight_fetches_a_group"] == nt and changes == used_tiles * nj
+
+
+def test_the_vmem_a_grouped_product_may_ask_for_comes_from_the_device(monkeypatch):
+    """Three quarters of what the device says a core has; where jax knows no
+    such device (the interpreter here), what a kernel gets without asking."""
+    import types
+
+    assert px._gmm_vmem_cap() == px._GMM_VMEM_DEFAULT
+    monkeypatch.setattr(px.pltpu, "get_tpu_info", lambda: types.SimpleNamespace(vmem_capacity_bytes=128 << 20))
+    assert px._gmm_vmem_cap() == V5E_VMEM_CAP
+
+
+# K, N, rows a tile, tiles of a wave: the three configurations' products as their cells run them
+GMM_CELLS = {
+    "lfm2_decode_fc": (2048, 1792, 64, 56), "lfm2_decode_proj": (1792, 2048, 64, 56),
+    "lfm2_prefill_fc": (2048, 1792, 128, 104), "lfm2_prefill_proj": (1792, 2048, 128, 104),
+    "axk1_decode_fc": (7168, 2048, 16, 16), "axk1_prefill_fc": (7168, 2048, 128, 48),
+    "axk1_prefill_proj": (2048, 7168, 128, 48),
+    "hybrid_fc": (2048, 512, 128, 128), "hybrid_proj": (512, 2048, 128, 128),
+}
+
+
+@pytest.mark.parametrize("device", ["v5e", "unknown"])
+@pytest.mark.parametrize("cell", sorted(GMM_CELLS))
+def test_the_schedule_at_the_three_configurations_widths(monkeypatch, cell, device):
+    """On a v5e the whole matrix is the block at every width a cell runs,
+    fetched once a group, inside the VMEM the call states.  The hybrid
+    trainer's 2 MiB block asks for nothing on any device: one whole block on
+    a ``(tiles, 1)`` grid inside the default limit, as before.  On a device
+    whose VMEM jax does not know, the serving widths go through in column
+    blocks inside the default limit, and the schedule says they are fetched
+    a tile."""
+    K, N, TM, nt = GMM_CELLS[cell]
+    if device == "v5e":
+        monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: V5E_VMEM_CAP)
+    plan = px._gmm_blocks(K, N, 2, TM, nt)
+    grid, (x_spec, w_spec), o_spec = px._gmm_specs(TM, K, nt, plan, False)
+    if device == "v5e" or cell.startswith("hybrid"):
+        assert plan["col_blocks"] == 1 and plan["weight_fetches_a_group"] == 1
+        assert plan["weight_block_bytes"] == K * N * 2 and grid == (nt, 1)
+        assert (x_spec.block_shape, w_spec.block_shape, o_spec.block_shape) == ((TM, K), (1, K, N), (TM, N))
+    else:
+        assert plan["col_blocks"] > 1 and plan["weight_fetches_a_group"] == nt and plan["vmem_limit_bytes"] == 0
+        assert grid == (nt, plan["col_blocks"]) and w_spec.block_shape == (1, K, N // plan["col_blocks"])
+    if cell.startswith("hybrid"):
+        assert plan["vmem_limit_bytes"] == 0 and K * N * 2 == 2 << 20
+    elif device == "v5e":
+        assert 2 * K * N * 2 < plan["vmem_limit_bytes"] <= V5E_VMEM_CAP
+
+
+def test_gmm_schedule_holds_the_last_grouped_product_built(interpreted):
+    xb, w, tg, tu = _expert_operands()
+    assert px.grouped_mm(xb, w, tg, tu) is not None
+    assert px.gmm_schedule == px._gmm_blocks(128, 128, 4, 8, tg.shape[0])
+    assert set(px.gmm_schedule) >= {"col_blocks", "weight_block_bytes", "vmem_limit_bytes", "weight_fetches_a_group"}
+    assert all(isinstance(v, int) for v in px.gmm_schedule.values())
+
+
 def _expert_layer(hf, params_mlp, x):
     cfg = llama.Config(**arch.program_config(hf))
     return tt.jit(lambda mp, x_: llama.sparse_moe_mlp(mp, x_, cfg))(params_mlp, x)

@@ -766,6 +766,57 @@ def test_the_narrow_head_cells_programs_lower_to_their_kernels(kind, tpu_shardin
             assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
 
 
+# K, N, rows a tile, tiles, groups, and whether the device's VMEM is known (a v5e's) or not
+GMM_SHAPES = {
+    "lfm2/2048x1792/decode": (2048, 1792, 64, 56, 32, True), "lfm2/1792x2048/decode": (1792, 2048, 64, 56, 32, True),
+    "lfm2/2048x1792/prefill": (2048, 1792, 128, 104, 32, True), "lfm2/1792x2048/prefill": (1792, 2048, 128, 104, 32, True),
+    "axk1/7168x2048/decode": (7168, 2048, 16, 16, 12, True), "axk1/7168x2048/prefill": (7168, 2048, 128, 48, 12, True),
+    "axk1/2048x7168/prefill": (2048, 7168, 128, 48, 12, True),
+    # a matrix the VMEM asked for does not hold twice: column blocks inside a tile, under the default limit
+    "unknown_vmem/7168x2048/decode": (7168, 2048, 16, 16, 12, False),
+    "unknown_vmem/2048x7168/prefill": (2048, 7168, 128, 48, 12, False),
+}
+
+
+@pytest.mark.parametrize("transpose_w", [False, True], ids=["w", "w_transposed"])
+@pytest.mark.parametrize("case", sorted(GMM_SHAPES))
+def test_the_grouped_product_compiles_inside_the_vmem_it_asks_for(case, transpose_w, tpu_sharding, monkeypatch):
+    """``moe_grouped_mm`` at the widths the two serving cells run it, the
+    whole matrix the block (7.3 MB at LFM2's widths, 29.4 MB at A.X-K1's):
+    Mosaic takes each inside the scoped limit the call states.  Where the
+    device's VMEM is not known the call states none and the column blocks
+    it falls back to compile inside the default."""
+    K, N, TM, nt, groups, known = GMM_SHAPES[case]
+    if known:
+        monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)      # three quarters of a v5e core's
+    plan = px._gmm_blocks(K, N, 2, TM, nt)
+    specs = [((nt * TM, K), BF), ((groups, N, K) if transpose_w else (groups, K, N), BF), ((nt,), I32), ((1,), I32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    fn = functools.partial(px._moe_grouped_mm.__wrapped__, transpose_w=transpose_w)
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and 'kernel_name = "moe_grouped_mm"' in text
+    if known:
+        assert plan["col_blocks"] == 1 and plan["weight_fetches_a_group"] == 1
+        assert px._GMM_VMEM_DEFAULT < plan["vmem_limit_bytes"] <= 96 << 20
+        assert f'\\22size\\22: {plan["vmem_limit_bytes"]}}}' in text              # the scoped limit the call states
+    else:
+        assert plan["col_blocks"] > 1 and plan["weight_fetches_a_group"] == nt
+        assert plan["vmem_limit_bytes"] == 0 and "scoped_memory_configs" not in text
+    if tpu_sharding is not None:
+        assert re.search(r"%moe_grouped_mm(\.\d+)? = ", lowered.compile().as_text())
+
+
+def test_the_hybrid_trainers_grouped_product_states_no_vmem_limit(tpu_sharding):
+    """``2048 x 512``: one whole 2 MiB block on a ``(tiles, 1)`` grid under the default limit, as it was."""
+    specs = [((128 * 128, 2048), BF), ((32, 2048, 512), BF), ((128,), I32), ((1,), I32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    text = jax.jit(px._moe_grouped_mm.__wrapped__).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert 'kernel_name = "moe_grouped_mm"' in text and "scoped_memory_configs" not in text
+    module = _mosaic_module(text)
+    assert "iteration_bounds = array<i64: 128, 1>" in module and "memref<1x2048x512xbf16" in module
+
+
 def test_every_pallas_call_site_is_named():
     import inspect
 

@@ -140,7 +140,7 @@ class TestTools:
         for t in tools:
             py_compile.compile(str(t), doraise=True)
 
-    @pytest.mark.parametrize("tool", ["flash_tune.py", "gdn_tune.py", "mla_tune.py", "conv_tune.py"])
+    @pytest.mark.parametrize("tool", ["flash_tune.py", "gdn_tune.py", "mla_tune.py", "conv_tune.py", "moe_tune.py"])
     def test_kernel_timers_without_a_tpu_exit_nonzero_and_print_no_result(self, tool):
         proc = subprocess.run(
             [sys.executable, str(self.TOOLS / tool)],
@@ -178,6 +178,29 @@ class TestTools:
             with mla_tune.taken_out(part):
                 out = mla_tune.all_layers(2, 128)(q, arena, fresh, tables, pos)
             assert out.shape == (3, 4, 128) and (px._mla_dot, px._mla_start_chunk, px._mla_wait_chunk) == hooks
+
+
+    def test_moe_tune_builds_the_cells_waves_and_checks_under_the_interpreter(self, monkeypatch):
+        """The tool's shapes are the cells' (tile, wave and rows an expert as
+        ``moe_row_tile`` / ``moe_wave_tiles`` give them), an even routing fills
+        a tile an expert and the skewed one some twice, and its ``--check``
+        (the interpreted kernel against ``lax.ragged_dot`` here) passes at a
+        small shape, forward and transposed."""
+        sys.path.insert(0, str(self.TOOLS.parent))
+        from tools import moe_tune
+
+        (tg, used), = (ws := moe_tune.waves(skew=False, **moe_tune.SHAPES["lfm2_decode"]))[0]
+        assert (ws[1], tg.shape[0], int(used[0])) == (64, 56, 32) and set(ws[2]) == {32}
+        ws = moe_tune.waves(skew=True, **moe_tune.SHAPES["lfm2_decode"])
+        assert ws[2].sum() == 1024 and ws[2].max() > 64 and int(ws[0][0][1][0]) > 32 - (ws[2] == 0).sum()
+        ws = moe_tune.waves(skew=False, **moe_tune.SHAPES["axk1_decode"])
+        assert (ws[1], ws[0][0][0].shape[0], int(ws[0][0][1][0])) == (16, 16, 12)
+        assert moe_tune.waves(skew=False, **moe_tune.SHAPES["lfm2_prefill"])[1] == 128
+        monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setitem(moe_tune.SHAPES, "small", dict(tokens=64, k=4, held=8, total=8, C=256, I=128))
+        monkeypatch.setitem(moe_tune.SHAPES, "small_t", dict(tokens=64, k=4, held=4, total=16, C=256, I=128, tile=8,
+                                                             transposed=True))
+        assert moe_tune.check(["small", "small_t"]) < 1e-3
 
 
 class TestSharpEdges:

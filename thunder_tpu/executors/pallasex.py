@@ -2748,10 +2748,14 @@ ex.register_implementation(PrimIDs.GDN_CHUNK_BACKWARD, _gdn_bwd_op,
 # ---------------------------------------------------------------------------
 # Grouped matrix products over rows sorted by group: ``moe_grouped_mm`` and
 # ``moe_grouped_mm_dw``.  The plan (``jaxex.moe_plan``) pads every group to
-# whole row tiles, so a grid step is one tile against one group's weights,
-# chosen by the scalar-prefetched ``tile_group``.  Tiles past ``tiles_used``
-# point at the last used tile's rows and the last group's weights, so nothing
-# is fetched for them; they write zeros.  The time follows the tiles used.
+# whole row tiles, so a grid step is one tile against a block of one group's
+# weights, chosen by the scalar-prefetched ``tile_group``.  The block is the
+# whole matrix where VMEM holds that twice (``_gmm_blocks``): a group's tiles
+# follow one another, a block index that does not change is not fetched again,
+# so a group's weights are copied once however many tiles it has.  Tiles past
+# ``tiles_used`` point at the last used tile's rows and weight block, so
+# nothing is fetched for them; they write zeros.  The time follows the tiles
+# used.
 # ---------------------------------------------------------------------------
 
 
@@ -2769,44 +2773,82 @@ def _gmm_kernel(tg_ref, used_ref, x_ref, w_ref, o_ref, *, transpose_w):
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def _gmm_col_block(K: int, N: int, itemsize: int) -> int:
-    """Columns of the weight block: all of them where a group's whole matrix
-    is at most 2 MiB (one grid step a tile, the weights fetched once a group),
-    else the widest 128-multiple under that."""
-    if K * N * itemsize <= 2 << 20:
-        return N
-    for b in (1024, 512, 256, 128):
-        if N % b == 0 and K * b * itemsize <= 2 << 20:
-            return b
-    return 128 if N % 128 == 0 else N
+# what a kernel gets of VMEM without asking, and what Mosaic keeps beside the blocks
+_GMM_VMEM_DEFAULT = 16 << 20
+_GMM_VMEM_MARGIN = 4 << 20
+
+# what the last grouped product built was laid out as (trace time; a dict of
+# its own, as ``flash_schedule``)
+gmm_schedule: dict[str, int] = {}
+
+
+def _gmm_vmem_cap() -> int:
+    """VMEM a grouped product may ask for: three quarters of the core's where
+    the device says what it has (96 of a v5e's 128 MiB), else what a kernel
+    gets without asking, which compiles anywhere."""
+    try:
+        return pltpu.get_tpu_info().vmem_capacity_bytes * 3 // 4
+    except ValueError:       # not a TPU jax knows the sizes of (the interpreter, a lowering for another host's chip)
+        return _GMM_VMEM_DEFAULT
+
+
+def _gmm_vmem(TM: int, K: int, TN: int, itemsize: int) -> int:
+    """Bytes a grid step holds: the weight block, the row tile and the output
+    tile twice each (the pipeline's two buffers), the float32 product once."""
+    return 2 * (K * TN + TM * K + TM * TN) * itemsize + 4 * TM * TN
+
+
+def _gmm_blocks(K: int, N: int, itemsize: int, TM: int, nt: int) -> dict[str, int]:
+    """How ``moe_grouped_mm`` walks ``nt`` tiles of ``TM`` rows against
+    matrices of ``K x N``, from the shapes alone.  The weight block is the
+    whole matrix where two of them fit the VMEM the kernel may ask for
+    (``_gmm_vmem_cap``): one grid step a tile, and a group's block fetched
+    once.  Else it is the widest 128-multiple of columns that fits, walked
+    inside a tile, and a group's weights are fetched once for each of its
+    tiles (``weight_fetches_a_group`` then says how many that can be: every
+    tile).  At ``K x N x itemsize <= 2 MiB`` this is the whole matrix inside
+    the default limit, as it always was."""
+    def need(TN):
+        return _gmm_vmem(TM, K, TN, itemsize) + _GMM_VMEM_MARGIN
+
+    widths = [b for b in range(N, 0, -128) if N % b == 0] if N % 128 == 0 else [N]
+    cap = _gmm_vmem_cap()
+    TN = next((b for b in widths if need(b) <= cap), widths[-1])
+    return {"col_block": TN, "col_blocks": N // TN, "weight_block_bytes": K * TN * itemsize,
+            "vmem_limit_bytes": need(TN) if need(TN) > _GMM_VMEM_DEFAULT else 0,
+            "weight_fetches_a_group": 1 if TN == N else nt}
+
+
+def _gmm_specs(TM: int, K: int, nt: int, plan: dict[str, int], transpose_w: bool):
+    """The grid of a grouped product laid out as ``plan`` and the block of the
+    rows, of the weights and of the product a grid step holds."""
+    TN, nj = plan["col_block"], plan["col_blocks"]
+    # a tile past the used ones stays on the rows and the weight block the last used one held: no copy for it
+    tile = lambda t, used: jnp.minimum(t, jnp.maximum(used[0] - 1, 0))  # noqa: E731
+    col = lambda t, j, used: jnp.where(t < used[0], j, nj - 1)  # noqa: E731
+    if transpose_w:
+        w_spec = pl.BlockSpec((1, TN, K), lambda t, j, tg, used: (tg[tile(t, used)], col(t, j, used), 0))
+    else:
+        w_spec = pl.BlockSpec((1, K, TN), lambda t, j, tg, used: (tg[tile(t, used)], 0, col(t, j, used)))
+    return ((nt, nj), [pl.BlockSpec((TM, K), lambda t, j, tg, used: (tile(t, used), 0)), w_spec],
+            pl.BlockSpec((TM, TN), lambda t, j, tg, used: (t, j)))
 
 
 @functools.partial(jax.jit, static_argnames=("transpose_w",))
 def _moe_grouped_mm(x, w, tile_group, tiles_used, transpose_w: bool = False):
     R, K = x.shape
     nt = tile_group.shape[0]
-    TM = R // nt
     N = w.shape[1] if transpose_w else w.shape[2]
-    TN = _gmm_col_block(K, N, x.dtype.itemsize)
-    last = lambda used: jnp.maximum(used[0] - 1, 0)  # noqa: E731
-    # a tile past the used ones stays on the weight block the step before it held: no copy for it
-    col = lambda t, j, used: jnp.where(t < used[0], j, N // TN - 1)  # noqa: E731
-    if transpose_w:
-        w_spec = pl.BlockSpec((1, TN, K), lambda t, j, tg, used: (tg[t], col(t, j, used), 0))
-    else:
-        w_spec = pl.BlockSpec((1, K, TN), lambda t, j, tg, used: (tg[t], 0, col(t, j, used)))
+    plan = _gmm_blocks(K, N, x.dtype.itemsize, R // nt, nt)
+    grid, in_specs, out_specs = _gmm_specs(R // nt, K, nt, plan, transpose_w)
     params = {}
     if not _interpret():
-        params["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"))
+        limit = {"vmem_limit_bytes": plan["vmem_limit_bytes"]} if plan["vmem_limit_bytes"] else {}
+        params["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"), **limit)
     return pl.pallas_call(
         functools.partial(_gmm_kernel, transpose_w=transpose_w),
         name="moe_grouped_mm",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(nt, N // TN),
-            in_specs=[pl.BlockSpec((TM, K), lambda t, j, tg, used: (jnp.minimum(t, last(used)), 0)), w_spec],
-            out_specs=pl.BlockSpec((TM, TN), lambda t, j, tg, used: (t, j)),
-        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=2, grid=grid, in_specs=in_specs, out_specs=out_specs),
         out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
         interpret=_interpret(),
         **params,
@@ -2884,6 +2926,8 @@ def grouped_mm(x, w, tile_group, tiles_used, transpose_w=False):
             and _gmm_dispatchable(x, w)):
         return None
     stats["grouped_mm"] = stats.get("grouped_mm", 0) + 1
+    gmm_schedule.update(_gmm_blocks(x.shape[1], N, x.dtype.itemsize, x.shape[0] // tile_group.shape[0],
+                                    tile_group.shape[0]))
     return _moe_grouped_mm(x, w, tile_group, tiles_used, transpose_w=bool(transpose_w))
 
 

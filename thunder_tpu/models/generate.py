@@ -180,15 +180,16 @@ MOE_DECODE_ROW_TILE = 16   # bfloat16's sublane tile: the fewest rows moe_groupe
 
 def moe_row_tile(rows_an_expert: float) -> int:
     """Rows of a tile of the served expert share's sorted rows, from the rows an
-    even routing sends a held expert.  ``moe_grouped_mm`` walks the row tiles
-    and, inside a tile, the column blocks of its expert's weights, so an expert
-    whose rows span two tiles has its weights read twice: a tile holds about
-    twice an expert's even share (a power of two, so that an expert that drew a
-    few rows more still fits one), no fewer than ``MOE_DECODE_ROW_TILE`` and no
+    even routing sends a held expert.  ``moe_grouped_mm`` keeps an expert's
+    weight block in VMEM while its row tiles pass, so a second tile costs no
+    bytes; it costs the matrix unit a second load of every weight tile, which
+    at a few rows a tile is all of a tile's time.  So a tile holds about twice
+    an expert's even share (a power of two, so that an expert that drew a few
+    rows more still fits one), no fewer than ``MOE_DECODE_ROW_TILE`` and no
     more than the trainer's ``MOE_ROW_TILE``, which a prompt's rows fill
-    several times over.  (The cell that showed it, 32 rows an expert: tiles of
-    16 read every expert's weights 2.5 times a step, 0.37 of the product's
-    roofline; ``PERF.md`` section 6, PR 39.)"""
+    several times over.  (The cell that showed it, 32 rows an expert, before
+    the block stayed: tiles of 16 read every expert's weights 2.5 times a
+    step, 0.37 of the product's roofline; ``PERF.md`` section 6, PRs 39, 40.)"""
     from thunder_tpu.core.prims import MOE_ROW_TILE
 
     if rows_an_expert >= MOE_ROW_TILE // 2:
@@ -205,7 +206,8 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear):
     sorted by held expert into whole row tiles, grouped products through
     ``moe_grouped_mm``, nothing dropped); what the other experts would add is
     left out, and the shared expert is added once.  A decode step routes a few
-    rows an expert, so its tiles are narrow (:func:`moe_row_tile`)."""
+    rows an expert, so its tiles are narrow (:func:`moe_row_tile`); an expert's
+    weights are fetched once a product however many tiles its rows fill."""
     from thunder_tpu.executors import jaxex
 
     B, T, C = x.shape

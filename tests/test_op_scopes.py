@@ -232,7 +232,8 @@ def test_the_manifest_lists_every_split_in_its_cell():
     per_layer = {m["name"]: m for m in common.manifest()["per_layer"]}
     cells = {"train": "mistral7b-train-1chip.seq8k", "hyb": "qwen3next-train-1chip.seq8k-x2",
              "offline": "mistral7b-serve-1chip.offline-batch", "hybserve": "olmo-hybrid-serve-1chip.offline-longgen",
-             "mlaserve": "axk1-serve-1chip.offline-longctx", "lfm2serve": "lfm2moe-serve-1chip.offline-wide"}
+             "mlaserve": "axk1-serve-1chip.offline-longctx", "lfm2serve": "lfm2moe-serve-1chip.offline-wide",
+             "flashserve": "phi4flash-serve-1chip.offline-reason"}
     for q in ("mixer", "mlp", "head", "unscoped", "optimizer", "backward"):
         splits = ("train", "hyb") if q in ("optimizer", "backward") else tuple(cells)
         for sp in splits:

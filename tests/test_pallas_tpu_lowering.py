@@ -821,7 +821,7 @@ def test_every_pallas_call_site_is_named():
     import inspect
 
     src = inspect.getsource(px)
-    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 18      # PR 37: the causal conv's two
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 20      # PR 41: the selective scan's two
     assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
         "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
         "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",
@@ -842,3 +842,124 @@ def test_kernels_agree_with_their_jnp_references_interpreted(dtype):
                       dtype=dtype, window=7)
     assert len(rows) >= 20
     assert all(r["ok"] for r in rows), [r for r in rows if not r["ok"]]
+
+
+# --------------------------------------------------------------------------
+# a decoder-hybrid-decoder as the Phi-4-mini-flash cell serves it: a selective
+# scan, differential attention over a ring and over one layer's shared blocks
+# --------------------------------------------------------------------------
+
+FLASH_CELL = "phi4flash-serve-1chip.offline-reason"
+
+
+@pytest.mark.parametrize("kernel", ["ssm_scan_fwd", "ssm_decode_step", "paged_attn_decode/shared", "paged_attn_decode/ring"])
+def test_the_hybrid_decoder_cells_kernels_compile_at_its_shapes(kernel, tpu_sharding, monkeypatch):
+    """The scan over the longest prefill bucket (5,120 tokens of 5,120 channels
+    by 16 states, the state carried in VMEM, nothing of the state's history
+    kept); the step on 96 rows' slots of the nine layers' arena, in place; the
+    differential walk (two softmaxes a head pair, ``packed_out``) over the one
+    global layer's blocks at a table of 552 and over a ring's at a window of
+    512: whole-tile slabs, no arena copied."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    kernel, _, which = kernel.partition("/")
+    d, N, rows, width = 5120, 16, 96, 552
+    if kernel == "ssm_scan_fwd":
+        Ts = 5120
+        fn, claim = px.ssm_scan, "ssm_scan"
+        specs = [((1, Ts, d), BF), ((1, Ts, d), F32), ((1, Ts, N), F32), ((1, Ts, N), F32), ((N, d), F32), ((1, N, d), F32)]
+    elif kernel == "ssm_decode_step":
+        fn, claim = functools.partial(px.ssm_decode_step, layer=8), "ssm_decode"
+        specs = [((rows + 1, 9, N, d), F32), ((rows,), I32), ((rows, d), BF), ((rows, d), F32), ((rows, N), F32),
+                 ((rows, N), F32), ((N, d), F32)]
+    else:
+        ring = which == "ring"
+        arena = (((rows + 1) * 33, 8, 10, BS, 128) if ring else (36864, 1, 10, BS, 128), BF)
+        fn, claim = functools.partial(px.paged_attn_decode, layer=7 if ring else 0, window=512 if ring else None,
+                                      packed_out=True), None
+        specs = [((rows, 40, 64), BF), arena, arena, ((rows, 20, 64), BF), ((rows, 20, 64), BF), ((rows, width), I32),
+                 ((rows,), I32)]
+    before = dict(px.stats)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and f'kernel_name = "{kernel}"' in text
+    if claim:
+        assert px.stats.get(claim, 0) == before.get(claim, 0) + 1            # claimed, not the XLA form
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        call = next(l for l in compiled.as_text().splitlines() if re.search(rf"%{kernel}(\.\d+)? = ", l))
+        if kernel == "ssm_decode_step":
+            assert "output_to_operand_aliasing" in call                       # in place on the arena
+        if kernel == "paged_attn_decode":
+            assert compiled.memory_analysis().temp_size_in_bytes == 0         # no arena copy
+            assert jax.eval_shape(fn, *args).shape == (rows, 10, 4, 128)      # a pair's two softmaxes, V_g whole
+
+
+@functools.cache
+def _flash_engine():
+    """The cell's engine at its published widths, eight layers (three scans, two
+    window layers, the global layer, a gated memory unit and a cross layer), over
+    weights that are shapes alone."""
+    import thunder_tpu as tt
+    from chipbench import common
+    from thunder_tpu.models import llama
+
+    _, config, mix = common.open_cell(FLASH_CELL)
+    arch = common.load_module("models", config["arch"])
+    hf = {**config, "num_hidden_layers": 8}
+    cfg = llama.Config(**arch.program_config(hf))
+    params = jax.eval_shape(functools.partial(arch.make_params, hf), common.seed_words(1))
+    return cfg, params, tt.serve(None, params, cfg, **{**config["engine"], **mix["engine"], "num_blocks": 700})
+
+
+@pytest.mark.parametrize("kind", ["prefill_fresh", "decode_paged"])
+def test_the_hybrid_decoder_cells_programs_lower_to_their_kernels(kind, tpu_sharding, monkeypatch):
+    """A whole prompt's prefill scans through ``ssm_scan_fwd``, attends through
+    ``_flash_fwd`` (a pair's queries padded with zeros into their half of a
+    128-lane row) in the self-decoder and projects the head for one row; a decode
+    step of 96 rows runs ``ssm_decode_step`` a scan layer, walks the rings and the
+    one global layer's blocks through ``paged_attn_decode`` (the cross layer
+    walks the global layer's: one more call, no more arena), and lands K and V
+    through one ``paged_token_write`` a kind each; no arena is gathered."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    monkeypatch.setattr(px, "_pallas_available", lambda: True)
+    cfg, params, eng = _flash_engine()
+    st = eng.stats()
+    assert st["attn"]["mode"] == "paged" and st["attn"]["path"] == "walk" and st["attn"]["lane_pack"] == 2
+    assert st["attn"]["shared_kv_layers"] == 2
+    state = eng.pool.state
+    assert eng.pool.k_arena.shape == (700, 1, 10, 16, 128) and state.ring_blocks == 33
+    assert state.shapes == {"conv": (97, 3, 3, 5120), "state": (97, 3, 16, 5120),
+                            "k_ring": (97 * 33, 2, 10, 16, 128), "v_ring": (97 * 33, 2, 10, 16, 128)}
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=tpu_sharding)  # noqa: E731
+    one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
+    before = dict(px.stats)
+    if kind == "prefill_fresh":
+        Tb = 5120
+        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
+        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)), one((1,)))
+    else:
+        prog = eng._build_decode_paged(96, 552)
+        args = (weights, one((96,)), one((96,)), one((96, 552)), arenas, one((96, 2), jnp.uint32), {}, one((96,)),
+                one((96,)))
+    lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
+    assert "paged_attn_verify" not in text
+    assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)      # nothing of an arena's five dims
+    if kind == "prefill_fresh":
+        assert claimed("ssm_scan") == 3 and text.count('kernel_name = "ssm_scan_fwd"') >= 1
+        assert claimed("direct") == 2 and 'kernel_name = "_flash_fwd"' in text   # the two window layers: whole prompts
+        assert {int(m) for m in re.findall(rf"tensor<1x(\d+)x{cfg.padded_vocab_size}xf32>", text)} == {1}
+        assert {int(m) for m in re.findall(r"tensor<1x(\d+)x10240xbf16>", text)} == {1, Tb}   # the cross half's MLPs: a row
+    else:
+        assert claimed("ssm_decode") == 3 and text.count('kernel_name = "ssm_decode_step"') >= 1
+        assert text.count('kernel_name = "paged_attn_decode"') == 4              # two rings, the global layer, the cross layer
+        assert text.count('kernel_name = "paged_token_write"') == 4              # K and V of the paged kind and of the ring
+    if tpu_sharding is not None:
+        hlo = lowered.compile().as_text()
+        names = ("_flash_fwd", "ssm_scan_fwd") if kind == "prefill_fresh" else (
+            "paged_attn_decode", "paged_token_write", "ssm_decode_step")
+        for name in names:
+            assert re.search(rf"%{name}(\.\d+)? = ", hlo), name

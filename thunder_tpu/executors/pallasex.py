@@ -1314,7 +1314,7 @@ def _lane_packed_outputs(o, P: int):
 
 
 def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
-                      layer, k_scale=None, v_scale=None, window=None):
+                      layer, k_scale=None, v_scale=None, window=None, packed_out=False):
     """Single-token attention straight off the KV block arena, one layer.
 
     ``q``: (B, nh, hs) queries at the compute dtype; ``k_arena``/``v_arena``:
@@ -1338,7 +1338,12 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     sliding window they are refused (``paged_supported`` sends such a model to
     the gather path when the engine is built).  ``bs`` = 8 and 16 both
     compile, at int8 and bfloat16.
-    Returns (B, nh, hs) attention outputs at ``q.dtype``.
+    Returns (B, nh, hs) attention outputs at ``q.dtype``.  ``packed_out`` (a
+    lane-packed arena only): the walk's rows whole, ``(B, ng / P, P * rep, P *
+    hs)``: row ``j * rep + r`` of group ``g`` is query ``r`` of KV head ``g P + j``,
+    its weights on *every* head's values of the row, side by side.  That is
+    what differential attention sums (``models.generate.diff_attention``: both
+    softmaxes of a head pair over one fetch of the pair's K and V row).
     """
     B, nh, hs = q.shape
     _, _L, ng, bs, lanes = k_arena.shape
@@ -1346,6 +1351,7 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     assert P * hs == lanes and (P == 1 or k_scale is None), (hs, lanes)
     rep = nh // (ng * P)
     assert rep * ng * P == nh, (nh, ng, P)
+    assert not packed_out or (P > 1 and paged_walk_lanes_ok(lanes)), "packed_out: the walk over a lane-packed arena"
     if not paged_walk_lanes_ok(lanes):
         if window is not None:
             raise NotImplementedError(
@@ -1398,6 +1404,8 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
         interpret=_interpret(),
         **kwargs,
     )(tables, pos, q, *arenas, fresh_k[:, :, None, :], fresh_v[:, :, None, :])
+    if packed_out:
+        return out
     if P > 1:
         out = _lane_packed_outputs(out, P)
     return out.reshape(B, nh, -1)
@@ -3179,6 +3187,200 @@ def causal_conv1d_backward(g, x, w, activation=None):
     operands; or None."""
     tiles = _conv_claim(x, w, activation, g)
     return None if tiles is None else _conv_bwd(g.astype(x.dtype), x, w, activation=activation, tiles=tiles)
+
+
+# ---------------------------------------------------------------------------
+# The selective scan of a state-space layer (Mamba-1), for the server.  A
+# sequence's state is ``S (N, d)`` in float32: ``N`` states (16) on sublanes,
+# the ``d`` channels on lanes, so a token's decay ``exp(dt_t A)`` (``A (N, d)``),
+# its input ``(dt_t u_t) B_t^T`` and its read-out ``sum_n S[n] C_t[n]`` are whole
+# vector operations and ``B_t``, ``C_t`` broadcast along lanes as columns.
+#   S_t = exp(dt_t * A) * S_t-1 + (dt_t * u_t) * B_t        y_t = sum_n S_t * C_t
+# A padded token carries ``dt = 0``: decay one, input zero, the state as it was.
+#
+# ``ssm_scan_fwd`` (a whole prompt): a grid step is a tile of channels and a
+# block of tokens; the state stays in VMEM scratch across a tile's token blocks
+# (the innermost grid axis, walked in order) and leaves as the scan's third
+# result after the last.  Tokens go eight a loop turn: one aligned load of
+# their rows of u and dt, eight steps in straight-line code, one aligned store
+# of their rows of y.  A token's ``B_t`` and ``C_t`` are rows of a ``(8, N)`` block
+# and become columns by a masked lane sum (``_column``).  The work is the vector
+# unit's: about ``7 N d`` float32 operations and ``N d`` exponentials a token
+# against ``12 d`` bytes, so the kernel is bound by the vector unit, not by HBM
+# (``chipbench/kernels/ssm_scan.py`` counts both).
+#
+# ``ssm_decode_step`` (one token a row): the row's slot of the state arena in
+# and out through one aliased block, as ``gdn_decode_step`` does.
+# ---------------------------------------------------------------------------
+
+_SSM_TOKENS = 8            # tokens a loop turn: a float32 sublane tile
+_SSM_BLOCK_TOKENS = 256    # tokens a grid step, the most
+_SSM_TILE_LANES = 1024     # channels a grid step, the most
+
+
+def ssm_step_math(S, dt, u, b, c, A):
+    """One token in float32: ``S (N, d)``, ``dt`` and ``u`` rows ``(1, d)``, ``b``
+    and ``c`` columns ``(N, 1)``, ``A (N, d)`` -> ``(y (1, d), S)``.  The scan
+    kernel's step, the decode kernel's body and the XLA forms are this one
+    function."""
+    S = jnp.exp(dt * A) * S + (dt * u) * b
+    return jnp.sum(S * c, axis=0, keepdims=True), S
+
+
+def _ssm_scan_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, last_ref, s_ref, *, TB):
+    f32 = jnp.float32
+    t = pl.program_id(2)
+
+    @pl.when(t == 0)
+    def _start():
+        s_ref[...] = h0_ref[0].astype(f32)
+
+    A = a_ref[...]
+    N = A.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1)).astype(f32)
+
+    def turn(i, S):
+        at = pl.multiple_of(i * _SSM_TOKENS, _SSM_TOKENS)
+        u, dt = u_ref[0, pl.ds(at, _SSM_TOKENS), :], dt_ref[0, pl.ds(at, _SSM_TOKENS), :]
+        b, c = b_ref[0, pl.ds(at, _SSM_TOKENS), :], c_ref[0, pl.ds(at, _SSM_TOKENS), :]
+        ys = []
+        for j in range(_SSM_TOKENS):
+            y, S = ssm_step_math(S, dt[j:j + 1], u[j:j + 1], _column(b[j:j + 1], eye), _column(c[j:j + 1], eye), A)
+            ys.append(y)
+        y_ref[0, pl.ds(at, _SSM_TOKENS), :] = jnp.concatenate(ys, axis=0)
+        return S
+
+    s_ref[...] = jax.lax.fori_loop(0, TB // _SSM_TOKENS, turn, s_ref[...])
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _end():
+        last_ref[0] = s_ref[...].astype(last_ref.dtype)
+
+
+def _ssm_tiles(T: int, d: int) -> tuple[int, int] | None:
+    """``(tokens a grid step, channels a grid step)`` of :func:`_ssm_scan_fwd`, or
+    None where the shapes do not tile: whole sublane tiles of tokens, whole lane
+    tiles of channels."""
+    if T % _SSM_TOKENS or d % 128:
+        return None
+    TB = next(b for b in (256, 128, 64, 32, 16, 8) if b <= _SSM_BLOCK_TOKENS and T % b == 0)
+    tC = next(c for c in (1024, 512, 256, 128) if c <= _SSM_TILE_LANES and d % c == 0)
+    return TB, tC
+
+
+@functools.partial(jax.jit, static_argnames=("TB", "tC"))
+def _ssm_scan_fwd(u, dt, Bm, Cm, A, h0, TB: int, tC: int):
+    """u, dt ``(B, T, d)``, Bm, Cm ``(B, T, N)``, A ``(N, d)``, all float32, h0
+    ``(B, N, d)`` -> y ``(B, T, d)`` float32 and the state after the last token
+    in ``h0``'s dtype."""
+    B, T, d = u.shape
+    N = A.shape[0]
+    tile = pl.BlockSpec((1, TB, tC), lambda b, c, t: (b, t, c))
+    cols = pl.BlockSpec((1, TB, N), lambda b, c, t: (b, t, 0))
+    state = pl.BlockSpec((1, N, tC), lambda b, c, t: (b, 0, c))
+    kwargs = {}
+    if not _interpret():
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pl.pallas_call(
+        functools.partial(_ssm_scan_kernel, TB=TB),
+        name="ssm_scan_fwd",
+        grid=(B, d // tC, T // TB),
+        in_specs=[tile, tile, cols, cols, pl.BlockSpec((N, tC), lambda b, c, t: (0, c)), state],
+        out_specs=[tile, state],
+        out_shape=[jax.ShapeDtypeStruct((B, T, d), jnp.float32), jax.ShapeDtypeStruct((B, N, d), h0.dtype)],
+        scratch_shapes=[pltpu.VMEM((N, tC), jnp.float32)],
+        interpret=_interpret(),
+        **kwargs,
+    )(u, dt, Bm, Cm, A, h0)
+
+
+def ssm_scan_xla(u, dt, Bm, Cm, A, h0):
+    """The scan's XLA form, a token a step of ``lax.scan``: the same operands,
+    the same results (what the CPU runs, and what the kernel is tested against)."""
+    f32 = jnp.float32
+
+    def step(S, xs):
+        u_t, dt_t, b_t, c_t = xs
+        y, S = jax.vmap(ssm_step_math, in_axes=(0, 0, 0, 0, 0, None))(
+            S, dt_t[:, None], u_t[:, None], b_t[:, :, None], c_t[:, :, None], A)
+        return S, y[:, 0]
+
+    tm = lambda a: jnp.swapaxes(a.astype(f32), 0, 1)  # noqa: E731 -- token-major
+    S, ys = jax.lax.scan(step, h0.astype(f32), (tm(u), tm(dt), tm(Bm), tm(Cm)))
+    return jnp.swapaxes(ys, 0, 1), S.astype(h0.dtype)
+
+
+#: the last scan built, at trace time (a dict of its own, as ``flash_schedule``)
+ssm_schedule: dict[str, int] = {}
+
+
+def ssm_scan(u, dt, Bm, Cm, A, h0):
+    """A prompt's selective scan from the state ``h0 (B, N, d)``: u, dt ``(B, T,
+    d)``, Bm, Cm ``(B, T, N)``, ``A (N, d)`` (negative) -> ``(y (B, T, d) float32,
+    the state after the last token)``.  ``ssm_scan_fwd`` where Pallas runs and
+    the shapes tile, else the XLA form."""
+    tiles = _ssm_tiles(u.shape[1], u.shape[2]) if _enabled() and _gmm_dispatchable(u, dt, h0) else None
+    if tiles is None:
+        return ssm_scan_xla(u, dt, Bm, Cm, A, h0)
+    stats["ssm_scan"] = stats.get("ssm_scan", 0) + 1
+    ssm_schedule.update(block_tokens=tiles[0], tile_channels=tiles[1], states=A.shape[0])
+    f32 = jnp.float32
+    return _ssm_scan_fwd(u.astype(f32), dt.astype(f32), Bm.astype(f32), Cm.astype(f32), A.astype(f32), h0,
+                         TB=tiles[0], tC=tiles[1])
+
+
+def _ssm_decode_kernel(slot_ref, u_ref, dt_ref, b_ref, c_ref, a_ref, s_ref, y_ref, so_ref):
+    del slot_ref   # the row's slot lives in the BlockSpec index maps
+    y, S = ssm_step_math(s_ref[0, 0].astype(jnp.float32), dt_ref[0], u_ref[0], b_ref[0], c_ref[0], a_ref[...])
+    so_ref[0, 0] = S.astype(so_ref.dtype)
+    y_ref[0] = y
+
+
+def ssm_decode_step_xla(arena, slots, u, dt, Bm, Cm, A, *, layer: int):
+    """:func:`ssm_decode_step`'s XLA form: the rows' states gathered, stepped, scattered."""
+    f32 = jnp.float32
+    y, S = jax.vmap(ssm_step_math, in_axes=(0, 0, 0, 0, 0, None))(
+        arena[slots, layer].astype(f32), dt.astype(f32)[:, None], u.astype(f32)[:, None],
+        Bm.astype(f32)[:, :, None], Cm.astype(f32)[:, :, None], A.astype(f32))
+    return y[:, 0], arena.at[slots, layer].set(S.astype(arena.dtype))
+
+
+def ssm_decode_step(arena, slots, u, dt, Bm, Cm, A, *, layer: int):
+    """One token a row through the selective scan, the state read and written
+    once, in place.  ``arena (slots + 1, L_ssm, N, d)`` (float32, or what the
+    pool was told to store); ``slots (rows,)`` int32, 0 the sink; u, dt ``(rows,
+    d)``, Bm, Cm ``(rows, N)``, ``A (N, d)``.  Returns ``(y (rows, d) float32,
+    arena)``.  The kernel where Pallas runs and the channels are whole lane
+    tiles, else the XLA form."""
+    rows, d = u.shape
+    N = A.shape[0]
+    if not (_enabled() and d % 128 == 0):
+        return ssm_decode_step_xla(arena, slots, u, dt, Bm, Cm, A, layer=layer)
+    stats["ssm_decode"] = stats.get("ssm_decode", 0) + 1
+    f32 = jnp.float32
+    row = lambda i, s: (i, 0, 0)  # noqa: E731
+    mine = lambda i, s: (s[i], layer, 0, 0)  # noqa: E731
+    kwargs = {}
+    if not _interpret():
+        kwargs["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    y, arena = pl.pallas_call(
+        _ssm_decode_kernel,
+        name="ssm_decode_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows,),
+            in_specs=[pl.BlockSpec((1, 1, d), row), pl.BlockSpec((1, 1, d), row),
+                      pl.BlockSpec((1, N, 1), row), pl.BlockSpec((1, N, 1), row),
+                      pl.BlockSpec((N, d), lambda i, s: (0, 0)), pl.BlockSpec((1, 1, N, d), mine)],
+            out_specs=[pl.BlockSpec((1, 1, d), row), pl.BlockSpec((1, 1, N, d), mine)]),
+        out_shape=[jax.ShapeDtypeStruct((rows, 1, d), f32), jax.ShapeDtypeStruct(arena.shape, arena.dtype)],
+        input_output_aliases={6: 1},     # operands: the slot table, five small ones, the arena
+        interpret=_interpret(),
+        **kwargs,
+    )(slots.astype(jnp.int32), u.astype(f32)[:, None], dt.astype(f32)[:, None],
+      Bm.astype(f32)[:, :, None], Cm.astype(f32)[:, :, None], A.astype(f32), arena)
+    return y[:, 0], arena
 
 
 # install the fast paths so XLA fusion regions and TrainStep trace evaluation

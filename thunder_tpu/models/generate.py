@@ -44,6 +44,11 @@ __all__ = [
     "state_shapes",
     "gdn_mixer",
     "shortconv_mixer",
+    "ssm_mixer",
+    "gmu_mixer",
+    "diff_attention",
+    "ring_blocks",
+    "ring_block_shape",
     "mla_mixer",
     "mla_latent",
     "moe_share_mlp",
@@ -454,10 +459,16 @@ def state_shapes(cfg: Config, B: int) -> dict:
     linear_attention: ``conv (L_lin, B, K - 1, channels)``, the conv's last
     inputs, and ``state (L_lin, B, nv, dk, dv)``, the delta rule's.  conv (a
     gated short convolution): ``conv (L_conv, B, conv_kernel - 1, n_embd)``, the
-    conv's last inputs, and nothing else.  Empty for a model of attention
+    conv's last inputs, and nothing else.  ssm (a selective scan): ``conv (L_ssm,
+    B, ssm_conv_kernel - 1, ssm_inner)`` and the scan's ``state (L_ssm, B,
+    ssm_state, ssm_inner)``, the channels on the last axis (the chip would pad a
+    last axis of 16 states to 128 lanes).  Empty for a model of attention
     layers alone."""
     if cfg.conv_layers:
         return {"conv": (len(cfg.conv_layers), B, cfg.conv_kernel - 1, cfg.n_embd)}
+    if cfg.ssm_layers:
+        n = len(cfg.ssm_layers)
+        return {"conv": (n, B, cfg.ssm_conv_kernel - 1, cfg.ssm_inner), "state": (n, B, cfg.ssm_state, cfg.ssm_inner)}
     n = len(cfg.linear_layers)
     if not n:
         return {}
@@ -483,7 +494,9 @@ def kv_block_shape(cfg: Config, block_size: int, lane_pack: int = 1) -> tuple[in
     """Per-block geometry ``(L, n_query_groups, block_size, hs)`` of the
     paged serving pool's arena — one block holds ``block_size`` consecutive
     token slots of every layer's K (or V), so a gather over a request's
-    block table reassembles exactly the :func:`cache_shape` layout.  With
+    block table reassembles exactly the :func:`cache_shape` layout (of
+    ``cfg.paged_kv_layers``: a sliding_attention layer's K and V live in the ring
+    arenas, :func:`ring_block_shape`).  With
     ``lane_pack`` P > 1 (:func:`kv_lane_pack`; the pool's choice) a row holds
     P consecutive KV heads of one token side by side: ``(L, n_query_groups / P,
     block_size, P * hs)``, head ``g`` in lanes ``[(g % P) hs, (g % P + 1) hs)``
@@ -495,7 +508,21 @@ def kv_block_shape(cfg: Config, block_size: int, lane_pack: int = 1) -> tuple[in
     lays a narrower row out anyway and what the decode kernel's copies need."""
     if cfg.latent:
         return (cfg.n_layer, 1, block_size, -(-cfg.latent_width // 128) * 128)
-    return (len(cfg.kv_layers), cfg.n_query_groups // lane_pack, block_size, cfg.head_size * lane_pack)
+    return (len(cfg.paged_kv_layers), cfg.n_query_groups // lane_pack, block_size, cfg.head_size * lane_pack)
+
+
+def ring_blocks(cfg: Config, block_size: int) -> int:
+    """Blocks of a request's ring: the ``layer_window`` tokens a
+    sliding_attention layer may attend, and a block of slack (the window
+    seldom starts on a block's edge).  Block ``i`` of a sequence lives in entry
+    ``i % ring_blocks`` and is overwritten ``ring_blocks`` blocks later, when every
+    token of it has left the window.  0 for a model without such layers."""
+    return -(-cfg.layer_window // block_size) + 1 if cfg.ring_layers else 0
+
+
+def ring_block_shape(cfg: Config, block_size: int, lane_pack: int = 1) -> tuple[int, int, int, int]:
+    """:func:`kv_block_shape` of the sliding_attention layers' ring arenas."""
+    return (len(cfg.ring_layers), *kv_block_shape(cfg, block_size, lane_pack)[1:])
 
 
 def ring_slot(pos, window: int):
@@ -757,6 +784,197 @@ def shortconv_mixer(cp, x, tail, cfg: Config, *, n_real=None, lin=_linear):
         return lin(y, cp["out_proj"]), new_tail
 
 
+
+def ssm_mixer(sp, x, tail, cfg: Config, recur, *, n_real=None, lin=_linear):
+    """An ssm layer's mixer (a selective scan, Mamba-1) on new tokens ``x (B, T,
+    C)``, for the dense cache and the paged server alike: ``[u | z] = x W_in``;
+    ``u <- SiLU(conv(u) + b)``, causal and depthwise over ``[tail | u]`` in
+    float32; ``[r | B | C] = u W_x``; ``dt = softplus(r W_dt + b_dt)``; the scan
+    itself is ``recur(u, dt, B, C, A) -> S C`` (u ``(B, T, d)`` at x's dtype, dt
+    float32, B and C ``(B, T, N)``, ``A = -exp(A_log)`` transposed to ``(N, d)``;
+    the result ``(B, T, d)`` float32), the caller's closure reading and writing
+    the state wherever it keeps it; ``m = S C + D u``; ``(m SiLU(z)) W_out``.
+    ``tail (B, K - 1, d)`` holds the conv's inputs of the K - 1 tokens before
+    ``x``.  Of the T tokens the first ``n_real`` are real (all, where None): the
+    others get ``dt = 0``, which leaves the state exactly as it was, and the new
+    tail ends at the last real token.  Returns ``(y (B, T, C), new tail, m (B,
+    T, d) at x's dtype)``: a gmu layer gates ``m``."""
+    f32 = jnp.float32
+    N, R = cfg.ssm_state, cfg.ssm_dt_rank
+    with scope("ssm/in_proj"):
+        u, z = jnp.split(lin(x, sp["in_proj"]), 2, axis=-1)
+    with scope("ssm/conv"):
+        conv, new_tail = _causal_taps(tail, u, sp["conv_w"], n_real)
+        u = jax.nn.silu(conv + sp["conv_b"].astype(f32)).astype(x.dtype)
+    with scope("ssm/scan"):
+        rbc = lin(u, sp["x_proj"])
+        Bm, Cm = rbc[..., R:R + N].astype(f32), rbc[..., R + N:].astype(f32)
+        dt = jax.nn.softplus(lin(rbc[..., :R], sp["dt_proj"]).astype(f32) + sp["dt_bias"].astype(f32))
+        if n_real is not None:
+            dt = jnp.where((jnp.arange(x.shape[1]) < n_real)[None, :, None], dt, 0.0)
+        m = recur(u, dt, Bm, Cm, -jnp.exp(sp["A_log"].astype(f32)).T)
+        m = (m + sp["D"].astype(f32) * u.astype(f32)).astype(x.dtype)
+    with scope("ssm/out"):
+        return lin((m.astype(f32) * jax.nn.silu(z.astype(f32))).astype(x.dtype), sp["out_proj"]), new_tail, m
+
+
+def ssm_recur_dense(state):
+    """``recur`` for :func:`ssm_mixer` over a dense state ``(B, N, d)``: the scan
+    for a piece of a prompt, one step for a token.  Returns ``(recur, box)``;
+    after the call ``box[0]`` is the state after the last token, in ``state``'s dtype."""
+    box = [state]
+
+    def recur(u, dt, Bm, Cm, A):
+        from thunder_tpu.executors import pallasex
+
+        if u.shape[1] > 1:
+            y, box[0] = pallasex.ssm_scan(u, dt, Bm, Cm, A, state)
+            return y
+        f32 = jnp.float32
+        y, S = jax.vmap(pallasex.ssm_step_math, in_axes=(0, 0, 0, 0, 0, None))(
+            state.astype(f32), dt, u.astype(f32), Bm[:, 0, :, None], Cm[:, 0, :, None], A)
+        box[0] = S.astype(state.dtype)
+        return y
+
+    return recur, box
+
+
+def gmu_mixer(gp, x, m, *, lin=_linear):
+    """A gmu layer's mixer (a gated memory unit): ``(m SiLU(x W_1)) W_2``, ``m (B,
+    T, d)`` the scan output of the model's last ssm layer at the same
+    positions.  No cache: in a decode step ``m`` is a value inside the step."""
+    with scope("gmu/in_proj"):
+        g = lin(x, gp["in_proj"])
+    with scope("gmu/gate"):
+        y = (m.astype(jnp.float32) * jax.nn.silu(g.astype(jnp.float32))).astype(x.dtype)
+    with scope("gmu/out"):
+        return lin(y, gp["out_proj"])
+
+
+def diff_lambda(ap, layer: int):
+    """``(lambda, lambda_init)`` of a differential-attention layer, float32:
+    ``l0 = 0.8 - 0.6 exp(-0.3 layer)``, ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + l0``."""
+    f32 = jnp.float32
+    l0 = 0.8 - 0.6 * math.exp(-0.3 * layer)
+    dot = lambda a, b: jnp.sum(ap[a].astype(f32) * ap[b].astype(f32))  # noqa: E731
+    return jnp.exp(dot("lambda_q1", "lambda_k1")) - jnp.exp(dot("lambda_q2", "lambda_k2")) + l0, l0
+
+
+def diff_attention(ap, x, layer: int, cfg: Config, attend, *, kv_x=None, cross=False, lin=_linear, name="attn"):
+    """A differential-attention layer's mixer, for the dense cache and the paged
+    server alike.  ``x (B, T, C)`` gives the queries; ``kv_x (B, Tk, C)`` the keys
+    and values (``x`` itself where None); ``cross``: a cross_attention layer,
+    which projects no K and V (``attend`` reads another layer's).  Query heads pair ``(2j, 2j + 1)``, KV heads ``(2g, 2g + 1)`` with
+    ``g = j // (n_head / n_query_groups)``: a pair's keys are one row of ``2 hs``
+    lanes, its values ``V_g = [v_2g | v_2g+1]`` another, which is how the paged
+    arena holds them (``kv_lane_pack``).  ``attend(q, k, v) -> a``: ``q (B, G, 2,
+    J, T, hs)`` (G pairs of KV heads; first or second of the pair; J
+    differential heads a pair), ``k`` and ``v`` ``(B, ng, Tk, hs)`` or None, ``a
+    (B, G, 2, J, T, 2 hs)`` the softmax-weighted ``V_g`` of each query, the first
+    over ``k_2g`` and the second over ``k_2g+1``, scores scaled by ``hs^-1/2``.
+    ``o_j = (1 - l0) RMSNorm_2hs(a_1 - lambda a_2)`` (:func:`diff_lambda`), then
+    ``W_o``.  ``name``: the scopes' prefix (``swa``, ``cross``, ``attn``).  Returns
+    ``y (B, T, C)``."""
+    B, T, _ = x.shape
+    hs, nh, ng = cfg.head_size, cfg.n_head, cfg.n_query_groups
+    G, J = ng // 2, nh // ng
+    with scope(f"{name}/qkv"):
+        q = lin(x, ap["wq"], ap.get("bq")).reshape(B, T, G, J, 2, hs).transpose(0, 2, 4, 3, 1, 5)
+        k = v = None
+        if not cross:
+            src = x if kv_x is None else kv_x
+            heads = lambda a: a.reshape(B, src.shape[1], ng, hs).transpose(0, 2, 1, 3)  # noqa: E731
+            k, v = heads(lin(src, ap["wk"], ap.get("bk"))), heads(lin(src, ap["wv"], ap.get("bv")))
+    a = attend(q, k, v)
+    with scope(f"{name}/diff"):
+        lam, l0 = diff_lambda(ap, layer)
+        o = a[:, :, 0].astype(jnp.float32) - lam * a[:, :, 1].astype(jnp.float32)     # (B, G, J, T, 2 hs)
+        o = (_rms(o, ap["subln"], cfg.norm_eps) * (1.0 - l0)).astype(x.dtype)
+    with scope(f"{name}/out"):
+        return lin(o.transpose(0, 3, 1, 2, 4).reshape(B, T, nh * hs), ap["wo"], ap.get("bo"))
+
+
+def pair_rows(k):
+    """K (or V) ``(B, ng, T, hs)`` as a head pair's rows ``(B, ng / 2, T, 2 hs)``: heads ``2g`` and ``2g + 1`` side by side."""
+    B, ng, T, hs = k.shape
+    return k.reshape(B, ng // 2, 2, T, hs).transpose(0, 1, 3, 2, 4).reshape(B, ng // 2, T, 2 * hs)
+
+
+def diff_attend_dense(q, kr, vr, keep, *, causal_window=False):
+    """``attend`` of :func:`diff_attention` over key and value rows in hand: ``q
+    (B, G, 2, J, T, hs)``, ``kr``/``vr (B, G, Tk, 2 hs)`` (:func:`pair_rows`),
+    ``keep`` a mask that broadcasts against ``(B, G, J, T, Tk)``.
+    ``causal_window``: the mask is a prompt's own causal triangle, banded by
+    that window where it is an int (None: no band; False: some other mask): the
+    flash kernel takes it where it takes the shapes, the queries padded with
+    zeros to the row's ``2 hs`` lanes in their own half, which cancels the
+    other head's keys exactly."""
+    B, G, _, J, T, hs = q.shape
+    scale = 1.0 / math.sqrt(hs)
+    if causal_window is not False:
+        from thunder_tpu.executors import pallasex
+
+        own = jnp.eye(2, dtype=q.dtype)                                 # (half of the query, half of the lanes)
+        qp = (q[:, :, :, :, :, None, :] * own[None, None, :, None, None, :, None]).reshape(B, G * 2 * J, T, 2 * hs)
+        flash = pallasex.flash_sdpa(qp, kr, vr, None, True, scale, causal_window)
+        if flash is not None:
+            return flash[0].reshape(B, G, 2, J, T, 2 * hs)
+    out = []
+    for half in range(2):
+        s = jnp.einsum("bgjtd,bgkd->bgjtk", q[:, :, half], kr[..., half * hs:(half + 1) * hs].astype(q.dtype),
+                       preferred_element_type=jnp.float32) * scale
+        w = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(q.dtype)
+        out.append(jnp.einsum("bgjtk,bgkd->bgjtd", w, vr.astype(q.dtype)))
+    return jnp.stack(out, axis=2)
+
+
+def _diff_attn_with_cache(ap, x, layer, ck, cv, pos, cfg: Config, *, kind, row=None, lin=_linear, sharded=False):
+    """A differential-attention layer of :func:`forward_with_cache`: ``x (B, T,
+    C)`` at positions ``[pos, pos + T)`` against and into this layer's dense
+    cache ``ck``/``cv (B, ng, Tc, hs)`` (slot = position; a sliding_attention
+    layer's window lives in the mask).  ``kind`` "cross_attention": ``ck``/``cv``
+    are the cross source's, read and not written.  ``row``: the queries are row
+    ``row`` of ``x`` alone (a prompt's cross half; the layer's own K and V still
+    come from every row).  A prompt at a static position 0 attends its fresh keys
+    (the flash kernel where it takes them).  Returns ``(y, ck, cv)``."""
+    B, T, _ = x.shape
+    W = cfg.layer_window if kind == "sliding_attention" else None
+    vec = _is_vec_pos(pos)
+    fresh = isinstance(pos, int) and pos == 0 and T > 1 and kind != "cross_attention"
+    name = {"sliding_attention": "swa", "cross_attention": "cross"}.get(kind, "attn")
+    box = [ck, cv]
+    # a cross layer after the narrowed one is handed the one row already
+    xq = x if row is None or T == 1 else jax.lax.dynamic_slice_in_dim(x, row, 1, axis=1)
+    Tq = xq.shape[1]
+
+    def attend(q, k, v):
+        with scope(f"{name}/cache"):
+            if k is not None:
+                if vec:
+                    upd = jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(c, u, p, axis=1))
+                    box[0], box[1] = upd(ck, k.astype(ck.dtype), pos), upd(cv, v.astype(cv.dtype), pos)
+                else:
+                    box[0] = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), pos, axis=2)
+                    box[1] = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), pos, axis=2)
+            kk, vv = (k, v) if fresh else (box[0], box[1])
+            kr, vr = pair_rows(kk), pair_rows(vv)
+            first = (pos[:, None] if vec else pos) + (0 if row is None else row)
+            qpos = (first + jnp.arange(Tq))[..., :, None]                           # (B?, Tq, 1)
+            j = jnp.arange(kr.shape[2])
+            keep = j <= qpos
+            if W is not None:
+                keep = jnp.logical_and(keep, j > qpos - W)
+            keep = keep[:, None, None] if vec else keep[None, None, None]
+        with scope(f"{name}/attn"):
+            # a whole prompt attending itself off a mesh: the flash kernel's case
+            whole = fresh and row is None and not sharded
+            return diff_attend_dense(q, kr, vr, keep,
+                                     causal_window=(W if W is not None and T > W else None) if whole else False)
+
+    y = diff_attention(ap, xq, layer, cfg, attend, kv_x=x, cross=kind == "cross_attention", lin=lin, name=name)
+    return y, box[0], box[1]
+
+
 def gdn_recur_dense(state):
     """``recur`` for :func:`gdn_mixer` over a dense state ``(B, nv, dk, dv)``:
     the chunked scan for a piece of a prompt, one step of the rule for a
@@ -873,10 +1091,17 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
 
     new_k, new_v, new_conv, new_state, new_latent = [], [], [], [], []
     lin = partial(_linear, quantized=quantized)
+    # a model with a cross half (``cfg.cross_from``) whose caller wants one row's
+    # logits runs that half on the one row: the layer the cross layers read
+    # projects K and V on every position and its query on the row; the layers
+    # after it keep no cache and see the row alone.  Exact, and half a prompt's products
+    one_row = logits_at is not None and cfg.cross_from is not None
+    gmu_m = None
     for l, bp in enumerate(params["blocks"]):
         lora_l = None
         if lora:
             lora_l = {t: (ab["a"][:, l], ab["b"][:, l]) for t, ab in lora.items()}
+        kind = cfg.layer_kind(l)
         with scope(f"blk{l}"):
             with scope("mixer"):
                 # OLMo's blocks norm what a sublayer gives, not what it takes
@@ -885,13 +1110,36 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
                 else:
                     with scope("norm"):
                         n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
-                if cfg.layer_kind(l) == "linear_attention":
+                if kind == "ssm":
+                    j = len(new_state)
+                    recur, box = ssm_recur_dense(cache["state"][j])
+                    h, tail, m = ssm_mixer(bp["ssm"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
+                    new_conv.append(tail)
+                    new_state.append(box[0])
+                    if l == cfg.gmu_source:
+                        gmu_m = m
+                elif kind == "gmu":
+                    h = gmu_mixer(bp["gmu"], n1, gmu_m, lin=lin)
+                elif cfg.diff_attention:
+                    j = len(new_k) if kind != "cross_attention" else cfg.kv_layers.index(cfg.cross_from)
+                    src = cache if kind != "cross_attention" else {"k": new_k, "v": new_v}
+                    narrow = one_row and l == cfg.cross_from
+                    h, ck, cv = _diff_attn_with_cache(
+                        bp["attn"], n1, l, src["k"][j], src["v"][j], pos, cfg, kind=kind, lin=lin, sharded=sharded,
+                        row=logits_at if narrow or (one_row and kind == "cross_attention") else None)
+                    if kind != "cross_attention":
+                        new_k.append(ck)
+                        new_v.append(cv)
+                    if narrow:      # from here on the row alone
+                        x, n1 = (jax.lax.dynamic_slice_in_dim(a, logits_at, 1, axis=1) for a in (x, n1))
+                        gmu_m = None if gmu_m is None else jax.lax.dynamic_slice_in_dim(gmu_m, logits_at, 1, axis=1)
+                elif kind == "linear_attention":
                     j = len(new_state)
                     recur, box = gdn_recur_dense(cache["state"][j])
                     h, tail = gdn_mixer(bp["gdn"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
                     new_conv.append(tail)
                     new_state.append(box[0])
-                elif cfg.layer_kind(l) == "conv":
+                elif kind == "conv":
                     h, tail = shortconv_mixer(bp["conv"], n1, cache["conv"][len(new_conv)], cfg,
                                               n_real=n_real, lin=lin)
                     new_conv.append(tail)
@@ -915,7 +1163,7 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
             cache.update(conv=jnp.stack(new_conv))
         if new_state:
             cache.update(state=jnp.stack(new_state))
-    return _head_logits(params, x, cfg, logits_at, quantized), cache
+    return _head_logits(params, x, cfg, None if one_row else logits_at, quantized), cache
 
 
 def _head_logits(params, x, cfg: Config, logits_at, quantized):

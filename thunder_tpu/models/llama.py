@@ -42,6 +42,10 @@ __all__ = [
 ]
 
 
+# the layer kinds of a decoder-hybrid-decoder (SambaY): built in models.generate, for tt.serve
+HYBRID_DECODER_KINDS = ("ssm", "sliding_attention", "gmu", "cross_attention")
+
+
 @dataclass
 class Config:
     """Architecture description (reference: litgpt Config; tests/litgpt_model.py:7)."""
@@ -96,9 +100,35 @@ class Config:
     gelu_approximate: str = "none"
     # A kind per layer (hf ``layer_types``): "full_attention" (softmax
     # attention, the default for every layer), "linear_attention" (a gated
-    # delta-rule mixer, below) or "conv" (a gated short convolution, below; in
-    # the server alone).  None = all full attention
+    # delta-rule mixer, below), "conv" (a gated short convolution, below; in
+    # the server alone), or, in the server alone too, the four kinds of a
+    # decoder-hybrid-decoder: "ssm" (a selective scan, below),
+    # "sliding_attention" (softmax attention over the last ``layer_window``
+    # keys: the window is this kind's, where ``sliding_window`` is every
+    # layer's), "gmu" (a gated memory unit: the last ssm layer's scan output at
+    # the same position, gated) and "cross_attention" (queries alone; keys and
+    # values are the last full_attention layer's).  None = all full attention
     layer_types: tuple | None = None
+    layer_window: int | None = None
+    # Differential attention in every attention layer of the model (the cross
+    # ones too): query heads in adjacent pairs ``(2j, 2j + 1)``, KV heads in
+    # adjacent pairs ``(2g, 2g + 1)``, ``g = j // (n_head / n_query_groups)``;
+    # ``o_j = (1 - l0) RMSNorm_{2 hs}((A_1 - lambda A_2) [v_2g | v_2g+1])``,
+    # ``A_1 = softmax(q_2j k_2g^T)``, ``A_2 = softmax(q_2j+1 k_2g+1^T)``,
+    # ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + l0``, ``l0 = 0.8 - 0.6 exp(-0.3 layer)``
+    diff_attention: bool = False
+    # The selective scan of "ssm" layers (Mamba-1): ``[u | z] = x W_in`` (to 2
+    # ``ssm_inner``); ``u <- SiLU(conv(u) + b)``, causal and depthwise over
+    # ``ssm_conv_kernel`` taps; ``[r | B | C] = u W_x`` (to ``ssm_dt_rank`` + 2
+    # ``ssm_state``); ``dt = softplus(r W_dt + b_dt)``; ``S_t = exp(dt_t A) S_t-1 +
+    # (dt_t u_t) B_t^T`` with ``A = -exp(A_log)`` (``ssm_inner`` x ``ssm_state``),
+    # in float32; ``m_t = S_t C_t + D u_t``; ``(m SiLU(z)) W_out``.  A sequence
+    # keeps ``S`` and the conv's last ``ssm_conv_kernel - 1`` inputs.  A "gmu"
+    # layer is ``(m SiLU(x W_1)) W_2`` on the last ssm layer's ``m``
+    ssm_inner: int = 0
+    ssm_state: int = 16
+    ssm_dt_rank: int = 0
+    ssm_conv_kernel: int = 4
     # The gated short convolution of "conv" layers (hf Lfm2ShortConv): ``[B | C |
     # u] = x W_in``, a causal depthwise conv of ``conv_kernel`` taps (hf
     # ``conv_L_cache``) without bias or activation over ``B * u``, gated by ``C``,
@@ -218,9 +248,12 @@ class Config:
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             assert len(self.layer_types) == self.n_layer, "layer_types needs one kind a layer"
-            assert set(self.layer_types) <= {"full_attention", "linear_attention", "conv"}, self.layer_types
-            assert not {"linear_attention", "conv"} <= set(self.layer_types), (
-                "linear_attention and conv layers in one model: a request's state slot holds one kind's arenas")
+            assert set(self.layer_types) <= {"full_attention", "linear_attention", "conv", *HYBRID_DECODER_KINDS}, (
+                self.layer_types)
+            assert sum(k in self.layer_types for k in ("linear_attention", "conv", "ssm")) <= 1, (
+                "linear_attention, conv and ssm layers: a request's state slot holds one kind's arenas")
+            if set(self.layer_types) & set(HYBRID_DECODER_KINDS):
+                self._check_hybrid_decoder()
             if "conv" in self.layer_types:
                 assert self.conv_kernel >= 2, "conv layers need conv_kernel >= 2 taps"
                 assert not self.bias and not self.parallel_residual, "conv: sequential, bias-free blocks only"
@@ -267,6 +300,29 @@ class Config:
             scale *= _yarn_mscale(float(yarn["factor"]), float(yarn["mscale_all_dim"])) ** 2
         return scale
 
+    def _check_hybrid_decoder(self) -> None:
+        """What the four kinds of a decoder-hybrid-decoder need of each other."""
+        kinds = self.layer_types
+        assert self.sliding_window is None, "layer_window is the window of sliding_attention layers; sliding_window is model-wide"
+        assert not (self.parallel_residual or self.post_sublayer_norm or self.latent or self.qk_norm
+                    or self.qk_norm_whole), "ssm / sliding_attention / gmu / cross_attention: plain sequential blocks"
+        if "sliding_attention" in kinds:
+            assert self.layer_window and self.layer_window > 0, "sliding_attention layers need layer_window"
+        if "ssm" in kinds or "gmu" in kinds:
+            assert self.ssm_inner > 0 and self.ssm_state > 0 and self.ssm_dt_rank > 0 and self.ssm_conv_kernel >= 2, (
+                "ssm layers need ssm_inner, ssm_state, ssm_dt_rank and ssm_conv_kernel >= 2")
+        if "gmu" in kinds:
+            assert "ssm" in kinds[:kinds.index("gmu")], "a gmu layer gates the scan output of an ssm layer before it"
+        if "cross_attention" in kinds:
+            assert "full_attention" in kinds[:kinds.index("cross_attention")], (
+                "a cross_attention layer reads the K/V of a full_attention layer before it")
+            first = min(kinds.index(k) for k in ("gmu", "cross_attention") if k in kinds)
+            assert not set(kinds[first:]) - {"gmu", "cross_attention"}, (
+                "the cross half (gmu and cross_attention layers) is the model's last layers")
+        if self.diff_attention:
+            assert self.n_head % 2 == 0 and self.n_query_groups % 2 == 0 and 2 * self.head_size == 128, (
+                "differential attention: head pairs that fill a 128-lane row (heads of 64)")
+
     def mlp_dense(self, i: int) -> bool:
         """Whether layer ``i`` of a SparseMoE model is one of its leading dense layers."""
         return self.mlp_class == "SparseMoE" and i < self.first_k_dense
@@ -279,7 +335,51 @@ class Config:
         """The model layers that keep K and V, in order: the one map from a
         model layer to its layer of the server's K/V cache and arenas
         (``kv_layers.index(i)``).  Every layer of a model without ``layer_types``."""
-        return tuple(i for i in range(self.n_layer) if self.layer_kind(i) == "full_attention")
+        return tuple(i for i in range(self.n_layer) if self.layer_kind(i) in ("full_attention", "sliding_attention"))
+
+    @property
+    def ring_layers(self) -> tuple:
+        """The sliding_attention layers, in order: their K/V live in a ring of
+        ``layer_window`` tokens (and a block of slack) a request, whatever its
+        length (``ring_layers.index(i)`` is the layer of the ring arenas)."""
+        return tuple(i for i in range(self.n_layer) if self.layer_kind(i) == "sliding_attention")
+
+    @property
+    def paged_kv_layers(self) -> tuple:
+        """The layers whose K/V fill a request's block table, a block every
+        ``block_size`` tokens of its whole length: ``kv_layers`` less ``ring_layers``."""
+        return tuple(i for i in self.kv_layers if self.layer_kind(i) == "full_attention")
+
+    @property
+    def ssm_layers(self) -> tuple:
+        """The selective-scan layers, in order (``ssm_layers.index(i)`` is the layer of the ssm and conv arenas)."""
+        return tuple(i for i in range(self.n_layer) if self.layer_kind(i) == "ssm")
+
+    @property
+    def hybrid_decoder(self) -> bool:
+        """A decoder-hybrid-decoder: any of its four layer kinds, or differential
+        attention.  The server keeps such a model's caches a layer kind and runs
+        it through the paged decode program and whole-prompt prefills alone."""
+        return bool(set(self.layer_types or ()) & set(HYBRID_DECODER_KINDS)) or self.diff_attention
+
+    @property
+    def cross_from(self) -> int | None:
+        """The layer whose K/V the cross_attention layers read (the last
+        full_attention layer before them), or None.  From this layer on a
+        prompt needs one row alone: the layer's own K/V on every position, and
+        its query, the layers after it and the head on the row that is sampled."""
+        kinds = self.layer_types or ()
+        if "cross_attention" not in kinds:
+            return None
+        return max(i for i in range(kinds.index("cross_attention")) if kinds[i] == "full_attention")
+
+    @property
+    def gmu_source(self) -> int | None:
+        """The ssm layer whose scan output the gmu layers gate: the last before them."""
+        kinds = self.layer_types or ()
+        if "gmu" not in kinds:
+            return None
+        return max(i for i in range(kinds.index("gmu")) if kinds[i] == "ssm")
 
     @property
     def linear_layers(self) -> tuple:
@@ -296,8 +396,8 @@ class Config:
     @property
     def state_layers(self) -> tuple:
         """The layers that keep something a request beside K and V (a slot of
-        the server's state pool): the linear_attention or the conv layers."""
-        return self.linear_layers or self.conv_layers
+        the server's state pool): the linear_attention, the conv or the ssm layers."""
+        return self.linear_layers or self.conv_layers or self.ssm_layers
 
     @property
     def linear_qkv_width(self) -> int:
@@ -423,6 +523,11 @@ name_to_config: dict[str, Config] = {c.name: c for c in configs}
 #
 
 
+def _inv_softplus(y):
+    """``x`` with ``softplus(x) = y``, for ``y > 0``."""
+    return y + jnp.log(-jnp.expm1(-y))
+
+
 def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16) -> dict:
     """Builds the params pytree.  Layout (per block):
     attn: qkv packed as separate wq/wk/wv + wo; mlp: fc_1 (gate), fc_2 (up),
@@ -484,6 +589,24 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 "conv_w": dense(next(keys), config.conv_kernel, C),
                 "out_proj": dense(next(keys), C, C),
             }
+        elif config.layer_kind(i) == "ssm":
+            C, d, N, R = config.n_embd, config.ssm_inner, config.ssm_state, config.ssm_dt_rank
+            block["ssm"] = {
+                "in_proj": dense(next(keys), C, 2 * d),             # [u | z]
+                "conv_w": dense(next(keys), config.ssm_conv_kernel, d),
+                "conv_b": zeros(d),
+                "x_proj": dense(next(keys), d, R + 2 * N),           # [r | B | C]
+                "dt_proj": dense(next(keys), R, d),
+                # as the layer that trains these models starts them: dt log-uniform
+                # in [1e-3, 0.1] through the softplus, A = -(1 .. N), D = 1
+                "dt_bias": _inv_softplus(jnp.exp(jnp.linspace(math.log(1e-3), math.log(0.1), d))).astype(jnp.float32),
+                "A_log": jnp.broadcast_to(jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)), (d, N)),
+                "D": jnp.ones((d,), jnp.float32),
+                "out_proj": dense(next(keys), d, C),
+            }
+        elif config.layer_kind(i) == "gmu":
+            block["gmu"] = {"in_proj": dense(next(keys), config.n_embd, config.ssm_inner),
+                            "out_proj": dense(next(keys), config.ssm_inner, config.n_embd)}
         elif config.latent:
             dc, dr, rq = config.kv_lora_rank, config.qk_rope_head_dim, config.q_lora_rank
             block["attn"] = {
@@ -496,21 +619,25 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 "wq_b": dense(next(keys), rq, nh * hs),
             }
         else:
-            block["attn"] = {
-                "wq": dense(next(keys), config.n_embd, nh * hs * (2 if config.attn_output_gate else 1)),
-                "wk": dense(next(keys), config.n_embd, ng * hs),
-                "wv": dense(next(keys), config.n_embd, ng * hs),
-                "wo": dense(next(keys), nh * hs, config.n_embd),
-            }
+            block["attn"] = {"wq": dense(next(keys), config.n_embd, nh * hs * (2 if config.attn_output_gate else 1))}
+            if config.layer_kind(i) != "cross_attention":       # a cross layer projects its queries alone
+                block["attn"].update(wk=dense(next(keys), config.n_embd, ng * hs),
+                                     wv=dense(next(keys), config.n_embd, ng * hs))
+            block["attn"]["wo"] = dense(next(keys), nh * hs, config.n_embd)
+            if config.diff_attention:
+                lam = jax.random.normal(next(keys), (4, hs), jnp.float32) * 0.1
+                block["attn"].update(lambda_q1=lam[0], lambda_k1=lam[1], lambda_q2=lam[2], lambda_k2=lam[3],
+                                     subln=jnp.ones((2 * hs,), dtype=dtype))
             if config.qk_norm:
                 block["attn"].update(q_norm=norm_init((hs,), dtype=dtype), k_norm=norm_init((hs,), dtype=dtype))
             if config.qk_norm_whole:
                 block["attn"].update(q_norm=norm_init((nh * hs,), dtype=dtype), k_norm=norm_init((ng * hs,), dtype=dtype))
         if config.bias:     # never beside a linear_attention or conv layer (Config refuses)
             block["norm_1_b"] = zeros(config.n_embd)
-            block["attn"].update(
-                bq=zeros(nh * hs), bk=zeros(ng * hs), bv=zeros(ng * hs), bo=zeros(config.n_embd)
-            )
+            if "attn" in block:
+                block["attn"].update(bq=zeros(nh * hs), bo=zeros(config.n_embd))
+                if "wk" in block["attn"]:
+                    block["attn"].update(bk=zeros(ng * hs), bv=zeros(ng * hs))
         if not config.shared_attention_norm:
             block["norm_2"] = norm_init((config.n_embd,), dtype=dtype)
             if config.bias:
@@ -880,12 +1007,17 @@ def mlp(mp, x, config: Config):
 
 def serving_only(config: Config) -> str | None:
     """Why ``block_forward`` (``tt.jit`` / ``make_train_step``) cannot run this
-    config, or None: latent attention, the gated short convolution, the sigmoid
-    routers and leading dense layers are built in ``models.generate`` for the
-    server alone."""
+    config, or None: latent attention, the gated short convolution, a
+    decoder-hybrid-decoder's kinds (selective scan, per-kind window, gated
+    memory unit, cross attention, differential attention), the sigmoid routers
+    and leading dense layers are built in ``models.generate`` for the server
+    alone."""
     if config.conv_layers:
         return ("layer_types with 'conv' (the gated short convolution is built in models.generate, for tt.serve, "
                 "and has no traced form)")
+    if config.hybrid_decoder:
+        return ("layer_types with 'ssm', 'sliding_attention', 'gmu' or 'cross_attention', or differential attention "
+                "(a decoder-hybrid-decoder's kinds are built in models.generate, for tt.serve, and have no traced form)")
     if config.latent:
         return "kv_lora_rank > 0 (latent attention is built in models.generate, for tt.serve, and has no traced form)"
     if config.mlp_class == "SparseMoE" and (config.moe_router != "softmax" or config.first_k_dense

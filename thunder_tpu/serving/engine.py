@@ -113,6 +113,7 @@ from thunder_tpu.serving.faults import (
     resolve_fault_plan,
 )
 from thunder_tpu.serving.kv_pool import (
+    RING_ARENAS,
     gather_state,
     scatter_state,
     SINK_BLOCK,
@@ -122,6 +123,8 @@ from thunder_tpu.serving.kv_pool import (
     dest_for_pos,
     gather_dense,
     gather_rows,
+    ring_dest,
+    ring_tables,
     scatter_blocks,
     scatter_token,
 )
@@ -255,11 +258,31 @@ _program_cache: dict = {}
 
 
 def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=None, lora=None,
-                       mesh=None, decode_steps: int = 1, model_fn=None) -> str | None:
+                       mesh=None, decode_steps: int = 1, model_fn=None, kv_dtype=None, prefill_chunk=None,
+                       priorities=None, fault_plan=None, attn: str = "auto") -> str | None:
     """Why an engine with these options cannot serve a config that keeps a
     state a request beside its KV (a delta rule's recurrent state and conv
-    tail, or a short convolution's tail alone), or None.  Each is a mechanism
-    that is not built, not a shortcut that was skipped (ROADMAP Queue 2)."""
+    tail, a short convolution's tail alone, or a selective scan's state and
+    tail), or None.  Each is a mechanism that is not built, not a shortcut that
+    was skipped (ROADMAP Queue 2).  A window of a layer kind
+    (``cfg.layer_window``) passes: its K/V live in the slot's ring; the
+    model-wide ``sliding_window`` beside a state is still refused.  A
+    decoder-hybrid-decoder (``cfg.hybrid_decoder``) has its paged decode
+    program and whole-prompt prefills alone, and refuses what needs another."""
+    if getattr(cfg, "hybrid_decoder", False):
+        if kv_dtype is not None:
+            return ("kv_dtype= (an int8 or fp8 arena) is unsupported: the ring arenas and the differential walk "
+                    "(two K heads a 128-lane row, one scale a head) have no quantised form")
+        if prefill_chunk is not None:
+            return ("prefill_chunk= is unsupported: a piece of a prompt past position 0 has no program over "
+                    "per-kind caches (the scan from a slot's state, the ring's older blocks)")
+        if priorities is not None:
+            return "priorities= is unsupported: a preempted request resumes through the chunk programs, which are not built"
+        if fault_plan is not None:
+            return "fault_plan= is unsupported: re-prefill recovery replays through the chunk programs, which are not built"
+        if attn == "gather":
+            return ("attn='gather' is unsupported: the gather decode program has no per-kind form; the paged decode "
+                    "program runs the walk's XLA form where Pallas is off")
     if prefix_sharing:
         return ("prefix_sharing=True is unsupported: a prefix's KV blocks can be shared, its "
                 "recurrent state or conv tail cannot (no snapshot of the state at a block edge is kept)")
@@ -372,12 +395,17 @@ class ServingEngine:
         if self._hybrid:
             why = hybrid_unsupported(
                 cfg, prefix_sharing=prefix_sharing, sessions=sessions, speculative=speculative,
-                lora=lora, mesh=mesh, decode_steps=decode_steps, model_fn=model_fn)
+                lora=lora, mesh=mesh, decode_steps=decode_steps, model_fn=model_fn, kv_dtype=kv_dtype,
+                prefill_chunk=prefill_chunk, priorities=priorities, fault_plan=fault_plan, attn=attn)
             if why:
                 kind = ("linear_attention layers (a recurrent state a request)" if cfg.linear_layers
-                        else "conv layers (a conv tail a request)")
+                        else "conv layers (a conv tail a request)" if cfg.conv_layers
+                        else "ssm layers (a selective scan's state a request) beside per-kind K/V")
                 raise NotImplementedError(f"config {getattr(cfg, 'name', '?')!r} has {kind}: {why}")
             prefix_sharing = False
+        # per-kind caches (a ring a slot for window layers, one layer's blocks read
+        # by the cross layers): the paged decode program and whole prompts alone
+        self._perkind = bool(getattr(cfg, "hybrid_decoder", False))
         self._latent = bool(getattr(cfg, "latent", False))
         if self._latent:
             why = latent_unsupported(cfg, kv_dtype=kv_dtype, cache_dtype=cache_dtype, speculative=speculative,
@@ -454,9 +482,9 @@ class ServingEngine:
             if not ok:
                 raise ValueError(f"attn='paged' is unsupported here: {why}")
             self.attn, self._attn_fallback_reason = "paged", None
-        elif attn == "auto" and ok and (paged_available() or self._latent):
-            # a latent arena has the paged decode program alone: without
-            # Pallas its kernel call is the XLA form
+        elif attn == "auto" and ok and (paged_available() or self._latent or self._perkind):
+            # a latent arena and per-kind caches have the paged decode program
+            # alone: without Pallas its kernel call is the XLA form
             self.attn, self._attn_fallback_reason = "paged", None
         elif attn == "auto":
             self.attn = "gather"
@@ -1122,7 +1150,11 @@ class ServingEngine:
         ``latent (L, tokens, latent_width)`` in their place; and, for a model with
         linear_attention layers, its slot's ``state (L_lin, nv, dk, dv)`` and
         ``conv (L_lin, K - 1, channels)`` as stored (conv layers: ``conv
-        (L_conv, conv_kernel - 1, n_embd)`` alone).  A lane-packed arena's
+        (L_conv, conv_kernel - 1, n_embd)`` alone; ssm layers: ``state (L_ssm,
+        ssm_state, ssm_inner)`` and ``conv``).  A model with sliding_attention
+        layers: ``k``/``v`` are the full_attention layers' and ``k_ring``/``v_ring
+        (L_ring, ng, n, hs)`` the window layers' last ``n = min(tokens,
+        layer_window)`` tokens, in order.  A lane-packed arena's
         heads come apart again.  For the tests and for a
         comparison with a reference; changes nothing."""
         if self.async_step:
@@ -1142,7 +1174,18 @@ class ServingEngine:
             k, v = gather_dense(arenas["k"], arenas["v"], table, self.pool.lane_pack)
         out = {"tokens": req.pos, "k": k[:, 0, :, :req.pos], "v": v[:, 0, :, :req.pos]}
         if self._hybrid:
-            out.update({name: arena[req.state_slot] for name, arena in self.pool.state.arenas.items()})
+            state = self.pool.state
+            out.update({name: arena[req.state_slot] for name, arena in state.arenas.items() if name not in RING_ARENAS})
+            if state.ring_blocks:
+                # the ring's tokens in order: the last ``layer_window`` (fewer for a
+                # shorter sequence), ``[tokens - n, tokens)``, what the last query attended
+                bs, n = self.pool.block_size, min(req.pos, self.cfg.layer_window)
+                lo = (req.pos - n) // bs
+                table = ring_tables(jnp.asarray([req.state_slot], jnp.int32), state.ring_blocks,
+                                    self.pool.blocks_for_tokens(req.pos))[:, lo:]
+                for name in RING_ARENAS:
+                    rows = gather_rows(arenas[name], table, self.pool.lane_pack)[:, 0]
+                    out[name] = rows[:, :, req.pos - n - lo * bs:req.pos - lo * bs]
         return out
 
     def shutdown(self, *, drain: bool = True) -> None:
@@ -1249,6 +1292,12 @@ class ServingEngine:
                 # step; and the KV heads a row of the arena holds
                 "path": self._attn_path,
                 "lane_pack": self.pool.lane_pack,
+                # per-kind caches: the layers that walk one layer's blocks (the
+                # layer itself and the cross_attention layers after it), and the
+                # rows whole prompts ran through those layers (one a prompt)
+                **({"shared_kv_layers": 1 + sum(k == "cross_attention" for k in self.cfg.layer_types),
+                    "prefill_cross_rows": self.prefill_fresh_runs}
+                   if self._perkind and self.cfg.cross_from is not None else {}),
                 # per-kind resolution: decode and chunk-prefill resolve
                 # independently (the chunk kernel needs block-aligned
                 # widths and no sliding window), so a single top-level
@@ -2813,6 +2862,7 @@ class ServingEngine:
             # a latent engine builds the paged decode program with or without
             # Pallas (the kernel, or its XLA form): which one is the program's
             ("latent", paged_available()) if self._latent else None,
+            ("perkind", paged_available()) if self._perkind else None,
         )
 
     def _program(self, kind: str, a: int, b: int) -> tuple[Callable, bool]:
@@ -2828,6 +2878,10 @@ class ServingEngine:
         gkey = (static, kind, a, b) if static is not None else None
         prog = _program_cache.get(gkey) if gkey is not None else None
         compiled = prog is None
+        if compiled and self._perkind and kind not in ("prefill_fresh", "decode_paged"):
+            raise NotImplementedError(
+                f"program kind {kind!r} is not built for per-kind caches (config {getattr(self.cfg, 'name', '?')!r}): "
+                "a whole prompt at position 0 (prefill_fresh) and one token a row (decode_paged) are")
         if compiled:
             if kind in ("spec_prefill", "spec_prefill_chunk", "draft_decode",
                         "verify", "verify_paged"):
@@ -2939,10 +2993,27 @@ class ServingEngine:
         return {"k": kd, "v": vd}
 
     @scope("mixer/cache")
-    def _blocks_back(self, arenas, cache, dest) -> tuple[dict, Any]:
+    def _blocks_back(self, arenas, cache, dest, ring=None) -> tuple[dict, Any]:
         """A one-row dense cache's blocks back into their arenas at ``dest``
         (inside a program): the arenas written, and the quantisation error
-        measured (0 for a pool stored at the compute dtype)."""
+        measured (0 for a pool stored at the compute dtype).  ``ring`` (a
+        model with sliding_attention layers): the prompt's ``(state slot,
+        n_real)``; those layers' last blocks go to the slot's ring
+        (``kv_pool.ring_dest``), the other layers' to ``dest``."""
+        cfg = self.cfg
+        if getattr(cfg, "ring_layers", ()):
+            sslot, n_real = ring
+            bs, n_ring = self.pool.block_size, self.pool.state.ring_blocks
+            # a kind's layers of the dense cache, by slices (an index array would gather the cache)
+            of = lambda a, layers: jnp.stack([a[cfg.kv_layers.index(i)] for i in layers])  # noqa: E731
+            n = min(n_ring, cache["k"].shape[3] // bs)
+            start, rdest = ring_dest(sslot[0], n_real, n, n_ring, bs)
+            out = {}
+            for name in ("k", "v"):
+                last = jax.lax.dynamic_slice_in_dim(of(cache[name], cfg.ring_layers), start * bs, n * bs, axis=3)
+                out[name + "_ring"] = scatter_blocks(arenas[name + "_ring"], last, rdest)
+                out[name] = scatter_blocks(arenas[name], of(cache[name], cfg.paged_kv_layers), dest)
+            return out, jnp.float32(0.0)
         if self._latent:
             return {"latent": scatter_blocks(arenas["latent"], cache["latent"], dest)}, jnp.float32(0.0)
         if self.pool.quantized_kv:
@@ -3016,7 +3087,7 @@ class ServingEngine:
             tok = sample_token(last, temp, sub)            # (1,) — solo-prefill parity
             with scope("mixer/cache"):
                 kept = scatter_state(arenas, cache, sslot) if hybrid else {}
-            written, qerr = self._blocks_back(arenas, cache, dest)
+            written, qerr = self._blocks_back(arenas, cache, dest, ring=(sslot, n_real) if hybrid else None)
             return tok, {**written, **kept}, key, qerr
 
         if fresh:

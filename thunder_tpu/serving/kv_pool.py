@@ -55,12 +55,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from thunder_tpu.models.generate import kv_block_shape, kv_lane_pack
+from thunder_tpu.models.generate import kv_block_shape, kv_lane_pack, ring_block_shape, ring_blocks
 from thunder_tpu.serving.quant import is_quantized_kv, resolve_kv_dtype
 
 __all__ = ["PoolExhaustedError", "ArenaMismatchError", "PagedKVPool", "StatePool",
            "PrefixIndex", "chunk_tables", "dest_for_pos", "gather_state", "scatter_state", "gather_rows", "pack_lanes",
-           "OCCUPANCY_WINDOW"]
+           "ring_tables", "ring_dest", "RING_ARENAS", "OCCUPANCY_WINDOW"]
 
 SINK_BLOCK = 0  # reserved physical block for padding/expired table entries
 
@@ -96,6 +96,7 @@ class ArenaMismatchError(ValueError):
 
 
 SINK_SLOT = 0   # reserved state slot for padding rows, as block 0 is for table entries
+RING_ARENAS = ("k_ring", "v_ring")   # the sliding_attention layers' K and V, a ring of blocks a state slot
 
 
 class StatePool:
@@ -107,7 +108,17 @@ class StatePool:
     and the conv's last inputs, ``conv (slots + 1, L_lin, K - 1,
     channels)`` at the compute dtype.  conv (a gated short convolution): the
     tails ``conv (slots + 1, L_conv, conv_kernel - 1, n_embd)`` at the compute
-    dtype, and no ``state``.  A request leases one slot at admission
+    dtype, and no ``state``.  ssm (a selective scan): ``state (slots + 1, L_ssm,
+    ssm_state, ssm_inner)`` in float32 and ``conv (slots + 1, L_ssm, K - 1,
+    ssm_inner)``.  A model with sliding_attention layers keeps their K and V
+    here too, a table a layer kind: a slot owns a **ring** of ``ring_blocks`` =
+    ``ceil(layer_window / block_size) + 1`` blocks of ``k_ring`` / ``v_ring``
+    (``((slots + 1) * ring_blocks, L_ring, ng / P, block_size, P hs)``, laid out
+    as the paged arenas are), block ``i`` of the sequence in entry ``i %
+    ring_blocks`` of the slot's ring (:func:`ring_tables`), overwritten when its
+    last token has left the window: the window kind's reservation is the ring,
+    whatever the request's length, where the other kind's is the whole length
+    in blocks of the paged arenas.  A request leases one slot at admission
     and gives it back when it finishes; slot 0 is the garbage sink padding
     rows of a static-shape program point at.  The arrays travel in the KV
     pool's ``arenas`` pytree (donated beside K and V, rebuilt with them); a
@@ -116,7 +127,7 @@ class StatePool:
 
     STATE_DTYPE = jnp.float32
 
-    def __init__(self, cfg, slots: int, dtype):
+    def __init__(self, cfg, slots: int, dtype, *, block_size: int = 16, lane_pack: int = 1):
         from thunder_tpu.models.generate import state_shapes
 
         if slots < 1:
@@ -124,6 +135,10 @@ class StatePool:
         shapes = state_shapes(cfg, slots + 1)
         # slot-major: a row's state is one contiguous slab a kernel can name by its slot
         self.shapes = {k: (v[1], v[0], *v[2:]) for k, v in shapes.items()}
+        self.ring_blocks = ring_blocks(cfg, block_size)
+        if self.ring_blocks:
+            ring = ((slots + 1) * self.ring_blocks, *ring_block_shape(cfg, block_size, lane_pack))
+            self.shapes.update({name: ring for name in RING_ARENAS})
         self.dtypes = {k: jnp.dtype(self.STATE_DTYPE if k == "state" else dtype) for k in self.shapes}
         self.num_slots = int(slots)
         self.layers = len(cfg.state_layers)
@@ -186,9 +201,16 @@ class StatePool:
     def arena_bytes(self) -> int:
         return sum(int(a.nbytes) for a in self._arenas.values())
 
+    def ring_bytes(self) -> int:
+        """Bytes of the ring arenas (0 without sliding_attention layers)."""
+        return sum(int(self._arenas[name].nbytes) for name in RING_ARENAS if name in self._arenas)
+
     def snapshot(self) -> dict:
-        """``dtype`` is the recurrent state's storage where there is one, else the tails'."""
-        return {"slots": self.num_slots, "leased": self.leased,
+        """``dtype`` is the recurrent state's storage where there is one, else the tails'.
+        ``ring_*``: the window kind's table, a ring a slot; its fill is the slots leased."""
+        ring = ({"ring_blocks": self.ring_blocks, "ring_arena_bytes": self.ring_bytes(),
+                 "ring_fill_frac": self.leased / self.num_slots} if self.ring_blocks else {})
+        return {**ring, "slots": self.num_slots, "leased": self.leased,
                 "free_low_water": self._free_low_water, "arena_bytes": self.arena_bytes(),
                 "slot_bytes": self.slot_bytes(), "layers": self.layers, "arenas": sorted(self.shapes),
                 "dtype": str(self.dtypes.get("state", self.dtypes["conv"])), "conv_dtype": str(self.dtypes["conv"]),
@@ -262,7 +284,9 @@ class PagedKVPool:
         if getattr(cfg, "state_layers", ()):
             if state_slots is None:
                 raise ValueError("a config with linear_attention or conv layers needs state_slots=")
-            self.state = StatePool(cfg, state_slots, dtype)
+            if self.quantized_kv and getattr(cfg, "ring_layers", ()):
+                raise ValueError("the ring arenas of sliding_attention layers have no quantised storage")
+            self.state = StatePool(cfg, state_slots, dtype, block_size=self.block_size, lane_pack=self.lane_pack)
         # independent buffers (no copy traffic between K and V updates)
         self.k_arena = self._zeros(shape, self.kv_dtype)
         self.v_arena = None if self.latent else self._zeros(shape, self.kv_dtype)
@@ -399,7 +423,7 @@ class PagedKVPool:
         if self.latent:
             counted = cfg.n_layer * cfg.latent_width * item
         else:
-            counted = 2 * len(cfg.kv_layers) * cfg.n_query_groups * cfg.head_size * item
+            counted = 2 * len(cfg.paged_kv_layers) * cfg.n_query_groups * cfg.head_size * item
         lanes = self._arena_shape[-1]
         laid_out = (1 if self.latent else 2) * int(np.prod(self._arena_shape[1:-1])) * (-(-lanes // 128) * 128) * item
         if self.quantized_kv:       # the two scale arenas, as counted
@@ -441,8 +465,8 @@ class PagedKVPool:
         return n_blocks * self.block_size
 
     def dense_shape(self, B: int, n_blocks: int) -> tuple[int, ...]:
-        L, ng, bs, hs = kv_block_shape(self.cfg, self.block_size)   # the dense cache packs nothing
-        return (L, B, ng, n_blocks * bs, hs)
+        _, ng, bs, hs = kv_block_shape(self.cfg, self.block_size)   # the dense cache packs nothing
+        return (len(self.cfg.kv_layers), B, ng, n_blocks * bs, hs)  # and holds every layer that keeps K and V
 
     def block_bytes(self) -> int:
         """Bytes one block costs across all arenas (K+V data, plus the
@@ -727,6 +751,28 @@ def scatter_state(arenas, cache, slots):
     slots (padding rows all write the sink, slot 0).  Returns the arenas written."""
     return {name: arenas[name].at[slots].set(jnp.swapaxes(cache[name], 0, 1).astype(arenas[name].dtype))
             for name in ("conv", "state") if name in arenas}
+
+
+def ring_tables(slots, n_ring: int, width: int):
+    """The sliding_attention layers' block tables, from the rows' state slots
+    ``(B,)``: ``(B, width)`` int32 over the ring arenas, entry ``i`` the block
+    that holds block ``i`` of the sequence *while it is in the window*: ``slots *
+    n_ring + i % n_ring``.  An entry behind the window names a block that now
+    holds a later one: the window's mask never reaches it.  Slot 0's ring is the
+    sink (its block 0 the ring arenas' block 0).  Pure jnp; call inside jit."""
+    return slots[:, None] * n_ring + (jnp.arange(width, dtype=jnp.int32) % n_ring)[None, :]
+
+
+def ring_dest(slot, n_real, n: int, n_ring: int, block_size: int):
+    """Where a whole prompt's last blocks go in its ring: of the ``n`` blocks
+    that end with the one holding token ``n_real - 1`` (``n <= n_ring``), the
+    first's index in the sequence (clipped at 0) and their ``(n,)`` destinations
+    in the ring arenas, the sink for a block past the last real token.  Pure
+    jnp; call inside jit."""
+    last = (n_real - 1) // block_size
+    start = jnp.maximum(last - (n - 1), 0)
+    i = start + jnp.arange(n, dtype=jnp.int32)
+    return start, jnp.where(i <= last, slot * n_ring + i % n_ring, SINK_BLOCK)
 
 
 def gather_rows(arena, tables, lane_pack: int = 1):

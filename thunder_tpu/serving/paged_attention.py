@@ -45,12 +45,19 @@ from thunder_tpu.executors.pallasex import (
     paged_chunk_write,
     paged_chunk_write_fused,
     paged_token_write,
+    paged_available,
     paged_walk_lanes_ok,
     paged_token_write_fused,
+    ssm_decode_step,
 )
 from thunder_tpu.models.generate import (
+    diff_attend_dense,
+    diff_attention,
     gdn_mixer,
+    gmu_mixer,
     kv_lane_pack,
+    ring_blocks,
+    ssm_mixer,
     shortconv_mixer,
     mla_absorb,
     mla_mixer,
@@ -64,6 +71,7 @@ from thunder_tpu.models.generate import (
     _project_qkv,
 )
 from thunder_tpu.observability.events import scope
+from thunder_tpu.serving.kv_pool import gather_rows, ring_tables
 from thunder_tpu.serving.quant import quantize_kv
 
 __all__ = ["forward_paged", "with_state", "write_fresh_kv", "write_fresh_kv_live",
@@ -252,6 +260,65 @@ def _mla_paged(ap, x, arena, tables, pos, cos_t, sin_t, cfg, *, layer, cdtype, l
     return mla_mixer(ap, x, cos_t, sin_t, cfg, attend, lin=lin), box[0]
 
 
+def _ssm_paged(sp, x, arenas, sslots, cfg, *, layer, lin):
+    """An ssm layer of :func:`forward_paged`, one token a row:
+    ``generate.ssm_mixer`` (the one mixer; the dense cache calls it too) with the
+    state where the server keeps it: ``ssm_decode_step`` on the row's slot of the
+    state arena, in place, and the conv's tail in its slot of the conv arena.
+    Returns ``(y, state arena, conv arena, m)``; ``m`` is what a gmu layer gates."""
+    if x.shape[1] != 1:
+        raise NotImplementedError(
+            "a selective scan is served one token a row here (ssm_decode_step); a whole prompt goes through "
+            "the prefill_fresh program's scan, and a piece of one has no program yet")
+    held = {"state": arenas["state"]}
+    with scope("ssm/cache"):
+        tail = arenas["conv"][sslots, layer]                         # (B, K - 1, d)
+
+    def recur(u, dt, Bm, Cm, A):
+        y, held["state"] = ssm_decode_step(held["state"], sslots, u[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0], A, layer=layer)
+        return y[:, None]
+
+    y, new_tail, m = ssm_mixer(sp, x, tail, cfg, recur, lin=lin)
+    with scope("ssm/cache"):
+        return y, held["state"], arenas["conv"].at[sslots, layer].set(new_tail), m
+
+
+def _diff_paged(ap, x, l, cfg, k_arena, v_arena, tables, pos, *, layer, window, cdtype, name, lin, fresh_kv=None):
+    """A differential-attention layer of :func:`forward_paged`, one token a
+    row: ``generate.diff_attention`` (the one mixer; the dense cache calls it
+    too) with the keys and values where the server keeps them.  The pair's two
+    softmaxes are one walk over ``layer`` of the lane-packed arenas
+    (``paged_attn_decode(packed_out=True)``: a 128-lane row is the pair's two K
+    heads, another its two V heads side by side, fetched once for both); without
+    Pallas the same sums over the row's gathered blocks.  ``fresh_kv``: a
+    cross_attention layer's: this step's K and V of the layer it reads, which
+    the arena does not hold yet.  Returns ``(y, (fresh K, fresh V))``, ``(B, ng,
+    hs)`` at the cache compute dtype."""
+    box = []
+
+    def attend(q, k, v):
+        B, G, _, J, _, hs = q.shape
+        fk, fv = fresh_kv if k is None else (k[:, :, 0].astype(cdtype), v[:, :, 0].astype(cdtype))
+        box.extend((fk, fv))
+        with scope(f"{name}/attn"):
+            if paged_available():
+                out = paged_attn_decode(q[..., 0, :].reshape(B, 2 * G * J, hs), k_arena, v_arena, fk, fv, tables, pos,
+                                        layer=layer, window=window, packed_out=True)
+                return out.reshape(B, G, 2, J, 1, 2 * hs)
+            # the walk's XLA form: the rows' blocks gathered, this step's row set at its position
+            put = jax.vmap(lambda rows, new, p: jax.lax.dynamic_update_slice_in_dim(rows, new[:, None], p, axis=1))
+            kr, vr = (put(gather_rows(a[:, layer:layer + 1], tables)[0], f.reshape(B, G, 2 * hs).astype(a.dtype), pos)
+                      for a, f in ((k_arena, fk), (v_arena, fv)))
+            j = jnp.arange(kr.shape[2])[None, :]
+            keep = j <= pos[:, None]
+            if window is not None:
+                keep = jnp.logical_and(keep, j > pos[:, None] - window)
+            return diff_attend_dense(q, kr, vr, keep[:, None, None, None, :])
+
+    y = diff_attention(ap, x, l, cfg, attend, cross=fresh_kv is not None, lin=lin, name=name)
+    return y, (box[0], box[1])
+
+
 def with_state(arenas, fresh):
     """The arenas a paged program returns: K and V as the writers left them,
     the state and conv arenas as :func:`forward_paged` left them."""
@@ -287,7 +354,13 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     the K/V arenas' layer axis counts the full-attention layers only
     (``cfg.kv_layers``), and ``fresh`` carries the two updated state arenas
     beside the fresh K/V (:func:`with_state`).  A model with conv layers: the
-    same with ``conv`` alone (the tails, :func:`_conv_paged`)."""
+    same with ``conv`` alone (the tails, :func:`_conv_paged`).  A
+    decoder-hybrid-decoder (ssm, sliding_attention, gmu, cross_attention; one
+    token a row): ``state`` and ``conv`` are the scans', ``k_ring`` / ``v_ring`` hold
+    the sliding_attention layers' K and V, a ring a state slot
+    (``kv_pool.ring_tables``), ``k`` / ``v`` the full_attention layers' alone, which
+    the cross_attention layers walk too; ``fresh`` carries ``k_ring`` / ``v_ring``
+    ``(B, L_ring, ng, hs)`` and ``ring_tables`` for :func:`write_fresh_kv`."""
     B, T = idx.shape
     hs, nh = cfg.head_size, cfg.n_head
     window = cfg.sliding_window
@@ -305,6 +378,7 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     lin = partial(_linear, quantized=quantized)
     delta_fn = lora_delta_fused if (lora_fused and mesh is None) else _lora_delta
     fresh_k, fresh_v, fresh_rows = [], [], []
+    ring_k, ring_v, ring_tabs, gmu_m = [], [], None, None
     for l, bp in enumerate(params["blocks"]):
         lora_l = None
         if lora:
@@ -316,12 +390,41 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                 else:
                     with scope("norm"):
                         n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
-                if cfg.layer_kind(l) == "linear_attention":
+                kind = cfg.layer_kind(l)
+                if kind == "ssm":
+                    h, state_arena, conv_arena, m = _ssm_paged(
+                        bp["ssm"], n1, {"state": state_arena, "conv": conv_arena}, sslots, cfg, layer=n_lin, lin=lin)
+                    n_lin += 1
+                    if l == cfg.gmu_source:
+                        gmu_m = m
+                elif kind == "gmu":
+                    h = gmu_mixer(bp["gmu"], n1, gmu_m, lin=lin)
+                elif cfg.diff_attention:
+                    if T != 1:
+                        raise NotImplementedError("differential attention is walked one token a row (a draft's "
+                                                  "verify and a piece of a prompt have no such kernel)")
+                    if kind == "sliding_attention":
+                        if ring_tabs is None:
+                            ring_tabs = ring_tables(sslots, ring_blocks(cfg, arenas["k_ring"].shape[3]), tables.shape[1])
+                        h, kv = _diff_paged(bp["attn"], n1, l, cfg, arenas["k_ring"], arenas["v_ring"], ring_tabs, pos,
+                                            layer=len(ring_k), window=cfg.layer_window, cdtype=cdtype, name="swa", lin=lin)
+                        ring_k.append(kv[0])
+                        ring_v.append(kv[1])
+                    else:   # a full_attention layer's own blocks, or the cross source's: the walk's layer is the owner's
+                        cross = kind == "cross_attention"
+                        own = cfg.paged_kv_layers.index(cfg.cross_from if cross else l)
+                        h, kv = _diff_paged(bp["attn"], n1, l, cfg, arenas["k"], arenas["v"], tables, pos, layer=own,
+                                            window=None, cdtype=cdtype, name="cross" if cross else "attn", lin=lin,
+                                            fresh_kv=(fresh_k[own], fresh_v[own]) if cross else None)
+                        if not cross:
+                            fresh_k.append(kv[0])
+                            fresh_v.append(kv[1])
+                elif kind == "linear_attention":
                     h, state_arena, conv_arena = _gdn_paged(
                         bp["gdn"], n1, {"state": state_arena, "conv": conv_arena}, sslots, pos, cfg,
                         layer=n_lin, n_real=n_real, lin=lin)
                     n_lin += 1
-                elif cfg.layer_kind(l) == "conv":
+                elif kind == "conv":
                     h, conv_arena = _conv_paged(bp["conv"], n1, conv_arena, sslots, pos, cfg,
                                                 layer=n_conv, n_real=n_real, lin=lin)
                     n_conv += 1
@@ -363,6 +466,8 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
         if cfg.latent:          # (B, L, 1, W): the token writer's layout, one group
             return logits, {"latent": jnp.stack(fresh_rows, axis=1)[:, :, None]}
         fresh = {"k": jnp.stack(fresh_k, axis=1), "v": jnp.stack(fresh_v, axis=1)}
+        if ring_k:      # the sliding_attention layers' K and V of this step, and the tables of their rings
+            fresh.update(k_ring=jnp.stack(ring_k, axis=1), v_ring=jnp.stack(ring_v, axis=1), ring_tables=ring_tabs)
     if conv_arena is not None:
         fresh.update(conv=conv_arena)
     if state_arena is not None:
@@ -441,7 +546,11 @@ def write_fresh_kv(arenas, fresh, tables, pos, *, block_size, kv_dtype=None,
     if kv_dtype is None:
         w = partial(_write, tables=tables, pos=pos, block_size=block_size,
                     mesh=mesh)
-        return {"k": w(arenas["k"], fresh["k"]), "v": w(arenas["v"], fresh["v"])}
+        out = {"k": w(arenas["k"], fresh["k"]), "v": w(arenas["v"], fresh["v"])}
+        if "k_ring" in fresh:   # the sliding_attention layers': the same writer over their rings' tables
+            w = partial(_write, tables=fresh["ring_tables"], pos=pos, block_size=block_size, mesh=mesh)
+            out.update(k_ring=w(arenas["k_ring"], fresh["k_ring"]), v_ring=w(arenas["v_ring"], fresh["v_ring"]))
+        return out
     ka, ks = _write_fused(arenas["k"], arenas["k_scale"], fresh["k"], tables,
                           pos, block_size=block_size, mesh=mesh)
     va, vs = _write_fused(arenas["v"], arenas["v_scale"], fresh["v"], tables,

@@ -333,6 +333,281 @@ class TestEventLoop:
 
 
 #
+# one decode step ahead: a steady batch's next step goes out before the harvest
+#
+
+
+def _dense(monkeypatch):
+    cfg = llama.Config.from_name("tiny-llama-debug", **MICRO)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    return cfg, params, dict(block_size=4, num_blocks=32, max_batch=3, cache_dtype=jnp.float32,
+                             temperature=0.7, **BUCKETS)
+
+
+def _hybrid(monkeypatch):
+    """Gated-DeltaNet layers beside one attention layer: the chain carries ``sslots``."""
+    from _hybrid_tiny import tiny_model
+
+    monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+    cfg, params = tiny_model()
+    return cfg, params, dict(attn="paged", max_batch=3, num_blocks=40, block_size=16, prefill_buckets=[32, 64],
+                             batch_buckets=[4], block_buckets=[8])
+
+
+def _latent(monkeypatch):
+    """One latent a token a layer in place of K and V, an expert share."""
+    from test_mla_serving import tiny_model
+
+    cfg, params = tiny_model()
+    return cfg, params, dict(num_blocks=40, block_size=16, max_batch=3, prefill_buckets=(16, 32, 48))
+
+
+def _ring(monkeypatch):
+    """A selective scan's state, a ring of blocks a slot for the window layers,
+    one global layer's blocks."""
+    from test_hybrid_decoder_serving import ENGINE, model
+
+    cfg, params = model()
+    return cfg, params, {**ENGINE, "max_batch": 3}
+
+
+KINDS = {"dense": _dense, "hybrid": _hybrid, "latent": _latent, "ring": _ring}
+AHEAD_REQUESTS = [(9, 7), (14, 5), (5, 9), (12, 6), (7, 4)]     # prompt, new tokens: five through three slots
+
+
+def _drive(eng, cfg):
+    """The requests of ``AHEAD_REQUESTS``, stepped to the end: tokens, finish
+    reasons and the key each request ended on."""
+    rng = np.random.default_rng(21)
+    handles = [eng.submit(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), max_new_tokens=m,
+                          key=jax.random.PRNGKey(100 + i)) for i, (n, m) in enumerate(AHEAD_REQUESTS)]
+    eng.drain()
+    res = [h.result(drive=False) for h in handles]
+    return ([tuple(r.tokens) for r in res], [r.finish_reason for r in res],
+            [np.asarray(h._req.key).tolist() for h in handles])
+
+
+class TestDecodeAhead:
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_a_step_ahead_serves_what_the_synchronous_loop_serves(self, kind, monkeypatch):
+        """Tokens, finish reasons and the keys the requests end on are those of
+        ``async_step=False``, for a dense, a hybrid (``sslots``), a latent and a
+        ring engine; the async engine ran steps ahead, the synchronous none."""
+        cfg, params, opts = KINDS[kind](monkeypatch)
+        eng = tt.serve(None, params, cfg, **opts)
+        ahead = _drive(eng, cfg)
+        sync_eng = tt.serve(None, params, cfg, async_step=False, **opts)
+        sync = _drive(sync_eng, cfg)
+        assert ahead == sync
+        st = eng.stats()["decode_ahead"]
+        assert 0 < st["ahead"] < st["dispatches"] == eng.decode_steps
+        assert st["share"] == st["ahead"] / st["dispatches"]
+        assert sync_eng.stats()["decode_ahead"] == {"dispatches": sync_eng.decode_steps, "ahead": 0, "share": 0.0}
+        assert eng.pool.num_free == eng.pool.num_usable and eng.pool.n_retired <= 1
+        eng.shutdown(), sync_eng.shutdown()
+
+    def test_ahead_only_in_steps_that_neither_end_nor_admit_a_row(self, micro):
+        """A backlog through three slots, step by step: a step that dispatched
+        ahead finished no request and left the batch as it was; the last step
+        before a row's end by length is never ahead; and the count is what the
+        lengths say (every chained step but the last of each chain)."""
+        cfg, params = micro
+        eng = _engine(cfg, params, max_batch=3)
+        rng = np.random.default_rng(22)
+        handles = [eng.submit(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), max_new_tokens=m)
+                   for n, m in AHEAD_REQUESTS]
+        ahead_steps = 0
+        while eng.scheduler.queue or eng.scheduler.running:
+            before = eng.decode_ahead_steps
+            done = sum(h.done() for h in handles)
+            rec = eng._inflight_decode
+            rids = None if rec is None else [r.rid for r in rec["running"]]
+            eng.step()
+            if eng.decode_ahead_steps > before:
+                ahead_steps += 1
+                assert sum(h.done() for h in handles) == done          # no row ended at this harvest
+                assert [r.rid for r in eng._inflight_decode["running"]] == rids    # and none joined
+                assert eng._decode_state["ahead"] >= 0
+        st = eng.stats()["decode_ahead"]
+        assert st["ahead"] == ahead_steps > 0
+        assert tt.metrics_snapshot()["serving.steps.decode_ahead"] >= ahead_steps
+        assert all(h.result(drive=False).finish_reason == "length" for h in handles)
+
+    def test_a_lone_request_runs_every_step_but_its_first_ahead(self, micro):
+        """One request of 10 new tokens: token 0 is the prefill's, nine decode
+        steps follow; the first builds the chain, the other eight go out ahead
+        of the harvest before them (none of those harvests ends the row: the
+        ninth's does, and no dispatch follows it).  The chain counts down: 8
+        steps at its rebuild, none left after the last."""
+        cfg, params = micro
+        eng = _engine(cfg, params)
+        (p,) = _prompts(cfg, (5,), seed=23)
+        h = eng.submit(p, max_new_tokens=10)
+        left = []
+        while not h.done():
+            eng.step()
+            if eng._decode_state is not None:
+                left.append(eng._decode_state["ahead"])
+        np.testing.assert_array_equal(h.result(drive=False).tokens, _solo(params, p, cfg, 10))
+        assert left[:9] == list(range(8, -1, -1))
+        assert eng.stats()["decode_ahead"] == {"dispatches": 9, "ahead": 8, "share": 8 / 9}
+
+    @pytest.mark.parametrize("how", ["constraint", "speculation", "decode_steps"])
+    def test_what_the_next_dispatch_needs_from_the_host_keeps_the_old_order(self, micro, how):
+        cfg, params = micro
+        (p,) = _prompts(cfg, (5,), seed=24)
+        if how == "constraint":
+            from thunder_tpu.serving import TokenSetConstraint
+
+            eng = _engine(cfg, params, constraints=True)
+            h = eng.submit(p, max_new_tokens=8, constraint=TokenSetConstraint(cfg.padded_vocab_size, {3, 4, 9}))
+        elif how == "speculation":
+            from thunder_tpu.serving import SpecConfig
+
+            dcfg = llama.Config.from_name("tiny-llama-debug", **{**MICRO, "intermediate_size": 16})
+            draft = llama.init_params(dcfg, jax.random.PRNGKey(9), dtype=jnp.float32)
+            eng = _engine(cfg, params, speculative=SpecConfig(draft, dcfg, K=2))
+            h = eng.submit(p, max_new_tokens=8)
+        else:
+            eng = _engine(cfg, params, decode_steps=2)
+            h = eng.submit(p, max_new_tokens=8)
+        assert h.result().finish_reason == "length"
+        st = eng.stats()["decode_ahead"]
+        assert st["dispatches"] > 0 and st["ahead"] == 0 and st["share"] == 0.0
+
+    def test_an_unconstrained_batch_of_a_constrained_engine_runs_ahead(self, micro):
+        """The condition is the batch's, not the engine's: with no automaton in
+        the batch the mask is the cached all-true one, nothing of the host's."""
+        cfg, params = micro
+        (p,) = _prompts(cfg, (5,), seed=25)
+        eng = _engine(cfg, params, constraints=True)
+        eng.submit(p, max_new_tokens=8).result()
+        assert eng.stats()["decode_ahead"]["ahead"] > 0
+
+    def test_a_row_that_ends_unseen_costs_one_dead_row_step(self, micro):
+        """With ``eos_id`` armed a row may end at step k while step k+1 is on the
+        device: that step holds one ``dead_scan_row``, no token follows the EOS,
+        the chain is dropped at the harvest, and the tokens are the synchronous
+        loop's."""
+        cfg, params = micro
+        prompts = _prompts(cfg, (5, 8, 6), seed=26)
+        free = _engine(cfg, params, temperature=0.9)
+        hs = [free.submit(p, max_new_tokens=12, key=jax.random.PRNGKey(i)) for i, p in enumerate(prompts)]
+        free.drain()
+        eos = hs[0].result(drive=False).new_tokens[5]      # request 0's sixth token ends it
+        out = {}
+        for mode in (True, False):
+            eng = _engine(cfg, params, temperature=0.9, eos_id=eos, goodput=True, async_step=mode)
+            hs = [eng.submit(p, max_new_tokens=12, key=jax.random.PRNGKey(i)) for i, p in enumerate(prompts)]
+            eng.drain()
+            out[mode] = ([h.result(drive=False).new_tokens for h in hs],
+                         [h.result(drive=False).finish_reason for h in hs])
+            if mode:
+                waste = eng.stats()["goodput"]["waste"]
+                n_eos = sum(r == "eos" for r in out[mode][1])
+                assert n_eos >= 1 and 1 <= waste.get("dead_scan_row", 0) <= n_eos
+                assert eng.stats()["decode_ahead"]["ahead"] > 0
+        assert out[True] == out[False]
+        for toks, reason in zip(*out[True]):
+            assert (reason == "eos") == (eos in toks)
+            if reason == "eos":
+                assert toks.index(eos) == len(toks) - 1
+
+    def test_a_window_that_lets_blocks_go_keeps_the_old_order_at_that_step(self, micro):
+        """A sliding window of 6 over blocks of 2: a block leaves the window
+        every other step.  The step whose harvest frees one is never ahead (its
+        successor's tables change), the steps between are, and the tokens are
+        the synchronous loop's."""
+        cfg, params = micro
+        wcfg = llama.Config.from_name("tiny-llama-debug", **{**MICRO, "sliding_window": 6})
+        p = np.arange(4, dtype=np.int32) + 2
+        sync = _engine(wcfg, params, block_size=2, num_blocks=16, max_batch=1, async_step=False)
+        want = sync.submit(p, max_new_tokens=12).result().tokens
+        eng = _engine(wcfg, params, block_size=2, num_blocks=16, max_batch=1)
+        h = eng.submit(p, max_new_tokens=12)
+        freed_ahead = freed = 0
+        while not h.done():
+            free, ahead = eng.pool.num_free, eng.decode_ahead_steps
+            eng.step()
+            if eng.pool.num_free > free and not h.done():
+                freed += 1
+                freed_ahead += eng.decode_ahead_steps > ahead
+        np.testing.assert_array_equal(h.result(drive=False).tokens, want)
+        assert freed >= 3 and freed_ahead == 0 and eng.decode_ahead_steps >= 3
+
+    def test_a_deadline_that_has_passed_keeps_the_old_order(self, micro):
+        """The chain knows its rows' first deadline: once the engine's clock is
+        past it no step goes out ahead (the expiry follows the harvest and would
+        take the row out of that batch)."""
+        cfg, params = micro
+        clk = {"t": 0.0}
+        eng = _engine(cfg, params, clock=lambda: clk["t"])
+        (p,) = _prompts(cfg, (5,), seed=29)
+        h = eng.submit(p, max_new_tokens=24, deadline=9.0)
+        while not h.done():
+            ahead = eng.decode_ahead_steps
+            late = clk["t"] >= 9.0
+            eng.step()
+            assert not (late and eng.decode_ahead_steps > ahead)
+            clk["t"] += 1.5
+        assert h.result(drive=False).finish_reason == "deadline" and eng.decode_ahead_steps >= 3
+        assert eng.pool.num_free == eng.pool.num_usable
+
+    def test_the_harvest_keeps_the_handles_the_dispatch_ahead_parked(self, micro):
+        """A pool that counts what ``release_retired`` drops: the harvest after a
+        dispatch ahead drops what was parked before that dispatch and keeps its
+        own entry (its program is on the device), so a handle is dropped one
+        harvest after its consumer's, never with it."""
+        cfg, params = micro
+        eng = _engine(cfg, params)
+        pool = eng.pool
+        log = []
+        real = pool.release_retired
+
+        def counting(upto=None):
+            before = pool.n_retired
+            real(upto)
+            log.append((eng.decode_ahead_steps, before, pool.n_retired))
+
+        pool.release_retired = counting
+        (p,) = _prompts(cfg, (5,), seed=27)
+        h = eng.submit(p, max_new_tokens=8)
+        seen_ahead = 0
+        while not h.done():
+            log.clear()
+            ahead_before = eng.decode_ahead_steps
+            eng.step()
+            if eng.decode_ahead_steps > ahead_before:
+                seen_ahead += 1
+                # one release in the step, at the harvest: everything but the
+                # entry the dispatch ahead had just parked
+                assert len(log) == 1 and log[0][2] == 1 and log[0][1] >= 1
+                assert pool.n_retired == 1
+            else:
+                assert all(after == 0 for _, _, after in log)
+        assert seen_ahead > 0 and pool.n_retired == 0
+
+    def test_the_overlap_accounting_counts_a_step_ahead_from_the_harvest_before_it(self, micro):
+        """Two dispatches precede a wait: the record dispatched ahead takes its
+        place in ``overlap_frac`` and in the device seconds from the moment the
+        step before it was fetched, so neither counts that step twice."""
+        cfg, params = micro
+        eng = _engine(cfg, params, goodput=True)
+        (p,) = _prompts(cfg, (5,), seed=28)
+        h = eng.submit(p, max_new_tokens=8)
+        stamps = []
+        while not h.done():
+            eng.step()
+            rec = eng._inflight_decode
+            if rec is not None and "parked" in rec:
+                assert rec["t_dev"] >= rec["t_disp"]       # the wait for the step before ended after this dispatch
+                stamps.append(rec["t_dev"])
+        assert stamps == sorted(stamps) and len(stamps) == eng.decode_ahead_steps > 0
+        s = eng.stats()
+        assert 0 <= s["overlap_frac_mean"] <= 1
+
+
+#
 # soak (slow): every guarantee at once
 #
 

@@ -520,6 +520,110 @@ class TestShutdownInflight:
 
 
 #
+# a decode record dispatched ahead of the harvest before it
+#
+
+
+def _ahead_in_flight(eng) -> bool:
+    rec = eng._inflight_decode
+    return rec is not None and "parked" in rec
+
+
+class TestFaultsWithAStepAhead:
+    """The engine dispatches a steady batch's next decode step before it
+    harvests the last one.  Faults, the watchdog, eviction and shutdown meet
+    that record as they meet any in-flight record: its tokens were never
+    promised, its parked handles go with it."""
+
+    def _ref(self, cfg, params, n=10):
+        return _engine(cfg, params).submit(P0, max_new_tokens=n).result().new_tokens
+
+    @pytest.mark.parametrize("point,kind,at", [
+        (FP_DECODE, "oom", 4),       # the dispatch ahead itself refuses, the step before still in flight
+        (FP_SCATTER, "fail", 4),     # it fails past the donation
+        (FP_HARVEST, "hang", 4),     # the harvest it overtook fails, the step ahead on the device
+    ])
+    def test_a_fault_around_a_dispatch_ahead_recovers_bit_identical(self, micro, point, kind, at):
+        cfg, params = micro
+        ref = self._ref(cfg, params)
+        eng = _engine(cfg, params, fault_plan=FaultPlan(specs=[FaultSpec(point=point, kind=kind, at=at)]))
+        seen = []
+        recover = eng._recover
+        eng._recover = lambda cause: (seen.append((eng.decode_ahead_steps, _ahead_in_flight(eng))), recover(cause))
+        r = eng.submit(P0, max_new_tokens=10).result()
+        assert r.new_tokens == ref and r.finish_reason == "length"
+        assert eng.recoveries == 1 and len(seen) == 1 and seen[0][0] >= 1
+        if point == FP_HARVEST:
+            assert seen[0][1]                              # the record ahead was in flight, and was discarded
+        assert eng.decode_ahead_steps > seen[0][0]         # and the engine ran ahead again afterwards
+        assert _pool_clean(eng)
+
+    def test_a_poison_row_named_by_the_dispatch_ahead_is_quarantined_alone(self, micro):
+        cfg, params = micro
+        eng = _engine(cfg, params)
+        ha = eng.submit(P0, max_new_tokens=10, key=jax.random.PRNGKey(7))
+        hb = eng.submit(P1, max_new_tokens=10, key=jax.random.PRNGKey(8))
+        refa, refb = ha.result().new_tokens, hb.result().new_tokens
+        eng = _engine(cfg, params, fault_plan=FaultPlan(specs=[FaultSpec(point=FP_DECODE, kind="nan", at=4, rid=0)]))
+        ha = eng.submit(P0, max_new_tokens=10, key=jax.random.PRNGKey(7))
+        hb = eng.submit(P1, max_new_tokens=10, key=jax.random.PRNGKey(8))
+        eng.drain()
+        ra, rb = ha.result(drive=False), hb.result(drive=False)
+        assert ra.finish_reason == "error" and ra.new_tokens == refa[:len(ra.new_tokens)]
+        assert rb.finish_reason == "length" and rb.new_tokens == refb
+        assert eng.recoveries == 0 and eng.decode_ahead_steps > 0 and _pool_clean(eng)
+
+    def test_the_watchdog_finds_the_overtaken_record(self, micro):
+        cfg, params = micro
+        ref = self._ref(cfg, params)
+        clk = {"t": 0.0}
+        eng = _engine(cfg, params, clock=lambda: clk["t"], watchdog_timeout_s=5.0)
+        h = eng.submit(P0, max_new_tokens=10)
+        jumped = False
+        while not h.done():
+            eng.step()
+            if not jumped and _ahead_in_flight(eng):
+                clk["t"] += 100.0                          # the record in flight is "hung"; the next step runs ahead of it
+                jumped = True
+        assert jumped and h.result(drive=False).new_tokens == ref
+        assert eng.recoveries == 1 and _pool_clean(eng)
+
+    def test_evict_and_shutdown_with_a_record_ahead(self, micro):
+        cfg, params = micro
+        ref = self._ref(cfg, params)
+        eng = _engine(cfg, params)
+        h = eng.submit(P0, max_new_tokens=10)
+        while not _ahead_in_flight(eng):
+            eng.step()
+        eng.evict(h)                                       # the record on the device names a finished row
+        assert h.result(drive=False).finish_reason == "evicted"
+        assert eng.pool.num_free == eng.pool.num_usable
+        assert eng.submit(P0, max_new_tokens=10).result().new_tokens == ref
+        assert _pool_clean(eng)
+        h = eng.submit(P0, max_new_tokens=10)
+        while not _ahead_in_flight(eng):
+            eng.step()
+        assert eng.pool.n_retired >= 1
+        eng.shutdown(drain=False)
+        assert eng._inflight_decode is None and eng._decode_state is None
+        assert h.result(drive=False).finish_reason == "evicted" and _pool_clean(eng)
+
+    def test_held_reads_the_caches_behind_a_record_ahead(self, micro):
+        """``held()`` harvests what is in flight first: with a record ahead that
+        is one record, as ever, and the chain goes on from it."""
+        cfg, params = micro
+        ref = self._ref(cfg, params)
+        eng = _engine(cfg, params)
+        h = eng.submit(P0, max_new_tokens=10)
+        while not _ahead_in_flight(eng):
+            eng.step()
+        held = eng.held(h)
+        assert eng._inflight_decode is None and held["tokens"] == h._req.pos == len(P0) + len(h.tokens_so_far()) - 1
+        assert eng.pool.n_retired == 0
+        assert h.result().new_tokens == ref and _pool_clean(eng)
+
+
+#
 # chaos soak (slow): random seeded plan over a mixed int8+LoRA workload
 #
 

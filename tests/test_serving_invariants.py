@@ -662,6 +662,93 @@ def _(micro):
 
 
 #
+# one decode step ahead
+#
+
+
+@case("decode_ahead-every_chained_step_between_turnovers")
+def _(micro):
+    """Four requests of nine tokens through four slots: token 0 is the
+    prefill's, eight decode steps follow on one batch; the first builds the
+    chain, the other seven go out before the harvest of the step before."""
+    cfg, params = micro
+    eng = _engine(cfg, params)
+    res = eng.run(_reqs(cfg, (5, 6, 7, 8), 9, seed=180))
+    assert all(r.finish_reason == "length" for r in res)
+    assert eng.stats()["decode_ahead"] == {"dispatches": 8, "ahead": 7, "share": 7 / 8}
+    assert eng.stats()["mean_batch_occupancy"] == 4.0
+
+
+@case("decode_ahead-new_programs")
+def _(micro):
+    """The step ahead calls the programs the old order called: a model no
+    other engine has served compiles the same kinds as often under
+    ``async_step=False``, and a second async engine compiles none."""
+    reqs = None
+    counts = {}
+    for mode, vocab in ((True, 72), (False, 80)):
+        cfg, params = _model(vocab_size=vocab)
+        reqs = _reqs(cfg, (5, 9, 6, 4, 7), 7, seed=190)
+        eng = _engine(cfg, params, async_step=mode, max_batch=3)
+        eng.run([dict(r) for r in reqs])
+        counts[mode] = {k: v for k, v in eng.compile_counts.items() if v}
+        assert (eng.stats()["decode_ahead"]["ahead"] > 0) == mode
+    assert counts[True] == counts[False]
+    cfg, params = _model(vocab_size=72)
+    second = _engine(cfg, params, max_batch=3)
+    second.run([dict(r) for r in _reqs(cfg, (5, 9, 6, 4, 7), 7, seed=190)])
+    assert sum(second.compile_counts.values()) == 0 and second.stats()["decode_ahead"]["ahead"] > 0
+
+
+@case("decode_ahead-none_under_speculation")
+def _(micro):
+    """A speculative round's next inputs hang on what the verify accepted:
+    every round keeps the old order."""
+    _, eng, _ = _speculated()
+    st = eng.stats()["decode_ahead"]
+    assert st["dispatches"] == eng.spec_rounds > 0 and st["ahead"] == 0 and st["share"] == 0.0
+
+
+@case("decode_ahead-a_mesh_engine_runs_as_far_ahead")
+def _(micro):
+    """Under a ``tp`` mesh the chained state is placed as the programs place
+    it: the same steps go out ahead as on one device, for the same tokens."""
+    from thunder_tpu import distributed as dist
+
+    cfg, params = micro
+    reqs = _reqs(cfg, (3, 5, 9, 4, 6), 6, seed=200)
+    single = _engine(cfg, params, max_batch=3)
+    res = single.run([dict(r) for r in reqs])
+    mesh = dist.make_mesh({"tp": 2}, devices=jax.devices()[:2])
+    eng = _engine(cfg, params, mesh=mesh, max_batch=3)
+    res_m = eng.run([dict(r) for r in reqs])
+    assert eng.stats()["decode_ahead"] == single.stats()["decode_ahead"]
+    assert 0 < eng.stats()["decode_ahead"]["ahead"] < eng.decode_steps
+    assert _tokens(res_m) == _tokens(res)
+
+
+@case("decode_ahead-quantised_arena_lora_and_sessions")
+def _(micro):
+    """An int8 arena (its scales are donated and parked with K and V), a LoRA
+    mix and a session's parked blocks: the tokens of ``async_step=False``."""
+    cfg, params = micro
+    out = {}
+    for mode in (True, False):
+        reg = AdapterRegistry(cfg, max_adapters=2, rank=2)
+        reg.register("a", make_lora_factors(cfg, 2, jax.random.PRNGKey(5), std=0.5))
+        eng = _engine(cfg, params, async_step=mode, kv_dtype="int8", lora=reg, sessions=True, max_batch=3)
+        reqs = _reqs(cfg, (5, 9, 6, 7), 8, seed=210)
+        reqs[1]["adapter_id"] = reqs[3]["adapter_id"] = "a"
+        reqs[0]["session_id"] = "s"
+        res = eng.run([dict(r) for r in reqs])
+        turn2 = np.concatenate([res[0].tokens, _prompt(215, 3, cfg)])
+        res.append(eng.run([{"prompt": turn2, "max_new_tokens": 5, "session_id": "s"}])[0])
+        out[mode] = _tokens(res)
+        assert (eng.stats()["decode_ahead"]["ahead"] > 0) == mode
+    assert out[True] == out[False]
+
+
+#
 # donation: a parameter tree's update
 #
 

@@ -40,7 +40,7 @@ SERVE_SPANS = {
     "serve.harvest.wait": (("kind",), "serve.harvest"),
     "serve.harvest.emit": ((), "serve.harvest"),
     "serve.expire": ((), "serve.step"),
-    "serve.decode_dispatch": (("rows", "bucket", "steady"), "serve.step"),
+    "serve.decode_dispatch": (("rows", "bucket", "steady", "ahead"), "serve.step"),
     "serve.decode_dispatch.call": ((), "serve.decode_dispatch"),
     "serve.admit": (("admitted",), "serve.step"),
     "serve.prefill_dispatch": (("rid", "tokens", "bucket", "piece"), "serve.step"),
@@ -214,6 +214,25 @@ class TestEngineSpans:
         decodes = prof.named("serve.decode_dispatch")
         assert {s["args"]["steady"] for s in decodes} <= {0, 1}
         assert all(s["args"]["rows"] in (1, 2) for s in decodes)
+        # ahead: the dispatch came before its step's harvest (a steady batch of
+        # the async loop, and then it was chained); the benchmark's reader
+        # counts them off the same file
+        ahead = [s for s in decodes if s["args"]["ahead"]]
+        assert {s["args"]["ahead"] for s in decodes} <= {0, 1} and bool(ahead) == async_step
+        assert len(ahead) == eng.decode_ahead_steps and all(s["args"]["steady"] for s in ahead)
+        for s in decodes if async_step else ():
+            step = next(p for p in step_spans if p["start"] <= s["start"] and s["end"] <= p["end"])
+            (h,) = [h for h in prof.named("serve.harvest") if step["start"] <= h["start"] <= step["end"]]
+            assert (s["end"] <= h["start"]) == bool(s["args"]["ahead"])
+        from chipbench import common, program_spans
+
+        reader = common.load_reader("decode_ahead_share.offline")
+        (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        spans = program_spans.load(path)
+        assert reader.read({"program_spans": spans}) == len(ahead) / len(decodes)
+        for sp in spans:
+            sp.args.pop("ahead", None)      # a program that gives the span no such argument, as this PR's parent
+        assert reader.read({"program_spans": spans}) is None and reader.read({"program_spans": []}) is None
         # no session's worth of ring entries: trace=False keeps the ring empty
         assert events() == []
 

@@ -39,7 +39,17 @@ The drive loop is an explicit, threadless **event loop** with two lanes
   dispatches, and token streaming for batch *k−1* all run while the device
   computes, and the next ``step()`` harvests the in-flight tokens (the
   only host block, measured into ``serving.decode.stall_s`` and the
-  ``serving.step.overlap_frac`` gauge);
+  ``serving.step.overlap_frac`` gauge).  It keeps **one decode step
+  ahead** while the batch is steady: where host state alone says that step
+  *k+1* runs on the batch of the in-flight step *k* (no row writes its last
+  position at *k*, no window lets blocks go, no constraint mask, deadline or
+  prefill piece is due, the decode-ready rows are the chain's:
+  :meth:`ServingEngine._ahead_batch`), *k+1* is dispatched from the chained
+  device state **before** *k* is harvested, so the fetch of *k*'s tokens,
+  the emit walk and the stream callbacks run under the device and not
+  beside it.  A turnover step (a row ends or joins) keeps the order above;
+  ``stats()["decode_ahead"]`` and the ``ahead`` argument of the
+  ``serve.decode_dispatch`` span say how often each happened;
 - the **prefill lane** splits prompts longer than ``prefill_chunk`` into
   block-aligned pow-2 chunks (program kind ``prefill_chunk``, bounded by
   the same ``_table_widths``/bucket accounting) and dispatches at most one
@@ -711,6 +721,7 @@ class ServingEngine:
         self._closed = False
         # drive-loop accounting (mirrored into the registry as it changes)
         self.decode_steps = 0
+        self.decode_ahead_steps = 0     # of them, dispatched before the harvest of the step before
         self.prefill_runs = 0
         self.prefill_fresh_runs = 0     # of them, whole prompts at position 0
         self.chunk_runs = 0
@@ -753,6 +764,7 @@ class ServingEngine:
         # values but keeps objects, so these survive observability resets)
         reg0 = registry()
         self._m_steps_decode = reg0.counter("serving.steps.decode")
+        self._m_steps_ahead = reg0.counter("serving.steps.decode_ahead")
         self._m_occupancy = reg0.histogram("serving.batch_occupancy")
         self._m_tokens = reg0.counter("serving.tokens")
         self._m_queue_depth = reg0.gauge("serving.queue_depth")
@@ -911,7 +923,9 @@ class ServingEngine:
         decode/prefill futures from step *k−1* (the one host block — the
         idle backoff of every drive loop is this wait on the futures table,
         never a busy poll), expire deadlines, dispatch decode for batch *k*,
-        then admit + dispatch prefill pieces while the device computes.
+        then admit + dispatch prefill pieces while the device computes.  In
+        a steady batch the decode dispatch comes first, ahead of the harvest
+        (:meth:`_step_async` has the overlap contract).
         Sync (``async_step=False``): the original expire → admit+prefill →
         one blocking decode.  Returns whether any work happened.  When a
         flight recorder is armed, any exception out of the step auto-dumps
@@ -999,6 +1013,12 @@ class ServingEngine:
     def _step_async(self) -> bool:
         """One event-loop turn.  Phase order is the overlap contract:
 
+        0. **decode dispatch ahead** — where the host can tell without the
+           in-flight step's tokens that the next step runs on the same batch
+           (:meth:`_ahead_batch`), it is dispatched first, from the chained
+           device state: the device goes from step *k* straight into *k+1*
+           while the host does phases 1-2 for step *k*, and phase 3 is
+           skipped.  Any other step (a turnover) keeps the order below;
         1. **harvest** — materialize the previous step's in-flight decode
            tokens and prefill pieces (stream callbacks, finishes, window
            expiry land here, one device-latency late but in order);
@@ -1008,10 +1028,19 @@ class ServingEngine:
            starts on step *k* while the host continues;
         4. admissions + chunked-prefill advancement — all host/dispatch
            work that overlaps the device's decode.
+
+        Either way one decode record is in flight between two steps.
         """
-        worked = self._harvest()
+        batch = self._ahead_batch()
+        prev = None
+        if batch is not None:
+            # _inflight_decode stays step k's record until the dispatch has
+            # gone through: a fault inside it loses neither
+            prev = self._inflight_decode
+            self._decode_once(batch)
+        worked = self._harvest(prev)
         worked = self._expire_deadlines() or worked
-        if self.scheduler.decode_ready():
+        if prev is None and self.scheduler.decode_ready():
             self._decode_once()
             worked = True
         worked = self._admit() or worked
@@ -1021,15 +1050,17 @@ class ServingEngine:
             self._update_gauges()
         return worked
 
-    def _harvest(self) -> bool:
+    def _harvest(self, prev: dict | None = None) -> bool:
         """Materializes every in-flight future (decode first: it was
         dispatched before the prefill pieces, so the device finishes it
         first).  This is where the host blocks — drive loops calling
-        ``step()`` back off *inside* this wait instead of busy-polling."""
+        ``step()`` back off *inside* this wait instead of busy-polling.
+        ``prev``: the decode record to harvest where the next one was
+        dispatched ahead of it; that one stays in flight."""
         with self._span("serve.harvest"):
-            return self._harvest_inflight()
+            return self._harvest_inflight(prev)
 
-    def _harvest_inflight(self) -> bool:
+    def _harvest_inflight(self, prev: dict | None = None) -> bool:
         wd = self.watchdog_timeout_s
         if wd is not None:
             # the watchdog: an in-flight record that aged past the timeout
@@ -1038,7 +1069,7 @@ class ServingEngine:
             now = self.scheduler.clock()
             inflight = list(self._inflight_prefill)
             if self._inflight_decode is not None:
-                inflight.append(self._inflight_decode)
+                inflight.append(prev or self._inflight_decode)
             for wrec in inflight:
                 age = now - wrec["t_clock"]
                 if age > wd:
@@ -1046,7 +1077,11 @@ class ServingEngine:
                             if wrec["kind"] == "decode" else [wrec["req"].rid])
                     raise WatchdogTimeout(FP_HARVEST, rids, age_s=age)
         worked = False
-        rec, self._inflight_decode = self._inflight_decode, None
+        parked = None
+        if prev is not None:
+            rec, parked = prev, self._inflight_decode["parked"]
+        else:
+            rec, self._inflight_decode = self._inflight_decode, None
         if rec is not None:
             self._decode_harvest(rec)
             worked = True
@@ -1058,10 +1093,15 @@ class ServingEngine:
             # every record above materialized at least one output of its
             # program, so all of last step's donated-arena consumers have
             # completed — dropping the parked handles is free now (doing it
-            # at dispatch would block the host for the whole device step)
+            # at dispatch would block the host for the whole device step).
+            # The handles the dispatch ahead parked are another matter: its
+            # program is on the device now, so they wait for its own harvest
             with self._span("serve.harvest.emit"):
-                self._release_retired()
+                self._release_retired(parked)
                 self._sample_occupancy()
+        if prev is not None:
+            nxt = self._inflight_decode
+            self._trace_decode_begin(nxt["running"], nxt["step"], nxt["compiled"], nxt["bucket"])
         return worked
 
     def _harvest_inline(self, rec: dict) -> None:
@@ -1075,11 +1115,15 @@ class ServingEngine:
                 self._release_retired()     # outputs materialized: consumer done
                 self._sample_occupancy()
 
-    def _release_retired(self) -> None:
+    def _release_retired(self, upto: int | None = None) -> None:
         """Drops the parked donated-arena handles of every pool the engine
         owns (target always; the draft arena too under speculative
-        serving — both are donated by the same harvested round)."""
-        self.pool.release_retired()
+        serving — both are donated by the same harvested round).  ``upto``:
+        the target pool's mark before a dispatch ahead, whose handles stay
+        (the K/V, state, conv and ring arenas park as one entry; a
+        speculative engine never dispatches ahead, so the draft pool has
+        none to keep)."""
+        self.pool.release_retired(upto)
         if self.draft_pool is not None:
             self.draft_pool.release_retired()
 
@@ -1266,6 +1310,10 @@ class ServingEngine:
             "prefill_chunk": sch.prefill_chunk,
             "decode_steps": self.decode_steps,
             "decode_steps_per_visit": self.n_decode_steps,
+            "decode_ahead": {
+                "dispatches": self.decode_steps, "ahead": self.decode_ahead_steps,
+                "share": self.decode_ahead_steps / self.decode_steps if self.decode_steps else 0.0,
+            },
             "host_visits": self.host_visits,
             "tokens_per_host_visit": (
                 self.decode_lane_tokens / self.host_visits
@@ -1935,34 +1983,82 @@ class ServingEngine:
     # decode
     #
 
-    def _decode_once(self) -> None:
+    def _decode_once(self, batch: tuple | None = None) -> None:
         """One decode-lane turn: dispatch the bucketed decode program for
         the decode-ready batch; sync harvests inline, async parks the
-        record in the in-flight table for the next step's harvest."""
+        record in the in-flight table for the next step's harvest.
+        ``batch``: what :meth:`_ahead_batch` found, for the dispatch ahead
+        of the in-flight record's harvest."""
+        ahead = batch is not None
         with self._span("serve.decode_dispatch") as sp:
             if self.spec is not None:
                 from thunder_tpu.serving.speculative import spec_decode_dispatch
 
                 rec = spec_decode_dispatch(self)
             else:
-                rec = self._decode_dispatch()
-            # steady: last step's device outputs were this step's inputs
+                parked = self.pool.n_retired
+                rec = self._decode_dispatch(batch)
+                if ahead:
+                    # what was parked before this dispatch is all the coming
+                    # harvest may drop
+                    rec["parked"] = parked
+                    self.decode_ahead_steps += 1
+                    self._m_steps_ahead.inc()
+            # steady: last step's device outputs were this step's inputs;
+            # ahead: dispatched before the host had last step's tokens
             sp.set(rows=len(rec["running"]), bucket="{}x{}".format(*rec["bucket"]),
-                   steady=rec["steady"])
+                   steady=rec["steady"], ahead=int(ahead))
             if self.async_step:
                 self._inflight_decode = rec
         if not self.async_step:
             self._harvest_inline(rec)
 
-    def _decode_dispatch(self) -> dict:
+    def _decode_batch(self, running: list) -> tuple:
+        """A decode batch and its signature: the rows in order, the batch
+        bucket and the table-width bucket.  While it stays the same the
+        chained device state feeds the next step."""
+        Bb, nbb = self.scheduler.decode_bucket(running)
+        return running, (tuple(r.rid for r in running), Bb, self._nbb(nbb))
+
+    def _ahead_batch(self) -> tuple | None:
+        """The batch of the next decode step where it may be dispatched
+        before the in-flight step *k* is harvested, else ``None`` (today's
+        order).  All of it is host state, none of it a token of step *k*:
+
+        - a decode record is in flight and the chain it left stands
+          (``_decode_state``; a speculative round leaves none);
+        - the chain has steps left before a row of it writes its last
+          position or a window lets blocks go at the harvest, and no row of
+          it is constrained (``ahead``, counted down from the chain's
+          rebuild: no walk over the rows here), nor runs ``decode_steps=N``;
+        - no prefill piece is in flight whose harvest would change the
+          batch, and no row's deadline has passed;
+        - the decode-ready rows and their buckets are the chain's (an
+          eviction, a resumed row or a quarantine changes them).
+
+        A row that ends at step *k* unseen (``eos_id``) makes step *k+1*
+        one dead row-step (``dead_scan_row``) and drops the chain at the
+        harvest, so the step after is a turnover step."""
+        st = self._decode_state
+        if (self._inflight_decode is None or st is None or st["ahead"] <= 0
+                or self._inflight_prefill):
+            return None
+        if st["deadline"] is not None and self.scheduler.clock() >= st["deadline"]:
+            return None
+        running = self.scheduler.decode_ready()
+        if not running:
+            return None
+        batch = self._decode_batch(running)
+        return batch if batch[1] == st["sig"] else None
+
+    def _decode_dispatch(self, batch: tuple | None = None) -> dict:
         sch, pool = self.scheduler, self.pool
-        running = (sch.decode_ready() if self.async_step
-                   else list(sch.running))                 # FIFO admission order
-        self._fault_point(FP_DECODE, tuple(r.rid for r in running))
-        Bb, _nbb_raw = sch.decode_bucket(running)
-        nbb = self._nbb(_nbb_raw)
+        running, sig = batch or self._decode_batch(
+            sch.decode_ready() if self.async_step
+            else list(sch.running))                        # FIFO admission order
+        self._fault_point(FP_DECODE, sig[0])
+        _, Bb, nbb = sig
         bs = pool.block_size
-        sig = (tuple(r.rid for r in running), Bb, nbb)
         N = self.n_decode_steps
         st = self._decode_state
         steady = st is not None and st["sig"] == sig
@@ -1976,6 +2072,7 @@ class ServingEngine:
             sslots_d = st.get("sslots")
             host_pos = st["host_pos"] + N
             stop_d = st.get("stop")
+            ahead_left, deadline = st["ahead"] - 1, st["deadline"]
         else:
             toks = np.zeros(Bb, dtype=np.int32)
             host_pos = np.zeros(Bb, dtype=np.int32)
@@ -1988,8 +2085,26 @@ class ServingEngine:
             # FINISH_LENGTH (see _build_decode_multi); -1 parks padding rows
             # dead from step 0
             stop = np.full(Bb, -1, dtype=np.int32)
+            # how many steps of this chain may be dispatched ahead of the
+            # harvest before theirs (_ahead_batch): until that harvest ends a
+            # row by length or frees a row's window-expired blocks; none
+            # where the next dispatch needs a value from the host.  And the
+            # first deadline among the rows
+            ahead_left, deadline = (1 << 30 if N == 1 else 0), None
+            W = sch.sliding_window
             for i, r in enumerate(running):
                 wpos = r.prompt_len + len(r.generated) - 1  # slot this step writes
+                ahead_left = min(ahead_left, r.max_new_tokens - len(r.generated) - 1)
+                if W is not None:
+                    # harvest m of the chain sees pos = wpos + m + 1 and frees
+                    # the first live block once (pos + 1 - W) // bs passes it
+                    live = next((j for j, b in enumerate(r.block_table) if b != SINK_BLOCK), None)
+                    if live is not None:
+                        ahead_left = min(ahead_left, max(0, (live + 1) * bs + W - wpos - 2))
+                if r.constraint is not None:
+                    ahead_left = 0
+                if r.deadline_t is not None:
+                    deadline = r.deadline_t if deadline is None else min(deadline, r.deadline_t)
                 toks[i] = r.generated[-1]
                 host_pos[i] = wpos
                 tables[i, : len(r.block_table)] = r.block_table
@@ -2055,13 +2170,8 @@ class ServingEngine:
             hp = np.asarray(host_pos, dtype=np.int64)[:, None] + np.arange(N)
             real = int(np.minimum(np.maximum(-(-hp // bs), 1), nbb).sum())
             self._goodput.note_blocks(kind, Bb * nbb * N, real)
-        tr = self._tracer
-        if tr is not None:
-            for r in running:
-                tr.begin(r.rid, "decode", step=self.decode_steps,
-                         compile=compiled, bucket=[Bb, nbb], lane="decode",
-                         attn=self.attn,
-                         **({"steps": N} if N > 1 else {}))
+        if batch is None:                   # ahead: once the step before is harvested
+            self._trace_decode_begin(running, self.decode_steps, compiled, [Bb, nbb])
         call_args = (self.params, toks_d, pos_d, tables_d, pool.arenas,
                      keys_d, lora_arenas, slots_d)
         if N > 1:
@@ -2084,6 +2194,7 @@ class ServingEngine:
         self._decode_state = {
             "sig": sig, "toks": nxt, "pos": new_pos, "tables": tables_d,
             "keys": new_keys, "slots": slots_d, "host_pos": host_pos,
+            "ahead": ahead_left, "deadline": deadline,
             **({"stop": stop_d} if N > 1 else {}),
             **({"sslots": sslots_d} if sslots_d is not None else {}),
         }
@@ -2101,6 +2212,20 @@ class ServingEngine:
         self._m_occupancy.observe(len(running))
         return rec
 
+    def _trace_decode_begin(self, running: list, step: int, compiled: bool, bucket: list) -> None:
+        """Opens the rows' ``decode`` spans of one decode dispatch (its
+        harvest closes them): at the dispatch, or, for a record dispatched
+        ahead, once the step before it is harvested — the device begins it
+        then, and a row has one ``decode`` span open at a time."""
+        tr = self._tracer
+        if tr is not None:
+            N = self.n_decode_steps
+            for r in running:
+                tr.begin(r.rid, "decode", step=step,
+                         compile=compiled, bucket=bucket, lane="decode",
+                         attn=self.attn,
+                         **({"steps": N} if N > 1 else {}))
+
     def _decode_harvest(self, rec: dict) -> None:
         if rec.get("spec"):
             from thunder_tpu.serving.speculative import spec_decode_harvest
@@ -2116,6 +2241,11 @@ class ServingEngine:
             fetched = [np.asarray(rec[k]) for k in
                        (("nxt", "emit", "new_keys") if multi else ("nxt", "new_keys"))]
         stall = time.perf_counter() - t0
+        if self._inflight_decode is not None:
+            # a record dispatched ahead of this harvest: the device took it up
+            # when this one's step ended, so its share of the overlap
+            # accounting begins here and not at its dispatch
+            self._inflight_decode["t_dev"] = t0 + stall
         with self._span("serve.harvest.emit"):
             (self._decode_emit_multi if multi else self._decode_emit)(rec, t0, stall, *fetched)
 
@@ -2123,9 +2253,10 @@ class ServingEngine:
         sch = self.scheduler
         running = rec["running"]
         if self.async_step:
-            # overlap accounting: host work since dispatch vs the residual
-            # device wait the materialization just paid
-            overlapped = t0 - rec["t_disp"]
+            # overlap accounting: host work since dispatch (since the device
+            # took the step up, where it was dispatched ahead) vs the
+            # residual device wait the materialization just paid
+            overlapped = t0 - rec.get("t_dev", rec["t_disp"])
             frac = overlapped / (overlapped + stall) if (overlapped + stall) > 0 else 0.0
             self._stall_s_sum += stall
             self._overlap_frac_sum += frac
@@ -2155,7 +2286,7 @@ class ServingEngine:
                 waste["dead_scan_row"] = n_dead
             gtag = gp.account(rec["pkind"], Bb, 1, committed=live, **waste)
             gp.note_device_s(rec["pkind"],
-                             time.perf_counter() - rec["t_disp"])
+                             time.perf_counter() - rec.get("t_dev", rec["t_disp"]))
         tr = self._tracer
         if tr is not None:                                 # tokens host-visible
             for r in running:

@@ -558,11 +558,19 @@ class PagedKVPool:
             self.k_scale = arenas["k_scale"]
             self.v_scale = arenas["v_scale"]
 
-    def release_retired(self) -> None:
+    @property
+    def n_retired(self) -> int:
+        """How many donations' handles are parked: the mark an engine takes
+        before a dispatch whose handles must outlive the next release."""
+        return len(self._retired)
+
+    def release_retired(self, upto: int | None = None) -> None:
         """Drops the parked donated-arena handles (cheap once their
         consuming executions have completed — call after materializing any
-        later output of the same device stream)."""
-        self._retired.clear()
+        later output of the same device stream).  ``upto`` keeps everything
+        parked after that mark (:attr:`n_retired` when it was taken): the
+        handles a program still on the device is consuming."""
+        del self._retired[:upto]
 
     def _zeros(self, shp: tuple, dt) -> jax.Array:
         """A zeroed arena buffer, shard-local under a mesh (no device ever

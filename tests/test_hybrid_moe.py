@@ -348,15 +348,15 @@ WAVE_TILES = 8
 
 def _plan(idx, first, held, tile):
     """The whole sorted buffer: every wave's rows, one after another."""
-    plan = jaxex.moe_plan(jnp.asarray(idx), first, held, tile, WAVE_TILES)
+    plan = jaxex.moe_plan(jnp.asarray(idx), jnp.ones(idx.shape), first, held, tile, WAVE_TILES)
     waves = plan["tile_group"].shape[0] // WAVE_TILES
     row_src = np.concatenate([np.asarray(jaxex.moe_wave_rows(plan, w, tile, WAVE_TILES)[0]) for w in range(waves)])
     return row_src, np.asarray(plan["tile_group"]), int(plan["tiles_used"])
 
 
 def _first_wave(idx, first, held, tile, wave_tiles=64):
-    plan = jaxex.moe_plan(jnp.asarray(idx), first, held, tile, wave_tiles)
-    row_src, tg, used = jaxex.moe_wave_rows(plan, 0, tile, wave_tiles)
+    plan = jaxex.moe_plan(jnp.asarray(idx), jnp.ones(idx.shape), first, held, tile, wave_tiles)
+    row_src, _, _, tg, used = jaxex.moe_wave_rows(plan, 0, tile, wave_tiles)
     return row_src, tg, used.reshape(1)
 
 
@@ -634,7 +634,7 @@ def test_rows_past_the_first_wave_are_computed_not_dropped(skew):
     w1, w3 = (jnp.asarray(rng.standard_normal((held, C, I)), jnp.float32) for _ in range(2))
     w2 = jnp.asarray(rng.standard_normal((held, I, C)), jnp.float32)
     wave_tiles = jaxex.moe_wave_tiles(N * k, held, total, tile)
-    assert wave_tiles == 8 and int(jaxex.moe_plan(jnp.asarray(idx), first, held, tile, wave_tiles)["tiles_used"]) > 16
+    assert wave_tiles == 8 and int(jaxex.moe_plan(jnp.asarray(idx), tw, first, held, tile, wave_tiles)["tiles_used"]) > 16
 
     def dense(x, tw, w1, w3, w2):
         return sum(jnp.sum(tw * (idx == e + first), axis=1)[:, None]
@@ -645,6 +645,165 @@ def test_rows_past_the_first_wave_are_computed_not_dropped(skew):
     got = jaxex._moe_expert_share_backward_impl(dy, x, jnp.asarray(idx), tw, w1, w3, w2, first, total, tile)
     ref = jax.vjp(dense, x, tw, w1, w3, w2)[1](dy)
     assert max(rel(a, b) for a, b in zip(got, ref)) < 1e-5
+
+
+# the rows' way into the sorted buffer and back (PR 43): ``row_src`` and its inverse ``pos``
+ROUTINGS = {
+    "decode_256x4_of_32_at_tile_64": dict(N=256, k=4, first=0, held=32, total=32, tile=64),
+    "held_12_of_192": dict(N=64, k=8, first=24, held=12, total=192, tile=16),
+    "skew_fills_a_second_and_third_wave": dict(N=50, k=4, first=4, held=8, total=256, tile=8, over=16, onto=5),
+    "an_expert_draws_no_row": dict(N=96, k=4, first=0, held=16, total=16, tile=8, never=3),
+    "every_row_on_one_expert": dict(N=40, k=2, first=2, held=4, total=8, tile=8, onto=3, rest_outside=True),
+    "ten_assignments_a_buffer_row": dict(N=512, k=8, first=24, held=12, total=192, tile=16),
+}
+
+
+def _routed(N, k, first, held, total, tile, over=None, onto=None, never=None, rest_outside=False, C=16, I=8, seed=0):
+    """``top_idx``, weights, rows and the held experts' float32 weights of a case."""
+    rng = np.random.default_rng(seed)
+    pool = np.array([e for e in range(over or total) if e != never])
+    idx = np.stack([rng.permutation(pool)[:k] for _ in range(N)]).astype(np.int32)
+    if rest_outside:                      # every other slot on an expert that is not held
+        outside = np.array([e for e in range(total) if not first <= e < first + held])
+        idx = np.stack([rng.permutation(outside)[:k] for _ in range(N)]).astype(np.int32)
+    if onto is not None:
+        idx[:, 0] = onto
+    tw = jnp.asarray(rng.random((N, k)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((N, C)), jnp.float32)
+    w1, w3 = (jnp.asarray(rng.standard_normal((held, C, I)), jnp.float32) for _ in range(2))
+    w2 = jnp.asarray(rng.standard_normal((held, I, C)), jnp.float32)
+    return jnp.asarray(idx), tw, x, w1, w3, w2
+
+
+def _dense_share(idx, first, held):
+    def dense(x, tw, w1, w3, w2):
+        return sum(jnp.sum(tw * (idx == e + first), axis=1)[:, None]
+                   * ((jax.nn.silu(x @ w1[e]) * (x @ w3[e])) @ w2[e]) for e in range(held))
+    return dense
+
+
+@pytest.mark.parametrize("case", list(ROUTINGS))
+def test_pos_and_row_src_are_inverse_wherever_a_row_is_routed(case):
+    c = ROUTINGS[case]
+    idx, tw = _routed(**c)[:2]
+    N, k, first, held, tile = (c[n] for n in ("N", "k", "first", "held", "tile"))
+    wave_tiles = jaxex.moe_wave_tiles(N * k, held, c["total"], tile)
+    plan = jaxex.moe_plan(idx, tw, first, held, tile, wave_tiles)
+    row_src, row_w = np.asarray(plan["row_src"]), np.asarray(plan["row_w"])
+    flat = np.asarray(idx).reshape(-1)
+    is_held = (flat >= first) & (flat < first + held)
+    rows = np.flatnonzero(row_src >= 0)
+    assert sorted(row_src[rows]) == list(np.flatnonzero(is_held)), "an assignment on a held expert has one row, no other has any"
+    assert (row_w[rows] == np.asarray(tw).reshape(-1)[row_src[rows]]).all() and (np.delete(row_w, rows) == 0).all(), \
+        "the sort carried each row's weight along"
+    waves = plan["tile_group"].shape[0] // wave_tiles
+    assert (case == "skew_fills_a_second_and_third_wave") == (int(plan["tiles_used"]) > 2 * wave_tiles)
+    if case == "ten_assignments_a_buffer_row":
+        assert plan["pos"] is None, "many assignments a buffer row: the tokens are added to, no inverse is made"
+        return
+    pos = np.asarray(plan["pos"]).reshape(-1)
+    assert (pos[row_src[rows]] == rows).all() and (pos[~is_held] == -1).all() and (row_src[pos[is_held]] == np.flatnonzero(is_held)).all()
+    # a wave's own view: positions counted from its first row, -1 for what lies in another wave
+    seen = np.zeros_like(pos)
+    for w in range(waves):
+        rs, pw, *_ = jaxex.moe_wave_rows(plan, w, tile, wave_tiles)
+        rs, pw = np.asarray(rs), np.asarray(pw).reshape(-1)
+        here = np.flatnonzero(pw >= 0)
+        assert (rs[pw[here]] == here).all() and len(here) == (rs >= 0).sum()
+        seen[here] += 1
+    assert (seen == is_held).all(), "every held assignment lies in exactly one wave"
+
+
+@pytest.mark.parametrize("case", list(ROUTINGS))
+def test_the_share_and_its_backward_match_the_dense_reference(case):
+    c = ROUTINGS[case]
+    idx, tw, x, w1, w3, w2 = _routed(**c)
+    first, held, total, tile = (c[n] for n in ("first", "held", "total", "tile"))
+    dense = _dense_share(idx, first, held)
+    assert rel(jaxex._moe_share(x, idx, tw, w1, w3, w2, first, total, tile), dense(x, tw, w1, w3, w2)) < 1e-5
+    dy = jnp.asarray(np.random.default_rng(1).standard_normal(x.shape), jnp.float32)
+    got = jaxex._moe_expert_share_backward_impl(dy, x, idx, tw, w1, w3, w2, first, total, tile)
+    ref = jax.vjp(dense, x, tw, w1, w3, w2)[1](dy)
+    assert max(rel(a, b) for a, b in zip(got, ref) if float(jnp.abs(b).max()) > 0) < 1e-5
+    assert all(float(jnp.abs(a).max()) == 0 for a, b in zip(got, ref) if float(jnp.abs(b).max()) == 0)
+
+
+@pytest.mark.parametrize("case", list(ROUTINGS))
+def test_a_tokens_result_is_the_same_bits_alone_and_among_the_others(case):
+    """The solo contract at the share: a token's rows are added in buffer
+    order, which its own routing fixes, so who else is in the batch changes no
+    bit of its result, in either form of the way back.  (A routing skewed
+    enough to spill into later waves adds a token's rows a wave at a time, and
+    which wave holds a row depends on the batch: equal to a rounding there, as
+    it was before the rows were gathered.)"""
+    c = ROUTINGS[case]
+    idx, tw, x, w1, w3, w2 = _routed(**c)
+    first, total, tile = c["first"], c["total"], c["tile"]
+    among = np.asarray(jaxex._moe_share(x, idx, tw, w1, w3, w2, first, total, tile))
+    held_slots = np.asarray((idx >= first) & (idx < first + c["held"])).sum(axis=1)
+    for n in (0, int(np.argmax(held_slots)), c["N"] - 1):
+        alone = np.asarray(jaxex._moe_share(x[n:n + 1], idx[n:n + 1], tw[n:n + 1], w1, w3, w2, first, total, tile))
+        if case == "skew_fills_a_second_and_third_wave":
+            assert rel(alone[0], among[n]) < 1e-6
+        else:
+            assert (alone[0] == among[n]).all(), (n, held_slots[n])
+
+
+CELL_SHARES = {      # tokens, k, held, all, tile, C, I; and whether the tokens gather their rows back
+    "lfm2_decode": (256, 4, 32, 32, 64, 2048, 1792, True), "lfm2_prefill": (2048, 4, 32, 32, 128, 2048, 1792, True),
+    "axk1_decode": (64, 8, 12, 192, 16, 7168, 2048, True), "axk1_prefill": (8192, 8, 12, 192, 128, 7168, 2048, False),
+    "hybrid_train": (16384, 10, 32, 512, 128, 2048, 512, False),
+}
+
+
+def _share_shapes(N, k, held, C, I, dtype=jnp.bfloat16):
+    sd = jax.ShapeDtypeStruct
+    return (sd((N, C), dtype), sd((N, k), jnp.int32), sd((N, k), jnp.float32), sd((held, C, I), dtype),
+            sd((held, C, I), dtype), sd((held, I, C), dtype))
+
+
+def _scatters(jaxpr):
+    """Every scatter of a jaxpr and of the jaxprs inside it: ``(primitive, dtype of the updates)``."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            out.append((eqn.primitive.name, eqn.invars[2].aval.dtype))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out += _scatters(inner)
+    return out
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHARES))
+def test_no_floating_point_scatter_where_the_tokens_gather_their_rows(cell):
+    """At the shapes the cells run: where a share holds a fair part of the
+    experts, or the step is a decode step, neither the share nor its backward
+    holds a scatter with floating-point updates; where ten assignments stand
+    for a buffer row (a long prompt of a share of 12 of 192, the trainer's 32
+    of 512) the rows are still added to their tokens, and no inverse is made."""
+    N, k, held, total, tile, C, I, gathers = CELL_SHARES[cell]
+    x, idx, tw, w1, w3, w2 = _share_shapes(N, k, held, C, I)
+    wave_tiles = jaxex.moe_wave_tiles(N * k, held, total, tile)
+    assert (jax.eval_shape(lambda i, w: jaxex.moe_plan(i, w, 0, held, tile, wave_tiles), idx, tw)["pos"] is not None) == gathers
+    fwd = jax.make_jaxpr(lambda *a: jaxex._moe_share(*a, 0, total, tile))(x, idx, tw, w1, w3, w2)
+    bwd = jax.make_jaxpr(lambda *a: jaxex._moe_expert_share_backward_impl(*a, 0, total, tile))(x, x, idx, tw, w1, w3, w2)
+    floating = [s for s in _scatters(fwd.jaxpr) + _scatters(bwd.jaxpr) if jnp.issubdtype(s[1], jnp.floating)]
+    assert (not floating) == gathers, floating
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHARES))
+def test_dispatch_and_combine_keep_the_two_index_arrays_and_no_rows(cell):
+    N, k, held, total, tile, C, I, gathers = CELL_SHARES[cell]
+    R = jaxex.moe_wave_tiles(N * k, held, total, tile) * tile
+    sd, static = jax.ShapeDtypeStruct, (N, k, jnp.dtype(jnp.bfloat16))
+    row_src, pos = sd((R,), jnp.int32), sd((N, k), jnp.int32) if gathers else None
+    kept = [jax.eval_shape(functools.partial(jaxex._dispatch_fwd, static), sd((N, C), jnp.bfloat16), sd((N, k), jnp.float32),
+                           row_src, pos, sd((R,), jnp.float32))[1],
+            jax.eval_shape(functools.partial(jaxex._combine_fwd, static), sd((R, C), jnp.bfloat16), row_src, pos)[1]]
+    leaves = jax.tree_util.tree_leaves(kept)
+    assert leaves and all(leaf.dtype == jnp.int32 and leaf.shape in ((R,), (N, k)) for leaf in leaves), leaves
 
 
 # --------------------------------------------------------------------------

@@ -925,9 +925,30 @@ def _grouped_mm_dw_impl(x, dy, tile_group, tiles_used, groups):
 # here is about held / all of that.  The rows are therefore worked through in
 # *waves* sized for an even routing (``moe_wave_tiles``): the first always, the
 # others only where the routing filled the one before (``lax.cond``), so memory
-# and time follow the rows routed and nothing is ever dropped.  A wave gathers
-# its rows from the tokens and adds its results back to them; differentiated,
-# the gather becomes the scatter-add and the scatter-add the gather.
+# and time follow the rows routed and nothing is ever dropped.
+#
+# The buffer is a partial permutation of the assignments, kept both ways:
+# ``row_src[r]`` is the assignment row ``r`` holds, ``pos[n, s]`` the row
+# assignment ``(n, s)`` landed in.  A wave's rows are a gather by ``row_src``
+# (``_dispatch``), and the tokens take their results back by a gather by
+# ``pos`` and a sum over ``k`` (``_combine``): no padding row is read back, so
+# none needs a zero on the way in.  Differentiated, each is the other: the
+# rows' gradient is gathered by ``pos``, the results' by ``row_src``, and the
+# two index arrays are all either keeps.
+#
+# XLA's scatter-add walks its update rows one after another, at five to ten
+# times what a gathered row costs (46-137 ns against 9-13 at a width of 2048 on
+# a v5e; ``tools/moe_tune.py --glue``), so the gather wins wherever the
+# assignments are not many times a wave's rows: every shape of a share that
+# holds a fair part of the experts, and any decode step.  Where a few of many
+# experts are held and the rows are many (a long prompt, the trainer's step:
+# ten assignments for a buffer row) the gather would walk ten rows to find
+# one and keep ``(k, N, C)`` of them, so there the tokens are still added to
+# (``pos`` is ``None``).  Both forms add a token's rows in buffer order, so
+# they agree to the bit and so does a token alone with a token in a batch.
+_ROWS_GATHERED_A_ROW_SCATTERED = 4
+
+
 def moe_wave_tiles(assignments: int, held: int, total: int, tile: int) -> int:
     """Tiles of one wave: the rows an even routing sends to ``held`` of
     ``total`` experts and an eighth more, a tile of padding a group, in eights."""
@@ -935,14 +956,23 @@ def moe_wave_tiles(assignments: int, held: int, total: int, tile: int) -> int:
     return max(8, -(-int(1.125 * even + held) // 8) * 8)
 
 
-def moe_plan(top_idx, first: int, held: int, tile: int, wave_tiles: int) -> dict:
+def moe_plan(top_idx, top_w, first: int, held: int, tile: int, wave_tiles: int) -> dict:
     """Sorts the ``(N, k)`` choices that fall on experts ``[first, first +
     held)`` by expert, each group padded to whole ``tile``-row tiles, into a
     buffer of ``R`` rows: the worst case the shapes allow, in whole waves.
-    Returns int32 ``order (N * k,)`` (the flat assignments ``n * k + s`` sorted
-    by expert, those on other experts last), ``off (held + 1,)`` (where each
-    group starts in ``order``), ``cnt``, ``poff (held,)`` (its rows, and where
-    it starts in the buffer), ``tile_group (R / tile,)`` and ``tiles_used ()``."""
+    One stable sort lays the buffer out: the flat assignments ``n * k + s``
+    under their expert's key, and after them ``tile - 1`` fillers a group, as
+    many under the group's key as pad it to whole tiles and the rest, like the
+    assignments on other experts, under a key past the last; the sort carries
+    the assignments' weights ``top_w (N, k)`` along, since a gather of scalars
+    costs nanoseconds an element.  Returns int32 ``row_src (R,)`` (the
+    assignment a row holds, -1 for padding and past the rows routed), ``row_w
+    (R,)`` (its weight, 0 there; a constant to differentiation, see
+    ``_dispatch``), ``cnt (held,)`` (each group's rows), ``tile_group (R /
+    tile,)``, ``tiles_used ()`` and ``pos (N, k)``: the buffer row each
+    assignment landed in (the sort's inverse, by a second sort), -1 where its
+    expert is not held; ``None`` where the shapes leave the tokens to be added
+    to (many assignments a buffer row: see above)."""
     N, k = top_idx.shape
     A = N * k
     wave = wave_tiles * tile
@@ -950,32 +980,39 @@ def moe_plan(top_idx, first: int, held: int, tile: int, wave_tiles: int) -> dict
     i32 = jnp.int32
     e = top_idx.reshape(A).astype(i32) - first
     key = jnp.where((e >= 0) & (e < held), e, held)
-    skey, order = jax.lax.sort((key, jnp.arange(A, dtype=i32)), num_keys=1, is_stable=True)
-    off = jnp.searchsorted(skey, jnp.arange(held + 1, dtype=i32)).astype(i32)
-    cnt = off[1:] - off[:-1]
-    padded = -(-cnt // tile) * tile                            # each group in whole tiles
-    pend = jnp.cumsum(padded)
-    t = jnp.arange(R // tile, dtype=i32)
-    tile_group = jnp.minimum(jnp.searchsorted(pend, t * tile, side="right"), held - 1).astype(i32)
-    return {"order": order, "off": off, "cnt": cnt, "poff": pend - padded,
-            "tile_group": tile_group, "tiles_used": (pend[-1] // tile).astype(i32)}
+    groups = jnp.arange(held, dtype=i32)
+    cnt = jnp.sum(key[:, None] == groups[None, :], axis=0, dtype=i32)
+    short = -cnt % tile                                        # rows that pad a group to whole tiles
+    fill = jnp.where(jnp.arange(tile - 1, dtype=i32)[None, :] < short[:, None], groups[:, None], held).reshape(-1)
+    L = A + fill.shape[0]                                      # what is sorted; the buffer's tail past it is padding
+    keys = jnp.concatenate([key, fill])
+    src = jnp.concatenate([jnp.arange(A, dtype=i32), jnp.full(fill.shape, A, i32)])
+    w = jnp.pad(jax.lax.stop_gradient(top_w).reshape(A), (0, L - A))
+    skey, ssrc, sw = jax.lax.sort((keys, src, w), num_keys=1, is_stable=True)
+    routed = (skey < held) & (ssrc < A)
+    row_src = jnp.pad(jnp.where(routed, ssrc, -1), (0, R - L), constant_values=-1)
+    row_w = jnp.pad(jnp.where(routed, sw, 0), (0, R - L))
+    tile_group = jnp.minimum(jnp.pad(skey, (0, R - L), constant_values=held)[::tile], held - 1)
+    pos = None
+    if A <= _ROWS_GATHERED_A_ROW_SCATTERED * wave:
+        _, at = jax.lax.sort((ssrc, jnp.arange(L, dtype=i32)), num_keys=1, is_stable=True)
+        pos = jnp.where(key < held, at[:A], -1).reshape(N, k)   # the fillers sort past the assignments
+    return {"row_src": row_src, "row_w": row_w, "cnt": cnt, "tile_group": tile_group,
+            "tiles_used": (jnp.sum(cnt + short) // tile).astype(i32), "pos": pos}
 
 
 def moe_wave_rows(plan: dict, w: int, tile: int, wave_tiles: int):
-    """Wave ``w`` of the sorted buffer: ``row_src (wave rows,)`` (the flat
-    assignment a row holds, -1 for padding and past the rows routed), its
-    tiles' groups and how many of them are used."""
+    """Wave ``w`` of the sorted buffer: its ``row_src``, ``pos`` counted from
+    its first row (-1 for an assignment that lies in another wave; ``None`` as
+    the plan's), its ``row_w``, its tiles' groups and how many of them are used."""
     T = wave_tiles
-    tg = plan["tile_group"][w * T:(w + 1) * T]
-    used = jnp.clip(plan["tiles_used"] - w * T, 0, T)
-    t = jnp.arange(T, dtype=jnp.int32)
-    # a tile's first row within its group; its rows follow one another in ``order``
-    within = ((w * T + t) * tile - plan["poff"][tg])[:, None] + jnp.arange(tile, dtype=jnp.int32)[None, :]
-    valid = (t < used)[:, None] & (within < plan["cnt"][tg][:, None])
-    rank = plan["off"][tg][:, None] + within
-    A = plan["order"].shape[0]
-    row_src = jnp.where(valid, plan["order"][jnp.clip(rank, 0, A - 1)], -1)
-    return row_src.reshape(T * tile), tg, used
+    rows = slice(w * T * tile, (w + 1) * T * tile)
+    pos = plan["pos"]
+    if pos is not None:
+        pos = pos - rows.start
+        pos = jnp.where((pos >= 0) & (pos < T * tile), pos, -1)
+    return (plan["row_src"][rows], pos, plan["row_w"][rows], plan["tile_group"][w * T:(w + 1) * T],
+            jnp.clip(plan["tiles_used"] - w * T, 0, T))
 
 
 @jax.custom_vjp
@@ -992,20 +1029,98 @@ def _gmm_bwd(res, g):
 _gmm.defvjp(lambda x, w, tg, tu: (_gmm(x, w, tg, tu), (x, w, tg, tu)), _gmm_bwd)
 
 
-def _moe_wave(x, top_w, fc_1, fc_2, proj, row_src, tile_group, tiles_used):
-    """One wave of the sorted buffer: its rows gathered from their tokens,
-    through the experts' SwiGLU as grouped products, weighted, added back to
-    their tokens in float32.  ``row_src`` and ``tile_group`` are the wave's."""
-    k = top_w.shape[1]
-    valid = row_src >= 0
-    a = jnp.maximum(row_src, 0)
-    xb = jnp.where(valid[:, None], jnp.take(x, a // k, axis=0), 0)
-    wb = jnp.where(valid, jnp.take(top_w.reshape(-1), a), 0)
+def _take_rows(v, idx):
+    """``v[idx]`` along axis 0 for indices known to lie inside (no select on
+    an index's validity behind the gather)."""
+    return v.at[idx].get(mode="promise_in_bounds")
+
+
+def _rows_of_tokens(v, row_src, k, mask: bool):
+    """Buffer rows from token rows ``v (N, C)``: row ``r`` is that of token
+    ``row_src[r] // k``; a padding row is zero with ``mask``, else some token's."""
+    rows = _take_rows(v, jnp.maximum(row_src, 0) // k)
+    return jnp.where((row_src >= 0)[:, None], rows, 0) if mask else rows
+
+
+def _tokens_of_rows(vb, row_src, pos, N, k, dtype):
+    """Token rows ``(N, C)`` from buffer rows ``vb (R, C)``: the sum in
+    ``dtype`` of the rows a token's assignments landed in, taken in buffer
+    order whichever form the shapes chose.  By ``pos``: one gather of ``(k,
+    N)`` rows, slot-major so that a slot's rows lie together, added a slot at a
+    time, a slot without a row here adding zero.  Without: the rows added to
+    their tokens, padding rows as zeros."""
+    y = jnp.zeros((N, vb.shape[1]), dtype)
+    if pos is None:
+        return y.at[jnp.maximum(row_src, 0) // k].add(jnp.where((row_src >= 0)[:, None], vb, 0).astype(dtype))
+    pos = jnp.sort(pos, axis=1).T                             # buffer order: by expert, as a scatter adds them
+    rows = _take_rows(vb, jnp.maximum(pos, 0))
+    for s in range(k):
+        y = y + jnp.where((pos[s] >= 0)[:, None], rows[s], 0).astype(dtype)
+    return y
+
+
+# ``static`` of the two below: (tokens N, slots k, the rows' dtype)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(static, x, top_w, row_src, pos, row_w):
+    """A wave's rows ``xb (R, C)`` and their weights ``wb (R,)``: the rows
+    gathered from the tokens by ``row_src`` (a padding row holds some token's,
+    since nothing reads its product back), the weights as the plan's sort
+    laid ``top_w`` out (``row_w``).  Their gradients are gathered by ``pos``,
+    the weights' into ``top_w``."""
+    return _rows_of_tokens(x, row_src, static[1], False), row_w.astype(top_w.dtype)
+
+
+def _dispatch_bwd(static, res, g):
+    N, k, _ = static
+    row_src, pos = res
+    dxb, dwb = g
+    dx = _tokens_of_rows(dxb, row_src, pos, N, k, dxb.dtype)
+    if pos is None:
+        dw = jnp.zeros((N * k,), dwb.dtype).at[jnp.maximum(row_src, 0)].add(jnp.where(row_src >= 0, dwb, 0))
+    else:
+        dw = jnp.where(pos >= 0, _take_rows(dwb, jnp.maximum(pos, 0)), 0)
+    return dx, dw.reshape(N, k), None, None, None
+
+
+def _dispatch_fwd(static, x, top_w, row_src, pos, row_w):
+    return _dispatch(static, x, top_w, row_src, pos, row_w), (row_src, pos)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(static, yb, row_src, pos):
+    """What a wave adds to the tokens, float32 ``(N, C)``: each token's rows
+    of ``yb (R, C)`` summed.  Its gradient is gathered by ``row_src``, zero on
+    the padding rows: through it every gradient of the wave is zero there."""
+    N, k, _ = static
+    return _tokens_of_rows(yb, row_src, pos, N, k, jnp.float32)
+
+
+def _combine_fwd(static, yb, row_src, pos):
+    return _combine(static, yb, row_src, pos), row_src
+
+
+def _combine_bwd(static, row_src, g):
+    _, k, dtype = static
+    return _rows_of_tokens(g, row_src, k, True).astype(dtype), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _moe_wave(x, top_w, fc_1, fc_2, proj, row_src, pos, row_w, tile_group, tiles_used):
+    """One wave of the sorted buffer: its rows gathered from their tokens
+    (by ``row_src``), through the experts' SwiGLU as grouped products,
+    weighted, and gathered back by their tokens (by ``pos``) and summed in
+    float32.  ``row_src``, ``pos``, ``row_w`` and ``tile_group`` are the wave's."""
+    static = (*top_w.shape, jnp.dtype(x.dtype))
+    xb, wb = _dispatch(static, x, top_w, row_src, pos, row_w)
     used = tiles_used.reshape(1)
     h = jax.nn.silu(_gmm(xb, fc_1, tile_group, used)) * _gmm(xb, fc_2, tile_group, used)
     yb = _gmm(h * wb[:, None].astype(h.dtype), proj, tile_group, used)
-    # padding rows hold zeros: they may land on token 0
-    return jnp.zeros(x.shape, jnp.float32).at[a // k].add(yb.astype(jnp.float32))
+    return _combine(static, yb, row_src, pos)
 
 
 def _run_wave(static, plan, w, *operands):
@@ -1053,7 +1168,7 @@ _overflow.defvjp(lambda static, plan, *operands: (_overflow(static, plan, *opera
 def _moe_share(x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile=MOE_ROW_TILE):
     held = fc_1.shape[0]
     wave_tiles = moe_wave_tiles(top_idx.size, held, total, tile)
-    plan = moe_plan(top_idx, first, held, tile, wave_tiles)
+    plan = moe_plan(top_idx, top_w, first, held, tile, wave_tiles)
     static = (tile, wave_tiles, plan["tile_group"].shape[0] // wave_tiles)
     operands = (x, top_w, fc_1, fc_2, proj)
     y = _run_wave(static, plan, 0, *operands)

@@ -209,10 +209,12 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear):
     holds experts ``[expert_first, expert_first + expert_held)`` and computes
     their part (``jaxex._moe_share``, the trainer's forward: the step's rows
     sorted by held expert into whole row tiles, grouped products through
-    ``moe_grouped_mm``, nothing dropped); what the other experts would add is
-    left out, and the shared expert is added once.  A decode step routes a few
-    rows an expert, so its tiles are narrow (:func:`moe_row_tile`); an expert's
-    weights are fetched once a product however many tiles its rows fill."""
+    ``moe_grouped_mm``, each token gathering its ``k`` results back by where
+    they landed and summing them in float32, nothing dropped); what the other
+    experts would add is left out, and the shared expert is added once.  A
+    decode step routes a few rows an expert, so its tiles are narrow
+    (:func:`moe_row_tile`); an expert's weights are fetched once a product
+    however many tiles its rows fill."""
     from thunder_tpu.executors import jaxex
 
     B, T, C = x.shape

@@ -1,6 +1,7 @@
 """Time the expert share's grouped product alone on the chip, at the shapes the cells run.
 
     python3 tools/moe_tune.py [--check] [--shapes lfm2_decode,lfm2_prefill] [--vmem-mib 32,48]
+    python3 tools/moe_tune.py --glue [--check] [--shapes lfm2_decode,lfm2_prefill]
 
 One line a shape, a product (``fc``: rows x ``(C, I)``; ``proj``: rows x
 ``(I, C)``) and a routing (``even``: every held expert the same rows; ``skew``:
@@ -22,8 +23,19 @@ fills it, as the model runs it.  ``--vmem-mib`` times the kernel again with
 it is on the line).  To compare kernels, put each variant in
 a tree of its own under ``_checkout/`` with this file in it and run the tool in
 each, all in one call.  ``--check`` first compares the compiled kernel with
-``lax.ragged_dot`` at every shape.  Needs a TPU; exits non-zero without one,
-or if the check fails."""
+``lax.ragged_dot`` at every shape.
+
+``--glue`` times the share outside its kernel instead, a part a line under the
+skewed routing, the form before PR 43 (kept below) beside ``jaxex``'s: the
+``plan`` (the sort and the first wave's ``row_src``; the new one with the
+rows' weights, and with ``pos`` where the shapes gather by it), the ``dispatch``
+(rows gathered by ``row_src``; before, the weights too, and both masked), the
+``swiglu`` pass over the wave's rows (one form), the ``combine`` (a scatter-add
+of the wave's rows before; the shape's first line says which form ``jaxex``
+chose), and the whole ``_moe_share`` with the part of it that is not
+``moe_grouped_mm*``.  ``--check`` there holds the new
+combine to the old one's bits.  Needs a TPU; exits non-zero without one, or if
+a check fails."""
 import argparse
 import os
 import sys
@@ -65,10 +77,11 @@ def waves(tokens, k, held, total, skew, tile=None, **_):
     each, the tile's rows, and the rows routed a held expert."""
     tile = tile or moe_row_tile(tokens * k / total)
     wave_tiles = jaxex.moe_wave_tiles(tokens * k, held, total, tile)
-    plan = jaxex.moe_plan(jnp.asarray(routing(tokens, k, held, total, skew)), 0, held, tile, wave_tiles)
+    idx = jnp.asarray(routing(tokens, k, held, total, skew))
+    plan = jaxex.moe_plan(idx, jnp.ones(idx.shape, jnp.float32), 0, held, tile, wave_tiles)
     out = []
     for w in range(plan["tile_group"].shape[0] // wave_tiles):
-        _, tg, used = jaxex.moe_wave_rows(plan, w, tile, wave_tiles)
+        *_, tg, used = jaxex.moe_wave_rows(plan, w, tile, wave_tiles)
         if int(used) > 0:
             out.append((tg, used.reshape(1)))
     return out, tile, np.asarray(plan["cnt"])
@@ -130,9 +143,104 @@ def time_shape(name, skew: bool):
               f"beside it {sum(ms.values()) - own:.3f}  {schedule}", flush=True)
 
 
+# ---- the share outside its kernel (--glue) ----------------------------------------------
+
+def _plan_before(top_idx, held, tile, wave_tiles):
+    """``moe_plan`` and the first wave's ``moe_wave_rows`` as they stood before PR 43."""
+    N, k = top_idx.shape
+    A, i32 = N * k, jnp.int32
+    wave = wave_tiles * tile
+    R = -(-(A + held * (tile - 1)) // wave) * wave
+    e = top_idx.reshape(A).astype(i32)
+    key = jnp.where((e >= 0) & (e < held), e, held)
+    skey, order = jax.lax.sort((key, jnp.arange(A, dtype=i32)), num_keys=1, is_stable=True)
+    off = jnp.searchsorted(skey, jnp.arange(held + 1, dtype=i32)).astype(i32)
+    cnt = off[1:] - off[:-1]
+    padded = -(-cnt // tile) * tile
+    pend = jnp.cumsum(padded)
+    tile_group = jnp.minimum(jnp.searchsorted(pend, jnp.arange(R // tile, dtype=i32) * tile, side="right"), held - 1).astype(i32)
+    tg = tile_group[:wave_tiles]
+    used = jnp.clip((pend[-1] // tile).astype(i32), 0, wave_tiles)
+    t = jnp.arange(wave_tiles, dtype=i32)
+    within = (t * tile - (pend - padded)[tg])[:, None] + jnp.arange(tile, dtype=i32)[None, :]
+    valid = (t < used)[:, None] & (within < cnt[tg][:, None])
+    row_src = jnp.where(valid, order[jnp.clip(off[tg][:, None] + within, 0, A - 1)], -1)
+    return row_src.reshape(wave), tg, used
+
+
+def _dispatch_before(x, top_w, row_src):
+    valid, a = row_src >= 0, jnp.maximum(row_src, 0)
+    return (jnp.where(valid[:, None], jnp.take(x, a // top_w.shape[1], axis=0), 0),
+            jnp.where(valid, jnp.take(top_w.reshape(-1), a), 0))
+
+
+def _combine_before(yb, row_src, N, k):
+    return jnp.zeros((N, yb.shape[1]), jnp.float32).at[jnp.maximum(row_src, 0) // k].add(yb.astype(jnp.float32))
+
+
+def _ms(fn, *args):
+    """ms a call of ``fn`` jitted alone: everything it starts on the device, and its largest operations."""
+    from tools.flash_tune import kernel_ms
+
+    call = jax.jit(fn)
+    run = lambda: jax.block_until_ready(call(*args))    # noqa: E731
+    run()
+    ms = kernel_ms(run, REPS)
+    return sum(ms.values()), ms
+
+
+def glue_shape(name, check_bits: bool) -> bool:
+    shape = SHAPES[name]
+    N, k, held, total, C, I = (shape[n] for n in ("tokens", "k", "held", "total", "C", "I"))
+    tile = shape.get("tile") or moe_row_tile(N * k / total)
+    wave_tiles = jaxex.moe_wave_tiles(N * k, held, total, tile)
+    R = wave_tiles * tile
+    idx = jnp.asarray(routing(N, k, held, total, True))
+    keys = jax.random.split(jax.random.PRNGKey(2), 8)
+    x, yb = (jax.random.normal(kk, sh).astype(jnp.bfloat16) for kk, sh in zip(keys, ((N, C), (R, C))))
+    h1, h2 = (jax.random.normal(kk, (R, I)).astype(jnp.bfloat16) for kk in keys[2:4])
+    top_w = jax.random.uniform(keys[4], (N, k), jnp.float32)
+    w1, w3 = ((jax.random.normal(kk, (held, C, I)) * 0.05).astype(jnp.bfloat16) for kk in keys[5:7])
+    w2 = (jax.random.normal(keys[7], (held, I, C)) * 0.05).astype(jnp.bfloat16)
+
+    def plan_now(idx_, top_w_):
+        return jaxex.moe_wave_rows(jaxex.moe_plan(idx_, top_w_, 0, held, tile, wave_tiles), 0, tile, wave_tiles)
+
+    row_src, pos, row_w, _, used = jax.jit(plan_now)(idx, top_w)
+    static = (N, k, jnp.dtype(jnp.bfloat16))
+    form = "scatter-add" if pos is None else "gather by pos"
+    print(f"--- {name}: {N} tokens x {k} = {N * k} assignments on {held} of {total}, tiles of {tile}, a wave of {R} rows "
+          f"({int(used)} of {wave_tiles} tiles used, {int((row_src >= 0).sum())} rows routed); the tokens' rows come back by {form}",
+          flush=True)
+    parts = [
+        ("plan", lambda: _ms(lambda i: _plan_before(i, held, tile, wave_tiles), idx), lambda: _ms(plan_now, idx, top_w)),
+        ("dispatch", lambda: _ms(_dispatch_before, x, top_w, row_src),
+         lambda: _ms(lambda *a: jaxex._dispatch(static, *a), x, top_w, row_src, pos, row_w)),
+        ("swiglu", lambda: _ms(lambda a, b, w: jax.nn.silu(a) * b * w[:, None].astype(a.dtype), h1, h2, jnp.ones((R,), jnp.float32)), None),
+        ("combine", lambda: _ms(lambda y, r: _combine_before(y, r, N, k), jnp.where((row_src >= 0)[:, None], yb, 0), row_src),
+         lambda: _ms(lambda *a: jaxex._combine(static, *a), yb, row_src, pos)),
+    ]
+    for part, before, now in parts:
+        b, _ = before()
+        n, ops = now() if now else (b, {})
+        top = ", ".join(f"{o} {t * 1e3:.0f}" for o, t in sorted(ops.items(), key=lambda kv: -kv[1])[:3])
+        print(f"{name:18s} {part:9s} before {b * 1e3:8.1f} us   now {n * 1e3:8.1f} us   [{top}]", flush=True)
+    whole, ops = _ms(lambda *a: jaxex._moe_share(*a, 0, total, tile), x, idx, top_w, w1, w3, w2)
+    kernel = sum(t for o, t in ops.items() if o.startswith("moe_grouped_mm"))
+    print(f"{name:18s} {'share':9s} whole {whole * 1e3:8.1f} us, moe_grouped_mm {kernel * 1e3:8.1f}, beside it {(whole - kernel) * 1e3:8.1f}", flush=True)
+    if not check_bits:
+        return True
+    want = jax.jit(lambda y, r: _combine_before(y, r, N, k))(jnp.where((row_src >= 0)[:, None], yb, 0), row_src)
+    got = jax.jit(lambda *a: jaxex._combine(static, *a))(yb, row_src, pos)
+    same = bool(jnp.all(want == got))
+    print(f"check {name:18s} the combine's bits are the scatter-add's: {same}", flush=True)
+    return same
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--check", action="store_true")
+    ap.add_argument("--glue", action="store_true", help="time the share's parts outside the kernel, the form before PR 43 beside the new")
     ap.add_argument("--shapes", default=",".join(SHAPES))
     ap.add_argument("--vmem-mib", default="", help="comma-separated values of pallasex._gmm_vmem_cap to time beside the device's")
     args = ap.parse_args()
@@ -142,6 +250,9 @@ def main():
                  f"{device['platform']!r} ({device['kind']}).  Nothing was measured.")
     print(device, flush=True)
     names = [n for n in args.shapes.split(",") if n]
+    if args.glue:
+        same = [glue_shape(n, args.check) for n in names if not SHAPES[n].get("transposed")]
+        sys.exit(0 if all(same) else "moe_tune: the combine disagrees with the scatter-add it replaced")
     if args.check and check(names) > 0.01:       # bfloat16 results of a float32 sum: a rounding of the last place
         sys.exit("moe_tune: the compiled kernel disagrees with lax.ragged_dot")
     for mib in [None] + [int(m) for m in args.vmem_mib.split(",") if m]:

@@ -186,7 +186,7 @@ def serve_phase(device: dict, dev) -> None:
                      "error": r.error, "new_tokens": len(got),
                      "first_token_matches_solo": bool(len(got) and got[0] == solo[0]),
                      "tokens_matching_solo": int(np.sum(got == solo[:len(got)]))})
-    decode = stats["attn"]["kinds"]["decode"]
+    decode = {k: stats["attn"][k] for k in ("path", "fallback_steps", "kv_chunk_tokens")}
     emit(device, "serve", t0, config=CONFIG, n_layer=cfg.n_layer,
          depth_cut_from=llama.Config.from_name(CONFIG).n_layer,
          dtype="bfloat16", num_blocks=num_blocks, block_size=block_size,
@@ -197,8 +197,7 @@ def serve_phase(device: dict, dev) -> None:
          / (len(rows) * NEW_TOKENS), memory=memory([dev]))
     assert all(r["finish"] == "length" and r["error"] is None
                and r["new_tokens"] == NEW_TOKENS for r in rows), rows
-    assert decode["mode"] == "paged" and decode["fallback_steps"] == 0, decode
-    assert stats["attn"]["fallback_steps"] == 0 and stats["recoveries"] == 0, stats["attn"]
+    assert decode["path"] == "walk" and decode["fallback_steps"] == 0 and stats["recoveries"] == 0, stats["attn"]
     # later tokens may part ways: with random weights the top two logits are
     # close, and one rounding flips the argmax and everything after it
     assert all(r["first_token_matches_solo"] for r in rows), rows

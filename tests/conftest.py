@@ -52,6 +52,82 @@ def _reset_observability_state():
         tt.reset_observability()
 
 
+# The paged attention entries (``pallasex.paged_attn_decode`` / ``_verify``, and
+# ``mla_paged_decode``) choose their form from the backend: on the CPU their
+# XLA form, unless THUNDER_TPU_PALLAS_INTERPRET=1 opts into the kernels under
+# the Pallas interpreter (the program family the chip runs).  A test that takes
+# ``attn_form`` runs once in each; the environment is the test's alone.
+PALLAS_INTERPRET = "THUNDER_TPU_PALLAS_INTERPRET"
+ATTN_FORMS = ("xla", "interpreted")
+
+
+def set_attn_form(env, form: str) -> None:
+    """``env``: a ``pytest.MonkeyPatch`` (a test's, or a module fixture's own
+    ``MonkeyPatch.context()``), which puts the variable back."""
+    assert form in ATTN_FORMS, form
+    if form == "interpreted":
+        env.setenv(PALLAS_INTERPRET, "1")
+    else:
+        env.delenv(PALLAS_INTERPRET, raising=False)
+
+
+def in_each_attn_form(fn) -> list:
+    """``fn()`` once in each form, in ``ATTN_FORMS``' order: the results."""
+    out = []
+    for form in ATTN_FORMS:
+        with _pytest.MonkeyPatch.context() as env:
+            set_attn_form(env, form)
+            out.append(fn())
+    return out
+
+
+def prim_names(jaxpr, *, skip=("pallas_call",)) -> list:
+    """``(primitive name, eqn)`` of every equation of a jaxpr, recursing into
+    sub-jaxprs (pjit, custom_vjp, scan, ...) but not into pallas kernel bodies."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append((eqn.primitive.name, eqn))
+        if eqn.primitive.name in skip:
+            continue
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", None)
+            if sub is not None and hasattr(sub, "eqns"):
+                names.extend(prim_names(sub, skip=skip))
+            elif hasattr(v, "eqns"):
+                names.extend(prim_names(v, skip=skip))
+    return names
+
+
+def arena_census(arenas, jaxpr) -> tuple:
+    """``(arena gathers, scatters)`` of a serving program's jaxpr: gathers whose
+    operand is one of the pool's ``arenas`` or one layer's slice of it (what the
+    kernels' XLA form gathers from), and scatters of any kind."""
+    import jax
+
+    shapes = {(a.shape[0], *a.shape[2:]) for a in jax.tree_util.tree_leaves(arenas)}
+    gathers = scatters = 0
+    for name, eqn in prim_names(jaxpr):
+        shape = tuple(eqn.invars[0].aval.shape) if name == "gather" else ()
+        gathers += len(shape) > 2 and (shape[0], *shape[2:]) in shapes
+        scatters += name.startswith("scatter")
+    return gathers, scatters
+
+
+@_pytest.fixture(params=ATTN_FORMS)
+def attn_form(request, monkeypatch):
+    set_attn_form(monkeypatch, request.param)
+    return request.param
+
+
+@_pytest.fixture(autouse=True, scope="module")
+def _a_file_leaves_the_environment_as_it_found_it():
+    # a file that left the interpreter switched on changed which programs the
+    # worker's next file built (PR 32)
+    before = _os.environ.get(PALLAS_INTERPRET)
+    yield
+    assert _os.environ.get(PALLAS_INTERPRET) == before, "a test of this file left THUNDER_TPU_PALLAS_INTERPRET changed"
+
+
 def pytest_configure(config):
     # tier-1 runs with -m 'not slow'; soak/long-horizon tests opt out with it
     config.addinivalue_line("markers", "slow: long-running test, excluded from tier-1")
@@ -64,11 +140,14 @@ def pytest_configure(config):
 # The tier-1 command runs `--dist loadfile`: a file is one worker's from start
 # to end, so the run lasts as long as the last long file to start.  xdist's own
 # order (by number of tests) starts a file of five long tests last; collection
-# order starts it late in the alphabet.  Start the long files first (seconds a
-# file: ROADMAP.md D11); everything else keeps its order.
+# order starts it late in the alphabet.  Start the long files first, longest
+# first (seconds a file on one of six busy workers, PR 44's tree: 493, 370, 305,
+# 302, 235, 233, 212, 208, 182, 151, 141, 121; the next is 103); everything
+# else keeps its order.
 _LONGEST_FIRST = (
-    "test_sequence_parallel.py", "test_ring_attention.py", "test_hybrid_moe.py",
-    "test_train_cli.py", "test_paged_attention.py", "test_pallas.py",
+    "test_hybrid_moe.py", "test_sequence_parallel.py", "test_pallas_tpu_lowering.py", "test_ring_attention.py",
+    "test_train_cli.py", "test_mla_serving.py", "test_paged_attention.py", "test_shortconv_serving.py",
+    "test_hybrid_decoder_serving.py", "test_pallas.py", "test_causal_conv.py", "test_hybrid_serving.py",
 )
 
 

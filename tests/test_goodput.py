@@ -205,8 +205,8 @@ class TestConservationMatrix:
         rep = eng.goodput_report()
         assert rep.get("enabled", True) is not False
         assert set(rep["per_kind"]) <= {
-            "prefill", "prefill_fresh", "prefill_chunk", "decode", "decode_paged",
-            "decode_multi", "decode_multi_paged"}
+            "prefill", "prefill_fresh", "prefill_chunk", "prefill_chunk_paged", "decode_paged",
+            "decode_multi_paged"}
         assert "prefill_fresh" in rep["per_kind"]      # whole prompts at position 0
         assert sum(k["positions"] for k in rep["per_kind"].values()) \
             == rep["positions"]

@@ -123,11 +123,11 @@ def test_a_prompts_flash_call_is_the_masked_softmax(interpreted):
 
 MISTRAL_LIKE = dict(name="tiny-window", n_layer=2, n_head=4, n_query_groups=2, n_embd=64, head_size=128, vocab_size=128,
                     intermediate_size=128, sliding_window=24, block_size=256)
-# sha256 of the decode programs' jaxprs (kernel bodies included), computed on the commit before this kind came
-# (PR 40's tree, d1d06c0) and again here; regenerate with `python tests/test_hybrid_decoder_kernels.py` after a
-# deliberate change to the windowed path
+# sha256 of the decode program's jaxpr (kernel bodies included), computed on the commit before this kind came
+# (PR 40's tree, d1d06c0) and again here (and again at PR 44, which moved the choice of the attention call's form
+# behind the kernel's entry: the program with the kernel in it is what it was); regenerate with
+# `python tests/test_hybrid_decoder_kernels.py` after a deliberate change to the windowed path
 WINDOW_PROGRAMS = {
-    "decode": "468547c47399bd464eb271e9981763f681e9220453359f6fcca2d937660011b9",
     "decode_paged": "c61380311f52a7edac71102e584a9875d4bb290c634c82cd0edd9c8329a77457",
 }
 
@@ -139,13 +139,13 @@ def _window_program_digests() -> dict:
     cfg = llama.Config(**MISTRAL_LIKE)
     params = jax.eval_shape(lambda: llama.init_params(cfg, dtype=jnp.float32))
     out = {}
-    for kind, attn in (("decode", "gather"), ("decode_paged", "paged")):
+    for kind in WINDOW_PROGRAMS:
         old = os.environ.get("THUNDER_TPU_PALLAS_INTERPRET")
         os.environ["THUNDER_TPU_PALLAS_INTERPRET"] = "1"
         try:
-            eng = tt.serve(None, params, cfg, block_size=8, num_blocks=32, max_batch=2, attn=attn,
+            eng = tt.serve(None, params, cfg, block_size=8, num_blocks=32, max_batch=2,
                            cache_dtype=jnp.float32, prefix_sharing=False)
-            prog = (eng._build_decode if kind == "decode" else eng._build_decode_paged)(2, 8)
+            prog = eng._build_decode_paged(2, 8)
             i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
             args = (params, i32(2), i32(2), i32(2, 8), jax.eval_shape(lambda: eng.pool.arenas),
                     jax.ShapeDtypeStruct((2, 2), jnp.uint32), {}, i32(2))

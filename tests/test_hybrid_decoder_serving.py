@@ -167,7 +167,7 @@ def test_served_tokens_are_solo_generates_and_the_references_best(kernels, monke
     cfg, params = model()
     eng = tt.serve(None, params, cfg, **ENGINE)
     st = eng.stats()["attn"]
-    assert st["mode"] == "paged" and st["lane_pack"] == 2 and st["shared_kv_layers"] == 2
+    assert st["path"] == ("walk" if kernels else "xla") and st["lane_pack"] == 2 and st["shared_kv_layers"] == 2
     prompts, new = [prompt(40, 6), prompt(23, 7), prompt(64, 8)], [60, 50, 36]
     before = dict(px.stats)
     got = served(eng, prompts, new)
@@ -248,10 +248,12 @@ def test_what_the_kinds_cannot_do_yet_is_refused_by_name():
                          (dict(speculative=object()), "speculative"), (dict(mesh=object()), "mesh"),
                          (dict(decode_steps=2), "decode_steps"), (dict(kv_dtype="fp8"), "kv_dtype"),
                          (dict(prefill_chunk=32), "prefill_chunk"), (dict(priorities=True), "priorities"),
-                         (dict(fault_plan=object()), "fault_plan"), (dict(attn="gather"), "gather"),
+                         (dict(fault_plan=object()), "fault_plan"),
                          (dict(lora=object()), "lora")]:
         assert word in hybrid_unsupported(cfg, **option), option
     assert hybrid_unsupported(cfg) is None          # a window of a layer kind beside a state: served
+    with pytest.raises(TypeError, match="unexpected keyword argument 'attn'"):      # one decode program a job
+        tt.serve(None, params, cfg, attn="gather", **ENGINE)
     with pytest.raises(NotImplementedError, match="kv_dtype"):
         tt.serve(None, params, cfg, kv_dtype="int8", **ENGINE)
     eng = tt.serve(None, params, cfg, **ENGINE)

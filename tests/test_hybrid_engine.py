@@ -27,6 +27,7 @@ from thunder_tpu.models import llama  # noqa: E402
 from thunder_tpu.serving import ServingEngine, faults  # noqa: E402
 
 from _hybrid_tiny import tiny_model, tokens as _tokens  # noqa: E402
+from conftest import ATTN_FORMS, set_attn_form  # noqa: E402
 
 @pytest.fixture(autouse=True)
 def interpreted(monkeypatch):
@@ -51,9 +52,9 @@ def solo(model):
     return [np.asarray(G.generate(params, _tokens(p, seed=p)[None], cfg, n, T_max=128))[0, p:] for p, n in REQUESTS]
 
 
-def _engine(model, attn, **kw):
+def _engine(model, **kw):
     cfg, params = model
-    opts = dict(attn=attn, max_batch=3, num_blocks=40, block_size=16, prefill_buckets=[32, 64],
+    opts = dict(max_batch=3, num_blocks=40, block_size=16, prefill_buckets=[32, 64],
                 batch_buckets=[4], block_buckets=[8])
     return tt.serve(None, params, cfg, **{**opts, **kw})
 
@@ -66,41 +67,41 @@ def _served(eng, which=range(len(REQUESTS))):
 
 @pytest.fixture(scope="module")
 def served(model):
-    """Six requests through three slots, so slots and state slots turn over, on
-    both decode paths; the engine's counts afterwards."""
+    """Six requests through three slots, so slots and state slots turn over, in
+    both forms of the decode program's attention; the engine's counts afterwards."""
     out = {}
-    with pytest.MonkeyPatch.context() as env:       # the worker's next test file gets its environment back
-        env.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
-        for attn in ("gather", "paged"):
+    for form in ATTN_FORMS:
+        with pytest.MonkeyPatch.context() as env:   # the worker's next test file gets its environment back
+            set_attn_form(env, form)
             claims = px.stats.get("gdn_decode", 0)
-            eng = _engine(model, attn)
-            out[attn] = (_served(eng), eng.stats(), eng._flight_state(), px.stats.get("gdn_decode", 0) - claims)
+            eng = _engine(model)
+            out[form] = (_served(eng), eng.stats(), eng._flight_state(), px.stats.get("gdn_decode", 0) - claims)
             eng.shutdown()
     return out
 
 
 @pytest.mark.parametrize("i", range(len(REQUESTS)))
-@pytest.mark.parametrize("attn", ["gather", "paged"])
-def test_a_served_request_is_bit_identical_to_solo_generate(served, solo, attn, i):
-    assert np.array_equal(served[attn][0][i], solo[i])
+@pytest.mark.parametrize("form", ATTN_FORMS)
+def test_a_served_request_is_bit_identical_to_solo_generate(served, solo, form, i):
+    assert np.array_equal(served[form][0][i], solo[i])
 
 
-@pytest.mark.parametrize("attn", ["gather", "paged"])
-def test_the_engine_reports_its_state_pool(served, attn):
-    _, stats, flight, claims = served[attn]
+@pytest.mark.parametrize("form", ATTN_FORMS)
+def test_the_engine_reports_its_state_pool(served, form):
+    _, stats, flight, claims = served[form]
     st = stats["state"]
     assert st["slots"] == 3 and st["leased"] == 0 and st["free_low_water"] == 0 and st["layers"] == 3
     assert st["dtype"] == "float32" and st["arena_bytes"] == 4 * st["slot_bytes"]
     assert stats["pool_occupancy"]["state"]["fill_frac"] == 0.0 and flight["pool"]["state"]["slots"] == 3
-    assert stats["attn"]["mode"] == attn and stats["recoveries"] == 0
-    if attn == "paged":
-        assert stats["attn"]["fallback_steps"] == 0
+    assert stats["attn"]["path"] == ("xla" if form == "xla" else "walk") and stats["recoveries"] == 0
+    assert stats["attn"]["fallback_steps"] == (stats["decode_steps"] if form == "xla" else 0)
+    assert "decode_paged" in stats["compile_counts"] and "decode" not in stats["compile_counts"]
 
 
 def test_state_gauges_are_published(model):
     from thunder_tpu.observability.metrics import registry
 
-    eng = _engine(model, "paged")
+    eng = _engine(model)
     eng.submit(_tokens(20), max_new_tokens=3)
     eng.step()
     reg = registry()
@@ -109,9 +110,8 @@ def test_state_gauges_are_published(model):
     eng.shutdown(drain=False)
 
 
-@pytest.mark.parametrize("attn", ["gather", "paged"])
-def test_a_recovery_rebuilds_the_state_through_the_prefill_programs(model, solo, attn):
-    eng = _engine(model, attn)
+def test_a_recovery_rebuilds_the_state_through_the_prefill_programs(model, solo, attn_form):
+    eng = _engine(model)
     handles = [eng.submit(_tokens(REQUESTS[i][0], seed=REQUESTS[i][0]), max_new_tokens=REQUESTS[i][1]) for i in (0, 1, 2)]
     for _ in range(6):
         eng.step()
@@ -126,7 +126,7 @@ def test_a_recovery_rebuilds_the_state_through_the_prefill_programs(model, solo,
 
 def test_an_injected_fault_recovers_to_the_same_tokens(model, solo):
     plan = faults.FaultPlan(specs=[faults.FaultSpec(point=faults.FP_DECODE, kind="oom", at=3)])
-    eng = _engine(model, "paged", fault_plan=plan)
+    eng = _engine(model, fault_plan=plan)
     got = _served(eng, which=(0, 3))
     assert np.array_equal(got[0], solo[0]) and np.array_equal(got[1], solo[3])
     assert eng.stats()["recoveries"] >= 1
@@ -134,7 +134,7 @@ def test_an_injected_fault_recovers_to_the_same_tokens(model, solo):
 
 
 def test_an_evicted_request_frees_both_kinds_and_the_others_are_untouched(model, solo):
-    eng = _engine(model, "paged")
+    eng = _engine(model)
     handles = [eng.submit(_tokens(REQUESTS[i][0], seed=REQUESTS[i][0]), max_new_tokens=REQUESTS[i][1]) for i in (0, 1, 4)]
     for _ in range(4):
         eng.step()
@@ -148,9 +148,8 @@ def test_an_evicted_request_frees_both_kinds_and_the_others_are_untouched(model,
     eng.shutdown()
 
 
-@pytest.mark.parametrize("attn", ["gather", "paged"])
-def test_a_chunked_prompt_carries_its_state_from_piece_to_piece(model, solo, attn):
-    eng = _engine(model, attn, prefill_chunk=32)
+def test_a_chunked_prompt_carries_its_state_from_piece_to_piece(model, solo, attn_form):
+    eng = _engine(model, prefill_chunk=32)
     got = _served(eng, which=(2, 5, 0))                    # 64 = two whole pieces, 50 and 40 = a piece and a padded rest
     for tokens, i in zip(got, (2, 5, 0)):
         assert np.array_equal(tokens, solo[i]), i
@@ -161,20 +160,20 @@ def test_a_chunked_prompt_carries_its_state_from_piece_to_piece(model, solo, att
 def test_the_state_arena_is_float32_and_a_narrower_one_is_the_controls_to_plant(model, monkeypatch):
     from thunder_tpu.serving.kv_pool import StatePool
 
-    eng = _engine(model, "paged")
+    eng = _engine(model)
     assert eng.stats()["state"]["dtype"] == "float32" and eng.pool.state.state.dtype == jnp.float32
     eng.shutdown(drain=False)
     # no option of the engine's: the benchmark's control sets the pool's constant
     # (chipbench/drivers/serve_held.py --state-arena), and the programs follow the arena they are handed
     monkeypatch.setattr(StatePool, "STATE_DTYPE", jnp.bfloat16)
-    eng = _engine(model, "paged")
+    eng = _engine(model)
     assert eng.stats()["state"]["dtype"] == "bfloat16" and eng.pool.state.state.dtype == jnp.bfloat16
     assert len(_served(eng, which=(1,))[0]) == 9
     eng.shutdown()
 
 
 def test_fp8_kv_beside_a_float32_state(model):
-    eng = _engine(model, "paged", kv_dtype="fp8")
+    eng = _engine(model, kv_dtype="fp8")
     assert set(eng.pool.arenas) == {"k", "v", "k_scale", "v_scale", "state", "conv"}
     assert len(_served(eng, which=(0, 1))[1]) == 9
     eng.shutdown()
@@ -196,13 +195,16 @@ def _refusals(model):
         "lora": (dict(lora=object.__new__(AdapterRegistry)), "lora=.*mixer's projections"),
         "mesh": (dict(mesh=object()), "mesh=.*tp axis"),
         "decode_steps": (dict(decode_steps=4), "decode_steps > 1.*advancing its state"),
-        "model_fn": (dict(model_fn=lambda *a, **k: None), "custom model_fn"),
     }
 
 
 @pytest.mark.parametrize("feature", ["prefix_sharing", "sessions", "speculative", "lora", "mesh", "decode_steps", "model_fn"])
 def test_each_refused_feature_raises_with_its_reason(model, feature):
     cfg, params = model
+    if feature == "model_fn":       # refused for every model: a model is a Config
+        with pytest.raises(NotImplementedError, match="llama.Config.*custom model_fn"):
+            tt.serve(lambda *a, **k: None, params, cfg, num_blocks=8, max_batch=1)
+        return
     kw, why = _refusals(model)[feature]
     with pytest.raises(NotImplementedError, match="linear_attention layers.*" + why):
         ServingEngine(params, cfg, num_blocks=8, max_batch=1, **kw)
@@ -210,7 +212,7 @@ def test_each_refused_feature_raises_with_its_reason(model, feature):
 
 def test_prefix_sharing_defaults_off_for_a_state_and_on_for_a_dense_model(model):
     cfg, params = model
-    eng = _engine(model, "gather")
+    eng = _engine(model)
     assert eng.prefix_sharing is False
     eng.shutdown(drain=False)
     dense_cfg = llama.Config.from_name("tiny-llama-debug")
@@ -226,7 +228,7 @@ def test_held_is_what_solo_generation_holds(model, kv_dtype):
     blocks against solo ``generate()``'s own cache after the same tokens (a
     full-width arena holds solo's keys bit for bit; an fp8 one a rounding away)."""
     cfg, params = model
-    eng = _engine(model, "paged", **({"kv_dtype": kv_dtype} if kv_dtype else {}))
+    eng = _engine(model, **({"kv_dtype": kv_dtype} if kv_dtype else {}))
     prompt = _tokens(19, seed=7)
     h = eng.submit(prompt, max_new_tokens=8)
     other = eng.submit(_tokens(11, seed=8), max_new_tokens=8)      # a second row beside it

@@ -92,9 +92,15 @@ def test_the_interpreted_walk_over_packed_rows_is_its_xla_form(hs, ng, rep, monk
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
         same = px.paged_attn_decode(q, *plain, fk, fv, tables, pos, layer=1)   # the plain arena, same kernel body
         np.testing.assert_allclose(np.asarray(got), np.asarray(same), atol=2e-5)
-    windowed = px.paged_attn_decode(q, *packed, fk, fv, tables, jnp.asarray([40, 9, 48], jnp.int32), layer=0, window=12)
-    plain_w = px.paged_attn_decode(q, *plain, fk, fv, tables, jnp.asarray([40, 9, 48], jnp.int32), layer=0, window=12)
+    wpos = jnp.asarray([40, 9, 47], jnp.int32)
+    windowed = px.paged_attn_decode(q, *packed, fk, fv, tables, wpos, layer=0, window=12)
+    plain_w = px.paged_attn_decode(q, *plain, fk, fv, tables, wpos, layer=0, window=12)
     np.testing.assert_allclose(np.asarray(windowed), np.asarray(plain_w), atol=2e-5)
+    # and the entry's own XLA form (Pallas off), over the packed rows: heads taken apart, the window in the mask
+    monkeypatch.delenv("THUNDER_TPU_PALLAS_INTERPRET")
+    assert px.paged_decode_path(128, 12) == "xla"
+    xla = px.paged_attn_decode(q, *packed, fk, fv, tables, wpos, layer=0, window=12)
+    np.testing.assert_allclose(np.asarray(xla), np.asarray(windowed), atol=2e-5)
 
 
 @pytest.mark.parametrize("hs,ng", [(64, 4), (32, 4)])
@@ -121,26 +127,23 @@ def test_what_the_token_writer_lands_in_packed_rows_comes_back(hs, ng, monkeypat
 
 
 # a served sequence at a head of 128, as the commit before the lane-packed layout served it
-# (tiny seeded model below, float32, attn="gather" and the kernels interpreted alike)
+# (tiny seeded model below, float32, the decode kernel's XLA form and the kernels interpreted alike)
 HEAD_128 = dict(name="head128", n_layer=2, n_head=2, n_query_groups=1, n_embd=64, head_size=128,
                 intermediate_size=96, vocab_size=256, block_size=128)
 HEAD_128_TOKENS = [[2, 232, 232, 232, 232, 232, 232, 232, 36, 36, 36, 36], [104, 23, 103, 23, 103, 23, 103, 57, 57, 57, 57, 57]]
 
 
-@pytest.mark.parametrize("attn", ["gather", "paged"])
-def test_a_head_of_128_keeps_its_arena_and_its_tokens(attn, monkeypatch):
-    if attn == "paged":
-        monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+def test_a_head_of_128_keeps_its_arena_and_its_tokens(attn_form):
     cfg = llama.Config(**HEAD_128)
     params = llama.init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
     assert G.kv_lane_pack(cfg) == 1 and G.kv_block_shape(cfg, 16) == (2, 1, 16, 128)
-    eng = tt.serve(None, params, cfg, num_blocks=12, block_size=16, max_batch=2, attn=attn, prefill_buckets=(16, 32))
+    eng = tt.serve(None, params, cfg, num_blocks=12, block_size=16, max_batch=2, prefill_buckets=(16, 32))
     assert eng.pool.k_arena.shape == (12, 2, 1, 16, 128) and eng.pool.lane_pack == 1
     occ = eng.stats()["pool_occupancy"]
     assert occ["token_bytes_counted"] == occ["token_bytes_laid_out"] == 2 * 2 * 128 * 4
     got = _serve(eng, [tokens(21, 3), tokens(9, 4)], new=12)
     assert [g.tolist() for g in got] == HEAD_128_TOKENS
-    assert eng.stats()["attn"]["path"] == ("walk" if attn == "paged" else None)
+    assert eng.stats()["attn"]["path"] == ("walk" if attn_form == "interpreted" else "xla")
 
 
 def test_a_narrow_head_unpacked_is_laid_out_at_twice_its_bytes():

@@ -264,7 +264,7 @@ def test_served_together_is_the_references_greedy_choice(model, prompts, served)
         assert float(np.min(top2[:, 1] - top2[:, 0])) > 1e-4          # no tie decides a token at this seed
         np.testing.assert_array_equal(got, best)
     counts = eng.stats()["compile_counts"]
-    assert counts["prefill_fresh"] >= 1 and counts["decode_paged"] >= 1 and counts["decode"] == 0
+    assert counts["prefill_fresh"] >= 1 and counts["decode_paged"] >= 1 and "decode" not in counts
 
 
 def test_served_is_solo_generate(served, solo):
@@ -281,7 +281,7 @@ def test_the_other_program_kinds_serve_the_same_tokens(model, prompts, solo, opt
         np.testing.assert_array_equal(got, want)
     st = eng.stats()
     if "prefill_chunk" in options:
-        assert st["chunk_runs"] > 0 and eng.attn_chunk == "gather"     # a piece attends its expanded keys
+        assert st["chunk_runs"] > 0 and st["attn"]["chunk"] == "gather"     # a piece attends its expanded keys
 
 
 def test_a_shared_prefix_is_read_from_the_first_requests_blocks(model):
@@ -433,18 +433,26 @@ def test_the_engine_claims_the_kernels_where_pallas_runs(model, prompts, solo, m
 REFUSED = {
     "kv_dtype": (dict(kv_dtype="fp8"), "no dequant"),
     "int8": (dict(kv_dtype="int8"), "no dequant"),
-    "attn_gather": (dict(attn="gather"), "no latent form"),
-    "model_fn": (dict(model_fn=lambda *a, **k: None), "mirror forward_with_cache"),
+}
+GONE = {    # what no model can ask for any more: one decode program a job, a model a Config
+    "attn_gather": (dict(attn="gather"), TypeError, "unexpected keyword argument 'attn'"),
+    "model_fn": (dict(model_fn=lambda *a, **k: None), NotImplementedError, "llama.Config.*custom model_fn"),
 }
 
 
-@pytest.mark.parametrize("feature", [*REFUSED, "speculative", "lora", "mesh"])
+@pytest.mark.parametrize("feature", [*REFUSED, *GONE, "speculative", "lora", "mesh"])
 def test_each_refused_feature_raises_with_its_reason(model, feature):
     cfg, params = model
+    if feature in GONE:
+        options, error, reason = GONE[feature]
+        options = dict(options)
+        with pytest.raises(error, match=reason):
+            tt.serve(options.pop("model_fn", None), params, cfg, num_blocks=8, max_batch=1, **options)
+        return
     if feature in REFUSED:
         options, reason = REFUSED[feature]
         with pytest.raises(NotImplementedError, match=f"latent attention.*{reason}"):
-            tt.serve(options.pop("model_fn", None), params, cfg, num_blocks=8, max_batch=1, **options)
+            tt.serve(None, params, cfg, num_blocks=8, max_batch=1, **options)
         return
     reasons = {"speculative": "verify step attends several draft tokens", "lora": "latent projections",
                "mesh": "no heads axis shards"}

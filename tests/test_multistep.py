@@ -1,8 +1,8 @@
 """Device-resident multi-step decode: N tokens per host visit (ISSUE 16).
 
 The load-bearing guarantee is differential and bit-exact at the token
-level: an engine with ``decode_steps=N`` (the ``decode_multi`` /
-``decode_multi_paged`` program kinds — the decode body wrapped in a
+level: an engine with ``decode_steps=N`` (the ``decode_multi_paged``
+program kind — the decode body wrapped in a
 ``lax.scan`` with in-program EOS/length stopping and per-request liveness
 masks) must serve tokens identical to the 1-step engine across the whole
 matrix: greedy AND temperature, int8 KV, LoRA, prefix sharing, chunked
@@ -15,13 +15,15 @@ the same static config compiles nothing.
 
 The third pillar is structural: a request finishing at step k < N must
 not over-serve, its remaining scan iterations keep-mask KV writes to the
-sink block (poisoned-sink regression, gather AND paged), and the compiled
-``decode_multi_paged`` program still contains zero arena gathers/scatters
-(gather program as positive control).
+sink block (poisoned-sink regression, in both forms of the program's
+attention), and with the kernel in it the compiled ``decode_multi_paged``
+program still contains zero arena gathers/scatters (the kernel's XLA form as
+positive control).
 
-Everything runs on CPU (paged kernels in Pallas interpret mode, automatic
-off-TPU); paged multi-step tests are kept few — an N-step interpret-mode
-scan costs N kernel evaluations per visit.
+Everything runs on CPU: by default the attention kernel's XLA form, and under
+``THUNDER_TPU_PALLAS_INTERPRET=1`` (``conftest.attn_form``) the kernel in
+Pallas interpret mode; the interpreted multi-step tests are kept few — an
+N-step interpret-mode scan costs N kernel evaluations per visit.
 """
 from __future__ import annotations
 
@@ -31,6 +33,7 @@ import numpy as np
 import pytest
 
 import thunder_tpu as tt
+from conftest import arena_census, set_attn_form
 from thunder_tpu.models import llama
 from thunder_tpu.serving import AdapterRegistry, FaultPlan, FaultSpec, make_lora_factors
 from thunder_tpu.serving.faults import FP_DECODE
@@ -93,22 +96,23 @@ def _vs_one_step(cfg, params, prompts, n=6, N=4, keys=None, engine_kw=None,
 
 
 class TestMultiStepParity:
-    def test_greedy_gather(self, micro):
+    def test_greedy_xla_form(self, micro):
         cfg, params = micro
         t1, t4 = _vs_one_step(cfg, params, _prompts(cfg))
         assert t1 == t4
 
-    def test_greedy_gather_off_pow2_horizon(self, micro):
+    def test_greedy_xla_form_off_pow2_horizon(self, micro):
         """N=3: the horizon is one static knob, not a power-of-two bucket —
         any N compiles one program and serves identical tokens."""
         cfg, params = micro
         t1, t3 = _vs_one_step(cfg, params, _prompts(cfg), N=3)
         assert t1 == t3
 
-    def test_greedy_paged(self, micro):
+    def test_greedy_kernel(self, micro, monkeypatch):
         cfg, params = micro
+        set_attn_form(monkeypatch, "interpreted")
         t1, t4 = _vs_one_step(cfg, params, _prompts(cfg, lens=(3, 7)),
-                              engine_kw=dict(attn="paged", max_batch=2))
+                              engine_kw=dict(max_batch=2))
         assert t1 == t4
 
     def test_temperature_with_request_keys(self, micro):
@@ -120,14 +124,14 @@ class TestMultiStepParity:
                               keys=keys, engine_kw=dict(temperature=0.7))
         assert t1 == t4
 
-    def test_int8_kv_gather_and_paged(self, micro):
+    def test_int8_kv_xla_form_and_kernel(self, micro, monkeypatch):
         cfg, params = micro
         t1, t4 = _vs_one_step(cfg, params, _prompts(cfg),
                               engine_kw=dict(kv_dtype="int8"))
         assert t1 == t4
+        set_attn_form(monkeypatch, "interpreted")
         p1, p4 = _vs_one_step(cfg, params, _prompts(cfg, lens=(3, 7)),
-                              engine_kw=dict(kv_dtype="int8", attn="paged",
-                                             max_batch=2))
+                              engine_kw=dict(kv_dtype="int8", max_batch=2))
         assert p1 == p4
 
     def test_lora_mix(self, micro):
@@ -195,8 +199,7 @@ class TestMultiStepParity:
 
 
 class TestBoundaryStopping:
-    @pytest.mark.parametrize("attn", ["gather", "paged"])
-    def test_eos_inside_visit_with_poisoned_sink(self, micro, attn):
+    def test_eos_inside_visit_with_poisoned_sink(self, micro, attn_form):
         """A request hitting EOS at step k < N stops there — and its
         remaining scan iterations keep-mask to the sink block.  Poisoning
         the sink mid-run proves no dead iteration's write (or read)
@@ -204,17 +207,17 @@ class TestBoundaryStopping:
         the shared batch is unperturbed."""
         cfg, params = micro
         prompts = _prompts(cfg, lens=(3, 7))
-        ref1 = _drive(_engine(cfg, params, attn=attn, max_batch=2),
+        ref1 = _drive(_engine(cfg, params, max_batch=2),
                       prompts, n=8)
         # an EOS the reference stream emits mid-visit: generated token #2
         # of request 0 (prompt excluded), i.e. finish at step 2 of the
         # first 4-step visit (the first generated token comes from prefill)
         eos = ref1[0][len(prompts[0]) + 2]
-        ref = _drive(_engine(cfg, params, attn=attn, max_batch=2,
+        ref = _drive(_engine(cfg, params, max_batch=2,
                              eos_id=int(eos)), prompts, n=8)
         assert len(ref[0]) < len(ref1[0])                  # EOS really fired early
 
-        eng = _engine(cfg, params, attn=attn, max_batch=2, eos_id=int(eos),
+        eng = _engine(cfg, params, max_batch=2, eos_id=int(eos),
                       decode_steps=4, async_step=False)
         handles = [eng.submit(p, max_new_tokens=8) for p in prompts]
         for _ in range(3):
@@ -293,21 +296,6 @@ class TestBoundaryStopping:
 #
 
 
-def _prim_names(jaxpr, *, skip=("pallas_call",)):
-    names = []
-    for eqn in jaxpr.eqns:
-        names.append((eqn.primitive.name, eqn))
-        if eqn.primitive.name in skip:
-            continue
-        for v in eqn.params.values():
-            sub = getattr(v, "jaxpr", None)
-            if sub is not None and hasattr(sub, "eqns"):
-                names.extend(_prim_names(sub, skip=skip))
-            elif hasattr(v, "eqns"):
-                names.extend(_prim_names(v, skip=skip))
-    return names
-
-
 def _multi_decode_args(eng, Bb, nbb):
     key = jax.random.PRNGKey(0)
     return (
@@ -325,30 +313,24 @@ def _multi_decode_args(eng, Bb, nbb):
 
 def _census(eng, kind, Bb=4, nbb=4):
     prog, _ = eng._program(kind, Bb, nbb)
-    jaxpr = jax.make_jaxpr(prog)(*_multi_decode_args(eng, Bb, nbb)).jaxpr
-    arena_shapes = {tuple(a.shape)
-                    for a in jax.tree_util.tree_leaves(eng.pool.arenas)}
-    arena_gathers = scatters = 0
-    for name, eqn in _prim_names(jaxpr):
-        if name == "gather" and tuple(eqn.invars[0].aval.shape) in arena_shapes:
-            arena_gathers += 1
-        if name.startswith("scatter"):
-            scatters += 1
-    return arena_gathers, scatters
+    return arena_census(eng.pool.arenas, jax.make_jaxpr(prog)(*_multi_decode_args(eng, Bb, nbb)).jaxpr)
 
 
 class TestMultiProgramPurity:
-    def test_paged_multi_has_zero_arena_gathers_and_scatters(self, micro):
+    def test_paged_multi_has_zero_arena_gathers_and_scatters(self, micro, monkeypatch):
         cfg, params = micro
-        eng = _engine(cfg, params, attn="paged", decode_steps=4)
+        set_attn_form(monkeypatch, "interpreted")
+        eng = _engine(cfg, params, decode_steps=4)
         assert _census(eng, "decode_multi_paged") == (0, 0)
 
-    def test_gather_multi_is_the_positive_control(self, micro):
-        """The same census on the gather multi program finds both op
-        families — proving the walk sees through pjit AND the scan."""
+    def test_gather_multi_is_the_positive_control(self, micro, monkeypatch):
+        """The same census on the multi program built with Pallas off (the
+        kernel's XLA form) finds both op families — proving the walk sees
+        through pjit AND the scan."""
         cfg, params = micro
-        eng = _engine(cfg, params, attn="gather", decode_steps=4)
-        arena_gathers, scatters = _census(eng, "decode_multi")
+        set_attn_form(monkeypatch, "xla")
+        eng = _engine(cfg, params, decode_steps=4)
+        arena_gathers, scatters = _census(eng, "decode_multi_paged")
         assert arena_gathers > 0 and scatters > 0
 
 
@@ -369,7 +351,16 @@ class TestKnobContract:
         eb = _engine(cfg, params, temperature=temp, decode_steps=1)
         _drive(eb, _prompts(cfg, lens=(4,)), n=4)
         assert eb.stats()["compile_counts"]["prefill"] == 0
-        assert eb.stats()["compile_counts"]["decode"] == 0
+        assert eb.stats()["compile_counts"]["decode_paged"] == 0
+
+    def test_the_eos_a_scan_stops_at_is_program_identity(self, micro):
+        """The multi-step program has its EOS baked in (a row dies at it inside
+        the scan): two horizons' engines that differ in it share no program,
+        and the 1-step engines, which stop on the host, share all of theirs."""
+        cfg, params = micro
+        keys = {eos: _engine(cfg, params, decode_steps=4, eos_id=eos)._static_key() for eos in (None, 7, 9)}
+        assert len(set(keys.values())) == 3
+        assert _engine(cfg, params, eos_id=7)._static_key() == _engine(cfg, params)._static_key()
 
     def test_rejects_bad_horizon(self, micro):
         cfg, params = micro
@@ -397,8 +388,7 @@ class TestKnobContract:
         st = eng.stats()
         decode_compiles = sum(
             st["compile_counts"][k]
-            for k in ("decode", "decode_paged", "decode_multi",
-                      "decode_multi_paged"))
+            for k in ("decode_paged", "decode_multi_paged"))
         assert decode_compiles <= st["bucket_bound"]
 
 

@@ -1,23 +1,24 @@
 """Paged-attention decode: Pallas kernel over the KV block arena (ISSUE 13).
 
 The load-bearing guarantee is differential and bit-exact at the token
-level: an engine with ``attn="paged"`` (flash-decoding kernel reading K/V
-straight from the block arena) must serve tokens identical to
-``attn="gather"`` (dense gather/scatter round-trip) and to solo
-``generate()`` — greedy AND temperature, int8/fp8 KV, LoRA mixes, chunked
-prefill, prefix sharing, and fault-recovery replay.  Logits are only
-ulp-close (online vs full softmax reorder), so every assertion here
-compares tokens, never arena bytes.
+level: the decode program with the flash-decoding kernel in it (reading K/V
+straight from the block arena; here under the Pallas interpreter,
+``THUNDER_TPU_PALLAS_INTERPRET=1``) must serve tokens identical to the same
+program with the kernel's XLA form in it (Pallas off: what a default CPU
+engine builds) and to solo ``generate()`` — greedy AND temperature, int8/fp8
+KV, LoRA mixes, chunked prefill, prefix sharing, and fault-recovery replay.
+Logits are only ulp-close (online vs full softmax reorder), so every
+assertion here compares tokens, never arena bytes.
 
-The second pillar is structural: the compiled ``decode_paged`` program
-must contain **zero** arena-sized gather primitives and zero scatters
-(asserted on the jaxpr, with the gather program as positive control), and
-physical block 0 (the sink / table padding target) must be dead weight —
-poisoning it mid-run changes nothing on either path.
+The second pillar is structural: with the kernel in it the compiled
+``decode_paged`` program must contain **zero** arena gather primitives and
+zero scatters (asserted on the jaxpr, with the XLA form as positive
+control), and physical block 0 (the sink / table padding target) must be
+dead weight — poisoning it mid-run changes nothing in either form.
 
-Everything runs on CPU with the kernels in Pallas interpret mode
-(``attn="paged"`` forces the kernel regardless of backend), so tier-1
-exercises the real kernel math, not a stand-in.
+The third is the entry's own choice (``pallasex.paged_decode_path``): which
+form ``paged_attn_decode`` takes follows from what it can observe, and the
+engine's ``stats()["attn"]`` says which.
 """
 from __future__ import annotations
 
@@ -27,6 +28,7 @@ import numpy as np
 import pytest
 
 import thunder_tpu as tt
+from conftest import arena_census, in_each_attn_form, set_attn_form
 from thunder_tpu.executors import pallasex as px
 from thunder_tpu.models import generate as gen
 from thunder_tpu.models import llama
@@ -35,7 +37,7 @@ from thunder_tpu.serving.faults import FP_DECODE
 from thunder_tpu.serving.kernel_check import _ref_attend
 from thunder_tpu.serving.kv_pool import gather_dense
 from thunder_tpu.serving.lora import valid_targets
-from thunder_tpu.serving.paged_attention import paged_supported
+from thunder_tpu.serving.paged_attention import decode_path
 from thunder_tpu.serving.quant import gather_dense_q, quantize_kv
 
 # 2 layers (layer-indexed arena reads), GQA 4:2 (in-kernel q-group
@@ -83,14 +85,10 @@ def _drive(eng, prompts, n=5, keys=None, **submit_kw):
 
 
 def _both(cfg, params, prompts, n=5, keys=None, engine_kw=None, submit_kw=None):
-    """Tokens from a gather engine and a paged engine, same workload."""
+    """Tokens from an engine of each form, same workload."""
     engine_kw = engine_kw or {}
     submit_kw = submit_kw or {}
-    tg = _drive(_engine(cfg, params, attn="gather", **engine_kw), prompts, n,
-                keys=keys, **submit_kw)
-    tp = _drive(_engine(cfg, params, attn="paged", **engine_kw), prompts, n,
-                keys=keys, **submit_kw)
-    return tg, tp
+    return in_each_attn_form(lambda: _drive(_engine(cfg, params, **engine_kw), prompts, n, keys=keys, **submit_kw))
 
 
 #
@@ -99,7 +97,7 @@ def _both(cfg, params, prompts, n=5, keys=None, engine_kw=None, submit_kw=None):
 
 
 class TestPagedParity:
-    def test_greedy_vs_gather_and_solo(self, micro):
+    def test_greedy_vs_xla_form_and_solo(self, micro):
         cfg, params = micro
         prompts = _prompts(cfg)
         tg, tp = _both(cfg, params, prompts)
@@ -134,11 +132,11 @@ class TestPagedParity:
         cfg, params = micro
         targets = ("wq", "wk", "wv", "wo", "fc_1", "fc_2", "proj")
 
-        def serve_one(attn):
+        def serve_one():
             reg = AdapterRegistry(cfg, rank=2, max_adapters=2, targets=targets)
             reg.register("alice", make_lora_factors(
                 cfg, 2, jax.random.PRNGKey(9), targets, std=0.5))
-            eng = _engine(cfg, params, lora=reg, attn=attn)
+            eng = _engine(cfg, params, lora=reg)
             prompts = _prompts(cfg, lens=(3, 6, 10))
             hs = [eng.submit(prompts[0], max_new_tokens=5, adapter_id="alice"),
                   eng.submit(prompts[1], max_new_tokens=5),
@@ -146,7 +144,8 @@ class TestPagedParity:
             eng.drain()
             return [tuple(h.result(drive=False).tokens) for h in hs]
 
-        assert serve_one("gather") == serve_one("paged")
+        tx, tk = in_each_attn_form(serve_one)
+        assert tx == tk
 
     def test_chunked_prefill(self, micro):
         cfg, params = micro
@@ -158,8 +157,8 @@ class TestPagedParity:
         cfg, params = micro
         base = (np.arange(10) * 7 + 3).astype(np.int32) % cfg.vocab_size
 
-        def serve_one(attn):
-            eng = _engine(cfg, params, attn=attn, max_batch=2)
+        def serve_one():
+            eng = _engine(cfg, params, max_batch=2)
             ha = eng.submit(base, max_new_tokens=4)
             eng.step()                               # prefill A, register prefix
             hb = eng.submit(base.copy(), max_new_tokens=4)
@@ -169,21 +168,24 @@ class TestPagedParity:
             assert rb.shared_prefix_blocks == 2      # sharing actually happened
             return tuple(ra.tokens), tuple(rb.tokens)
 
-        assert serve_one("gather") == serve_one("paged")
+        tx, tk = in_each_attn_form(serve_one)
+        assert tx == tk
 
-    def test_fault_recovery_replay(self, micro):
+    def test_fault_recovery_replay(self, micro, monkeypatch):
         """Re-prefill recovery rebuilds the arena, then decode resumes on
-        the kernel path — tokens still match the fault-free gather run."""
+        the kernel — tokens still match the fault-free run of the XLA form."""
         cfg, params = micro
         p = (np.arange(6) * 3 + 1).astype(np.int32) % cfg.vocab_size
-        ref = _drive(_engine(cfg, params, attn="gather"), [p], n=8)
+        set_attn_form(monkeypatch, "xla")
+        ref = _drive(_engine(cfg, params), [p], n=8)
+        set_attn_form(monkeypatch, "interpreted")
         eng = _engine(
-            cfg, params, attn="paged",
+            cfg, params,
             fault_plan=FaultPlan(specs=[FaultSpec(point=FP_DECODE, kind="oom", at=3)]),
         )
         got = _drive(eng, [p], n=8)
         assert got == ref
-        assert eng.recoveries == 1
+        assert eng.recoveries == 1 and eng.stats()["attn"]["path"] == "walk"
 
     def test_sliding_window(self):
         cfg = llama.Config.from_name("tiny-llama-debug", **MICRO, sliding_window=5)
@@ -239,6 +241,10 @@ _TOL = 8 * float(jnp.finfo(jnp.float32).eps)     # serving.kernel_check's bound
 
 
 class TestDecodeWalk:
+    @pytest.fixture(autouse=True)
+    def interpreted(self, monkeypatch):
+        set_attn_form(monkeypatch, "interpreted")
+
     @pytest.mark.parametrize("context", W_CONTEXTS)
     def test_block_and_chunk_edges(self, small_chunks, context):
         (k, v), tables, q, fk, fv = _walk_inputs(ng=2)
@@ -298,7 +304,8 @@ class TestDecodeWalk:
     def test_narrow_heads_go_block_by_block(self, monkeypatch, storage, ng):
         """Where the walk cannot be compiled (on the TPU: a head size that is
         not whole 128-lane tiles) the token rides as query 0 of a verify
-        chunk; the same attention, here interpreted."""
+        chunk; the same attention, here interpreted.  With a sliding window,
+        which that kernel has not, the entry takes its XLA form."""
         monkeypatch.setattr(px, "paged_walk_lanes_ok", lambda lanes: False)
         assert px.paged_kv_chunk_blocks(ng, W_BS, W_HS, 4) == 1
         (k, v), tables, q, fk, fv = _walk_inputs(ng=ng, B=3, seed=5)
@@ -310,8 +317,55 @@ class TestDecodeWalk:
             kd, vd = gather_dense_q(k, v, ks, vs, tables, jnp.float32)
         got = _decode(q, k, v, fk, fv, tables, pos, ks=ks, vs=vs)
         assert float(jnp.max(jnp.abs(got - _reference(q, kd, vd, fk, fv, pos)))) <= _TOL
-        with pytest.raises(NotImplementedError, match="sliding window"):
-            _decode(q, k, v, fk, fv, tables, pos, window=8, ks=ks, vs=vs)
+        assert px.paged_decode_path(W_HS, None) == "by_blocks" and px.paged_decode_path(W_HS, 8) == "xla"
+        jaxpr = jax.make_jaxpr(lambda *a: _decode(*a, tables, pos, window=8, ks=ks, vs=vs))(q, k, v, fk, fv)
+        assert "pallas_call" not in str(jaxpr)
+        pos[-1] -= 1    # the XLA form puts the fresh row in its slot of the table, as the writer will
+        got = _decode(q, k, v, fk, fv, tables, pos, window=8, ks=ks, vs=vs)
+        assert float(jnp.max(jnp.abs(got - _reference(q, kd, vd, fk, fv, pos, 8)))) <= _TOL
+
+    @pytest.mark.parametrize("window", [None, 10])
+    @pytest.mark.parametrize("storage,layout", [
+        ("bfloat16", "head_a_row"), ("bfloat16", "lane_packed"), ("bfloat16", "packed_out"),
+        ("int8", "head_a_row"),
+        pytest.param("fp8", "head_a_row", marks=pytest.mark.skipif(_FP8 is None, reason="no float8_e4m3fn"))])
+    def test_the_xla_form_is_the_kernel_and_the_reference(self, small_chunks, monkeypatch, storage, layout, window):
+        """``paged_attn_xla`` (what the entry takes where Pallas is off) against
+        the interpreted kernel and against ``gather_dense`` + the plain float32
+        reference, on the same bytes: bfloat16 and quantised arenas, with and
+        without the window, a head a row, two heads a row, and the rows whole
+        (``packed_out``); ragged positions, one row with no cached token."""
+        ng, B, P = 4, 4, 1 if layout == "head_a_row" else 2
+        keys = iter(jax.random.split(jax.random.PRNGKey(7), 8))
+        rnd = lambda *shape: jax.random.normal(next(keys), shape, jnp.float32).astype(jnp.bfloat16)
+        nb = 1 + B * W_NBB
+        k, v = rnd(nb, W_L, ng, W_BS, W_HS), rnd(nb, W_L, ng, W_BS, W_HS)
+        tables = (1 + jnp.arange(B * W_NBB, dtype=jnp.int32)).reshape(B, W_NBB)
+        q, fk, fv = rnd(B, ng * W_REP, W_HS), rnd(B, ng, W_HS), rnd(B, ng, W_HS)
+        pos = jnp.asarray([W_C * W_BS + 5, 0, W_NBB * W_BS - 1, W_BS], jnp.int32)
+        ks = vs = None
+        kd, vd = gather_dense(k, v, tables)
+        if storage != "bfloat16":
+            dt = jnp.int8 if storage == "int8" else _FP8
+            (k, ks), (v, vs) = quantize_kv(k, dt), quantize_kv(v, dt)
+            kd, vd = gather_dense_q(k, v, ks, vs, tables, jnp.bfloat16)
+        ref = _reference(q, kd, vd, fk, fv, pos, window)
+        if P > 1:   # two KV heads side by side in a row, as the pool lays them out
+            pack = lambda a: a.transpose(0, 1, 3, 2, 4).reshape(nb, W_L, W_BS, ng // P, P * W_HS).transpose(0, 1, 3, 2, 4)
+            k, v = pack(k), pack(v)
+        call = lambda: px.paged_attn_decode(q, k, v, fk, fv, tables, pos, layer=W_LAYER, window=window,
+                                            k_scale=ks, v_scale=vs, packed_out=layout == "packed_out")
+        kernel = call()
+        set_attn_form(monkeypatch, "xla")
+        assert px.paged_decode_path(k.shape[-1], window) == "xla"
+        xla = call()
+        assert "pallas_call" not in str(jax.make_jaxpr(call)())
+        tol = 8 * float(jnp.finfo(jnp.bfloat16).eps)            # serving.kernel_check's bound
+        assert xla.shape == kernel.shape and xla.dtype == kernel.dtype
+        assert float(jnp.max(jnp.abs(xla.astype(jnp.float32) - kernel.astype(jnp.float32)))) <= tol
+        if layout == "packed_out":  # a row's own head's lanes are the attention; the others its weights on the neighbour's values
+            xla = px._lane_packed_outputs(xla.reshape(B, ng // P, P * W_REP, P * W_HS), P).reshape(B, ng * W_REP, W_HS)
+        assert float(jnp.max(jnp.abs(xla.astype(jnp.float32) - ref))) <= tol
 
     def test_chunk_follows_the_shapes_not_the_table(self):
         # offline-batch's shapes: 8 groups of 16 x 128 bfloat16 -> 16 blocks,
@@ -328,15 +382,15 @@ class TestDecodeWalk:
 
 
 class TestSinkBlockHygiene:
-    @pytest.mark.parametrize("attn", ["gather", "paged"])
-    def test_tokens_invariant_to_block0_garbage(self, micro, attn):
-        """Block 0 backs every table's padding; neither decode path may
-        ever read it into scores.  Poison it mid-run: tokens unchanged."""
+    def test_tokens_invariant_to_block0_garbage(self, micro, attn_form):
+        """Block 0 backs every table's padding; neither form of the decode
+        program's attention may ever read it into scores.  Poison it mid-run:
+        tokens unchanged."""
         cfg, params = micro
         prompts = _prompts(cfg, lens=(3, 7))
-        ref = _drive(_engine(cfg, params, attn=attn, max_batch=2), prompts, n=6)
+        ref = _drive(_engine(cfg, params, max_batch=2), prompts, n=6)
 
-        eng = _engine(cfg, params, attn=attn, max_batch=2, async_step=False)
+        eng = _engine(cfg, params, max_batch=2, async_step=False)
         handles = [eng.submit(p, max_new_tokens=6) for p in prompts]
         for _ in range(3):
             eng.step()                                # past prefill, mid-decode
@@ -352,23 +406,6 @@ class TestSinkBlockHygiene:
 #
 # structural: the paged decode program really is gather/scatter-free
 #
-
-
-def _prim_names(jaxpr, *, skip=("pallas_call",)):
-    """All primitive names in a jaxpr, recursing into sub-jaxprs (pjit,
-    custom_vjp, scan, ...) but not into pallas kernel bodies."""
-    names = []
-    for eqn in jaxpr.eqns:
-        names.append((eqn.primitive.name, eqn))
-        if eqn.primitive.name in skip:
-            continue
-        for v in eqn.params.values():
-            sub = getattr(v, "jaxpr", None)
-            if sub is not None and hasattr(sub, "eqns"):
-                names.extend(_prim_names(sub, skip=skip))
-            elif hasattr(v, "eqns"):
-                names.extend(_prim_names(v, skip=skip))
-    return names
 
 
 def _decode_args(eng, Bb, nbb):
@@ -388,107 +425,97 @@ def _decode_args(eng, Bb, nbb):
 
 def _census(eng, kind, Bb=4, nbb=4):
     prog, _ = eng._program(kind, Bb, nbb)
-    jaxpr = jax.make_jaxpr(prog)(*_decode_args(eng, Bb, nbb)).jaxpr
-    arena_shapes = {tuple(a.shape) for a in jax.tree_util.tree_leaves(eng.pool.arenas)}
-    arena_gathers = scatters = 0
-    for name, eqn in _prim_names(jaxpr):
-        if name == "gather" and tuple(eqn.invars[0].aval.shape) in arena_shapes:
-            arena_gathers += 1
-        if name.startswith("scatter"):
-            scatters += 1
-    return arena_gathers, scatters
+    return arena_census(eng.pool.arenas, jax.make_jaxpr(prog)(*_decode_args(eng, Bb, nbb)).jaxpr)
 
 
 class TestProgramPurity:
-    def test_paged_decode_has_zero_arena_gathers_and_scatters(self, micro):
+    def test_paged_decode_has_zero_arena_gathers_and_scatters(self, micro, monkeypatch):
         cfg, params = micro
-        eng = _engine(cfg, params, attn="paged")
-        assert _census(eng, "decode_paged") == (0, 0)
+        set_attn_form(monkeypatch, "interpreted")
+        assert _census(_engine(cfg, params), "decode_paged") == (0, 0)
 
-    def test_gather_decode_is_the_positive_control(self, micro):
-        """The same census on the gather program finds both op families —
-        proving the walk actually sees through pjit into the program."""
+    def test_gather_decode_is_the_positive_control(self, micro, monkeypatch):
+        """The same census on the program built with Pallas off (the kernel's
+        XLA form gathers a layer's rows and puts the fresh one among them)
+        finds both op families — proving the walk actually sees through pjit
+        into the program."""
         cfg, params = micro
-        eng = _engine(cfg, params, attn="gather")
-        arena_gathers, scatters = _census(eng, "decode")
+        set_attn_form(monkeypatch, "xla")
+        arena_gathers, scatters = _census(_engine(cfg, params), "decode_paged")
         assert arena_gathers > 0 and scatters > 0
 
-    def test_quantized_paged_program_is_pure_too(self, micro):
+    def test_quantized_paged_program_is_pure_too(self, micro, monkeypatch):
         cfg, params = micro
-        eng = _engine(cfg, params, attn="paged", kv_dtype="int8")
-        assert _census(eng, "decode_paged") == (0, 0)
+        set_attn_form(monkeypatch, "interpreted")
+        assert _census(_engine(cfg, params, kv_dtype="int8"), "decode_paged") == (0, 0)
 
 
 #
-# knob resolution + observability
+# the entry's own choice + observability
 #
 
 
-class TestAttnKnob:
-    def test_paged_stats_counters_and_census(self, micro):
+TEN_KINDS = {"prefill", "prefill_fresh", "prefill_chunk", "prefill_chunk_paged", "decode_paged",
+             "decode_multi_paged", "spec_prefill", "spec_prefill_chunk", "draft_decode", "verify_paged"}
+
+
+class TestEntryChoice:
+    def test_kernel_steps_count_no_fallback(self, micro, monkeypatch):
         cfg, params = micro
-        eng = _engine(cfg, params, attn="paged")
+        set_attn_form(monkeypatch, "interpreted")
+        eng = _engine(cfg, params)
         _drive(eng, _prompts(cfg, lens=(3, 5)), n=4)
-        st = eng.stats()["attn"]
-        assert st["mode"] == "paged" and st["requested"] == "paged"
-        assert st["fallback_reason"] is None
-        assert st["kernel_steps"] > 0 and st["fallback_steps"] == 0
+        st = eng.stats()
+        assert st["attn"]["path"] == "walk" and st["attn"]["fallback_steps"] == 0 and st["decode_steps"] > 0
         # what walk the run measured: at these tiny widths the cap of 512
         # keys a chunk binds, not the byte budget
-        assert st["kv_chunk_tokens"] == 512
-        # the module program cache may satisfy this engine's decode_paged
-        # program from an earlier engine; the census key exists either way
-        assert "decode_paged" in eng.compile_counts
-        assert eng.compile_counts["decode"] == 0
-        snap = tt.metrics_snapshot()
-        assert snap["serving.attn.kernel_steps"] == st["kernel_steps"]
+        assert st["attn"]["kv_chunk_tokens"] == 512
+        # one decode program a job: no gather twin among the kinds
+        assert set(eng.compile_counts) == TEN_KINDS
+        assert tt.metrics_snapshot().get("serving.attn.fallback_steps", 0) == 0
 
-    def test_gather_mode_counts_nothing(self, micro):
+    def test_the_stats_keys_the_benchmark_reads(self, micro):
         cfg, params = micro
-        eng = _engine(cfg, params, attn="gather")
+        st = _engine(cfg, params).stats()["attn"]
+        assert {"fallback_steps", "path", "lane_pack", "kv_chunk_tokens"} <= set(st)
+        assert "mode" not in st and "requested" not in st and "kinds" not in st
+
+    def test_pallas_off_counts_every_decode_step(self, micro, monkeypatch):
+        """Without THUNDER_TPU_PALLAS_INTERPRET=1 a CPU engine builds the same
+        ``decode_paged`` program with the kernel's XLA form in it, and counts
+        every decode step as a fallback step: a cell that lost its kernel on
+        the chip would read the same, and not be ``correct``."""
+        set_attn_form(monkeypatch, "xla")
+        cfg, params = micro
+        eng = _engine(cfg, params)
         _drive(eng, _prompts(cfg, lens=(3,)), n=4)
-        st = eng.stats()["attn"]
-        assert st["mode"] == "gather" and st["requested"] == "gather"
-        assert st["kernel_steps"] == 0 and st["fallback_steps"] == 0
-        assert st["fallback_reason"] is None and st["kv_chunk_tokens"] is None
+        st = eng.stats()
+        assert st["attn"]["path"] == "xla" and "decode_paged" in st["compile_counts"]
+        assert st["attn"]["fallback_steps"] == st["decode_steps"] > 0
+        assert tt.metrics_snapshot()["serving.attn.fallback_steps"] == st["attn"]["fallback_steps"]
 
-    def test_auto_falls_back_on_cpu_and_counts(self, micro, monkeypatch):
-        """Without THUNDER_TPU_PALLAS_INTERPRET=1, auto on CPU keeps the
-        gather path (tier-1 speed) and counts every decode as a fallback."""
-        monkeypatch.delenv("THUNDER_TPU_PALLAS_INTERPRET", raising=False)
+    def test_a_custom_model_fn_is_refused(self, micro):
         cfg, params = micro
-        eng = _engine(cfg, params, attn="auto")
-        _drive(eng, _prompts(cfg, lens=(3,)), n=4)
-        st = eng.stats()["attn"]
-        assert st["mode"] == "gather" and st["requested"] == "auto"
-        assert st["fallback_reason"]
-        assert st["fallback_steps"] > 0
-        assert tt.metrics_snapshot()["serving.attn.fallback_steps"] == st["fallback_steps"]
-
-    def test_forced_paged_rejects_custom_model_fn(self, micro):
-        cfg, params = micro
-        with pytest.raises(ValueError, match="custom model_fn"):
+        with pytest.raises(NotImplementedError, match="llama.Config"):
             tt.serve(lambda *a, **k: None, params, cfg, block_size=4,
-                     num_blocks=16, max_batch=2, cache_dtype=jnp.float32,
-                     attn="paged")
+                     num_blocks=16, max_batch=2, cache_dtype=jnp.float32)
 
-    def test_invalid_knob_value(self, micro):
+    def test_the_attn_option_is_gone(self, micro):
         cfg, params = micro
-        with pytest.raises(ValueError, match="attn="):
-            _engine(cfg, params, attn="fancy")
+        with pytest.raises(TypeError, match="attn"):
+            _engine(cfg, params, attn="paged")
 
-    @pytest.mark.parametrize("attn", ["auto", "paged"])
+    @pytest.mark.parametrize("what", ["entry", "engine"])
     @pytest.mark.parametrize("hs", [64, 96])
-    def test_narrow_windowed_heads_take_the_gather_path_on_tpu(self, hs, attn, monkeypatch):
+    def test_narrow_windowed_heads_take_the_xla_form_on_tpu(self, hs, what, monkeypatch):
         """Compiled for the TPU the decode walk cannot copy arena slabs whose
         rows are not whole 128-lane tiles (test_pallas_tpu_lowering holds the
         compiler to that), and the per-block kernel that serves such arenas has
-        no sliding window: a model with both resolves to the gather path when
-        the engine is built, with a counted reason, and an explicit
-        attn="paged" is refused there, not at the first decode step.  A head
-        of 96 is such an arena always; a head of 64 where its KV heads cannot
-        lie two to a row: a quantised arena here (at the compute dtype the pool
-        packs them, and the walk takes the window)."""
+        no sliding window: for a model with both the entry takes its XLA form,
+        the engine says so when it is built (``path`` "xla") and counts the
+        steps.  A head of 96 is such an arena always; a head of 64 where its KV
+        heads cannot lie two to a row: a quantised arena here (at the compute
+        dtype the pool packs them, and the walk takes the window)."""
         def cfg_of(**kw):
             return llama.Config.from_name("tiny-llama-debug", **{
                 **MICRO, "n_head": 2, "n_query_groups": 2, "n_embd": 2 * hs, **kw})
@@ -497,30 +524,39 @@ class TestAttnKnob:
         assert cfg.head_size == hs
         store = {"kv_dtype": "int8"} if hs == 64 else {}
         monkeypatch.setattr(px, "_interpret", lambda: False)   # as on the chip
-        ok, why = paged_supported(cfg, True, arena_lanes=hs)
-        assert not ok and f"head_size={hs}" in why and "window" in why
-        assert paged_supported(cfg, True)[0] == (hs == 64)            # two heads of 64 a row: walked, window and all
-        assert paged_supported(cfg_of(), True) == (True, "")           # no window
-        assert paged_supported(cfg_of(n_embd=256, sliding_window=8), True) == (True, "")
-        params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-        if attn == "paged":
-            with pytest.raises(ValueError, match=f"head_size={hs}"):
-                _engine(cfg, params, attn=attn, **store)
-            return
         monkeypatch.setattr(px, "_pallas_available", lambda: True)
-        st = _engine(cfg, params, attn=attn, **store).stats()["attn"]
-        assert st["mode"] == "gather" and f"head_size={hs}" in st["fallback_reason"]
-        assert st["kv_chunk_tokens"] is None and st["path"] is None
+        if what == "entry":
+            assert decode_path(cfg, arena_lanes=hs) == "xla"
+            assert decode_path(cfg) == ("walk" if hs == 64 else "xla")   # two heads of 64 a row: walked, window and all
+            assert decode_path(cfg_of()) == ("walk" if hs == 64 else "by_blocks")        # no window
+            assert decode_path(cfg_of(), arena_lanes=hs) == "by_blocks"
+            assert decode_path(cfg_of(n_embd=256, sliding_window=8)) == "walk"
+            # and the entry does what the path says: no kernel in its trace
+            (k, v), tables, q, fk, fv = _walk_inputs(ng=2)
+            jaxpr = jax.make_jaxpr(lambda *a: _decode(*a, tables, [9], window=8))(q, k, v, fk, fv)
+            assert px.paged_decode_path(W_HS, 8) == "xla" and "pallas_call" not in str(jaxpr)
+            return
+        params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+        st = _engine(cfg, params, **store).stats()["attn"]
+        assert st["path"] == "xla" and st["kv_chunk_tokens"] == 4
         # without the window the same heads stay on the kernels, a block a step
-        st = _engine(cfg_of(), params, attn=attn, **store).stats()["attn"]
-        assert st["mode"] == "paged" and st["kv_chunk_tokens"] == 4 and st["path"] == "by_blocks"
+        st = _engine(cfg_of(), params, **store).stats()["attn"]
+        assert st["kv_chunk_tokens"] == 4 and st["path"] == "by_blocks"
         if hs == 64:    # and at the compute dtype on the walk, in packed rows, with the window
-            st = _engine(cfg, params, attn=attn).stats()["attn"]
-            assert (st["mode"], st["path"], st["lane_pack"]) == ("paged", "walk", 2)
+            st = _engine(cfg, params).stats()["attn"]
+            assert (st["path"], st["lane_pack"]) == ("walk", 2)
 
-    def test_paged_supported_reasons(self, micro):
+    def test_decode_path_follows_the_backend_and_the_mesh(self, micro, monkeypatch):
+        from jax.sharding import Mesh
+
         cfg, _ = micro
-        ok, why = paged_supported(cfg, True)
-        assert ok and why == ""
-        ok, why = paged_supported(cfg, False)
-        assert not ok and "model_fn" in why
+        set_attn_form(monkeypatch, "interpreted")
+        assert decode_path(cfg) == "walk"
+        dp = Mesh(np.asarray(jax.devices()[:2]), ("dp",))
+        tp = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+        assert decode_path(cfg, dp) == "xla"                      # no tp axis: the arena is replicated
+        assert decode_path(cfg, tp) == "walk"
+        odd = llama.Config.from_name("tiny-llama-debug", **{**MICRO, "n_head": 3, "n_query_groups": 1, "n_embd": 24})
+        assert decode_path(odd, tp) == "xla"                      # heads that tp does not split
+        set_attn_form(monkeypatch, "xla")
+        assert decode_path(cfg) == "xla" and decode_path(cfg, tp) == "xla"

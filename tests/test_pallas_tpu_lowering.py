@@ -67,7 +67,10 @@ def tpu_sharding():
 
 @pytest.fixture(autouse=True)
 def compiled_not_interpreted(monkeypatch):
+    """As on the chip: Pallas on (the paged attention entries take their kernels
+    and not the XLA form) and nothing interpreted."""
     monkeypatch.setattr(px, "_interpret", lambda: False)
+    monkeypatch.setattr(px, "_pallas_available", lambda: True)
 
 
 def _cases(nh: int, ng: int) -> dict:
@@ -418,8 +421,8 @@ def test_narrow_heads_decode_block_by_block(shape, hs, store, tpu_sharding, monk
     copies cannot be compiled (Mosaic holds the arena padded to 128 lanes and
     refuses the narrower slice), so ``paged_attn_decode`` sends the token
     through ``paged_attn_verify``'s per-block grid, which compiles; with a
-    sliding window it raises before any lowering (``paged_supported`` keeps
-    such a model off the kernels).  If the last check fails because Mosaic
+    sliding window, which that kernel has not, it takes its XLA form (no
+    kernel in the lowering).  If the last check fails because Mosaic
     now compiles the walk, drop ``paged_walk_lanes_ok``.  (A head of 64 in a
     lane-packed arena is another arena: rows of 128 lanes, which the walk takes:
     ``test_the_narrow_head_cells_kernels_compile_at_its_shapes``.)"""
@@ -427,8 +430,8 @@ def test_narrow_heads_decode_block_by_block(shape, hs, store, tpu_sharding, monk
     args = _decode_args(nh, ng, hs, BS, B, NBB, NB, L, store, tpu_sharding)
     assert not px.paged_walk_lanes_ok(hs) and px.paged_walk_lanes_ok(128)
     assert px.paged_head_size_ok(64) and not px.paged_head_size_ok(96)      # 64 divides a lane tile: packable
-    with pytest.raises(NotImplementedError, match="sliding window"):
-        jax.jit(_decode(1, window=24)).trace(*args)
+    assert px.paged_decode_path(hs, 24) == "xla" and px.paged_decode_path(hs) == "by_blocks"
+    assert "tpu_custom_call" not in jax.jit(_decode(1, window=24)).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
     lowered = jax.jit(_decode(1)).trace(*args).lower(lowering_platforms=("tpu",))
     name = "paged_attn_verify" + ("_quant" if store != BF else "")
     assert f'kernel_name = "{name}"' in lowered.as_text()
@@ -438,6 +441,37 @@ def test_narrow_heads_decode_block_by_block(shape, hs, store, tpu_sharding, monk
     monkeypatch.setattr(px, "paged_walk_lanes_ok", lambda lanes: True)
     with pytest.raises(Exception, match=r"must be aligned to tiling \(128\)"):
         jax.jit(_decode(1)).trace(*args).lower(lowering_platforms=("tpu",)).compile()
+
+
+@pytest.mark.parametrize("hs,store", [(64, "int8"), (96, None)], ids=["64_unpacked", "96"])
+def test_a_windowed_narrow_heads_decode_program_lowers_with_the_xla_form(hs, store, tpu_sharding):
+    """The whole ``decode_paged`` program of a windowed model whose arena rows
+    the walk cannot fetch (heads of 64 a row each, as a quantised arena keeps
+    them, and heads of 96): the engine says "xla" when it is built, and the
+    program lowers for the TPU with the attention gathered in XLA and the
+    token writers still kernels (before PR 44: ``NotImplementedError`` from
+    the entry, kept off it by a second decode program)."""
+    import thunder_tpu as tt
+    from thunder_tpu.models import llama
+
+    cfg = llama.Config(name=f"windowed-{hs}", n_layer=2, n_head=4, n_query_groups=2, n_embd=4 * hs, head_size=hs,
+                       intermediate_size=256, vocab_size=512, block_size=512, sliding_window=64)
+    params = jax.eval_shape(lambda: llama.init_params(cfg, dtype=BF))
+    eng = tt.serve(None, params, cfg, num_blocks=64, block_size=BS, max_batch=4, **({"kv_dtype": store} if store else {}))
+    st = eng.stats()["attn"]
+    assert st["path"] == "xla" and st["lane_pack"] == 1 and eng.pool.k_arena.shape[-1] == hs
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=tpu_sharding)  # noqa: E731
+    one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
+    prog = eng._build_decode_paged(4, 16)
+    lowered = prog.trace(weights, one((4,)), one((4,)), one((4, 16)), arenas, one((4, 2), jnp.uint32), {}, one((4,))
+                         ).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert "paged_attn_decode" not in text and "paged_attn_verify" not in text
+    assert f'kernel_name = "paged_token_write{"_fused" if store else ""}"' in text
+    assert re.search(r"gather", text)
+    if tpu_sharding is not None:
+        lowered.compile()
 
 
 # --------------------------------------------------------------------------
@@ -731,7 +765,7 @@ def test_the_narrow_head_cells_programs_lower_to_their_kernels(kind, tpu_shardin
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     cfg, params, eng = _lfm2_engine()
     st = eng.stats()
-    assert st["attn"]["mode"] == "paged" and st["attn"]["path"] == "walk" and st["attn"]["lane_pack"] == 2
+    assert st["attn"]["path"] == "walk" and st["attn"]["lane_pack"] == 2
     assert eng.pool.k_arena.shape == (700, 1, 4, 16, 128) and eng.pool.state.conv.shape == (257, 3, 2, 2048)
     occ = st["pool_occupancy"]
     assert occ["token_bytes_counted"] == occ["token_bytes_laid_out"] == 2 * 8 * 64 * 2       # one attention layer here
@@ -925,7 +959,7 @@ def test_the_hybrid_decoder_cells_programs_lower_to_their_kernels(kind, tpu_shar
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     cfg, params, eng = _flash_engine()
     st = eng.stats()
-    assert st["attn"]["mode"] == "paged" and st["attn"]["path"] == "walk" and st["attn"]["lane_pack"] == 2
+    assert st["attn"]["path"] == "walk" and st["attn"]["lane_pack"] == 2
     assert st["attn"]["shared_kv_layers"] == 2
     state = eng.pool.state
     assert eng.pool.k_arena.shape == (700, 1, 10, 16, 128) and state.ring_blocks == 33

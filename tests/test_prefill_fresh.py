@@ -74,7 +74,7 @@ WHOLE = {
     "int8_kv_pool": dict(engine=dict(kv_dtype="int8")),
     "constrained": dict(constrained=True),
     "lora_slot": dict(lora=True),
-    "paged_decode_kernels": dict(engine=dict(attn="paged"), interpret=True),
+    "paged_decode_kernels": dict(interpret=True),
 }
 
 
@@ -105,7 +105,7 @@ def test_a_whole_prompt_takes_prefill_fresh_and_is_bit_identical_to_solo(case, m
     assert st["prefill_fresh_runs"] == st["prefill_runs"] == 1 and st["chunk_runs"] == 0
     assert _kinds(eng) == {"prefill_fresh"}
     if spec.get("interpret"):          # a fresh prefill is no attention fallback step
-        assert st["attn"]["mode"] == "paged" and st["attn"]["fallback_steps"] == 0 and st["attn"]["kernel_steps"] == 5
+        assert st["attn"]["path"] == "walk" and st["attn"]["fallback_steps"] == 0 and st["decode_steps"] == 5
     solo_kw = {} if T_max is None else {"T_max": T_max}
     assert np.array_equal(np.asarray(got.new_tokens), _solo(cfg, params, prompt, 6, **solo_kw))
 
@@ -312,23 +312,31 @@ def test_under_a_tp_mesh_the_prompt_keeps_the_einsum_form(interpreted):
 
 
 # --------------------------------------------------------------------------
-# a custom forward, written to the signature the engine always called
+# the forward is the in-tree one: a model is a llama.Config
 # --------------------------------------------------------------------------
 
 @pytest.mark.parametrize("piece", ["a_whole_prompt", "behind_a_shared_prefix"])
-def test_a_custom_model_fn_is_called_as_before_and_its_full_logits_indexed(piece):
-    """``tt.serve(model_fn, ...)``: a forward that knows nothing of
-    ``logits_at`` returns (B, T, V) and the engine takes row ``n_real - 1``."""
+def test_the_in_tree_forward_serves_both_pieces_and_a_model_fn_is_refused(piece, monkeypatch):
+    """The two pieces a custom ``model_fn`` used to be called for, served by the
+    in-tree forward, which projects row ``n_real - 1`` alone: static position 0
+    for a whole prompt, a traced one behind a shared prefix.  ``tt.serve(fn,
+    ...)`` itself refuses, with what to do instead."""
     cfg, params = _model(n_query_groups=2)
     seen = []
+    real = gen.forward_with_cache
 
-    def model_fn(params, idx, pos, cache, cos_all, sin_all, cfg, *, quantized=False, lora=None, lora_scaling=1.0):
-        logits, cache = gen.forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg,
-                                               quantized=quantized, lora=lora, lora_scaling=lora_scaling)
+    def spy(params, idx, pos, cache, cos_all, sin_all, cfg, **kw):
+        logits, cache = real(params, idx, pos, cache, cos_all, sin_all, cfg, **kw)
         seen.append((idx.shape[1], logits.shape[1], isinstance(pos, int)))
         return logits, cache
 
-    eng = tt.serve(model_fn, params, cfg, block_size=4, num_blocks=40, max_batch=2, cache_dtype=jnp.float32, **BUCKETS)
+    with pytest.raises(NotImplementedError, match="llama.Config"):
+        tt.serve(spy, params, cfg, block_size=4, num_blocks=40, max_batch=2, cache_dtype=jnp.float32, **BUCKETS)
+    import thunder_tpu.serving.engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "forward_with_cache", spy)
+    monkeypatch.setattr(engine_mod, "_program_cache", {})       # the programs are traced here, through the spy
+    eng = tt.serve(None, params, cfg, block_size=4, num_blocks=40, max_batch=2, cache_dtype=jnp.float32, **BUCKETS)
     prompt = _prompt(cfg, 13, seed=17)
     if piece == "behind_a_shared_prefix":
         eng.submit(prompt, max_new_tokens=8)
@@ -336,7 +344,6 @@ def test_a_custom_model_fn_is_called_as_before_and_its_full_logits_indexed(piece
         prompt = np.concatenate([prompt[:8], _prompt(cfg, 5, seed=18)])
     got = eng.submit(prompt, max_new_tokens=5).result()
     assert np.array_equal(np.asarray(got.new_tokens), _solo(cfg, params, prompt, 5))
-    prefills = [s for s in seen if s[0] > 1]
-    assert all(T == rows for T, rows, _ in prefills)               # every row projected: the model's own business
-    assert [static for _, _, static in prefills] == ([True] if piece == "a_whole_prompt" else [True, False])
+    assert all(T > 1 and rows == 1 for T, rows, _ in seen)         # a prompt's pieces alone, one row projected
+    assert [static for _, _, static in seen] == ([True] if piece == "a_whole_prompt" else [True, False])
     eng.shutdown()

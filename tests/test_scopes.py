@@ -260,8 +260,7 @@ def _serve_program(kind: str, program: str, *, tpu: bool = False):
     params = jax.eval_shape(make, common.seed_words(5))
     # shapes of its own for the TPU's lowering: the jitted kernel wrappers keep what they traced under the interpreter
     bs, Tb, Bb, nbb = (16, 256, 4, 32) if tpu else (16, 128, 4, 16)
-    eng = tt.serve(None, params, cfg, num_blocks=40, block_size=bs, max_batch=Bb, prefill_buckets=(Tb,),
-                   attn="auto" if tpu else "paged")
+    eng = tt.serve(None, params, cfg, num_blocks=40, block_size=bs, max_batch=Bb, prefill_buckets=(Tb,))
     sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
     one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt)  # noqa: E731
     weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)

@@ -350,7 +350,7 @@ def _hybrid(monkeypatch):
 
     monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
     cfg, params = tiny_model()
-    return cfg, params, dict(attn="paged", max_batch=3, num_blocks=40, block_size=16, prefill_buckets=[32, 64],
+    return cfg, params, dict(max_batch=3, num_blocks=40, block_size=16, prefill_buckets=[32, 64],
                              batch_buckets=[4], block_buckets=[8])
 
 

@@ -176,14 +176,12 @@ class TestConstrainedServing:
         assert set(h2.result(drive=False).new_tokens) == {3}
         eng.shutdown()
 
-    @pytest.mark.parametrize("attn", ["gather", "paged"])
-    def test_multistep_masks_per_scan_step(self, micro, attn):
+    def test_multistep_masks_per_scan_step(self, micro, attn_form):
         """decode_steps=N: one mask per scan step, shipped as scan xs —
         the emitted stream follows the automaton step-for-step."""
         cfg, params = micro
         V = cfg.padded_vocab_size
-        eng = _engine(cfg, params, constraints=True, decode_steps=3,
-                      attn=attn)
+        eng = _engine(cfg, params, constraints=True, decode_steps=3)
         c = sequence_constraint(V, [[3], [5, 6], [7]])
         r = eng.submit(_prompt(5, 7, cfg), max_new_tokens=5,
                        constraint=c).result()

@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 import thunder_tpu as tt
+from conftest import set_attn_form
 from thunder_tpu.models import llama
 from thunder_tpu.serving import (
     AdapterRegistry,
@@ -125,11 +126,11 @@ def _(micro):
     counts = st["compile_counts"]
     # every prompt here is whole and at position 0: the fresh kind, which reads
     # no table and so is built once a prefill bucket, whatever the table's width
-    assert counts["prefill_fresh"] >= 1 and counts["prefill"] == 0 and counts["decode"] >= 1
+    assert counts["prefill_fresh"] >= 1 and counts["prefill"] == 0 and counts["decode_paged"] >= 1
     assert st["prefill_fresh_runs"] == st["prefill_runs"] == 6
     assert sum(counts.values()) <= st["bucket_bound"]
     widths = len(eng._table_widths)
-    assert counts["decode"] <= len(eng.scheduler.batch_buckets) * widths
+    assert counts["decode_paged"] <= len(eng.scheduler.batch_buckets) * widths
     assert counts["prefill_fresh"] <= len(eng.scheduler.prefill_buckets)
 
 
@@ -360,17 +361,27 @@ def _(micro):
 #
 
 
-PAGED = dict(attn="paged", prefill_chunk=8, prefill_buckets=(8, 16), block_buckets=(12,),
-             num_blocks=64)
+PAGED = dict(prefill_chunk=8, prefill_buckets=(8, 16), block_buckets=(12,), num_blocks=64)
+
+
+def _with_kernels(fn):
+    """``fn()`` while the paged programs are built with their kernels (under the
+    interpreter) and not the XLA forms."""
+    with pytest.MonkeyPatch.context() as env:
+        set_attn_form(env, "interpreted")
+        return fn()
 
 
 @functools.cache
 def _ragged():
-    """One long row among three short ones through the paged engine."""
+    """One long row among three short ones through the engine with the kernels in its programs."""
     cfg, params = _model()
     reqs = _reqs(cfg, (3, 3, 3, 40), 5, seed=80)
-    eng = _engine(cfg, params, goodput=True, **PAGED)
-    return reqs, eng, eng.run([dict(r) for r in reqs])
+
+    def serve():
+        eng = _engine(cfg, params, goodput=True, **PAGED)
+        return reqs, eng, eng.run([dict(r) for r in reqs])
+    return _with_kernels(serve)
 
 
 @case("ragged-blocks_walked")
@@ -391,21 +402,20 @@ def _(micro):
     serves the same tokens."""
     cfg, params = micro
     reqs, eng, res = _ragged()
-    warm = _engine(cfg, params, goodput=True, **PAGED)
-    res_w = warm.run([dict(r) for r in reqs])
-    kinds = warm.stats()["attn"]["kinds"]
-    assert kinds["decode"]["mode"] == kinds["prefill_chunk"]["mode"] == "paged"
-    assert kinds["prefill_chunk"]["kernel_steps"] > 0
+    warm = _with_kernels(lambda: _engine(cfg, params, goodput=True, **PAGED))
+    res_w = _with_kernels(lambda: warm.run([dict(r) for r in reqs]))
+    st = warm.stats()
+    assert st["attn"]["path"] == "walk" and st["attn"]["chunk"] == "paged" and st["chunk_runs"] > 0
     assert sum(warm.compile_counts.values()) == 0
     assert _tokens(res_w) == _tokens(res)
 
 
 @case("ragged-compiles_within_bucket_bound")
 def _(micro):
-    """The paged kinds replace their gather twins, they do not add to them."""
+    """A job has one decode kind and one chunk kind."""
     cfg, params = _model(vocab_size=56)
-    eng = _engine(cfg, params, **PAGED)
-    eng.run(_reqs(cfg, (3, 5, 21, 40), 4, seed=90))
+    eng = _with_kernels(lambda: _engine(cfg, params, **PAGED))
+    _with_kernels(lambda: eng.run(_reqs(cfg, (3, 5, 21, 40), 4, seed=90)))
     st = eng.stats()
     counts = {k: v for k, v in st["compile_counts"].items() if v}
     assert set(counts) <= {"prefill", "prefill_fresh", "prefill_chunk_paged", "decode_paged"}, counts
@@ -417,7 +427,7 @@ def _(micro):
     """Every decode dispatch of the paged engine went through the kernel."""
     _, eng, _ = _ragged()
     st = eng.stats()
-    assert st["attn"]["kinds"]["decode"]["kernel_steps"] == st["decode_steps"] > 0
+    assert st["attn"]["path"] == "walk" and st["decode_steps"] > 0
     assert st["attn"]["fallback_steps"] == 0
 
 
@@ -479,9 +489,9 @@ def _(micro):
     assert len(long_lanes) == 1 and st["router"]["affinity_hits"] >= 2
     (long_lane,) = long_lanes
     short_eng = fleet.engines[1 - long_lane]
-    widths = {k[2] for k in short_eng._programs if k[0] == "decode"}
+    widths = {k[2] for k in short_eng._programs if k[0] == "decode_paged"}
     assert widths == {4}, widths
-    wide = {k[2] for k in fleet.engines[long_lane]._programs if k[0] == "decode"}
+    wide = {k[2] for k in fleet.engines[long_lane]._programs if k[0] == "decode_paged"}
     assert 16 in wide
 
 
@@ -534,7 +544,7 @@ def _(micro):
     reqs, _, res = _speculated()
     second = _spec_engine()
     res2 = second.run([dict(r) for r in reqs])
-    assert {"draft_decode", "verify", "spec_prefill"} <= set(second.compile_counts)
+    assert {"draft_decode", "verify_paged", "spec_prefill"} <= set(second.compile_counts)
     assert sum(second.compile_counts.values()) == 0
     assert not any(r.prefill_compiled for r in res2)
     assert _tokens(res2) == _tokens(res)

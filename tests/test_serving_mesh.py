@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import thunder_tpu as tt
+from conftest import set_attn_form
 from thunder_tpu import distributed as dist
 from thunder_tpu.models import generate as gen
 from thunder_tpu.models import llama
@@ -219,7 +220,7 @@ class TestMeshEngine:
         # solo engines ignore the mesh component entirely
         solo = tt.serve(None, params, cfg, block_size=4, num_blocks=32,
                         cache_dtype=jnp.float32)
-        assert solo._static_key()[-1] is None
+        assert solo._mesh_key is None and None in solo._static_key()
 
     def test_mesh_observability(self, mesh_served):
         """stats()['mesh'], the flight-state snapshot, and serving.mesh.*
@@ -270,9 +271,9 @@ class TestMeshEngine:
 
 
 class TestMeshPagedAttention:
-    """attn="paged" under SPMD (ISSUE 13): the kernels run shard_map-local
-    over tp with heads-local specs matching kv_cache_spec, and mesh-served
-    tokens stay identical to the gather path."""
+    """The paged kernels under SPMD (ISSUE 13): they run shard_map-local over
+    tp with heads-local specs matching kv_cache_spec (here interpreted), and
+    mesh-served tokens stay identical to the XLA form's."""
 
     def _drive(self, cfg, params, mesh, **kw):
         eng = _engine(cfg, params, mesh, max_batch=2, **kw)
@@ -282,14 +283,17 @@ class TestMeshPagedAttention:
         eng.drain()
         return [tuple(h.result(drive=False).tokens) for h in hs], eng
 
-    def test_paged_parity_on_mesh(self, micro, tp2):
+    def test_paged_parity_on_mesh(self, micro, tp2, monkeypatch):
         cfg, params = micro
         mesh, _ = tp2
-        tg, _ = self._drive(cfg, params, mesh, attn="gather")
-        tp_, eng = self._drive(cfg, params, mesh, attn="paged")
+        set_attn_form(monkeypatch, "xla")
+        tg, eng = self._drive(cfg, params, mesh)
+        assert eng.stats()["attn"]["path"] == "xla" and eng.stats()["attn"]["fallback_steps"] > 0
+        set_attn_form(monkeypatch, "interpreted")
+        tp_, eng = self._drive(cfg, params, mesh)
         assert tg == tp_
-        st = eng.stats()["attn"]
-        assert st["mode"] == "paged" and st["kernel_steps"] > 0
+        st = eng.stats()
+        assert st["attn"]["path"] == "walk" and st["attn"]["fallback_steps"] == 0 and st["decode_steps"] > 0
 
     def test_kv_chunk_tokens_follow_the_local_arena(self, micro, tp2, monkeypatch):
         """stats()["attn"]["kv_chunk_tokens"] is derived from the arena shard
@@ -299,29 +303,39 @@ class TestMeshPagedAttention:
 
         cfg, params = micro
         mesh, _ = tp2
-        one = _engine(cfg, params, None, max_batch=2, attn="paged")
+        set_attn_form(monkeypatch, "interpreted")
+        one = _engine(cfg, params, None, max_batch=2)
         _, _, ng, bs, hs = one.pool.k_arena.shape
         monkeypatch.setattr(px, "_PAGED_CHUNK_BYTES", 4 * (2 * 2 * ng * bs * hs * 4))
         for m, blocks in ((None, 4), (mesh, 8)):
-            eng = _engine(cfg, params, m, max_batch=2, attn="paged")
+            eng = _engine(cfg, params, m, max_batch=2)
             assert eng.stats()["attn"]["kv_chunk_tokens"] == blocks * bs
 
-    def test_paged_int8_parity_on_mesh(self, micro, tp2):
+    def test_paged_int8_parity_on_mesh(self, micro, tp2, monkeypatch):
         cfg, params = micro
         mesh, _ = tp2
-        tg, _ = self._drive(cfg, params, mesh, attn="gather", kv_dtype="int8")
-        tp_, _ = self._drive(cfg, params, mesh, attn="paged", kv_dtype="int8")
+        set_attn_form(monkeypatch, "xla")
+        tg, _ = self._drive(cfg, params, mesh, kv_dtype="int8")
+        set_attn_form(monkeypatch, "interpreted")
+        tp_, _ = self._drive(cfg, params, mesh, kv_dtype="int8")
         assert tg == tp_
 
-    def test_unshardable_heads_rejected(self, tp2):
-        """tp=2 with n_query_groups=1: kv_cache_spec would degrade to
-        replicated while the shard_map specs split heads — forcing the
-        kernel must refuse instead of silently disagreeing."""
+    def test_unshardable_heads_take_the_xla_form(self, tp2, attn_form):
+        """tp=2 with n_query_groups=1: kv_cache_spec degrades to replicated,
+        which shard_map specs that split heads would disagree with.  The
+        attention call is then the XLA form on the arrays as GSPMD holds them
+        (counted: ``path`` "xla") and the writers run on each device's whole
+        copy; the tokens are solo ``generate()``'s."""
         mesh, _ = tp2
         cfg = llama.Config.from_name(
             "tiny-llama-debug", n_layer=1, n_head=3, n_query_groups=1,
             n_embd=24, intermediate_size=32, vocab_size=32, block_size=64)
         params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-        with pytest.raises(ValueError, match="heads do not shard"):
-            tt.serve(None, params, cfg, mesh=mesh, block_size=4, num_blocks=16,
-                     max_batch=2, cache_dtype=jnp.float32, attn="paged")
+        eng = tt.serve(None, params, cfg, mesh=mesh, block_size=4, num_blocks=16,
+                       max_batch=2, cache_dtype=jnp.float32)
+        prompt = (np.arange(6) * 5 + 2).astype(np.int32) % cfg.vocab_size
+        got = eng.submit(prompt, max_new_tokens=5).result().tokens
+        st = eng.stats()
+        assert st["attn"]["path"] == "xla" and st["attn"]["fallback_steps"] == st["decode_steps"] > 0
+        solo = np.asarray(gen.generate(params, prompt[None], cfg, 5, cache_dtype=jnp.float32))[0]
+        np.testing.assert_array_equal(got, solo)

@@ -203,10 +203,10 @@ class TestSessionServing:
         assert r2.new_tokens == rc.new_tokens
         assert r2.shared_prefix_blocks > 0 and st["reattach_hits"] == 1
 
-    def test_turn2_reattach_parity_paged(self, micro):
+    def test_turn2_reattach_parity_paged(self, micro, monkeypatch):
+        monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")     # the kernel in the decode program, interpreted
         cfg, params = micro
-        r2, rc, st = self._two_turns(cfg, params, key1=None, key2=None,
-                                     engine_kw=dict(attn="paged"))
+        r2, rc, st = self._two_turns(cfg, params, key1=None, key2=None, engine_kw={})
         assert r2.new_tokens == rc.new_tokens
         assert r2.shared_prefix_blocks > 0 and st["reattach_hits"] == 1
 

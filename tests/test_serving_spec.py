@@ -4,13 +4,13 @@ The load-bearing guarantee is differential and bit-exact at the token
 level: an engine built with ``speculative=SpecConfig(draft_params,
 draft_cfg, K)`` must serve tokens identical to solo
 ``speculative_generate()`` — greedy AND temperature, K∈{2,4}, int8 KV,
-LoRA-on-target, prefix sharing, chunked prefill, async on/off, paged or
-gather verify, and across fault retry / re-prefill recovery.  The PRNG
+LoRA-on-target, prefix sharing, chunked prefill, async on/off, the verify
+kernel or its XLA form, and across fault retry / re-prefill recovery.  The PRNG
 chain only advances at harvest, so the draft arena is soft state and a
 recovered run replays bit-identically.
 
-Structural pillars: the ``verify_paged`` program contains zero arena-sized
-gathers and zero scatters (gather verify as positive control); the
+Structural pillars: with the kernel in it the ``verify_paged`` program contains
+zero arena gathers and zero scatters (the kernel's XLA form as positive control); the
 program set stays within ``stats()["bucket_bound"]``; and
 ``speculative=None`` engines are byte-identical to a world where the
 subsystem does not exist (module program cache gains no entries).
@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 import thunder_tpu as tt
+from conftest import arena_census, set_attn_form
 from thunder_tpu.models import generate as gen
 from thunder_tpu.models import llama
 from thunder_tpu.models import speculative as mspec
@@ -294,75 +295,92 @@ def _verify_args(eng, Bb, nbb):
 
 
 def _census(eng, kind, Bb=4, nbb=8):
-    """Arena-sized gathers + all scatters in the verify program's jaxpr,
-    skipping pallas kernel bodies (the test_paged_attention walk)."""
+    """Arena gathers + all scatters in the verify program's jaxpr, skipping
+    pallas kernel bodies (the test_paged_attention walk)."""
     prog, _ = eng._program(kind, Bb, nbb)
-    jaxpr = jax.make_jaxpr(prog)(*_verify_args(eng, Bb, nbb)).jaxpr
-    arena_shapes = {tuple(a.shape) for a in jax.tree_util.tree_leaves(eng.pool.arenas)}
-
-    def walk(jx, skip=("pallas_call",)):
-        out = []
-        for eqn in jx.eqns:
-            out.append(eqn)
-            if eqn.primitive.name in skip:
-                continue
-            for v in eqn.params.values():
-                sub = getattr(v, "jaxpr", None)
-                if sub is not None and hasattr(sub, "eqns"):
-                    out.extend(walk(sub))
-                elif hasattr(v, "eqns"):
-                    out.extend(walk(v))
-        return out
-
-    arena_gathers = scatters = 0
-    for eqn in walk(jaxpr):
-        if (eqn.primitive.name == "gather"
-                and tuple(eqn.invars[0].aval.shape) in arena_shapes):
-            arena_gathers += 1
-        if eqn.primitive.name.startswith("scatter"):
-            scatters += 1
-    return arena_gathers, scatters
+    return arena_census(eng.pool.arenas, jax.make_jaxpr(prog)(*_verify_args(eng, Bb, nbb)).jaxpr)
 
 
 class TestPagedVerify:
-    def test_paged_verify_parity_greedy_and_sampled(self, models):
+    def test_paged_verify_parity_greedy_and_sampled(self, models, monkeypatch):
+        set_attn_form(monkeypatch, "interpreted")
         cfg = models[0]
         p = _prompt(1, 7, cfg)
-        eng = _engine(models, attn="paged")
+        eng = _engine(models)
         r = eng.submit(p, max_new_tokens=10).result()
         np.testing.assert_array_equal(r.tokens, _solo(models, p, 10))
-        st = eng.stats()["attn"]
-        assert st["kernel_steps"] > 0 and st["fallback_steps"] == 0
+        st = eng.stats()
+        assert st["attn"]["path"] == "walk" and st["attn"]["fallback_steps"] == 0 and st["decode_steps"] > 0
         k = jax.random.PRNGKey(7)
-        teng = _engine(models, attn="paged", temperature=0.7)
+        teng = _engine(models, temperature=0.7)
         rt = teng.submit(p, max_new_tokens=8, key=k).result()
         np.testing.assert_array_equal(
             rt.tokens, _solo(models, p, 8, temperature=0.7, key=k))
 
-    def test_paged_verify_program_is_pure(self, models):
-        eng = _engine(models, attn="paged")
-        assert _census(eng, "verify_paged") == (0, 0)
+    def test_paged_verify_program_is_pure(self, models, monkeypatch):
+        set_attn_form(monkeypatch, "interpreted")
+        assert _census(_engine(models), "verify_paged") == (0, 0)
 
-    def test_gather_verify_is_the_positive_control(self, models):
-        eng = _engine(models, attn="gather")
-        arena_gathers, scatters = _census(eng, "verify")
+    def test_gather_verify_is_the_positive_control(self, models, monkeypatch):
+        """The verify program built with Pallas off: the kernel's XLA form
+        gathers a layer's rows and puts the chunk's among them."""
+        set_attn_form(monkeypatch, "xla")
+        arena_gathers, scatters = _census(_engine(models), "verify_paged")
         assert arena_gathers > 0 and scatters > 0
 
-    def test_quantized_paged_verify_is_pure_too(self, models):
-        eng = _engine(models, attn="paged", kv_dtype="int8")
-        assert _census(eng, "verify_paged") == (0, 0)
+    def test_quantized_paged_verify_is_pure_too(self, models, monkeypatch):
+        set_attn_form(monkeypatch, "interpreted")
+        assert _census(_engine(models, kv_dtype="int8"), "verify_paged") == (0, 0)
 
-    def test_auto_without_interpret_falls_back_recorded(self, models, monkeypatch):
-        monkeypatch.delenv("THUNDER_TPU_PALLAS_INTERPRET", raising=False)
+    def test_without_interpret_every_round_is_a_counted_fallback(self, models, monkeypatch):
+        set_attn_form(monkeypatch, "xla")
         if jax.default_backend() == "tpu":
-            pytest.skip("auto resolves to the kernel on TPU")
+            pytest.skip("the entry takes the kernel on TPU")
         cfg = models[0]
-        eng = _engine(models, attn="auto")
+        eng = _engine(models)
         p = _prompt(1, 6, cfg)
         r = eng.submit(p, max_new_tokens=6).result()
         np.testing.assert_array_equal(r.tokens, _solo(models, p, 6))
-        st = eng.stats()["attn"]
-        assert st["mode"] == "gather" and st["fallback_reason"]
+        st = eng.stats()
+        assert st["attn"]["path"] == "xla" and st["attn"]["fallback_steps"] == st["decode_steps"] > 0
+        assert "verify_paged" in st["compile_counts"] and "verify" not in st["compile_counts"]
+
+    @pytest.mark.parametrize("storage", ["bfloat16", "int8", "fp8"])
+    def test_the_xla_form_of_verify_is_the_kernel_and_the_reference(self, storage, monkeypatch):
+        """``paged_attn_verify``'s XLA form (T queries a row, the causal mask a
+        query) against the interpreted kernel and against ``gather_dense`` + the
+        plain float32 reference on the same bytes: ragged positions, one row
+        with no cached token."""
+        from thunder_tpu.executors import pallasex as px
+        from thunder_tpu.serving.kernel_check import _ref_attend
+        from thunder_tpu.serving.kv_pool import gather_dense
+        from thunder_tpu.serving.quant import gather_dense_q, quantize_kv
+
+        B, nh, ng, T, hs, bs, L, nbb, layer = 3, 4, 2, 5, 16, 4, 2, 6, 1
+        keys = iter(jax.random.split(jax.random.PRNGKey(11), 8))
+        rnd = lambda *shape: jax.random.normal(next(keys), shape, jnp.float32).astype(jnp.bfloat16)
+        nb = 1 + B * nbb
+        k, v = rnd(nb, L, ng, bs, hs), rnd(nb, L, ng, bs, hs)
+        tables = (1 + jnp.arange(B * nbb, dtype=jnp.int32)).reshape(B, nbb)
+        q, fk, fv = rnd(B, nh, T, hs), rnd(B, ng, T, hs), rnd(B, ng, T, hs)
+        pos = jnp.asarray([13, 0, nbb * bs - T], jnp.int32)
+        ks = vs = None
+        kd, vd = gather_dense(k, v, tables)
+        if storage != "bfloat16":
+            dt = jnp.int8 if storage == "int8" else jnp.float8_e4m3fn
+            (k, ks), (v, vs) = quantize_kv(k, dt), quantize_kv(v, dt)
+            kd, vd = gather_dense_q(k, v, ks, vs, tables, jnp.bfloat16)
+        ref = _ref_attend(q, kd[layer], vd[layer], fk, fv, pos, None)
+        call = lambda: px.paged_attn_verify(q, k, v, fk, fv, tables, pos, layer=layer, k_scale=ks, v_scale=vs)
+        set_attn_form(monkeypatch, "interpreted")
+        kernel = call()
+        set_attn_form(monkeypatch, "xla")
+        xla = call()
+        assert "pallas_call" not in str(jax.make_jaxpr(call)())
+        tol = 8 * float(jnp.finfo(jnp.bfloat16).eps)
+        assert xla.shape == kernel.shape == (B, nh, T, hs) and xla.dtype == kernel.dtype
+        assert float(jnp.max(jnp.abs(xla.astype(jnp.float32) - kernel.astype(jnp.float32)))) <= tol
+        assert float(jnp.max(jnp.abs(xla.astype(jnp.float32) - ref))) <= tol
 
 
 #

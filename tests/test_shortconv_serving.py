@@ -306,14 +306,16 @@ def test_served_is_solo_generate(served, solo):
 
 
 @pytest.mark.parametrize("options", [{"prefill_chunk": 16}, {"async_step": False}, {"max_batch": 2},
-                                     {"attn": "paged", "batch_buckets": (4,), "block_buckets": (4,)}],
+                                     {"interpreted": True, "batch_buckets": (4,), "block_buckets": (4,)}],
                          ids=["chunked", "sync", "two-slots", "kernels"])
 def test_the_other_program_kinds_serve_the_same_tokens(model, prompts, solo, options, monkeypatch):
     """A prompt in chunks (the gather chunk program: a lane-packed arena has no
     multi-query kernel), the synchronous loop, two slots for four requests (a
     slot, its tail and its blocks reused after a longer request), and the Pallas
     kernels interpreted (the lane-packed walk and the token writer)."""
-    if options.get("attn") == "paged":
+    options = dict(options)
+    interpreted = options.pop("interpreted", False)
+    if interpreted:
         monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
     cfg, params = model
     eng = _engine(cfg, params, **options)
@@ -321,10 +323,10 @@ def test_the_other_program_kinds_serve_the_same_tokens(model, prompts, solo, opt
         np.testing.assert_array_equal(got, want)
     st = eng.stats()
     if "prefill_chunk" in options:
-        assert st["chunk_runs"] > 0 and st["attn"]["kinds"]["prefill_chunk"]["mode"] == "gather"
-    if options.get("attn") == "paged":
+        assert st["chunk_runs"] > 0 and st["attn"]["chunk"] == "gather"
+    if interpreted:
         assert st["attn"]["path"] == "walk" and st["compile_counts"]["decode_paged"] >= 1
-        assert "lane-packed" in st["attn"]["kinds"]["prefill_chunk"]["fallback_reason"]
+        assert "lane-packed" in st["attn"]["chunk_why"]
     eng.shutdown()
 
 
@@ -375,7 +377,7 @@ def test_the_engine_says_what_its_arenas_hold(served):
     assert eng.pool.k_arena.shape == (40, 2, 1, 16, 128)
     assert st["state"]["arenas"] == ["conv"] and st["state"]["layers"] == 3
     assert st["state"]["slot_bytes"] == 3 * 2 * 64 * 4 and eng.pool.state.state is None
-    assert st["attn"]["lane_pack"] == 2 and st["attn"]["path"] in (None, "walk")
+    assert st["attn"]["lane_pack"] == 2 and st["attn"]["path"] in ("xla", "walk")
     assert st["moe"] == {"experts_held": 8, "expert_first": 0, "experts_published": 8, "router": "sigmoid_bias"}
 
 
@@ -417,9 +419,13 @@ def test_the_delta_rules_slot_costs_what_it_cost():
 @pytest.mark.parametrize("feature,reason", [
     ("prefix_sharing", "recurrent state or conv tail cannot"), ("sessions", "no state snapshot"),
     ("speculative", "no rollback"), ("lora", "in_proj"), ("mesh", "no layout under a tp axis"),
-    ("decode_steps", "go on advancing its state"), ("model_fn", "mirror forward_with_cache")])
+    ("decode_steps", "go on advancing its state"), ("model_fn", "llama.Config.*custom model_fn")])
 def test_each_refused_feature_raises_with_its_reason(model, feature, reason):
     cfg, params = model
+    if feature == "model_fn":       # refused for every model: a model is a Config
+        with pytest.raises(NotImplementedError, match=reason):
+            tt.serve(lambda *a, **k: None, params, cfg, num_blocks=8, max_batch=1)
+        return
     value = 4 if feature == "decode_steps" else True if feature == "prefix_sharing" else object()
     assert reason in engine_mod.hybrid_unsupported(cfg, **{feature: value})
     assert engine_mod.hybrid_unsupported(cfg) is None

@@ -949,10 +949,8 @@ def serve(model_fn, params, cfg, **kwargs):
     :class:`thunder_tpu.serving.ServingEngine` with ``submit(prompt, *,
     max_new_tokens, deadline, stream_cb) -> RequestHandle``, a synchronous
     ``step()`` drive loop, and ``run()``/``drain()``/``shutdown()``.
-    ``model_fn=None`` serves the in-tree ``models.generate`` forward; pass a
-    callable with the same signature to serve a custom model (``pos`` is
-    the Python integer 0 for a whole prompt, as solo ``generate()`` passes
-    it, and a traced int32 otherwise; it returns ``(B, T, V)`` logits).
+    The model is ``cfg`` (a ``llama.Config``) served by the in-tree
+    ``models.generate`` forward; the first argument must be ``None``.
     Mesh serving: ``mesh=`` (plus optional ``shardings=`` from
     ``distributed``'s rule tables) runs the whole engine SPMD — params
     placed once, the KV block arena sharded heads-over-``tp``

@@ -1084,8 +1084,11 @@ def flash_cross_entropy(logits, target):
 #   (``input_output_aliases``), so the update is in place and the decode
 #   program stays scatter-free.
 #
-# Both run under the Pallas interpreter off-TPU, so CPU tier-1 tests execute
-# the real kernels (``tt.serve(..., attn="paged")``).
+# The two attention entries choose their own form from what they can observe
+# (:func:`paged_decode_path`): the kernel where Pallas is on (the TPU, or the
+# interpreter a CPU opted into with ``THUNDER_TPU_PALLAS_INTERPRET=1``) and it
+# takes the arena's rows, else :func:`paged_attn_xla`, the same attention in
+# XLA.  The programs above them are the same either way.
 # ---------------------------------------------------------------------------
 
 
@@ -1117,6 +1120,65 @@ def paged_head_size_ok(hs: int) -> bool:
     quantised arena of a narrow head (a head a row, its scale a head's) or KV
     heads that do not come in whole rows go a block a grid step."""
     return _interpret() or hs % 128 == 0 or (hs < 128 and 128 % hs == 0)
+
+
+def paged_decode_path(lanes: int, window: int | None = None) -> str:
+    """The form :func:`paged_attn_decode` takes here for arena rows ``lanes``
+    wide under a sliding ``window``: ``"walk"`` (the chunked walk), ``"by_blocks"``
+    (a block a grid step: rows Mosaic cannot slice), or ``"xla"``
+    (:func:`paged_attn_xla`: Pallas is off, or such rows with a window, which
+    the per-block kernel has not)."""
+    if not _pallas_available():
+        return "xla"
+    if paged_walk_lanes_ok(lanes):
+        return "walk"
+    return "by_blocks" if window is None else "xla"
+
+
+def paged_attn_xla(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *, layer,
+                   k_scale=None, v_scale=None, window=None, packed_out=False):
+    """:func:`paged_attn_verify` (and, at ``T`` = 1, :func:`paged_attn_decode`)
+    in XLA, for a backend without Pallas and for the arenas the kernels do not
+    take: ``layer``'s blocks of the rows gathered by the table (a quantised
+    arena dequantised to the cache compute dtype, ``quant.gather_dense_q``;
+    a lane-packed one's heads taken apart), the fresh rows put at ``[pos, pos +
+    T)``, the causal keep mask with the sliding window, one softmax.  It is the
+    dense cache's own attention (``generate.attend_dense``) on the dense cache's
+    own operands, so what it serves is solo ``generate()``'s to the bit.
+
+    ``q (B, nh, T, hs)``, ``fresh_k``/``fresh_v (B, ng, T, hs)``; the rest as the
+    kernels take them, and the table holds the slots of ``[pos, pos + T)`` (the
+    writer's, one call later).  Returns ``(B, nh, T, hs)``; ``packed_out`` (rows of a head
+    pair, differential attention): the pair's two softmaxes over its K row, each
+    weighing the whole V row (``generate.diff_attend_dense``), ``(B, ng / 2, 2
+    rep, T, 2 hs)``."""
+    from thunder_tpu.models.generate import attend_dense, diff_attend_dense, pair_rows
+    from thunder_tpu.serving.kv_pool import gather_rows
+    from thunder_tpu.serving.quant import gather_dense_q
+
+    B, nh, T, hs = q.shape
+    G, lanes = k_arena.shape[2], k_arena.shape[4]
+    one = slice(layer, layer + 1)
+    if k_scale is not None:
+        kd, vd = gather_dense_q(k_arena[:, one], v_arena[:, one], k_scale[:, one], v_scale[:, one],
+                                tables, fresh_k.dtype)
+    else:
+        P = 1 if packed_out else lanes // hs
+        kd, vd = gather_rows(k_arena[:, one], tables, P), gather_rows(v_arena[:, one], tables, P)
+    if packed_out:
+        assert lanes == 2 * hs, (lanes, hs)
+        fresh_k, fresh_v = pair_rows(fresh_k), pair_rows(fresh_v)
+    put = jax.vmap(lambda rows, new, p: jax.lax.dynamic_update_slice_in_dim(rows, new, p, axis=1))
+    kd, vd = put(kd[0], fresh_k.astype(kd.dtype), pos), put(vd[0], fresh_v.astype(vd.dtype), pos)
+    j = jnp.arange(kd.shape[2])
+    qpos = (pos[:, None] + jnp.arange(T))[:, :, None]                  # (B, T, 1)
+    keep = j <= qpos
+    if window is not None:
+        keep = jnp.logical_and(keep, j > qpos - window)
+    if packed_out:
+        out = diff_attend_dense(q.reshape(B, G, 2, nh // (2 * G), T, hs), kd, vd, keep[:, None, None])
+        return out.reshape(B, G, nh // G, T, lanes)
+    return attend_dense(q, kd, vd, keep[:, None])
 
 
 # VMEM that one chunk of the decode walk may hold: K and V of its table
@@ -1202,7 +1264,7 @@ def _arena_walk(tab_ref, i, p_i, q, arenas, bufs, sem, *, layer, bs, C, window,
     def dequant(slot):
         # per table entry and group: (bs, hs) stored values times their
         # (bs, 1) scale column, rounded to the cache compute dtype exactly as
-        # the gather path's dequantize does
+        # ``quant.gather_dense_q`` does
         def one(t, _):
             for x_buf, s_buf, d_buf in zip(bufs[:2], bufs[2:4], bufs[4:]):
                 for g in range(ng):
@@ -1335,9 +1397,9 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     on zeros, the arena's bytes read once.  On the TPU the chunked walk needs
     the arena's rows to be whole 128-lane tiles (:func:`paged_walk_lanes_ok`);
     other arenas go a block a grid step (:func:`_decode_by_blocks`), and with a
-    sliding window they are refused (``paged_supported`` sends such a model to
-    the gather path when the engine is built).  ``bs`` = 8 and 16 both
-    compile, at int8 and bfloat16.
+    sliding window, or where Pallas is off, through :func:`paged_attn_xla`
+    (:func:`paged_decode_path` says which).  ``bs`` = 8 and 16 both compile, at
+    int8 and bfloat16.
     Returns (B, nh, hs) attention outputs at ``q.dtype``.  ``packed_out`` (a
     lane-packed arena only): the walk's rows whole, ``(B, ng / P, P * rep, P *
     hs)``: row ``j * rep + r`` of group ``g`` is query ``r`` of KV head ``g P + j``,
@@ -1351,12 +1413,13 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     assert P * hs == lanes and (P == 1 or k_scale is None), (hs, lanes)
     rep = nh // (ng * P)
     assert rep * ng * P == nh, (nh, ng, P)
-    assert not packed_out or (P > 1 and paged_walk_lanes_ok(lanes)), "packed_out: the walk over a lane-packed arena"
-    if not paged_walk_lanes_ok(lanes):
-        if window is not None:
-            raise NotImplementedError(
-                f"paged_attn_decode on the TPU: arena rows of {lanes} lanes (head_size {hs}) are not "
-                "whole 128-lane tiles and the per-block kernel has no sliding window")
+    path = paged_decode_path(lanes, window)
+    assert not packed_out or (P > 1 and path != "by_blocks"), "packed_out: a lane-packed arena, walked"
+    if path == "xla":
+        out = paged_attn_xla(q[:, :, None], k_arena, v_arena, fresh_k[:, :, None], fresh_v[:, :, None], tables, pos,
+                             layer=layer, k_scale=k_scale, v_scale=v_scale, window=window, packed_out=packed_out)
+        return jnp.squeeze(out, -2)
+    if path == "by_blocks":
         return _decode_by_blocks(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos,
                                  layer=layer, k_scale=k_scale, v_scale=v_scale)
     quantized = k_scale is not None
@@ -1893,8 +1956,9 @@ def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     is (request, kv-group, kv-block) with one ``(bs, hs)`` arena tile a step,
     fetched through BlockSpec index maps that :func:`_ragged_step` clamps to
     the request's live blocks.  Sliding-window models are rejected upstream
-    (speculation needs full caches; the chunked-prefill resolution falls
-    back to gather).  Returns (B, nh, T, hs) at ``q.dtype``.
+    (speculation needs full caches; a windowed model's prompt pieces take the
+    gather chunk).  Where Pallas is off, :func:`paged_attn_xla`.  Returns (B, nh,
+    T, hs) at ``q.dtype``.
     """
     B, nh, T, hs = q.shape
     num_blocks, _L, ng, bs, lanes = k_arena.shape
@@ -1902,6 +1966,9 @@ def paged_attn_verify(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
         raise NotImplementedError(
             f"paged_attn_verify: a lane-packed arena (rows of {lanes} lanes for heads of {hs}) has no "
             "multi-query kernel; build the pool with lane_pack=1")
+    if not _pallas_available():
+        return paged_attn_xla(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, layer=layer,
+                              k_scale=k_scale, v_scale=v_scale)
     nbb = int(tables.shape[1])
     rep = nh // ng
     assert rep * ng == nh, (nh, ng)
@@ -1986,7 +2053,7 @@ def paged_chunk_write(arena, vals, dest, pos, *, block_size):
     step per chunk block lands a whole (L, ng, bs, hs) slab at
     ``dest[pos // bs + c]`` via the aliased output, so untouched blocks keep
     their bytes and no scatter primitive appears in the program.  Trailing
-    bucket-padding slots write garbage exactly like the gather path's
+    bucket-padding slots write garbage exactly like the gather chunk's
     ``scatter_blocks`` — sunk, never attended, or overwritten before use.
     """
     bs = block_size

@@ -602,6 +602,22 @@ def _band_keep(T: int, W):
     return keep if W is None else jnp.logical_and(keep, col > row - W)
 
 
+def attend_dense(q, kk, vv, keep):
+    """The scoring tail of attention over keys and values in hand: ``q (B, nh,
+    T, hs)``, ``kk``/``vv (B, ng, Tk, hs)`` (grouped; any dtype), ``keep`` a mask
+    that broadcasts against ``(B, nh, T, Tk)``.  One softmax in float32, the
+    weights rounded to ``q``'s dtype before the value product.  The dense cache
+    ends in it, and so does the paged kernels' XLA form
+    (``pallasex.paged_attn_xla``): the two agree to the bit.  Returns ``(B, nh,
+    T, hs)``."""
+    kk, vv = _expand_groups(kk, vv, q.shape[1])
+    scores = jnp.einsum(
+        "bhqd,bhkd->bhqk", q, kk.astype(q.dtype), preferred_element_type=jnp.float32
+    ) / math.sqrt(q.shape[-1])
+    w = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", w, vv.astype(q.dtype))
+
+
 def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized=False,
                      lora=None, lora_scaling=1.0, sharded=False):
     """x: (B, T, C) new tokens at global positions [pos, pos+T).  Writes their
@@ -686,16 +702,7 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
 
             flash = pallasex.flash_sdpa(q, k, v, None, True, 1.0 / math.sqrt(hs),
                                         W if W is not None and T > W else None)
-        if flash is not None:
-            y = flash[0]
-        else:
-            kk, vv = _expand_groups(kk, vv, nh)
-            scores = jnp.einsum(
-                "bhqd,bhkd->bhqk", q, kk.astype(q.dtype), preferred_element_type=jnp.float32
-            ) / math.sqrt(hs)
-            scores = jnp.where(keep, scores, -jnp.inf)
-            w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-            y = jnp.einsum("bhqk,bhkd->bhqd", w, vv.astype(q.dtype))
+        y = flash[0] if flash is not None else attend_dense(q, kk, vv, keep)
     with scope("out"):
         y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
         out = lin(y, ap["wo"], ap.get("bo"))

@@ -7,7 +7,7 @@ block-granular KV pool shared by every in-flight request (with reference-
 counted prefix sharing), bucketed batch shapes so the compiled-program set
 stays bounded, and per-request deadlines, streaming, and telemetry.
 
-Entry point: ``tt.serve(model_fn, params, cfg, ...)`` (or construct
+Entry point: ``tt.serve(None, params, cfg, ...)`` (or construct
 :class:`ServingEngine` directly).  Everything is strictly additive — no
 other compiled program changes by importing or using this package.
 

@@ -6,10 +6,11 @@ their prefill, and leave it the step they finish — batch occupancy is a
 scheduling property, not a caller-visible one.  The engine composes the
 pieces the repo already has:
 
-- ``models.generate.forward_with_cache`` is the model step — the pool's
-  gathered block views reassemble exactly the dense cache layout it
-  consumes, and per-row vector positions (the speculative-decode machinery)
-  drive mixed-progress batches;
+- ``models.generate.forward_with_cache`` is the model step of a prompt —
+  the pool's gathered block views reassemble exactly the dense cache layout
+  it consumes; ``serving.paged_attention.forward_paged`` is the same math a
+  token a row, its attention straight off the block arena (one decode
+  program a job: the kernel where it compiles, its XLA form elsewhere);
 - the **paged pool** (:mod:`serving.kv_pool`) owns cache memory; every
   program donates the arenas so updates stay in place (PR 4);
 - the **scheduler** (:mod:`serving.scheduler`) owns admission, FIFO order,
@@ -130,19 +131,16 @@ from thunder_tpu.serving.kv_pool import (
     PagedKVPool,
     PrefixIndex,
     chunk_tables,
-    dest_for_pos,
     gather_dense,
     gather_rows,
     ring_dest,
     ring_tables,
     scatter_blocks,
-    scatter_token,
 )
 from thunder_tpu.serving.lora import gather_adapter_slots
 from thunder_tpu.serving.quant import (
     gather_dense_q,
     scatter_blocks_q,
-    scatter_token_q,
 )
 from thunder_tpu.serving.scheduler import (
     FINISH_DEADLINE,
@@ -268,8 +266,8 @@ _program_cache: dict = {}
 
 
 def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=None, lora=None,
-                       mesh=None, decode_steps: int = 1, model_fn=None, kv_dtype=None, prefill_chunk=None,
-                       priorities=None, fault_plan=None, attn: str = "auto") -> str | None:
+                       mesh=None, decode_steps: int = 1, kv_dtype=None, prefill_chunk=None,
+                       priorities=None, fault_plan=None) -> str | None:
     """Why an engine with these options cannot serve a config that keeps a
     state a request beside its KV (a delta rule's recurrent state and conv
     tail, a short convolution's tail alone, or a selective scan's state and
@@ -290,9 +288,6 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
             return "priorities= is unsupported: a preempted request resumes through the chunk programs, which are not built"
         if fault_plan is not None:
             return "fault_plan= is unsupported: re-prefill recovery replays through the chunk programs, which are not built"
-        if attn == "gather":
-            return ("attn='gather' is unsupported: the gather decode program has no per-kind form; the paged decode "
-                    "program runs the walk's XLA form where Pallas is off")
     if prefix_sharing:
         return ("prefix_sharing=True is unsupported: a prefix's KV blocks can be shared, its "
                 "recurrent state or conv tail cannot (no snapshot of the state at a block edge is kept)")
@@ -310,15 +305,12 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
     if int(decode_steps) > 1:
         return ("decode_steps > 1 is unsupported: a row that finishes inside a multi-step visit "
                 "would go on advancing its state")
-    if model_fn is not None:
-        return "a custom model_fn is unsupported: the state programs mirror forward_with_cache"
     if cfg.sliding_window is not None:
         return ("a sliding window is unsupported beside a recurrent state or conv tail "
                 "(block expiry is untested with it)")
     return None
 
-def latent_unsupported(cfg, *, kv_dtype=None, cache_dtype=None, speculative=None, lora=None, mesh=None,
-                       model_fn=None, attn: str = "auto") -> str | None:
+def latent_unsupported(cfg, *, kv_dtype=None, cache_dtype=None, speculative=None, lora=None, mesh=None) -> str | None:
     """Why an engine with these options cannot serve a latent-attention config
     (one latent a token a layer in place of K and V), or None.  Each is a
     mechanism that is not built (ROADMAP Queue 2)."""
@@ -337,11 +329,6 @@ def latent_unsupported(cfg, *, kv_dtype=None, cache_dtype=None, speculative=None
     if lora is not None:
         return ("lora= is unsupported: the adapter arenas target wq/wk/wv/wo; the latent projections "
                 "(wq_a, wq_b, wkv_a, wkv_b) have none")
-    if model_fn is not None:
-        return "a custom model_fn is unsupported: the latent programs mirror forward_with_cache"
-    if attn == "gather":
-        return ("attn='gather' is unsupported: the gather decode program has no latent form; the paged "
-                "decode program runs mla_paged_decode's XLA form where Pallas is off")
     return None
 
 
@@ -358,7 +345,6 @@ class ServingEngine:
         params,
         cfg,
         *,
-        model_fn: Callable | None = None,
         block_size: int = 16,
         num_blocks: int = 64,
         max_batch: int = 8,
@@ -380,7 +366,6 @@ class ServingEngine:
         flight_recorder=None,
         mesh=None,
         shardings=None,
-        attn: str = "auto",
         async_step: bool = True,
         prefill_chunk: int | None = None,
         fault_plan=None,
@@ -399,14 +384,13 @@ class ServingEngine:
         require_servable(cfg)
         # a model with linear_attention layers keeps a recurrent state a
         # request beside its KV, one with conv layers a conv tail; what such a
-        # state cannot serve yet refuses here, with its reason (as
-        # paged_supported does for the kernels)
+        # state cannot serve yet refuses here, with its reason
         self._hybrid = bool(getattr(cfg, "state_layers", ()))
         if self._hybrid:
             why = hybrid_unsupported(
                 cfg, prefix_sharing=prefix_sharing, sessions=sessions, speculative=speculative,
-                lora=lora, mesh=mesh, decode_steps=decode_steps, model_fn=model_fn, kv_dtype=kv_dtype,
-                prefill_chunk=prefill_chunk, priorities=priorities, fault_plan=fault_plan, attn=attn)
+                lora=lora, mesh=mesh, decode_steps=decode_steps, kv_dtype=kv_dtype,
+                prefill_chunk=prefill_chunk, priorities=priorities, fault_plan=fault_plan)
             if why:
                 kind = ("linear_attention layers (a recurrent state a request)" if cfg.linear_layers
                         else "conv layers (a conv tail a request)" if cfg.conv_layers
@@ -419,7 +403,7 @@ class ServingEngine:
         self._latent = bool(getattr(cfg, "latent", False))
         if self._latent:
             why = latent_unsupported(cfg, kv_dtype=kv_dtype, cache_dtype=cache_dtype, speculative=speculative,
-                                     lora=lora, mesh=mesh, model_fn=model_fn, attn=attn)
+                                     lora=lora, mesh=mesh)
             if why:
                 raise NotImplementedError(
                     f"config {getattr(cfg, 'name', '?')!r} has latent attention (one latent a token a "
@@ -456,7 +440,6 @@ class ServingEngine:
         self._mesh_collectives: dict | None = None         # lazy decode census
         self.params = params
         self.cfg = cfg
-        self._forward = model_fn if model_fn is not None else forward_with_cache
         self.temperature = float(temperature)
         self.eos_id = eos_id
         self.quantized = bool(quantized)
@@ -471,56 +454,19 @@ class ServingEngine:
             # kernel, which reads a head a row: speculation keeps that layout
             **({"lane_pack": 1} if speculative is not None else {}),
         )
-        # decode attention path, resolved ONCE at construction (each engine
-        # builds exactly one decode program kind, so the program-set bound
-        # in stats() is unchanged): "paged" runs the Pallas flash-decoding
-        # kernel straight off the block arena (interpret mode off-TPU),
-        # "gather" keeps the dense gather/scatter pair, "auto" takes the
-        # kernel when it is structurally supported AND Pallas is enabled on
-        # this backend (TPU, or THUNDER_TPU_PALLAS_INTERPRET=1), else falls
-        # back to gather and counts serving.attn.fallback_steps
-        if attn not in ("auto", "paged", "gather"):
-            raise ValueError(
-                f"attn= must be 'auto', 'paged', or 'gather', got {attn!r}")
-        from thunder_tpu.executors.pallasex import paged_available
-        from thunder_tpu.serving.paged_attention import paged_supported
+        # the form the decode program's attention call takes here, and the keys
+        # a step of its walk attends (a group), from the arena shard the kernel
+        # is handed: the kernel's entry decides (pallasex.paged_decode_path), this
+        # is what it will say.  "xla" counts every decode step in fallback_steps
+        from thunder_tpu.executors.pallasex import _MLA_CHUNK_KEYS, paged_kv_chunk_blocks
+        from thunder_tpu.serving.paged_attention import decode_path
 
-        ok, why = paged_supported(cfg, self._forward is forward_with_cache, mesh,
-                                  arena_lanes=None if self._latent else self.pool.k_arena.shape[-1])
-        self._attn_requested = attn
-        if attn == "paged":
-            if not ok:
-                raise ValueError(f"attn='paged' is unsupported here: {why}")
-            self.attn, self._attn_fallback_reason = "paged", None
-        elif attn == "auto" and ok and (paged_available() or self._latent or self._perkind):
-            # a latent arena and per-kind caches have the paged decode program
-            # alone: without Pallas its kernel call is the XLA form
-            self.attn, self._attn_fallback_reason = "paged", None
-        elif attn == "auto":
-            self.attn = "gather"
-            self._attn_fallback_reason = why or "pallas disabled on this backend"
-        else:
-            self.attn, self._attn_fallback_reason = "gather", None
-        self.attn_kernel_steps = 0
         self.attn_fallback_steps = 0
-        # keys a step of paged_attn_decode's walk attends (a group), from the
-        # arena shard the decode program's kernel is handed; None on gather
-        self._kv_chunk_tokens = None
-        self._attn_path = None
-        if self._latent:
-            from thunder_tpu.executors.pallasex import _MLA_CHUNK_KEYS
-
-            self._kv_chunk_tokens = _MLA_CHUNK_KEYS
-        elif self.attn == "paged":
-            from thunder_tpu.executors.pallasex import paged_kv_chunk_blocks
-
-            from thunder_tpu.executors.pallasex import paged_walk_lanes_ok
-
-            arena = self.pool.k_arena
-            _, _, ng, bs, hs = arena.sharding.shard_shape(arena.shape)
-            self._kv_chunk_tokens = bs * paged_kv_chunk_blocks(
-                ng, bs, hs, arena.dtype.itemsize)
-            self._attn_path = "walk" if paged_walk_lanes_ok(hs) else "by_blocks"
+        arena = self.pool.k_arena
+        _, _, ng, bs, lanes = arena.sharding.shard_shape(arena.shape)
+        self._attn_path = decode_path(cfg, mesh, arena_lanes=lanes)     # a latent arena: mla_paged_decode's walk
+        self._kv_chunk_tokens = (_MLA_CHUNK_KEYS if self._latent
+                                 else bs * paged_kv_chunk_blocks(ng, bs, lanes, arena.dtype.itemsize))
         # multi-tenant LoRA: a bounded AdapterRegistry shared across engines;
         # its stacked factor arenas are program *arguments* (register/evict
         # are data writes), only its geometry enters the program identity
@@ -561,11 +507,7 @@ class ServingEngine:
         if speculative is not None:
             from thunder_tpu.serving.speculative import validate_spec
 
-            validate_spec(
-                speculative, cfg,
-                custom_forward=self._forward is not forward_with_cache,
-                sliding_window=cfg.sliding_window,
-            )
+            validate_spec(speculative, cfg, sliding_window=cfg.sliding_window)
             if mesh is not None:
                 from thunder_tpu.serving.mesh import place_params as _pp
 
@@ -625,25 +567,18 @@ class ServingEngine:
             self.prefix_sharing = False
             sch.prefill_chunk = None
         self._table_widths = self._table_width_buckets()
-        # chunked prefill resolves its kernel/gather path INDEPENDENTLY of
-        # decode (stats()["attn"]["kinds"] reports both): the paged chunk
-        # writer lands whole (L, ng, bs, hs) block slabs built from the
-        # chunk's fresh K/V alone, so every chunk boundary must fall on a
-        # block edge — the chunk width and every prefill bucket must be
-        # multiples of the pool block size (the FINAL piece runs the
-        # ``prefill`` kind and may stay ragged).  Sliding-window models
-        # keep the gather chunk (the multi-query kernel has no windowed
-        # keep-mask), and speculative engines keep ``spec_prefill_chunk``
-        # (it writes the draft arena too).  Resolution happens ONCE here,
-        # so the program-identity story is unchanged: the paged chunk kind
-        # REPLACES the gather chunk kind 1:1 per engine and the
-        # bucket_bound formula in stats() is untouched.
+        # a piece of a prompt has two programs, chosen once here from shapes
+        # alone: the paged chunk writer lands whole (L, ng, bs, hs) block slabs
+        # built from the chunk's fresh K/V alone, so every chunk boundary must
+        # fall on a block edge — the chunk width and every prefill bucket must be
+        # multiples of the pool block size (the FINAL piece runs the ``prefill``
+        # kind and may stay ragged).  What the multi-query kernel has no form for
+        # keeps the gather chunk: a sliding window, a latent cache, lane-packed
+        # rows; and speculative engines keep ``spec_prefill_chunk`` (it writes
+        # the draft arena too).  One kind an engine, so the bucket_bound formula
+        # in stats() is untouched.
         sch = self.scheduler
-        if self.attn != "paged":
-            chunk_why = (self._attn_fallback_reason
-                         if self._attn_requested == "auto"
-                         else "attn='gather' requested")
-        elif self.spec is not None:
+        if self.spec is not None:
             chunk_why = "speculative prefill writes the draft arena (gather chunk)"
         elif cfg.sliding_window is not None:
             chunk_why = "sliding-window keep-mask is decode-only"
@@ -661,11 +596,7 @@ class ServingEngine:
         else:
             chunk_why = None
         self.attn_chunk = "paged" if chunk_why is None else "gather"
-        self._attn_chunk_fallback_reason = chunk_why
-        # per-kind [kernel, fallback] step counters beside the decode-only
-        # aggregates (attn_kernel_steps/attn_fallback_steps keep their
-        # pre-existing decode semantics)
-        self._attn_steps = {"decode": [0, 0], "prefill_chunk": [0, 0]}
+        self._attn_chunk_why = chunk_why
         # fault tolerance: the chaos plan (None = unarmed — one `is None`
         # check per fault point, compiled programs byte-identical either
         # way), the retry/backoff policy, and the harvest watchdog on the
@@ -729,11 +660,10 @@ class ServingEngine:
         self.tokens_generated = 0
         self._occupancy_sum = 0
         self.compile_counts = {"prefill": 0, "prefill_fresh": 0, "prefill_chunk": 0,
-                               "prefill_chunk_paged": 0, "decode": 0,
-                               "decode_paged": 0, "decode_multi": 0,
+                               "prefill_chunk_paged": 0, "decode_paged": 0,
                                "decode_multi_paged": 0, "spec_prefill": 0,
                                "spec_prefill_chunk": 0, "draft_decode": 0,
-                               "verify": 0, "verify_paged": 0}
+                               "verify_paged": 0}
         # host-visit amortization accounting: one host_visit per decode-lane
         # harvest (a visit serves up to n_decode_steps tokens per row)
         self.host_visits = 0
@@ -772,7 +702,6 @@ class ServingEngine:
         self._m_pool_util = reg0.gauge("serving.pool.utilization")
         self._m_pool_free = reg0.gauge("serving.pool.free_blocks")
         self._m_pool_low_water = reg0.gauge("serving.pool.free_blocks_low_water")
-        self._m_attn_kernel = reg0.counter("serving.attn.kernel_steps")
         self._m_attn_fallback = reg0.counter("serving.attn.fallback_steps")
         self._m_host_visits = reg0.counter("serving.decode.host_visits")
         self._m_pool_occ = reg0.gauge("serving.pool.occupancy_frac")
@@ -1329,41 +1258,25 @@ class ServingEngine:
             "overlap_frac_mean": (self._overlap_frac_sum / n) if n else None,
             "compile_counts": dict(self.compile_counts),
             "attn": {
-                "mode": self.attn,
-                "requested": self._attn_requested,
-                "fallback_reason": self._attn_fallback_reason,
-                "kernel_steps": self.attn_kernel_steps,
+                # decode steps whose attention call took the XLA form (every one
+                # or none: the form is the engine's, ``path``)
                 "fallback_steps": self.attn_fallback_steps,
                 "kv_chunk_tokens": self._kv_chunk_tokens,
-                # which form the decode kernel takes for this arena (None on
-                # gather or a latent arena): the chunked walk, or a block a grid
-                # step; and the KV heads a row of the arena holds
+                # the form the decode kernel's entry takes for this arena: the
+                # chunked walk, a block a grid step, or its XLA form; and the KV
+                # heads a row of the arena holds
                 "path": self._attn_path,
                 "lane_pack": self.pool.lane_pack,
+                # a prompt piece's program ("paged" or "gather"), and why it is
+                # the gather chunk where it is
+                "chunk": self.attn_chunk,
+                "chunk_why": self._attn_chunk_why,
                 # per-kind caches: the layers that walk one layer's blocks (the
                 # layer itself and the cross_attention layers after it), and the
                 # rows whole prompts ran through those layers (one a prompt)
                 **({"shared_kv_layers": 1 + sum(k == "cross_attention" for k in self.cfg.layer_types),
                     "prefill_cross_rows": self.prefill_fresh_runs}
                    if self._perkind and self.cfg.cross_from is not None else {}),
-                # per-kind resolution: decode and chunk-prefill resolve
-                # independently (the chunk kernel needs block-aligned
-                # widths and no sliding window), so a single top-level
-                # mode/reason can't tell the whole story
-                "kinds": {
-                    "decode": {
-                        "mode": self.attn,
-                        "fallback_reason": self._attn_fallback_reason,
-                        "kernel_steps": self._attn_steps["decode"][0],
-                        "fallback_steps": self._attn_steps["decode"][1],
-                    },
-                    "prefill_chunk": {
-                        "mode": self.attn_chunk,
-                        "fallback_reason": self._attn_chunk_fallback_reason,
-                        "kernel_steps": self._attn_steps["prefill_chunk"][0],
-                        "fallback_steps": self._attn_steps["prefill_chunk"][1],
-                    },
-                },
             },
             "bucket_bound": kinds * len(self._table_widths) + (
                 len(sch.prefill_buckets) if self.spec is None else 0),
@@ -1706,19 +1619,6 @@ class ServingEngine:
         return ("prefill_chunk_paged" if self.attn_chunk == "paged"
                 else "prefill_chunk")
 
-    def _note_chunk_attn_step(self) -> None:
-        """Per-kind attn step accounting for one chunk dispatch (the decode
-        aggregates keep their decode-only semantics)."""
-        st = self._attn_steps["prefill_chunk"]
-        if self.attn_chunk == "paged":
-            st[0] += 1
-        else:
-            st[1] += 1
-            if self._attn_requested != "gather":
-                # the user asked for kernels (paged or auto) but the chunk
-                # kind resolved gather: that is a fallback step
-                self._m_attn_fallback.inc()
-
     def _prefill_dispatch(self, req: Request) -> dict:
         """Dispatches the next prefill piece for ``req`` and returns its
         in-flight record.  A piece is either a full ``prefill`` (samples
@@ -1861,8 +1761,6 @@ class ServingEngine:
         else:
             self.chunk_runs += 1
             reg.counter("serving.steps.prefill_chunk").inc()
-            if self.spec is None:
-                self._note_chunk_attn_step()
         if compiled:
             # cold-compile TTFT outliers must be distinguishable from queue
             # delay: count prefill RUNS that paid a compile (vs
@@ -2082,7 +1980,7 @@ class ServingEngine:
             slots = np.zeros(Bb, dtype=np.int32)           # padding rows: base slot
             sslots = np.zeros(Bb, dtype=np.int32)          # padding rows: the sink state slot
             # multi-step stopping: the last position a row may write before
-            # FINISH_LENGTH (see _build_decode_multi); -1 parks padding rows
+            # FINISH_LENGTH (see _build_decode_multi_paged); -1 parks padding rows
             # dead from step 0
             stop = np.full(Bb, -1, dtype=np.int32)
             # how many steps of this chain may be dispatched ahead of the
@@ -2135,10 +2033,7 @@ class ServingEngine:
                 cmask_d = jnp.asarray(m)
             else:
                 cmask_d = self._ones_mask(shape)
-        if N > 1:
-            kind = "decode_multi_paged" if self.attn == "paged" else "decode_multi"
-        else:
-            kind = "decode_paged" if self.attn == "paged" else "decode"
+        kind = "decode_multi_paged" if N > 1 else "decode_paged"
         prog, compiled = self._program(kind, Bb, nbb)
         lora_arenas = self._lora_arenas()
         if self.mesh is not None and self._mesh_collectives is None:
@@ -2152,16 +2047,8 @@ class ServingEngine:
             self._mesh_collectives = self._collective_census(
                 (kind, Bb, nbb), prog, ex,
             )
-        if self.attn == "paged":
-            self.attn_kernel_steps += 1
-            self._attn_steps["decode"][0] += 1
-            self._m_attn_kernel.inc()
-        elif self._attn_requested == "auto":
-            # auto resolved to gather: every decode step is a fallback step
-            self.attn_fallback_steps += 1
-            self._attn_steps["decode"][1] += 1
-            self._m_attn_fallback.inc()
-        if self._goodput is not None and self.attn == "paged":
+        self._note_attn_step()
+        if self._goodput is not None and self._attn_path != "xla":
             # ragged-decode visibility: the bucket's tables span Bb x nbb
             # blocks per step but the kernel's walk streams only each row's
             # live range — per-row ceil(pos / bs) clamped to [1, nbb]
@@ -2212,6 +2099,13 @@ class ServingEngine:
         self._m_occupancy.observe(len(running))
         return rec
 
+    def _note_attn_step(self) -> None:
+        """One decode (or verify) dispatch: a fallback step where its attention
+        call is the XLA form."""
+        if self._attn_path == "xla":
+            self.attn_fallback_steps += 1
+            self._m_attn_fallback.inc()
+
     def _trace_decode_begin(self, running: list, step: int, compiled: bool, bucket: list) -> None:
         """Opens the rows' ``decode`` spans of one decode dispatch (its
         harvest closes them): at the dispatch, or, for a record dispatched
@@ -2223,7 +2117,7 @@ class ServingEngine:
             for r in running:
                 tr.begin(r.rid, "decode", step=step,
                          compile=compiled, bucket=bucket, lane="decode",
-                         attn=self.attn,
+                         attn=self._attn_path,
                          **({"steps": N} if N > 1 else {}))
 
     def _decode_harvest(self, rec: dict) -> None:
@@ -2874,7 +2768,6 @@ class ServingEngine:
                     jnp.asarray([adapter_slot], dtype=jnp.int32),
                     *self._state_args(req.state_slot if req is not None else 0, n_real),
                 )
-                self._note_chunk_attn_step()
             pool.set_arenas(arenas)
             if req is not None:
                 # every real position of a replay piece is recomputation
@@ -2930,8 +2823,7 @@ class ServingEngine:
             if rec.get("spec"):
                 K = self.spec.K
                 gp.account("draft_decode", Bb, K, **{cause: Bb * K})
-                vkind = rec.get("vkind", "verify")
-                gp.account(vkind, Bb, K + 1, **{cause: Bb * (K + 1)})
+                gp.account(rec["vkind"], Bb, K + 1, **{cause: Bb * (K + 1)})
             else:
                 n = rec.get("multi", 1)
                 gp.account(rec["pkind"], Bb, n, **{cause: Bb * n})
@@ -2953,18 +2845,15 @@ class ServingEngine:
         per call so registrations/evictions land without recompiling."""
         return self._registry.arenas if self._registry is not None else {}
 
-    def _static_key(self) -> tuple | None:
+    def _static_key(self) -> tuple:
         """Global program-cache key for everything baked into a bucket
-        program besides its bucket dims — or None (per-engine programs only)
-        when a custom ``model_fn`` makes the closure unkeyable.  Mesh
+        program besides its bucket dims.  Mesh
         engines extend the key with the mesh fingerprint (axis layout +
         device ids), so programs compile once per (mesh, bucket) and a
         different device set never reuses a stale placement.  The LoRA
         component is the registry *geometry* only — adapter ids and factor
         values are program arguments, so a batch mixing tenants can never
         grow the program set."""
-        if self._forward is not forward_with_cache:
-            return None
         import dataclasses
 
         from thunder_tpu.executors.pallasex import paged_available
@@ -2983,17 +2872,17 @@ class ServingEngine:
              tuple(sorted(dataclasses.asdict(self.spec.draft_cfg).items())))
             if self.spec is not None else None,
             # the multi-step horizon: ONE knob joining the key, not
-            # per-horizon buckets; N=1 collapses to None so a decode_steps=1
-            # engine shares the module program cache with default engines
-            self.n_decode_steps if self.n_decode_steps > 1 else None,
+            # per-horizon buckets, and the EOS its scan stops a row at; N=1
+            # collapses to None so a decode_steps=1 engine shares the module
+            # program cache with default engines
+            (self.n_decode_steps, self.eos_id) if self.n_decode_steps > 1 else None,
             # constrained decoding: one boolean knob — schemas/automata are
             # mask ARGUMENTS (the LoRA idiom), so program identity never
             # sees a grammar; off collapses to None for cache sharing
             "constrained" if self._constraints else None,
-            # a latent engine builds the paged decode program with or without
-            # Pallas (the kernel, or its XLA form): which one is the program's
-            ("latent", paged_available()) if self._latent else None,
-            ("perkind", paged_available()) if self._perkind else None,
+            # the paged programs are built with or without Pallas (a kernel, or
+            # its XLA form): which one is the program's
+            paged_available(),
         )
 
     def _program(self, kind: str, a: int, b: int) -> tuple[Callable, bool]:
@@ -3005,24 +2894,21 @@ class ServingEngine:
         prog = self._programs.get(key)
         if prog is not None:
             return prog, False
-        static = self._static_key()
-        gkey = (static, kind, a, b) if static is not None else None
-        prog = _program_cache.get(gkey) if gkey is not None else None
+        gkey = (self._static_key(), kind, a, b)
+        prog = _program_cache.get(gkey)
         compiled = prog is None
         if compiled and self._perkind and kind not in ("prefill_fresh", "decode_paged"):
             raise NotImplementedError(
                 f"program kind {kind!r} is not built for per-kind caches (config {getattr(self.cfg, 'name', '?')!r}): "
                 "a whole prompt at position 0 (prefill_fresh) and one token a row (decode_paged) are")
         if compiled:
-            if kind in ("spec_prefill", "spec_prefill_chunk", "draft_decode",
-                        "verify", "verify_paged"):
+            if kind in ("spec_prefill", "spec_prefill_chunk", "draft_decode", "verify_paged"):
                 from thunder_tpu.serving import speculative as _spec_mod
 
                 build = partial({
                     "spec_prefill": _spec_mod.build_spec_prefill,
                     "spec_prefill_chunk": _spec_mod.build_spec_prefill_chunk,
                     "draft_decode": _spec_mod.build_draft_decode,
-                    "verify": _spec_mod.build_verify,
                     "verify_paged": _spec_mod.build_verify_paged,
                 }[kind], self)
             else:
@@ -3030,9 +2916,7 @@ class ServingEngine:
                          "prefill_fresh": partial(self._build_prefill, fresh=True),
                          "prefill_chunk": self._build_prefill_chunk,
                          "prefill_chunk_paged": self._build_prefill_chunk_paged,
-                         "decode": self._build_decode,
                          "decode_paged": self._build_decode_paged,
-                         "decode_multi": self._build_decode_multi,
                          "decode_multi_paged": self._build_decode_multi_paged,
                          }[kind]
             prog = build(a, b)
@@ -3041,15 +2925,14 @@ class ServingEngine:
             self._compile_log.append({"kind": kind, "bucket": [a, b],
                                       "cause": f"new {kind} geometry"})
             registry().counter(f"serving.compiles.{kind}").inc()
-            if gkey is not None:
-                # LRU-ish bound (the _generate_cache idiom).  64, not 32: a
-                # multi-tenant deployment legitimately runs several static
-                # configs at once (f32 + int8 pools, per-registry-geometry
-                # LoRA variants), and evicting a live config's programs
-                # re-pays its compiles on the next request
-                if len(_program_cache) >= 64:
-                    _program_cache.pop(next(iter(_program_cache)))
-                _program_cache[gkey] = prog
+            # LRU-ish bound (the _generate_cache idiom).  64, not 32: a
+            # multi-tenant deployment legitimately runs several static
+            # configs at once (f32 + int8 pools, per-registry-geometry
+            # LoRA variants), and evicting a live config's programs
+            # re-pays its compiles on the next request
+            if len(_program_cache) >= 64:
+                _program_cache.pop(next(iter(_program_cache)))
+            _program_cache[gkey] = prog
         self._programs[key] = prog
         return prog, compiled
 
@@ -3069,9 +2952,7 @@ class ServingEngine:
                 draft_arena_sh=self.draft_pool.arena_sharding,
             )
         kw = program_shardings(kind, self.params, self.mesh, self.pool.arena_sharding)
-        if self._constraints and kind in (
-                "prefill", "prefill_fresh", "decode", "decode_paged",
-                "decode_multi", "decode_multi_paged"):
+        if self._constraints and kind in ("prefill", "prefill_fresh", "decode_paged", "decode_multi_paged"):
             # the trailing constraint-mask argument is replicated like every
             # other small host-built per-step array
             from jax.sharding import NamedSharding, PartitionSpec
@@ -3086,15 +2967,12 @@ class ServingEngine:
         extra AOT compile, so it is cached module-wide next to the program
         cache — one census per (mesh, static config, bucket) per process —
         and mirrored into the ``serving.mesh.collectives.decode`` gauge."""
-        static = self._static_key()
-        gkey = ("collectives", static, *bucket_key) if static is not None else None
-        got = _collectives_cache.get(gkey) if gkey is not None else None
+        gkey = ("collectives", self._static_key(), *bucket_key)
+        got = _collectives_cache.get(gkey)
         if got is None:
             from thunder_tpu.serving.mesh import collective_counts
 
-            got = collective_counts(prog, *example_args)
-            if gkey is not None:
-                _collectives_cache[gkey] = got
+            got = _collectives_cache[gkey] = collective_counts(prog, *example_args)
         registry().gauge("serving.mesh.collectives.decode").set(got.get("total", 0))
         return got
 
@@ -3170,11 +3048,10 @@ class ServingEngine:
         there: for a prompt at 0 that fills it, the same slots).  Everything
         else is the ``prefill`` kind's.
 
-        The in-tree forward projects row ``n_real - 1`` alone onto the
-        vocabulary (``logits_at``) and, under a mesh, keeps the prompt's
-        attention off the flash kernel (``sharded``); a custom ``model_fn``
-        is called as before and its full logits indexed."""
-        cfg, fwd, temp = self.cfg, self._forward, self.temperature
+        The forward projects row ``n_real - 1`` alone onto the vocabulary
+        (``logits_at``) and, under a mesh, keeps the prompt's attention off the
+        flash kernel (``sharded``)."""
+        cfg, temp = self.cfg, self.temperature
         hybrid = self._hybrid
         cdtype = jnp.dtype(self.pool.dtype)
         cap = self.pool.capacity_tokens(nbb)
@@ -3183,7 +3060,7 @@ class ServingEngine:
         # other kinds' are built at: a bucket adds no eager program of its own
         cos_all, sin_all = build_rope_cache(
             cfg, self.pool.capacity_tokens(self._nbb(nbb)) if fresh else cap)
-        in_tree, sharded = fwd is forward_with_cache, self.mesh is not None
+        sharded = self.mesh is not None
 
         def run(params, toks, pos, n_real, arenas, table, dest, key, lora, slot, cmask):
             held, more = {}, {}
@@ -3198,20 +3075,18 @@ class ServingEngine:
                     # a prompt's first piece starts from zeros, whatever the slot's
                     # last owner left, and the padded tail leaves the state alone
                     sslot, cmask = cmask[0], cmask[1:]
-                    if fresh:       # zeros without a read (a hybrid engine serves the in-tree forward only)
+                    if fresh:       # zeros without a read
                         held = {name: jnp.zeros(shape, arenas[name].dtype)
                                 for name, shape in state_shapes(cfg, 1).items()}
                     else:
                         held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
                     more = {"n_real": n_real}
-            own = {"logits_at": n_real - 1, "sharded": sharded} if in_tree else {}
-            logits, cache = fwd(
+            logits, cache = forward_with_cache(
                 params, toks, pos, {**dense, **held}, cos_all, sin_all, cfg,
-                **self._fwd_kwargs(lora, slot), **more, **own,
+                **self._fwd_kwargs(lora, slot), **more, logits_at=n_real - 1, sharded=sharded,
             )
             with scope("head/sample"):
-                last = logits[:, 0] if in_tree else jax.lax.dynamic_index_in_dim(
-                    logits, n_real - 1, axis=1, keepdims=False)
+                last = logits[:, 0]
                 if cmask:
                     last = jnp.where(cmask[0], last, -jnp.inf)
                 key, sub = jax.random.split(key)
@@ -3245,7 +3120,7 @@ class ServingEngine:
         bit-identical to an unchunked prefill).  The logits head is traced
         but unused, so XLA dead-code-eliminates the lm_head matmul — a
         chunk is strictly cheaper than a same-width prefill."""
-        cfg, fwd = self.cfg, self._forward
+        cfg = self.cfg
         hybrid = self._hybrid
         cdtype = jnp.dtype(self.pool.dtype)
         cap = self.pool.capacity_tokens(nbb)
@@ -3261,7 +3136,7 @@ class ServingEngine:
                 sslot, n_real = state_args
                 held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
                 more = {"n_real": n_real}
-            _logits, cache = fwd(
+            _logits, cache = forward_with_cache(
                 params, toks, pos, {**dense, **held}, cos_all, sin_all, cfg,
                 **self._fwd_kwargs(lora, slot), **more,
             )
@@ -3276,10 +3151,10 @@ class ServingEngine:
         same returns — but the chunk's attention runs the multi-query paged
         kernel straight off the arenas (earlier chunks' KV is read in block
         granules with the causal intra-chunk mask fused in-kernel) and the
-        chunk's fresh K/V lands via the block-granule chunk writer, so the
-        compiled program contains zero arena gather/scatter primitives (the
-        purity census asserts this with the gather chunk program as positive
-        control).  Quantized pools take the fused absmax quantize-on-write
+        chunk's fresh K/V lands via the block-granule chunk writer, so with
+        the kernel in it the compiled program contains zero arena
+        gather/scatter primitives (the purity census asserts this with the
+        gather chunk program as positive control).  Quantized pools take the fused absmax quantize-on-write
         epilogue; LoRA deltas run the fused kernel when meshless.  Only
         built when the construction-time chunk resolution picked "paged"
         (block-aligned chunk widths, no sliding window)."""
@@ -3317,90 +3192,23 @@ class ServingEngine:
 
         return prefill_chunk_paged
 
-    def _build_decode(self, Bb: int, nbb: int) -> Callable:
-        cfg, fwd, temp = self.cfg, self._forward, self.temperature
-        hybrid = self._hybrid
-        qkv = self.pool.quantized_kv
-        cdtype = jnp.dtype(self.pool.dtype)
-        bs = self.pool.block_size
-        cap = self.pool.capacity_tokens(nbb)
-        cos_all, sin_all = build_rope_cache(cfg, cap)
-
-        # The scatter destination is DERIVED inside the program (block =
-        # table[pos // bs], slot = pos % bs) and the program returns pos+1,
-        # so a steady-state decode step consumes only its predecessor's
-        # device outputs (toks=nxt, keys=new_keys, pos=new_pos) plus the
-        # cached tables/slots — zero host->device transfers per step (the
-        # engine's _decode_state chain).  Padding rows carry all-sink
-        # tables, and out-of-range block indices clamp to the row's last
-        # (sink) entry, so derived destinations stay sink-routed.
-        # Constrained engines pass one trailing ``(Bb, V)`` bool mask
-        # (all-True rows are a bit-exact no-op); plain engines pass nothing.
-        @partial(jax.jit, donate_argnums=(4,), **self._jit_kwargs("decode"))
-        def decode(params, toks, pos, tables, arenas, keys, lora, slots, *cmask):
-            held = {}
-            with scope("mixer/cache"):
-                dest_block = jnp.take_along_axis(
-                    tables, (pos // bs)[:, None], axis=1)[:, 0]
-                dest_slot = pos % bs
-                if qkv:
-                    kd, vd = gather_dense_q(
-                        arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
-                        tables, cdtype,
-                    )
-                else:
-                    kd, vd = gather_dense(arenas["k"], arenas["v"], tables, self.pool.lane_pack)
-                if hybrid:
-                    # the rows' state slots ride before the constraint mask
-                    sslots, cmask = cmask[0], cmask[1:]
-                    held = gather_state(arenas, sslots, jnp.zeros((Bb,), bool))
-            logits, cache = fwd(
-                params, toks[:, None], pos, {"k": kd, "v": vd, **held}, cos_all, sin_all, cfg,
-                **self._fwd_kwargs(lora, slots),
-            )
-            with scope("head/sample"):
-                sp = jax.vmap(jax.random.split)(keys)      # per-request key chains
-                new_keys, subs = sp[:, 0], sp[:, 1]
-                lg = logits[:, 0]
-                if cmask:
-                    lg = jnp.where(cmask[0], lg, -jnp.inf)
-            # (1, V) per row under vmap == the unbatched B=1 generate() draw
-            nxt = jax.vmap(lambda l, k: sample_token(l[None], temp, k)[0])(
-                lg, subs
-            )
-            with scope("mixer/cache"):
-                kc = cache["k"].transpose(1, 0, 2, 3, 4)   # (B, L, ng, cap, hs)
-                vc = cache["v"].transpose(1, 0, 2, 3, 4)
-                pick = jax.vmap(
-                    lambda c, p: jax.lax.dynamic_index_in_dim(c, p, axis=2, keepdims=False)
-                )
-                if qkv:
-                    # the picked values are THIS step's freshly computed K/V (the
-                    # dense cache write at pos), so quantize-on-scatter sees exact
-                    # inputs — no requantization drift across steps
-                    k_arena, k_scale = scatter_token_q(
-                        arenas["k"], arenas["k_scale"], pick(kc, pos), dest_block, dest_slot)
-                    v_arena, v_scale = scatter_token_q(
-                        arenas["v"], arenas["v_scale"], pick(vc, pos), dest_block, dest_slot)
-                    new = {"k": k_arena, "v": v_arena,
-                           "k_scale": k_scale, "v_scale": v_scale}
-                else:
-                    new = {"k": scatter_token(arenas["k"], pick(kc, pos), dest_block, dest_slot),
-                           "v": scatter_token(arenas["v"], pick(vc, pos), dest_block, dest_slot)}
-                if hybrid:
-                    new.update(scatter_state(arenas, cache, sslots))
-            return nxt, new_keys, pos + 1, new
-
-        return decode
-
     def _build_decode_paged(self, Bb: int, nbb: int) -> Callable:
-        """The kernel twin of :meth:`_build_decode`: same signature, same
-        sampling/key-chain math, same returns — but attention runs the
-        Pallas paged kernel straight off the arenas (scalar-prefetch block
-        tables, in-kernel keep-mask + dequant) and the fresh token lands via
-        the aliased write kernel, so the compiled program contains zero
-        gather/scatter primitives (tests assert this on the jaxpr) and no
-        dense cache ever materializes."""
+        """The decode step: one token a row, sampled per row (under ``vmap`` the
+        unbatched ``generate()`` draw, each request on its own key chain).
+        Attention runs the paged kernel straight off the arenas
+        (scalar-prefetch block tables, in-kernel keep-mask + dequant) and the
+        fresh token lands via the aliased write kernel, so with the kernel in it
+        the compiled program contains zero gather/scatter primitives (tests
+        assert this on the jaxpr) and no dense cache ever materializes; the
+        kernel's XLA form gathers one layer's rows (``paged_attention``).
+
+        The write's destination is derived inside the program (block =
+        ``table[pos // bs]``, slot = ``pos % bs``) and the program returns
+        ``pos + 1``, so a steady-state step consumes only its predecessor's
+        device outputs plus the cached tables/slots: zero host->device transfers
+        a step (the engine's ``_decode_state`` chain).  Padding rows carry
+        all-sink tables.  Constrained engines pass one trailing ``(Bb, V)`` bool
+        mask (all-True rows are a bit-exact no-op); plain engines pass nothing."""
         from thunder_tpu.serving.paged_attention import forward_paged, with_state, write_fresh_kv
 
         cfg, temp = self.cfg, self.temperature
@@ -3441,110 +3249,28 @@ class ServingEngine:
 
         return decode_paged
 
-    def _build_decode_multi(self, Bb: int, nbb: int) -> Callable:
-        """N decode steps per host visit: the single-step decode body
-        wrapped in a ``lax.scan`` with in-program stopping.
+    def _build_decode_multi_paged(self, Bb: int, nbb: int) -> Callable:
+        """N decode steps per host visit: the single-step decode body wrapped
+        in a ``lax.scan`` with in-program stopping.
 
         Per-row liveness: a row is live while ``pos <= stop`` and no EOS has
         been sampled (``stop = prompt_len + max_new_tokens - 2`` is the last
         position a row may write — exactly the position at which the
         single-step engine's :meth:`_emit_token` fires FINISH_LENGTH on the
         resulting token).  A dead row keep-masks its KV write to the sink
-        block (:func:`dest_for_pos`), freezes ``pos`` and ``toks``, and
+        block (``write_fresh_kv_live``), freezes ``pos`` and ``toks``, and
         stops splitting its PRNG key — so the per-request key chain advances
         exactly once per *emitted* token, preserving the harvest-time
         key-advance contract that makes fault-recovery replay bit-identical.
-        Padding rows enter with ``stop = -1`` and are dead from step 0.
+        Padding rows enter with ``stop = -1`` and are dead from step 0.  Each
+        iteration runs the paged kernel straight off the arenas, so with the
+        kernel in it the N-step program contains zero arena gather/scatter
+        primitives.
 
         Returns the scan's stacked ``(ys_tok, ys_emit)`` — the (N, Bb)
         token matrix and liveness mask the harvest reads — plus the final
         ``(toks, keys, pos)`` carry for the engine's ``_decode_state``
         device-to-device chain, and the donated arenas."""
-        cfg, fwd, temp = self.cfg, self._forward, self.temperature
-        qkv = self.pool.quantized_kv
-        cdtype = jnp.dtype(self.pool.dtype)
-        bs = self.pool.block_size
-        cap = self.pool.capacity_tokens(nbb)
-        cos_all, sin_all = build_rope_cache(cfg, cap)
-        eos = self.eos_id
-        N = self.n_decode_steps
-
-        @partial(jax.jit, donate_argnums=(4,),
-                 **self._jit_kwargs("decode_multi"))
-        def decode_multi(params, toks, pos, tables, arenas, keys, lora, slots, stop,
-                         *cmask):
-            kw = self._fwd_kwargs(lora, slots)   # LoRA gather once per visit
-            live0 = pos <= stop
-
-            def body(carry, step_mask):
-                toks, pos, keys, live, arenas = carry
-                dest_block, dest_slot = dest_for_pos(
-                    tables, pos, live, block_size=bs)
-                if qkv:
-                    kd, vd = gather_dense_q(
-                        arenas["k"], arenas["v"],
-                        arenas["k_scale"], arenas["v_scale"], tables, cdtype,
-                    )
-                else:
-                    kd, vd = gather_dense(arenas["k"], arenas["v"], tables, self.pool.lane_pack)
-                logits, cache = fwd(
-                    params, toks[:, None], pos, {"k": kd, "v": vd},
-                    cos_all, sin_all, cfg, **kw,
-                )
-                sp = jax.vmap(jax.random.split)(keys)
-                new_keys = jnp.where(live[:, None], sp[:, 0], keys)
-                lg = logits[:, 0]
-                if cmask:
-                    lg = jnp.where(step_mask, lg, -jnp.inf)
-                nxt = jax.vmap(lambda l, k: sample_token(l[None], temp, k)[0])(
-                    lg, sp[:, 1]
-                )
-                kc = cache["k"].transpose(1, 0, 2, 3, 4)
-                vc = cache["v"].transpose(1, 0, 2, 3, 4)
-                pick = jax.vmap(
-                    lambda c, p: jax.lax.dynamic_index_in_dim(
-                        c, p, axis=2, keepdims=False)
-                )
-                if qkv:
-                    k_arena, k_scale = scatter_token_q(
-                        arenas["k"], arenas["k_scale"], pick(kc, pos),
-                        dest_block, dest_slot)
-                    v_arena, v_scale = scatter_token_q(
-                        arenas["v"], arenas["v_scale"], pick(vc, pos),
-                        dest_block, dest_slot)
-                    new_arenas = {"k": k_arena, "v": v_arena,
-                                  "k_scale": k_scale, "v_scale": v_scale}
-                else:
-                    new_arenas = {
-                        "k": scatter_token(arenas["k"], pick(kc, pos),
-                                           dest_block, dest_slot),
-                        "v": scatter_token(arenas["v"], pick(vc, pos),
-                                           dest_block, dest_slot)}
-                done = pos >= stop
-                if eos is not None:
-                    done = done | (nxt == eos)
-                toks_n = jnp.where(live, nxt, toks)
-                pos_n = jnp.where(live, pos + 1, pos)
-                live_n = live & ~done
-                return (toks_n, pos_n, new_keys, live_n, new_arenas), (nxt, live)
-
-            # the constraint masks are scan xs: one (Bb, V) slice per step,
-            # computed host-side from the exact masks(N) lookahead
-            (toks_f, pos_f, keys_f, _live_f, arenas), (ys_tok, ys_emit) = (
-                jax.lax.scan(body, (toks, pos, keys, live0, arenas),
-                             cmask[0] if cmask else None, length=N))
-            return ys_tok, ys_emit, toks_f, keys_f, pos_f, arenas
-
-        return decode_multi
-
-    def _build_decode_multi_paged(self, Bb: int, nbb: int) -> Callable:
-        """The kernel twin of :meth:`_build_decode_multi`: same scan, same
-        liveness/key-chain math, but each iteration runs the Pallas paged
-        kernel straight off the arenas and folds the fresh token K/V back
-        in via the masked write kernel (live rows commit at ``pos``, dead
-        rows keep-mask to the sink block) — so the compiled N-step program
-        still contains zero arena gather/scatter primitives (the purity
-        census asserts this with the gather program as positive control)."""
         from thunder_tpu.serving.paged_attention import (
             forward_paged,
             write_fresh_kv_live,
@@ -3603,8 +3329,8 @@ class ServingEngine:
 
 
 def serve(model_fn, params, cfg, **kwargs) -> ServingEngine:
-    """Builds a :class:`ServingEngine` over ``model_fn`` (``None`` → the
-    in-tree ``models.generate.forward_with_cache``).  See
+    """Builds a :class:`ServingEngine` over the in-tree forward of ``cfg`` (a
+    ``llama.Config`` is the model; ``model_fn`` must be ``None``).  See
     :class:`ServingEngine` for the knobs; nothing about constructing an
     engine touches any other compiled program (strictly additive).
 
@@ -3625,16 +3351,16 @@ def serve(model_fn, params, cfg, **kwargs) -> ServingEngine:
     mix tenants, and the compiled-program set grows only with the registry
     *geometry* (rank, slots, targets), never with adapter ids.
 
-    Paged-attention decode: ``attn="paged"`` runs decode through the Pallas
-    flash-decoding kernel straight off the KV block arena (scalar-prefetch
-    block tables, in-kernel keep-mask and int8/fp8 dequant, aliased
-    in-place fresh-token write) — the compiled decode program contains zero
-    gather/scatter primitives and no dense cache copy.  ``attn="auto"``
-    (default) takes the kernel when structurally supported and Pallas is
-    enabled (TPU, or ``THUNDER_TPU_PALLAS_INTERPRET=1`` for interpret mode
-    on CPU), else falls back to the gather path, counting
-    ``serving.attn.fallback_steps``; ``attn="gather"`` pins the dense
-    gather/scatter pair.  Served tokens are bit-identical across all three.
+    Paged-attention decode: there is one decode program.  Its attention call
+    is the Pallas flash-decoding kernel straight off the KV block arena
+    (scalar-prefetch block tables, in-kernel keep-mask and int8/fp8 dequant,
+    aliased in-place fresh-token write: zero gather/scatter primitives and no
+    dense cache copy) wherever the kernel compiles: the TPU, or a CPU that
+    opted into the interpreter with ``THUNDER_TPU_PALLAS_INTERPRET=1``.
+    Elsewhere the same call is its XLA form, and every such decode step counts
+    in ``stats()["attn"]["fallback_steps"]`` (``serving.attn.fallback_steps``);
+    ``stats()["attn"]["path"]`` reads ``"walk"``, ``"by_blocks"`` or ``"xla"``.
+    Served tokens are bit-identical either way.
 
     Multi-step decode: ``decode_steps=N`` runs N decode steps per host
     visit inside one compiled program (a ``lax.scan`` over the decode body
@@ -3702,6 +3428,10 @@ def serve(model_fn, params, cfg, **kwargs) -> ServingEngine:
     byte-identical to today's (the module program cache is shared either
     way).  See :mod:`thunder_tpu.serving.router` for routing semantics
     and the multi-host (process-0) caveat."""
+    if model_fn is not None:
+        raise NotImplementedError(
+            "tt.serve serves the in-tree forward of a llama.Config: describe the model as a Config "
+            "(layer kinds, widths) and pass None here; a custom model_fn has no paged decode program")
     replicas = kwargs.pop("replicas", None)
     fault_plans = kwargs.pop("fault_plans", None)
     mesh = kwargs.get("mesh")
@@ -3722,11 +3452,10 @@ def serve(model_fn, params, cfg, **kwargs) -> ServingEngine:
                 f"replicas={n} with a mesh requires a 'dp' axis to split "
                 f"on (axes: {mesh.axis_names})"
             )
-        return ReplicatedEngine(params, cfg, model_fn=model_fn, replicas=n,
-                                fault_plans=fault_plans, **kwargs)
+        return ReplicatedEngine(params, cfg, replicas=n, fault_plans=fault_plans, **kwargs)
     if fault_plans is not None:
         raise ValueError(
             "fault_plans= is the per-replica form; a solo engine takes "
             "fault_plan="
         )
-    return ServingEngine(params, cfg, model_fn=model_fn, **kwargs)
+    return ServingEngine(params, cfg, **kwargs)

@@ -1,14 +1,16 @@
 """Every serving Pallas kernel against the jnp program it replaces.
 
-The engine-level differential tests (paged tokens == gather tokens == solo
-``generate()``) run the kernels under the Pallas interpreter, which accepts
-block shapes and ops the TPU compiler refuses and proves nothing about what
-the compiled kernel computes.  :func:`run_checks` calls each kernel directly
-on random arenas and compares with the gather path's own building blocks
-(``kv_pool.gather_dense`` / ``scatter_token`` / ``scatter_blocks``, their
-``quant`` twins, ``generate._lora_delta``), so the same code is the CPU test
-(interpreted, tiny shapes) and the on-chip check (``chip_smoke.py``,
-Mistral-7B widths).
+The engine-level differential tests (served tokens with the kernels == with
+their XLA forms == solo ``generate()``) run the kernels under the Pallas
+interpreter, which accepts block shapes and ops the TPU compiler refuses and
+proves nothing about what the compiled kernel computes.  :func:`run_checks`
+calls each kernel directly on random arenas and compares with the dense
+cache's own building blocks (``kv_pool.gather_dense`` / ``scatter_token`` /
+``scatter_blocks``, their ``quant`` twins, ``generate._lora_delta``), so the
+same code is the CPU test (interpreted, tiny shapes) and the on-chip check
+(``chip_smoke.py``, Mistral-7B widths).  The two attention entries' XLA form
+(``pallasex.paged_attn_xla``: what they take where Pallas is off) is held to
+the same reference in a row of its own beside each kernel's.
 
 Tolerances, by kind of check:
 
@@ -139,18 +141,22 @@ def run_checks(*, n_head, n_query_groups, head_size, block_size, n_layer=2,
     for store, w in cases:
         ka, va, ks, vs = stores[store]
         kd, vd = dense(store)
+        xla = jax.jit(functools.partial(px.paged_attn_xla, layer=layer, window=w))
         got = jax.jit(functools.partial(px.paged_attn_decode, layer=layer, window=w))(
             q1, ka, va, fk1, fv1, tables, pos, k_scale=ks, v_scale=vs)
         ref = _ref_attend(q1[:, :, None], kd, vd, fk1[:, :, None], fv1[:, :, None], pos, w)
         tag = "_".join(x for x in (store, "window" if w else "") if x)
-        row(f"paged_attn_decode{'/' + tag if tag else ''}",
-            jnp.max(jnp.abs(got.astype(jnp.float32) - ref[:, :, 0])), 8 * eps)
+        tag = "/" + tag if tag else ""
+        row(f"paged_attn_decode{tag}", jnp.max(jnp.abs(got.astype(jnp.float32) - ref[:, :, 0])), 8 * eps)
+        got = xla(q1[:, :, None], ka, va, fk1[:, :, None], fv1[:, :, None], tables, pos, k_scale=ks, v_scale=vs)
+        row(f"paged_attn_xla/decode{tag}", jnp.max(jnp.abs(got.astype(jnp.float32) - ref)), 8 * eps)
         if w is None:
             got = jax.jit(functools.partial(px.paged_attn_verify, layer=layer))(
                 qT, ka, va, fkT, fvT, tables, pos, k_scale=ks, v_scale=vs)
             ref = _ref_attend(qT, kd, vd, fkT, fvT, pos, None)
-            row(f"paged_attn_verify{'/' + store if store else ''}",
-                jnp.max(jnp.abs(got.astype(jnp.float32) - ref)), 8 * eps)
+            row(f"paged_attn_verify{tag}", jnp.max(jnp.abs(got.astype(jnp.float32) - ref)), 8 * eps)
+            got = xla(qT, ka, va, fkT, fvT, tables, pos, k_scale=ks, v_scale=vs)
+            row(f"paged_attn_xla/verify{tag}", jnp.max(jnp.abs(got.astype(jnp.float32) - ref)), 8 * eps)
 
     # token writes: plain, keep-masked at a chunk offset, rank-4 scale arena
     vals = rnd(B, L, ng, hs)

@@ -147,20 +147,19 @@ def program_shardings(kind: str, params, mesh: Mesh, arena_sh: NamedSharding,
     like the data arenas, so the spec applies to both ranks.
 
     Argument orders match ``ServingEngine._build_prefill`` /
-    ``_build_prefill_chunk`` / ``_build_decode`` exactly:
+    ``_build_prefill_chunk`` / ``_build_decode_paged`` exactly:
 
     - prefill: ``(params, toks, pos, n_real, arenas, table, dest, key,
       lora, slot)`` → ``(tok, arenas, key, qerr)``
     - prefill_fresh: prefill's row without ``pos`` and ``table``
     - prefill_chunk: ``(params, toks, pos, arenas, table, dest, lora,
       slot)`` → ``(arenas, qerr)``
-    - decode:  ``(params, toks, pos, tables, arenas, keys, lora, slots)``
-      → ``(nxt, new_keys, new_pos, arenas)`` (scatter destinations are
+    - decode_paged:  ``(params, toks, pos, tables, arenas, keys, lora,
+      slots)`` → ``(nxt, new_keys, new_pos, arenas)`` (write destinations are
       derived in-program from ``tables``/``pos``, and the returned device
-      outputs chain into the next step's inputs)
-    - decode_paged: same row as decode — the kernel path keeps the exact
-      decode signature/returns; inside the program the paged kernels run
-      under ``shard_map`` with heads-local specs matching ``arena_sh``
+      outputs chain into the next step's inputs); inside the program the
+      paged kernels run under ``shard_map`` with heads-local specs matching
+      ``arena_sh``
 
     Donation composes with the async engine's deferred materialization:
     the returned arena pytree carries the same per-shard sharding in and
@@ -189,12 +188,12 @@ def program_shardings(kind: str, params, mesh: Mesh, arena_sh: NamedSharding,
             in_shardings=(param_sh, repl, repl, arena_sh, repl, repl, repl, repl),
             out_shardings=(arena_sh, repl),
         )
-    if kind in ("decode", "decode_paged"):
+    if kind == "decode_paged":
         return dict(
             in_shardings=(param_sh, repl, repl, repl, arena_sh, repl, repl, repl),
             out_shardings=(repl, repl, repl, arena_sh),
         )
-    if kind in ("decode_multi", "decode_multi_paged"):
+    if kind == "decode_multi_paged":
         # the decode row plus the replicated per-row stop positions:
         # (params, toks, pos, tables, arenas, keys, lora, slots, stop)
         #   -> (ys_tok, ys_emit, toks_f, keys_f, pos_f, arenas) — the
@@ -232,7 +231,7 @@ def program_shardings(kind: str, params, mesh: Mesh, arena_sh: NamedSharding,
             in_shardings=(dparam_sh, repl, repl, repl, draft_arena_sh, repl),
             out_shardings=(repl, repl, repl, draft_arena_sh),
         )
-    assert kind in ("verify", "verify_paged"), kind
+    assert kind == "verify_paged", kind
     # (params, toks, pos, tables, arenas, drafts, q_rows, keys, lora,
     #  slots) -> (emitted, n_emit, y, new_keys, new_pos, arenas)
     return dict(

@@ -1,9 +1,5 @@
 """Paged-attention decode: the serving forward that never densifies the KV.
 
-The gather decode path (``engine._build_decode``) reassembles every
-request's KV from the block arena into the dense ``forward_with_cache``
-layout and scatters the fresh token back — one full-cache copy per token
-per request, in *both* directions.  This module is the kernel-backed twin:
 :func:`forward_paged` runs the same per-layer math as
 ``models.generate.forward_with_cache`` (norms, QKV projection + rope, LoRA
 deltas, MLP, head) but attention reads K/V **directly from the arena** via
@@ -11,22 +7,27 @@ deltas, MLP, head) but attention reads K/V **directly from the arena** via
 table, positional keep-mask and int8/fp8 dequant fused in-kernel), and
 :func:`write_fresh_kv` lands the step's fresh K/V in place via
 ``paged_token_write`` — so the compiled decode program contains zero
-gather/scatter primitives (asserted in tests/test_paged_attention.py).
+gather/scatter primitives (asserted in tests/test_paged_attention.py).  It is
+the one decode program: where the kernel does not run (Pallas off, or an
+arena it does not take: :func:`decode_path`) the attention call is its XLA
+form, ``pallasex.paged_attn_xla``, which gathers the rows' blocks of one layer
+and ends in the dense cache's own attention.
 
 Parity contract (the serving bit-exactness bar): the kernel scores the
 arena's strictly-older slots and folds the *fresh* token — at the cache
-compute dtype, exactly what the dense path would have just written — as the
-final online-softmax term, so greedy/temperature tokens match the gather
-path and solo ``generate()`` across f32/bf16 caches, int8/fp8 KV, LoRA
-mixes, and meshes.  Quantization happens outside the kernels with the same
-``quant.quantize_kv`` call ``scatter_token_q`` uses, so stored bytes are
-bit-identical too.
+compute dtype, exactly what the dense cache would have just written — as the
+final online-softmax term, so greedy/temperature tokens match solo
+``generate()`` across f32/bf16 caches, int8/fp8 KV, LoRA mixes, and meshes.
+Quantization happens with ``quant.quantize_kv``'s math, so stored bytes are
+what ``scatter_token_q`` would store.
 
 Mesh: the kernels are plain ``pallas_call``s with no SPMD rule, so under a
 mesh each call is wrapped in ``jax.shard_map`` over the ``tp`` axis with
 heads-local specs matching ``distributed.kv_cache_spec`` (arena heads at
-axis 2, query heads at axis 1) — attention stays device-local, exactly like
-the gathered path.
+axis 2, query heads at axis 1) — attention stays device-local.  Where the
+heads do not split over ``tp`` (``kv_cache_spec`` replicates the arena then)
+the attention call is the XLA form outside ``shard_map`` and the writers run
+on each device's whole copy.
 """
 from __future__ import annotations
 
@@ -42,16 +43,15 @@ from thunder_tpu.executors.pallasex import (
     mla_paged_decode,
     paged_attn_decode,
     paged_attn_verify,
+    paged_attn_xla,
     paged_chunk_write,
     paged_chunk_write_fused,
+    paged_decode_path,
     paged_token_write,
-    paged_available,
-    paged_walk_lanes_ok,
     paged_token_write_fused,
     ssm_decode_step,
 )
 from thunder_tpu.models.generate import (
-    diff_attend_dense,
     diff_attention,
     gdn_mixer,
     gmu_mixer,
@@ -71,11 +71,11 @@ from thunder_tpu.models.generate import (
     _project_qkv,
 )
 from thunder_tpu.observability.events import scope
-from thunder_tpu.serving.kv_pool import gather_rows, ring_tables
+from thunder_tpu.serving.kv_pool import ring_tables
 from thunder_tpu.serving.quant import quantize_kv
 
 __all__ = ["forward_paged", "with_state", "write_fresh_kv", "write_fresh_kv_live",
-           "write_fresh_kv_masked", "write_fresh_kv_chunk", "paged_supported"]
+           "write_fresh_kv_masked", "write_fresh_kv_chunk", "decode_path"]
 
 
 def _smap(fn, mesh, in_specs, out_specs):
@@ -85,102 +85,60 @@ def _smap(fn, mesh, in_specs, out_specs):
     return shard_map_compat(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
-def paged_supported(cfg, model_fn_is_default: bool, mesh=None, arena_lanes: int | None = None) -> tuple[bool, str]:
-    """Structural support check for the paged decode path: ``(ok, why)``.
+def _tp_axis(mesh, *heads) -> str | None:
+    """``"tp"`` where the mesh's ``tp`` axis splits head counts ``heads`` the way
+    ``kv_cache_spec`` lays the arena out (the kernels then run on a device's own
+    heads), else None: the arena is replicated, and a spec that names ``tp``
+    would disagree with it."""
+    if "tp" in mesh.axis_names and all(h % int(mesh.shape["tp"]) == 0 for h in heads):
+        return "tp"
+    return None
 
-    The kernel mirrors ``forward_with_cache``'s math, so a custom
-    ``model_fn`` can't ride it; compiled for the TPU, the decode kernel's
-    windowed walk needs arena rows of whole 128-lane tiles (``arena_lanes``: the
-    pool's, where the caller has built one; else what the pool would lay out
-    for this config on one device: a head of whole tiles, or a head that
-    divides 128 with its KV heads in whole rows, lane-packed).  The heads that
-    still take the per-block kernel, which has no window: 96 and the like, a
-    narrow head in a quantised or sharded arena, or one whose KV heads do not
-    fill rows.  And under a mesh the
-    heads must actually shard over ``tp`` the way ``kv_cache_spec`` lays the
-    arena out (a degraded/replicated spec would silently disagree with the
-    shard_map specs here)."""
-    if not model_fn_is_default:
-        return False, "custom model_fn (kernel mirrors forward_with_cache)"
+
+def decode_path(cfg, mesh=None, arena_lanes: int | None = None) -> str:
+    """The form the decode program's attention call takes for this config:
+    ``"walk"``, ``"by_blocks"`` or ``"xla"`` (``pallasex.paged_decode_path`` of the
+    arena's rows: ``arena_lanes``, the pool's where the caller has built one,
+    else what the pool would lay out on one device: a head of whole tiles, or
+    a head that divides 128 with its KV heads in whole rows, lane-packed), and
+    ``"xla"`` under a mesh whose ``tp`` axis does not split the heads."""
+    if mesh is not None and _tp_axis(mesh, cfg.n_head, cfg.n_query_groups) is None:
+        return "xla"
     if arena_lanes is None:
         arena_lanes = cfg.head_size * (1 if mesh is not None else kv_lane_pack(cfg))
-    if cfg.sliding_window is not None and not paged_walk_lanes_ok(arena_lanes):
-        return False, (
-            f"head_size={cfg.head_size} in arena rows of {arena_lanes} lanes (not whole 128-lane tiles) and "
-            "the model has a sliding window: the per-block decode kernel has none")
-    if mesh is not None:
-        if "tp" not in mesh.axis_names:
-            return False, "mesh has no tp axis"
-        tp = int(mesh.shape["tp"])
-        if tp > 1 and (cfg.n_query_groups % tp != 0 or cfg.n_head % tp != 0):
-            return False, (
-                f"heads do not shard: n_head={cfg.n_head} "
-                f"n_query_groups={cfg.n_query_groups} vs tp={tp}"
-            )
-    return True, ""
+    return paged_decode_path(arena_lanes, cfg.sliding_window)
 
 
-def _attn_paged(q, arenas, fresh_k, fresh_v, tables, pos, *, layer, window, mesh):
-    """One layer's kernel call, shard_map-wrapped under a mesh (specs match
-    ``kv_cache_spec``: arena/scale heads at axis 2, q/fresh heads at axis 1)."""
-    quantized = "k_scale" in arenas
+def _attn_paged(q, arenas, fresh_k, fresh_v, tables, pos, *, layer, mesh, window=None):
+    """One layer's attention call: ``q (B, nh, hs)`` through
+    ``paged_attn_decode``, ``(B, nh, T, hs)`` (a draft's verify, a piece of a
+    prompt; no window) through ``paged_attn_verify``.  Under a mesh it is
+    shard_map-wrapped (specs match ``kv_cache_spec``: arena/scale heads at axis
+    2, q/fresh heads at axis 1, a query-position axis riding along unsharded);
+    where ``tp`` does not split the heads, the XLA form on the arrays as GSPMD
+    holds them."""
+    multi = q.ndim == 4
+    scales = {name: arenas[name] for name in ("k_scale", "v_scale") if name in arenas}
+    entry = paged_attn_verify if multi else partial(paged_attn_decode, window=window)
     if mesh is None:
-        return paged_attn_decode(
-            q, arenas["k"], arenas["v"], fresh_k, fresh_v, tables, pos,
-            layer=layer, window=window,
-            k_scale=arenas.get("k_scale"), v_scale=arenas.get("v_scale"),
-        )
-    hspec = P(None, "tp", None)                    # (B, heads, hs)
-    aspec = P(None, None, "tp", None, None)        # (nb, L, ng, bs, hs)
-    sspec = P(None, None, "tp", None)              # (nb, L, ng, bs)
-    if quantized:
-        def local(q_, ka, va, ks, vs, fk, fv, t, p):
-            return paged_attn_decode(q_, ka, va, fk, fv, t, p, layer=layer,
-                                     window=window, k_scale=ks, v_scale=vs)
+        return entry(q, arenas["k"], arenas["v"], fresh_k, fresh_v, tables, pos, layer=layer, **scales)
+    tp = _tp_axis(mesh, q.shape[1], arenas["k"].shape[2])
+    if tp is None:
+        expand = (lambda x: x) if multi else (lambda x: x[:, :, None])
+        out = paged_attn_xla(expand(q), arenas["k"], arenas["v"], expand(fresh_k), expand(fresh_v), tables, pos,
+                             layer=layer, window=window, **scales)
+        return out if multi else out[:, :, 0]
+    hspec = P(None, tp, None, None) if multi else P(None, tp, None)    # (B, heads[, T], hs)
+    aspec = P(None, None, tp, None, None)                              # (nb, L, ng, bs, hs)
+    sspec = P(None, None, tp, None)                                    # (nb, L, ng, bs)
+    names = tuple(scales)
 
-        in_specs = (hspec, aspec, aspec, sspec, sspec, hspec, hspec, P(None, None), P(None))
-        args = (q, arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
-                fresh_k, fresh_v, tables, pos)
-    else:
-        def local(q_, ka, va, fk, fv, t, p):
-            return paged_attn_decode(q_, ka, va, fk, fv, t, p, layer=layer,
-                                     window=window)
+    def local(q_, ka, va, fk, fv, t, p, *sc):
+        return entry(q_, ka, va, fk, fv, t, p, layer=layer, **dict(zip(names, sc)))
 
-        in_specs = (hspec, aspec, aspec, hspec, hspec, P(None, None), P(None))
-        args = (q, arenas["k"], arenas["v"], fresh_k, fresh_v, tables, pos)
-    return _smap(local, mesh, in_specs, hspec)(*args)
-
-
-def _attn_paged_multi(q, arenas, fresh_k, fresh_v, tables, pos, *, layer, mesh):
-    """Multi-token-query (verify) kernel call: ``q`` (B, nh, T, hs), fresh
-    K/V (B, ng, T, hs).  Same mesh layout as :func:`_attn_paged` with the
-    query-position axis riding along unsharded.  No sliding window —
-    ``paged_supported`` already rejects windowed configs for speculation."""
-    quantized = "k_scale" in arenas
-    if mesh is None:
-        return paged_attn_verify(
-            q, arenas["k"], arenas["v"], fresh_k, fresh_v, tables, pos,
-            layer=layer,
-            k_scale=arenas.get("k_scale"), v_scale=arenas.get("v_scale"),
-        )
-    hspec = P(None, "tp", None, None)              # (B, heads, T, hs)
-    aspec = P(None, None, "tp", None, None)        # (nb, L, ng, bs, hs)
-    sspec = P(None, None, "tp", None)              # (nb, L, ng, bs)
-    if quantized:
-        def local(q_, ka, va, ks, vs, fk, fv, t, p):
-            return paged_attn_verify(q_, ka, va, fk, fv, t, p, layer=layer,
-                                     k_scale=ks, v_scale=vs)
-
-        in_specs = (hspec, aspec, aspec, sspec, sspec, hspec, hspec, P(None, None), P(None))
-        args = (q, arenas["k"], arenas["v"], arenas["k_scale"], arenas["v_scale"],
-                fresh_k, fresh_v, tables, pos)
-    else:
-        def local(q_, ka, va, fk, fv, t, p):
-            return paged_attn_verify(q_, ka, va, fk, fv, t, p, layer=layer)
-
-        in_specs = (hspec, aspec, aspec, hspec, hspec, P(None, None), P(None))
-        args = (q, arenas["k"], arenas["v"], fresh_k, fresh_v, tables, pos)
-    return _smap(local, mesh, in_specs, hspec)(*args)
+    in_specs = (hspec, aspec, aspec, hspec, hspec, P(None, None), P(None)) + (sspec,) * len(names)
+    return _smap(local, mesh, in_specs, hspec)(
+        q, arenas["k"], arenas["v"], fresh_k, fresh_v, tables, pos, *scales.values())
 
 
 def _gdn_paged(gp, x, arenas, sslots, pos, cfg, *, layer, n_real, lin):
@@ -289,8 +247,7 @@ def _diff_paged(ap, x, l, cfg, k_arena, v_arena, tables, pos, *, layer, window, 
     too) with the keys and values where the server keeps them.  The pair's two
     softmaxes are one walk over ``layer`` of the lane-packed arenas
     (``paged_attn_decode(packed_out=True)``: a 128-lane row is the pair's two K
-    heads, another its two V heads side by side, fetched once for both); without
-    Pallas the same sums over the row's gathered blocks.  ``fresh_kv``: a
+    heads, another its two V heads side by side, fetched once for both).  ``fresh_kv``: a
     cross_attention layer's: this step's K and V of the layer it reads, which
     the arena does not hold yet.  Returns ``(y, (fresh K, fresh V))``, ``(B, ng,
     hs)`` at the cache compute dtype."""
@@ -301,19 +258,9 @@ def _diff_paged(ap, x, l, cfg, k_arena, v_arena, tables, pos, *, layer, window, 
         fk, fv = fresh_kv if k is None else (k[:, :, 0].astype(cdtype), v[:, :, 0].astype(cdtype))
         box.extend((fk, fv))
         with scope(f"{name}/attn"):
-            if paged_available():
-                out = paged_attn_decode(q[..., 0, :].reshape(B, 2 * G * J, hs), k_arena, v_arena, fk, fv, tables, pos,
-                                        layer=layer, window=window, packed_out=True)
-                return out.reshape(B, G, 2, J, 1, 2 * hs)
-            # the walk's XLA form: the rows' blocks gathered, this step's row set at its position
-            put = jax.vmap(lambda rows, new, p: jax.lax.dynamic_update_slice_in_dim(rows, new[:, None], p, axis=1))
-            kr, vr = (put(gather_rows(a[:, layer:layer + 1], tables)[0], f.reshape(B, G, 2 * hs).astype(a.dtype), pos)
-                      for a, f in ((k_arena, fk), (v_arena, fv)))
-            j = jnp.arange(kr.shape[2])[None, :]
-            keep = j <= pos[:, None]
-            if window is not None:
-                keep = jnp.logical_and(keep, j > pos[:, None] - window)
-            return diff_attend_dense(q, kr, vr, keep[:, None, None, None, :])
+            out = paged_attn_decode(q[..., 0, :].reshape(B, 2 * G * J, hs), k_arena, v_arena, fk, fv, tables, pos,
+                                    layer=layer, window=window, packed_out=True)
+            return out.reshape(B, G, 2, J, 1, 2 * hs)
 
     y = diff_attention(ap, x, l, cfg, attend, cross=fresh_kv is not None, lin=lin, name=name)
     return y, (box[0], box[1])
@@ -450,8 +397,7 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                         else:
                             fk = k.astype(cdtype)                  # (B, ng, T, hs)
                             fv = v.astype(cdtype)
-                            y = _attn_paged_multi(q, arenas, fk, fv, tables, pos,
-                                                  layer=kvl, mesh=mesh)
+                            y = _attn_paged(q, arenas, fk, fv, tables, pos, layer=kvl, mesh=mesh)
                             y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
                     with scope("out"):
                         h = lin(y, bp["attn"]["wo"], bp["attn"].get("bo"))
@@ -480,9 +426,9 @@ def _write(arena, vals, tables, pos, *, block_size, mesh, n_emit=None, offset=0,
     if mesh is None:
         return paged_token_write(arena, vals, tables, pos, block_size=block_size,
                                  n_emit=n_emit, offset=offset, name=name)
-    rank5 = arena.ndim == 5
-    aspec = P(None, None, "tp", None, None) if rank5 else P(None, None, "tp", None)
-    vspec = P(None, None, "tp", None) if rank5 else P(None, None, "tp")
+    rank5, tp = arena.ndim == 5, _tp_axis(mesh, arena.shape[2])
+    aspec = P(None, None, tp, None, None) if rank5 else P(None, None, tp, None)
+    vspec = P(None, None, tp, None) if rank5 else P(None, None, tp)
     if n_emit is None:
         return _smap(
             lambda a, v, t, p: paged_token_write(a, v, t, p, block_size=block_size),
@@ -507,9 +453,10 @@ def _write_fused(arena, scale, vals, tables, pos, *, block_size, mesh,
         return paged_token_write_fused(arena, scale, vals, tables, pos,
                                        block_size=block_size, n_emit=n_emit,
                                        offset=offset)
-    aspec = P(None, None, "tp", None, None)
-    sspec = P(None, None, "tp", None)
-    vspec = P(None, None, "tp", None)
+    tp = _tp_axis(mesh, arena.shape[2])
+    aspec = P(None, None, tp, None, None)
+    sspec = P(None, None, tp, None)
+    vspec = P(None, None, tp, None)
     if n_emit is None:
         return _smap(
             lambda a, s, v, t, p: paged_token_write_fused(
@@ -534,7 +481,7 @@ def write_fresh_kv(arenas, fresh, tables, pos, *, block_size, kv_dtype=None,
     quantized (int8/fp8) — quantization is **fused into the writer kernel**
     (``paged_token_write_fused`` runs the exact ``quantize_kv`` absmax math
     as its epilogue and lands value + scale through two aliased outputs),
-    so the stored bytes stay bit-identical to the gather path's while no
+    so the stored bytes stay bit-identical to ``scatter_token_q``'s while no
     standalone quantize op appears in the program.  Returns the updated
     arenas dict (aliased buffers: no scatter primitive, untouched blocks
     keep their bytes; padding rows land in sink block 0, never attended).
@@ -604,8 +551,8 @@ def write_fresh_kv_masked(arenas, fresh, tables, pos, n_emit, *, block_size,
     ``pos + k``; the rest are sink-routed (block 0, never attended), so
     rejected candidates leave no trace and the next round re-derives them
     from scratch.  Quantization matches :func:`write_fresh_kv` — the fused
-    in-kernel absmax epilogue per chunk offset, bit-identical bytes to the
-    gather path's commits."""
+    in-kernel absmax epilogue per chunk offset, bit-identical bytes to
+    ``scatter_token_q``'s."""
     T = fresh["k"].shape[3]
     out = dict(arenas)
     if kv_dtype is None:
@@ -653,10 +600,12 @@ def write_fresh_kv_chunk(arenas, fresh, dest, pos, *, block_size,
     (0.0 unquantized)."""
     bs = block_size
 
+    tp = None if mesh is None else _tp_axis(mesh, arenas["k"].shape[2])
+
     def plain(arena, vals):
         if mesh is None:
             return paged_chunk_write(arena, vals, dest, pos, block_size=bs)
-        aspec = P(None, None, "tp", None, None)
+        aspec = P(None, None, tp, None, None)
         return _smap(
             lambda a, v, d, p: paged_chunk_write(a, v, d, p, block_size=bs),
             mesh, (aspec, aspec, P(None), P(None)), aspec,
@@ -666,13 +615,13 @@ def write_fresh_kv_chunk(arenas, fresh, dest, pos, *, block_size,
         if mesh is None:
             return paged_chunk_write_fused(arena, scale, vals, dest, pos,
                                            block_size=bs)
-        aspec = P(None, None, "tp", None, None)
-        sspec = P(None, None, "tp", None)
+        aspec = P(None, None, tp, None, None)
+        sspec = P(None, None, tp, None)
         return _smap(
             lambda a, s, v, d, p: paged_chunk_write_fused(
                 a, s, v, d, p, block_size=bs),
             mesh, (aspec, sspec, aspec, P(None), P(None)),
-            (aspec, sspec, P(None, "tp", None)),
+            (aspec, sspec, P(None, tp, None)),
         )(arena, scale, vals, dest, pos)
 
     kb = _chunk_blocks(fresh["k"], bs)

@@ -159,7 +159,6 @@ class ReplicatedEngine:
         params,
         cfg,
         *,
-        model_fn: Callable | None = None,
         replicas: int,
         mesh=None,
         fault_plans: Sequence | None = None,
@@ -206,7 +205,6 @@ class ReplicatedEngine:
         for i in range(replicas):
             self._engines.append(ServingEngine(
                 params, cfg,
-                model_fn=model_fn,
                 mesh=submeshes[i],
                 fault_plan=fault_plans[i] if fault_plans is not None else None,
                 # owned telemetry (a path) must not be opened N times over;

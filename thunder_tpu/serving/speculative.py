@@ -95,8 +95,7 @@ class SpecConfig:
     draft_kv_dtype: Any = None
 
 
-def validate_spec(spec: SpecConfig, cfg, *, custom_forward: bool,
-                  sliding_window) -> None:
+def validate_spec(spec: SpecConfig, cfg, *, sliding_window) -> None:
     """Engine-construction validation: everything the key-chain mirroring
     and the K-token arena math require, checked before any allocation."""
     if not isinstance(spec, SpecConfig):
@@ -108,13 +107,6 @@ def validate_spec(spec: SpecConfig, cfg, *, custom_forward: bool,
             "speculative serving needs a shared tokenizer: draft "
             f"padded_vocab_size={spec.draft_cfg.padded_vocab_size} != target "
             f"{cfg.padded_vocab_size}"
-        )
-    if custom_forward:
-        raise ValueError(
-            "speculative serving requires the in-tree forward "
-            "(model_fn=None): the draft/verify programs mirror the solo "
-            "speculative_generate() key chain, which a custom forward "
-            "cannot guarantee"
         )
     if sliding_window is not None or getattr(cfg, "sliding_window", None) \
             or getattr(spec.draft_cfg, "sliding_window", None):
@@ -383,57 +375,17 @@ def build_draft_decode(eng, Bb: int, nbb: int):
     return draft_decode
 
 
-def build_verify(eng, Bb: int, nbb: int):
-    """ONE target forward over the K+1 chunk ``[cur, d_1..d_K]`` (per-row
-    vector positions; the dense gathered view + the ``j <= qpos`` keep
-    mask exactly reproduce solo's cache semantics), the shared rejection
-    rule, and a keep-masked commit: offset k's fresh K/V lands at
-    ``pos + k`` iff ``k < n_emit``, else it sink-routes — the target arena
-    only ever holds committed tokens' K/V."""
-    cfg = eng.cfg
-    K, temp = eng.spec.K, eng.temperature
-    qkv = eng.pool.quantized_kv
-    cdtype = jnp.dtype(eng.pool.dtype)
-    bs = eng.pool.block_size
-    cap = eng.pool.capacity_tokens(nbb)
-    cos, sin = build_rope_cache(cfg, cap)
-
-    @partial(jax.jit, donate_argnums=(4,), **eng._jit_kwargs("verify"))
-    def verify(params, toks, pos, tables, arenas, drafts, q_rows, keys,
-               lora, slots):
-        chunk = jnp.concatenate([toks[:, None], drafts], axis=1)  # (B, K+1)
-        dense = _gather(arenas, tables, qkv, cdtype)
-        tlogits, cache = forward_with_cache(
-            params, chunk, pos, dense, cos, sin, cfg,
-            **eng._fwd_kwargs(lora, slots),
-        )
-        emitted, n_emit, y, new_keys = _acceptance(
-            tlogits, drafts, q_rows, keys, temp, K)
-        kc = cache["k"].transpose(1, 0, 2, 3, 4)
-        vc = cache["v"].transpose(1, 0, 2, 3, 4)
-        for k in range(K + 1):
-            p_k = pos + k
-            live = k < n_emit
-            db = jnp.where(
-                live,
-                jnp.take_along_axis(tables, (p_k // bs)[:, None], axis=1)[:, 0],
-                SINK_BLOCK,
-            )
-            ds = jnp.where(live, p_k % bs, 0)
-            arenas = _scatter_at(arenas, kc, vc, p_k, db, ds, qkv)
-        return emitted, n_emit, y, new_keys, pos + n_emit, arenas
-
-    return verify
-
-
 def build_verify_paged(eng, Bb: int, nbb: int):
-    """The kernel twin of :func:`build_verify`: same signature, same
-    acceptance math, same returns — attention runs the multi-token-query
-    Pallas paged kernel straight off the arenas (q_len K+1, causal
-    intra-chunk mask inside the online softmax) and the accepted prefix
-    commits through the keep-masked write kernel, so the compiled program
-    touches the arenas with zero gather/scatter primitives (jaxpr-asserted
-    by tests, with the gather ``verify`` as the positive control)."""
+    """ONE target forward over the K+1 chunk ``[cur, d_1..d_K]`` (per-row
+    vector positions), the shared rejection rule, and a keep-masked commit:
+    offset k's fresh K/V lands at ``pos + k`` iff ``k < n_emit``, else it
+    sink-routes — the target arena only ever holds committed tokens' K/V.
+    Attention runs the multi-token-query paged kernel straight off the arenas
+    (q_len K+1, causal intra-chunk mask inside the online softmax) and the
+    accepted prefix commits through the keep-masked write kernel, so with the
+    kernel in it the compiled program touches the arenas with zero
+    gather/scatter primitives (jaxpr-asserted by tests, with the kernel's XLA
+    form as the positive control)."""
     from thunder_tpu.serving.paged_attention import (
         forward_paged,
         write_fresh_kv_masked,
@@ -523,7 +475,7 @@ def spec_decode_dispatch(eng) -> dict:
     # depend only on history below pos, which the draft program never
     # touches), so the retried round stays bit-identical
     eng._fault_point(FP_VERIFY, tuple(r.rid for r in running))
-    vkind = "verify_paged" if eng.attn == "paged" else "verify"
+    vkind = "verify_paged"
     vprog, vcompiled = eng._program(vkind, Bb, nbb)
     lora_arenas = eng._lora_arenas()
     if eng.mesh is not None and eng._mesh_collectives is None:
@@ -533,18 +485,13 @@ def spec_decode_dispatch(eng) -> dict:
             (eng.params, toks_d, pos_d, tables_d, pool.arenas,
              drafts, q_rows, keys_mid, lora_arenas, slots_d),
         )
-    if eng.attn == "paged":
-        eng.attn_kernel_steps += 1
-        eng._m_attn_kernel.inc()
-    elif eng._attn_requested == "auto":
-        eng.attn_fallback_steps += 1
-        eng._m_attn_fallback.inc()
+    eng._note_attn_step()
     tr = eng._tracer
     if tr is not None:
         for r in running:
             tr.begin(r.rid, "decode", step=eng.decode_steps,
                      compile=dcompiled or vcompiled, bucket=[Bb, nbb],
-                     lane="decode", attn=eng.attn, spec=True, K=K)
+                     lane="decode", attn=eng._attn_path, spec=True, K=K)
     with eng._span("serve.decode_dispatch.call"), \
             eng._compile_span(vcompiled, vkind, Bb, nbb):
         emitted, n_emit, y, new_keys, new_pos, arenas = vprog(

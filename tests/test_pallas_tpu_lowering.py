@@ -675,7 +675,8 @@ def test_the_latent_cells_programs_lower_to_their_kernels(kind, tpu_sharding, mo
         args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)))
     else:
         prog = eng._build_decode_paged(64, 640)
-        args = (weights, one((64,)), one((64,)), one((64, 640)), arenas, one((64, 2), jnp.uint32), {}, one((64,)))
+        args = (weights, one((64,)), one((64,)), one((64, 640)), arenas, one((64, 2), jnp.uint32), {}, one((64,)),
+                one((4,), F32))         # the expert share's running sums (PR 45)
     lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
     text = lowered.as_text()
     claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
@@ -780,7 +781,7 @@ def test_the_narrow_head_cells_programs_lower_to_their_kernels(kind, tpu_shardin
     else:
         prog = eng._build_decode_paged(256, 256)
         args = (weights, one((256,)), one((256,)), one((256, 256)), arenas, one((256, 2), jnp.uint32), {}, one((256,)),
-                one((256,)))
+                one((4,), F32), one((256,)))        # the expert share's running sums (PR 45), then the state slots
     lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
     text = lowered.as_text()
     claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
@@ -855,7 +856,7 @@ def test_every_pallas_call_site_is_named():
     import inspect
 
     src = inspect.getsource(px)
-    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 20      # PR 41: the selective scan's two
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 22      # PR 45: the Mamba-2 scan's two
     assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
         "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
         "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",
@@ -997,3 +998,111 @@ def test_the_hybrid_decoder_cells_programs_lower_to_their_kernels(kind, tpu_shar
             "paged_attn_decode", "paged_token_write", "ssm_decode_step")
         for name in names:
             assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
+
+
+NEMO_CELL = "nemotron3super-serve-1chip.offline-rollouts"
+
+
+@pytest.mark.parametrize("kernel", ["ssd_chunk_fwd", "ssd_chunk_fwd/float32", "ssd_decode_step"])
+def test_the_mamba2_cells_kernels_compile_at_its_shapes(kernel, tpu_sharding, monkeypatch):
+    """The chunked scan over the longest prefill bucket (5,120 tokens of 128 heads
+    of 64 channels in 8 groups, 128 states; a group's ``(128, 1024)`` float32 state
+    carried in VMEM across the token blocks, the last state out), in bfloat16 and
+    at full precision (a float32 witness); the step on 128 rows' slots of the five
+    layers' arena, ``(128, 8192)`` = 4.19 MB a slot a layer, in place."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    kernel, _, which = kernel.partition("/")
+    H, P, G, N, rows, Ts = 128, 64, 8, 128, 128, 5120
+    d, xdt = H * P, F32 if which == "float32" else BF
+    if kernel == "ssd_chunk_fwd":
+        fn, claim = px.ssd_chunk, "ssd_chunk"
+        specs = [((1, Ts, d), xdt), ((1, Ts, H), F32), ((1, Ts, G, N), xdt), ((1, Ts, G, N), xdt), ((H,), F32),
+                 ((1, N, d), F32)]
+    else:
+        fn, claim = functools.partial(px.ssd_decode_step, layer=4), "ssd_decode"
+        specs = [((rows + 1, 5, N, d), F32), ((rows,), I32), ((rows, d), BF), ((rows, H), F32), ((rows, G, N), BF),
+                 ((rows, G, N), BF), ((H,), F32)]
+    before = dict(px.stats)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and f'kernel_name = "{kernel}"' in text
+    assert px.stats.get(claim, 0) == before.get(claim, 0) + 1 and px.stats["ssd"] == before.get("ssd", 0) + 1
+    if kernel == "ssd_chunk_fwd":
+        assert px.ssd_schedule == {"block_tokens": 512, "chunk": 128, "heads": H, "head_dim": P, "groups": G, "states": N}
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        call = next(l for l in compiled.as_text().splitlines() if re.search(rf"%{kernel}(\.\d+)? = ", l))
+        if kernel == "ssd_decode_step":
+            assert "output_to_operand_aliasing" in call                       # in place on the arena
+            assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20   # no copy of the 2.7 GB arena
+
+
+@functools.cache
+def _nemo_engine():
+    """The cell's engine at its published widths and its own depth (one period of
+    eleven: five Mamba-2, five expert and one attention layer), over weights that
+    are shapes alone."""
+    import thunder_tpu as tt
+    from chipbench import common
+    from thunder_tpu.models import llama
+
+    _, config, mix = common.open_cell(NEMO_CELL)
+    arch = common.load_module("models", config["arch"])
+    cfg = llama.Config(**arch.program_config(config))
+    params = jax.eval_shape(functools.partial(arch.make_params, config), common.seed_words(1))
+    return cfg, params, tt.serve(None, params, cfg, **{**config["engine"], **mix["engine"], "num_blocks": 700,
+                                                       "max_batch": 8, "batch_buckets": [8]})
+
+
+@pytest.mark.parametrize("kind", ["prefill_fresh", "decode_paged"])
+def test_the_mamba2_cells_programs_lower_to_their_kernels(kind, tpu_sharding, monkeypatch):
+    """A whole prompt's prefill scans through ``ssd_chunk_fwd`` a Mamba-2 layer,
+    attends through ``_flash_fwd`` in the one attention layer, sorts its rows
+    through ``moe_grouped_mm`` (two products an expert layer: no ``fc_2``) and
+    projects the head for one row; a decode step runs ``ssd_decode_step`` a Mamba-2
+    layer on the state arena in place, walks the attention layer's blocks through
+    ``paged_attn_decode`` and returns the expert share's running sums; no arena is
+    gathered."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    monkeypatch.setattr(px, "_pallas_available", lambda: True)
+    monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)
+    cfg, params, eng = _nemo_engine()
+    st = eng.stats()
+    assert st["attn"]["path"] == "walk" and st["attn"]["lane_pack"] == 1 and st["moe"]["experts_held"] == 128
+    assert eng.pool.k_arena.shape == (700, 1, 2, 16, 128)
+    assert eng.pool.state.shapes == {"conv": (9, 5, 3, 10240), "state": (9, 5, 128, 8192)}
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=tpu_sharding)  # noqa: E731
+    one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
+    before = dict(px.stats)
+    if kind == "prefill_fresh":
+        Tb = 5120
+        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
+        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)), one((1,)))
+    else:
+        prog = eng._build_decode_paged(8, 496)
+        args = (weights, one((8,)), one((8,)), one((8, 496)), arenas, one((8, 2), jnp.uint32), {}, one((8,)),
+                one((4,), F32), one((8,)))
+    lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
+    assert "paged_attn_verify" not in text
+    assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)      # nothing of an arena's five dims
+    assert claimed("grouped_mm") >= 10 and 'kernel_name = "moe_grouped_mm"' in text
+    if kind == "prefill_fresh":
+        assert claimed("ssd_chunk") == 5 and claimed("ssd") == 5 and 'kernel_name = "ssd_chunk_fwd"' in text
+        assert claimed("direct") == 1 and 'kernel_name = "_flash_fwd"' in text
+        assert {int(m) for m in re.findall(rf"tensor<1x(\d+)x{cfg.padded_vocab_size}xf32>", text)} == {1}
+    else:
+        assert claimed("ssd_decode") == 5 and claimed("ssd") == 5 and 'kernel_name = "ssd_decode_step"' in text
+        assert text.count('kernel_name = "paged_attn_decode"') == 1
+        assert text.count('kernel_name = "paged_token_write"') == 2
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        hlo = compiled.as_text()
+        names = ("_flash_fwd", "ssd_chunk_fwd", "moe_grouped_mm") if kind == "prefill_fresh" else (
+            "paged_attn_decode", "paged_token_write", "ssd_decode_step", "moe_grouped_mm")
+        for name in names:
+            assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
+        print(kind, "temporaries", compiled.memory_analysis().temp_size_in_bytes)

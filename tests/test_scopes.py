@@ -271,6 +271,7 @@ def _serve_program(kind: str, program: str, *, tpu: bool = False):
     else:
         prog = eng._build_decode_paged(Bb, nbb)
         args = (weights, one((Bb,)), one((Bb,)), one((Bb, nbb)), arenas, one((Bb, 2), jnp.uint32), {}, one((Bb,)),
+                *([one((4,), jnp.float32)] if eng._moe_rows is not None else []),      # an expert share's running sums
                 *([one((Bb,))] if eng._hybrid else []))
     traced = prog.trace(*args)
     return traced.lower(lowering_platforms=("tpu",)) if tpu else traced.lower()

@@ -378,7 +378,9 @@ def test_the_engine_says_what_its_arenas_hold(served):
     assert st["state"]["arenas"] == ["conv"] and st["state"]["layers"] == 3
     assert st["state"]["slot_bytes"] == 3 * 2 * 64 * 4 and eng.pool.state.state is None
     assert st["attn"]["lane_pack"] == 2 and st["attn"]["path"] in ("xla", "walk")
-    assert st["moe"] == {"experts_held": 8, "expert_first": 0, "experts_published": 8, "router": "sigmoid_bias"}
+    assert {k: st["moe"][k] for k in ("experts_held", "expert_first", "experts_published", "router")} == {
+        "experts_held": 8, "expert_first": 0, "experts_published": 8, "router": "sigmoid_bias"}
+    assert set(st["moe"]) >= {"expert_rows_per_step", "experts_hit_share", "row_sums"}
 
 
 # --------------------------------------------------------------------------

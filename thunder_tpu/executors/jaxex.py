@@ -1114,11 +1114,15 @@ def _moe_wave(x, top_w, fc_1, fc_2, proj, row_src, pos, row_w, tile_group, tiles
     """One wave of the sorted buffer: its rows gathered from their tokens
     (by ``row_src``), through the experts' SwiGLU as grouped products,
     weighted, and gathered back by their tokens (by ``pos``) and summed in
-    float32.  ``row_src``, ``pos``, ``row_w`` and ``tile_group`` are the wave's."""
+    float32.  ``row_src``, ``pos``, ``row_w`` and ``tile_group`` are the wave's.
+    ``fc_2`` None (the server's ungated experts): ``relu(. fc_1)^2`` in the SwiGLU's place."""
     static = (*top_w.shape, jnp.dtype(x.dtype))
     xb, wb = _dispatch(static, x, top_w, row_src, pos, row_w)
     used = tiles_used.reshape(1)
-    h = jax.nn.silu(_gmm(xb, fc_1, tile_group, used)) * _gmm(xb, fc_2, tile_group, used)
+    if fc_2 is None:        # ungated experts: W2 relu(W1 x)^2
+        h = jnp.square(jax.nn.relu(_gmm(xb, fc_1, tile_group, used)))
+    else:
+        h = jax.nn.silu(_gmm(xb, fc_1, tile_group, used)) * _gmm(xb, fc_2, tile_group, used)
     yb = _gmm(h * wb[:, None].astype(h.dtype), proj, tile_group, used)
     return _combine(static, yb, row_src, pos)
 
@@ -1164,8 +1168,10 @@ _overflow.defvjp(lambda static, plan, *operands: (_overflow(static, plan, *opera
                  _overflow_bwd)
 
 
-@impl(PrimIDs.MOE_EXPERT_SHARE)
-def _moe_share(x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile=MOE_ROW_TILE):
+def _moe_share_planned(x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile):
+    """The held experts' part of the layer and the plan it was computed by
+    (``moe_plan``: the server reads its ``cnt``).  ``fc_2`` None: ungated experts
+    of two matrices (the server's alone: undifferentiated)."""
     held = fc_1.shape[0]
     wave_tiles = moe_wave_tiles(top_idx.size, held, total, tile)
     plan = moe_plan(top_idx, top_w, first, held, tile, wave_tiles)
@@ -1174,7 +1180,12 @@ def _moe_share(x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile=MOE_ROW_T
     y = _run_wave(static, plan, 0, *operands)
     if static[2] > 1:
         y = y + _overflow(static, plan, *operands)
-    return y.astype(x.dtype)
+    return y.astype(x.dtype), plan
+
+
+@impl(PrimIDs.MOE_EXPERT_SHARE)
+def _moe_share(x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile=MOE_ROW_TILE):
+    return _moe_share_planned(x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile)[0]
 
 
 @impl(PrimIDs.MOE_EXPERT_SHARE_BACKWARD)

@@ -3450,6 +3450,299 @@ def ssm_decode_step(arena, slots, u, dt, Bm, Cm, A, *, layer: int):
     return y[:, 0], arena
 
 
+# ---------------------------------------------------------------------------
+# The state-space duality scan of a Mamba-2 layer, for the server.  A
+# sequence's state is one matrix a head, ``S[h] (P, N)`` (``P`` channels of the
+# head, ``N`` states), with one scalar decay a head and ``B``, ``C`` shared by a
+# group of heads.  It is held ``(N, d)``, ``d = H P``: head ``h``'s matrix
+# transposed in columns ``[h P, (h + 1) P)``, so the channels lie on lanes (a
+# head of 64 fills a lane tile with its neighbour), a token's ``B_t`` and ``C_t``
+# of a group are columns ``(N, 1)`` that broadcast along them, and the decay
+# and the input are rows ``(1, d)``:
+#   S_t = a_t * S_t-1 + (dt_t x_t) * B_t        y_t = sum_n S_t * C_t
+# with ``a_t = exp(dt_t A)`` a channel (a head's value repeated).  A padded
+# token carries ``dt = 0``: decay one, input zero, the state as it was.
+#
+# ``ssd_chunk_fwd`` (a whole prompt): grid (row, group, block of tokens); the
+# block axis is sequential and a group's ``(N, d / G)`` float32 state stays in
+# VMEM scratch across it, starting from ``h0`` and leaving as the second result.
+# A block is chunks of ``SSD_CHUNK`` tokens; a chunk is matrix products (the
+# structure of ``gdn_chunk_fwd`` without the delta rule's triangular inverse):
+#   G = C B^T                                         one a group, (Q, Q)
+#   W_h = G * exp(cum_t - cum_s) [s <= t] * dt_s     a head's decayed scores
+#   y = W_h x_h + exp(cum_t) (C S_prev)_h            within the chunk, and what came before it
+#   S <- exp(cum_Q) S_prev + B^T (x * dt * exp(cum_Q - cum))
+# ``cum`` is the head's log-decay ``dt A`` summed from the chunk's first token
+# (made outside, a row and a column layout of it: a ``(Q, 1)`` column in HBM is
+# a 128-lane tile a row).  Heads of fewer than 128 channels share a lane tile:
+# a tile's heads are told apart by a lane mask, so every product is whole
+# tiles and no half tile is sliced out.  The scores and the read-out go
+# through the matrix unit in one bfloat16 pass (their sum is rounded to
+# bfloat16 before ``W_out`` anyway); the state's update in two (the scaled
+# input's high and low halves: the state is what a request keeps, in float32).
+#
+# ``ssd_decode_step`` (one token a row): the row's slot of the state arena,
+# ``(N, d)`` = 4.19 MB at 128 heads of 64 and 128 states, in and out through one
+# aliased block as ``gdn_decode_step`` does; a lane tile of channels at a time
+# stays in registers through decay, input and read-out.  Memory bound: a read
+# and a write of the state a row.
+# ---------------------------------------------------------------------------
+
+SSD_CHUNK = 128            # tokens of a chunk: the published ``chunk_size``; the scan's block, no part of the maths
+_SSD_BLOCK_TOKENS = 512    # tokens a grid step, the most
+
+
+def ssd_step_math(S, a, xdt, b, c):
+    """One token of one group in float32: ``S (N, dg)``, the decay ``a`` and the
+    input ``xdt = dt x`` rows ``(1, dg)``, ``b`` and ``c`` columns ``(N, 1)`` -> ``(y
+    (1, dg), S)``.  The decode kernel's body, the dense cache's step and the XLA
+    forms are this one function."""
+    S = a * S + xdt * b
+    return jnp.sum(S * c, axis=0, keepdims=True), S
+
+
+def _ssd_channels(v, P: int):
+    """A head's value on each of its ``P`` channels: ``(..., H)`` to ``(..., H P)``."""
+    return jnp.repeat(v, P, axis=-1)
+
+
+def ssd_scan_xla(x, dt, Bm, Cm, A, h0):
+    """The scan's XLA form, a token a step of ``lax.scan``: x ``(B, T, d)``, dt
+    ``(B, T, H)``, Bm, Cm ``(B, T, G, N)``, ``A (H,)``, h0 ``(B, N, d)`` -> ``(y (B, T,
+    d) float32, the state after the last token)`` (what the CPU runs, and what
+    the kernel is tested against)."""
+    f32 = jnp.float32
+    B, T, d = x.shape
+    G, N = Bm.shape[2], Bm.shape[3]
+    P = d // dt.shape[-1]
+    dtc = _ssd_channels(dt.astype(f32), P)                              # (B, T, d)
+    a = jnp.exp(dtc * _ssd_channels(A.astype(f32), P))
+    xdt = dtc * x.astype(f32)
+    step = jax.vmap(jax.vmap(ssd_step_math))                            # over rows and groups
+
+    def one(S, xs):
+        a_t, xdt_t, b_t, c_t = xs
+        grp = lambda v: v.reshape(B, G, 1, d // G)  # noqa: E731
+        y, S = step(S, grp(a_t), grp(xdt_t), b_t[..., None], c_t[..., None])
+        return S, y.reshape(B, d)
+
+    tm = lambda v: jnp.swapaxes(v, 0, 1)  # noqa: E731 -- token-major
+    S0 = h0.astype(f32).reshape(B, N, G, d // G).transpose(0, 2, 1, 3)  # (B, G, N, dg)
+    S, ys = jax.lax.scan(one, S0, (tm(a), tm(xdt), tm(Bm.astype(f32)), tm(Cm.astype(f32))))
+    return jnp.swapaxes(ys, 0, 1), S.transpose(0, 2, 1, 3).reshape(B, N, d).astype(h0.dtype)
+
+
+def _ssd_chunk_kernel(x_ref, b_ref, c_ref, dtc_ref, cumc_ref, dtr_ref, cumr_ref, h0_ref, y_ref, last_ref, s_ref,
+                      *, Q, NC, P):
+    f32, bf = jnp.float32, jnp.bfloat16
+    exact = x_ref.dtype == f32          # float32 operands (the tests, a witness): every product at full precision
+
+    def dot(a, b, dims):
+        if exact:
+            return jax.lax.dot_general(a.astype(f32), b.astype(f32), dims, precision=jax.lax.Precision.HIGHEST,
+                                       preferred_element_type=f32)
+        return jax.lax.dot_general(a.astype(bf), b.astype(bf), dims, preferred_element_type=f32)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        s_ref[...] = h0_ref[0].astype(f32)
+
+    N, dg = s_ref.shape
+    per_tile = max(1, 128 // P)                 # heads that share a lane tile
+    width = max(P, 128)                         # lanes of a step: a tile of heads, or one wide head
+    low = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) // P
+    for c in range(NC):
+        rows = slice(c * Q, (c + 1) * Q)
+        Bc, Cc = b_ref[0, 0, rows, :], c_ref[0, 0, rows, :]                           # (Q, N)
+        scores = dot(Cc, Bc, _AB_T)                                                   # (Q, Q): C_t . B_s
+        dtc, cumc = dtc_ref[0, 0, rows, :], cumc_ref[0, 0, rows, :]                   # (Q, Hg) columns a head
+        dtr, cumr = dtr_ref[0, 0, :, rows], cumr_ref[0, 0, :, rows]                   # (Hg, Q) rows a head
+        for t in range(dg // width):
+            lanes = slice(t * width, (t + 1) * width)
+            xt = x_ref[0, rows, lanes]                                                # (Q, width)
+            S = s_ref[:, lanes]                                                       # (N, width)
+            before = dot(Cc, S, _AB)                                                  # (Q, width): C_t S_prev
+            y = jnp.zeros((Q, width), f32)
+            e_in = jnp.zeros((Q, width), f32)       # exp(cum_t): what the state before the chunk has decayed by
+            scale = jnp.zeros((Q, width), f32)      # dt_t exp(cum_Q - cum_t): a token's share of the state after it
+            e_all = jnp.zeros((1, width), f32)      # exp(cum_Q)
+            for j in range(per_tile):
+                h = t * per_tile + j
+                mine = lane_head == j                                                 # (1, width)
+                col, row = cumc[:, h:h + 1], cumr[h:h + 1, :]                         # (Q, 1), (1, Q)
+                W = scores * jnp.exp(jnp.where(low, col - row, _MASK_VALUE)) * dtr[h:h + 1, :]
+                y = y + dot(W, jnp.where(mine, xt, jnp.zeros_like(xt)) if per_tile > 1 else xt, _AB)
+                last = col[Q - 1:Q, :]                                                # (1, 1)
+                e_in = jnp.where(mine, jnp.exp(col), e_in)
+                scale = jnp.where(mine, dtc[:, h:h + 1] * jnp.exp(last - col), scale)
+                e_all = jnp.where(mine, jnp.exp(jnp.broadcast_to(last, (1, width))), e_all)
+            y_ref[0, rows, lanes] = (y + e_in * before).astype(y_ref.dtype)
+            xs = xt.astype(f32) * scale
+            if exact:
+                S = e_all * S + dot(Bc, xs, _AT_B)
+            else:       # two passes: the scaled input's high and low halves (B itself is bfloat16)
+                hi = xs.astype(bf)
+                S = e_all * S + dot(Bc, hi, _AT_B) + dot(Bc, xs - hi.astype(f32), _AT_B)
+            s_ref[:, lanes] = S
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _end():
+        last_ref[0] = s_ref[...].astype(last_ref.dtype)
+
+
+def _ssd_tiles(T: int, H: int, P: int, G: int) -> int | None:
+    """Tokens a grid step of :func:`_ssd_chunk_fwd`, or None where the shapes
+    do not tile: whole chunks of tokens, a group's channels in whole lane
+    tiles, a lane tile's heads in one group."""
+    dg = H * P // G
+    if T % SSD_CHUNK or H % G or dg % 128 or (P < 128 and 128 % P) or (P > 128 and P % 128):
+        return None
+    return next(b for b in (512, 256, 128) if b <= _SSD_BLOCK_TOKENS and T % b == 0)
+
+
+@functools.partial(jax.jit, static_argnames=("TB", "P"))
+def _ssd_chunk_fwd(x, Bm, Cm, dt, cum, h0, TB: int, P: int):
+    """x ``(B, T, d)``, Bm, Cm ``(B, G, T, N)`` in x's dtype, dt and cum (the
+    log-decay summed within each chunk) ``(B, G, T, Hg)`` float32, h0 ``(B, N, d)``
+    -> y ``(B, T, d)`` float32 and the state after the last token in ``h0``'s
+    dtype."""
+    B, T, d = x.shape
+    G, N, Hg = Bm.shape[1], Bm.shape[3], dt.shape[3]
+    dg = d // G
+    tile = pl.BlockSpec((1, TB, dg), lambda b, g, t: (b, t, g))
+    cols = pl.BlockSpec((1, 1, TB, N), lambda b, g, t: (b, g, t, 0))
+    heads = pl.BlockSpec((1, 1, TB, Hg), lambda b, g, t: (b, g, t, 0))
+    heads_t = pl.BlockSpec((1, 1, Hg, TB), lambda b, g, t: (b, g, 0, t))
+    state = pl.BlockSpec((1, N, dg), lambda b, g, t: (b, 0, g))
+    kwargs = {}
+    if not _interpret():
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=64 << 20)
+    return pl.pallas_call(
+        functools.partial(_ssd_chunk_kernel, Q=SSD_CHUNK, NC=TB // SSD_CHUNK, P=P),
+        name="ssd_chunk_fwd",
+        grid=(B, G, T // TB),
+        in_specs=[tile, cols, cols, heads, heads, heads_t, heads_t, state],
+        out_specs=[tile, state],
+        out_shape=[jax.ShapeDtypeStruct((B, T, d), jnp.float32), jax.ShapeDtypeStruct((B, N, d), h0.dtype)],
+        scratch_shapes=[pltpu.VMEM((N, dg), jnp.float32)],
+        interpret=_interpret(),
+        **kwargs,
+    )(x, Bm, Cm, dt, cum, jnp.swapaxes(dt, 2, 3), jnp.swapaxes(cum, 2, 3), h0)
+
+
+#: the last scan built, at trace time (a dict of its own, as ``flash_schedule``)
+ssd_schedule: dict[str, int] = {}
+
+
+def _ssd_claim(which: str) -> None:
+    stats["ssd"] = stats.get("ssd", 0) + 1
+    stats[which] = stats.get(which, 0) + 1
+
+
+def ssd_chunk(x, dt, Bm, Cm, A, h0):
+    """A prompt's Mamba-2 scan from the state ``h0 (B, N, d)``: x ``(B, T, d)``
+    (``d = H P``), dt ``(B, T, H)`` float32 (0 on a padded token), Bm, Cm ``(B, T,
+    G, N)``, ``A (H,)`` (negative) -> ``(y (B, T, d) float32, the state after the
+    last token)``.  ``ssd_chunk_fwd`` where Pallas runs and the shapes tile, else
+    the XLA form."""
+    B, T, d = x.shape
+    H, G = dt.shape[-1], Bm.shape[2]
+    P = d // H
+    TB = _ssd_tiles(T, H, P, G) if _enabled() and _gmm_dispatchable(x, dt, h0) else None
+    if TB is None:
+        return ssd_scan_xla(x, dt, Bm, Cm, A, h0)
+    _ssd_claim("ssd_chunk")
+    ssd_schedule.update(block_tokens=TB, chunk=SSD_CHUNK, heads=H, head_dim=P, groups=G, states=Bm.shape[3])
+    f32 = jnp.float32
+    dt = dt.astype(f32)
+    # the log-decay summed from each chunk's first token, a group's heads together
+    cum = jnp.cumsum((dt * A.astype(f32)).reshape(B, T // SSD_CHUNK, SSD_CHUNK, H), axis=2).reshape(B, T, H)
+    by_group = lambda v: v.reshape(B, T, G, H // G).transpose(0, 2, 1, 3)  # noqa: E731
+    cdt = x.dtype if str(x.dtype) == "bfloat16" else f32
+    return _ssd_chunk_fwd(x.astype(cdt), jnp.swapaxes(Bm, 1, 2).astype(cdt), jnp.swapaxes(Cm, 1, 2).astype(cdt),
+                          by_group(dt), by_group(cum), h0, TB=TB, P=P)
+
+
+def _ssd_decode_kernel(slot_ref, a_ref, xdt_ref, b_ref, c_ref, s_ref, y_ref, so_ref, *, G):
+    del slot_ref   # the row's slot lives in the BlockSpec index maps
+    f32 = jnp.float32
+    N, d = s_ref.shape[2], s_ref.shape[3]
+    dg = d // G
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1)).astype(f32)
+    for g in range(G):
+        # a group's B_t and C_t: rows in HBM (a column there is a lane tile a number), columns here
+        b = jnp.broadcast_to(_column(b_ref[0, g:g + 1, :], eye), (N, 128))
+        c = jnp.broadcast_to(_column(c_ref[0, g:g + 1, :], eye), (N, 128))
+        for t in range(dg // 128):      # a lane tile at a time: sixteen vregs through decay, input and read-out
+            lanes = slice(g * dg + t * 128, g * dg + (t + 1) * 128)
+            y, S = ssd_step_math(s_ref[0, 0, :, lanes].astype(f32), a_ref[0, :, lanes], xdt_ref[0, :, lanes], b, c)
+            so_ref[0, 0, :, lanes] = S.astype(so_ref.dtype)
+            y_ref[0, :, lanes] = y
+
+
+def _ssd_step_operands(x, dt, A):
+    """The step's rows a channel, float32: the decay ``exp(dt A)`` and the input ``dt x``, ``(rows, d)`` each."""
+    f32 = jnp.float32
+    P = x.shape[-1] // dt.shape[-1]
+    dtc = _ssd_channels(dt.astype(f32), P)
+    return jnp.exp(dtc * _ssd_channels(A.astype(f32), P)), dtc * x.astype(f32)
+
+
+def ssd_decode_step_xla(arena, slots, x, dt, Bm, Cm, A, *, layer: int):
+    """:func:`ssd_decode_step`'s XLA form: the rows' states gathered, stepped, scattered."""
+    f32 = jnp.float32
+    rows, d = x.shape
+    G, N = Bm.shape[1], Bm.shape[2]
+    a, xdt = _ssd_step_operands(x, dt, A)
+    grp = lambda v: v.reshape(rows, G, 1, d // G)  # noqa: E731
+    S = arena[slots, layer].astype(f32).reshape(rows, N, G, d // G).transpose(0, 2, 1, 3)
+    y, S = jax.vmap(jax.vmap(ssd_step_math))(S, grp(a), grp(xdt), Bm.astype(f32)[..., None], Cm.astype(f32)[..., None])
+    S = S.transpose(0, 2, 1, 3).reshape(rows, N, d)
+    return y.reshape(rows, d), arena.at[slots, layer].set(S.astype(arena.dtype))
+
+
+def ssd_decode_step(arena, slots, x, dt, Bm, Cm, A, *, layer: int):
+    """One token a row through the Mamba-2 scan, the state read and written
+    once, in place.  ``arena (slots + 1, L_m, N, d)`` (float32, or what the pool
+    was told to store); ``slots (rows,)`` int32, 0 the sink; x ``(rows, d)``, dt
+    ``(rows, H)``, Bm, Cm ``(rows, G, N)``, ``A (H,)``.  Returns ``(y (rows, d)
+    float32, arena)``.  The kernel where Pallas runs and a group's channels are
+    whole lane tiles, else the XLA form."""
+    rows, d = x.shape
+    G, N = Bm.shape[1], Bm.shape[2]
+    if not (_enabled() and (d // G) % 128 == 0 and d % G == 0):
+        return ssd_decode_step_xla(arena, slots, x, dt, Bm, Cm, A, layer=layer)
+    _ssd_claim("ssd_decode")
+    f32 = jnp.float32
+    a, xdt = _ssd_step_operands(x, dt, A)
+    row = lambda i, s: (i, 0, 0)  # noqa: E731
+    mine = lambda i, s: (s[i], layer, 0, 0)  # noqa: E731
+    kwargs = {}
+    if not _interpret():
+        # a row's state in and out, double-buffered: four blocks of (N, d) float32
+        tile = 4 * (-(-N // 8) * 8) * d * 4
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=max(32 << 20, tile + (16 << 20)))
+    y, arena = pl.pallas_call(
+        functools.partial(_ssd_decode_kernel, G=G),
+        name="ssd_decode_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows,),
+            in_specs=[pl.BlockSpec((1, 1, d), row), pl.BlockSpec((1, 1, d), row),
+                      pl.BlockSpec((1, G, N), row), pl.BlockSpec((1, G, N), row),
+                      pl.BlockSpec((1, 1, N, d), mine)],
+            out_specs=[pl.BlockSpec((1, 1, d), row), pl.BlockSpec((1, 1, N, d), mine)]),
+        out_shape=[jax.ShapeDtypeStruct((rows, 1, d), f32), jax.ShapeDtypeStruct(arena.shape, arena.dtype)],
+        input_output_aliases={5: 1},     # operands: the slot table, four small ones, the arena
+        interpret=_interpret(),
+        **kwargs,
+    )(slots.astype(jnp.int32), a[:, None], xdt[:, None], Bm.astype(f32), Cm.astype(f32), arena)
+    return y[:, 0], arena
+
+
 # install the fast paths so XLA fusion regions and TrainStep trace evaluation
 # reach the same kernels
 from thunder_tpu.executors import jaxex as _jaxex

@@ -45,6 +45,7 @@ __all__ = [
     "gdn_mixer",
     "shortconv_mixer",
     "ssm_mixer",
+    "mamba2_mixer",
     "gmu_mixer",
     "diff_attention",
     "ring_blocks",
@@ -102,7 +103,7 @@ def _rope(x, cos, sin):
     return (x * cos + rotated * sin).astype(x.dtype)
 
 
-def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0):
+def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0, moe_rows=None):
     lin = partial(_linear, quantized=quantized)
     if cfg.mlp_class == "LLaMAMoE":
         # stacked per-expert weights: per-request LoRA deltas are not
@@ -124,7 +125,7 @@ def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0):
     kind = cfg.mlp_class
     if kind == "SparseMoE":
         if "gate" in mp:
-            return moe_share_mlp(mp, x, cfg, lin=lin)
+            return moe_share_mlp(mp, x, cfg, lin=lin, moe_rows=moe_rows)
         kind = "LLaMAMLP"       # one of the model's leading dense layers
 
     def ll(name, inp, bias=None):
@@ -172,7 +173,10 @@ def route_sigmoid_bias(scores, bias, cfg: Config):
     groups) from float32 ``scores (N, E)`` in (0, 1) and ``bias (E,)``: the top
     ``n_expert_per_token`` of ``scores + bias``; the weights are the chosen
     *scores*, without the bias, over their sum plus 1e-6 (hf's
-    ``norm_topk_prob``), scaled by ``routed_scaling_factor``.  The bias moves the
+    ``norm_topk_prob``; LFM2's epsilon, where the DeepSeek-V3 family's code and
+    Nemotron-H's add 1e-20: a departure of under one part in a million of a
+    weight at two to 22 scores in (0, 1), noted in those configurations'
+    ``assumed``), scaled by ``routed_scaling_factor``.  The bias moves the
     choice and never a weight.  Returns ``(top_w, top_idx)``, both ``(N, k)``."""
     _, top_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), cfg.n_expert_per_token)
     top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
@@ -202,7 +206,7 @@ def moe_row_tile(rows_an_expert: float) -> int:
     return min(MOE_ROW_TILE, max(MOE_DECODE_ROW_TILE, 1 << math.ceil(math.log2(max(2 * rows_an_expert, 1)))))
 
 
-def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear):
+def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear, moe_rows=None):
     """A SparseMoE layer in the server, on ``x (B, T, C)``: the router scores
     *all* ``n_expert`` in float32 (:func:`route_sigmoid_group`, or
     :func:`route_sigmoid_bias` on ``mp["expert_bias"]``), the layer
@@ -214,11 +218,24 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear):
     experts would add is left out, and the shared expert is added once.  A
     decode step routes a few rows an expert, so its tiles are narrow
     (:func:`moe_row_tile`); an expert's weights are fetched once a product
-    however many tiles its rows fill."""
+    however many tiles its rows fill.
+
+    ``cfg.moe_latent_size``: the routed experts read ``x W_down`` (one shared
+    projection to the latent, made once) and their weighted sum goes through
+    ``W_up`` once; the router and the shared expert read ``x`` itself.
+    ``cfg.moe_activation`` "relu2": experts and shared expert are ``W2 relu(W1
+    .)^2``, two matrices (``jaxex._moe_share_planned`` with no ``fc_2``; the plan, the
+    sort, the gathers and the grouped products are the gated form's).
+
+    ``moe_rows``: a list that takes this layer's ``(rows that landed on held
+    experts, held experts with a row)``, int32 ``(2,)``, from the plan's own
+    counts (a decode step's: ``engine.stats()["moe"]``)."""
     from thunder_tpu.executors import jaxex
 
     B, T, C = x.shape
     I, Eh = cfg.intermediate_size, cfg.expert_held
+    Cx = cfg.moe_latent_size or C
+    gated = cfg.moe_activation == "swiglu"
     x2 = x.reshape(B * T, C)
     with scope("router"):
         scores = jax.nn.sigmoid(x2.astype(jnp.float32) @ mp["gate"].T.astype(jnp.float32))
@@ -227,15 +244,25 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear):
         else:
             top_w, top_idx = route_sigmoid_group(scores, cfg)
     even = B * T * cfg.n_expert_per_token / cfg.n_expert      # rows an even routing sends a held expert
+    xe = x2
+    if cfg.moe_latent_size:
+        with scope("latent_down"):
+            xe = lin(x2, mp["latent_down"])
     with scope("experts"):
-        y = jaxex._moe_share(
-            x2, top_idx, top_w, mp["fc_1"].reshape(Eh, C, I), mp["fc_2"].reshape(Eh, C, I),
-            mp["proj"].reshape(Eh, I, C), cfg.expert_first, cfg.n_expert,
-            tile=moe_row_tile(even))
+        y, plan = jaxex._moe_share_planned(
+            xe, top_idx, top_w, mp["fc_1"].reshape(Eh, Cx, I), mp["fc_2"].reshape(Eh, Cx, I) if gated else None,
+            mp["proj"].reshape(Eh, I, Cx), cfg.expert_first, cfg.n_expert, moe_row_tile(even))
+        if moe_rows is not None:
+            moe_rows.append(jnp.stack([jnp.sum(plan["cnt"]), jnp.sum(plan["cnt"] > 0, dtype=jnp.int32)]))
+    if cfg.moe_latent_size:
+        with scope("latent_up"):
+            y = lin(y, mp["latent_up"])
     if cfg.shared_expert_size:
         with scope("shared"):
             sp = mp["shared"]
-            shared = lin(jax.nn.silu(lin(x2, sp["fc_1"])) * lin(x2, sp["fc_2"]), sp["proj"])
+            hidden = (jax.nn.silu(lin(x2, sp["fc_1"])) * lin(x2, sp["fc_2"]) if gated
+                      else jnp.square(jax.nn.relu(lin(x2, sp["fc_1"]))))
+            shared = lin(hidden, sp["proj"])
             if cfg.shared_expert_gate:
                 shared = jax.nn.sigmoid(lin(x2, sp["gate"])) * shared
             y = y + shared
@@ -464,13 +491,22 @@ def state_shapes(cfg: Config, B: int) -> dict:
     conv's last inputs, and nothing else.  ssm (a selective scan): ``conv (L_ssm,
     B, ssm_conv_kernel - 1, ssm_inner)`` and the scan's ``state (L_ssm, B,
     ssm_state, ssm_inner)``, the channels on the last axis (the chip would pad a
-    last axis of 16 states to 128 lanes).  Empty for a model of attention
-    layers alone."""
+    last axis of 16 states to 128 lanes).  mamba2 (a Mamba-2 scan): ``conv
+    (L_m, B, mamba_conv_kernel - 1, mamba_conv_width)`` (the inputs ``[x | B | C]``)
+    and ``state (L_m, B, mamba_state, mamba_inner)``: head ``h``'s matrix ``S[h]
+    (head_dim, N)`` lies transposed in columns ``[h head_dim, (h + 1) head_dim)``,
+    so that a head of 64 channels fills its lanes with its neighbour and a
+    token's ``B_t`` and ``C_t`` broadcast along them as columns, as the ssm kind's
+    do.  Empty for a model of attention layers alone."""
     if cfg.conv_layers:
         return {"conv": (len(cfg.conv_layers), B, cfg.conv_kernel - 1, cfg.n_embd)}
     if cfg.ssm_layers:
         n = len(cfg.ssm_layers)
         return {"conv": (n, B, cfg.ssm_conv_kernel - 1, cfg.ssm_inner), "state": (n, B, cfg.ssm_state, cfg.ssm_inner)}
+    if cfg.mamba2_layers:       # the same layout: a head's (head_dim, N) matrix transposed, its channels on the lanes
+        n = len(cfg.mamba2_layers)
+        return {"conv": (n, B, cfg.mamba_conv_kernel - 1, cfg.mamba_conv_width),
+                "state": (n, B, cfg.mamba_state, cfg.mamba_inner)}
     n = len(cfg.linear_layers)
     if not n:
         return {}
@@ -848,6 +884,69 @@ def ssm_recur_dense(state):
     return recur, box
 
 
+def mamba2_mixer(mp, x, tail, cfg: Config, recur, *, n_real=None, lin=_linear):
+    """A mamba2 layer's mixer (Mamba-2, state-space duality) on new tokens ``x
+    (B, T, C)``, for the dense cache and the paged server alike: ``[z | xBC | dt]
+    = x W_in``; ``xBC <- SiLU(conv(xBC) + b)``, causal and depthwise over ``[tail |
+    xBC]`` in float32; ``[xs | B | C] = xBC`` (``xs`` the heads' channels, ``B`` and
+    ``C`` a group of heads); ``dt = softplus(dt + dt_bias)`` a head; the scan itself
+    is ``recur(xs, dt, B, C, A) -> S C`` (xs ``(B, T, d)`` at x's dtype, dt ``(B, T,
+    H)`` float32, B and C ``(B, T, G, N)``, ``A = -exp(A_log) (H,)``; the result ``(B,
+    T, d)`` float32), the caller's closure reading and writing the state wherever
+    it keeps it; ``y = S C + D xs``; ``y <- RMSNorm_group(y SiLU(z)) w`` (the gate
+    before the norm, the norm over each group's ``d / G`` channels); ``y W_out``.
+    ``tail (B, K - 1, d + 2 G N)`` holds the conv's inputs of the K - 1 tokens
+    before ``x``.  Of the T tokens the first ``n_real`` are real (all, where None):
+    the others get ``dt = 0``, which leaves the state exactly as it was, and the
+    new tail ends at the last real token.  Returns ``(y (B, T, C), new tail)``."""
+    f32 = jnp.float32
+    B, T, _ = x.shape
+    H, G, N, d = cfg.mamba_heads, cfg.mamba_groups, cfg.mamba_state, cfg.mamba_inner
+    W = cfg.mamba_conv_width
+    with scope("mamba2/in_proj"):
+        zxd = lin(x, mp["in_proj"])
+        z, xbc, dt = zxd[..., :d], zxd[..., d:d + W], zxd[..., d + W:]
+    with scope("mamba2/conv"):
+        conv, new_tail = _causal_taps(tail, xbc, mp["conv_w"], n_real)
+        xbc = jax.nn.silu(conv + mp["conv_b"].astype(f32)).astype(x.dtype)
+    with scope("mamba2/scan"):
+        xs = xbc[..., :d]
+        Bm, Cm = (xbc[..., d + i * G * N:d + (i + 1) * G * N].reshape(B, T, G, N) for i in (0, 1))
+        dt = jax.nn.softplus(dt.astype(f32) + mp["dt_bias"].astype(f32))
+        if n_real is not None:
+            dt = jnp.where((jnp.arange(T) < n_real)[None, :, None], dt, 0.0)
+        y = recur(xs, dt, Bm, Cm, -jnp.exp(mp["A_log"].astype(f32)))
+        y = y + jnp.repeat(mp["D"].astype(f32), cfg.mamba_head_dim) * xs.astype(f32)
+    with scope("mamba2/norm"):
+        y = (y * jax.nn.silu(z.astype(f32))).reshape(B, T, G, d // G)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+        y = (y.reshape(B, T, d) * mp["norm"].astype(f32)).astype(x.dtype)
+    with scope("mamba2/out"):
+        return lin(y, mp["out_proj"]), new_tail
+
+
+def mamba2_recur_dense(state):
+    """``recur`` for :func:`mamba2_mixer` over a dense state ``(B, N, d)``: the
+    chunked scan for a piece of a prompt, one step for a token.  Returns
+    ``(recur, box)``; after the call ``box[0]`` is the state after the last token,
+    in ``state``'s dtype."""
+    box = [state]
+
+    def recur(xs, dt, Bm, Cm, A):
+        from thunder_tpu.executors import pallasex
+
+        if xs.shape[1] > 1:
+            y, box[0] = pallasex.ssd_chunk(xs, dt, Bm, Cm, A, state)
+            return y
+        # one token: the paged step's XLA form on a one-layer arena whose slots are the rows
+        y, S = pallasex.ssd_decode_step_xla(state[:, None], jnp.arange(state.shape[0]), xs[:, 0], dt[:, 0],
+                                            Bm[:, 0], Cm[:, 0], A, layer=0)
+        box[0] = S[:, 0]
+        return y[:, None]
+
+    return recur, box
+
+
 def gmu_mixer(gp, x, m, *, lin=_linear):
     """A gmu layer's mixer (a gated memory unit): ``(m SiLU(x W_1)) W_2``, ``m (B,
     T, d)`` the scan output of the model's last ssm layer at the same
@@ -1021,12 +1120,28 @@ def require_servable(cfg: Config) -> None:
             "It trains through tt.jit / distributed.make_train_step (llama.gpt_loss).")
 
 
-def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0):
+def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0, moe_rows=None):
     """A block from its mixer's output ``h`` on: the residual sums, the norms
     and the MLP, for every block layout (``n1``: the mixer's input, which a
     shared attention norm hands to the MLP too).  The dense cache's forward
-    and the paged server's end their blocks here."""
-    mlp = partial(_mlp, bp["mlp"], cfg=cfg, quantized=quantized, lora=lora, lora_scaling=lora_scaling)
+    and the paged server's end their blocks here.
+
+    A model of single sublayers (``cfg.single_sublayer``): a mixer layer ends in
+    its residual sum; an "mlp" layer has no mixer (``h`` None) and is ``x +
+    MLP(norm_1(x))``, all of it under the ``mlp`` scope.  ``moe_rows``: see
+    :func:`moe_share_mlp`."""
+    if cfg.single_sublayer and h is not None:
+        with scope("mixer/residual"):
+            return x + h
+    mlp = partial(_mlp, bp["mlp"], cfg=cfg, quantized=quantized, lora=lora, lora_scaling=lora_scaling,
+                  moe_rows=moe_rows)
+    if cfg.single_sublayer:
+        with scope("mlp"):
+            with scope("norm"):
+                n = _norm(x, bp["norm_1"], cfg)
+            m = mlp(n)
+            with scope("residual"):
+                return x + m
     # each sublayer's norm and residual sum count with the sublayer
     if cfg.post_sublayer_norm:          # OLMo: the norms sit on what the sublayers give
         with scope("mixer/norm"):
@@ -1070,7 +1185,9 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
     ``cache["state"]`` beside ``k``/``v`` (:func:`state_shapes`), which hold
     the full-attention layers only; they are the conv's last inputs and the
     delta rule's state *before* position ``pos``.  A model with conv layers
-    keeps ``cache["conv"]`` alone (:func:`shortconv_mixer`'s tails).  ``n_real``: how many of the
+    keeps ``cache["conv"]`` alone (:func:`shortconv_mixer`'s tails); one with
+    mamba2 layers the scan's ``state`` and the conv's tail (:func:`mamba2_mixer`).
+    ``n_real``: how many of the
     T tokens are real (a padded prompt: the tail must leave the state alone).
 
     ``lora``: optional per-request LoRA factors —
@@ -1111,6 +1228,10 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
         if lora:
             lora_l = {t: (ab["a"][:, l], ab["b"][:, l]) for t, ab in lora.items()}
         kind = cfg.layer_kind(l)
+        if kind == "mlp":       # the layer is its feed-forward alone: no mixer, no cache
+            with scope(f"blk{l}"):
+                x = _close_block(bp, x, None, None, cfg, quantized=quantized)
+            continue
         with scope(f"blk{l}"):
             with scope("mixer"):
                 # OLMo's blocks norm what a sublayer gives, not what it takes
@@ -1119,7 +1240,13 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
                 else:
                     with scope("norm"):
                         n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
-                if kind == "ssm":
+                if kind == "mamba2":
+                    j = len(new_state)
+                    recur, box = mamba2_recur_dense(cache["state"][j])
+                    h, tail = mamba2_mixer(bp["mamba2"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
+                    new_conv.append(tail)
+                    new_state.append(box[0])
+                elif kind == "ssm":
                     j = len(new_state)
                     recur, box = ssm_recur_dense(cache["state"][j])
                     h, tail, m = ssm_mixer(bp["ssm"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)

@@ -44,6 +44,8 @@ __all__ = [
 
 # the layer kinds of a decoder-hybrid-decoder (SambaY): built in models.generate, for tt.serve
 HYBRID_DECODER_KINDS = ("ssm", "sliding_attention", "gmu", "cross_attention")
+# the server's alone too: a Mamba-2 (SSD) mixer, and a layer that is its feed-forward alone
+SINGLE_SUBLAYER_KINDS = ("mamba2", "mlp")
 
 
 @dataclass
@@ -107,7 +109,11 @@ class Config:
     # keys: the window is this kind's, where ``sliding_window`` is every
     # layer's), "gmu" (a gated memory unit: the last ssm layer's scan output at
     # the same position, gated) and "cross_attention" (queries alone; keys and
-    # values are the last full_attention layer's).  None = all full attention
+    # values are the last full_attention layer's); and, in the server alone,
+    # "mamba2" (a Mamba-2 mixer, below) and "mlp" (the layer is its feed-forward
+    # alone, no mixer).  A model with an "mlp" layer is made of single
+    # sublayers: every layer is ``x + f(norm_1(x))`` with ``f`` the kind's mixer
+    # or, for "mlp", the model's ``mlp_class``.  None = all full attention
     layer_types: tuple | None = None
     layer_window: int | None = None
     # Differential attention in every attention layer of the model (the cross
@@ -129,6 +135,22 @@ class Config:
     ssm_state: int = 16
     ssm_dt_rank: int = 0
     ssm_conv_kernel: int = 4
+    # The Mamba-2 (SSD) mixer of "mamba2" layers: ``mamba_heads`` heads of
+    # ``mamba_head_dim`` channels (``d`` = their product), ``mamba_groups`` groups
+    # of heads that share ``B`` and ``C`` (``mamba_state`` numbers each).  ``[z | xBC
+    # | dt] = u W_in`` (to ``2 d + 2 G N + H``); ``xBC <- SiLU(conv(xBC) + b)``,
+    # causal and depthwise over ``mamba_conv_kernel`` taps; ``dt <- softplus(dt +
+    # dt_bias)`` and ``A = -exp(A_log)``, one scalar a head; ``S_t[h] = exp(dt_t[h]
+    # A[h]) S_t-1[h] + dt_t[h] x_t[h] B_t[g]^T`` (``mamba_head_dim`` x
+    # ``mamba_state``, float32); ``y_t[h] = S_t[h] C_t[g] + D[h] x_t[h]``; ``y <-
+    # RMSNorm_group(y SiLU(z)) w`` (the gate before the norm, the norm over a
+    # group's ``d / G`` channels); ``y W_out``.  A sequence keeps ``S`` and the
+    # conv's last ``mamba_conv_kernel - 1`` inputs
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_groups: int = 1
+    mamba_state: int = 128
+    mamba_conv_kernel: int = 4
     # The gated short convolution of "conv" layers (hf Lfm2ShortConv): ``[B | C |
     # u] = x W_in``, a causal depthwise conv of ``conv_kernel`` taps (hf
     # ``conv_L_cache``) without bias or activation over ``B * u``, gated by ``C``,
@@ -179,6 +201,14 @@ class Config:
     topk_group: int = 1
     routed_scaling_factor: float = 1.0
     shared_expert_gate: bool = True
+    # ``moe_latent_size`` > 0: the routed experts work in a latent of that width
+    # (the router still reads the token): one shared down-projection before
+    # them (``latent_down``), their weighted sum through one shared
+    # up-projection (``latent_up``); the shared expert stays at the model's
+    # width.  ``moe_activation`` "relu2": experts and shared expert are ungated,
+    # ``W2 relu(W1 x)^2`` (two matrices, no ``fc_2``); "swiglu" as above
+    moe_latent_size: int = 0
+    moe_activation: str = "swiglu"
     # The first ``first_k_dense`` layers of a SparseMoE model keep a dense
     # SwiGLU of width ``dense_intermediate_size`` in place of the experts
     first_k_dense: int = 0
@@ -243,17 +273,33 @@ class Config:
                 assert self.n_expert_per_token <= self.topk_group * (self.n_expert // self.n_group)
             if self.first_k_dense and self.dense_intermediate_size is None:
                 self.dense_intermediate_size = self.intermediate_size
+            assert self.moe_activation in ("swiglu", "relu2"), self.moe_activation
+            assert self.moe_latent_size >= 0
         else:
             assert not self.first_k_dense, "first_k_dense: the leading dense layers of a SparseMoE model"
+            assert not self.moe_latent_size and self.moe_activation == "swiglu", (
+                "moe_latent_size and moe_activation are a SparseMoE layer's")
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             assert len(self.layer_types) == self.n_layer, "layer_types needs one kind a layer"
-            assert set(self.layer_types) <= {"full_attention", "linear_attention", "conv", *HYBRID_DECODER_KINDS}, (
-                self.layer_types)
-            assert sum(k in self.layer_types for k in ("linear_attention", "conv", "ssm")) <= 1, (
-                "linear_attention, conv and ssm layers: a request's state slot holds one kind's arenas")
+            assert set(self.layer_types) <= {"full_attention", "linear_attention", "conv", *HYBRID_DECODER_KINDS,
+                                             *SINGLE_SUBLAYER_KINDS}, self.layer_types
+            assert sum(k in self.layer_types for k in ("linear_attention", "conv", "ssm", "mamba2")) <= 1, (
+                "linear_attention, conv, ssm and mamba2 layers: a request's state slot holds one kind's arenas")
             if set(self.layer_types) & set(HYBRID_DECODER_KINDS):
                 self._check_hybrid_decoder()
+            if "mamba2" in self.layer_types:
+                H, G = self.mamba_heads, self.mamba_groups
+                assert H > 0 and self.mamba_head_dim > 0 and G > 0 and H % G == 0 and self.mamba_state > 0, (
+                    "mamba2 layers need mamba_heads (a multiple of mamba_groups), mamba_head_dim and mamba_state")
+                assert self.mamba_conv_kernel >= 2, "mamba2 layers need mamba_conv_kernel >= 2 taps"
+                assert not self.bias and not self.parallel_residual and not self.post_sublayer_norm, (
+                    "mamba2: sequential, bias-free blocks only")
+            if "mlp" in self.layer_types:
+                assert set(self.layer_types) <= {"full_attention", "mamba2", "mlp"}, (
+                    "single-sublayer blocks: a full_attention mixer, a mamba2 mixer or the feed-forward alone")
+                assert not (self.bias or self.parallel_residual or self.post_sublayer_norm or self.latent
+                            or self.first_k_dense), "single-sublayer blocks: x + f(norm_1(x)), bias-free"
             if "conv" in self.layer_types:
                 assert self.conv_kernel >= 2, "conv layers need conv_kernel >= 2 taps"
                 assert not self.bias and not self.parallel_residual, "conv: sequential, bias-free blocks only"
@@ -356,6 +402,27 @@ class Config:
         return tuple(i for i in range(self.n_layer) if self.layer_kind(i) == "ssm")
 
     @property
+    def mamba2_layers(self) -> tuple:
+        """The Mamba-2 layers, in order (``mamba2_layers.index(i)`` is the layer of the state and conv arenas)."""
+        return tuple(i for i in range(self.n_layer) if self.layer_kind(i) == "mamba2")
+
+    @property
+    def mamba_inner(self) -> int:
+        """Channels of a Mamba-2 layer's ``x``, ``z`` and ``y``: heads times their size."""
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_width(self) -> int:
+        """Channels of a Mamba-2 layer's conv: ``[x | B | C]``."""
+        return self.mamba_inner + 2 * self.mamba_groups * self.mamba_state
+
+    @property
+    def single_sublayer(self) -> bool:
+        """Every layer is one sublayer, ``x + f(norm_1(x))``: the model has layers
+        that are their feed-forward alone ("mlp"), so its mixer layers have none."""
+        return "mlp" in (self.layer_types or ())
+
+    @property
     def hybrid_decoder(self) -> bool:
         """A decoder-hybrid-decoder: any of its four layer kinds, or differential
         attention.  The server keeps such a model's caches a layer kind and runs
@@ -396,8 +463,8 @@ class Config:
     @property
     def state_layers(self) -> tuple:
         """The layers that keep something a request beside K and V (a slot of
-        the server's state pool): the linear_attention, the conv or the ssm layers."""
-        return self.linear_layers or self.conv_layers or self.ssm_layers
+        the server's state pool): the linear_attention, the conv, the ssm or the mamba2 layers."""
+        return self.linear_layers or self.conv_layers or self.ssm_layers or self.mamba2_layers
 
     @property
     def linear_qkv_width(self) -> int:
@@ -607,6 +674,22 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
         elif config.layer_kind(i) == "gmu":
             block["gmu"] = {"in_proj": dense(next(keys), config.n_embd, config.ssm_inner),
                             "out_proj": dense(next(keys), config.ssm_inner, config.n_embd)}
+        elif config.layer_kind(i) == "mamba2":
+            C, d, H = config.n_embd, config.mamba_inner, config.mamba_heads
+            block["mamba2"] = {
+                "in_proj": dense(next(keys), C, d + config.mamba_conv_width + H),       # [z | x B C | dt]
+                "conv_w": dense(next(keys), config.mamba_conv_kernel, config.mamba_conv_width),
+                "conv_b": zeros(config.mamba_conv_width),
+                # as the layer that trains these models starts them: dt log-uniform in
+                # [1e-3, 0.1] through the softplus, A = -(1 .. 16) a head, D = 1
+                "dt_bias": _inv_softplus(jnp.exp(jnp.linspace(math.log(1e-3), math.log(0.1), H))).astype(jnp.float32),
+                "A_log": jnp.log(jnp.linspace(1.0, 16.0, H, dtype=jnp.float32)),
+                "D": jnp.ones((H,), jnp.float32),
+                "norm": jnp.ones((d,), dtype=dtype),
+                "out_proj": dense(next(keys), d, C),
+            }
+        elif config.layer_kind(i) == "mlp":
+            pass                                                # the layer is its feed-forward alone
         elif config.latent:
             dc, dr, rq = config.kv_lora_rank, config.qk_rope_head_dim, config.q_lora_rank
             block["attn"] = {
@@ -638,7 +721,10 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
                 block["attn"].update(bq=zeros(nh * hs), bo=zeros(config.n_embd))
                 if "wk" in block["attn"]:
                     block["attn"].update(bk=zeros(ng * hs), bv=zeros(ng * hs))
-        if not config.shared_attention_norm:
+        if config.single_sublayer and config.layer_kind(i) != "mlp":
+            params["blocks"].append(block)                      # a mixer alone: no second norm, no feed-forward
+            continue
+        if not config.shared_attention_norm and not config.single_sublayer:
             block["norm_2"] = norm_init((config.n_embd,), dtype=dtype)
             if config.bias:
                 block["norm_2_b"] = zeros(config.n_embd)
@@ -666,19 +752,24 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
             # held experts stacked and flattened to two dims, "x @ W" layout:
             # fc_1/fc_2 (held * C, I), proj (held * I, C); the grouped
             # products view them as (held, C, I) and (held, I, C)
+            # (a latent share: C is the latent's width, and two shared projections stand around the experts)
             Eh, I, C = config.expert_held, config.intermediate_size, config.n_embd
+            Cx = config.moe_latent_size or C
+            gated = config.moe_activation == "swiglu"           # "relu2": two matrices an expert, no fc_2
             block["mlp"] = {
                 "gate": dense(next(keys), C, config.n_expert),
-                "fc_1": dense(next(keys), I, Eh * C),
-                "fc_2": dense(next(keys), I, Eh * C),
-                "proj": dense(next(keys), C, Eh * I),
+                "fc_1": dense(next(keys), I, Eh * Cx),
+                **({"fc_2": dense(next(keys), I, Eh * Cx)} if gated else {}),
+                "proj": dense(next(keys), Cx, Eh * I),
             }
+            if config.moe_latent_size:
+                block["mlp"].update(latent_down=dense(next(keys), C, Cx), latent_up=dense(next(keys), Cx, C))
             if config.moe_router == "sigmoid_bias":     # hf starts it at zero; it takes no gradient
                 block["mlp"]["expert_bias"] = jnp.zeros((config.n_expert,), jnp.float32)
             if config.shared_expert_size:
                 Is = config.shared_expert_size
                 block["mlp"]["shared"] = {
-                    "fc_1": dense(next(keys), C, Is), "fc_2": dense(next(keys), C, Is),
+                    "fc_1": dense(next(keys), C, Is), **({"fc_2": dense(next(keys), C, Is)} if gated else {}),
                     "proj": dense(next(keys), Is, C),
                 }
                 if config.shared_expert_gate:
@@ -1009,8 +1100,9 @@ def serving_only(config: Config) -> str | None:
     """Why ``block_forward`` (``tt.jit`` / ``make_train_step``) cannot run this
     config, or None: latent attention, the gated short convolution, a
     decoder-hybrid-decoder's kinds (selective scan, per-kind window, gated
-    memory unit, cross attention, differential attention), the sigmoid routers
-    and leading dense layers are built in ``models.generate`` for the server
+    memory unit, cross attention, differential attention), the Mamba-2 mixer,
+    single-sublayer blocks, the sigmoid routers, leading dense layers and the
+    latent ungated expert share are built in ``models.generate`` for the server
     alone."""
     if config.conv_layers:
         return ("layer_types with 'conv' (the gated short convolution is built in models.generate, for tt.serve, "
@@ -1020,10 +1112,15 @@ def serving_only(config: Config) -> str | None:
                 "(a decoder-hybrid-decoder's kinds are built in models.generate, for tt.serve, and have no traced form)")
     if config.latent:
         return "kv_lora_rank > 0 (latent attention is built in models.generate, for tt.serve, and has no traced form)"
+    if set(config.layer_types or ()) & set(SINGLE_SUBLAYER_KINDS):
+        return ("layer_types with 'mamba2' or 'mlp' (the Mamba-2 mixer and single-sublayer blocks are built in "
+                "models.generate, for tt.serve, and have no traced form: the chunked scan has no backward)")
     if config.mlp_class == "SparseMoE" and (config.moe_router != "softmax" or config.first_k_dense
-                                            or (config.shared_expert_size and not config.shared_expert_gate)):
-        return ("a SparseMoE layer with moe_router='sigmoid_group' or 'sigmoid_bias', first_k_dense or an ungated "
-                "shared expert (built in models.generate, for tt.serve; the traced expert layer routes by softmax)")
+                                            or (config.shared_expert_size and not config.shared_expert_gate)
+                                            or config.moe_latent_size or config.moe_activation != "swiglu"):
+        return ("a SparseMoE layer with moe_router='sigmoid_group' or 'sigmoid_bias', first_k_dense, an ungated "
+                "shared expert, moe_latent_size or moe_activation='relu2' (built in models.generate, for tt.serve; "
+                "the traced expert layer routes by softmax into SwiGLU experts at the model's width)")
     return None
 
 

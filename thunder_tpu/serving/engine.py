@@ -270,13 +270,14 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
                        priorities=None, fault_plan=None) -> str | None:
     """Why an engine with these options cannot serve a config that keeps a
     state a request beside its KV (a delta rule's recurrent state and conv
-    tail, a short convolution's tail alone, or a selective scan's state and
-    tail), or None.  Each is a mechanism that is not built, not a shortcut that
+    tail, a short convolution's tail alone, a selective scan's state and
+    tail, or a Mamba-2 scan's matrix state a head and tail), or None.  Each is a mechanism that is not built, not a shortcut that
     was skipped (ROADMAP Queue 2).  A window of a layer kind
     (``cfg.layer_window``) passes: its K/V live in the slot's ring; the
     model-wide ``sliding_window`` beside a state is still refused.  A
     decoder-hybrid-decoder (``cfg.hybrid_decoder``) has its paged decode
-    program and whole-prompt prefills alone, and refuses what needs another."""
+    program and whole-prompt prefills alone, and refuses what needs another;
+    so does a model with mamba2 layers, whose paged forward steps one token a row."""
     if getattr(cfg, "hybrid_decoder", False):
         if kv_dtype is not None:
             return ("kv_dtype= (an int8 or fp8 arena) is unsupported: the ring arenas and the differential walk "
@@ -284,6 +285,14 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
         if prefill_chunk is not None:
             return ("prefill_chunk= is unsupported: a piece of a prompt past position 0 has no program over "
                     "per-kind caches (the scan from a slot's state, the ring's older blocks)")
+        if priorities is not None:
+            return "priorities= is unsupported: a preempted request resumes through the chunk programs, which are not built"
+        if fault_plan is not None:
+            return "fault_plan= is unsupported: re-prefill recovery replays through the chunk programs, which are not built"
+    if getattr(cfg, "mamba2_layers", ()):
+        if prefill_chunk is not None:
+            return ("prefill_chunk= is unsupported: a piece of a prompt past position 0 has no program for a Mamba-2 "
+                    "layer (the chunked scan starts from a given state, but the paged program steps one token a row)")
         if priorities is not None:
             return "priorities= is unsupported: a preempted request resumes through the chunk programs, which are not built"
         if fault_plan is not None:
@@ -394,6 +403,7 @@ class ServingEngine:
             if why:
                 kind = ("linear_attention layers (a recurrent state a request)" if cfg.linear_layers
                         else "conv layers (a conv tail a request)" if cfg.conv_layers
+                        else "mamba2 layers (a Mamba-2 scan's matrix state a head a request)" if cfg.mamba2_layers
                         else "ssm layers (a selective scan's state a request) beside per-kind K/V")
                 raise NotImplementedError(f"config {getattr(cfg, 'name', '?')!r} has {kind}: {why}")
             prefix_sharing = False
@@ -680,6 +690,11 @@ class ServingEngine:
         # each decode step consumes the previous step's device outputs
         # directly (no host->device transfer); see _decode_dispatch
         self._decode_state: dict | None = None
+        # a SparseMoE model's decode steps sum how their rows fell on the held
+        # experts, on the device: float32 [steps, rows, rows^2, hit share] (stats()["moe"])
+        # (not under a mesh: the program's shardings there are a fixed list)
+        self._moe_rows = (jax.device_put(np.zeros((4,), np.float32))        # a transfer: no program is built for it
+                          if cfg.mlp_class == "SparseMoE" and mesh is None else None)
         # the speculative lane's chained round inputs (toks=y, pos+n_emit)
         # plus its acceptance accounting; see serving.speculative
         self._spec_state: dict | None = None
@@ -1124,7 +1139,10 @@ class ServingEngine:
         linear_attention layers, its slot's ``state (L_lin, nv, dk, dv)`` and
         ``conv (L_lin, K - 1, channels)`` as stored (conv layers: ``conv
         (L_conv, conv_kernel - 1, n_embd)`` alone; ssm layers: ``state (L_ssm,
-        ssm_state, ssm_inner)`` and ``conv``).  A model with sliding_attention
+        ssm_state, ssm_inner)`` and ``conv``; mamba2 layers: ``state (L_m,
+        mamba_state, mamba_inner)``, a head's matrix transposed in its columns, and
+        ``conv (L_m, K - 1, mamba_conv_width)``; a layer that is a feed-forward
+        alone keeps nothing and is passed over).  A model with sliding_attention
         layers: ``k``/``v`` are the full_attention layers' and ``k_ring``/``v_ring
         (L_ring, ng, n, hs)`` the window layers' last ``n = min(tokens,
         layer_window)`` tokens, in order.  A lane-packed arena's
@@ -1233,7 +1251,8 @@ class ServingEngine:
             "arena_bytes": self.pool.arena_bytes(),
             **({"state": self.pool.state.snapshot()} if self._hybrid else {}),
             **({"moe": {"experts_held": self.cfg.expert_held, "expert_first": self.cfg.expert_first,
-                        "experts_published": self.cfg.n_expert, "router": self.cfg.moe_router}}
+                        "experts_published": self.cfg.n_expert, "router": self.cfg.moe_router,
+                        **self._moe_rows_stats()}}
                if self.cfg.mlp_class == "SparseMoE" else {}),
             "async_step": self.async_step,
             "prefill_chunk": sch.prefill_chunk,
@@ -1295,6 +1314,25 @@ class ServingEngine:
                if self._goodput is not None else {}),
             "pool_occupancy": self.pool.occupancy_snapshot(),
         }
+
+    def _moe_rows_stats(self) -> dict:
+        """How the single-step decode program's rows fell on the held experts,
+        summed on the device a step (``_build_decode_paged``) and fetched here,
+        which waits for the step in flight: ``expert_rows_per_step``, the rows (of
+        ``batch bucket x n_expert_per_token`` routed, padding rows' too) that
+        landed on a held expert, a mean over the expert layers, as the steps'
+        ``mean`` and ``spread`` (their standard deviation); ``experts_hit_share``,
+        the share of the held experts that got at least one row, a mean over
+        layers and steps; ``row_sums`` ``[steps, sum of rows, sum of rows^2, sum of
+        hit shares]``, for a reader that wants a window's own (the difference of
+        two calls).  Nones before the first such step."""
+        n, rows, sq, hit = (float(v) for v in jax.device_get(self._moe_rows)) if self._moe_rows is not None else (0,) * 4
+        if not n:
+            return {"expert_rows_per_step": {"mean": None, "spread": None}, "experts_hit_share": None,
+                    "row_sums": [0.0, 0.0, 0.0, 0.0]}
+        mean = rows / n
+        return {"expert_rows_per_step": {"mean": mean, "spread": max(sq / n - mean * mean, 0.0) ** 0.5},
+                "experts_hit_share": hit / n, "row_sums": [n, rows, sq, hit]}
 
     def _spec_stats(self) -> dict:
         """Speculative-lane acceptance accounting: the histogram counts
@@ -2063,6 +2101,8 @@ class ServingEngine:
                      keys_d, lora_arenas, slots_d)
         if N > 1:
             call_args = call_args + (stop_d,)
+        elif self._moe_rows is not None:
+            call_args = call_args + (self._moe_rows,)
         if sslots_d is not None:
             call_args = call_args + (sslots_d,)
         if cmask_d is not None:
@@ -2074,7 +2114,9 @@ class ServingEngine:
             ys_tok, ys_emit, toks_f, keys_f, pos_f, arenas = outs
             nxt, new_keys, new_pos = toks_f, keys_f, pos_f
         else:
-            nxt, new_keys, new_pos, arenas = outs
+            nxt, new_keys, new_pos, arenas, *sums = outs
+            if sums:
+                self._moe_rows = sums[0]
         # past the point of no return: the call consumed the donated arenas
         self._fault_point(FP_SCATTER, tuple(r.rid for r in running))
         pool.set_arenas(arenas)
@@ -3221,13 +3263,18 @@ class ServingEngine:
         cos_all, sin_all = build_rope_cache(cfg, cap)
         mesh = self.mesh
 
+        moe = self._moe_rows is not None
+
         @partial(jax.jit, donate_argnums=(4,), **self._jit_kwargs("decode_paged"))
         def decode_paged(params, toks, pos, tables, arenas, keys, lora, slots,
                          *cmask):
             more = {}
+            if moe:
+                # the expert share's running sums ride first, and come back last
+                more, moe_sums, cmask = {"moe_rows": True}, cmask[0], cmask[1:]
             if hybrid:
                 # the rows' state slots ride before the constraint mask
-                more, cmask = {"sslots": cmask[0]}, cmask[1:]
+                more, cmask = {**more, "sslots": cmask[0]}, cmask[1:]
             logits, fresh = forward_paged(
                 params, toks[:, None], pos, arenas, tables, cos_all, sin_all,
                 cfg, cdtype=cdtype, mesh=mesh, lora_fused=True,
@@ -3245,6 +3292,11 @@ class ServingEngine:
             arenas = with_state(
                 write_fresh_kv(arenas, fresh, tables, pos, block_size=bs,
                                kv_dtype=kv_dtype, mesh=mesh), fresh)
+            if moe:
+                with scope("mlp/router"):       # this step's rows and hit share, a mean over the expert layers
+                    rows, hit = jnp.mean(fresh["moe_rows"].astype(jnp.float32), axis=0)
+                    moe_sums = moe_sums + jnp.stack([1.0, rows, rows * rows, hit / cfg.expert_held])
+                return nxt, new_keys, pos + 1, arenas, moe_sums
             return nxt, new_keys, pos + 1, arenas
 
         return decode_paged

@@ -49,6 +49,7 @@ from thunder_tpu.executors.pallasex import (
     paged_decode_path,
     paged_token_write,
     paged_token_write_fused,
+    ssd_decode_step,
     ssm_decode_step,
 )
 from thunder_tpu.models.generate import (
@@ -57,6 +58,7 @@ from thunder_tpu.models.generate import (
     gmu_mixer,
     kv_lane_pack,
     ring_blocks,
+    mamba2_mixer,
     ssm_mixer,
     shortconv_mixer,
     mla_absorb,
@@ -241,6 +243,29 @@ def _ssm_paged(sp, x, arenas, sslots, cfg, *, layer, lin):
         return y, held["state"], arenas["conv"].at[sslots, layer].set(new_tail), m
 
 
+def _mamba2_paged(mp, x, arenas, sslots, cfg, *, layer, lin):
+    """A mamba2 layer of :func:`forward_paged`, one token a row:
+    ``generate.mamba2_mixer`` (the one mixer; the dense cache calls it too) with
+    the state where the server keeps it: ``ssd_decode_step`` on the row's slot of
+    the state arena, in place, and the conv's tail in its slot of the conv arena.
+    Returns ``(y, state arena, conv arena)``."""
+    if x.shape[1] != 1:
+        raise NotImplementedError(
+            "a Mamba-2 scan is served one token a row here (ssd_decode_step); a whole prompt goes through "
+            "the prefill_fresh program's chunked scan, and a piece of one has no program yet")
+    held = {"state": arenas["state"]}
+    with scope("mamba2/cache"):
+        tail = arenas["conv"][sslots, layer]                         # (B, K - 1, d + 2 G N)
+
+    def recur(xs, dt, Bm, Cm, A):
+        y, held["state"] = ssd_decode_step(held["state"], sslots, xs[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0], A, layer=layer)
+        return y[:, None]
+
+    y, new_tail = mamba2_mixer(mp, x, tail, cfg, recur, lin=lin)
+    with scope("mamba2/cache"):
+        return y, held["state"], arenas["conv"].at[sslots, layer].set(new_tail)
+
+
 def _diff_paged(ap, x, l, cfg, k_arena, v_arena, tables, pos, *, layer, window, cdtype, name, lin, fresh_kv=None):
     """A differential-attention layer of :func:`forward_paged`, one token a
     row: ``generate.diff_attention`` (the one mixer; the dense cache calls it
@@ -274,7 +299,7 @@ def with_state(arenas, fresh):
 
 def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                   cdtype, quantized=False, lora=None, lora_scaling=1.0,
-                  mesh=None, lora_fused=False, sslots=None, n_real=None):
+                  mesh=None, lora_fused=False, sslots=None, n_real=None, moe_rows=False):
     """Decode/verify forward straight off the KV block arenas.
 
     Mirrors ``forward_with_cache`` (vec-pos) except attention: instead of
@@ -307,7 +332,13 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     the sliding_attention layers' K and V, a ring a state slot
     (``kv_pool.ring_tables``), ``k`` / ``v`` the full_attention layers' alone, which
     the cross_attention layers walk too; ``fresh`` carries ``k_ring`` / ``v_ring``
-    ``(B, L_ring, ng, hs)`` and ``ring_tables`` for :func:`write_fresh_kv`."""
+    ``(B, L_ring, ng, hs)`` and ``ring_tables`` for :func:`write_fresh_kv`.  A model
+    with mamba2 layers (one token a row): ``state`` and ``conv`` are the Mamba-2
+    scans' (:func:`_mamba2_paged`); an "mlp" layer (a feed-forward alone) keeps
+    nothing.  ``moe_rows``: ``fresh`` also carries ``moe_rows (L_moe, 2)`` int32, each
+    expert layer's rows that landed on held experts and held experts with a row
+    (``generate.moe_share_mlp``), which the decode program sums for
+    ``engine.stats()["moe"]``."""
     B, T = idx.shape
     hs, nh = cfg.head_size, cfg.n_head
     window = cfg.sliding_window
@@ -326,10 +357,15 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     delta_fn = lora_delta_fused if (lora_fused and mesh is None) else _lora_delta
     fresh_k, fresh_v, fresh_rows = [], [], []
     ring_k, ring_v, ring_tabs, gmu_m = [], [], None, None
+    rows_of = [] if moe_rows else None
     for l, bp in enumerate(params["blocks"]):
         lora_l = None
         if lora:
             lora_l = {t: (ab["a"][:, l], ab["b"][:, l]) for t, ab in lora.items()}
+        if cfg.layer_kind(l) == "mlp":      # the layer is its feed-forward alone: no mixer, no cache
+            with scope(f"blk{l}"):
+                x = _close_block(bp, x, None, None, cfg, quantized=quantized, moe_rows=rows_of)
+            continue
         with scope(f"blk{l}"):
             with scope("mixer"):
                 if cfg.post_sublayer_norm:
@@ -338,7 +374,11 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                     with scope("norm"):
                         n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
                 kind = cfg.layer_kind(l)
-                if kind == "ssm":
+                if kind == "mamba2":
+                    h, state_arena, conv_arena = _mamba2_paged(
+                        bp["mamba2"], n1, {"state": state_arena, "conv": conv_arena}, sslots, cfg, layer=n_lin, lin=lin)
+                    n_lin += 1
+                elif kind == "ssm":
                     h, state_arena, conv_arena, m = _ssm_paged(
                         bp["ssm"], n1, {"state": state_arena, "conv": conv_arena}, sslots, cfg, layer=n_lin, lin=lin)
                     n_lin += 1
@@ -405,19 +445,23 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                             h = h + delta_fn(y, *lora_l["wo"], lora_scaling)
                     fresh_k.append(fk)
                     fresh_v.append(fv)
-            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
+            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling,
+                             moe_rows=rows_of)
 
     logits = _head_logits(params, x, cfg, None, quantized)
     with scope("mixer/cache"):
         if cfg.latent:          # (B, L, 1, W): the token writer's layout, one group
-            return logits, {"latent": jnp.stack(fresh_rows, axis=1)[:, :, None]}
-        fresh = {"k": jnp.stack(fresh_k, axis=1), "v": jnp.stack(fresh_v, axis=1)}
+            fresh = {"latent": jnp.stack(fresh_rows, axis=1)[:, :, None]}
+        else:
+            fresh = {"k": jnp.stack(fresh_k, axis=1), "v": jnp.stack(fresh_v, axis=1)}
         if ring_k:      # the sliding_attention layers' K and V of this step, and the tables of their rings
             fresh.update(k_ring=jnp.stack(ring_k, axis=1), v_ring=jnp.stack(ring_v, axis=1), ring_tables=ring_tabs)
     if conv_arena is not None:
         fresh.update(conv=conv_arena)
     if state_arena is not None:
         fresh.update(state=state_arena)
+    if rows_of:
+        fresh.update(moe_rows=jnp.stack(rows_of))
     return logits, fresh
 
 

@@ -608,6 +608,151 @@ class TestDecodeAhead:
 
 
 #
+# a rebuild of the chain's inputs carries the standing rows over by request
+#
+
+REBUILT = ("toks", "host_pos", "tables", "keys", "slots", "sslots", "stop", "facts", "live", "constrained", "deadline")
+
+
+def _watch_rebuilds(eng):
+    """Holds every rebuild of ``eng`` to the full build: the arrays it hands
+    on (and what it recorded of each row, and what the chain may run ahead)
+    are, element for element and dtype for dtype, those of a build that
+    carries nothing.  Returns the log of rebuilds: the rids, whether it was a
+    full build, the rows written, and the rows that had to be: new to the
+    build before, or whose table, slots or stop differ from what it held."""
+    log = []
+    inner = eng._decode_inputs
+
+    def watched(running, sig, old):
+        host = inner(running, sig, old)
+        full = inner(running, sig, None)
+        assert full["full"] and full["written"] == len(running)
+        for name in REBUILT:
+            assert host[name].dtype == full[name].dtype, name
+            np.testing.assert_array_equal(host[name], full[name], err_msg=name)
+        assert eng._ahead_steps(host) == eng._ahead_steps(full)
+        changed = None
+        if not host["full"]:
+            at = old["at"]
+            changed = sum(
+                rid not in at or any(not np.array_equal(full[a][i], old[a][at[rid]])
+                                     for a in ("tables", "slots", "sslots", "stop"))
+                for i, rid in enumerate(sig[0]))
+        log.append({"rids": sig[0], "full": host["full"], "written": host["written"], "changed": changed,
+                    "epochs": [r.preemptions for r in running], "pos": host["host_pos"][:len(running)].copy()})
+        return host
+
+    eng._decode_inputs = watched
+    return log
+
+
+def _staggered(eng, cfg, lengths, seed=31, **kw):
+    rng = np.random.default_rng(seed)
+    return [eng.submit(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), max_new_tokens=m,
+                       key=jax.random.PRNGKey(200 + i), **kw) for i, (n, m) in enumerate(lengths)]
+
+
+class TestDecodeRebuild:
+    @pytest.mark.parametrize("case", ["turnover", "turnover_sync", "block_boundary", "window", "preempted",
+                                      "bucket_and_recover", "state_slots", "decode_steps", "deadlines"])
+    def test_a_rebuild_hands_on_what_the_full_build_would(self, micro, case, monkeypatch):
+        """At every rebuild of a run with staggered lengths the patched host
+        arrays equal the full build's (``_watch_rebuilds``), and the rows
+        written from Python are the rows that changed, not the batch."""
+        cfg, params = micro
+        opts = dict(temperature=0.7)
+        lengths = AHEAD_REQUESTS + [(6, 8), (11, 3)]           # seven through three slots
+        if case == "turnover_sync":
+            opts["async_step"] = False
+        elif case == "window":
+            cfg = llama.Config.from_name("tiny-llama-debug", **{**MICRO, "sliding_window": 6})
+            opts.update(block_size=2, num_blocks=48, block_buckets=(16,))
+        elif case == "state_slots":
+            cfg, params, opts = _hybrid(monkeypatch)
+        elif case == "decode_steps":
+            opts["decode_steps"] = 2
+        if case == "preempted":
+            eng = _engine(cfg, params, max_batch=2, batch_buckets=(2,), num_blocks=12, priorities=True, **opts)
+        elif case == "bucket_and_recover":
+            eng = _engine(cfg, params, max_batch=3, batch_buckets=(2, 4), **opts)
+        elif case == "state_slots":
+            eng = tt.serve(None, params, cfg, **opts)
+        else:
+            eng = _engine(cfg, params, max_batch=3, **opts)
+        log = _watch_rebuilds(eng)
+
+        if case == "preempted":
+            # two low rows fill the batch; a high arrival takes the younger
+            # one's slot, and the victim comes back an epoch later
+            lows = _staggered(eng, cfg, [(6, 14), (7, 14)], priority="low")
+            for _ in range(5):
+                eng.step()
+            high = _staggered(eng, cfg, [(5, 4)], seed=32, priority="high")
+            eng.drain()
+            assert eng.preempted == 1 and all(h.done() for h in lows + high)
+            stood = next(h.rid for h in lows if h._req.preemptions == 0)
+            joins = [e for e in log if not e["full"]]
+            assert joins and all(e["written"] <= 1 for e in joins)
+            # the row that stood was written once, by the first build
+            assert any(stood in e["rids"] and e["written"] == 1 and 1 in e["epochs"] for e in joins)
+        elif case == "bucket_and_recover":
+            hs = _staggered(eng, cfg, [(5, 12), (7, 10)])
+            for _ in range(4):
+                eng.step()
+            assert [e["full"] for e in log] == [True]           # two rows: the bucket of 2
+            hs += _staggered(eng, cfg, [(6, 9), (5, 6)], seed=33)   # a third row: the bucket of 4; a fourth waits
+            for _ in range(4):
+                eng.step()
+            assert [e["full"] for e in log] == [True, True] and len(log[-1]["rids"]) == 3
+            eng.recover()
+            eng.drain()
+            assert [e["full"] for e in log[:3]] == [True, True, True]
+            assert eng.stats()["decode_rebuild"]["full"] == sum(e["full"] for e in log) >= 3
+            assert all(h.result(drive=False).finish_reason == "length" for h in hs)
+        else:
+            hs = _staggered(eng, cfg, lengths if case != "block_boundary" else [(5, 24), (6, 4), (7, 9)],
+                            **({"deadline": 1e6} if case == "deadlines" else {}))
+            eng.drain()
+            assert all(h.result(drive=False).finish_reason == "length" for h in hs)
+
+        st = eng.stats()["decode_rebuild"]
+        assert st["rebuilds"] == len(log) and st["full"] == sum(e["full"] for e in log)
+        assert st["rows_written"] == sum(e["written"] for e in log)
+        assert st["rows_carried"] == sum(len(e["rids"]) - e["written"] for e in log)
+        assert st["carried_share"] == st["rows_carried"] / (st["rows_carried"] + st["rows_written"])
+        carried = [e for e in log if not e["full"]]
+        assert carried and st["rows_carried"] > 0
+        if case != "preempted":                                 # a resumed row may be given its old blocks again
+            assert all(e["written"] == e["changed"] for e in carried)
+        if case in ("turnover", "turnover_sync", "state_slots", "decode_steps", "deadlines"):
+            # a successor is the one row written; a rebuild after a row's end
+            # with nobody waiting writes none
+            for before, e in zip(log, log[1:]):
+                if not e["full"]:
+                    assert e["written"] == len(set(e["rids"]) - set(before["rids"])) <= 1
+            assert log[0]["full"] and not any(e["full"] for e in log[1:])
+        if case == "block_boundary":
+            # the long row crosses a block boundary between rebuilds and is
+            # carried all the same: its table was leased whole at admission
+            bs = eng.pool.block_size
+            crossed = [e for before, e in zip(log, log[1:])
+                       if e["rids"][0] == before["rids"][0] and e["pos"][0] // bs > before["pos"][0] // bs]
+            assert crossed and all(e["written"] == 0 for e in crossed)
+        if case == "window":
+            # a block leaves a row's window every other step: that row is
+            # written, its neighbours at another phase are not
+            assert any(e["written"] == 1 and len(e["rids"]) == 3 for e in carried)
+            assert sum(e["written"] for e in carried) < sum(len(e["rids"]) for e in carried)
+        if case == "turnover":
+            sync = _engine(cfg, params, max_batch=3, async_step=False, **opts)
+            want = _staggered(sync, cfg, lengths)
+            sync.drain()
+            assert [h.result(drive=False).tokens.tolist() for h in hs] == [h.result(drive=False).tokens.tolist() for h in want]
+        eng.shutdown()
+
+
+#
 # soak (slow): every guarantee at once
 #
 

@@ -281,3 +281,70 @@ class TestPreemption:
         h1.result()
         assert eng.preempted == 0
         eng.shutdown()
+
+
+#
+# the deadline check looks at the requests that carry a deadline, and at no other
+#
+
+
+class _NeverWalked(list):
+    """A queue or running list that fails the test if it is iterated."""
+
+    def __iter__(self):
+        raise AssertionError("the deadline check walked a list with no deadline in it")
+
+
+class TestDeadlineCheck:
+    @pytest.mark.parametrize("case", ["none_anywhere", "queued_and_running", "finished_first", "preempted"])
+    def test_deadline_expired_is_the_old_walk_over_fewer_requests(self, micro, case):
+        """With no deadline anywhere neither list is iterated; with deadlines
+        on queued and running requests the same requests expire in the same
+        order as the walk over ``running + queue`` gave (running first, each
+        list in its order), whatever order the deadlines were given in."""
+        from thunder_tpu.serving.kv_pool import PagedKVPool
+        from thunder_tpu.serving.scheduler import Scheduler
+
+        clk = {"t": 0.0}
+        pool = PagedKVPool(micro[0], num_blocks=64, block_size=4, dtype=jnp.float32)
+        sch = Scheduler(pool, max_batch=3, max_queue=16, clock=lambda: clk["t"])
+
+        def old_walk():
+            now = sch.clock()
+            return [r for r in (*sch.running, *sch.queue) if r.deadline_t is not None and now >= r.deadline_t]
+
+        def submit(deadline, priority=1):
+            return sch.submit(np.arange(5, dtype=np.int32), 4, key=np.zeros(2, np.uint32),
+                              deadline_s=deadline, priority=priority)
+
+        def admit():
+            head = sch.queue[0]
+            sch.admit(head, pool.alloc(sch.blocks_needed(head)), 0)
+            return head
+
+        if case == "none_anywhere":
+            for _ in range(5):
+                submit(None)
+            admit(), admit()
+            sch.queue, sch.running = _NeverWalked(sch.queue), _NeverWalked(sch.running)
+            clk["t"] = 1e9
+            assert sch.deadline_expired() == []
+            return
+        # deadlines out of order, on running and queued requests, some without
+        reqs = [submit(d) for d in (7.0, None, 3.0, 9.0, None, 2.0, 5.0)]
+        admit(), admit(), admit()                               # 7.0, None, 3.0 run; the rest wait
+        if case == "finished_first":
+            sch.finish(reqs[2], "evicted")                      # a deadline that no longer counts
+            sch.finish(reqs[5], "evicted")
+        elif case == "preempted":
+            sch.preempt(reqs[0])                                # running -> queued, its deadline stands
+        seen = []
+        for t in (0.0, 2.5, 4.0, 6.0, 8.0, 20.0):
+            clk["t"] = t
+            got = sch.deadline_expired()
+            assert got == old_walk() and all(a is b for a, b in zip(got, old_walk()))
+            seen.append([r.rid for r in got])
+        assert seen[0] == [] and len(seen[-1]) == (3 if case == "finished_first" else 5)
+        for r in old_walk():
+            sch.finish(r, "deadline")
+        assert sch.deadline_expired() == [] and not sch._deadlined

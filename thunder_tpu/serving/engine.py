@@ -690,6 +690,12 @@ class ServingEngine:
         # each decode step consumes the previous step's device outputs
         # directly (no host->device transfer); see _decode_dispatch
         self._decode_state: dict | None = None
+        # the host side of the last built decode inputs: its arrays and what
+        # each row was built from.  It outlives the harvest that drops the
+        # chain, and the next rebuild carries its standing rows over by
+        # request (_decode_inputs); recovery drops it
+        self._decode_host: dict | None = None
+        self._rebuilds = {"rebuilds": 0, "full": 0, "rows_written": 0, "rows_carried": 0}
         # a SparseMoE model's decode steps sum how their rows fell on the held
         # experts, on the device: float32 [steps, rows, rows^2, hit share] (stats()["moe"])
         # (not under a mesh: the program's shardings there are a fixed list)
@@ -969,7 +975,14 @@ class ServingEngine:
         2. expire deadlines (a request finished here is skipped by any
            in-flight record that still names it);
         3. **decode dispatch** for the decode-ready batch — the device
-           starts on step *k* while the host continues;
+           starts on step *k* while the host continues.  Where the batch or
+           a table changed (a turnover) the dispatch rebuilds the chain's
+           inputs: the rows that stand are carried over from the build
+           before by request and only the changed ones are written from
+           Python (:meth:`_decode_inputs`); the call takes the host arrays
+           as they are, and what the chain's later steps reuse of them goes
+           to the device in one transfer after it, under the step (the
+           deadline check of phase 2 looks at no request without one);
         4. admissions + chunked-prefill advancement — all host/dispatch
            work that overlaps the device's decode.
 
@@ -1238,6 +1251,8 @@ class ServingEngine:
             2 if (sch.prefill_chunk is not None or self.chunk_runs > 0) else 1
         )
         n = self._overlap_obs
+        rebuilds = self._rebuilds
+        rebuilt_rows = rebuilds["rows_written"] + rebuilds["rows_carried"]
         return {
             **({"replica": self.replica_id} if self.replica_id is not None else {}),
             **({"mesh": mesh} if mesh is not None else {}),
@@ -1261,6 +1276,13 @@ class ServingEngine:
             "decode_ahead": {
                 "dispatches": self.decode_steps, "ahead": self.decode_ahead_steps,
                 "share": self.decode_ahead_steps / self.decode_steps if self.decode_steps else 0.0,
+            },
+            # rebuilds of the decode chain's inputs: how many carried nothing
+            # over (the full build), and the rows written from Python against
+            # the rows taken from the build before
+            "decode_rebuild": {
+                **rebuilds,
+                "carried_share": rebuilds["rows_carried"] / rebuilt_rows if rebuilt_rows else 0.0,
             },
             "host_visits": self.host_visits,
             "tokens_per_host_visit": (
@@ -1941,9 +1963,11 @@ class ServingEngine:
                     self.decode_ahead_steps += 1
                     self._m_steps_ahead.inc()
             # steady: last step's device outputs were this step's inputs;
-            # ahead: dispatched before the host had last step's tokens
+            # ahead: dispatched before the host had last step's tokens;
+            # written: the rows a rebuild wrote from Python (the others it
+            # carried over from the build before)
             sp.set(rows=len(rec["running"]), bucket="{}x{}".format(*rec["bucket"]),
-                   steady=rec["steady"], ahead=int(ahead))
+                   steady=rec["steady"], ahead=int(ahead), written=rec.get("written", 0))
             if self.async_step:
                 self._inflight_decode = rec
         if not self.async_step:
@@ -1965,8 +1989,10 @@ class ServingEngine:
           (``_decode_state``; a speculative round leaves none);
         - the chain has steps left before a row of it writes its last
           position or a window lets blocks go at the harvest, and no row of
-          it is constrained (``ahead``, counted down from the chain's
-          rebuild: no walk over the rows here), nor runs ``decode_steps=N``;
+          it is constrained (``ahead``: the least of what each row allows,
+          reduced from per-row arrays at the chain's rebuild,
+          :meth:`_ahead_steps`, and counted down a step since: no walk over
+          the rows here), nor runs ``decode_steps=N``;
         - no prefill piece is in flight whose harvest would change the
           batch, and no row's deadline has passed;
         - the decode-ready rows and their buckets are the chain's (an
@@ -1974,7 +2000,9 @@ class ServingEngine:
 
         A row that ends at step *k* unseen (``eos_id``) makes step *k+1*
         one dead row-step (``dead_scan_row``) and drops the chain at the
-        harvest, so the step after is a turnover step."""
+        harvest, so the step after is a turnover step: its dispatch rebuilds
+        the chain's inputs, carrying over the rows that stand
+        (:meth:`_decode_inputs`)."""
         st = self._decode_state
         if (self._inflight_decode is None or st is None or st["ahead"] <= 0
                 or self._inflight_prefill):
@@ -1986,6 +2014,128 @@ class ServingEngine:
             return None
         batch = self._decode_batch(running)
         return batch if batch[1] == st["sig"] else None
+
+    @staticmethod
+    def _row_facts(r: Request) -> tuple:
+        """What a row of the decode inputs is built from, beside its first
+        live block: a row whose facts stand is carried over by a rebuild."""
+        return r.preemptions, len(r.generated), len(r.block_table), r.adapter_slot, r.state_slot
+
+    def _decode_row(self, host: dict, i: int, r: Request) -> None:
+        """Row ``i`` of the decode inputs, written from the request: the one
+        per-row form (the full build is this for every row), and what the row
+        was built from, which decides at the next rebuild whether it is
+        carried over."""
+        bt = r.block_table
+        wpos = r.prompt_len + len(r.generated) - 1         # slot this step writes
+        host["toks"][i] = r.generated[-1]
+        host["host_pos"][i] = wpos
+        host["tables"][i] = SINK_BLOCK
+        host["tables"][i, : len(bt)] = bt
+        host["keys"][i] = r.key
+        host["slots"][i] = r.adapter_slot
+        host["sslots"][i] = r.state_slot
+        # multi-step stopping: the last position a row may write before
+        # FINISH_LENGTH (see _build_decode_multi_paged)
+        host["stop"][i] = r.prompt_len + r.max_new_tokens - 2
+        live = -1
+        if self.scheduler.sliding_window is not None:
+            live = next((j for j, b in enumerate(bt) if b != SINK_BLOCK), -1)
+        host["facts"][i] = self._row_facts(r)
+        host["live"][i] = live
+        host["constrained"][i] = r.constraint is not None
+        host["deadline"][i] = np.inf if r.deadline_t is None else r.deadline_t
+
+    def _decode_inputs(self, running: list, sig: tuple, old: dict | None) -> dict:
+        """The host arrays of a decode step's inputs for ``running``, and what
+        each row was built from.  ``old``: the build before (``_decode_host``).
+
+        With nothing to carry from (``old`` is None: the first step, after
+        recovery; or it was built for another bucket) every row is
+        written from its request (:meth:`_decode_row`): the **full build**,
+        the reference.  Else the rows are mapped to the build before by rid
+        (FIFO order shifts every row behind a finished one, so by request and
+        not by index) and taken over in one indexed copy an array; written
+        from Python are only the rows that are new or whose recorded facts
+        differ from the request's: its ``preemptions`` epoch, how many tokens
+        it has generated (a row the last harvest passed over), the length of
+        its block table, its adapter and state slots, and under a sliding
+        window whether its first live block still is one (the harvest sinks
+        a prefix of the table).  ``toks``, ``host_pos`` and ``keys`` of a
+        carried row are what the last harvest fetched (:meth:`_decode_emit`
+        leaves them here).  Either way the arrays are, element for element,
+        the full build's; padding rows keep the defaults (the base adapter
+        slot, the sink state slot and block, ``stop`` -1: dead from step 0).
+        A built array is never written again: the device copy may alias it.
+        """
+        rids, Bb, nbb = sig
+        n = len(running)
+        key0 = np.asarray(running[0].key)
+        host = {
+            "bucket": (Bb, nbb), "n": n, "at": dict(zip(rids, range(n))),
+            "toks": np.zeros(Bb, dtype=np.int32),
+            "host_pos": np.zeros(Bb, dtype=np.int32),
+            "tables": np.full((Bb, nbb), SINK_BLOCK, dtype=np.int32),
+            "keys": np.zeros((Bb, *key0.shape), dtype=key0.dtype),
+            "slots": np.zeros(Bb, dtype=np.int32),
+            "sslots": np.zeros(Bb, dtype=np.int32),
+            "stop": np.full(Bb, -1, dtype=np.int32),
+            # what each row was built from: epoch, tokens generated, table
+            # length, adapter slot, state slot; its first live block under a
+            # window (-1: none); whether an automaton constrains it; its deadline
+            "facts": np.zeros((n, 5), dtype=np.int64),
+            "live": np.full(n, -1, dtype=np.int64),
+            "constrained": np.zeros(n, dtype=bool),
+            "deadline": np.full(n, np.inf),
+        }
+        host["full"] = old is None or old["bucket"] != (Bb, nbb)
+        if host["full"]:
+            write = range(n)
+        else:
+            at = old["at"]
+            src = np.fromiter((at.get(rid, -1) for rid in rids), dtype=np.intp, count=n)
+            found = src >= 0
+            src[~found] = 0
+            facts = np.array([self._row_facts(r) for r in running], dtype=np.int64)
+            keep = found & (facts == old["facts"][src]).all(axis=1)
+            if self.scheduler.sliding_window is not None:
+                for i in np.flatnonzero(keep).tolist():
+                    live = old["live"][src[i]]
+                    keep[i] = live >= 0 and running[i].block_table[live] != SINK_BLOCK
+            dst = np.flatnonzero(keep)
+            src = src[dst]
+            for name in ("toks", "host_pos", "tables", "keys", "slots", "sslots", "stop",
+                         "facts", "live", "constrained", "deadline"):
+                host[name][dst] = old[name][src]
+            write = np.flatnonzero(~keep).tolist()
+        with self._span("serve.decode_dispatch.rebuild.write"):
+            for i in write:
+                self._decode_row(host, i, running[i])
+        host["written"] = len(write)
+        return host
+
+    def _ahead_steps(self, host: dict) -> tuple[int, float | None]:
+        """How many steps of a chain built from ``host`` may be dispatched
+        ahead of the harvest before theirs (:meth:`_ahead_batch`), and the
+        first deadline among its rows: the least over the rows of what each
+        allows.  A row allows steps until the harvest that ends it by length
+        (``stop`` is its last write, ``host_pos`` this step's) or frees its
+        first live block under a window; none where the next dispatch needs a
+        value from the host (a constrained row, ``decode_steps=N``)."""
+        n = host["n"]
+        pos = host["host_pos"][:n].astype(np.int64)
+        ahead = min(1 << 30 if self.n_decode_steps == 1 else 0, int((host["stop"][:n] - pos).min()))
+        W = self.scheduler.sliding_window
+        if W is not None:
+            # harvest m of the chain sees pos = wpos + m + 1 and frees the
+            # first live block once (pos + 1 - W) // bs passes it
+            live = host["live"]
+            left = np.maximum(0, (live + 1) * self.pool.block_size + W - pos - 2)
+            ahead = min(ahead, int(np.where(live >= 0, left, ahead).min()))
+        if host["constrained"].any():
+            ahead = 0
+        deadline = float(host["deadline"].min())
+        return ahead, (None if deadline == np.inf else deadline)
 
     def _decode_dispatch(self, batch: tuple | None = None) -> dict:
         sch, pool = self.scheduler, self.pool
@@ -2009,51 +2159,27 @@ class ServingEngine:
             host_pos = st["host_pos"] + N
             stop_d = st.get("stop")
             ahead_left, deadline = st["ahead"] - 1, st["deadline"]
+            host = self._decode_host
         else:
-            toks = np.zeros(Bb, dtype=np.int32)
-            host_pos = np.zeros(Bb, dtype=np.int32)
-            tables = np.full((Bb, nbb), SINK_BLOCK, dtype=np.int32)
-            keys = np.zeros((Bb, *np.shape(running[0].key)),
-                            dtype=np.asarray(running[0].key).dtype)
-            slots = np.zeros(Bb, dtype=np.int32)           # padding rows: base slot
-            sslots = np.zeros(Bb, dtype=np.int32)          # padding rows: the sink state slot
-            # multi-step stopping: the last position a row may write before
-            # FINISH_LENGTH (see _build_decode_multi_paged); -1 parks padding rows
-            # dead from step 0
-            stop = np.full(Bb, -1, dtype=np.int32)
-            # how many steps of this chain may be dispatched ahead of the
-            # harvest before theirs (_ahead_batch): until that harvest ends a
-            # row by length or frees a row's window-expired blocks; none
-            # where the next dispatch needs a value from the host.  And the
-            # first deadline among the rows
-            ahead_left, deadline = (1 << 30 if N == 1 else 0), None
-            W = sch.sliding_window
-            for i, r in enumerate(running):
-                wpos = r.prompt_len + len(r.generated) - 1  # slot this step writes
-                ahead_left = min(ahead_left, r.max_new_tokens - len(r.generated) - 1)
-                if W is not None:
-                    # harvest m of the chain sees pos = wpos + m + 1 and frees
-                    # the first live block once (pos + 1 - W) // bs passes it
-                    live = next((j for j, b in enumerate(r.block_table) if b != SINK_BLOCK), None)
-                    if live is not None:
-                        ahead_left = min(ahead_left, max(0, (live + 1) * bs + W - wpos - 2))
-                if r.constraint is not None:
-                    ahead_left = 0
-                if r.deadline_t is not None:
-                    deadline = r.deadline_t if deadline is None else min(deadline, r.deadline_t)
-                toks[i] = r.generated[-1]
-                host_pos[i] = wpos
-                tables[i, : len(r.block_table)] = r.block_table
-                keys[i] = r.key
-                slots[i] = r.adapter_slot
-                sslots[i] = r.state_slot
-                stop[i] = r.prompt_len + r.max_new_tokens - 2
-            # commit once; the chained steps reuse these device buffers
-            toks_d, pos_d = jnp.asarray(toks), jnp.asarray(host_pos)
-            tables_d, keys_d = jnp.asarray(tables), jnp.asarray(keys)
-            slots_d = jnp.asarray(slots)
-            sslots_d = jnp.asarray(sslots) if self._hybrid else None
-            stop_d = jnp.asarray(stop) if N > 1 else None
+            # a rebuild: the rows the build before still holds are carried
+            # over by request, the others written (_decode_inputs).  The
+            # host arrays are this call's operands as they are (the call
+            # copies them in on its way to the device); what the chained
+            # steps reuse of them goes to the device after it, under the step
+            with self._span("serve.decode_dispatch.rebuild") as sp:
+                host = self._decode_inputs(running, sig, self._decode_host)
+                ahead_left, deadline = self._ahead_steps(host)
+                sp.set(full=int(host["full"]))
+            counts = self._rebuilds
+            counts["rebuilds"] += 1
+            counts["full"] += host["full"]
+            counts["rows_written"] += host["written"]
+            counts["rows_carried"] += host["n"] - host["written"]
+            toks_d, pos_d = host["toks"], host["host_pos"]
+            tables_d, keys_d, slots_d = host["tables"], host["keys"], host["slots"]
+            sslots_d = host["sslots"] if self._hybrid else None
+            stop_d = host["stop"] if N > 1 else None
+            host_pos = host["host_pos"]
         # constrained decoding: the per-row token masks are fresh host data
         # every dispatch (the automata advanced at the last harvest) — an
         # argument beside the chained device state, never part of it
@@ -2118,8 +2244,14 @@ class ServingEngine:
             if sums:
                 self._moe_rows = sums[0]
         # past the point of no return: the call consumed the donated arenas
-        self._fault_point(FP_SCATTER, tuple(r.rid for r in running))
+        self._fault_point(FP_SCATTER, sig[0])
         pool.set_arenas(arenas)
+        if not steady:
+            # the operands the chain's later steps take as they are: one
+            # transfer, while the device runs the step just dispatched
+            with self._span("serve.decode_dispatch.put"):
+                tables_d, slots_d, sslots_d, stop_d = jax.device_put((tables_d, slots_d, sslots_d, stop_d))
+        self._decode_host = host
         self._decode_state = {
             "sig": sig, "toks": nxt, "pos": new_pos, "tables": tables_d,
             "keys": new_keys, "slots": slots_d, "host_pos": host_pos,
@@ -2127,10 +2259,11 @@ class ServingEngine:
             **({"stop": stop_d} if N > 1 else {}),
             **({"sslots": sslots_d} if sslots_d is not None else {}),
         }
-        rec = {"kind": "decode", "running": running, "nxt": nxt,
+        rec = {"kind": "decode", "running": running, "rids": sig[0], "nxt": nxt,
                "new_keys": new_keys, "pos": host_pos, "bucket": [Bb, nbb],
                "pkind": kind, "compiled": compiled, "step": self.decode_steps,
-               "steady": steady,
+               "steady": steady, "host": host,
+               "written": 0 if steady else host["written"],
                "epochs": [r.preemptions for r in running],
                "t_disp": time.perf_counter(), "t_clock": sch.clock()}
         if N > 1:
@@ -2169,7 +2302,7 @@ class ServingEngine:
             return spec_decode_harvest(self, rec)
         multi = rec.get("multi")
         running = rec["running"]
-        self._fault_point(FP_HARVEST, tuple(r.rid for r in running))
+        self._fault_point(FP_HARVEST, rec["rids"])
         t0 = time.perf_counter()
         with self._span("serve.harvest.wait", kind="decode", rows=len(running)):
             # the host block: (Bb,) tokens, or the multi-step visit's (N, Bb)
@@ -2238,6 +2371,9 @@ class ServingEngine:
         pos = rec["pos"]
         emitted = 0
         invalidate = False
+        windowed = sch.sliding_window is not None
+        # Python ints once, not a numpy scalar a row
+        tok_of, pos_of = nxt.tolist(), pos.tolist()
         for i, r in enumerate(running):
             if r.state != "running" or (
                     epochs is not None and r.preemptions != epochs[i]):
@@ -2248,8 +2384,8 @@ class ServingEngine:
                 invalidate = True
                 continue
             r.key = new_keys[i]
-            r.pos = int(pos[i]) + 1
-            released = sch.expire_window_blocks(r)
+            r.pos = pos_of[i] + 1
+            released = sch.expire_window_blocks(r) if windowed else 0
             if released:
                 # every registered prefix of r starts at its (just-sunk)
                 # leading blocks — scrub before anyone can share them; the
@@ -2260,7 +2396,7 @@ class ServingEngine:
                     self._flight.record("window_expire", rid=r.rid,
                                         released=released)
             emitted += 1
-            self._emit_token(r, int(nxt[i]))
+            self._emit_token(r, tok_of[i])
             if r.state != "running":
                 invalidate = True                          # finished at this token
         self.tokens_generated += emitted
@@ -2271,9 +2407,16 @@ class ServingEngine:
             self._m_tokens.inc(emitted)
         if gp is not None:
             gp.commit_tokens(emitted)
+        host = rec["host"]
+        if host is self._decode_host:
+            # what a rebuild carries over of the rows this harvest went
+            # through (a row it passed over is a token short of its count,
+            # and is written anew): the fetched arrays themselves
+            host["toks"], host["keys"], host["host_pos"] = nxt, new_keys, pos + 1
+            host["facts"][:, 1] += 1
         if invalidate:
             # the chained decode inputs assumed an unchanged batch/tables;
-            # the next dispatch rebuilds from host state
+            # the next dispatch rebuilds them (_decode_inputs)
             self._decode_state = None
 
     def _decode_emit_multi(self, rec: dict, t0: float, stall: float, nxt, emit,
@@ -2388,6 +2531,13 @@ class ServingEngine:
             self._m_tokens.inc(emitted)
         if gp is not None:
             gp.commit_tokens(emitted)
+        host = rec["host"]
+        if host is self._decode_host:
+            # as _decode_emit: a row that served all N tokens stands at the
+            # last of them; one that went dead in-program is written anew
+            host["toks"], host["keys"], host["host_pos"] = nxt[N - 1], new_keys, pos + N
+            took = np.asarray(harvested, dtype=np.int64)
+            host["facts"][:, 1] = np.where(took == N, host["facts"][:, 1] + N, -1)
         if invalidate:
             self._decode_state = None
 
@@ -2874,6 +3024,7 @@ class ServingEngine:
             for prec in pending:
                 tr.end(prec["req"].rid, prec["span"], aborted=True)
         self._decode_state = None
+        self._decode_host = None
         self._spec_state = None
         self._release_retired()
 

@@ -39,6 +39,7 @@ import itertools
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from functools import cached_property
 from typing import Callable, Sequence
 
 import numpy as np
@@ -138,8 +139,9 @@ class Request:
     replay_until: int = 0
     replay_cause: str | None = None
 
-    @property
+    @cached_property
     def prompt_len(self) -> int:
+        """Read for every running row a step (``decode_ready``): computed once."""
         return int(self.prompt.shape[0])
 
     @property
@@ -211,6 +213,9 @@ class Scheduler:
         self.prefill_chunk = prefill_chunk
         self.queue: deque[Request] = deque()
         self.running: list[Request] = []     # admission order == FIFO batch order
+        # the queued/running requests that carry a deadline, by rid: all that
+        # deadline_expired looks at (most traffic carries none)
+        self._deadlined: dict[int, Request] = {}
         self._ids = itertools.count()
 
     #
@@ -319,6 +324,8 @@ class Scheduler:
                 f"wait queue full ({self.max_queue}); request rejected"
             )
         self._enqueue(req)
+        if req.deadline_t is not None:
+            self._deadlined[req.rid] = req
         return req
 
     def _enqueue(self, req: Request) -> None:
@@ -379,6 +386,8 @@ class Scheduler:
         req.state = "finished"
         req.finish_reason = reason
         req.finish_t = self.clock()
+        if req.deadline_t is not None:
+            self._deadlined.pop(req.rid, None)
         self._release(req)
 
     def _release(self, req: Request) -> None:
@@ -418,13 +427,18 @@ class Scheduler:
             self.queue.insert(at, req)
 
     def deadline_expired(self) -> list[Request]:
-        """Queued/running requests past their deadline.  The engine finishes
-        them (it must scrub its prefix index *before* blocks are freed)."""
+        """Queued/running requests past their deadline, running first, each in
+        its list's order.  The engine finishes them (it must scrub its prefix
+        index *before* blocks are freed).  Only the requests that carry a
+        deadline are looked at; the lists are walked only to order those
+        found late."""
+        if not self._deadlined:
+            return []
         now = self.clock()
-        return [
-            r for r in (*self.running, *self.queue)
-            if r.deadline_t is not None and now >= r.deadline_t
-        ]
+        late = {rid for rid, r in self._deadlined.items() if now >= r.deadline_t}
+        if not late:
+            return []
+        return [r for r in (*self.running, *self.queue) if r.rid in late]
 
     def expire_window_blocks(self, req: Request) -> int:
         """Releases blocks that slid fully out of the attention window:

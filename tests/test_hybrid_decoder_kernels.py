@@ -125,10 +125,11 @@ MISTRAL_LIKE = dict(name="tiny-window", n_layer=2, n_head=4, n_query_groups=2, n
                     intermediate_size=128, sliding_window=24, block_size=256)
 # sha256 of the decode program's jaxpr (kernel bodies included), computed on the commit before this kind came
 # (PR 40's tree, d1d06c0) and again here (and again at PR 44, which moved the choice of the attention call's form
-# behind the kernel's entry: the program with the kernel in it is what it was); regenerate with
-# `python tests/test_hybrid_decoder_kernels.py` after a deliberate change to the windowed path
+# behind the kernel's entry: the program with the kernel in it is what it was); PR 47 rebuilt the walk's chunk loop (every
+# form of it, the window's among them) and made its call an inner jit: the digest is that tree's.  Regenerate with
+# `PYTHONPATH=.:tests python tests/test_hybrid_decoder_kernels.py` after a deliberate change to the windowed path
 WINDOW_PROGRAMS = {
-    "decode_paged": "c61380311f52a7edac71102e584a9875d4bb290c634c82cd0edd9c8329a77457",
+    "decode_paged": "6025f42b9b46ad3abc0d7692fb28dd193d5060aef83852c4957852530de62d1e",
 }
 
 

@@ -399,6 +399,49 @@ def test_decode_walk_compiles_at_offline_batch_shapes(variant, store, window, tp
             assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+# the five serve cells that walk, from ``chipbench/configs`` and ``chipbench/traffic``: (query heads, KV heads, head size,
+# rows of the decode program, table width, pool blocks, attention layers held, window, packed_out); heads of 64 lane-packed
+WALK_CELLS = {
+    "mistral7b-serve-1chip.offline-batch": (32, 8, 128, 32, 224, 6144, 16, 4096, False),
+    "olmo-hybrid-serve-1chip.offline-longgen": (30, 30, 128, 32, 208, 5120, 4, None, False),
+    "lfm2moe-serve-1chip.offline-wide": (32, 8, 64, 256, 256, 47104, 3, None, False),
+    "phi4flash-serve-1chip.offline-reason": (40, 20, 64, 96, 552, 36864, 1, None, True),
+    "phi4flash-serve-1chip.offline-reason/ring": (40, 20, 64, 96, 552, 97 * 33, 8, 512, True),
+    "nemotron3super-serve-1chip.offline-rollouts": (32, 2, 128, 128, 496, 43008, 1, None, False),
+}
+
+
+@pytest.mark.parametrize("cell", WALK_CELLS)
+def test_the_walk_compiles_at_every_cells_shapes_as_the_call_the_readers_find(cell, tpu_sharding, monkeypatch):
+    """The rebuilt loop (static buffers a slot, a chunk's ``2 C`` starts
+    unrolled: copies of 8 to 120 KB, ``C`` 32 to 4) at each cell's decode
+    program's shapes: Mosaic takes its straight-line code and its VMEM, no arena
+    is copied, and the compiled call is what
+    ``chipbench/kernels/paged_attn_decode.py`` matches: the block table the
+    first operand (the positions, ``chain`` and the layer after it), one
+    four-dimensional result."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    nh, ng, hs, rows, width, pool, layers, window, packed_out = WALK_CELLS[cell]
+    groups = ng * hs // 128                                                     # the arena's rows a token
+    arena = ((pool, layers, groups, BS, 128), BF)
+    specs = [((rows, nh, hs), BF), arena, arena, ((rows, ng, hs), BF), ((rows, ng, hs), BF), ((rows, width), I32), ((rows,), I32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+    assert px.paged_kv_chunk_blocks(groups, BS, 128, 2) == {2: 32, 4: 32, 8: 16, 10: 12, 30: 4}[groups]
+    before = px.stats.get("paged_walk", 0)
+    fn = functools.partial(px.paged_attn_decode, layer=layers - 1, window=window, packed_out=packed_out)
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    assert px.stats["paged_walk"] == before + 1
+    assert lowered.as_text().count('kernel_name = "paged_attn_decode"') == 1
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        call = next(l for l in compiled.as_text().splitlines() if re.search(r"%paged_attn_decode(\.\d+)? = ", l))
+        assert re.search(rf"= bf16\[{rows},{groups},{nh // groups},128\]\S* custom-call\(%", call), call
+        shapes = {m.group(1): m.group(2) for m in re.finditer(r"%([\w.-]+) = (\w+\[[\d,]*\])", compiled.as_text())}
+        first = re.search(r"custom-call\(%([\w.-]+), %([\w.-]+), %([\w.-]+), %([\w.-]+), ", call).groups()
+        assert [shapes[name] for name in first] == [f"s32[{rows},{width}]", f"s32[{rows}]", f"s32[{rows + 1}]", "s32[1]"], call
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20        # no arena copy: the packed queries, the chain
+
+
 @pytest.mark.parametrize("store", [BF, I8], ids=["plain", "int8"])
 @pytest.mark.parametrize("bs", [8, 16])
 @pytest.mark.parametrize("hs", [128, 256])
@@ -990,7 +1033,10 @@ def test_the_hybrid_decoder_cells_programs_lower_to_their_kernels(kind, tpu_shar
         assert {int(m) for m in re.findall(r"tensor<1x(\d+)x10240xbf16>", text)} == {1, Tb}   # the cross half's MLPs: a row
     else:
         assert claimed("ssm_decode") == 3 and text.count('kernel_name = "ssm_decode_step"') >= 1
-        assert text.count('kernel_name = "paged_attn_decode"') == 4              # two rings, the global layer, the cross layer
+        # two rings, the global layer, the cross layer: four call sites, and one lowered body a form (the layer is
+        # an operand of ``_paged_decode_call``): the rings' (a window, their arenas) and the shared blocks'
+        assert claimed("paged_walk") == 4 and text.count('kernel_name = "paged_attn_decode"') == 2
+        assert len(re.findall(r"call @_paged_decode_call", text)) == 4
         assert text.count('kernel_name = "paged_token_write"') == 4              # K and V of the paged kind and of the ring
     if tpu_sharding is not None:
         hlo = lowered.compile().as_text()

@@ -302,10 +302,11 @@ def test_under_a_tp_mesh_the_prompt_keeps_the_einsum_form(interpreted):
     placed = dist.tp_fsdp(params, mesh)
     prompt = _prompt(cfg, 128, seed=15)
     eng = _engine(cfg, placed, mesh=mesh, block_size=16, num_blocks=24, prefill_buckets=(128,), block_buckets=(12,))
-    claims = dict(px.stats)
+    prompt_claims = lambda: {k: n for k, n in px.stats.items() if not k.startswith("paged_")}   # noqa: E731 - the decode
+    claims = prompt_claims()                                                   # steps' walks are counted a call site (PR 47)
     got = eng.submit(prompt, max_new_tokens=4).result()
     solo = np.asarray(gen.generate(placed, prompt[None], cfg, 4, cache_dtype=jnp.float32, mesh=mesh))[0, 128:]
-    assert px.stats == claims and eng.stats()["prefill_fresh_runs"] == 1
+    assert prompt_claims() == claims and eng.stats()["prefill_fresh_runs"] == 1
     assert np.array_equal(np.asarray(got.new_tokens), solo)
     assert np.array_equal(solo, _solo(cfg, params, prompt, 4))    # and the kernel's tokens, off the mesh
     eng.shutdown()

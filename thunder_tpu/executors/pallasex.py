@@ -1049,23 +1049,46 @@ def flash_cross_entropy(logits, target):
 # into the dense layout forward_with_cache wants.  These two kernels read and
 # write the arena *in place*:
 #
-# - ``paged_attn_decode``: grid (request,).  The arenas stay in HBM
-#   (``pl.ANY``) and the kernel fetches them itself: the block table and
-#   positions ride in as **scalar-prefetch** operands, and one grid step walks
-#   its request's table from the first live block (0, or where the sliding
-#   window begins) to the last in *chunks* of ``C`` consecutive entries.  A
-#   block's KV groups of one layer are contiguous in the arena, so one DMA a
-#   table entry lands all ``ng`` groups, ``(ng, bs, hs)``, in a
-#   double-buffered VMEM scratch; a chunk (``C * bs`` keys a group) is
-#   attended with one dot batched over the groups while the next chunk's
-#   copies are in flight (:func:`_arena_walk`; no gather primitive anywhere
-#   in the program).  ``C`` follows from the shapes and a fixed VMEM budget
-#   (:func:`paged_kv_chunk_blocks`).  Online softmax runs across chunks in
-#   loop-carried values; the positional keep-mask (strictly-older slots,
-#   optional sliding window) and the int8/fp8 dequant from the scale arenas
-#   are fused in-kernel; GQA is native (q reshaped to (B, ng, rep, hs)).  A
-#   request pays for its own context: neither the other rows nor the width of
-#   the table bucket enter its walk, so its output is the same bits alone, in
+# - ``paged_attn_decode``: grid (request,), walked in order.  The arenas stay
+#   in HBM (``pl.ANY``) and the kernel fetches them itself: the block table and
+#   positions ride in as **scalar-prefetch** operands (the table first, then
+#   the positions, ``chain`` and the layer: the trace readers find the call by
+#   the first), and one grid step walks its request's table from the first
+#   live block (0, or where the sliding window begins) to the last in *chunks*
+#   of ``C`` consecutive entries.  A block's KV groups of one layer are
+#   contiguous in the arena, so one DMA a table entry lands all ``ng`` groups,
+#   ``(ng, bs, hs)``, in a slot of VMEM scratch; a chunk (``C * bs`` keys a
+#   group) is attended with one dot batched over the groups while the next
+#   chunk's copies are in flight (:func:`_arena_walk`; no gather primitive
+#   anywhere in the program).  ``C`` follows from the shapes and a fixed VMEM
+#   budget (:func:`paged_kv_chunk_blocks`).
+#   The chunk loop is ``mla_paged_decode``'s (PR 47; what PR 35 measured is in
+#   the comment block above ``_MLA_CHUNK_KEYS``: a copy's start costs the core
+#   ~45 ns in a rolled loop and ~20 ns unrolled whatever it moves, and starts
+#   overlap products only in straight-line code whose target the products do
+#   not read).  The two slots are separate scratch arrays with static roles
+#   (K, V and, quantised, their scale rows: a tuple a slot); the loop takes two
+#   chunks a turn, attending one slot while the other's ``2 C`` copies are
+#   started in the same basic block (unrolled, nothing conditional around them),
+#   an odd last chunk once outside the loop; a slot's copies are awaited once an
+#   array, by the whole buffer's bytes.  What is started beside a row's last
+#   chunk is the first chunk of the next row that has one (``chain``, from
+#   ``pos`` and the window in :func:`_paged_decode_call`), so a request finds
+#   its first chunk in flight; grid step 0 starts the first such row's, the last
+#   fetches its own last chunk again and waits for it; the slot a row begins in
+#   rides in SMEM scratch.  Straight-line code is long, so the layer is an
+#   operand and a program's layers of one form share one traced body.  With 8
+#   and 16 KB slabs (two and four 128-lane groups a block) the walk was bound by
+#   the starts, not the bytes: 0.29 and 0.52 of its roofline before, where 40
+#   and 120 KB slabs read 0.83 and 0.88 (``PERF.md`` section 6, PR 47;
+#   ``tools/paged_tune.py`` times the kernel alone and splits a chunk's time).
+#   Online softmax runs across chunks in loop-carried values; the positional
+#   keep-mask (strictly-older slots, optional sliding window) and the int8/fp8
+#   dequant from the scale arenas are fused in-kernel; GQA is native (q
+#   reshaped to (B, ng, rep, hs)).  A request pays for its own context: which
+#   rows its neighbours are changes what is prefetched and when, never a
+#   chunk's boundaries nor the order of its sums, and the width of the table
+#   bucket does not enter its walk, so its output is the same bits alone, in
 #   any batch and under any bucket.  The *fresh* token's K/V (this step's
 #   projection, at the cache compute dtype — exactly what the dense path
 #   would have written before attending) joins as the final online-softmax
@@ -1088,7 +1111,9 @@ def flash_cross_entropy(logits, target):
 # (:func:`paged_decode_path`): the kernel where Pallas is on (the TPU, or the
 # interpreter a CPU opted into with ``THUNDER_TPU_PALLAS_INTERPRET=1``) and it
 # takes the arena's rows, else :func:`paged_attn_xla`, the same attention in
-# XLA.  The programs above them are the same either way.
+# XLA.  The programs above them are the same either way; ``stats`` counts a
+# call site by the form it took (``paged_walk``, ``paged_by_blocks``,
+# ``paged_xla``), at trace time.
 # ---------------------------------------------------------------------------
 
 
@@ -1182,8 +1207,9 @@ def paged_attn_xla(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *, layer,
 
 
 # VMEM that one chunk of the decode walk may hold: K and V of its table
-# entries, in both slots of the double buffer.  And the most keys a chunk
-# attends at once, which bounds the score tile whatever the byte budget says.
+# entries, in both slots.  And the most keys a chunk attends at once, which
+# bounds the score tile (and the length of a chunk's unrolled starts) whatever
+# the byte budget says.
 _PAGED_CHUNK_BYTES = 2 * 1024 * 1024
 _PAGED_CHUNK_KEYS = 512
 
@@ -1221,82 +1247,130 @@ def _scale_rows(scale, layer):
     slice, a reshape and a pad of one layer's scales (no gather) is what the
     kernel's row copies read instead."""
     nb, _, ng, bs = scale.shape
-    rows = scale[:, layer].reshape(nb, 1, ng * bs)
+    rows = jax.lax.dynamic_index_in_dim(scale, layer, axis=1, keepdims=False).reshape(nb, 1, ng * bs)
     return jnp.pad(rows, ((0, 0), (0, 0), (0, -(ng * bs) % 128)))
 
 
-def _arena_walk(tab_ref, i, p_i, q, arenas, bufs, sem, *, layer, bs, C, window,
-                cdtype, sm):
+def _paged_dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _paged_start_chunk(tab_ref, arenas, bufs, sems, r, at, hi, *, layer, C, unroll=True):
+    """Start the copies of row ``r``'s ``C`` table entries from ``at`` into one
+    slot's buffers, as straight-line code (a loop unrolled where the kernel is
+    lowered: traced once; the interpreter, which has nothing to overlap and
+    would compile every copy as a slice and an update, keeps it rolled): an
+    entry's ``(ng, bs, lanes)`` slab of K and of V and, quantised, its two
+    scale rows.  The tail of a row's last chunk (entries ``>= hi``) fetches
+    block ``hi - 1`` again: real arena bytes, masked as future ones."""
+    def one(t, _):
+        blk = tab_ref[r, jnp.minimum(at + t, hi - 1)]
+        for n, (src, dst) in enumerate(zip(arenas, bufs)):
+            src, dst = (src.at[blk, layer], dst.at[:, t]) if n < 2 else (src.at[blk], dst.at[t])
+            pltpu.make_async_copy(src, dst, sems.at[n]).start()
+    jax.lax.fori_loop(0, C, one, None, unroll=unroll)
+
+
+def _paged_wait_chunk(bufs, sems, *, C):
+    """Wait once an array for the bytes of the ``C`` copies started into it."""
+    for n, buf in enumerate(bufs):
+        whole = buf.at[:, pl.ds(0, C)] if n < 2 else buf.at[pl.ds(0, C)]
+        pltpu.make_async_copy(whole, whole, sems.at[n]).wait()
+
+
+def _arena_walk(tab_ref, pos_ref, chain_ref, i, q, arenas, bufs, dq, sem, par, *, layer, bs, C,
+                window, cdtype, sm, parts, unroll):
     """Request ``i``'s online softmax over its strictly-older arena slots:
     ``(m, l, acc)`` for queries ``q`` (ng, rows, hs), before the fresh term.
 
     ``arenas``: the HBM refs ``(k, v)``, or ``(k, v, k_rows, v_rows)`` with
-    the layer's :func:`_scale_rows`; ``bufs``: their VMEM chunk buffers,
-    ``(2, ng, C, bs, hs)`` for K/V and ``(2, C, 1, n)`` for scale rows, then
-    (quantized) the two ``(ng, C, bs, hs)`` buffers the dequantized chunk is
-    built in; ``sem``: DMA semaphores ``(2, len(arenas))``.
+    the layer's :func:`_scale_rows`; ``bufs``: a tuple a slot of their VMEM
+    chunk buffers, ``(ng, C, bs, hs)`` for K/V and ``(C, 1, n)`` for scale rows;
+    ``dq`` (quantised): the two ``(ng, C, bs, hs)`` buffers the dequantised
+    chunk is built in; ``sem``: DMA semaphores ``(2, len(arenas))``; ``par``:
+    the slot this row's first chunk is in, SMEM ``(1,)``; ``parts``: the
+    loop's product, chunk start and chunk wait (``tools/paged_tune.py`` takes
+    one out).
 
     The walk covers table entries ``[lo, hi)``: ``hi`` is the first block with
     no slot ``< pos``, ``lo`` the block of the oldest slot the window keeps.
     Chunk ``c`` is entries ``lo + c*C ...``, counted from the row's own first
-    live block.  The last chunk's tail (entries ``>= hi``) re-fetches block
-    ``hi - 1``: real arena bytes, so the value product never meets what an
-    unwritten buffer holds, and their slots are masked as future ones.  Every
-    chunk that runs starts at a live block and so keeps at least one slot:
-    ``exp`` never sees an all-masked row."""
+    live block; every chunk that runs starts at a live block and so keeps at
+    least one slot: ``exp`` never sees an all-masked row.
+
+    The loop is ``mla_paged_decode``'s (the comment block above
+    ``_MLA_CHUNK_KEYS`` has what was measured): the slots are arrays with static
+    roles and the loop takes two chunks a turn, one slot attended while the
+    other's ``2 C`` copies (``4 C`` quantised) are started beside the products in
+    the same straight-line code, nothing conditional around them, and awaited
+    once an array.  What is started beside a row's last chunk is the first
+    chunk of the next row that has one (``chain_ref[i]``; ``B``: none, and the
+    row fetches its own last chunk again and waits for it), so a row finds its
+    first chunk in flight, in the slot ``par`` names; grid step 0 starts the
+    first such row's (``chain_ref[B]``).  The grid is walked in order."""
+    dot, start_chunk, wait_chunk = parts
     quantized = len(arenas) == 4
     ng, rows, hs = q.shape
-    first = 0 if window is None else jnp.maximum(p_i - (window - 1), 0)
-    lo = first // bs
-    hi = jnp.where(first < p_i, (p_i + bs - 1) // bs, lo)
-    n_chunks = (hi - lo + C - 1) // C
+    B = pl.num_programs(0)
+    p_i = pos_ref[i]
 
-    def copies(c, slot, act):
-        # start, or wait for, the copies of chunk ``c``'s table entries
-        def one(t, _):
-            blk = tab_ref[i, jnp.minimum(lo + c * C + t, hi - 1)]
-            for n, (src, dst) in enumerate(zip(arenas, bufs)):
-                src, dst = ((src.at[blk, layer], dst.at[slot, :, t]) if n < 2
-                            else (src.at[blk], dst.at[slot, t]))
-                getattr(pltpu.make_async_copy(src, dst, sem.at[slot, n]), act)()
-        jax.lax.fori_loop(0, C, one, None)
+    def span(r):
+        p = pos_ref[r]
+        first = 0 if window is None else jnp.maximum(p - (window - 1), 0)
+        lo = first // bs
+        return lo, jnp.where(first < p, (p + bs - 1) // bs, lo)
 
-    def dequant(slot):
+    def start(r, c, span_r, k):
+        start_chunk(tab_ref, arenas, bufs[k], sem.at[k], r, span_r[0] + c * C, span_r[1], layer=layer, C=C,
+                    unroll=unroll)
+
+    def wait(k):
+        wait_chunk(bufs[0], sem.at[k], C=C)                # the slots' arrays are the same shapes
+
+    lo, hi = span(i)
+    n = (hi - lo + C - 1) // C
+    nxt = chain_ref[i]
+    last = nxt == B
+    r_n = jnp.minimum(nxt, B - 1)
+    lo_n, hi_n = span(r_n)
+
+    @pl.when(i == 0)
+    def _first():
+        par[0] = 0
+        first = chain_ref[B]
+
+        @pl.when(first < B)
+        def _():
+            start(first, 0, span(first), 0)
+
+    def ahead(c, k):
+        # into slot k: this row's chunk c if it has one, else the first chunk of
+        # the next row that has any; the last such row of the grid fetches its own
+        # last chunk again (waited for below, never attended)
+        own = jnp.logical_or(c < n, last)
+        chunk = jnp.where(c < n, c, jnp.where(last, n - 1, 0))
+        start(jnp.where(own, i, r_n), chunk, (jnp.where(own, lo, lo_n), jnp.where(own, hi, hi_n)), k)
+
+    def dequant(k):
         # per table entry and group: (bs, hs) stored values times their
         # (bs, 1) scale column, rounded to the cache compute dtype exactly as
         # ``quant.gather_dense_q`` does
         def one(t, _):
-            for x_buf, s_buf, d_buf in zip(bufs[:2], bufs[2:4], bufs[4:]):
+            for x_buf, s_buf, d_buf in zip(bufs[k][:2], bufs[k][2:], dq):
                 for g in range(ng):
-                    col = _scale_column(s_buf[slot, t], bs, at=g * bs)
-                    d_buf[g, t] = (x_buf[slot, g, t].astype(jnp.float32) * col
-                                   ).astype(cdtype)
+                    col = _scale_column(s_buf[t], bs, at=g * bs)
+                    d_buf[g, t] = (x_buf[g, t].astype(jnp.float32) * col).astype(cdtype)
         jax.lax.fori_loop(0, C, one, None)
 
     def tile(x):                                           # (ng, C, bs, hs)
-        return x.reshape(ng, C * bs, hs).astype(q.dtype)
+        return x[...].reshape(ng, C * bs, hs).astype(q.dtype)
 
-    @pl.when(n_chunks > 0)
-    def _first():
-        copies(0, 0, "start")
-
-    def chunk(c, carry):
+    def attend(c, k, carry):
         m_prev, l_prev, acc = carry
-        slot = c % 2
-
-        @pl.when(c + 1 < n_chunks)
-        def _next():
-            copies(c + 1, 1 - slot, "start")
-
-        copies(c, slot, "wait")
         if quantized:
-            dequant(slot)
-            k, v = tile(bufs[4][...]), tile(bufs[5][...])
-        else:
-            k, v = tile(bufs[0][slot]), tile(bufs[1][slot])
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-        ) / sm                                             # (ng, rows, C*bs)
+            dequant(k)
+        keys, vals = (tile(x) for x in (dq if quantized else bufs[k][:2]))
+        s = dot(q, keys, (((2,), (2,)), ((0,), (0,)))) / sm    # (ng, rows, C*bs)
         posn = (lo + c * C) * bs + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, C * bs), 2)
         keep = posn < p_i                                  # strictly older: the
@@ -1307,24 +1381,53 @@ def _arena_walk(tab_ref, i, p_i, q, arenas, bufs, sem, *, layer, bs, C, window,
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=2, keepdims=True)
-        acc = acc * corr + jax.lax.dot_general(
-            p.astype(q.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+        acc = acc * corr + dot(p.astype(q.dtype), vals, (((2,), (1,)), ((0,), (0,))))
         return m_new, l_new, acc
 
-    return jax.lax.fori_loop(0, n_chunks, chunk, (
-        jnp.full((ng, rows, 1), _MASK_VALUE, jnp.float32),
-        jnp.zeros((ng, rows, 1), jnp.float32),
-        jnp.zeros((ng, rows, hs), jnp.float32)))
+    def walk(a, b):
+        def pair(j, carry):
+            c = 2 * j
+            ahead(c + 1, b)
+            wait(a)
+            carry = attend(c, a, carry)
+            ahead(c + 2, a)
+            wait(b)
+            return attend(c + 1, b, carry)
+
+        def odd(carry):
+            ahead(n, b)
+            wait(a)
+            return attend(n - 1, a, carry)
+
+        carry = jax.lax.fori_loop(0, n // 2, pair, (
+            jnp.full((ng, rows, 1), _MASK_VALUE, jnp.float32),
+            jnp.zeros((ng, rows, 1), jnp.float32),
+            jnp.zeros((ng, rows, hs), jnp.float32)))
+        return jax.lax.cond(n % 2 == 1, odd, lambda carry: carry, carry)
+
+    base = par[0]
+    carry = jax.lax.cond(base == 0, lambda: walk(0, 1), lambda: walk(1, 0))
+
+    @pl.when(n > 0)
+    def _handed_over():
+        spare = (base + n) % 2
+        par[0] = spare
+
+        @pl.when(last)
+        def _():
+            wait(spare)
+    return carry
 
 
-def _paged_kernel(tab_ref, pos_ref, q_ref, *rest, n_arenas, sm, **walk):
+def _paged_kernel(tab_ref, pos_ref, chain_ref, layer_ref, q_ref, *rest, n_arenas, **walk):
     arenas, (fk_ref, fv_ref, o_ref), scratch = (
         rest[:n_arenas], rest[n_arenas:n_arenas + 3], rest[n_arenas + 3:])
-    i = pl.program_id(0)
+    sm = walk["sm"]
     q = q_ref[0]                                           # (ng, rep, hs)
     m_prev, l_prev, acc = _arena_walk(
-        tab_ref, i, pos_ref[i], q, arenas, scratch[:-1], scratch[-1], sm=sm, **walk)
+        tab_ref, pos_ref, chain_ref, pl.program_id(0), q, arenas,
+        (scratch[:n_arenas], scratch[n_arenas:2 * n_arenas]), scratch[2 * n_arenas:-2], *scratch[-2:],
+        layer=layer_ref[0], **walk)
     # the fresh token is one key: its score and value terms are written as
     # float32 multiply-and-sum, because Mosaic refuses the (rep, hs)·(1, hs)
     # dot_general for rep > 1.  The operands are rounded to q.dtype first, as
@@ -1415,6 +1518,7 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     assert rep * ng * P == nh, (nh, ng, P)
     path = paged_decode_path(lanes, window)
     assert not packed_out or (P > 1 and path != "by_blocks"), "packed_out: a lane-packed arena, walked"
+    stats["paged_" + path] = stats.get("paged_" + path, 0) + 1        # a call site, at trace time
     if path == "xla":
         out = paged_attn_xla(q[:, :, None], k_arena, v_arena, fresh_k[:, :, None], fresh_v[:, :, None], tables, pos,
                              layer=layer, k_scale=k_scale, v_scale=v_scale, window=window, packed_out=packed_out)
@@ -1422,56 +1526,68 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     if path == "by_blocks":
         return _decode_by_blocks(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos,
                                  layer=layer, k_scale=k_scale, v_scale=v_scale)
-    quantized = k_scale is not None
-    cdtype = fresh_k.dtype
     C = paged_kv_chunk_blocks(ng, bs, lanes, k_arena.dtype.itemsize)
-    sm = float(np.sqrt(hs))
     q = q.reshape(B, ng * P, rep, hs)
-    if P > 1:       # the kernel below sees heads of ``lanes``, ``P * rep`` query rows a group
+    if P > 1:       # the kernel sees heads of ``lanes``, ``P * rep`` query rows a group
         q = _lane_packed_queries(q, P)
         fresh_k, fresh_v = (x.reshape(B, ng, lanes) for x in (fresh_k, fresh_v))
-        rep, hs = P * rep, lanes
-
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    # (B, ng, 1, hs): a (1, 1, hs) block of the rank-3 array would tile
-    # (ng, hs) by (1, hs), which Mosaic refuses
-    fresh_spec = pl.BlockSpec((1, ng, 1, hs), lambda i, tab, p: (i, 0, 0, 0))
-    q_spec = pl.BlockSpec((1, ng, rep, hs), lambda i, tab, p: (i, 0, 0, 0))
-    arenas = [k_arena, v_arena]
-    scratch = [pltpu.VMEM((2, ng, C, bs, hs), k_arena.dtype)] * 2
-    if quantized:
-        arenas += [_scale_rows(k_scale, layer), _scale_rows(v_scale, layer)]
-        scratch += [pltpu.VMEM((2, C) + arenas[2].shape[1:], jnp.float32)] * 2
-        scratch += [pltpu.VMEM((ng, C, bs, hs), cdtype)] * 2
-    scratch.append(pltpu.SemaphoreType.DMA((2, len(arenas))))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B,),
-        in_specs=[q_spec] + [hbm] * len(arenas) + [fresh_spec, fresh_spec],
-        out_specs=q_spec,
-        scratch_shapes=scratch,
-    )
-    kwargs = {}
-    if not _interpret():
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel",))
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_kernel, n_arenas=len(arenas), layer=layer, bs=bs, C=C,
-            window=window, cdtype=cdtype, sm=sm,
-        ),
-        name="paged_attn_decode" + ("_quant" if quantized else ""),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, ng, rep, hs), q.dtype),
-        interpret=_interpret(),
-        **kwargs,
-    )(tables, pos, q, *arenas, fresh_k[:, :, None, :], fresh_v[:, :, None, :])
+    out = _paged_decode_call(
+        tables, pos, jnp.full((1,), layer, jnp.int32), q,
+        (k_arena, v_arena) if k_scale is None else (k_arena, v_arena, k_scale, v_scale),
+        fresh_k[:, :, None, :], fresh_v[:, :, None, :], C=C, window=window, sm=float(np.sqrt(hs)),
+        interpret=_interpret(), parts=(_paged_dot, _paged_start_chunk, _paged_wait_chunk))
     if packed_out:
         return out
     if P > 1:
         out = _lane_packed_outputs(out, P)
     return out.reshape(B, nh, -1)
+
+
+@functools.partial(jax.jit, static_argnames=("C", "window", "sm", "interpret", "parts"))
+def _paged_decode_call(tables, pos, layer, q, arenas, fresh_k, fresh_v, *, C, window, sm, interpret, parts):
+    """The walk's call, one traced and lowered body for every layer of a
+    program that shares its static arguments (the layer is an operand, as in
+    ``_mla_decode_call``): its straight-line copies make it some thousand
+    operations long.  ``arenas``: ``(k, v)`` or, quantised, ``(k, v, k_scale,
+    v_scale)``; ``q (B, ng, rep, hs)`` and ``fresh_* (B, ng, 1, hs)`` as the
+    kernel sees them (heads of the arena's rows).  ``chain`` (after ``tables``
+    and ``pos``, which the trace readers find the call by): ``chain[i]`` is the
+    next row after ``i`` with a cached token the window keeps (``B``: none),
+    ``chain[B]`` the first such row."""
+    B, ng, rep, hs = q.shape
+    bs = arenas[0].shape[3]
+    quantized = len(arenas) == 4
+    first = 0 if window is None else jnp.maximum(pos - (window - 1), 0)
+    after = jax.lax.cummin(jnp.where(first < pos, jnp.arange(B, dtype=jnp.int32), B), reverse=True)
+    chain = jnp.concatenate([after[1:], jnp.full((1,), B, jnp.int32), after[:1]])
+    if quantized:
+        arenas = (*arenas[:2], _scale_rows(arenas[2], layer[0]), _scale_rows(arenas[3], layer[0]))
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # (B, ng, 1, hs): a (1, 1, hs) block of the rank-3 array would tile
+    # (ng, hs) by (1, hs), which Mosaic refuses
+    fresh_spec = pl.BlockSpec((1, ng, 1, hs), lambda i, *_: (i, 0, 0, 0))
+    q_spec = pl.BlockSpec((1, ng, rep, hs), lambda i, *_: (i, 0, 0, 0))
+    slot = [pltpu.VMEM((ng, C, bs, hs), arenas[0].dtype)] * 2
+    if quantized:
+        slot += [pltpu.VMEM((C,) + arenas[2].shape[1:], jnp.float32)] * 2
+    scratch = slot * 2 + ([pltpu.VMEM((ng, C, bs, hs), fresh_k.dtype)] * 2 if quantized else [])
+    scratch += [pltpu.SemaphoreType.DMA((2, len(arenas))), pltpu.SMEM((1,), jnp.int32)]
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, n_arenas=len(arenas), bs=bs, C=C, window=window,
+                          cdtype=fresh_k.dtype, sm=sm, parts=parts, unroll=not interpret),
+        name="paged_attn_decode" + ("_quant" if quantized else ""),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B,),
+            in_specs=[q_spec] + [hbm] * len(arenas) + [fresh_spec, fresh_spec],
+            out_specs=q_spec, scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((B, ng, rep, hs), q.dtype),
+        interpret=interpret,
+        **kwargs,
+    )(tables, pos, chain, layer, q, *arenas, fresh_k, fresh_v)
 
 
 def _decode_by_blocks(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, **kw):

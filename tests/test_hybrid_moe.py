@@ -844,7 +844,7 @@ def test_an_expert_share_is_refused_and_linear_layers_alone_are_not():
                           linear_num_value_heads=2, linear_key_head_dim=8, linear_value_head_dim=16)
     assert linear.training_only is None
     require_servable(linear)
-    # this model's gated attention and zero-centred norms have no serving form either
+    # this model's zero-centred norms have no serving form (its gated attention has one since the window kind)
     gated = _tiny_cfg_and_params()[0]
     assert gated.attn_output_gate and gated.qk_norm and gated.norm_zero_centered
 

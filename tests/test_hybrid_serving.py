@@ -77,7 +77,7 @@ def test_linear_attention_is_served_and_an_expert_share_is_not(model):
 @pytest.mark.parametrize("knob", ["attn_output_gate", "qk_norm", "norm_zero_centered"])
 def test_attention_forms_the_server_lacks_are_refused_by_name(knob):
     cfg = llama.Config(name="gated", n_layer=1, n_head=2, n_embd=32, **{knob: True})
-    if knob == "qk_norm":       # served since the per-head norm went into generate._project_qkv
+    if knob in ("qk_norm", "attn_output_gate"):     # served since the per-head norm, then the gate, went into generate._project_qkv
         assert cfg.training_only is None
         return G.require_servable(cfg)
     with pytest.raises(NotImplementedError, match=knob):

@@ -239,7 +239,8 @@ def test_the_manifest_lists_every_split_in_its_cell():
         for sp in splits:
             m = per_layer[f"{q}_share_of_busy.{sp}"]
             # a later cell may join a split by its `workloads` list (PR 45: the manifest holds 128 entries, its most)
-            joined = {"nemotron3super-serve-1chip.offline-rollouts"} if sp == "flashserve" else set()
+            joined = ({"nemotron3super-serve-1chip.offline-rollouts", "trinity-mini-serve-1chip.offline-docqa"}
+                      if sp == "flashserve" else set())            # PR 48 joined the same way, and added no entry
             assert m["workloads"][0] == cells[sp] and set(m["workloads"][1:]) <= joined
             assert m["unit"] == "fraction" and m["source"] == "device_trace"
             assert m["moves"] == ("train_tok_per_s_per_chip" if sp in ("train", "hyb") else "serve_out_tok_per_s")

@@ -399,7 +399,7 @@ def test_decode_walk_compiles_at_offline_batch_shapes(variant, store, window, tp
             assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
-# the five serve cells that walk, from ``chipbench/configs`` and ``chipbench/traffic``: (query heads, KV heads, head size,
+# the six serve cells that walk, from ``chipbench/configs`` and ``chipbench/traffic``: (query heads, KV heads, head size,
 # rows of the decode program, table width, pool blocks, attention layers held, window, packed_out); heads of 64 lane-packed
 WALK_CELLS = {
     "mistral7b-serve-1chip.offline-batch": (32, 8, 128, 32, 224, 6144, 16, 4096, False),
@@ -408,6 +408,8 @@ WALK_CELLS = {
     "phi4flash-serve-1chip.offline-reason": (40, 20, 64, 96, 552, 36864, 1, None, True),
     "phi4flash-serve-1chip.offline-reason/ring": (40, 20, 64, 96, 552, 97 * 33, 8, 512, True),
     "nemotron3super-serve-1chip.offline-rollouts": (32, 2, 128, 128, 496, 43008, 1, None, False),
+    "trinity-mini-serve-1chip.offline-docqa": (32, 4, 128, 20, 720, 10240, 8, None, False),
+    "trinity-mini-serve-1chip.offline-docqa/ring": (32, 4, 128, 20, 720, 21 * 129, 24, 2048, False),
 }
 
 
@@ -837,6 +839,72 @@ def test_the_narrow_head_cells_programs_lower_to_their_kernels(kind, tpu_shardin
     else:
         assert text.count('kernel_name = "paged_attn_decode"') == len(cfg.kv_layers)
         assert text.count('kernel_name = "paged_token_write"') == 2
+    if tpu_sharding is not None:
+        hlo = lowered.compile().as_text()
+        names = ("_flash_fwd",) if kind == "prefill_fresh" else ("paged_attn_decode", "paged_token_write")
+        for name in (*names, "moe_grouped_mm"):
+            assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
+
+
+def _window_global_engine():
+    """The Trinity-Mini cell's engine at its published widths, the first period of
+    four layers (three window layers and a global one: both dense layers and two
+    expert layers of 16 held), over weights that are shapes alone."""
+    import thunder_tpu as tt
+    from chipbench import common
+    from thunder_tpu.models import llama
+
+    _, config, mix = common.open_cell("trinity-mini-serve-1chip.offline-docqa")
+    arch = common.load_module("models", config["arch"])
+    hf = {**config, "num_hidden_layers": 4}
+    cfg = llama.Config(**arch.program_config(hf))
+    params = jax.eval_shape(functools.partial(arch.make_params, hf), common.seed_words(1))
+    kw = {**config["engine"], **mix["engine"], "num_blocks": 1500, "max_batch": 2, "batch_buckets": [2]}
+    return cfg, params, tt.serve(None, params, cfg, **kw)
+
+
+@pytest.mark.parametrize("kind", ["prefill_fresh", "decode_paged"])
+def test_the_window_global_cells_programs_lower_to_their_kernels(kind, tpu_sharding, monkeypatch):
+    """A whole prompt of 3,840 tokens attends through ``_flash_fwd`` in every layer,
+    banded by the window of 2,048 in the three window layers and causal alone in
+    the global one, and sorts its rows through ``moe_grouped_mm``; a decode step
+    walks the slot's ring (129 entries of the table's 720) through
+    ``paged_attn_decode`` in the window layers and the request's own blocks in the
+    global one, and lands its K and V through one ``paged_token_write`` an arena:
+    two for the rings, two for the blocks; no arena is gathered."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    monkeypatch.setattr(px, "_pallas_available", lambda: True)
+    cfg, params, eng = _window_global_engine()
+    st = eng.stats()
+    assert st["attn"]["path"] == "walk" and st["attn"]["lane_pack"] == 1
+    assert eng.pool.k_arena.shape == (1500, 1, 4, 16, 128) and eng.pool.state.shapes["k_ring"] == (3 * 129, 3, 4, 16, 128)
+    assert sorted(eng.pool.arenas) == ["k", "k_ring", "v", "v_ring"]
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=tpu_sharding)  # noqa: E731
+    one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
+    before = dict(px.stats)
+    if kind == "prefill_fresh":
+        Tb = 3840
+        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
+        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)), one((1,)))
+    else:
+        prog = eng._build_decode_paged(2, 720)
+        args = (weights, one((2,)), one((2,)), one((2, 720)), arenas, one((2, 2), jnp.uint32), {}, one((2,)),
+                one((4,), F32), one((2,)))          # the expert share's running sums, then the state slots
+    lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
+    assert claimed("grouped_mm") >= 3 and 'kernel_name = "moe_grouped_mm"' in text
+    assert "paged_attn_verify" not in text
+    assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)      # nothing of an arena's five dims
+    if kind == "prefill_fresh":
+        # four calls claimed, two kernels in the text: the banded one the window layers share, the causal one
+        assert claimed("direct") == 4 and text.count('kernel_name = "_flash_fwd"') == 2
+        assert {int(m) for m in re.findall(rf"tensor<1x(\d+)x{cfg.padded_vocab_size}xf32>", text)} == {1}
+    else:
+        # the lowered text holds a function once however many layers call it: a walk a kind, four calls claimed
+        assert text.count('kernel_name = "paged_attn_decode"') == 2 and claimed("paged_walk") == 4
+        assert 1 <= text.count('kernel_name = "paged_token_write"') <= 4
     if tpu_sharding is not None:
         hlo = lowered.compile().as_text()
         names = ("_flash_fwd",) if kind == "prefill_fresh" else ("paged_attn_decode", "paged_token_write")

@@ -419,14 +419,18 @@ def _lora_delta(x, a, b, scaling):
 
 
 def _project_qkv(ap, x, cos_t, sin_t, cfg: Config, *, lin=None, lora=None,
-                 lora_scaling=1.0, delta_fn=None):
+                 lora_scaling=1.0, delta_fn=None, rope=True, gate=None):
     """QKV projections + partial rotary for new tokens: x (B, T, C) →
     q (B, nh, T, hs), k/v (B, ng, T, hs) — K/V stay at the grouped head
     count.  Shared by KV-cache decode and sequence-parallel training.
     ``lora``: optional ``{target: (a, b)}`` per-request factors for this
     layer (see :func:`_lora_delta`); ``delta_fn`` swaps the delta
     implementation (the serving kernel path passes its fused epilogue —
-    same ``(x, a, b, scaling)`` contract, bit-identical math)."""
+    same ``(x, a, b, scaling)`` contract, bit-identical math).  ``rope``
+    False: this layer rotates nothing (``Config.rotates``).  ``gate``: a list
+    that takes the output gate's logits ``(B, T, nh * hs)`` of a model with
+    ``attn_output_gate`` (``wq`` projects to q and gate a head; the caller
+    multiplies the heads' outputs by their sigmoid before ``wo``)."""
     if lin is None:
         lin = _linear
     if delta_fn is None:
@@ -444,13 +448,18 @@ def _project_qkv(ap, x, cos_t, sin_t, cfg: Config, *, lin=None, lora=None,
         q, k = proj("wq", "bq"), proj("wk", "bk")
         if cfg.qk_norm_whole:   # over the whole projection, before the split into heads
             q, k = _rms(q, ap["q_norm"], cfg.norm_eps), _rms(k, ap["k_norm"], cfg.norm_eps)
+        if cfg.attn_output_gate:
+            assert gate is not None, "attn_output_gate: the caller takes the gate (gate=[])"
+            q = q.reshape(B, T, nh, 2 * hs)
+            gate.append(q[..., hs:].reshape(B, T, nh * hs))
+            q = q[..., :hs]
         q, k = q.reshape(B, T, nh, hs), k.reshape(B, T, ng, hs)
         if cfg.qk_norm:         # a head at a time, one weight of ``hs`` a layer each, before the rotation
             q, k = _rms(q, ap["q_norm"], cfg.norm_eps), _rms(k, ap["k_norm"], cfg.norm_eps)
         q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
         v = proj("wv", "bv").reshape(B, T, ng, hs).transpose(0, 2, 1, 3)
     n_elem = cfg.rope_n_elem
-    if n_elem > 0:
+    if n_elem > 0 and rope:
         with scope("rope"):
             q_r = _rope(q[..., :n_elem], cos_t, sin_t)
             k_r = _rope(k[..., :n_elem], cos_t, sin_t)
@@ -477,7 +486,13 @@ def cache_shape(cfg: Config, B: int, T_max: int) -> tuple[int, int, int, int, in
     """Dense KV-cache geometry ``(L, B, n_query_groups, Tc, hs)`` — the one
     layout every cache consumer (``init_cache``, the serving KV pool's
     gathered views) agrees on.  ``L`` counts the layers that keep K and V
-    (``cfg.kv_layers``): a linear_attention layer has none."""
+    (``cfg.kv_layers``): a linear_attention layer has none.  A
+    sliding_attention layer's slots are positions, as a full_attention layer's
+    beside it are (one stacked array): its window lives in the mask
+    (``_attn_with_cache(layer_window=)``), and the server, which keeps such a
+    layer a ring a request, moves a prompt's last blocks there
+    (``engine._blocks_back``); what either holds of the last ``layer_window``
+    tokens is the same numbers."""
     if cfg.latent:   # one row a token a layer, read by every head: ``latent`` in the cache's dict
         return (cfg.n_layer, B, 1, T_max, cfg.latent_width)
     return (len(cfg.kv_layers), B, cfg.n_query_groups, cache_len(cfg, T_max), cfg.head_size)
@@ -654,8 +669,17 @@ def attend_dense(q, kk, vv, keep):
     return jnp.einsum("bhqk,bhkd->bhqd", w, vv.astype(q.dtype))
 
 
+def gated_out(y, gate):
+    """The heads' outputs ``y (B, T, nh * hs)`` times the sigmoid of their gate's
+    logits (``_project_qkv(gate=)``), before ``wo``; ``y`` where there is none."""
+    if not gate:
+        return y
+    with scope("gate"):
+        return y * jax.nn.sigmoid(gate[0].astype(jnp.float32)).astype(y.dtype)
+
+
 def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized=False,
-                     lora=None, lora_scaling=1.0, sharded=False):
+                     lora=None, lora_scaling=1.0, sharded=False, layer_window=None, rope=True):
     """x: (B, T, C) new tokens at global positions [pos, pos+T).  Writes their
     K/V into the per-layer cache (ck/cv: (B, ng, Tc, hs)) and attends against
     every slot the model may see.
@@ -672,15 +696,21 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
     flash kernel where that takes the shapes (grouped K/V as they are), else
     through the shared tail over ``(T, T)``.  ``sharded`` (the operands live on
     a mesh, where a bare ``pallas_call`` would be replicated) keeps the tail.
+
+    ``layer_window``: a sliding_attention layer's (``cfg.layer_window``): the
+    plain layout whatever the cache's length, the window in the mask (the
+    server moves such a layer's last blocks to the request's ring,
+    ``engine._blocks_back``).  ``rope`` False: the layer rotates nothing.
     """
     B, T, C = x.shape
     hs, nh, ng = cfg.head_size, cfg.n_head, cfg.n_query_groups
     lin = partial(_linear, quantized=quantized)
+    gate = []
     q, k, v = _project_qkv(ap, x, cos_t, sin_t, cfg, lin=lin, lora=lora,
-                           lora_scaling=lora_scaling)
+                           lora_scaling=lora_scaling, rope=rope, gate=gate)
     Tc = ck.shape[2]
-    W = cfg.sliding_window
-    ring = W is not None and Tc == W
+    W = cfg.sliding_window if layer_window is None else layer_window
+    ring = layer_window is None and W is not None and Tc == W
     vec = _is_vec_pos(pos)
     assert not (ring and vec), "per-row positions are not supported with a ring cache"
     fresh = isinstance(pos, int) and pos == 0 and T > 1
@@ -732,15 +762,15 @@ def _attn_with_cache(ap, x, cos_t, sin_t, ck, cv, pos, cfg: Config, *, quantized
             keep = (gp >= 0)[None, None, None, :]
 
     flash = None
-    with scope("attn"):
+    with scope("attn" if layer_window is None else "swa"):
         if fresh and not sharded:
             from thunder_tpu.executors import pallasex
 
             flash = pallasex.flash_sdpa(q, k, v, None, True, 1.0 / math.sqrt(hs),
                                         W if W is not None and T > W else None)
         y = flash[0] if flash is not None else attend_dense(q, kk, vv, keep)
+    y = gated_out(y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs), gate)
     with scope("out"):
-        y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
         out = lin(y, ap["wo"], ap.get("bo"))
         if lora is not None and "wo" in lora:
             out = out + _lora_delta(y, *lora["wo"], lora_scaling)
@@ -1110,8 +1140,8 @@ def gdn_recur_dense(state):
 
 def require_servable(cfg: Config) -> None:
     """The one refusal of a config this module's forward cannot run (an
-    expert share routed by softmax, attention with an output gate, norms with
-    zero-centred weights: ``Config.training_only``): such a model trains
+    expert share routed by softmax, norms with zero-centred weights:
+    ``Config.training_only``): such a model trains
     through ``tt.jit`` / ``make_train_step``; serving it is not built yet."""
     why = getattr(cfg, "training_only", None)
     if why:
@@ -1143,6 +1173,23 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
             with scope("residual"):
                 return x + m
     # each sublayer's norm and residual sum count with the sublayer
+    if cfg.sandwich_norm:               # a norm on what each sublayer takes and on what it gives
+        # A prompt's sums are each written out (``optimization_barrier``).  Given its row's one
+        # statistic a norm is elementwise, so XLA would keep every sublayer's output of every layer to
+        # the program's end and add them all up again wherever the stream is read: 64 arrays of
+        # ``(T, C)`` at 32 layers, 2.6 GB of a 9,984-token prompt's program (its buffer assignment;
+        # PERF.md, PR 48).  A decode step's rows are a few KB each and stay as XLA fuses them.
+        written = jax.lax.optimization_barrier if x.shape[-2] > 1 else (lambda a: a)
+        with scope("mixer/post_norm"):
+            x = written(x + _norm(h, bp["norm_1_post"], cfg))
+        with scope("mlp"):
+            with scope("norm"):
+                n2 = _norm(x, bp["norm_2"], cfg)
+            m = mlp(n2)
+            with scope("post_norm"):
+                m = _norm(m, bp["norm_2_post"], cfg)
+            with scope("residual"):
+                return written(x + m)
     if cfg.post_sublayer_norm:          # OLMo: the norms sit on what the sublayers give
         with scope("mixer/norm"):
             x = x + _norm(h, bp["norm_1"], cfg)
@@ -1288,6 +1335,7 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
                     h, ck, cv = _attn_with_cache(
                         bp["attn"], n1, cos_t, sin_t, cache["k"][j], cache["v"][j], pos, cfg,
                         quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, sharded=sharded,
+                        layer_window=cfg.layer_window if kind == "sliding_attention" else None, rope=cfg.rotates(l),
                     )
                     new_k.append(ck)
                     new_v.append(cv)

@@ -111,11 +111,20 @@ class Config:
     # the same position, gated) and "cross_attention" (queries alone; keys and
     # values are the last full_attention layer's); and, in the server alone,
     # "mamba2" (a Mamba-2 mixer, below) and "mlp" (the layer is its feed-forward
-    # alone, no mixer).  A model with an "mlp" layer is made of single
+    # alone, no mixer).  "sliding_attention" also stands beside
+    # "full_attention" in an ordinary decoder (no ssm, gmu or cross layer, no
+    # differential attention; ``rope_kinds`` below).  A model with an "mlp" layer is made of single
     # sublayers: every layer is ``x + f(norm_1(x))`` with ``f`` the kind's mixer
     # or, for "mlp", the model's ``mlp_class``.  None = all full attention
     layer_types: tuple | None = None
     layer_window: int | None = None
+    # "sliding_attention" beside "full_attention" in an ordinary decoder (GQA,
+    # q/k norm, any feed-forward; in the server alone): the window kind keeps a
+    # ring a request, the other its whole length in blocks.  ``rope_kinds``: the
+    # layer kinds whose q and k are rotated (None = every attention layer): a
+    # model that rotates in its window layers and not at all in its global
+    # ones names ("sliding_attention",)
+    rope_kinds: tuple | None = None
     # Differential attention in every attention layer of the model (the cross
     # ones too): query heads in adjacent pairs ``(2j, 2j + 1)``, KV heads in
     # adjacent pairs ``(2g, 2g + 1)``, ``g = j // (n_head / n_query_groups)``;
@@ -176,6 +185,9 @@ class Config:
     # OLMo 2/3 blocks: the norms sit on the sublayers' *outputs*
     # (``x + norm_1(mixer(x))``, then ``+ norm_2(mlp(.))``), not their inputs
     post_sublayer_norm: bool = False
+    # A norm on both sides of every sublayer (in the server alone): ``x +
+    # norm_1_post(mixer(norm_1(x)))``, then ``+ norm_2_post(mlp(norm_2(.)))``
+    sandwich_norm: bool = False
     # q/k RMSNorm over the whole projected width (``q_norm (nh * hs)``, ``k_norm
     # (ng * hs)``) before the split into heads (OLMo 2/3); ``qk_norm`` norms a head
     qk_norm_whole: bool = False
@@ -286,8 +298,15 @@ class Config:
                                              *SINGLE_SUBLAYER_KINDS}, self.layer_types
             assert sum(k in self.layer_types for k in ("linear_attention", "conv", "ssm", "mamba2")) <= 1, (
                 "linear_attention, conv, ssm and mamba2 layers: a request's state slot holds one kind's arenas")
-            if set(self.layer_types) & set(HYBRID_DECODER_KINDS):
+            if self.hybrid_decoder:
                 self._check_hybrid_decoder()
+            elif "sliding_attention" in self.layer_types:
+                assert set(self.layer_types) <= {"full_attention", "sliding_attention"}, (
+                    "sliding_attention outside a decoder-hybrid-decoder stands beside full_attention alone "
+                    "(a ring beside another kind's state slot is untested)")
+                assert self.layer_window and self.layer_window > 0, "sliding_attention layers need layer_window"
+                assert self.sliding_window is None and not self.latent and not self.qk_norm_whole, (
+                    "layer_window is the window of sliding_attention layers; sliding_window is model-wide")
             if "mamba2" in self.layer_types:
                 H, G = self.mamba_heads, self.mamba_groups
                 assert H > 0 and self.mamba_head_dim > 0 and G > 0 and H % G == 0 and self.mamba_state > 0, (
@@ -308,6 +327,12 @@ class Config:
                 assert nk > 0 and nv % nk == 0 and self.linear_key_head_dim > 0 and self.linear_value_head_dim > 0, (
                     "linear_attention layers need linear_num_key_heads/_value_heads and their head dims")
                 assert not self.bias and not self.parallel_residual, "linear_attention: sequential, bias-free blocks only"
+        if self.rope_kinds is not None:
+            self.rope_kinds = tuple(self.rope_kinds)
+            assert set(self.rope_kinds) <= {"full_attention", "sliding_attention"} and not self.latent, self.rope_kinds
+        if self.sandwich_norm:
+            assert not (self.parallel_residual or self.post_sublayer_norm or self.shared_attention_norm or self.bias
+                        or self.single_sublayer), "sandwich_norm: sequential bias-free blocks, a norm before and after each sublayer"
         assert not (self.qk_norm and self.qk_norm_whole), "qk_norm norms a head, qk_norm_whole the projection: one of them"
         if self.post_sublayer_norm:
             assert not self.parallel_residual and not self.shared_attention_norm, (
@@ -424,10 +449,22 @@ class Config:
 
     @property
     def hybrid_decoder(self) -> bool:
-        """A decoder-hybrid-decoder: any of its four layer kinds, or differential
-        attention.  The server keeps such a model's caches a layer kind and runs
-        it through the paged decode program and whole-prompt prefills alone."""
-        return bool(set(self.layer_types or ()) & set(HYBRID_DECODER_KINDS)) or self.diff_attention
+        """A decoder-hybrid-decoder: an ssm, gmu or cross_attention layer, or
+        differential attention (a sliding_attention layer alone is an ordinary
+        decoder's window kind).  The server keeps such a model's caches a layer
+        kind and runs it through the paged decode program and whole-prompt
+        prefills alone, as it does any model with ``ring_layers``."""
+        return bool(set(self.layer_types or ()) & {"ssm", "gmu", "cross_attention"}) or self.diff_attention
+
+    def rotates(self, i: int) -> bool:
+        """Whether layer ``i`` rotates its q and k (``rope_kinds``; every attention layer where None)."""
+        return self.rope_n_elem > 0 and (self.rope_kinds is None or self.layer_kind(i) in self.rope_kinds)
+
+    @property
+    def keeps_slot(self) -> bool:
+        """A request leases a slot of the server's state pool: for a state or a
+        tail (``state_layers``) or for its window layers' rings (``ring_layers``)."""
+        return bool(self.state_layers or self.ring_layers)
 
     @property
     def cross_from(self) -> int | None:
@@ -475,14 +512,13 @@ class Config:
     @property
     def training_only(self) -> str | None:
         """Why ``models.generate`` and ``tt.serve`` cannot run this config, or
-        None: the server's expert share routes by sigmoid scores alone, its
-        attention has no output gate and its norms no zero-centred weights."""
+        None: the server's expert share routes by sigmoid scores alone and its
+        norms have no zero-centred weights."""
         if self.mlp_class == "SparseMoE" and self.moe_router == "softmax":
             return ("its mlp_class is SparseMoE with the softmax router (the serving forward's expert "
                     "share routes by moe_router='sigmoid_group' or 'sigmoid_bias')")
-        for knob in ("attn_output_gate", "norm_zero_centered"):
-            if getattr(self, knob):
-                return f"it sets {knob} (the serving forward's attention and norms have no such form)"
+        if self.norm_zero_centered:
+            return "it sets norm_zero_centered (the serving forward's norms have no such form)"
         return None
 
     @classmethod
@@ -724,6 +760,9 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
         if config.single_sublayer and config.layer_kind(i) != "mlp":
             params["blocks"].append(block)                      # a mixer alone: no second norm, no feed-forward
             continue
+        if config.sandwich_norm:        # the norms on what the two sublayers give
+            block.update(norm_1_post=norm_init((config.n_embd,), dtype=dtype),
+                         norm_2_post=norm_init((config.n_embd,), dtype=dtype))
         if not config.shared_attention_norm and not config.single_sublayer:
             block["norm_2"] = norm_init((config.n_embd,), dtype=dtype)
             if config.bias:
@@ -1101,15 +1140,20 @@ def serving_only(config: Config) -> str | None:
     config, or None: latent attention, the gated short convolution, a
     decoder-hybrid-decoder's kinds (selective scan, per-kind window, gated
     memory unit, cross attention, differential attention), the Mamba-2 mixer,
-    single-sublayer blocks, the sigmoid routers, leading dense layers and the
-    latent ungated expert share are built in ``models.generate`` for the server
-    alone."""
+    single-sublayer blocks, the window kind of an ordinary decoder with its
+    rotation a layer kind and its norms on both sides of a sublayer, the sigmoid
+    routers, leading dense layers and the latent ungated expert share are built
+    in ``models.generate`` for the server alone."""
     if config.conv_layers:
         return ("layer_types with 'conv' (the gated short convolution is built in models.generate, for tt.serve, "
                 "and has no traced form)")
     if config.hybrid_decoder:
         return ("layer_types with 'ssm', 'sliding_attention', 'gmu' or 'cross_attention', or differential attention "
                 "(a decoder-hybrid-decoder's kinds are built in models.generate, for tt.serve, and have no traced form)")
+    if config.ring_layers or config.rope_kinds is not None or config.sandwich_norm:
+        return ("layer_types with 'sliding_attention' in an ordinary decoder, rope_kinds or sandwich_norm (a window a "
+                "layer kind, a rotation a layer kind and a norm on both sides of a sublayer are built in "
+                "models.generate, for tt.serve, and have no traced form)")
     if config.latent:
         return "kv_lora_rank > 0 (latent attention is built in models.generate, for tt.serve, and has no traced form)"
     if set(config.layer_types or ()) & set(SINGLE_SUBLAYER_KINDS):

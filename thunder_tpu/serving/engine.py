@@ -274,10 +274,43 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
     tail, or a Mamba-2 scan's matrix state a head and tail), or None.  Each is a mechanism that is not built, not a shortcut that
     was skipped (ROADMAP Queue 2).  A window of a layer kind
     (``cfg.layer_window``) passes: its K/V live in the slot's ring; the
-    model-wide ``sliding_window`` beside a state is still refused.  A
+    model-wide ``sliding_window`` beside a state is still refused.  An ordinary
+    decoder with sliding_attention layers (``cfg.ring_layers`` and no state)
+    leases the slot for its rings alone and is refused the same options, each
+    by what the ring lacks.  A
     decoder-hybrid-decoder (``cfg.hybrid_decoder``) has its paged decode
     program and whole-prompt prefills alone, and refuses what needs another;
     so does a model with mamba2 layers, whose paged forward steps one token a row."""
+    if getattr(cfg, "ring_layers", ()) and not getattr(cfg, "state_layers", ()):
+        # an ordinary decoder whose window layers keep a ring a request and nothing else
+        if kv_dtype is not None:
+            return ("kv_dtype= (an int8 or fp8 arena) is unsupported: the ring arenas have no scale rows (a slot's "
+                    "ring is written and walked at the model's dtype; only the paged arenas have a quantised form)")
+        if prefill_chunk is not None:
+            return ("prefill_chunk= is unsupported: a piece of a prompt past position 0 has no program over a ring "
+                    "(it would read the ring's older blocks while it overwrites them)")
+        if priorities is not None:
+            return "priorities= is unsupported: a preempted request resumes through the chunk programs, which are not built"
+        if fault_plan is not None:
+            return "fault_plan= is unsupported: re-prefill recovery replays through the chunk programs, which are not built"
+        if prefix_sharing:
+            return ("prefix_sharing=True is unsupported: a prefix's blocks of the full_attention layers can be shared, "
+                    "the ring its window layers held at the prefix's end cannot (a ring keeps its owner's last window)")
+        if sessions is not None and sessions is not False:
+            return ("sessions= is unsupported: a parked session re-attaches through the shared-prefix path, and its "
+                    "ring went back with its slot")
+        if speculative is not None:
+            return ("speculative= is unsupported: a draft's verify attends several tokens a row, and the ring is "
+                    "walked one token a row; a rejected draft's tokens would have overwritten the ring's oldest block")
+        if lora is not None and getattr(cfg, "attn_output_gate", False):
+            return ("lora= is unsupported: the adapter arena's wq target is n_head * head_size wide, and a gated "
+                    "layer's wq projects a head's query and its gate in one product of twice that")
+        if mesh is not None:
+            return "mesh= is unsupported: the ring arenas have no layout under a tp axis"
+        if int(decode_steps) > 1:
+            return ("decode_steps > 1 is unsupported: the multi-step program writes the paged arenas alone, and a "
+                    "row that finishes inside a visit would go on overwriting its ring")
+        return None
     if getattr(cfg, "hybrid_decoder", False):
         if kv_dtype is not None:
             return ("kv_dtype= (an int8 or fp8 arena) is unsupported: the ring arenas and the differential walk "
@@ -394,7 +427,7 @@ class ServingEngine:
         # a model with linear_attention layers keeps a recurrent state a
         # request beside its KV, one with conv layers a conv tail; what such a
         # state cannot serve yet refuses here, with its reason
-        self._hybrid = bool(getattr(cfg, "state_layers", ()))
+        self._hybrid = bool(getattr(cfg, "keeps_slot", False))
         if self._hybrid:
             why = hybrid_unsupported(
                 cfg, prefix_sharing=prefix_sharing, sessions=sessions, speculative=speculative,
@@ -404,12 +437,13 @@ class ServingEngine:
                 kind = ("linear_attention layers (a recurrent state a request)" if cfg.linear_layers
                         else "conv layers (a conv tail a request)" if cfg.conv_layers
                         else "mamba2 layers (a Mamba-2 scan's matrix state a head a request)" if cfg.mamba2_layers
-                        else "ssm layers (a selective scan's state a request) beside per-kind K/V")
+                        else "ssm layers (a selective scan's state a request) beside per-kind K/V" if cfg.ssm_layers
+                        else "sliding_attention layers (a ring of layer_window tokens a request)")
                 raise NotImplementedError(f"config {getattr(cfg, 'name', '?')!r} has {kind}: {why}")
             prefix_sharing = False
         # per-kind caches (a ring a slot for window layers, one layer's blocks read
         # by the cross layers): the paged decode program and whole prompts alone
-        self._perkind = bool(getattr(cfg, "hybrid_decoder", False))
+        self._perkind = bool(getattr(cfg, "hybrid_decoder", False) or getattr(cfg, "ring_layers", ()))
         self._latent = bool(getattr(cfg, "latent", False))
         if self._latent:
             why = latent_unsupported(cfg, kv_dtype=kv_dtype, cache_dtype=cache_dtype, speculative=speculative,
@@ -472,6 +506,9 @@ class ServingEngine:
         from thunder_tpu.serving.paged_attention import decode_path
 
         self.attn_fallback_steps = 0
+        # a model with window layers: the keys a decode step's rows attend, a layer of each kind
+        self._attended = ({"steps": 0, "full_attention": 0, "sliding_attention": 0}
+                          if getattr(cfg, "ring_layers", ()) else None)
         arena = self.pool.k_arena
         _, _, ng, bs, lanes = arena.sharding.shard_shape(arena.shape)
         self._attn_path = decode_path(cfg, mesh, arena_lanes=lanes)     # a latent arena: mla_paged_decode's walk
@@ -1318,6 +1355,9 @@ class ServingEngine:
                 **({"shared_kv_layers": 1 + sum(k == "cross_attention" for k in self.cfg.layer_types),
                     "prefill_cross_rows": self.prefill_fresh_runs}
                    if self._perkind and self.cfg.cross_from is not None else {}),
+                # window layers: the keys the decode steps' rows attended, summed, in a
+                # layer of each kind (``min(pos + 1, layer_window)`` a row against ``pos + 1``)
+                **({"attended_tokens": dict(self._attended)} if self._attended is not None else {}),
             },
             "bucket_bound": kinds * len(self._table_widths) + (
                 len(sch.prefill_buckets) if self.spec is None else 0),
@@ -2212,6 +2252,14 @@ class ServingEngine:
                 (kind, Bb, nbb), prog, ex,
             )
         self._note_attn_step()
+        if self._attended is not None:
+            # the keys a layer of each kind attends this step (one token a row: rings refuse
+            # decode_steps > 1), over the rows that hold a request: a window layer the last
+            # layer_window, the others all
+            seen = np.asarray(host_pos, dtype=np.int64)[:len(running)] + 1
+            self._attended["full_attention"] += int(seen.sum())
+            self._attended["sliding_attention"] += int(np.minimum(seen, self.cfg.layer_window).sum())
+            self._attended["steps"] += 1
         if self._goodput is not None and self._attn_path != "xla":
             # ragged-decode visibility: the bucket's tables span Bb x nbb
             # blocks per step but the kernel's walk streams only each row's
@@ -3212,7 +3260,10 @@ class ServingEngine:
             start, rdest = ring_dest(sslot[0], n_real, n, n_ring, bs)
             out = {}
             for name in ("k", "v"):
-                last = jax.lax.dynamic_slice_in_dim(of(cache[name], cfg.ring_layers), start * bs, n * bs, axis=3)
+                # a window layer's last blocks are cut out of the layer's own K (or V) before the layers are
+                # stacked: the rest of a long prompt's keys is dead once its layer has attended them
+                last = jnp.stack([jax.lax.dynamic_slice_in_dim(cache[name][cfg.kv_layers.index(i)], start * bs, n * bs, axis=2)
+                                  for i in cfg.ring_layers])
                 out[name + "_ring"] = scatter_blocks(arenas[name + "_ring"], last, rdest)
                 out[name] = scatter_blocks(arenas[name], of(cache[name], cfg.paged_kv_layers), dest)
             return out, jnp.float32(0.0)

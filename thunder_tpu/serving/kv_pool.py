@@ -207,13 +207,18 @@ class StatePool:
 
     def snapshot(self) -> dict:
         """``dtype`` is the recurrent state's storage where there is one, else the tails'.
-        ``ring_*``: the window kind's table, a ring a slot; its fill is the slots leased."""
-        ring = ({"ring_blocks": self.ring_blocks, "ring_arena_bytes": self.ring_bytes(),
+        ``ring_*``: the window kind's table, a ring a slot; its fill is the slots leased,
+        its bytes a slot's rings (``ring_slot_bytes``) and the leased slots' (``ring_leased_bytes``)."""
+        a_ring = self.ring_bytes() // (self.num_slots + 1)
+        ring = ({"ring_blocks": self.ring_blocks, "ring_arena_bytes": self.ring_bytes(), "ring_slot_bytes": a_ring,
+                 "ring_leased_bytes": self.leased * a_ring,
                  "ring_fill_frac": self.leased / self.num_slots} if self.ring_blocks else {})
+        # a pool of rings alone (an ordinary decoder's window layers) has neither: its dtype is the rings'
+        tails = self.dtypes.get("conv", self.dtypes.get(RING_ARENAS[0]))
         return {**ring, "slots": self.num_slots, "leased": self.leased,
                 "free_low_water": self._free_low_water, "arena_bytes": self.arena_bytes(),
                 "slot_bytes": self.slot_bytes(), "layers": self.layers, "arenas": sorted(self.shapes),
-                "dtype": str(self.dtypes.get("state", self.dtypes["conv"])), "conv_dtype": str(self.dtypes["conv"]),
+                "dtype": str(self.dtypes.get("state", tails)), "conv_dtype": str(tails),
                 "fill_frac": self.leased / self.num_slots}
 
 
@@ -281,9 +286,10 @@ class PagedKVPool:
         # layers keeps a state slot a request beside its blocks (the K/V
         # arenas' layer axis then holds the full-attention layers only)
         self.state = None
-        if getattr(cfg, "state_layers", ()):
+        if getattr(cfg, "keeps_slot", False):
             if state_slots is None:
-                raise ValueError("a config with linear_attention or conv layers needs state_slots=")
+                raise ValueError("a config with linear_attention, conv, ssm, mamba2 or sliding_attention layers "
+                                 "needs state_slots=")
             if self.quantized_kv and getattr(cfg, "ring_layers", ()):
                 raise ValueError("the ring arenas of sliding_attention layers have no quantised storage")
             self.state = StatePool(cfg, state_slots, dtype, block_size=self.block_size, lane_pack=self.lane_pack)

@@ -54,6 +54,7 @@ from thunder_tpu.executors.pallasex import (
 )
 from thunder_tpu.models.generate import (
     diff_attention,
+    gated_out,
     gdn_mixer,
     gmu_mixer,
     kv_lane_pack,
@@ -335,7 +336,10 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     ``(B, L_ring, ng, hs)`` and ``ring_tables`` for :func:`write_fresh_kv`.  A model
     with mamba2 layers (one token a row): ``state`` and ``conv`` are the Mamba-2
     scans' (:func:`_mamba2_paged`); an "mlp" layer (a feed-forward alone) keeps
-    nothing.  ``moe_rows``: ``fresh`` also carries ``moe_rows (L_moe, 2)`` int32, each
+    nothing.  An ordinary decoder with sliding_attention layers (one token a row):
+    those layers walk ``k_ring`` / ``v_ring`` through the slots' ring tables under
+    ``cfg.layer_window`` and the full_attention layers their own blocks, as the
+    hybrid's do; ``fresh`` carries both.  ``moe_rows``: ``fresh`` also carries ``moe_rows (L_moe, 2)`` int32, each
     expert layer's rows that landed on held experts and held experts with a row
     (``generate.moe_share_mlp``), which the decode program sums for
     ``engine.stats()["moe"]``."""
@@ -420,31 +424,45 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                                         layer=l, cdtype=cdtype, lin=lin)
                     fresh_rows.append(row)
                 else:
+                    gate = []
                     q, k, v = _project_qkv(bp["attn"], n1, cos_t, sin_t, cfg, lin=lin,
                                            lora=lora_l, lora_scaling=lora_scaling,
-                                           delta_fn=delta_fn)
-                    kvl = len(fresh_k)                         # this layer's place in the K/V arenas
+                                           delta_fn=delta_fn, rope=cfg.rotates(l), gate=gate)
+                    # an ordinary decoder's window kind walks the slot's ring under the
+                    # kind's window; every other layer its own blocks of the paged arenas
+                    swa = kind == "sliding_attention"
+                    if swa and T != 1:
+                        raise NotImplementedError("a sliding_attention layer's ring is walked one token a row (a "
+                                                  "draft's verify and a piece of a prompt have no such program)")
+                    if swa and ring_tabs is None:
+                        ring_tabs = ring_tables(sslots, ring_blocks(cfg, arenas["k_ring"].shape[3]), tables.shape[1])
+                    kvl = len(ring_k) if swa else len(fresh_k)      # this layer's place in its kind's arenas
                     # fresh K/V at the cache compute dtype — the exact values the dense
                     # path writes before attending
-                    with scope("attn"):
+                    with scope("swa" if swa else "attn"):
                         if T == 1:
                             # q: (B, nh, 1, hs) → (B, nh, hs)
                             fk = k[:, :, 0].astype(cdtype)
                             fv = v[:, :, 0].astype(cdtype)
-                            y = _attn_paged(q[:, :, 0], arenas, fk, fv, tables, pos,
-                                            layer=kvl, window=window, mesh=mesh)
+                            if swa:
+                                y = _attn_paged(q[:, :, 0], {"k": arenas["k_ring"], "v": arenas["v_ring"]}, fk, fv,
+                                                ring_tabs, pos, layer=kvl, window=cfg.layer_window, mesh=mesh)
+                            else:
+                                y = _attn_paged(q[:, :, 0], arenas, fk, fv, tables, pos,
+                                                layer=kvl, window=window, mesh=mesh)
                             y = y.reshape(B, 1, nh * hs)
                         else:
                             fk = k.astype(cdtype)                  # (B, ng, T, hs)
                             fv = v.astype(cdtype)
                             y = _attn_paged(q, arenas, fk, fv, tables, pos, layer=kvl, mesh=mesh)
                             y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
+                    y = gated_out(y, gate)
                     with scope("out"):
                         h = lin(y, bp["attn"]["wo"], bp["attn"].get("bo"))
                         if lora_l is not None and "wo" in lora_l:
                             h = h + delta_fn(y, *lora_l["wo"], lora_scaling)
-                    fresh_k.append(fk)
-                    fresh_v.append(fv)
+                    (ring_k if swa else fresh_k).append(fk)
+                    (ring_v if swa else fresh_v).append(fv)
             x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling,
                              moe_rows=rows_of)
 

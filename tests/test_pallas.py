@@ -197,7 +197,8 @@ def test_flash_windowed_rows_past_the_keys_band(monkeypatch, interpret_kernels, 
     scale = 1.0 / np.sqrt(hs)
     out, lse = pallasex.flash_sdpa(q, k, v, None, True, scale, window)
     dq, dk, dv = pallasex.flash_sdpa_backward(g, q, k, v, out, lse, None, True, scale, window)
-    assert pallasex.flash_schedule == {"grid_steps": 4, "running_blocks": 2, "edge_blocks_a_full_row": 1}
+    assert pallasex.flash_schedule == {"grid_steps": 4, "running_blocks": 2, "edge_blocks_a_full_row": 1,
+                                       "block_q": 128, "block_k": 128, "tail_rows": 0}
     assert np.isnan(np.asarray(_sdpa_reference(q, k, v, None, True, scale, window)[0][..., live:, :])).all()
     assert np.isfinite(np.asarray(out)).all() and not np.asarray(dq[..., live:, :]).any()
     top = lambda x: x[..., :live, :]   # noqa: E731
@@ -571,7 +572,8 @@ def test_flash_schedule_counter_at_the_train_cells_shapes(monkeypatch, cell, blo
             monkeypatch.delenv(f"THUNDER_TPU_FLASH_B{which}", raising=False)
     shape = {"mistral": (32, 8, 8192, 128, 32, 8, 4096), "hybrid": (32, 4, 8192, 256, 16, 2, None)}[cell]
     fwd, bwd = _trace_flash(*shape)
-    assert fwd == bwd == {"grid_steps": steps, "running_blocks": steps, "edge_blocks_a_full_row": edges_a_full_row}
+    assert fwd == bwd == {"grid_steps": steps, "running_blocks": steps, "edge_blocks_a_full_row": edges_a_full_row,
+                          "block_q": blocks or 1024, "block_k": blocks or 1024, "tail_rows": 0}
     # ``stats`` stays flat counters: its readers sum and subtract them
     assert all(type(v) is int for v in pallasex.stats.values())
 
@@ -579,6 +581,7 @@ def test_flash_schedule_counter_at_the_train_cells_shapes(monkeypatch, cell, blo
 @pytest.mark.parametrize("hs,dtype,mq,window,want", [
     (128, jnp.bfloat16, 1, 4096, 1024), (256, jnp.bfloat16, 1, None, 1024), (128, jnp.float32, 1, None, 1024),
     (384, jnp.bfloat16, 1, None, 512), (256, jnp.float32, 1, None, 512),   # a block of 1024 rows outgrows VMEM
+    (256, jnp.bfloat16, 1, 4096, 512), (128, jnp.float32, 1, 2048, 512),   # ... and so does a window's compare beside 512 bytes a row
     (128, jnp.bfloat16, 8192, None, 512),                                   # so does a (1024, 1024) mask block
     (128, jnp.bfloat16, 1, 1024, 512), (128, jnp.bfloat16, 1, 2048, 1024)])  # a band under two wide blocks
 def test_flash_blocks_follow_head_dtype_mask_and_window(monkeypatch, hs, dtype, mq, window, want):
@@ -586,8 +589,8 @@ def test_flash_blocks_follow_head_dtype_mask_and_window(monkeypatch, hs, dtype, 
     monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
     q = jax.ShapeDtypeStruct((4, 8192, hs), dtype)
     assert pallasex._flash_blocks(q, q, mq, window) == (want, want)
-    short = jax.ShapeDtypeStruct((4, 1536, hs), dtype)     # 1536 = 3 * 512
-    assert pallasex._flash_blocks(q, short, mq, window) == (want, 512)
+    short = jax.ShapeDtypeStruct((4, 1536, hs), dtype)     # one size for both axes, whatever the keys' length
+    assert len(set(pallasex._flash_blocks(q, short, mq, window))) == 1
 
 
 def _band_case(rep, hs, mask_kind, T=640, window=384):
@@ -616,7 +619,8 @@ def test_flash_band_with_interior_and_edge_blocks_matches_reference(monkeypatch,
     q, k, v, g, mask = _band_case(rep, hs, mask_kind)
     scale, window = 1.0 / np.sqrt(hs), 384
     out, lse = pallasex.flash_sdpa(q, k, v, mask, True, scale, window)
-    assert pallasex.flash_schedule == {"grid_steps": 5 * 4 - 6, "running_blocks": 14, "edge_blocks_a_full_row": 2}
+    assert pallasex.flash_schedule == {"grid_steps": 5 * 4 - 6, "running_blocks": 14, "edge_blocks_a_full_row": 2,
+                                       "block_q": 128, "block_k": 128, "tail_rows": 0}
     oref, lref = _sdpa_reference(q, k, v, mask, True, scale, window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oref), atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(lref), atol=2e-5, rtol=2e-5)
@@ -624,3 +628,22 @@ def test_flash_band_with_interior_and_edge_blocks_matches_reference(monkeypatch,
     want = _sdpa_backward_reference(g, q, k, v, out, lse, mask, True, scale, window)
     for a, b, n in zip(got, want, ("dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4, err_msg=n)
+
+
+@pytest.mark.parametrize("mask_kind", ["padding", "bias"])
+@pytest.mark.parametrize("blocks", [256, 512])
+def test_flash_ragged_last_block_with_an_additive_mask(monkeypatch, interpret_kernels, blocks, mask_kind):
+    """A mask's block reaches past the end with its operands' (``mq > 1``: a
+    block a grid step, rows past ``Tq`` too): nothing of it is padded, the
+    ragged blocks' select cuts what the copy left there."""
+    monkeypatch.setenv("THUNDER_TPU_FLASH_BQ", str(blocks))
+    monkeypatch.setenv("THUNDER_TPU_FLASH_BK", str(blocks))
+    q, k, v, g, mask = _band_case(4, 128, mask_kind)
+    scale, window = 1.0 / np.sqrt(128), {256: 352, 512: 320}[blocks]   # windows of its own: the variables are read at trace time
+    out, lse = pallasex.flash_sdpa(q, k, v, mask, True, scale, window)
+    assert (pallasex.flash_schedule["block_q"], pallasex.flash_schedule["tail_rows"]) == (blocks, -640 % blocks)
+    got = (out, lse, *pallasex.flash_sdpa_backward(g, q, k, v, out, lse, mask, True, scale, window))
+    oref, lref = _sdpa_reference(q, k, v, mask, True, scale, window)
+    want = (oref, lref, *_sdpa_backward_reference(g, q, k, v, oref, lref, mask, True, scale, window))
+    for a, b, n, tol in zip(got, want, ("out", "lse", "dq", "dk", "dv"), (2e-5, 2e-5, 2e-4, 2e-4, 2e-4)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=tol, rtol=tol, err_msg=n)

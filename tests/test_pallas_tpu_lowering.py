@@ -159,12 +159,18 @@ def _cases(nh: int, ng: int) -> dict:
           ops=(((nh, Tc, HS), BF), ((ng, Tc, HS), BF), ((nh, 1, Tc), F32)))
     flash("/head256_rep8", heads=(8, 1),
           ops=(((8, Tc, 256), BF), ((1, Tc, 256), BF), ((8, 1, Tc), F32)))
+    # ... under a window: 512 bytes a row and the window's compare do not fit blocks of 1024 (``_flash_bwd_dkv``)
+    flash("/head256_window", window=2048, heads=(8, 1),
+          ops=(((8, 2 * Tc, 256), BF), ((1, 2 * Tc, 256), BF), ((8, 1, 2 * Tc), F32)))
     # the widest tiles ``_flash_blocks`` derives blocks of 1024 for: float32 at
     # head 128, and every block of the rectangle with a padding mask's row
     flash("/float32_blocks_of_1024",
           ops=(((nh, Tc, HS), F32), ((ng, Tc, HS), F32), ((nh, 1, Tc), F32)))
     flash("/padding_mask_blocks_of_1024", mask=((1, 1, Tc), F32), mode="shared", mq=1, causal=False,
           ops=(((nh, Tc, HS), BF), ((ng, Tc, HS), BF), ((nh, 1, Tc), F32)))
+    # ... and with a ragged last block (T 6912 = 6.75 blocks of 1024): the tail's form of each body beside the other two
+    Tr = 6912
+    flash("/ragged_head256_rep8", heads=(8, 1), ops=(((8, Tr, 256), BF), ((1, Tr, 256), BF), ((8, 1, Tr), F32)))
     cases["flash_cross_entropy"] = (
         px._flash_ce.__wrapped__, [((256, 2048), BF), ((256,), I32)])
     # the chunked gated delta rule (2 key heads, 4 value heads, two blocks of
@@ -321,6 +327,35 @@ def test_kernel_lowers_and_compiles_for_tpu(shape, kernel, tpu_sharding):
         compiled = lowered.compile().as_text()
         for name in kernel_names(kernel):
             assert re.search(rf"%{name}(\.\d+)? = ", compiled), (kernel, name)
+
+
+@pytest.mark.parametrize("window", [None, 2048], ids=["global", "window2048"])
+@pytest.mark.parametrize("T", [3840, 5888, 7936, 9984])
+def test_flash_fwd_compiles_at_the_docqa_buckets_with_a_ragged_last_block(T, window, tpu_sharding, monkeypatch):
+    """``trinity-mini-serve-1chip.offline-docqa``'s four prefill buckets, 32
+    heads over 4 of 128, a global layer's call and a window layer's: odd
+    multiples of 256, which ``_flash_blocks`` gives blocks of 1024 whose last
+    reaches 256 rows past the end.  Mosaic compiles that for a v5e (VMEM
+    fits, a ragged block's copies are laid out); the tail's form of the body
+    is in the module there and in none at 8192, which the block divides."""
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
+
+    def lowered(T_):
+        q = jax.ShapeDtypeStruct((32, T_, HS), BF, sharding=tpu_sharding)
+        k = jax.ShapeDtypeStruct((4, T_, HS), BF, sharding=tpu_sharding)
+        return jax.jit(lambda q_, k_, v: px._flash_fwd.__wrapped__(
+            q_, k_, v, None, True, HS ** -0.5, 32, 4, None, 1, window)).trace(q, k, k).lower(lowering_platforms=("tpu",))
+
+    whole = _mosaic_module(lowered(8192).as_text())
+    assert px.flash_schedule["block_q"] == 1024 and px.flash_schedule["tail_rows"] == 0
+    ragged = lowered(T)
+    assert (px.flash_schedule["block_q"], px.flash_schedule["block_k"], px.flash_schedule["tail_rows"]) == (1024, 1024, 256)
+    module = _mosaic_module(ragged.as_text())
+    # three forms of the body for two: one more pair of products, and the select that zeroes V's tail
+    assert module.count("tpu.matmul") == whole.count("tpu.matmul") + 2 == 6
+    if tpu_sharding is not None:
+        assert re.search(r"%_flash_fwd(\.\d+)? = ", ragged.compile().as_text())
 
 
 @pytest.mark.parametrize("kernel", ["gdn_chunk_fwd", "gdn_chunk_bwd"])
@@ -525,7 +560,7 @@ def test_a_windowed_narrow_heads_decode_program_lowers_with_the_xla_form(hs, sto
 
 SERVE_CELLS = {   # cell -> (layers lowered, full-attention layers among them, a prefill bucket, its flash blocks)
     "mistral7b-serve-1chip.offline-batch": (2, 2, 3072, 1024),
-    "olmo-hybrid-serve-1chip.offline-longgen": (4, 1, 2560, 512),
+    "olmo-hybrid-serve-1chip.offline-longgen": (4, 1, 2560, 1024),     # two and a half: the third block is ragged
 }
 
 
@@ -590,8 +625,9 @@ def test_a_whole_prompts_prefill_lowers_to_flash_and_a_head_of_one_row(cell, kin
         # what is gathered: the token embeddings (and nothing of an arena, which has five dims)
         assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)
         sched = px.flash_schedule
-        n = Tb // block
+        n = -(-Tb // block)
         assert sched["grid_steps"] == sched["running_blocks"] == n * (n + 1) // 2      # the causal triangle's blocks
+        assert (sched["block_q"], sched["tail_rows"]) == (block, -Tb % block)
     else:
         assert claims == 0 and "_flash_fwd" not in text
         assert f"tensor<1x{nh}x{Tb}x{table}xf32>" in text               # scores against every slot of the table

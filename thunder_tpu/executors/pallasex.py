@@ -94,43 +94,55 @@ def _enabled() -> bool:
     return _pallas_available()
 
 
-def _block(T: int, which: str = "", wide: bool = False) -> int:
-    """Largest supported block size dividing ``T``: from 1024 down where the
-    call is ``wide`` (``_flash_blocks``), else from 512.
-
-    ``THUNDER_TPU_FLASH_BQ`` / ``THUNDER_TPU_FLASH_BK`` override the choice
-    for the q/kv axis (tuning knob; ignored when it does not divide T).
-    Overrides are read at trace time — call ``jax.clear_caches()`` after
-    changing them.
-    """
-    if which:
-        env = os.environ.get(f"THUNDER_TPU_FLASH_B{which}")
-        if env:
-            try:
-                b = int(env)
-            except ValueError:
-                b = 0
-            if b > 0 and T % b == 0:
-                return b
-    for b in (1024, 512, 256, 128)[0 if wide else 1:]:
-        if T % b == 0:
-            return b
-    return 0
+def _env_block(which: str) -> int:
+    """``THUNDER_TPU_FLASH_BQ`` / ``THUNDER_TPU_FLASH_BK``: the q/kv axis's
+    block for ``tools/flash_tune.py`` (a multiple of 128; it need not divide
+    the length), 0 where unset.  Read at trace time: call
+    ``jax.clear_caches()`` after changing them."""
+    try:
+        b = int(os.environ.get(f"THUNDER_TPU_FLASH_B{which}") or 0)
+    except ValueError:
+        return 0
+    return b if b > 0 and b % 128 == 0 else 0
 
 
-def _flash_blocks(q, k, mq: int, window: int | None) -> tuple[int, int]:
+# What a flash call costs a head: a grid step, whatever it holds, and a
+# thousand pairs of a listed block.  ``tools/flash_tune.py --fit`` on one v5e
+# (PR 49, call 2): ``_flash_fwd`` at 32 heads over 4 of 128, seven lengths from
+# 2560 to 9984 with and without a window of 2048, in blocks of 256, 512 and
+# 1024; the 42 readings lie within 9% of the line, 4.4% in the mean.
+_FLASH_STEP_US, _FLASH_KPAIR_NS = 0.95, 3.22
+
+
+def _flash_blocks(q, k, mq: int, window: int | None, causal: bool = True) -> tuple[int, int]:
     """``(BQ, BK)`` of a flash call on q ``(BH, Tq, hs)`` and k ``(BG, Tk, hs)``.
 
-    Blocks of 1024 where they fit VMEM (a row of ``hs`` elements within 512
-    bytes, no mask block a query row: what Mosaic compiles for a v5e) and the
-    causal band is at least two of them wide; else 512 and down.  Measured on
-    one v5e at T 8192 (PERF.md, PR 29): a grid step costs its 0.35 us whatever
-    it holds and the forward kernel rescales its accumulator once a step, so
-    the wider block wins (16.9 -> 14.0 ms at head 128 under a window of 4096,
-    32.7 -> 30.8 ms at head 256 without) until the band's edges waste more
-    than that (a tie under a window of 1024)."""
-    wide = q.shape[2] * q.dtype.itemsize <= 512 and mq == 1 and (window is None or window >= 2048)
-    return _block(q.shape[1], "Q", wide), _block(k.shape[1], "K", wide)
+    One size for both, of 512, 256, 128, and 1024 where it fits VMEM (a row of
+    ``hs`` elements within 512 bytes, within 256 under a window, whose second
+    compare is one more tile; no mask block a query row: what Mosaic compiles
+    for a v5e) and the causal band is at least two of them wide.
+    Neither length need be a multiple of it: the schedule's last block is
+    ragged (``_band_blocks``).  Taken is the size with the least modelled time
+    a head, ``steps * _FLASH_STEP_US + pairs listed * _FLASH_KPAIR_NS``, both
+    counted from the blocks ``_band_blocks`` lists: wide blocks save steps (a
+    step costs the same whatever it holds, and the forward kernel rescales
+    its accumulator once a step), narrow ones the pairs that a band's edges
+    and a ragged end waste.  A step costs what 295 thousand pairs do, so from
+    a few blocks on the widest allowed wins: 9984 goes in ten blocks of 1024
+    with 256 rows of the last past the end (in blocks of 256, its largest
+    divisor, the same call takes four times as long: PERF.md, PR 49), 2560 in
+    three with 512 past it (12% under five of 512), 128 and 256 in one of their own."""
+    Tq, Tk = q.shape[1], k.shape[1]
+    row = q.shape[2] * q.dtype.itemsize
+    wide = mq == 1 and (row <= 512 if window is None else row <= 256 and window >= 2048)
+
+    def us(b):
+        bq, bk = _env_block("Q") or b, _env_block("K") or b
+        run, _ = _band_blocks(Tq, Tk, bq, bk, causal, window)
+        steps = int(np.maximum(run.sum(axis=1), 1).sum())     # a row that keeps no pair still gets a block
+        return steps * _FLASH_STEP_US + steps * bq * bk * _FLASH_KPAIR_NS * 1e-6, bq, bk
+
+    return min(us(b) for b in (1024, 512, 256, 128)[0 if wide else 1:])[1:]
 
 
 def _pad128(hs: int) -> int:
@@ -210,7 +222,7 @@ def _supported(q_shape, k_shape, v_shape, dtype, causal, mask_shape=None, window
     # head sizes that aren't lane-aligned (e.g. 64) run zero-padded to 128
     if _pad128(hs) > 512:
         return False
-    if _block(Tq) == 0 or _block(Tk) == 0:
+    if Tq % 128 or Tk % 128:
         return False
     # causal with Tq != Tk uses top-left alignment (torch/aten convention):
     # the kernels index rows/cols globally, so no extra restriction
@@ -253,26 +265,41 @@ def _supported(q_shape, k_shape, v_shape, dtype, causal, mask_shape=None, window
 # ``_flash_bwd_dq`` transpose a column to a row, a row to a column, once a row
 # of blocks.
 #
-# Blocks are ``_flash_blocks``' (1024 where they fit VMEM and the band).  On
-# one v5e (PERF.md, PR 29) the two backward kernels then run at 88-94% of the
-# MXU's peak over the pairs their blocks hold, the forward kernel at 67%.
+# Blocks are ``_flash_blocks``': the size that costs least by its count of
+# steps and of pairs listed, 1024 where that fits VMEM and the band.  At T 8192
+# on one v5e (PERF.md, PR 29) the two backward kernels then run at 88-94% of
+# the MXU's peak over the pairs their blocks hold, the forward kernel at 67%.
+#
+# A length need not be a multiple of its block.  The list then ends in a
+# ragged block, flagged ``_TAILQ`` (it reaches past ``Tq``) or ``_TAILK``
+# (past ``Tk``): its copy brings the rows there are and leaves the rest of
+# the buffer as it was.  A row past ``Tq`` is written nowhere, and in
+# ``_flash_fwd`` / ``_flash_bwd_dq`` touches no other row; a row past ``Tk``
+# likewise in ``_flash_bwd_dkv``.  The other way round the tail would reach a
+# sum (``p = 0`` times a stale ``V`` row is NaN), so a kernel built over such a
+# list has a third form of its body, run in the ragged blocks alone: the
+# tail's operands selected to zero and the mask cut at the length.  Where the
+# blocks divide both lengths no entry carries the flag and the kernels hold
+# no such form: they are what they were before there was one.
 #
 
-_FIRST, _LAST, _EDGE = 1, 2, 4
+_FIRST, _LAST, _EDGE, _TAILQ, _TAILK = 1, 2, 4, 8, 16
 
 
 def _band_blocks(Tq: int, Tk: int, BQ: int, BK: int, causal: bool, window: int | None):
-    """Boolean grids over the blocks of the score matrix: ``run`` where block
-    ``(i, j)`` (rows ``i*BQ ..``, columns ``j*BK ..``) holds a kept pair,
+    """Boolean grids over the blocks of the score matrix, ``ceil(Tq / BQ)`` by
+    ``ceil(Tk / BK)``: ``run`` where block ``(i, j)`` (rows ``i*BQ ..``,
+    columns ``j*BK ..``, as far as the lengths go) holds a kept pair,
     ``whole`` where it holds nothing else.  A pair is kept where ``0 <= row -
     col`` (causal) ``< window``."""
-    i = np.arange(Tq // BQ)[:, None]
-    j = np.arange(Tk // BK)[None, :]
+    nq, nk = -(-Tq // BQ), -(-Tk // BK)
+    i = np.arange(nq)[:, None]
+    j = np.arange(nk)[None, :]
     if not causal:
-        run = np.ones((Tq // BQ, Tk // BK), bool)
+        run = np.ones((nq, nk), bool)
         return run, run
     # row - col over a block spans [lo, hi], every value taken
-    lo, hi = i * BQ - (j * BK + BK - 1), i * BQ + BQ - 1 - j * BK
+    lo, hi = i * BQ - (np.minimum(j * BK + BK, Tk) - 1), np.minimum(i * BQ + BQ, Tq) - 1 - j * BK
     top = np.inf if window is None else window - 1
     return (hi >= 0) & (lo <= top), (lo >= 0) & (hi <= top)
 
@@ -285,10 +312,10 @@ def _flash_schedule(Tq: int, Tk: int, BQ: int, BK: int, causal: bool, window: in
 
     Listed are the blocks with a kept pair, row by row (``by_column``: column
     by column, each column ``rep`` times with ``head`` counting up).  ``flag``
-    has ``_FIRST``/``_LAST`` on a row's (column's) first and last entry and
-    ``_EDGE`` where the block also holds a masked pair.  A row (column)
-    without any kept pair still gets its nearest block, fully masked, so its
-    output is written."""
+    has ``_FIRST``/``_LAST`` on a row's (column's) first and last entry,
+    ``_EDGE`` where the block also holds a masked pair, ``_TAILQ``/``_TAILK``
+    where it reaches past ``Tq``/``Tk``.  A row (column) without any kept pair
+    still gets its nearest block, fully masked, so its output is written."""
     run, whole = _band_blocks(Tq, Tk, BQ, BK, causal, window)
     if by_column:
         run, whole = run.T, whole.T
@@ -300,8 +327,10 @@ def _flash_schedule(Tq: int, Tk: int, BQ: int, BK: int, causal: bool, window: in
             cross = [min(line * along // across, run.shape[1] - 1)]
         walk = [(c, r) for r in range(rep) for c in cross]
         for n, (c, r) in enumerate(walk):
+            i, j = (c, line) if by_column else (line, c)
             flag = (n == 0) * _FIRST | (n == len(walk) - 1) * _LAST | (not whole[line, c]) * _EDGE
-            entries.append((c, line, r, flag) if by_column else (line, c, r, flag))
+            flag |= ((i + 1) * BQ > Tq) * _TAILQ | ((j + 1) * BK > Tk) * _TAILK
+            entries.append((i, j, r, flag))
     out = tuple(np.array(a, dtype=np.int32) for a in zip(*entries))
     for a in out:   # cached: every caller gets these very arrays
         a.setflags(write=False)
@@ -309,49 +338,75 @@ def _flash_schedule(Tq: int, Tk: int, BQ: int, BK: int, causal: bool, window: in
 
 
 # what the last flash call built visits a head (trace time): grid steps, the
-# blocks among them with a kept pair, the edge blocks of a full row.  Not
-# among ``stats``: readers sum and subtract those counters.
+# blocks among them with a kept pair, the edge blocks of a full row, the
+# blocks' sizes and the rows of the last query block past ``Tq``.  Not among
+# ``stats``: readers sum and subtract those counters.
 flash_schedule: dict[str, int] = {}
 
 
 def _note_schedule(Tq, Tk, BQ, BK, causal, window):
     qi, _, _, flag = _flash_schedule(Tq, Tk, BQ, BK, causal, window)
     run, _ = _band_blocks(Tq, Tk, BQ, BK, causal, window)
-    edges = np.bincount(qi[(flag & _EDGE) != 0], minlength=Tq // BQ)
+    edges = np.bincount(qi[(flag & _EDGE) != 0], minlength=run.shape[0])
     flash_schedule.update(
         grid_steps=len(qi),
         running_blocks=int(run.sum()),
         edge_blocks_a_full_row=int(edges[np.argmax(np.bincount(qi))]),
+        block_q=BQ,
+        block_k=BK,
+        tail_rows=-Tq % BQ,
     )
 
 
-def _keep(row0, col0, shape, rows_axis: int, window):
+def _keep(row0, col0, shape, rows_axis: int, window, causal: bool = True, rows=None, cols=None):
     """The kept pairs of an edge block whose first row and column are
-    ``row0``/``col0``; ``rows_axis`` is the axis the rows run along."""
+    ``row0``/``col0``; ``rows_axis`` is the axis the rows run along.  In a
+    ragged block ``rows``/``cols`` cut at a length.  None: every pair."""
     row = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, rows_axis)
     col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rows_axis)
-    keep = row >= col
+    keeps = [row >= col] if causal else []
     if window is not None:
-        keep = jnp.logical_and(keep, col > row - window)
-    return keep
+        keeps.append(col > row - window)
+    keeps += [x < end for x, end in ((row, rows), (col, cols)) if end is not None]
+    return functools.reduce(jnp.logical_and, keeps) if keeps else None
 
 
-def _on_edge(flag, causal: bool, body):
+def _load(ref, ragged: bool, n, B: int, end: int, axis: int = 0):
+    """A block's operand; in a ragged block (the ``n``-th of ``B`` along
+    ``axis``) with everything from ``end`` on selected to zero: what the copy
+    did not bring."""
+    x = ref[0]
+    if not ragged:
+        return x
+    return jnp.where(n * B + jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) < end, x, jnp.zeros_like(x))
+
+
+def _on_edge(flag, causal: bool, body, tail: int = 0):
     """Run ``body(masked)``: with the band's mask on an edge block, without
-    it on every other."""
-    if not causal:
-        body(False)
+    it on every other.  ``tail`` (``_TAILQ`` or ``_TAILK``, where the list has
+    such blocks and the kernel must not read past that end): ``body(True,
+    True)`` on those."""
+    def inside():
+        if not causal:
+            body(False)
+            return
+        edge = (flag & _EDGE) != 0
+        pl.when(edge)(lambda: body(True))
+        pl.when(jnp.logical_not(edge))(lambda: body(False))
+
+    if not tail:
+        inside()
         return
-    edge = (flag & _EDGE) != 0
-    pl.when(edge)(lambda: body(True))
-    pl.when(jnp.logical_not(edge))(lambda: body(False))
+    ragged = (flag & tail) != 0
+    pl.when(ragged)(lambda: body(True, True))
+    pl.when(jnp.logical_not(ragged))(inside)
 
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b^T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
 
 
-def _fwd_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window):
+def _fwd_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window, Tq, Tk):
     if has_mask:
         q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_s, l_s, acc_s = refs
     else:
@@ -366,13 +421,14 @@ def _fwd_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    def block(masked: bool):
-        v = v_ref[0]
+    def block(masked: bool, ragged: bool = False):
+        v = _load(v_ref, ragged, j, BK, Tk)   # p is 0 on the columns past Tk, and 0 times what lies there need not be
         s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT, preferred_element_type=jnp.float32) * scale  # (BQ, BK)
         if has_mask:
             s = s + mask_ref[0].astype(jnp.float32)  # (1|BQ, BK) broadcasts
         if masked:
-            s = jnp.where(_keep(i * BQ, j * BK, (BQ, BK), 0, window), s, _MASK_VALUE)
+            keep = _keep(i * BQ, j * BK, (BQ, BK), 0, window, causal, cols=Tk if ragged else None)
+            s = jnp.where(keep, s, _MASK_VALUE)
         m_prev = m_s[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -382,7 +438,7 @@ def _fwd_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask
         acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
 
-    _on_edge(flag, causal, block)
+    _on_edge(flag, causal, block, _TAILK if Tk % BK else 0)
 
     @pl.when((flag & _LAST) != 0)
     def _finalize():
@@ -434,7 +490,7 @@ def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: 
     classify the mask layout (see _canon_mask)."""
     BH, Tq, hs = q.shape
     Tk = k.shape[1]
-    BQ, BK = _flash_blocks(q, k, mq, window)
+    BQ, BK = _flash_blocks(q, k, mq, window, causal)
     has_mask = mask is not None
     qi, kj, _, flag = _flash_schedule(Tq, Tk, BQ, BK, causal, window)
     _note_schedule(Tq, Tk, BQ, BK, causal, window)
@@ -444,7 +500,8 @@ def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: 
         in_specs.append(mask_spec)
         operands.append(mask)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window),
+        functools.partial(_fwd_kernel, BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window,
+                          Tq=Tq, Tk=Tk),
         name="_flash_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -471,7 +528,7 @@ def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: 
 #
 
 
-def _bwd_dq_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window):
+def _bwd_dq_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window, Tq, Tk):
     if has_mask:
         g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, mask_ref, dq_ref, dq_s, lse_s, delta_s = refs
     else:
@@ -486,27 +543,28 @@ def _bwd_dq_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_m
         lse_s[...] = lse_ref[0].T
         delta_s[...] = delta_ref[0].T
 
-    def block(masked: bool):
-        k = k_ref[0]
+    def block(masked: bool, ragged: bool = False):
+        k = _load(k_ref, ragged, j, BK, Tk)   # the columns past Tk: p, dp and ds are 0 there, and so is what meets them
         g = g_ref[0]
         s = jax.lax.dot_general(q_ref[0], k, _NT, preferred_element_type=jnp.float32) * scale
         if has_mask:
             s = s + mask_ref[0].astype(jnp.float32)
         p = jnp.exp(s - lse_s[...])  # (BQ, BK)
         if masked:
-            p = jnp.where(_keep(i * BQ, j * BK, (BQ, BK), 0, window), p, 0.0)
-        dp = jax.lax.dot_general(g, v_ref[0], _NT, preferred_element_type=jnp.float32)  # (BQ, BK)
+            keep = _keep(i * BQ, j * BK, (BQ, BK), 0, window, causal, cols=Tk if ragged else None)
+            p = jnp.where(keep, p, 0.0)
+        dp = jax.lax.dot_general(g, _load(v_ref, ragged, j, BK, Tk), _NT, preferred_element_type=jnp.float32)  # (BQ, BK)
         ds = p * (dp - delta_s[...])
         dq_s[...] += scale * jax.lax.dot_general(ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    _on_edge(flag, causal, block)
+    _on_edge(flag, causal, block, _TAILK if Tk % BK else 0)
 
     @pl.when((flag & _LAST) != 0)
     def _finalize():
         dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(qi_ref, kj_ref, head_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window):
+def _bwd_dkv_kernel(qi_ref, kj_ref, head_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window, Tq, Tk):
     del head_ref   # the index maps' alone
     if has_mask:
         g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, mask_ref, dk_ref, dv_ref, dk_s, dv_s = refs
@@ -521,22 +579,27 @@ def _bwd_dkv_kernel(qi_ref, kj_ref, head_ref, flag_ref, *refs, BQ, BK, causal, s
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    def block(masked: bool):
+    def block(masked: bool, ragged: bool = False):
         # everything transposed: tiles are (BK, BQ), lse and delta rows
-        q = q_ref[0]
-        g = g_ref[0]
+        # the rows past Tq, columns here: q, g, lse and delta zero make p one and dp, ds zero there
+        q = _load(q_ref, ragged, i, BQ, Tq)
+        g = _load(g_ref, ragged, i, BQ, Tq)
         s = jax.lax.dot_general(k_ref[0], q, _NT, preferred_element_type=jnp.float32) * scale
         if has_mask:
             s = s + mask_ref[0].astype(jnp.float32).T  # (BK, 1|BQ) broadcasts
-        p = jnp.exp(s - lse_ref[0])
+        p = jnp.exp(s - _load(lse_ref, ragged, i, BQ, Tq, 1))
         if masked:
-            p = jnp.where(_keep(i * BQ, j * BK, (BK, BQ), 1, window), p, 0.0)
+            # past Tq only a mask's own rows reach p (one more (1024, 1024) compare would not fit VMEM at a wide head)
+            cut = Tq if ragged and has_mask and mask_ref.shape[1] > 1 else None
+            keep = _keep(i * BQ, j * BK, (BK, BQ), 1, window, causal, rows=cut)
+            if keep is not None:
+                p = jnp.where(keep, p, 0.0)
         dv_s[...] += jax.lax.dot_general(p.astype(g.dtype), g, _NN, preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v_ref[0], g, _NT, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0])
+        ds = p * (dp - _load(delta_ref, ragged, i, BQ, Tq, 1))
         dk_s[...] += scale * jax.lax.dot_general(ds.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
 
-    _on_edge(flag, causal, block)
+    _on_edge(flag, causal, block, _TAILQ if Tq % BQ else 0)
 
     @pl.when((flag & _LAST) != 0)
     def _finalize():
@@ -556,11 +619,11 @@ def _flash_bwd(g, q, k, v, out, lse, mask, causal: bool, scale: float, H: int, G
     a column and dk/dv are summed in its float32 accumulators."""
     BH, Tq, hs = q.shape
     BG, Tk, _ = k.shape
-    BQ, BK = _flash_blocks(q, k, mq, window)
+    BQ, BK = _flash_blocks(q, k, mq, window, causal)
     has_mask = mask is not None
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1).reshape(BH, 1, Tq)
     _note_schedule(Tq, Tk, BQ, BK, causal, window)
-    static = dict(BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window)
+    static = dict(BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window, Tq=Tq, Tk=Tk)
     operands = [g, q, k, v, lse, delta] + ([mask] if has_mask else [])
 
     def specs(by_group):

@@ -1,18 +1,26 @@
 """Time the three flash kernels alone on the chip, at the benchmark cells' shapes.
 
     python3 tools/flash_tune.py [--shapes mistral,hybrid] [--blocks 512x512,512x256] [--check]
-    python3 tools/flash_tune.py --serve
+    python3 tools/flash_tune.py --serve [--cells trinity-mini]
+    python3 tools/flash_tune.py --fit
 
-For each shape and each (BQ, BK) (none given: what ``pallasex._block``
+For each shape and each (BQ, BK) (none given: what ``pallasex._flash_blocks``
 derives), one line: ms a call of ``_flash_fwd``, ``_flash_bwd_dq`` and
 ``_flash_bwd_dkv`` by name from a device trace of five forward and five
 backward calls, and of whatever else XLA runs beside them in the backward
 program (``delta``, a sum over the group's heads).  ``--check`` first compares
-out, dq, dk, dv with the float32 reference at T 2048 (compiled kernels, not
-the interpreter).  ``--serve``: the forward kernel alone as a whole prompt's
-prefill calls it in the two serve cells (``generate._attn_with_cache`` at a
-static position 0), a line a prefill bucket, beside the form it replaced: the
-same prompt scored in float32 against every slot of the request's table.
+out, dq, dk, dv with the float32 reference at T 2048 and at T 2304, which no
+derived block divides (compiled kernels, not the interpreter).  ``--serve``:
+the forward kernel alone as a whole prompt's prefill calls it in three serve
+cells (``generate._attn_with_cache`` at a static position 0), a line a prefill
+bucket and kind of layer: the derived block, the rows of its last block past
+the end, ms a call and the share of the MXU's peak over the kept pairs; the
+same in the largest block that divides the length (the rule before PR 49) and
+padded with zeros to a whole block and sliced (the other form of a ragged
+end); and, where a table's float32 scores fit the device, the form PR 33
+replaced.  ``--fit``: the two constants of ``_flash_blocks``' model, a grid
+step's us and a thousand listed pairs' ns, by least squares over Trinity-Mini's
+layer at its buckets and three exact lengths, in blocks of 256, 512 and 1024.
 Needs a TPU; exits non-zero if a geometry failed."""
 import argparse
 import os
@@ -24,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import trace
+from chipbench import common, trace
 from thunder_tpu._platform import device_info
 from thunder_tpu.executors import pallasex as px
 from thunder_tpu.executors.jaxex import _sdpa_backward_reference, _sdpa_reference
@@ -32,10 +40,14 @@ from thunder_tpu.executors.jaxex import _sdpa_backward_reference, _sdpa_referenc
 # B, H, G, T, hs, window: one sequence of the Mistral train cell; the two of
 # the hybrid cell's gated attention layer
 SHAPES = {"mistral": (1, 32, 8, 8192, 128, 4096), "hybrid": (2, 16, 2, 8192, 256, None)}
-# H, G, hs, window, slots of the request's table, prefill buckets: a full-attention
-# layer of ``offline-batch`` (Mistral-7B) and of ``offline-longgen`` (Olmo-Hybrid-7B)
-SERVE = {"mistral": (32, 8, 128, 4096, 3584, (1024, 2048, 3072)),
-         "olmo-hybrid": (30, 30, 128, None, 3328, (1024, 2048, 2560))}
+# H, G, hs, windows, slots of the request's table, prefill buckets: a full-attention
+# layer of ``offline-batch`` (Mistral-7B) and of ``offline-longgen`` (Olmo-Hybrid-7B);
+# a global and a window layer of ``offline-docqa`` (Trinity-Mini: one chip's 4 of 32
+# heads a KV group; a table's float32 scores would not fit beside them)
+SERVE = {"mistral": (32, 8, 128, (4096,), 3584, (1024, 2048, 3072)),
+         "olmo-hybrid": (30, 30, 128, (None,), 3328, (1024, 2048, 2560)),
+         "trinity-mini": (32, 4, 128, (None, 2048), None, (3840, 5888, 7936, 9984))}
+FIT_LENGTHS = (2560, 3584, 8192)     # beside Trinity-Mini's buckets: the other cells' longest, which a block divides
 REPS = 5
 
 
@@ -88,30 +100,108 @@ def table_form(q, kt, vt, window):
     return jnp.einsum("bhqk,bhkd->bhqd", w, vv)
 
 
+def set_blocks(blocks: str = ""):
+    """``THUNDER_TPU_FLASH_BQ`` / ``_BK`` from "BQxBK", or neither; on a change the traces made before are dropped."""
+    names = [f"THUNDER_TPU_FLASH_B{which}" for which in "QK"]
+    before = [os.environ.pop(n, None) for n in names]
+    after = blocks.split("x") if blocks else [None, None]
+    os.environ.update({n: b for n, b in zip(names, after) if b})
+    if before != after:
+        jax.clear_caches()
+
+
+def kept_pairs(T, window):
+    """Pairs a causal call of length T keeps a head."""
+    return int(np.minimum(np.arange(T) + 1, window or T).sum())
+
+
+def divisor_block(T, hs, window):
+    """The block before PR 49: the largest of 1024 (where ``_flash_blocks`` allows it), 512, 256, 128 that divides T."""
+    wide = hs * 2 <= 512 and (window is None or window >= 2048)
+    return next(b for b in (1024, 512, 256, 128)[0 if wide else 1:] if T % b == 0)
+
+
+def flash_ms(q, k, v, bind, blocks=""):
+    """ms a call of ``_flash_fwd`` as ``flash_sdpa`` builds it (``blocks``: forced), its output and its schedule."""
+    set_blocks(blocks)
+    hs = q.shape[-1]
+    flash = jax.jit(lambda q, k, v: px.flash_sdpa(q, k, v, None, True, 1.0 / np.sqrt(hs), bind)[0])
+    out = jax.block_until_ready(flash(q, k, v))
+    schedule = dict(px.flash_schedule)
+    ms = kernel_ms(lambda: jax.block_until_ready(flash(q, k, v)), REPS)
+    set_blocks()
+    return ms, out, schedule
+
+
+def rel_err(a, b):
+    return float(jnp.linalg.norm((a - b).astype(jnp.float32)) / jnp.linalg.norm(b.astype(jnp.float32)))
+
+
 def time_serve(name):
-    H, G, hs, window, table, buckets = SERVE[name]
-    for T in buckets:
+    H, G, hs, windows, table, buckets = SERVE[name]
+    peak = common.peaks(device_info()["kind"])["bf16_flops_per_sec"]
+    share = lambda T, bind, ms: 4 * kept_pairs(T, bind) * hs * H / (ms * 1e-3) / peak   # noqa: E731
+    for T, window in ((T, w) for T in buckets for w in windows):
         q, k, v, _ = (x.reshape(1, -1, T, hs) for x in operands(1, H, G, T, hs))
-        kt, vt = (jnp.pad(x, ((0, 0), (0, 0), (0, table - T), (0, 0))) for x in (k, v))
         bind = window if window is not None and T > window else None      # as _attn_with_cache passes it
-        flash = jax.jit(lambda q, k, v: px.flash_sdpa(q, k, v, None, True, 1.0 / np.sqrt(hs), bind)[0])
-        dense = jax.jit(lambda q, kt, vt: table_form(q, kt, vt, window))
-        a, b = jax.block_until_ready((flash(q, k, v), dense(q, kt, vt)))
-        err = float(jnp.linalg.norm((a - b).astype(jnp.float32)) / jnp.linalg.norm(b.astype(jnp.float32)))
-        schedule = dict(px.flash_schedule)
-        kernel = kernel_ms(lambda: jax.block_until_ready(flash(q, k, v)), REPS)
-        form = kernel_ms(lambda: jax.block_until_ready(dense(q, kt, vt)), REPS)
-        ops = ", ".join(f"{n} {t:.3f}" for n, t in sorted(form.items(), key=lambda kv: -kv[1])[:4])
-        print(f"serve {name:11s} T {T:5d}: _flash_fwd {kernel.get('_flash_fwd', float('nan')):7.3f} ms a layer"
-              f" (all ops {sum(kernel.values()):7.3f})   against a table of {table}: {sum(form.values()):7.3f} ms ({ops})"
-              f"   they differ by {err:.5f}   schedule {schedule}", flush=True)
+        kernel, a, schedule = flash_ms(q, k, v, bind)
+        ms = kernel.get("_flash_fwd", float("nan"))
+        line = (f"serve {name:12s} T {T:5d} window {bind}: block {schedule['block_q']}x{schedule['block_k']}"
+                f" tail_rows {schedule['tail_rows']} steps {schedule['grid_steps']}: _flash_fwd {ms:7.3f} ms a layer"
+                f" (all ops {sum(kernel.values()):7.3f}), {share(T, bind, ms):.3f} of the peak over kept pairs")
+        if schedule["tail_rows"]:
+            old = divisor_block(T, hs, bind)
+            was, b, _ = flash_ms(q, k, v, bind, f"{old}x{old}")
+            was = was.get("_flash_fwd", float("nan"))
+            line += f"   in blocks of {old}: {was:7.3f} ms, {share(T, bind, was):.3f} (they differ by {rel_err(a, b):.5f})"
+            # the other form of a ragged end: zeros up to a whole block, the kernel at that length, a slice
+            more = ((0, 0), (0, 0), (0, schedule["tail_rows"]), (0, 0))
+            padded = jax.jit(lambda q, k, v: px.flash_sdpa(
+                *(jnp.pad(x, more) for x in (q, k, v)), None, True, 1.0 / np.sqrt(hs), bind)[0][:, :, :T])
+            b = jax.block_until_ready(padded(q, k, v))
+            form = kernel_ms(lambda: jax.block_until_ready(padded(q, k, v)), REPS)
+            line += (f"   padded and sliced: {sum(form.values()):7.3f} ms, _flash_fwd {form.get('_flash_fwd', float('nan')):7.3f}"
+                     f" (they differ by {rel_err(a, b):.5f})")
+        if table is not None:
+            kt, vt = (jnp.pad(x, ((0, 0), (0, 0), (0, table - T), (0, 0))) for x in (k, v))
+            dense = jax.jit(lambda q, kt, vt: table_form(q, kt, vt, window))
+            b = jax.block_until_ready(dense(q, kt, vt))
+            form = kernel_ms(lambda: jax.block_until_ready(dense(q, kt, vt)), REPS)
+            ops = ", ".join(f"{n} {t:.3f}" for n, t in sorted(form.items(), key=lambda kv: -kv[1])[:4])
+            line += f"   against a table of {table}: {sum(form.values()):7.3f} ms ({ops})   they differ by {rel_err(a, b):.5f}"
+        print(line, flush=True)
+
+
+def fit():
+    """``pallasex._FLASH_STEP_US`` and ``_FLASH_KPAIR_NS``: us a head of ``_flash_fwd`` at Trinity-Mini's layer
+    against its schedule's steps and listed pairs, every length in blocks of 256, 512 and 1024, by least
+    squares on the relative error (a short call counts as a long one)."""
+    H, G, hs, windows, _, buckets = SERVE["trinity-mini"]
+    rows = []
+    for T in sorted(buckets + FIT_LENGTHS):
+        q, k, v, _ = (x.reshape(1, -1, T, hs) for x in operands(1, H, G, T, hs))
+        for window in windows:
+            for block in (256, 512, 1024):
+                kernel, _, schedule = flash_ms(q, k, v, window, f"{block}x{block}")
+                us = kernel["_flash_fwd"] * 1e3 / H
+                rows.append((schedule["grid_steps"], schedule["grid_steps"] * block * block / 1e3, us))
+                print(f"fit T {T:5d} window {window} block {block:4d}: steps {rows[-1][0]:4d}, {rows[-1][1] / 1e3:7.2f} M pairs"
+                      f" listed, {us:8.2f} us a head", flush=True)
+    steps, kpairs, us = np.array(rows).T
+    (a, b), *_ = np.linalg.lstsq(np.stack([steps / us, kpairs / us], 1), np.ones_like(us), rcond=None)
+    off = (steps * a + kpairs * b) / us - 1
+    print(f"fit: a grid step {a:.3f} us, a thousand listed pairs {b * 1e3:.3f} ns; the model is off by"
+          f" {np.abs(off).mean():.3f} in the mean, {off.min():+.3f} to {off.max():+.3f}", flush=True)
 
 
 def check():
-    """Compiled kernels against the float32 reference, T 2048, both cells' kinds."""
+    """Compiled kernels against the float32 reference, both cells' kinds, at T 2048 and at T 2304 with a
+    ragged last block (the derived 512, and 1024)."""
     worst = 0.0
-    for name, (B, H, G, _, hs, window) in SHAPES.items():
-        T, window = 2048, None if window is None else 1024
+    for (name, (B, H, G, _, hs, window)), (T, blocks) in (
+            (s, c) for s in SHAPES.items() for c in ((2048, ""), (2304, ""), (2304, "1024x1024"))):
+        set_blocks(blocks)
+        window = None if window is None else 1024
         q, k, v, g = (x.reshape(B, -1, T, hs) for x in operands(B, H, G, T, hs))
         scale = 1.0 / np.sqrt(hs)
         out, lse = px.flash_sdpa(q, k, v, None, True, scale, window)
@@ -122,7 +212,8 @@ def check():
         for what, a, b in zip(("out", "dq", "dk", "dv"), got, want):
             err = float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
             worst = max(worst, err)
-            print(f"check {name:8s} {what:3s} relative error {err:.5f}", flush=True)
+            print(f"check {name:8s} T {T} {what:3s} relative error {err:.5f}   schedule {px.flash_schedule}", flush=True)
+    set_blocks()
     return worst
 
 
@@ -132,7 +223,9 @@ def main():
     ap.add_argument("--blocks", default="")
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--serve", action="store_true", help="the forward kernel at the serve cells' prefill buckets")
-    ap.add_argument("--window", type=int, help="another window for the mistral shape (to place _block's rule)")
+    ap.add_argument("--cells", default=",".join(SERVE), help="which of them")
+    ap.add_argument("--fit", action="store_true", help="the two constants of _flash_blocks' model")
+    ap.add_argument("--window", type=int, help="another window for the mistral shape (to place _flash_blocks' rule)")
     args = ap.parse_args()
     device = device_info()
     if device["platform"] != "tpu":
@@ -143,17 +236,16 @@ def main():
         SHAPES["mistral"] = (*SHAPES["mistral"][:5], args.window)
     if args.check and check() > 0.02:   # bfloat16 operands: 0.003-0.006
         sys.exit("flash_tune: the compiled kernels disagree with the reference")
+    if args.fit:
+        fit()
     if args.serve:
-        for name in SERVE:
+        for name in args.cells.split(","):
             time_serve(name)
+    if args.fit or args.serve:
         return
     failed = []
     for blocks in args.blocks.split(",") if args.blocks else [""]:
-        for which in "QK":
-            os.environ.pop(f"THUNDER_TPU_FLASH_B{which}", None)
-        if blocks:
-            os.environ["THUNDER_TPU_FLASH_BQ"], os.environ["THUNDER_TPU_FLASH_BK"] = blocks.split("x")
-        jax.clear_caches()
+        set_blocks(blocks)
         for name in args.shapes.split(","):
             try:
                 time_shape(name, blocks)

@@ -10,6 +10,7 @@ tokens the host saw between the two boundaries over the time between them.
 from __future__ import annotations
 
 import functools
+import heapq
 import time
 
 import jax
@@ -214,6 +215,10 @@ def measure(ctx: dict, st: dict, chk: dict) -> dict:
         "correct": bool(chk["ok"] and not any(faults.values())),
         "host": {"window_s": window, "engine_step_ms": step_ms, "finished": finished,
                  "engine_step_max_ms": max(step_ms), "engine_steps": len(step_ms),
+                 # where and how long: one step over a second among thousands of 17 ms is a pause
+                 # of the machine, a run of slow ones is the program's
+                 "slowest_steps": " ".join(f"{i}:{ms / 1e3:.4f}s" for i, ms in heapq.nlargest(
+                     5, enumerate(step_ms), key=lambda step: step[1])),
                  "gap_percentiles_ms": {q: common.percentile(gaps_ms, q)
                                         for q in (50, 75, 90, 95, 97, 99)} if gaps_ms else {},
                  "tokens_in_window": emitted1 - emitted0, **traced,
@@ -228,5 +233,5 @@ def measure(ctx: dict, st: dict, chk: dict) -> dict:
 def _slim(stats: dict) -> dict:
     keep = ("decode_steps", "prefill_runs", "tokens_generated", "mean_batch_occupancy",
             "host_visits", "pool_utilization", "pool_occupancy", "goodput", "arena_bytes",
-            "queue_depth", "running", "step_calls", "recoveries")
+            "queue_depth", "running", "step_calls", "recoveries", "decode_rebuild", "prefill_fresh_runs")
     return {k: stats[k] for k in keep if k in stats}

@@ -1,7 +1,6 @@
 """Layer: kernels.  Source: device_trace for the time, the architecture's `sizes`
-for the work.  **A reader without an entry** (the per-layer manifest is full: read
-by hand on a traced run, `python3 chipbench/layer_metrics/...` has no command;
-`run.py` finds it by name once a `benchmark` PR lists it).
+for the work.  Listed for the Trinity-Mini cell since PR 50 (PR 48 wrote it when
+the per-layer manifest was full).
 `moe_grouped_mm_roofline_share` for a SwiGLU expert share at the model's width in
 a server whose decode step reaches *some* of the held experts: `.lfm2serve` and
 `.mlaserve` count every held expert's weights a step, which at 1.25 rows a held

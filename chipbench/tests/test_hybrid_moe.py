@@ -87,13 +87,19 @@ def test_the_manifest_gained_one_configuration_one_cell_and_their_metrics():
     assert (CFG["published_num_hidden_layers"], CFG["published_num_experts"], CFG["published_vocab_size"]) == (
         48, 512, 151936)
     listed = {m["name"] for m in M["end_to_end"] + M["per_layer"] if CELL in m.get("workloads", [])}
+    # since PR 50 a quantity one reader serves is one entry an end-to-end metric (`.train`, both train cells); the
+    # six shares by scope keep their split until tests/test_op_scopes.py is rewritten
+    by_scope = {f"{q}_share_of_busy.hyb" for q in ("mixer", "mlp", "head", "unscoped", "optimizer", "backward")}
+    own = {"gdn_scan_roofline_share.hyb", "gdn_share_of_busy.hyb", "moe_grouped_mm_roofline_share.hyb",
+           "causal_conv_roofline_share.hyb"}
+    folded = {"moe_share_of_busy.train", "moe_glue_share_of_busy.train", "device_idle_share.train",
+              "pallas_share_of_busy.train", "hbm_peak_share.train"}
     assert listed == {"train_tok_per_s_per_chip", "train_host_dispatch_ms", "train_step_device_ms", "train_mfu",
-                      "flash_sdpa_roofline_share", "gdn_scan_roofline_share.hyb", "gdn_share_of_busy.hyb",
-                      "moe_share_of_busy.hyb", "moe_grouped_mm_roofline_share.hyb", "device_idle_share.hyb",
-                      "pallas_share_of_busy.hyb", "hbm_peak_share.hyb"}
+                      "flash_sdpa_roofline_share"} | by_scope | own | folded
     for m in M["per_layer"]:
-        if m["name"].endswith(".hyb"):
-            assert m["workloads"] == [CELL] and m["moves"] == "train_tok_per_s_per_chip"
+        if m["name"] in listed and "." in m["name"]:                      # by_scope, own and folded
+            assert m["workloads"][-1] == CELL and m["moves"] == "train_tok_per_s_per_chip"
+            assert len(m["workloads"]) == 1 or m["name"].endswith(".train")
             assert callable(common.load_reader(m["name"]).read)
 
 

@@ -77,12 +77,12 @@ def test_turnaround_and_host_time_match_a_flat_scan_of_the_recorded_events(tmp_p
     where.mkdir(parents=True)
     shutil.copy(RECORDED, where / "host.xplane.pb")
     ctx = {"trace_dir": str(tmp_path), "trace": trace.Trace([], [])}
-    t = reader("engine_turnaround_ms.offline").read(ctx)
+    t = reader("engine_turnaround_ms.serve").read(ctx)
     shutil.rmtree(tmp_path / "plugins")
-    h = reader("engine_host_ms_per_step.offline").read(ctx)
+    h = reader("engine_host_ms_per_step.serve").read(ctx)
     assert abs(t - 1e3 * sorted(turn)[15]) < 1e-9 and abs(h - 1e3 * sorted(host)[15]) < 1e-9
     assert 0.1 < t < 50 and 0.1 < h < 50                          # milliseconds on this CPU
-    assert reader("prefill_device_ms_per_ktok.offline").read(ctx) is None   # no device plane
+    assert reader("prefill_device_ms_per_ktok.serve").read(ctx) is None   # no device plane
 
 
 def span(name, start_ms, dur_ms, **args):
@@ -141,10 +141,10 @@ def test_prefill_runs_are_paired_with_the_spans_that_started_them():
     pairs = ps.prefill_pairs(spans, modules)
     assert [(sp.args["rid"], round(run.dur / MS, 6)) for sp, run in pairs] == [(8, 120.0), (9, 35.0)]
     tr = trace.Trace([trace.Device("/device:TPU:0", [op("fusion.1", 3, 4)], modules)], [])
-    got = reader("prefill_device_ms_per_ktok.offline").read({"trace": tr, "program_spans": spans})
+    got = reader("prefill_device_ms_per_ktok.serve").read({"trace": tr, "program_spans": spans})
     assert abs(got - (120.0 + 35.0) / 2.0) < 1e-9     # 155 ms for 2.0 thousand tokens
     # none in the stretch, or a program without spans: nothing to report
-    assert reader("prefill_device_ms_per_ktok.offline").read(
+    assert reader("prefill_device_ms_per_ktok.serve").read(
         {"trace": tr, "program_spans": two_steps()[:7]}) is None
 
 
@@ -177,8 +177,8 @@ def test_clock_bounds_pair_each_call_with_its_run_and_its_wait():
 def test_a_program_without_spans_gives_none_not_an_error():
     ctx = {"trace": trace.Trace([], []), "program_spans": [],
            "counters": {"compile_cache": {"persistent_cache_hits": 3, "persistent_cache_misses": 0}}}
-    for metric in ("engine_turnaround_ms.offline", "engine_host_ms_per_step.offline",
-                   "prefill_device_ms_per_ktok.offline", "setup_jax_trace_s", "setup_xla_s"):
+    for metric in ("engine_turnaround_ms.serve", "engine_host_ms_per_step.serve",
+                   "prefill_device_ms_per_ktok.serve", "setup_jax_trace_s", "setup_xla_s"):
         assert reader(metric).read(ctx) is None, metric
     # a step that harvested nothing, or dispatched before it harvested (the
     # synchronous loop), has no turn-around

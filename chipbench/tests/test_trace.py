@@ -45,3 +45,29 @@ def test_breakdown_names_operations_and_the_host_span_over_each_gap():
     # the long gaps are the sleeps; the short ones lie inside a round
     assert max(gaps, key=gaps.get) == "chipbench.sleep"
     assert abs(sum(gaps.values()) - (tr.window_s() - tr.busy_s())) < 1e-9
+
+
+def test_a_gap_takes_the_programs_span_inside_the_benchmarks():
+    """A serve cell's step: ``chipbench.engine_step`` around the program's ``thunder_tpu.serve.*``
+    spans.  The innermost span over a gap's middle names it, whichever prefix it carries, a span
+    with another prefix is not read at all, and the sum is still the window's idle time."""
+    op = lambda name, start, dur: trace.Op(name, "", start, dur)  # noqa: E731
+    ops = [op("fusion.1", 0.0, 1.0), op("fusion.2", 1.5, 1.0), op("fusion.3", 4.5, 0.5),
+           op("fusion.4", 5.25, 0.75), op("fusion.5", 7.0, 1.0)]
+    spans = [op("chipbench.engine_step", 0.5, 4.25),                 # gaps 1.0-1.5, 2.5-4.5
+             op("thunder_tpu.serve.step", 0.75, 3.9),
+             op("thunder_tpu.serve.harvest", 2.6, 1.8), op("thunder_tpu.serve.harvest.wait", 2.75, 1.5),
+             op("chipbench.engine_step", 4.9, 1.0),                  # gap 5.0-5.25: no program span over it
+             op("thunder_tpu.serve.step", 5.3, 0.5)]                 # gap 6.0-7.0: nothing over its middle
+    tr = trace.Trace([trace.Device("/device:TPU:0", ops, [])], spans)
+    gaps = dict(tr.idle_gaps())
+    assert gaps == {"thunder_tpu.serve.harvest.wait": 2.0, "(no span)": 1.0, "thunder_tpu.serve.step": 0.5,
+                    "chipbench.engine_step": 0.25}
+    assert list(gaps) == ["thunder_tpu.serve.harvest.wait", "(no span)", "thunder_tpu.serve.step",
+                          "chipbench.engine_step"]                   # largest first
+    assert abs(sum(gaps.values()) - (tr.window_s() - tr.busy_s())) < 1e-12
+    assert tr.idle_share() == 1.0 - 4.25 / 8.0                       # no span moves the idle share
+    # the spans in any order, and one that the file's loader would not have kept
+    assert all(n.startswith(trace.HOST_SPAN_PREFIX) for n in gaps if n != "(no span)")
+    shuffled = trace.Trace(tr.devices, spans[::-1])
+    assert dict(shuffled.idle_gaps()) == gaps

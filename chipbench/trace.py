@@ -3,7 +3,9 @@
 A device plane (``/device:TPU:<n>``) has one line of operations (``XLA Ops``:
 what the core runs, one after another) and one of programs (``XLA Modules``:
 each run of a jitted program).  The host plane has one line a thread, with
-the ``chipbench.*`` spans that ``TraceAnnotation`` writes.
+the ``chipbench.*`` spans that the benchmark's ``TraceAnnotation`` writes and
+the program's own ``thunder_tpu.*`` spans (``observability.events.span``) on
+the same clock.
 
 - busy: the union of the operation intervals of a device; idle is the rest
   of the window, which runs from the first operation's start to the last
@@ -16,8 +18,10 @@ the ``chipbench.*`` spans that ``TraceAnnotation`` writes.
   ``pallas_call`` of the program carries ``name=`` yet, so a kernel is known
   by the jitted wrapper its custom call is named after, or by its operands.
 - a program's time: the durations of its runs on the modules line.
-- an idle gap is named after the ``chipbench.*`` host span that covers its
-  middle, or ``(no span)``.
+- an idle gap is named after the innermost ``chipbench.*`` or ``thunder_tpu.*``
+  host span that covers its middle, or ``(no span)``: in a serve cell the
+  engine's phase (``thunder_tpu.serve.harvest.wait``, ``.prefill_dispatch``),
+  not the ``chipbench.engine_step`` around all of them.
 """
 from __future__ import annotations
 
@@ -28,7 +32,7 @@ import re
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-HOST_SPAN_PREFIX = "chipbench."
+HOST_SPAN_PREFIX = ("chipbench.", "thunder_tpu.")
 # Pallas kernels reach XLA as a custom call to Mosaic
 PALLAS_MARKS = ('custom_call_target="tpu_custom_call"',)
 
@@ -51,7 +55,7 @@ class Device:
 @dataclasses.dataclass
 class Trace:
     devices: list
-    host_spans: list    # Op, name starting with chipbench.
+    host_spans: list    # Op, name starting with one of HOST_SPAN_PREFIX
 
     # ---- window and busy -------------------------------------------------
     def window(self) -> tuple[float, float]:
@@ -125,9 +129,15 @@ class Trace:
                 gaps.append((prev, a))
             prev = max(prev, b)
         agg: dict[str, float] = {}
+        # the gaps come in time order, so one pass over the spans by start serves them all (with the
+        # program's spans a traced stretch holds thousands of each)
+        spans, cover, k = sorted(self.host_spans, key=lambda s: s.start), [], 0
         for a, b in gaps:
             mid = (a + b) / 2
-            cover = [s for s in self.host_spans if s.start <= mid <= s.start + s.dur]
+            while k < len(spans) and spans[k].start <= mid:
+                cover.append(spans[k])
+                k += 1
+            cover = [s for s in cover if mid <= s.start + s.dur]
             # the innermost span names the gap
             name = min(cover, key=lambda s: s.dur).name if cover else "(no span)"
             agg[name] = agg.get(name, 0.0) + (b - a)

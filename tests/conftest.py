@@ -52,6 +52,28 @@ def _reset_observability_state():
         tt.reset_observability()
 
 
+@_pytest.fixture
+def jax_stretches():
+    """JAX's own trace / lower / compile stretches in the event ring and in
+    ``compile_cache.stats()`` for one test (what ``compile_cache.enable()``
+    registers, without a cache directory), and taken off again: a listener
+    left on writes every later compile of the worker into the ring, which
+    the next file's test then finds beside its own events."""
+    from jax import monitoring
+
+    from thunder_tpu.core import compile_cache as cc
+
+    was = cc._listener_registered
+    cc._register_listeners()
+    yield cc
+    if not was:
+        monitoring.unregister_event_listener(cc._on_event)
+        monitoring.unregister_event_duration_listener(cc._on_duration)
+        monitoring.unregister_event_time_span_listener(cc._on_time_span)
+        monitoring.unregister_scalar_listener(cc._on_scalar)
+        cc._listener_registered = False
+
+
 # The paged attention entries (``pallasex.paged_attn_decode`` / ``_verify``, and
 # ``mla_paged_decode``) choose their form from the backend: on the CPU their
 # XLA form, unless THUNDER_TPU_PALLAS_INTERPRET=1 opts into the kernels under

@@ -39,8 +39,11 @@ eng = tt.serve(None, params, cfg, block_size=4, num_blocks=8, max_batch=1)
 h = eng.submit(np.arange(5, dtype=np.int32), max_new_tokens=2)
 eng.drain()
 assert len(h.result(drive=False).new_tokens) == 2
+ring = tt.observability.events()
 print(json.dumps({**compile_cache.stats(),
-                  "config_dir": jax.config.jax_compilation_cache_dir}))
+                  "config_dir": jax.config.jax_compilation_cache_dir,
+                  "built": eng._compile_log,
+                  "ring": [e for e in ring if e["name"] in ("import", "serve.compile", "jax.backend_compile")]}))
 """
 
 # no variable from outside, and a compile before the cache is switched on:
@@ -133,4 +136,104 @@ class TestPersistentCompilationCache:
         jfn(np.ones(4, dtype=np.float32))
         pc = tt.compile_stats(jfn).persistent_cache
         assert set(pc) == {"persistent_cache_hits", "persistent_cache_misses", "dir",
-                           "jaxpr_trace_s", "lower_s", "backend_compile_s"}
+                           "jaxpr_trace_s", "lower_s", "backend_compile_s",
+                           "trace_self_s", "by_program"}
+
+    def test_a_process_start_up_is_in_the_ring(self, serve_runs):
+        """`import` once, from before JAX's import to the package's last
+        line; one `serve.compile` pair a program the engine built; and each
+        program's backend stretch says whether the persistent cache answered
+        (the first process wrote it, the second read it)."""
+        for run, hit in ((serve_runs["first"], 0), (serve_runs["second"], 1)):
+            ring = run["ring"]
+            (imp,) = [e for e in ring if e["name"] == "import"]
+            assert imp["ph"] == "X" and 0 < imp["args"]["jax_s"] < imp["dur"] / 1e6 < 120
+            pairs = [e for e in ring if e["name"] == "serve.compile"]
+            begins, ends = pairs[0::2], pairs[1::2]         # one at a time, on one thread
+            assert [e["ph"] for e in pairs] == ["B", "E"] * len(begins) and pairs[0]["ts"] > imp["ts"] + imp["dur"]
+            assert (sorted((b["args"]["kind"], b["args"]["bucket"]) for b in begins)
+                    == sorted((c["kind"], "{}x{}".format(*c["bucket"])) for c in run["built"]))
+            for b, e in zip(begins, ends):
+                # the program under its own name, inside the span of its first call
+                (mine,) = [x for x in ring if x["name"] == "jax.backend_compile"
+                           and b["ts"] <= x["ts"] and x["ts"] + x["dur"] <= e["ts"]]
+                assert mine["args"] == {"fun_name": b["args"]["kind"], "cache_hit": hit}
+
+
+def _sleepy(seconds):
+    import time
+
+    time.sleep(seconds)         # at trace time: a stretch long enough to be told from the clock's grain
+
+
+class TestStretchesByProgram:
+    def test_a_jit_inside_a_jit_is_a_stretch_inside_a_stretch(self, jax_stretches):
+        """JAX's three stages of both programs as complete events on the
+        ring's clock, the inner's trace inside the outer's, and the inner's
+        seconds counted once in `trace_self_s`."""
+        import time
+
+        import jax
+        import jax.numpy as jnp
+
+        from thunder_tpu.observability import clear_events, events
+
+        @jax.jit
+        def ring_inner(x):
+            _sleepy(0.02)
+            return jnp.tanh(x) * 2
+
+        @jax.jit
+        def ring_outer(x):
+            _sleepy(0.01)
+            return ring_inner(x) + 1
+
+        clear_events()
+        before = jax_stretches.stats()
+        t0 = time.perf_counter_ns() / 1e3
+        ring_outer(jnp.ones(4)).block_until_ready()
+        t1 = time.perf_counter_ns() / 1e3
+        after = jax_stretches.stats()
+        ring = [e for e in events() if e["name"].startswith("jax.")]
+        assert all(e["ph"] == "X" and t0 <= e["ts"] and e["ts"] + e["dur"] <= t1 for e in ring)
+        by = {(e["name"], e["args"]["fun_name"]): e for e in ring}
+        assert {("jax.trace", "ring_outer"), ("jax.lower", "ring_outer"), ("jax.backend_compile", "ring_outer"),
+                ("jax.trace", "ring_inner")} <= set(by)
+        outer, inner = by["jax.trace", "ring_outer"], by["jax.trace", "ring_inner"]
+        assert outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+        assert inner["dur"] >= 20e3 and outer["dur"] >= 30e3
+        # in order on one thread: traced, then lowered, then compiled
+        assert (outer["ts"] + outer["dur"] <= by["jax.lower", "ring_outer"]["ts"]
+                and by["jax.lower", "ring_outer"]["ts"] + by["jax.lower", "ring_outer"]["dur"]
+                <= by["jax.backend_compile", "ring_outer"]["ts"])
+        # JAX's own primitives, traced in a few hundred microseconds inside, stay out of the ring
+        assert ("jax.trace", "tanh") not in by
+        grown = {k: after[k] - before[k] for k in ("jaxpr_trace_s", "trace_self_s", "lower_s", "backend_compile_s")}
+        # the inner's 20 ms are in the outer's stretch and in its own: twice in one sum, once in the other
+        assert grown["jaxpr_trace_s"] >= grown["trace_self_s"] + 0.02 and 0.03 <= grown["trace_self_s"]
+        rows = after["by_program"]
+        assert rows["ring_outer"]["n"] == 1 and 0.01 <= rows["ring_outer"]["trace_self_s"] < 0.02 + 0.01
+        assert rows["ring_inner"]["trace_self_s"] >= 0.02 and rows["ring_inner"]["backend_s"] == 0.0
+        assert rows["ring_outer"]["lower_s"] > 0 and rows["ring_outer"]["backend_s"] > 0
+
+    def test_the_rows_are_bounded_and_sum_to_the_counters(self, jax_stretches, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+
+        monkeypatch.setattr(jax_stretches, "_programs", {})
+        monkeypatch.setattr(jax_stretches, "_counts", {k: type(v)() for k, v in jax_stretches._counts.items()})
+        for i in range(jax_stretches.BY_PROGRAM_ROWS + 4):
+            fn = lambda x, i=i: x * i + 1                           # noqa: E731
+            fn.__name__ = f"row_{i}"
+            jax.jit(fn)(jnp.ones(3))
+        st = jax_stretches.stats()
+        rows = st["by_program"]
+        assert len(rows) == jax_stretches.BY_PROGRAM_ROWS + 1 and rows["others"]["n"] >= 4
+        assert sum(name.startswith("row_") for name in rows) >= jax_stretches.BY_PROGRAM_ROWS - 4
+        for column, key in (("trace_self_s", "trace_self_s"), ("lower_s", "lower_s"),
+                            ("backend_s", "backend_compile_s")):
+            assert sum(r[column] for r in rows.values()) == pytest.approx(st[key], abs=1e-4), column
+        assert 0 < st["trace_self_s"] <= st["jaxpr_trace_s"]
+        # what the drivers subtract and print keeps its names
+        assert set(st) == {"persistent_cache_hits", "persistent_cache_misses", "jaxpr_trace_s", "lower_s",
+                           "backend_compile_s", "trace_self_s", "by_program", "dir"}

@@ -222,26 +222,48 @@ def test_the_six_readers_read_what_the_file_gives_and_nothing_on_a_program_witho
     assert not any(getattr(common.load_reader(f"{q}.train"), "SHARE_OF_PEAK", False) for q in values)
     # the parent's trace: the same operations, no group in any name
     bare = {"trace": _trace(), "op_scopes": {}}
-    got = {q: common.load_reader(f"{q}.offline").read(bare) for q in values}
+    got = {q: common.load_reader(f"{q}.serve").read(bare) for q in values}
     assert got.pop("unscoped_share_of_busy") == 1.0 and set(got.values()) == {None}
     empty = {"trace": trace.Trace([], []), "op_scopes": {}}
-    assert all(common.load_reader(f"{q}.offline").read(empty) is None for q in values)
+    assert all(common.load_reader(f"{q}.serve").read(empty) is None for q in values)
 
 
-def test_the_manifest_lists_every_split_in_its_cell():
-    per_layer = {m["name"]: m for m in common.manifest()["per_layer"]}
-    cells = {"train": "mistral7b-train-1chip.seq8k", "hyb": "qwen3next-train-1chip.seq8k-x2",
-             "offline": "mistral7b-serve-1chip.offline-batch", "hybserve": "olmo-hybrid-serve-1chip.offline-longgen",
-             "mlaserve": "axk1-serve-1chip.offline-longctx", "lfm2serve": "lfm2moe-serve-1chip.offline-wide",
-             "flashserve": "phi4flash-serve-1chip.offline-reason"}
-    for q in ("mixer", "mlp", "head", "unscoped", "optimizer", "backward"):
-        splits = ("train", "hyb") if q in ("optimizer", "backward") else tuple(cells)
-        for sp in splits:
-            m = per_layer[f"{q}_share_of_busy.{sp}"]
-            # a later cell may join a split by its `workloads` list (PR 45: the manifest holds 128 entries, its most)
-            joined = ({"nemotron3super-serve-1chip.offline-rollouts", "trinity-mini-serve-1chip.offline-docqa"}
-                      if sp == "flashserve" else set())            # PR 48 joined the same way, and added no entry
-            assert m["workloads"][0] == cells[sp] and set(m["workloads"][1:]) <= joined
-            assert m["unit"] == "fraction" and m["source"] == "device_trace"
-            assert m["moves"] == ("train_tok_per_s_per_chip" if sp in ("train", "hyb") else "serve_out_tok_per_s")
-        assert not any(n.startswith(f"{q}_share_of_busy.") and n.split(".")[1] not in splits for n in per_layer)
+# ---- the six shares by scope in the manifest ------------------------------------------------------
+# Held in both forms while both exist (PERF.md section 7, W0(q)): as PR 50 left them, one entry
+# a split (`.train`, `.hyb`; `.offline`, `.hybserve`, `.mlaserve`, `.lfm2serve`, `.flashserve`,
+# a later cell joined to the last one's list), and folded, one entry an end-to-end metric
+# (`.train`, `.serve`) whose `workloads` are the cells of that kind in the manifest's order.
+MANIFEST = common.manifest()
+PER_LAYER = {m["name"]: m for m in MANIFEST["per_layer"]}
+KIND_OF = {"train": "train_tok_per_s_per_chip", "serve": "serve_out_tok_per_s"}
+CELLS = {kind: [w["name"] for w in MANIFEST["workloads"]
+                if w["name"] in next(m for m in MANIFEST["end_to_end"] if m["name"] == e2e)["workloads"]]
+         for kind, e2e in KIND_OF.items()}
+SPLITS_BEFORE_THE_FOLD = {"train": ("train", "hyb"),
+                          "serve": ("offline", "hybserve", "mlaserve", "lfm2serve", "flashserve")}
+
+
+@pytest.mark.parametrize("q", ["mixer", "mlp", "head", "unscoped", "optimizer", "backward"])
+def test_the_manifest_lists_a_share_by_scope_once_for_every_cell_of_its_kinds(q):
+    quantity = f"{q}_share_of_busy"
+    entries = {n: m for n, m in PER_LAYER.items() if n.partition(".")[0] == quantity}
+    kinds = ("train",) if q in ("optimizer", "backward") else ("train", "serve")      # a server has neither
+    assert {m["moves"] for m in entries.values()} == {KIND_OF[k] for k in kinds}
+    for m in entries.values():
+        assert m["unit"] == "fraction" and m["source"] == "device_trace", m["name"]
+        assert common.load_reader(m["name"]).__name__.endswith(quantity)             # one reader, whatever the split
+    for kind in kinds:
+        mine = {n.partition(".")[2]: m for n, m in entries.items() if m["moves"] == KIND_OF[kind]}
+        if set(mine) == {kind}:                                                       # folded
+            assert mine[kind]["workloads"] == CELLS[kind]
+            continue
+        assert set(mine) <= set(SPLITS_BEFORE_THE_FOLD[kind]), sorted(mine)
+        listed = [w for split in SPLITS_BEFORE_THE_FOLD[kind] if split in mine for w in mine[split]["workloads"]]
+        assert listed == CELLS[kind], (quantity, kind)      # every cell of the kind, once, in the manifest's order
+
+
+def test_no_quantity_is_listed_twice_for_a_cell():
+    for w in (w["name"] for w in MANIFEST["workloads"]):
+        quantities = [n.partition(".")[0] for n, m in PER_LAYER.items() if w in m.get("workloads", [w])]
+        assert len(quantities) == len(set(quantities)), (w, sorted(q for q in quantities if quantities.count(q) > 1))
+    assert len(PER_LAYER) == len(MANIFEST["per_layer"]) <= 96

@@ -2,8 +2,10 @@
 
 ``observability.events.span`` opens a ``jax.profiler.TraceAnnotation`` named
 ``thunder_tpu.<name>`` always, and writes the ring only where it did before
-(the compile pipeline always, serving under ``trace=True``).  A profiler
-session is the only switch: these tests start one over a few steps of the
+(the compile pipeline always, serving under ``trace=True``) and around what a
+process does once a program (``serve.compile``, a ``TrainStep``'s first call as
+``xla_compile``): a start-up is a timeline, a steady step writes nothing.  A
+profiler session is the only switch for the rest: these tests start one over a few steps of the
 micro engine and of a tiny ``TrainStep`` and read the ``.xplane.pb`` back.
 Everything runs on the micro model, on the CPU.
 """
@@ -226,23 +228,35 @@ class TestEngineSpans:
             assert (s["end"] <= h["start"]) == bool(s["args"]["ahead"])
         from chipbench import common, program_spans
 
-        reader = common.load_reader("decode_ahead_share.offline")
+        reader = common.load_reader("decode_ahead_share.serve")
         (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
         spans = program_spans.load(path)
         assert reader.read({"program_spans": spans}) == len(ahead) / len(decodes)
         for sp in spans:
             sp.args.pop("ahead", None)      # a program that gives the span no such argument, as this PR's parent
         assert reader.read({"program_spans": spans}) is None and reader.read({"program_spans": []}) is None
-        # no session's worth of ring entries: trace=False keeps the ring empty
-        assert events() == []
+        # no session's worth of ring entries: trace=False leaves the ring the first calls alone
+        assert {e["name"] for e in events()} == {"serve.compile"}
 
-    def test_no_session_and_no_trace_leaves_the_ring_empty(self, micro):
+    def test_no_trace_leaves_a_compile_pair_a_program_and_nothing_a_steady_step(self, micro, monkeypatch):
         cfg, params = micro
-        eng = _engine(cfg, params)
+        monkeypatch.setattr(engine_mod, "_program_cache", {})
+        eng = _engine(cfg, params, num_blocks=32)
         clear_events()
-        eng.run([{"prompt": np.arange(5, dtype=np.int32), "max_new_tokens": 3}])
+        prompt = np.arange(5, dtype=np.int32)
+        eng.run([{"prompt": prompt, "max_new_tokens": 56}])     # every bucket a context of 61 reaches, built on the way
+        ring = events()
+        # one pair a program built, on the process's own track, each closed before the next opens
+        assert [(e["ph"], e["name"]) for e in ring] == [("B", "serve.compile"), ("E", "serve.compile")] * len(eng._compile_log)
+        assert ([(e["args"]["kind"], e["args"]["bucket"]) for e in ring if e["ph"] == "B"]
+                == [(c["kind"], "{}x{}".format(*c["bucket"])) for c in eng._compile_log])
+        assert {(e["cat"], e["pid"]) for e in ring} == {("thunder_tpu", os.getpid())} and len(ring) >= 4
+        # fifty steps of the warm engine: not one event more
+        handle = eng.submit(prompt, max_new_tokens=56)
+        for _ in range(50):
+            eng.step()
+        assert not handle.done() and events() == ring
         eng.shutdown(drain=False)
-        assert events() == []
 
     def test_trace_true_puts_the_step_spans_in_the_ring_under_the_same_names(self, micro):
         cfg, params = micro
@@ -291,10 +305,22 @@ class TestTrainSpans:
         # a ring pair a step would evict the compile spans: the step stays out
         ring = {e["name"] for e in events()}
         assert "train.step" not in ring and "train.snapshot" in ring
+        # the first call of the step just built is a ring span of its own, after the build's
+        first_calls = [e for e in events() if e["name"] == "xla_compile"]
+        assert [(e["ph"], e.get("args")) for e in first_calls] == [("B", {"fn": "train_step"}), ("E", None)]
+        build_end = next(e["ts"] for e in events() if e["ph"] == "E" and e["name"] == "compile")
+        assert build_end <= first_calls[0]["ts"]
+        (in_profile,) = [s for s in prof.named("xla_compile") if s["args"].get("fn") == "train_step"]
+        assert first["start"] <= in_profile["start"] and in_profile["end"] <= first["end"]
+        # twenty more steps: nothing in the ring
+        before = events()
+        p, o = res.params, res.opt_state
+        for _ in range(20):
+            p, o, loss = step(p, o, *batch)
+        assert np.isfinite(float(loss)) and events() == before
 
 
-def test_compile_seconds_grow_after_a_fresh_jit():
-    compile_cache._register_listeners()     # what enable() registers, without a cache directory
+def test_compile_seconds_grow_after_a_fresh_jit(jax_stretches):
     before = compile_cache.stats()
     assert {"jaxpr_trace_s", "lower_s", "backend_compile_s"} <= set(before)
     x = jnp.ones((32, 32))
@@ -308,3 +334,36 @@ def test_compile_seconds_grow_after_a_fresh_jit():
     jax.jit(lambda a: jnp.tanh(a @ a) + 26.0)(x).block_until_ready()
     grown = compile_cache.stats()["backend_compile_s"] - again["backend_compile_s"]
     assert 0 < grown < 60
+
+
+# the six entries that split `setup_s`, each with its bucket of `chipbench/setup_spans.py`'s table
+SETUP_ENTRIES = {"setup_import_s": "import_s", "setup_prefill_programs_s": "prefill_programs_s",
+                 "setup_decode_programs_s": "decode_programs_s", "setup_step_programs_s": "step_programs_s",
+                 "setup_other_programs_s": "other_programs_s", "setup_unspanned_s": "unspanned_s"}
+
+
+@pytest.mark.parametrize("entry", sorted(SETUP_ENTRIES))
+def test_a_setup_reader_reads_its_bucket_of_a_hand_written_ring(entry, monkeypatch):
+    from chipbench import common, setup_spans
+    from thunder_tpu import observability as obs
+
+    doc = common.load_json("tests", "data", "setup_ring.json")
+    ring = doc["events"]
+    monkeypatch.setattr(obs, "events", lambda: ring)
+    reader = common.load_reader(entry)
+    ctx = {"t_process": doc["t_process"], "setup_s": doc["setup_s"]}
+    assert reader.read(ctx) == doc["expected"][SETUP_ENTRIES[entry]]
+    # every second of set-up is in one bucket: the six entries, the build's spans and the snapshot
+    table = setup_spans.of(ctx)
+    assert {k: table[k] for k in doc["expected"]} == doc["expected"]
+    assert sum(table[b] for b in SETUP_ENTRIES.values()) + table["pipeline_s"] + table["snapshot_s"] == doc["setup_s"]
+    m = next(m for m in common.manifest()["per_layer"] if m["name"] == entry)
+    assert (m["unit"], m["better"], m["source"], m["moves"]) == ("s", "lower", "program_span", "setup_s")
+    # no part sums: a ring that is full has lost its oldest events, and one without the
+    # `import` event is a program that keeps no such timeline (this PR's parent)
+    fresh = {"t_process": doc["t_process"], "setup_s": doc["setup_s"]}
+    monkeypatch.setattr(obs, "events", lambda: [e for e in ring if e["name"] != "import"])
+    assert reader.read(dict(fresh)) is None
+    monkeypatch.setattr(obs, "events", lambda: ring)
+    monkeypatch.setattr(obs, "event_buffer_capacity", lambda: len(ring))
+    assert reader.read(dict(fresh)) is None

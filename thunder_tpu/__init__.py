@@ -15,6 +15,11 @@ from __future__ import annotations
 import time
 from typing import Any, Callable, Sequence
 
+_T_IMPORT = time.perf_counter_ns()     # the start of the `import` event (this module's last lines)
+import jax  # noqa: F401  (first and on its own: the event says what of the import was JAX's)
+
+_T_JAX = time.perf_counter_ns()
+
 from thunder_tpu import clang  # noqa: F401
 from thunder_tpu import numpy  # noqa: F401  (registers the numpy langctx)
 
@@ -977,3 +982,10 @@ def serve(model_fn, params, cfg, **kwargs):
     from thunder_tpu.serving import serve as _serve
 
     return _serve(model_fn, params, cfg, **kwargs)
+
+
+# The package's own import as one complete event in the ring, on the ring's
+# clock: a process's start-up timeline (``export_chrome_trace``) begins here.
+observability.record_event(
+    "X", "import", {"jax_s": (_T_JAX - _T_IMPORT) / 1e9},
+    ts=_T_IMPORT / 1e3, dur=(time.perf_counter_ns() - _T_IMPORT) / 1e3)

@@ -696,6 +696,7 @@ class TrainStep:
             # the fw/bw split, transforms and lowering all happen in _build
             with span("compile", fn="train_step"):
                 self._cache[key] = self._build(params, opt_state, batch)
+            self._cache[key]["step_uncalled"] = True
         self._jitted = self._cache[key]["step"]
         return self._cache[key]
 
@@ -738,7 +739,15 @@ class TrainStep:
             self._calls += 1
             batch = self._prepare(batch)
             with self._mesh_context(), self._donation_ctx():
-                return self._get_jitted(params, opt_state, batch)(params, opt_state, *batch)
+                entry = self._get_entry(params, opt_state, batch)
+                if entry["step_uncalled"]:
+                    # the first call of a step just built: JAX traces the
+                    # whole step, lowers it and compiles or loads it here.
+                    # The name a fusion's first call has (executors/xlaex.py)
+                    entry["step_uncalled"] = False
+                    with span("xla_compile", fn="train_step"):
+                        return entry["step"](params, opt_state, *batch)
+                return entry["step"](params, opt_state, *batch)
 
     def grads(self, params, opt_state, *batch):
         """One micro step: ``(loss, grads)`` with no optimizer update — the

@@ -8,7 +8,11 @@ keyword or environment variable switches them: with no profiler session an
 annotation is inactive and costs about two microseconds.  The names:
 
 - compile pipeline (always also in the ring buffer below): ``compile``,
-  ``transform:*``, ``lower``, ``lower:*``, ``codegen``, ``xla_compile``;
+  ``transform:*``, ``lower``, ``lower:*``, ``codegen``, ``xla_compile``
+  (a fusion's first call, and [``fn="train_step"``] a ``TrainStep``'s);
+- start-up, in the ring alone, as complete events: ``import`` [``jax_s``]
+  and JAX's own ``jax.trace`` / ``jax.lower`` / ``jax.backend_compile``
+  [``fun_name``] for every program (``core/compile_cache.py``);
 - one serving engine step (``serving/engine.py``): ``serve.step`` [``step``,
   ``queued``, ``running``, ``t_ns``] with the children ``serve.harvest``
   (``serve.harvest.wait`` [``kind``, ``rows`` or ``rid``]: the host waits for
@@ -18,7 +22,8 @@ annotation is inactive and costs about two microseconds.  The names:
   ``serve.admit`` [``admitted``], ``serve.prefill_dispatch`` [``rid``,
   ``tokens``, ``bucket``, ``piece``], ``serve.gauges``, and
   ``serve.compile`` [``kind``, ``bucket``] around the first call of a
-  freshly built bucket program; ``serve.recover`` around a recovery;
+  freshly built bucket program; ``serve.recover`` around a recovery (these
+  two always also in the ring: no steady step opens them);
 - training: ``train.step`` [``step``] around ``TrainStep.__call__`` and
   ``train.snapshot`` [``bytes``] around ``train_loop``'s host snapshot.
 

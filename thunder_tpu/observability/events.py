@@ -12,17 +12,29 @@ as Chrome-trace / Perfetto JSON.
 
 Every stage of the compile pipeline (interpretation, each transform,
 lowering/claiming, codegen, XLA compile) is a span that always writes the
-ring.  The serving engine's step phases (``serve.step`` and its children)
-and the training step (``train.step``) pass ``ring=False`` unless request
-tracing is on (``tt.serve(trace=True)``): a ring pair every few
-milliseconds for ever would only evict the compile spans.  The serving
-plane (``observability/tracing.py``) also records *async* per-request
-lifecycle spans (``ph: "b"/"e"`` keyed by request id) into the same ring;
-each ``serve.step`` carries ``t_ns``, the ring's clock at its entry, so an
-operator can lay those on the profiler's timeline.  The oldest events drop
-first (an orphaned ``B`` from eviction is tolerated by Perfetto), so
-long-running processes never grow unbounded.  Nothing on ``tt.jit``'s
-per-call *dispatch* path opens a span.
+ring.  So does what a process does once a program or once at all, which
+makes its start-up a timeline: ``import`` (the package's own import, a
+complete event with ``jax_s``, the part that was JAX's), ``xla_compile``
+[``fn="train_step"``] around the first call of a ``TrainStep`` just built
+(the name a fusion's first call has), and one complete event for each
+stretch JAX itself timed, ``jax.trace`` / ``jax.lower`` /
+``jax.backend_compile`` [``fun_name``] (``core/compile_cache.py`` writes
+them from JAX's monitoring events, stamped on this ring's clock).  The
+serving engine's step phases (``serve.step`` and its children) and the
+training step (``train.step``) pass ``ring=False`` unless request tracing is
+on (``tt.serve(trace=True)``): a ring pair every few milliseconds for ever
+would only evict the compile spans.  Two of the engine's spans are the
+exception and write the ring always, because no steady step opens them:
+``serve.compile`` [``kind``, ``bucket``] around the first call of a program
+the engine built (without it the ``jax.*`` stretches of a serving process
+lie under nothing and nobody can say which bucket they were for), and
+``serve.recover``.  The serving plane (``observability/tracing.py``) also
+records *async* per-request lifecycle spans (``ph: "b"/"e"`` keyed by request
+id) into the same ring; each ``serve.step`` carries ``t_ns``, the ring's
+clock at its entry, so an operator can lay those on the profiler's timeline.
+The oldest events drop first (an orphaned ``B`` from eviction is tolerated
+by Perfetto), so long-running processes never grow unbounded.  Nothing on
+``tt.jit``'s per-call *dispatch* path opens a span.
 
 The ring capacity (``THUNDER_TPU_EVENT_BUFFER``) is re-read on every
 append, so changing it after import takes effect on the next recorded
@@ -92,6 +104,8 @@ def record_event(
     pid: int | None = None,
     tid: int | None = None,
     id: int | None = None,
+    ts: float | None = None,
+    dur: float | None = None,
 ) -> None:
     """Appends one Chrome-trace event (``ph``: "B"/"E"/"b"/"e"/"i"/"X"...)
     stamped with the monotonic clock in microseconds.  ``cat`` groups the
@@ -99,15 +113,20 @@ def record_event(
     ``"serving.*"`` = the serving plane); ``pid``/``tid`` default to the
     real process/thread but may name a synthetic display track; ``id`` keys
     async (``"b"``/``"e"``) span pairs — the serving tracer uses the
-    request id."""
+    request id.  A complete event (``"X"``) of a stretch somebody else
+    timed gives its own ``ts`` (its start on the same clock,
+    ``time.perf_counter_ns() / 1e3``) and ``dur``, both in microseconds; it
+    is appended when it ends, so the ring is not in ``ts`` order."""
     ev = {
         "ph": ph,
         "name": name,
         "cat": cat,
-        "ts": time.perf_counter_ns() / 1e3,
+        "ts": time.perf_counter_ns() / 1e3 if ts is None else ts,
         "pid": os.getpid() if pid is None else pid,
         "tid": threading.get_ident() if tid is None else tid,
     }
+    if dur is not None:
+        ev["dur"] = dur
     if id is not None:
         ev["id"] = id
     if args:

@@ -946,13 +946,13 @@ class ServingEngine:
                 worked = True
         return worked
 
-    def _span(self, name: str, **meta) -> span:
-        """A span of the step loop (``observability.events.span``): always an
-        annotation in a ``jax.profiler`` trace; in the event ring, on the
-        tracer's engine track, only under ``trace=True``."""
+    def _span(self, name: str, *, rare: bool = False, **meta) -> span:
+        """A span of the step loop (``observability.events.span``): always an annotation in a ``jax.profiler``
+        trace; in the event ring, on the tracer's engine track, under ``trace=True``.  A ``rare`` one (once a
+        program or a fault, never in a steady step) writes the ring always: the process's own track then."""
         tr = self._tracer
         if tr is None:
-            return span(name, ring=False, **meta)
+            return span(name, ring=rare, **meta)
         return span(name, track=tr.engine_track, **meta)
 
     def _compile_span(self, compiled: bool, kind: str, a: int, b: int):
@@ -961,7 +961,7 @@ class ServingEngine:
         or loads it.  Nothing around any later call."""
         if not compiled:
             return contextlib.nullcontext()
-        return self._span("serve.compile", kind=kind, bucket=f"{a}x{b}")
+        return self._span("serve.compile", rare=True, kind=kind, bucket=f"{a}x{b}")
 
     def _expire_deadlines(self) -> bool:
         with self._span("serve.expire"):
@@ -2860,7 +2860,7 @@ class ServingEngine:
         if self._flight is not None:
             self._flight.record("recover", cause=cause,
                                 rids=[r.rid for r in self.scheduler.running])
-        with self._span("serve.recover", cause=cause.get("type")):
+        with self._span("serve.recover", rare=True, cause=cause.get("type")):
             self._recover_until_sound()
         self.recoveries += 1
         self._retry_streak = 0

@@ -16,6 +16,8 @@ recorder's lane state.  Bucket sets are pinned small (tier-1 budget).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -371,67 +373,165 @@ def _ring(monkeypatch):
     return cfg, params, {**ENGINE, "max_batch": 3}
 
 
+def _window(monkeypatch):
+    """A sliding window of 6 over blocks of 2: a block leaves a row's window every
+    other step, so the window's count and the lengths' bound the chain together."""
+    cfg = llama.Config.from_name("tiny-llama-debug", **{**MICRO, "sliding_window": 6})
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    return cfg, params, dict(block_size=2, num_blocks=48, max_batch=3, cache_dtype=jnp.float32, temperature=0.7,
+                             **{**BUCKETS, "block_buckets": (16,)})
+
+
 KINDS = {"dense": _dense, "hybrid": _hybrid, "latent": _latent, "ring": _ring}
 AHEAD_REQUESTS = [(9, 7), (14, 5), (5, 9), (12, 6), (7, 4)]     # prompt, new tokens: five through three slots
+# under ``_window``: odd prompts, so the rows' blocks leave their windows at the same harvests, every other step
+WINDOW_REQUESTS = [(9, 7), (13, 6), (5, 9), (11, 6), (7, 4)]
 
 
-def _drive(eng, cfg):
-    """The requests of ``AHEAD_REQUESTS``, stepped to the end: tokens, finish
-    reasons and the key each request ended on."""
+def _backlog(eng, cfg, lengths=AHEAD_REQUESTS):
+    """``lengths`` submitted, each request with a key of its own."""
     rng = np.random.default_rng(21)
-    handles = [eng.submit(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), max_new_tokens=m,
-                          key=jax.random.PRNGKey(100 + i)) for i, (n, m) in enumerate(AHEAD_REQUESTS)]
-    eng.drain()
+    return [eng.submit(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), max_new_tokens=m,
+                       key=jax.random.PRNGKey(100 + i)) for i, (n, m) in enumerate(lengths)]
+
+
+def _drive(eng, cfg, lengths=AHEAD_REQUESTS):
+    """The requests of ``lengths``, stepped to the end: tokens, finish
+    reasons and the key each request ended on."""
+    return _results(_backlog(eng, cfg, lengths), eng)
+
+
+def _results(handles, eng=None):
+    """Tokens, finish reasons and the key each request ended on, after a drain."""
+    if eng is not None:
+        eng.drain()
     res = [h.result(drive=False) for h in handles]
     return ([tuple(r.tokens) for r in res], [r.finish_reason for r in res],
             [np.asarray(h._req.key).tolist() for h in handles])
 
 
+@functools.cache
+def _through(kind):
+    """One kind's backlog through three slots, once for the tests that read it:
+    the async engine stepped to the end (what it handed a successor while the
+    step past a row's end was on the device), then the synchronous one."""
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        cfg, params, opts = {**KINDS, "window": _window}[kind](monkeypatch)
+        lengths = WINDOW_REQUESTS if kind == "window" else AHEAD_REQUESTS
+        eng = tt.serve(None, params, cfg, goodput=True, **opts)
+        handles = _backlog(eng, cfg, lengths)
+        handed = {"blocks": 0, "slots": 0}
+        while eng.scheduler.queue or eng.scheduler.running:
+            held = {r.rid: (set(r.block_table), r.state_slot) for r in eng.scheduler.running}
+            eng.step()
+            rec = eng._inflight_decode
+            if rec is None or not rec["ending"]:
+                continue
+            # step k+1 is in flight, the ended row one of its rows
+            ended = [r.rid for r in rec["running"] if r.state != "running"]
+            assert len(ended) == rec["ending"] == 1 and eng._decode_state is None
+            blocks, slot = held[ended[0]]
+            for r in eng.scheduler.running:
+                if r.rid not in held:                       # admitted under that step
+                    handed["blocks"] += bool(blocks & set(r.block_table) - {0})
+                    handed["slots"] += bool(slot) and r.state_slot == slot
+        sync_eng = tt.serve(None, params, cfg, async_step=False, **opts)
+        out = {"cfg": cfg, "params": params, "opts": opts, "handed": handed, "hybrid": eng._hybrid,
+               "requests": [(h._req.prompt, h._req.max_new_tokens) for h in handles],
+               "served": _results(handles), "sync": _drive(sync_eng, cfg, lengths),
+               "stats": eng.stats(), "sync_stats": sync_eng.stats(), "steps": (eng.decode_steps, sync_eng.decode_steps),
+               "pool_clean": eng.pool.num_free == eng.pool.num_usable and eng.pool.n_retired <= 1}
+        eng.shutdown(), sync_eng.shutdown()
+        return out
+
+
 class TestDecodeAhead:
     @pytest.mark.parametrize("kind", list(KINDS))
-    def test_a_step_ahead_serves_what_the_synchronous_loop_serves(self, kind, monkeypatch):
+    def test_a_step_ahead_serves_what_the_synchronous_loop_serves(self, kind):
         """Tokens, finish reasons and the keys the requests end on are those of
         ``async_step=False``, for a dense, a hybrid (``sslots``), a latent and a
         ring engine; the async engine ran steps ahead, the synchronous none."""
-        cfg, params, opts = KINDS[kind](monkeypatch)
-        eng = tt.serve(None, params, cfg, **opts)
-        ahead = _drive(eng, cfg)
-        sync_eng = tt.serve(None, params, cfg, async_step=False, **opts)
-        sync = _drive(sync_eng, cfg)
-        assert ahead == sync
-        st = eng.stats()["decode_ahead"]
-        assert 0 < st["ahead"] < st["dispatches"] == eng.decode_steps
+        ran = _through(kind)
+        assert ran["served"] == ran["sync"]
+        st = ran["stats"]["decode_ahead"]
+        assert 0 < st["ahead"] < st["dispatches"] == ran["steps"][0]
         assert st["share"] == st["ahead"] / st["dispatches"]
-        assert sync_eng.stats()["decode_ahead"] == {"dispatches": sync_eng.decode_steps, "ahead": 0, "share": 0.0}
-        assert eng.pool.num_free == eng.pool.num_usable and eng.pool.n_retired <= 1
-        eng.shutdown(), sync_eng.shutdown()
+        assert ran["sync_stats"]["decode_ahead"] == {"dispatches": ran["steps"][1], "ahead": 0, "share": 0.0}
+        assert ran["pool_clean"]
 
-    def test_ahead_only_in_steps_that_neither_end_nor_admit_a_row(self, micro):
-        """A backlog through three slots, step by step: a step that dispatched
-        ahead finished no request and left the batch as it was; the last step
-        before a row's end by length is never ahead; and the count is what the
-        lengths say (every chained step but the last of each chain)."""
+    def test_a_step_ahead_may_see_a_row_end_at_its_harvest_and_none_join(self, micro):
+        """A backlog through three slots, step by step.  A step that dispatched
+        ahead left the batch as it was (nobody joined); the harvest under it
+        finished exactly the rows the dispatch knew were past their end
+        (``ending``: here one or none), and then the chain is gone: no second
+        step past an end.  A chain sends ahead what its rows' lengths say: the
+        steps before the first end, and one more where a row outlives it."""
         cfg, params = micro
-        eng = _engine(cfg, params, max_batch=3)
+        eng = _engine(cfg, params, max_batch=3, goodput=True)
         rng = np.random.default_rng(22)
         handles = [eng.submit(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), max_new_tokens=m)
                    for n, m in AHEAD_REQUESTS]
-        ahead_steps = 0
+        chains = []                     # a chain: [steps ahead its rows' lengths allow, steps ahead it sent]
         while eng.scheduler.queue or eng.scheduler.running:
-            before = eng.decode_ahead_steps
+            ahead, steps = eng.decode_ahead_steps, eng.decode_steps
             done = sum(h.done() for h in handles)
             rec = eng._inflight_decode
             rids = None if rec is None else [r.rid for r in rec["running"]]
             eng.step()
-            if eng.decode_ahead_steps > before:
-                ahead_steps += 1
-                assert sum(h.done() for h in handles) == done          # no row ended at this harvest
-                assert [r.rid for r in eng._inflight_decode["running"]] == rids    # and none joined
-                assert eng._decode_state["ahead"] >= 0
-        st = eng.stats()["decode_ahead"]
-        assert st["ahead"] == ahead_steps > 0
-        assert tt.metrics_snapshot()["serving.steps.decode_ahead"] >= ahead_steps
+            rec = eng._inflight_decode
+            if eng.decode_ahead_steps > ahead:
+                chains[-1][1] += 1
+                assert [r.rid for r in rec["running"]] == rids                   # none joined
+                assert sum(h.done() for h in handles) - done == rec["ending"] <= 1   # who ended was known to
+                assert (eng._decode_state is None) == bool(rec["ending"])        # one step past, never two
+            elif eng.decode_steps > steps:
+                assert not rec["steady"] and rec["ending"] == 0                  # a turnover: a rebuild
+                left = [r.max_new_tokens - len(r.generated) - 1 for r in rec["running"]]
+                chains.append([min(left) + (max(left) > min(left)), 0])
+                assert eng._decode_state["ahead"] == chains[-1][0]
+            else:
+                assert sum(h.done() for h in handles) - done in (0, 2)           # the last two rows end together
+        assert all(sent == allowed for allowed, sent in chains)
+        # r1 (5 new tokens) ends first, r0 and r2 while their successors' chains are one step old,
+        # r3 and r4 together: nobody outlives them, no step runs for nobody
+        assert chains == [[4, 4], [1, 1], [1, 1], [0, 0]]
+        st = eng.stats()
+        assert st["decode_ahead"] == {"dispatches": 10, "ahead": 6, "share": 0.6, "through_end": 3}
+        assert st["decode_rebuild"]["rebuilds"] == len(chains) == 1 + st["decode_ahead"]["through_end"]
+        assert st["goodput"]["waste"]["dead_scan_row"] == 3
+        assert tt.metrics_snapshot()["serving.steps.decode_ahead"] >= 6
         assert all(h.result(drive=False).finish_reason == "length" for h in handles)
+
+    @pytest.mark.parametrize("kind", [*KINDS, "window"])
+    def test_a_row_that_ends_by_length_costs_one_dead_row_step_and_one_rebuild(self, kind):
+        """The backlog through three slots, for each kind of thing a dead row-step
+        writes: K/V blocks, a state slot, a ring under a window, a latent table,
+        blocks a window sinks.  While the step past a row's end is on the device
+        the row's blocks and its state slot are the successor's already (the pool
+        hands the last freed out first); the tokens are the synchronous loop's
+        all the same, and the two successors' solo ``generate()``'s; every dead
+        row-step is one the host knew of, and a finished row costs one rebuild
+        (the step its successor joins), not two."""
+        ran = _through(kind)
+        assert ran["served"] == ran["sync"] and ran["pool_clean"]
+        for i in (3, 4):                                    # who was handed a dead row's blocks and slot
+            p, m = ran["requests"][i]
+            solo = gen.generate(ran["params"], p[None], ran["cfg"], m, T_max=64, cache_dtype=jnp.float32,
+                                temperature=ran["opts"].get("temperature", 0.0), key=jax.random.PRNGKey(100 + i))
+            assert ran["served"][0][i] == tuple(np.asarray(solo)[0].tolist()), i
+        st = ran["stats"]
+        through = st["decode_ahead"]["through_end"]
+        assert st["goodput"]["waste"].get("dead_scan_row", 0) == through
+        if kind == "window":
+            # the harvest of every other step frees a block and stops the chain, whatever the
+            # lengths say: one end of the three falls on a step between, and goes through
+            assert st["decode_ahead"] == {"dispatches": 11, "ahead": 2, "share": 2 / 11, "through_end": 1}
+        else:
+            assert st["decode_ahead"] == {"dispatches": 10, "ahead": 6, "share": 0.6, "through_end": 3}
+            # r1, r0 and r2 each end under a step ahead and cost the rebuild their successor
+            # joins at (r2 has none: the batch shrinks); r3 and r4 end together, nobody after them
+            assert st["decode_rebuild"]["rebuilds"] == 1 + through
+            assert ran["handed"] == {"blocks": 2, "slots": 2 if ran["hybrid"] else 0}
 
     def test_a_lone_request_runs_every_step_but_its_first_ahead(self, micro):
         """One request of 10 new tokens: token 0 is the prefill's, nine decode

@@ -13,6 +13,7 @@ the compiled-program set byte-identical (module-cache assertion).
 """
 from __future__ import annotations
 
+import collections
 import json
 
 import jax
@@ -556,6 +557,59 @@ class TestFaultsWithAStepAhead:
         if point == FP_HARVEST:
             assert seen[0][1]                              # the record ahead was in flight, and was discarded
         assert eng.decode_ahead_steps > seen[0][0]         # and the engine ran ahead again afterwards
+        assert _pool_clean(eng)
+
+    @pytest.mark.parametrize("point,kind", [
+        (FP_DECODE, "oom"),          # the dispatch ahead through the end refuses, the ending step still in flight
+        (FP_SCATTER, "fail"),        # it fails past the donation
+        (FP_HARVEST, "hang"),        # the ending step's harvest fails, the step past the end on the device
+    ])
+    def test_a_fault_around_a_dispatch_ahead_through_a_rows_end_loses_neither_record(self, micro, point, kind):
+        """The twin at an ending step: P0's last step is in flight and the
+        dispatch ahead carries P0 as a dead row beside P1.  A fault inside that
+        dispatch leaves the ending step's record where it was, one in the
+        harvest finds both; recovery discards what is in flight, P0's last token
+        and P1's are derived again, and both streams are the fault-free ones."""
+        cfg, params = micro
+        lengths = ((P0, 5, 7), (P1, 10, 8))
+
+        def submit(eng):
+            return [eng.submit(p, max_new_tokens=n, key=jax.random.PRNGKey(k)) for p, n, k in lengths]
+
+        # the fault-free run, its arrivals at each point counted: where the step ahead through the end falls
+        eng = _engine(cfg, params)
+        arrivals = collections.Counter()
+        eng._fault_point = lambda pt, rids=(): arrivals.update([pt])
+        hs, at = submit(eng), None
+        while not all(h.done() for h in hs):
+            before = dict(arrivals)
+            eng.step()
+            rec = eng._inflight_decode
+            if rec is not None and rec["ending"]:
+                assert at is None                              # once: P1 ends alone, nobody outlives it
+                at = before[point] + 1                         # the turn's first arrival: the dispatch, or step k's harvest
+        ref = [h.result(drive=False).new_tokens for h in hs]
+        assert at is not None and eng.stats()["decode_ahead"]["through_end"] == 1
+
+        eng = _engine(cfg, params, fault_plan=FaultPlan(specs=[FaultSpec(point=point, kind=kind, at=at)]))
+        seen = []
+        recover = eng._recover
+
+        def watched(cause):
+            rec = eng._inflight_decode
+            seen.append((rec is not None and rec["ending"], [len(r.generated) for r in eng.scheduler.running]))
+            return recover(cause)
+
+        eng._recover = watched
+        hs = submit(eng)
+        eng.drain()
+        res = [h.result(drive=False) for h in hs]
+        assert [r.new_tokens for r in res] == ref and all(r.finish_reason == "length" for r in res)
+        assert eng.recoveries == 1 and len(seen) == 1
+        ending, generated = seen[0]
+        # P0 stood one token from its end: the record in flight was its last step's
+        # (a fault in the dispatch), or the step past it with P0 dead in it (a fault in the harvest)
+        assert generated == [4, 4] and ending == (1 if point == FP_HARVEST else 0)
         assert _pool_clean(eng)
 
     def test_a_poison_row_named_by_the_dispatch_ahead_is_quarantined_alone(self, micro):

@@ -42,7 +42,7 @@ SERVE_SPANS = {
     "serve.harvest.wait": (("kind",), "serve.harvest"),
     "serve.harvest.emit": ((), "serve.harvest"),
     "serve.expire": ((), "serve.step"),
-    "serve.decode_dispatch": (("rows", "bucket", "steady", "ahead"), "serve.step"),
+    "serve.decode_dispatch": (("rows", "bucket", "steady", "ahead", "ending"), "serve.step"),
     "serve.decode_dispatch.call": ((), "serve.decode_dispatch"),
     "serve.admit": (("admitted",), "serve.step"),
     "serve.prefill_dispatch": (("rid", "tokens", "bucket", "piece"), "serve.step"),
@@ -66,10 +66,11 @@ def _engine(cfg, params, **kw):
     return tt.serve(None, params, cfg, **kw)
 
 
-def _submit(eng, cfg, n=3, max_new=4):
+def _submit(eng, cfg, max_new=(4, 6, 5)):
+    """Three prompts of 2, 5 and 8 tokens through two slots: the first row ends with the second standing."""
     rng = np.random.default_rng(7)
-    return [eng.submit(rng.integers(0, cfg.vocab_size, (2 + 3 * i,)).astype(np.int32),
-                       max_new_tokens=max_new) for i in range(n)]
+    return [eng.submit(rng.integers(0, cfg.vocab_size, (2 + 3 * i,)).astype(np.int32), max_new_tokens=m)
+            for i, m in enumerate(max_new)]
 
 
 class Profile:
@@ -222,6 +223,12 @@ class TestEngineSpans:
         ahead = [s for s in decodes if s["args"]["ahead"]]
         assert {s["args"]["ahead"] for s in decodes} <= {0, 1} and bool(ahead) == async_step
         assert len(ahead) == eng.decode_ahead_steps and all(s["args"]["steady"] for s in ahead)
+        # ending: the rows a step ahead carries past their end by length (dead row-steps the
+        # host knew of); none in a steady step, nor in any step of the synchronous loop
+        through = [s for s in decodes if s["args"]["ending"]]
+        assert all(s["args"]["ahead"] and s["args"]["ending"] >= 1 for s in through)
+        assert len(through) == eng.stats()["decode_ahead"].get("through_end", 0) and bool(through) == async_step
+        assert any(s["args"]["ending"] == 0 for s in ahead) or not async_step
         for s in decodes if async_step else ():
             step = next(p for p in step_spans if p["start"] <= s["start"] and s["end"] <= p["end"])
             (h,) = [h for h in prof.named("serve.harvest") if step["start"] <= h["start"] <= step["end"]]

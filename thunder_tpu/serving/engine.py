@@ -42,15 +42,26 @@ The drive loop is an explicit, threadless **event loop** with two lanes
   only host block, measured into ``serving.decode.stall_s`` and the
   ``serving.step.overlap_frac`` gauge).  It keeps **one decode step
   ahead** while the batch is steady: where host state alone says that step
-  *k+1* runs on the batch of the in-flight step *k* (no row writes its last
-  position at *k*, no window lets blocks go, no constraint mask, deadline or
-  prefill piece is due, the decode-ready rows are the chain's:
-  :meth:`ServingEngine._ahead_batch`), *k+1* is dispatched from the chained
-  device state **before** *k* is harvested, so the fetch of *k*'s tokens,
-  the emit walk and the stream callbacks run under the device and not
-  beside it.  A turnover step (a row ends or joins) keeps the order above;
-  ``stats()["decode_ahead"]`` and the ``ahead`` argument of the
-  ``serve.decode_dispatch`` span say how often each happened;
+  *k+1* runs on the batch of the in-flight step *k* (no window lets blocks
+  go, no constraint mask, deadline or prefill piece is due, the
+  decode-ready rows are the chain's: :meth:`ServingEngine._ahead_batch`),
+  *k+1* is dispatched from the chained device state **before** *k* is
+  harvested, so the fetch of *k*'s tokens, the emit walk and the stream
+  callbacks run under the device and not beside it.  The chain goes
+  **through a row's end by length**: where *k* is the step at which a row
+  writes its last position (the host knows it from ``stop``) and another
+  row of the chain outlives it, *k+1* still leaves ahead, on the chain's
+  rows, the ended row among them as one dead row-step
+  (``dead_scan_row``); the row's finish, its blocks' and slot's return
+  and the client's callback run under *k+1*, and the harvest of *k* drops
+  the chain, so the step its successor joins is the one turnover step a
+  finished row costs.  Never a step for nobody (a chain whose every row
+  ends at *k* stops there), never two steps past an end, never a token
+  for the dead row (nothing emitted, its key and ``pos`` where the end
+  left them).  A turnover step (a row joins, or anything else changed)
+  keeps the order above; ``stats()["decode_ahead"]`` and the ``ahead``
+  and ``ending`` arguments of the ``serve.decode_dispatch`` span say how
+  often each happened;
 - the **prefill lane** splits prompts longer than ``prefill_chunk`` into
   block-aligned pow-2 chunks (program kind ``prefill_chunk``, bounded by
   the same ``_table_widths``/bucket accounting) and dispatches at most one
@@ -700,6 +711,7 @@ class ServingEngine:
         # drive-loop accounting (mirrored into the registry as it changes)
         self.decode_steps = 0
         self.decode_ahead_steps = 0     # of them, dispatched before the harvest of the step before
+        self.decode_through_end_steps = 0   # of those, one step past a row's end by length
         self.prefill_runs = 0
         self.prefill_fresh_runs = 0     # of them, whole prompts at position 0
         self.chunk_runs = 0
@@ -1005,7 +1017,14 @@ class ServingEngine:
            (:meth:`_ahead_batch`), it is dispatched first, from the chained
            device state: the device goes from step *k* straight into *k+1*
            while the host does phases 1-2 for step *k*, and phase 3 is
-           skipped.  Any other step (a turnover) keeps the order below;
+           skipped.  That holds through a row's end by length: where *k* is
+           some row's last step and another row outlives it, *k+1* goes out
+           on the chain's rows with the ended row as one dead row-step, and
+           phases 1, 2 and 4 (the row's finish, its successor's admission
+           and prefill) run under it; the harvest drops the chain, so the
+           next turn is the one turnover a finished row costs.  Never a step
+           for nobody, never two steps past an end, never a token for the
+           dead row.  Any other step (a turnover) keeps the order below;
         1. **harvest** — materialize the previous step's in-flight decode
            tokens and prefill pieces (stream callbacks, finishes, window
            expiry land here, one device-latency late but in order);
@@ -1313,6 +1332,9 @@ class ServingEngine:
             "decode_ahead": {
                 "dispatches": self.decode_steps, "ahead": self.decode_ahead_steps,
                 "share": self.decode_ahead_steps / self.decode_steps if self.decode_steps else 0.0,
+                # of the steps ahead, those one step past a row's end by length:
+                # there once one has gone (as a cause of the ledger's ``waste``)
+                **({"through_end": self.decode_through_end_steps} if self.decode_through_end_steps else {}),
             },
             # rebuilds of the decode chain's inputs: how many carried nothing
             # over (the full build), and the rows written from Python against
@@ -2001,13 +2023,17 @@ class ServingEngine:
                     # harvest may drop
                     rec["parked"] = parked
                     self.decode_ahead_steps += 1
+                    self.decode_through_end_steps += rec["ending"] > 0
                     self._m_steps_ahead.inc()
             # steady: last step's device outputs were this step's inputs;
             # ahead: dispatched before the host had last step's tokens;
+            # ending: of its rows, those past their end by length (a step
+            # ahead through an end: dead row-steps the host knew of);
             # written: the rows a rebuild wrote from Python (the others it
             # carried over from the build before)
             sp.set(rows=len(rec["running"]), bucket="{}x{}".format(*rec["bucket"]),
-                   steady=rec["steady"], ahead=int(ahead), written=rec.get("written", 0))
+                   steady=rec["steady"], ahead=int(ahead), ending=rec.get("ending", 0),
+                   written=rec.get("written", 0))
             if self.async_step:
                 self._inflight_decode = rec
         if not self.async_step:
@@ -2027,22 +2053,28 @@ class ServingEngine:
 
         - a decode record is in flight and the chain it left stands
           (``_decode_state``; a speculative round leaves none);
-        - the chain has steps left before a row of it writes its last
-          position or a window lets blocks go at the harvest, and no row of
-          it is constrained (``ahead``: the least of what each row allows,
-          reduced from per-row arrays at the chain's rebuild,
+        - the chain has steps left (``ahead``: the least of what each row
+          allows, reduced from per-row arrays at the chain's rebuild,
           :meth:`_ahead_steps`, and counted down a step since: no walk over
-          the rows here), nor runs ``decode_steps=N``;
+          the rows here): before a window lets blocks go at the harvest, and
+          up to **one step past the first end by length** where a row of the
+          chain outlives that end; no row of it is constrained, nor runs
+          ``decode_steps=N``;
         - no prefill piece is in flight whose harvest would change the
           batch, and no row's deadline has passed;
         - the decode-ready rows and their buckets are the chain's (an
           eviction, a resumed row or a quarantine changes them).
 
-        A row that ends at step *k* unseen (``eos_id``) makes step *k+1*
-        one dead row-step (``dead_scan_row``) and drops the chain at the
-        harvest, so the step after is a turnover step: its dispatch rebuilds
-        the chain's inputs, carrying over the rows that stand
-        (:meth:`_decode_inputs`)."""
+        A row that ended at step *k* makes step *k+1* one dead row-step
+        (``dead_scan_row``): the host knew it beforehand (by length: the
+        ``ending`` rows of the dispatch, ``stats()["decode_ahead"]
+        ["through_end"]``) or not (``eos_id``); either way the harvest of
+        *k* drops the chain, so no chain runs two steps past an end, the
+        dead row's token is never emitted, its key and ``pos`` stay where
+        the end left them, and the step after is a turnover step: its
+        dispatch rebuilds the chain's inputs, carrying over the rows that
+        stand (:meth:`_decode_inputs`).  A chain whose every row ends at
+        *k* has no step ahead left at *k*: no step runs for nobody."""
         st = self._decode_state
         if (self._inflight_decode is None or st is None or st["ahead"] <= 0
                 or self._inflight_prefill):
@@ -2157,21 +2189,28 @@ class ServingEngine:
     def _ahead_steps(self, host: dict) -> tuple[int, float | None]:
         """How many steps of a chain built from ``host`` may be dispatched
         ahead of the harvest before theirs (:meth:`_ahead_batch`), and the
-        first deadline among its rows: the least over the rows of what each
-        allows.  A row allows steps until the harvest that ends it by length
-        (``stop`` is its last write, ``host_pos`` this step's) or frees its
-        first live block under a window; none where the next dispatch needs a
-        value from the host (a constrained row, ``decode_steps=N``)."""
+        first deadline among its rows.  By length (``stop`` is a row's last
+        write, ``host_pos`` this step's): the steps up to the first that ends
+        a row, and **one more where a row outlives that end**: that step
+        leaves with the ended row as a dead row-step, while the harvest that
+        finishes the row runs under it.  One and never two: that harvest drops the
+        chain.  None more where every row ends there: no step for nobody.
+        Combined with, not in place of, what else bounds the chain: under a
+        window the steps until a harvest frees a row's first live block, and
+        none at all where the next dispatch needs a value from the host (a
+        constrained row, ``decode_steps=N``)."""
         n = host["n"]
         pos = host["host_pos"][:n].astype(np.int64)
-        ahead = min(1 << 30 if self.n_decode_steps == 1 else 0, int((host["stop"][:n] - pos).min()))
+        left = host["stop"][:n] - pos                      # steps before the one that ends each row
+        first = int(left.min())
+        ahead = first + int((left > first).any()) if self.n_decode_steps == 1 else 0
         W = self.scheduler.sliding_window
         if W is not None:
             # harvest m of the chain sees pos = wpos + m + 1 and frees the
             # first live block once (pos + 1 - W) // bs passes it
             live = host["live"]
-            left = np.maximum(0, (live + 1) * self.pool.block_size + W - pos - 2)
-            ahead = min(ahead, int(np.where(live >= 0, left, ahead).min()))
+            free_at = np.maximum(0, (live + 1) * self.pool.block_size + W - pos - 2)
+            ahead = min(ahead, int(np.where(live >= 0, free_at, ahead).min()))
         if host["constrained"].any():
             ahead = 0
         deadline = float(host["deadline"].min())
@@ -2252,11 +2291,16 @@ class ServingEngine:
                 (kind, Bb, nbb), prog, ex,
             )
         self._note_attn_step()
+        # rows this step writes past their last position: none but in the
+        # step ahead through an end (_ahead_steps), where they hold no request
+        # any more and count for none in the batch's occupancy
+        past = host["stop"][:host["n"]] < host_pos[:host["n"]]
+        ending = int(past.sum())
         if self._attended is not None:
             # the keys a layer of each kind attends this step (one token a row: rings refuse
             # decode_steps > 1), over the rows that hold a request: a window layer the last
             # layer_window, the others all
-            seen = np.asarray(host_pos, dtype=np.int64)[:len(running)] + 1
+            seen = np.asarray(host_pos, dtype=np.int64)[:len(running)][~past] + 1
             self._attended["full_attention"] += int(seen.sum())
             self._attended["sliding_attention"] += int(np.minimum(seen, self.cfg.layer_window).sum())
             self._attended["steps"] += 1
@@ -2312,14 +2356,15 @@ class ServingEngine:
                "pkind": kind, "compiled": compiled, "step": self.decode_steps,
                "steady": steady, "host": host,
                "written": 0 if steady else host["written"],
+               "ending": ending,
                "epochs": [r.preemptions for r in running],
                "t_disp": time.perf_counter(), "t_clock": sch.clock()}
         if N > 1:
             rec.update(multi=N, nxt=ys_tok, emit=ys_emit, new_keys=keys_f)
         self.decode_steps += 1
-        self._occupancy_sum += len(running)
+        self._occupancy_sum += len(running) - ending
         self._m_steps_decode.inc()
-        self._m_occupancy.observe(len(running))
+        self._m_occupancy.observe(len(running) - ending)
         return rec
 
     def _note_attn_step(self) -> None:

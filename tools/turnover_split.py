@@ -4,8 +4,9 @@
     JAX_PLATFORMS=cpu python3 tools/turnover_split.py chiprun_out/traces/<cell>/<file>.xplane.pb [repo root]
 
 A decode dispatch is a ``turnover`` where it rebuilt the chain's inputs
-(``steady`` = 0), ``ahead`` where it went out before the harvest, else
-``steady``.  Prints, as JSON: every span's count, median and total with the
+(``steady`` = 0), ``ahead`` where it went out before the harvest
+(``through`` where a row of it was past its end by length: ``ending`` > 0,
+PR 52), else ``steady``.  Prints, as JSON: every span's count, median and total with the
 kind of the dispatch it is or lies under; the device's idle seconds by the
 innermost span over each gap's middle (the two clocks can differ by a
 millisecond or two: trust the total, not the split); a step's host
@@ -21,7 +22,11 @@ import sys
 
 def _kind(s):
     if s.name == "serve.decode_dispatch":
-        return "turnover" if not int(s.args.get("steady", 0)) else ("ahead" if int(s.args.get("ahead", 0)) else "steady")
+        if not int(s.args.get("steady", 0)):
+            return "turnover"
+        if not int(s.args.get("ahead", 0)):
+            return "steady"
+        return "through" if int(s.args.get("ending", 0)) else "ahead"
     return None
 
 

@@ -170,6 +170,7 @@ _LONGEST_FIRST = (
     "test_hybrid_moe.py", "test_sequence_parallel.py", "test_pallas_tpu_lowering.py", "test_ring_attention.py",
     "test_train_cli.py", "test_mla_serving.py", "test_paged_attention.py", "test_shortconv_serving.py",
     "test_hybrid_decoder_serving.py", "test_pallas.py", "test_paged_walk.py", "test_causal_conv.py", "test_hybrid_serving.py",
+    "test_hc_serving.py",
 )
 
 

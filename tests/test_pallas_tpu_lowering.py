@@ -667,17 +667,22 @@ def _mosaic_module(lowered_text: str) -> str:
         return str(ir.Module.parse(base64.b64decode(body)))
 
 
-@pytest.mark.parametrize("kernel", ["mla_paged_decode", "mla_paged_decode/128heads", "mla_latent_write", "mla_latent_write_masked"])
+@pytest.mark.parametrize("kernel", ["mla_paged_decode", "mla_paged_decode/128heads", "mla_paged_decode/32heads",
+                                    "mla_latent_write", "mla_latent_write_masked"])
 def test_the_latent_kernels_compile_at_the_cells_shapes(kernel, tpu_sharding, monkeypatch):
     """64 rows, a table 640 blocks wide, the cell's arena of 32768 blocks of 16
     rows of 640 (576 padded to whole lane tiles), 64 heads (and 128, a whole pass
     of the matrix unit): the walk's copies are whole-tile slabs, the table fits
     the scalar memory, nothing of the arena is copied; the two chunk buffers are
     what ``mla_chunk_keys`` derives and fit the budget it states; the rows are
-    held and the queries streamed, so the module transposes nothing."""
+    held and the queries streamed, so the module transposes nothing.  ``32heads``:
+    the Xing4.0 cell's walk, 32 rows of 32 heads over a table 524 blocks wide and
+    an arena of 16,784 blocks (60 operations a byte: bound by bytes alone)."""
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     kernel, _, heads = kernel.partition("/")
-    rows, width, pool, layers, nh, W, dc = 64, 640, 32768, 6, 128 if heads else 64, 640, 512
+    rows, width, pool, layers, nh, W, dc = 64, 640, 32768, 6, {"": 64, "128heads": 128, "32heads": 32}[heads], 640, 512
+    if heads == "32heads":
+        rows, width, pool = 32, 524, 16784
     arena, tab, pos = ((pool, layers, 1, BS, W), BF), ((rows, width), I32), ((rows,), I32)
     if kernel == "mla_paged_decode":
         fn = functools.partial(px.mla_paged_decode, layer=layers - 1, dc=dc, scale=0.13)
@@ -720,15 +725,20 @@ def test_a_row_of_576_is_refused_by_the_walks_copies(tpu_sharding, monkeypatch):
                             jnp.zeros((2, 4), I32), jnp.zeros((2,), I32), layer=0, dc=512, scale=1.0)
 
 
+HC_CELL = "xing4-serve-1chip.offline-digest"
+# cell -> (a prefill bucket, decode rows, table width)
+MLA_PROGRAMS = {MLA_CELL: (4096, 64, 640), HC_CELL: (8192, 32, 524)}
+
+
 @functools.cache
-def _mla_engine():
+def _mla_engine(cell=MLA_CELL):
     """The cell's engine at its published widths, the dense layer and one
     expert layer, over weights that are shapes alone."""
     import thunder_tpu as tt
     from chipbench import common
     from thunder_tpu.models import llama
 
-    _, config, mix = common.open_cell(MLA_CELL)
+    _, config, mix = common.open_cell(cell)
     arch = common.load_module("models", config["arch"])
     hf = {**config, "num_hidden_layers": 2}
     cfg = llama.Config(**arch.program_config(hf))
@@ -737,27 +747,33 @@ def _mla_engine():
 
 
 @pytest.mark.parametrize("kind", ["prefill_fresh", "decode_paged"])
-def test_the_latent_cells_programs_lower_to_their_kernels(kind, tpu_sharding, monkeypatch):
+@pytest.mark.parametrize("cell", sorted(MLA_PROGRAMS), ids=lambda c: c.partition("-")[0])
+def test_the_latent_cells_programs_lower_to_their_kernels(cell, kind, tpu_sharding, monkeypatch):
     """A whole prompt's prefill attends its expanded keys through ``_flash_fwd``
     (heads of 192 beside values of 128, padded with zeros to one size) and
     sorts its rows through ``moe_grouped_mm``; a decode step calls
     ``mla_paged_decode`` once a layer, ``moe_grouped_mm`` for the expert layer
-    and lands its rows through one ``mla_latent_write``; no arena is gathered."""
+    and lands its rows through one ``mla_latent_write``; no arena is gathered.
+    The Xing4.0 cell: 32 heads, all 64 experts of 3584 x 1024 a layer, the stream
+    four wide under hyper-connections (plain XLA between the kernels), its 8,192
+    bucket and its 32-row step."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
-    cfg, params, eng = _mla_engine()
+    if cell == HC_CELL:
+        monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)      # a v5e's, which the chip reports: what it runs
+    cfg, params, eng = _mla_engine(cell)
+    Tb, rows, width = MLA_PROGRAMS[cell]
     sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=tpu_sharding)  # noqa: E731
     one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
     weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
     before = dict(px.stats)
     if kind == "prefill_fresh":
-        Tb = 4096
         prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
         args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)))
     else:
-        prog = eng._build_decode_paged(64, 640)
-        args = (weights, one((64,)), one((64,)), one((64, 640)), arenas, one((64, 2), jnp.uint32), {}, one((64,)),
-                one((4,), F32))         # the expert share's running sums (PR 45)
+        prog = eng._build_decode_paged(rows, width)
+        args = (weights, one((rows,)), one((rows,)), one((rows, width)), arenas, one((rows, 2), jnp.uint32), {},
+                one((rows,)), one((4,), F32))         # the expert share's running sums (PR 45)
     lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
     text = lowered.as_text()
     claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
@@ -766,6 +782,7 @@ def test_the_latent_cells_programs_lower_to_their_kernels(kind, tpu_sharding, mo
     if kind == "prefill_fresh":
         assert claimed("direct") == cfg.n_layer and 'kernel_name = "_flash_fwd"' in text
         assert px.flash_schedule["grid_steps"] > 0
+        assert f"tensor<1x{cfg.n_head}x{Tb}x{cfg.head_size}xbf16>" in text      # q, k and the padded v a head of 192
         assert {int(m) for m in re.findall(rf"tensor<1x(\d+)x{cfg.padded_vocab_size}xf32>", text)} == {1}
     else:
         assert claimed("mla_decode") == cfg.n_layer
@@ -954,6 +971,9 @@ GMM_SHAPES = {
     "lfm2/2048x1792/prefill": (2048, 1792, 128, 104, 32, True), "lfm2/1792x2048/prefill": (1792, 2048, 128, 104, 32, True),
     "axk1/7168x2048/decode": (7168, 2048, 16, 16, 12, True), "axk1/7168x2048/prefill": (7168, 2048, 128, 48, 12, True),
     "axk1/2048x7168/prefill": (2048, 7168, 128, 48, 12, True),
+    # Xing4.0: all 64 experts of a layer, 2 rows an expert a decode step, 512 a prompt of 8,192
+    "xing4/3584x1024/decode": (3584, 1024, 16, 72, 64, True), "xing4/3584x1024/prefill": (3584, 1024, 128, 320, 64, True),
+    "xing4/1024x3584/prefill": (1024, 3584, 128, 320, 64, True),
     # a matrix the VMEM asked for does not hold twice: column blocks inside a tile, under the default limit
     "unknown_vmem/7168x2048/decode": (7168, 2048, 16, 16, 12, False),
     "unknown_vmem/2048x7168/prefill": (2048, 7168, 128, 48, 12, False),

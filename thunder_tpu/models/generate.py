@@ -52,6 +52,9 @@ __all__ = [
     "ring_block_shape",
     "mla_mixer",
     "mla_latent",
+    "hc_maps",
+    "hc_open",
+    "hc_close",
     "moe_share_mlp",
     "moe_row_tile",
     "route_sigmoid_group",
@@ -1138,6 +1141,88 @@ def gdn_recur_dense(state):
     return recur, box
 
 
+def _stream_product(x, w):
+    """``sum_jc x[b, j, t, c] w[m, j, c]`` as ``(m, B, T)`` float32, to float32's
+    accuracy whatever the stream's dtype.  A float32 stream at the highest
+    precision.  A bfloat16 one is exact as it stands, so ``w`` alone is split into
+    bfloat16 pieces (three hold float32's 24 bits) and each piece is one pass of
+    the matrix unit with float32 sums; a stream a (T, C) slab, so no slab is
+    transposed to be multiplied."""
+    n = x.shape[1]
+    if x.dtype == jnp.float32:
+        return sum(jnp.einsum("btc,mc->mbt", x[:, j], w[:, j], precision=jax.lax.Precision.HIGHEST) for j in range(n))
+    pieces, rest = [], w
+    for _ in range(3):
+        pieces.append(rest.astype(x.dtype))
+        rest = rest - pieces[-1].astype(jnp.float32)
+    w3 = jnp.concatenate(pieces)                                                    # (3 m, n, C)
+    out = sum(jnp.einsum("btc,mc->mbt", x[:, j], w3[:, j], preferred_element_type=jnp.float32) for j in range(n))
+    return out.reshape(3, w.shape[0], *out.shape[1:]).sum(axis=0)
+
+
+def hc_maps(hp, x, cfg: Config):
+    """The three maps one hyper-connection gives each token of the stream ``x
+    (B, n, T, C)`` (``n`` = ``cfg.hc_mult``; a stream is a major axis, a ``(T, C)``
+    slab, so nothing is tiled over an axis of 4), all in float32 and with the
+    tokens on the last axis (a map is a few numbers a token: ``(n, n, B, T)`` fills
+    the lanes where ``(B, T, n, n)`` would fill a thirty-second of a tile):
+
+        x' = rms_nC(vec(X)) * norm                eps hc_eps; the weight is folded into phi, the map being linear
+        H_pre  = sigmoid(a_0 (x' phi_pre) + b_pre)                                    (n, B, T)
+        H_post = 2 sigmoid(a_1 (x' phi_post) + b_post)                                (n, B, T)
+        M = exp(clip(a_2 (x' phi_res) + b_res, hc_res_clamp)) as (n, n): [i, j] to stream i from stream j
+        hc_sinkhorn_iters times: M /= its columns' sums + hc_eps, then M /= its rows' sums + hc_eps   -> H_res (n, n, B, T)
+
+    ``hp``: ``phi (n (n + 2), n C)`` rows ``[pre | post | res]``, ``norm (n C)``,
+    ``alpha (3,)`` and ``bias (n (n + 2),)`` float32."""
+    B, n, T, C = x.shape
+    f32 = jnp.float32
+    with scope("norm"):
+        xf = x.astype(f32)
+        r = jax.lax.rsqrt(jnp.mean(xf * xf, axis=(1, 3)) + cfg.hc_eps)               # (B, T)
+    with scope("maps"):
+        phi = (hp["phi"].astype(f32) * hp["norm"].astype(f32)).reshape(-1, n, C)
+        raw = _stream_product(x, phi) * r                                           # (n (n + 2), B, T)
+        a, b = hp["alpha"].astype(f32), hp["bias"].astype(f32)[:, None, None]
+        h_pre = jax.nn.sigmoid(a[0] * raw[:n] + b[:n])
+        h_post = 2.0 * jax.nn.sigmoid(a[1] * raw[n:2 * n] + b[n:2 * n])
+    with scope("sinkhorn"):
+        lo, hi = cfg.hc_res_clamp
+        m = jnp.exp(jnp.clip(a[2] * raw[2 * n:] + b[2 * n:], lo, hi)).reshape(n, n, B, T)
+        for _ in range(cfg.hc_sinkhorn_iters):
+            m = m / (jnp.sum(m, axis=0, keepdims=True) + cfg.hc_eps)
+            m = m / (jnp.sum(m, axis=1, keepdims=True) + cfg.hc_eps)
+    return h_pre, h_post, m
+
+
+def hc_open(hp, x, cfg: Config):
+    """What a sublayer reads of the stream ``x (B, n, T, C)``: ``u = H_pre X (B, T,
+    C)`` at the stream's dtype, summed in float32, and the two maps its
+    :func:`hc_close` writes back by.  The dense cache's forward and the paged
+    server's open every sublayer here (under ``mixer`` or ``mlp``)."""
+    with scope("hc/open"):
+        h_pre, h_post, h_res = hc_maps(hp, x, cfg)
+        with scope("read"):
+            u = sum(h_pre[j][..., None] * x[:, j].astype(jnp.float32) for j in range(x.shape[1]))
+            return u.astype(x.dtype), (h_post, h_res)
+
+
+def hc_close(x, f, maps):
+    """The stream after a sublayer gave ``f (B, T, C)``: ``H_res X + H_post^T f
+    (B, n, T, C)``, in float32, stored at the stream's dtype.  No
+    ``optimization_barrier`` writes a prompt's stream out, as :func:`_close_block`
+    writes a sandwich norm's sums: the rounding to the stream's dtype ends each
+    sublayer's fusion, and an 8,192-token prompt's program holds the same 1.195 GB
+    of temporaries with a barrier here as without (its buffer assignment for a
+    v5e; PERF.md, PR 53)."""
+    h_post, h_res = maps
+    n = x.shape[1]
+    with scope("hc/close"):
+        xf, ff = x.astype(jnp.float32), f.astype(jnp.float32)
+        return jnp.stack([sum(h_res[i, j][..., None] * xf[:, j] for j in range(n)) + h_post[i][..., None] * ff
+                          for i in range(n)], axis=1).astype(x.dtype)
+
+
 def require_servable(cfg: Config) -> None:
     """The one refusal of a config this module's forward cannot run (an
     expert share routed by softmax, norms with zero-centred weights:
@@ -1150,7 +1235,7 @@ def require_servable(cfg: Config) -> None:
             "It trains through tt.jit / distributed.make_train_step (llama.gpt_loss).")
 
 
-def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0, moe_rows=None):
+def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0, moe_rows=None, hc=None):
     """A block from its mixer's output ``h`` on: the residual sums, the norms
     and the MLP, for every block layout (``n1``: the mixer's input, which a
     shared attention norm hands to the MLP too).  The dense cache's forward
@@ -1159,7 +1244,9 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
     A model of single sublayers (``cfg.single_sublayer``): a mixer layer ends in
     its residual sum; an "mlp" layer has no mixer (``h`` None) and is ``x +
     MLP(norm_1(x))``, all of it under the ``mlp`` scope.  ``moe_rows``: see
-    :func:`moe_share_mlp`."""
+    :func:`moe_share_mlp`.  ``hc``: under hyper-connections (``cfg.hc_mult`` > 1)
+    ``x`` is the stream ``(B, n, T, C)`` and ``hc`` what the mixer's
+    :func:`hc_open` returned beside its input."""
     if cfg.single_sublayer and h is not None:
         with scope("mixer/residual"):
             return x + h
@@ -1172,6 +1259,14 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
             m = mlp(n)
             with scope("residual"):
                 return x + m
+    if cfg.hc_mult > 1:                 # a hyper-connection where each residual sum of the pre-norm block stands
+        with scope("mixer"):
+            x = hc_close(x, h, hc)
+        with scope("mlp"):
+            u, hc = hc_open(bp["hc_2"], x, cfg)
+            with scope("norm"):
+                n2 = _norm(u, bp["norm_2"], cfg, bp.get("norm_2_b"))
+            return hc_close(x, mlp(n2), hc)
     # each sublayer's norm and residual sum count with the sublayer
     if cfg.sandwich_norm:               # a norm on what each sublayer takes and on what it gives
         # A prompt's sums are each written out (``optimization_barrier``).  Given its row's one
@@ -1218,6 +1313,14 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
             return x + m
 
 
+def _to_streams(x, cfg: Config):
+    """The embedding ``(B, T, C)`` as the residual stream: itself, or under
+    hyper-connections copied to ``hc_mult`` streams ``(B, n, T, C)`` (arXiv:2409.19606)."""
+    if cfg.hc_mult == 1:
+        return x
+    return jnp.broadcast_to(x[:, None], (x.shape[0], cfg.hc_mult, *x.shape[1:]))
+
+
 def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *,
                        quantized=False, lora=None, lora_scaling=1.0, n_real=None, logits_at=None,
                        sharded=False):
@@ -1254,6 +1357,7 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
                     lambda p: jax.lax.dynamic_slice_in_dim(params["wpe"], p, T, axis=0))(pos)
             else:
                 x = x + jax.lax.dynamic_slice_in_dim(params["wpe"], pos, T, axis=0)
+        x = _to_streams(x, cfg)
         if vec:
             # (B, 1, T, n_elem): broadcasts against (B, nh, T, hs) inside _rope
             cos_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(cos_all, p, T, axis=0))(pos)[:, None]
@@ -1282,11 +1386,13 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
         with scope(f"blk{l}"):
             with scope("mixer"):
                 # OLMo's blocks norm what a sublayer gives, not what it takes
+                # under hyper-connections the sublayer reads a mixture of the streams
+                u, hc = hc_open(bp["hc_1"], x, cfg) if cfg.hc_mult > 1 else (x, None)
                 if cfg.post_sublayer_norm:
-                    n1 = x
+                    n1 = u
                 else:
                     with scope("norm"):
-                        n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
+                        n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b"))
                 if kind == "mamba2":
                     j = len(new_state)
                     recur, box = mamba2_recur_dense(cache["state"][j])
@@ -1339,7 +1445,7 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
                     )
                     new_k.append(ck)
                     new_v.append(cv)
-            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling)
+            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, hc=hc)
 
     with scope("mixer/cache"):
         cache = {"latent": jnp.stack(new_latent)} if cfg.latent else {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
@@ -1351,7 +1457,13 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
 
 
 def _head_logits(params, x, cfg: Config, logits_at, quantized):
-    """The last norm and the logits in float32, of row ``logits_at`` alone where given."""
+    """The last norm and the logits in float32, of row ``logits_at`` alone where
+    given.  The streams of hyper-connections are summed first (arXiv:2409.19606)."""
+    if cfg.hc_mult > 1:
+        with scope("head/norm"):
+            if logits_at is not None:
+                x, logits_at = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=2), None
+            x = jnp.sum(x.astype(jnp.float32), axis=1).astype(x.dtype)
     with scope("head/norm"):
         x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
         if logits_at is not None:

@@ -242,6 +242,20 @@ class Config:
     # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
     # ``mscale``, ``mscale_all_dim``; stored like ``rope_scaling_llama3``
     rope_scaling_yarn: tuple | dict | None = None
+    # Manifold-constrained hyper-connections (arXiv:2512.24880, on arXiv:2409.19606;
+    # in the server alone), on where ``hc_mult`` > 1: the residual stream is
+    # ``hc_mult`` copies wide, ``X (n, C)`` a token.  A sublayer ``F`` reads ``u =
+    # H_pre X`` and writes ``X <- H_res X + H_post^T F(norm(u))``; the three maps are
+    # the token's own, from one RMSNorm (eps ``hc_eps``) over the flattened ``n C``:
+    # ``H_pre = sigmoid(.)`` and ``H_post = 2 sigmoid(.)`` of ``n`` numbers each,
+    # ``H_res (n, n)`` the exponential of ``n^2`` numbers clipped to
+    # ``hc_res_clamp``, its columns then its rows normalised ``hc_sinkhorn_iters``
+    # times (``models.generate.hc_maps``).  The embedding is copied to the ``n``
+    # streams and they are summed before the last norm
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple = (-30.0, 30.0)
 
     def __post_init__(self):
         if isinstance(self.rope_scaling_llama3, dict):
@@ -330,6 +344,13 @@ class Config:
         if self.rope_kinds is not None:
             self.rope_kinds = tuple(self.rope_kinds)
             assert set(self.rope_kinds) <= {"full_attention", "sliding_attention"} and not self.latent, self.rope_kinds
+        self.hc_res_clamp = tuple(float(v) for v in self.hc_res_clamp)
+        if self.hc_mult > 1:
+            assert not (self.parallel_residual or self.post_sublayer_norm or self.sandwich_norm
+                        or self.shared_attention_norm or self.single_sublayer or self.hybrid_decoder), (
+                "hc_mult > 1: the plain pre-norm block alone, x + f(norm(x)) a sublayer (a hyper-connection "
+                "takes the place of each residual sum; no other block layout has one)")
+            assert self.hc_sinkhorn_iters >= 1 and len(self.hc_res_clamp) == 2
         if self.sandwich_norm:
             assert not (self.parallel_residual or self.post_sublayer_norm or self.shared_attention_norm or self.bias
                         or self.single_sublayer), "sandwich_norm: sequential bias-free blocks, a norm before and after each sublayer"
@@ -644,7 +665,7 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
         return (jax.random.normal(key, (fan_out, fan_in), dtype=jnp.float32) * std).astype(dtype)
 
     n_keys = 3 + config.n_layer * (5 + 3 * max(1, config.n_expert) + (8 if config.mlp_class == "SparseMoE" else 0)
-                                   + (2 if config.latent else 0))
+                                   + (2 if config.latent else 0) + (2 if config.hc_mult > 1 else 0))
     keys = iter(jax.random.split(key, n_keys))
 
     def zeros(n):
@@ -760,6 +781,17 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
         if config.single_sublayer and config.layer_kind(i) != "mlp":
             params["blocks"].append(block)                      # a mixer alone: no second norm, no feed-forward
             continue
+        if config.hc_mult > 1:          # a hyper-connection a sublayer: the mixer's and the feed-forward's
+            n, nC = config.hc_mult, config.hc_mult * config.n_embd
+            for name in ("hc_1", "hc_2"):
+                block[name] = {
+                    # rows [pre (n) | post (n) | res (n * n, to stream i from stream j at i * n + j)] over vec(X)
+                    "phi": dense(next(keys), nC, n * (n + 2)),
+                    "norm": jnp.ones((nC,), dtype=dtype),
+                    # as the paper starts them: the token's own part small, the stream passed on nearly as it is
+                    "alpha": jnp.full((3,), 0.01, jnp.float32),
+                    "bias": jnp.concatenate([jnp.zeros((2 * n,)), 4.0 * jnp.eye(n).reshape(-1)]).astype(jnp.float32),
+                }
         if config.sandwich_norm:        # the norms on what the two sublayers give
             block.update(norm_1_post=norm_init((config.n_embd,), dtype=dtype),
                          norm_2_post=norm_init((config.n_embd,), dtype=dtype))
@@ -1142,8 +1174,8 @@ def serving_only(config: Config) -> str | None:
     memory unit, cross attention, differential attention), the Mamba-2 mixer,
     single-sublayer blocks, the window kind of an ordinary decoder with its
     rotation a layer kind and its norms on both sides of a sublayer, the sigmoid
-    routers, leading dense layers and the latent ungated expert share are built
-    in ``models.generate`` for the server alone."""
+    routers, leading dense layers, the latent ungated expert share and a stream
+    under hyper-connections are built in ``models.generate`` for the server alone."""
     if config.conv_layers:
         return ("layer_types with 'conv' (the gated short convolution is built in models.generate, for tt.serve, "
                 "and has no traced form)")
@@ -1156,6 +1188,9 @@ def serving_only(config: Config) -> str | None:
                 "models.generate, for tt.serve, and have no traced form)")
     if config.latent:
         return "kv_lora_rank > 0 (latent attention is built in models.generate, for tt.serve, and has no traced form)"
+    if config.hc_mult > 1:
+        return ("hc_mult > 1 (a residual stream hc_mult wide under hyper-connections is built in models.generate, "
+                "for tt.serve, and has no traced form: block_forward carries one stream)")
     if set(config.layer_types or ()) & set(SINGLE_SUBLAYER_KINDS):
         return ("layer_types with 'mamba2' or 'mlp' (the Mamba-2 mixer and single-sublayer blocks are built in "
                 "models.generate, for tt.serve, and have no traced form: the chunked scan has no backward)")

@@ -66,12 +66,14 @@ from thunder_tpu.models.generate import (
     mla_mixer,
     mla_unabsorb,
     pad_lanes,
+    hc_open,
     _close_block,
     _head_logits,
     _linear,
     _lora_delta,
     _norm,
     _project_qkv,
+    _to_streams,
 )
 from thunder_tpu.observability.events import scope
 from thunder_tpu.serving.kv_pool import ring_tables
@@ -356,6 +358,7 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                 lambda p: jax.lax.dynamic_slice_in_dim(params["wpe"], p, T, axis=0))(pos)
         cos_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(cos_all, p, T, axis=0))(pos)[:, None]
         sin_t = jax.vmap(lambda p: jax.lax.dynamic_slice_in_dim(sin_all, p, T, axis=0))(pos)[:, None]
+        x = _to_streams(x, cfg)
 
     lin = partial(_linear, quantized=quantized)
     delta_fn = lora_delta_fused if (lora_fused and mesh is None) else _lora_delta
@@ -372,11 +375,13 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
             continue
         with scope(f"blk{l}"):
             with scope("mixer"):
+                # under hyper-connections the sublayer reads a mixture of the streams
+                u, hc = hc_open(bp["hc_1"], x, cfg) if cfg.hc_mult > 1 else (x, None)
                 if cfg.post_sublayer_norm:
-                    n1 = x
+                    n1 = u
                 else:
                     with scope("norm"):
-                        n1 = _norm(x, bp["norm_1"], cfg, bp.get("norm_1_b"))
+                        n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b"))
                 kind = cfg.layer_kind(l)
                 if kind == "mamba2":
                     h, state_arena, conv_arena = _mamba2_paged(
@@ -464,7 +469,7 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                     (ring_k if swa else fresh_k).append(fk)
                     (ring_v if swa else fresh_v).append(fv)
             x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling,
-                             moe_rows=rows_of)
+                             moe_rows=rows_of, hc=hc)
 
     logits = _head_logits(params, x, cfg, None, quantized)
     with scope("mixer/cache"):

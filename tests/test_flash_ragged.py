@@ -92,12 +92,13 @@ def test_flash_schedule_of_a_ragged_rectangle(case, by_column):
 
 
 # a whole prompt's flash call a cell and a prefill bucket (chipbench/traffic/*.json): head size as the kernel sees it
-# (64 padded, a latent's 192 padded to 256, a differential pair packed), the layers' windows, and a bucket's block
+# (64 padded, a latent's 192 as it is since PR 54, a differential pair packed), the layers' windows, and a bucket's block
 # beside the largest that divides it, which it had to be before PR 49
 CELL_CALLS = {
     "offline-batch": (128, (4096,), {1024: (1024, 1024), 2048: (1024, 1024), 3072: (1024, 1024)}),
     "offline-longgen": (128, (None,), {1024: (1024, 1024), 2048: (1024, 1024), 2560: (1024, 512)}),
-    "offline-longctx": (256, (None,), {4096: (1024, 1024), 6144: (1024, 1024), 8192: (1024, 1024)}),
+    "offline-longctx": (192, (None,), {4096: (1024, 1024), 6144: (1024, 1024), 8192: (1024, 1024)}),
+    "offline-digest": (192, (None,), {5120: (1024, 1024), 6656: (1024, 512), 8192: (1024, 1024)}),
     "offline-wide": (128, (None,), {1024: (1024, 1024), 2048: (1024, 1024), 2560: (1024, 512)}),
     "offline-reason": (128, (None,), {2048: (1024, 1024), 3584: (1024, 512), 5120: (1024, 1024)}),
     "offline-reason/window": (128, (512,), {2048: (512, 512), 3584: (512, 512), 5120: (512, 512)}),

@@ -198,7 +198,8 @@ def test_flash_windowed_rows_past_the_keys_band(monkeypatch, interpret_kernels, 
     out, lse = pallasex.flash_sdpa(q, k, v, None, True, scale, window)
     dq, dk, dv = pallasex.flash_sdpa_backward(g, q, k, v, out, lse, None, True, scale, window)
     assert pallasex.flash_schedule == {"grid_steps": 4, "running_blocks": 2, "edge_blocks_a_full_row": 1,
-                                       "block_q": 128, "block_k": 128, "tail_rows": 0}
+                                       "block_q": 128, "block_k": 128, "tail_rows": 0,
+                                       "head_qk": 128, "head_v": 128, "lanes_padded": 0}
     assert np.isnan(np.asarray(_sdpa_reference(q, k, v, None, True, scale, window)[0][..., live:, :])).all()
     assert np.isfinite(np.asarray(out)).all() and not np.asarray(dq[..., live:, :]).any()
     top = lambda x: x[..., :live, :]   # noqa: E731
@@ -620,7 +621,8 @@ def test_flash_band_with_interior_and_edge_blocks_matches_reference(monkeypatch,
     scale, window = 1.0 / np.sqrt(hs), 384
     out, lse = pallasex.flash_sdpa(q, k, v, mask, True, scale, window)
     assert pallasex.flash_schedule == {"grid_steps": 5 * 4 - 6, "running_blocks": 14, "edge_blocks_a_full_row": 2,
-                                       "block_q": 128, "block_k": 128, "tail_rows": 0}
+                                       "block_q": 128, "block_k": 128, "tail_rows": 0,
+                                       "head_qk": max(hs, 128), "head_v": max(hs, 128), "lanes_padded": 3 * (max(hs, 128) - hs)}
     oref, lref = _sdpa_reference(q, k, v, mask, True, scale, window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oref), atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(lref), atol=2e-5, rtol=2e-5)

@@ -329,6 +329,14 @@ def test_kernel_lowers_and_compiles_for_tpu(shape, kernel, tpu_sharding):
             assert re.search(rf"%{name}(\.\d+)? = ", compiled), (kernel, name)
 
 
+def _lowered_flash_fwd(H, G, T, hs, hv, sharding, window=None):
+    q = jax.ShapeDtypeStruct((H, T, hs), BF, sharding=sharding)
+    k = jax.ShapeDtypeStruct((G, T, hs), BF, sharding=sharding)
+    v = jax.ShapeDtypeStruct((G, T, hv), BF, sharding=sharding)
+    return jax.jit(lambda q_, k_, v_: px._flash_fwd.__wrapped__(
+        q_, k_, v_, None, True, hs ** -0.5, H, G, None, 1, window)).trace(q, k, v).lower(lowering_platforms=("tpu",))
+
+
 @pytest.mark.parametrize("window", [None, 2048], ids=["global", "window2048"])
 @pytest.mark.parametrize("T", [3840, 5888, 7936, 9984])
 def test_flash_fwd_compiles_at_the_docqa_buckets_with_a_ragged_last_block(T, window, tpu_sharding, monkeypatch):
@@ -341,12 +349,7 @@ def test_flash_fwd_compiles_at_the_docqa_buckets_with_a_ragged_last_block(T, win
     monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
     monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
 
-    def lowered(T_):
-        q = jax.ShapeDtypeStruct((32, T_, HS), BF, sharding=tpu_sharding)
-        k = jax.ShapeDtypeStruct((4, T_, HS), BF, sharding=tpu_sharding)
-        return jax.jit(lambda q_, k_, v: px._flash_fwd.__wrapped__(
-            q_, k_, v, None, True, HS ** -0.5, 32, 4, None, 1, window)).trace(q, k, k).lower(lowering_platforms=("tpu",))
-
+    lowered = lambda T_: _lowered_flash_fwd(32, 4, T_, HS, HS, tpu_sharding, window)  # noqa: E731
     whole = _mosaic_module(lowered(8192).as_text())
     assert px.flash_schedule["block_q"] == 1024 and px.flash_schedule["tail_rows"] == 0
     ragged = lowered(T)
@@ -356,6 +359,46 @@ def test_flash_fwd_compiles_at_the_docqa_buckets_with_a_ragged_last_block(T, win
     assert module.count("tpu.matmul") == whole.count("tpu.matmul") + 2 == 6
     if tpu_sharding is not None:
         assert re.search(r"%_flash_fwd(\.\d+)? = ", ragged.compile().as_text())
+
+
+@pytest.mark.parametrize("heads", [32, 64], ids=["xing4", "axk1"])
+def test_flash_fwd_compiles_with_values_at_their_own_width(heads, tpu_sharding, monkeypatch):
+    """A latent prompt's call at both latent cells' heads and 8,192 tokens, as
+    ``_fwd_local`` builds it (PR 54): q/k a head of 192 as they are (a block's
+    last dimension the array's whole width), v, the accumulator and the result
+    at 128.  Blocks of 1024; the score product takes 192 lanes, the weighted
+    sum 128, and nothing in the module is 256 wide."""
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
+    lowered = _lowered_flash_fwd(heads, heads, 8192, 192, 128, tpu_sharding)
+    assert (px.flash_schedule["block_q"], px.flash_schedule["block_k"], px.flash_schedule["tail_rows"]) == (1024, 1024, 0)
+    text = lowered.as_text()
+    assert f"-> (tensor<{heads}x8192x128xbf16>, tensor<{heads}x1x8192xf32>" in text      # the result as wide as v
+    module = _mosaic_module(text)
+    assert module.count("tpu.matmul") == 4
+    assert "vector<1024x192xbf16>, vector<1024x192xbf16>, vector<1024x1024xf32>" in module       # q k^T
+    assert "vector<1024x1024xbf16>, vector<1024x128xbf16>, vector<1024x128xf32>" in module       # p v
+    assert "memref<1024x128xf32, #tpu.memory_space<vmem>>" in module and not re.search(r"x256x(bf16|f32)", module)
+    if tpu_sharding is not None:
+        assert re.search(r"%_flash_fwd(\.\d+)? = ", lowered.compile().as_text())
+
+
+def test_flash_fwd_at_one_width_is_the_module_it_was(tpu_sharding, monkeypatch):
+    """The unchanged path stays the unchanged path: 32 heads over 4 of 128 at
+    8,192 (Trinity-Mini's layer, the shape the ragged cases above compare
+    with) lowers to two forms of the body and no tail's, every operand, the
+    accumulator and the result 128 wide."""
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
+    lowered = _lowered_flash_fwd(32, 4, 8192, HS, HS, tpu_sharding)
+    assert (px.flash_schedule["block_q"], px.flash_schedule["tail_rows"]) == (1024, 0)
+    module = _mosaic_module(lowered.as_text())
+    assert module.count("tpu.matmul") == 4 and "arith.select" in module          # the edge form's mask, no tail form
+    assert module.count("vector<1024x128xbf16>, vector<1024x128xbf16>, vector<1024x1024xf32>") == 2
+    assert module.count("vector<1024x1024xbf16>, vector<1024x128xbf16>, vector<1024x128xf32>") == 2
+    assert not re.search(r"x(192|256)x(bf16|f32)", module)
+    if tpu_sharding is not None:
+        assert re.search(r"%_flash_fwd(\.\d+)? = ", lowered.compile().as_text())
 
 
 @pytest.mark.parametrize("kernel", ["gdn_chunk_fwd", "gdn_chunk_bwd"])
@@ -750,7 +793,7 @@ def _mla_engine(cell=MLA_CELL):
 @pytest.mark.parametrize("cell", sorted(MLA_PROGRAMS), ids=lambda c: c.partition("-")[0])
 def test_the_latent_cells_programs_lower_to_their_kernels(cell, kind, tpu_sharding, monkeypatch):
     """A whole prompt's prefill attends its expanded keys through ``_flash_fwd``
-    (heads of 192 beside values of 128, padded with zeros to one size) and
+    (heads of 192 over values of 128, each as it is: no pad, no slice, PR 54) and
     sorts its rows through ``moe_grouped_mm``; a decode step calls
     ``mla_paged_decode`` once a layer, ``moe_grouped_mm`` for the expert layer
     and lands its rows through one ``mla_latent_write``; no arena is gathered.
@@ -782,7 +825,16 @@ def test_the_latent_cells_programs_lower_to_their_kernels(cell, kind, tpu_shardi
     if kind == "prefill_fresh":
         assert claimed("direct") == cfg.n_layer and 'kernel_name = "_flash_fwd"' in text
         assert px.flash_schedule["grid_steps"] > 0
-        assert f"tensor<1x{cfg.n_head}x{Tb}x{cfg.head_size}xbf16>" in text      # q, k and the padded v a head of 192
+        assert (px.flash_schedule["head_qk"], px.flash_schedule["head_v"], px.flash_schedule["lanes_padded"]) == (192, 128, 0)
+        nh, (hs, hv) = cfg.n_head, (cfg.head_size, cfg.v_head_dim)
+        assert (hs, hv) == (192, 128) and f"tensor<1x{nh}x{Tb}x{hs}xbf16>" in text and f"tensor<1x{nh}x{Tb}x{hv}xbf16>" in text
+        # q and k at the kept width, v and the call's result at v_head_dim: no zero is added to a head and none cut off
+        heads = lambda w: rf"tensor<{nh}x{Tb}x{w}xbf16>"  # noqa: E731
+        assert re.search(rf"call @_flash_fwd\S*\([^)]*\) : \({heads(hs)}, {heads(hs)}, {heads(hv)}\) -> \(?{heads(hv)}", text)
+        assert not re.search(rf"stablehlo\.pad[^\n]*\(tensor<(1x)?{nh}x{Tb}x\d+xbf16>", text)
+        assert not re.search(rf"stablehlo\.slice[^\n]*\(tensor<{nh}x{Tb}x\d+xbf16>\)", text)
+        # one cut of a head of 192 to 128 a layer: the query's part without a position; the result has none
+        assert len(re.findall(rf"stablehlo\.slice[^\n]*\(tensor<1x{nh}x{Tb}x{hs}xbf16>\) -> tensor<1x{nh}x{Tb}x{hv}xbf16>", text)) == cfg.n_layer
         assert {int(m) for m in re.findall(rf"tensor<1x(\d+)x{cfg.padded_vocab_size}xf32>", text)} == {1}
     else:
         assert claimed("mla_decode") == cfg.n_layer

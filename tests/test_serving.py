@@ -558,20 +558,26 @@ def test_serving_is_strictly_additive(micro):
     def fn(x):
         return x * 2.0 + 1.0
 
+    def program(jfn):
+        # a pass's header says how long it took: the clock's, not the program's (4 ms for 0 on a loaded machine, PR 54)
+        import re
+
+        return re.sub(r"\(took \d+ milliseconds\)", "", tt.last_traces(jfn)[-1].python())
+
     x = np.ones((4, 4), np.float32)
     before = tt.jit(fn)
     before(x)
-    ref = tt.last_traces(before)[-1].python()
+    ref = program(before)
     eng = _engine(cfg, params)
     eng.run([{"prompt": np.arange(3, dtype=np.int32), "max_new_tokens": 2}])
     after = tt.jit(fn)
     after(x)
-    assert tt.last_traces(after)[-1].python() == ref
+    assert program(after) == ref
     instrumented = _engine(cfg, params, trace=True, slo=True, flight_recorder=True)
     instrumented.run([{"prompt": np.arange(3, dtype=np.int32), "max_new_tokens": 2}])
     again = tt.jit(fn)
     again(x)
-    assert tt.last_traces(again)[-1].python() == ref
+    assert program(again) == ref
 
 
 @pytest.mark.slow

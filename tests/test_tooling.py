@@ -151,6 +151,24 @@ class TestTools:
         assert proc.stdout.strip() == "" and "needs a TPU" in proc.stderr
 
 
+    def test_flash_tune_times_a_latent_call_in_three_forms_that_agree(self, monkeypatch, capsys):
+        """``--latent``: keys and values as they are, q/k padded to whole tiles,
+        and all three padded to one width and sliced back (the call before PR
+        54) are one result under the interpreter, and a line a form names its
+        matrix passes a tile; the device's times are stubbed."""
+        from thunder_tpu.executors import pallasex as px
+        sys.path.insert(0, str(self.TOOLS.parent))
+        from tools import flash_tune
+
+        monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr(flash_tune, "LATENT", {"tiny": (2, (192, 128), (256,))})
+        monkeypatch.setattr(flash_tune, "kernel_ms", lambda run, reps: (run(), {"_flash_fwd": 1.0, "pad": 0.5})[1])
+        flash_tune.time_latent("tiny", check=True)
+        out = capsys.readouterr().out
+        assert [l.split("[")[1].split(" passes")[0] for l in out.splitlines() if " passes a tile" in l] == ["2 + 1", "2 + 1", "2 + 2"]
+        assert "the forms differ by 0.000000" in out and "FAILED" not in out
+        assert "head_qk" not in px.flash_schedule       # the kernel alone: the widths are its dispatcher's to note
+
     def test_mla_tune_builds_the_cells_operands_and_checks_under_the_interpreter(self, monkeypatch):
         """The tool's contexts are the mix's, its tables scatter a row's blocks
         and sink-pad, and its ``--check`` (the interpreted kernel against

@@ -208,19 +208,22 @@ def _canon_mask(mask_shape, q_shape, k_shape):
     return None
 
 
-def _supported(q_shape, k_shape, v_shape, dtype, causal, mask_shape=None, window=None) -> bool:
+def _supported(q_shape, k_shape, v_shape, dtype, causal, mask_shape=None, window=None, own_v_width=False) -> bool:
     if window is not None and (not causal or int(window) <= 0):
         return False
     *_, Tq, hs = q_shape
     Tk = k_shape[-2]
-    if v_shape[-1] != hs:  # kernels assume one head dim for q/k/v
+    # the forward kernel takes v (and writes out) at a width of its own
+    # (``own_v_width``: a latent prompt's heads of 192 over values of 128);
+    # the backward kernels assume one head dim for q/k/v
+    if v_shape[-1] != hs and not own_v_width:
         return False
     if _gqa_rep(q_shape, k_shape) is None:
         return False
     if k_shape[:-2] != v_shape[:-2]:
         return False
     # head sizes that aren't lane-aligned (e.g. 64) run zero-padded to 128
-    if _pad128(hs) > 512:
+    if max(_pad128(hs), _pad128(v_shape[-1])) > 512:
         return False
     if Tq % 128 or Tk % 128:
         return False
@@ -339,15 +342,23 @@ def _flash_schedule(Tq: int, Tk: int, BQ: int, BK: int, causal: bool, window: in
 
 # what the last flash call built visits a head (trace time): grid steps, the
 # blocks among them with a kept pair, the edge blocks of a full row, the
-# blocks' sizes and the rows of the last query block past ``Tq``.  Not among
-# ``stats``: readers sum and subtract those counters.
+# blocks' sizes and the rows of the last query block past ``Tq``; and, from the
+# dispatcher that padded the operands, the widths the kernels run at
+# (``head_qk``, ``head_v``) with the zeros added a row over q, k and v together
+# (``lanes_padded``).  Not among ``stats``: readers sum and subtract those
+# counters.
 flash_schedule: dict[str, int] = {}
+
+
+def _note_widths(hs: int, hv: int, hp: int, hvp: int):
+    flash_schedule.update(head_qk=hp, head_v=hvp, lanes_padded=2 * (hp - hs) + hvp - hv)
 
 
 def _note_schedule(Tq, Tk, BQ, BK, causal, window):
     qi, _, _, flag = _flash_schedule(Tq, Tk, BQ, BK, causal, window)
     run, _ = _band_blocks(Tq, Tk, BQ, BK, causal, window)
     edges = np.bincount(qi[(flag & _EDGE) != 0], minlength=run.shape[0])
+    flash_schedule.clear()      # the widths are the dispatcher's to add: none of an earlier call's stay
     flash_schedule.update(
         grid_steps=len(qi),
         running_blocks=int(run.sum()),
@@ -484,18 +495,21 @@ def _flash_params():
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "H", "G", "mode", "mq", "window"))
 def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: str | None, mq: int,
                window: int | None = None):
-    """q (BH, Tq, hs), k/v (BG, Tk, hs), mask (M, mq, Tk) f32 or None
-    -> out (BH, Tq, hs), lse (BH, 1, Tq) f32.  ``H``/``G`` are the per-shard
-    q/KV head counts (the flat-batch gather key for GQA); ``mode``/``mq``
-    classify the mask layout (see _canon_mask)."""
+    """q (BH, Tq, hs), k (BG, Tk, hs), v (BG, Tk, hv), mask (M, mq, Tk) f32 or
+    None -> out (BH, Tq, hv), lse (BH, 1, Tq) f32.  ``H``/``G`` are the
+    per-shard q/KV head counts (the flat-batch gather key for GQA);
+    ``mode``/``mq`` classify the mask layout (see _canon_mask).  ``hv`` is a
+    shape the body reads: v's blocks, the accumulator and the output are that
+    wide, the score product ``hs``; where the two are equal the specs are."""
     BH, Tq, hs = q.shape
-    Tk = k.shape[1]
+    Tk, hv = k.shape[1], v.shape[2]
     BQ, BK = _flash_blocks(q, k, mq, window, causal)
     has_mask = mask is not None
     qi, kj, _, flag = _flash_schedule(Tq, Tk, BQ, BK, causal, window)
     _note_schedule(Tq, Tk, BQ, BK, causal, window)
-    q_spec, kv_spec, row_spec, mask_spec = _flash_specs(H, G, mode, mq, BQ, BK, hs)
-    in_specs, operands = [q_spec, kv_spec, kv_spec], [q, k, v]
+    q_spec, k_spec, row_spec, mask_spec = _flash_specs(H, G, mode, mq, BQ, BK, hs)
+    o_spec, v_spec, _, _ = _flash_specs(H, G, mode, mq, BQ, BK, hv)
+    in_specs, operands = [q_spec, k_spec, v_spec], [q, k, v]
     if has_mask:
         in_specs.append(mask_spec)
         operands.append(mask)
@@ -507,15 +521,15 @@ def _flash_fwd(q, k, v, mask, causal: bool, scale: float, H: int, G: int, mode: 
             num_scalar_prefetch=3,
             grid=(BH, len(qi)),
             in_specs=in_specs,
-            out_specs=[q_spec, row_spec],
+            out_specs=[o_spec, row_spec],
             scratch_shapes=[
                 pltpu.VMEM((BQ, 1), jnp.float32),
                 pltpu.VMEM((BQ, 1), jnp.float32),
-                pltpu.VMEM((BQ, hs), jnp.float32),
+                pltpu.VMEM((BQ, hv), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Tq, hs), q.dtype),
+            jax.ShapeDtypeStruct((BH, Tq, hv), q.dtype),
             jax.ShapeDtypeStruct((BH, 1, Tq), jnp.float32),
         ],
         interpret=_interpret(),
@@ -717,22 +731,35 @@ def _canon_mask_operand(mask, q_shape, k_shape):
 
 
 def _fwd_local(q, k, v, mask, causal: bool, scale: float, window: int | None = None):
-    """Single-device forward on concrete arrays: flatten batch, pad hs, run.
-    ``mask`` is the original-rank additive mask or None."""
+    """Single-device forward on concrete arrays: flatten batch, pad the head
+    sizes, run.  ``mask`` is the original-rank additive mask or None.
+
+    q/k go at their width and v at its own, never padded to the other's
+    (zeros the score product and the weighted sum would multiply).  v is
+    padded with zeros to whole lane tiles; so are q/k where the two widths are
+    one (the call there ever was, a head of 64 padded to 128 included, and the
+    one the backward kernels pad alike).  Beside a v of another width, q/k
+    wider than a tile and a whole number of half tiles go as they are, a
+    block's last dimension the array's whole width: at a latent prompt's 192
+    over 128 the kernel takes the same time as at 256 and the two pads' writes
+    are not made (``tools/flash_tune.py --latent``, PERF.md, PR 54: 6.79 ms a
+    call for 7.63 at 32 heads and 8,192 tokens, 9.94 with all three at 256)."""
     *batch, Tq, hs = q.shape
-    Tk = k.shape[-2]
-    hp = _pad128(hs)
+    Tk, hv = k.shape[-2], v.shape[-1]
+    as_it_is = hs != hv and hs > 128 and hs % 64 == 0
+    hp, hvp = hs if as_it_is else _pad128(hs), _pad128(hv)
     BH, BG, H, G = _local_geometry(q.shape, k.shape)
     mask3, mode, mq = _canon_mask_operand(mask, q.shape, k.shape)
     out, lse = _flash_fwd(
         _pad_hs(q.reshape(BH, Tq, hs), hs, hp),
         _pad_hs(k.reshape(BG, Tk, hs), hs, hp),
-        _pad_hs(v.reshape(BG, Tk, hs), hs, hp),
+        _pad_hs(v.reshape(BG, Tk, hv), hv, hvp),
         mask3,
         bool(causal), float(scale), H, G, mode, mq,
         window=None if window is None else int(window),
     )
-    return out[..., :hs].reshape(*batch, Tq, hs), lse.reshape(*batch, Tq)
+    _note_widths(hs, hv, hp, hvp)
+    return out[..., :hv].reshape(*batch, Tq, hv), lse.reshape(*batch, Tq)
 
 
 def _bwd_local(g, q, k, v, out, lse, mask, causal: bool, scale: float, window: int | None = None):
@@ -749,6 +776,7 @@ def _bwd_local(g, q, k, v, out, lse, mask, causal: bool, scale: float, window: i
         bool(causal), float(scale), H, G, mode, mq,
         window=None if window is None else int(window),
     )
+    _note_widths(hs, hs, hp, hp)
     return (
         dq[..., :hs].reshape(q.shape),
         dk[..., :hs].reshape(k.shape),
@@ -835,10 +863,11 @@ def _mask_shard_spec(mask, q_shape, k_shape, qkv_spec):
 
 
 def flash_sdpa(q, k, v, mask, causal, scale, window=None):
-    """Returns (out, lse) via the flash kernels, or None if unsupported."""
+    """Returns (out, lse) via the flash kernels, or None if unsupported.
+    ``v`` may be narrower or wider than ``q``/``k``: out is as wide as it."""
     if not _enabled() or not _supported(
         q.shape, k.shape, v.shape, q.dtype, causal,
-        mask.shape if mask is not None else None, window,
+        mask.shape if mask is not None else None, window, own_v_width=True,
     ):
         return None
     from jax.sharding import PartitionSpec as P
@@ -925,9 +954,15 @@ _sdpa_bwd_op = ex.register_operator(
 
 
 def _sdpa_checker(q, k, v, mask, causal, scale, window=None):
+    # A v of another width is claimed only where no operand asks for a
+    # gradient: ``_sdpa_bwd_checker`` keeps the one-width rule, so a forward
+    # claimed in a differentiated trace is one whose backward is claimed too.
+    # (jaxex's own ``sdpa`` still tries ``flash_sdpa`` on the arrays it is
+    # given; its ``(out, lse)`` are what the reference backward reads.)
+    trains = any(getattr(x, "requires_grad", False) for x in (q, k, v))
     return _enabled() and _supported(
         q.shape, k.shape, v.shape, q.dtype, causal,
-        mask.shape if mask is not None else None, window,
+        mask.shape if mask is not None else None, window, own_v_width=not trains,
     )
 
 

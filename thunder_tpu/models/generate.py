@@ -368,8 +368,8 @@ def _mla_with_cache(ap, x, cos_t, sin_t, cl, pos, cfg: Config, *, quantized=Fals
     at least ``cfg.latent_width`` (the paged pool's rows are padded to whole
     lane tiles): writes the new tokens' rows at ``[pos, pos + T)`` and attends.
     A whole prompt at the static position 0 attends its own expanded keys,
-    through the flash kernel where that takes the shapes (q, k and v padded
-    with zeros to one head size: exact); a later piece of a prompt the expanded
+    through the flash kernel where that takes the shapes (q and k a head of
+    ``head_size``, v and the result of ``v_head_dim``); a later piece the expanded
     keys of the whole cache; one token (T = 1) the cached rows themselves, in
     the absorbed form.  Returns ``(y, cl)``."""
     B, T, _ = x.shape
@@ -400,9 +400,9 @@ def _mla_with_cache(ap, x, cos_t, sin_t, cl, pos, cfg: Config, *, quantized=Fals
             if fresh and not sharded:
                 from thunder_tpu.executors import pallasex
 
-                flash = pallasex.flash_sdpa(q, k, pad_lanes(v, q.shape[-1]), None, True, cfg.attn_scale, None)
+                flash = pallasex.flash_sdpa(q, k, v, None, True, cfg.attn_scale, None)
                 if flash is not None:
-                    return flash[0][..., :cfg.v_head_dim]
+                    return flash[0]
             s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * cfg.attn_scale
             w = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(q.dtype)
             return jnp.einsum("bhqk,bhkd->bhqd", w, v)

@@ -3,6 +3,7 @@
     python3 tools/flash_tune.py [--shapes mistral,hybrid] [--blocks 512x512,512x256] [--check]
     python3 tools/flash_tune.py --serve [--cells trinity-mini]
     python3 tools/flash_tune.py --fit
+    python3 tools/flash_tune.py --latent [--check]
 
 For each shape and each (BQ, BK) (none given: what ``pallasex._flash_blocks``
 derives), one line: ms a call of ``_flash_fwd``, ``_flash_bwd_dq`` and
@@ -21,6 +22,14 @@ end); and, where a table's float32 scores fit the device, the form PR 33
 replaced.  ``--fit``: the two constants of ``_flash_blocks``' model, a grid
 step's us and a thousand listed pairs' ns, by least squares over Trinity-Mini's
 layer at its buckets and three exact lengths, in blocks of 256, 512 and 1024.
+``--latent``: a latent prompt's call at the two latent cells' heads and prefill
+buckets (heads of 192 over values of 128), a line a bucket and three forms
+each with its pads and slices inside the timed function: q/k and v as they
+are, q/k padded to 256 beside v at 128, and all three padded to 256 with the
+result sliced (the call before PR 54); us a call of everything the function
+runs, of ``_flash_fwd`` alone, the matrix passes a 128 x 128 tile of a block,
+and the first form's gain over the last.  ``--check`` holds the three to each
+other and to the float32 reference at the first bucket.
 Needs a TPU; exits non-zero if a geometry failed."""
 import argparse
 import os
@@ -47,6 +56,8 @@ SHAPES = {"mistral": (1, 32, 8, 8192, 128, 4096), "hybrid": (2, 16, 2, 8192, 256
 SERVE = {"mistral": (32, 8, 128, (4096,), 3584, (1024, 2048, 3072)),
          "olmo-hybrid": (30, 30, 128, (None,), 3328, (1024, 2048, 2560)),
          "trinity-mini": (32, 4, 128, (None, 2048), None, (3840, 5888, 7936, 9984))}
+# heads, (q/k, v) widths, prefill buckets: ``offline-digest`` (Xing4.0) and ``offline-longctx`` (A.X-K1)
+LATENT = {"xing4": (32, (192, 128), (5120, 6656, 8192)), "axk1": (64, (192, 128), (4096, 6144, 8192))}
 FIT_LENGTHS = (2560, 3584, 8192)     # beside Trinity-Mini's buckets: the other cells' longest, which a block divides
 REPS = 5
 
@@ -172,6 +183,55 @@ def time_serve(name):
         print(line, flush=True)
 
 
+def latent_forms(H, hs, hv):
+    """name -> (fn(q, k, v) -> out (H, T, hv), kernel widths): the three forms of a latent prompt's call."""
+    wide = px._pad128(hs)
+    pad = lambda x, w: px._pad_hs(x, x.shape[-1], w)   # noqa: E731
+    call = lambda q, k, v: px._flash_fwd(q, k, v, None, True, hs ** -0.5, H, H, None, 1)[0]   # noqa: E731
+    return {
+        "as they are": (call, (hs, hv)),
+        f"q/k at {wide}": (lambda q, k, v: call(pad(q, wide), pad(k, wide), v), (wide, hv)),
+        # generate._mla_with_cache's pad to q's width, _fwd_local's to whole tiles, and the two slices back
+        f"all at {wide}": (lambda q, k, v: call(pad(q, wide), pad(k, wide), pad(pad(v, hs), wide))[..., :hs][..., :hv],
+                           (wide, wide)),
+    }
+
+
+def time_latent(name, check):
+    H, (hs, hv), buckets = LATENT[name]
+    forms = latent_forms(H, hs, hv)
+    first, *_, last = forms
+    for n, T in enumerate(buckets):
+        ks = jax.random.split(jax.random.PRNGKey(T), 3)
+        q, k = (jax.random.normal(key, (H, T, hs), jnp.bfloat16) for key in ks[:2])
+        v = jax.random.normal(ks[2], (H, T, hv), jnp.bfloat16)
+        outs, total = {}, {}
+        for form, (fn, (wqk, wv)) in forms.items():
+            head = f"latent {name:5s} heads {H} T {T:5d} ({hs} | {hv}) {form:12s}"
+            try:
+                jitted = jax.jit(fn)
+                outs[form] = jax.block_until_ready(jitted(q, k, v))
+                schedule = dict(px.flash_schedule)
+                us = {op: ms * 1e3 for op, ms in kernel_ms(lambda: jax.block_until_ready(jitted(q, k, v)), REPS).items()}
+            except Exception as e:  # a width Mosaic refuses is a result of the search
+                print(f"{head}: FAILED {type(e).__name__}: {str(e)[:300]}", flush=True)
+                continue
+            total[form] = sum(us.values())
+            beside = ", ".join(f"{op} {t:.0f}" for op, t in sorted(us.items(), key=lambda kv: -kv[1]) if op != "_flash_fwd")
+            print(f"{head} [{-(-wqk // 128)} + {-(-wv // 128)} passes a tile, block {schedule['block_q']}x{schedule['block_k']}]:"
+                  f" {total[form]:8.0f} us a call, _flash_fwd {us.get('_flash_fwd', float('nan')):8.0f}"
+                  f" (beside it: {beside or 'nothing'})", flush=True)
+        for form in (f for f in total if last in total and f != last):
+            print(f"latent {name:5s} heads {H} T {T:5d}: {form} against {last}: {total[form] / total[last] - 1:+.3f}", flush=True)
+        if check and n == 0:
+            want, _ = _sdpa_reference(*(x.astype(jnp.float32)[None] for x in (q, k, v)), None, True, hs ** -0.5)
+            errs = {form: round(rel_err(out, want[0]), 5) for form, out in outs.items()}
+            print(f"check latent {name} T {T}: relative error against the float32 reference {errs}; the forms differ by "
+                  f"{max(rel_err(a, outs[first]) for a in outs.values()):.6f}", flush=True)
+            if max(errs.values()) > 0.02:
+                sys.exit("flash_tune: a latent form disagrees with the reference")
+
+
 def fit():
     """``pallasex._FLASH_STEP_US`` and ``_FLASH_KPAIR_NS``: us a head of ``_flash_fwd`` at Trinity-Mini's layer
     against its schedule's steps and listed pairs, every length in blocks of 256, 512 and 1024, by least
@@ -225,6 +285,7 @@ def main():
     ap.add_argument("--serve", action="store_true", help="the forward kernel at the serve cells' prefill buckets")
     ap.add_argument("--cells", default=",".join(SERVE), help="which of them")
     ap.add_argument("--fit", action="store_true", help="the two constants of _flash_blocks' model")
+    ap.add_argument("--latent", action="store_true", help="a latent prompt's call, keys and values at their own widths or padded")
     ap.add_argument("--window", type=int, help="another window for the mistral shape (to place _flash_blocks' rule)")
     args = ap.parse_args()
     device = device_info()
@@ -234,6 +295,10 @@ def main():
     print(device, flush=True)
     if args.window:
         SHAPES["mistral"] = (*SHAPES["mistral"][:5], args.window)
+    if args.latent:
+        for name in LATENT:
+            time_latent(name, args.check)
+        return
     if args.check and check() > 0.02:   # bfloat16 operands: 0.003-0.006
         sys.exit("flash_tune: the compiled kernels disagree with the reference")
     if args.fit:

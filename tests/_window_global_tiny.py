@@ -15,6 +15,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from chipbench import common  # noqa: E402
+from conftest import compiled_forward  # noqa: E402
 from thunder_tpu.models import generate as G  # noqa: E402
 from thunder_tpu.models import llama  # noqa: E402
 
@@ -43,10 +44,15 @@ def prompt(n: int, seed: int = 0) -> np.ndarray:
     return np.random.default_rng(seed).integers(0, HF["vocab_size"], (n,)).astype(np.int32)
 
 
-def dense_forward(cfg, params, toks, T_max=128, **kw):
+def dense_forward(cfg, params, toks, T_max=128, anew=False, **kw):
+    """The whole prompt through the dense cache, compiled; traced ``anew``, the weights the
+    arrays they are, where a test has planted a fault in the program's functions (some by
+    the identity of a weight): a callable kept from before would not hold it."""
     cos, sin = llama.build_rope_cache(cfg, T_max)
     cache = G.init_cache(cfg, 1, T_max, jnp.float32)
-    return G.forward_with_cache(params, jnp.asarray(toks)[None], 0, cache, cos, sin, cfg, **kw)
+    if anew:
+        return jax.jit(lambda t, c: G.forward_with_cache(params, t, 0, c, cos, sin, cfg, **kw))(jnp.asarray(toks)[None], cache)
+    return compiled_forward(cfg, **kw)(params, jnp.asarray(toks)[None], cache, cos, sin)
 
 
 def rel(got, want) -> float:
@@ -54,6 +60,11 @@ def rel(got, want) -> float:
     return float(np.sqrt(np.sum((got - want) ** 2) / np.sum(want ** 2)))
 
 
+# the reference as it is, a layer's own compiled calls inside one compiled call: one program a count of positions
+_ref_logits = jax.jit(functools.partial(arch.ref_logits, HF))
+
+
 def ref_logits(params, toks, positions, hf=HF):
+    ref = _ref_logits if hf is HF else functools.partial(arch.ref_logits, hf)
     with jax.default_matmul_precision("highest"):
-        return arch.ref_logits(hf, params, jnp.asarray(np.pad(toks, (0, 128 - len(toks)))), jnp.asarray(positions))
+        return ref(params, jnp.asarray(np.pad(toks, (0, 128 - len(toks)))), jnp.asarray(positions))

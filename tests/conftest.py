@@ -103,6 +103,38 @@ def in_each_attn_form(fn) -> list:
     return out
 
 
+_COMPILED_FORWARDS: dict = {}
+
+
+def compiled_forward(cfg, *, decode: bool = False, **static):
+    """``models.generate.forward_with_cache`` under ``jax.jit``: one callable a
+    (config, static options, attention form), kept for the worker, so that the
+    tests that run the same forward share a compile.  Called eagerly a served
+    model's forward dispatches every operation of every layer alone: a tiny
+    decoder-hybrid-decoder's 40 tokens took 8.7 s so and 1.3 s compiled, the
+    logits 1.5e-5 apart under a limit of 1e-4 of the largest (PR 55).
+
+    ``(params, tokens (B, T), cache, cos, sin)``: a whole prompt at the Python
+    ``0`` for which the program takes its fresh path; with ``decode``
+    ``(params, tokens, pos, cache, cos, sin)``, a piece at a traced position.
+    A test that reads ``pallasex.stats``, counts programs, plants a fault in
+    the program or compares the eager path itself calls ``forward_with_cache``."""
+    import jax
+
+    from thunder_tpu.models import generate as G
+
+    key = (repr(cfg), decode, tuple(sorted(static.items())), _os.environ.get(PALLAS_INTERPRET))
+    if key not in _COMPILED_FORWARDS:
+        if decode:
+            fn = lambda params, toks, pos, cache, cos, sin: G.forward_with_cache(  # noqa: E731
+                params, toks, pos, cache, cos, sin, cfg, **static)
+        else:
+            fn = lambda params, toks, cache, cos, sin: G.forward_with_cache(  # noqa: E731
+                params, toks, 0, cache, cos, sin, cfg, **static)
+        _COMPILED_FORWARDS[key] = jax.jit(fn)
+    return _COMPILED_FORWARDS[key]
+
+
 def prim_names(jaxpr, *, skip=("pallas_call",)) -> list:
     """``(primitive name, eqn)`` of every equation of a jaxpr, recursing into
     sub-jaxprs (pjit, custom_vjp, scan, ...) but not into pallas kernel bodies."""
@@ -150,30 +182,38 @@ def _a_file_leaves_the_environment_as_it_found_it():
     assert _os.environ.get(PALLAS_INTERPRET) == before, "a test of this file left THUNDER_TPU_PALLAS_INTERPRET changed"
 
 
+def _memory_mappings() -> tuple:
+    """``(this process's memory mappings, the most the kernel gives a process)``, zeros where ``/proc`` does not say."""
+    try:
+        with open("/proc/self/maps") as own, open("/proc/sys/vm/max_map_count") as most:
+            return sum(1 for _ in own), int(most.read())
+    except (OSError, ValueError):
+        return 0, 0
+
+
+@_pytest.fixture(autouse=True, scope="module")
+def _a_worker_drops_its_compiled_programs_before_it_runs_out_of_mappings():
+    # Every program XLA compiles for the CPU holds three or four memory mappings for as long as JAX keeps it, and JAX
+    # keeps them all.  A tier-1 worker compiles some sixteen thousand programs, Linux gives a process 65,530 mappings
+    # (vm.max_map_count), and the compile that asks for one more dies of a segmentation fault in
+    # ``backend_compile_and_load``, in whatever test runs then: 15 to 19 minutes into four whole runs of four
+    # (PR 55: a worker's count read 65,428 twenty seconds before it died).  Past half the limit a file's end drops
+    # the caches; what is still used compiles again.
+    yield
+    held, most = _memory_mappings()
+    if held > most // 2 > 0:
+        import jax
+
+        jax.clear_caches()
+
+
 def pytest_configure(config):
     # tier-1 runs with -m 'not slow'; soak/long-horizon tests opt out with it
     config.addinivalue_line("markers", "slow: long-running test, excluded from tier-1")
-    # xdist hands files out by their number of tests, largest first, unless told
-    # otherwise: the order below is to stand (no xdist, no such option)
-    if hasattr(config.option, "loadscopereorder"):
-        config.option.loadscopereorder = False
 
 
-# The tier-1 command runs `--dist loadfile`: a file is one worker's from start
-# to end, so the run lasts as long as the last long file to start.  xdist's own
-# order (by number of tests) starts a file of five long tests last; collection
-# order starts it late in the alphabet.  Start the long files first, longest
-# first (seconds a file on one of six busy workers, PR 44's tree: 493, 370, 305,
-# 302, 235, 233, 212, 208, 182, 151, 141, 121; the next is 103); everything
-# else keeps its order.
-_LONGEST_FIRST = (
-    "test_hybrid_moe.py", "test_sequence_parallel.py", "test_pallas_tpu_lowering.py", "test_ring_attention.py",
-    "test_train_cli.py", "test_mla_serving.py", "test_paged_attention.py", "test_shortconv_serving.py",
-    "test_hybrid_decoder_serving.py", "test_pallas.py", "test_paged_walk.py", "test_causal_conv.py", "test_hybrid_serving.py",
-    "test_hc_serving.py",
-)
-
-
-def pytest_collection_modifyitems(items):
-    rank = {name: i for i, name in enumerate(_LONGEST_FIRST)}
-    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
+# No order is given to the tests.  The tier-1 command (`commands` in /root/TESTS_LAST_RUN.json) runs six xdist workers
+# with `--dist load`, which hands a worker that runs dry a twelfth of what is left, in collection order, and takes
+# nothing back: the wall is the work over six plus one late hand-out.  Long files first and the cheap tests last, the
+# list that stood here for `--dist loadfile`, makes that hand-out the dearest (PR 55, replayed on one run's seconds:
+# 956-1,086 s for three such orders, 842 s as collected, 822-965 s for thirty shuffles; `CHANGES.md`).

@@ -22,6 +22,7 @@ import pytest
 
 import thunder_tpu as tt
 from chipbench import common
+from conftest import compiled_forward
 from thunder_tpu.executors import pallasex as px
 from thunder_tpu.models import generate as G
 from thunder_tpu.models import llama
@@ -54,7 +55,11 @@ def prompt(n: int, seed: int = 0) -> np.ndarray:
 def dense_forward(cfg, params, toks, T_max=128, **kw):
     cos, sin = llama.build_rope_cache(cfg, T_max)
     cache = G.init_cache(cfg, 1, T_max, jnp.float32)
-    return G.forward_with_cache(params, jnp.asarray(toks)[None], 0, cache, cos, sin, cfg, **kw)
+    return compiled_forward(cfg, **kw)(params, jnp.asarray(toks)[None], cache, cos, sin)
+
+
+# the reference as it is, a layer's own compiled calls inside one compiled call: one program a length
+ref_logits = jax.jit(functools.partial(arch.ref_logits, HF))
 
 
 def rel(got, want) -> float:
@@ -96,7 +101,7 @@ def test_every_kind_agrees_with_the_reference(T):
     toks = prompt(T)
     with jax.default_matmul_precision("highest"):
         logits, _ = dense_forward(cfg, params, toks)
-        want = arch.ref_logits(HF, params, jnp.asarray(toks), jnp.arange(T))
+        want = ref_logits(params, jnp.asarray(toks), jnp.arange(T))
         assert float(jnp.abs(logits[0] - want).max()) < 1e-4 * float(jnp.abs(want).max())
         n = min(77, T - 3)
         _, cache = dense_forward(cfg, params, toks[:n])
@@ -142,8 +147,9 @@ def test_prefill_then_decode_through_the_dense_cache_is_the_full_forward():
     cos, sin = llama.build_rope_cache(cfg, 128)
     lg, cache = dense_forward(cfg, params, toks[:60])
     errs = [float(jnp.abs(lg - full[:, :60]).max())]
+    step = compiled_forward(cfg, decode=True)
     for t in range(60, 100):
-        lg, cache = G.forward_with_cache(params, jnp.asarray(toks[t:t + 1])[None], jnp.int32(t), cache, cos, sin, cfg)
+        lg, cache = step(params, jnp.asarray(toks[t:t + 1])[None], jnp.int32(t), cache, cos, sin)
         errs.append(float(jnp.abs(lg[:, 0] - full[:, t]).max()))
     assert max(errs) < 2e-5
 
@@ -179,8 +185,7 @@ def test_served_tokens_are_solo_generates_and_the_references_best(kernels, monke
         np.testing.assert_array_equal(toks, solo)
         seq = np.concatenate([p, toks])
         with jax.default_matmul_precision("highest"):
-            lg = arch.ref_logits(HF, params, jnp.asarray(np.pad(seq, (0, 128 - len(seq)))),
-                                 jnp.arange(len(p) - 1, len(seq) - 1))
+            lg = ref_logits(params, jnp.asarray(np.pad(seq, (0, 128 - len(seq)))), jnp.arange(len(p) - 1, len(seq) - 1))
         short = np.asarray(jnp.max(lg, axis=-1) - jnp.take_along_axis(lg, jnp.asarray(toks)[:, None], axis=-1)[:, 0])
         assert float(short.max()) < 1e-3
     eng.shutdown(drain=False)

@@ -31,6 +31,7 @@ from thunder_tpu.serving.kv_pool import PagedKVPool, StatePool  # noqa: E402
 from thunder_tpu.serving.scheduler import Scheduler  # noqa: E402
 
 from _hybrid_tiny import TINY, arch, tiny_model, tokens as _tokens  # noqa: E402
+from conftest import compiled_forward  # noqa: E402
 
 # float32 sums in another order (the chunked scan against the token-by-token
 # recurrence, XLA's dots against the reference's): a head that remembers (alpha
@@ -134,10 +135,10 @@ def walked(model):
     seq = _tokens(60, seed=2)
     cache = G.init_cache(cfg, 1, 64, dtype=jnp.float32)
     cos, sin = llama.build_rope_cache(cfg, 64)
-    lg, cache = G.forward_with_cache(params, jnp.asarray(seq[None, :40]), 0, cache, cos, sin, cfg)
-    rows = [lg[0]]
+    lg, cache = compiled_forward(cfg)(params, jnp.asarray(seq[None, :40]), cache, cos, sin)
+    rows, step = [lg[0]], compiled_forward(cfg, decode=True)
     for i in range(40, 60):
-        lg, cache = G.forward_with_cache(params, jnp.asarray(seq[None, i:i + 1]), i, cache, cos, sin, cfg)
+        lg, cache = step(params, jnp.asarray(seq[None, i:i + 1]), i, cache, cos, sin)
         rows.append(lg[0])
     got = jnp.concatenate(rows)[:, :TINY["vocab_size"]]
     return got, arch.ref_logits(TINY, params, jnp.asarray(seq), jnp.arange(60)), cache

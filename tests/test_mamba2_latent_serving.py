@@ -23,6 +23,7 @@ import pytest
 
 import thunder_tpu as tt
 from chipbench import common
+from conftest import compiled_forward
 from thunder_tpu.executors import jaxex
 from thunder_tpu.executors import pallasex as px
 from thunder_tpu.models import generate as G
@@ -55,10 +56,19 @@ def prompt(n: int, seed: int = 0) -> np.ndarray:
     return np.random.default_rng(seed).integers(0, HF["vocab_size"], (n,)).astype(np.int32)
 
 
-def dense_forward(cfg, params, toks, T_max=256, **kw):
+def dense_forward(cfg, params, toks, T_max=256, anew=False, **kw):
+    """The whole prompt through the dense cache, compiled; traced ``anew`` for the test that
+    counts the kernels' claims, which are made as a call is traced: a kept callable makes none."""
     cos, sin = llama.build_rope_cache(cfg, T_max)
     cache = G.init_cache(cfg, 1, T_max, jnp.float32)
-    return G.forward_with_cache(params, jnp.asarray(toks)[None], 0, cache, cos, sin, cfg, **kw)
+    if anew:
+        return jax.jit(lambda p, t, c: G.forward_with_cache(p, t, 0, c, cos, sin, cfg, **kw))(
+            params, jnp.asarray(toks)[None], cache)
+    return compiled_forward(cfg, **kw)(params, jnp.asarray(toks)[None], cache, cos, sin)
+
+
+# the reference as it is, a layer's own compiled calls inside one compiled call: one program a length
+ref_logits = jax.jit(functools.partial(arch.ref_logits, HF))
 
 
 def rel(got, want) -> float:
@@ -113,8 +123,8 @@ def test_every_kind_agrees_with_the_reference(T, n_real, attn_form):
     n = n_real or T
     before = px.stats.get("ssd_chunk", 0)
     with jax.default_matmul_precision("highest"):
-        logits, cache = dense_forward(cfg, params, toks, **({"n_real": n_real} if n_real else {}))
-        want = arch.ref_logits(HF, params, jnp.asarray(toks), jnp.arange(n))
+        logits, cache = dense_forward(cfg, params, toks, anew=True, **({"n_real": n_real} if n_real else {}))
+        want = ref_logits(params, jnp.asarray(toks), jnp.arange(n))
         held = arch.ref_caches(HF, params, padded(toks), n)
     assert (px.stats.get("ssd_chunk", 0) > before) == (attn_form == "interpreted" and T % 128 == 0)
     assert float(jnp.abs(logits[0, :n] - want).max()) < 1e-4 * float(jnp.abs(want).max())
@@ -133,8 +143,9 @@ def test_prefill_then_decode_through_the_dense_cache_is_the_full_forward():
     cos, sin = llama.build_rope_cache(cfg, 256)
     lg, cache = dense_forward(cfg, params, toks[:60])
     errs = [float(jnp.abs(lg - full[:, :60]).max())]
+    step = compiled_forward(cfg, decode=True)
     for t in range(60, 100):
-        lg, cache = G.forward_with_cache(params, jnp.asarray(toks[t:t + 1])[None], jnp.int32(t), cache, cos, sin, cfg)
+        lg, cache = step(params, jnp.asarray(toks[t:t + 1])[None], jnp.int32(t), cache, cos, sin)
         errs.append(float(jnp.abs(lg[:, 0] - full[:, t]).max()))
     assert max(errs) < 2e-5
 
@@ -168,7 +179,7 @@ def test_served_tokens_are_solo_generates_and_the_references_best(attn_form):
         np.testing.assert_array_equal(toks, solo)
         seq = np.concatenate([p, toks])
         with jax.default_matmul_precision("highest"):
-            lg = arch.ref_logits(HF, params, padded(seq), jnp.arange(len(p) - 1, len(seq) - 1))
+            lg = ref_logits(params, padded(seq), jnp.arange(len(p) - 1, len(seq) - 1))
         short = np.asarray(jnp.max(lg, axis=-1) - jnp.take_along_axis(lg, jnp.asarray(toks)[:, None], axis=-1)[:, 0])
         assert float(short.max()) < 1e-3
     eng.shutdown(drain=False)

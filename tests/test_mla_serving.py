@@ -26,6 +26,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import thunder_tpu as tt  # noqa: E402
 from chipbench import common  # noqa: E402
+from conftest import compiled_forward  # noqa: E402
 from thunder_tpu.executors import pallasex as px  # noqa: E402
 from thunder_tpu.models import generate as G  # noqa: E402
 from thunder_tpu.models import llama  # noqa: E402
@@ -166,14 +167,15 @@ def test_forward_with_cache_gives_the_references_logits(model):
     toks = jnp.asarray(tokens(T, 1))
     ref = np.asarray(arch.ref_logits(TINY, params, toks, jnp.arange(T)))
     cos, sin = llama.build_rope_cache(cfg, 64)
-    whole, _ = G.forward_with_cache(params, toks[None], 0, G.init_cache(cfg, 1, 64, jnp.float32), cos, sin, cfg)
+    fresh, later = compiled_forward(cfg), compiled_forward(cfg, decode=True)
+    whole, _ = fresh(params, toks[None], G.init_cache(cfg, 1, 64, jnp.float32), cos, sin)
     np.testing.assert_allclose(np.asarray(whole[0]), ref, atol=2e-4)
     # a first piece, a later piece at a traced position (expanded over the cache), then tokens (absorbed)
-    _, cache = G.forward_with_cache(params, toks[None, :16], 0, G.init_cache(cfg, 1, 64, jnp.float32), cos, sin, cfg)
-    piece, cache = G.forward_with_cache(params, toks[None, 16:split], jnp.int32(16), cache, cos, sin, cfg)
+    _, cache = fresh(params, toks[None, :16], G.init_cache(cfg, 1, 64, jnp.float32), cos, sin)
+    piece, cache = later(params, toks[None, 16:split], jnp.int32(16), cache, cos, sin)
     np.testing.assert_allclose(np.asarray(piece[0]), ref[16:split], atol=2e-4)
     for t in range(split, T):
-        one, cache = G.forward_with_cache(params, toks[None, t:t + 1], jnp.asarray([t], jnp.int32), cache, cos, sin, cfg)
+        one, cache = later(params, toks[None, t:t + 1], jnp.asarray([t], jnp.int32), cache, cos, sin)
         np.testing.assert_allclose(np.asarray(one[0, 0]), ref[t], atol=2e-4)
     want = np.asarray(next(iter(arch.ref_latents(TINY, params, toks, T))))
     np.testing.assert_allclose(np.asarray(cache["latent"][0, 0, 0, :T]), want, atol=2e-5)

@@ -133,10 +133,10 @@ class TestExpertParallel:
 
         cfg, mp, x, mesh = self._mk()
         dense = np.asarray(tt.jit(lambda p, t: llama.moe_mlp(p, t, cfg))(mp, x))
-        out = ep.ep_moe_mlp(
-            mp, jnp.asarray(x), mesh=mesh, n_expert=cfg.n_expert,
+        out = jax.jit(lambda mp_, x_: ep.ep_moe_mlp(        # compiled, as every shard_map of this class: see below
+            mp_, x_, mesh=mesh, n_expert=cfg.n_expert,
             n_expert_per_token=cfg.n_expert_per_token, capacity_factor=8.0,
-        )
+        ))(mp, jnp.asarray(x))
         np.testing.assert_allclose(np.asarray(out), dense, rtol=1e-4, atol=1e-5)
 
     def test_grads_flow_through_all_to_all(self):
@@ -149,7 +149,8 @@ class TestExpertParallel:
                               n_expert_per_token=2, capacity_factor=8.0)
             return jnp.sum(y ** 2)
 
-        g = jax.grad(loss)(mp, jnp.asarray(x))
+        # compiled: eagerly each operation under the shard_map is dispatched alone
+        g = jax.jit(jax.grad(loss))(mp, jnp.asarray(x))
         leaves = jax.tree_util.tree_leaves(g)
         assert all(bool(jnp.all(jnp.isfinite(v))) for v in leaves)
         assert all(bool(jnp.any(v != 0)) for v in leaves)
@@ -158,8 +159,8 @@ class TestExpertParallel:
         from thunder_tpu.distributed import moe as ep
 
         cfg, mp, x, mesh = self._mk()
-        out = ep.ep_moe_mlp(mp, jnp.asarray(x), mesh=mesh, n_expert=cfg.n_expert,
-                            n_expert_per_token=2, capacity_factor=0.5)
+        out = jax.jit(lambda mp_, x_: ep.ep_moe_mlp(mp_, x_, mesh=mesh, n_expert=cfg.n_expert,
+                                                    n_expert_per_token=2, capacity_factor=0.5))(mp, jnp.asarray(x))
         assert bool(jnp.all(jnp.isfinite(out)))
 
 

@@ -8,13 +8,24 @@ against torch references for every op in ``tests/opinfos.py``, and every
 op's error-input generator must raise the documented exception type (the
 reference's error_input_generator axis).
 """
+import zlib
+
 import numpy as np
 import pytest
 import torch
 
 import thunder_tpu as tt
 
+import opinfos as _opinfos
 from opinfos import OpInfo, opinfos
+
+
+@pytest.fixture(autouse=True)
+def _a_tests_samples_are_its_own(request):
+    # ``opinfos._t`` draws from one generator of its module, so what a test drew depended on the tests its worker
+    # ran before it, which ``--dist load`` changes from run to run: ``floor_divide`` in bfloat16 met a quotient a
+    # rounding away from a whole number in one of PR 55's whole runs.  A generator a test, seeded by the test's name.
+    _opinfos.rng = np.random.default_rng(zlib.crc32(request.node.name.encode()))
 
 _f32_ids = [o.name for o in opinfos]
 _bf16_infos = [o for o in opinfos if o.supports_bf16]

@@ -249,7 +249,9 @@ def test_the_servers_scan_and_step_compile_at_the_published_heads(kernel, tpu_sh
     lane tile.  The scan reaches ``gdn_chunk_fwd`` padded to 128 and 256
     (zero columns: exact) instead of falling to the XLA form, at the longest
     prefill bucket; the step takes the arena's ``(96, 192)`` tiles as they
-    are, a row's thirty heads a grid step, 32 rows of 12 layers' arena."""
+    are, a row's thirty heads a grid step, 32 rows of 12 layers' arena.
+    Only the compile shows that Mosaic takes those blocks at these shapes and
+    that XLA's call keeps the arena aliased to its result."""
     monkeypatch.setattr(px, "_interpret", lambda: False)
     monkeypatch.setattr(px, "_enabled", lambda: True)
     H, dk, dv = 30, 96, 192
@@ -288,7 +290,9 @@ def test_conv_kernels_compile_at_the_published_widths(cell, kernel, tpu_sharding
     side by side over the hybrid cell's two sequences, ``(2, 8192, 8192)``, and
     of Olmo-Hybrid over a prompt, ``(1, 2560, 11520)`` (90 lane tiles: tiles of
     768 channels).  One kernel a call, no padded or float32 copy beside it: the
-    call's only temporaries are the backward pass's partial rows of ``dw``."""
+    call's only temporaries are the backward pass's partial rows of ``dw``:
+    only the compile shows them (``memory_analysis``), and that Mosaic takes
+    the tiles."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     B_, Ts, C = {"qwen3next_train": (2, 8192, 8192), "olmo_hybrid": (1, 2560, 11520)}[cell]
     x, w = ((B_, Ts, C), BF), ((C, 4), BF)
@@ -314,6 +318,9 @@ def test_conv_kernels_compile_at_the_published_widths(cell, kernel, tpu_sharding
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("kernel", CASES["gqa"])
 def test_kernel_lowers_and_compiles_for_tpu(shape, kernel, tpu_sharding):
+    """Every kernel at a small shape, lowered to a Mosaic call under its name.
+    Only the compile shows that Mosaic takes its blocks and operations (the
+    interpreter takes what the TPU compiler refuses) and what XLA names the call."""
     fn, specs = CASES[shape][kernel]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
     lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
@@ -344,7 +351,8 @@ def test_flash_fwd_compiles_at_the_docqa_buckets_with_a_ragged_last_block(T, win
     heads over 4 of 128, a global layer's call and a window layer's: odd
     multiples of 256, which ``_flash_blocks`` gives blocks of 1024 whose last
     reaches 256 rows past the end.  Mosaic compiles that for a v5e (VMEM
-    fits, a ragged block's copies are laid out); the tail's form of the body
+    fits, a ragged block's copies are laid out: what only the compile
+    shows); the tail's form of the body
     is in the module there and in none at 8192, which the block divides."""
     monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
     monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
@@ -367,7 +375,8 @@ def test_flash_fwd_compiles_with_values_at_their_own_width(heads, tpu_sharding, 
     ``_fwd_local`` builds it (PR 54): q/k a head of 192 as they are (a block's
     last dimension the array's whole width), v, the accumulator and the result
     at 128.  Blocks of 1024; the score product takes 192 lanes, the weighted
-    sum 128, and nothing in the module is 256 wide."""
+    sum 128, and nothing in the module is 256 wide.  Only the compile shows
+    that Mosaic lays out a block whose last dimension is 192 lanes."""
     monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
     monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
     lowered = _lowered_flash_fwd(heads, heads, 8192, 192, 128, tpu_sharding)
@@ -387,7 +396,8 @@ def test_flash_fwd_at_one_width_is_the_module_it_was(tpu_sharding, monkeypatch):
     """The unchanged path stays the unchanged path: 32 heads over 4 of 128 at
     8,192 (Trinity-Mini's layer, the shape the ragged cases above compare
     with) lowers to two forms of the body and no tail's, every operand, the
-    accumulator and the result 128 wide."""
+    accumulator and the result 128 wide.  The compile: that Mosaic still
+    takes the module the ragged cases are compared with."""
     monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
     monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
     lowered = _lowered_flash_fwd(32, 4, 8192, HS, HS, tpu_sharding)
@@ -409,7 +419,8 @@ def test_scan_kernels_at_the_hybrid_cell_take_rows_not_columns(kernel, tpu_shard
     float32 operand or result is a column of 128-lane tiles, 128 times its
     bytes in HBM and in the copies XLA puts before the call (268 MB each
     here); the backward call takes the state a block of 512 tokens and no
-    forward call comes before it."""
+    forward call comes before it.  Only the compile shows the layouts XLA
+    gives the call's operands and that the forward needs no temporary."""
     monkeypatch.setattr(px, "_interpret", lambda: False)
     monkeypatch.setattr(px, "_enabled", lambda: True)
     B_, Hk, Hv, Ts = 2, 16, 32, 8192
@@ -455,7 +466,9 @@ def test_decode_walk_compiles_at_offline_batch_shapes(variant, store, window, tp
     """``paged_attn_decode`` at the benchmark cell's own shapes (Mistral-7B
     heads 32/8, 32 rows, a table 224 blocks wide, the cell's pool): the chunk
     the kernel derives there, and the VMEM its buffers and its score tile
-    take, are checked by Mosaic without a chip.  The quantised stores walk
+    take, are checked by Mosaic without a chip (only the compile does, as it
+    alone shows the call the roofline reader matches and that no arena is
+    copied).  The quantised stores walk
     twice the blocks a chunk (C = 32) and add the two dequantised buffers."""
     nh, ng, rows, width, pool, layers = 32, 8, 32, 224, 6144, 16
     quantized = store != BF
@@ -499,7 +512,8 @@ def test_the_walk_compiles_at_every_cells_shapes_as_the_call_the_readers_find(ce
     is copied, and the compiled call is what
     ``chipbench/kernels/paged_attn_decode.py`` matches: the block table the
     first operand (the positions, ``chain`` and the layer after it), one
-    four-dimensional result."""
+    four-dimensional result.  All three are the compile's to show: Mosaic's
+    limits, XLA's temporaries, the call as XLA writes it."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     nh, ng, hs, rows, width, pool, layers, window, packed_out = WALK_CELLS[cell]
     groups = ng * hs // 128                                                     # the arena's rows a token
@@ -528,7 +542,8 @@ def test_the_walk_compiles_at_every_cells_shapes_as_the_call_the_readers_find(ce
 @pytest.mark.parametrize("shape", SHAPES)
 def test_decode_walk_compiles_at_other_widths(shape, hs, bs, store, tpu_sharding):
     """Whole lane tiles of head size, and a block of half a bfloat16 sublane
-    tile: what :func:`paged_attn_decode`'s docstring says the TPU takes."""
+    tile: what :func:`paged_attn_decode`'s docstring says the TPU takes, which
+    only Mosaic can say: the compile is the whole test."""
     if tpu_sharding is None:
         pytest.skip("no device-less TPU topology to compile for")
     nh, ng = SHAPES[shape]
@@ -548,7 +563,9 @@ def test_narrow_heads_decode_block_by_block(shape, hs, store, tpu_sharding, monk
     kernel in the lowering).  If the last check fails because Mosaic
     now compiles the walk, drop ``paged_walk_lanes_ok``.  (A head of 64 in a
     lane-packed arena is another arena: rows of 128 lanes, which the walk takes:
-    ``test_the_narrow_head_cells_kernels_compile_at_its_shapes``.)"""
+    ``test_the_narrow_head_cells_kernels_compile_at_its_shapes``.)  Both
+    compiles are Mosaic's answer, which no lowering holds: it takes the
+    per-block grid and raises on the walk's narrow slice."""
     nh, ng = {**SHAPES, "gpt2": (12, 12)}[shape]
     args = _decode_args(nh, ng, hs, BS, B, NBB, NB, L, store, tpu_sharding)
     assert not px.paged_walk_lanes_ok(hs) and px.paged_walk_lanes_ok(128)
@@ -573,7 +590,9 @@ def test_a_windowed_narrow_heads_decode_program_lowers_with_the_xla_form(hs, sto
     them, and heads of 96): the engine says "xla" when it is built, and the
     program lowers for the TPU with the attention gathered in XLA and the
     token writers still kernels (before PR 44: ``NotImplementedError`` from
-    the entry, kept off it by a second decode program)."""
+    the entry, kept off it by a second decode program).  The compile (a tiny
+    model: seconds) shows that XLA takes the gathered form beside the writers'
+    Mosaic calls."""
     import thunder_tpu as tt
     from thunder_tpu.models import llama
 
@@ -600,6 +619,30 @@ def test_a_windowed_narrow_heads_decode_program_lowers_with_the_xla_form(hs, sto
 # --------------------------------------------------------------------------
 # the serving engine's whole-prompt prefill, as the two serve cells build it
 # --------------------------------------------------------------------------
+
+# A whole program's compile for the v5e is XLA's passes over its plain part, and they grow with the bucket (A.X-K1's
+# two layers: 47.9 s at 4,096 tokens, 13.2 s at 1,024, 5.3 s at 256, the kernels alone 2.6-4.7 s at the full shapes;
+# PR 55).  What only that compile shows (XLA takes the program with its Mosaic calls among its own operations and
+# keeps each under the name a device trace shows) it shows at sixteen blocks of the pool; the lowering, with every
+# assertion on its text, stays at the cell's bucket, and the kernels are compiled alone at the cell's shapes.
+COMPILED_BUCKET = 256
+
+
+def _fresh_prefill(eng, weights, arenas, one, state_slots=True):
+    """``Tb -> (program, operands)``: an engine's ``prefill_fresh`` at a bucket, over shapes alone; ``state_slots``:
+    the engine leases a state slot a request and the program takes it."""
+    def at(Tb):
+        return eng._build_prefill(Tb, Tb // BS, fresh=True), (
+            weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)),
+            *([one((1,))] if state_slots else []))
+    return at
+
+
+def _compiled_prefill(prefill):
+    """``prefill(Tb) -> (program, operands)`` compiled for the v5e at ``COMPILED_BUCKET``."""
+    prog, args = prefill(COMPILED_BUCKET)
+    return prog.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+
 
 SERVE_CELLS = {   # cell -> (layers lowered, full-attention layers among them, a prefill bucket, its flash blocks)
     "mistral7b-serve-1chip.offline-batch": (2, 2, 3072, 1024),
@@ -651,7 +694,9 @@ def test_a_whole_prompts_prefill_lowers_to_flash_and_a_head_of_one_row(cell, kin
     gather from the K/V arenas, no float32 tensor of (heads, bucket, table
     width), and a head product of one row.  The ``prefill`` kind beside it
     still gathers the table and scores all of it, with the head on one row
-    too (both take ``logits_at``)."""
+    too (both take ``logits_at``).  Compiled at the cell's bucket, since the
+    compile alone shows the program's temporaries there (under one layer's
+    float32 scores) and the call as the readers meet it."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     layers, full, Tb, block = SERVE_CELLS[cell]
     cfg, eng, nbb, lowered, claims = _lower_prefill(cell, kind, tpu_sharding)
@@ -720,7 +765,9 @@ def test_the_latent_kernels_compile_at_the_cells_shapes(kernel, tpu_sharding, mo
     what ``mla_chunk_keys`` derives and fit the budget it states; the rows are
     held and the queries streamed, so the module transposes nothing.  ``32heads``:
     the Xing4.0 cell's walk, 32 rows of 32 heads over a table 524 blocks wide and
-    an arena of 16,784 blocks (60 operations a byte: bound by bytes alone)."""
+    an arena of 16,784 blocks (60 operations a byte: bound by bytes alone).
+    Only the compile shows Mosaic taking the buffers and the table at these
+    shapes, no temporary beside the call, and the call as XLA writes it."""
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     kernel, _, heads = kernel.partition("/")
     rows, width, pool, layers, nh, W, dc = 64, 640, 32768, 6, {"": 64, "128heads": 128, "32heads": 32}[heads], 640, 512
@@ -799,7 +846,9 @@ def test_the_latent_cells_programs_lower_to_their_kernels(cell, kind, tpu_shardi
     and lands its rows through one ``mla_latent_write``; no arena is gathered.
     The Xing4.0 cell: 32 heads, all 64 experts of 3584 x 1024 a layer, the stream
     four wide under hyper-connections (plain XLA between the kernels), its 8,192
-    bucket and its 32-row step."""
+    bucket and its 32-row step.  Only the compile shows XLA taking the program
+    with the Mosaic calls among its own operations and keeping each under its
+    name: the prompt's at ``COMPILED_BUCKET``, the step's as it is."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     if cell == HC_CELL:
@@ -810,9 +859,9 @@ def test_the_latent_cells_programs_lower_to_their_kernels(cell, kind, tpu_shardi
     one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
     weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
     before = dict(px.stats)
+    prefill = _fresh_prefill(eng, weights, arenas, one, state_slots=False)
     if kind == "prefill_fresh":
-        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
-        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)))
+        prog, args = prefill(Tb)
     else:
         prog = eng._build_decode_paged(rows, width)
         args = (weights, one((rows,)), one((rows,)), one((rows, width)), arenas, one((rows, 2), jnp.uint32), {},
@@ -840,7 +889,7 @@ def test_the_latent_cells_programs_lower_to_their_kernels(cell, kind, tpu_shardi
         assert claimed("mla_decode") == cfg.n_layer
         assert text.count('kernel_name = "mla_latent_write"') == 1
     if tpu_sharding is not None:
-        hlo = lowered.compile().as_text()
+        hlo = (_compiled_prefill(prefill) if kind == "prefill_fresh" else lowered.compile()).as_text()
         names = ("_flash_fwd",) if kind == "prefill_fresh" else ("mla_paged_decode", "mla_latent_write")
         for name in (*names, "moe_grouped_mm"):
             assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
@@ -860,7 +909,8 @@ def test_the_narrow_head_cells_kernels_compile_at_its_shapes(kernel, tpu_shardin
     cell's arena of 47,104 blocks of 16 tokens, 8 KV heads of 64 two to a
     128-lane row (``(blocks, 3, 4, 16, 128)``), 32 query heads: the walk's copies
     are whole-tile slabs, nothing of the arena is copied, and no per-block
-    kernel stands in."""
+    kernel stands in.  Only the compile shows Mosaic taking 65,536 entries of
+    scalar memory and the slabs, and no temporary beside the walk."""
     kernel, _, windowed = kernel.partition("/")
     rows, width, pool, layers, nh, ng, hs = 256, 256, 47104, 3, 32, 8, 64
     arena, tab, pos = ((pool, layers, ng // 2, BS, 128), BF), ((rows, width), I32), ((rows,), I32)
@@ -911,7 +961,9 @@ def test_the_narrow_head_cells_programs_lower_to_their_kernels(kind, tpu_shardin
     ``paged_attn_decode`` once an attention layer, routes through
     ``moe_grouped_mm`` and lands its K and V through one ``paged_token_write``
     each; the conv layers are XLA's, their tails a slot's rows; no arena is
-    gathered and no per-block kernel is called."""
+    gathered and no per-block kernel is called.  Only the compile shows XLA
+    taking the program with its Mosaic calls and keeping each under its name:
+    the prompt's at ``COMPILED_BUCKET``, the step's as it is."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     cfg, params, eng = _lfm2_engine()
@@ -924,10 +976,10 @@ def test_the_narrow_head_cells_programs_lower_to_their_kernels(kind, tpu_shardin
     one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
     weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
     before = dict(px.stats)
+    prefill = _fresh_prefill(eng, weights, arenas, one)
     if kind == "prefill_fresh":
         Tb = 2560
-        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
-        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)), one((1,)))
+        prog, args = prefill(Tb)
     else:
         prog = eng._build_decode_paged(256, 256)
         args = (weights, one((256,)), one((256,)), one((256, 256)), arenas, one((256, 2), jnp.uint32), {}, one((256,)),
@@ -945,7 +997,7 @@ def test_the_narrow_head_cells_programs_lower_to_their_kernels(kind, tpu_shardin
         assert text.count('kernel_name = "paged_attn_decode"') == len(cfg.kv_layers)
         assert text.count('kernel_name = "paged_token_write"') == 2
     if tpu_sharding is not None:
-        hlo = lowered.compile().as_text()
+        hlo = (_compiled_prefill(prefill) if kind == "prefill_fresh" else lowered.compile()).as_text()
         names = ("_flash_fwd",) if kind == "prefill_fresh" else ("paged_attn_decode", "paged_token_write")
         for name in (*names, "moe_grouped_mm"):
             assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
@@ -976,7 +1028,9 @@ def test_the_window_global_cells_programs_lower_to_their_kernels(kind, tpu_shard
     walks the slot's ring (129 entries of the table's 720) through
     ``paged_attn_decode`` in the window layers and the request's own blocks in the
     global one, and lands its K and V through one ``paged_token_write`` an arena:
-    two for the rings, two for the blocks; no arena is gathered."""
+    two for the rings, two for the blocks; no arena is gathered.  Only the
+    compile shows XLA taking the program with its Mosaic calls and keeping each
+    under its name: the prompt's at ``COMPILED_BUCKET``, the step's as it is."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     cfg, params, eng = _window_global_engine()
@@ -988,10 +1042,10 @@ def test_the_window_global_cells_programs_lower_to_their_kernels(kind, tpu_shard
     one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
     weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
     before = dict(px.stats)
+    prefill = _fresh_prefill(eng, weights, arenas, one)
     if kind == "prefill_fresh":
         Tb = 3840
-        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
-        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)), one((1,)))
+        prog, args = prefill(Tb)
     else:
         prog = eng._build_decode_paged(2, 720)
         args = (weights, one((2,)), one((2,)), one((2, 720)), arenas, one((2, 2), jnp.uint32), {}, one((2,)),
@@ -1011,7 +1065,7 @@ def test_the_window_global_cells_programs_lower_to_their_kernels(kind, tpu_shard
         assert text.count('kernel_name = "paged_attn_decode"') == 2 and claimed("paged_walk") == 4
         assert 1 <= text.count('kernel_name = "paged_token_write"') <= 4
     if tpu_sharding is not None:
-        hlo = lowered.compile().as_text()
+        hlo = (_compiled_prefill(prefill) if kind == "prefill_fresh" else lowered.compile()).as_text()
         names = ("_flash_fwd",) if kind == "prefill_fresh" else ("paged_attn_decode", "paged_token_write")
         for name in (*names, "moe_grouped_mm"):
             assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
@@ -1037,7 +1091,8 @@ GMM_SHAPES = {
 def test_the_grouped_product_compiles_inside_the_vmem_it_asks_for(case, transpose_w, tpu_sharding, monkeypatch):
     """``moe_grouped_mm`` at the widths the two serving cells run it, the
     whole matrix the block (7.3 MB at LFM2's widths, 29.4 MB at A.X-K1's):
-    Mosaic takes each inside the scoped limit the call states.  Where the
+    Mosaic takes each inside the scoped limit the call states, a limit only
+    the compile holds it to.  Where the
     device's VMEM is not known the call states none and the column blocks
     it falls back to compile inside the default."""
     K, N, TM, nt, groups, known = GMM_SHAPES[case]
@@ -1113,7 +1168,9 @@ def test_the_hybrid_decoder_cells_kernels_compile_at_its_shapes(kernel, tpu_shar
     kept); the step on 96 rows' slots of the nine layers' arena, in place; the
     differential walk (two softmaxes a head pair, ``packed_out``) over the one
     global layer's blocks at a table of 552 and over a ring's at a window of
-    512: whole-tile slabs, no arena copied."""
+    512: whole-tile slabs, no arena copied.  Only the compile shows Mosaic
+    holding the state in VMEM at these widths, the step's arena aliased to its
+    result and no temporary beside the walk."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     kernel, _, which = kernel.partition("/")
     d, N, rows, width = 5120, 16, 96, 552
@@ -1174,7 +1231,9 @@ def test_the_hybrid_decoder_cells_programs_lower_to_their_kernels(kind, tpu_shar
     step of 96 rows runs ``ssm_decode_step`` a scan layer, walks the rings and the
     one global layer's blocks through ``paged_attn_decode`` (the cross layer
     walks the global layer's: one more call, no more arena), and lands K and V
-    through one ``paged_token_write`` a kind each; no arena is gathered."""
+    through one ``paged_token_write`` a kind each; no arena is gathered.  Only
+    the compile shows XLA taking the program with its Mosaic calls and keeping
+    each under its name: the prompt's at ``COMPILED_BUCKET``, the step's as it is."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     cfg, params, eng = _flash_engine()
@@ -1189,10 +1248,10 @@ def test_the_hybrid_decoder_cells_programs_lower_to_their_kernels(kind, tpu_shar
     one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
     weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
     before = dict(px.stats)
+    prefill = _fresh_prefill(eng, weights, arenas, one)
     if kind == "prefill_fresh":
         Tb = 5120
-        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
-        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)), one((1,)))
+        prog, args = prefill(Tb)
     else:
         prog = eng._build_decode_paged(96, 552)
         args = (weights, one((96,)), one((96,)), one((96, 552)), arenas, one((96, 2), jnp.uint32), {}, one((96,)),
@@ -1215,7 +1274,7 @@ def test_the_hybrid_decoder_cells_programs_lower_to_their_kernels(kind, tpu_shar
         assert len(re.findall(r"call @_paged_decode_call", text)) == 4
         assert text.count('kernel_name = "paged_token_write"') == 4              # K and V of the paged kind and of the ring
     if tpu_sharding is not None:
-        hlo = lowered.compile().as_text()
+        hlo = (_compiled_prefill(prefill) if kind == "prefill_fresh" else lowered.compile()).as_text()
         names = ("_flash_fwd", "ssm_scan_fwd") if kind == "prefill_fresh" else (
             "paged_attn_decode", "paged_token_write", "ssm_decode_step")
         for name in names:
@@ -1231,7 +1290,9 @@ def test_the_mamba2_cells_kernels_compile_at_its_shapes(kernel, tpu_sharding, mo
     of 64 channels in 8 groups, 128 states; a group's ``(128, 1024)`` float32 state
     carried in VMEM across the token blocks, the last state out), in bfloat16 and
     at full precision (a float32 witness); the step on 128 rows' slots of the five
-    layers' arena, ``(128, 8192)`` = 4.19 MB a slot a layer, in place."""
+    layers' arena, ``(128, 8192)`` = 4.19 MB a slot a layer, in place.  Only
+    the compile shows Mosaic carrying the state in VMEM at these shapes, the
+    arena aliased to the step's result and no copy of it."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     kernel, _, which = kernel.partition("/")
     H, P, G, N, rows, Ts = 128, 64, 8, 128, 128, 5120
@@ -1285,7 +1346,9 @@ def test_the_mamba2_cells_programs_lower_to_their_kernels(kind, tpu_sharding, mo
     projects the head for one row; a decode step runs ``ssd_decode_step`` a Mamba-2
     layer on the state arena in place, walks the attention layer's blocks through
     ``paged_attn_decode`` and returns the expert share's running sums; no arena is
-    gathered."""
+    gathered.  Only the compile shows XLA taking the program with its Mosaic calls
+    and keeping each under its name: the prompt's at ``COMPILED_BUCKET``, the
+    step's as it is."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)
@@ -1298,10 +1361,10 @@ def test_the_mamba2_cells_programs_lower_to_their_kernels(kind, tpu_sharding, mo
     one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
     weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
     before = dict(px.stats)
+    prefill = _fresh_prefill(eng, weights, arenas, one)
     if kind == "prefill_fresh":
         Tb = 5120
-        prog = eng._build_prefill(Tb, Tb // BS, fresh=True)
-        args = (weights, one((1, Tb)), one(()), arenas, one((Tb // BS,)), one((2,), jnp.uint32), {}, one((1,)), one((1,)))
+        prog, args = prefill(Tb)
     else:
         prog = eng._build_decode_paged(8, 496)
         args = (weights, one((8,)), one((8,)), one((8, 496)), arenas, one((8, 2), jnp.uint32), {}, one((8,)),
@@ -1321,10 +1384,11 @@ def test_the_mamba2_cells_programs_lower_to_their_kernels(kind, tpu_sharding, mo
         assert text.count('kernel_name = "paged_attn_decode"') == 1
         assert text.count('kernel_name = "paged_token_write"') == 2
     if tpu_sharding is not None:
-        compiled = lowered.compile()
+        compiled = _compiled_prefill(prefill) if kind == "prefill_fresh" else lowered.compile()
         hlo = compiled.as_text()
         names = ("_flash_fwd", "ssd_chunk_fwd", "moe_grouped_mm") if kind == "prefill_fresh" else (
             "paged_attn_decode", "paged_token_write", "ssd_decode_step", "moe_grouped_mm")
         for name in names:
             assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
-        print(kind, "temporaries", compiled.memory_analysis().temp_size_in_bytes)
+        print(kind, f"temporaries (a bucket of {COMPILED_BUCKET})" if kind == "prefill_fresh" else "temporaries",
+              compiled.memory_analysis().temp_size_in_bytes)

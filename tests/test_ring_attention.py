@@ -3,6 +3,10 @@
 Beyond-reference capability (the reference has no sequence parallelism,
 SURVEY §2.6): blockwise ring attention over ``sp`` must reproduce the
 single-device softmax exactly — forward and gradients.
+
+Every ring here runs under ``jax.jit``: eagerly, each operation inside the
+``shard_map`` is dispatched alone over the eight virtual devices (the gradients'
+test took 95 s so, and takes seconds compiled, to the same tolerances).
 """
 import jax
 import jax.numpy as jnp
@@ -42,7 +46,7 @@ def _qkv(B=2, H=2, T=64, hs=16, dtype=np.float32):
 def test_matches_single_device(causal):
     q, k, v = _qkv()
     mesh = dist.make_mesh({"sp": 8})
-    got = ring_attention(q, k, v, mesh=mesh, causal=causal)
+    got = jax.jit(lambda q, k, v: ring_attention(q, k, v, mesh=mesh, causal=causal))(q, k, v)
     ref = _ref_attention(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-6)
 
@@ -50,7 +54,7 @@ def test_matches_single_device(causal):
 def test_composes_with_other_axes():
     q, k, v = _qkv(T=32)
     mesh = dist.make_mesh({"dp": 2, "sp": 4})
-    got = ring_attention(q, k, v, mesh=mesh, axis="sp", causal=True)
+    got = jax.jit(lambda q, k, v: ring_attention(q, k, v, mesh=mesh, axis="sp", causal=True))(q, k, v)
     ref = _ref_attention(q, k, v, True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-6)
 
@@ -65,7 +69,7 @@ def test_gradients_match_single_device():
     def ref_loss(q, k, v):
         return jnp.sum(_ref_attention(q, k, v, True) ** 2)
 
-    g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
+    g_ring = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
     g_ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
     for gr, gf in zip(g_ring, g_ref):
         np.testing.assert_allclose(np.asarray(gr), np.asarray(gf), rtol=1e-4, atol=1e-5)
@@ -75,7 +79,7 @@ def test_bf16_inputs():
     q, k, v = _qkv(dtype=np.float32)
     q, k, v = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
     mesh = dist.make_mesh({"sp": 8})
-    got = ring_attention(q, k, v, mesh=mesh, causal=True)
+    got = jax.jit(lambda q, k, v: ring_attention(q, k, v, mesh=mesh, causal=True))(q, k, v)
     ref = _ref_attention(q, k, v, True)
     np.testing.assert_allclose(
         np.asarray(got, dtype=np.float32), np.asarray(ref, dtype=np.float32), rtol=5e-2, atol=5e-2
@@ -87,7 +91,9 @@ def test_self_attention_layer():
     x = jnp.asarray(rng.standard_normal((B, T, C)).astype(np.float32))
     wq, wk, wv, wo = (jnp.asarray(rng.standard_normal((C, C)).astype(np.float32) * 0.1) for _ in range(4))
     mesh = dist.make_mesh({"sp": 8})
-    got = ring_self_attention(x, wq, wk, wv, wo, mesh=mesh, n_head=H)
+    got = jax.jit(
+        lambda x, wq, wk, wv, wo: ring_self_attention(x, wq, wk, wv, wo, mesh=mesh, n_head=H)
+    )(x, wq, wk, wv, wo)
 
     q = (x @ wq.T).reshape(B, T, H, C // H).transpose(0, 2, 1, 3)
     k = (x @ wk.T).reshape(B, T, H, C // H).transpose(0, 2, 1, 3)
@@ -104,15 +110,14 @@ def test_sliding_window_exact_and_skips_far_steps(window):
     needs 2 resident blocks (1 k/v rotation), not the full 8-step ring."""
     q, k, v = _qkv(T=64)  # sp=8 -> t_loc=8
     mesh = dist.make_mesh({"sp": 8})
-    got = ring_attention(q, k, v, mesh=mesh, causal=True, window=window)
+    ring = lambda q, k, v: ring_attention(q, k, v, mesh=mesh, causal=True, window=window)  # noqa: E731
+    got = jax.jit(ring)(q, k, v)
     ref = _ref_attention(q, k, v, True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-6)
 
     t_loc = 8
     expected_steps = min(8, 1 if window <= 1 else (window - 2) // t_loc + 2)
-    jaxpr = str(jax.make_jaxpr(
-        lambda q, k, v: ring_attention(q, k, v, mesh=mesh, causal=True, window=window)
-    )(q, k, v))
+    jaxpr = str(jax.make_jaxpr(ring)(q, k, v))
     # one k + one v ppermute per rotation; the last step does not rotate
     assert jaxpr.count("ppermute") == 2 * (expected_steps - 1), (window, expected_steps)
 

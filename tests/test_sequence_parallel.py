@@ -1,7 +1,12 @@
 """Sequence-parallel training on the virtual 8-device mesh: the full llama
 loss under one shard_map over ``sp`` (``distributed/sp.py``) against the
-single-device train step.  A file of its own (the kernels' tests are in
-``test_ring_attention.py``) so that ``--dist loadfile`` can give it a worker.
+single-device train step.  The kernels' tests are in ``test_ring_attention.py``.
+
+Every loss here runs under ``jax.jit``: called eagerly, each operation inside
+the ``shard_map`` is dispatched alone over the four virtual devices, and the
+five tests took one worker 746 s of tier-1's run (``--dist load``, six workers,
+cut at 1,470 s on PR 54's tree) where they take under a minute compiled, to
+the same loss.
 """
 import jax
 import jax.numpy as jnp
@@ -14,8 +19,6 @@ class TestSequenceParallelTraining:
     """Full llama loss under one shard_map over sp (distributed/sp.py)."""
 
     def _setup(self, **over):
-        from thunder_tpu.models import llama
-
         cfg = llama.Config.from_name("tiny-llama-debug", **over)
         params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
         B, T = 2, 32
@@ -28,7 +31,6 @@ class TestSequenceParallelTraining:
         import optax
 
         from thunder_tpu import distributed as dist
-        from thunder_tpu.models import llama
 
         mesh1 = dist.make_mesh({"dp": 1}, devices=jax.devices()[:1])
         step = dist.make_train_step(
@@ -37,26 +39,27 @@ class TestSequenceParallelTraining:
         )
         return step.grads(params, step.init_optimizer_state(params), idx, tgt, cos, sin)
 
-    def test_sp_loss_matches_single_device(self):
+    def _sp_loss(self, cfg, loss_fn=None):
+        """``loss_fn`` (``dist.sp_gpt_loss`` unless given) over ``sp=4`` as a
+        function of the arrays alone, for ``jax.jit`` and ``jax.grad``."""
         from thunder_tpu import distributed as dist
 
+        loss_fn = loss_fn or dist.sp_gpt_loss
+        mesh = dist.make_mesh({"sp": 4}, devices=jax.devices()[:4])
+        return lambda p, idx, tgt, cos, sin: loss_fn(p, idx, tgt, cos, sin, cfg, mesh=mesh)
+
+    def test_sp_loss_matches_single_device(self):
         cfg, params, idx, tgt, cos, sin = self._setup()
         ref_loss, _ = self._ref(cfg, params, idx, tgt, cos, sin)
 
-        mesh = dist.make_mesh({"sp": 4}, devices=jax.devices()[:4])
-        loss = dist.sp_gpt_loss(params, idx, tgt, cos, sin, cfg, mesh=mesh)
+        loss = jax.jit(self._sp_loss(cfg))(params, idx, tgt, cos, sin)
         assert abs(float(loss) - float(ref_loss)) < 1e-4
 
     def test_sp_grads_match_single_device(self):
-        from thunder_tpu import distributed as dist
-
         cfg, params, idx, tgt, cos, sin = self._setup()
         ref_loss, ref_grads = self._ref(cfg, params, idx, tgt, cos, sin)
 
-        mesh = dist.make_mesh({"sp": 4}, devices=jax.devices()[:4])
-        loss, grads = jax.value_and_grad(
-            lambda p: dist.sp_gpt_loss(p, idx, tgt, cos, sin, cfg, mesh=mesh)
-        )(params)
+        loss, grads = jax.jit(jax.value_and_grad(self._sp_loss(cfg)))(params, idx, tgt, cos, sin)
         assert abs(float(loss) - float(ref_loss)) < 1e-4
         jax.tree_util.tree_map(
             lambda g, r: np.testing.assert_allclose(
@@ -66,28 +69,22 @@ class TestSequenceParallelTraining:
         )
 
     def test_sp_gqa_config(self):
-        from thunder_tpu import distributed as dist
-
         cfg, params, idx, tgt, cos, sin = self._setup(n_head=4, n_query_groups=2)
         ref_loss, _ = self._ref(cfg, params, idx, tgt, cos, sin)
-        mesh = dist.make_mesh({"sp": 4}, devices=jax.devices()[:4])
-        loss = dist.sp_gpt_loss(params, idx, tgt, cos, sin, cfg, mesh=mesh)
+        loss = jax.jit(self._sp_loss(cfg))(params, idx, tgt, cos, sin)
         assert abs(float(loss) - float(ref_loss)) < 1e-4
 
     def test_sp_sliding_window_matches_single_device(self):
         # an sp loss that drops the window computes full causal attention for
         # sliding-window (Mistral-family) configs, silently.  The window must
         # thread into the ring and match the fused-SDPA reference numerics.
-        from thunder_tpu import distributed as dist
-
         cfg, params, idx, tgt, cos, sin = self._setup(sliding_window=8)
         ref_loss, _ = self._ref(cfg, params, idx, tgt, cos, sin)
-        mesh = dist.make_mesh({"sp": 4}, devices=jax.devices()[:4])
-        loss = dist.sp_gpt_loss(params, idx, tgt, cos, sin, cfg, mesh=mesh)
+        loss = jax.jit(self._sp_loss(cfg))(params, idx, tgt, cos, sin)
         assert abs(float(loss) - float(ref_loss)) < 1e-4
         # the band must actually bite at T=32 > window=8: dropping it diverges
         nowin = llama.Config.from_name("tiny-llama-debug")
-        full = dist.sp_gpt_loss(params, idx, tgt, cos, sin, nowin, mesh=mesh)
+        full = jax.jit(self._sp_loss(nowin))(params, idx, tgt, cos, sin)
         assert abs(float(full) - float(ref_loss)) > 1e-4
 
     def test_ulysses_sliding_window_matches_ring(self):
@@ -95,6 +92,5 @@ class TestSequenceParallelTraining:
 
         cfg, params, idx, tgt, cos, sin = self._setup(sliding_window=8)
         ref_loss, _ = self._ref(cfg, params, idx, tgt, cos, sin)
-        mesh = dist.make_mesh({"sp": 4}, devices=jax.devices()[:4])
-        loss = dist.ulysses_gpt_loss(params, idx, tgt, cos, sin, cfg, mesh=mesh)
+        loss = jax.jit(self._sp_loss(cfg, dist.ulysses_gpt_loss))(params, idx, tgt, cos, sin)
         assert abs(float(loss) - float(ref_loss)) < 1e-4

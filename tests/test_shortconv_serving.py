@@ -27,6 +27,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import thunder_tpu as tt  # noqa: E402
 from chipbench import common  # noqa: E402
+from conftest import compiled_forward  # noqa: E402
 from thunder_tpu.executors import pallasex as px  # noqa: E402
 from thunder_tpu.models import generate as G  # noqa: E402
 from thunder_tpu.models import llama  # noqa: E402
@@ -227,12 +228,12 @@ def test_forward_with_cache_gives_the_references_logits(model):
     cos, sin = llama.build_rope_cache(cfg, 64)
     cache = G.init_cache(cfg, 1, 64, dtype=jnp.float32)
     assert set(cache) == {"k", "v", "conv"}
-    lg, cache = G.forward_with_cache(params, jnp.asarray(seq[None]), 0, cache, cos, sin, cfg)
+    lg, cache = compiled_forward(cfg)(params, jnp.asarray(seq[None]), cache, cos, sin)
     want = arch.ref_logits(TINY, params, jnp.asarray(seq), jnp.arange(48))
     assert float(jnp.max(jnp.abs(lg[0, :, :256] - want))) < 2e-4
     # then a token through the cache
     nxt = np.asarray(jnp.argmax(lg[0, -1]))[None].astype(np.int32)
-    lg1, _ = G.forward_with_cache(params, jnp.asarray(nxt[None]), 48, cache, cos, sin, cfg)
+    lg1, _ = compiled_forward(cfg, decode=True)(params, jnp.asarray(nxt[None]), 48, cache, cos, sin)
     want1 = arch.ref_logits(TINY, params, jnp.asarray(np.concatenate([seq, nxt])), jnp.arange(48, 49))
     assert float(jnp.max(jnp.abs(lg1[0, :, :256] - want1))) < 2e-4
 
@@ -241,13 +242,11 @@ def test_a_prompt_in_two_pieces_is_the_prompt_in_one(model):
     cfg, params = model
     seq = tokens(48, 1)
     cos, sin = llama.build_rope_cache(cfg, 64)
-    whole, held = G.forward_with_cache(params, jnp.asarray(seq[None]), 0, G.init_cache(cfg, 1, 64, dtype=jnp.float32),
-                                       cos, sin, cfg)
+    whole, held = compiled_forward(cfg)(params, jnp.asarray(seq[None]), G.init_cache(cfg, 1, 64, dtype=jnp.float32), cos, sin)
     cache = G.init_cache(cfg, 1, 64, dtype=jnp.float32)
     # the first piece padded to 24 with 20 real tokens, as a bucket pads it
-    a, cache = G.forward_with_cache(params, jnp.asarray(np.concatenate([seq[:20], seq[:4]])[None]), 0, cache, cos, sin,
-                                    cfg, n_real=20)
-    b, cache = G.forward_with_cache(params, jnp.asarray(seq[None, 20:]), 20, cache, cos, sin, cfg)
+    a, cache = compiled_forward(cfg, n_real=20)(params, jnp.asarray(np.concatenate([seq[:20], seq[:4]])[None]), cache, cos, sin)
+    b, cache = compiled_forward(cfg, decode=True)(params, jnp.asarray(seq[None, 20:]), 20, cache, cos, sin)
     np.testing.assert_allclose(np.asarray(cache["conv"]), np.asarray(held["conv"]), atol=1e-5)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([a[:, :20], b], axis=1)), np.asarray(whole), atol=2e-4)
     for n, (kind, want) in zip(range(5), arch.ref_caches(TINY, params, jnp.asarray(seq), 48)):

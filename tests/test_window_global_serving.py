@@ -32,6 +32,7 @@ from thunder_tpu.models import llama
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _window_global_tiny import (  # noqa: E402
     BS, HF, KINDS, W, arch, dense_forward, model, prompt, ref_logits, rel)
+from conftest import compiled_forward  # noqa: E402
 
 # --------------------------------------------------------------------------
 # the config and the layout
@@ -124,8 +125,9 @@ def test_prefill_then_decode_through_the_dense_cache_is_the_references_full_forw
     cos, sin = llama.build_rope_cache(cfg, 128)
     lg, cache = dense_forward(cfg, params, toks[:12])       # inside the window: decode carries it across the edge
     errs = [float(jnp.abs(lg[0] - want[:12]).max())]
+    step = compiled_forward(cfg, decode=True)
     for t in range(12, 48):
-        lg, cache = G.forward_with_cache(params, jnp.asarray(toks[t:t + 1])[None], jnp.int32(t), cache, cos, sin, cfg)
+        lg, cache = step(params, jnp.asarray(toks[t:t + 1])[None], jnp.int32(t), cache, cos, sin)
         errs.append(float(jnp.abs(lg[0, 0] - want[t]).max()))
     assert max(errs) < tol
 
@@ -137,17 +139,17 @@ def test_a_global_layer_is_untouched_by_the_positions_rotation():
     toks = prompt(40, 11)
     _, cache = dense_forward(cfg, params, toks)
     cos, sin = llama.build_rope_cache(cfg, 128)
-    shifted = G.forward_with_cache(params, jnp.asarray(toks)[None], jnp.int32(7), G.init_cache(cfg, 1, 128, jnp.float32),
-                                   cos, sin, cfg)[1]
+    shifted = compiled_forward(cfg, decode=True)(params, jnp.asarray(toks)[None], jnp.int32(7),
+                                                 G.init_cache(cfg, 1, 128, jnp.float32), cos, sin)[1]
     # layer 0 is a window layer whose input is the embedding (the same at both offsets): rotated by position
     assert rel(shifted["k"][0, 0][:, 7:47], cache["k"][0, 0][:, :40]) > 0.3
     np.testing.assert_allclose(shifted["v"][0, 0][:, 7:47], cache["v"][0, 0][:, :40], atol=1e-6)
     # a model of global layers alone: its first layer's keys (the embedding's) are the same at any offset
     hf = {**HF, "layer_types": ["full_attention"] * 4}
     gcfg = llama.Config(**arch.program_config(hf))
-    a, ca = G.forward_with_cache(params, jnp.asarray(toks)[None], 0, G.init_cache(gcfg, 1, 128, jnp.float32), cos, sin, gcfg)
-    _, cb = G.forward_with_cache(params, jnp.asarray(toks)[None], jnp.int32(9), G.init_cache(gcfg, 1, 128, jnp.float32),
-                                 cos, sin, gcfg)
+    a, ca = compiled_forward(gcfg)(params, jnp.asarray(toks)[None], G.init_cache(gcfg, 1, 128, jnp.float32), cos, sin)
+    _, cb = compiled_forward(gcfg, decode=True)(params, jnp.asarray(toks)[None], jnp.int32(9),
+                                                G.init_cache(gcfg, 1, 128, jnp.float32), cos, sin)
     np.testing.assert_allclose(cb["k"][0, 0][:, 9:49], ca["k"][0, 0][:, :40], atol=1e-6)
     want = ref_logits(params, toks, np.arange(40), hf)
     assert float(jnp.abs(a[0] - want).max()) < 1e-4 * float(jnp.abs(want).max())
@@ -184,7 +186,7 @@ def test_each_piece_of_the_block_fails_the_comparison_when_left_out(piece, monke
         params = {**params, "blocks": [
             {**bp, "mlp": {**bp["mlp"], "shared": jax.tree_util.tree_map(jnp.zeros_like, bp["mlp"]["shared"])}}
             if "shared" in bp["mlp"] else bp for bp in params["blocks"]]}
-    got = dense_forward(cfg, params, toks)[0][0]
+    got = dense_forward(cfg, params, toks, anew=True)[0][0]        # traced anew: the planted fault is in it
     assert float(jnp.abs(got - want).max()) > 100 * 1e-4 * spread, piece
 
 

@@ -845,10 +845,14 @@ def test_the_latent_cells_programs_lower_to_their_kernels(cell, kind, tpu_shardi
     ``mla_paged_decode`` once a layer, ``moe_grouped_mm`` for the expert layer
     and lands its rows through one ``mla_latent_write``; no arena is gathered.
     The Xing4.0 cell: 32 heads, all 64 experts of 3584 x 1024 a layer, the stream
-    four wide under hyper-connections (plain XLA between the kernels), its 8,192
-    bucket and its 32-row step.  Only the compile shows XLA taking the program
-    with the Mosaic calls among its own operations and keeping each under its
-    name: the prompt's at ``COMPILED_BUCKET``, the step's as it is."""
+    four wide under hyper-connections, its 8,192 bucket and its 32-row step: a
+    prompt's boundaries between sublayers are ``hc_mix`` calls (the first open,
+    a joined close and open at each of the others, the last close one row's and
+    XLA's), a step's rows keep to XLA's fusions.  Only the compile shows XLA
+    taking the program with the Mosaic calls among its own operations and
+    keeping each under its name, ``hc_mix`` under the ``hc/`` scope its two
+    readers look for, with no float32 copy of the stream left beside it: the
+    prompt's at ``COMPILED_BUCKET``, the step's as it is."""
     monkeypatch.setattr(px, "_enabled", lambda: True)
     monkeypatch.setattr(px, "_pallas_available", lambda: True)
     if cell == HC_CELL:
@@ -888,11 +892,49 @@ def test_the_latent_cells_programs_lower_to_their_kernels(cell, kind, tpu_shardi
     else:
         assert claimed("mla_decode") == cfg.n_layer
         assert text.count('kernel_name = "mla_latent_write"') == 1
+    if cell == HC_CELL:     # a layer's two sublayers a boundary each, and the close after the last
+        fused = 2 * cfg.n_layer if kind == "prefill_fresh" else 0
+        assert (claimed("hc_fused"), claimed("hc_fallback")) == (fused, 2 * cfg.n_layer + 1 - fused)
+        assert ('kernel_name = "hc_mix"' in text) == bool(fused)
     if tpu_sharding is not None:
         hlo = (_compiled_prefill(prefill) if kind == "prefill_fresh" else lowered.compile()).as_text()
         names = ("_flash_fwd",) if kind == "prefill_fresh" else ("mla_paged_decode", "mla_latent_write")
         for name in (*names, "moe_grouped_mm"):
             assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
+        if cell == HC_CELL and kind == "prefill_fresh":
+            calls = re.findall(r"%hc_mix(?:\.\d+)? = [^\n]*", hlo)
+            assert len(calls) == 2 * cfg.n_layer and all(re.search(r'op_name="[^"]*/hc/(open|join)/', c) for c in calls), calls
+            wide = rf"f32\[1,{cfg.hc_mult},{COMPILED_BUCKET},{cfg.n_embd}\]"
+            assert re.search(wide.replace("f32", "bf16"), hlo) and not [
+                line for line in hlo.splitlines() if re.search(wide, line) and re.search(r'op_name="[^"]*/hc/', line)]
+
+
+@pytest.mark.parametrize("form,T", [("open", 8192), ("join", 8192), ("close", 8192), ("join", 5000)],
+                         ids=["open", "join", "close", "join/ragged"])
+def test_the_hyper_connections_boundary_compiles_at_the_cells_shapes(form, T, tpu_sharding, monkeypatch):
+    """``hc_mix`` at the Xing4.0 cell's widths, four streams of 3,584 in bfloat16, an 8,192-token prompt (and a
+    length whose last tile is ragged): the first open, a joined close and open, the last close.  Only the compile
+    shows Mosaic taking the three turned tiles, the products that contract both last axes and the unaligned rows
+    of the maps' block, all inside the scoped limit the call states (a quarter of a v5e core's VMEM)."""
+    monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)      # a v5e's, which the chip reports
+    n, C = 4, 3584
+    m = n * (n + 2)
+    sds = lambda shape, dt=BF: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    x = sds((1, n, T, C))
+    owed = None if form == "open" else (sds((1, T, C)), (sds((n, 1, T), F32), sds((n, n, 1, T), F32)))
+    hp = None if form == "close" else {"phi": sds((m, n * C)), "norm": sds((n * C,)), "alpha": sds((3,), F32), "bias": sds((m,), F32)}
+    before = dict(px.stats)
+    lowered = jax.jit(lambda x, owed, hp: px.hc_mix(x, owed, hp, eps=1e-6, iters=20, clamp=(-30.0, 30.0))).trace(
+        x, owed, hp).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert px.stats["hc_fused"] - before.get("hc_fused", 0) == 1 and px.hc_schedule["block_tokens"] == 128
+    assert px.hc_schedule["grid_steps"] == -(-T // 128)
+    assert text.count("tpu_custom_call") == 1 and 'kernel_name = "hc_mix"' in text
+    limit = px.hc_schedule["vmem_limit_bytes"]
+    assert px._GMM_VMEM_DEFAULT < limit <= 32 << 20 and f'\\22size\\22: {limit}}}' in text       # the scoped limit the call states
+    assert f"tensor<1x{n}x{T}x{C}xbf16>" in text and f"tensor<1x{n}x{T}x{C}xf32>" not in text    # no float32 copy of the stream beside the call
+    if tpu_sharding is not None:
+        assert re.search(r"%hc_mix(\.\d+)? = ", lowered.compile().as_text())
 
 
 # --------------------------------------------------------------------------
@@ -1130,7 +1172,7 @@ def test_every_pallas_call_site_is_named():
     import inspect
 
     src = inspect.getsource(px)
-    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 22      # PR 45: the Mamba-2 scan's two
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 23      # PR 45: the Mamba-2 scan's two; PR 56: hc_mix
     assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
         "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
         "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",

@@ -46,7 +46,7 @@ from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_
 __all__ = [
     "ex", "pallas_ex", "flash_sdpa", "flash_sdpa_backward",
     "paged_attn_decode", "paged_token_write", "paged_available", "paged_head_size_ok", "mla_paged_decode",
-    "gdn_chunk", "gdn_chunk_state", "gdn_decode_step", "grouped_mm", "grouped_mm_dw", "causal_conv1d", "causal_conv1d_backward",
+    "gdn_chunk", "gdn_chunk_state", "gdn_decode_step", "grouped_mm", "grouped_mm_dw", "causal_conv1d", "causal_conv1d_backward", "hc_mix",
 ]
 
 # exp(MASK_VALUE - lse) underflows to 0 without the inf-inf NaN hazard of -inf
@@ -3955,6 +3955,262 @@ def ssd_decode_step(arena, slots, x, dt, Bm, Cm, A, *, layer: int):
         **kwargs,
     )(slots.astype(jnp.int32), a[:, None], xdt[:, None], Bm.astype(f32), Cm.astype(f32), arena)
     return y[:, 0], arena
+
+
+# ---------------------------------------------------------------------------
+# A hyper-connection's boundary between two sublayers, one pass over the stream:
+# ``hc_mix`` closes the sublayer that ended and opens the one that begins.
+#
+#   close   X' = H_res X + H_post^T f                      float32, rounded to the stream's dtype, written once
+#   open    r = rsqrt(mean_nC(X'^2) + eps)                 on X' as rounded: what ``hc_open`` reads back from memory
+#           z = scale * (phi X') r + bias                   (n (n + 2), TQ): the tokens on the lanes
+#           H_pre = sigmoid(z), H_post = 2 sigmoid(z), H_res = Sinkhorn(exp(clip(z)))
+#           u = H_pre X'                                    float32 as summed: the sublayer's norm rounds it, once
+#
+# (``models.generate.hc_maps`` / ``hc_open`` / ``hc_close`` are the same lines in
+# ``jax.numpy``: the fallback, and what the tests hold this to.)  Grid (B, T /
+# TQ); a grid step holds all ``n`` streams of ``TQ`` tokens, ``(n, TQ, C)``, and
+# ``phi`` stays where the first step put it.  A map is a few numbers a token and
+# lives a row a map with the tokens on the lanes, in HBM ``(B, n + n n, T)``
+# float32: ``[H_post | H_res]``, what the next boundary's close reads.  The
+# mixing wants a token's number down a column, so the rows are turned in the
+# kernel (a transpose of ``(128, TQ)`` float32: data moves, nothing is summed, so
+# a ragged tile's rows past the end stay their own).  Two loops walk the tile
+# ``rows`` tokens at a time, a lane tile at a time, so the float32 terms of a
+# step stay near the registers: the close (with the squares of what it rounds),
+# and, once the maps are known, ``u`` from the block the close wrote.  Between
+# them the products run on the matrix unit: a bfloat16 stream is exact as it
+# stands, so ``phi`` comes as three bfloat16 pieces (``generate._stream_product``)
+# and each is one pass with float32 sums; a float32 stream takes the unit's own
+# multi-pass float32.  Either half may be absent (the model's first open has
+# nothing to close, its last close opens nothing): a static flag, one kernel.
+# A token's numbers depend on its own row alone.
+# ---------------------------------------------------------------------------
+
+# On one v5e at the Xing4.0 cell's widths (four streams of 3,584, an 8,192-token prompt's twelve sublayers: 11.2 ms,
+# 0.58 of the count's roofline, which leaves ``f`` and ``u`` out; PERF.md, PR 56) the copies bind: with the close's
+# loop, the products, the iterations and the read taken out one at a time or all together the prompt takes as long
+# (10.1-10.4 ms of 10.4 with ``u`` at 16 bits: 7.3 GB at 717 GB/s; 7.75 GB at 692 as it is).  So the tile is the
+# shortest (128 and 256 tokens run alike, 512 a tenth slower and over half of VMEM), and the lane tiles a loop turn
+# the fewest that keep the arithmetic under the copies (1: 12.5 ms; 2: 10.9; 4, 7, 14: 10.4; all 28 unrolled: 10.2): a
+# turn's lines are traced and lowered once a form and a program, and all 28 cost a start 11 s at three buckets.
+_HC_TILE = 128          # tokens a grid step
+_HC_LANE_TILES = 4      # lane tiles a loop turn, the most
+
+
+def _hc_vmem(TQ: int, n: int, C: int, itemsize: int) -> int:
+    """Bytes a grid step of both halves holds: the stream's block in and out,
+    what the sublayer gave and what the next one reads (float32), the maps and ``phi``
+    (three pieces of a 16-bit stream), twice each (the pipeline's two buffers);
+    the three turned tiles; the products' float32 sums; and what Mosaic keeps
+    beside them."""
+    m, nm, pieces = n * (n + 2), n * (n + 1), 3 if itemsize == 2 else 1
+    blocks = (2 * n + 1) * TQ * C * itemsize + TQ * C * 4 + 2 * nm * TQ * 4 + pieces * m * n * C * itemsize
+    return 2 * blocks + 3 * 128 * TQ * 4 + 4 * pieces * m * TQ * 4 + _GMM_VMEM_MARGIN
+
+
+def _hc_tile(T: int, n: int, C: int, itemsize: int) -> int | None:
+    """Tokens a grid step of ``hc_mix`` holds, or None where the shapes are not
+    the kernel's: under a tile of tokens (a decode step's rows: XLA fuses a
+    sublayer's fifty small operations into a handful), a ``C`` that is not
+    whole lane tiles, no room in the VMEM the kernel may ask for."""
+    if C % 128 or n < 2 or T < _HC_TILE or _hc_vmem(_HC_TILE, n, C, itemsize) > _gmm_vmem_cap():
+        return None
+    return _HC_TILE
+
+
+def _hc_lanes(l, K: int):
+    """The ``K`` lane tiles of loop turn ``l``."""
+    return [pl.ds(pl.multiple_of(l * (128 * K) + k * 128, 128), 128) for k in range(K)]
+
+
+def _hc_mix_kernel(*refs, n, close, opens, eps, iters, clamp):
+    f32 = jnp.float32
+    refs = list(refs)
+    x_ref = refs.pop(0)
+    f_ref, m_ref = (refs.pop(0), refs.pop(0)) if close else (None, None)
+    w_ref, sb_ref = (refs.pop(0), refs.pop(0)) if opens else (None, None)
+    xo_ref = refs.pop(0) if close else None
+    u_ref, mo_ref = (refs.pop(0), refs.pop(0)) if opens else (None, None)
+    rows_ref, cols_ref, ss_ref = refs
+    dt = x_ref.dtype
+    TQ, C = x_ref.shape[2], x_ref.shape[3]
+    m, nm = n * (n + 2), n * (n + 1)
+    R = _sublane_rows(dt)
+    K = next(k for k in range(_HC_LANE_TILES, 0, -1) if (C // 128) % k == 0)
+    down = lambda tile, k: jnp.broadcast_to(tile[:, k:k + 1], (R, 128))  # noqa: E731 -- map k of the rows' tokens
+
+    if close:       # the closing maps, a token's down a column
+        rows_ref[0:nm, :] = m_ref[0]
+        cols_ref[...] = rows_ref[...].T
+
+    def mix(r, carry):
+        rows = pl.ds(pl.multiple_of(r * R, R), R)
+        if close:
+            tile = cols_ref[rows, :]
+            post = [down(tile, i) for i in range(n)]
+            res = [[down(tile, n + i * n + j) for j in range(n)] for i in range(n)]
+
+        def lane_tiles(l, ss):
+            for lanes in _hc_lanes(l, K):
+                xs = [x_ref[0, j, rows, lanes].astype(f32) for j in range(n)]
+                if close:
+                    ff = f_ref[0, rows, lanes].astype(f32)
+                    for i in range(n):
+                        o = res[i][0] * xs[0]
+                        for j in range(1, n):
+                            o = o + res[i][j] * xs[j]
+                        o = (o + post[i] * ff).astype(dt)
+                        xo_ref[0, i, rows, lanes] = o
+                        if opens:
+                            o = o.astype(f32)
+                            ss = ss + o * o
+                elif opens:
+                    for xj in xs:
+                        ss = ss + xj * xj
+            return ss
+
+        ss = jax.lax.fori_loop(0, C // (128 * K), lane_tiles, jnp.zeros((R, 128), f32))
+        if opens:
+            ss_ref[rows, :] = jnp.broadcast_to(jnp.sum(ss, axis=1, keepdims=True), (R, 128))
+        return carry
+
+    jax.lax.fori_loop(0, TQ // R, mix, 0)
+    if not opens:
+        return
+    src = xo_ref if close else x_ref
+    exact = {"precision": jax.lax.Precision.HIGHEST} if dt == f32 else {}
+    acc = None
+    for j in range(n):      # (pieces m, TQ): both operands' last axes contract, no slab is turned
+        p = jax.lax.dot_general(w_ref[j], src[0, j], _AB_T, preferred_element_type=f32, **exact)
+        acc = p if acc is None else acc + p
+    raw = acc[0:m]
+    for k in range(1, w_ref.shape[1] // m):
+        raw = raw + acc[k * m:(k + 1) * m]
+    r = jax.lax.rsqrt(ss_ref[...].T[0:1, :] * (1.0 / (n * C)) + eps)                   # (1, TQ)
+    z = (raw * r) * sb_ref[:, 0:1] + sb_ref[:, 1:2]
+    gate = _sigmoid(z[0:2 * n], True)
+    gate = gate * jnp.where(jax.lax.broadcasted_iota(jnp.int32, (2 * n, 1), 0) < n, 1.0, 2.0)     # [H_pre | H_post]
+    to = jnp.exp(jnp.clip(z[2 * n:], clamp[0], clamp[1]))
+    to = [to[i * n:(i + 1) * n] for i in range(n)]                                      # to stream i, from j down the sublanes
+
+    def sinkhorn(_, to):
+        cols = to[0]
+        for t in to[1:]:
+            cols = cols + t
+        to = [t / (cols + eps) for t in to]
+        return tuple(t / (jnp.sum(t, axis=0, keepdims=True) + eps) for t in to)
+
+    to = jax.lax.fori_loop(0, iters, sinkhorn, tuple(to))
+    mo_ref[0, 0:n, :] = gate[n:2 * n]
+    for i in range(n):
+        mo_ref[0, n + i * n:n + (i + 1) * n, :] = to[i]
+    rows_ref[0:n, :] = gate[0:n]
+    cols_ref[...] = rows_ref[...].T
+
+    def read(r, carry):
+        rows = pl.ds(pl.multiple_of(r * R, R), R)
+        tile = cols_ref[rows, :]
+        pre = [down(tile, j) for j in range(n)]
+
+        def lane_tiles(l, carry):
+            for lanes in _hc_lanes(l, K):
+                u = pre[0] * src[0, 0, rows, lanes].astype(f32)
+                for j in range(1, n):
+                    u = u + pre[j] * src[0, j, rows, lanes].astype(f32)
+                u_ref[0, rows, lanes] = u
+            return carry
+
+        return jax.lax.fori_loop(0, C // (128 * K), lane_tiles, carry)
+
+    jax.lax.fori_loop(0, TQ // R, read, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "iters", "clamp", "TQ"))
+def _hc_mix(x, owed, hp, *, eps, iters, clamp, TQ):
+    B, n, T, C = x.shape
+    f32, dt = jnp.float32, x.dtype
+    m, nm = n * (n + 2), n * (n + 1)
+    close, opens = owed is not None, hp is not None
+    stream = pl.BlockSpec((1, n, TQ, C), lambda b, t: (b, 0, t, 0))
+    slab = pl.BlockSpec((1, TQ, C), lambda b, t: (b, t, 0))
+    maps = pl.BlockSpec((1, nm, TQ), lambda b, t: (b, 0, t))
+    operands, in_specs, out_specs, out_shape = [x], [stream], [], []
+    if close:
+        f, (h_post, h_res) = owed
+        held = jnp.concatenate([h_post, h_res.reshape(n * n, B, T)]).transpose(1, 0, 2)      # (B, n + n n, T)
+        operands += [f, held]
+        in_specs += [slab, maps]
+        out_specs.append(stream)
+        out_shape.append(jax.ShapeDtypeStruct(x.shape, dt))
+    if opens:
+        phi = (hp["phi"].astype(f32) * hp["norm"].astype(f32)).reshape(m, n, C)
+        pieces = []
+        for _ in range(1 if dt == f32 else 3):      # three bfloat16 pieces hold float32's 24 bits
+            pieces.append(phi.astype(dt))
+            phi = phi - pieces[-1].astype(f32)
+        w = jnp.concatenate(pieces).transpose(1, 0, 2)                                      # (n, pieces m, C)
+        scale = jnp.repeat(hp["alpha"].astype(f32), np.array([n, n, n * n]), total_repeat_length=m)
+        operands += [w, jnp.stack([scale, hp["bias"].astype(f32)], axis=1)]
+        in_specs += [pl.BlockSpec(w.shape, lambda b, t: (0, 0, 0)), pl.BlockSpec((m, 2), lambda b, t: (0, 0))]
+        out_specs += [slab, maps]
+        out_shape += [jax.ShapeDtypeStruct((B, T, C), f32), jax.ShapeDtypeStruct((B, nm, T), f32)]
+    params = {}
+    if not _interpret():
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_hc_vmem(TQ, n, C, dt.itemsize))
+    out = pl.pallas_call(
+        functools.partial(_hc_mix_kernel, n=n, close=close, opens=opens, eps=eps, iters=iters, clamp=clamp),
+        name="hc_mix",
+        grid=(B, pl.cdiv(T, TQ)),
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((128, TQ), f32), pltpu.VMEM((TQ, 128), f32), pltpu.VMEM((TQ, 128), f32)],
+        interpret=_interpret(),
+        **params,
+    )(*operands)
+    out = list(out)
+    xo = out.pop(0) if close else x
+    if not opens:
+        return xo, None, None
+    u, held = out
+    held = held.transpose(1, 0, 2)
+    return xo, u, (held[:n], held[n:].reshape(n, n, B, T))
+
+
+# what the last boundary built was laid out as, or why it kept to ``jax.numpy`` (trace time; a dict of its own, as ``flash_schedule``)
+hc_schedule: dict = {}
+
+
+def hc_mix(x, owed, hp, *, eps: float, iters: int, clamp: tuple, why: str = ""):
+    """The stream ``x (B, n, T, C)`` through one boundary between two sublayers,
+    read once: the close of the sublayer that ended (``owed``: ``(f (B, T, C),
+    (H_post (n, B, T), H_res (n, n, B, T)))``, what it gave and the maps its open
+    returned; None at the model's first open) and the open of the one that
+    begins (``hp``: its ``phi``, ``norm``, ``alpha``, ``bias``; None at the last
+    close).  Returns ``(x', u, (H_post, H_res))`` as ``generate.hc_close`` then
+    ``hc_open`` give them (``u`` in float32; it and the maps None without an open half), or None
+    where the caller's ``jax.numpy`` lines are to run: it has a reason of its
+    own (``why``: a mesh, planted maps), Pallas is off, the operands live on
+    several devices, or the shapes are not the kernel's (:func:`_hc_tile`).
+    ``stats["hc_fused"]`` / ``["hc_fallback"]`` count the boundaries (trace
+    time); ``hc_schedule`` keeps the last one's tile or reason."""
+    B, n, T, C = x.shape
+    itemsize = x.dtype.itemsize
+    if not why:
+        why = ("no Pallas" if not _enabled() else "several devices" if not _gmm_dispatchable(x)
+               else "dtype" if str(x.dtype) not in ("bfloat16", "float32") else "")
+    TQ = None if why else _hc_tile(T, n, C, itemsize)
+    if TQ is None:
+        stats["hc_fallback"] = stats.get("hc_fallback", 0) + 1
+        hc_schedule.clear()
+        hc_schedule.update(fallback=why or "shape", tokens=T, width=C)
+        return None
+    stats["hc_fused"] = stats.get("hc_fused", 0) + 1
+    hc_schedule.clear()
+    hc_schedule.update(block_tokens=TQ, grid_steps=B * -(-T // TQ),
+                       vmem_limit_bytes=_hc_vmem(TQ, n, C, itemsize))
+    return _hc_mix(x, owed, hp, eps=float(eps), iters=int(iters), clamp=tuple(float(c) for c in clamp), TQ=TQ)
 
 
 # install the fast paths so XLA fusion regions and TrainStep trace evaluation

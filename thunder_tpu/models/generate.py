@@ -55,6 +55,7 @@ __all__ = [
     "hc_maps",
     "hc_open",
     "hc_close",
+    "hc_step",
     "moe_share_mlp",
     "moe_row_tile",
     "route_sigmoid_group",
@@ -1197,30 +1198,67 @@ def hc_maps(hp, x, cfg: Config):
 
 def hc_open(hp, x, cfg: Config):
     """What a sublayer reads of the stream ``x (B, n, T, C)``: ``u = H_pre X (B, T,
-    C)`` at the stream's dtype, summed in float32, and the two maps its
-    :func:`hc_close` writes back by.  The dense cache's forward and the paged
-    server's open every sublayer here (under ``mixer`` or ``mlp``)."""
+    C)`` in float32 as it was summed, and the two maps its :func:`hc_close`
+    writes back by.  The sublayer's norm rounds ``u`` to the stream's dtype,
+    once: a copy of it at 16 bits in between is a second rounding of every
+    sublayer's input, which XLA's fusion of these lines drops on the chip and a
+    kernel that wrote one would not (PERF.md, PR 56: the deepest latents' error
+    7.5% up with it, 2% without).  :func:`hc_step` opens every sublayer (under
+    ``mixer`` or ``mlp``), here or in its kernel."""
     with scope("hc/open"):
         h_pre, h_post, h_res = hc_maps(hp, x, cfg)
         with scope("read"):
             u = sum(h_pre[j][..., None] * x[:, j].astype(jnp.float32) for j in range(x.shape[1]))
-            return u.astype(x.dtype), (h_post, h_res)
+            return u, (h_post, h_res)
 
 
 def hc_close(x, f, maps):
     """The stream after a sublayer gave ``f (B, T, C)``: ``H_res X + H_post^T f
     (B, n, T, C)``, in float32, stored at the stream's dtype.  No
-    ``optimization_barrier`` writes a prompt's stream out, as :func:`_close_block`
+    ``optimization_barrier`` writes the stream out, as :func:`_close_block`
     writes a sandwich norm's sums: the rounding to the stream's dtype ends each
-    sublayer's fusion, and an 8,192-token prompt's program holds the same 1.195 GB
-    of temporaries with a barrier here as without (its buffer assignment for a
-    v5e; PERF.md, PR 53)."""
+    sublayer's fusion (with these lines at every boundary an 8,192-token
+    prompt's program held the same 1.195 GB of temporaries with a barrier here
+    as without: its buffer assignment for a v5e; PERF.md, PR 53.  Since PR 56 a
+    prompt's boundaries are :func:`hc_step`'s kernel and the program holds 0.991
+    GB; these lines run a decode step's rows and whatever else falls back)."""
     h_post, h_res = maps
     n = x.shape[1]
     with scope("hc/close"):
         xf, ff = x.astype(jnp.float32), f.astype(jnp.float32)
         return jnp.stack([sum(h_res[i, j][..., None] * xf[:, j] for j in range(n)) + h_post[i][..., None] * ff
                           for i in range(n)], axis=1).astype(x.dtype)
+
+
+_HC_MAPS = hc_maps      # whoever plants another (the benchmark's controls, the tests') gets what it planted
+
+
+def hc_step(hp, xs, cfg: Config, *, sharded=False):
+    """The stream through one boundary between two sublayers.  ``xs``: ``(x, owed)``,
+    the stream ``(B, n, T, C)`` and the close it is still owed, ``(f, maps)``: what
+    the sublayer that ended gave and the maps its open returned (None at the
+    model's first open).  ``hp``: the hyper-connection of the sublayer that begins
+    (None at the last close).  Returns ``(x', u, maps')``: the stream closed, what
+    the sublayer reads of it (float32: its norm rounds it) and the maps its own
+    close is owed (both None without ``hp``).
+
+    Whole prompts and pieces take ``pallasex.hc_mix``, which reads the stream
+    once for both halves (under ``hc/join``; ``hc/open`` or ``hc/close`` with one
+    half absent); a decode step's rows, a mesh (``sharded``), a ``C`` that is
+    not whole lane tiles, the CPU without the interpreter and a planted
+    :func:`hc_maps` take :func:`hc_close` then :func:`hc_open`: the same numbers
+    from XLA's fusions, and what the tests hold the kernel to."""
+    from thunder_tpu.executors import pallasex
+
+    x, owed = xs
+    why = "mesh" if sharded else "planted maps" if hc_maps is not _HC_MAPS else ""
+    with scope("hc/" + ("close" if hp is None else "open" if owed is None else "join")):
+        fused = pallasex.hc_mix(x, owed, hp, eps=cfg.hc_eps, iters=cfg.hc_sinkhorn_iters, clamp=cfg.hc_res_clamp, why=why)
+    if fused is not None:
+        return fused
+    if owed is not None:
+        x = hc_close(x, *owed)
+    return (x, *hc_open(hp, x, cfg)) if hp is not None else (x, None, None)
 
 
 def require_servable(cfg: Config) -> None:
@@ -1235,7 +1273,8 @@ def require_servable(cfg: Config) -> None:
             "It trains through tt.jit / distributed.make_train_step (llama.gpt_loss).")
 
 
-def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0, moe_rows=None, hc=None):
+def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0, moe_rows=None, hc=None,
+                 sharded=False):
     """A block from its mixer's output ``h`` on: the residual sums, the norms
     and the MLP, for every block layout (``n1``: the mixer's input, which a
     shared attention norm hands to the MLP too).  The dense cache's forward
@@ -1245,8 +1284,11 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
     its residual sum; an "mlp" layer has no mixer (``h`` None) and is ``x +
     MLP(norm_1(x))``, all of it under the ``mlp`` scope.  ``moe_rows``: see
     :func:`moe_share_mlp`.  ``hc``: under hyper-connections (``cfg.hc_mult`` > 1)
-    ``x`` is the stream ``(B, n, T, C)`` and ``hc`` what the mixer's
-    :func:`hc_open` returned beside its input."""
+    ``x`` is the stream ``(B, n, T, C)`` and ``hc`` the maps the mixer's open
+    returned beside its input; what comes back is the stream with the close the
+    MLP leaves owed, ``(x, (f, maps))``, which the next layer's first open (or the
+    head) joins to its own read of the stream (:func:`hc_step`; ``sharded`` as
+    there)."""
     if cfg.single_sublayer and h is not None:
         with scope("mixer/residual"):
             return x + h
@@ -1260,13 +1302,11 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
             with scope("residual"):
                 return x + m
     if cfg.hc_mult > 1:                 # a hyper-connection where each residual sum of the pre-norm block stands
-        with scope("mixer"):
-            x = hc_close(x, h, hc)
         with scope("mlp"):
-            u, hc = hc_open(bp["hc_2"], x, cfg)
+            x, u, hc = hc_step(bp["hc_2"], (x, (h, hc)), cfg, sharded=sharded)
             with scope("norm"):
-                n2 = _norm(u, bp["norm_2"], cfg, bp.get("norm_2_b"))
-            return hc_close(x, mlp(n2), hc)
+                n2 = _norm(u, bp["norm_2"], cfg, bp.get("norm_2_b")).astype(x.dtype)
+            return x, (mlp(n2), hc)
     # each sublayer's norm and residual sum count with the sublayer
     if cfg.sandwich_norm:               # a norm on what each sublayer takes and on what it gives
         # A prompt's sums are each written out (``optimization_barrier``).  Given its row's one
@@ -1315,10 +1355,11 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
 
 def _to_streams(x, cfg: Config):
     """The embedding ``(B, T, C)`` as the residual stream: itself, or under
-    hyper-connections copied to ``hc_mult`` streams ``(B, n, T, C)`` (arXiv:2409.19606)."""
+    hyper-connections copied to ``hc_mult`` streams ``(B, n, T, C)`` (arXiv:2409.19606),
+    with the close it is owed, none yet: ``(x, None)``, as :func:`hc_step` takes it."""
     if cfg.hc_mult == 1:
         return x
-    return jnp.broadcast_to(x[:, None], (x.shape[0], cfg.hc_mult, *x.shape[1:]))
+    return jnp.broadcast_to(x[:, None], (x.shape[0], cfg.hc_mult, *x.shape[1:])), None
 
 
 def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *,
@@ -1387,12 +1428,12 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
             with scope("mixer"):
                 # OLMo's blocks norm what a sublayer gives, not what it takes
                 # under hyper-connections the sublayer reads a mixture of the streams
-                u, hc = hc_open(bp["hc_1"], x, cfg) if cfg.hc_mult > 1 else (x, None)
+                x, u, hc = hc_step(bp["hc_1"], x, cfg, sharded=sharded) if cfg.hc_mult > 1 else (x, x, None)
                 if cfg.post_sublayer_norm:
                     n1 = u
                 else:
-                    with scope("norm"):
-                        n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b"))
+                    with scope("norm"):     # (under hyper-connections ``u`` is float32: rounded here)
+                        n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b")).astype(x.dtype)
                 if kind == "mamba2":
                     j = len(new_state)
                     recur, box = mamba2_recur_dense(cache["state"][j])
@@ -1445,7 +1486,8 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
                     )
                     new_k.append(ck)
                     new_v.append(cv)
-            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, hc=hc)
+            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, hc=hc,
+                             sharded=sharded)
 
     with scope("mixer/cache"):
         cache = {"latent": jnp.stack(new_latent)} if cfg.latent else {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
@@ -1453,16 +1495,23 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
             cache.update(conv=jnp.stack(new_conv))
         if new_state:
             cache.update(state=jnp.stack(new_state))
-    return _head_logits(params, x, cfg, None if one_row else logits_at, quantized), cache
+    return _head_logits(params, x, cfg, None if one_row else logits_at, quantized, sharded=sharded), cache
 
 
-def _head_logits(params, x, cfg: Config, logits_at, quantized):
+def _head_logits(params, x, cfg: Config, logits_at, quantized, *, sharded=False):
     """The last norm and the logits in float32, of row ``logits_at`` alone where
-    given.  The streams of hyper-connections are summed first (arXiv:2409.19606)."""
+    given.  Under hyper-connections ``x`` comes with the last sublayer's close
+    still owed (:func:`hc_step`): the row is cut out of the stream, of what the
+    sublayer gave and of its maps first, so a prompt's last close is one row's;
+    then the streams are summed (arXiv:2409.19606)."""
     if cfg.hc_mult > 1:
-        with scope("head/norm"):
+        with scope("head"):
+            x, (f, maps) = x
             if logits_at is not None:
-                x, logits_at = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=2), None
+                row = lambda a, axis: jax.lax.dynamic_slice_in_dim(a, logits_at, 1, axis=axis)  # noqa: E731
+                x, f, maps, logits_at = row(x, 2), row(f, 1), tuple(row(a, a.ndim - 1) for a in maps), None
+            x = hc_step(None, (x, (f, maps)), cfg, sharded=sharded)[0]
+        with scope("head/norm"):
             x = jnp.sum(x.astype(jnp.float32), axis=1).astype(x.dtype)
     with scope("head/norm"):
         x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))

@@ -1357,6 +1357,7 @@ class ServingEngine:
             "decode_stall_s_mean": (self._stall_s_sum / n) if n else None,
             "overlap_frac_mean": (self._overlap_frac_sum / n) if n else None,
             "compile_counts": dict(self.compile_counts),
+            **({"hc": self._hc_counts()} if self.cfg.hc_mult > 1 else {}),
             "attn": {
                 # decode steps whose attention call took the XLA form (every one
                 # or none: the form is the engine's, ``path``)
@@ -1398,6 +1399,15 @@ class ServingEngine:
                if self._goodput is not None else {}),
             "pool_occupancy": self.pool.occupancy_snapshot(),
         }
+
+    @staticmethod
+    def _hc_counts() -> dict:
+        """The hyper-connections' boundaries this process's programs were built with (trace time,
+        ``pallasex.stats``): those that took ``hc_mix`` (a prompt's) and those that kept to XLA's
+        fusions (a decode step's rows, a prompt's one-row last close, a mesh)."""
+        from thunder_tpu.executors.pallasex import stats
+
+        return {"fused": stats.get("hc_fused", 0), "fallback": stats.get("hc_fallback", 0)}
 
     def _moe_rows_stats(self) -> dict:
         """How the single-step decode program's rows fell on the held experts,

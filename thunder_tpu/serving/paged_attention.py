@@ -66,7 +66,7 @@ from thunder_tpu.models.generate import (
     mla_mixer,
     mla_unabsorb,
     pad_lanes,
-    hc_open,
+    hc_step,
     _close_block,
     _head_logits,
     _linear,
@@ -376,12 +376,12 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
         with scope(f"blk{l}"):
             with scope("mixer"):
                 # under hyper-connections the sublayer reads a mixture of the streams
-                u, hc = hc_open(bp["hc_1"], x, cfg) if cfg.hc_mult > 1 else (x, None)
+                x, u, hc = hc_step(bp["hc_1"], x, cfg, sharded=mesh is not None) if cfg.hc_mult > 1 else (x, x, None)
                 if cfg.post_sublayer_norm:
                     n1 = u
                 else:
-                    with scope("norm"):
-                        n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b"))
+                    with scope("norm"):     # (under hyper-connections ``u`` is float32: rounded here)
+                        n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b")).astype(x.dtype)
                 kind = cfg.layer_kind(l)
                 if kind == "mamba2":
                     h, state_arena, conv_arena = _mamba2_paged(
@@ -469,9 +469,9 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
                     (ring_k if swa else fresh_k).append(fk)
                     (ring_v if swa else fresh_v).append(fv)
             x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling,
-                             moe_rows=rows_of, hc=hc)
+                             moe_rows=rows_of, hc=hc, sharded=mesh is not None)
 
-    logits = _head_logits(params, x, cfg, None, quantized)
+    logits = _head_logits(params, x, cfg, None, quantized, sharded=mesh is not None)
     with scope("mixer/cache"):
         if cfg.latent:          # (B, L, 1, W): the token writer's layout, one group
             fresh = {"latent": jnp.stack(fresh_rows, axis=1)[:, :, None]}

@@ -2,8 +2,8 @@
 
 The load-bearing guarantee is the conservation identity: for every program
 the engine dispatches, the ledger's ``committed + sum(waste)`` equals
-``rows x positions`` as exact integers — across sampling modes, multi-step
-decode, speculative rounds, preemption, fault recovery, and session
+``rows x positions`` as exact integers — across sampling modes, steps sent
+ahead through a row's end, speculative rounds, preemption, fault recovery, and session
 re-attach.  The ledger runs strict by default, so a violated dispatch
 raises :class:`ConservationError` the moment it is accounted; these tests
 additionally pin the *aggregate* identity and that ``committed_tokens``
@@ -178,22 +178,22 @@ class TestLedgerUnit:
 
 class TestConservationMatrix:
     @pytest.mark.parametrize("temperature", [0.0, 0.7])
-    @pytest.mark.parametrize("multi", [1, 4])
-    def test_decode_matrix(self, micro, temperature, multi):
+    @pytest.mark.parametrize("ends", ["together", "apart"])
+    def test_decode_matrix(self, micro, temperature, ends):
         cfg, params = micro
-        eng = _engine(cfg, params, temperature=temperature,
-                      decode_steps=multi, goodput=True)
+        eng = _engine(cfg, params, temperature=temperature, goodput=True)
         keys = ([jax.random.PRNGKey(i) for i in range(3)]
-                if temperature else None)
+                if temperature else [None] * 3)
         prompts = [_prompt(40 + i, 5 + i, cfg) for i in range(3)]
-        res = _drive(eng, prompts, n=6, keys=keys)
+        new = (6, 6, 6) if ends == "together" else (4, 6, 8)
+        hs = [eng.submit(p, max_new_tokens=n, key=k) for p, n, k in zip(prompts, new, keys)]
+        eng.drain()
         snap = eng.stats()["goodput"]
         _check_conserved(snap)
-        assert snap["committed_tokens"] == _streamed(res) == 18
-        if multi > 1:
-            # max_new=6 is not a multiple of N=4: frozen scan iterations
-            # past each row's stop position must land in dead_scan_row
-            assert snap["waste"].get("dead_scan_row", 0) > 0
+        assert snap["committed_tokens"] == _streamed([h.result(drive=False) for h in hs]) == 18
+        # rows that end apart: the step sent ahead through each end but the
+        # last runs the ended row once more, and that row-step is dead_scan_row
+        assert snap["waste"].get("dead_scan_row", 0) == (0 if ends == "together" else 2)
         eng.shutdown()
 
     def test_every_dispatch_classified(self, micro):
@@ -205,8 +205,7 @@ class TestConservationMatrix:
         rep = eng.goodput_report()
         assert rep.get("enabled", True) is not False
         assert set(rep["per_kind"]) <= {
-            "prefill", "prefill_fresh", "prefill_chunk", "prefill_chunk_paged", "decode_paged",
-            "decode_multi_paged"}
+            "prefill", "prefill_fresh", "prefill_chunk", "prefill_chunk_paged", "decode_paged"}
         assert "prefill_fresh" in rep["per_kind"]      # whole prompts at position 0
         assert sum(k["positions"] for k in rep["per_kind"].values()) \
             == rep["positions"]

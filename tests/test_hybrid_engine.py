@@ -194,11 +194,10 @@ def _refusals(model):
         "speculative": (dict(speculative=object.__new__(SpecConfig)), "speculative=.*no rollback"),
         "lora": (dict(lora=object.__new__(AdapterRegistry)), "lora=.*mixer's projections"),
         "mesh": (dict(mesh=object()), "mesh=.*tp axis"),
-        "decode_steps": (dict(decode_steps=4), "decode_steps > 1.*advancing its state"),
     }
 
 
-@pytest.mark.parametrize("feature", ["prefix_sharing", "sessions", "speculative", "lora", "mesh", "decode_steps", "model_fn"])
+@pytest.mark.parametrize("feature", ["prefix_sharing", "sessions", "speculative", "lora", "mesh", "model_fn"])
 def test_each_refused_feature_raises_with_its_reason(model, feature):
     cfg, params = model
     if feature == "model_fn":       # refused for every model: a model is a Config

@@ -383,7 +383,7 @@ def test_what_the_kinds_cannot_do_yet_is_refused_by_name():
     cfg, params = model()
     for option, word in [(dict(prefix_sharing=True), "prefix_sharing"), (dict(sessions=True), "sessions"),
                          (dict(speculative=object()), "speculative"), (dict(mesh=object()), "mesh"),
-                         (dict(decode_steps=2), "decode_steps"), (dict(prefill_chunk=32), "prefill_chunk"),
+                         (dict(prefill_chunk=32), "prefill_chunk"),
                          (dict(priorities=True), "priorities"), (dict(fault_plan=object()), "fault_plan"),
                          (dict(lora=object()), "lora")]:
         assert word in hybrid_unsupported(cfg, **option), option

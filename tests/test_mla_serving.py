@@ -274,8 +274,7 @@ def test_served_is_solo_generate(served, solo):
         np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("options", [{"prefill_chunk": 16}, {"decode_steps": 4}, {"async_step": False}],
-                         ids=["chunked", "multi_step", "sync"])
+@pytest.mark.parametrize("options", [{"prefill_chunk": 16}, {"async_step": False}], ids=["chunked", "sync"])
 def test_the_other_program_kinds_serve_the_same_tokens(model, prompts, solo, options):
     cfg, params = model
     eng = _engine(cfg, params, **options)
@@ -395,7 +394,7 @@ def test_the_decode_kernel_interpreted_is_its_xla_form(dtype, tol, monkeypatch):
 
 def test_the_latent_write_lands_one_row_a_layer(monkeypatch):
     monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
-    from thunder_tpu.serving.paged_attention import write_fresh_kv, write_fresh_kv_live
+    from thunder_tpu.serving.paged_attention import write_fresh_kv
 
     args, _ = _decode_case(jnp.float32, 1)
     arena, tables, pos = args["arena"], args["tables"], args["pos"]
@@ -405,12 +404,6 @@ def test_the_latent_write_lands_one_row_a_layer(monkeypatch):
     for i in range(4):
         want[int(tables[i, int(pos[i]) // 16]), :, 0, int(pos[i]) % 16] = np.asarray(rows[i, :, 0])
     np.testing.assert_array_equal(np.asarray(out), want)
-    live = jnp.asarray([True, False, True, False])
-    masked = write_fresh_kv_live({"latent": arena}, {"latent": rows}, tables, pos, live, block_size=16)["latent"]
-    dead = np.array(arena)
-    for i in (0, 2):
-        dead[int(tables[i, int(pos[i]) // 16]), :, 0, int(pos[i]) % 16] = np.asarray(rows[i, :, 0])
-    np.testing.assert_array_equal(np.asarray(masked)[1:], dead[1:])   # a dead row's write went to the sink, block 0
 
 
 def test_the_engine_claims_the_kernels_where_pallas_runs(model, prompts, solo, monkeypatch):

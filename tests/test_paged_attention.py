@@ -455,8 +455,8 @@ class TestProgramPurity:
 #
 
 
-TEN_KINDS = {"prefill", "prefill_fresh", "prefill_chunk", "prefill_chunk_paged", "decode_paged",
-             "decode_multi_paged", "spec_prefill", "spec_prefill_chunk", "draft_decode", "verify_paged"}
+NINE_KINDS = {"prefill", "prefill_fresh", "prefill_chunk", "prefill_chunk_paged", "decode_paged",
+              "spec_prefill", "spec_prefill_chunk", "draft_decode", "verify_paged"}
 
 
 class TestEntryChoice:
@@ -471,7 +471,7 @@ class TestEntryChoice:
         # keys a chunk binds, not the byte budget
         assert st["attn"]["kv_chunk_tokens"] == 512
         # one decode program a job: no gather twin among the kinds
-        assert set(eng.compile_counts) == TEN_KINDS
+        assert set(eng.compile_counts) == NINE_KINDS
         assert tt.metrics_snapshot().get("serving.attn.fallback_steps", 0) == 0
 
     def test_the_stats_keys_the_benchmark_reads(self, micro):
@@ -504,6 +504,32 @@ class TestEntryChoice:
         cfg, params = micro
         with pytest.raises(TypeError, match="attn"):
             _engine(cfg, params, attn="paged")
+
+    def test_the_decode_steps_option_is_gone(self, micro):
+        """One way to keep the chip fed between decode steps, the dispatch
+        ahead: no horizon to set, no scan program kind, nothing of a look-ahead
+        in the scheduler or on a constraint; and ``eos_id``, which only the scan
+        baked in, is the host's alone (engines that differ in it share programs)."""
+        import inspect
+
+        from thunder_tpu.serving import Constraint, DFAConstraint, TokenSetConstraint
+        from thunder_tpu.serving.engine import ServingEngine
+        from thunder_tpu.serving.scheduler import Scheduler
+
+        cfg, params = micro
+        with pytest.raises(TypeError, match="decode_steps"):
+            _engine(cfg, params, decode_steps=2)
+        with pytest.raises(TypeError, match="decode_steps"):
+            tt.serve(None, params, cfg, decode_steps=2)
+        options = [p for p in inspect.signature(ServingEngine.__init__).parameters.values() if p.kind is p.KEYWORD_ONLY]
+        assert len(options) == 32 and "decode_steps" not in {p.name for p in options}
+        eng = _engine(cfg, params, eos_id=7)
+        assert set(eng.compile_counts) == NINE_KINDS and len(NINE_KINDS) == 9
+        assert isinstance(eng.decode_steps, int) and "decode_steps_per_visit" not in eng.stats()   # the dispatch counter stays
+        assert eng._static_key() == _engine(cfg, params)._static_key()
+        assert "decode_horizon" not in inspect.signature(Scheduler.__init__).parameters
+        assert not hasattr(eng.scheduler, "decode_horizon") and "decode_horizon" not in eng.scheduler.state_snapshot()
+        assert not any(hasattr(c, "masks") for c in (Constraint, DFAConstraint, TokenSetConstraint))
 
     @pytest.mark.parametrize("what", ["entry", "engine"])
     @pytest.mark.parametrize("hs", [64, 96])

@@ -26,7 +26,8 @@ import pytest
 import thunder_tpu as tt
 from thunder_tpu.models import generate as gen
 from thunder_tpu.models import llama
-from thunder_tpu.serving import AdapterRegistry, AdmissionError, make_lora_factors
+from thunder_tpu.serving import AdapterRegistry, AdmissionError, FaultPlan, FaultSpec, make_lora_factors
+from thunder_tpu.serving.faults import FP_DECODE
 
 MICRO = dict(
     n_layer=1, n_head=2, n_embd=16, intermediate_size=32, vocab_size=32, block_size=64,
@@ -382,23 +383,56 @@ def _window(monkeypatch):
                              **{**BUCKETS, "block_buckets": (16,)})
 
 
+def _int8_kernel(monkeypatch):
+    """An int8 arena, its scales beside K and V, walked by the interpreted kernel."""
+    monkeypatch.setenv("THUNDER_TPU_PALLAS_INTERPRET", "1")
+    cfg, params, opts = _dense(monkeypatch)
+    return cfg, params, {**opts, "kv_dtype": "int8"}
+
+
+def _prefix(monkeypatch):
+    """Every prompt opens with the same block: a successor attends a block
+    another request wrote, and a dead row-step lands beside one still shared."""
+    cfg, params, opts = _dense(monkeypatch)
+    return cfg, params, {**opts, "shared": 4}
+
+
+def _chunked(monkeypatch):
+    """Prompts past 8 tokens arrive in pieces between the decode steps; the
+    synchronous loop, which has no pieces, takes them whole."""
+    cfg, params, opts = _dense(monkeypatch)
+    return cfg, params, {**opts, "prefill_chunk": 8}
+
+
+def _fault(monkeypatch):
+    """The third decode dispatch, one sent ahead, fails as out of memory: the
+    step in flight is dropped with it and the rows replay through their prefill."""
+    cfg, params, opts = _dense(monkeypatch)
+    return cfg, params, {**opts, "fault_plan": FaultPlan(specs=[FaultSpec(point=FP_DECODE, kind="oom", at=3)])}
+
+
 KINDS = {"dense": _dense, "hybrid": _hybrid, "latent": _latent, "ring": _ring}
+# options beside the step ahead, each against the synchronous loop
+OPTIONS = {"int8_kernel": _int8_kernel, "prefix": _prefix, "chunked": _chunked, "fault": _fault}
+ASYNC_ONLY = ("prefill_chunk", "fault_plan")    # the synchronous reference takes prompts whole and meets no fault
 AHEAD_REQUESTS = [(9, 7), (14, 5), (5, 9), (12, 6), (7, 4)]     # prompt, new tokens: five through three slots
 # under ``_window``: odd prompts, so the rows' blocks leave their windows at the same harvests, every other step
 WINDOW_REQUESTS = [(9, 7), (13, 6), (5, 9), (11, 6), (7, 4)]
 
 
-def _backlog(eng, cfg, lengths=AHEAD_REQUESTS):
-    """``lengths`` submitted, each request with a key of its own."""
+def _backlog(eng, cfg, lengths=AHEAD_REQUESTS, shared=0):
+    """``lengths`` submitted, each request with a key of its own; the first
+    ``shared`` tokens of every prompt the same."""
     rng = np.random.default_rng(21)
-    return [eng.submit(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), max_new_tokens=m,
-                       key=jax.random.PRNGKey(100 + i)) for i, (n, m) in enumerate(lengths)]
+    head = rng.integers(0, cfg.vocab_size, (shared,)).astype(np.int32)
+    return [eng.submit(np.concatenate([head, rng.integers(0, cfg.vocab_size, (n - shared,)).astype(np.int32)]),
+                       max_new_tokens=m, key=jax.random.PRNGKey(100 + i)) for i, (n, m) in enumerate(lengths)]
 
 
-def _drive(eng, cfg, lengths=AHEAD_REQUESTS):
+def _drive(eng, cfg, lengths=AHEAD_REQUESTS, shared=0):
     """The requests of ``lengths``, stepped to the end: tokens, finish
     reasons and the key each request ended on."""
-    return _results(_backlog(eng, cfg, lengths), eng)
+    return _results(_backlog(eng, cfg, lengths, shared), eng)
 
 
 def _results(handles, eng=None):
@@ -416,10 +450,11 @@ def _through(kind):
     the async engine stepped to the end (what it handed a successor while the
     step past a row's end was on the device), then the synchronous one."""
     with pytest.MonkeyPatch.context() as monkeypatch:
-        cfg, params, opts = {**KINDS, "window": _window}[kind](monkeypatch)
+        cfg, params, opts = {**KINDS, **OPTIONS, "window": _window}[kind](monkeypatch)
         lengths = WINDOW_REQUESTS if kind == "window" else AHEAD_REQUESTS
+        shared = opts.pop("shared", 0)
         eng = tt.serve(None, params, cfg, goodput=True, **opts)
-        handles = _backlog(eng, cfg, lengths)
+        handles = _backlog(eng, cfg, lengths, shared)
         handed = {"blocks": 0, "slots": 0}
         while eng.scheduler.queue or eng.scheduler.running:
             held = {r.rid: (set(r.block_table), r.state_slot) for r in eng.scheduler.running}
@@ -435,10 +470,11 @@ def _through(kind):
                 if r.rid not in held:                       # admitted under that step
                     handed["blocks"] += bool(blocks & set(r.block_table) - {0})
                     handed["slots"] += bool(slot) and r.state_slot == slot
-        sync_eng = tt.serve(None, params, cfg, async_step=False, **opts)
+        sync_eng = tt.serve(None, params, cfg, async_step=False, **{k: v for k, v in opts.items() if k not in ASYNC_ONLY})
         out = {"cfg": cfg, "params": params, "opts": opts, "handed": handed, "hybrid": eng._hybrid,
                "requests": [(h._req.prompt, h._req.max_new_tokens) for h in handles],
-               "served": _results(handles), "sync": _drive(sync_eng, cfg, lengths),
+               "served": _results(handles), "sync": _drive(sync_eng, cfg, lengths, shared),
+               "shared_blocks": sum(h.result(drive=False).shared_prefix_blocks for h in handles),
                "stats": eng.stats(), "sync_stats": sync_eng.stats(), "steps": (eng.decode_steps, sync_eng.decode_steps),
                "pool_clean": eng.pool.num_free == eng.pool.num_usable and eng.pool.n_retired <= 1}
         eng.shutdown(), sync_eng.shutdown()
@@ -446,11 +482,13 @@ def _through(kind):
 
 
 class TestDecodeAhead:
-    @pytest.mark.parametrize("kind", list(KINDS))
+    @pytest.mark.parametrize("kind", [*KINDS, *OPTIONS])
     def test_a_step_ahead_serves_what_the_synchronous_loop_serves(self, kind):
         """Tokens, finish reasons and the keys the requests end on are those of
         ``async_step=False``, for a dense, a hybrid (``sslots``), a latent and a
-        ring engine; the async engine ran steps ahead, the synchronous none."""
+        ring engine, and for a dense one with an int8 arena under the kernel,
+        shared prefixes, prompts in pieces and a fault that replays the rows;
+        the async engine ran steps ahead, the synchronous none."""
         ran = _through(kind)
         assert ran["served"] == ran["sync"]
         st = ran["stats"]["decode_ahead"]
@@ -458,6 +496,10 @@ class TestDecodeAhead:
         assert st["share"] == st["ahead"] / st["dispatches"]
         assert ran["sync_stats"]["decode_ahead"] == {"dispatches": ran["steps"][1], "ahead": 0, "share": 0.0}
         assert ran["pool_clean"]
+        assert (ran["shared_blocks"] > 0) == (kind == "prefix")
+        assert bool(ran["stats"]["chunk_runs"]) == (kind in ("chunked", "fault"))      # a replay runs the chunk programs
+        assert ran["stats"]["recoveries"] == (kind == "fault")
+        assert (ran["stats"]["attn"]["path"] != "xla") == (kind in ("int8_kernel", "hybrid"))
 
     def test_a_step_ahead_may_see_a_row_end_at_its_harvest_and_none_join(self, micro):
         """A backlog through three slots, step by step.  A step that dispatched
@@ -552,7 +594,7 @@ class TestDecodeAhead:
         assert left[:9] == list(range(8, -1, -1))
         assert eng.stats()["decode_ahead"] == {"dispatches": 9, "ahead": 8, "share": 8 / 9}
 
-    @pytest.mark.parametrize("how", ["constraint", "speculation", "decode_steps"])
+    @pytest.mark.parametrize("how", ["constraint", "speculation"])
     def test_what_the_next_dispatch_needs_from_the_host_keeps_the_old_order(self, micro, how):
         cfg, params = micro
         (p,) = _prompts(cfg, (5,), seed=24)
@@ -561,15 +603,12 @@ class TestDecodeAhead:
 
             eng = _engine(cfg, params, constraints=True)
             h = eng.submit(p, max_new_tokens=8, constraint=TokenSetConstraint(cfg.padded_vocab_size, {3, 4, 9}))
-        elif how == "speculation":
+        else:
             from thunder_tpu.serving import SpecConfig
 
             dcfg = llama.Config.from_name("tiny-llama-debug", **{**MICRO, "intermediate_size": 16})
             draft = llama.init_params(dcfg, jax.random.PRNGKey(9), dtype=jnp.float32)
             eng = _engine(cfg, params, speculative=SpecConfig(draft, dcfg, K=2))
-            h = eng.submit(p, max_new_tokens=8)
-        else:
-            eng = _engine(cfg, params, decode_steps=2)
             h = eng.submit(p, max_new_tokens=8)
         assert h.result().finish_reason == "length"
         st = eng.stats()["decode_ahead"]
@@ -612,6 +651,71 @@ class TestDecodeAhead:
             assert (reason == "eos") == (eos in toks)
             if reason == "eos":
                 assert toks.index(eos) == len(toks) - 1
+
+    def test_a_row_that_ends_unseen_leaves_the_sink_out_of_what_is_attended(self, micro, attn_form):
+        """EOS sampled at step k while k+1 is on the device, in both forms of the
+        program's attention, with the sink block poisoned once the first step is
+        out: what the padding rows and the dead row-step write and read there
+        reaches nothing a live row attends.  The tokens are those of a
+        synchronous engine whose sink is clean, the other row's all eight."""
+        cfg, params = micro
+        prompts = _prompts(cfg, (3, 7), seed=30)
+
+        def serve(poison=False, **kw):
+            eng = _engine(cfg, params, max_batch=2, temperature=0.9, goodput=True, **kw)
+            hs = [eng.submit(p, max_new_tokens=8, key=jax.random.PRNGKey(i)) for i, p in enumerate(prompts)]
+            if poison:
+                eng.step(), eng.step()                     # the prompts, then the first decode step: in flight
+                assert eng.decode_steps == 1 and not any(h.done() for h in hs)
+                arenas = dict(eng.pool.arenas)
+                arenas["k"], arenas["v"] = arenas["k"].at[0].set(997.0), arenas["v"].at[0].set(-997.0)
+                eng.pool.set_arenas(arenas)
+            eng.drain()
+            res = [h.result(drive=False) for h in hs]
+            return eng, [r.new_tokens for r in res], [r.finish_reason for r in res]
+
+        eos = serve(async_step=False)[1][0][2]             # request 0's third token ends it
+        _, want, why = serve(async_step=False, eos_id=eos)
+        assert why == ["eos", "length"] and len(want[0]) == 3
+        eng, got, reasons = serve(poison=True, eos_id=eos)
+        assert (got, reasons) == (want, why)
+        st = eng.stats()
+        assert st["attn"]["path"] == ("walk" if attn_form == "interpreted" else "xla")
+        # the step after the EOS left before the EOS was seen: one dead row-step, the chain dropped, a rebuild for one row
+        assert st["decode_ahead"] == {"dispatches": 7, "ahead": 5, "share": 5 / 7}
+        assert st["goodput"]["waste"]["dead_scan_row"] == 1 and st["decode_rebuild"]["rebuilds"] == 2
+
+    @pytest.mark.parametrize("where", ["on", "inside"])
+    def test_a_length_that_ends_on_or_inside_the_steps_sent_ahead_is_served_to_the_token(self, micro, where):
+        """Two rows; the first ends after five tokens, at the chain's fourth step.
+        ``on``: the second has six, so its last token is the one the step sent
+        ahead *through* the first row's end brings: it is emitted, the row is not
+        dispatched again, and nothing runs for nobody.  ``inside``: the second
+        has five too, one step inside what the chain would have sent: both end
+        at the fourth step and no step leaves past it.  Either way each row has
+        its tokens to the last and no more, and they are the synchronous loop's."""
+        cfg, params = micro
+        new = (5, 6 if where == "on" else 5)
+        prompts = _prompts(cfg, (5, 6), seed=31)
+        out = {}
+        for mode in (False, True):
+            eng = _engine(cfg, params, max_batch=2, goodput=True, async_step=mode)
+            hs = [eng.submit(p, max_new_tokens=m) for p, m in zip(prompts, new)]
+            eng.drain()
+            res = [h.result(drive=False) for h in hs]
+            assert [r.finish_reason for r in res] == ["length"] * 2 and [len(r.new_tokens) for r in res] == list(new)
+            assert eng.pool.num_free == eng.pool.num_usable
+            out[mode] = [r.new_tokens for r in res]
+        assert out[True] == out[False]
+        st = eng.stats()                                    # the async engine's
+        through = int(where == "on")
+        # four steps to the first end, three of them ahead; ``on``: one more, ahead and through the end
+        assert st["decode_ahead"] == {"dispatches": 4 + through, "ahead": 3 + through, "share": (3 + through) / (4 + through),
+                                      **({"through_end": 1} if through else {})}
+        assert st["goodput"]["waste"].get("dead_scan_row", 0) == through and st["decode_rebuild"]["rebuilds"] == 1
+        # a harvest a dispatch, and every token but a row's first (its prompt's) from one
+        assert st["host_visits"] == st["decode_steps"] == 4 + through
+        assert st["tokens_per_host_visit"] == (sum(new) - 2) / st["host_visits"]
 
     def test_a_window_that_lets_blocks_go_keeps_the_old_order_at_that_step(self, micro):
         """A sliding window of 6 over blocks of 2: a block leaves the window
@@ -755,7 +859,7 @@ def _staggered(eng, cfg, lengths, seed=31, **kw):
 
 class TestDecodeRebuild:
     @pytest.mark.parametrize("case", ["turnover", "turnover_sync", "block_boundary", "window", "preempted",
-                                      "bucket_and_recover", "state_slots", "decode_steps", "deadlines"])
+                                      "bucket_and_recover", "state_slots", "deadlines"])
     def test_a_rebuild_hands_on_what_the_full_build_would(self, micro, case, monkeypatch):
         """At every rebuild of a run with staggered lengths the patched host
         arrays equal the full build's (``_watch_rebuilds``), and the rows
@@ -770,8 +874,6 @@ class TestDecodeRebuild:
             opts.update(block_size=2, num_blocks=48, block_buckets=(16,))
         elif case == "state_slots":
             cfg, params, opts = _hybrid(monkeypatch)
-        elif case == "decode_steps":
-            opts["decode_steps"] = 2
         if case == "preempted":
             eng = _engine(cfg, params, max_batch=2, batch_buckets=(2,), num_blocks=12, priorities=True, **opts)
         elif case == "bucket_and_recover":
@@ -825,7 +927,7 @@ class TestDecodeRebuild:
         assert carried and st["rows_carried"] > 0
         if case != "preempted":                                 # a resumed row may be given its old blocks again
             assert all(e["written"] == e["changed"] for e in carried)
-        if case in ("turnover", "turnover_sync", "state_slots", "decode_steps", "deadlines"):
+        if case in ("turnover", "turnover_sync", "state_slots", "deadlines"):
             # a successor is the one row written; a rebuild after a row's end
             # with nobody waiting writes none
             for before, e in zip(log, log[1:]):

@@ -2,7 +2,7 @@
 
 The load-bearing guarantees: (1) every emitted token of a constrained
 request lies in the automaton's allowed set — greedy and temperature,
-gather and paged decode, single- and multi-step; (2) schemas are program
+gather and paged decode; (2) schemas are program
 *arguments* (the LoRA idiom) — after an engine's geometry set is warm, a
 brand-new constraint compiles ZERO programs; (3) unconstrained rows ride
 through an all-True mask bit-identically, and ``constraints=None``
@@ -20,7 +20,6 @@ import thunder_tpu as tt
 from thunder_tpu.models import llama
 from thunder_tpu.serving import (
     Constraint,
-    ConstraintLookaheadError,
     DFAConstraint,
     TokenSetConstraint,
     sequence_constraint,
@@ -61,15 +60,14 @@ def _prompt(seed, n, cfg):
 
 
 class TestConstraints:
-    def test_token_set_mask_advance_and_lookahead(self):
+    def test_token_set_mask_and_advance(self):
         c = TokenSetConstraint(64, [3, 4, 5])
         m = c.mask()
         assert m.shape == (64,) and m.sum() == 3 and m[3] and not m[0]
         c.advance(4)
         with pytest.raises(ValueError, match="violates"):
             c.advance(7)
-        ms = c.masks(5)                        # stationary: any horizon
-        assert ms.shape == (5, 64) and (ms == m).all()
+        assert (c.mask() == m).all()           # stationary
         with pytest.raises(ValueError):
             TokenSetConstraint(64, [])
         with pytest.raises(ValueError):
@@ -90,25 +88,24 @@ class TestConstraints:
         with pytest.raises(ValueError, match="transitions"):
             DFAConstraint(np.full((2, 8), 7))  # state out of range
 
-    def test_dfa_lookahead_exact_or_refuses(self):
-        # position-determined: frontier states agree step by step
+    def test_a_sequence_constraint_is_its_steps_in_order(self):
+        def walk(c, toks):
+            seen = []
+            for t in toks:
+                seen.append(list(np.flatnonzero(c.mask())))
+                c.advance(t)
+            return seen
+
         c = sequence_constraint(8, [[1], [2, 3], [4]])
-        ms = c.masks(4)
-        assert list(np.flatnonzero(ms[0])) == [1]
-        assert list(np.flatnonzero(ms[1])) == [2, 3]
-        assert list(np.flatnonzero(ms[2])) == [4]
-        assert list(np.flatnonzero(ms[3])) == [4]   # last step repeats
+        assert walk(c, [1, 3, 4, 4]) == [[1], [2, 3], [4], [4]]     # last step repeats
         cyc = sequence_constraint(8, [[1], [2]], cycle=True)
-        assert list(np.flatnonzero(cyc.masks(3)[2])) == [1]
-        # divergent frontier: state 0 -> {0, 1} with different allowed sets
+        assert walk(cyc, [1, 2, 1]) == [[1], [2], [1]]
+        # where the next mask hangs on the token drawn: state 0 -> {0, 1} with different allowed sets
         t = np.full((2, 8), -1)
         t[0, 1] = 1
         t[0, 2] = 0
         t[1, 3] = 1
-        d = DFAConstraint(t)
-        d.masks(1)                              # one step is always fine
-        with pytest.raises(ConstraintLookaheadError):
-            d.masks(2)
+        assert walk(DFAConstraint(t), [2, 1, 3]) == [[1, 2], [1, 2], [3]]
 
     def test_base_class_contract(self):
         c = Constraint(8)
@@ -122,9 +119,9 @@ class TestConstraints:
             def advance(self, token):
                 pass
 
-        assert OneStep(8).masks(1).shape == (1, 8)   # default n==1 path
-        with pytest.raises(ConstraintLookaheadError):
-            OneStep(8).masks(2)                      # default refuses lookahead
+        assert OneStep(8).mask().shape == (8,) and OneStep(8).vocab_size == 8
+        with pytest.raises(NotImplementedError):
+            c.advance(0)
 
 
 #
@@ -144,7 +141,9 @@ class TestConstrainedServing:
         assert set(r.new_tokens) <= allowed
         eng.shutdown()
 
-    def test_dfa_forces_exact_shape(self, micro):
+    def test_dfa_forces_exact_shape(self, micro, attn_form):
+        """In both forms of the decode program's attention: the mask is an
+        argument beside the kernel's as it is beside the XLA form's."""
         cfg, params = micro
         V = cfg.padded_vocab_size
         eng = _engine(cfg, params, constraints=True)
@@ -174,33 +173,6 @@ class TestConstrainedServing:
         eng.drain()
         assert h1.result(drive=False).new_tokens == ref.new_tokens
         assert set(h2.result(drive=False).new_tokens) == {3}
-        eng.shutdown()
-
-    def test_multistep_masks_per_scan_step(self, micro, attn_form):
-        """decode_steps=N: one mask per scan step, shipped as scan xs —
-        the emitted stream follows the automaton step-for-step."""
-        cfg, params = micro
-        V = cfg.padded_vocab_size
-        eng = _engine(cfg, params, constraints=True, decode_steps=3)
-        c = sequence_constraint(V, [[3], [5, 6], [7]])
-        r = eng.submit(_prompt(5, 7, cfg), max_new_tokens=5,
-                       constraint=c).result()
-        assert r.new_tokens[0] == 3
-        assert r.new_tokens[1] in (5, 6)
-        assert r.new_tokens[2:] == (7, 7, 7)
-        eng.shutdown()
-
-    def test_multistep_lookahead_validated_at_submit(self, micro):
-        cfg, params = micro
-        V = cfg.padded_vocab_size
-        eng = _engine(cfg, params, constraints=True, decode_steps=2)
-        t = np.full((2, V), -1)
-        t[0, 1] = 1
-        t[0, 2] = 0
-        t[1, 3] = 1
-        with pytest.raises(ConstraintLookaheadError):
-            eng.submit(_prompt(6, 7, cfg), max_new_tokens=4,
-                       constraint=DFAConstraint(t))
         eng.shutdown()
 
     def test_submit_validation(self, micro):

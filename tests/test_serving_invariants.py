@@ -551,7 +551,7 @@ def _(micro):
 
 
 #
-# goodput, sessions, recovery, multi-step decode
+# goodput, sessions, recovery
 #
 
 
@@ -646,29 +646,6 @@ def _(micro):
     assert [r.tokens_recomputed for r in res] == expected
     assert eng.stats()["goodput"]["waste"]["replay_recovery"] == sum(expected) + 4
     assert _tokens(res) == _tokens(ref)
-
-
-@case("multistep-host_visits_per_token_at_every_horizon")
-def _(micro):
-    """At horizon N the host visits the device at most 1/N as often a decode
-    token (a tenth of slack for the last, partial visit), for the horizons on
-    either side of the one test_multistep.py holds."""
-    cfg, params = micro
-
-    def visits_per_token(n):
-        eng = _engine(cfg, params, num_blocks=64, **({"decode_steps": n} if n > 1 else {}))
-        res = eng.run(_reqs(cfg, (5, 6, 7, 8), 17, seed=150))
-        assert eng.stats()["mean_batch_occupancy"] == 4.0
-        st = eng.stats()
-        decode_tokens = st["tokens_generated"] - len(res)
-        assert decode_tokens == 4 * 16
-        return st["host_visits"] / decode_tokens, _tokens(res)
-
-    base, ref = visits_per_token(1)
-    for n in (2, 8):
-        per, toks = visits_per_token(n)
-        assert per <= base / n * 1.1, (n, per, base)
-        assert toks == ref
 
 
 #

@@ -420,17 +420,17 @@ def test_the_delta_rules_slot_costs_what_it_cost():
 @pytest.mark.parametrize("feature,reason", [
     ("prefix_sharing", "recurrent state or conv tail cannot"), ("sessions", "no state snapshot"),
     ("speculative", "no rollback"), ("lora", "in_proj"), ("mesh", "no layout under a tp axis"),
-    ("decode_steps", "go on advancing its state"), ("model_fn", "llama.Config.*custom model_fn")])
+    ("model_fn", "llama.Config.*custom model_fn")])
 def test_each_refused_feature_raises_with_its_reason(model, feature, reason):
     cfg, params = model
     if feature == "model_fn":       # refused for every model: a model is a Config
         with pytest.raises(NotImplementedError, match=reason):
             tt.serve(lambda *a, **k: None, params, cfg, num_blocks=8, max_batch=1)
         return
-    value = 4 if feature == "decode_steps" else True if feature == "prefix_sharing" else object()
+    value = True if feature == "prefix_sharing" else object()
     assert reason in engine_mod.hybrid_unsupported(cfg, **{feature: value})
     assert engine_mod.hybrid_unsupported(cfg) is None
-    if feature in ("prefix_sharing", "decode_steps"):
+    if feature == "prefix_sharing":
         with pytest.raises(NotImplementedError, match="conv tail a request"):
             tt.serve(None, params, cfg, num_blocks=8, max_batch=1, **{feature: value})
 

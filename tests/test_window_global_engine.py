@@ -123,7 +123,7 @@ def test_the_allocator_keeps_a_ring_and_a_whole_length_a_request_and_frees_both(
 
 REFUSALS = [(dict(prefix_sharing=True), "prefix_sharing"), (dict(sessions=True), "sessions"),
             (dict(speculative=object()), "speculative"), (dict(mesh=object()), "mesh"),
-            (dict(decode_steps=2), "decode_steps"), (dict(kv_dtype="fp8"), "kv_dtype"),
+            (dict(kv_dtype="fp8"), "kv_dtype"),
             (dict(prefill_chunk=32), "prefill_chunk"), (dict(priorities=True), "priorities"),
             (dict(fault_plan=object()), "fault_plan"), (dict(lora=object()), "lora")]
 

@@ -1793,15 +1793,10 @@ def paged_token_write(arena, vals, tables, pos, *, block_size, n_emit=None,
     primitive appears in the program.  Padding rows (all-sink tables, pos 0)
     land in sink block 0, whose contents are never attended.
 
-    Keep-masked form, for the speculative verify commit and multi-step
-    decode: with ``n_emit`` (B,) int32 and a static chunk ``offset``,
-    request ``i`` lands ``vals[i]`` at slot ``pos[i] + offset`` iff ``offset
-    < n_emit[i]``; rejected rows route to sink block 0 slot 0, so a rejected
-    draft's KV stays invisible.  With ``offset=0`` and ``n_emit = live ∈ {0,
-    1}`` the predicate *is* the per-row liveness mask of multi-step decode
-    (``write_fresh_kv_live``): a live row stores the same bytes as the
-    unmasked write, a finished row sinks every remaining iteration's write,
-    and the N-step program stays static-shape with zero scatters.
+    Keep-masked form, for the speculative verify commit: with ``n_emit``
+    (B,) int32 and a static chunk ``offset``, request ``i`` lands ``vals[i]``
+    at slot ``pos[i] + offset`` iff ``offset < n_emit[i]``; rejected rows
+    route to sink block 0 slot 0, so a rejected draft's KV stays invisible.
 
     ``name``: the kernel's name in a device trace (a latent arena's write is
     ``mla_latent_write``).

@@ -2,7 +2,7 @@
 
 Every serving program dispatch performs ``rows x positions`` token-position
 slots of device work: a prefill bucket is ``1 x Tb``, a decode visit is
-``Bb x 1``, a multi-step visit ``Bb x N``, a draft round ``Bb x K`` plus a
+``Bb x 1``, a draft round ``Bb x K`` plus a
 ``Bb x (K+1)`` verify.  Only some of those slots become tokens a user
 streams; the rest is the price of static shapes, speculation, and replay.
 The :class:`GoodputLedger` classifies **every** slot into exactly one
@@ -27,8 +27,8 @@ Waste taxonomy
     Prompt-bucket padding: prefill positions beyond the real chunk.
 ``dead_scan_row``
     Device work for rows that were (or went) dead before their tokens
-    could stream: multi-step scan iterations frozen after a row's stop
-    position, rows that finished or were discarded while the dispatch was
+    could stream: the row-step a step dispatched ahead runs through a
+    row's end, rows that finished or were discarded while the dispatch was
     in flight, and speculative positions accepted by verify but trimmed
     by an EOS/length finish before streaming.
 ``draft_rejected``

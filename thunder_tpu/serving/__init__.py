@@ -118,7 +118,6 @@ from thunder_tpu.serving.scheduler import (  # noqa: F401
 )
 from thunder_tpu.serving.constrain import (  # noqa: F401
     Constraint,
-    ConstraintLookaheadError,
     DFAConstraint,
     TokenSetConstraint,
     sequence_constraint,
@@ -180,7 +179,6 @@ __all__ = [
     "PRIORITY_LOW",
     "PRIORITY_LEVELS",
     "Constraint",
-    "ConstraintLookaheadError",
     "TokenSetConstraint",
     "DFAConstraint",
     "sequence_constraint",

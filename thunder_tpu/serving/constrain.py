@@ -9,10 +9,9 @@ or allow-list is pure data fed through that argument:
 
 - the engine keeps the automaton **host-side** on the request
   (:class:`Constraint` instances are plain Python state machines);
-- at every dispatch the host asks each constrained row for its mask(s)
-  over the next draw(s) and ships a ``(B, V)`` bool tensor (``(N, B, V)``
-  for ``decode_steps=N`` — one mask per scan step, consumed as scan
-  ``xs``);
+- at every dispatch the host asks each constrained row for its mask
+  over the next draw (:meth:`Constraint.mask`, the one call the engine
+  makes) and ships a ``(B, V)`` bool tensor;
 - inside the program the mask is applied as
   ``logits = where(mask, logits, -inf)`` immediately before
   :func:`sample_token`, so greedy argmax and temperature sampling both
@@ -30,16 +29,6 @@ their sampled tokens match an unconstrained engine exactly.  The
 collapses to ``None`` when off — the off-path compiles byte-identical
 programs (same module-cache entries) as an engine built before this
 module existed.
-
-Multi-step decode (``decode_steps=N``) needs masks for N draws *at
-dispatch time*, before any of those tokens exist.  A constraint can
-honestly promise that only when its next-N masks are determined by
-position alone (stationary allow-lists; automata whose reachable states
-agree step-by-step).  :meth:`Constraint.masks` is the contract:
-implementations must return exact per-step masks or raise
-:class:`ConstraintLookaheadError`; the engine validates at ``submit()``
-so an incompatible (constraint, ``decode_steps``) pair fails fast
-instead of emitting schema-violating tokens.
 """
 
 from __future__ import annotations
@@ -48,20 +37,10 @@ import numpy as np
 
 __all__ = [
     "Constraint",
-    "ConstraintLookaheadError",
     "TokenSetConstraint",
     "DFAConstraint",
     "sequence_constraint",
 ]
-
-
-class ConstraintLookaheadError(ValueError):
-    """The constraint cannot exactly predict masks ``n`` draws ahead.
-
-    Raised by :meth:`Constraint.masks` when ``n`` exceeds what the
-    automaton can promise without knowing the sampled tokens — the
-    engine surfaces it at ``submit()`` for ``decode_steps > 1``.
-    """
 
 
 class Constraint:
@@ -85,27 +64,12 @@ class Constraint:
         """Consume one emitted token, moving the automaton forward."""
         raise NotImplementedError
 
-    # -- optional lookahead (multi-step decode) -----------------------------
-    def masks(self, n: int) -> np.ndarray:
-        """``(n, vocab_size)`` bool — exact masks for the next ``n`` draws.
-
-        The default handles ``n == 1`` via :meth:`mask` and refuses
-        longer horizons; subclasses whose masks are position-determined
-        override it.
-        """
-        if n == 1:
-            return self.mask()[None]
-        raise ConstraintLookaheadError(
-            f"{type(self).__name__} cannot predict masks {n} steps ahead; "
-            "use decode_steps=1 or a position-determined constraint")
-
 
 class TokenSetConstraint(Constraint):
     """A stationary allow-list: every draw must come from ``allowed_ids``.
 
     The simplest useful schema (digits only, yes/no, an enum of tool
-    names).  Stationary masks trivially support any ``decode_steps``
-    horizon.
+    names).
     """
 
     def __init__(self, vocab_size: int, allowed_ids):
@@ -128,9 +92,6 @@ class TokenSetConstraint(Constraint):
             raise ValueError(
                 f"token {int(token)} violates TokenSetConstraint")
 
-    def masks(self, n: int) -> np.ndarray:
-        return np.broadcast_to(self._mask, (n, self.vocab_size)).copy()
-
 
 class DFAConstraint(Constraint):
     """A token-level DFA: ``transitions[state, token] -> next state | -1``.
@@ -139,12 +100,6 @@ class DFAConstraint(Constraint):
     marks a forbidden token.  The grammar — a JSON skeleton, a CSV row
     shape, a tool-call syntax — is entirely in the table, which is plain
     data: registering a new grammar compiles nothing.
-
-    Multi-step lookahead is exact when the reachable-state frontier
-    agrees on its allowed set at every step (true for position-determined
-    grammars such as fixed-shape records); otherwise
-    :class:`ConstraintLookaheadError` is raised rather than returning an
-    approximate mask.
     """
 
     def __init__(self, transitions, start: int = 0):
@@ -174,23 +129,6 @@ class DFAConstraint(Constraint):
     def reset(self) -> None:
         self.state = self._start
 
-    def masks(self, n: int) -> np.ndarray:
-        out = np.zeros((n, self.vocab_size), dtype=bool)
-        frontier = {self.state}
-        for k in range(n):
-            per_state = [self._table[s] >= 0 for s in sorted(frontier)]
-            for m in per_state[1:]:
-                if not np.array_equal(per_state[0], m):
-                    raise ConstraintLookaheadError(
-                        f"DFA masks diverge {k} steps ahead "
-                        f"(reachable states {sorted(frontier)}); this grammar "
-                        "cannot run under decode_steps > 1")
-            out[k] = per_state[0]
-            frontier = {int(self._table[s, t])
-                        for s in frontier
-                        for t in np.flatnonzero(self._table[s] >= 0)}
-        return out
-
 
 def sequence_constraint(vocab_size: int, steps, *, cycle: bool = False) -> DFAConstraint:
     """Build a position-determined DFA from per-step allow-lists.
@@ -198,9 +136,7 @@ def sequence_constraint(vocab_size: int, steps, *, cycle: bool = False) -> DFACo
     ``steps`` is a sequence of token-id collections: draw ``k`` must come
     from ``steps[k]``; after the last step the automaton either repeats
     the final step forever (``cycle=False``) or wraps to step 0
-    (``cycle=True`` — e.g. ``digit, comma, digit, comma, ...``).  Being
-    position-determined, the result supports any ``decode_steps``
-    lookahead.
+    (``cycle=True`` — e.g. ``digit, comma, digit, comma, ...``).
     """
     steps = [sorted(set(int(t) for t in s)) for s in steps]
     if not steps or any(not s for s in steps):

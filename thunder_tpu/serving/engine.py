@@ -277,7 +277,7 @@ _program_cache: dict = {}
 
 
 def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=None, lora=None,
-                       mesh=None, decode_steps: int = 1, kv_dtype=None, prefill_chunk=None,
+                       mesh=None, kv_dtype=None, prefill_chunk=None,
                        priorities=None, fault_plan=None) -> str | None:
     """Why an engine with these options cannot serve a config that keeps a
     state a request beside its KV (a delta rule's recurrent state and conv
@@ -318,9 +318,6 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
                     "layer's wq projects a head's query and its gate in one product of twice that")
         if mesh is not None:
             return "mesh= is unsupported: the ring arenas have no layout under a tp axis"
-        if int(decode_steps) > 1:
-            return ("decode_steps > 1 is unsupported: the multi-step program writes the paged arenas alone, and a "
-                    "row that finishes inside a visit would go on overwriting its ring")
         return None
     if getattr(cfg, "hybrid_decoder", False):
         if kv_dtype is not None:
@@ -355,9 +352,6 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
                 "targets; the mixer's projections (in_proj_qkvz, in_proj, out_proj) have none")
     if mesh is not None:
         return "mesh= is unsupported: the state arena has no layout under a tp axis"
-    if int(decode_steps) > 1:
-        return ("decode_steps > 1 is unsupported: a row that finishes inside a multi-step visit "
-                "would go on advancing its state")
     if cfg.sliding_window is not None:
         return ("a sliding window is unsupported beside a recurrent state or conv tail "
                 "(block expiry is untested with it)")
@@ -426,7 +420,6 @@ class ServingEngine:
         watchdog_timeout_s: float | None = None,
         speculative=None,
         replica_id: int | None = None,
-        decode_steps: int = 1,
         sessions=None,
         priorities=None,
         constraints=None,
@@ -442,7 +435,7 @@ class ServingEngine:
         if self._hybrid:
             why = hybrid_unsupported(
                 cfg, prefix_sharing=prefix_sharing, sessions=sessions, speculative=speculative,
-                lora=lora, mesh=mesh, decode_steps=decode_steps, kv_dtype=kv_dtype,
+                lora=lora, mesh=mesh, kv_dtype=kv_dtype,
                 prefill_chunk=prefill_chunk, priorities=priorities, fault_plan=fault_plan)
             if why:
                 kind = ("linear_attention layers (a recurrent state a request)" if cfg.linear_layers
@@ -544,23 +537,6 @@ class ServingEngine:
         # sharding), but block ids are allocated once per request from the
         # target pool and index both arenas (the draft pool's free list is
         # never consulted), so the allocator/prefix machinery stays single
-        # device-resident multi-step decode: N tokens per host visit via an
-        # in-program lax.scan over the decode body.  Stored as
-        # n_decode_steps (self.decode_steps is the dispatch counter); N=1
-        # is byte-identical to the single-step engine (same program kinds,
-        # same static keys, shared module program cache).
-        self.n_decode_steps = int(decode_steps)
-        if self.n_decode_steps < 1:
-            raise ValueError(f"decode_steps= must be >= 1, got {decode_steps}")
-        if speculative is not None and self.n_decode_steps > 1:
-            from thunder_tpu.serving.speculative import multi_step_supported
-
-            ok_ms, why_ms = multi_step_supported(speculative)
-            if not ok_ms:
-                raise ValueError(
-                    f"decode_steps={self.n_decode_steps} with speculative= "
-                    f"is unsupported: {why_ms}"
-                )
         self.spec = speculative
         if speculative is not None:
             from thunder_tpu.serving.speculative import validate_spec
@@ -592,12 +568,8 @@ class ServingEngine:
             sliding_window=cfg.sliding_window,
             prefill_chunk=prefill_chunk,
             # a speculative round's draft scan writes up to K slots past the
-            # last committed token — admission must reserve that overshoot;
-            # a multi-step decode visit likewise writes up to N-1 slots past
-            # the first token of the visit before the host sees any of them
-            reserve_extra_tokens=(speculative.K if speculative is not None
-                                  else self.n_decode_steps - 1),
-            decode_horizon=self.n_decode_steps,
+            # last committed token — admission must reserve that overshoot
+            reserve_extra_tokens=speculative.K if speculative is not None else 0,
         )
         if getattr(cfg, "learned_pos_embedding", False):
             # wpe has block_size rows and dynamic_slice clamps silently past
@@ -720,11 +692,9 @@ class ServingEngine:
         self._occupancy_sum = 0
         self.compile_counts = {"prefill": 0, "prefill_fresh": 0, "prefill_chunk": 0,
                                "prefill_chunk_paged": 0, "decode_paged": 0,
-                               "decode_multi_paged": 0, "spec_prefill": 0,
-                               "spec_prefill_chunk": 0, "draft_decode": 0,
-                               "verify_paged": 0}
-        # host-visit amortization accounting: one host_visit per decode-lane
-        # harvest (a visit serves up to n_decode_steps tokens per row)
+                               "spec_prefill": 0, "spec_prefill_chunk": 0,
+                               "draft_decode": 0, "verify_paged": 0}
+        # one host_visit per decode-lane harvest
         self.host_visits = 0
         self.decode_lane_tokens = 0
         # async lanes: the in-flight futures table — one deferred decode
@@ -886,10 +856,6 @@ class ServingEngine:
                 raise ValueError(
                     f"constraint.vocab_size={constraint.vocab_size} != model "
                     f"logit width {self._vocab}")
-            # multi-step decode needs exact masks N draws ahead; fail at
-            # submit, not mid-scan (ConstraintLookaheadError propagates)
-            if self.n_decode_steps > 1:
-                constraint.masks(self.n_decode_steps)
         reg = registry()
         try:
             req = self.scheduler.submit(
@@ -1328,7 +1294,6 @@ class ServingEngine:
             "async_step": self.async_step,
             "prefill_chunk": sch.prefill_chunk,
             "decode_steps": self.decode_steps,
-            "decode_steps_per_visit": self.n_decode_steps,
             "decode_ahead": {
                 "dispatches": self.decode_steps, "ahead": self.decode_ahead_steps,
                 "share": self.decode_ahead_steps / self.decode_steps if self.decode_steps else 0.0,
@@ -1485,7 +1450,6 @@ class ServingEngine:
                 "async_step": self.async_step,
                 "decode_inflight": (
                     {"step": dec["step"], "bucket": dec["bucket"],
-                     "steps": dec.get("multi", 1),
                      "rids": [r.rid for r in dec["running"]]}
                     if dec is not None else None
                 ),
@@ -2068,8 +2032,7 @@ class ServingEngine:
           :meth:`_ahead_steps`, and counted down a step since: no walk over
           the rows here): before a window lets blocks go at the harvest, and
           up to **one step past the first end by length** where a row of the
-          chain outlives that end; no row of it is constrained, nor runs
-          ``decode_steps=N``;
+          chain outlives that end; no row of it is constrained;
         - no prefill piece is in flight whose harvest would change the
           batch, and no row's deadline has passed;
         - the decode-ready rows and their buckets are the chain's (an
@@ -2117,8 +2080,7 @@ class ServingEngine:
         host["keys"][i] = r.key
         host["slots"][i] = r.adapter_slot
         host["sslots"][i] = r.state_slot
-        # multi-step stopping: the last position a row may write before
-        # FINISH_LENGTH (see _build_decode_multi_paged)
+        # the last position a row may write before FINISH_LENGTH
         host["stop"][i] = r.prompt_len + r.max_new_tokens - 2
         live = -1
         if self.scheduler.sliding_window is not None:
@@ -2208,12 +2170,12 @@ class ServingEngine:
         Combined with, not in place of, what else bounds the chain: under a
         window the steps until a harvest frees a row's first live block, and
         none at all where the next dispatch needs a value from the host (a
-        constrained row, ``decode_steps=N``)."""
+        constrained row)."""
         n = host["n"]
         pos = host["host_pos"][:n].astype(np.int64)
         left = host["stop"][:n] - pos                      # steps before the one that ends each row
         first = int(left.min())
-        ahead = first + int((left > first).any()) if self.n_decode_steps == 1 else 0
+        ahead = first + int((left > first).any())
         W = self.scheduler.sliding_window
         if W is not None:
             # harvest m of the chain sees pos = wpos + m + 1 and frees the
@@ -2234,19 +2196,17 @@ class ServingEngine:
         self._fault_point(FP_DECODE, sig[0])
         _, Bb, nbb = sig
         bs = pool.block_size
-        N = self.n_decode_steps
         st = self._decode_state
         steady = st is not None and st["sig"] == sig
         if steady:
             # steady state: the batch composition and tables are unchanged
             # since the last step, so this step's inputs ARE the previous
-            # step's device outputs (toks=nxt, keys=new_keys, pos=pos+N)
+            # step's device outputs (toks=nxt, keys=new_keys, pos=pos+1)
             # plus the cached tables/slots — zero host->device transfers
             toks_d, pos_d = st["toks"], st["pos"]
             tables_d, keys_d, slots_d = st["tables"], st["keys"], st["slots"]
             sslots_d = st.get("sslots")
-            host_pos = st["host_pos"] + N
-            stop_d = st.get("stop")
+            host_pos = st["host_pos"] + 1
             ahead_left, deadline = st["ahead"] - 1, st["deadline"]
             host = self._decode_host
         else:
@@ -2267,34 +2227,28 @@ class ServingEngine:
             toks_d, pos_d = host["toks"], host["host_pos"]
             tables_d, keys_d, slots_d = host["tables"], host["keys"], host["slots"]
             sslots_d = host["sslots"] if self._hybrid else None
-            stop_d = host["stop"] if N > 1 else None
             host_pos = host["host_pos"]
         # constrained decoding: the per-row token masks are fresh host data
         # every dispatch (the automata advanced at the last harvest) — an
         # argument beside the chained device state, never part of it
         cmask_d = None
         if self._constraints:
-            shape = ((N, Bb, self._vocab) if N > 1 else (Bb, self._vocab))
+            shape = (Bb, self._vocab)
             if any(r.constraint is not None for r in running):
                 m = np.ones(shape, dtype=bool)
                 for i, r in enumerate(running):
                     if r.constraint is not None:
-                        if N > 1:
-                            m[:, i, :] = r.constraint.masks(N)
-                        else:
-                            m[i] = r.constraint.mask()
+                        m[i] = r.constraint.mask()
                 cmask_d = jnp.asarray(m)
             else:
                 cmask_d = self._ones_mask(shape)
-        kind = "decode_multi_paged" if N > 1 else "decode_paged"
+        kind = "decode_paged"
         prog, compiled = self._program(kind, Bb, nbb)
         lora_arenas = self._lora_arenas()
         if self.mesh is not None and self._mesh_collectives is None:
             # census BEFORE the call: the arenas are donated by it
             ex = (self.params, toks_d, pos_d, tables_d, pool.arenas,
                   keys_d, lora_arenas, slots_d)
-            if N > 1:
-                ex = ex + (stop_d,)
             if cmask_d is not None:
                 ex = ex + (cmask_d,)
             self._mesh_collectives = self._collective_census(
@@ -2307,9 +2261,8 @@ class ServingEngine:
         past = host["stop"][:host["n"]] < host_pos[:host["n"]]
         ending = int(past.sum())
         if self._attended is not None:
-            # the keys a layer of each kind attends this step (one token a row: rings refuse
-            # decode_steps > 1), over the rows that hold a request: a window layer the last
-            # layer_window, the others all
+            # the keys a layer of each kind attends this step (one token a row), over the
+            # rows that hold a request: a window layer the last layer_window, the others all
             seen = np.asarray(host_pos, dtype=np.int64)[:len(running)][~past] + 1
             self._attended["full_attention"] += int(seen.sum())
             self._attended["sliding_attention"] += int(np.minimum(seen, self.cfg.layer_window).sum())
@@ -2320,16 +2273,14 @@ class ServingEngine:
             # live range — per-row ceil(pos / bs) clamped to [1, nbb]
             # (padding rows collapse to one block, the sink); host ints
             # only, the dispatch itself is untouched
-            hp = np.asarray(host_pos, dtype=np.int64)[:, None] + np.arange(N)
+            hp = np.asarray(host_pos, dtype=np.int64)
             real = int(np.minimum(np.maximum(-(-hp // bs), 1), nbb).sum())
-            self._goodput.note_blocks(kind, Bb * nbb * N, real)
+            self._goodput.note_blocks(kind, Bb * nbb, real)
         if batch is None:                   # ahead: once the step before is harvested
             self._trace_decode_begin(running, self.decode_steps, compiled, [Bb, nbb])
         call_args = (self.params, toks_d, pos_d, tables_d, pool.arenas,
                      keys_d, lora_arenas, slots_d)
-        if N > 1:
-            call_args = call_args + (stop_d,)
-        elif self._moe_rows is not None:
+        if self._moe_rows is not None:
             call_args = call_args + (self._moe_rows,)
         if sslots_d is not None:
             call_args = call_args + (sslots_d,)
@@ -2338,13 +2289,9 @@ class ServingEngine:
         with self._span("serve.decode_dispatch.call"), \
                 self._compile_span(compiled, kind, Bb, nbb):
             outs = prog(*call_args)
-        if N > 1:
-            ys_tok, ys_emit, toks_f, keys_f, pos_f, arenas = outs
-            nxt, new_keys, new_pos = toks_f, keys_f, pos_f
-        else:
-            nxt, new_keys, new_pos, arenas, *sums = outs
-            if sums:
-                self._moe_rows = sums[0]
+        nxt, new_keys, new_pos, arenas, *sums = outs
+        if sums:
+            self._moe_rows = sums[0]
         # past the point of no return: the call consumed the donated arenas
         self._fault_point(FP_SCATTER, sig[0])
         pool.set_arenas(arenas)
@@ -2352,13 +2299,12 @@ class ServingEngine:
             # the operands the chain's later steps take as they are: one
             # transfer, while the device runs the step just dispatched
             with self._span("serve.decode_dispatch.put"):
-                tables_d, slots_d, sslots_d, stop_d = jax.device_put((tables_d, slots_d, sslots_d, stop_d))
+                tables_d, slots_d, sslots_d = jax.device_put((tables_d, slots_d, sslots_d))
         self._decode_host = host
         self._decode_state = {
             "sig": sig, "toks": nxt, "pos": new_pos, "tables": tables_d,
             "keys": new_keys, "slots": slots_d, "host_pos": host_pos,
             "ahead": ahead_left, "deadline": deadline,
-            **({"stop": stop_d} if N > 1 else {}),
             **({"sslots": sslots_d} if sslots_d is not None else {}),
         }
         rec = {"kind": "decode", "running": running, "rids": sig[0], "nxt": nxt,
@@ -2369,8 +2315,6 @@ class ServingEngine:
                "ending": ending,
                "epochs": [r.preemptions for r in running],
                "t_disp": time.perf_counter(), "t_clock": sch.clock()}
-        if N > 1:
-            rec.update(multi=N, nxt=ys_tok, emit=ys_emit, new_keys=keys_f)
         self.decode_steps += 1
         self._occupancy_sum += len(running) - ending
         self._m_steps_decode.inc()
@@ -2391,27 +2335,22 @@ class ServingEngine:
         then, and a row has one ``decode`` span open at a time."""
         tr = self._tracer
         if tr is not None:
-            N = self.n_decode_steps
             for r in running:
                 tr.begin(r.rid, "decode", step=step,
                          compile=compiled, bucket=bucket, lane="decode",
-                         attn=self._attn_path,
-                         **({"steps": N} if N > 1 else {}))
+                         attn=self._attn_path)
 
     def _decode_harvest(self, rec: dict) -> None:
         if rec.get("spec"):
             from thunder_tpu.serving.speculative import spec_decode_harvest
 
             return spec_decode_harvest(self, rec)
-        multi = rec.get("multi")
         running = rec["running"]
         self._fault_point(FP_HARVEST, rec["rids"])
         t0 = time.perf_counter()
         with self._span("serve.harvest.wait", kind="decode", rows=len(running)):
-            # the host block: (Bb,) tokens, or the multi-step visit's (N, Bb)
-            # token matrix and liveness mask
-            fetched = [np.asarray(rec[k]) for k in
-                       (("nxt", "emit", "new_keys") if multi else ("nxt", "new_keys"))]
+            # the host block: (Bb,) tokens and keys
+            nxt, new_keys = np.asarray(rec["nxt"]), np.asarray(rec["new_keys"])
         stall = time.perf_counter() - t0
         if self._inflight_decode is not None:
             # a record dispatched ahead of this harvest: the device took it up
@@ -2419,7 +2358,7 @@ class ServingEngine:
             # accounting begins here and not at its dispatch
             self._inflight_decode["t_dev"] = t0 + stall
         with self._span("serve.harvest.emit"):
-            (self._decode_emit_multi if multi else self._decode_emit)(rec, t0, stall, *fetched)
+            self._decode_emit(rec, t0, stall, nxt, new_keys)
 
     def _decode_emit(self, rec: dict, t0: float, stall: float, nxt, new_keys) -> None:
         sch = self.scheduler
@@ -2520,128 +2459,6 @@ class ServingEngine:
         if invalidate:
             # the chained decode inputs assumed an unchanged batch/tables;
             # the next dispatch rebuilds them (_decode_inputs)
-            self._decode_state = None
-
-    def _decode_emit_multi(self, rec: dict, t0: float, stall: float, nxt, emit,
-                           new_keys) -> None:
-        """Harvest one multi-step visit: up to N tokens per row.
-
-        ``rec["nxt"]`` is the (N, Bb) token matrix and ``rec["emit"]`` the
-        (N, Bb) liveness mask from the scan's stacked outputs.  The emitted
-        prefix of each column is exactly the tokens the 1-step engine would
-        have served: the in-program ``done`` predicate (pos >= stop, or
-        token == eos) coincides bit-for-bit with ``_emit_token``'s
-        FINISH_LENGTH / FINISH_EOS conditions, so a column with k < N
-        emitted tokens finished at its k-th token and the remaining
-        iterations keep-masked their KV writes to the sink block."""
-        sch = self.scheduler
-        running = rec["running"]
-        N = rec["multi"]
-        if self.async_step:
-            overlapped = t0 - rec["t_disp"]
-            frac = overlapped / (overlapped + stall) if (overlapped + stall) > 0 else 0.0
-            self._stall_s_sum += stall
-            self._overlap_frac_sum += frac
-            self._overlap_obs += 1
-            self._m_stall.observe(stall)
-            self._m_overlap.set(frac)
-        tr = self._tracer
-        harvested = [int(emit[:, i].sum()) for i in range(len(running))]
-        epochs = rec.get("epochs")
-        gp, gtag = self._goodput, None
-        if gp is not None:
-            # exact pre-emit classification of the Bb x N scan slots: the
-            # in-program done predicate coincides with _emit_token's finish
-            # conditions, so a live row streams min(k, budget, eos-cut)
-            # tokens and its remaining iterations were dead scan rows
-            Bb = rec["bucket"][0]
-            committed = n_stale = n_dead = 0
-            for i, r in enumerate(running):
-                if epochs is not None and r.preemptions != epochs[i]:
-                    n_stale += N
-                elif r.state != "running":
-                    n_dead += N
-                else:
-                    streamed = min(harvested[i],
-                                   r.max_new_tokens - len(r.generated))
-                    if self.eos_id is not None:
-                        for s in range(streamed):
-                            if int(nxt[s, i]) == self.eos_id:
-                                streamed = s + 1
-                                break
-                    committed += streamed
-                    n_dead += N - streamed
-            waste = {}
-            if Bb > len(running):
-                waste["pad_row"] = (Bb - len(running)) * N
-            if n_stale:
-                waste["replay_preemption"] = n_stale
-            if n_dead:
-                waste["dead_scan_row"] = n_dead
-            gtag = gp.account(rec["pkind"], Bb, N, committed=committed,
-                              **waste)
-            gp.note_device_s(rec["pkind"],
-                             time.perf_counter() - rec["t_disp"])
-        if tr is not None:                                 # tokens host-visible
-            # one span per request per HOST VISIT (not N phantom per-token
-            # spans): tagged with how many of the N steps actually emitted
-            for i, r in enumerate(running):
-                tr.end(r.rid, "decode", harvested=harvested[i],
-                       **({"goodput": gtag} if gtag is not None else {}))
-        if self._flight is not None:
-            self._flight.record("decode", step=rec["step"],
-                                batch=len(running), bucket=rec["bucket"],
-                                compiled=rec["compiled"], steps=N,
-                                harvested=harvested,
-                                rids=[r.rid for r in running],
-                                **({"goodput": gtag}
-                                   if gtag is not None else {}))
-        pos = rec["pos"]
-        emitted = 0
-        invalidate = False
-        for i, r in enumerate(running):
-            if r.state != "running" or (
-                    epochs is not None and r.preemptions != epochs[i]):
-                # finished mid-flight (tokens never promised) or preempted
-                # and resumed (the resumed chain re-derives these tokens)
-                invalidate = True
-                continue
-            k = harvested[i]
-            r.key = new_keys[i]
-            r.pos = int(pos[i]) + k
-            released = sch.expire_window_blocks(r)
-            if released:
-                invalidate = True
-                self._unregister_prefix(r)
-                if self._flight is not None:
-                    self._flight.record("window_expire", rid=r.rid,
-                                        released=released)
-            for s in range(k):
-                emitted += 1
-                self._emit_token(r, int(nxt[s, i]))
-                if r.state != "running":
-                    invalidate = True                      # finished at this token
-                    break
-            if k < N:
-                # the row went dead in-program; the chained device state no
-                # longer matches this row's host state
-                invalidate = True
-        self.tokens_generated += emitted
-        self.decode_lane_tokens += emitted
-        self.host_visits += 1
-        self._m_host_visits.inc()
-        if emitted:
-            self._m_tokens.inc(emitted)
-        if gp is not None:
-            gp.commit_tokens(emitted)
-        host = rec["host"]
-        if host is self._decode_host:
-            # as _decode_emit: a row that served all N tokens stands at the
-            # last of them; one that went dead in-program is written anew
-            host["toks"], host["keys"], host["host_pos"] = nxt[N - 1], new_keys, pos + N
-            took = np.asarray(harvested, dtype=np.int64)
-            host["facts"][:, 1] = np.where(took == N, host["facts"][:, 1] + N, -1)
-        if invalidate:
             self._decode_state = None
 
     #
@@ -3120,8 +2937,7 @@ class ServingEngine:
                 gp.account("draft_decode", Bb, K, **{cause: Bb * K})
                 gp.account(rec["vkind"], Bb, K + 1, **{cause: Bb * (K + 1)})
             else:
-                n = rec.get("multi", 1)
-                gp.account(rec["pkind"], Bb, n, **{cause: Bb * n})
+                gp.account(rec["pkind"], Bb, 1, **{cause: Bb})
         pending, self._inflight_prefill = self._inflight_prefill, []
         if tr is not None:
             for prec in pending:
@@ -3167,11 +2983,6 @@ class ServingEngine:
              str(self.draft_pool.kv_dtype),
              tuple(sorted(dataclasses.asdict(self.spec.draft_cfg).items())))
             if self.spec is not None else None,
-            # the multi-step horizon: ONE knob joining the key, not
-            # per-horizon buckets, and the EOS its scan stops a row at; N=1
-            # collapses to None so a decode_steps=1 engine shares the module
-            # program cache with default engines
-            (self.n_decode_steps, self.eos_id) if self.n_decode_steps > 1 else None,
             # constrained decoding: one boolean knob — schemas/automata are
             # mask ARGUMENTS (the LoRA idiom), so program identity never
             # sees a grammar; off collapses to None for cache sharing
@@ -3213,7 +3024,6 @@ class ServingEngine:
                          "prefill_chunk": self._build_prefill_chunk,
                          "prefill_chunk_paged": self._build_prefill_chunk_paged,
                          "decode_paged": self._build_decode_paged,
-                         "decode_multi_paged": self._build_decode_multi_paged,
                          }[kind]
             prog = build(a, b)
             # a genuinely new program for this geometry: count the compile
@@ -3248,7 +3058,7 @@ class ServingEngine:
                 draft_arena_sh=self.draft_pool.arena_sharding,
             )
         kw = program_shardings(kind, self.params, self.mesh, self.pool.arena_sharding)
-        if self._constraints and kind in ("prefill", "prefill_fresh", "decode_paged", "decode_multi_paged"):
+        if self._constraints and kind in ("prefill", "prefill_fresh", "decode_paged"):
             # the trailing constraint-mask argument is replicated like every
             # other small host-built per-step array
             from jax.sharding import NamedSharding, PartitionSpec
@@ -3558,84 +3368,6 @@ class ServingEngine:
 
         return decode_paged
 
-    def _build_decode_multi_paged(self, Bb: int, nbb: int) -> Callable:
-        """N decode steps per host visit: the single-step decode body wrapped
-        in a ``lax.scan`` with in-program stopping.
-
-        Per-row liveness: a row is live while ``pos <= stop`` and no EOS has
-        been sampled (``stop = prompt_len + max_new_tokens - 2`` is the last
-        position a row may write — exactly the position at which the
-        single-step engine's :meth:`_emit_token` fires FINISH_LENGTH on the
-        resulting token).  A dead row keep-masks its KV write to the sink
-        block (``write_fresh_kv_live``), freezes ``pos`` and ``toks``, and
-        stops splitting its PRNG key — so the per-request key chain advances
-        exactly once per *emitted* token, preserving the harvest-time
-        key-advance contract that makes fault-recovery replay bit-identical.
-        Padding rows enter with ``stop = -1`` and are dead from step 0.  Each
-        iteration runs the paged kernel straight off the arenas, so with the
-        kernel in it the N-step program contains zero arena gather/scatter
-        primitives.
-
-        Returns the scan's stacked ``(ys_tok, ys_emit)`` — the (N, Bb)
-        token matrix and liveness mask the harvest reads — plus the final
-        ``(toks, keys, pos)`` carry for the engine's ``_decode_state``
-        device-to-device chain, and the donated arenas."""
-        from thunder_tpu.serving.paged_attention import (
-            forward_paged,
-            write_fresh_kv_live,
-        )
-
-        cfg, temp = self.cfg, self.temperature
-        qkv = self.pool.quantized_kv
-        cdtype = jnp.dtype(self.pool.dtype)
-        kv_dtype = jnp.dtype(self.pool.kv_dtype) if qkv else None
-        bs = self.pool.block_size
-        cap = self.pool.capacity_tokens(nbb)
-        cos_all, sin_all = build_rope_cache(cfg, cap)
-        mesh = self.mesh
-        eos = self.eos_id
-        N = self.n_decode_steps
-
-        @partial(jax.jit, donate_argnums=(4,),
-                 **self._jit_kwargs("decode_multi_paged"))
-        def decode_multi_paged(params, toks, pos, tables, arenas, keys, lora,
-                               slots, stop, *cmask):
-            kw = self._fwd_kwargs(lora, slots)   # LoRA gather once per visit
-            live0 = pos <= stop
-
-            def body(carry, step_mask):
-                toks, pos, keys, live, arenas = carry
-                logits, fresh = forward_paged(
-                    params, toks[:, None], pos, arenas, tables,
-                    cos_all, sin_all, cfg, cdtype=cdtype, mesh=mesh,
-                    lora_fused=True, **kw,
-                )
-                sp = jax.vmap(jax.random.split)(keys)
-                new_keys = jnp.where(live[:, None], sp[:, 0], keys)
-                lg = logits[:, 0]
-                if cmask:
-                    lg = jnp.where(step_mask, lg, -jnp.inf)
-                nxt = jax.vmap(lambda l, k: sample_token(l[None], temp, k)[0])(
-                    lg, sp[:, 1]
-                )
-                new_arenas = write_fresh_kv_live(
-                    arenas, fresh, tables, pos, live,
-                    block_size=bs, kv_dtype=kv_dtype, mesh=mesh)
-                done = pos >= stop
-                if eos is not None:
-                    done = done | (nxt == eos)
-                toks_n = jnp.where(live, nxt, toks)
-                pos_n = jnp.where(live, pos + 1, pos)
-                live_n = live & ~done
-                return (toks_n, pos_n, new_keys, live_n, new_arenas), (nxt, live)
-
-            (toks_f, pos_f, keys_f, _live_f, arenas), (ys_tok, ys_emit) = (
-                jax.lax.scan(body, (toks, pos, keys, live0, arenas),
-                             cmask[0] if cmask else None, length=N))
-            return ys_tok, ys_emit, toks_f, keys_f, pos_f, arenas
-
-        return decode_multi_paged
-
 
 def serve(model_fn, params, cfg, **kwargs) -> ServingEngine:
     """Builds a :class:`ServingEngine` over the in-tree forward of ``cfg`` (a
@@ -3670,22 +3402,6 @@ def serve(model_fn, params, cfg, **kwargs) -> ServingEngine:
     in ``stats()["attn"]["fallback_steps"]`` (``serving.attn.fallback_steps``);
     ``stats()["attn"]["path"]`` reads ``"walk"``, ``"by_blocks"`` or ``"xla"``.
     Served tokens are bit-identical either way.
-
-    Multi-step decode: ``decode_steps=N`` runs N decode steps per host
-    visit inside one compiled program (a ``lax.scan`` over the decode body
-    with in-program EOS/length stopping and per-request liveness masks —
-    finished rows keep-mask their KV writes to the sink block), serving up
-    to N tokens per dispatch.  Tokens stay bit-identical to the 1-step
-    engine across the whole matrix (greedy/temperature, int8/fp8 KV, LoRA,
-    prefix sharing, chunked prefill, fault recovery); host visits per
-    served token drop to ~1/N.  N joins the program static key as one knob
-    (not per-horizon buckets), and ``decode_steps=1`` (default) is
-    byte-identical to the pre-knob engine, sharing the module program
-    cache.  The trade-off is loop-boundary scheduling: admissions,
-    deadline expiry, window reclamation, and streaming all happen at visit
-    boundaries, so N widens token-delivery granularity by up to N steps.
-    Incompatible with ``speculative=`` (that lane already amortizes host
-    visits over accepted tokens; construction raises with the reason).
 
     Async serving: ``async_step=True`` (default) runs ``step()`` as an
     event loop — decode for batch *k* is dispatched and the host admits,

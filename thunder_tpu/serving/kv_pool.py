@@ -827,11 +827,9 @@ def dest_for_pos(tables, pos, live, *, block_size):
 
     ``tables``: (B, nb) int32 (sink-padded); ``pos``/``live``: (B,).  Live
     rows advance through their own table as ``pos`` crosses block
-    boundaries (``tables[b, pos // bs]``, the in-program table walk the
-    multi-step decode scan relies on — the full table is leased at
+    boundaries (``tables[b, pos // bs]`` — the full table is leased at
     admission, so every entry the walk can reach is owned); dead rows route
-    to ``(SINK_BLOCK, 0)`` so a finished request's remaining scan
-    iterations write only garbage the sink absorbs.  Pure jnp; call inside
+    to ``(SINK_BLOCK, 0)``, where a write is garbage the sink absorbs.  Pure jnp; call inside
     jit.  ``take_along_axis`` clamps an out-of-range block index to the
     row's last (sink-padded) entry, matching the single-step derivation."""
     blk = jnp.take_along_axis(tables, (pos // block_size)[:, None], axis=1)[:, 0]

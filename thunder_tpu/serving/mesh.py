@@ -193,18 +193,6 @@ def program_shardings(kind: str, params, mesh: Mesh, arena_sh: NamedSharding,
             in_shardings=(param_sh, repl, repl, repl, arena_sh, repl, repl, repl),
             out_shardings=(repl, repl, repl, arena_sh),
         )
-    if kind == "decode_multi_paged":
-        # the decode row plus the replicated per-row stop positions:
-        # (params, toks, pos, tables, arenas, keys, lora, slots, stop)
-        #   -> (ys_tok, ys_emit, toks_f, keys_f, pos_f, arenas) — the
-        # stacked scan outputs and final carries stay replicated; the
-        # arenas keep the heads-over-tp sharding through every iteration
-        # (the scan carries them, so donation still chains per-shard)
-        return dict(
-            in_shardings=(param_sh, repl, repl, repl, arena_sh, repl, repl,
-                          repl, repl),
-            out_shardings=(repl, repl, repl, repl, repl, arena_sh),
-        )
     # the speculative lane (serving.speculative): draft params/arena carry
     # their own placements; the host-built chunk arrays stay replicated
     dparam_sh = jax.tree_util.tree_map(lambda x: x.sharding, draft_params)

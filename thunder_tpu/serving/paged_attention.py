@@ -79,7 +79,7 @@ from thunder_tpu.observability.events import scope
 from thunder_tpu.serving.kv_pool import ring_tables
 from thunder_tpu.serving.quant import quantize_kv
 
-__all__ = ["forward_paged", "with_state", "write_fresh_kv", "write_fresh_kv_live",
+__all__ = ["forward_paged", "with_state", "write_fresh_kv",
            "write_fresh_kv_masked", "write_fresh_kv_chunk", "decode_path"]
 
 
@@ -569,41 +569,6 @@ def write_fresh_kv(arenas, fresh, tables, pos, *, block_size, kv_dtype=None,
                           pos, block_size=block_size, mesh=mesh)
     va, vs = _write_fused(arenas["v"], arenas["v_scale"], fresh["v"], tables,
                           pos, block_size=block_size, mesh=mesh)
-    return {"k": ka, "v": va, "k_scale": ks, "v_scale": vs}
-
-
-@scope("mixer/cache")
-def write_fresh_kv_live(arenas, fresh, tables, pos, live, *, block_size,
-                        kv_dtype=None, mesh=None):
-    """Lands one multi-step scan iteration's fresh K/V, keep-masked by
-    per-row liveness.
-
-    ``fresh``: ``{"k"/"v": (B, L, ng, hs)}`` from a T=1
-    :func:`forward_paged` call; ``live``: (B,) bool.  A live row commits at
-    ``pos`` exactly like :func:`write_fresh_kv`; a dead row (finished
-    earlier in the scan, or batch padding) is sink-routed (block 0, never
-    attended) so the remaining iterations of a finished request leave no
-    trace in its real blocks.  Implemented as an offset-0 masked write —
-    ``n_emit = live`` makes :func:`paged_token_write`'s
-    ``offset < n_emit`` predicate the liveness mask itself — so the stored
-    bytes for live rows are bit-identical to the single-step kernel's and
-    the program still contains zero scatter primitives.  Quantized pools
-    take the same fused quantize-on-write epilogue as
-    :func:`write_fresh_kv`."""
-    n_emit = live.astype(jnp.int32)
-    if "latent" in arenas:
-        return {"latent": _write(arenas["latent"], fresh["latent"], tables, pos, n_emit=n_emit, offset=0,
-                                 block_size=block_size, mesh=mesh, name="mla_latent_write")}
-    if kv_dtype is None:
-        w = partial(_write, tables=tables, pos=pos, n_emit=n_emit,
-                    offset=0, block_size=block_size, mesh=mesh)
-        return {"k": w(arenas["k"], fresh["k"]), "v": w(arenas["v"], fresh["v"])}
-    ka, ks = _write_fused(arenas["k"], arenas["k_scale"], fresh["k"], tables,
-                          pos, block_size=block_size, mesh=mesh,
-                          n_emit=n_emit, offset=0)
-    va, vs = _write_fused(arenas["v"], arenas["v_scale"], fresh["v"], tables,
-                          pos, block_size=block_size, mesh=mesh,
-                          n_emit=n_emit, offset=0)
     return {"k": ka, "v": va, "k_scale": ks, "v_scale": vs}
 
 

@@ -169,7 +169,6 @@ class Scheduler:
         sliding_window: int | None = None,
         prefill_chunk: int | None = None,
         reserve_extra_tokens: int = 0,
-        decode_horizon: int = 1,
     ):
         self.pool = pool
         self.max_batch = int(max_batch)
@@ -178,15 +177,8 @@ class Scheduler:
         self.sliding_window = sliding_window
         # extra cache slots reserved past prompt+max_new (speculative
         # serving: a round's draft scan writes up to K slots past the last
-        # committed token, and those writes must land in owned blocks;
-        # multi-step decode likewise reserves its N-1 slot overshoot)
+        # committed token, and those writes must land in owned blocks)
         self.reserve_extra_tokens = int(reserve_extra_tokens)
-        # tokens one decode dispatch may serve per row before the host sees
-        # any of them (decode_steps=N).  Admission, deadline expiry, and
-        # window reclamation all happen at visit boundaries — the horizon is
-        # recorded so snapshots/diagnostics can attribute the added
-        # scheduling latency to the knob rather than to a stall
-        self.decode_horizon = int(decode_horizon)
         max_blocks = pool.num_usable
         self.batch_buckets = tuple(batch_buckets) if batch_buckets else pow2_buckets(1, self.max_batch)
         self.block_buckets = tuple(block_buckets) if block_buckets else pow2_buckets(1, max_blocks)
@@ -495,7 +487,6 @@ class Scheduler:
             "block_buckets": list(self.block_buckets),
             "prefill_buckets": list(self.prefill_buckets),
             "prefill_chunk": self.prefill_chunk,
-            "decode_horizon": self.decode_horizon,
             "requests": [row(r) for r in (*self.running, *self.queue)],
         }
 

@@ -71,7 +71,7 @@ from thunder_tpu.serving.quant import (
     scatter_token_q,
 )
 
-__all__ = ["SpecConfig", "multi_step_supported", "validate_spec"]
+__all__ = ["SpecConfig", "validate_spec"]
 
 
 @dataclass
@@ -115,28 +115,6 @@ def validate_spec(spec: SpecConfig, cfg, *, sliding_window) -> None:
             "window expiry would invalidate the K-token draft/verify arena "
             "math (solo speculative_generate has the same restriction)"
         )
-
-
-def multi_step_supported(spec: SpecConfig) -> tuple[bool, str | None]:
-    """Whether the speculative lane can chain draft+verify rounds behind
-    ``decode_steps=N`` (the in-program multi-step scan).
-
-    Currently always ``(False, reason)``: a spec round emits a
-    *data-dependent* 1..K+1 tokens, so N rounds inside one program would
-    need ragged (N, K+1) outputs plus an in-program replay of the
-    harvest-side accounting (per-round acceptance histogram, key-chain
-    mirroring against solo ``speculative_generate``, draft-arena trim on
-    rejection) that today runs on the host between rounds.  The engine
-    records this reason and rejects ``decode_steps>1`` with
-    ``speculative=`` at construction rather than silently serving a
-    different schedule — a spec round already amortizes the host visit
-    over its accepted tokens, so the two knobs target the same overhead."""
-    return False, (
-        "a speculative round emits a data-dependent 1..K+1 tokens per host "
-        "visit; chaining N rounds in-program needs ragged outputs and "
-        "in-program acceptance accounting that currently lives on the host "
-        "(the spec lane already amortizes host visits over accepted tokens)"
-    )
 
 
 #

@@ -4,13 +4,16 @@
     JAX_PLATFORMS=cpu python3 tools/lowered_same.py same <out dir> <other out dir>
 
 ``lower`` writes, from the tree given (its own ``thunder_tpu``, ``chipbench`` and
-``tests``; run a copy of this file against a parent's checkout), the text one
-program a cell lowers to with ``lowering_platforms=("tpu",)``, Pallas on and
+``tests``; run a copy of this file against a parent's checkout), the text a
+cell's programs lower to with ``lowering_platforms=("tpu",)``, Pallas on and
 nothing interpreted, over weights that are shapes alone: a ``prefill_fresh``
 bucket of ``mistral7b-serve-1chip`` (3,072), ``lfm2moe-serve-1chip`` (2,560)
 and ``phi4flash-serve-1chip`` (5,120) at the depths
-``tests/test_pallas_tpu_lowering.py`` builds them, and ``mistral7b-train-1chip``'s
-step at one layer.  ``same`` compares two such directories file by file.  A
+``tests/test_pallas_tpu_lowering.py`` builds them, ``decode_paged`` at one batch
+and block bucket of ``mistral7b-serve-1chip`` (K/V blocks), ``lfm2moe-serve-1chip``
+(a tail beside packed rows) and ``axk1-serve-1chip`` (latent rows), and
+``mistral7b-train-1chip``'s step at one layer.  ``same`` compares two such
+directories file by file.  A
 Mosaic kernel's body is bytecode that carries its source's path and line
 numbers, so each is parsed and printed without locations first; everything
 else is compared as it is.  Exits non-zero where a text differs."""
@@ -20,7 +23,8 @@ import os
 import re
 import sys
 
-CELLS = ("mistral7b-serve-1chip", "lfm2moe-serve-1chip", "phi4flash-serve-1chip", "mistral7b-train-1chip")
+CELLS = ("mistral7b-serve-1chip", "lfm2moe-serve-1chip", "phi4flash-serve-1chip", "axk1-serve-1chip",
+         "mistral7b-train-1chip")
 
 
 def lower(root, out, cells):
@@ -44,13 +48,22 @@ def lower(root, out, cells):
         print(name, len(text), "bytes,", text.count("tpu_custom_call"), "kernels,",
               text.count('kernel_name = "_flash_fwd"'), "_flash_fwd", flush=True)
 
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)   # noqa: E731
+    one = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt)   # noqa: E731
+
     def prefill(eng, params, Tb):
-        sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)   # noqa: E731
-        one = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt)   # noqa: E731
         nbb = Tb // eng.pool.block_size
         args = (jax.tree_util.tree_map(sds, params), one((1, Tb)), one(()), jax.tree_util.tree_map(sds, eng.pool.arenas),
                 one((nbb,)), one((2,), jnp.uint32), {}, one((1,)), one((1,)))
         return eng._build_prefill(Tb, nbb, fresh=True).trace(*args).lower(lowering_platforms=("tpu",))
+
+    def decode(eng, params, rows, width):
+        """``decode_paged`` at ``(rows, width)`` with the operands the engine's dispatch hands it: after the adapter
+        slots the expert share's running sums (a sparse model), then the state slots (a state a request)."""
+        args = (jax.tree_util.tree_map(sds, params), one((rows,)), one((rows,)), one((rows, width)),
+                jax.tree_util.tree_map(sds, eng.pool.arenas), one((rows, 2), jnp.uint32), {}, one((rows,)),
+                *([one((4,), jnp.float32)] if eng._moe_rows is not None else []), *([one((rows,))] if eng._hybrid else []))
+        return eng._build_decode_paged(rows, width).trace(*args).lower(lowering_platforms=("tpu",))
 
     if any("serve" in c for c in cells):
         import test_pallas_tpu_lowering as t
@@ -59,12 +72,18 @@ def lower(root, out, cells):
     if "mistral7b-serve-1chip" in cells:
         keep("mistral7b-serve-1chip.prefill_fresh_3072",
              t._lower_prefill("mistral7b-serve-1chip.offline-batch", "prefill_fresh", None)[3])
+        _, params, eng = t._cell_engine("mistral7b-serve-1chip.offline-batch")
+        keep("mistral7b-serve-1chip.decode_paged_2x192", decode(eng, params, 2, 192))
     if "lfm2moe-serve-1chip" in cells:
         _, params, eng = t._lfm2_engine()
         keep("lfm2moe-serve-1chip.prefill_fresh_2560", prefill(eng, params, 2560))
+        keep("lfm2moe-serve-1chip.decode_paged_256x256", decode(eng, params, 256, 256))
     if "phi4flash-serve-1chip" in cells:
         _, params, eng = t._flash_engine()
         keep("phi4flash-serve-1chip.prefill_fresh_5120", prefill(eng, params, 5120))
+    if "axk1-serve-1chip" in cells:
+        _, params, eng = t._mla_engine()
+        keep("axk1-serve-1chip.decode_paged_64x640", decode(eng, params, 64, 640))
     if "mistral7b-train-1chip" in cells:
         from chipbench import common
         from chipbench.drivers import train

@@ -763,7 +763,13 @@ CELL_SHARES = {      # tokens, k, held, all, tile, C, I; and whether the tokens 
     "lfm2_decode": (256, 4, 32, 32, 64, 2048, 1792, True), "lfm2_prefill": (2048, 4, 32, 32, 128, 2048, 1792, True),
     "axk1_decode": (64, 8, 12, 192, 16, 7168, 2048, True), "axk1_prefill": (8192, 8, 12, 192, 128, 7168, 2048, False),
     "hybrid_train": (16384, 10, 32, 512, 128, 2048, 512, False),
+    "xing4_prefill": (8192, 4, 64, 64, 128, 3584, 1024, True), "xing4_prefill_5k": (5120, 4, 64, 64, 128, 3584, 1024, True),
+    "xing4_decode": (32, 4, 64, 64, 16, 3584, 1024, True), "trinity_prefill": (9984, 8, 16, 128, 128, 2048, 1024, False),
+    "lfm2_prefill_3k": (3072, 4, 32, 32, 128, 2048, 1792, True),
 }
+# where ``moe_combine`` is asked (``jaxex._kernel_takes``; PERF.md, PR 58): wherever XLA would add to the tokens, and
+# where it would gather from Xing4.0's prompts up; LFM2's prompts and every decode step keep XLA's gather
+WALKED = {"axk1_prefill", "hybrid_train", "xing4_prefill", "xing4_prefill_5k", "trinity_prefill"}
 
 
 def _share_shapes(N, k, held, C, I, dtype=jnp.bfloat16):
@@ -773,11 +779,11 @@ def _share_shapes(N, k, held, C, I, dtype=jnp.bfloat16):
 
 
 def _scatters(jaxpr):
-    """Every scatter of a jaxpr and of the jaxprs inside it: ``(primitive, dtype of the updates)``."""
+    """Every scatter of a jaxpr and of the jaxprs inside it: ``(primitive, dtype of the updates, their rank)``."""
     out = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name.startswith("scatter"):
-            out.append((eqn.primitive.name, eqn.invars[2].aval.dtype))
+            out.append((eqn.primitive.name, eqn.invars[2].aval.dtype, eqn.invars[2].aval.ndim))
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
@@ -786,9 +792,25 @@ def _scatters(jaxpr):
     return out
 
 
+@pytest.fixture
+def xla_combine(monkeypatch):
+    """The tokens' rows come back by XLA's two forms: ``moe_combine`` is not asked."""
+    monkeypatch.setattr(jaxex, "_tokens_of_rows_fast_path", None)
+
+
+@pytest.fixture
+def walked(interpreted, monkeypatch):
+    """``moe_combine`` takes every call it can, a decode step's few tokens too,
+    at the VMEM a v5e gives (the interpreter knows none: a cell's widths would
+    not fit the default)."""
+    monkeypatch.setattr(jaxex, "_TOKENS_A_KERNEL_CALL", 0)
+    monkeypatch.setattr(jaxex, "_ASSIGNMENTS_GATHERED_A_KERNEL_CALL", 0)
+    monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)
+
+
 @pytest.mark.parametrize("cell", list(CELL_SHARES))
-def test_no_floating_point_scatter_where_the_tokens_gather_their_rows(cell):
-    """At the shapes the cells run: where a share holds a fair part of the
+def test_no_floating_point_scatter_where_the_tokens_gather_their_rows(cell, xla_combine):
+    """At the shapes the cells run, in XLA's forms: where a share holds a fair part of the
     experts, or the step is a decode step, neither the share nor its backward
     holds a scatter with floating-point updates; where ten assignments stand
     for a buffer row (a long prompt of a share of 12 of 192, the trainer's 32
@@ -803,17 +825,242 @@ def test_no_floating_point_scatter_where_the_tokens_gather_their_rows(cell):
     assert (not floating) == gathers, floating
 
 
+def _pallas_calls(jaxpr) -> list:
+    """The ``name`` of every ``pallas_call`` of a jaxpr and of the jaxprs inside it."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"] if "name" in eqn.params else eqn.params["name_and_src_info"].name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out += _pallas_calls(inner)
+    return out
+
+
 @pytest.mark.parametrize("cell", list(CELL_SHARES))
-def test_dispatch_and_combine_keep_the_two_index_arrays_and_no_rows(cell):
-    N, k, held, total, tile, C, I, gathers = CELL_SHARES[cell]
-    R = jaxex.moe_wave_tiles(N * k, held, total, tile) * tile
-    sd, static = jax.ShapeDtypeStruct, (N, k, jnp.dtype(jnp.bfloat16))
-    row_src, pos = sd((R,), jnp.int32), sd((N, k), jnp.int32) if gathers else None
+def test_no_floating_point_scatter_anywhere_where_the_kernel_walks_the_tokens(cell, interpreted, monkeypatch, request):
+    """The twin on the kernel's path, at the cells' own shapes (traced, not run):
+    the share and its backward hold ``moe_combine`` wherever XLA would add to
+    the tokens (a prompt of A.X-K1 or Trinity-Mini, the trainer's step) and at
+    Xing4.0's prompts, and there no scatter of rows (the walk needs no ``pos``:
+    the plan makes none where it made none, so the routing weights' gradient is
+    still added to, a number a row); LFM2's prompts and every decode step keep
+    XLA's gather and hold no ``moe_combine``."""
+    monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)
+    # the grouped products' jits keep what they traced, the interpreter's call with it: not for the compiles
+    # of ``test_pallas_tpu_lowering.py`` at these very shapes, should this worker run them next
+    request.addfinalizer(px._moe_grouped_mm.clear_cache)
+    request.addfinalizer(px._moe_grouped_mm_dw.clear_cache)
+    N, k, held, total, tile, C, I, _ = CELL_SHARES[cell]
+    x, idx, tw, w1, w3, w2 = _share_shapes(N, k, held, C, I)
+    wave_tiles = jaxex.moe_wave_tiles(N * k, held, total, tile)
+    assert (jax.eval_shape(lambda i, w: jaxex.moe_plan(i, w, 0, held, tile, wave_tiles), idx, tw)["pos"] is not None) == CELL_SHARES[cell][-1]
+    before = px.stats.get("moe_combine", 0)
+    fwd = jax.make_jaxpr(lambda *a: jaxex._moe_share(*a, 0, total, tile))(x, idx, tw, w1, w3, w2)
+    bwd = jax.make_jaxpr(lambda *a: jaxex._moe_expert_share_backward_impl(*a, 0, total, tile))(x, x, idx, tw, w1, w3, w2)
+    takes = cell in WALKED
+    assert takes == jaxex._kernel_takes((N, k, None, held), wave_tiles * tile)
+    floating = [s for s in _scatters(fwd.jaxpr) + _scatters(bwd.jaxpr) if jnp.issubdtype(s[1], jnp.floating)]
+    assert (not [s for s in floating if s[2] > 1]) == (takes or CELL_SHARES[cell][-1]), "no rows are scattered where the kernel walks"
+    # where the plan makes no ``pos`` the routing weights' gradient is still added to, a number a row
+    assert (not [s for s in floating if s[2] == 1]) == CELL_SHARES[cell][-1], floating
+    assert ("moe_combine" in _pallas_calls(fwd.jaxpr)) == takes and ("moe_combine" in _pallas_calls(bwd.jaxpr)) == takes
+    assert (px.stats.get("moe_combine", 0) > before) == takes
+    if takes:
+        assert px.combine_schedule["block_tokens"] == 128 and px.combine_schedule["streams"] == held
+        assert px.combine_schedule["vmem_limit_bytes"] <= 96 << 20
+
+
+def _kept_by_dispatch_and_combine(cell, pos: bool):
+    N, k, held, total, tile, C, I, _ = CELL_SHARES[cell]
+    tiles = jaxex.moe_wave_tiles(N * k, held, total, tile)
+    R = tiles * tile
+    sd, static = jax.ShapeDtypeStruct, (N, k, jnp.dtype(jnp.bfloat16), held)
+    row_src, pos, tg = sd((R,), jnp.int32), sd((N, k), jnp.int32) if pos else None, sd((tiles,), jnp.int32)
     kept = [jax.eval_shape(functools.partial(jaxex._dispatch_fwd, static), sd((N, C), jnp.bfloat16), sd((N, k), jnp.float32),
-                           row_src, pos, sd((R,), jnp.float32))[1],
-            jax.eval_shape(functools.partial(jaxex._combine_fwd, static), sd((R, C), jnp.bfloat16), row_src, pos)[1]]
-    leaves = jax.tree_util.tree_leaves(kept)
-    assert leaves and all(leaf.dtype == jnp.int32 and leaf.shape in ((R,), (N, k)) for leaf in leaves), leaves
+                           row_src, pos, sd((R,), jnp.float32), tg)[1],
+            jax.eval_shape(functools.partial(jaxex._combine_fwd, static), sd((R, C), jnp.bfloat16), row_src, pos, tg)[1]]
+    return jax.tree_util.tree_leaves(kept), ((R,), (N, k)), (tiles,)
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHARES))
+def test_dispatch_and_combine_keep_the_two_index_arrays_and_no_rows(cell, xla_combine):
+    leaves, index_arrays, groups = _kept_by_dispatch_and_combine(cell, CELL_SHARES[cell][-1])
+    assert leaves and all(leaf.dtype == jnp.int32 and leaf.shape in (*index_arrays, groups) for leaf in leaves), leaves
+    assert sum(leaf.shape in index_arrays for leaf in leaves) == (3 if CELL_SHARES[cell][-1] else 2)
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHARES))
+def test_on_the_kernels_path_they_keep_the_two_index_arrays_and_no_rows(cell, walked):
+    """``moe_combine`` walks by ``row_src`` and reads which group a row tile is
+    of: what the two keep for the backward pass is still ``row_src`` and, where
+    the plan makes it, ``pos`` (the dispatch's both, the combine's
+    ``row_src``), int32, and beside them the wave's ``tile_group`` (a number a
+    row tile, which the grouped products keep already); no row of ``C`` numbers."""
+    leaves, index_arrays, groups = _kept_by_dispatch_and_combine(cell, CELL_SHARES[cell][-1])
+    assert all(leaf.dtype == jnp.int32 and leaf.shape in (*index_arrays, groups) for leaf in leaves), leaves
+    assert sum(leaf.shape in index_arrays for leaf in leaves) == (3 if CELL_SHARES[cell][-1] else 2)
+    assert sum(leaf.shape == groups for leaf in leaves) == 1
+
+
+# ``moe_combine`` (PR 58) against XLA's two forms, to the bit: a rehearsal size of each cell's share
+# (its k, its held of all, its tile; fewer tokens, a narrow C), and the routings that corner the walk
+WALKS = {      # tokens, k, first, held, all, tile, C, and how the routing is bent
+    "lfm2_decode": dict(N=256, k=4, first=0, held=32, total=32, tile=64, C=128),
+    "lfm2_prefill": dict(N=640, k=4, first=0, held=32, total=32, tile=128, C=128),
+    "axk1_decode": dict(N=64, k=8, first=24, held=12, total=192, tile=16, C=256),
+    "axk1_prefill": dict(N=1100, k=8, first=24, held=12, total=192, tile=128, C=128),
+    "hybrid_train": dict(N=1024, k=10, first=64, held=32, total=512, tile=128, C=128),
+    "a_token_with_all_its_rows_and_most_with_none": dict(N=300, k=4, first=8, held=4, total=64, tile=16, C=128, all_on=(7, 299)),
+    "every_expert_held_and_one_draws_no_row": dict(N=200, k=4, first=0, held=8, total=8, tile=16, C=128, never=3),
+    "a_second_and_a_third_wave": dict(N=400, k=4, first=4, held=8, total=256, tile=16, C=128, over=16, onto=5),
+    "a_share_that_draws_nothing": dict(N=130, k=2, first=6, held=2, total=8, tile=16, C=128, over=6),
+}
+
+
+def _walk_case(N, k, first, held, total, tile, C, over=None, onto=None, never=None, all_on=(), seed=0):
+    rng = np.random.default_rng(seed)
+    pool = np.array([e for e in range(over or total) if e != never])
+    idx = np.stack([rng.permutation(pool)[:k] for _ in range(N)]).astype(np.int32)
+    if onto is not None:
+        idx[:, 0] = onto
+    for n in all_on:
+        idx[n] = first + rng.permutation(held)[:k]
+    tw = jnp.asarray(rng.random((N, k)), jnp.float32)
+    wave_tiles = jaxex.moe_wave_tiles(N * k, held, total, tile)
+    return jnp.asarray(idx), tw, wave_tiles, jaxex.moe_plan(jnp.asarray(idx), tw, first, held, tile, wave_tiles)
+
+
+@pytest.mark.parametrize("rows_dtype,sum_dtype", [("float32", "float32"), ("bfloat16", "float32"), ("bfloat16", "bfloat16")],
+                         ids=["float32_rows", "bfloat16_rows_summed_in_float32", "bfloat16_rows_summed_at_16_bits"])
+@pytest.mark.parametrize("case", list(WALKS))
+def test_the_kernel_gives_the_bits_of_both_xla_forms(case, rows_dtype, sum_dtype, walked, monkeypatch):
+    """Every wave of the buffer: ``moe_combine``, the scatter-add and the gather
+    by ``pos`` add a token's rows lowest first in float32, so the three agree in
+    every bit.  The sum of the rows' gradient comes back at the rows' own 16
+    bits: rounded once from the float32 sum, as the chip's scatter-add and its
+    fused adds round it (``tools/moe_tune.py --glue --check`` holds the kernel
+    to their bits there; this CPU's XLA rounds after every row, which is not
+    what the program runs on)."""
+    c = WALKS[case]
+    N, k, held, tile, C = (c[n] for n in ("N", "k", "held", "tile", "C"))
+    idx, _, wave_tiles, plan = _walk_case(**c)
+    counts = np.asarray((idx >= c["first"]) & (idx < c["first"] + held)).sum(axis=1)
+    if "all_on" in c:
+        assert (counts[list(c["all_on"])] == k).all() and (counts == 0).mean() > 0.5
+    waves = plan["tile_group"].shape[0] // wave_tiles
+    assert (case == "a_second_and_a_third_wave") == (int(plan["tiles_used"]) > 2 * wave_tiles)
+    static = (N, k, jnp.dtype(rows_dtype), held)
+    rng = np.random.default_rng(1)
+    for w in range(min(waves, 3)):
+        row_src, pos, _, tg, _ = jaxex.moe_wave_rows(plan, w, tile, wave_tiles)
+        vb = jnp.asarray(rng.standard_normal((wave_tiles * tile, C)), rows_dtype)
+        before = px.stats.get("moe_combine", 0)
+        got = jaxex._tokens_of_rows(vb, row_src, pos, tg, static, sum_dtype)
+        assert px.stats["moe_combine"] == before + 1 and "fallback" not in px.combine_schedule
+        with monkeypatch.context() as m:
+            m.setattr(jaxex, "_tokens_of_rows_fast_path", None)
+            added = jaxex._tokens_of_rows(vb, row_src, None, tg, static, jnp.float32).astype(sum_dtype)
+            gathered = added if pos is None else jaxex._tokens_of_rows(vb, row_src, pos, tg, static, jnp.float32).astype(sum_dtype)
+        assert got.dtype == jnp.dtype(sum_dtype) and got.shape == (N, C)
+        got, added, gathered = (np.asarray(a.astype(jnp.float32)) for a in (got, added, gathered))
+        assert (got == added).all() and (got == gathered).all(), (w, np.abs(got - added).max())
+        here = np.asarray(row_src)[np.asarray(row_src) >= 0] // k
+        assert (got[np.setdiff1d(np.arange(N), here)] == 0).all(), "a token without a row in this wave gets zeros"
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["lfm2_prefill", "axk1_prefill", "hybrid_train", "a_second_and_a_third_wave"])
+def test_the_share_and_its_gradients_are_the_xla_paths_through_the_kernel(case, dtype, walked, monkeypatch):
+    """``_moe_share`` and ``jax.vjp`` of it with ``moe_combine`` on the way
+    (the share's sum and, backward, the sum of the rows' gradient; the weights'
+    gradient by ``pos`` where XLA's form adds) against XLA's forms: the share,
+    ``dx``, the routing weights' and the three matrices' gradients, bit for bit
+    (a 16-bit ``dx`` to its rounding: see the test above).
+    (Where later waves run, XLA compiles each ``cond`` branch's ``y + wave`` with
+    its own form's adds in it and is free to add a wave's rows to ``y`` one by
+    one; the kernel's sum is a wave's alone.  Equal to a rounding there, as a
+    token alone and among others is where waves differ.)"""
+    c = WALKS[case]
+    N, k, first, held, total, tile, C = (c[n] for n in ("N", "k", "first", "held", "total", "tile", "C"))
+    idx, tw, *_ = _walk_case(**c)
+    rng = np.random.default_rng(2)
+    x, dy = (jnp.asarray(rng.standard_normal((N, C)), dtype) for _ in range(2))
+    w1, w3 = (jnp.asarray(rng.standard_normal((held, C, 128)) * 0.1, dtype) for _ in range(2))
+    w2 = jnp.asarray(rng.standard_normal((held, 128, C)) * 0.1, dtype)
+
+    def run():
+        y, vjp = jax.vjp(lambda *a: jaxex._moe_share(a[0], idx, *a[1:], first, total, tile), x, tw, w1, w3, w2)
+        return (y, *vjp(dy))
+
+    before = px.stats.get("moe_combine", 0)
+    got = run()
+    assert px.stats["moe_combine"] >= before + 2, "the share's sum and the rows' gradient's"
+    with monkeypatch.context() as m:
+        m.setattr(jaxex, "_tokens_of_rows_fast_path", None)
+        want = run()
+    for name, a, b in zip(("y", "dx", "dtop_w", "dfc_1", "dfc_2", "dproj"), got, want):
+        assert a.dtype == b.dtype, name
+        if case == "a_second_and_a_third_wave":
+            assert rel(a, b) < (1e-6 if dtype == "float32" else 4e-3), name
+        elif name == "dx" and dtype == "bfloat16":      # rounded once here; after every row by this CPU's XLA (not by the chip's)
+            assert rel(a, b) < 4e-3, name
+        else:
+            assert (np.asarray(a.astype(jnp.float32)) == np.asarray(b.astype(jnp.float32))).all(), name
+
+
+def test_a_token_alone_in_xla_has_the_bits_of_the_token_among_the_others_in_the_kernel(interpreted, monkeypatch):
+    """The solo contract across the two: a request's last token alone is a call
+    of one token, XLA's; in a prompt of 640 it is the kernel's."""
+    monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)
+    monkeypatch.setattr(jaxex, "_ASSIGNMENTS_GATHERED_A_KERNEL_CALL", 2048)
+    idx, tw, x, w1, w3, w2 = _routed(N=640, k=4, first=0, held=8, total=16, tile=16, C=128, I=128)
+    before = px.stats.get("moe_combine", 0)
+    among = np.asarray(jaxex._moe_share(x, idx, tw, w1, w3, w2, 0, 16, 16))
+    before, took = px.stats["moe_combine"], px.stats["moe_combine"] - before
+    assert took >= 1
+    for n in (0, 77, 639):
+        alone = np.asarray(jaxex._moe_share(x[n:n + 1], idx[n:n + 1], tw[n:n + 1], w1, w3, w2, 0, 16, 16))
+        assert (alone[0] == among[n]).all(), n
+    assert px.stats["moe_combine"] == before, "a call of one token is XLA's"
+
+
+@pytest.mark.parametrize("why", ["shape", "several devices", "dtype", "VMEM", "no Pallas", "few tokens"])
+def test_where_the_kernel_declines_xla_runs_and_the_schedule_says_why(why, interpreted, monkeypatch):
+    N, k, held, total, tile, C = 640, 4, 8, 16, 16, 128
+    dtype = jnp.float32
+    monkeypatch.setattr(jaxex, "_ASSIGNMENTS_GATHERED_A_KERNEL_CALL", 2048)
+    if why == "shape":
+        C = 192                              # not whole lane tiles
+    elif why == "dtype":
+        dtype = jnp.float16
+    elif why == "VMEM":
+        monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 1 << 20)
+    elif why == "no Pallas":
+        monkeypatch.delenv("THUNDER_TPU_PALLAS_INTERPRET")
+    elif why == "few tokens":
+        N = jaxex._TOKENS_A_KERNEL_CALL - 1
+    idx, tw, x, w1, w3, w2 = _routed(N=N, k=k, first=0, held=held, total=total, tile=tile, C=C, I=128)
+    x, w1, w3, w2 = (a.astype(dtype) for a in (x, w1, w3, w2))
+    wave_tiles = jaxex.moe_wave_tiles(N * k, held, total, tile)
+    px.combine_schedule.clear()
+    before = px.stats.get("moe_combine", 0)
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("x",))
+    with (px.mesh_context(mesh) if why == "several devices" else monkeypatch.context()):
+        said = px.combine_declines(N, wave_tiles * tile, wave_tiles, C, held, dtype, jnp.float32)
+        got = jaxex._moe_share(x, idx, tw, w1, w3, w2, 0, total, tile)
+        assert px.stats.get("moe_combine", 0) == before
+        schedule = dict(px.combine_schedule)
+        with monkeypatch.context() as m:
+            m.setattr(jaxex, "_tokens_of_rows_fast_path", None)
+            want = jaxex._moe_share(x, idx, tw, w1, w3, w2, 0, total, tile)
+    assert (np.asarray(got, np.float32) == np.asarray(want, np.float32)).all()
+    if why == "few tokens":       # jaxex's own rule, beside the one that chooses between XLA's forms: the kernel is not asked
+        assert said == "" and not schedule
+    else:
+        assert said == why and schedule == {"fallback": why, "tokens": N, "slots": k, "rows": wave_tiles * tile, "width": C}
 
 
 # --------------------------------------------------------------------------

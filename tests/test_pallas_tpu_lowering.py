@@ -875,6 +875,12 @@ def test_the_latent_cells_programs_lower_to_their_kernels(cell, kind, tpu_shardi
     claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
     assert claimed("grouped_mm") >= 3 and 'kernel_name = "moe_grouped_mm"' in text
     assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)      # nothing of an arena's five dims
+    # a prompt's tokens take their experts' rows back through ``moe_combine`` (PR 58: the first wave's call and the
+    # later waves' behind their ``cond``), a decode step's few rows through XLA's gather
+    assert (claimed("moe_combine") >= 1) == ('kernel_name = "moe_combine"' in text) == (kind == "prefill_fresh")
+    if kind == "prefill_fresh":
+        assert px.combine_schedule["streams"] == {MLA_CELL: 12, HC_CELL: 64}[cell] and px.combine_schedule["chunk_rows"] == 16   # the experts held
+        assert not re.search(r"stablehlo\.scatter[^\n]*xf32>", text) and not re.search(rf"tensor<{cfg.n_expert_per_token}x{Tb}x{cfg.n_embd}xbf16>", text)
     if kind == "prefill_fresh":
         assert claimed("direct") == cfg.n_layer and 'kernel_name = "_flash_fwd"' in text
         assert px.flash_schedule["grid_steps"] > 0
@@ -1168,11 +1174,64 @@ def test_the_hybrid_trainers_grouped_product_states_no_vmem_limit(tpu_sharding):
     assert "iteration_bounds = array<i64: 128, 1>" in module and "memref<1x2048x512xbf16" in module
 
 
+# tokens, k, held, the wave's rows, their tile, C: a prompt of each cell that holds an expert share, the trainer's step, a decode step's
+COMBINE_SHAPES = {
+    "axk1_prefill": (8192, 8, 12, 6144, 128, 7168), "xing4_prefill": (8192, 4, 64, 45056, 128, 3584),
+    "hybrid_train": (16384, 10, 32, 16384, 128, 2048), "trinity_prefill": (9984, 8, 16, 13312, 128, 2048),
+    "lfm2_prefill": (2048, 4, 32, 12288, 128, 2048), "axk1_decode": (64, 8, 12, 128, 16, 7168),
+    "lfm2_decode": (256, 4, 32, 3200, 64, 2048),
+}
+
+
+@pytest.mark.parametrize("summed", ["float32", "bfloat16"], ids=["the_shares_sum", "the_rows_gradients_sum"])
+@pytest.mark.parametrize("cell", sorted(COMBINE_SHAPES))
+def test_the_combine_compiles_at_the_cells_shapes_inside_the_vmem_it_asks_for(cell, summed, tpu_sharding, monkeypatch):
+    """``moe_combine`` at the cells' own shapes, bfloat16 rows: ``(8192, 8)``
+    into ``(N, 7168)`` from a wave of 6,144 rows, ``(8192, 4)`` into ``(N,
+    3584)`` from 45,056, the trainer's ``(16384, 10)`` into ``(N, 2048)`` from
+    16,384, a decode step's (which ``jaxex`` leaves to XLA: the kernel compiles
+    all the same).  Only the compile shows that Mosaic takes the chunk copies
+    (a sublane tile of rows out of a tiled array in HBM), the wave's whole list
+    of rows in SMEM and the row reads at a dynamic sublane; and holds the call to the
+    scoped limit it states, which stays under what a v5e gives."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)      # three quarters of a v5e core's, which the chip reports
+    N, k, held, R, tile, C = COMBINE_SHAPES[cell]
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=tpu_sharding) for sh, dt in (((R, C), BF), ((R,), I32), ((R // tile,), I32))]
+    before = px.stats.get("moe_combine", 0)
+    lowered = jax.jit(lambda vb, rs, tg: px.combine(vb, rs, tg, N, k, held, summed)).trace(*args).lower(lowering_platforms=("tpu",))
+    assert px.stats["moe_combine"] == before + 1
+    plan = dict(px.combine_schedule)
+    assert plan["block_tokens"] == min(N, 128) and plan["streams"] == held and plan["chunk_rows"] == 16 and plan["rows_listed"] == R
+    assert plan["vmem_limit_bytes"] == px._combine_vmem(plan["block_tokens"], C, held, 2, jnp.dtype(summed).itemsize) <= 96 << 20
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and 'kernel_name = "moe_combine"' in text
+    assert f'\\22size\\22: {max(plan["vmem_limit_bytes"], px._GMM_VMEM_DEFAULT)}}}' in text     # the scoped limit the call states
+    module = _mosaic_module(text)
+    assert f"memref<{R}x{C}xbf16, #tpu.memory_space<any>>" in module and f"iteration_bounds = array<i64: {-(-N // 128)}>" in module
+    if tpu_sharding is not None:
+        assert re.search(r"%moe_combine(\.\d+)? = ", lowered.compile().as_text())
+
+
+def test_the_combine_declines_what_does_not_fit_the_vmem_of_an_unknown_device(monkeypatch):
+    """Where the device's VMEM is not known (this CPU, a lowering for another
+    host's chip) the chunks of a wide share do not fit the default 16 MiB and
+    the call is XLA's; the token tile shrinks before that."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    assert px._gmm_vmem_cap() == px._GMM_VMEM_DEFAULT
+    assert px.combine_declines(8192, 45056, 352, 3584, 64, BF, F32) == "VMEM"
+    assert px.combine_declines(8192, 6144, 48, 7168, 12, BF, F32) == "" and px._combine_tile(8192, 7168, 12, 2, 4) == 16
+    assert px.combine_declines(16384, 16384, 128, 2048, 32, BF, BF) == "" and px._combine_tile(16384, 2048, 32, 2, 2) == 128
+    # a row, its group and its token in one int32 of the list, and the list in SMEM
+    assert px.combine_declines(16384, 1 << 18, 2048, 2048, 32, BF, BF) == "shape"
+    assert px.combine_declines(16384, 1 << 17, 1024, 2048, 1024, BF, BF) == "shape"
+
+
 def test_every_pallas_call_site_is_named():
     import inspect
 
     src = inspect.getsource(px)
-    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 23      # PR 45: the Mamba-2 scan's two; PR 56: hc_mix
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 24      # PR 45: the Mamba-2 scan's two; PR 56: hc_mix; PR 58: moe_combine
     assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
         "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
         "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",

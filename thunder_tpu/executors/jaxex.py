@@ -885,6 +885,7 @@ def _causal_conv1d_backward_impl(g, x, w, activation=None):
 # the tiles used.
 _grouped_mm_fast_path: Callable | None = None      # (x, w, tile_group, tiles_used, transpose_w) -> out or None
 _grouped_mm_dw_fast_path: Callable | None = None   # (x, dy, tile_group, tiles_used, groups) -> dw or None
+_tokens_of_rows_fast_path: Callable | None = None  # (vb, row_src, tile_group, N, k, groups, dtype) -> token rows or None
 
 
 def _group_sizes(tile_group, tiles_used, rows, groups):
@@ -946,7 +947,24 @@ def _grouped_mm_dw_impl(x, dy, tile_group, tiles_used, groups):
 # one and keep ``(k, N, C)`` of them, so there the tokens are still added to
 # (``pos`` is ``None``).  Both forms add a token's rows in buffer order, so
 # they agree to the bit and so does a token alone with a token in a batch.
+#
+# Where pallasex takes the call (``moe_combine``, through
+# ``_tokens_of_rows_fast_path``: rows of whole lane tiles, one device) neither
+# form runs: the kernel walks the routed rows a tile of tokens at a time (by
+# ``row_src`` and the row tiles' groups: it needs no ``pos``) and reads the
+# buffer once, a sublane tile of rows at a time, adding a token's rows in the
+# same order to the same bits, at 40-50 ns a row of 2,048 to 3,584 numbers and
+# 110 at 7,168 whatever the shapes (a v5e; ``tools/moe_tune.py --glue``).  It is
+# asked where that beats XLA's form (``_kernel_takes``): wherever the tokens
+# would be added to, bar a call of few tokens; and where they would gather, from
+# 16 k assignments on, where XLA keeps the ``(k, N, C)`` rows it gathered and
+# adds them in further passes (71 ns a row at Xing4.0's prompts of 5,120 and
+# 8,192 tokens; LFM2's of 2,048 and 3,072, which it still gathers and adds in
+# one fusion or two, cost 9 and 24, and a decode step's rows 3-12 us a call
+# against the kernel's 13-55 with its first chunk a group).
 _ROWS_GATHERED_A_ROW_SCATTERED = 4
+_TOKENS_A_KERNEL_CALL = 512
+_ASSIGNMENTS_GATHERED_A_KERNEL_CALL = 16384
 
 
 def moe_wave_tiles(assignments: int, held: int, total: int, tile: int) -> int:
@@ -1042,13 +1060,29 @@ def _rows_of_tokens(v, row_src, k, mask: bool):
     return jnp.where((row_src >= 0)[:, None], rows, 0) if mask else rows
 
 
-def _tokens_of_rows(vb, row_src, pos, N, k, dtype):
+def _kernel_takes(static, R: int) -> bool:
+    """Whether a wave of ``R`` rows back to ``static``'s tokens is the kernel's
+    to take (where there is one): from ``N``, ``k`` and which form XLA would
+    run, as ``moe_plan`` chooses it."""
+    N, k = static[:2]
+    if N * k > _ROWS_GATHERED_A_ROW_SCATTERED * R:
+        return N >= _TOKENS_A_KERNEL_CALL
+    return N * k >= _ASSIGNMENTS_GATHERED_A_KERNEL_CALL
+
+
+def _tokens_of_rows(vb, row_src, pos, tile_group, static, dtype):
     """Token rows ``(N, C)`` from buffer rows ``vb (R, C)``: the sum in
     ``dtype`` of the rows a token's assignments landed in, taken in buffer
     order whichever form the shapes chose.  By ``pos``: one gather of ``(k,
     N)`` rows, slot-major so that a slot's rows lie together, added a slot at a
     time, a slot without a row here adding zero.  Without: the rows added to
-    their tokens, padding rows as zeros."""
+    their tokens, padding rows as zeros.  Or neither, where a kernel takes the
+    call (``tile_group``: the group of each of the wave's row tiles)."""
+    N, k, _, groups = static
+    if _tokens_of_rows_fast_path is not None and _kernel_takes(static, vb.shape[0]):
+        res = _tokens_of_rows_fast_path(vb, row_src, tile_group, N, k, groups, dtype)
+        if res is not None:
+            return res
     y = jnp.zeros((N, vb.shape[1]), dtype)
     if pos is None:
         return y.at[jnp.maximum(row_src, 0) // k].add(jnp.where((row_src >= 0)[:, None], vb, 0).astype(dtype))
@@ -1059,9 +1093,9 @@ def _tokens_of_rows(vb, row_src, pos, N, k, dtype):
     return y
 
 
-# ``static`` of the two below: (tokens N, slots k, the rows' dtype)
+# ``static`` of the two below: (tokens N, slots k, the rows' dtype, the groups held)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _dispatch(static, x, top_w, row_src, pos, row_w):
+def _dispatch(static, x, top_w, row_src, pos, row_w, tile_group):
     """A wave's rows ``xb (R, C)`` and their weights ``wb (R,)``: the rows
     gathered from the tokens by ``row_src`` (a padding row holds some token's,
     since nothing reads its product back), the weights as the plan's sort
@@ -1071,40 +1105,38 @@ def _dispatch(static, x, top_w, row_src, pos, row_w):
 
 
 def _dispatch_bwd(static, res, g):
-    N, k, _ = static
-    row_src, pos = res
+    N, k = static[:2]
+    row_src, pos, tile_group = res
     dxb, dwb = g
-    dx = _tokens_of_rows(dxb, row_src, pos, N, k, dxb.dtype)
+    dx = _tokens_of_rows(dxb, row_src, pos, tile_group, static, dxb.dtype)
     if pos is None:
         dw = jnp.zeros((N * k,), dwb.dtype).at[jnp.maximum(row_src, 0)].add(jnp.where(row_src >= 0, dwb, 0))
     else:
         dw = jnp.where(pos >= 0, _take_rows(dwb, jnp.maximum(pos, 0)), 0)
-    return dx, dw.reshape(N, k), None, None, None
+    return dx, dw.reshape(N, k), None, None, None, None
 
 
-def _dispatch_fwd(static, x, top_w, row_src, pos, row_w):
-    return _dispatch(static, x, top_w, row_src, pos, row_w), (row_src, pos)
+def _dispatch_fwd(static, x, top_w, row_src, pos, row_w, tile_group):
+    return _dispatch(static, x, top_w, row_src, pos, row_w, tile_group), (row_src, pos, tile_group)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _combine(static, yb, row_src, pos):
+def _combine(static, yb, row_src, pos, tile_group):
     """What a wave adds to the tokens, float32 ``(N, C)``: each token's rows
     of ``yb (R, C)`` summed.  Its gradient is gathered by ``row_src``, zero on
     the padding rows: through it every gradient of the wave is zero there."""
-    N, k, _ = static
-    return _tokens_of_rows(yb, row_src, pos, N, k, jnp.float32)
+    return _tokens_of_rows(yb, row_src, pos, tile_group, static, jnp.float32)
 
 
-def _combine_fwd(static, yb, row_src, pos):
-    return _combine(static, yb, row_src, pos), row_src
+def _combine_fwd(static, yb, row_src, pos, tile_group):
+    return _combine(static, yb, row_src, pos, tile_group), row_src
 
 
 def _combine_bwd(static, row_src, g):
-    _, k, dtype = static
-    return _rows_of_tokens(g, row_src, k, True).astype(dtype), None, None
+    return _rows_of_tokens(g, row_src, static[1], True).astype(static[2]), None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -1116,15 +1148,15 @@ def _moe_wave(x, top_w, fc_1, fc_2, proj, row_src, pos, row_w, tile_group, tiles
     weighted, and gathered back by their tokens (by ``pos``) and summed in
     float32.  ``row_src``, ``pos``, ``row_w`` and ``tile_group`` are the wave's.
     ``fc_2`` None (the server's ungated experts): ``relu(. fc_1)^2`` in the SwiGLU's place."""
-    static = (*top_w.shape, jnp.dtype(x.dtype))
-    xb, wb = _dispatch(static, x, top_w, row_src, pos, row_w)
+    static = (*top_w.shape, jnp.dtype(x.dtype), fc_1.shape[0])
+    xb, wb = _dispatch(static, x, top_w, row_src, pos, row_w, tile_group)
     used = tiles_used.reshape(1)
     if fc_2 is None:        # ungated experts: W2 relu(W1 x)^2
         h = jnp.square(jax.nn.relu(_gmm(xb, fc_1, tile_group, used)))
     else:
         h = jax.nn.silu(_gmm(xb, fc_1, tile_group, used)) * _gmm(xb, fc_2, tile_group, used)
     yb = _gmm(h * wb[:, None].astype(h.dtype), proj, tile_group, used)
-    return _combine(static, yb, row_src, pos)
+    return _combine(static, yb, row_src, pos, tile_group)
 
 
 def _run_wave(static, plan, w, *operands):

@@ -3225,6 +3225,224 @@ def grouped_mm_dw(x, dy, tile_group, tiles_used, groups):
 
 
 # ---------------------------------------------------------------------------
+# The tokens take their experts' rows back: ``moe_combine``.  Token rows ``(N,
+# C)`` from the sorted buffer's rows ``vb (R, C)``, each the sum of the rows its
+# assignments landed in, added lowest row first in float32, in one pass over
+# the bytes: where XLA runs a scatter-add that walks its updates one after
+# another (``pos`` None in ``jaxex``) or keeps a ``(k, N, C)`` gather.
+#
+# A slice of a tiled array in HBM starts and ends on a tile of rows (Mosaic
+# refuses a copy of one row of ``(R, C)``), so the buffer is read in *chunks*
+# of a sublane tile of rows (8 float32, 16 bfloat16), every chunk that holds a
+# routed row once.  The plan pads a group to whole row tiles and sorts a
+# group's rows by token, so a walk over the tokens a tile at a time, a tile's
+# rows in buffer order, meets each group's rows one after another: a group is
+# a stream with two chunk buffers of static roles (a chunk's parity).  Every
+# group's first chunk is started at the first grid step (``tile_group`` says
+# where a group's run of row tiles begins); where the walk reaches a chunk's
+# first row it waits for that chunk and starts the group's next one behind it;
+# the one left in flight a group is awaited at the last grid step.  A 16-bit
+# chunk is widened to float32 where it arrives (whole vregs), so a row is read
+# and added as a float32 sublane.  (The sums laid out a lane tile a sublane, so
+# that a row is whole vregs, and the chunks laid out again to match by strided
+# stores, ran no faster at any shape and a tenth slower at Xing4.0's: what a
+# row costs is the walk's scalar steps, 40 ns, and at A.X-K1's width the
+# result's own write, 235 MB a wave at 610 GB/s; ``PERF.md``, PR 58.)
+#
+# Grid: a tile of tokens a step, in order (the streams' state crosses steps).
+# The walk is one loop over the tile's routed rows: ``ent`` (prefetched whole)
+# lists the wave's routed rows by token tile, buffer order inside a tile, one
+# int32 a row: its buffer row, its group and its token in the tile; ``off`` is
+# where each tile's rows start.  It costs by the rows routed: a token without
+# a row here is never looked at, and keeps the zeros its tile started from.
+# ---------------------------------------------------------------------------
+
+_COMBINE_TOKENS = 128      # tokens a grid step, the most
+_COMBINE_ROWS = 1 << 17    # rows a wave, the most: ``ent`` is prefetched whole (512 KB of SMEM compile for a v5e)
+
+# what the last combine was laid out as, or why it kept to XLA (trace time; a dict of its own, as ``hc_schedule``)
+combine_schedule: dict = {}
+
+
+def _combine_vmem(TN: int, C: int, groups: int, itemsize: int, out_itemsize: int) -> int:
+    """Bytes a grid step holds: two chunks a group at the rows' dtype, one a
+    group widened where that is 16 bits, the float32 sums of a tile where the
+    result is not float32, the result's block twice (the pipeline's two
+    buffers), and what Mosaic keeps beside them."""
+    G = 32 // itemsize      # a sublane tile of rows
+    chunks = 2 * groups * G * C * itemsize + (groups * G * C * 4 if itemsize < 4 else 0)
+    return chunks + (TN * C * 4 if out_itemsize < 4 else 0) + 2 * TN * C * out_itemsize + _GMM_VMEM_MARGIN
+
+
+def _combine_tile(N: int, C: int, groups: int, itemsize: int, out_itemsize: int) -> int | None:
+    """Tokens a grid step of ``moe_combine`` holds: ``_COMBINE_TOKENS``, halved
+    until the step fits the VMEM the kernel may ask for (``_gmm_vmem_cap``), all
+    ``N`` where they are fewer; None where the chunks alone do not fit."""
+    TN, cap = _COMBINE_TOKENS, _gmm_vmem_cap()
+    while _combine_vmem(TN, C, groups, itemsize, out_itemsize) > cap:
+        if TN == 8:
+            return None
+        TN //= 2
+    return min(N, TN)
+
+
+def _combine_bits(groups: int) -> tuple[int, int]:
+    """Bits of an entry of ``ent`` that hold the token in its tile, and the group."""
+    return (_COMBINE_TOKENS - 1).bit_length(), max(1, (groups - 1).bit_length())
+
+
+def _combine_kernel(off_ref, ent_ref, tg_ref, vb, o_ref, ring, wide, acc, sem, flying, *, G, groups, tile):
+    f32 = jnp.float32
+    i = pl.program_id(0)
+    nq = vb.shape[0] // G
+    tb, gb = _combine_bits(groups)
+    sums = o_ref if acc is None else acc
+
+    def copy(g, q):     # chunk q of the buffer (past its end: the last one again) into group g's buffer of q's parity
+        rows = pl.ds(pl.multiple_of(jnp.minimum(q, nq - 1) * G, G), G)
+        return pltpu.make_async_copy(vb.at[rows], ring.at[2 * g + q % 2], sem.at[g, q % 2])
+
+    @pl.when(i == 0)
+    def _first():
+        def idle(g, _):
+            flying[g] = -1      # the chunk of group g that is in flight
+
+        def head(p, _):         # a group's first chunk: that of the first row tile of its run
+            g = tg_ref[p]
+
+            @pl.when(jnp.logical_or(p == 0, tg_ref[jnp.maximum(p - 1, 0)] != g))
+            def _():
+                copy(g, p * (tile // G)).start()
+                flying[g] = p * (tile // G)
+
+        jax.lax.fori_loop(0, groups, idle, None)
+        jax.lax.fori_loop(0, tg_ref.shape[0], head, None)
+
+    sums[...] = jnp.zeros_like(sums)
+
+    def row(e, _):
+        v = ent_ref[e]
+        t, g, r = v & ((1 << tb) - 1), (v >> tb) & ((1 << gb) - 1), v >> (tb + gb)
+        q = r // G
+
+        @pl.when(r % G == 0)        # a group's rows are met one after another: a chunk's first row is met first
+        def _next_chunk():
+            copy(g, q).wait()
+            copy(g, q + 1).start()
+            flying[g] = q + 1
+            if wide is not None:
+                wide[g] = ring[2 * g + q % 2].astype(f32)
+
+        here = pl.ds(t, 1)
+        src = ring[2 * g + q % 2, pl.ds(r % G, 1), :] if wide is None else wide[g, pl.ds(r % G, 1), :]
+        sums[here, :] = sums[here, :] + src
+
+    jax.lax.fori_loop(off_ref[i], off_ref[i + 1], row, None)
+    if acc is not None:
+        o_ref[...] = acc[...].astype(o_ref.dtype)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _last():
+        def drain(g, _):
+            q = flying[g]
+
+            @pl.when(q >= 0)
+            def _():
+                copy(g, q).wait()
+
+        jax.lax.fori_loop(0, groups, drain, None)
+
+
+@functools.partial(jax.jit, static_argnames=("N", "k", "groups", "dtype", "TN", "interpret"))
+def _moe_combine(vb, row_src, tile_group, *, N, k, groups, dtype, TN, interpret):
+    R, C = vb.shape
+    tile = R // tile_group.shape[0]
+    G = _sublane_rows(vb.dtype)
+    i32 = jnp.int32
+    steps = -(-N // TN)
+    tb, gb = _combine_bits(groups)
+    # the routed rows by token tile, buffer order inside a tile (the sort is stable): row, group and token in one int32
+    token = jnp.maximum(row_src, 0) // k
+    step = jnp.where(row_src >= 0, token // TN, steps).astype(i32)
+    ent = (jnp.arange(R, dtype=i32) << (tb + gb)) | (jnp.repeat(tile_group.astype(i32), tile) << tb) | (token % TN).astype(i32)
+    _, ent = jax.lax.sort((step, ent), num_keys=1, is_stable=True)
+    per_step = jnp.sum(step[None, :] == jnp.arange(steps, dtype=i32)[:, None], axis=1, dtype=i32)
+    off = jnp.concatenate([jnp.zeros((1,), i32), jnp.cumsum(per_step)])
+    widen = vb.dtype.itemsize < 4
+    scratch = [pltpu.VMEM((2 * groups, G, C), vb.dtype)]
+    scratch += [pltpu.VMEM((groups, G, C), jnp.float32)] if widen else []
+    scratch += [pltpu.VMEM((TN, C), jnp.float32)] if dtype != jnp.float32 else []
+    scratch += [pltpu.SemaphoreType.DMA((groups, 2)), pltpu.SMEM((groups,), i32)]
+
+    def kernel(off_ref, ent_ref, tg_ref, vb_ref, o_ref, ring, *rest):
+        rest = list(rest)
+        wide = rest.pop(0) if widen else None
+        acc = rest.pop(0) if dtype != jnp.float32 else None
+        _combine_kernel(off_ref, ent_ref, tg_ref, vb_ref, o_ref, ring, wide, acc, *rest, G=G, groups=groups, tile=tile)
+
+    params = {}
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(_combine_vmem(TN, C, groups, vb.dtype.itemsize, jnp.dtype(dtype).itemsize), _GMM_VMEM_DEFAULT))
+    return pl.pallas_call(
+        kernel,
+        name="moe_combine",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(steps,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((TN, C), lambda i, *_: (i, 0)),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((N, C), dtype),
+        interpret=interpret,
+        **params,
+    )(off, ent, tile_group.astype(i32), vb)
+
+
+def combine_declines(N: int, R: int, tiles: int, C: int, groups: int, dtype, out_dtype) -> str:
+    """Why ``moe_combine`` leaves a call of these shapes to XLA, or ``""``: the
+    shapes and the device alone decide (``_gmm_supported`` / ``_gmm_dispatchable``'s tests)."""
+    if not _enabled():
+        return "no Pallas"
+    if jnp.dtype(dtype).name not in ("bfloat16", "float32") or jnp.dtype(out_dtype) not in (jnp.dtype(dtype), jnp.float32):
+        return "dtype"      # the sum is the share's float32 or, of the rows' gradient, at the rows' own
+    mesh = _mesh_var.get()
+    if mesh is not None and mesh.devices.size > 1:
+        return "several devices"
+    if C % 128 or tiles <= 0 or R % tiles or (R // tiles) % _sublane_rows(dtype):
+        return "shape"
+    if R > _COMBINE_ROWS or (R - 1) >> (31 - sum(_combine_bits(groups))):
+        return "shape"      # a row, its group and its token do not fit an int32 of ``ent``, or ``ent`` SMEM
+    if _combine_tile(N, C, groups, jnp.dtype(dtype).itemsize, jnp.dtype(out_dtype).itemsize) is None:
+        return "VMEM"
+    return ""
+
+
+def combine(vb, row_src, tile_group, N, k, groups, dtype):
+    """``moe_combine``: the token rows ``(N, C)`` at ``dtype`` from the buffer
+    rows ``vb (R, C)`` by ``row_src (R,)`` (the assignment ``n k + s`` a row
+    holds, -1 for none) and ``tile_group`` (the group of each row tile,
+    ``groups`` of them), or None where XLA's forms are to run;
+    ``stats["moe_combine"]`` counts the calls taken (trace time) and
+    ``combine_schedule`` keeps the last one's layout, or the reason it declined."""
+    R, C = vb.shape
+    why = combine_declines(N, R, tile_group.shape[0], C, groups, vb.dtype, dtype)
+    if not why and any(_concrete_multi_device(a) for a in (vb, row_src)):
+        why = "several devices"
+    combine_schedule.clear()
+    if why:
+        combine_schedule.update(fallback=why, tokens=N, slots=k, rows=R, width=C)
+        return None
+    itemsize, out_itemsize = vb.dtype.itemsize, jnp.dtype(dtype).itemsize
+    TN = _combine_tile(N, C, groups, itemsize, out_itemsize)
+    stats["moe_combine"] = stats.get("moe_combine", 0) + 1
+    combine_schedule.update(block_tokens=TN, grid_steps=-(-N // TN), chunk_rows=_sublane_rows(vb.dtype), streams=groups,
+                            rows_listed=R, vmem_limit_bytes=_combine_vmem(TN, C, groups, itemsize, out_itemsize))
+    return _moe_combine(vb, row_src, tile_group, N=int(N), k=int(k), groups=int(groups), dtype=jnp.dtype(dtype), TN=TN,
+                        interpret=_interpret())
+
+
+# ---------------------------------------------------------------------------
 # The DeltaNet layers' causal depthwise conv with its activation, one pass over
 # HBM each way: ``causal_conv1d_fwd`` and ``causal_conv1d_bwd``.
 #
@@ -4220,5 +4438,6 @@ _jaxex._gdn_bwd_fast_path = gdn_chunk_backward
 _jaxex._gdn_state_fast_path = gdn_chunk_state
 _jaxex._grouped_mm_fast_path = grouped_mm
 _jaxex._grouped_mm_dw_fast_path = grouped_mm_dw
+_jaxex._tokens_of_rows_fast_path = combine
 _jaxex._causal_conv_fast_path = causal_conv1d
 _jaxex._causal_conv_bwd_fast_path = causal_conv1d_backward

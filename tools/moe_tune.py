@@ -31,10 +31,15 @@ skewed routing, the form before PR 43 (kept below) beside ``jaxex``'s: the
 rows' weights, and with ``pos`` where the shapes gather by it), the ``dispatch``
 (rows gathered by ``row_src``; before, the weights too, and both masked), the
 ``swiglu`` pass over the wave's rows (one form), the ``combine`` (a scatter-add
-of the wave's rows before; the shape's first line says which form ``jaxex``
-chose), and the whole ``_moe_share`` with the part of it that is not
-``moe_grouped_mm*``.  ``--check`` there holds the new
-combine to the old one's bits.  Needs a TPU; exits non-zero without one, or if
+of the wave's rows before; then the XLA form the shapes choose, named on the
+shape's first line, and ``moe_combine`` side by side, the kernel with the GB/s
+of its one pass: the rows routed read and the token rows written once; where
+``jaxex`` leaves the call to XLA, a decode step's, the kernel is timed all the
+same), and the whole ``_moe_share`` with the part of it that is not
+``moe_grouped_mm*``.  ``--glue`` also knows a prompt and a decode step of
+``xing4-serve-1chip.offline-digest`` and Trinity-Mini's longest prompt.
+``--check`` there holds both to the scatter-add's bits, the kernel's 16-bit sum
+(the rows' gradient) with them.  Needs a TPU; exits non-zero without one, or if
 a check fails."""
 import argparse
 import os
@@ -59,6 +64,15 @@ SHAPES = {
     "axk1_prefill": dict(tokens=8192, k=8, held=12, total=192, C=7168, I=2048),
     "hybrid_forward": dict(tokens=16384, k=10, held=32, total=512, C=2048, I=512, tile=128),
     "hybrid_transposed": dict(tokens=16384, k=10, held=32, total=512, C=2048, I=512, tile=128, transposed=True),
+}
+# what --glue times besides
+GLUE_SHAPES = {
+    "xing4_prefill": dict(tokens=8192, k=4, held=64, total=64, C=3584, I=1024),
+    "xing4_decode": dict(tokens=32, k=4, held=64, total=64, C=3584, I=1024),
+    "trinity_prefill": dict(tokens=9984, k=8, held=16, total=128, C=2048, I=1024),
+    # where XLA's gather by pos and the kernel cross (jaxex._tokens_of_rows' rule): LFM2's longest bucket, Xing4's shortest
+    "lfm2_prefill_3k": dict(tokens=3072, k=4, held=32, total=32, C=2048, I=1792),
+    "xing4_prefill_5k": dict(tokens=5120, k=4, held=64, total=64, C=3584, I=1024),
 }
 
 
@@ -189,8 +203,19 @@ def _ms(fn, *args):
     return sum(ms.values()), ms
 
 
+def _xla(fn):
+    """``fn`` with ``moe_combine`` out of the way: the XLA form the shapes choose."""
+    def run(*a):
+        fast, jaxex._tokens_of_rows_fast_path = jaxex._tokens_of_rows_fast_path, None
+        try:
+            return fn(*a)
+        finally:
+            jaxex._tokens_of_rows_fast_path = fast
+    return run
+
+
 def glue_shape(name, check_bits: bool) -> bool:
-    shape = SHAPES[name]
+    shape = {**SHAPES, **GLUE_SHAPES}[name]
     N, k, held, total, C, I = (shape[n] for n in ("tokens", "k", "held", "total", "C", "I"))
     tile = shape.get("tile") or moe_row_tile(N * k / total)
     wave_tiles = jaxex.moe_wave_tiles(N * k, held, total, tile)
@@ -202,38 +227,51 @@ def glue_shape(name, check_bits: bool) -> bool:
     top_w = jax.random.uniform(keys[4], (N, k), jnp.float32)
     w1, w3 = ((jax.random.normal(kk, (held, C, I)) * 0.05).astype(jnp.bfloat16) for kk in keys[5:7])
     w2 = (jax.random.normal(keys[7], (held, I, C)) * 0.05).astype(jnp.bfloat16)
+    static = (N, k, jnp.dtype(jnp.bfloat16), held)
+    walked = jaxex._kernel_takes(static, R) and not px.combine_declines(N, R, wave_tiles, C, held, x.dtype, jnp.float32)
 
     def plan_now(idx_, top_w_):
         return jaxex.moe_wave_rows(jaxex.moe_plan(idx_, top_w_, 0, held, tile, wave_tiles), 0, tile, wave_tiles)
 
-    row_src, pos, row_w, _, used = jax.jit(plan_now)(idx, top_w)
-    static = (N, k, jnp.dtype(jnp.bfloat16))
+    row_src, pos, row_w, tg, used = jax.jit(plan_now)(idx, top_w)
+    routed = int((row_src >= 0).sum())
     form = "scatter-add" if pos is None else "gather by pos"
     print(f"--- {name}: {N} tokens x {k} = {N * k} assignments on {held} of {total}, tiles of {tile}, a wave of {R} rows "
-          f"({int(used)} of {wave_tiles} tiles used, {int((row_src >= 0).sum())} rows routed); the tokens' rows come back by {form}",
-          flush=True)
+          f"({int(used)} of {wave_tiles} tiles used, {routed} rows routed); in XLA the tokens' rows come back by {form}; "
+          f"jaxex gives the call to {'moe_combine' if walked else 'XLA'}", flush=True)
     parts = [
         ("plan", lambda: _ms(lambda i: _plan_before(i, held, tile, wave_tiles), idx), lambda: _ms(plan_now, idx, top_w)),
         ("dispatch", lambda: _ms(_dispatch_before, x, top_w, row_src),
-         lambda: _ms(lambda *a: jaxex._dispatch(static, *a), x, top_w, row_src, pos, row_w)),
+         lambda: _ms(lambda *a: jaxex._dispatch(static, *a), x, top_w, row_src, pos, row_w, tg)),
         ("swiglu", lambda: _ms(lambda a, b, w: jax.nn.silu(a) * b * w[:, None].astype(a.dtype), h1, h2, jnp.ones((R,), jnp.float32)), None),
         ("combine", lambda: _ms(lambda y, r: _combine_before(y, r, N, k), jnp.where((row_src >= 0)[:, None], yb, 0), row_src),
-         lambda: _ms(lambda *a: jaxex._combine(static, *a), yb, row_src, pos)),
+         lambda: _ms(_xla(lambda *a: jaxex._combine(static, *a)), yb, row_src, pos, tg)),
     ]
     for part, before, now in parts:
         b, _ = before()
         n, ops = now() if now else (b, {})
         top = ", ".join(f"{o} {t * 1e3:.0f}" for o, t in sorted(ops.items(), key=lambda kv: -kv[1])[:3])
         print(f"{name:18s} {part:9s} before {b * 1e3:8.1f} us   now {n * 1e3:8.1f} us   [{top}]", flush=True)
+    for out in (jnp.float32, jnp.bfloat16):     # the share's sum, and the sum of the rows' gradient
+        kern, ops = _ms(lambda y, r, t, out=out: px.combine(y, r, t, N, k, held, out), yb, row_src, tg)
+        own = sum(t for o, t in ops.items() if o.startswith("moe_combine"))
+        least = routed * C * yb.dtype.itemsize + N * C * jnp.dtype(out).itemsize
+        print(f"{name:18s} {'combine':9s} moe_combine to {jnp.dtype(out).name:8s} {kern * 1e3:8.1f} us ({own * 1e3:.1f} its own, the rows' list beside it)   "
+              f"{least / own / 1e6:6.1f} GB/s of one pass over {least / 1e6:.1f} MB   {dict(px.combine_schedule)}", flush=True)
     whole, ops = _ms(lambda *a: jaxex._moe_share(*a, 0, total, tile), x, idx, top_w, w1, w3, w2)
     kernel = sum(t for o, t in ops.items() if o.startswith("moe_grouped_mm"))
     print(f"{name:18s} {'share':9s} whole {whole * 1e3:8.1f} us, moe_grouped_mm {kernel * 1e3:8.1f}, beside it {(whole - kernel) * 1e3:8.1f}", flush=True)
     if not check_bits:
         return True
-    want = jax.jit(lambda y, r: _combine_before(y, r, N, k))(jnp.where((row_src >= 0)[:, None], yb, 0), row_src)
-    got = jax.jit(lambda *a: jaxex._combine(static, *a))(yb, row_src, pos)
-    same = bool(jnp.all(want == got))
-    print(f"check {name:18s} the combine's bits are the scatter-add's: {same}", flush=True)
+    same = True
+    for out in (jnp.float32, jnp.bfloat16):
+        want = jax.jit(lambda y, r, out=out: jnp.zeros((N, C), out).at[jnp.maximum(r, 0) // k].add(y.astype(out)))(
+            jnp.where((row_src >= 0)[:, None], yb, 0), row_src)
+        xla = jax.jit(_xla(lambda y, r, p, t, out=out: jaxex._tokens_of_rows(y, r, p, t, static, out)))(yb, row_src, pos, tg)
+        got = jax.jit(lambda y, r, t, out=out: px.combine(y, r, t, N, k, held, out))(yb, row_src, tg)
+        bits = (bool(jnp.all(want == xla)), bool(jnp.all(want == got)))
+        print(f"check {name:18s} a {jnp.dtype(out).name} sum has the scatter-add's bits: XLA's form {bits[0]}, moe_combine {bits[1]}", flush=True)
+        same &= all(bits) if out == jnp.float32 else bits[1] or not bits[0]
     return same
 
 
@@ -249,9 +287,11 @@ def main():
         sys.exit(f"moe_tune: times the kernel on a device and needs a TPU; jax found "
                  f"{device['platform']!r} ({device['kind']}).  Nothing was measured.")
     print(device, flush=True)
+    if args.glue and args.shapes == ",".join(SHAPES):
+        args.shapes = ",".join([*SHAPES, *GLUE_SHAPES])
     names = [n for n in args.shapes.split(",") if n]
     if args.glue:
-        same = [glue_shape(n, args.check) for n in names if not SHAPES[n].get("transposed")]
+        same = [glue_shape(n, args.check) for n in names if not SHAPES.get(n, {}).get("transposed")]
         sys.exit(0 if all(same) else "moe_tune: the combine disagrees with the scatter-add it replaced")
     if args.check and check(names) > 0.01:       # bfloat16 results of a float32 sum: a rounding of the last place
         sys.exit("moe_tune: the compiled kernel disagrees with lax.ragged_dot")

@@ -8,6 +8,7 @@ rounding; the tolerance is that of float32 sums in another order.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 import sys
@@ -1073,28 +1074,30 @@ def _tiny_cfg_and_params():
 
 
 def test_generate_refuses_the_config_with_one_clear_error():
-    """Since PR 32 the server runs linear_attention layers; this model's expert
-    share is what it still lacks, and the refusal names it."""
+    """Since PR 32 the server runs linear_attention layers and since PR 59 an expert
+    share routed by softmax; this model's zero-centred norms are what it still
+    lacks, and the refusal names them."""
     from thunder_tpu.models import generate
 
     cfg, params = _tiny_cfg_and_params()
-    with pytest.raises(NotImplementedError, match="cannot be served.*SparseMoE"):
+    with pytest.raises(NotImplementedError, match="cannot be served.*norm_zero_centered"):
         generate.generate(params, jnp.zeros((1, 4), jnp.int32), cfg, 2)
 
 
 def test_serve_refuses_the_config_with_one_clear_error():
     cfg, params = _tiny_cfg_and_params()
-    with pytest.raises(NotImplementedError, match="cannot be served.*SparseMoE"):
+    with pytest.raises(NotImplementedError, match="cannot be served.*norm_zero_centered"):
         tt.serve(None, params, cfg, num_blocks=8, max_batch=1)
 
 
-def test_an_expert_share_is_refused_and_linear_layers_alone_are_not():
+def test_zero_centred_norms_are_refused_and_a_softmax_expert_share_and_linear_layers_are_not():
     from thunder_tpu.models.generate import require_servable
 
     cfg = llama.Config(name="moe-only", n_layer=2, n_head=4, n_embd=64, mlp_class="SparseMoE", n_expert=8,
                        n_expert_per_token=2, intermediate_size=32)
-    with pytest.raises(NotImplementedError, match="SparseMoE"):
-        require_servable(cfg)
+    require_servable(cfg)                                  # the softmax router is served since PR 59
+    with pytest.raises(NotImplementedError, match="norm_zero_centered"):
+        require_servable(dataclasses.replace(cfg, norm_zero_centered=True))
     require_servable(llama.Config.from_name("tiny-mistral-debug"))
     linear = llama.Config(name="linear-dense", n_layer=2, n_head=4, n_embd=64, intermediate_size=96,
                           layer_types=("linear_attention", "full_attention"), linear_num_key_heads=2,

@@ -65,14 +65,14 @@ def test_the_layer_map_lives_in_the_config(model):
     assert G.cache_shape(dense, 1, 32)[0] == dense.n_layer
 
 
-def test_linear_attention_is_served_and_an_expert_share_is_not(model):
+def test_linear_attention_is_served_and_so_is_a_softmax_expert_share(model):
     cfg, _ = model
     assert cfg.training_only is None
     G.require_servable(cfg)
     moe = llama.Config(name="moe-only", n_layer=2, n_head=4, n_embd=64, mlp_class="SparseMoE", n_expert=8,
                        n_expert_per_token=2, intermediate_size=32)
-    with pytest.raises(NotImplementedError, match="SparseMoE"):
-        G.require_servable(moe)
+    assert moe.training_only is None                       # the softmax router is served since PR 59
+    G.require_servable(moe)
 
 
 @pytest.mark.parametrize("knob", ["attn_output_gate", "qk_norm", "norm_zero_centered"])

@@ -467,9 +467,11 @@ def test_training_refuses_the_config_with_its_reason(model):
                           topk_group=1)
     assert "sigmoid_group" in llama.serving_only(routed) and routed.training_only is None
     softmax = dataclasses.replace(routed, moe_router="softmax")
-    assert llama.serving_only(softmax) is None and "softmax router" in softmax.training_only
-    with pytest.raises(NotImplementedError, match="SparseMoE with the softmax router"):
-        G.require_servable(softmax)
+    assert llama.serving_only(softmax) is None and softmax.training_only is None     # served and trained (PR 59)
+    G.require_servable(softmax)
+    zero_centred = dataclasses.replace(softmax, norm_zero_centered=True)
+    with pytest.raises(NotImplementedError, match="norm_zero_centered"):
+        G.require_servable(zero_centred)
 
 
 def test_latent_attention_without_a_query_bottleneck_is_refused(model):

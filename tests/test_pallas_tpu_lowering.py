@@ -369,6 +369,26 @@ def test_flash_fwd_compiles_at_the_docqa_buckets_with_a_ragged_last_block(T, win
         assert re.search(r"%_flash_fwd(\.\d+)? = ", ragged.compile().as_text())
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["global", "window4096"])
+@pytest.mark.parametrize("T", [2560, 5120, 7680, 10240])
+def test_flash_fwd_compiles_at_seven_query_heads_a_kv_head(T, window, tpu_sharding, monkeypatch):
+    """``smallthinker-serve-1chip.offline-mixedlen``'s four prefill buckets, 28 heads
+    over 4 of 128: ``rep`` = 7 reaches the kernel through its index map alone (``kh =
+    h // rep``), a global layer's causal call and a window layer's banded one at a
+    window of 4,096.  Blocks of 1024, ragged by 512 at the odd buckets; the band at
+    10,240 keeps 40 of the triangle's 55 blocks."""
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
+    monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
+    lowered = _lowered_flash_fwd(28, 4, T, HS, HS, tpu_sharding, window)
+    sched, n = px.flash_schedule, -(-T // 1024)
+    assert (sched["block_q"], sched["block_k"], sched["tail_rows"]) == (1024, 1024, -T % 1024)
+    band = sum(min(i + 1, 5) for i in range(n))               # a query block sees its own and the four before it
+    assert sched["grid_steps"] == (band if window and n > 5 else n * (n + 1) // 2)
+    assert lowered.as_text().count('kernel_name = "_flash_fwd"') == 1
+    if tpu_sharding is not None:
+        assert re.search(r"%_flash_fwd(\.\d+)? = ", lowered.compile().as_text())
+
+
 @pytest.mark.parametrize("heads", [32, 64], ids=["xing4", "axk1"])
 def test_flash_fwd_compiles_with_values_at_their_own_width(heads, tpu_sharding, monkeypatch):
     """A latent prompt's call at both latent cells' heads and 8,192 tokens, as
@@ -501,6 +521,9 @@ WALK_CELLS = {
     "nemotron3super-serve-1chip.offline-rollouts": (32, 2, 128, 128, 496, 43008, 1, None, False),
     "trinity-mini-serve-1chip.offline-docqa": (32, 4, 128, 20, 720, 10240, 8, None, False),
     "trinity-mini-serve-1chip.offline-docqa/ring": (32, 4, 128, 20, 720, 21 * 129, 24, 2048, False),
+    # 7 query heads a KV head: no power of two, no whole sublane tile of 8
+    "smallthinker-serve-1chip.offline-mixedlen": (28, 4, 128, 64, 688, 25600, 2, None, False),
+    "smallthinker-serve-1chip.offline-mixedlen/ring": (28, 4, 128, 64, 688, 65 * 257, 6, 4096, False),
 }
 
 
@@ -1110,6 +1133,69 @@ def test_the_window_global_cells_programs_lower_to_their_kernels(kind, tpu_shard
         assert {int(m) for m in re.findall(rf"tensor<1x(\d+)x{cfg.padded_vocab_size}xf32>", text)} == {1}
     else:
         # the lowered text holds a function once however many layers call it: a walk a kind, four calls claimed
+        assert text.count('kernel_name = "paged_attn_decode"') == 2 and claimed("paged_walk") == 4
+        assert 1 <= text.count('kernel_name = "paged_token_write"') <= 4
+    if tpu_sharding is not None:
+        hlo = (_compiled_prefill(prefill) if kind == "prefill_fresh" else lowered.compile()).as_text()
+        names = ("_flash_fwd",) if kind == "prefill_fresh" else ("paged_attn_decode", "paged_token_write")
+        for name in (*names, "moe_grouped_mm"):
+            assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
+
+
+def _prerouted_engine():
+    """The SmallThinker cell's engine at its published widths, the first period of
+    four layers (the global layer, then three window layers; 64 experts held in
+    each), over weights that are shapes alone."""
+    import thunder_tpu as tt
+    from chipbench import common
+    from thunder_tpu.models import llama
+
+    _, config, mix = common.open_cell("smallthinker-serve-1chip.offline-mixedlen")
+    arch = common.load_module("models", config["arch"])
+    hf = {**config, "num_hidden_layers": 4}
+    cfg = llama.Config(**arch.program_config(hf))
+    params = jax.eval_shape(functools.partial(arch.make_params, hf), common.seed_words(1))
+    kw = {**config["engine"], **mix["engine"], "num_blocks": 1500, "max_batch": 2, "batch_buckets": [2]}
+    return cfg, params, tt.serve(None, params, cfg, **kw)
+
+
+@pytest.mark.parametrize("kind", ["prefill_fresh", "decode_paged"])
+def test_the_prerouted_cells_programs_lower_to_their_kernels(kind, tpu_sharding, monkeypatch):
+    """A whole prompt of 2,560 tokens attends through ``_flash_fwd`` in every layer
+    (causal alone in the global layer, which comes first; banded by the window of
+    4,096 in the three after it) and sorts its rows through ``moe_grouped_mm`` (three
+    products an expert layer: the gated ReLU is SwiGLU's three matrices); a decode
+    step walks the request's own blocks in the global layer and the slot's ring (257
+    entries of the table's 688) in the window layers, all at 7 query heads a KV
+    head.  The router's product reads the block's input and lowers under
+    ``mlp/router``; no arena is gathered."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    monkeypatch.setattr(px, "_pallas_available", lambda: True)
+    cfg, params, eng = _prerouted_engine()
+    st = eng.stats()
+    assert st["attn"]["path"] == "walk" and st["attn"]["lane_pack"] == 1
+    assert eng.pool.k_arena.shape == (1500, 1, 4, 16, 128) and eng.pool.state.shapes["k_ring"] == (3 * 257, 3, 4, 16, 128)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=tpu_sharding)  # noqa: E731
+    one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
+    before = dict(px.stats)
+    prefill = _fresh_prefill(eng, weights, arenas, one)
+    if kind == "prefill_fresh":
+        prog, args = prefill(2560)
+    else:
+        prog = eng._build_decode_paged(2, 688)
+        args = (weights, one((2,)), one((2,)), one((2, 688)), arenas, one((2, 2), jnp.uint32), {}, one((2,)),
+                one((4,), F32), one((2,)))          # the expert share's running sums, then the state slots
+    lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text(debug_info=True)
+    claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
+    assert claimed("grouped_mm") >= 3 and 'kernel_name = "moe_grouped_mm"' in text
+    assert "blk0/mlp/router/" in text and "/mixer/router/" not in text
+    assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)      # nothing of an arena's five dims
+    if kind == "prefill_fresh":
+        # at 2,560 tokens the window of 4,096 masks nothing: the banded call is the causal one's kernel
+        assert claimed("direct") == 4 and 1 <= text.count('kernel_name = "_flash_fwd"') <= 2
+    else:
         assert text.count('kernel_name = "paged_attn_decode"') == 2 and claimed("paged_walk") == 4
         assert 1 <= text.count('kernel_name = "paged_token_write"') <= 4
     if tpu_sharding is not None:

@@ -1142,31 +1142,32 @@ def _combine_bwd(static, row_src, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _moe_wave(x, top_w, fc_1, fc_2, proj, row_src, pos, row_w, tile_group, tiles_used):
+def _moe_wave(gate, x, top_w, fc_1, fc_2, proj, row_src, pos, row_w, tile_group, tiles_used):
     """One wave of the sorted buffer: its rows gathered from their tokens
     (by ``row_src``), through the experts' SwiGLU as grouped products,
     weighted, and gathered back by their tokens (by ``pos``) and summed in
     float32.  ``row_src``, ``pos``, ``row_w`` and ``tile_group`` are the wave's.
-    ``fc_2`` None (the server's ungated experts): ``relu(. fc_1)^2`` in the SwiGLU's place."""
+    ``fc_2`` None (the server's ungated experts): ``relu(. fc_1)^2`` in the SwiGLU's place.
+    ``gate``: the gated experts' activation, ``jax.nn.silu`` or the server's ``jax.nn.relu``."""
     static = (*top_w.shape, jnp.dtype(x.dtype), fc_1.shape[0])
     xb, wb = _dispatch(static, x, top_w, row_src, pos, row_w, tile_group)
     used = tiles_used.reshape(1)
     if fc_2 is None:        # ungated experts: W2 relu(W1 x)^2
         h = jnp.square(jax.nn.relu(_gmm(xb, fc_1, tile_group, used)))
     else:
-        h = jax.nn.silu(_gmm(xb, fc_1, tile_group, used)) * _gmm(xb, fc_2, tile_group, used)
+        h = gate(_gmm(xb, fc_1, tile_group, used)) * _gmm(xb, fc_2, tile_group, used)
     yb = _gmm(h * wb[:, None].astype(h.dtype), proj, tile_group, used)
     return _combine(static, yb, row_src, pos, tile_group)
 
 
 def _run_wave(static, plan, w, *operands):
-    tile, wave_tiles, _ = static
-    return _moe_wave(*operands, *moe_wave_rows(plan, w, tile, wave_tiles))
+    tile, wave_tiles, _, gate = static
+    return _moe_wave(gate, *operands, *moe_wave_rows(plan, w, tile, wave_tiles))
 
 
 def _waves_after_the_first(static, plan, *operands):
     """Waves 1.. of the buffer, each only where the routing reached it."""
-    _, wave_tiles, n_waves = static
+    _, wave_tiles, n_waves, _ = static
     y = jnp.zeros(operands[0].shape, jnp.float32)
     for w in range(1, n_waves):
         y = jax.lax.cond(plan["tiles_used"] > w * wave_tiles,
@@ -1200,14 +1201,15 @@ _overflow.defvjp(lambda static, plan, *operands: (_overflow(static, plan, *opera
                  _overflow_bwd)
 
 
-def _moe_share_planned(x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile):
+def _moe_share_planned(x, top_idx, top_w, fc_1, fc_2, proj, first, total, tile, gate=jax.nn.silu):
     """The held experts' part of the layer and the plan it was computed by
     (``moe_plan``: the server reads its ``cnt``).  ``fc_2`` None: ungated experts
-    of two matrices (the server's alone: undifferentiated)."""
+    of two matrices (the server's alone: undifferentiated).  ``gate``: the gated
+    experts' activation, ``jax.nn.silu`` (SwiGLU) or ``jax.nn.relu`` (the server's gated ReLU)."""
     held = fc_1.shape[0]
     wave_tiles = moe_wave_tiles(top_idx.size, held, total, tile)
     plan = moe_plan(top_idx, top_w, first, held, tile, wave_tiles)
-    static = (tile, wave_tiles, plan["tile_group"].shape[0] // wave_tiles)
+    static = (tile, wave_tiles, plan["tile_group"].shape[0] // wave_tiles, gate)
     operands = (x, top_w, fc_1, fc_2, proj)
     y = _run_wave(static, plan, 0, *operands)
     if static[2] > 1:

@@ -107,7 +107,7 @@ def _rope(x, cos, sin):
     return (x * cos + rotated * sin).astype(x.dtype)
 
 
-def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0, moe_rows=None):
+def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0, moe_rows=None, route_x=None):
     lin = partial(_linear, quantized=quantized)
     if cfg.mlp_class == "LLaMAMoE":
         # stacked per-expert weights: per-request LoRA deltas are not
@@ -129,7 +129,7 @@ def _mlp(mp, x, cfg: Config, *, quantized=False, lora=None, lora_scaling=1.0, mo
     kind = cfg.mlp_class
     if kind == "SparseMoE":
         if "gate" in mp:
-            return moe_share_mlp(mp, x, cfg, lin=lin, moe_rows=moe_rows)
+            return moe_share_mlp(mp, x, cfg, lin=lin, moe_rows=moe_rows, route_x=route_x)
         kind = "LLaMAMLP"       # one of the model's leading dense layers
 
     def ll(name, inp, bias=None):
@@ -188,6 +188,17 @@ def route_sigmoid_bias(scores, bias, cfg: Config):
     return top_w, top_idx
 
 
+def route_softmax(logits, cfg: Config):
+    """The softmax choice (the trainer's, ``llama.sparse_moe_mlp``; Qwen3-MoE,
+    SmallThinker) from float32 router ``logits (N, E)``: a softmax over *all*
+    ``n_expert``, the top ``n_expert_per_token`` of the probabilities, renormalised
+    to sum one (hf's ``norm_topk_prob``; no epsilon: the chosen probabilities are
+    the largest of a softmax, never all zero).  Returns ``(top_w, top_idx)``, both
+    ``(N, k)``."""
+    top_w, top_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_expert_per_token)
+    return top_w / jnp.sum(top_w, axis=-1, keepdims=True), top_idx
+
+
 MOE_DECODE_ROW_TILE = 16   # bfloat16's sublane tile: the fewest rows moe_grouped_mm compiles for
 
 
@@ -210,10 +221,10 @@ def moe_row_tile(rows_an_expert: float) -> int:
     return min(MOE_ROW_TILE, max(MOE_DECODE_ROW_TILE, 1 << math.ceil(math.log2(max(2 * rows_an_expert, 1)))))
 
 
-def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear, moe_rows=None):
+def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear, moe_rows=None, route_x=None):
     """A SparseMoE layer in the server, on ``x (B, T, C)``: the router scores
-    *all* ``n_expert`` in float32 (:func:`route_sigmoid_group`, or
-    :func:`route_sigmoid_bias` on ``mp["expert_bias"]``), the layer
+    *all* ``n_expert`` in float32 (:func:`route_softmax`, :func:`route_sigmoid_group`,
+    or :func:`route_sigmoid_bias` on ``mp["expert_bias"]``), the layer
     holds experts ``[expert_first, expert_first + expert_held)`` and computes
     their part (``jaxex._moe_share``, the trainer's forward: the step's rows
     sorted by held expert into whole row tiles, grouped products through
@@ -229,7 +240,13 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear, moe_rows=None):
     ``W_up`` once; the router and the shared expert read ``x`` itself.
     ``cfg.moe_activation`` "relu2": experts and shared expert are ``W2 relu(W1
     .)^2``, two matrices (``jaxex._moe_share_planned`` with no ``fc_2``; the plan, the
-    sort, the gathers and the grouped products are the gated form's).
+    sort, the gathers and the grouped products are the gated form's); "reglu":
+    ``W2 (relu(W1 .) * W3 .)``, SwiGLU's three matrices under another gate.
+
+    ``route_x (B, T, C)``: what the router reads where that is not what the experts
+    read (``cfg.moe_route_block_input``: the block's input, and then required, so that
+    a caller that goes round :func:`_close_block` fails by name; else None).  The
+    shared expert and the latent read ``x``.
 
     ``moe_rows``: a list that takes this layer's ``(rows that landed on held
     experts, held experts with a row)``, int32 ``(2,)``, from the plan's own
@@ -239,14 +256,20 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear, moe_rows=None):
     B, T, C = x.shape
     I, Eh = cfg.intermediate_size, cfg.expert_held
     Cx = cfg.moe_latent_size or C
-    gated = cfg.moe_activation == "swiglu"
+    gated = cfg.moe_activation != "relu2"
+    gate = jax.nn.relu if cfg.moe_activation == "reglu" else jax.nn.silu      # of the gated forms
+    assert (route_x is not None) == cfg.moe_route_block_input, (
+        "moe_route_block_input: the router reads the block's input, which only _close_block hands through")
     x2 = x.reshape(B * T, C)
     with scope("router"):
-        scores = jax.nn.sigmoid(x2.astype(jnp.float32) @ mp["gate"].T.astype(jnp.float32))
-        if cfg.moe_router == "sigmoid_bias":
-            top_w, top_idx = route_sigmoid_bias(scores, mp["expert_bias"], cfg)
+        routed = x2 if route_x is None else route_x.reshape(B * T, C)
+        logits = routed.astype(jnp.float32) @ mp["gate"].T.astype(jnp.float32)
+        if cfg.moe_router == "softmax":
+            top_w, top_idx = route_softmax(logits, cfg)
+        elif cfg.moe_router == "sigmoid_bias":
+            top_w, top_idx = route_sigmoid_bias(jax.nn.sigmoid(logits), mp["expert_bias"], cfg)
         else:
-            top_w, top_idx = route_sigmoid_group(scores, cfg)
+            top_w, top_idx = route_sigmoid_group(jax.nn.sigmoid(logits), cfg)
     even = B * T * cfg.n_expert_per_token / cfg.n_expert      # rows an even routing sends a held expert
     xe = x2
     if cfg.moe_latent_size:
@@ -255,7 +278,7 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear, moe_rows=None):
     with scope("experts"):
         y, plan = jaxex._moe_share_planned(
             xe, top_idx, top_w, mp["fc_1"].reshape(Eh, Cx, I), mp["fc_2"].reshape(Eh, Cx, I) if gated else None,
-            mp["proj"].reshape(Eh, I, Cx), cfg.expert_first, cfg.n_expert, moe_row_tile(even))
+            mp["proj"].reshape(Eh, I, Cx), cfg.expert_first, cfg.n_expert, moe_row_tile(even), gate=gate)
         if moe_rows is not None:
             moe_rows.append(jnp.stack([jnp.sum(plan["cnt"]), jnp.sum(plan["cnt"] > 0, dtype=jnp.int32)]))
     if cfg.moe_latent_size:
@@ -264,7 +287,7 @@ def moe_share_mlp(mp, x, cfg: Config, *, lin=_linear, moe_rows=None):
     if cfg.shared_expert_size:
         with scope("shared"):
             sp = mp["shared"]
-            hidden = (jax.nn.silu(lin(x2, sp["fc_1"])) * lin(x2, sp["fc_2"]) if gated
+            hidden = (gate(lin(x2, sp["fc_1"])) * lin(x2, sp["fc_2"]) if gated
                       else jnp.square(jax.nn.relu(lin(x2, sp["fc_1"]))))
             shared = lin(hidden, sp["proj"])
             if cfg.shared_expert_gate:
@@ -1262,9 +1285,8 @@ def hc_step(hp, xs, cfg: Config, *, sharded=False):
 
 
 def require_servable(cfg: Config) -> None:
-    """The one refusal of a config this module's forward cannot run (an
-    expert share routed by softmax, norms with zero-centred weights:
-    ``Config.training_only``): such a model trains
+    """The one refusal of a config this module's forward cannot run (norms with
+    zero-centred weights: ``Config.training_only``): such a model trains
     through ``tt.jit`` / ``make_train_step``; serving it is not built yet."""
     why = getattr(cfg, "training_only", None)
     if why:
@@ -1283,7 +1305,10 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
     A model of single sublayers (``cfg.single_sublayer``): a mixer layer ends in
     its residual sum; an "mlp" layer has no mixer (``h`` None) and is ``x +
     MLP(norm_1(x))``, all of it under the ``mlp`` scope.  ``moe_rows``: see
-    :func:`moe_share_mlp`.  ``hc``: under hyper-connections (``cfg.hc_mult`` > 1)
+    :func:`moe_share_mlp`.  ``cfg.moe_route_block_input``: the expert layer's router
+    reads ``x`` as it comes in, the block's input, beside the experts'
+    ``norm_2(x + h)`` (its products keep the scope ``mlp/router``).
+    ``hc``: under hyper-connections (``cfg.hc_mult`` > 1)
     ``x`` is the stream ``(B, n, T, C)`` and ``hc`` the maps the mixer's open
     returned beside its input; what comes back is the stream with the close the
     MLP leaves owed, ``(x, (f, maps))``, which the next layer's first open (or the
@@ -1293,7 +1318,7 @@ def _close_block(bp, x, n1, h, cfg: Config, *, quantized=False, lora=None, lora_
         with scope("mixer/residual"):
             return x + h
     mlp = partial(_mlp, bp["mlp"], cfg=cfg, quantized=quantized, lora=lora, lora_scaling=lora_scaling,
-                  moe_rows=moe_rows)
+                  moe_rows=moe_rows, route_x=x if cfg.moe_route_block_input else None)
     if cfg.single_sublayer:
         with scope("mlp"):
             with scope("norm"):

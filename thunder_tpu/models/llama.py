@@ -218,9 +218,16 @@ class Config:
     # them (``latent_down``), their weighted sum through one shared
     # up-projection (``latent_up``); the shared expert stays at the model's
     # width.  ``moe_activation`` "relu2": experts and shared expert are ungated,
-    # ``W2 relu(W1 x)^2`` (two matrices, no ``fc_2``); "swiglu" as above
+    # ``W2 relu(W1 x)^2`` (two matrices, no ``fc_2``); "reglu": the gated ReLU,
+    # ``W2 (relu(W1 x) * W3 x)``, SwiGLU's three matrices (in the server alone);
+    # "swiglu" as above
     moe_latent_size: int = 0
     moe_activation: str = "swiglu"
+    # The router of every SparseMoE layer reads the *block's* input (the residual
+    # stream before ``norm_1`` and the mixer) where the experts read
+    # ``norm_2(x + mixer)``: the choice is known before attention runs
+    # (SmallThinker; in the server alone, the pre-norm sequential block alone)
+    moe_route_block_input: bool = False
     # The first ``first_k_dense`` layers of a SparseMoE model keep a dense
     # SwiGLU of width ``dense_intermediate_size`` in place of the experts
     first_k_dense: int = 0
@@ -299,12 +306,16 @@ class Config:
                 assert self.n_expert_per_token <= self.topk_group * (self.n_expert // self.n_group)
             if self.first_k_dense and self.dense_intermediate_size is None:
                 self.dense_intermediate_size = self.intermediate_size
-            assert self.moe_activation in ("swiglu", "relu2"), self.moe_activation
+            assert self.moe_activation in ("swiglu", "relu2", "reglu"), self.moe_activation
             assert self.moe_latent_size >= 0
+            assert not self.moe_route_block_input or not (
+                self.parallel_residual or self.post_sublayer_norm or self.sandwich_norm or self.hc_mult > 1
+                or self.first_k_dense or set(self.layer_types or ()) & set(SINGLE_SUBLAYER_KINDS)), (
+                "moe_route_block_input: every layer a pre-norm sequential block with an expert layer")
         else:
             assert not self.first_k_dense, "first_k_dense: the leading dense layers of a SparseMoE model"
-            assert not self.moe_latent_size and self.moe_activation == "swiglu", (
-                "moe_latent_size and moe_activation are a SparseMoE layer's")
+            assert not self.moe_latent_size and self.moe_activation == "swiglu" and not self.moe_route_block_input, (
+                "moe_latent_size, moe_activation and moe_route_block_input are a SparseMoE layer's")
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             assert len(self.layer_types) == self.n_layer, "layer_types needs one kind a layer"
@@ -533,11 +544,7 @@ class Config:
     @property
     def training_only(self) -> str | None:
         """Why ``models.generate`` and ``tt.serve`` cannot run this config, or
-        None: the server's expert share routes by sigmoid scores alone and its
-        norms have no zero-centred weights."""
-        if self.mlp_class == "SparseMoE" and self.moe_router == "softmax":
-            return ("its mlp_class is SparseMoE with the softmax router (the serving forward's expert "
-                    "share routes by moe_router='sigmoid_group' or 'sigmoid_bias')")
+        None: the server's norms have no zero-centred weights."""
         if self.norm_zero_centered:
             return "it sets norm_zero_centered (the serving forward's norms have no such form)"
         return None
@@ -826,7 +833,7 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
             # (a latent share: C is the latent's width, and two shared projections stand around the experts)
             Eh, I, C = config.expert_held, config.intermediate_size, config.n_embd
             Cx = config.moe_latent_size or C
-            gated = config.moe_activation == "swiglu"           # "relu2": two matrices an expert, no fc_2
+            gated = config.moe_activation != "relu2"            # "relu2": two matrices an expert, no fc_2
             block["mlp"] = {
                 "gate": dense(next(keys), C, config.n_expert),
                 "fc_1": dense(next(keys), I, Eh * Cx),
@@ -1174,8 +1181,9 @@ def serving_only(config: Config) -> str | None:
     memory unit, cross attention, differential attention), the Mamba-2 mixer,
     single-sublayer blocks, the window kind of an ordinary decoder with its
     rotation a layer kind and its norms on both sides of a sublayer, the sigmoid
-    routers, leading dense layers, the latent ungated expert share and a stream
-    under hyper-connections are built in ``models.generate`` for the server alone."""
+    routers, a router that reads the block's input, leading dense layers, the
+    latent ungated expert share, the gated-ReLU experts and a stream under
+    hyper-connections are built in ``models.generate`` for the server alone."""
     if config.conv_layers:
         return ("layer_types with 'conv' (the gated short convolution is built in models.generate, for tt.serve, "
                 "and has no traced form)")
@@ -1196,10 +1204,12 @@ def serving_only(config: Config) -> str | None:
                 "models.generate, for tt.serve, and have no traced form: the chunked scan has no backward)")
     if config.mlp_class == "SparseMoE" and (config.moe_router != "softmax" or config.first_k_dense
                                             or (config.shared_expert_size and not config.shared_expert_gate)
-                                            or config.moe_latent_size or config.moe_activation != "swiglu"):
+                                            or config.moe_latent_size or config.moe_activation != "swiglu"
+                                            or config.moe_route_block_input):
         return ("a SparseMoE layer with moe_router='sigmoid_group' or 'sigmoid_bias', first_k_dense, an ungated "
-                "shared expert, moe_latent_size or moe_activation='relu2' (built in models.generate, for tt.serve; "
-                "the traced expert layer routes by softmax into SwiGLU experts at the model's width)")
+                "shared expert, moe_latent_size, moe_activation='relu2' or 'reglu', or moe_route_block_input (built "
+                "in models.generate, for tt.serve; the traced expert layer routes by softmax on the tensor its "
+                "SwiGLU experts read, at the model's width)")
     return None
 
 

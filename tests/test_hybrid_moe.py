@@ -549,9 +549,10 @@ def test_the_schedule_at_the_three_configurations_widths(monkeypatch, cell, devi
     plan = px._gmm_blocks(K, N, 2, TM, nt)
     grid, (x_spec, w_spec), o_spec = px._gmm_specs(TM, K, nt, plan, False)
     if device == "v5e" or cell.startswith("hybrid"):
-        assert plan["col_blocks"] == 1 and plan["weight_fetches_a_group"] == 1
-        assert plan["weight_block_bytes"] == K * N * 2 and grid == (nt, 1)
-        assert (x_spec.block_shape, w_spec.block_shape, o_spec.block_shape) == ((TM, K), (1, K, N), (TM, N))
+        assert plan["col_blocks"] == 1 and plan["weight_fetches_a_group"] == 1 and plan["weights_ahead"] == (TM == 128)
+        assert plan["weight_block_bytes"] == K * N * 2 and grid == ((nt,) if TM == 128 else (nt, 1))
+        # a prompt's and the trainer's weights stay whole in HBM for the kernel's own copies: no block of them
+        assert (x_spec.block_shape, w_spec.block_shape, o_spec.block_shape) == ((TM, K), None if TM == 128 else (1, K, N), (TM, N))
     else:
         assert plan["col_blocks"] > 1 and plan["weight_fetches_a_group"] == nt and plan["vmem_limit_bytes"] == 0
         assert grid == (nt, plan["col_blocks"]) and w_spec.block_shape == (1, K, N // plan["col_blocks"])
@@ -567,6 +568,114 @@ def test_gmm_schedule_holds_the_last_grouped_product_built(interpreted):
     assert px.gmm_schedule == px._gmm_blocks(128, 128, 4, 8, tg.shape[0])
     assert set(px.gmm_schedule) >= {"col_blocks", "weight_block_bytes", "vmem_limit_bytes", "weight_fetches_a_group"}
     assert all(isinstance(v, int) for v in px.gmm_schedule.values())
+
+
+# ``moe_grouped_mm`` where it copies its own weights a group ahead (``pallasex._gmm_ahead_kernel``; a row tile of
+# ``MOE_ROW_TILE``) against the ``BlockSpec`` form a decode step keeps.  Tiles a group, then ``tiles_used`` and the
+# tiles the buffer holds past the groups' own (the plan names its last group for them).
+AHEAD_TM, AHEAD_K, AHEAD_N = 128, 128, 256
+AHEAD_ROUTINGS = {
+    "an_empty_group_between_two_with_rows": ((2, 0, 3), 5, 3),
+    "the_first_and_the_last_group_empty": ((0, 2, 1, 0), 3, 2),
+    "one_tile_groups_beside_eight_tile_groups": ((1, 8, 1, 8), 18, 0),       # tiles_used == nt
+    "no_tile_used": ((3, 3), 0, 2),
+    "one_tile_used": ((3, 3), 1, 2),
+    "the_used_tiles_end_inside_a_run": ((2, 3, 2), 4, 1),
+    "the_used_tiles_end_where_a_run_would_open": ((2, 3, 2), 5, 1),
+    "two_runs_the_buffers_swap_roles_once": ((2, 2), 4, 4),
+    "an_odd_number_of_runs": ((1, 2, 1, 2, 1), 7, 1),
+    "every_group_one_tile": ((1, 1, 1, 1, 1, 1), 6, 2),
+}
+
+
+def _ahead_tiles(case):
+    tiles, used, past = AHEAD_ROUTINGS[case]
+    tg = np.repeat(np.arange(len(tiles)), tiles).tolist() + [len(tiles) - 1] * past
+    return jnp.asarray(tg, jnp.int32), jnp.asarray([used], jnp.int32), len(tiles)
+
+
+def _blockspec_form(monkeypatch):
+    """``moe_grouped_mm`` as a decode step builds it, at any row tile."""
+    blocks = px._gmm_blocks
+    monkeypatch.setattr(px, "_gmm_blocks", lambda *a: {**blocks(*a), "weights_ahead": 0})
+
+
+@pytest.mark.parametrize("transpose_w", [False, True], ids=["w", "w_transposed"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(AHEAD_ROUTINGS))
+def test_the_product_that_copies_its_own_weights_has_the_blockspec_forms_bits(interpreted, monkeypatch, case, dtype,
+                                                                              transpose_w):
+    tg, used, groups = _ahead_tiles(case)
+    nt = tg.shape[0]
+    rng = np.random.default_rng(nt)
+    K, N = (AHEAD_N, AHEAD_K) if transpose_w else (AHEAD_K, AHEAD_N)
+    x = jnp.asarray(rng.standard_normal((nt * AHEAD_TM, K)), dtype)
+    w = jnp.asarray(rng.standard_normal((groups, AHEAD_K, AHEAD_N)), dtype)       # (G, K, N), or (G, N, K) transposed
+    assert px._gmm_blocks(K, N, x.dtype.itemsize, AHEAD_TM, nt)["weights_ahead"] == 1
+    got = px._moe_grouped_mm.__wrapped__(x, w, tg, used, transpose_w=transpose_w)
+    _blockspec_form(monkeypatch)
+    want = px._moe_grouped_mm.__wrapped__(x, w, tg, used, transpose_w=transpose_w)
+    assert got.dtype == want.dtype and got.shape == want.shape == (nt * AHEAD_TM, N)
+    assert bool(jnp.all(got == want)), "bit for bit"
+    n = int(used[0]) * AHEAD_TM
+    assert float(jnp.abs(got[n:]).max(initial=0.0)) == 0.0 and (n == 0 or float(jnp.abs(got[:n]).min()) > 0.0)
+
+
+@pytest.mark.parametrize("case", list(AHEAD_ROUTINGS))
+def test_a_runs_weights_are_asked_for_at_the_first_tile_of_the_run_before_and_nothing_is_left_in_flight(case):
+    """The kernel's walk over ``pallasex._gmm_runs``' three vectors, in Python:
+    every used tile multiplies a buffer that holds its own group's weights and
+    was waited for; a copy lands only in the buffer whose run has passed; one
+    copy a run of used tiles, the first alone not ahead; none past ``tiles_used``."""
+    tg, used, _ = _ahead_tiles(case)
+    opens, parity, nxt = (np.asarray(v) for v in px._gmm_runs(tg))
+    tg, used = np.asarray(tg), int(used[0])
+    holds, flying, copies = [None, None], [None, None], 0
+    for t in range(used):
+        k = int(parity[t])
+        if opens[t]:
+            if t == 0:
+                flying[0], copies = int(tg[0]), 1
+            if nxt[t] < used:       # started before the wait for the run's own
+                assert flying[1 - k] is None, "the other buffer's run has passed and nothing is on its way there"
+                flying[1 - k], copies = int(tg[nxt[t]]), copies + 1
+            assert flying[k] is not None, "a run waits for a copy that was started"
+            holds[k], flying[k] = flying[k], None
+        assert holds[k] == tg[t], "a tile multiplies its own group's weights"
+    assert flying == [None, None], "nothing in flight when the call ends"
+    runs = int(opens[:used].sum())
+    assert copies == runs and (used == 0 or opens[0] == 1 and parity[0] == 0)
+    assert (np.diff(parity[:used][opens[:used] == 1]) != 0).all(), "consecutive runs take the two buffers in turn"
+
+
+@pytest.mark.parametrize("tile,layout,ahead", [(128, "whole_matrix", 1), (64, "whole_matrix", 0), (16, "whole_matrix", 0),
+                                               (128, "column_blocks", 0)])
+def test_only_a_prompts_row_tile_over_a_whole_matrix_copies_its_own_weights(interpreted, monkeypatch, tile, layout, ahead):
+    """A decode step's tile (``generate.moe_row_tile`` gives 128 only from 64
+    rows an expert up) builds the ``BlockSpec`` form, and so does a matrix that
+    goes through in column blocks; ``gmm_schedule`` and the counter say which."""
+    if layout == "column_blocks":
+        monkeypatch.setattr(px, "_GMM_VMEM_MARGIN", 0)
+        monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: px._gmm_vmem(tile, GMM_K, 128, 4))
+    tg, used = _gmm_tiles()
+    x = jnp.ones((tg.shape[0] * tile, GMM_K), jnp.float32)
+    w = jnp.ones((GMM_GROUPS, GMM_K, GMM_N), jnp.float32)
+    before = (px.stats.get("grouped_mm", 0), px.stats.get("grouped_mm_ahead", 0))
+    out = px.grouped_mm(x, w, tg, used)
+    assert out is not None and float(out[0, 0]) == GMM_K
+    assert px.gmm_schedule["weights_ahead"] == ahead and (px.gmm_schedule["col_blocks"] > 1) == (layout == "column_blocks")
+    assert (px.stats["grouped_mm"], px.stats.get("grouped_mm_ahead", 0)) == (before[0] + 1, before[1] + ahead)
+
+
+def test_a_decode_steps_row_tile_is_under_the_prompts_in_every_cell():
+    """Rows an even routing sends a held expert in a decode step of the six serve cells with an expert layer
+    (LFM2 256 x 4 / 32, A.X-K1 64 x 8 / 192, Xing4.0 32 x 4 / 64, Nemotron 128 x 22 / 512, Trinity 20 x 8 / 128,
+    SmallThinker 64 x 6 / 64) and in their shortest prompt buckets: the shape that tells the two forms apart."""
+    from thunder_tpu.core.prims import MOE_ROW_TILE
+    from thunder_tpu.models.generate import moe_row_tile
+
+    assert [moe_row_tile(r) for r in (32, 2.67, 2, 5.5, 1.25, 6)] == [64, 16, 16, 16, 16, 16] and MOE_ROW_TILE == 128
+    assert all(moe_row_tile(r) == MOE_ROW_TILE for r in (64, 512 * 4 / 32, 2560 * 8 / 192, 2048 * 22 / 512, 2560 * 6 / 64))
 
 
 def _expert_layer(hf, params_mlp, x):

@@ -1214,50 +1214,118 @@ GMM_SHAPES = {
     # Xing4.0: all 64 experts of a layer, 2 rows an expert a decode step, 512 a prompt of 8,192
     "xing4/3584x1024/decode": (3584, 1024, 16, 72, 64, True), "xing4/3584x1024/prefill": (3584, 1024, 128, 320, 64, True),
     "xing4/1024x3584/prefill": (1024, 3584, 128, 320, 64, True),
+    # SmallThinker: all 64 experts, 6 rows an expert a decode step, 960 a prompt of 10,240
+    "smallthinker/2560x768/decode": (2560, 768, 16, 96, 64, True), "smallthinker/2560x768/prefill": (2560, 768, 128, 608, 64, True),
+    "smallthinker/768x2560/prefill": (768, 2560, 128, 608, 64, True),
+    # Trinity-Mini: 16 of 128 experts, 1.25 rows an expert a decode step, 624 a prompt of 9,984
+    "trinity/2048x1024/decode": (2048, 1024, 16, 24, 16, True), "trinity/2048x1024/prefill": (2048, 1024, 128, 104, 16, True),
+    "trinity/1024x2048/prefill": (1024, 2048, 128, 104, 16, True),
+    # Nemotron 3 Super at its latent width: 128 of 512 experts, 5.5 rows an expert a decode step, 220 a prompt of 5,120
+    "nemotron/1024x2688/decode": (1024, 2688, 16, 184, 128, True), "nemotron/1024x2688/prefill": (1024, 2688, 128, 376, 128, True),
+    "nemotron/2688x1024/prefill": (2688, 1024, 128, 376, 128, True),
+    # the hybrid trainer's forward products, and transposed the gradients of their rows
+    "hybrid/2048x512/train": (2048, 512, 128, 128, 32, True), "hybrid/512x2048/train": (512, 2048, 128, 128, 32, True),
     # a matrix the VMEM asked for does not hold twice: column blocks inside a tile, under the default limit
     "unknown_vmem/7168x2048/decode": (7168, 2048, 16, 16, 12, False),
     "unknown_vmem/2048x7168/prefill": (2048, 7168, 128, 48, 12, False),
 }
 
+# sha256 of what a decode step's ``moe_grouped_mm`` lowers to for a TPU (the program's text, the kernel's body printed
+# without its source locations in its bytecode's place) on the commit before a prompt's product came to copy its own
+# weights (PR 60): a decode step keeps the ``BlockSpec`` form, letter for letter.  ``w`` alone; after a deliberate
+# change to that form regenerate with ``python tests/test_pallas_tpu_lowering.py``.
+GMM_DECODE_TEXT = {
+    "axk1/7168x2048/decode": "ef168ff5c30cf0ddfe7c9ce78732263d4f60070329e7cc2e7dd29d204cc6a695",
+    "lfm2/1792x2048/decode": "1226f7297fd3ed2d7c73904c6b80a85cc7bb57ab37b4d2836d58d4099db73524",
+    "lfm2/2048x1792/decode": "13eef86754ae74507739f4c5ff1d30df6bb237b8d13d8b505d100499ca993f46",
+    "nemotron/1024x2688/decode": "c5adb361c7b46538554a1079e1c5f25f12b00d894d1d08326851a936565aac7c",
+    "smallthinker/2560x768/decode": "5595142452c18c7f7f02d9ced6104f7a32cf90a89dc6804277e971bd7aa95876",
+    "trinity/2048x1024/decode": "544746324616635ed05d9af9366c3e9e604164d10e6bec3671cae795e9fa8fc5",
+    "xing4/3584x1024/decode": "bc80aeb0b5db46b2797bc011ca0840d65bedb6321858b6b3bb3e93c47e6bf613",
+}
+
+
+def _gmm_lowered(case, transpose_w, sharding):
+    K, N, TM, nt, groups, _ = GMM_SHAPES[case]
+    specs = [((nt * TM, K), BF), ((groups, N, K) if transpose_w else (groups, K, N), BF), ((nt,), I32), ((1,), I32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in specs]
+    fn = functools.partial(px._moe_grouped_mm.__wrapped__, transpose_w=transpose_w)
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+
+
+def _gmm_text_digest(text: str) -> str:
+    """As ``tools/lowered_same.py`` compares two trees' programs: the kernel's body printed without locations."""
+    import hashlib
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from tools.lowered_same import plain
+
+    plain_text, bodies = plain(text)
+    assert bodies == 1
+    return hashlib.sha256(plain_text.encode()).hexdigest()
+
 
 @pytest.mark.parametrize("transpose_w", [False, True], ids=["w", "w_transposed"])
 @pytest.mark.parametrize("case", sorted(GMM_SHAPES))
 def test_the_grouped_product_compiles_inside_the_vmem_it_asks_for(case, transpose_w, tpu_sharding, monkeypatch):
-    """``moe_grouped_mm`` at the widths the two serving cells run it, the
-    whole matrix the block (7.3 MB at LFM2's widths, 29.4 MB at A.X-K1's):
-    Mosaic takes each inside the scoped limit the call states, a limit only
-    the compile holds it to.  Where the
-    device's VMEM is not known the call states none and the column blocks
-    it falls back to compile inside the default."""
+    """``moe_grouped_mm`` at the widths the serving cells and the hybrid
+    trainer run it, the whole matrix the block (2.1 MB at the trainer's widths,
+    3.9 at SmallThinker's, 7.3 at LFM2's and Xing4.0's, 29.4 at A.X-K1's): Mosaic
+    takes each inside the scoped limit the call states (``_gmm_blocks``: none
+    where the default holds it), a limit only the compile holds it to.  A
+    prompt's and the trainer's products, a row tile of 128, copy their own
+    weights a group ahead: ``w`` whole in HBM, two buffers of a block and two
+    semaphores, one grid axis, five prefetched vectors, the copy of the next
+    group at the low priority, under the one name the metrics find the kernel
+    by.  A decode step's keep the ``BlockSpec`` form.  Where the device's VMEM
+    is not known the call states none and the column blocks it falls back to
+    compile inside the default."""
     K, N, TM, nt, groups, known = GMM_SHAPES[case]
     if known:
         monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)      # three quarters of a v5e core's
     plan = px._gmm_blocks(K, N, 2, TM, nt)
-    specs = [((nt * TM, K), BF), ((groups, N, K) if transpose_w else (groups, K, N), BF), ((nt,), I32), ((1,), I32)]
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
-    fn = functools.partial(px._moe_grouped_mm.__wrapped__, transpose_w=transpose_w)
-    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    lowered = _gmm_lowered(case, transpose_w, tpu_sharding)
     text = lowered.as_text()
     assert text.count("tpu_custom_call") == 1 and 'kernel_name = "moe_grouped_mm"' in text
+    module = _mosaic_module(text)
+    w_block = f"{N}x{K}" if transpose_w else f"{K}x{N}"
     if known:
-        assert plan["col_blocks"] == 1 and plan["weight_fetches_a_group"] == 1
-        assert px._GMM_VMEM_DEFAULT < plan["vmem_limit_bytes"] <= 96 << 20
+        assert plan["col_blocks"] == 1 and plan["weight_fetches_a_group"] == 1 and plan["weights_ahead"] == (TM == 128)
+        need = px._gmm_vmem(TM, K, N, 2) + px._GMM_VMEM_MARGIN      # two blocks of the weights, of the rows and of the product
+        assert plan["vmem_limit_bytes"] == (need if need > px._GMM_VMEM_DEFAULT else 0) <= 96 << 20
+    else:
+        assert plan["col_blocks"] > 1 and plan["weight_fetches_a_group"] == nt and plan["weights_ahead"] == 0
+    if plan["vmem_limit_bytes"]:
         assert f'\\22size\\22: {plan["vmem_limit_bytes"]}}}' in text              # the scoped limit the call states
     else:
-        assert plan["col_blocks"] > 1 and plan["weight_fetches_a_group"] == nt
-        assert plan["vmem_limit_bytes"] == 0 and "scoped_memory_configs" not in text
+        assert "scoped_memory_configs" not in text
+    if plan["weights_ahead"]:
+        assert f"iteration_bounds = array<i64: {nt}>" in module and "scalar_prefetch = 5" in module
+        assert f"memref<{groups}x{w_block}xbf16, #tpu.memory_space<any>>" in module
+        assert module.count(f"memref<{w_block}xbf16, #tpu.memory_space<vmem>>") >= 2
+        # three copies in the text: the call's first block, and the next run's from either buffer's arm, those two behind the rows' own
+        assert module.count("tpu.enqueue_dma") == 3 and module.count("priority = 1 : i32") == 2
+    else:
+        assert f"iteration_bounds = array<i64: {nt}, {plan['col_blocks']}>" in module and "scalar_prefetch = 2" in module
+        assert "tpu.enqueue_dma" not in module and "memory_space<any>" not in module
+    if case in GMM_DECODE_TEXT and not transpose_w:
+        assert _gmm_text_digest(_gmm_lowered(case, False, None).as_text()) == GMM_DECODE_TEXT[case]
     if tpu_sharding is not None:
         assert re.search(r"%moe_grouped_mm(\.\d+)? = ", lowered.compile().as_text())
 
 
+def test_every_cells_decode_step_is_held_to_the_text_the_parent_lowers():
+    assert sorted(GMM_DECODE_TEXT) == sorted(c for c in GMM_SHAPES if c.endswith("/decode") and GMM_SHAPES[c][5])
+
+
 def test_the_hybrid_trainers_grouped_product_states_no_vmem_limit(tpu_sharding):
-    """``2048 x 512``: one whole 2 MiB block on a ``(tiles, 1)`` grid under the default limit, as it was."""
-    specs = [((128 * 128, 2048), BF), ((32, 2048, 512), BF), ((128,), I32), ((1,), I32)]
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
-    text = jax.jit(px._moe_grouped_mm.__wrapped__).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    """``2048 x 512``: whole 2 MiB blocks, two of them the kernel's own, under the default limit, as it was."""
+    text = _gmm_lowered("hybrid/2048x512/train", False, tpu_sharding).as_text()
     assert 'kernel_name = "moe_grouped_mm"' in text and "scoped_memory_configs" not in text
     module = _mosaic_module(text)
-    assert "iteration_bounds = array<i64: 128, 1>" in module and "memref<1x2048x512xbf16" in module
+    assert "iteration_bounds = array<i64: 128>" in module and module.count("memref<2048x512xbf16, #tpu.memory_space<vmem>>") >= 2
 
 
 # tokens, k, held, the wave's rows, their tile, C: a prompt of each cell that holds an expert share, the trainer's step, a decode step's
@@ -1579,3 +1647,11 @@ def test_the_mamba2_cells_programs_lower_to_their_kernels(kind, tpu_sharding, mo
             assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
         print(kind, f"temporaries (a bucket of {COMPILED_BUCKET})" if kind == "prefill_fresh" else "temporaries",
               compiled.memory_analysis().temp_size_in_bytes)
+
+
+if __name__ == "__main__":      # the digests of ``GMM_DECODE_TEXT``, from the tree this file's ``thunder_tpu`` is imported from
+    px._interpret = lambda: False
+    px._gmm_vmem_cap = lambda: 96 << 20
+    print(px.__file__)
+    for case_ in sorted(c for c in GMM_SHAPES if c.endswith("/decode") and GMM_SHAPES[c][5]):
+        print(f'    "{case_}": "{_gmm_text_digest(_gmm_lowered(case_, False, None).as_text())}",')

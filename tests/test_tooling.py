@@ -198,7 +198,7 @@ class TestTools:
             assert out.shape == (3, 4, 128) and (px._mla_dot, px._mla_start_chunk, px._mla_wait_chunk) == hooks
 
 
-    def test_moe_tune_builds_the_cells_waves_and_checks_under_the_interpreter(self, monkeypatch):
+    def test_moe_tune_builds_the_cells_waves_and_checks_under_the_interpreter(self, monkeypatch, tmp_path):
         """The tool's shapes are the cells' (tile, wave and rows an expert as
         ``moe_row_tile`` / ``moe_wave_tiles`` give them), an even routing fills
         a tile an expert and the skewed one some twice, and its ``--check``
@@ -218,7 +218,12 @@ class TestTools:
         monkeypatch.setitem(moe_tune.SHAPES, "small", dict(tokens=64, k=4, held=8, total=8, C=256, I=128))
         monkeypatch.setitem(moe_tune.SHAPES, "small_t", dict(tokens=64, k=4, held=4, total=16, C=256, I=128, tile=8,
                                                              transposed=True))
-        assert moe_tune.check(["small", "small_t"]) < 1e-3
+        # a prompt's row tile: the product copies its own weights, and the check holds it to the BlockSpec form's bits too
+        monkeypatch.setitem(moe_tune.SHAPES, "small_prompt", dict(tokens=1024, k=4, held=8, total=8, C=128, I=256))
+        monkeypatch.setattr(moe_tune, "KEPT", str(tmp_path / "moe_tune.txt"))
+        assert moe_tune.check(["small", "small_t", "small_prompt"]) < 1e-3
+        assert "small_prompt       fc   relative error" in (tmp_path / "moe_tune.txt").read_text()
+        assert "the BlockSpec form's bits: True" in (tmp_path / "moe_tune.txt").read_text()
 
 
 class TestSharpEdges:

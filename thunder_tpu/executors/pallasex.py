@@ -40,7 +40,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from thunder_tpu.core.prims import CAUSAL_CONV_ACTIVATIONS, GDN_CHUNK, PrimIDs, gdn_state_stride, prim_lookup
+from thunder_tpu.core.prims import CAUSAL_CONV_ACTIVATIONS, GDN_CHUNK, MOE_ROW_TILE, PrimIDs, gdn_state_stride, prim_lookup
 from thunder_tpu.extend import OperatorExecutor, add_default_executor, register_executor
 
 __all__ = [
@@ -3040,6 +3040,27 @@ ex.register_implementation(PrimIDs.GDN_CHUNK_BACKWARD, _gdn_bwd_op,
 # ``tiles_used`` point at the last used tile's rows and weight block, so
 # nothing is fetched for them; they write zeros.  The time follows the tiles
 # used.
+#
+# Pallas fetches a ``BlockSpec`` operand one grid step ahead, and a group's
+# tiles follow one another under one block index: the next group's weights
+# would start to arrive behind the group's *last* tile, and the fetch and all
+# but one tile's product add up.  So where a group can have several tiles (a
+# row tile of ``MOE_ROW_TILE``: a prompt's, the trainer's) and the block is the
+# whole matrix, the kernel copies the weights itself, a group ahead
+# (``weights_ahead``, ``_gmm_ahead_kernel``): ``w`` stays in HBM, a *run* (a
+# group's tiles, one after another) holds the buffer of its ordinal's parity,
+# and its first tile starts the copy of the next run's group into the other
+# one, whose tiles have all passed, and then waits for its own.  Step 0 starts
+# its own group's copy first: one block a call stays bare.  No copy is started
+# for a run that begins at or past ``tiles_used``: nothing is in flight when
+# the call ends.  Two things about that copy decide whether it hides at all (on
+# a v5e, ``PERF.md`` PR 60): it is started *before* the wait for the run's own,
+# so that through a stretch of one-tile groups the queue always holds the next
+# block, and at the low priority, so that the row tiles the pipeline fetches a
+# step ahead do not queue behind megabytes of weights (at the default one the
+# form ran no faster than the ``BlockSpec`` form, and slower under a skewed
+# routing).  A decode step's tile is smaller, its groups are one tile, one grid
+# step ahead is a group ahead, and it keeps the ``BlockSpec`` form.
 # ---------------------------------------------------------------------------
 
 
@@ -3055,6 +3076,58 @@ def _gmm_kernel(tg_ref, used_ref, x_ref, w_ref, o_ref, *, transpose_w):
     @pl.when(t >= used_ref[0])
     def _pad():
         o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _gmm_ahead_kernel(tg_ref, used_ref, opens_ref, parity_ref, next_ref, x_ref, w_hbm, o_ref, buf0, buf1, sem, *,
+                      transpose_w):
+    t = pl.program_id(0)
+    used = used_ref[0]
+    dims = (((1,), (1,)), ((), ())) if transpose_w else (((1,), (0,)), ((), ()))
+    bufs = (buf0, buf1)
+
+    def copy(tile, k):      # the weights of ``tile``'s group into buffer k
+        return pltpu.make_async_copy(w_hbm.at[tg_ref[tile]], bufs[k], sem.at[k])
+
+    def tile_of_a_run_in(k):
+        @pl.when(opens_ref[t] == 1)
+        def _open():
+            if k == 0:      # the call's first run is in buffer 0
+                @pl.when(t == 0)
+                def _():
+                    copy(0, 0).start()
+
+            nxt = next_ref[t]
+
+            @pl.when(nxt < used)
+            def _():
+                copy(nxt, 1 - k).start(priority=1)
+
+            copy(t, k).wait()
+
+        o_ref[...] = jax.lax.dot_general(x_ref[...], bufs[k][...], dims,
+                                         preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    @pl.when(t < used)
+    def _compute():
+        for k in (0, 1):
+            pl.when(parity_ref[t] == k)(functools.partial(tile_of_a_run_in, k))
+
+    @pl.when(t >= used)
+    def _pad():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _gmm_runs(tile_group):
+    """The runs of ``tile_group (nt,)``, a group's tiles one after another, as
+    the kernel that copies its own weights reads them, int32 ``(nt,)`` each:
+    whether a tile opens a run, its run's parity, and the tile that opens the
+    next run (``nt``: none)."""
+    nt = tile_group.shape[0]
+    t = jnp.arange(nt, dtype=jnp.int32)
+    opens = jnp.concatenate([jnp.ones((1,), bool), tile_group[1:] != tile_group[:-1]])
+    parity = (jnp.cumsum(opens, dtype=jnp.int32) - 1) % 2
+    from_here = jax.lax.cummin(jnp.where(opens, t, nt), reverse=True)       # the first run that opens at t or after
+    return opens.astype(jnp.int32), parity, jnp.concatenate([from_here[1:], jnp.full((1,), nt, jnp.int32)])
 
 
 # what a kernel gets of VMEM without asking, and what Mosaic keeps beside the blocks
@@ -3091,7 +3164,9 @@ def _gmm_blocks(K: int, N: int, itemsize: int, TM: int, nt: int) -> dict[str, in
     inside a tile, and a group's weights are fetched once for each of its
     tiles (``weight_fetches_a_group`` then says how many that can be: every
     tile).  At ``K x N x itemsize <= 2 MiB`` this is the whole matrix inside
-    the default limit, as it always was."""
+    the default limit, as it always was.  ``weights_ahead``: the kernel copies
+    the whole matrix itself, a group ahead of its tiles, where a group can
+    have several (a row tile of ``MOE_ROW_TILE``; a decode step's is smaller)."""
     def need(TN):
         return _gmm_vmem(TM, K, TN, itemsize) + _GMM_VMEM_MARGIN
 
@@ -3100,15 +3175,20 @@ def _gmm_blocks(K: int, N: int, itemsize: int, TM: int, nt: int) -> dict[str, in
     TN = next((b for b in widths if need(b) <= cap), widths[-1])
     return {"col_block": TN, "col_blocks": N // TN, "weight_block_bytes": K * TN * itemsize,
             "vmem_limit_bytes": need(TN) if need(TN) > _GMM_VMEM_DEFAULT else 0,
-            "weight_fetches_a_group": 1 if TN == N else nt}
+            "weight_fetches_a_group": 1 if TN == N else nt, "weights_ahead": int(TN == N and TM >= MOE_ROW_TILE)}
 
 
 def _gmm_specs(TM: int, K: int, nt: int, plan: dict[str, int], transpose_w: bool):
     """The grid of a grouped product laid out as ``plan`` and the block of the
-    rows, of the weights and of the product a grid step holds."""
+    rows, of the weights and of the product a grid step holds.  Where the
+    kernel copies its own weights (``weights_ahead``) they stay whole where
+    they are and the grid is the tiles alone."""
     TN, nj = plan["col_block"], plan["col_blocks"]
     # a tile past the used ones stays on the rows and the weight block the last used one held: no copy for it
     tile = lambda t, used: jnp.minimum(t, jnp.maximum(used[0] - 1, 0))  # noqa: E731
+    if plan["weights_ahead"]:
+        return ((nt,), [pl.BlockSpec((TM, K), lambda t, tg, used, *_: (tile(t, used), 0)), pl.BlockSpec(memory_space=pl.ANY)],
+                pl.BlockSpec((TM, TN), lambda t, *_: (t, 0)))
     col = lambda t, j, used: jnp.where(t < used[0], j, nj - 1)  # noqa: E731
     if transpose_w:
         w_spec = pl.BlockSpec((1, TN, K), lambda t, j, tg, used: (tg[tile(t, used)], col(t, j, used), 0))
@@ -3128,15 +3208,21 @@ def _moe_grouped_mm(x, w, tile_group, tiles_used, transpose_w: bool = False):
     params = {}
     if not _interpret():
         limit = {"vmem_limit_bytes": plan["vmem_limit_bytes"]} if plan["vmem_limit_bytes"] else {}
-        params["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"), **limit)
+        params["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary",) * len(grid), **limit)
+    if plan["weights_ahead"]:
+        kernel, prefetched = _gmm_ahead_kernel, (tile_group, tiles_used, *_gmm_runs(tile_group))
+        scratch = [pltpu.VMEM(w.shape[1:], w.dtype), pltpu.VMEM(w.shape[1:], w.dtype), pltpu.SemaphoreType.DMA((2,))]
+    else:
+        kernel, prefetched, scratch = _gmm_kernel, (tile_group, tiles_used), []
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_w=transpose_w),
+        functools.partial(kernel, transpose_w=transpose_w),
         name="moe_grouped_mm",
-        grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=2, grid=grid, in_specs=in_specs, out_specs=out_specs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=len(prefetched), grid=grid, in_specs=in_specs,
+                                               out_specs=out_specs, scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
         interpret=_interpret(),
         **params,
-    )(tile_group, tiles_used, x, w)
+    )(*prefetched, x, w)
 
 
 def _gmm_dw_kernel(tg_ref, used_ref, x_ref, dy_ref, zero_ref, o_ref, acc_ref):
@@ -3212,6 +3298,7 @@ def grouped_mm(x, w, tile_group, tiles_used, transpose_w=False):
     stats["grouped_mm"] = stats.get("grouped_mm", 0) + 1
     gmm_schedule.update(_gmm_blocks(x.shape[1], N, x.dtype.itemsize, x.shape[0] // tile_group.shape[0],
                                     tile_group.shape[0]))
+    stats["grouped_mm_ahead"] = stats.get("grouped_mm_ahead", 0) + gmm_schedule["weights_ahead"]
     return _moe_grouped_mm(x, w, tile_group, tiles_used, transpose_w=bool(transpose_w))
 
 

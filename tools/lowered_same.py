@@ -11,9 +11,14 @@ bucket of ``mistral7b-serve-1chip`` (3,072), ``lfm2moe-serve-1chip`` (2,560)
 and ``phi4flash-serve-1chip`` (5,120) at the depths
 ``tests/test_pallas_tpu_lowering.py`` builds them, ``decode_paged`` at one batch
 and block bucket of ``mistral7b-serve-1chip`` (K/V blocks), ``lfm2moe-serve-1chip``
-(a tail beside packed rows) and ``axk1-serve-1chip`` (latent rows), and
-``mistral7b-train-1chip``'s step at one layer.  ``same`` compares two such
-directories file by file.  A
+(a tail beside packed rows), ``axk1-serve-1chip`` (latent rows) and
+``smallthinker-serve-1chip`` (rings beside blocks, 64 experts at 6 rows each;
+two rows of 688 blocks), and ``mistral7b-train-1chip``'s step at one layer, the
+grouped product given the VMEM a v5e reports (96 MiB: what the chip lowers;
+since PR 60 a prompt's product copies its own weights and a decode step's does
+not, so ``lfm2moe-serve-1chip.prefill_fresh_2560`` differs across that commit
+and no ``decode_paged`` does).  ``same`` compares two such directories file by
+file.  A
 Mosaic kernel's body is bytecode that carries its source's path and line
 numbers, so each is parsed and printed without locations first; everything
 else is compared as it is.  Exits non-zero where a text differs."""
@@ -24,7 +29,7 @@ import re
 import sys
 
 CELLS = ("mistral7b-serve-1chip", "lfm2moe-serve-1chip", "phi4flash-serve-1chip", "axk1-serve-1chip",
-         "mistral7b-train-1chip")
+         "smallthinker-serve-1chip", "mistral7b-train-1chip")
 
 
 def lower(root, out, cells):
@@ -39,6 +44,7 @@ def lower(root, out, cells):
     assert px.__file__.startswith(root), px.__file__
     px._interpret = lambda: False
     px._pallas_available = px._enabled = lambda: True
+    px._gmm_vmem_cap = lambda: 96 << 20
     os.makedirs(out, exist_ok=True)
 
     def keep(name, lowered):
@@ -84,6 +90,9 @@ def lower(root, out, cells):
     if "axk1-serve-1chip" in cells:
         _, params, eng = t._mla_engine()
         keep("axk1-serve-1chip.decode_paged_64x640", decode(eng, params, 64, 640))
+    if "smallthinker-serve-1chip" in cells:
+        _, params, eng = t._prerouted_engine()
+        keep("smallthinker-serve-1chip.decode_paged_2x688", decode(eng, params, 2, 688))
     if "mistral7b-train-1chip" in cells:
         from chipbench import common
         from chipbench.drivers import train

@@ -20,10 +20,24 @@ top-8; 64 rows in tiles of 16), and the forward and the rows' gradient of
 top-10, 16,384 tokens).  A wave past the first is timed where the routing
 fills it, as the model runs it.  ``--vmem-mib`` times the kernel again with
 ``pallasex._gmm_vmem_cap`` at each value (which block the rule derives from
-it is on the line).  To compare kernels, put each variant in
-a tree of its own under ``_checkout/`` with this file in it and run the tool in
-each, all in one call.  ``--check`` first compares the compiled kernel with
-``lax.ragged_dot`` at every shape.
+it is on the line).  Since PR 60 a prompt's and the trainer's products copy
+their own weights a group ahead (``gmm_schedule["weights_ahead"]``), and such a
+shape is timed in both forms, the ``BlockSpec`` form (the kernel before PR 60,
+which a decode step keeps) on a line of its own: ``bytes`` (the weights of the
+groups hit, the used rows and the product once, at the HBM's peak), ``products``
+(the used rows' at the MXU's peak) and the call, all in ms, so the bare piece is
+the call less the larger of the two; the shapes then also hold a prompt of
+``xing4-serve-1chip.offline-digest`` (8,192 tokens, 64 experts of ``3584 x
+1024``, top-4), ``trinity-mini-serve-1chip.offline-docqa`` (9,984; 16 of 128 of
+``2048 x 1024``, top-8), ``smallthinker-serve-1chip.offline-mixedlen`` (5,120;
+64 of ``2560 x 768``, top-6) and ``nemotron3super-serve-1chip.offline-rollouts``
+(3,584; 128 of 512 at the latent width, ``1024 x 2688``, top-22).  To compare
+other kernels, put each variant in a tree of its own under ``_checkout/`` with
+this file in it and run the tool in each, all in one call.  ``--check`` first
+compares the compiled kernel with ``lax.ragged_dot`` at every shape, and the two
+forms with each other bit for bit.  The check's and the timings' lines are
+also kept in ``chiprun_out/moe_tune.txt`` (a call shows only the end of a long
+output).
 
 ``--glue`` times the share outside its kernel instead, a part a line under the
 skewed routing, the form before PR 43 (kept below) beside ``jaxex``'s: the
@@ -42,6 +56,7 @@ same), and the whole ``_moe_share`` with the part of it that is not
 (the rows' gradient) with them.  Needs a TPU; exits non-zero without one, or if
 a check fails."""
 import argparse
+import contextlib
 import os
 import sys
 
@@ -50,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import common
 from thunder_tpu._platform import device_info
 from thunder_tpu.executors import jaxex
 from thunder_tpu.executors import pallasex as px
@@ -64,16 +80,28 @@ SHAPES = {
     "axk1_prefill": dict(tokens=8192, k=8, held=12, total=192, C=7168, I=2048),
     "hybrid_forward": dict(tokens=16384, k=10, held=32, total=512, C=2048, I=512, tile=128),
     "hybrid_transposed": dict(tokens=16384, k=10, held=32, total=512, C=2048, I=512, tile=128, transposed=True),
+    "xing4_prefill": dict(tokens=8192, k=4, held=64, total=64, C=3584, I=1024),
+    "trinity_prefill": dict(tokens=9984, k=8, held=16, total=128, C=2048, I=1024),
+    "smallthinker_prefill": dict(tokens=5120, k=6, held=64, total=64, C=2560, I=768),
+    "nemotron_prefill": dict(tokens=3584, k=22, held=128, total=512, C=1024, I=2688),
 }
 # what --glue times besides
 GLUE_SHAPES = {
-    "xing4_prefill": dict(tokens=8192, k=4, held=64, total=64, C=3584, I=1024),
     "xing4_decode": dict(tokens=32, k=4, held=64, total=64, C=3584, I=1024),
-    "trinity_prefill": dict(tokens=9984, k=8, held=16, total=128, C=2048, I=1024),
     # where XLA's gather by pos and the kernel cross (jaxex._tokens_of_rows' rule): LFM2's longest bucket, Xing4's shortest
     "lfm2_prefill_3k": dict(tokens=3072, k=4, held=32, total=32, C=2048, I=1792),
     "xing4_prefill_5k": dict(tokens=5120, k=4, held=64, total=64, C=3584, I=1024),
 }
+
+KEPT = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "chiprun_out", "moe_tune.txt")
+
+
+def say(line: str):
+    """A line of the kernel's own timings: printed, and kept where the chip tool brings it back whole."""
+    print(line, flush=True)
+    os.makedirs(os.path.dirname(KEPT), exist_ok=True)
+    with open(KEPT, "a") as f:
+        f.write(line + "\n")
 
 
 def routing(tokens, k, held, total, skew: bool, seed=0):
@@ -130,31 +158,61 @@ def check(names) -> float:
                 jaxex._grouped_mm_fast_path = fast
             err = float(jnp.linalg.norm(got.astype(jnp.float32) - want) / jnp.linalg.norm(want))
             worst = max(worst, err)
-            print(f"check {name:18s} {product:4s} relative error {err:.6f}", flush=True)
+            bits = ""
+            if px.gmm_schedule.get("weights_ahead"):
+                with blockspec_form():
+                    same = bool(jnp.all(px.grouped_mm(x, w, tg, used, t) == got))
+                bits = f"  the BlockSpec form's bits: {same}"
+                worst = worst if same else float("inf")
+            say(f"check {name:18s} {product:4s} relative error {err:.6f}{bits}")
     return worst
 
 
-def time_shape(name, skew: bool):
+@contextlib.contextmanager
+def blockspec_form():
+    """``moe_grouped_mm`` as it was before PR 60 at every shape: the weights a ``BlockSpec`` operand."""
+    blocks = px._gmm_blocks
+    px._gmm_blocks = lambda *a: {**blocks(*a), "weights_ahead": 0}
+    px._moe_grouped_mm.clear_cache()
+    try:
+        yield
+    finally:
+        px._gmm_blocks = blocks
+        px._moe_grouped_mm.clear_cache()
+
+
+def time_shape(name, skew: bool, device_kind: str):
     from tools.flash_tune import kernel_ms
 
     shape = SHAPES[name]
     ws, tile, cnt = waves(skew=skew, **shape)
     t = bool(shape.get("transposed"))
+    peak = common.peaks(device_kind)
+    flops_s, bytes_s = peak["bf16_flops_per_sec"], peak["hbm_bytes_per_sec"]
     for product in ("fc", "proj"):
         x, w, (K, N) = operands(product=product, rows=ws[0][0].shape[0] * tile, **shape)
-        call = jax.jit(lambda x_, w_, tg, used: px.grouped_mm(x_, w_, tg, used, t))
-        run = lambda: jax.block_until_ready([call(x, w, tg, used) for tg, used in ws])   # noqa: E731, B023
-        run()
-        ms = kernel_ms(run, REPS)
-        own = sum(v for n, v in ms.items() if n.startswith("moe_grouped_mm"))
         used = sum(int(u[0]) for _, u in ws)
         groups = int((cnt > 0).sum())
         least = (groups * K * N + used * tile * (K + N)) * x.dtype.itemsize
-        schedule = dict(getattr(px, "gmm_schedule", {}))       # a parent's tree has none
-        print(f"{name:18s} {product:4s} {'skew' if skew else 'even'}  {own:7.3f} ms  {least / own / 1e6:6.1f} GB/s "
-              f"{2 * used * tile * K * N / own / 1e9:6.1f} TF/s  rows x ({K}, {N}) in tiles of {tile}: {used} of "
-              f"{len(ws)} x {ws[0][0].shape[0]} tiles used, at most {int(-(-cnt.max() // tile))} a group ({groups} groups)  "
-              f"beside it {sum(ms.values()) - own:.3f}  {schedule}", flush=True)
+        flops = 2 * used * tile * K * N
+
+        def line():
+            call = jax.jit(lambda x_, w_, tg, used_: px.grouped_mm(x_, w_, tg, used_, t))
+            run = lambda: jax.block_until_ready([call(x, w, tg, used_) for tg, used_ in ws])   # noqa: E731, B023
+            run()
+            ms = kernel_ms(run, REPS)
+            own = sum(v for n, v in ms.items() if n.startswith("moe_grouped_mm"))
+            schedule = dict(px.gmm_schedule)
+            form = "ahead" if schedule.get("weights_ahead") else "blockspec"
+            say(f"{name:20s} {product:4s} {'skew' if skew else 'even'} {form:9s} bytes {least / bytes_s * 1e3:7.3f}  products "
+                f"{flops / flops_s * 1e3:7.3f}  call {own:7.3f} ms  {least / own / 1e6:6.1f} GB/s {flops / own / 1e9:6.1f} TF/s  "
+                f"rows x ({K}, {N}) in tiles of {tile}: {used} of {len(ws)} x {ws[0][0].shape[0]} tiles used, at most "
+                f"{int(-(-cnt.max() // tile))} a group ({groups} groups)  beside it {sum(ms.values()) - own:.3f}  {schedule}")
+            return schedule
+
+        if line().get("weights_ahead"):
+            with blockspec_form():
+                line()
 
 
 # ---- the share outside its kernel (--glue) ----------------------------------------------
@@ -302,7 +360,7 @@ def main():
             print(f"_gmm_vmem_cap = {mib} MiB", flush=True)
         for name in names:
             for skew in (False, True):
-                time_shape(name, skew)
+                time_shape(name, skew, device["kind"])
 
 
 if __name__ == "__main__":

@@ -92,6 +92,8 @@ def test_the_engine_reports_its_state_pool(served, form):
     st = stats["state"]
     assert st["slots"] == 3 and st["leased"] == 0 and st["free_low_water"] == 0 and st["layers"] == 3
     assert st["dtype"] == "float32" and st["arena_bytes"] == 4 * st["slot_bytes"]
+    # as counted, and as the chip's tiles hold them (a tiny row of 12 by 48 fills a tenth of its tiles)
+    assert st["arena_laid_out_bytes"] == 4 * st["slot_laid_out_bytes"] > st["arena_bytes"]
     assert stats["pool_occupancy"]["state"]["fill_frac"] == 0.0 and flight["pool"]["state"]["slots"] == 3
     assert stats["attn"]["path"] == ("xla" if form == "xla" else "walk") and stats["recoveries"] == 0
     assert stats["attn"]["fallback_steps"] == (stats["decode_steps"] if form == "xla" else 0)
@@ -107,6 +109,7 @@ def test_state_gauges_are_published(model):
     reg = registry()
     assert reg.gauge("serving.state.slots").value == 3 and reg.gauge("serving.state.leased").value == 1
     assert reg.gauge("serving.state.arena_bytes").value == eng.stats()["state"]["arena_bytes"]
+    assert reg.gauge("serving.state.arena_laid_out_bytes").value == eng.stats()["state"]["arena_laid_out_bytes"]
     eng.shutdown(drain=False)
 
 

@@ -27,7 +27,8 @@ from thunder_tpu.executors import jaxex  # noqa: E402
 from thunder_tpu.executors import pallasex as px  # noqa: E402
 from thunder_tpu.models import generate as G  # noqa: E402
 from thunder_tpu.models import llama  # noqa: E402
-from thunder_tpu.serving.kv_pool import PagedKVPool, StatePool  # noqa: E402
+from thunder_tpu.serving.kv_pool import (  # noqa: E402
+    PagedKVPool, StatePool, gather_state, pack_state_heads, scatter_state, tiled_bytes, unpack_state_heads)
 from thunder_tpu.serving.scheduler import Scheduler  # noqa: E402
 
 from _hybrid_tiny import TINY, arch, tiny_model, tokens as _tokens  # noqa: E402
@@ -275,8 +276,9 @@ def test_compiled_heads_are_padded_to_whole_lane_tiles(monkeypatch):
 
 
 def _decode_inputs(rows=5, slots=6, L=3, Hk=2, Hv=4, dk=12, dv=24):
+    """The arena as the pool lays it out, ``(slots + 1, L, dk, Hv dv)``: a matrix a head, the heads side by side."""
     ks = jax.random.split(jax.random.PRNGKey(11), 7)
-    arena = jax.random.normal(ks[0], (slots + 1, L, Hv, dk, dv))
+    arena = pack_state_heads(jax.random.normal(ks[0], (slots + 1, L, Hv, dk, dv)))
     q, k = (jax.random.normal(key, (rows, Hk, dk)) * 0.3 for key in ks[1:3])
     v = jax.random.normal(ks[3], (rows, Hv, dv))
     g = -jax.nn.softplus(jax.random.normal(ks[4], (rows, Hv)))
@@ -290,11 +292,11 @@ def test_decode_step_is_one_step_of_the_recurrence_in_place():
     before = px.stats.get("gdn_decode", 0)
     o, new = px.gdn_decode_step(arena, slots, q, k, v, g, beta, layer=1)
     assert px.stats["gdn_decode"] == before + 1
-    h0 = arena[slots, 1]
+    h0 = unpack_state_heads(arena[slots, 1], 4)
     want_o, want_S = _recurrence(*(a[:, :, None] for a in (q, k, v)), g[:, :, None], beta[:, :, None], h0)
     assert rel(o[:3], want_o[:3, :, 0]) < 1e-6
     for row, slot in enumerate([3, 1, 6]):
-        assert rel(new[slot, 1], want_S[row]) < 1e-6
+        assert rel(unpack_state_heads(new[slot, 1], 4), want_S[row]) < 1e-6
     # every other layer and slot keeps its bytes; the sink is anyone's
     untouched = np.ones(arena.shape[:2], bool)
     untouched[[3, 1, 6, 0], 1] = False
@@ -305,16 +307,82 @@ def test_decode_step_and_the_dense_caches_step_are_the_same_formulas():
     arena, q, k, v, g, beta = _decode_inputs(rows=3, Hk=2, Hv=2)
     slots = jnp.asarray([2, 5, 4], jnp.int32)
     o, new = px.gdn_decode_step(arena, slots, q, k, v, g, beta, layer=0)
-    recur, box = G.gdn_recur_dense(arena[slots, 0])
+    recur, box = G.gdn_recur_dense(unpack_state_heads(arena[slots, 0], 2))
     o2 = recur(q[:, :, None], k[:, :, None], v[:, :, None], g[:, :, None], beta[:, :, None])
     # one function, two compilations: XLA contracts a multiply-add here and not there
-    assert rel(o, o2[:, :, 0]) < 1e-6 and rel(new[slots, 0], box[0]) < 1e-6
+    assert rel(o, o2[:, :, 0]) < 1e-6 and rel(unpack_state_heads(new[slots, 0], 2), box[0]) < 1e-6
 
 
 def test_decode_step_keeps_a_bfloat16_arena_in_bfloat16():
     arena, q, k, v, g, beta = _decode_inputs()
     o, new = px.gdn_decode_step(arena.astype(jnp.bfloat16), jnp.asarray([1, 2, 3, 4, 5], jnp.int32), q, k, v, g, beta, layer=2)
     assert new.dtype == jnp.bfloat16 and o.dtype == v.dtype
+
+
+class _Ref:
+    """A kernel's ref over a ``jax.numpy`` array, so that the body runs a line at a time."""
+
+    def __init__(self, x):
+        self.x = x
+
+    shape = property(lambda self: self.x.shape)
+    dtype = property(lambda self: self.x.dtype)
+
+    def __getitem__(self, i):
+        return self.x[i]
+
+    def __setitem__(self, i, value):
+        self.x = self.x.at[i].set(value)
+
+
+# Hk, Hv, dk, dv: the cell's heads scaled down (two a group of three lane tiles), a head of half a tile (two a
+# group of one), Qwen3-Next's (a head a group, two value heads a key head), an odd count and the rehearsal's
+# (no group ends on a tile's edge: the whole width), the last with two value heads a key head too
+@pytest.mark.parametrize("Hk,Hv,dk,dv,group,arena_dtype", [
+    (6, 6, 24, 192, 2, jnp.float32), (4, 4, 16, 64, 2, jnp.float32), (2, 4, 16, 128, 1, jnp.float32),
+    (3, 3, 24, 192, 3, jnp.float32), (2, 2, 12, 24, 2, jnp.float32), (2, 4, 12, 24, 4, jnp.float32),
+    (6, 6, 24, 192, 2, jnp.bfloat16), (2, 4, 12, 24, 4, jnp.bfloat16)])
+def test_a_head_beside_its_neighbours_keeps_the_bits_of_the_step_alone(Hk, Hv, dk, dv, group, arena_dtype):
+    """The kernel's body on a row of heads side by side against ``gdn_step_math`` a
+    head at a time, ``(dk, dv)`` with its own key and query columns: the same
+    bits in ``o`` and in the state.  Both run a line at a time (``disable_jit``:
+    compiled, this CPU's XLA contracts a multiply-add in one program and not in
+    another, a last bit that is the compiler's and not the layout's)."""
+    assert px._gdn_group(Hv, dv) == group
+    f32, W, rep = jnp.float32, Hv * dv, Hv // Hk
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    heads = jax.random.normal(ks[0], (Hv, dk, dv)).astype(arena_dtype)
+    q, k = (jax.random.normal(key, (Hk, dk)) * 0.3 for key in ks[1:3])
+    v = jax.random.normal(ks[3], (Hv, dv))
+    a = jnp.exp(-jax.nn.softplus(jax.random.normal(ks[4], (Hv,))))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[5], (Hv,)))
+    lanes = lambda x: jnp.repeat(x, dv)[None, None]  # noqa: E731
+    with jax.disable_jit():
+        o, so = _Ref(jnp.zeros((1, 1, W), f32)), _Ref(jnp.zeros((1, 1, dk, W), arena_dtype))
+        px._gdn_decode_kernel(None, _Ref(q.T[None]), _Ref(k.T[None]), _Ref(v.reshape(1, 1, W)), _Ref(lanes(a)),
+                              _Ref(lanes(beta)), _Ref(pack_state_heads(heads)[None, None]), o, so, Hv=Hv, rep=rep)
+        got_S = unpack_state_heads(so.x[0, 0], Hv)
+        for h in range(Hv):
+            want_o, want_S = px.gdn_step_math(heads[h].astype(f32), k[h // rep][:, None], q[h // rep][:, None], v[h][None],
+                                              jnp.full((1, dv), a[h]), jnp.full((1, dv), beta[h]))
+            assert jnp.array_equal(o.x[0, 0, h * dv:(h + 1) * dv], want_o[0]), h
+            assert jnp.array_equal(got_S[h], want_S.astype(arena_dtype)), h
+
+
+@pytest.mark.parametrize("Hk,Hv,dk,dv", [(4, 4, 24, 192), (3, 3, 24, 192)])
+def test_the_compiled_walk_writes_its_rows_slots_and_no_other(Hk, Hv, dk, dv):
+    """The whole call under the interpreter at heads of whole and of broken
+    lane groups: the rows' slots take the recurrence's step, the sink is
+    anyone's, and every slot and layer not named keeps its bytes."""
+    arena, q, k, v, g, beta = _decode_inputs(rows=3, slots=4, L=2, Hk=Hk, Hv=Hv, dk=dk, dv=dv)
+    slots = jnp.asarray([4, 2, 0], jnp.int32)
+    o, new = px.gdn_decode_step(arena, slots, q, k, v, g, beta, layer=1)
+    want_o, want_S = _recurrence(*(a[:, :, None] for a in (q, k, v)), g[:, :, None], beta[:, :, None],
+                                 unpack_state_heads(arena[slots, 1], Hv))
+    assert rel(o[:2], want_o[:2, :, 0]) < 1e-6 and rel(unpack_state_heads(new[slots[:2], 1], Hv), want_S[:2]) < 1e-6
+    untouched = np.ones(arena.shape[:2], bool)
+    untouched[[4, 2, 0], 1] = False
+    assert jnp.array_equal(new[untouched], arena[untouched])
 
 
 # --------------------------------------------------------------------------
@@ -325,7 +393,8 @@ def test_state_pool_leases_frees_and_rebuilds(model):
     cfg, _ = model
     pool = PagedKVPool(cfg, num_blocks=8, block_size=16, dtype=jnp.float32, state_slots=3)
     st = pool.state
-    assert isinstance(st, StatePool) and st.state.shape == (4, 3, 2, 12, 24) and st.state.dtype == jnp.float32
+    assert isinstance(st, StatePool) and st.state.shape == (4, 3, 12, 2 * 24) and st.state.dtype == jnp.float32
+    assert st.state_heads == 2
     assert st.conv.shape == (4, 3, 3, 96) and pool.k_arena.shape == (8, 1, 4, 16, 12)
     assert set(pool.arenas) == {"k", "v", "state", "conv"}
     got = [st.lease(), st.lease(), st.lease()]
@@ -337,11 +406,52 @@ def test_state_pool_leases_frees_and_rebuilds(model):
         st.free(2)
     assert st.lease() == 2 and st.snapshot()["free_low_water"] == 0
     pool.set_arenas({**pool.arenas, "state": pool.arenas["state"] + 1.0})
-    assert float(pool.state.state[1, 0, 0, 0, 0]) == 1.0
+    assert float(pool.state.state[1, 0, 0, 0]) == 1.0
     pool.rebuild_arenas()
     assert float(jnp.max(jnp.abs(pool.state.state))) == 0.0 and st.leased == 3      # slots survive a rebuild
     assert st.slot_bytes() == 3 * (2 * 12 * 24 * 4 + 3 * 96 * 4)
     assert pool.state_snapshot()["state"]["slots"] == 3 and pool.occupancy_snapshot()["state"]["leased"] == 3
+
+
+def test_a_slots_state_goes_round_through_the_dense_caches_layout(model):
+    """``gather_state`` hands a slot's rows over as the dense cache keeps them, a
+    matrix a head, and ``scatter_state`` lays them side by side again: the same
+    bytes back, the other slots untouched, a fresh row zeros whatever the slot held."""
+    cfg, _ = model
+    pool = PagedKVPool(cfg, num_blocks=8, block_size=16, dtype=jnp.float32, state_slots=3)
+    heads = pool.state.state_heads
+    ks = jax.random.split(jax.random.PRNGKey(2), 2)
+    arenas = {"state": jax.random.normal(ks[0], pool.state.state.shape), "conv": jax.random.normal(ks[1], pool.state.conv.shape)}
+    slots = jnp.asarray([2], jnp.int32)
+    dense = gather_state(arenas, slots, jnp.asarray([False]), heads)
+    assert {k: v.shape for k, v in dense.items()} == G.state_shapes(cfg, 1)
+    for h in range(heads):       # head h: columns [h dv, (h + 1) dv) of the arena's row
+        assert jnp.array_equal(dense["state"][:, 0, h], arenas["state"][2, :, :, h * 24:(h + 1) * 24])
+    back = scatter_state({k: jnp.zeros_like(v) for k, v in arenas.items()}, dense, slots, heads)
+    for name in ("state", "conv"):
+        assert jnp.array_equal(back[name][2], arenas[name][2]) and not jnp.any(back[name][jnp.asarray([0, 1, 3])])
+    fresh = gather_state(arenas, slots, jnp.asarray([True]), heads)
+    assert not jnp.any(fresh["state"]) and not jnp.any(fresh["conv"])
+    assert jnp.array_equal(unpack_state_heads(pack_state_heads(dense["state"]), heads), dense["state"])
+
+
+def test_a_state_arena_counts_its_bytes_and_what_the_chip_lays_out_for_them(model):
+    """Olmo-Hybrid's row a layer, thirty heads of ``(96, 192)`` side by side, is
+    whole tiles both ways; a head a row is a third more (192 lanes lie as 256);
+    16-bit rows come in sixteens.  The pool reports both numbers."""
+    assert tiled_bytes((33, 12, 96, 5760), jnp.float32) == 33 * 12 * 96 * 5760 * 4
+    assert tiled_bytes((33, 12, 30, 96, 192), jnp.float32) * 3 == 33 * 12 * 96 * 5760 * 4 * 4
+    assert tiled_bytes((33, 12, 3, 11520), jnp.bfloat16) == 33 * 12 * 16 * 11520 * 2
+    cfg, _ = model
+    st = PagedKVPool(cfg, num_blocks=8, block_size=16, dtype=jnp.float32, state_slots=3).state
+    snap = st.snapshot()
+    assert snap["arena_bytes"] == st.arena_bytes() and snap["slot_bytes"] == st.arena_bytes() // 4
+    assert snap["arena_laid_out_bytes"] == st.laid_out_bytes("state") + st.laid_out_bytes("conv") > snap["arena_bytes"]
+    assert snap["slot_laid_out_bytes"] == snap["arena_laid_out_bytes"] // 4
+    # the tiny state, (12, 48) a row a layer: two sublane tiles by one lane tile
+    assert st.laid_out_bytes("state") == 4 * 3 * 16 * 128 * 4
+    st.install({"conv": st.conv, "state": jnp.zeros((2, 1, 30, 96, 192), jnp.float32)})     # planted: a head a row
+    assert st.laid_out_bytes("state") * 3 == int(st.state.nbytes) * 4
 
 
 def test_a_mismatched_state_arena_is_refused_at_the_swap(model):

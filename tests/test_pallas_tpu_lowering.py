@@ -192,12 +192,13 @@ def _cases(nh: int, ng: int) -> dict:
     cases["gdn_chunk_bwd/head256"] = (gdn_bwd, gdn_bwd_specs(2 * HS, BF))
     # the server's two: the scan from a state to a state, and one step a row on the
     # state arena in place, at heads of 96 and 192 (three fourths of a lane tile
-    # and one and a half), and at a bfloat16 arena
+    # and one and a half: the arena's four heads side by side are six tiles, walked
+    # two heads at a time), and at a bfloat16 arena
     cases["gdn_chunk_fwd/state"] = (
         lambda q_, k, v, g, b, h: px._gdn_fwd.__wrapped__(q_, k, v, g, b, 2, 4, Cg, 512, h0=h),
         gdn_bwd_specs(HS, BF)[1:6] + [((4, HS, HS), F32)])
     step = lambda a, s, q_, k, v, g, b: px.gdn_decode_step(a, s, q_, k, v, g, b, layer=1)  # noqa: E731
-    step_specs = lambda dt: [((5, 2, 4, 96, 192), dt), ((3,), I32), ((3, 2, 96), BF), ((3, 2, 96), BF),  # noqa: E731
+    step_specs = lambda dt: [((5, 2, 96, 4 * 192), dt), ((3,), I32), ((3, 2, 96), BF), ((3, 2, 96), BF),  # noqa: E731
                              ((3, 4, 192), BF), ((3, 4), F32), ((3, 4), F32)]
     cases["gdn_decode_step"] = (step, step_specs(F32))
     cases["gdn_decode_step/bfloat16"] = (step, step_specs(BF))
@@ -248,8 +249,9 @@ def test_the_servers_scan_and_step_compile_at_the_published_heads(kernel, tpu_sh
     """Olmo-Hybrid's delta-rule heads are 96 and 192 wide, neither a whole
     lane tile.  The scan reaches ``gdn_chunk_fwd`` padded to 128 and 256
     (zero columns: exact) instead of falling to the XLA form, at the longest
-    prefill bucket; the step takes the arena's ``(96, 192)`` tiles as they
-    are, a row's thirty heads a grid step, 32 rows of 12 layers' arena.
+    prefill bucket; the step takes a row of the arena, its thirty heads side by
+    side as ``(96, 5760)`` (45 lane tiles, no padding), a grid step, two heads at
+    a time, 32 rows of 12 layers' arena.
     Only the compile shows that Mosaic takes those blocks at these shapes and
     that XLA's call keeps the arena aliased to its result."""
     monkeypatch.setattr(px, "_interpret", lambda: False)
@@ -263,7 +265,7 @@ def test_the_servers_scan_and_step_compile_at_the_published_heads(kernel, tpu_sh
         name = "gdn_chunk_fwd"
     else:
         fn = functools.partial(px.gdn_decode_step, layer=11)
-        specs = [((33, 12, H, dk, dv), F32), ((32,), I32), ((32, H, dk), BF), ((32, H, dk), BF), ((32, H, dv), BF),
+        specs = [((33, 12, dk, H * dv), F32), ((32,), I32), ((32, H, dk), BF), ((32, H, dk), BF), ((32, H, dv), BF),
                  ((32, H), F32), ((32, H), F32)]
         name = "gdn_decode_step"
     before = dict(px.stats)

@@ -801,6 +801,43 @@ def _(micro):
     assert [len(r.new_tokens) for r in res_m] == [len(r.new_tokens) for r in res]
 
 
+#
+# the delta rule's state arena: a row's value heads side by side
+#
+
+
+@case("state_arena-two_value_heads_a_key_head_keep_solos_bits")
+def _(micro):
+    """Two value heads read one key head (Qwen3-Next's ratio; Olmo-Hybrid's is
+    one to one, ``tests/test_hybrid_engine.py``): served through the arena's
+    rows of heads side by side, in both forms of the decode program, the tokens
+    are solo ``generate()``'s; and the pool counts a slot's bytes and what the
+    chip's tiles hold for them."""
+    from _hybrid_tiny import TINY, arch
+    from chipbench import common
+    from thunder_tpu.models import generate as G
+
+    hf = {**TINY, "model_name": "serving-invariants-two-values-a-key", "linear_num_key_heads": 1, "num_hidden_layers": 2,
+          "layer_types": ["linear_attention", "full_attention"]}
+    cfg = llama.Config(**arch.program_config(hf))
+    params = arch.make_params(hf, common.seed_words(3), dtype=jnp.float32)
+    reqs = [(21, 7), (9, 6), (30, 5)]
+    prompts = [_prompt(40 + i, n, cfg) for i, (n, _) in enumerate(reqs)]
+    solo = [np.asarray(G.generate(params, p[None], cfg, n, T_max=64))[0, len(p):] for p, (_, n) in zip(prompts, reqs)]
+    for form in ("xla", "interpreted"):
+        with pytest.MonkeyPatch.context() as env:
+            set_attn_form(env, form)
+            eng = tt.serve(None, params, cfg, max_batch=2, num_blocks=24, block_size=16, prefill_buckets=[32],
+                           batch_buckets=[2], block_buckets=[4])
+            res = eng.run([{"prompt": p, "max_new_tokens": n} for p, (_, n) in zip(prompts, reqs)])
+            st = eng.stats()["state"]
+            eng.shutdown()
+        for r, want in zip(res, solo):
+            assert np.array_equal(np.asarray(r.new_tokens), want), form
+        assert eng.pool.state.state.shape == (3, 1, 12, 2 * 24) and eng.pool.state.state_heads == 2
+        assert st["arena_bytes"] == 3 * st["slot_bytes"] < st["arena_laid_out_bytes"] == 3 * st["slot_laid_out_bytes"]
+
+
 @pytest.mark.parametrize("name", list(CASES))
 def test_count_invariant(name, micro):
     CASES[name](micro)

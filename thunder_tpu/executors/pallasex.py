@@ -30,6 +30,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import math
 import os
 import types
 from typing import Callable
@@ -2919,7 +2920,13 @@ def gdn_chunk_backward(do, q, k, v, g, beta, states, chunk=GDN_CHUNK):
 # ---------------------------------------------------------------------------
 # One delta-rule step a row, on the server's state arena: ``gdn_decode_step``.
 #
-# Grid (row); a row's state, ``(Hv, dk, dv)`` of the arena's slot the
+# The arena lays a row's value heads side by side, ``(slots + 1, L_lin, dk, Hv
+# dv)``: head ``h``'s ``(dk, dv)`` matrix in columns ``[h dv, (h + 1) dv)``, the key
+# on the sublanes.  A last axis of one head pads to whole lane tiles where the
+# chip holds it (192 lanes lie as 256: a third more bytes a step and a slot);
+# thirty heads of 192 are 45 tiles exactly (``kv_pool.StatePool``).
+#
+# Grid (row); a row's state, ``(dk, Hv dv)`` of the arena's slot the
 # scalar-prefetched table names, comes into VMEM once, takes the decay, the
 # rank-one update and the read-out on the vector unit in float32 (products of
 # one row: the MXU has nothing to win), and goes back to the same place: the
@@ -2927,7 +2934,15 @@ def gdn_chunk_backward(do, q, k, v, g, beta, states, chunk=GDN_CHUNK):
 # program holds no gather, update and scatter of 2 MB a row.  Padding rows
 # name slot 0, the sink, which nobody reads.  q and k arrive a column a head,
 # ``(rows, dk, Hk)``: a head's key is then a lane of a small block and
-# broadcasts along the state's lanes without a transpose in the kernel.
+# broadcasts along the state's lanes without a transpose in the kernel; v, the
+# decay and beta arrive a row a row, ``(rows, 1, Hv dv)``, a head's number on
+# each of its lanes.  The body walks the row in groups of ``G`` heads whose
+# width is whole lane tiles (``_gdn_group``: 2 heads at a ``dv`` of 192 or 64, 1 at
+# 128; the whole width where the heads never end on a tile's edge, which is
+# the tests' tiny shapes): a group's lanes take the key and query columns of
+# their own heads by a lane mask, and then the step is ``gdn_step_math`` on the
+# ``(dk, G dv)`` tile as it is on one head's: a lane's numbers depend on its own
+# column alone, and the sums run along ``dk``.
 #   a = exp(g);  kS = k^T S;  qS = q^T S;  d = beta (v - a kS)
 #   S <- a S + k d^T;   o = a qS + (q . k) d      (= q^T of the new S)
 # ---------------------------------------------------------------------------
@@ -2937,42 +2952,66 @@ def gdn_step_math(S, kc, qc, v, a, beta):
     columns ``(dk, 1)``, ``v``, the decay ``a = exp(g)`` and ``beta`` as rows
     ``(1, dv)`` -> ``(o (1, dv), S)``.  The kernel's body and the dense cache's
     step (``models.generate``) are this one function: a served token and a
-    solo ``generate()`` token take the same formulas in the same order."""
+    solo ``generate()`` token take the same formulas in the same order.  The
+    kernel hands it several heads side by side, ``S (dk, G dv)`` with ``kc`` and
+    ``qc`` as wide (each lane its head's column): every line is a lane's own."""
     kS = jnp.sum(S * kc, axis=0, keepdims=True)
     qS = jnp.sum(S * qc, axis=0, keepdims=True)
     d = beta * (v - a * kS)
     return a * qS + jnp.sum(qc * kc, axis=0, keepdims=True) * d, a * S + kc * d
 
 
+def _gdn_group(Hv: int, dv: int) -> int:
+    """Heads a step of the decode kernel's walk: the fewest whose width is
+    whole 128-lane tiles, where the row's heads come in whole such groups;
+    else all of them, one group of the whole width."""
+    G = 128 // math.gcd(dv, 128)
+    return G if Hv % G == 0 else Hv
+
+
 def _gdn_decode_kernel(slot_ref, qT_ref, kT_ref, v_ref, a_ref, b_ref, s_ref, o_ref, so_ref, *, Hv, rep):
     del slot_ref   # the row's slot lives in the BlockSpec index maps
     f32 = jnp.float32
     qT, kT = qT_ref[0], kT_ref[0]                                     # (dk, Hk) float32
-    for h in range(Hv):
-        o, S = gdn_step_math(s_ref[0, 0, h].astype(f32), kT[:, h // rep:h // rep + 1], qT[:, h // rep:h // rep + 1],
-                             v_ref[0, h:h + 1, :].astype(f32), a_ref[0, h:h + 1, :], b_ref[0, h:h + 1, :])
-        so_ref[0, 0, h] = S.astype(so_ref.dtype)
-        o_ref[0, h:h + 1, :] = o.astype(o_ref.dtype)
+    dv = s_ref.shape[3] // Hv
+    G = _gdn_group(Hv, dv)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, G * dv), 1)
+
+    def columns(cT, first):
+        """Each lane of the group its head's column of ``cT``: ``(dk, 1)`` for one head, else ``(dk, G dv)``."""
+        col = cT[:, first // rep:first // rep + 1]
+        for j in range(1, G):
+            if (first + j) // rep != (first + j - 1) // rep:
+                col = jnp.where(lane >= j * dv, cT[:, (first + j) // rep:(first + j) // rep + 1], col)
+        return col
+
+    for first in range(0, Hv, G):
+        lanes = slice(first * dv, (first + G) * dv)
+        o, S = gdn_step_math(s_ref[0, 0, :, lanes].astype(f32), columns(kT, first), columns(qT, first),
+                             v_ref[0, :, lanes].astype(f32), a_ref[0, :, lanes], b_ref[0, :, lanes])
+        so_ref[0, 0, :, lanes] = S.astype(so_ref.dtype)
+        o_ref[0, :, lanes] = o.astype(o_ref.dtype)
 
 
 def gdn_decode_step(arena, slots, q, k, v, g, beta, *, layer: int):
     """One token a row through the gated delta rule, the state read and
-    written once, in place.  ``arena (slots + 1, L_lin, Hv, dk, dv)`` (float32,
-    or what the pool was told to store); ``slots (rows,)`` int32, 0 the sink;
-    q, k ``(rows, Hk, dk)`` as the mixer hands them (unit keys, scaled
-    queries), v ``(rows, Hv, dv)``, g (log-decay) and beta ``(rows, Hv)``
-    float32.  Returns ``(o (rows, Hv, dv) in v's dtype, arena)``."""
+    written once, in place.  ``arena (slots + 1, L_lin, dk, Hv dv)``, a row's value
+    heads side by side (float32, or what the pool was told to store); ``slots
+    (rows,)`` int32, 0 the sink; q, k ``(rows, Hk, dk)`` as the mixer hands them
+    (unit keys, scaled queries), v ``(rows, Hv, dv)``, g (log-decay) and beta
+    ``(rows, Hv)`` float32.  Returns ``(o (rows, Hv, dv) in v's dtype, arena)``."""
     stats["gdn_decode"] = stats.get("gdn_decode", 0) + 1
     rows, Hk, dk = q.shape
     Hv, dv = v.shape[1], v.shape[2]
+    W = Hv * dv
     f32 = jnp.float32
-    lanes = lambda x: jnp.broadcast_to(x.astype(f32)[:, :, None], (rows, Hv, dv))  # noqa: E731
-    row = lambda i, s: (i, 0, 0)  # noqa: E731
-    mine = lambda i, s: (s[i], layer, 0, 0, 0)  # noqa: E731
+    lanes = lambda x: _ssd_channels(x.astype(f32), dv)[:, None]  # noqa: E731 -- a head's number on each of its lanes
+    col = lambda i, s: (i, 0, 0)  # noqa: E731
+    mine = lambda i, s: (s[i], layer, 0, 0)  # noqa: E731
     kwargs = {}
     if not _interpret():
-        # a row's state in and out, double-buffered: four blocks of Hv (dk, dv) tiles
-        tile = 4 * Hv * (-(-dk // 8) * 8) * (-(-dv // 128) * 128) * 4
+        # a row's state in and out, double-buffered: four blocks of (dk, Hv dv)
+        tile = 4 * (-(-dk // 8) * 8) * (-(-W // 128) * 128) * 4
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=max(32 << 20, tile + (16 << 20)))
     o, arena = pl.pallas_call(
@@ -2980,17 +3019,17 @@ def gdn_decode_step(arena, slots, q, k, v, g, beta, *, layer: int):
         name="gdn_decode_step",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(rows,),
-            in_specs=[pl.BlockSpec((1, dk, Hk), row), pl.BlockSpec((1, dk, Hk), row),
-                      pl.BlockSpec((1, Hv, dv), row), pl.BlockSpec((1, Hv, dv), row), pl.BlockSpec((1, Hv, dv), row),
-                      pl.BlockSpec((1, 1, Hv, dk, dv), mine)],
-            out_specs=[pl.BlockSpec((1, Hv, dv), row), pl.BlockSpec((1, 1, Hv, dk, dv), mine)]),
-        out_shape=[jax.ShapeDtypeStruct((rows, Hv, dv), v.dtype), jax.ShapeDtypeStruct(arena.shape, arena.dtype)],
+            in_specs=[pl.BlockSpec((1, dk, Hk), col), pl.BlockSpec((1, dk, Hk), col),
+                      pl.BlockSpec((1, 1, W), col), pl.BlockSpec((1, 1, W), col), pl.BlockSpec((1, 1, W), col),
+                      pl.BlockSpec((1, 1, dk, W), mine)],
+            out_specs=[pl.BlockSpec((1, 1, W), col), pl.BlockSpec((1, 1, dk, W), mine)]),
+        out_shape=[jax.ShapeDtypeStruct((rows, 1, W), v.dtype), jax.ShapeDtypeStruct(arena.shape, arena.dtype)],
         input_output_aliases={6: 1},     # operands: the slot table, five small ones, the arena
         interpret=_interpret(),
         **kwargs,
     )(slots.astype(jnp.int32), jnp.swapaxes(q, 1, 2).astype(f32), jnp.swapaxes(k, 1, 2).astype(f32),
-      v, lanes(jnp.exp(g)), lanes(beta), arena)
-    return o, arena
+      v.reshape(rows, 1, W), lanes(jnp.exp(g)), lanes(beta), arena)
+    return o.reshape(rows, Hv, dv), arena
 
 
 def _gdn_full(q, k, v, g, beta):

@@ -749,6 +749,7 @@ class ServingEngine:
             st = self.pool.state
             reg0.gauge("serving.state.slots").set(st.num_slots)
             reg0.gauge("serving.state.arena_bytes").set(st.arena_bytes())
+            reg0.gauge("serving.state.arena_laid_out_bytes").set(st.laid_out_bytes())
             self._m_state_leased = reg0.gauge("serving.state.leased")
             self._m_state_low_water = reg0.gauge("serving.state.free_slots_low_water")
         if speculative is not None:
@@ -1171,8 +1172,9 @@ class ServingEngine:
         tokens, hs)`` as attention reads them (a quantised arena comes
         dequantised, at the compute dtype), or, for a latent-attention model,
         ``latent (L, tokens, latent_width)`` in their place; and, for a model with
-        linear_attention layers, its slot's ``state (L_lin, nv, dk, dv)`` and
-        ``conv (L_lin, K - 1, channels)`` as stored (conv layers: ``conv
+        linear_attention layers, its slot's ``state (L_lin, nv, dk, dv)`` (the
+        arena keeps a row's heads side by side, ``(dk, nv dv)``: they come apart
+        again) and ``conv (L_lin, K - 1, channels)`` as stored (conv layers: ``conv
         (L_conv, conv_kernel - 1, n_embd)`` alone; ssm layers: ``state (L_ssm,
         ssm_state, ssm_inner)`` and ``conv``; mamba2 layers: ``state (L_m,
         mamba_state, mamba_inner)``, a head's matrix transposed in its columns, and
@@ -1201,7 +1203,7 @@ class ServingEngine:
         out = {"tokens": req.pos, "k": k[:, 0, :, :req.pos], "v": v[:, 0, :, :req.pos]}
         if self._hybrid:
             state = self.pool.state
-            out.update({name: arena[req.state_slot] for name, arena in state.arenas.items() if name not in RING_ARENAS})
+            out.update(state.slot_rows(req.state_slot))
             if state.ring_blocks:
                 # the ring's tokens in order: the last ``layer_window`` (fewer for a
                 # shorter sequence), ``[tokens - n, tokens)``, what the last query attended
@@ -3162,6 +3164,7 @@ class ServingEngine:
         flash kernel (``sharded``)."""
         cfg, temp = self.cfg, self.temperature
         hybrid = self._hybrid
+        state_heads = self.pool.state.state_heads if hybrid else 0
         cdtype = jnp.dtype(self.pool.dtype)
         cap = self.pool.capacity_tokens(nbb)
         # a fresh program's table is as wide as its bucket; its rope table is
@@ -3188,7 +3191,7 @@ class ServingEngine:
                         held = {name: jnp.zeros(shape, arenas[name].dtype)
                                 for name, shape in state_shapes(cfg, 1).items()}
                     else:
-                        held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
+                        held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)), state_heads)
                     more = {"n_real": n_real}
             logits, cache = forward_with_cache(
                 params, toks, pos, {**dense, **held}, cos_all, sin_all, cfg,
@@ -3201,7 +3204,7 @@ class ServingEngine:
                 key, sub = jax.random.split(key)
             tok = sample_token(last, temp, sub)            # (1,) — solo-prefill parity
             with scope("mixer/cache"):
-                kept = scatter_state(arenas, cache, sslot) if hybrid else {}
+                kept = scatter_state(arenas, cache, sslot, state_heads) if hybrid else {}
             written, qerr = self._blocks_back(arenas, cache, dest, ring=(sslot, n_real) if hybrid else None)
             return tok, {**written, **kept}, key, qerr
 
@@ -3231,6 +3234,7 @@ class ServingEngine:
         chunk is strictly cheaper than a same-width prefill."""
         cfg = self.cfg
         hybrid = self._hybrid
+        state_heads = self.pool.state.state_heads if hybrid else 0
         cdtype = jnp.dtype(self.pool.dtype)
         cap = self.pool.capacity_tokens(nbb)
         cos_all, sin_all = build_rope_cache(cfg, cap)
@@ -3243,13 +3247,13 @@ class ServingEngine:
             held, more = {}, {}
             if hybrid:
                 sslot, n_real = state_args
-                held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)))
+                held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)), state_heads)
                 more = {"n_real": n_real}
             _logits, cache = forward_with_cache(
                 params, toks, pos, {**dense, **held}, cos_all, sin_all, cfg,
                 **self._fwd_kwargs(lora, slot), **more,
             )
-            kept = scatter_state(arenas, cache, sslot) if hybrid else {}
+            kept = scatter_state(arenas, cache, sslot, state_heads) if hybrid else {}
             written, qerr = self._blocks_back(arenas, cache, dest)
             return {**written, **kept}, qerr
 

@@ -48,6 +48,8 @@ Design points:
 """
 from __future__ import annotations
 
+import functools
+import math
 from collections import deque
 from typing import Sequence
 
@@ -60,6 +62,7 @@ from thunder_tpu.serving.quant import is_quantized_kv, resolve_kv_dtype
 
 __all__ = ["PoolExhaustedError", "ArenaMismatchError", "PagedKVPool", "StatePool",
            "PrefixIndex", "chunk_tables", "dest_for_pos", "gather_state", "scatter_state", "gather_rows", "pack_lanes",
+           "pack_state_heads", "unpack_state_heads", "tiled_bytes",
            "ring_tables", "ring_dest", "RING_ARENAS", "OCCUPANCY_WINDOW"]
 
 SINK_BLOCK = 0  # reserved physical block for padding/expired table entries
@@ -103,10 +106,17 @@ class StatePool:
     """What a model's linear_attention or conv layers keep a request, beside
     its KV blocks: slot-indexed arenas of what ``generate.state_shapes`` names.
     linear_attention: the delta rule's states, ``state (slots +
-    1, L_lin, nv, dk, dv)`` in float32 (``STATE_DTYPE``: no option of the
-    engine's, a deployment holds what the configuration states),
-    and the conv's last inputs, ``conv (slots + 1, L_lin, K - 1,
-    channels)`` at the compute dtype.  conv (a gated short convolution): the
+    1, L_lin, dk, nv dv)`` in float32 (``STATE_DTYPE``: no option of the
+    engine's, a deployment holds what the configuration states): a row's value
+    heads side by side, head ``h``'s ``(dk, dv)`` matrix in columns ``[h dv, (h +
+    1) dv)`` with the key on the sublanes (:func:`pack_state_heads`;
+    ``state_heads`` = ``nv``).  The layout is the pool's, as ``lane_pack`` is the
+    K/V arena's: the chip pads an array's last axis to whole 128-lane tiles, so
+    a head of 192 a row would lie as 256 (a third more bytes a slot and a decode
+    step), and thirty of them side by side are 45 tiles exactly; the dense cache
+    keeps ``(L_lin, B, nv, dk, dv)`` and :func:`gather_state` / :func:`scatter_state`
+    cross between the two.  And the conv's last inputs, ``conv (slots + 1, L_lin,
+    K - 1, channels)`` at the compute dtype.  conv (a gated short convolution): the
     tails ``conv (slots + 1, L_conv, conv_kernel - 1, n_embd)`` at the compute
     dtype, and no ``state``.  ssm (a selective scan): ``state (slots + 1, L_ssm,
     ssm_state, ssm_inner)`` in float32 and ``conv (slots + 1, L_ssm, K - 1,
@@ -135,6 +145,12 @@ class StatePool:
         shapes = state_shapes(cfg, slots + 1)
         # slot-major: a row's state is one contiguous slab a kernel can name by its slot
         self.shapes = {k: (v[1], v[0], *v[2:]) for k, v in shapes.items()}
+        # the delta rule's state, a matrix a value head: the heads side by side on the lanes
+        # (0: another kind's state, or none: the arena's rows are the dense cache's)
+        self.state_heads = 0
+        if len(self.shapes.get("state", ())) == 5:
+            n, L, nv, dk, dv = self.shapes["state"]
+            self.state_heads, self.shapes["state"] = nv, (n, L, dk, nv * dv)
         self.ring_blocks = ring_blocks(cfg, block_size)
         if self.ring_blocks:
             ring = ((slots + 1) * self.ring_blocks, *ring_block_shape(cfg, block_size, lane_pack))
@@ -201,6 +217,20 @@ class StatePool:
     def arena_bytes(self) -> int:
         return sum(int(a.nbytes) for a in self._arenas.values())
 
+    def laid_out_bytes(self, name: str | None = None) -> int:
+        """Bytes the chip holds for the arenas (for ``name`` alone), their last two
+        axes in whole tiles (:func:`tiled_bytes`): what ``arena_bytes`` counts, and the padding."""
+        return sum(tiled_bytes(a.shape, a.dtype) for k, a in self._arenas.items() if name in (None, k))
+
+    def slot_rows(self, slot: int) -> dict:
+        """What ``slot`` holds, the rings apart, a layer a row as the dense cache keeps it:
+        the delta rule's heads a matrix each again, ``state (L_lin, nv, dk, dv)``."""
+        rows = {name: arena[slot] for name, arena in self._arenas.items()
+                if name not in RING_ARENAS and not (name == "state" and self.state_heads)}
+        if self.state_heads:
+            rows["state"] = slot_state_heads(self._arenas["state"], slot, heads=self.state_heads)
+        return rows
+
     def ring_bytes(self) -> int:
         """Bytes of the ring arenas (0 without sliding_attention layers)."""
         return sum(int(self._arenas[name].nbytes) for name in RING_ARENAS if name in self._arenas)
@@ -208,16 +238,21 @@ class StatePool:
     def snapshot(self) -> dict:
         """``dtype`` is the recurrent state's storage where there is one, else the tails'.
         ``ring_*``: the window kind's table, a ring a slot; its fill is the slots leased,
-        its bytes a slot's rings (``ring_slot_bytes``) and the leased slots' (``ring_leased_bytes``)."""
+        its bytes a slot's rings (``ring_slot_bytes``) and the leased slots' (``ring_leased_bytes``).
+        ``arena_bytes`` / ``slot_bytes`` are the elements as counted, ``arena_laid_out_bytes`` /
+        ``slot_laid_out_bytes`` what the chip's tiles hold for them (:func:`tiled_bytes`)."""
         a_ring = self.ring_bytes() // (self.num_slots + 1)
         ring = ({"ring_blocks": self.ring_blocks, "ring_arena_bytes": self.ring_bytes(), "ring_slot_bytes": a_ring,
                  "ring_leased_bytes": self.leased * a_ring,
                  "ring_fill_frac": self.leased / self.num_slots} if self.ring_blocks else {})
         # a pool of rings alone (an ordinary decoder's window layers) has neither: its dtype is the rings'
         tails = self.dtypes.get("conv", self.dtypes.get(RING_ARENAS[0]))
+        laid_out = self.laid_out_bytes()
         return {**ring, "slots": self.num_slots, "leased": self.leased,
                 "free_low_water": self._free_low_water, "arena_bytes": self.arena_bytes(),
-                "slot_bytes": self.slot_bytes(), "layers": self.layers, "arenas": sorted(self.shapes),
+                "slot_bytes": self.slot_bytes(), "arena_laid_out_bytes": laid_out,
+                "slot_laid_out_bytes": laid_out // (self.num_slots + 1),
+                "layers": self.layers, "arenas": sorted(self.shapes),
                 "dtype": str(self.dtypes.get("state", tails)), "conv_dtype": str(tails),
                 "fill_frac": self.leased / self.num_slots}
 
@@ -746,25 +781,67 @@ def chunk_tables(block_table, pos: int, n_tokens: int, nbb: int,
     return table, dest
 
 
-def gather_state(arenas, slots, fresh):
+def tiled_bytes(shape, dtype) -> int:
+    """Bytes of an array as the chip lays it out: its last two axes in whole
+    tiles of ``(8, 128)`` 32-bit elements (``(16, 128)`` at 16 bits, ``(32, 128)``
+    at 8), the other axes as they are."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, r, c = shape
+    sub = 8 * max(1, 4 // item)
+    return math.prod(lead) * (-(-r // sub) * sub) * (-(-c // 128) * 128) * item
+
+
+def pack_state_heads(x):
+    """``x (..., nv, dk, dv)``, a matrix a value head, as the state arena's rows
+    ``(..., dk, nv dv)``: the heads side by side, head ``h`` in columns ``[h dv, (h
+    + 1) dv)``."""
+    *lead, nv, dk, dv = x.shape
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, dk, nv * dv)
+
+
+def unpack_state_heads(x, heads: int):
+    """The inverse of :func:`pack_state_heads`: ``(..., dk, nv dv)`` to ``(..., nv, dk, dv)``."""
+    *lead, dk, W = x.shape
+    return jnp.swapaxes(x.reshape(*lead, dk, heads, W // heads), -3, -2)
+
+
+@functools.partial(jax.jit, static_argnames="heads")
+def slot_state_heads(arena, slot, *, heads: int):
+    """One slot's rows of the delta rule's state arena, a matrix a head: ``(L_lin,
+    nv, dk, dv)``.  One program, where the same lines run eagerly are four
+    (``StatePool.slot_rows``, for ``engine.held``, which a benchmark reads that counts the programs a run built)."""
+    return unpack_state_heads(arena[slot], heads)
+
+
+def gather_state(arenas, slots, fresh, state_heads: int = 0):
     """The rows' recurrent state in the dense cache's layout
     (``generate.state_shapes``): ``{"conv": (L_lin, B, K - 1, channels),
     "state": (L_lin, B, nv, dk, dv)}`` from the slot-major arenas, zeros for
     a row that is ``fresh`` (``(B,)`` bool: its sequence starts here, and the
     slot still holds its last owner's); ``conv`` alone for a model of conv
-    layers.  Pure jnp; call inside jit."""
-    def one(arena):
-        rows = jnp.take(arena, slots, axis=0)                        # (B, L_lin, ...)
+    layers.  ``state_heads`` (``StatePool.state_heads``): the state arena's rows
+    hold that many value heads side by side, and come apart here.  Pure jnp;
+    call inside jit."""
+    def one(name):
+        rows = jnp.take(arenas[name], slots, axis=0)                 # (B, L_lin, ...)
         rows = jnp.where(fresh.reshape((-1,) + (1,) * (rows.ndim - 1)), jnp.zeros_like(rows), rows)
+        if name == "state" and state_heads:
+            rows = unpack_state_heads(rows, state_heads)
         return jnp.swapaxes(rows, 0, 1)
-    return {name: one(arenas[name]) for name in ("conv", "state") if name in arenas}
+    return {name: one(name) for name in ("conv", "state") if name in arenas}
 
 
-def scatter_state(arenas, cache, slots):
+def scatter_state(arenas, cache, slots, state_heads: int = 0):
     """The inverse of :func:`gather_state`: the rows' new state back to their
-    slots (padding rows all write the sink, slot 0).  Returns the arenas written."""
-    return {name: arenas[name].at[slots].set(jnp.swapaxes(cache[name], 0, 1).astype(arenas[name].dtype))
-            for name in ("conv", "state") if name in arenas}
+    slots (padding rows all write the sink, slot 0), the delta rule's heads
+    side by side again where the arena keeps ``state_heads`` of them a row.
+    Returns the arenas written."""
+    def one(name):
+        rows = jnp.swapaxes(cache[name], 0, 1)
+        if name == "state" and state_heads:
+            rows = pack_state_heads(rows)
+        return arenas[name].at[slots].set(rows.astype(arenas[name].dtype))
+    return {name: one(name) for name in ("conv", "state") if name in arenas}
 
 
 def ring_tables(slots, n_ring: int, width: int):

@@ -76,7 +76,7 @@ from thunder_tpu.models.generate import (
     _to_streams,
 )
 from thunder_tpu.observability.events import scope
-from thunder_tpu.serving.kv_pool import ring_tables
+from thunder_tpu.serving.kv_pool import pack_state_heads, ring_tables, unpack_state_heads
 from thunder_tpu.serving.quant import quantize_kv
 
 __all__ = ["forward_paged", "with_state", "write_fresh_kv",
@@ -152,6 +152,7 @@ def _gdn_paged(gp, x, arenas, sslots, pos, cfg, *, layer, n_real, lin):
     server keeps it.  A decode step (T = 1) runs ``gdn_decode_step`` on the
     state arena in place; a piece of a prompt (one row) runs the chunked scan
     from the slot's state, from zeros where the piece starts at position 0,
+    (the arena's rows taken apart into heads, ``kv_pool.unpack_state_heads``),
     and writes the last state back.  Returns ``(y, state arena, conv arena)``."""
     from thunder_tpu.executors import jaxex
 
@@ -169,11 +170,11 @@ def _gdn_paged(gp, x, arenas, sslots, pos, cfg, *, layer, n_real, lin):
                                                g[:, :, 0], beta[:, :, 0], layer=layer)
             return o[:, :, None]
         with scope("cache"):
-            h0 = held["state"][sslots, layer]
+            h0 = unpack_state_heads(held["state"][sslots, layer], v.shape[1])
             h0 = jnp.where(fresh[:, None, None, None], jnp.zeros_like(h0), h0)
         o, last = jaxex.gdn_chunk_state(q, k, v, g, beta, h0)
         with scope("cache"):
-            held["state"] = held["state"].at[sslots, layer].set(last)
+            held["state"] = held["state"].at[sslots, layer].set(pack_state_heads(last))
         return o
 
     y, new_tail = gdn_mixer(gp, x, tail, cfg, recur, n_real=n_real, lin=lin)

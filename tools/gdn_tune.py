@@ -5,8 +5,11 @@
 ``--serve`` times what a server runs instead, at Olmo-Hybrid's heads (30 of 96
 and 192): ``gdn_chunk_fwd`` from a state to a state at every prefill bucket
 (T 1024, 2048, 2560, one sequence) and ``gdn_decode_step`` on a 33-slot,
-12-layer arena at 32 rows; with ``--check`` both against the float32
-recurrence first.  One line: ms a call of ``gdn_chunk_fwd`` and ``gdn_chunk_bwd`` by name from a
+12-layer arena as the pool lays it out (a row's heads side by side, ``(96,
+5760)``) at 32 rows, its ms a call with the GB/s of the state's bytes as
+counted and as the chip's tiles hold them; with ``--check`` both against the
+float32 recurrence first, and the step against ``gdn_step_math`` a head at a
+time, bit for bit.  One line: ms a call of ``gdn_chunk_fwd`` and ``gdn_chunk_bwd`` by name from a
 device trace of five forward and five backward calls (B 2, 16 key and 32 value
 heads of 128, T 8192, bfloat16), of whatever else XLA runs beside them (the
 cumulative log-decay, the sum over the heads of a key head), and
@@ -25,6 +28,7 @@ import jax.numpy as jnp
 from thunder_tpu._platform import device_info
 from thunder_tpu.core.prims import GDN_CHUNK, gdn_state_stride
 from thunder_tpu.executors import pallasex as px
+from thunder_tpu.serving.kv_pool import pack_state_heads, tiled_bytes, unpack_state_heads
 from tools.flash_tune import REPS, kernel_ms
 
 # B, Hk, Hv, T, dk, dv: the two sequences of the hybrid cell's DeltaNet layers
@@ -104,23 +108,44 @@ def serve(check: bool):
     Hk, Hv, dk, dv, rows, slots, layers, buckets = SERVE
     rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))   # noqa: E731
     key = jax.random.PRNGKey(1)
-    arena = jax.random.normal(key, (slots, layers, Hv, dk, dv)) * 0.1
+    heads = jax.random.normal(key, (slots, layers, Hv, dk, dv)) * 0.1     # a matrix a head, as the dense cache keeps it
+    arena = pack_state_heads(heads)                                       # (slots, layers, dk, Hv dv): the pool's
     table = jnp.arange(1, rows + 1, dtype=jnp.int32)
     if check:
         _, q, k, v, g, beta = operands(1, Hk, Hv, 1024, dk, dv)
-        beta, h0 = 2.0 * beta, arena[1:2, 0]
+        beta, h0 = 2.0 * beta, heads[1:2, 0]
         o, last = px.gdn_chunk_state(q, k, v, g, beta, h0)
         with jax.default_matmul_precision("highest"):
             want = recurrence_from(*(x.astype(jnp.float32) for x in (q, k, v, g, beta)), h0)
             tok = [x[:, :, 0] for x in operands(rows, Hk, Hv, 1, dk, dv)[1:]]
             tok[4] = 2.0 * tok[4]
             so, sa = px.gdn_decode_step(arena, table, *tok, layer=3)
-            swant = recurrence_from(*(x.astype(jnp.float32)[:, :, None] for x in tok), arena[table, 3])
+            sa = unpack_state_heads(sa, Hv)
+            swant = recurrence_from(*(x.astype(jnp.float32)[:, :, None] for x in tok), heads[table, 3])
         worst = max(rel(o, want[0]), rel(last, want[1]), rel(so, swant[0][:, :, 0]), rel(sa[table, 3], swant[1]))
         print(f"check serve: scan o {rel(o, want[0]):.6f} last state {rel(last, want[1]):.6f}; "
               f"step o {rel(so, swant[0][:, :, 0]):.6f} state {rel(sa[table, 3], swant[1]):.6f}", flush=True)
         if worst > 0.02:
             sys.exit("gdn_tune: the compiled serving kernels disagree with the recurrence")
+        # the bits: a head alone is a group of the whole width, which is ``gdn_step_math`` on its (dk, dv) tile
+        # as Mosaic compiles it; side by side with its neighbours a head has to read the same.  Beside it XLA's
+        # own compile of the same function (the dense cache's step), which may sum along dk in another order.
+        q_, k_, v_, g_, b_ = tok
+        alone = [px.gdn_decode_step(heads[:, :, h], table, q_[:, h:h + 1], k_[:, h:h + 1], v_[:, h:h + 1], g_[:, h:h + 1],
+                                    b_[:, h:h + 1], layer=3) for h in range(Hv)]
+        same_o = bool(jnp.array_equal(so, jnp.concatenate([a[0] for a in alone], axis=1)))
+        same_s = bool(jnp.array_equal(sa, jnp.stack([a[1] for a in alone], axis=2)))
+        f32 = jnp.float32
+        col = lambda a: a.astype(f32)[..., None]  # noqa: E731
+        row = lambda a: jnp.broadcast_to(a.astype(f32)[..., None, None], (rows, Hv, 1, dv))  # noqa: E731
+        xo, xs = jax.jit(jax.vmap(jax.vmap(px.gdn_step_math)))(
+            heads[table, 3], col(k_), col(q_), v_.astype(f32)[:, :, None], row(jnp.exp(g_)), row(b_))
+        print(f"check serve: step against gdn_step_math a head at a time: o the same bits {same_o}, state the same bits "
+              f"{same_s}; against XLA's compile of it: o {bool(jnp.array_equal(so, xo[:, :, 0].astype(so.dtype)))} "
+              f"state {bool(jnp.array_equal(sa[table, 3], xs))} (largest difference "
+              f"{float(jnp.max(jnp.abs(sa[table, 3] - xs))):.3g})", flush=True)
+        if not (same_o and same_s):
+            sys.exit("gdn_tune: a head beside its neighbours does not keep the bits it has alone")
     for T in buckets:
         _, *ops = operands(1, Hk, Hv, T, dk, dv)
         fwd = jax.jit(px.gdn_chunk_state)
@@ -139,8 +164,10 @@ def serve(check: bool):
     ms = kernel_ms(once, REPS)
     own = sum(t for n, t in ms.items() if n.startswith("gdn_decode_step"))
     counted = rows * 2 * Hv * dk * dv * 4
-    print(f"serve step, {rows} rows: gdn_decode_step {own:7.3f} ms a call ({counted / own / 1e6:.1f} GB/s of state as "
-          f"counted); beside it {sum(ms.values()) - own:.3f}", flush=True)
+    laid_out = rows * 2 * tiled_bytes(arena.shape[2:], arena.dtype)
+    print(f"serve step, {rows} rows, a row {arena.shape[2:]}: gdn_decode_step {own:7.3f} ms a call ({counted / own / 1e6:.1f} "
+          f"GB/s of state as counted, {laid_out / own / 1e6:.1f} as laid out: {laid_out / counted:.3f} of the count); "
+          f"beside it {sum(ms.values()) - own:.3f}", flush=True)
 
 
 def main():

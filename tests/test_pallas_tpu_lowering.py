@@ -1657,3 +1657,92 @@ if __name__ == "__main__":      # the digests of ``GMM_DECODE_TEXT``, from the t
     print(px.__file__)
     for case_ in sorted(c for c in GMM_SHAPES if c.endswith("/decode") and GMM_SHAPES[c][5]):
         print(f'    "{case_}": "{_gmm_text_digest(_gmm_lowered(case_, False, None).as_text())}",')
+
+
+# --------------------------------------------------------------------------
+# a looped model as the Ouro-2.6B cell serves it: the slab a traced operand
+# --------------------------------------------------------------------------
+
+def test_the_walk_compiles_with_the_slab_a_traced_operand_inside_a_scan(tpu_sharding, monkeypatch):
+    """``paged_attn_decode`` at the looped cell's decode shapes (16 query heads over 16
+    KV heads of 128, 192 slabs, 12 rows of 28 blocks, the cell's pool) with the layer
+    ``t * 48 + 47``, ``t`` the carry of a ``lax.scan`` over the four passes: the walk takes
+    its layer as an operand, so the scan's body holds one kernel, Mosaic compiles it
+    and no arena is copied or sliced."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    nh, ng, rows, width, pool, slabs = 16, 16, 12, 28, 336, 192
+    arena = ((pool, slabs, ng, BS, 128), BF)
+    specs = [((rows, nh, HS), BF), arena, arena, ((rows, ng, HS), BF), ((rows, ng, HS), BF), ((rows, width), I32), ((rows,), I32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=tpu_sharding) for s, dt in specs]
+
+    def passes(q, k, v, fk, fv, tables, pos):
+        def one(h, t):
+            y = px.paged_attn_decode(h, k, v, fk, fv, tables, pos, layer=t * 48 + 47)
+            return y, None
+        return jax.lax.scan(one, q, jnp.arange(4, dtype=I32))[0]
+
+    before = px.stats.get("paged_walk", 0)
+    lowered = jax.jit(passes).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert px.stats["paged_walk"] == before + 1 and text.count('kernel_name = "paged_attn_decode"') == 1
+    assert "stablehlo.while" in text and "paged_attn_verify" not in text
+    if tpu_sharding is not None:
+        compiled = lowered.compile()
+        call = next(l for l in compiled.as_text().splitlines() if re.search(r"%paged_attn_decode(\.\d+)? = ", l))
+        assert re.search(rf"= bf16\[{rows},{ng},1,128\]\S* custom-call\(%", call), call
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20        # no slab of the arena copied out
+
+
+@functools.cache
+def _looped_engine():
+    """The Ouro-2.6B cell's engine at its published widths and its four passes, the
+    depth cut to two blocks (8 slabs), over weights that are shapes alone."""
+    import thunder_tpu as tt
+    from chipbench import common
+    from thunder_tpu.models import llama
+
+    _, config, mix = common.open_cell("ouro-serve-1chip.offline-shortqa")
+    arch = common.load_module("models", config["arch"])
+    hf = {**config, "num_hidden_layers": 2}
+    cfg = llama.Config(**arch.program_config(hf))
+    params = jax.eval_shape(functools.partial(arch.make_params, hf), common.seed_words(1))
+    return cfg, params, tt.serve(None, params, cfg, **{**config["engine"], **mix["engine"]})
+
+
+@pytest.mark.parametrize("kind", ["prefill_fresh", "decode_paged"])
+def test_the_looped_cells_programs_lower_to_their_kernels(kind, tpu_sharding, monkeypatch):
+    """The passes are one loop in each program: a whole prompt of 384 tokens attends
+    through one ``_flash_fwd`` a block of the body (two here), whatever the four passes;
+    a decode step of 12 rows x 28 blocks walks its slab through one ``paged_attn_decode``
+    body and lands all 8 slabs' K and V through one ``paged_token_write`` an arena; no
+    arena is gathered, and both return the exit rule's rows beside the token."""
+    monkeypatch.setattr(px, "_enabled", lambda: True)
+    cfg, params, eng = _looped_engine()
+    st = eng.stats()
+    assert st["attn"]["path"] == "walk" and eng.pool.k_arena.shape == (336, 8, 16, 16, 128)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=tpu_sharding)  # noqa: E731
+    one = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    weights, arenas = jax.tree_util.tree_map(sds, params), jax.tree_util.tree_map(sds, eng.pool.arenas)
+    before = dict(px.stats)
+    if kind == "prefill_fresh":
+        prog, args = _fresh_prefill(eng, weights, arenas, one, state_slots=False)(384)
+    else:
+        prog = eng._build_decode_paged(12, 28)
+        args = (weights, one((12,)), one((12,)), one((12, 28)), arenas, one((12, 2), jnp.uint32), {}, one((12,)))
+    lowered = prog.trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    claimed = lambda k: px.stats.get(k, 0) - before.get(k, 0)  # noqa: E731
+    out = jax.eval_shape(prog, *args)
+    assert out[-1].shape == ((1, 5) if kind == "prefill_fresh" else (12, 5)) and out[-1].dtype == F32
+    assert "stablehlo.while" in text and "paged_attn_verify" not in text
+    assert not re.search(r"gather[^\n]*tensor<\d+x\d+x\d+x\d+x\d+x", text)      # nothing of an arena's five dims
+    if kind == "prefill_fresh":
+        assert claimed("direct") == 2 and 'kernel_name = "_flash_fwd"' in text
+        assert {int(m) for m in re.findall(rf"tensor<1x(\d+)x{cfg.padded_vocab_size}xf32>", text)} == {1}
+    else:
+        assert text.count('kernel_name = "paged_attn_decode"') == 1 and claimed("paged_walk") == 2
+        assert 1 <= text.count('kernel_name = "paged_token_write"') <= 2
+    if tpu_sharding is not None:
+        hlo = lowered.compile().as_text()
+        for name in (("_flash_fwd",) if kind == "prefill_fresh" else ("paged_attn_decode", "paged_token_write")):
+            assert re.search(rf"%{name}(\.\d+)? = ", hlo), name

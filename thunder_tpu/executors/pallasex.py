@@ -1282,13 +1282,15 @@ def paged_attn_xla(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *, layer,
 
     B, nh, T, hs = q.shape
     G, lanes = k_arena.shape[2], k_arena.shape[4]
-    one = slice(layer, layer + 1)
+    if isinstance(layer, int):
+        one = lambda a: a[:, layer:layer + 1]  # noqa: E731
+    else:       # a looped model's slab, traced (``llama.Config.kv_slab``)
+        one = lambda a: jax.lax.dynamic_slice_in_dim(a, layer, 1, axis=1)  # noqa: E731
     if k_scale is not None:
-        kd, vd = gather_dense_q(k_arena[:, one], v_arena[:, one], k_scale[:, one], v_scale[:, one],
-                                tables, fresh_k.dtype)
+        kd, vd = gather_dense_q(one(k_arena), one(v_arena), one(k_scale), one(v_scale), tables, fresh_k.dtype)
     else:
         P = 1 if packed_out else lanes // hs
-        kd, vd = gather_rows(k_arena[:, one], tables, P), gather_rows(v_arena[:, one], tables, P)
+        kd, vd = gather_rows(one(k_arena), tables, P), gather_rows(one(v_arena), tables, P)
     if packed_out:
         assert lanes == 2 * hs, (lanes, hs)
         fresh_k, fresh_v = pair_rows(fresh_k), pair_rows(fresh_v)
@@ -1616,6 +1618,8 @@ def paged_attn_decode(q, k_arena, v_arena, fresh_k, fresh_v, tables, pos, *,
     rep = nh // (ng * P)
     assert rep * ng * P == nh, (nh, ng, P)
     path = paged_decode_path(lanes, window)
+    if path == "by_blocks" and not isinstance(layer, int):
+        path = "xla"        # a block a grid step takes the layer as a constant of its index maps; a traced one cannot be
     assert not packed_out or (P > 1 and path != "by_blocks"), "packed_out: a lane-packed arena, walked"
     stats["paged_" + path] = stats.get("paged_" + path, 0) + 1        # a call site, at trace time
     if path == "xla":

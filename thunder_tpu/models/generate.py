@@ -513,7 +513,8 @@ def cache_shape(cfg: Config, B: int, T_max: int) -> tuple[int, int, int, int, in
     """Dense KV-cache geometry ``(L, B, n_query_groups, Tc, hs)`` — the one
     layout every cache consumer (``init_cache``, the serving KV pool's
     gathered views) agrees on.  ``L`` counts the layers that keep K and V
-    (``cfg.kv_layers``): a linear_attention layer has none.  A
+    (``cfg.kv_layers``): a linear_attention layer has none; a looped model keeps
+    them a pass (``cfg.kv_slabs``, laid out by ``cfg.kv_slab``).  A
     sliding_attention layer's slots are positions, as a full_attention layer's
     beside it are (one stacked array): its window lives in the mask
     (``_attn_with_cache(layer_window=)``), and the server, which keeps such a
@@ -522,7 +523,7 @@ def cache_shape(cfg: Config, B: int, T_max: int) -> tuple[int, int, int, int, in
     tokens is the same numbers."""
     if cfg.latent:   # one row a token a layer, read by every head: ``latent`` in the cache's dict
         return (cfg.n_layer, B, 1, T_max, cfg.latent_width)
-    return (len(cfg.kv_layers), B, cfg.n_query_groups, cache_len(cfg, T_max), cfg.head_size)
+    return (cfg.kv_slabs, B, cfg.n_query_groups, cache_len(cfg, T_max), cfg.head_size)
 
 
 def state_shapes(cfg: Config, B: int) -> dict:
@@ -588,7 +589,7 @@ def kv_block_shape(cfg: Config, block_size: int, lane_pack: int = 1) -> tuple[in
     lays a narrower row out anyway and what the decode kernel's copies need."""
     if cfg.latent:
         return (cfg.n_layer, 1, block_size, -(-cfg.latent_width // 128) * 128)
-    return (len(cfg.paged_kv_layers), cfg.n_query_groups // lane_pack, block_size, cfg.head_size * lane_pack)
+    return (cfg.paged_kv_slabs, cfg.n_query_groups // lane_pack, block_size, cfg.head_size * lane_pack)
 
 
 def ring_blocks(cfg: Config, block_size: int) -> int:
@@ -1387,9 +1388,63 @@ def _to_streams(x, cfg: Config):
     return jnp.broadcast_to(x[:, None], (x.shape[0], cfg.hc_mult, *x.shape[1:])), None
 
 
+def _slab(a, j):
+    """Layer ``j`` of a stacked cache: a slice where ``j`` is a Python integer (every
+    one-pass model), a dynamic index where a looped model's pass number is traced."""
+    return a[j] if isinstance(j, int) else jax.lax.dynamic_index_in_dim(a, j, 0, keepdims=False)
+
+
+def close_pass(params, u, cfg: Config, t):
+    """What closes pass ``t`` of a looped model: the last norm, after every pass."""
+    return _norm(u, params["ln_f"], cfg, params.get("ln_f_b"))
+
+
+def exit_rule(gates, cfg: Config):
+    """A looped model's exit rule on the gates' logits ``(n_pass, ...)`` float32:
+    ``lambda_t = sigmoid(g_t)``, ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` and the
+    last pass takes what is left; a row exits at the first pass whose cumulative
+    ``p`` reaches ``cfg.exit_threshold``, at the last where none does.  Returns
+    ``(pass (...) int32, p (n_pass, ...))``."""
+    lam = jax.nn.sigmoid(gates)
+    stay = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])      # prod_{j<t} (1 - lambda_j)
+    p = jnp.concatenate([(lam * before)[:-1], before[-1:]])
+    reached = jnp.cumsum(p, axis=0) >= cfg.exit_threshold
+    last = gates.shape[0] - 1
+    return jnp.where(jnp.any(reached, axis=0), jnp.argmax(reached, axis=0), last).astype(jnp.int32), p
+
+
+def run_passes(params, x, cfg: Config, blocks, row=None):
+    """A looped model's passes over the embedding ``x (B, T, C)``: ``blocks(h, t) ->
+    (u, kept)`` (the stack on ``h`` with pass ``t``'s slabs of the cache, ``t`` traced)
+    is the body of one ``lax.scan``, so the program holds the blocks once whatever
+    ``cfg.n_pass``; :func:`close_pass` closes every pass and its output opens the
+    next; the exit gate reads each closed state (of row ``row`` alone where given)
+    and :func:`exit_rule` picks, a row, the pass whose state the head reads.  Every
+    pass runs whatever the rule says: later tokens attend this token's K/V of every
+    pass.  Returns ``(closed state (B, T or 1, C), kept stacked (n_pass, ...),
+    (pass chosen (B, T or 1), exit probabilities (n_pass, B, T or 1)))``."""
+    gate = params["exit_gate"]
+
+    def one(h, t):
+        u, kept = blocks(h, t)
+        with scope("head/norm"):
+            h = close_pass(params, u, cfg, t)
+        with scope("head/gate"):
+            r = h if row is None else jax.lax.dynamic_slice_in_dim(h, row, 1, axis=1)
+            g = r.astype(jnp.float32) @ gate["w"].astype(jnp.float32) + gate["b"].astype(jnp.float32)
+        return h, (kept, r, g)
+
+    _, (kept, rows, gates) = jax.lax.scan(one, x, jnp.arange(cfg.n_pass, dtype=jnp.int32))
+    with scope("head/exit"):
+        chosen, p = exit_rule(gates, cfg)
+        x = jnp.take_along_axis(rows, chosen[None, ..., None], axis=0)[0]
+    return x, kept, (chosen, p)
+
+
 def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *,
                        quantized=False, lora=None, lora_scaling=1.0, n_real=None, logits_at=None,
-                       sharded=False):
+                       sharded=False, exits=None):
     """Forward of new tokens ``idx`` (B, T) at global positions [pos, pos+T)
     against/into ``cache``.  Returns (logits (B, T, V), updated cache); with
     ``logits_at`` (an index into the T tokens, traced or not) the head runs on
@@ -1410,7 +1465,11 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
     ``{target: {"a": (B, L, r, fin), "b": (B, L, fout, r)}}`` with one
     adapter per batch row (the layout
     :func:`serving.lora.gather_adapter_slots` produces); the delta
-    ``lora_scaling * B(A(x))`` lands next to each target's matmul."""
+    ``lora_scaling * B(A(x))`` lands next to each target's matmul.
+
+    A looped model (``cfg.n_pass`` > 1, :func:`run_passes`): the cache holds a
+    slab a layer a pass (``cfg.kv_slab``), and ``exits`` (a list) takes the exit
+    rule's ``(pass chosen, exit probabilities)`` of the rows the head read."""
     B, T = idx.shape
     vec = _is_vec_pos(pos)
     with scope("embed"):        # the tokens' rows, and their positions' rows of the rope tables
@@ -1432,88 +1491,107 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
             cos_t = jax.lax.dynamic_slice_in_dim(cos_all, pos, T, axis=0)
             sin_t = jax.lax.dynamic_slice_in_dim(sin_all, pos, T, axis=0)
 
-    new_k, new_v, new_conv, new_state, new_latent = [], [], [], [], []
     lin = partial(_linear, quantized=quantized)
     # a model with a cross half (``cfg.cross_from``) whose caller wants one row's
     # logits runs that half on the one row: the layer the cross layers read
     # projects K and V on every position and its query on the row; the layers
     # after it keep no cache and see the row alone.  Exact, and half a prompt's products
     one_row = logits_at is not None and cfg.cross_from is not None
-    gmu_m = None
-    for l, bp in enumerate(params["blocks"]):
-        lora_l = None
-        if lora:
-            lora_l = {t: (ab["a"][:, l], ab["b"][:, l]) for t, ab in lora.items()}
-        kind = cfg.layer_kind(l)
-        if kind == "mlp":       # the layer is its feed-forward alone: no mixer, no cache
+
+    def blocks(x, t):
+        """The stack of blocks on ``x``, once: pass ``t`` of a looped model (its slabs of the
+        cache, ``cfg.kv_slab``; traced there), the Python integer 0 for every other.  Returns the
+        stream and what each kind of layer keeps, a list a kind."""
+        new_k, new_v, new_conv, new_state, new_latent = [], [], [], [], []
+        gmu_m = None
+        for l, bp in enumerate(params["blocks"]):
+            lora_l = None
+            if lora:
+                lora_l = {name: (ab["a"][:, l], ab["b"][:, l]) for name, ab in lora.items()}
+            kind = cfg.layer_kind(l)
+            if kind == "mlp":       # the layer is its feed-forward alone: no mixer, no cache
+                with scope(f"blk{l}"):
+                    x = _close_block(bp, x, None, None, cfg, quantized=quantized)
+                continue
             with scope(f"blk{l}"):
-                x = _close_block(bp, x, None, None, cfg, quantized=quantized)
-            continue
-        with scope(f"blk{l}"):
-            with scope("mixer"):
-                # OLMo's blocks norm what a sublayer gives, not what it takes
-                # under hyper-connections the sublayer reads a mixture of the streams
-                x, u, hc = hc_step(bp["hc_1"], x, cfg, sharded=sharded) if cfg.hc_mult > 1 else (x, x, None)
-                if cfg.post_sublayer_norm:
-                    n1 = u
-                else:
-                    with scope("norm"):     # (under hyper-connections ``u`` is float32: rounded here)
-                        n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b")).astype(x.dtype)
-                if kind == "mamba2":
-                    j = len(new_state)
-                    recur, box = mamba2_recur_dense(cache["state"][j])
-                    h, tail = mamba2_mixer(bp["mamba2"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
-                    new_conv.append(tail)
-                    new_state.append(box[0])
-                elif kind == "ssm":
-                    j = len(new_state)
-                    recur, box = ssm_recur_dense(cache["state"][j])
-                    h, tail, m = ssm_mixer(bp["ssm"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
-                    new_conv.append(tail)
-                    new_state.append(box[0])
-                    if l == cfg.gmu_source:
-                        gmu_m = m
-                elif kind == "gmu":
-                    h = gmu_mixer(bp["gmu"], n1, gmu_m, lin=lin)
-                elif cfg.diff_attention:
-                    j = len(new_k) if kind != "cross_attention" else cfg.kv_layers.index(cfg.cross_from)
-                    src = cache if kind != "cross_attention" else {"k": new_k, "v": new_v}
-                    narrow = one_row and l == cfg.cross_from
-                    h, ck, cv = _diff_attn_with_cache(
-                        bp["attn"], n1, l, src["k"][j], src["v"][j], pos, cfg, kind=kind, lin=lin, sharded=sharded,
-                        row=logits_at if narrow or (one_row and kind == "cross_attention") else None)
-                    if kind != "cross_attention":
+                with scope("mixer"):
+                    # OLMo's blocks norm what a sublayer gives, not what it takes
+                    # under hyper-connections the sublayer reads a mixture of the streams
+                    x, u, hc = hc_step(bp["hc_1"], x, cfg, sharded=sharded) if cfg.hc_mult > 1 else (x, x, None)
+                    if cfg.post_sublayer_norm:
+                        n1 = u
+                    else:
+                        with scope("norm"):     # (under hyper-connections ``u`` is float32: rounded here)
+                            n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b")).astype(x.dtype)
+                    if kind == "mamba2":
+                        j = len(new_state)
+                        recur, box = mamba2_recur_dense(cache["state"][j])
+                        h, tail = mamba2_mixer(bp["mamba2"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
+                        new_conv.append(tail)
+                        new_state.append(box[0])
+                    elif kind == "ssm":
+                        j = len(new_state)
+                        recur, box = ssm_recur_dense(cache["state"][j])
+                        h, tail, m = ssm_mixer(bp["ssm"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
+                        new_conv.append(tail)
+                        new_state.append(box[0])
+                        if l == cfg.gmu_source:
+                            gmu_m = m
+                    elif kind == "gmu":
+                        h = gmu_mixer(bp["gmu"], n1, gmu_m, lin=lin)
+                    elif cfg.diff_attention:
+                        j = len(new_k) if kind != "cross_attention" else cfg.kv_layers.index(cfg.cross_from)
+                        src = cache if kind != "cross_attention" else {"k": new_k, "v": new_v}
+                        narrow = one_row and l == cfg.cross_from
+                        h, ck, cv = _diff_attn_with_cache(
+                            bp["attn"], n1, l, src["k"][j], src["v"][j], pos, cfg, kind=kind, lin=lin, sharded=sharded,
+                            row=logits_at if narrow or (one_row and kind == "cross_attention") else None)
+                        if kind != "cross_attention":
+                            new_k.append(ck)
+                            new_v.append(cv)
+                        if narrow:      # from here on the row alone
+                            x, n1 = (jax.lax.dynamic_slice_in_dim(a, logits_at, 1, axis=1) for a in (x, n1))
+                            gmu_m = None if gmu_m is None else jax.lax.dynamic_slice_in_dim(gmu_m, logits_at, 1, axis=1)
+                    elif kind == "linear_attention":
+                        j = len(new_state)
+                        recur, box = gdn_recur_dense(cache["state"][j])
+                        h, tail = gdn_mixer(bp["gdn"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
+                        new_conv.append(tail)
+                        new_state.append(box[0])
+                    elif kind == "conv":
+                        h, tail = shortconv_mixer(bp["conv"], n1, cache["conv"][len(new_conv)], cfg,
+                                                  n_real=n_real, lin=lin)
+                        new_conv.append(tail)
+                    elif cfg.latent:
+                        h, cl = _mla_with_cache(bp["attn"], n1, cos_t, sin_t, cache["latent"][l], pos, cfg,
+                                                quantized=quantized, sharded=sharded)
+                        new_latent.append(cl)
+                    else:
+                        j = cfg.kv_slab(t, len(new_k))
+                        h, ck, cv = _attn_with_cache(
+                            bp["attn"], n1, cos_t, sin_t, _slab(cache["k"], j), _slab(cache["v"], j), pos, cfg,
+                            quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, sharded=sharded,
+                            layer_window=cfg.layer_window if kind == "sliding_attention" else None, rope=cfg.rotates(l),
+                        )
                         new_k.append(ck)
                         new_v.append(cv)
-                    if narrow:      # from here on the row alone
-                        x, n1 = (jax.lax.dynamic_slice_in_dim(a, logits_at, 1, axis=1) for a in (x, n1))
-                        gmu_m = None if gmu_m is None else jax.lax.dynamic_slice_in_dim(gmu_m, logits_at, 1, axis=1)
-                elif kind == "linear_attention":
-                    j = len(new_state)
-                    recur, box = gdn_recur_dense(cache["state"][j])
-                    h, tail = gdn_mixer(bp["gdn"], n1, cache["conv"][j], cfg, recur, n_real=n_real, lin=lin)
-                    new_conv.append(tail)
-                    new_state.append(box[0])
-                elif kind == "conv":
-                    h, tail = shortconv_mixer(bp["conv"], n1, cache["conv"][len(new_conv)], cfg,
-                                              n_real=n_real, lin=lin)
-                    new_conv.append(tail)
-                elif cfg.latent:
-                    h, cl = _mla_with_cache(bp["attn"], n1, cos_t, sin_t, cache["latent"][l], pos, cfg,
-                                            quantized=quantized, sharded=sharded)
-                    new_latent.append(cl)
-                else:
-                    j = len(new_k)
-                    h, ck, cv = _attn_with_cache(
-                        bp["attn"], n1, cos_t, sin_t, cache["k"][j], cache["v"][j], pos, cfg,
-                        quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, sharded=sharded,
-                        layer_window=cfg.layer_window if kind == "sliding_attention" else None, rope=cfg.rotates(l),
-                    )
-                    new_k.append(ck)
-                    new_v.append(cv)
-            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, hc=hc,
-                             sharded=sharded)
+                x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling, hc=hc,
+                                 sharded=sharded)
+        return x, (new_k, new_v, new_conv, new_state, new_latent)
 
+    if cfg.n_pass > 1:      # a looped model: the stack as the body of one loop over the passes
+        def kept(h, t):
+            u, (new_k, new_v, *_) = blocks(h, t)
+            with scope("mixer/cache"):
+                return u, (jnp.stack(new_k), jnp.stack(new_v))
+
+        x, (ks, vs), exit_ = run_passes(params, x, cfg, kept, row=logits_at)
+        if exits is not None:
+            exits.append(exit_)
+        with scope("mixer/cache"):      # (n_pass, L, ...) is the slabs' own order
+            cache = {"k": ks.reshape(-1, *ks.shape[2:]), "v": vs.reshape(-1, *vs.shape[2:])}
+        return _head_logits(params, x, cfg, None, quantized, sharded=sharded, closed=True), cache
+    x, (new_k, new_v, new_conv, new_state, new_latent) = blocks(x, 0)
     with scope("mixer/cache"):
         cache = {"latent": jnp.stack(new_latent)} if cfg.latent else {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
         if new_conv:
@@ -1523,9 +1601,10 @@ def forward_with_cache(params, idx, pos, cache, cos_all, sin_all, cfg: Config, *
     return _head_logits(params, x, cfg, None if one_row else logits_at, quantized, sharded=sharded), cache
 
 
-def _head_logits(params, x, cfg: Config, logits_at, quantized, *, sharded=False):
+def _head_logits(params, x, cfg: Config, logits_at, quantized, *, sharded=False, closed=False):
     """The last norm and the logits in float32, of row ``logits_at`` alone where
-    given.  Under hyper-connections ``x`` comes with the last sublayer's close
+    given (``closed``: a looped model's state, which its pass's norm has closed
+    already).  Under hyper-connections ``x`` comes with the last sublayer's close
     still owed (:func:`hc_step`): the row is cut out of the stream, of what the
     sublayer gave and of its maps first, so a prompt's last close is one row's;
     then the streams are summed (arXiv:2409.19606)."""
@@ -1538,10 +1617,11 @@ def _head_logits(params, x, cfg: Config, logits_at, quantized, *, sharded=False)
             x = hc_step(None, (x, (f, maps)), cfg, sharded=sharded)[0]
         with scope("head/norm"):
             x = jnp.sum(x.astype(jnp.float32), axis=1).astype(x.dtype)
-    with scope("head/norm"):
-        x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
-        if logits_at is not None:
-            x = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
+    if not closed:
+        with scope("head/norm"):
+            x = _norm(x, params["ln_f"], cfg, params.get("ln_f_b"))
+            if logits_at is not None:
+                x = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
     with scope("head/logits"):
         head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
         return (_linear(x, head, params.get("lm_head_b"), quantized=quantized)).astype(jnp.float32)

@@ -263,6 +263,15 @@ class Config:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: tuple = (-30.0, 30.0)
+    # A looped model (arXiv:2510.25741; in the server alone), on where ``n_pass`` >
+    # 1: the stack of ``n_layer`` blocks runs ``n_pass`` times over one set of
+    # weights.  The last norm closes *every* pass and its output opens the next;
+    # layer ``l`` of pass ``t`` keeps K and V of its own (``kv_slab``: a cache a
+    # pass); an exit gate ``sigmoid(w_g . h + b_g)`` reads each pass's closed state
+    # and the head reads, a token, the first pass whose cumulative exit
+    # probability reaches ``exit_threshold`` (the last pass where none does)
+    n_pass: int = 1
+    exit_threshold: float = 1.0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling_llama3, dict):
@@ -365,6 +374,12 @@ class Config:
         if self.sandwich_norm:
             assert not (self.parallel_residual or self.post_sublayer_norm or self.shared_attention_norm or self.bias
                         or self.single_sublayer), "sandwich_norm: sequential bias-free blocks, a norm before and after each sublayer"
+        assert self.n_pass >= 1, self.n_pass
+        if self.n_pass > 1:
+            assert self.layer_types is None and not (self.latent or self.hc_mult > 1 or self.sliding_window
+                                                     or self.learned_pos_embedding or self.tie_embeddings), (
+                "n_pass > 1: a stack of full_attention blocks over K and V (no layer kinds, latent cache, "
+                "hyper-connections, model-wide window, learned positions or tied head has a looped form)")
         assert not (self.qk_norm and self.qk_norm_whole), "qk_norm norms a head, qk_norm_whole the projection: one of them"
         if self.post_sublayer_norm:
             assert not self.parallel_residual and not self.shared_attention_norm, (
@@ -439,6 +454,24 @@ class Config:
         model layer to its layer of the server's K/V cache and arenas
         (``kv_layers.index(i)``).  Every layer of a model without ``layer_types``."""
         return tuple(i for i in range(self.n_layer) if self.layer_kind(i) in ("full_attention", "sliding_attention"))
+
+    def kv_slab(self, t, l):
+        """The one map from (pass ``t``, K/V layer ``l``: ``kv_layers.index`` of the model
+        layer) to its layer of the server's K/V cache and arenas: a cache a pass,
+        the passes one after another.  ``t`` may be a traced value; a one-pass
+        model's slab is its layer."""
+        return t * len(self.kv_layers) + l
+
+    @property
+    def kv_slabs(self) -> int:
+        """Layers of the dense K/V cache: one a layer that keeps K and V, a pass."""
+        return self.n_pass * len(self.kv_layers)
+
+    @property
+    def paged_kv_slabs(self) -> int:
+        """Layers of the paged K/V arenas: ``kv_slabs`` less the sliding_attention
+        layers, whose K and V live in the ring arenas (no looped model has one)."""
+        return self.kv_slabs - len(self.ring_layers)
 
     @property
     def ring_layers(self) -> tuple:
@@ -645,6 +678,12 @@ configs: list[Config] = [
     Config(name="Mixtral-8x7B-like", block_size=32768, vocab_size=32000, n_layer=32,
            n_head=32, n_embd=4096, n_query_groups=8, intermediate_size=14336,
            rope_base=1000000, mlp_class="LLaMAMoE", n_expert=8, n_expert_per_token=2),
+    # a looped model (hf ByteDance/Ouro-2.6B config.json): 48 blocks with a norm on both sides of
+    # each sublayer, run total_ut_steps = 4 times over one set of weights, a K/V cache a pass, an
+    # exit gate on each pass's closed state (early_exit_threshold 1: the last pass unless a gate saturates)
+    Config(name="Ouro-2.6B", block_size=65536, vocab_size=49152, padded_vocab_size=49152, n_layer=48, n_head=16,
+           n_embd=2048, head_size=128, n_query_groups=16, intermediate_size=5632, norm_eps=1e-6,
+           rope_base=1000000, sandwich_norm=True, n_pass=4, exit_threshold=1.0),
 ]
 name_to_config: dict[str, Config] = {c.name: c for c in configs}
 
@@ -694,6 +733,9 @@ def init_params(config: Config, key: jax.Array | None = None, dtype=jnp.bfloat16
         params["wpe"] = (jax.random.normal(next(keys), (config.block_size, config.n_embd),
                                            dtype=jnp.float32) * std).astype(dtype)
 
+    if config.n_pass > 1:       # the exit gate on a pass's closed state: Linear(n_embd, 1) with a bias
+        params["exit_gate"] = {"w": dense(jax.random.fold_in(key, config.n_pass), config.n_embd, 1)[0],
+                               "b": jnp.zeros((), dtype=dtype)}
     # a zero-centred norm weight starts at 0 (scale 1 + w = 1)
     norm_init = jnp.zeros if config.norm_zero_centered else jnp.ones
     if config.norm_zero_centered:
@@ -1182,8 +1224,13 @@ def serving_only(config: Config) -> str | None:
     single-sublayer blocks, the window kind of an ordinary decoder with its
     rotation a layer kind and its norms on both sides of a sublayer, the sigmoid
     routers, a router that reads the block's input, leading dense layers, the
-    latent ungated expert share, the gated-ReLU experts and a stream under
-    hyper-connections are built in ``models.generate`` for the server alone."""
+    latent ungated expert share, the gated-ReLU experts, a stream under
+    hyper-connections and a stack run ``n_pass`` times are built in
+    ``models.generate`` for the server alone."""
+    if config.n_pass > 1:
+        return ("n_pass > 1 (a looped model: the stack run n_pass times over one set of weights, the last norm "
+                "closing every pass, and the exit gate are built in models.generate, for tt.serve; the trainer "
+                "has no backward pass through the loop and no source for the published objective)")
     if config.conv_layers:
         return ("layer_types with 'conv' (the gated short convolution is built in models.generate, for tt.serve, "
                 "and has no traced form)")

@@ -291,7 +291,23 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
     by what the ring lacks.  A
     decoder-hybrid-decoder (``cfg.hybrid_decoder``) has its paged decode
     program and whole-prompt prefills alone, and refuses what needs another;
-    so does a model with mamba2 layers, whose paged forward steps one token a row."""
+    so does a model with mamba2 layers, whose paged forward steps one token a row.
+    A looped model (``cfg.n_pass`` > 1) keeps no state but a K/V slab a layer a
+    pass, the slab a traced operand of the loop's one body: what takes the layer
+    as a constant, or was not carried through the loop, is refused here too."""
+    if getattr(cfg, "n_pass", 1) > 1:
+        if speculative is not None:
+            return ("speculative= is unsupported: a draft's verify attends several tokens a row through a kernel "
+                    "whose index maps take the layer as a constant, and a looped model's slab is traced")
+        if lora is not None:
+            return ("lora= is unsupported: the adapter arenas hold a delta a layer, and whether a pass shares "
+                    "its layer's adapter with the other passes is not carried through the loop")
+        if mesh is not None:
+            return "mesh= is unsupported: the loop's traced slab is not carried into the walk's shard_map under a tp axis"
+        if sessions is not None and sessions is not False:
+            return ("sessions= is unsupported: a parked session's re-attach has not been carried through the loop "
+                    "(its prefix holds a slab a pass)")
+        return None
     if getattr(cfg, "ring_layers", ()) and not getattr(cfg, "state_layers", ()):
         # an ordinary decoder whose window layers keep a ring a request and nothing else
         if kv_dtype is not None:
@@ -356,6 +372,13 @@ def hybrid_unsupported(cfg, *, prefix_sharing=None, sessions=None, speculative=N
         return ("a sliding window is unsupported beside a recurrent state or conv tail "
                 "(block expiry is untested with it)")
     return None
+
+@scope("head/exit")
+def _exit_rows(chosen, p):
+    """A looped model's exit rule a row as one array ``(B, 1 + n_pass)`` float32: the pass
+    chosen, then the exit probabilities (``generate.exit_rule``'s ``(B,)`` and ``(n_pass, B)``)."""
+    return jnp.concatenate([chosen[:, None].astype(jnp.float32), p.T], axis=1)
+
 
 def latent_unsupported(cfg, *, kv_dtype=None, cache_dtype=None, speculative=None, lora=None, mesh=None) -> str | None:
     """Why an engine with these options cannot serve a latent-attention config
@@ -432,19 +455,22 @@ class ServingEngine:
         # request beside its KV, one with conv layers a conv tail; what such a
         # state cannot serve yet refuses here, with its reason
         self._hybrid = bool(getattr(cfg, "keeps_slot", False))
-        if self._hybrid:
+        self._passes = getattr(cfg, "n_pass", 1)
+        if self._hybrid or self._passes > 1:
             why = hybrid_unsupported(
                 cfg, prefix_sharing=prefix_sharing, sessions=sessions, speculative=speculative,
                 lora=lora, mesh=mesh, kv_dtype=kv_dtype,
                 prefill_chunk=prefill_chunk, priorities=priorities, fault_plan=fault_plan)
             if why:
-                kind = ("linear_attention layers (a recurrent state a request)" if cfg.linear_layers
+                kind = (f"a stack run n_pass = {cfg.n_pass} times (a K/V slab a layer a pass)" if self._passes > 1
+                        else "linear_attention layers (a recurrent state a request)" if cfg.linear_layers
                         else "conv layers (a conv tail a request)" if cfg.conv_layers
                         else "mamba2 layers (a Mamba-2 scan's matrix state a head a request)" if cfg.mamba2_layers
                         else "ssm layers (a selective scan's state a request) beside per-kind K/V" if cfg.ssm_layers
                         else "sliding_attention layers (a ring of layer_window tokens a request)")
                 raise NotImplementedError(f"config {getattr(cfg, 'name', '?')!r} has {kind}: {why}")
-            prefix_sharing = False
+            if self._hybrid:
+                prefix_sharing = False
         # per-kind caches (a ring a slot for window layers, one layer's blocks read
         # by the cross layers): the paged decode program and whole prompts alone
         self._perkind = bool(getattr(cfg, "hybrid_decoder", False) or getattr(cfg, "ring_layers", ()))
@@ -513,6 +539,13 @@ class ServingEngine:
         # a model with window layers: the keys a decode step's rows attend, a layer of each kind
         self._attended = ({"steps": 0, "full_attention": 0, "sliding_attention": 0}
                           if getattr(cfg, "ring_layers", ()) else None)
+        # a looped model: the served tokens by the pass the exit rule chose and their exit
+        # probabilities summed (both harvested with the tokens), and the keys its decode steps'
+        # rows attended, a layer of a pass and once a slab-walk (stats()["passes"], ["attn"])
+        self._pass_sums = None
+        if self._passes > 1:
+            self._pass_sums = {"exit": [0] * self._passes, "exit_mass": np.zeros(self._passes, np.float64)}
+            self._attended = {"steps": 0, "full_attention": 0}
         arena = self.pool.k_arena
         _, _, ng, bs, lanes = arena.sharding.shard_shape(arena.shape)
         self._attn_path = decode_path(cfg, mesh, arena_lanes=lanes)     # a latent arena: mla_paged_decode's walk
@@ -614,6 +647,8 @@ class ServingEngine:
             chunk_why = "sliding-window keep-mask is decode-only"
         elif self._latent:
             chunk_why = "a latent cache's piece attends its expanded keys (the dense form)"
+        elif self._passes > 1:
+            chunk_why = "a looped model's slab is a traced operand: a piece attends its gathered keys (the dense form)"
         elif self.pool.lane_pack > 1:
             chunk_why = (f"a lane-packed arena ({self.pool.lane_pack} KV heads a row) has no multi-query "
                          "kernel: a chunk attends its gathered keys")
@@ -1165,7 +1200,7 @@ class ServingEngine:
         if not handle.done():
             self._finish(handle._req, FINISH_EVICTED)
 
-    def held(self, handle: RequestHandle) -> dict:
+    def held(self, handle: RequestHandle, layers: Sequence[int] | None = None) -> dict:
         """What the caches hold of a running request, read once nothing is
         in flight (an async engine harvests first): ``tokens``, how many of
         its prompt and generated tokens went in; ``k`` and ``v`` ``(L_kv, ng,
@@ -1183,7 +1218,9 @@ class ServingEngine:
         layers: ``k``/``v`` are the full_attention layers' and ``k_ring``/``v_ring
         (L_ring, ng, n, hs)`` the window layers' last ``n = min(tokens,
         layer_window)`` tokens, in order.  A lane-packed arena's
-        heads come apart again.  For the tests and for a
+        heads come apart again.  ``layers``: the layers of the paged K/V arenas to
+        return, in that order (all of them where None): a looped model's 192 slabs
+        of a request do not fit beside its arena.  For the tests and for a
         comparison with a reference; changes nothing."""
         if self.async_step:
             self._harvest()
@@ -1191,6 +1228,9 @@ class ServingEngine:
         if req.state != "running":
             raise RuntimeError(f"request {req.rid} is {req.state}: the caches hold a running request only")
         arenas = self.pool.arenas
+        if layers is not None:      # a slice a layer: an index array would gather the arena through a copy of it
+            pick = lambda a: jnp.stack([jax.lax.index_in_dim(a, l, axis=1, keepdims=False) for l in layers], axis=1)  # noqa: E731
+            arenas = {name: pick(a) if name in ("k", "v", "k_scale", "v_scale") else a for name, a in arenas.items()}
         table = jnp.asarray([req.block_table[:self.pool.blocks_for_tokens(req.pos)]], dtype=jnp.int32)
         if self._latent:     # (L, tokens, latent_width): the rows without their lane padding
             rows = gather_rows(arenas["latent"], table)
@@ -1325,6 +1365,13 @@ class ServingEngine:
             "overlap_frac_mean": (self._overlap_frac_sum / n) if n else None,
             "compile_counts": dict(self.compile_counts),
             **({"hc": self._hc_counts()} if self.cfg.hc_mult > 1 else {}),
+            # a looped model: decode steps, the layers they applied (steps x passes x layers), the
+            # served tokens by the pass the exit rule chose and their exit probabilities summed
+            **({"passes": {"steps": self.decode_steps,
+                           "layer_passes": self.decode_steps * self._passes * self.cfg.n_layer,
+                           "exit": list(self._pass_sums["exit"]),
+                           "exit_mass": [float(m) for m in self._pass_sums["exit_mass"]]}}
+               if self._pass_sums is not None else {}),
             "attn": {
                 # decode steps whose attention call took the XLA form (every one
                 # or none: the form is the engine's, ``path``)
@@ -1346,8 +1393,11 @@ class ServingEngine:
                     "prefill_cross_rows": self.prefill_fresh_runs}
                    if self._perkind and self.cfg.cross_from is not None else {}),
                 # window layers: the keys the decode steps' rows attended, summed, in a
-                # layer of each kind (``min(pos + 1, layer_window)`` a row against ``pos + 1``)
-                **({"attended_tokens": dict(self._attended)} if self._attended is not None else {}),
+                # layer of each kind (``min(pos + 1, layer_window)`` a row against ``pos + 1``);
+                # a looped model: in a layer of a pass, and once a slab-walk (every layer of every pass)
+                **({"attended_tokens": {**self._attended, **({"slab_walks": self._attended["full_attention"] * self.cfg.kv_slabs}
+                                                              if self._passes > 1 else {})}}
+                   if self._attended is not None else {}),
             },
             "bucket_bound": kinds * len(self._table_widths) + (
                 len(sch.prefill_buckets) if self.spec is None else 0),
@@ -1802,10 +1852,11 @@ class ServingEngine:
                          if req.constraint is not None
                          else self._ones_mask((1, self._vocab)),)
             with first_call:
-                tok, arenas, key, qerr = prog(*args)
+                tok, arenas, key, qerr, *exit_rows = prog(*args)
             rec = {"kind": "prefill", "req": req, "tok": tok, "key": key,
                    "qerr": qerr, "compiled": compiled, "span": name,
-                   "epoch": req.preemptions, "t_clock": sch.clock()}
+                   "epoch": req.preemptions, "t_clock": sch.clock(),
+                   **({"exit": exit_rows[0]} if exit_rows else {})}
         elif self.spec is not None:
             with first_call:
                 arenas, darenas, qerr = prog(
@@ -1917,6 +1968,8 @@ class ServingEngine:
                 tr.end(req.rid, "prefill.host")
                 tr.end(req.rid, "prefill", compile=req.prefill_compiled)
             self.tokens_generated += 1                     # prefill samples token 0
+            if "exit" in rec:
+                self._note_exit(np.asarray(rec["exit"])[0])
             if gp is not None:
                 gp.commit_tokens(1)                        # token 0 streams below
             reg = registry()
@@ -2267,7 +2320,8 @@ class ServingEngine:
             # rows that hold a request: a window layer the last layer_window, the others all
             seen = np.asarray(host_pos, dtype=np.int64)[:len(running)][~past] + 1
             self._attended["full_attention"] += int(seen.sum())
-            self._attended["sliding_attention"] += int(np.minimum(seen, self.cfg.layer_window).sum())
+            if "sliding_attention" in self._attended:
+                self._attended["sliding_attention"] += int(np.minimum(seen, self.cfg.layer_window).sum())
             self._attended["steps"] += 1
         if self._goodput is not None and self._attn_path != "xla":
             # ragged-decode visibility: the bucket's tables span Bb x nbb
@@ -2292,6 +2346,7 @@ class ServingEngine:
                 self._compile_span(compiled, kind, Bb, nbb):
             outs = prog(*call_args)
         nxt, new_keys, new_pos, arenas, *sums = outs
+        exit_rows = sums.pop() if self._passes > 1 else None
         if sums:
             self._moe_rows = sums[0]
         # past the point of no return: the call consumed the donated arenas
@@ -2316,12 +2371,19 @@ class ServingEngine:
                "written": 0 if steady else host["written"],
                "ending": ending,
                "epochs": [r.preemptions for r in running],
-               "t_disp": time.perf_counter(), "t_clock": sch.clock()}
+               "t_disp": time.perf_counter(), "t_clock": sch.clock(),
+               **({"exit": exit_rows} if exit_rows is not None else {})}
         self.decode_steps += 1
         self._occupancy_sum += len(running) - ending
         self._m_steps_decode.inc()
         self._m_occupancy.observe(len(running) - ending)
         return rec
+
+    def _note_exit(self, row) -> None:
+        """One served token of a looped model: ``row`` is ``[pass chosen, p_0, ..., p_last]``
+        as its program returned it (``_exit_rows``)."""
+        self._pass_sums["exit"][int(row[0])] += 1
+        self._pass_sums["exit_mass"] += row[1:]
 
     def _note_attn_step(self) -> None:
         """One decode (or verify) dispatch: a fallback step where its attention
@@ -2353,6 +2415,8 @@ class ServingEngine:
         with self._span("serve.harvest.wait", kind="decode", rows=len(running)):
             # the host block: (Bb,) tokens and keys
             nxt, new_keys = np.asarray(rec["nxt"]), np.asarray(rec["new_keys"])
+            if "exit" in rec:
+                rec["exit"] = np.asarray(rec["exit"])
         stall = time.perf_counter() - t0
         if self._inflight_decode is not None:
             # a record dispatched ahead of this harvest: the device took it up
@@ -2440,6 +2504,8 @@ class ServingEngine:
                     self._flight.record("window_expire", rid=r.rid,
                                         released=released)
             emitted += 1
+            if "exit" in rec:
+                self._note_exit(rec["exit"][i])
             self._emit_token(r, tok_of[i])
             if r.state != "running":
                 invalidate = True                          # finished at this token
@@ -3193,9 +3259,10 @@ class ServingEngine:
                     else:
                         held = gather_state(arenas, sslot, jnp.reshape(pos == 0, (1,)), state_heads)
                     more = {"n_real": n_real}
+            exits = [] if self._passes > 1 else None
             logits, cache = forward_with_cache(
                 params, toks, pos, {**dense, **held}, cos_all, sin_all, cfg,
-                **self._fwd_kwargs(lora, slot), **more, logits_at=n_real - 1, sharded=sharded,
+                **self._fwd_kwargs(lora, slot), **more, logits_at=n_real - 1, sharded=sharded, exits=exits,
             )
             with scope("head/sample"):
                 last = logits[:, 0]
@@ -3206,6 +3273,9 @@ class ServingEngine:
             with scope("mixer/cache"):
                 kept = scatter_state(arenas, cache, sslot, state_heads) if hybrid else {}
             written, qerr = self._blocks_back(arenas, cache, dest, ring=(sslot, n_real) if hybrid else None)
+            if exits:       # a looped model: the pass the head read and the exit probabilities, with the token
+                chosen, p = exits[0]
+                return tok, {**written, **kept}, key, qerr, _exit_rows(chosen[:, 0], p[:, :, 0])
             return tok, {**written, **kept}, key, qerr
 
         if fresh:
@@ -3368,6 +3438,8 @@ class ServingEngine:
                     rows, hit = jnp.mean(fresh["moe_rows"].astype(jnp.float32), axis=0)
                     moe_sums = moe_sums + jnp.stack([1.0, rows, rows * rows, hit / cfg.expert_held])
                 return nxt, new_keys, pos + 1, arenas, moe_sums
+            if "exit" in fresh:     # a looped model: a row's pass and exit probabilities, harvested with its token
+                return nxt, new_keys, pos + 1, arenas, _exit_rows(*fresh["exit"])
             return nxt, new_keys, pos + 1, arenas
 
         return decode_paged

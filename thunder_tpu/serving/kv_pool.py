@@ -464,13 +464,14 @@ class PagedKVPool:
         if self.latent:
             counted = cfg.n_layer * cfg.latent_width * item
         else:
-            counted = 2 * len(cfg.paged_kv_layers) * cfg.n_query_groups * cfg.head_size * item
+            counted = 2 * cfg.paged_kv_slabs * cfg.n_query_groups * cfg.head_size * item
         lanes = self._arena_shape[-1]
         laid_out = (1 if self.latent else 2) * int(np.prod(self._arena_shape[1:-1])) * (-(-lanes // 128) * 128) * item
         if self.quantized_kv:       # the two scale arenas, as counted
             laid_out += 2 * int(np.prod(self._scale_shape[1:])) * 4
         return {"kind": "latent" if self.latent else "kv", "token_bytes_counted": counted,
-                "token_bytes_laid_out": laid_out // self.block_size, "lane_pack": self.lane_pack}
+                "token_bytes_laid_out": laid_out // self.block_size, "lane_pack": self.lane_pack,
+                "slabs": self._arena_shape[1]}
 
     def state_snapshot(self) -> dict:
         """Allocator state for the flight recorder: occupancy plus the
@@ -507,7 +508,7 @@ class PagedKVPool:
 
     def dense_shape(self, B: int, n_blocks: int) -> tuple[int, ...]:
         _, ng, bs, hs = kv_block_shape(self.cfg, self.block_size)   # the dense cache packs nothing
-        return (len(self.cfg.kv_layers), B, ng, n_blocks * bs, hs)  # and holds every layer that keeps K and V
+        return (self.cfg.kv_slabs, B, ng, n_blocks * bs, hs)        # and holds every layer that keeps K and V, a pass
 
     def block_bytes(self) -> int:
         """Bytes one block costs across all arenas (K+V data, plus the

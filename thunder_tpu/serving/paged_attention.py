@@ -74,6 +74,7 @@ from thunder_tpu.models.generate import (
     _norm,
     _project_qkv,
     _to_streams,
+    run_passes,
 )
 from thunder_tpu.observability.events import scope
 from thunder_tpu.serving.kv_pool import pack_state_heads, ring_tables, unpack_state_heads
@@ -106,12 +107,15 @@ def decode_path(cfg, mesh=None, arena_lanes: int | None = None) -> str:
     arena's rows: ``arena_lanes``, the pool's where the caller has built one,
     else what the pool would lay out on one device: a head of whole tiles, or
     a head that divides 128 with its KV heads in whole rows, lane-packed), and
-    ``"xla"`` under a mesh whose ``tp`` axis does not split the heads."""
+    ``"xla"`` under a mesh whose ``tp`` axis does not split the heads, and for a
+    looped model whose head the walk does not take."""
     if mesh is not None and _tp_axis(mesh, cfg.n_head, cfg.n_query_groups) is None:
         return "xla"
     if arena_lanes is None:
         arena_lanes = cfg.head_size * (1 if mesh is not None else kv_lane_pack(cfg))
-    return paged_decode_path(arena_lanes, cfg.sliding_window)
+    path = paged_decode_path(arena_lanes, cfg.sliding_window)
+    # a block a grid step takes the layer as a constant of its index maps; a looped model's slab is traced
+    return "xla" if path == "by_blocks" and cfg.n_pass > 1 else path
 
 
 def _attn_paged(q, arenas, fresh_k, fresh_v, tables, pos, *, layer, mesh, window=None):
@@ -345,11 +349,14 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
     hybrid's do; ``fresh`` carries both.  ``moe_rows``: ``fresh`` also carries ``moe_rows (L_moe, 2)`` int32, each
     expert layer's rows that landed on held experts and held experts with a row
     (``generate.moe_share_mlp``), which the decode program sums for
-    ``engine.stats()["moe"]``."""
+    ``engine.stats()["moe"]``.  A looped model (``cfg.n_pass`` > 1; one token a row):
+    the stack is the body of ``generate.run_passes``' loop, layer ``l`` of pass ``t``
+    walks slab ``cfg.kv_slab(t, l)`` of the arenas, ``fresh`` holds every slab's K and V
+    in that order and ``exit``: the pass the exit rule chose a row ``(B,)`` and its
+    exit probabilities ``(n_pass, B)``."""
     B, T = idx.shape
     hs, nh = cfg.head_size, cfg.n_head
     window = cfg.sliding_window
-    state_arena, conv_arena, n_lin, n_conv = arenas.get("state"), arenas.get("conv"), 0, 0
     with scope("embed"):        # the tokens' rows, and their positions' rows of the rope tables
         x = params["wte"][idx]
         if cfg.scale_embedding:
@@ -363,115 +370,139 @@ def forward_paged(params, idx, pos, arenas, tables, cos_all, sin_all, cfg, *,
 
     lin = partial(_linear, quantized=quantized)
     delta_fn = lora_delta_fused if (lora_fused and mesh is None) else _lora_delta
-    fresh_k, fresh_v, fresh_rows = [], [], []
-    ring_k, ring_v, ring_tabs, gmu_m = [], [], None, None
     rows_of = [] if moe_rows else None
-    for l, bp in enumerate(params["blocks"]):
-        lora_l = None
-        if lora:
-            lora_l = {t: (ab["a"][:, l], ab["b"][:, l]) for t, ab in lora.items()}
-        if cfg.layer_kind(l) == "mlp":      # the layer is its feed-forward alone: no mixer, no cache
-            with scope(f"blk{l}"):
-                x = _close_block(bp, x, None, None, cfg, quantized=quantized, moe_rows=rows_of)
-            continue
-        with scope(f"blk{l}"):
-            with scope("mixer"):
-                # under hyper-connections the sublayer reads a mixture of the streams
-                x, u, hc = hc_step(bp["hc_1"], x, cfg, sharded=mesh is not None) if cfg.hc_mult > 1 else (x, x, None)
-                if cfg.post_sublayer_norm:
-                    n1 = u
-                else:
-                    with scope("norm"):     # (under hyper-connections ``u`` is float32: rounded here)
-                        n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b")).astype(x.dtype)
-                kind = cfg.layer_kind(l)
-                if kind == "mamba2":
-                    h, state_arena, conv_arena = _mamba2_paged(
-                        bp["mamba2"], n1, {"state": state_arena, "conv": conv_arena}, sslots, cfg, layer=n_lin, lin=lin)
-                    n_lin += 1
-                elif kind == "ssm":
-                    h, state_arena, conv_arena, m = _ssm_paged(
-                        bp["ssm"], n1, {"state": state_arena, "conv": conv_arena}, sslots, cfg, layer=n_lin, lin=lin)
-                    n_lin += 1
-                    if l == cfg.gmu_source:
-                        gmu_m = m
-                elif kind == "gmu":
-                    h = gmu_mixer(bp["gmu"], n1, gmu_m, lin=lin)
-                elif cfg.diff_attention:
-                    if T != 1:
-                        raise NotImplementedError("differential attention is walked one token a row (a draft's "
-                                                  "verify and a piece of a prompt have no such kernel)")
-                    if kind == "sliding_attention":
-                        if ring_tabs is None:
-                            ring_tabs = ring_tables(sslots, ring_blocks(cfg, arenas["k_ring"].shape[3]), tables.shape[1])
-                        h, kv = _diff_paged(bp["attn"], n1, l, cfg, arenas["k_ring"], arenas["v_ring"], ring_tabs, pos,
-                                            layer=len(ring_k), window=cfg.layer_window, cdtype=cdtype, name="swa", lin=lin)
-                        ring_k.append(kv[0])
-                        ring_v.append(kv[1])
-                    else:   # a full_attention layer's own blocks, or the cross source's: the walk's layer is the owner's
-                        cross = kind == "cross_attention"
-                        own = cfg.paged_kv_layers.index(cfg.cross_from if cross else l)
-                        h, kv = _diff_paged(bp["attn"], n1, l, cfg, arenas["k"], arenas["v"], tables, pos, layer=own,
-                                            window=None, cdtype=cdtype, name="cross" if cross else "attn", lin=lin,
-                                            fresh_kv=(fresh_k[own], fresh_v[own]) if cross else None)
-                        if not cross:
-                            fresh_k.append(kv[0])
-                            fresh_v.append(kv[1])
-                elif kind == "linear_attention":
-                    h, state_arena, conv_arena = _gdn_paged(
-                        bp["gdn"], n1, {"state": state_arena, "conv": conv_arena}, sslots, pos, cfg,
-                        layer=n_lin, n_real=n_real, lin=lin)
-                    n_lin += 1
-                elif kind == "conv":
-                    h, conv_arena = _conv_paged(bp["conv"], n1, conv_arena, sslots, pos, cfg,
-                                                layer=n_conv, n_real=n_real, lin=lin)
-                    n_conv += 1
-                elif cfg.latent:
-                    h, row = _mla_paged(bp["attn"], n1, arenas["latent"], tables, pos, cos_t, sin_t, cfg,
-                                        layer=l, cdtype=cdtype, lin=lin)
-                    fresh_rows.append(row)
-                else:
-                    gate = []
-                    q, k, v = _project_qkv(bp["attn"], n1, cos_t, sin_t, cfg, lin=lin,
-                                           lora=lora_l, lora_scaling=lora_scaling,
-                                           delta_fn=delta_fn, rope=cfg.rotates(l), gate=gate)
-                    # an ordinary decoder's window kind walks the slot's ring under the
-                    # kind's window; every other layer its own blocks of the paged arenas
-                    swa = kind == "sliding_attention"
-                    if swa and T != 1:
-                        raise NotImplementedError("a sliding_attention layer's ring is walked one token a row (a "
-                                                  "draft's verify and a piece of a prompt have no such program)")
-                    if swa and ring_tabs is None:
-                        ring_tabs = ring_tables(sslots, ring_blocks(cfg, arenas["k_ring"].shape[3]), tables.shape[1])
-                    kvl = len(ring_k) if swa else len(fresh_k)      # this layer's place in its kind's arenas
-                    # fresh K/V at the cache compute dtype — the exact values the dense
-                    # path writes before attending
-                    with scope("swa" if swa else "attn"):
-                        if T == 1:
-                            # q: (B, nh, 1, hs) → (B, nh, hs)
-                            fk = k[:, :, 0].astype(cdtype)
-                            fv = v[:, :, 0].astype(cdtype)
-                            if swa:
-                                y = _attn_paged(q[:, :, 0], {"k": arenas["k_ring"], "v": arenas["v_ring"]}, fk, fv,
-                                                ring_tabs, pos, layer=kvl, window=cfg.layer_window, mesh=mesh)
-                            else:
-                                y = _attn_paged(q[:, :, 0], arenas, fk, fv, tables, pos,
-                                                layer=kvl, window=window, mesh=mesh)
-                            y = y.reshape(B, 1, nh * hs)
-                        else:
-                            fk = k.astype(cdtype)                  # (B, ng, T, hs)
-                            fv = v.astype(cdtype)
-                            y = _attn_paged(q, arenas, fk, fv, tables, pos, layer=kvl, mesh=mesh)
-                            y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
-                    y = gated_out(y, gate)
-                    with scope("out"):
-                        h = lin(y, bp["attn"]["wo"], bp["attn"].get("bo"))
-                        if lora_l is not None and "wo" in lora_l:
-                            h = h + delta_fn(y, *lora_l["wo"], lora_scaling)
-                    (ring_k if swa else fresh_k).append(fk)
-                    (ring_v if swa else fresh_v).append(fv)
-            x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling,
-                             moe_rows=rows_of, hc=hc, sharded=mesh is not None)
 
+    def blocks(x, t, state_arena, conv_arena):
+        """The stack of blocks on ``x``, once: pass ``t`` of a looped model (its slabs of the
+        arenas, ``cfg.kv_slab``; traced there), the Python integer 0 for every other."""
+        n_lin = n_conv = 0
+        fresh_k, fresh_v, fresh_rows = [], [], []
+        ring_k, ring_v, ring_tabs, gmu_m = [], [], None, None
+        for l, bp in enumerate(params["blocks"]):
+            lora_l = None
+            if lora:
+                lora_l = {name: (ab["a"][:, l], ab["b"][:, l]) for name, ab in lora.items()}
+            if cfg.layer_kind(l) == "mlp":      # the layer is its feed-forward alone: no mixer, no cache
+                with scope(f"blk{l}"):
+                    x = _close_block(bp, x, None, None, cfg, quantized=quantized, moe_rows=rows_of)
+                continue
+            with scope(f"blk{l}"):
+                with scope("mixer"):
+                    # under hyper-connections the sublayer reads a mixture of the streams
+                    x, u, hc = hc_step(bp["hc_1"], x, cfg, sharded=mesh is not None) if cfg.hc_mult > 1 else (x, x, None)
+                    if cfg.post_sublayer_norm:
+                        n1 = u
+                    else:
+                        with scope("norm"):     # (under hyper-connections ``u`` is float32: rounded here)
+                            n1 = _norm(u, bp["norm_1"], cfg, bp.get("norm_1_b")).astype(x.dtype)
+                    kind = cfg.layer_kind(l)
+                    if kind == "mamba2":
+                        h, state_arena, conv_arena = _mamba2_paged(
+                            bp["mamba2"], n1, {"state": state_arena, "conv": conv_arena}, sslots, cfg, layer=n_lin, lin=lin)
+                        n_lin += 1
+                    elif kind == "ssm":
+                        h, state_arena, conv_arena, m = _ssm_paged(
+                            bp["ssm"], n1, {"state": state_arena, "conv": conv_arena}, sslots, cfg, layer=n_lin, lin=lin)
+                        n_lin += 1
+                        if l == cfg.gmu_source:
+                            gmu_m = m
+                    elif kind == "gmu":
+                        h = gmu_mixer(bp["gmu"], n1, gmu_m, lin=lin)
+                    elif cfg.diff_attention:
+                        if T != 1:
+                            raise NotImplementedError("differential attention is walked one token a row (a draft's "
+                                                      "verify and a piece of a prompt have no such kernel)")
+                        if kind == "sliding_attention":
+                            if ring_tabs is None:
+                                ring_tabs = ring_tables(sslots, ring_blocks(cfg, arenas["k_ring"].shape[3]), tables.shape[1])
+                            h, kv = _diff_paged(bp["attn"], n1, l, cfg, arenas["k_ring"], arenas["v_ring"], ring_tabs, pos,
+                                                layer=len(ring_k), window=cfg.layer_window, cdtype=cdtype, name="swa", lin=lin)
+                            ring_k.append(kv[0])
+                            ring_v.append(kv[1])
+                        else:   # a full_attention layer's own blocks, or the cross source's: the walk's layer is the owner's
+                            cross = kind == "cross_attention"
+                            own = cfg.paged_kv_layers.index(cfg.cross_from if cross else l)
+                            h, kv = _diff_paged(bp["attn"], n1, l, cfg, arenas["k"], arenas["v"], tables, pos, layer=own,
+                                                window=None, cdtype=cdtype, name="cross" if cross else "attn", lin=lin,
+                                                fresh_kv=(fresh_k[own], fresh_v[own]) if cross else None)
+                            if not cross:
+                                fresh_k.append(kv[0])
+                                fresh_v.append(kv[1])
+                    elif kind == "linear_attention":
+                        h, state_arena, conv_arena = _gdn_paged(
+                            bp["gdn"], n1, {"state": state_arena, "conv": conv_arena}, sslots, pos, cfg,
+                            layer=n_lin, n_real=n_real, lin=lin)
+                        n_lin += 1
+                    elif kind == "conv":
+                        h, conv_arena = _conv_paged(bp["conv"], n1, conv_arena, sslots, pos, cfg,
+                                                    layer=n_conv, n_real=n_real, lin=lin)
+                        n_conv += 1
+                    elif cfg.latent:
+                        h, row = _mla_paged(bp["attn"], n1, arenas["latent"], tables, pos, cos_t, sin_t, cfg,
+                                            layer=l, cdtype=cdtype, lin=lin)
+                        fresh_rows.append(row)
+                    else:
+                        gate = []
+                        q, k, v = _project_qkv(bp["attn"], n1, cos_t, sin_t, cfg, lin=lin,
+                                               lora=lora_l, lora_scaling=lora_scaling,
+                                               delta_fn=delta_fn, rope=cfg.rotates(l), gate=gate)
+                        # an ordinary decoder's window kind walks the slot's ring under the
+                        # kind's window; every other layer its own blocks of the paged arenas
+                        swa = kind == "sliding_attention"
+                        if swa and T != 1:
+                            raise NotImplementedError("a sliding_attention layer's ring is walked one token a row (a "
+                                                      "draft's verify and a piece of a prompt have no such program)")
+                        if swa and ring_tabs is None:
+                            ring_tabs = ring_tables(sslots, ring_blocks(cfg, arenas["k_ring"].shape[3]), tables.shape[1])
+                        kvl = len(ring_k) if swa else len(fresh_k)      # this layer's place in its kind's arenas
+                        # fresh K/V at the cache compute dtype — the exact values the dense
+                        # path writes before attending
+                        with scope("swa" if swa else "attn"):
+                            if T == 1:
+                                # q: (B, nh, 1, hs) → (B, nh, hs)
+                                fk = k[:, :, 0].astype(cdtype)
+                                fv = v[:, :, 0].astype(cdtype)
+                                if swa:
+                                    y = _attn_paged(q[:, :, 0], {"k": arenas["k_ring"], "v": arenas["v_ring"]}, fk, fv,
+                                                    ring_tabs, pos, layer=kvl, window=cfg.layer_window, mesh=mesh)
+                                else:
+                                    y = _attn_paged(q[:, :, 0], arenas, fk, fv, tables, pos,
+                                                    layer=cfg.kv_slab(t, kvl), window=window, mesh=mesh)
+                                y = y.reshape(B, 1, nh * hs)
+                            else:
+                                fk = k.astype(cdtype)                  # (B, ng, T, hs)
+                                fv = v.astype(cdtype)
+                                y = _attn_paged(q, arenas, fk, fv, tables, pos, layer=kvl, mesh=mesh)
+                                y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
+                        y = gated_out(y, gate)
+                        with scope("out"):
+                            h = lin(y, bp["attn"]["wo"], bp["attn"].get("bo"))
+                            if lora_l is not None and "wo" in lora_l:
+                                h = h + delta_fn(y, *lora_l["wo"], lora_scaling)
+                        (ring_k if swa else fresh_k).append(fk)
+                        (ring_v if swa else fresh_v).append(fv)
+                x = _close_block(bp, x, n1, h, cfg, quantized=quantized, lora=lora_l, lora_scaling=lora_scaling,
+                                 moe_rows=rows_of, hc=hc, sharded=mesh is not None)
+        return x, (fresh_k, fresh_v, fresh_rows, ring_k, ring_v, ring_tabs), state_arena, conv_arena
+
+    if cfg.n_pass > 1:      # a looped model: the stack as the body of one loop over the passes
+        if T != 1:
+            raise NotImplementedError("a looped model's arenas are walked one token a row (a draft's verify and a "
+                                      "piece of a prompt take the layer as a constant of their kernel)")
+
+        def kept(h, t):
+            u, (fresh_k, fresh_v, *_), _, _ = blocks(h, t, None, None)
+            with scope("mixer/cache"):
+                return u, (jnp.stack(fresh_k, axis=1), jnp.stack(fresh_v, axis=1))
+
+        x, (ks, vs), (chosen, p) = run_passes(params, x, cfg, kept)
+        logits = _head_logits(params, x, cfg, None, quantized, sharded=mesh is not None, closed=True)
+        with scope("mixer/cache"):      # (n_pass, B, L, ng, hs) to the writer's (B, slabs, ng, hs), the slabs' own order
+            fresh = {name: jnp.moveaxis(a, 0, 1).reshape(B, -1, *a.shape[3:]) for name, a in (("k", ks), ("v", vs))}
+        fresh.update(exit=(chosen[:, 0], p[:, :, 0]))
+        return logits, fresh
+    x, (fresh_k, fresh_v, fresh_rows, ring_k, ring_v, ring_tabs), state_arena, conv_arena = blocks(
+        x, 0, arenas.get("state"), arenas.get("conv"))
     logits = _head_logits(params, x, cfg, None, quantized, sharded=mesh is not None)
     with scope("mixer/cache"):
         if cfg.latent:          # (B, L, 1, W): the token writer's layout, one group

@@ -138,7 +138,7 @@ def train_phase(device: dict, devices) -> None:
     res = train_loop(step, params, opt_state, lambda s: (idx, tgt, cos, sin),
                      steps=TRAIN_STEPS)
     losses = [float(x) for x in res.losses]
-    claimed = {k: pallasex.stats[k] - claimed_before[k] for k in pallasex.stats}
+    claimed = {k: pallasex.stats[k] - claimed_before.get(k, 0) for k in pallasex.stats}
     emit(device, "train", t0, config=CONFIG, n_layer=n_layer, depth_cut_from=full.n_layer,
          mesh=dict(mesh.shape), batch=B, seq_len=SEQ_LEN, dtype="bfloat16",
          params=total, state_bytes=6 * total,

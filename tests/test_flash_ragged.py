@@ -172,12 +172,20 @@ OPS_AT_PR48 = (
 )
 
 
+# ... and the backward's one walk of those blocks (PR 63), in the backward pair's place: ten products for fourteen
+OPS_ONE_WALK = {"add": 8, "and": 4, "broadcast_in_dim": 4, "cond": 6, "convert_element_type": 16, "dot_general": 10, "eq": 2,
+                "exp": 2, "ge": 1, "get": 24, "gt": 1, "iota": 2, "jit": 1, "mul": 13, "multiple_of": 2, "ne": 3, "not": 1,
+                "pallas_call": 1, "program_id": 1, "reduce_sum": 1, "reshape": 1, "select_n": 1, "sub": 5, "swap": 12}
+
+
+@pytest.mark.parametrize("form", ["two_kernels", "one_walk"])
 @pytest.mark.parametrize("T", [8192, 9984])
-def test_the_tails_form_is_traced_only_where_a_block_is_ragged(monkeypatch, T):
-    """At a length its block divides the three kernels trace what they traced
-    before a block could be ragged, operation for operation; at 9984 each
-    holds a third form of its body (two more products in the forward kernel,
-    seven in the backward pair) with the cut at the length beside the band's."""
+def test_the_tails_form_is_traced_only_where_a_block_is_ragged(monkeypatch, T, form):
+    """At a length its block divides the kernels trace what they traced
+    before a block could be ragged, operation for operation (the backward's
+    one walk what PR 63 wrote); at 9984 each holds a third form of its body
+    (two more products in the forward kernel, seven in the backward pair, five
+    in the one walk) with the cut at the length beside the band's."""
     monkeypatch.delenv("THUNDER_TPU_FLASH_BQ", raising=False)
     monkeypatch.delenv("THUNDER_TPU_FLASH_BK", raising=False)
     q, k = (jax.ShapeDtypeStruct((n, T, 128), jnp.bfloat16) for n in (8, 2))
@@ -185,11 +193,13 @@ def test_the_tails_form_is_traced_only_where_a_block_is_ragged(monkeypatch, T):
     fwd = jax.make_jaxpr(lambda q, k, v: pallasex._flash_fwd.__wrapped__(
         q, k, v, None, True, 128 ** -0.5, 8, 2, None, 1, 4096))(q, k, k)
     bwd = jax.make_jaxpr(lambda g, q, k, v, o, l: pallasex._flash_bwd.__wrapped__(
-        g, q, k, v, o, l, None, True, 128 ** -0.5, 8, 2, None, 1, 4096))(q, q, k, k, q, lse)
+        g, q, k, v, o, l, None, True, 128 ** -0.5, 8, 2, None, 1, 4096, form=form))(q, q, k, k, q, lse)
     got = _traced_ops(fwd.jaxpr, {}), _traced_ops(bwd.jaxpr, {})
+    want = (OPS_AT_PR48[0], OPS_AT_PR48[1] if form == "two_kernels" else OPS_ONE_WALK)
     assert pallasex.flash_schedule["block_q"] == 1024 and pallasex.flash_schedule["tail_rows"] == -T % 1024
+    assert pallasex.flash_schedule["bwd_form"] == form
     if T == 8192:
-        assert got == OPS_AT_PR48
+        assert got == want
     else:
-        assert [g["dot_general"] - w["dot_general"] for g, w in zip(got, OPS_AT_PR48)] == [2, 7]
-        assert all("lt" in g for g in got) and not any("lt" in w for w in OPS_AT_PR48)
+        assert [g["dot_general"] - w["dot_general"] for g, w in zip(got, want)] == [2, 7 if form == "two_kernels" else 5]
+        assert all("lt" in g for g in got) and not any("lt" in w for w in want)

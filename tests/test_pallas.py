@@ -197,8 +197,12 @@ def test_flash_windowed_rows_past_the_keys_band(monkeypatch, interpret_kernels, 
     scale = 1.0 / np.sqrt(hs)
     out, lse = pallasex.flash_sdpa(q, k, v, None, True, scale, window)
     dq, dk, dv = pallasex.flash_sdpa_backward(g, q, k, v, out, lse, None, True, scale, window)
+    # the backward's one walk lists the column's two kept blocks a head: a row of blocks that keeps no pair is no
+    # step of it, and its dq stays the zeros a head's sum starts from
     assert pallasex.flash_schedule == {"grid_steps": 4, "running_blocks": 2, "edge_blocks_a_full_row": 1,
                                        "block_q": 128, "block_k": 128, "tail_rows": 0,
+                                       "bwd_form": "one_walk", "bwd_grid_steps": 2 * rep,
+                                       "bwd_resident_bytes": (4 + 2 * 4) * 128 * (512 + 2 * 128),
                                        "head_qk": 128, "head_v": 128, "lanes_padded": 0}
     assert np.isnan(np.asarray(_sdpa_reference(q, k, v, None, True, scale, window)[0][..., live:, :])).all()
     assert np.isfinite(np.asarray(out)).all() and not np.asarray(dq[..., live:, :]).any()
@@ -573,8 +577,12 @@ def test_flash_schedule_counter_at_the_train_cells_shapes(monkeypatch, cell, blo
             monkeypatch.delenv(f"THUNDER_TPU_FLASH_B{which}", raising=False)
     shape = {"mistral": (32, 8, 8192, 128, 32, 8, 4096), "hybrid": (32, 4, 8192, 256, 16, 2, None)}[cell]
     fwd, bwd = _trace_flash(*shape)
-    assert fwd == bwd == {"grid_steps": steps, "running_blocks": steps, "edge_blocks_a_full_row": edges_a_full_row,
-                          "block_q": blocks or 1024, "block_k": blocks or 1024, "tail_rows": 0}
+    assert fwd == {"grid_steps": steps, "running_blocks": steps, "edge_blocks_a_full_row": edges_a_full_row,
+                   "block_q": blocks or 1024, "block_k": blocks or 1024, "tail_rows": 0}
+    # the backward call walks those blocks once a query head of a group, its sums in VMEM (the interpreter's form)
+    rep, hs = shape[4] // shape[5], shape[3]
+    assert bwd == {**fwd, "bwd_form": "one_walk", "bwd_grid_steps": rep * steps,
+                   "bwd_resident_bytes": (4 + 2 * 2) * hs * 3 * 8192}
     # ``stats`` stays flat counters: its readers sum and subtract them
     assert all(type(v) is int for v in pallasex.stats.values())
 

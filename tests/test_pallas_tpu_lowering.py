@@ -338,6 +338,52 @@ def test_kernel_lowers_and_compiles_for_tpu(shape, kernel, tpu_sharding):
             assert re.search(rf"%{name}(\.\d+)? = ", compiled), (kernel, name)
 
 
+# H, G, T (None: ``COMPILED_BUCKET``), head, window, a full mask a query row
+ONE_WALK = {"bucket": (8, 2, None, HS, None, False), "bucket_full_mask": (8, 2, None, HS, None, True),
+            "mistral_train": (32, 8, 8192, HS, 4096, False), "head256_rep8": (8, 1, 2048, 256, None, False),
+            "ragged": (8, 2, 2304, HS, None, False)}
+
+
+@pytest.mark.parametrize("case", ONE_WALK)
+def test_flash_bwd_one_walk_compiles_for_a_v5e(case, tpu_sharding, monkeypatch):
+    """The backward pass as the chip builds it (PR 63), the grouped product's way
+    of giving a compile on this CPU a v5e's 96 MiB to ask for: one kernel named
+    ``_flash_bwd``, five products a form of its body, dq of a head and dk, dv
+    of the group resident in float32 beside the output blocks, and the limit
+    it states from those bytes.  At ``COMPILED_BUCKET``, with a mask a query
+    row, at the Mistral train cell's call (24 MiB resident), at heads of 256,
+    8 to a group, and with a ragged last block (the tail's form of the body).  Only the compile shows that
+    Mosaic takes the product over the tile's first axis and sums into rows of
+    a scratch the whole sequence long.  ``CASES`` keeps the two kernels: they
+    are what this CPU's 16 MiB take."""
+    for which in "QK":
+        monkeypatch.delenv(f"THUNDER_TPU_FLASH_B{which}", raising=False)
+    monkeypatch.setattr(px, "_gmm_vmem_cap", lambda: 96 << 20)
+    H, G, T_, hs, window, masked = ONE_WALK[case]
+    T_ = T_ or COMPILED_BUCKET
+    spec = lambda *shape, dt=BF: jax.ShapeDtypeStruct(shape, dt, sharding=tpu_sharding)  # noqa: E731
+    q, k, lse = spec(H, T_, hs), spec(G, T_, hs), spec(H, 1, T_, dt=F32)
+    mask = [spec(H, T_, T_, dt=F32)] if masked else []
+    before = dict(px.stats)
+    lowered = jax.jit(lambda g, q_, k_, v, o, l, *m: px._flash_bwd.__wrapped__(
+        g, q_, k_, v, o, l, *(m or (None,)), True, hs ** -0.5, H, G, "full" if masked else None, T_ if masked else 1, window,
+    )).trace(q, q, k, k, q, lse, *mask).lower(lowering_platforms=("tpu",))
+    sched = dict(px.flash_schedule)
+    block = sched["block_q"]
+    assert sched["bwd_form"] == "one_walk" and sched["bwd_grid_steps"] == H // G * sched["grid_steps"]
+    assert sched["bwd_resident_bytes"] == 4 * hs * 3 * -(-T_ // block) * block + 2 * 2 * hs * 3 * T_
+    assert px.stats["flash_bwd_one_walk"] == before.get("flash_bwd_one_walk", 0) + 1
+    assert px.stats.get("flash_bwd_two_kernels", 0) == before.get("flash_bwd_two_kernels", 0)
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and 'kernel_name = "_flash_bwd"' in text
+    assert f'\\22size\\22: {sched["bwd_resident_bytes"] + (16 << 20)}}}' in text       # the scoped limit the call states
+    module = _mosaic_module(text)
+    assert module.count("tpu.matmul") == 5 * (3 if sched["tail_rows"] else 2)      # whole and edge, and the tail's
+    assert f"memref<{-(-T_ // block) * block}x{hs}xf32, #tpu.memory_space<vmem>>" in module      # the sums, a sequence long
+    if tpu_sharding is not None:
+        assert re.search(r"%_flash_bwd(\.\d+)? = ", lowered.compile().as_text())
+
+
 def _lowered_flash_fwd(H, G, T, hs, hv, sharding, window=None):
     q = jax.ShapeDtypeStruct((H, T, hs), BF, sharding=sharding)
     k = jax.ShapeDtypeStruct((G, T, hs), BF, sharding=sharding)
@@ -1387,7 +1433,7 @@ def test_every_pallas_call_site_is_named():
     import inspect
 
     src = inspect.getsource(px)
-    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 24      # PR 45: the Mamba-2 scan's two; PR 56: hc_mix; PR 58: moe_combine
+    assert src.count("pallas_call(") == len(re.findall(r"\n +name=", src)) == 25      # PR 45: the Mamba-2 scan's two; PR 56: hc_mix; PR 58: moe_combine; PR 63: the flash backward's one walk
     assert {n for names in map(kernel_names, CASES["gqa"]) for n in names} == {
         "_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv", "flash_cross_entropy",
         "paged_attn_decode", "paged_attn_decode_quant", "paged_attn_verify",

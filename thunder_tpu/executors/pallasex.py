@@ -241,7 +241,7 @@ def _supported(q_shape, k_shape, v_shape, dtype, causal, mask_shape=None, window
 #
 # The flash kernels.
 #
-# One mechanism for all three: a grid step is a block of the score matrix
+# One mechanism for all four: a grid step is a block of the score matrix
 # that holds at least one kept pair.  ``_flash_schedule`` lists those blocks
 # from ``(Tq, Tk, BQ, BK, causal, window)`` at trace time; the kernels take
 # the list by scalar prefetch, their grid is ``(heads, blocks listed)`` and
@@ -255,24 +255,56 @@ def _supported(q_shape, k_shape, v_shape, dtype, causal, mask_shape=None, window
 # share an accumulator (a row of blocks for ``_flash_fwd``/``_flash_bwd_dq``;
 # for ``_flash_bwd_dkv`` a column, walked once for each of the group's ``rep``
 # query heads, so dk and dv leave the kernel summed over the group in
-# float32).  ``edge`` marks a block that also holds a masked pair: the block
-# on the diagonal and the one on the window's far edge.  Only those build the
-# iotas, compares and select; every other block holds kept pairs alone.  A
-# user's additive mask is added in every block.
+# float32; for ``_flash_bwd`` a query head's whole walk).  ``edge`` marks a
+# block that also holds a masked pair: the block on the diagonal and the one
+# on the window's far edge.  Only those build the iotas, compares and select;
+# every other block holds kept pairs alone.  A user's additive mask is added
+# in every block.
+#
+# The backward pass is one kernel, ``_flash_bwd``, one walk of the blocks
+# (PR 63): a block makes ``s^T``, ``p^T``, ``dp^T`` and ``ds^T`` once and from
+# them ``dv += p^T g``, ``dk += ds^T q`` and ``dq += ds k``, five products
+# where ``_flash_bwd_dq`` and ``_flash_bwd_dkv`` together spend seven (and two
+# sets of exponentials, compares and loads of ``lse`` and ``delta``).  Its
+# order is ``_flash_walk``'s: a KV group a program of the grid's first axis,
+# the group's query heads outermost, a head's columns, a column's kept rows.
+# The sums that cross blocks stay in VMEM in float32: dq of the head being
+# walked, ``(Tq, hs)``, added to at rows ``i BQ`` and written once, cast, at
+# the head's last block; dk and dv of the group, ``2 x (Tk, hs)``, added to at
+# rows ``j BK`` through all ``rep`` heads and written once at the group's
+# end.  Nothing is read back from HBM to be added to, and every row of dq, dk
+# and dv receives its terms in the order the two kernels give them (heads,
+# then rows, for a column; columns ascending for a row): on the chip the
+# result is theirs to the bit (``tools/flash_tune.py --check``).  K and V are
+# fetched once a head, not once a group, which the products hide: columns
+# outermost with all ``rep`` heads' dq resident read 7.06 ms for 6.93 at the
+# Mistral cell's call and does not fit at heads of 256 (PERF.md, PR 63).
+# ``ds k`` contracts the transposed tile over its first axis; Mosaic turns
+# the tile on the XLU, idle otherwise, and the product costs what the others
+# do.  The kernel states its VMEM limit from its own bytes
+# (``_flash_walk_bytes``: the sums and the three output blocks, 24 MiB at the
+# Mistral train cell's call, 48 at the hybrid cell's heads of 256, beside the
+# 16 MiB of block tiles).  Where that does not fit what the device reports
+# (``_flash_bwd_form``, by ``_gmm_vmem_cap``: 32,768 tokens at a head of 128
+# on a v5e, 16,384 at 256), and in a compile for a device that reports
+# nothing, the two kernels run as before.  ``stats["flash_bwd_one_walk"]`` /
+# ``["flash_bwd_two_kernels"]`` count the calls traced in each form.
 #
 # ``lse`` and the backward pass's ``delta`` are lane-dense rows, ``(BH, 1,
 # Tq)`` float32 in blocks of ``(1, 1, BQ)``: a ``(BQ, 1)`` block of a
 # ``(BH, Tq, 1)`` array is a column of 128-lane tiles, 128 times its bytes in
-# HBM and in every copy.  ``_flash_bwd_dkv`` computes the scores transposed
-# (``k q^T``, a ``(BK, BQ)`` tile) so the rows broadcast as they arrive and
-# ``dv += p^T g``, ``dk += ds^T q`` are plain products; ``_flash_fwd`` and
-# ``_flash_bwd_dq`` transpose a column to a row, a row to a column, once a row
-# of blocks.
+# HBM and in every copy.  ``_flash_bwd`` and ``_flash_bwd_dkv`` compute the
+# scores transposed (``k q^T``, a ``(BK, BQ)`` tile) so the rows broadcast as
+# they arrive and ``dv += p^T g``, ``dk += ds^T q`` are plain products;
+# ``_flash_fwd`` and ``_flash_bwd_dq`` transpose a column to a row, a row to a
+# column, once a row of blocks.
 #
 # Blocks are ``_flash_blocks``': the size that costs least by its count of
 # steps and of pairs listed, 1024 where that fits VMEM and the band.  At T 8192
 # on one v5e (PERF.md, PR 29) the two backward kernels then run at 88-94% of
-# the MXU's peak over the pairs their blocks hold, the forward kernel at 67%.
+# the MXU's peak over the pairs their blocks hold, the forward kernel at 67%;
+# the one walk at 94% at the Mistral train cell's call and 97% at the hybrid
+# cell's (PERF.md, PR 63: 6.93 and 16.22 ms a call for 10.05 and 22.94).
 #
 # A length need not be a multiple of its block.  The list then ends in a
 # ragged block, flagged ``_TAILQ`` (it reaches past ``Tq``) or ``_TAILK``
@@ -282,7 +314,8 @@ def _supported(q_shape, k_shape, v_shape, dtype, causal, mask_shape=None, window
 # likewise in ``_flash_bwd_dkv``.  The other way round the tail would reach a
 # sum (``p = 0`` times a stale ``V`` row is NaN), so a kernel built over such a
 # list has a third form of its body, run in the ragged blocks alone: the
-# tail's operands selected to zero and the mask cut at the length.  Where the
+# tail's operands selected to zero and the mask cut at the length
+# (``_flash_bwd`` sums along both axes, so it zeroes both tails).  Where the
 # blocks divide both lengths no entry carries the flag and the kernels hold
 # no such form: they are what they were before there was one.
 #
@@ -348,7 +381,7 @@ def _flash_schedule(Tq: int, Tk: int, BQ: int, BK: int, causal: bool, window: in
 # (``head_qk``, ``head_v``) with the zeros added a row over q, k and v together
 # (``lanes_padded``).  Not among ``stats``: readers sum and subtract those
 # counters.
-flash_schedule: dict[str, int] = {}
+flash_schedule: dict[str, int | str] = {}
 
 
 def _note_widths(hs: int, hv: int, hp: int, hvp: int):
@@ -416,6 +449,7 @@ def _on_edge(flag, causal: bool, body, tail: int = 0):
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b^T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
 
 
 def _fwd_kernel(qi_ref, kj_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window, Tq, Tk):
@@ -487,10 +521,10 @@ def _flash_specs(H: int, G: int, mode: str | None, mq: int, BQ: int, BK: int, hs
     )
 
 
-def _flash_params():
+def _flash_params(**more):
     if _interpret():
         return {}
-    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))}
+    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"), **more)}
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "H", "G", "mode", "mq", "window"))
@@ -622,29 +656,151 @@ def _bwd_dkv_kernel(qi_ref, kj_ref, head_ref, flag_ref, *refs, BQ, BK, causal, s
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "scale", "H", "G", "mode", "mq", "window"))
+def _bwd_walk_kernel(qi_ref, kj_ref, head_ref, flag_ref, *refs, BQ, BK, causal, scale, has_mask, window, Tq, Tk):
+    del head_ref   # the index maps' alone
+    if has_mask:
+        g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, mask_ref, dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s = refs
+    else:
+        g_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s = refs
+        mask_ref = None
+    t = pl.program_id(1)
+    i, j, flag = qi_ref[t], kj_ref[t], flag_ref[t]
+    rows = pl.ds(pl.multiple_of(i * BQ, BQ), BQ)
+    cols = pl.ds(pl.multiple_of(j * BK, BK), BK)
+
+    @pl.when(t == 0)
+    def _open_group():
+        dk_s[...] = jnp.zeros_like(dk_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
+    @pl.when((flag & _FIRST) != 0)
+    def _open_head():
+        dq_s[...] = jnp.zeros_like(dq_s)
+
+    def block(masked: bool, ragged: bool = False):
+        # ``_bwd_dkv_kernel``'s tile, (BK, BQ), and its zeros past Tq; past Tk ``_bwd_dq_kernel``'s: k and v zero
+        # there, so that what the copy did not bring reaches no row of dq
+        past_q, past_k = ragged and Tq % BQ != 0, ragged and Tk % BK != 0
+        q, g = _load(q_ref, past_q, i, BQ, Tq), _load(g_ref, past_q, i, BQ, Tq)
+        k, v = _load(k_ref, past_k, j, BK, Tk), _load(v_ref, past_k, j, BK, Tk)
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
+        if has_mask:
+            s = s + mask_ref[0].astype(jnp.float32).T  # (BK, 1|BQ) broadcasts
+        p = jnp.exp(s - _load(lse_ref, past_q, i, BQ, Tq, 1))
+        if masked:
+            # past Tq only a mask's own rows reach p; past Tk its columns do
+            keep = _keep(i * BQ, j * BK, (BK, BQ), 1, window, causal,
+                         rows=Tq if past_q and has_mask and mask_ref.shape[1] > 1 else None,
+                         cols=Tk if past_k and has_mask else None)
+            if keep is not None:
+                p = jnp.where(keep, p, 0.0)
+        dv_s[cols, :] += jax.lax.dot_general(p.astype(g.dtype), g, _NN, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, g, _NT, preferred_element_type=jnp.float32)
+        ds = (p * (dp - _load(delta_ref, past_q, i, BQ, Tq, 1))).astype(q.dtype)
+        dk_s[cols, :] += scale * jax.lax.dot_general(ds, q, _NN, preferred_element_type=jnp.float32)
+        dq_s[rows, :] += scale * jax.lax.dot_general(ds, k, _TN, preferred_element_type=jnp.float32)
+
+    _on_edge(flag, causal, block, (_TAILQ if Tq % BQ else 0) | (_TAILK if Tk % BK else 0))
+
+    @pl.when((flag & _LAST) != 0)
+    def _close_head():
+        dq_ref[0] = dq_s[:Tq, :].astype(dq_ref.dtype)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _close_group():
+        dk_ref[0] = dk_s[:Tk, :].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[:Tk, :].astype(dv_ref.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _flash_walk(Tq: int, Tk: int, BQ: int, BK: int, causal: bool, window: int | None, rep: int):
+    """``_flash_schedule``'s third order, the one walk's: a KV group's query
+    heads outermost, a head's columns, a column's kept rows.  ``_FIRST`` /
+    ``_LAST`` bracket a head (its dq); the group's dk and dv open at the first
+    entry and close at the last."""
+    qi, kj, _, flag = _flash_schedule(Tq, Tk, BQ, BK, causal, window, by_column=True)
+    flag = flag & ~(_FIRST | _LAST)
+    flag[0] |= _FIRST
+    flag[-1] |= _LAST
+    out = (np.tile(qi, rep), np.tile(kj, rep), np.repeat(np.arange(rep, dtype=np.int32), len(qi)), np.tile(flag, rep))
+    for a in out:
+        a.setflags(write=False)
+    return out
+
+
+def _flash_walk_bytes(Tq: int, Tk: int, BQ: int, BK: int, hs: int, itemsize: int) -> int:
+    """VMEM the one walk holds from block to block: dq of a head and dk, dv of
+    the group in float32, whole blocks long, and the three output blocks they
+    are cast into at a head's and the group's end (two buffers each, the
+    pipeline's)."""
+    return 4 * hs * (-(-Tq // BQ) * BQ + 2 * -(-Tk // BK) * BK) + 2 * itemsize * hs * (Tq + 2 * Tk)
+
+
+def _flash_bwd_form(resident: int) -> str:
+    """``one_walk`` where its sums fit the VMEM a kernel may ask for
+    (``_gmm_vmem_cap``: what the device reports; the interpreter has none to
+    fill) beside the block tiles ``_flash_blocks`` sized for the 16 MiB a
+    kernel gets without asking; else ``two_kernels``."""
+    return "one_walk" if _interpret() or resident + _GMM_VMEM_DEFAULT <= _gmm_vmem_cap() else "two_kernels"
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "H", "G", "mode", "mq", "window", "form"))
 def _flash_bwd(g, q, k, v, out, lse, mask, causal: bool, scale: float, H: int, G: int, mode: str | None, mq: int,
-               window: int | None = None):
+               window: int | None = None, form: str | None = None):
     """g/q/out (BH, Tq, hs), k/v (BG, Tk, hs), lse (BH, 1, Tq);
     returns (dq (BH,...), dk, dv (BG,...)).
 
-    GQA: ``_flash_bwd_dq`` runs over the flat query heads with K/V gathered by
+    ``_flash_bwd`` (``one_walk``) runs over the KV groups and walks a group's
+    blocks once a query head, with dq of the head and dk, dv of the group
+    summed in VMEM.  Where those sums do not fit (``_flash_bwd_form``; ``form``
+    is the tests' and the tuning tool's to set), two kernels:
+    ``_flash_bwd_dq`` runs over the flat query heads with K/V gathered by
     index map; ``_flash_bwd_dkv`` runs over the KV groups and walks each
     column of blocks once a query head of the group, so K/V are fetched once
     a column and dk/dv are summed in its float32 accumulators."""
     BH, Tq, hs = q.shape
     BG, Tk, _ = k.shape
+    rep = H // G
     BQ, BK = _flash_blocks(q, k, mq, window, causal)
     has_mask = mask is not None
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1).reshape(BH, 1, Tq)
     _note_schedule(Tq, Tk, BQ, BK, causal, window)
     static = dict(BQ=BQ, BK=BK, causal=causal, scale=scale, has_mask=has_mask, window=window, Tq=Tq, Tk=Tk)
     operands = [g, q, k, v, lse, delta] + ([mask] if has_mask else [])
+    resident = _flash_walk_bytes(Tq, Tk, BQ, BK, hs, q.dtype.itemsize)
+    form = form or _flash_bwd_form(resident)
+    stats["flash_bwd_" + form] = stats.get("flash_bwd_" + form, 0) + 1        # a call site, at trace time
 
     def specs(by_group):
         q_spec, kv_spec, row_spec, mask_spec = _flash_specs(H, G, mode, mq, BQ, BK, hs, by_group)
         ins = [q_spec, q_spec, kv_spec, kv_spec, row_spec, row_spec] + ([mask_spec] if has_mask else [])
         return ins, q_spec, kv_spec
+
+    if form == "one_walk":
+        sched = _flash_walk(Tq, Tk, BQ, BK, causal, window, rep)
+        flash_schedule.update(bwd_form=form, bwd_resident_bytes=resident, bwd_grid_steps=len(sched[0]))
+        sums = lambda T, B: pltpu.VMEM((-(-T // B) * B, hs), jnp.float32)   # noqa: E731 - whole blocks long
+        return pl.pallas_call(
+            functools.partial(_bwd_walk_kernel, **static),
+            name="_flash_bwd",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(BG, len(sched[0])),
+                in_specs=specs(True)[0],
+                out_specs=[
+                    pl.BlockSpec((1, Tq, hs), lambda b, t, *p: (b * rep + p[2][t], 0, 0)),
+                    *[pl.BlockSpec((1, Tk, hs), lambda b, t, *p: (b, 0, 0))] * 2,
+                ],
+                scratch_shapes=[sums(Tq, BQ), sums(Tk, BK), sums(Tk, BK)],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((BH, Tq, hs), q.dtype),
+                jax.ShapeDtypeStruct((BG, Tk, hs), k.dtype),
+                jax.ShapeDtypeStruct((BG, Tk, hs), v.dtype),
+            ],
+            interpret=_interpret(),
+            **_flash_params(vmem_limit_bytes=resident + _GMM_VMEM_DEFAULT),
+        )(*sched, *operands)
 
     qi, kj, _, flag = _flash_schedule(Tq, Tk, BQ, BK, causal, window)
     dq_in, dq_out, _ = specs(False)
@@ -667,7 +823,8 @@ def _flash_bwd(g, q, k, v, out, lse, mask, causal: bool, scale: float, H: int, G
         **_flash_params(),
     )(qi, kj, flag, *operands)
 
-    sched = _flash_schedule(Tq, Tk, BQ, BK, causal, window, by_column=True, rep=H // G)
+    sched = _flash_schedule(Tq, Tk, BQ, BK, causal, window, by_column=True, rep=rep)
+    flash_schedule.update(bwd_form=form, bwd_resident_bytes=0, bwd_grid_steps=rep * len(qi) + len(sched[0]))
     dkv_in, _, kv_out = specs(True)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **static),

@@ -1,4 +1,4 @@
-"""Time the three flash kernels alone on the chip, at the benchmark cells' shapes.
+"""Time the four flash kernels alone on the chip, at the benchmark cells' shapes.
 
     python3 tools/flash_tune.py [--shapes mistral,hybrid] [--blocks 512x512,512x256] [--check]
     python3 tools/flash_tune.py --serve [--cells trinity-mini]
@@ -6,12 +6,17 @@
     python3 tools/flash_tune.py --latent [--check]
 
 For each shape and each (BQ, BK) (none given: what ``pallasex._flash_blocks``
-derives), one line: ms a call of ``_flash_fwd``, ``_flash_bwd_dq`` and
-``_flash_bwd_dkv`` by name from a device trace of five forward and five
-backward calls, and of whatever else XLA runs beside them in the backward
-program (``delta``, a sum over the group's heads).  ``--check`` first compares
+derives): a line for ``_flash_fwd`` and a line for each form of the backward
+pass, ``_flash_bwd`` (the one walk) and ``_flash_bwd_dq`` + ``_flash_bwd_dkv``
+(the two kernels it replaced, kept for what the walk's sums do not fit): ms a
+call by name from a device trace of five calls, the bytes the form keeps
+resident in VMEM, its grid steps a KV group, and whatever else XLA runs beside
+the kernels in the backward program (``delta``); then the one walk against
+the pair, and which form ``pallasex._flash_bwd_form`` takes on this device.
+``--check`` first compares
 out, dq, dk, dv with the float32 reference at T 2048 and at T 2304, which no
-derived block divides (compiled kernels, not the interpreter).  ``--serve``:
+derived block divides, and holds the backward pass's two forms to each
+other, bit for bit (compiled kernels, not the interpreter).  ``--serve``:
 the forward kernel alone as a whole prompt's prefill calls it in three serve
 cells (``generate._attn_with_cache`` at a static position 0), a line a prefill
 bucket and kind of layer: the derived block, the rows of its last block past
@@ -81,19 +86,40 @@ def kernel_ms(run, reps):
     return {name: seconds * 1e3 / reps for name, seconds in ops}
 
 
+BWD_FORMS = {"one_walk": ("_flash_bwd",), "two_kernels": ("_flash_bwd_dq", "_flash_bwd_dkv")}
+
+
 def time_shape(name, blocks):
     B, H, G, T, hs, window = SHAPES[name]
     q, k, v, g = operands(B, H, G, T, hs)
     scale = 1.0 / np.sqrt(hs)
     fwd = lambda: px._flash_fwd(q, k, v, None, True, scale, H, G, None, 1, window)   # noqa: E731
     out, lse = jax.block_until_ready(fwd())
-    bwd = lambda: px._flash_bwd(g, q, k, v, out, lse, None, True, scale, H, G, None, 1, window)   # noqa: E731
-    jax.block_until_ready(bwd())
-    ms = kernel_ms(lambda: jax.block_until_ready((fwd(), bwd())), REPS)
-    three = [ms.pop(n, float("nan")) for n in ("_flash_fwd", "_flash_bwd_dq", "_flash_bwd_dkv")]
-    rest = ", ".join(f"{n} {t:.3f}" for n, t in sorted(ms.items(), key=lambda kv: -kv[1])[:4])
-    print(f"{name:8s} {blocks or 'derived':>9s}: fwd {three[0]:7.3f}  dq {three[1]:7.3f}  dkv {three[2]:7.3f}"
-          f"  sum {sum(three):7.3f} ms   beside them: {rest}   schedule {px.flash_schedule}", flush=True)
+    ms = kernel_ms(lambda: jax.block_until_ready(fwd()), REPS)
+    head = f"{name:8s} {blocks or 'derived':>9s}:"
+    print(f"{head} fwd {ms.pop('_flash_fwd', float('nan')):7.3f} ms", flush=True)
+    took = {}
+    for form, names in BWD_FORMS.items():
+        bwd = lambda: px._flash_bwd(g, q, k, v, out, lse, None, True, scale, H, G, None, 1, window, form=form)   # noqa: E731
+        try:
+            jax.block_until_ready(bwd())
+        except Exception as e:  # a form Mosaic refuses at this geometry is a result of the search
+            print(f"{head} bwd {form:11s} FAILED {type(e).__name__}: {str(e)[:300]}", flush=True)
+            continue
+        schedule = dict(px.flash_schedule)
+        ms = kernel_ms(lambda: jax.block_until_ready(bwd()), REPS)
+        kernels = [ms.pop(n, float("nan")) for n in names]
+        took[form] = sum(kernels)
+        rest = ", ".join(f"{n} {t:.3f}" for n, t in sorted(ms.items(), key=lambda kv: -kv[1])[:4])
+        print(f"{head} bwd {form:11s} " + "  ".join(f"{n} {t:7.3f}" for n, t in zip(names, kernels))
+              + f"  sum {took[form]:7.3f} ms   resident {schedule['bwd_resident_bytes'] / 2**20:.1f} MiB,"
+              f" {schedule['bwd_grid_steps']} steps a group   beside them: {rest}   schedule {schedule}", flush=True)
+    if len(took) == 2:
+        print(f"{head} one walk against the two kernels: {took['one_walk'] / took['two_kernels'] - 1:+.3f}", flush=True)
+    rule = px._flash_bwd_form(px._flash_walk_bytes(T, T, *px._flash_blocks(q, k, 1, window), hs, q.dtype.itemsize))
+    print(f"{head} the byte rule takes {rule} ({px._gmm_vmem_cap() / 2**20:.0f} MiB of VMEM to ask for)", flush=True)
+    if rule not in took:
+        raise RuntimeError(f"{rule}, the form the byte rule takes, did not run")
 
 
 def table_form(q, kt, vt, window):
@@ -273,6 +299,19 @@ def check():
             err = float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
             worst = max(worst, err)
             print(f"check {name:8s} T {T} {what:3s} relative error {err:.5f}   schedule {px.flash_schedule}", flush=True)
+        # the form the byte rule did not take, against the one it took
+        took = px.flash_schedule["bwd_form"]
+        other = next(f for f in BWD_FORMS if f != took)
+        flat = lambda x: x.reshape(-1, T, hs)   # noqa: E731
+        pair = px._flash_bwd(*(flat(x) for x in (g, q, k, v, out)), lse.reshape(-1, 1, T), None, True, scale, H, G, None, 1,
+                             window, form=other)
+        for what, a, b in zip(("dq", "dk", "dv"), got[1:], pair):
+            a, b = flat(a).astype(jnp.float32), b.astype(jnp.float32)
+            err = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+            worst = max(worst, err)
+            differ = int(jnp.sum(a != b))
+            print(f"check {name:8s} T {T} {what:3s} {took} against {other}: {'they differ' if differ else 'the same bits'},"
+                  f" relative {err:.2e}, {differ} of {a.size} elements", flush=True)
     set_blocks()
     return worst
 

@@ -17,8 +17,9 @@ two rows of 688 blocks), and ``mistral7b-train-1chip``'s step at one layer, the
 grouped product given the VMEM a v5e reports (96 MiB: what the chip lowers;
 since PR 60 a prompt's product copies its own weights and a decode step's does
 not, so ``lfm2moe-serve-1chip.prefill_fresh_2560`` differs across that commit
-and no ``decode_paged`` does).  ``same`` compares two such directories file by
-file.  A
+and no ``decode_paged`` does; across PR 63 ``mistral7b-train-1chip.step_1layer``
+differs, one backward flash kernel for two, and no serve program does).  ``same``
+compares two such directories file by file.  A
 Mosaic kernel's body is bytecode that carries its source's path and line
 numbers, so each is parsed and printed without locations first; everything
 else is compared as it is.  Exits non-zero where a text differs."""
